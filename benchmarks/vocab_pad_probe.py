@@ -17,10 +17,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from deepspeed_tpu.utils.jax_env import honor_jax_platforms
-
-honor_jax_platforms()
-
 import jax
 import jax.numpy as jnp
 

@@ -101,14 +101,12 @@ def initialize(
         collate_fn=collate_fn,
     )
 
-    # monitor wiring (reference engine.py:278 MonitorMaster)
-    try:
-        from .monitor.monitor import MonitorMaster
+    # monitor wiring (reference engine.py:278 MonitorMaster); a missing
+    # optional writer (tensorboard / wandb) disables itself inside
+    from .monitor.monitor import MonitorMaster
 
-        monitor = MonitorMaster(engine.config)
-        engine.monitor = monitor if monitor.enabled else None
-    except Exception:
-        engine.monitor = None
+    monitor = MonitorMaster(engine.config)
+    engine.monitor = monitor if monitor.enabled else None
     if engine.monitor is not None and engine.telemetry is not None:
         # registry gauges fan out to every Monitor backend at steps_per_print
         engine.telemetry.attach_monitor(engine.monitor)

@@ -16,9 +16,6 @@ as device loss. The agent:
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -48,45 +45,13 @@ def choose_compatible_world_size(
     return max(fitting)
 
 
-def _default_probe(timeout_s: float) -> bool:
-    """Device liveness = a tiny compute completing ON THE EXPECTED PLATFORM,
-    probed in a KILLABLE subprocess — an in-process probe of a wedged
-    accelerator plugin hangs unrecoverably (the exact failure mode this
-    monitor exists to detect).
-
-    Scope: valid where a second process can reach the accelerator (remote
-    tunnel / proxy runtimes, CPU meshes). On classic TPU VMs the training
-    process holds libtpu exclusively, so a child CANNOT init the backend —
-    use :func:`make_progress_probe` there instead (no subprocess; watches
-    the training step counter). The child prints its backend and the probe
-    fails on a platform mismatch, so a silent CPU fallback can never report
-    a wedged accelerator as healthy."""
-    expected = (os.environ.get("JAX_PLATFORMS") or "").split(",")[0].strip()
-    code = (
-        "import jax, jax.numpy as jnp;"
-        "x = jnp.ones((64, 64), jnp.bfloat16);"
-        "(x @ x).block_until_ready();"
-        "print('PROBE_BACKEND', jax.default_backend())"
-    )
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=timeout_s, stdin=subprocess.DEVNULL,
-        )
-    except subprocess.TimeoutExpired:
-        return False
-    if proc.returncode != 0 or "PROBE_BACKEND" not in proc.stdout:
-        return False
-    backend = proc.stdout.strip().split()[-1]
-    return not expected or backend == expected
-
-
 def make_progress_probe(get_step: Callable[[], int], stall_s: float = 300.0):
-    """Probe from TRAINING PROGRESS instead of a subprocess: healthy while
-    ``get_step()`` advances within ``stall_s``. Works on exclusive-libtpu
-    deployments where no second process can touch the chip (the reference's
-    worker monitoring also watches the worker, not the device). Pass e.g.
-    ``lambda: engine.global_steps``."""
+    """THE probe for an in-process :class:`DeviceMonitor`: healthy while
+    ``get_step()`` advances within ``stall_s``. A chip belongs to one process
+    at a time — the training process holds it, so liveness is read from
+    training progress, never by opening the device from a second process
+    (the reference's worker monitoring also watches the worker, not the
+    device). Pass e.g. ``lambda: engine.global_steps``."""
     state = {"step": None, "t": time.monotonic()}
 
     def probe(_timeout_s: float) -> bool:
@@ -128,16 +93,18 @@ class DeviceMonitor:
 
     def __init__(
         self,
+        probe_fn: Callable[[float], bool],
         interval_s: float = 60.0,
         probe_timeout_s: float = 90.0,
         failures_to_trip: int = 2,
-        probe_fn: Optional[Callable[[float], bool]] = None,
         on_trip: Optional[Callable[[], None]] = None,
     ):
+        """``probe_fn(timeout_s) -> healthy``; in the training process build
+        it with :func:`make_progress_probe` (``lambda: engine.global_steps``)."""
         self.interval_s = float(interval_s)
         self.probe_timeout_s = float(probe_timeout_s)
         self.failures_to_trip = int(failures_to_trip)
-        self.probe_fn = probe_fn or _default_probe
+        self.probe_fn = probe_fn
         self.on_trip = on_trip
         self.consecutive_failures = 0
         self.probes = 0

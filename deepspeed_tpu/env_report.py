@@ -18,10 +18,6 @@ RED_NO = "\033[93m[NO]\033[0m"
 def main() -> int:
     import jax
 
-    from deepspeed_tpu.utils.jax_env import honor_jax_platforms
-
-    honor_jax_platforms()
-
     import deepspeed_tpu
     from deepspeed_tpu.ops.op_builder import op_report
 

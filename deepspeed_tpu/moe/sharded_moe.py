@@ -329,7 +329,7 @@ def moe_mlp_ep(
     from jax import lax as _lax
     from jax.sharding import PartitionSpec as _P
 
-    from ..utils.compat import shard_map
+    from jax import shard_map
 
     world = int(mesh.shape.get("ep", 1))
     B, S, M = x.shape

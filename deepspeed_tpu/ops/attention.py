@@ -4,7 +4,10 @@ The capability analog of the reference's fused transformer kernels
 (``csrc/transformer/ds_transformer_cuda.cpp`` softmax/attention pieces): the
 FLOPs-heavy attention inner loop runs as a hand-written TPU kernel
 (``deepspeed_tpu/ops/pallas/flash_attention.py``) when shapes allow, with a
-pure-XLA fallback that still fuses well (MXU einsums + f32 softmax).
+pure-XLA path elsewhere that still fuses well (MXU einsums + f32 softmax).
+Under ``impl="auto"`` the shape gate (``flash_ok``, ``*_attention_ok``) alone
+decides; a kernel that fails to lower or compile raises — nothing here
+catches it and substitutes the jnp path.
 
 Layout convention here is [B, S, H, D] (batch, seq, heads, head_dim).
 """
@@ -16,8 +19,35 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
-from ..utils.logging import warning_once
+
+def _on_each_device(kernel, q, k, v, *replicated):
+    """``kernel(q, k, v, *replicated)`` on [B,S,H,D] operands, run per device.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so inside a jit over a multi-device mesh the
+    kernel must sit in a ``shard_map``: batch over ``dp`` and heads over
+    ``tp`` where they divide, every other axis replicated. The mesh is the
+    ambient one (``jax.set_mesh`` — ``DeepSpeedEngine`` sets it around its
+    jitted steps). With no ambient mesh, a single device, or when the caller
+    is already inside a manual region (ring attention, the serving TP
+    programs) the kernel is called directly."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(q, k, v, *replicated)
+    size = dict(zip(mesh.axis_names, mesh.axis_sizes))
+    dp = "dp" if size.get("dp", 1) > 1 and q.shape[0] % size["dp"] == 0 else None
+    tp = (
+        "tp" if size.get("tp", 1) > 1
+        and all(x.shape[2] % size["tp"] == 0 for x in (q, k, v)) else None
+    )
+    spec = PartitionSpec(dp, None, tp, None)
+    return jax.shard_map(
+        kernel,
+        in_specs=(spec, spec, spec) + (PartitionSpec(),) * len(replicated),
+        out_specs=spec, check_vma=False,
+    )(q, k, v, *replicated)
 
 
 def causal_attention_jnp(q, k, v, sm_scale: Optional[float] = None):
@@ -56,28 +86,19 @@ def cached_attention(q, k_cache, v_cache, pos, impl: str = "auto", sm_scale: Opt
 
     Dispatch mirrors :func:`causal_attention`: the Pallas online-softmax
     decode kernel on TPU (reference softmax_context fused inference kernel),
-    jnp fallback elsewhere, with the same warn-and-fall-back contract. The
-    jnp GQA fallback is a grouped einsum — the cache is never repeated on
-    either path.
+    jnp path elsewhere, chosen by the shape gate alone. The jnp GQA path is a
+    grouped einsum — the cache is never repeated on either path.
     """
     from .pallas.flash_attention import validate_kv_heads
 
     B, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
-    # validate the head ratio HERE: raised inside the kernel, the auto
-    # dispatch would swallow it as a "pallas unavailable" warning and the
-    # fallback would then fail with an unrelated reshape error
     validate_kv_heads(H, k_cache, v_cache)
     if impl in ("auto", "pallas"):
         from .pallas.decode_attention import decode_attention, decode_attention_ok
 
         if impl == "pallas" or decode_attention_ok(S, D, k_cache.dtype.itemsize):
-            try:
-                return decode_attention(q, k_cache, v_cache, pos, sm_scale=sm_scale)
-            except Exception as e:  # pragma: no cover
-                if impl == "pallas":
-                    raise
-                warning_once(f"pallas decode attention unavailable ({e}); using jnp path")
+            return decode_attention(q, k_cache, v_cache, pos, sm_scale=sm_scale)
     elif impl != "jnp":
         raise ValueError(f"unknown attention impl {impl}")
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
@@ -143,15 +164,10 @@ def paged_cached_attention(
         )
 
         if impl == "pallas" or paged_decode_attention_ok(page, D, k_pool.dtype.itemsize):
-            try:
-                return paged_decode_attention(
-                    q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-                    scales=scales,
-                )
-            except Exception as e:  # pragma: no cover
-                if impl == "pallas":
-                    raise
-                warning_once(f"pallas paged attention unavailable ({e}); using jnp path")
+            return paged_decode_attention(
+                q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
+                scales=scales,
+            )
     elif impl != "jnp":
         raise ValueError(f"unknown attention impl {impl}")
     # gather [B,n,KV,page,D] → logical [B,T,KV,D] per slot (pure data
@@ -207,18 +223,10 @@ def paged_multitoken_cached_attention(
         if impl == "pallas" or paged_multitoken_attention_ok(
             page, D, T, k_pool.dtype.itemsize
         ):
-            try:
-                return paged_multitoken_attention(
-                    q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-                    scales=scales,
-                )
-            except Exception as e:  # pragma: no cover
-                if impl == "pallas":
-                    raise
-                warning_once(
-                    f"pallas multitoken paged attention unavailable ({e}); "
-                    "using jnp path"
-                )
+            return paged_multitoken_attention(
+                q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
+                scales=scales,
+            )
     elif impl != "jnp":
         raise ValueError(f"unknown attention impl {impl}")
     kd, vd = gather_pool_pages(k_pool, v_pool, block_tables, scales)
@@ -289,16 +297,17 @@ def causal_attention(q, k, v, impl: str = "auto", sm_scale: Optional[float] = No
     if impl in ("auto", "pallas"):
         ok = windowed_attention_ok(q) if window is not None else _pallas_ok(q)
         if impl == "pallas" or ok:
-            try:
-                from .pallas.flash_attention import flash_attention
+            from .pallas.flash_attention import flash_attention
 
-                return flash_attention(
-                    q, k, v, causal=True, sm_scale=sm_scale, window=window
-                )
-            except Exception as e:  # pragma: no cover
-                if impl == "pallas":
-                    raise
-                warning_once(f"pallas flash attention unavailable ({e}); using jnp path")
+            # a (possibly traced) window rides along as a replicated operand
+            win = () if window is None else (jnp.asarray(window, jnp.int32),)
+            return _on_each_device(
+                lambda q, k, v, *w: flash_attention(
+                    q, k, v, causal=True, sm_scale=sm_scale,
+                    window=w[0] if w else None,
+                ),
+                q, k, v, *win,
+            )
         if window is not None:
             return causal_attention_windowed_jnp(q, k, v, window, sm_scale)
         return causal_attention_jnp(q, k, v, sm_scale)
@@ -322,7 +331,7 @@ def bidirectional_attention_jnp(q, k, v, mask=None, sm_scale: Optional[float] = 
 def bidirectional_attention(
     q, k, v, mask=None, impl: str = "auto", sm_scale: Optional[float] = None
 ):
-    """Non-causal dispatcher with the same warn-and-fall-back contract as
+    """Non-causal dispatcher with the same gate-decides contract as
     :func:`causal_attention`. The Pallas flash kernel serves the unmasked
     case; a padding mask routes to the jnp path (the kernel has no mask
     input — masked encoder batches are typically short enough that the
@@ -331,13 +340,11 @@ def bidirectional_attention(
         return bidirectional_attention_jnp(q, k, v, mask, sm_scale)
     if impl in ("auto", "pallas"):
         if impl == "pallas" or _pallas_ok(q):
-            try:
-                from .pallas.flash_attention import flash_attention
+            from .pallas.flash_attention import flash_attention
 
-                return flash_attention(q, k, v, causal=False, sm_scale=sm_scale)
-            except Exception as e:  # pragma: no cover
-                if impl == "pallas":
-                    raise
-                warning_once(f"pallas flash attention unavailable ({e}); using jnp path")
+            return _on_each_device(
+                functools.partial(flash_attention, causal=False, sm_scale=sm_scale),
+                q, k, v,
+            )
         return bidirectional_attention_jnp(q, k, v, None, sm_scale)
     raise ValueError(f"unknown attention impl {impl}")

@@ -568,9 +568,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, sm_scale: float, causal: bool, interpret: boo
 # itself gets large past ~256k tokens per device.
 GRID_KERNEL_MAX_SEQ = 128 * 2048
 
-# jax version compat: the params class was renamed TPUCompilerParams ->
-# CompilerParams; older jaxlib pins only carry the old name
-_GRID_PARAMS = getattr(pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None))(
+_GRID_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
 
@@ -898,16 +896,19 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # would need cross-grid-step output accumulation over the group).
 # ---------------------------------------------------------------------------
 
-# OPT-IN until hardware-proven (DS_FLASH_BSE=1): the D-lane blocks sit at
-# h*D lane offsets inside E, and for D=64 those are sub-128-lane origins —
-# a Mosaic tiling surface interpret mode cannot validate. The hardware CI
-# (TestBSEFlashHardware) compiles it on a chip; flip the default only with
-# that evidence.
+# OPT-IN (DS_FLASH_BSE=1), and D % 128 == 0 only: the D-lane blocks sit at
+# h*D lane offsets inside E, and a D=64 block is a sub-128-lane block that
+# the Pallas TPU lowering rejects (chip run, PR 21: "last two dimensions of
+# your block shape are divisible by 8 and 128"). D=128 compiled and matched
+# the 3D path on a v5e (TestBSEFlashHardware).
 _BSE_ENABLED = os.environ.get("DS_FLASH_BSE", "0") == "1"
 
 
 def _bse_ok(S: int, D: int, itemsize: int = 2) -> bool:
-    return _BSE_ENABLED and resident_ok(S, D, itemsize) and _fused_bwd_ok(S, D)
+    return (
+        _BSE_ENABLED and D % NUM_LANES == 0
+        and resident_ok(S, D, itemsize) and _fused_bwd_ok(S, D)
+    )
 
 
 def _fwd_bse(q2, k2, v2, H: int, sm_scale, causal, interpret, window):

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import pcast as _pcast, shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -110,7 +110,7 @@ def pipeline_apply(
             shifted = lax.ppermute(out, "pp", [(i, (i + 1) % Pn) for i in range(Pn)])
             return shifted, out
 
-        carry0 = _pcast(jnp.zeros_like(x_micro[0]), ("pp",), to="varying")
+        carry0 = lax.pcast(jnp.zeros_like(x_micro[0]), ("pp",), to="varying")
         _, outs = lax.scan(tick, carry0, jnp.arange(T))  # [T, mb, ...]
         # last stage's outputs for ticks P-1..T-1 are microbatches 0..M-1
         results = lax.dynamic_slice_in_dim(outs, Pn - 1, M, axis=0)
@@ -243,7 +243,7 @@ def pipeline_train_1f1b(
             def skip_head(_):
                 # pcast: branch outputs must match do_head's varying-over-pp
                 # type (its results depend on the stage-local ``out``)
-                vary = lambda x: _pcast(x, ("pp",), to="varying")
+                vary = lambda x: lax.pcast(x, ("pp",), to="varying")
                 return (
                     vary(jnp.float32(0.0)),
                     jax.tree.map(lambda x: vary(jnp.zeros_like(x)), head_p),
@@ -280,7 +280,7 @@ def pipeline_train_1f1b(
             return (ring, next_act, next_dh, gL, gH, loss_sum, dx_buf), None
 
         mb_shape = xm.shape[1:]
-        varying = lambda x: _pcast(x, ("pp",), to="varying")
+        varying = lambda x: lax.pcast(x, ("pp",), to="varying")
         carry0 = (
             varying(jnp.zeros((R,) + mb_shape, xm.dtype)),  # ring
             varying(jnp.zeros(mb_shape, xm.dtype)),  # recv_act

@@ -33,7 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG_INF = -1e30

@@ -786,7 +786,7 @@ class DeepSpeedEngine:
         return fn(state, batch, rng)
 
     def _make_onebit_train_step(self, **opt_flags):
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         model = self.module
         opt = self.optimizer
@@ -1382,7 +1382,7 @@ class DeepSpeedEngine:
         stage B pays ~1 B/elem. Skipping stage B only wins if the optimizer
         update itself is reorganized to run on flat bucket shards; until
         then the reduce-scatter primitive stays a tested building block."""
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         from ..comm import compressed as cco
 
@@ -1638,6 +1638,14 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # public training surface
     # ------------------------------------------------------------------
+    def _mesh_scope(self):
+        """The engine's mesh as jax's ambient mesh while a step traces or
+        runs. ``ops.attention`` reads it to put its Pallas kernels in a
+        ``shard_map``: GSPMD cannot partition a Mosaic custom call, so on
+        more than one chip a bare kernel inside the jitted step fails to
+        lower ("Mosaic kernels cannot be automatically partitioned")."""
+        return jax.set_mesh(self.mesh)
+
     def train_batch(self, batch: Optional[PyTree] = None, data_iter: Optional[Iterator] = None) -> Dict[str, Any]:
         """Run one full training step (GAS micro-batches + optimizer update).
 
@@ -1695,7 +1703,8 @@ class DeepSpeedEngine:
                 (self.state, device_batch, step_rng),
             )
             self._step_structs_key = self._jit_step_programs()
-        self.state, metrics = self._train_step(self.state, device_batch, step_rng)
+        with self._mesh_scope():
+            self.state, metrics = self._train_step(self.state, device_batch, step_rng)
         self.global_steps += 1
         # monotonic train_batch ordinal: the fault-injection index. NOT
         # global_steps — a rollback rewinds that, which would re-fire the
@@ -1937,7 +1946,7 @@ class DeepSpeedEngine:
         compressed layer's trace-time records."""
         from ..comm.compressed import suspend_records
 
-        with suspend_records():
+        with suspend_records(), self._mesh_scope():
             return self._train_step.lower(*self._step_arg_structs).compile()
 
     def _compiled_step(self):
@@ -2343,12 +2352,14 @@ class DeepSpeedEngine:
         if self.param_offload_enabled:
             # dslint: disable=jnp-in-hot-loop — API returns a device scalar
             return jnp.float32(self._infinity.eval_loss(device_batch, rng))
-        return self._eval_step(self.state.params, device_batch, rng)
+        with self._mesh_scope():
+            return self._eval_step(self.state.params, device_batch, rng)
 
     def predict(self, batch: PyTree):
         assert self._jit_apply is not None, "module has no apply_fn"
         cparams = _cast_params(self.state.params, self.compute_dtype)
-        return self._jit_apply(cparams, batch)
+        with self._mesh_scope():
+            return self._jit_apply(cparams, batch)
 
     # ------------------------------------------------------------------
     # properties (reference engine.py:466-788 property surface)

@@ -432,7 +432,7 @@ def _compressed_gather_program(mesh, zero_axis, world, method, block,
     import jax.numpy as jnp
 
     from ...comm import compressed as cco
-    from ...utils.compat import shard_map
+    from jax import shard_map
 
     in_spec = PartitionSpec(*spec)
     out_entries = list(spec) + [None] * (len(shape) - len(spec))

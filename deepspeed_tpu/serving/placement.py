@@ -54,7 +54,7 @@ from ..analysis.sharding_rules import (
     verify_spec_table,
 )
 from ..module_inject.tp_shard import tp_shard_serving_params
-from ..utils.compat import shard_map
+from jax import shard_map
 from .kv_cache import PageAllocator, init_pools
 
 PyTree = Any

@@ -46,9 +46,9 @@ from typing import Any, Dict, List, Optional
 # ---------------------------------------------------------------------------
 
 # bf16 matmul peak flop/s, HBM bytes/s, and per-link ICI bytes/s by device
-# kind (published TPU specs; bench.py's PEAK_TFLOPS agrees on the flops
-# column). Keys match ``jax.Device.device_kind`` substrings, checked longest
-# first so "TPU v5p" wins over "TPU v5".
+# kind (published TPU specs). THE peak table: benches and the comm logger read
+# it through :func:`chip_peak`. Keys match ``jax.Device.device_kind``
+# substrings, checked longest first so "TPU v5p" wins over "TPU v5".
 PEAK_TABLE: Dict[str, Dict[str, float]] = {
     "TPU v4": dict(peak_flops=275e12, hbm_bytes_per_s=1.23e12, ici_bytes_per_s=4.8e10),
     "TPU v5 lite": dict(peak_flops=197e12, hbm_bytes_per_s=8.19e11, ici_bytes_per_s=4.0e10),
@@ -58,9 +58,9 @@ PEAK_TABLE: Dict[str, Dict[str, float]] = {
     "TPU v6 lite": dict(peak_flops=918e12, hbm_bytes_per_s=1.64e12, ici_bytes_per_s=4.0e10),
 }
 
-# nominal CPU host fallback (one modern server core group): keeps MFU /
-# roofline DEFINED on the CPU test mesh, clearly labeled estimated. The
-# absolute numbers matter less than the ratios being finite and stable.
+# nominal entry for the CPU test mesh only (``device_kind == "cpu"``): keeps
+# MFU / roofline DEFINED there, clearly labeled estimated. An accelerator
+# that is not in the table is an error, never this entry.
 CPU_FALLBACK = dict(peak_flops=2.0e11, hbm_bytes_per_s=5.0e10, ici_bytes_per_s=2.0e10)
 
 
@@ -88,23 +88,29 @@ def chip_peak(device_kind: Optional[str] = None,
               peak_flops_override: float = 0.0) -> PeakSpec:
     """Look up the peak entry for ``device_kind`` (default: first jax device).
 
-    Unknown kinds get the CPU fallback entry, flagged ``source="fallback"``
-    so dashboards can render the MFU as an estimate.
+    The CPU host gets the nominal ``CPU_FALLBACK`` entry, flagged
+    ``source="fallback"`` so dashboards can render the MFU as an estimate; any
+    other kind that is not in the table raises — a utilization computed
+    against another chip's peak is worse than none.
     ``peak_flops_override`` (e.g. ``telemetry.introspection.peak_tflops``)
     replaces the flops column only.
     """
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = "unknown"
-    entry, source = CPU_FALLBACK, "fallback"
+        device_kind = jax.devices()[0].device_kind
+    kind = str(device_kind).lower()
     for key in sorted(PEAK_TABLE, key=len, reverse=True):
-        if key.lower() in str(device_kind).lower():
+        if key.lower() in kind:
             entry, source = PEAK_TABLE[key], "table"
             break
+    else:
+        if kind != "cpu":
+            raise ValueError(
+                f"chip_peak: device_kind {device_kind!r} is not in PEAK_TABLE "
+                f"({sorted(PEAK_TABLE)}); add its published peaks there"
+            )
+        entry, source = CPU_FALLBACK, "fallback"
     flops = float(peak_flops_override) or entry["peak_flops"]
     if peak_flops_override:
         source = "override"

@@ -60,15 +60,6 @@ def get_fp32_state_dict_from_zero_checkpoint(ckpt_dir: str, tag: Optional[str] =
 
 
 def main():
-    try:
-        from .jax_env import honor_jax_platforms
-    except ImportError:  # invoked as a bare script, not via -m / console script
-        import sys
-
-        sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
-        from deepspeed_tpu.utils.jax_env import honor_jax_platforms
-
-    honor_jax_platforms()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("ckpt_dir")
     ap.add_argument("output_file")

@@ -1,10 +1,10 @@
-"""Hardware-mode kernel CI (VERDICT r2 item 8): compile — not interpret —
-the Mosaic kernels on a real TPU chip and check parity against the jnp
-reference paths.
+"""Hardware-mode kernel CI: compile — not interpret — the Mosaic kernels on
+a real TPU chip and check parity against the jnp reference paths.
 
 Run with:  DS_TPU_TESTS=1 python -m pytest tests/ -m tpu -q
 (conftest skips its CPU forcing under DS_TPU_TESTS=1; everything here skips
-unless the active backend is a TPU).
+unless the active backend is a TPU). From the sandbox, through the chip tool:
+    chiprun -- env DS_TPU_TESTS=1 python -m pytest -m tpu -q
 """
 
 import jax
@@ -125,14 +125,26 @@ class TestFlashAttentionHardware:
 
 
 class TestBSEFlashHardware:
-    """S-major flash entry (lane-offset head blocks over [B,S,E]) — opt-in
-    until this very test proves the Mosaic surface: D=64 blocks sit at
-    64-lane origins inside E, which interpret mode cannot validate."""
+    """S-major flash entry (lane-offset head blocks over [B,S,E]), opt-in.
+    D=128 blocks sit at 128-lane origins and compile; a D=64 block is a
+    sub-128-lane block that the Pallas TPU lowering rejects, so the gate
+    refuses it (chip run, PR 21)."""
 
-    @pytest.mark.parametrize("D,H", [(64, 4), (128, 2)])
-    def test_bse_fwd_bwd_matches_3d_on_chip(self, D, H):
+    def test_gate_refuses_sub_lane_head_dim(self):
         from deepspeed_tpu.ops.pallas import flash_attention as fa
 
+        prev = fa._BSE_ENABLED
+        fa._BSE_ENABLED = True
+        try:
+            assert not fa._bse_ok(512, 64)
+            assert fa._bse_ok(512, 128)
+        finally:
+            fa._BSE_ENABLED = prev
+
+    def test_bse_fwd_bwd_matches_3d_on_chip(self):
+        from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+        D, H = 128, 2
         q, k, v = _qkv(1, 512, H, D, seed=11)
 
         def grads():
@@ -267,12 +279,81 @@ class TestDecodeAttentionHardware:
         )
 
 
+class TestPagedAttentionHardware:
+    """The serving kernels at GPT-2-XL head shapes (25 heads of 64, page 16):
+    compiled by Mosaic through the dispatcher, matched against its jnp
+    gather path. The CPU suite only ever interprets them."""
+
+    H, D = 25, 64
+
+    def _pool(self, B, n, page, seed, int8=False):
+        from deepspeed_tpu.ops.quantizer import quantize_kv_pages
+
+        rs = np.random.RandomState(seed)
+        P = B * n + 1  # page 0 is the scratch page; tables never name it
+        kf = jnp.asarray(rs.randn(P, self.H, page, self.D), jnp.float32)
+        vf = jnp.asarray(rs.randn(P, self.H, page, self.D), jnp.float32)
+        bt = jnp.asarray(rs.permutation(np.arange(1, P)).reshape(B, n), jnp.int32)
+        if not int8:
+            return kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16), bt, None
+        kq, ks = quantize_kv_pages(kf)
+        vq, vs = quantize_kv_pages(vf)
+        return kq, vq, bt, jnp.stack([ks, vs], axis=-1)
+
+    @pytest.mark.parametrize("int8,page", [(False, 16), (True, 32)])
+    def test_paged_decode_compiles_and_matches(self, int8, page):
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+
+        B, n = 4, 8
+        kp, vp, bt, scales = self._pool(B, n, page, seed=30, int8=int8)
+        rs = np.random.RandomState(31)
+        q = jnp.asarray(rs.randn(B, self.H, self.D), jnp.bfloat16)
+        pos = jnp.asarray([0, page - 1, 3 * page + 5, n * page - 1], jnp.int32)
+
+        def run(impl):
+            return jax.jit(
+                lambda q, kp, vp, bt, pos, sc: paged_cached_attention(
+                    q, kp, vp, bt, pos, impl=impl, scales=sc
+                )
+            )(q, kp, vp, bt, pos, scales)
+
+        np.testing.assert_allclose(
+            np.asarray(run("pallas"), np.float32),
+            np.asarray(run("jnp"), np.float32), atol=2e-2, rtol=2e-2,
+        )
+
+    @pytest.mark.parametrize(
+        "int8,page,T", [(False, 16, 5), (False, 16, 128), (True, 32, 5)]
+    )
+    def test_paged_multitoken_compiles_and_matches(self, int8, page, T):
+        from deepspeed_tpu.ops.attention import paged_multitoken_cached_attention
+
+        B = 2
+        n = (T + 3 * page) // page + 1
+        kp, vp, bt, scales = self._pool(B, n, page, seed=32, int8=int8)
+        rs = np.random.RandomState(33)
+        q = jnp.asarray(rs.randn(B, T, self.H, self.D), jnp.bfloat16)
+        base = jnp.asarray([0, 2 * page + 3], jnp.int32)
+
+        def run(impl):
+            return jax.jit(
+                lambda q, kp, vp, bt, base, sc: paged_multitoken_cached_attention(
+                    q, kp, vp, bt, base, impl=impl, scales=sc
+                )
+            )(q, kp, vp, bt, base, scales)
+
+        np.testing.assert_allclose(
+            np.asarray(run("pallas"), np.float32),
+            np.asarray(run("jnp"), np.float32), atol=2e-2, rtol=2e-2,
+        )
+
+
 class TestRingFlashHardware:
     def test_ring_flash_compiles_on_chip(self):
         """Single-chip sp=1 ring: one diagonal step — compiles the flash
         fwd/bwd kernels inside the ring scan + switch on hardware (the
         multi-device ring path itself is covered by the CPU-mesh tests)."""
-        from deepspeed_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         from deepspeed_tpu.ops.pallas.ring_flash_attention import ring_flash_attention

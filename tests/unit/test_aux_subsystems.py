@@ -184,16 +184,10 @@ class TestDeviceMonitor:
         assert not mon.probe_once() and not mon.healthy  # second: tripped
         assert mon.probe_once() and mon.healthy  # recovery clears it
 
-    def test_default_probe_is_subprocess(self):
-        from deepspeed_tpu.elasticity.elastic_agent import _default_probe
-
-        # killable even if the plugin would hang: an unreasonable timeout
-        # simply fails the probe instead of wedging the caller
-        assert _default_probe(0.01) is False
-
     def test_progress_probe(self):
-        """The no-subprocess probe for exclusive-libtpu deployments: healthy
-        while the step counter advances, stalls after stall_s without it."""
+        """The monitor's probe — no second process ever opens the device:
+        healthy while the step counter advances, stalls after stall_s
+        without it."""
         import time as _time
 
         from deepspeed_tpu.elasticity import make_progress_probe

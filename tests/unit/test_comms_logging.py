@@ -5,7 +5,7 @@ op mix of a compiled ZeRO step, measured-latency summary table."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu.comm.comm as dscomm
@@ -114,7 +114,7 @@ def test_onebit_wire_volume_reduction(mesh_dp8):
     compressed-allreduce program moves far fewer collective bytes than a
     dense pmean of the same gradient, measured from the post-optimization
     HLO (runtime/comm/compressed.py docstring claim)."""
-    from deepspeed_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.runtime.comm.compressed import compressed_allreduce

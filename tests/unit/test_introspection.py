@@ -44,6 +44,9 @@ def test_chip_peak_lookup_and_fallback():
     assert v5e.peak_flops == 197e12
     cpu = introspect.chip_peak("cpu")
     assert cpu.source == "fallback" and cpu.peak_flops > 0
+    # an accelerator missing from the table is an error, not the CPU entry
+    with pytest.raises(ValueError, match="not in PEAK_TABLE"):
+        introspect.chip_peak("TPU v9 mega")
     over = introspect.chip_peak("TPU v5p", peak_flops_override=123e12)
     assert over.peak_flops == 123e12 and over.source == "override"
 

@@ -242,7 +242,7 @@ class TestSparseTensor:
         assert sp.indices.shape[0] == 3  # unique ids {3, 9, 12}
 
     def test_sparse_allgather_apply(self, mesh_dp8):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from deepspeed_tpu.runtime.sparse_tensor import sparse_allgather_apply
@@ -261,7 +261,7 @@ class TestSparseTensor:
             mesh=mesh_dp8,
             in_specs=(P("dp"), P("dp")),
             out_specs=P(),  # dense result replicated
-            check_rep=False,
+            check_vma=False,
         )(ids, vals)
         expect = np.zeros((vocab, dim), np.float32)
         for r in range(8):
