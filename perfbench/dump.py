@@ -1,0 +1,52 @@
+"""Writes what a run saw, for the builder who has to find where a spread comes
+from: per-request stamps, per-step records, and the names in the trace. Used
+by ``run.py --dump`` and ``tools/sweep.py``; the driver never asks for it."""
+
+from __future__ import annotations
+
+import json
+import os
+
+
+def summary(ctx) -> dict:
+    from perfbench import arith
+
+    t0, t1 = ctx.window
+    gaps = arith.pooled_gaps(ctx.recs, t0, t1)
+    hist = {}
+    for g in gaps:
+        k = round(g, 2)
+        hist[k] = hist.get(k, 0) + 1
+    return {
+        "requests": len(ctx.recs), "counted": sum(1 for r in ctx.recs if r.counted),
+        "steps": len(ctx.steps), "gaps": len(gaps),
+        "gap_hist_10ms": {f"{k:.2f}": v for k, v in sorted(hist.items())},
+    }
+
+
+def write(out_dir: str, out: dict, ctx) -> None:
+    os.makedirs(out_dir, exist_ok=True)
+    tag = f"{out['cell']}.s{out['seed']}.t{int('breakdown' in out)}"
+    t0 = ctx.window[0]
+    doc = {
+        "result": out, "summary": summary(ctx),
+        "recs": [
+            {"due": r.due - t0, "plen": r.prompt_len, "new": r.new_tokens, "submit": r.t_submit - t0,
+             "admit": None if r.t_admit is None else r.t_admit - t0,
+             "first": None if r.t_first_token is None else r.t_first_token - t0,
+             "last": (r.t_emissions[-1] - t0) if r.t_emissions else None,
+             "n": r.n_tokens, "status": r.status, "counted": r.counted}
+            for r in ctx.recs
+        ],
+        "steps": [[s.kind, s.t0 - t0, s.t1 - s.t0, s.info] for s in ctx.steps],
+    }
+    if ctx.trace is not None:
+        tr = ctx.trace
+        doc["trace"] = {
+            "window_s": tr.window_s, "busy_s": tr.busy_s, "n_devices": tr.n_devices, "lines": tr.line_names,
+            "ops": sorted(tr.op_seconds.items(), key=lambda kv: -kv[1])[:80],
+            "modules": {k: [len(v), sum(v) / len(v)] for k, v in tr.module_durations.items()},
+            "idle_by_span": tr.idle_by_span,
+        }
+    with open(os.path.join(out_dir, tag + ".json"), "w") as f:
+        json.dump(doc, f)

@@ -1,0 +1,158 @@
+"""The harness end to end at ``gpt2-tiny`` on the CPU mesh: each kind of cell
+runs once with the profiler off and once with it on (module fixtures), and the
+tests read the result. Nothing here is a device number."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from perfbench import run
+
+from . import tiny
+
+SEED = 2**31 + 123   # the driver's seeds are large
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+@pytest.fixture(scope="module")
+def manifest(tmp_path_factory):
+    m = tiny.make(tmp_path_factory.mktemp("bench"))
+    m.validate()
+    return m
+
+
+@pytest.fixture(scope="module")
+def results(manifest, tmp_path_factory):
+    out = {}
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    for cell in ("train-xl-l16-1chip", "serve-xl-chat-open", "serve-xl-doc-batch"):
+        for trace in (False, True):
+            out[cell, trace] = run.run_cell(manifest, cell, SEED, 1.0, trace, require_tpu=False, trace_dir=trace_dir)
+    return out
+
+
+CELLS = ["train-xl-l16-1chip", "serve-xl-chat-open", "serve-xl-doc-batch"]
+
+
+@pytest.mark.parametrize("trace", [False, True])
+@pytest.mark.parametrize("cell", CELLS)
+def test_last_line_has_the_contracts_keys(results, cell, trace):
+    out, _ = results[cell, trace]
+    line = json.loads(json.dumps(out))   # what is printed
+    assert LINE_KEYS <= set(line)
+    assert DEVICE_KEYS <= set(line["device"])
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
+    for name, m in line["metrics"].items():
+        assert set(m) == {"value", "unit"} and isinstance(m["value"], float), name
+    assert line["notes"]["compilations_in_window"] == 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_untraced_run_reports_the_cells_end_to_end_metrics(manifest, results, cell):
+    out, _ = results[cell, False]
+    want = {m["name"] for m in manifest.metrics_for(cell, "end_to_end")}
+    assert set(out["metrics"]) == want and "setup_s" in want
+    assert all(v["value"] > 0 for v in out["metrics"].values())   # metrics that are never 0
+    assert "breakdown" not in out
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_run_reports_per_layer_metrics_that_need_no_device(manifest, results, cell):
+    out, ctx = results[cell, True]
+    listed = {m["name"]: m for m in manifest.metrics_for(cell, "per_layer")}
+    assert set(out["metrics"]) <= set(listed)
+    host = {n for n, m in listed.items() if m["source"] != "device_trace"}
+    assert host <= set(out["metrics"])
+    # the CPU has no device plane: every device_trace reader found nothing and
+    # its metric is left out, never reported as 0
+    assert not (set(out["metrics"]) - host)
+    assert ctx.traced is not None and ctx.trace is None
+
+
+def test_chat_window_counts_what_was_due_in_it(manifest, results):
+    out, ctx = results["serve-xl-chat-open", False]
+    tr = manifest.traffic("tiny-open")
+    assert out["attempted"] == round(tr["rate_rps"] * 1.0) == out["notes"]["offered_in_window"]
+    t0, t1 = ctx.window
+    counted = [r for r in ctx.recs if r.counted]
+    assert all(t0 <= r.due < t1 for r in counted)
+    assert any(r.due < t0 for r in ctx.recs), "the ramp offered load before the window"
+    assert all(r.n_tokens == r.new_tokens and r.status == "finished" for r in counted)
+    assert all(r.t_submit >= r.due for r in ctx.recs)
+
+
+def test_backlog_keeps_the_slots_busy_and_counts_tokens_inside_only(results):
+    out, ctx = results["serve-xl-doc-batch", True]
+    assert out["metrics"]["decode_occupancy.doc"]["value"] > 50
+    out0, ctx0 = results["serve-xl-doc-batch", False]
+    from perfbench import arith
+
+    t0, t1 = ctx0.window
+    inside = arith.tokens_in_window(ctx0.recs, t0, t1)
+    everything = arith.tokens_in_window(ctx0.recs, t0 - 100, t1 + 100)
+    assert 0 < inside < everything
+    assert out0["metrics"]["serve_tok_s"]["value"] == pytest.approx(inside / (t1 - t0))
+
+
+def test_training_counts_whole_steps_and_checks_the_reference(results):
+    out, ctx = results["train-xl-l16-1chip", False]
+    assert out["attempted"] == len([t for t in ctx.step_ends if ctx.window[0] <= t <= ctx.window[1]]) - 1
+    ref = out["notes"]["reference"]
+    assert ref["abs_diff"] <= ref["tol"] and ref["reference_loss"] > 5.0
+
+
+def test_the_chips_loss_tolerance_would_catch_a_dropped_layer(results):
+    """The tolerance the training configurations carry (1e-3) against what a
+    missing layer does to the reference's loss at this size."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.models import gpt2
+    from perfbench import reference
+
+    _, ctx = results["train-xl-l16-1chip", False]
+    cfg = ctx.config
+    keys = ("vocab_size", "n_positions", "n_embd", "n_layer", "n_head")
+    params = gpt2.init_params(gpt2.GPT2Config(**{k: cfg[k] for k in keys}), jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.default_rng(0).integers(0, cfg["vocab_size"], (2, cfg["seq"])).astype(np.int32))
+    kw = dict(n_head=cfg["n_head"], eps=cfg["layer_norm_epsilon"], vocab=cfg["vocab_size"])
+    full, cut = reference.lm_loss(params, ids, **kw), reference.lm_loss(params, ids, skip_layer=0, **kw)
+    assert abs(float(full) - float(cut)) > 1e-3
+
+
+def test_refuses_to_measure_on_a_cpu(manifest):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, os.path.join(tiny.REPO, "perfbench", "run.py"), "--workload", "train-xl-l16-1chip",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tiny.REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert p.returncode == 2 and p.stdout.strip() == "" and "only on a TPU" in p.stderr
+
+
+def test_unknown_cell_fails_before_jax_is_touched():
+    p = subprocess.run(
+        [sys.executable, os.path.join(tiny.REPO, "perfbench", "run.py"), "--workload", "nope", "--seed", "1",
+         "--seconds", "1", "--trace", "0"],
+        cwd=tiny.REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert p.returncode != 0 and p.stdout.strip() == "" and "no workload" in p.stderr
+
+
+def test_fails_without_the_program_beside_it(manifest):
+    """In a directory that holds only BENCHMARK.json and the files under
+    ``paths`` there is nothing to measure: non-zero, no result line."""
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    p = subprocess.run(
+        [sys.executable, os.path.join(manifest.root, "perfbench", "run.py"), "--workload", "train-xl-l16-1chip",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=manifest.root, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert p.returncode != 0 and p.stdout.strip() == ""
