@@ -60,14 +60,14 @@ RULES = {
 }
 
 DEFAULT_HOT_PATTERNS = [
-    "ServingEngine.step", "ServingEngine.run", "ServingEngine._admit",
+    "ServingEngine.step", "ServingEngine._step", "ServingEngine.run", "ServingEngine._admit",
     "ServingEngine._finish_slot", "ServingEngine.submit",
     # ISSUE 10: chunked prefill runs once per scheduler step while a slot
     # prefills, and _start_decoding is the per-admission transition _admit
     # used to carry — both stay under the hot-path lint
     "ServingEngine._advance_chunk", "ServingEngine._start_decoding",
     "ServingEngine._draft", "ServingEngine._accept_tokens",
-    "*.train_batch", "*.eval_batch",
+    "*.train_batch", "*._train_batch", "*._print_cadence", "*.eval_batch",
     "*._telemetry_step", "*._watchdog_step",
     "InferenceEngine.generate",
 ]

@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..parallel.topology import MeshSpec
 from ..runtime.module import ModuleSpec
 from ..runtime.zero.partitioning import ZeroShardingPolicy
+from ..telemetry import compile_stats, spans
 from ..utils.logging import log_dist, warning_once
 
 _UNSET = object()  # distinguishes an explicit kwarg from its default
@@ -272,38 +273,40 @@ class InferenceEngine:
 
         self.module = model
 
-        # --- params: shard over tp, convert dtype (reference engine.py:464)
-        init_rng = jax.random.PRNGKey(seed)
-        if params is None:
-            if model.init is None:
-                raise ValueError(
-                    "model has no initializer (ModuleSpec.init=None — the "
-                    "decoder zoo builds params from converted checkpoints); "
-                    "pass them via init_inference(..., params=...) or "
-                    "checkpoint=<dir>"
+        compile_stats.listen()
+        with spans.phase("ds.init.params", what="inference"):
+            # --- params: shard over tp, convert dtype (reference engine.py:464)
+            init_rng = jax.random.PRNGKey(seed)
+            if params is None:
+                if model.init is None:
+                    raise ValueError(
+                        "model has no initializer (ModuleSpec.init=None — the "
+                        "decoder zoo builds params from converted checkpoints); "
+                        "pass them via init_inference(..., params=...) or "
+                        "checkpoint=<dir>"
+                    )
+                abstract = jax.eval_shape(model.init, init_rng)
+                shardings = self.policy.param_shardings(abstract, model.logical_axes)
+                params = jax.jit(model.init, out_shardings=shardings)(init_rng)
+                self.param_shardings = shardings
+            else:
+                abstract = jax.eval_shape(lambda: params)
+                try:
+                    self.param_shardings = self.policy.param_shardings(abstract, model.logical_axes)
+                    params = jax.tree.map(jax.device_put, params, self.param_shardings)
+                except Exception:
+                    # quantized trees / trees whose structure diverges from
+                    # logical_axes fall back to replicated placement
+                    rep = NamedSharding(mesh, PartitionSpec())
+                    self.param_shardings = jax.tree.map(lambda _: rep, params)
+                    params = jax.tree.map(lambda x: jax.device_put(x, rep), params)
+            if not self.quantized:
+                params = jax.tree.map(
+                    lambda p: p.astype(dtype)
+                    if hasattr(p, "dtype") and jnp.issubdtype(p.dtype, jnp.floating)
+                    else p,
+                    params,
                 )
-            abstract = jax.eval_shape(model.init, init_rng)
-            shardings = self.policy.param_shardings(abstract, model.logical_axes)
-            params = jax.jit(model.init, out_shardings=shardings)(init_rng)
-            self.param_shardings = shardings
-        else:
-            abstract = jax.eval_shape(lambda: params)
-            try:
-                self.param_shardings = self.policy.param_shardings(abstract, model.logical_axes)
-                params = jax.tree.map(jax.device_put, params, self.param_shardings)
-            except Exception:
-                # quantized trees / trees whose structure diverges from
-                # logical_axes fall back to replicated placement
-                rep = NamedSharding(mesh, PartitionSpec())
-                self.param_shardings = jax.tree.map(lambda _: rep, params)
-                params = jax.tree.map(lambda x: jax.device_put(x, rep), params)
-        if not self.quantized:
-            params = jax.tree.map(
-                lambda p: p.astype(dtype)
-                if hasattr(p, "dtype") and jnp.issubdtype(p.dtype, jnp.floating)
-                else p,
-                params,
-            )
         self.params = params
         self._forward = jax.jit(model.apply_fn) if model.apply_fn is not None else None
         log_dist(

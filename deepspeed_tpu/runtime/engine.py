@@ -23,6 +23,7 @@ surface: ``train_batch``, ``eval_batch``, ``save_checkpoint``,
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from typing import Any, Callable, Dict, Iterable, Iterator, NamedTuple, Optional, Tuple
@@ -34,6 +35,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..parallel.topology import MeshSpec, mesh_axis_size
+from ..telemetry import compile_stats, spans
 from ..utils.logging import log_dist, logger
 from ..utils.pytree import path_str as _path_str
 from ..utils.timer import (
@@ -249,6 +251,7 @@ class DeepSpeedEngine:
                     "offload (those paths run host-driven multi-program steps)"
                 )
 
+        compile_stats.listen()  # every compilation from here on is a named phase
         # --- ZeRO-Infinity parameter tier (offload_param; stage3.py:465 analog)
         offp = zcfg.offload_param
         self.param_offload_enabled = (
@@ -258,10 +261,12 @@ class DeepSpeedEngine:
             # params never materialize on device: blocks stream host/NVMe ->
             # HBM per layer (runtime/zero/infinity.py). Everything below that
             # builds device param/opt state is bypassed.
-            self._init_param_offload(model, config, zcfg, seed, params)
+            with spans.phase("ds.init.params", what="infinity"):
+                self._init_param_offload(model, config, zcfg, seed, params)
             self._rng = jax.random.PRNGKey(seed + 1)
         else:
-            self._init_device_state(model, config, zcfg, seed, params, opt_cfg)
+            with spans.phase("ds.init.params", what="train_state"):
+                self._init_device_state(model, config, zcfg, seed, params, opt_cfg)
             self._rng = jax.random.PRNGKey(seed + 1)
 
         # --- debug modes (reference safe_mode / assert_ints_same_as_other_ranks)
@@ -1663,109 +1668,139 @@ class DeepSpeedEngine:
                     self._data_iterator = iter(RepeatingLoader(self.training_dataloader))
                 data_iter = self._data_iterator
             batch = next(data_iter)
-        tel = self.telemetry
-        sampled = tel is not None and tel.should_sample(self.global_steps + 1)
         wd = self._watchdog
         if wd is not None and wd.capture_pending:
             # a prior step tripped: this step runs under a bounded profiler
-            # capture (stopped after the sync below)
+            # capture (stopped after the sync below); opened before the spans
+            # so that they land in it
             wd.start_capture(self.global_steps + 1)
-        t_start = time.perf_counter() if (sampled or wd is not None) else 0.0
-        if self.wall_clock_breakdown:
-            self.timers(TRAIN_BATCH_TIMER).start()
-        self.tput_timer.start()
-        batch = self._prepare_batch(batch)
-        device_batch = self.shard_batch(batch)
-        t_prepared = time.perf_counter() if sampled else 0.0
-        # the standard jitted step folds global_step into the key in-graph;
-        # the host-driven paths (offload/onebit/infinity) still need a fresh
-        # key per call
-        if self._train_step_folds_rng:
-            step_rng = self._rng
-        else:
-            # dslint: disable=jnp-in-hot-loop — the host-driven paths
-            # (offload/onebit/infinity) consume a fresh key per call
-            self._rng, step_rng = jax.random.split(self._rng)
-        if self._step_arg_structs is None or (
-            sampled
-            and getattr(self, "_step_structs_key", -1) != self._jit_step_programs()
-        ):
-            # abstract arg specs kept for HLO-level comms accounting
-            # (comms_summary) without holding real buffers alive; recaptured
-            # on the sampled step after a retrace (curriculum seqlen change,
-            # new batch shape) so comm bytes re-derive from the CURRENT
-            # program — and only then, so steady-state sampled steps skip
-            # the tree_map
-            self._step_arg_structs = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(
-                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)
-                ),
-                (self.state, device_batch, step_rng),
-            )
-            self._step_structs_key = self._jit_step_programs()
-        with self._mesh_scope():
-            self.state, metrics = self._train_step(self.state, device_batch, step_rng)
-        self.global_steps += 1
-        # monotonic train_batch ordinal: the fault-injection index. NOT
-        # global_steps — a rollback rewinds that, which would re-fire the
-        # same scheduled fault on every post-rollback step forever.
-        self._train_batch_count = getattr(self, "_train_batch_count", 0) + 1
-        t_dispatched = time.perf_counter() if sampled else 0.0
-        nan_flag = metrics.pop("nan_in_grads", None) if isinstance(metrics, dict) else None
-        # dslint: disable=host-sync-in-step — debug.nan_check opts into a
-        # per-step flag read; the sync IS the feature
-        if nan_flag is not None and bool(jax.device_get(nan_flag)):
-            raise RuntimeError(
-                f"deepspeed_tpu debug: NaN/Inf detected in gradients at step "
-                f"{self.global_steps} (loss="
-                # dslint: disable=host-sync-in-step — raise path, already fatal
-                f"{float(jax.device_get(metrics['loss'])):.4f}). With bf16/fp32 "
-                "there is no loss-scale skip — this is a model/data bug. "
-                "Inspect the batch fed to this step; disable via "
-                "config debug.nan_check. (reference stage3.py:2031 "
-                "_has_inf_or_nan debug scan)"
-            )
-        if self.wall_clock_breakdown:
-            self.timers(TRAIN_BATCH_TIMER).stop(sync_tree=metrics)
-        # block on the step's outputs before stopping the throughput clock:
-        # XLA dispatches asynchronously, so stopping on dispatch-return would
-        # inflate samples/sec by the whole device step time
-        self.tput_timer.stop(sync_tree=metrics)
-        inj = self.fault_injector
-        if (
-            inj is not None
-            and isinstance(metrics, dict)
-            and inj.fire("nan_loss", self._train_batch_count)
-        ):
-            # ISSUE 7 fault injection: poison this step's loss scalar so the
-            # watchdog's non-finite detector (and the rollback/kill policy
-            # behind it) runs for real. Host-side only — the compiled
-            # program is untouched, so trajectories stay comparable.
-            metrics["loss"] = float("nan")
-            metrics["fault_injected"] = "nan_loss"
-            if wd is not None:
-                # route through the in-graph flags path too: off-cadence
-                # steps skip the scalar judgement (check_every > 1), and an
-                # injected fault that the cadence can silently miss tests
-                # nothing
-                metrics["anomaly_flags"] = 1  # FLAG_LOSS_NONFINITE
-        tripped = self._watchdog_step(wd, metrics, t_start) if wd is not None else []
-        if self._rollback is not None:
-            if tripped and wd.policy == "rollback":
-                self._apply_rollback(metrics)
-            elif (
-                not tripped
-                and self.global_steps % self.config.resilience.snapshot_every == 0
-            ):
-                # judged clean: refresh the last-known-good host snapshot
-                # (device→host copy only — tput_timer.stop already blocked
-                # on this step's outputs)
-                self._rollback.snapshot(self.state, self.global_steps)
-        if sampled:
-            self._telemetry_step(tel, metrics, t_start, t_prepared, t_dispatched)
-        if inj is not None and inj.fire("sigterm", self._train_batch_count):
-            inj.deliver_sigterm()
+        with spans.span("ds.train.batch", step=self.global_steps + 1) as sp:
+            return self._train_batch(batch, sp)
 
+    def _train_batch(self, batch: PyTree, sp_batch) -> Dict[str, Any]:
+        """The body of :meth:`train_batch`, tiled by four leaf spans
+        (``ds.train.prepare`` / ``dispatch`` / ``wait`` / ``post``; PERF.md
+        section 3). The sampled step record's ``spans`` are their durations."""
+        tel = self.telemetry
+        wd = self._watchdog
+        with spans.span("ds.train.prepare") as sp_prepare:
+            sampled = tel is not None and tel.should_sample(self.global_steps + 1)
+            if self.wall_clock_breakdown:
+                self.timers(TRAIN_BATCH_TIMER).start()
+            self.tput_timer.start()
+            batch = self._prepare_batch(batch)
+            device_batch = self.shard_batch(batch)
+        with spans.span("ds.train.dispatch") as sp_dispatch:
+            # the standard jitted step folds global_step into the key in-graph;
+            # the host-driven paths (offload/onebit/infinity) still need a fresh
+            # key per call
+            if self._train_step_folds_rng:
+                step_rng = self._rng
+            else:
+                # dslint: disable=jnp-in-hot-loop — the host-driven paths
+                # (offload/onebit/infinity) consume a fresh key per call
+                self._rng, step_rng = jax.random.split(self._rng)
+            first_call = self._step_arg_structs is None
+            if first_call or (
+                sampled
+                and getattr(self, "_step_structs_key", -1) != self._jit_step_programs()
+            ):
+                # abstract arg specs kept for HLO-level comms accounting
+                # (comms_summary) without holding real buffers alive; recaptured
+                # on the sampled step after a retrace (curriculum seqlen change,
+                # new batch shape) so comm bytes re-derive from the CURRENT
+                # program — and only then, so steady-state sampled steps skip
+                # the tree_map
+                self._step_arg_structs = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+                    ),
+                    (self.state, device_batch, step_rng),
+                )
+                self._step_structs_key = self._jit_step_programs()
+            # the first call traces, lowers and compiles (or loads) the step
+            with self._mesh_scope(), (
+                spans.phase("ds.init.programs", what="train_step")
+                if first_call else contextlib.nullcontext()
+            ):
+                self.state, metrics = self._train_step(self.state, device_batch, step_rng)
+            self.global_steps += 1
+            # monotonic train_batch ordinal: the fault-injection index. NOT
+            # global_steps — a rollback rewinds that, which would re-fire the
+            # same scheduled fault on every post-rollback step forever.
+            self._train_batch_count = getattr(self, "_train_batch_count", 0) + 1
+        with spans.span("ds.train.wait") as sp_wait:
+            nan_flag = metrics.pop("nan_in_grads", None) if isinstance(metrics, dict) else None
+            # dslint: disable=host-sync-in-step — debug.nan_check opts into a
+            # per-step flag read; the sync IS the feature
+            if nan_flag is not None and bool(jax.device_get(nan_flag)):
+                raise RuntimeError(
+                    f"deepspeed_tpu debug: NaN/Inf detected in gradients at step "
+                    f"{self.global_steps} (loss="
+                    # dslint: disable=host-sync-in-step — raise path, already fatal
+                    f"{float(jax.device_get(metrics['loss'])):.4f}). With bf16/fp32 "
+                    "there is no loss-scale skip — this is a model/data bug. "
+                    "Inspect the batch fed to this step; disable via "
+                    "config debug.nan_check. (reference stage3.py:2031 "
+                    "_has_inf_or_nan debug scan)"
+                )
+            if self.wall_clock_breakdown:
+                self.timers(TRAIN_BATCH_TIMER).stop(sync_tree=metrics)
+            # block on the step's outputs before stopping the throughput clock:
+            # XLA dispatches asynchronously, so stopping on dispatch-return would
+            # inflate samples/sec by the whole device step time
+            self.tput_timer.stop(sync_tree=metrics)
+            if sampled:
+                # dslint: disable=host-sync-in-step — the documented sampling
+                # sync (telemetry.sample_every amortizes it): the step record's
+                # "sync" span is this leaf and has to cover the device's step
+                # on the early steps too, before tput_timer starts blocking
+                jax.block_until_ready(metrics)
+        with spans.span("ds.train.post"):
+            inj = self.fault_injector
+            if (
+                inj is not None
+                and isinstance(metrics, dict)
+                and inj.fire("nan_loss", self._train_batch_count)
+            ):
+                # ISSUE 7 fault injection: poison this step's loss scalar so the
+                # watchdog's non-finite detector (and the rollback/kill policy
+                # behind it) runs for real. Host-side only — the compiled
+                # program is untouched, so trajectories stay comparable.
+                metrics["loss"] = float("nan")
+                metrics["fault_injected"] = "nan_loss"
+                if wd is not None:
+                    # route through the in-graph flags path too: off-cadence
+                    # steps skip the scalar judgement (check_every > 1), and an
+                    # injected fault that the cadence can silently miss tests
+                    # nothing
+                    metrics["anomaly_flags"] = 1  # FLAG_LOSS_NONFINITE
+            tripped = (
+                self._watchdog_step(wd, metrics, sp_batch.elapsed()) if wd is not None else []
+            )
+            if self._rollback is not None:
+                if tripped and wd.policy == "rollback":
+                    self._apply_rollback(metrics)
+                elif (
+                    not tripped
+                    and self.global_steps % self.config.resilience.snapshot_every == 0
+                ):
+                    # judged clean: refresh the last-known-good host snapshot
+                    # (device→host copy only — tput_timer.stop already blocked
+                    # on this step's outputs)
+                    self._rollback.snapshot(self.state, self.global_steps)
+            if sampled:
+                self._telemetry_step(
+                    tel, metrics, sp_batch,
+                    [("prepare", sp_prepare), ("dispatch", sp_dispatch), ("sync", sp_wait)],
+                )
+            if inj is not None and inj.fire("sigterm", self._train_batch_count):
+                inj.deliver_sigterm()
+            self._print_cadence(metrics, tel)
+        return metrics
+
+    def _print_cadence(self, metrics, tel) -> None:
+        """Every ``steps_per_print`` steps: log the scalars, feed the monitor."""
         if self.global_steps % self.steps_per_print == 0:
             # dslint: disable=host-sync-in-step — the print/monitor cadence
             # reads scalars once per steps_per_print, amortized by config
@@ -1803,32 +1838,29 @@ class DeepSpeedEngine:
                         mb["bytes_limit"] / 2**30,
                     )
                 )
-        return metrics
 
     # ------------------------------------------------------------------
     # telemetry (ISSUE 1 tentpole: registry + step tracer + exporters)
     # ------------------------------------------------------------------
-    def _telemetry_step(self, tel, metrics, t_start, t_prepared, t_dispatched) -> None:
+    def _telemetry_step(self, tel, metrics, sp_batch, leaves) -> None:
         """Assemble and emit one telemetry step record (sampled steps only).
+        ``leaves`` are the closed ``ds.train.*`` leaf spans of this step under
+        the record's span names; ``sp_batch`` is the open parent.
 
-        The ``device_get`` blocks on the step's outputs to read the scalars —
-        that sync is the cost of sampling; ``telemetry.sample_every``
-        amortizes it over unsampled steps, which add zero host callbacks."""
-        # dslint: disable=host-sync-in-step — the documented sampling sync
-        # (see docstring); telemetry.sample_every amortizes it
+        The step's outputs are already synced (the ``ds.train.wait`` leaf
+        blocks on a sampled step — the cost of sampling, which
+        ``telemetry.sample_every`` amortizes), so the ``device_get`` here is a
+        host copy."""
+        # dslint: disable=host-sync-in-step — see docstring
         host = jax.device_get(metrics) if isinstance(metrics, dict) else {}
-        t_synced = time.perf_counter()
+        duration_s = sp_batch.elapsed()
         scalars = {}
         for k, v in host.items():
             try:
                 scalars[k] = float(v)
             except (TypeError, ValueError):
                 pass
-        spans = [
-            ("prepare", (t_prepared - t_start) * 1e3),
-            ("dispatch", (t_dispatched - t_prepared) * 1e3),
-            ("sync", (t_synced - t_dispatched) * 1e3),
-        ]
+        step_spans = [(name, leaf.duration * 1e3) for name, leaf in leaves]
         self.timers.export_telemetry(tel.registry)
         self.tput_timer.export_telemetry(tel.registry)
         cache_size = getattr(self._train_step, "_cache_size", None)
@@ -1854,7 +1886,7 @@ class DeepSpeedEngine:
 
             report = _intro.step_report(
                 ana,
-                duration_s=t_synced - t_start,
+                duration_s=duration_s,
                 peak=_intro.chip_peak(
                     peak_flops_override=float(
                         getattr(tel.introspection, "peak_tflops", 0.0) or 0.0
@@ -1866,16 +1898,16 @@ class DeepSpeedEngine:
         tel.record_step(
             "train",
             step=self.global_steps,
-            duration_s=t_synced - t_start,
+            duration_s=duration_s,
             scalars=scalars,
-            spans=spans,
+            spans=step_spans,
             hbm=self.memory_breakdown(),
             comm_bytes=self._comm_bytes_by_axis(),
             comm_wire_bytes={a: r["wire_bytes"] for a, r in comp.items()} or None,
             extra=extra,
         )
 
-    def _watchdog_step(self, wd, metrics, t_start: float) -> list:
+    def _watchdog_step(self, wd, metrics, step_time_s: float) -> list:
         """Close any active anomaly capture, then judge this step's scalars
         (ISSUE 5 watchdog). ``anomaly_flags`` — the in-graph NaN/Inf bitmask
         — is popped from the metrics surface regardless of the check cadence.
@@ -1897,7 +1929,7 @@ class DeepSpeedEngine:
             if flags:
                 return wd.observe_step(self.global_steps, {}, flags=flags)
             return []
-        scalars: Dict[str, float] = {"step_time_s": time.perf_counter() - t_start}
+        scalars: Dict[str, float] = {"step_time_s": step_time_s}
         for k in ("loss", "grad_norm"):
             if isinstance(metrics, dict) and k in metrics:
                 try:

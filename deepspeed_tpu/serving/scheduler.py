@@ -70,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.gpt2 import GPT2Config
+from ..telemetry import spans
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
 from ..utils.logging import log_dist
@@ -423,6 +424,14 @@ class ServingEngine:
         self._c_tokens = m.counter("serving_tokens_total", "generated tokens")
         self._c_prefills = m.counter("serving_prefills_total", "prefill insertions")
         self._c_steps = m.counter("serving_decode_steps_total", "batched decode steps")
+        # occupancy and mean context are their quotients with the step counter
+        self._c_slot_steps = m.counter(
+            "serving_decode_slot_steps_total", "active slots summed over decode steps"
+        )
+        self._c_attended = m.counter(
+            "serving_attended_tokens_total",
+            "context tokens attended, summed over active slots and decode steps",
+        )
         self._c_timeouts = m.counter(
             "serving_timeout_evictions_total",
             "requests evicted mid-flight by deadline",
@@ -827,6 +836,10 @@ class ServingEngine:
     def _ensure_compiled(self) -> None:
         if self._prefill_exec is not None:
             return
+        with spans.phase("ds.init.programs", what="serving"):
+            self._compile_programs()
+
+    def _compile_programs(self) -> None:
         sc = self.config
         temp, tk, top_p = float(sc.temperature), int(sc.top_k), float(sc.top_p)
         quant = self.quantized
@@ -1193,118 +1206,139 @@ class ServingEngine:
         requests into free slots (prefill insertion), advance every active
         slot one token. Returns the number of active slots after the step."""
         self._ensure_compiled()
-        now = self.clock()
+        with spans.span(
+            "ds.serve.step", step=self._step_count, queue=len(self.queue)
+        ) as sp:
+            n_active = self._step()
+            sp.set(active=n_active)
+        return n_active
 
-        # 1. timeout eviction — a request past its deadline degrades to a
-        # truncated response; its slot and pages are reclaimed immediately
-        for i, slot in enumerate(self.slots):
-            if slot.request is None:
-                continue
-            dl = self._deadline(slot.request)
-            if dl is not None and now > dl:
-                self._c_timeouts.inc()
-                self._finish_slot(i, RequestStatus.TRUNCATED, "deadline exceeded", now)
-        if self.queue:
-            keep: Deque[Request] = deque()
-            for req in self.queue:
-                dl = self._deadline(req)
+    def _step(self) -> int:
+        """The body of :meth:`step`, tiled by leaf spans (``ds.serve.*``; the
+        names and attributes are listed in PERF.md section 3): no statement
+        that can take more than a few microseconds is outside one, and every
+        place that blocks on the device has a leaf whose name ends in
+        ``.wait``."""
+        with spans.span("ds.serve.admit") as sp:
+            admitted, blocked = 0, ""
+            now = self.clock()
+
+            # 1. timeout eviction — a request past its deadline degrades to a
+            # truncated response; its slot and pages are reclaimed immediately
+            for i, slot in enumerate(self.slots):
+                if slot.request is None:
+                    continue
+                dl = self._deadline(slot.request)
                 if dl is not None and now > dl:
-                    req.status = RequestStatus.TIMED_OUT
-                    req.detail = "deadline exceeded while queued"
-                    req.t_finish = now
-                    self._c_requests.inc(status=RequestStatus.TIMED_OUT)
-                    self._req_terminal(req, now)
-                    self.completed.append(req)
-                else:
-                    keep.append(req)
-            self.queue = keep
+                    self._c_timeouts.inc()
+                    self._finish_slot(i, RequestStatus.TRUNCATED, "deadline exceeded", now)
+            if self.queue:
+                keep: Deque[Request] = deque()
+                for req in self.queue:
+                    dl = self._deadline(req)
+                    if dl is not None and now > dl:
+                        req.status = RequestStatus.TIMED_OUT
+                        req.detail = "deadline exceeded while queued"
+                        req.t_finish = now
+                        self._c_requests.inc(status=RequestStatus.TIMED_OUT)
+                        self._req_terminal(req, now)
+                        self.completed.append(req)
+                    else:
+                        keep.append(req)
+                self.queue = keep
 
-        # queue-wait attribution (ISSUE 11): requests sitting out a retry
-        # backoff window are waiting on themselves, not on capacity — note
-        # it once per scheduler step so the trace can split queue wait by
-        # cause (the admission loop below attributes the capacity causes).
-        # _backoff_pending gates the queue scan: retries are rare and a
-        # deep queue would otherwise pay the walk every step
-        if self.tracer is not None and self._backoff_pending:
-            waiting = False
-            for r in self.queue:
-                if r.not_before > now:
-                    self.tracer.note_wait(r, "backoff")
-                    waiting = True
-            if not waiting:
-                self._backoff_pending = False
+            # queue-wait attribution (ISSUE 11): requests sitting out a retry
+            # backoff window are waiting on themselves, not on capacity — note
+            # it once per scheduler step so the trace can split queue wait by
+            # cause (the admission loop below attributes the capacity causes).
+            # _backoff_pending gates the queue scan: retries are rare and a
+            # deep queue would otherwise pay the walk every step
+            if self.tracer is not None and self._backoff_pending:
+                waiting = False
+                for r in self.queue:
+                    if r.not_before > now:
+                        self.tracer.note_wait(r, "backoff")
+                        waiting = True
+                if not waiting:
+                    self._backoff_pending = False
 
-        # 2. prefill insertions: FIFO admission into free slots, gated by the
-        # KV-page budget (head-of-line blocks until draining slots free
-        # pages). The page need is net of prefix-index pages the prompt can
-        # map (ISSUE 10 — shared pages cost nothing), and under pool
-        # pressure the index yields cold entries to live traffic before the
-        # head of line blocks. A drain stops admission entirely; a retried
-        # request still inside its backoff window (not_before) is passed
-        # over, not a head-of-line blocker.
-        while self.queue and not self._draining:
-            free = next(
-                (i for i, s in enumerate(self.slots) if s.request is None), None
-            )
-            if free is None:
-                # all slots busy: the ready head of line waited this step
-                # on slot capacity (queue depth, in SLO terms). The ready
-                # scan only serves that attribution — skip it untraced
-                if self.tracer is not None:
-                    idx = next(
-                        (j for j, r in enumerate(self.queue)
-                         if r.not_before <= now),
-                        None,
-                    )
-                    if idx is not None:
-                        self.tracer.note_wait(self.queue[idx], "no_free_slot")
-                break
-            idx = next(
-                (j for j, r in enumerate(self.queue) if r.not_before <= now),
-                None,
-            )
-            if idx is None:
-                break
-            req = self.queue[idx]
-            # ISSUE 17: before costing the reservation, restore any of the
-            # prompt's demoted prefix pages from the host tier (each restore
-            # turns a would-be recompute page into a mapped hit, shrinking
-            # `need` below). Depth-bounded per step — a long host-held chain
-            # keeps the request queued with a kv_restore wait and continues
-            # next step rather than absorbing unbounded device_put work.
-            if self.tiering is not None and self._tier_prefetch(req, now):
-                if self.tracer is not None:
-                    self.tracer.note_wait(req, "kv_restore")
-                break
-            # under disaggregation BOTH placements gate admission: the
-            # decode pool must hold the full private reservation, the
-            # prefill pool the prompt pages net of prefix hits. The index
-            # holds prefill-side pages, so eviction only relieves that side.
-            need = self._pages_needed(req)
-            p_alloc = self.prefill_set.allocator
-            p_need = (
-                self._prefill_pages_needed(req) if self.disaggregated else need
-            )
-            if need > self.allocator.free_pages or (
-                self.disaggregated and p_need > p_alloc.free_pages
-            ):
-                if self.prefix_cache is not None and len(self.prefix_cache):
-                    self.prefix_cache.evict(need_free=p_need)
-                    self._g_index_pages.set(len(self.prefix_cache))
-                    # eviction may have dropped the very pages the probe
-                    # counted as mappable — recompute, or _admit could
-                    # allocate past the pool
-                    need = self._pages_needed(req)
-                    if self.disaggregated:
-                        p_need = self._prefill_pages_needed(req)
+            # 2. prefill insertions: FIFO admission into free slots, gated by the
+            # KV-page budget (head-of-line blocks until draining slots free
+            # pages). The page need is net of prefix-index pages the prompt can
+            # map (ISSUE 10 — shared pages cost nothing), and under pool
+            # pressure the index yields cold entries to live traffic before the
+            # head of line blocks. A drain stops admission entirely; a retried
+            # request still inside its backoff window (not_before) is passed
+            # over, not a head-of-line blocker.
+            while self.queue and not self._draining:
+                free = next(
+                    (i for i, s in enumerate(self.slots) if s.request is None), None
+                )
+                if free is None:
+                    # all slots busy: the ready head of line waited this step
+                    # on slot capacity (queue depth, in SLO terms). The ready
+                    # scan only serves that attribution — skip it untraced
+                    if self.tracer is not None:
+                        idx = next(
+                            (j for j, r in enumerate(self.queue)
+                             if r.not_before <= now),
+                            None,
+                        )
+                        if idx is not None:
+                            self.tracer.note_wait(self.queue[idx], "no_free_slot")
+                    blocked = "no_free_slot"
+                    break
+                idx = next(
+                    (j for j, r in enumerate(self.queue) if r.not_before <= now),
+                    None,
+                )
+                if idx is None:
+                    blocked = "backoff"
+                    break
+                req = self.queue[idx]
+                # ISSUE 17: before costing the reservation, restore any of the
+                # prompt's demoted prefix pages from the host tier (each restore
+                # turns a would-be recompute page into a mapped hit, shrinking
+                # `need` below). Depth-bounded per step — a long host-held chain
+                # keeps the request queued with a kv_restore wait and continues
+                # next step rather than absorbing unbounded device_put work.
+                if self.tiering is not None and self._tier_prefetch(req, now):
+                    if self.tracer is not None:
+                        self.tracer.note_wait(req, "kv_restore")
+                    blocked = "kv_restore"
+                    break
+                # under disaggregation BOTH placements gate admission: the
+                # decode pool must hold the full private reservation, the
+                # prefill pool the prompt pages net of prefix hits. The index
+                # holds prefill-side pages, so eviction only relieves that side.
+                need = self._pages_needed(req)
+                p_alloc = self.prefill_set.allocator
+                p_need = (
+                    self._prefill_pages_needed(req) if self.disaggregated else need
+                )
                 if need > self.allocator.free_pages or (
                     self.disaggregated and p_need > p_alloc.free_pages
                 ):
-                    if self.tracer is not None:
-                        self.tracer.note_wait(req, "page_budget")
-                    break
-            del self.queue[idx]
-            self._admit(free, req)
+                    if self.prefix_cache is not None and len(self.prefix_cache):
+                        self.prefix_cache.evict(need_free=p_need)
+                        self._g_index_pages.set(len(self.prefix_cache))
+                        # eviction may have dropped the very pages the probe
+                        # counted as mappable — recompute, or _admit could
+                        # allocate past the pool
+                        need = self._pages_needed(req)
+                        if self.disaggregated:
+                            p_need = self._prefill_pages_needed(req)
+                    if need > self.allocator.free_pages or (
+                        self.disaggregated and p_need > p_alloc.free_pages
+                    ):
+                        if self.tracer is not None:
+                            self.tracer.note_wait(req, "page_budget")
+                        blocked = "page_budget"
+                        break
+                del self.queue[idx]
+                self._admit(free, req)
+                admitted += 1
+            sp.set(admitted=admitted, blocked=blocked)
 
         # 2b. chunked prefill (ISSUE 10): every PREFILLING slot advances ONE
         # chunk, then the decode batch below still runs — a long prompt pays
@@ -1312,12 +1346,18 @@ class ServingEngine:
         # decodes for its whole width. A slot whose first token is already
         # in flight (pending_tok) is past its last chunk — it waits on the
         # handoff phase below, not on more chunks.
-        for i, slot in enumerate(self.slots):
-            if (
-                slot.request is not None and slot.prefilling
-                and slot.pending_tok is None
-            ):
-                self._advance_chunk(i)
+        pre = [
+            i for i, s in enumerate(self.slots)
+            if s.request is not None and s.prefilling and s.pending_tok is None
+        ]
+        if pre:
+            with spans.span("ds.serve.chunk", chunks=len(pre)) as sp:
+                n_tok = 0
+                for i in pre:
+                    s = self.slots[i]
+                    n_tok += min(self.chunk_width, s.request.prompt_len - s.prefill_pos)
+                    self._advance_chunk(i)
+                sp.set(tokens=n_tok)
 
         # 2c. disaggregated handoff completion (ISSUE 14): a slot whose
         # prefill placement has sampled the first token moves its prompt KV
@@ -1331,16 +1371,20 @@ class ServingEngine:
                 if s.request is not None and s.pending_tok is not None
             ]
             if pend:
-                force = not any(
-                    s.request is not None and not s.prefilling
-                    for s in self.slots
-                )
-                for i in pend:
-                    arr = self.slots[i].pending_tok
-                    ready = getattr(arr, "is_ready", None)
-                    if force or ready is None or ready():
-                        self._complete_handoff(i)
-                        force = False  # a decode-active slot now exists
+                with spans.span("ds.serve.handoff") as sp:
+                    done = 0
+                    force = not any(
+                        s.request is not None and not s.prefilling
+                        for s in self.slots
+                    )
+                    for i in pend:
+                        arr = self.slots[i].pending_tok
+                        ready = getattr(arr, "is_ready", None)
+                        if force or ready is None or ready():
+                            self._complete_handoff(i)
+                            done += 1
+                            force = False  # a decode-active slot now exists
+                    sp.set(slots=done)
 
         # 3. one batched decode (or speculative verify) step for every slot
         # that is past prefill
@@ -1349,151 +1393,169 @@ class ServingEngine:
             if s.request is not None and not s.prefilling
         ]
         if active:
-            t0 = self.clock()
-            drafts: dict = {}
-            # the AOT executable takes the numpy slot tables directly — a
-            # jnp.asarray wrapper here would dispatch four extra device ops
-            # per decode step (dslint jnp-in-hot-loop)
-            if self.spec_enabled:
-                T = self.spec_k + 1
-                vt = np.zeros((self.max_slots, T), np.int32)
-                vt[:, 0] = self.table.tokens
-                for i in active:
-                    d = self._draft(self.slots[i].request)
-                    drafts[i] = d
-                    vt[i, 1:] = d
-                dset = self.decode_set
-                out = dset.take_pools(self._verify_exec(
-                    dset.params, *dset.pool_args(),
-                    vt, self.table.seq_lens, self.table.block_tables,
-                ))
-                self._c_spec_steps.inc()
-                self._c_spec_drafted.inc(self.spec_k * len(active))
-            else:
-                dset = self.decode_set
-                out = dset.take_pools(self._decode_exec(
-                    dset.params, *dset.pool_args(),
-                    self.table.tokens, self.table.seq_lens,
-                    self.table.block_tables, self.table.keys,
-                ))
+            with spans.span("ds.serve.decode.dispatch", active=len(active)) as sp:
+                t0 = self.clock()
+                # tokens the queries attend (each slot's cached context and the
+                # token this step writes) and the pages those contexts hold
+                lens = self.table.seq_lens[active]
+                attended = int(lens.sum()) + len(active)
+                sp.set(
+                    attended=attended,
+                    pages=int((lens // self.page_size).sum()) + len(active),
+                )
+                self._c_slot_steps.inc(len(active))
+                self._c_attended.inc(attended)
+                drafts: dict = {}
+                # the AOT executable takes the numpy slot tables directly — a
+                # jnp.asarray wrapper here would dispatch four extra device ops
+                # per decode step (dslint jnp-in-hot-loop)
+                if self.spec_enabled:
+                    T = self.spec_k + 1
+                    vt = np.zeros((self.max_slots, T), np.int32)
+                    vt[:, 0] = self.table.tokens
+                    for i in active:
+                        d = self._draft(self.slots[i].request)
+                        drafts[i] = d
+                        vt[i, 1:] = d
+                    dset = self.decode_set
+                    out = dset.take_pools(self._verify_exec(
+                        dset.params, *dset.pool_args(),
+                        vt, self.table.seq_lens, self.table.block_tables,
+                    ))
+                    self._c_spec_steps.inc()
+                    self._c_spec_drafted.inc(self.spec_k * len(active))
+                else:
+                    dset = self.decode_set
+                    out = dset.take_pools(self._decode_exec(
+                        dset.params, *dset.pool_args(),
+                        self.table.tokens, self.table.seq_lens,
+                        self.table.block_tables, self.table.keys,
+                    ))
             # the ONE deliberate sync of the slot loop: the scheduler must
             # read the sampled tokens to retire/advance slots
-            out_np = jax.device_get(out)  # dslint: disable=host-sync-in-step
-            now = self.clock()
-            self._h_step.observe(now - t0)
-            self._c_steps.inc()
-            self._step_count += 1
-            dt = now - t0
-            self._ema_step_s = (
-                dt if self._ema_step_s == 0.0
-                else 0.8 * self._ema_step_s + 0.2 * dt
-            )
-            # pass 1 — tokens + trace events for EVERY slot, batched into
-            # ONE tracer ingestion (one lock round-trip per step, not per
-            # slot), and ingested BEFORE any retirement below can fold a
-            # finishing request's buffer into its terminal record
-            emitted: list = []
-            ev_batch: list = []
-            heat_batch: list = []
-            heat = self._heat_decode  # ISSUE 16: decode-pool heat ledger
-            page = self.page_size
-            for i in active:
-                req = self.slots[i].request
-                if self.spec_enabled:
-                    toks = self._accept_tokens(req, drafts[i], out_np[i])
-                else:
-                    toks = [int(out_np[i])]
-                req.tokens.extend(toks)
-                if heat is not None:
-                    # the step's KV write landed in the page holding the last
-                    # emitted position; the attended set is the slot's
-                    # block-table prefix (leanest columnar shape — offline
-                    # expansion rides the session's S-event page list)
-                    pos_after = self.slots[i].pos + len(toks)
-                    heat_batch.append((
-                        i, int(self.table.block_tables[i, (pos_after - 1) // page]),
-                        pages_for(pos_after, page),
-                    ))
-                # one emission timestamp per token: an accepted speculative
-                # run lands at ONE instant — the streaming-client truth the
-                # TPOT quantiles derive from (ISSUE 11)
-                req.t_emissions.extend([now] * len(toks))
-                if self.tracer is not None:
-                    ev_batch.append((req.id, {
-                        "e": "verify", "t": now, "step": self._step_count,
-                        "slot": i, "emitted": len(toks),
-                        "drafted": self.spec_k, "accepted": len(toks) - 1,
-                        "total": len(req.tokens),
-                    } if self.spec_enabled else (
-                        # plain decode: the lean columnar series (emitted
-                        # is always 1) — this line runs for every slot of
-                        # every step the engine ever takes
-                        now, self._step_count, i,
-                    )))
-                emitted.append((i, toks))
-            if ev_batch:
-                if self.spec_enabled:
-                    self.tracer.step_events(ev_batch)
-                else:
-                    self.tracer.decode_events(ev_batch)
-            if heat_batch:
-                heat.touch_step(now, self._step_count, heat_batch)
-            # pass 2 — advance/retire the slots
-            for i, toks in emitted:
-                slot = self.slots[i]
-                req = slot.request
-                slot.pos += len(toks)
-                slot.step += 1
-                self.table.seq_lens[i] = slot.pos
-                self.table.tokens[i] = toks[-1]
-                if len(req.tokens) >= req.max_new_tokens or (
-                    req.eos_token_id is not None
-                    and toks[-1] == req.eos_token_id
-                ):
-                    self._finish_slot(i, RequestStatus.FINISHED, "", now)
-                elif req.stall_after is not None and len(req.tokens) >= req.stall_after:
-                    # injected transient slot failure (ISSUE 7): evict and
-                    # route through the retry-with-backoff path
-                    self._fail_slot(i, "injected slot stall", now)
-                elif slot.keys is not None and slot.step < len(slot.keys):
-                    self.table.keys[i] = slot.keys[slot.step]
+            with spans.span("ds.serve.decode.wait"):
+                out_np = jax.device_get(out)  # dslint: disable=host-sync-in-step
+            with spans.span("ds.serve.emit") as sp:
+                n_emit = n_fin = 0
+                now = self.clock()
+                self._h_step.observe(now - t0)
+                self._c_steps.inc()
+                self._step_count += 1
+                dt = now - t0
+                self._ema_step_s = (
+                    dt if self._ema_step_s == 0.0
+                    else 0.8 * self._ema_step_s + 0.2 * dt
+                )
+                # pass 1 — tokens + trace events for EVERY slot, batched into
+                # ONE tracer ingestion (one lock round-trip per step, not per
+                # slot), and ingested BEFORE any retirement below can fold a
+                # finishing request's buffer into its terminal record
+                emitted: list = []
+                ev_batch: list = []
+                heat_batch: list = []
+                heat = self._heat_decode  # ISSUE 16: decode-pool heat ledger
+                page = self.page_size
+                for i in active:
+                    req = self.slots[i].request
+                    if self.spec_enabled:
+                        toks = self._accept_tokens(req, drafts[i], out_np[i])
+                    else:
+                        toks = [int(out_np[i])]
+                    req.tokens.extend(toks)
+                    n_emit += len(toks)
+                    if heat is not None:
+                        # the step's KV write landed in the page holding the last
+                        # emitted position; the attended set is the slot's
+                        # block-table prefix (leanest columnar shape — offline
+                        # expansion rides the session's S-event page list)
+                        pos_after = self.slots[i].pos + len(toks)
+                        heat_batch.append((
+                            i, int(self.table.block_tables[i, (pos_after - 1) // page]),
+                            pages_for(pos_after, page),
+                        ))
+                    # one emission timestamp per token: an accepted speculative
+                    # run lands at ONE instant — the streaming-client truth the
+                    # TPOT quantiles derive from (ISSUE 11)
+                    req.t_emissions.extend([now] * len(toks))
+                    if self.tracer is not None:
+                        ev_batch.append((req.id, {
+                            "e": "verify", "t": now, "step": self._step_count,
+                            "slot": i, "emitted": len(toks),
+                            "drafted": self.spec_k, "accepted": len(toks) - 1,
+                            "total": len(req.tokens),
+                        } if self.spec_enabled else (
+                            # plain decode: the lean columnar series (emitted
+                            # is always 1) — this line runs for every slot of
+                            # every step the engine ever takes
+                            now, self._step_count, i,
+                        )))
+                    emitted.append((i, toks))
+                if ev_batch:
+                    if self.spec_enabled:
+                        self.tracer.step_events(ev_batch)
+                    else:
+                        self.tracer.decode_events(ev_batch)
+                if heat_batch:
+                    heat.touch_step(now, self._step_count, heat_batch)
+                # pass 2 — advance/retire the slots
+                for i, toks in emitted:
+                    slot = self.slots[i]
+                    req = slot.request
+                    slot.pos += len(toks)
+                    slot.step += 1
+                    self.table.seq_lens[i] = slot.pos
+                    self.table.tokens[i] = toks[-1]
+                    if len(req.tokens) >= req.max_new_tokens or (
+                        req.eos_token_id is not None
+                        and toks[-1] == req.eos_token_id
+                    ):
+                        self._finish_slot(i, RequestStatus.FINISHED, "", now)
+                        n_fin += 1
+                    elif req.stall_after is not None and len(req.tokens) >= req.stall_after:
+                        # injected transient slot failure (ISSUE 7): evict and
+                        # route through the retry-with-backoff path
+                        self._fail_slot(i, "injected slot stall", now)
+                    elif slot.keys is not None and slot.step < len(slot.keys):
+                        self.table.keys[i] = slot.keys[slot.step]
+                sp.set(tokens=n_emit, finished=n_fin)
 
-        # straggler detection (ISSUE 5 watchdog): a request resident in a
-        # slot far beyond its expected decode budget (straggler_factor x
-        # max_new_tokens x EMA step time) is flagged once — a wedged or
-        # pathologically slow request surfaces instead of silently holding
-        # a slot. Slots advance in lockstep, so residence time is the only
-        # per-request axis that can straggle.
-        if self.watchdog is not None and self._ema_step_s > 0.0:
-            factor = float(getattr(self.watchdog.config, "straggler_factor", 3.0))
-            now = self.clock()
-            for slot in self.slots:
-                req = slot.request
-                if req is None or req.t_first_token is None:
-                    continue
-                budget = factor * max(1, req.max_new_tokens) * self._ema_step_s
-                elapsed = now - req.t_first_token
-                if elapsed > budget and self.watchdog.observe_straggler(
-                    self._step_count, req.id,
-                    f"slot residence {elapsed:.3f}s > {budget:.3f}s "
-                    f"({len(req.tokens)}/{req.max_new_tokens} tokens)",
-                ):
-                    self._c_stragglers.inc()
+        with spans.span("ds.serve.housekeep"):
+            # straggler detection (ISSUE 5 watchdog): a request resident in a
+            # slot far beyond its expected decode budget (straggler_factor x
+            # max_new_tokens x EMA step time) is flagged once — a wedged or
+            # pathologically slow request surfaces instead of silently holding
+            # a slot. Slots advance in lockstep, so residence time is the only
+            # per-request axis that can straggle.
+            if self.watchdog is not None and self._ema_step_s > 0.0:
+                factor = float(getattr(self.watchdog.config, "straggler_factor", 3.0))
+                now = self.clock()
+                for slot in self.slots:
+                    req = slot.request
+                    if req is None or req.t_first_token is None:
+                        continue
+                    budget = factor * max(1, req.max_new_tokens) * self._ema_step_s
+                    elapsed = now - req.t_first_token
+                    if elapsed > budget and self.watchdog.observe_straggler(
+                        self._step_count, req.id,
+                        f"slot residence {elapsed:.3f}s > {budget:.3f}s "
+                        f"({len(req.tokens)}/{req.max_new_tokens} tokens)",
+                    ):
+                        self._c_stragglers.inc()
 
-        n_active = sum(1 for s in self.slots if s.request is not None)
-        self._g_queue.set(len(self.queue))
-        self._g_util.set(n_active / self.max_slots)
-        self._g_pages.set(self.allocator.pages_in_use)
-        self._g_occ.set(self.allocator.pages_in_use / self.allocator.capacity)
-        self._g_pages_shared.set(self.allocator.pages_shared)
-        if self.prefix_cache is not None:
-            self._g_index_pages.set(len(self.prefix_cache))
-        if self.tiering is not None:
-            self._tier_pump()
-        if self._step_count and self._step_count % 32 == 0:
-            self.stats()  # refresh the quantile gauges for textfile scrapes
-        if self._journal is not None:
-            self._journal.maybe_snapshot(self.clock())
+            n_active = sum(1 for s in self.slots if s.request is not None)
+            self._g_queue.set(len(self.queue))
+            self._g_util.set(n_active / self.max_slots)
+            self._g_pages.set(self.allocator.pages_in_use)
+            self._g_occ.set(self.allocator.pages_in_use / self.allocator.capacity)
+            self._g_pages_shared.set(self.allocator.pages_shared)
+            if self.prefix_cache is not None:
+                self._g_index_pages.set(len(self.prefix_cache))
+            if self.tiering is not None:
+                self._tier_pump()
+            if self._step_count and self._step_count % 32 == 0:
+                self.stats()  # refresh the quantile gauges for textfile scrapes
+            if self._journal is not None:
+                self._journal.maybe_snapshot(self.clock())
         return n_active
 
     def _pages_needed(self, req: Request) -> int:
@@ -1801,7 +1863,8 @@ class ServingEngine:
         self._c_prefills.inc()
         # deliberate sync: TTFT is defined by the first token reaching the
         # host, and an at-admission EOS must retire the slot before decode
-        tok0 = int(jax.device_get(first)[0])  # dslint: disable=host-sync-in-step
+        with spans.span("ds.serve.prefill.wait"):
+            tok0 = int(jax.device_get(first)[0])  # dslint: disable=host-sync-in-step
         if self.tracer is not None:
             self.tracer.event(
                 req, "prefill", self.clock(), step=self._step_count,
@@ -1852,7 +1915,8 @@ class ServingEngine:
             return
         # deliberate sync, as in _admit: the final chunk's sample is the
         # request's first token
-        tok0 = int(jax.device_get(tok)[0])  # dslint: disable=host-sync-in-step
+        with spans.span("ds.serve.chunk.wait"):
+            tok0 = int(jax.device_get(tok)[0])  # dslint: disable=host-sync-in-step
         self._start_decoding(slot_i, tok0)
 
     def _complete_handoff(self, slot_i: int) -> None:
@@ -1871,7 +1935,8 @@ class ServingEngine:
         req = slot.request
         # phase 2c only calls here once the array is ready (or nothing is
         # decoding, so blocking costs no batch progress)
-        tok0 = int(jax.device_get(slot.pending_tok)[0])  # dslint: disable=host-sync-in-step
+        with spans.span("ds.serve.handoff.wait"):
+            tok0 = int(jax.device_get(slot.pending_tok)[0])  # dslint: disable=host-sync-in-step
         slot.pending_tok = None
         if req.max_new_tokens == 1 or (
             req.eos_token_id is not None and tok0 == req.eos_token_id
@@ -1901,7 +1966,8 @@ class ServingEngine:
         dset.set_pools(out)
         # sync for latency truth: the handoff gauge must cover the actual
         # copy, not its async dispatch
-        jax.block_until_ready(out)  # dslint: disable=host-sync-in-step
+        with spans.span("ds.serve.handoff.wait"):
+            jax.block_until_ready(out)  # dslint: disable=host-sync-in-step
         now = self.clock()
         nbytes = sum(int(x.nbytes) for x in packed)
         self._c_handoffs.inc()
@@ -1971,7 +2037,8 @@ class ServingEngine:
             )
             # dslint: disable=jnp-in-hot-loop
             keys = jax.random.split(key1, req.max_new_tokens - 1)
-            slot.keys = np.asarray(keys)  # dslint: disable=host-sync-in-step
+            with spans.span("ds.serve.keys.wait"):
+                slot.keys = np.asarray(keys)  # dslint: disable=host-sync-in-step
             self.table.keys[slot_i] = slot.keys[0]
         if req.max_new_tokens == 1 or (
             req.eos_token_id is not None and tok0 == req.eos_token_id
