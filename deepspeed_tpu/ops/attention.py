@@ -142,11 +142,12 @@ def paged_cached_attention(
     dtype is int8.
 
     Dispatch mirrors :func:`cached_attention`: the Pallas paged kernel on TPU
-    (the block-table gather IS the kernel's index map — no dense copy; int8
-    pages dequantize INSIDE the kernel, so HBM traffic is the halved code
-    bytes), and a pure-jnp fallback that gathers the slot's pages into a
-    dense view and runs the exact grouped einsum of :func:`cached_attention`
-    with a per-slot mask, so the two paths agree with the dense cache."""
+    (the block-table gather IS the kernel's index maps — no dense copy, no
+    page past a slot's own length; int8 codes are scaled INSIDE the kernel,
+    so HBM traffic is the halved code bytes), and a pure-jnp fallback that
+    gathers the slot's pages into a dense view and runs the exact grouped
+    einsum of :func:`cached_attention` with a per-slot mask, so the two
+    paths agree with the dense cache."""
     B, H, D = q.shape
     P, KV, page, _ = k_pool.shape
     if H % KV != 0:
@@ -163,7 +164,9 @@ def paged_cached_attention(
             paged_decode_attention_ok,
         )
 
-        if impl == "pallas" or paged_decode_attention_ok(page, D, k_pool.dtype.itemsize):
+        if impl == "pallas" or paged_decode_attention_ok(
+            KV, page, D, k_pool.dtype.itemsize
+        ):
             return paged_decode_attention(
                 q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
                 scales=scales,

@@ -116,70 +116,114 @@ def decode_attention(
 # paged variant: K/V live in a shared page pool, gathered through a per-slot
 # block table (the serving subsystem's cache layout, serving/kv_cache.py).
 # Reference analog: vLLM's paged_attention kernel — but expressed TPU-natively:
-# the gather IS the BlockSpec index map (scalar-prefetched block table drives
-# which pool page each grid step DMAs into VMEM), so no dense copy of the
-# cache ever materializes.
+# the gather IS the BlockSpec index map (the scalar-prefetched block table
+# names the pool page each page input DMAs into VMEM, all kv-heads of the
+# page in one contiguous run), so no dense copy of the cache ever
+# materializes, and the map stops at the slot's own last page.
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  sm_scale: float, page: int, rep: int = 1,
-                  quantized: bool = False):
-    """Online-softmax accumulation over one slot's pages.
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    Grid (B, H, n_pages): TPU grids run sequentially, so the (m, l, acc)
-    scratch persists across the innermost page dimension — reset at page 0,
-    emitted at the last page. Pages wholly past ``pos`` skip their compute
-    (their DMA still runs; block-table rows pad with the scratch page, so the
-    wasted bandwidth is one page per padded entry).
 
-    ``quantized`` (ISSUE 12): K/V blocks arrive as int8 codes and a fourth
-    input carries the page's [1, KV, 2] scales (gathered by the SAME
-    block-table index map) — dequantization happens here in VMEM, so the
-    HBM read per page is the halved code bytes plus 8 bytes of scale."""
+def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
+                        n_pages: Optional[int] = None):
+    """(kv-heads, pages) one grid step of the paged decode kernel holds, from
+    the shapes alone: K and V of the block, double-buffered, at their padded
+    VMEM tile sizes, stay inside ``VMEM_RESIDENT_BYTES``. All heads of a page
+    when they fit (then as many pages as fit, a power of two, at most
+    ``S_BLOCK`` keys and the table's width); else the largest divisor of
+    ``KV`` whose single page fits. ``None`` when one head's page does not."""
+    from .flash_attention import VMEM_RESIDENT_BYTES
+
+    sublane = max(1, 32 // itemsize)
+    tile = _round_up(page, sublane) * _round_up(D, 128) * itemsize
+    fit = VMEM_RESIDENT_BYTES // (4 * tile)  # (head, page) tiles: K, V x 2 buffers
+    if fit < 1:
+        return None
+    if fit < KV:
+        return max(h for h in range(1, fit + 1) if KV % h == 0), 1
+    cap = min(fit // KV, max(1, S_BLOCK // page), n_pages or S_BLOCK)
+    return KV, 1 << (cap.bit_length() - 1)
+
+
+def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
+                  nhb: int, quantized: bool = False):
+    """Online-softmax accumulation over one slot's pages, ``G`` pages and
+    ``HB`` kv-heads (all of them, unless a page of all does not fit VMEM) to
+    a grid step.
+
+    Grid (B * nhb, ceil(n_pages / G)), sequential: row ``r`` is slot
+    ``r // nhb``, head block ``r % nhb``; the (m, l, acc) scratch persists
+    across the row's page blocks, reset at block 0 and emitted at the slot's
+    LAST OWN block ``pos // (G * page)``. Blocks past it skip their compute,
+    and the walked table (:func:`paged_decode_attention`) names for them the
+    pages the last own block named, so Pallas fetches nothing. ``rest`` holds
+    the block's ``G`` K pages and ``G`` V pages, ``[1, HB, page, D]`` each.
+    A page ref past the slot's last page holds one of the slot's earlier
+    pages; its scores are masked and its probabilities are exactly 0.
+
+    The arithmetic is batched over heads: ``s[h, r, p]`` and ``acc[h, r, d]``
+    are one ``dot_general`` each over ``[HB, G * page, D]``, q viewed as
+    ``[HB, rep, D]`` so a GQA group reads its single pool column.
+
+    ``quantized`` (ISSUE 12): K/V pages are int8 codes (exact in the query's
+    float type) and one more input carries the block's K and V scales per
+    key column, ``[2, HB, 1, G * page]``; scores and probabilities are scaled
+    in VMEM, so the HBM read per page stays the halved code bytes."""
+    k_refs, v_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
     if quantized:
-        s_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        s_ref, (o_ref, m_ref, l_ref, acc_ref) = None, rest
-    b = pl.program_id(0)
-    g = pl.program_id(1) // rep  # this program's kv-head column
-    j = pl.program_id(2)
-    D = q_ref.shape[-1]
+        sc_ref, *rest = rest
+    o_ref, m_ref, l_ref, acc_ref = rest
+    r = pl.program_id(0)
+    j = pl.program_id(1)
+    pos = pos_ref[r if nhb == 1 else jax.lax.div(r, nhb)]
+    GP = G * k_refs[0].shape[2]
+    last_blk = jax.lax.div(pos, GP)
 
     @pl.when(j == 0)
     def _reset():
-        m_ref[0] = jnp.float32(-1e30)
-        l_ref[0] = jnp.float32(0.0)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[b]
-
-    @pl.when(j * page <= pos)
+    @pl.when(j <= last_blk)
     def _update():
-        q = q_ref[...].reshape(1, D)
-        k = k_ref[0, 0]  # [page, D]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]  # [HB, rep, D]
+        k = jnp.concatenate([r[0] for r in k_refs], axis=1)  # [HB, GP, D]
+        v = jnp.concatenate([r[0] for r in v_refs], axis=1)
         if quantized:
-            k = k.astype(jnp.float32) * s_ref[0, g, 0]
-            v = v.astype(jnp.float32) * s_ref[0, g, 1]
-        s = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * sm_scale  # [page,1]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0) + j * page
-        s = jnp.where(idx <= pos, s, -1e30)
-        m_prev, l_prev = m_ref[0], l_ref[0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s))
+            k, v = k.astype(q.dtype), v.astype(q.dtype)
+        # dots take the pool's storage dtype with f32 accumulation (bf16
+        # products are exact in the accumulator); scores/softmax state f32
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale  # [HB, rep, GP]
+        if quantized:
+            s = s * sc_ref[0, 0, 0, 0]
+        live = jax.lax.broadcasted_iota(jnp.int32, (1, 1, GP), 2) + j * GP <= pos
+        s = jnp.where(live, s, -1e30)
+        m_prev, l_prev = m_ref[...], l_ref[...]  # [HB, rep, 1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur)
-        m_ref[0] = m_cur
-        l_ref[0] = l_prev * corr + jnp.sum(p)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p.astype(v.dtype).T, v, preferred_element_type=jnp.float32
-        )
+        m_ref[...] = m_cur
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            # a padded table entry's scale row may hold anything: 0 * it too
+            p = p * jnp.where(live, sc_ref[0, 0, 1, 0], 0.0)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [HB, rep, D]
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[0], 1e-30)
-        ).reshape(o_ref.shape).astype(o_ref.dtype)
+        @pl.when(j == last_blk)
+        def _emit():
+            o_ref[0, 0] = (
+                acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+            ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -194,14 +238,18 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Single-token attention against a PAGED cache → [B, H, D].
 
-    Each slot's logical cache is ``block_tables[b]``'s pages concatenated;
-    the index map gathers page ``j`` of slot ``b`` straight from the pool
-    (scalar-prefetched table), streaming one page per grid step through VMEM
-    with an online softmax. GQA as in :func:`decode_attention` (KV < H reads
-    the group's pool column). ``scales`` (ISSUE 12): int8 pools ride the
-    same index map — page ``bt[b, j]``'s [KV, 2] scale row DMAs beside the
-    code block and the dequantize runs in VMEM, so the memory-bound decode
-    read is half the bf16 bytes."""
+    Each slot's logical cache is ``block_tables[b]``'s pages concatenated up
+    to ``pos[b]``; the kernel walks them ``G`` pages at a time with all
+    kv-heads in one step (:func:`paged_decode_blocks` picks both from the
+    shapes). Each of the ``G`` page inputs is the same pool under its own
+    index map: the scalar-prefetched table names the page, one DMA brings
+    its whole ``[KV, page, D]`` run, and the map stops at the slot's own
+    last page, so table entries past ``pos[b] // page`` are never read. GQA
+    (KV < H) reads the group's pool column once for its ``rep`` query
+    heads. ``scales`` (ISSUE 12): int8 pools are served by the same kernel;
+    the slots' per-page scale rows are gathered through the table into
+    per-key columns here (a few hundred KB beside the halved code bytes)
+    and applied to the scores and the probabilities in VMEM."""
     B, H, D = q.shape
     P, KV, page, _ = k_pool.shape
     n_pages = block_tables.shape[1]
@@ -210,48 +258,78 @@ def paged_decode_attention(
     rep = H // KV
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
     quantized = scales is not None
+    blocks = paged_decode_blocks(KV, page, D, k_pool.dtype.itemsize, n_pages)
+    if blocks is None:
+        raise ValueError(
+            f"paged_decode_attention: one [{page}, {D}] page of one head "
+            "does not fit the kernel's VMEM budget"
+        )
+    HB, G = blocks
+    nhb, n_blk, GP = KV // HB, -(-n_pages // G), G * page
 
+    # The table as the grid walks it: entry (j, g) of a row is the page that
+    # input g holds in block j. Past the slot's last page that is the page the
+    # input held a block ago (an unchanged index fetches nothing) or, in the
+    # first block, the slot's first page. Computed here once for all layers
+    # (the same table and lengths: XLA folds the repeats), so an index map
+    # is one SMEM read.
+    pos = jnp.asarray(pos, jnp.int32)
+    last = (pos // page)[:, None]  # [B, 1] the slot's last own page
+    e = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :]
+    e = jnp.minimum(e // G, last // G) * G + e % G
+    e = jnp.clip(jnp.where(e > last, e - G, e), 0, last)
+    walk = jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32), e, axis=1)
+
+    def row(r):  # grid row -> (slot, head block)
+        return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
+
+    def page_spec(g):
+        def index_map(r, j, walk, pos):
+            b, hb = row(r)
+            return walk[b, j * G + g], hb, 0, 0
+
+        return pl.BlockSpec((1, HB, page, D), index_map)
+
+    def qo_map(r, j, walk, pos):
+        return (*row(r), 0, 0, 0)
+
+    qo_spec = pl.BlockSpec((1, 1, HB, rep, D), qo_map)
+    pages = [page_spec(g) for g in range(G)]
+    in_specs = [qo_spec] + pages + pages
+    operands = [q.reshape(B, nhb, HB, rep, D)] + [k_pool] * G + [v_pool] * G
+    if quantized:
+        # per key column of each page block: [B, n_blk, 2, nhb, HB, 1, GP];
+        # blocks past the slot's last keep its index, so nothing is fetched
+        st = jnp.asarray(scales, jnp.float32)[block_tables]  # [B, n, KV, 2]
+        st = jnp.pad(st, ((0, 0), (0, n_blk * G - n_pages), (0, 0), (0, 0)))
+        st = jnp.repeat(st, page, axis=1).reshape(B, n_blk, GP, nhb, HB, 2)
+        operands.append(st.transpose(0, 1, 5, 3, 4, 2)[..., None, :])
+
+        def scale_map(r, j, walk, pos):
+            b, hb = row(r)
+            return b, jax.lax.min(j, jax.lax.div(pos[b], GP)), 0, hb, 0, 0, 0
+
+        in_specs.append(pl.BlockSpec((1, 1, 2, 1, HB, 1, GP), scale_map))
     kernel = functools.partial(
-        _paged_kernel, sm_scale=float(scale), page=page, rep=rep,
+        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb,
         quantized=quantized,
     )
-    q4 = q.reshape(B, H, 1, D)
-    pool_spec = pl.BlockSpec(
-        (1, 1, page, D), lambda b, h, j, bt, pos: (bt[b, j], h // rep, 0, 0)
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, D), lambda b, h, j, bt, pos: (b, h, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [q4, k_pool, v_pool]
-    if quantized:
-        # the scale row rides the block-table gather: trailing (KV, 2)
-        # block == the array's own trailing dims, Mosaic-legal for any KV
-        in_specs.append(pl.BlockSpec(
-            (1, KV, 2), lambda b, h, j, bt, pos: (bt[b, j], 0, 0)
-        ))
-        operands.append(jnp.asarray(scales, jnp.float32))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block table + per-slot positions
-            grid=(B, H, n_pages),
+            num_scalar_prefetch=2,  # walked table + per-slot positions
+            grid=(B * nhb, n_blk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, j, bt, pos: (b, h, 0, 0)),
+            out_specs=qo_spec,
             scratch_shapes=[
-                pltpu.SMEM((1,), jnp.float32),  # running max
-                pltpu.SMEM((1,), jnp.float32),  # running denominator
-                pltpu.VMEM((1, D), jnp.float32),  # output accumulator
+                pltpu.VMEM((HB, rep, 1), jnp.float32),  # running max
+                pltpu.VMEM((HB, rep, 1), jnp.float32),  # running denominator
+                pltpu.VMEM((HB, rep, D), jnp.float32),  # output accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nhb, HB, rep, D), q.dtype),
         interpret=interpret,
-    )(
-        jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(pos, jnp.int32),
-        *operands,
-    )
+    )(walk, pos, *operands)
     return out.reshape(B, H, D)
 
 
@@ -384,30 +462,39 @@ def paged_multitoken_attention(
     return jnp.swapaxes(out, 1, 2)  # [B, T, H, D]
 
 
-def paged_decode_attention_ok(page: int, D: int, itemsize: int = 2) -> bool:
-    """Trace-time gate for the paged kernel: TPU backend, lane-friendly head
-    dim, sublane-aligned page length, and one page's K+V fitting VMEM (per-
-    program cost is pool/B/H independent — that's the point of paging)."""
-    from .flash_attention import VMEM_RESIDENT_BYTES
-
+def _paged_page_ok(page: int, D: int, itemsize: int) -> bool:
+    """What both paged kernels ask of a page: TPU backend, lane-friendly head
+    dim, sublane-aligned page length."""
     sublane = max(1, 32 // max(1, itemsize))
     return (
         jax.default_backend() == "tpu"
         and D % 64 == 0
         and page % sublane == 0
-        and 2 * page * D * itemsize <= VMEM_RESIDENT_BYTES
+    )
+
+
+def paged_decode_attention_ok(
+    KV: int, page: int, D: int, itemsize: int = 2
+) -> bool:
+    """Trace-time gate for the paged decode kernel: the page rule, and a
+    block :func:`paged_decode_blocks` can place in VMEM (``KV`` is the pool's
+    own head count: a tensor-parallel shard passes its ``KV / tp``)."""
+    return (
+        _paged_page_ok(page, D, itemsize)
+        and paged_decode_blocks(KV, page, D, itemsize) is not None
     )
 
 
 def paged_multitoken_attention_ok(
     page: int, D: int, T: int, itemsize: int = 2
 ) -> bool:
-    """Gate for the multitoken paged kernel: the single-token gate plus the
-    [T, D] query/accumulator slabs staying VMEM-resident."""
+    """Gate for the multitoken paged kernel: the page rule plus one head's
+    K+V page and the [T, D] query/accumulator slabs staying VMEM-resident
+    (its per-program cost is pool/B/H independent)."""
     from .flash_attention import VMEM_RESIDENT_BYTES
 
     return (
-        paged_decode_attention_ok(page, D, itemsize)
+        _paged_page_ok(page, D, itemsize)
         and (2 * page * D * itemsize + T * D * (itemsize + 4)
              <= VMEM_RESIDENT_BYTES)
     )

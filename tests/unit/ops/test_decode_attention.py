@@ -387,3 +387,197 @@ class TestQuantizedPagedAttention:
             paged_cached_attention(
                 q, kf, vf, bt, pos, impl="jnp", scales=scales
             )
+
+
+class TestPagedDecodeServedShape:
+    """ISSUE 25: the paged decode kernel takes all kv-heads and a block of
+    ``G`` pages to a grid step and stops at the slot's own last page. Cases
+    at the benchmark's served shape (25 heads of 64, page 16, table 64 wide,
+    8 slots) against the jnp fallback, with every page the slots do not own
+    — the scratch page behind padded table entries included — set to NaN:
+    a kernel that reads past a slot's length shows it."""
+
+    PAGE = 16
+
+    def _pool(self, pos, KV, D, page, n, dtype, seed):
+        """Pools, a table whose entries past ``pos // page`` name scratch
+        page 0, and the same pools with every page no slot owns poisoned."""
+        rs = np.random.RandomState(seed)
+        owned = [int(p) // page + 1 for p in pos]
+        P = sum(owned) + 1
+        ids = rs.permutation(np.arange(1, P))
+        bt = np.zeros((len(pos), n), np.int32)
+        at = 0
+        for b, k in enumerate(owned):
+            bt[b, :k] = ids[at:at + k]
+            at += k
+        kp = rs.randn(P, KV, page, D).astype(np.float32)
+        vp = rs.randn(P, KV, page, D).astype(np.float32)
+        return (jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+                jnp.asarray(bt), jnp.asarray(pos, jnp.int32))
+
+    @staticmethod
+    def _poison(pool):
+        return pool.at[0].set(jnp.nan)
+
+    def _check(self, pos, H=25, KV=25, D=64, page=16, n=64, dtype=jnp.float32,
+               idle=(), tol=2e-5, seed=0):
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+        )
+
+        kp, vp, bt, pos = self._pool(pos, KV, D, page, n, dtype, seed)
+        # an idle slot sits on the scratch page with pos 0, as the scheduler
+        # leaves it; what it computes is never read
+        for b in idle:
+            bt = bt.at[b].set(0)
+        rs = np.random.RandomState(seed + 1)
+        q = jnp.asarray(rs.randn(len(pos), H, D), dtype)
+        out = paged_decode_attention(
+            q, self._poison(kp), self._poison(vp), bt, pos, interpret=True
+        )
+        ref = paged_cached_attention(q, kp, vp, bt, pos, impl="jnp")
+        live = np.array([b not in idle for b in range(len(pos))])
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[live], np.asarray(ref, np.float32)[live],
+            atol=tol, rtol=tol,
+        )
+
+    def _gp(self, itemsize=4, KV=25, D=64, page=16, n=64):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_blocks,
+        )
+
+        return paged_decode_blocks(KV, page, D, itemsize, n)[1] * page
+
+    @pytest.mark.parametrize("case", [
+        "page_edges", "block_edges", "idle_slots", "one_full_seven_short",
+    ])
+    def test_lengths_at_served_shape(self, case):
+        gp, page = self._gp(), self.PAGE
+        pos, idle = {
+            # the last row of a page, the first of the next
+            "page_edges": ([page - 1, page, 2 * page - 1, 2 * page, 1, 0,
+                            5 * page - 1, 5 * page], ()),
+            # the last row of a page block, the first of the next
+            "block_edges": ([gp - 1, gp, 2 * gp - 1, 2 * gp, gp + page,
+                             3 * gp - 1, 3 * gp, gp - page], ()),
+            "idle_slots": ([0, 200, 0, 37, 0, 0, 411, 0], (0, 2, 4, 5, 7)),
+            "one_full_seven_short": ([1023, 3, 20, 15, 7, 33, 1, 12], ()),
+        }[case]
+        self._check(pos, idle=idle)
+
+    def test_bf16_pool_at_served_shape(self):
+        self._check([1023, 130, 0, 511, 16, 700, 64, 255], dtype=jnp.bfloat16,
+                    tol=2e-2, idle=(2,))
+
+    @pytest.mark.parametrize("rep", [2, 5])
+    def test_gqa_groups_read_one_pool_column(self, rep):
+        self._check([0, 17, 255, 256, 40, 1023, 100, 31], H=5 * rep, KV=5,
+                    seed=rep)
+
+    def test_head_dim_128(self):
+        self._check([0, 15, 16, 300, 1023, 64, 63, 500], H=4, KV=4, D=128,
+                    seed=3)
+
+    def test_head_blocks_when_a_page_of_all_heads_does_not_fit(self):
+        """KV=64 heads of a [128, 128] f32 page: 16 heads to a step, four
+        head blocks a slot, one page at a time."""
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_blocks,
+        )
+
+        assert paged_decode_blocks(64, 128, 128, 4, 3) == (16, 1)
+        self._check([5, 300], H=64, KV=64, D=128, page=128, n=3, seed=4)
+
+    @pytest.mark.parametrize("rep", [1, 2])
+    def test_int8_pool_with_scales(self, rep):
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+        )
+        from deepspeed_tpu.ops.quantizer import quantize_kv_pages
+
+        KV, D, page, n = 5, 64, 32, 32
+        kf, vf, bt, pos = self._pool(
+            [0, 31, 32, 1023, 255, 256, 700, 90], KV, D, page, n,
+            jnp.float32, 5,
+        )
+        kq, ks = quantize_kv_pages(kf)
+        vq, vs = quantize_kv_pages(vf)
+        scales = jnp.stack([ks, vs], axis=-1)  # [P, KV, 2]
+        q = jnp.asarray(
+            np.random.RandomState(6).randn(8, KV * rep, D), jnp.float32
+        )
+        # the scratch page's codes cannot be NaN; its scales can
+        out = paged_decode_attention(
+            q, kq, vq, bt, pos, interpret=True, scales=self._poison(scales)
+        )
+        ref = paged_cached_attention(
+            q, kq, vq, bt, pos, impl="jnp", scales=scales
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+        )
+
+
+class TestPagedDecodeBlocks:
+    """The block chooser and the gate: parameters come from the shapes."""
+
+    @pytest.mark.parametrize("shape,want", [
+        # GPT-2-XL's pool, bf16: 25 x 8 tiles of 4 KB, K and V, two buffers
+        ((25, 16, 64, 2, 64), (25, 8)),
+        ((64, 16, 128, 2, 64), (64, 4)),
+        # a tensor-parallel shard of five heads: capped at S_BLOCK keys
+        ((5, 16, 64, 2, 64), (5, 32)),
+        # a narrow table caps the block
+        ((25, 16, 64, 2, 4), (25, 4)),
+        ((25, 16, 64, 2, 3), (25, 2)),
+        # int8 pages of 32
+        ((25, 32, 64, 1, 32), (25, 8)),
+        # a page of all heads does not fit: head blocks, one page a step
+        ((64, 128, 128, 4, 8), (16, 1)),
+        ((25, 256, 128, 4, 4), (5, 1)),
+        # one head's page does not fit
+        ((8, 2048, 256, 4, 2), None),
+    ])
+    def test_blocks_from_shapes(self, shape, want):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_blocks,
+        )
+        from deepspeed_tpu.ops.pallas.flash_attention import (
+            VMEM_RESIDENT_BYTES,
+        )
+
+        got = paged_decode_blocks(*shape)
+        assert got == want
+        if got is not None:
+            KV, page, D, itemsize, _ = shape
+            hb, g = got
+            assert KV % hb == 0
+            sub = 32 // itemsize
+            tile = -(-page // sub) * sub * -(-D // 128) * 128 * itemsize
+            assert 4 * hb * g * tile <= VMEM_RESIDENT_BYTES
+
+    @pytest.mark.parametrize("args,want", [
+        ((25, 16, 64, 2), True),       # XL
+        ((64, 16, 128, 2), True),      # KV 64 x D 128
+        ((5, 16, 64, 2), True),        # XL under tp=5
+        ((25, 32, 64, 1), True),       # int8 pages of 32
+        ((25, 16, 64, 1), False),      # int8 wants pages of 32
+        ((25, 16, 80, 2), False),      # head dim off the lanes
+        ((8, 2048, 256, 4), False),    # one head's page over the budget
+    ])
+    def test_gate_on_tpu(self, monkeypatch, args, want):
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert da.paged_decode_attention_ok(*args) is want
+
+    def test_gate_off_tpu(self):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention_ok,
+        )
+
+        assert not paged_decode_attention_ok(25, 16, 64, 2)
