@@ -26,8 +26,15 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
-# reduced may never name a width (contract): these patterns are refused
-WIDTH_RE = re.compile(r"(_dim|_rank)$|hidden|intermediate|latent|state|proj|head_dim|n_embd|d_model|expan|experts_per")
+# reduced may never name a width (contract): a hidden, intermediate, latent,
+# state or projection size, a key that ends in _dim or _rank, a head size, an
+# expansion factor, a window, the experts per token. A key that counts layers
+# is depth, whatever it counts them of (num_hidden_layers), and is let through.
+WIDTH_RE = re.compile(
+    r"^(?!.*layers?$).*"
+    r"((_dim|_rank)$|hidden|intermediate|latent|state|proj|head_size|n_embd|n_inner|d_model|d_ff|d_kv"
+    r"|expan|experts_per|window)"
+)
 
 
 class ManifestError(ValueError):

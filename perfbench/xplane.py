@@ -43,7 +43,7 @@ class DeviceTrace:
 @dataclass
 class Trace:
     devices: List[DeviceTrace]
-    host_spans: List[Event]          # the benchmark's own TraceAnnotations
+    host_spans: List[Event]          # TraceAnnotations of the benchmark (perfbench.*) and of the program (ds.*)
     plane_names: List[str]
 
 
@@ -143,15 +143,21 @@ def gaps(ops: Sequence[Event], t0: int, t1: int) -> List[Tuple[int, int]]:
 
 
 def attribute_gaps(gap_list, host_spans: Sequence[Event]) -> Dict[str, int]:
-    """Idle nanoseconds by the innermost benchmark span open at each gap's
-    midpoint ('none' where no span was open)."""
+    """Idle nanoseconds by the innermost span open at each gap's midpoint
+    ('none' where no span was open)."""
     out: Dict[str, int] = collections.Counter()
-    for s, e in gap_list:
+    # one pass over gaps and spans in time order: a traced window holds some
+    # 10^5 gaps and, with the program's leaves, some 10^3 spans
+    spans = sorted(host_spans, key=lambda x: x[1])
+    open_spans: List[Event] = []
+    i = 0
+    for s, e in sorted(gap_list, key=lambda g: g[0] + g[1]):
         mid = (s + e) // 2
-        best = None
-        for n, hs, he in host_spans:
-            if hs <= mid < he and (best is None or he - hs < best[1]):
-                best = (n, he - hs)
+        while i < len(spans) and spans[i][1] <= mid:
+            open_spans.append(spans[i])
+            i += 1
+        open_spans = [sp for sp in open_spans if sp[2] > mid]
+        best = min(open_spans, key=lambda sp: sp[2] - sp[1], default=None)
         out[best[0] if best else "none"] += e - s
     return dict(out)
 
@@ -177,7 +183,7 @@ def window_of(trace: Trace, span_name: str = "perfbench.window") -> Tuple[int, i
     return min(starts), max(ends)
 
 
-def load(path: str, span_prefixes: Sequence[str] = ("perfbench.",), device_re: str = r"^/device:TPU:\d+$") -> Trace:
+def load(path: str, span_prefixes: Sequence[str] = ("perfbench.", "ds."), device_re: str = r"^/device:TPU:\d+$") -> Trace:
     """Read the newest ``*.xplane.pb`` under ``path`` (or ``path`` itself)."""
     from jax.profiler import ProfileData
 

@@ -1,10 +1,12 @@
 """BENCHMARK.json as committed is inside the contract's limits, the validator
-refuses what the contract refuses, and a cell, a configuration, a traffic mix
-and a metric with a new reader are added without editing a file that is there."""
+refuses what the contract refuses, and a cell, a configuration (of any
+architecture), a runner, a traffic mix and a metric with a new reader are
+added without editing a file that is there."""
 
 import copy
 import json
 import os
+import shutil
 
 import pytest
 
@@ -20,24 +22,48 @@ def real():
     return Manifest(REPO)
 
 
+# the cells in the order they were proved on the chip (PRs 23 and 26); a later PR's come after them
+PROVED = ["train-xl-l16-1chip", "serve-xl-chat-open", "serve-xl-doc-batch", "train-xl-dp4", "serve-xl-chat-loaded"]
+GPT2_XL = {"n_embd": 1600, "n_head": 25, "vocab_size": 50257, "n_positions": 1024}
+GPT2_XL_CUT = {"n_layer": 48, "attn_pdrop": 0.1, "embd_pdrop": 0.1, "resid_pdrop": 0.1}   # what `reduced` has named so far
+
+
+def check_manifest_and_configs(m):
+    """What holds of a committed benchmark, whatever its configurations are:
+    each file carries its source's value of every key it names in ``reduced``
+    (``published``), and a key differs from that value if and only if it is
+    in ``reduced``."""
+    m.validate()
+    assert os.path.getsize(os.path.join(m.root, "BENCHMARK.json")) < 64 * 1024
+    for c in m.doc["configs"]:
+        cfg = m.config(c["name"])
+        published = cfg.get("published", {})
+        assert set(c["reduced"]) <= set(published), (c["name"], "a reduced key without its published value")
+        for k, v in published.items():
+            assert (cfg[k] != v) == (k in c["reduced"]), (c["name"], k)
+        if cfg.get("model_type") == "gpt2":
+            assert {k: cfg[k] for k in GPT2_XL} == GPT2_XL, c["name"]
+            assert GPT2_XL_CUT.items() <= published.items(), c["name"]
+    for w in m.doc["workloads"]:
+        assert isinstance(m.traffic(w["traffic"])["loop"], str)
+
+
+def check_order(m):
+    """The cells that are there keep their relative order, and new ones come
+    after them."""
+    names = [w["name"] for w in m.doc["workloads"]]
+    known = [n for n in names if n in PROVED]
+    assert known == [n for n in PROVED if n in names]
+    assert names[: len(known)] == known
+    assert sum(1 for w in m.doc["workloads"] if w["chips"] == 4) <= max(1, len(names) // 4)
+
+
 def test_committed_manifest_validates_and_every_name_finds_its_file(real):
-    real.validate()
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
-    for c in real.doc["configs"]:
-        cfg = real.config(c["name"])
-        assert cfg["n_embd"] == 1600 and cfg["n_head"] == 25 and cfg["vocab_size"] == 50257 and cfg["n_positions"] == 1024
-        for k in ("n_layer", "attn_pdrop", "embd_pdrop", "resid_pdrop"):
-            published = {"n_layer": 48, "attn_pdrop": 0.1, "embd_pdrop": 0.1, "resid_pdrop": 0.1}[k]
-            assert (cfg[k] != published) == (k in c["reduced"]), (c["name"], k)
-    for w in real.doc["workloads"]:
-        assert real.traffic(w["traffic"])["loop"] in ("open", "backlog", "train_steps")
+    check_manifest_and_configs(real)
 
 
 def test_cells_are_in_the_order_they_were_proved_with_one_on_four_chips(real):
-    names = [w["name"] for w in real.doc["workloads"]]
-    order = ["train-xl-l16-1chip", "serve-xl-chat-open", "serve-xl-doc-batch", "train-xl-dp4"]
-    assert names == [n for n in order if n in names]
-    assert sum(1 for w in real.doc["workloads"] if w["chips"] == 4) <= 1
+    check_order(real)
 
 
 def test_every_cell_reports_setup_another_end_to_end_metric_and_a_layer_metric(real):
@@ -63,9 +89,10 @@ BREAKS = {
     "bound_over_a_tenth": lambda d: d["end_to_end"][0].update(bound=0.2),
     "moves_a_metric_the_cell_lacks": lambda d: d["per_layer"][0].update(moves="serve_tok_s"),
     "moves_nothing_known": lambda d: d["per_layer"][0].update(moves="nope"),
-    "two_four_chip_cells": lambda d: [w.update(chips=4) for w in d["workloads"][:2]],
+    "two_four_chip_cells": lambda d: [w.update(chips=4) for w in d["workloads"][: max(1, len(d["workloads"]) // 4) + 1]],
     "reduced_names_a_width": lambda d: d["configs"][0].update(reduced=["n_embd"]),
     "reduced_names_a_head_dim": lambda d: d["configs"][0].update(reduced=["head_dim"]),
+    "reduced_names_a_window": lambda d: d["configs"][0].update(reduced=["sliding_window"]),
     "extra_key_on_a_metric": lambda d: d["per_layer"][0].update(why="because"),
     "extra_top_level_key": lambda d: d.update(notes="x"),
     "no_setup_s": lambda d: d.update(end_to_end=[m for m in d["end_to_end"] if m["name"] != "setup_s"]),
@@ -89,16 +116,129 @@ def test_validator_refuses(real, case):
         _broken(real, BREAKS[case]).validate(check_files=False)
 
 
+# `reduced` takes depth and counts of things, in any architecture's spelling, and no width
+DEPTHS = ["num_hidden_layers", "n_layer", "num_layers", "first_k_dense_replace", "max_position_embeddings",
+          "num_experts", "n_routed_experts"]
+WIDTHS = ["hidden_size", "intermediate_size", "moe_intermediate_size", "head_dim", "kv_lora_rank",
+          "num_experts_per_tok", "n_embd", "d_model", "ssm_state_size", "sliding_window"]
+
+
+@pytest.mark.parametrize("key", DEPTHS)
+def test_reduced_may_name_a_depth_or_a_count(real, key):
+    _broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
+
+
+@pytest.mark.parametrize("key", WIDTHS)
+def test_reduced_may_not_name_a_width(real, key):
+    with pytest.raises(ManifestError, match="a width"):
+        _broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
+
+
+def test_a_table_of_eight_cells_takes_two_on_four_chips_and_not_three(real):
+    def grow(d):
+        first = d["workloads"][0]
+        for i in range(8 - len(d["workloads"])):
+            d["workloads"].append(dict(first, name=f"extra-{i}", traffic=f"extra-mix-{i}"))
+            for m in d["end_to_end"] + d["per_layer"]:
+                if first["name"] in m.get("workloads", ()):
+                    m["workloads"].append(f"extra-{i}")
+        first.update(chips=4)
+
+    eight = _broken(real, grow)
+    assert len(eight.doc["workloads"]) == 8 and sum(w["chips"] == 4 for w in eight.doc["workloads"]) == 2
+    eight.validate(check_files=False)
+    with pytest.raises(ManifestError, match="four-chip"):
+        _broken(eight, BREAKS["two_four_chip_cells"]).validate(check_files=False)
+
+
+def _files(root):
+    out = {}
+    for dirpath, dirs, files in os.walk(root):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for f in files:
+            p = os.path.join(dirpath, f)
+            out[os.path.relpath(p, root)] = open(p, "rb").read()
+    return out
+
+
+def _is_grown(old, new):
+    """``new`` is ``old`` with entries appended: to lists, at any depth."""
+    if isinstance(old, list):
+        return isinstance(new, list) and len(new) >= len(old) and all(_is_grown(a, b) for a, b in zip(old, new))
+    if isinstance(old, dict):
+        return isinstance(new, dict) and set(new) == set(old) and all(_is_grown(v, new[k]) for k, v in old.items())
+    return old == new
+
+
+@pytest.mark.parametrize("with_stand_in", [False, True])
+def test_a_new_architecture_goes_in_as_new_files_and_appended_entries(tmp_path, with_stand_in):
+    """What the next ``model_config`` PR does, on a copy of the *committed*
+    benchmark: a configuration of another ``model_type`` with Hugging Face's
+    keys and its depth cut, a runner of its own, a cell, one per-layer metric.
+    The copy validates, the checks of the committed manifest and of the order
+    hold of it, the tiny copy builds from it (with the cell if the PR brought
+    a stand-in for its runner, without it if not), and no file that was there
+    differs."""
+    root = str(tmp_path / "committed")
+    for sub in ("perfbench", os.path.join("tests", "perfbench")):
+        shutil.copytree(os.path.join(REPO, sub), os.path.join(root, sub), ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    before = _files(root)
+
+    def write(rel, text):
+        p = os.path.join(root, rel)
+        assert not os.path.exists(p)
+        os.makedirs(os.path.dirname(p), exist_ok=True)
+        with open(p, "w") as f:
+            f.write(text)
+
+    write("perfbench/configs/probe-moe-serve-1chip.json", json.dumps({
+        "model_type": "olmoe", "hidden_size": 2048, "intermediate_size": 1024, "num_hidden_layers": 10,
+        "num_attention_heads": 16, "num_experts": 64, "num_experts_per_tok": 8, "vocab_size": 50304,
+        "max_position_embeddings": 4096, "published": {"num_hidden_layers": 16}, "runner": "probe_runner",
+        "dtype": "bfloat16"}))
+    write("perfbench/runners/probe_runner.py",
+          "class Runner:\n    def __init__(self, ctx, seed, devices, span, log):\n        self.ctx = ctx\n")
+    write("perfbench/metrics/probe_load_share.json", json.dumps({"reader": "probe_load_share", "args": {}}))
+    write("perfbench/metrics/readers/probe_load_share.py", "def read(ctx):\n    return None\n")
+    if with_stand_in:
+        write("tests/perfbench/stand_ins/probe_runner.json", json.dumps({
+            "config": tiny.serve_config(runner="probe_runner"), "traffic": {"backlog": tiny.BACKLOG}}))
+    doc = json.loads(before["BENCHMARK.json"])
+    doc["configs"].append({"name": "probe-moe-serve-1chip", "source": "https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct",
+                           "file": "perfbench/configs/probe-moe-serve-1chip.json", "reduced": ["num_hidden_layers"],
+                           "why": "tests"})
+    doc["workloads"].append({"name": "serve-probe-doc", "config": "probe-moe-serve-1chip", "traffic": "doc-backlog",
+                             "chips": 1, "why": "tests"})
+    for e in doc["end_to_end"]:
+        if e["name"] == "serve_tok_s":
+            e["workloads"].append("serve-probe-doc")
+    doc["per_layer"].append({"name": "probe_load_share", "unit": "%", "better": "higher", "source": "program_counter",
+                             "layer": "tests", "moves": "serve_tok_s", "workloads": ["serve-probe-doc"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+
+    m = Manifest(root)
+    check_manifest_and_configs(m)
+    check_order(m)
+    t = tiny.make(tmp_path / "tiny", repo=root)
+    t.validate()
+    cells = [w["name"] for w in t.doc["workloads"]]
+    metrics = [x["name"] for x in t.doc["per_layer"]]
+    assert ("serve-probe-doc" in cells) == with_stand_in == ("probe_load_share" in metrics)
+    assert cells[:3] == ["train-xl-l16-1chip", "serve-xl-chat-open", "serve-xl-doc-batch"]
+    after = _files(root)
+    assert _is_grown(json.loads(before.pop("BENCHMARK.json")), json.loads(after["BENCHMARK.json"]))
+    for rel, data in before.items():
+        assert after[rel] == data, f"{rel} was edited"
+
+
 def test_discovery_needs_no_edit_to_a_file_that_is_there(tmp_path):
     """One of each is added to a temporary copy: files are written, entries are
     appended to BENCHMARK.json, nothing that exists is touched; the harness
     validates the copy and runs the new cell with the new reader."""
     m = tiny.make(tmp_path)
-    before = {}
-    for dirpath, _, files in os.walk(m.bench_dir):
-        for f in files:
-            p = os.path.join(dirpath, f)
-            before[p] = open(p, "rb").read()
+    before = _files(m.bench_dir)
 
     def write(rel, text):
         p = os.path.join(m.root, rel)
@@ -137,5 +277,6 @@ def test_discovery_needs_no_edit_to_a_file_that_is_there(tmp_path):
     assert out["correct"] and out["attempted"] == 6
     assert out["metrics"]["slots_times_requests"] == {"value": 2.0 * 2 * 6, "unit": "1"}
     assert "absent_span" not in out["metrics"]   # a reader that finds nothing returns nothing
-    for p, data in before.items():
-        assert open(p, "rb").read() == data, f"{p} was edited"
+    after = _files(m.bench_dir)
+    for rel, data in before.items():
+        assert after[rel] == data, f"{rel} was edited"

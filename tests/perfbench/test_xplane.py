@@ -32,6 +32,23 @@ def test_gaps_go_to_the_innermost_open_span():
     spans = [("perfbench.window", 0, 100), ("perfbench.srv.step", 10, 40), ("perfbench.srv.step", 50, 60)]
     got = xplane.attribute_gaps([(12, 20), (44, 48), (52, 54), (150, 160)], spans)
     assert got == {"perfbench.srv.step": 10, "perfbench.window": 4, "none": 10}
+    # neither the gaps nor the spans need come in time order
+    assert xplane.attribute_gaps([(150, 160), (52, 54), (12, 20), (44, 48)], spans[::-1]) == got
+
+
+def test_gaps_name_the_programs_leaf_where_the_trace_holds_one():
+    """With the program's spans beside the runner's (the default of ``load``),
+    ``breakdown.idle_gaps`` names what the host was doing inside a step."""
+    spans = [("perfbench.window", 0, 100), ("perfbench.srv.step", 10, 40), ("ds.serve.step", 11, 39),
+             ("ds.serve.decode.dispatch", 12, 15), ("ds.serve.decode.wait", 15, 35), ("ds.serve.emit", 35, 38)]
+    ops = [("a", 0, 13), ("b", 14, 16), ("c", 30, 36), ("d", 38, 100)]
+    trace = xplane.Trace([xplane.DeviceTrace("/device:TPU:0", ops=ops)], spans, [])
+    r = xplane.reduce(trace)
+    assert {k: round(v * 1e9) for k, v in r.idle_by_span.items()} == {
+        "ds.serve.decode.dispatch": 1, "ds.serve.decode.wait": 14, "ds.serve.emit": 2}
+    assert xplane.breakdown(r)["idle_gaps"][0] == ["sum:ds.serve.decode.wait", pytest.approx(14e-9)]
+    only_runner = xplane.Trace(trace.devices, [s for s in spans if s[0].startswith("perfbench.")], [])
+    assert set(xplane.reduce(only_runner).idle_by_span) == {"perfbench.srv.step"}
 
 
 @pytest.mark.parametrize("name,cat", [
@@ -82,6 +99,26 @@ def test_recorded_trace_reduces_to_busy_under_the_window(recorded):
     assert len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
     assert b["device_ops"][0][0] == "fusion-elementwise:fusion"
     assert all(k.startswith(("sum:", "longest:")) for k, _ in b["idle_gaps"])
+
+
+def test_load_keeps_the_runners_and_the_programs_spans_by_default(tmp_path):
+    """A trace recorded here (host planes only: the CPU has no device plane)
+    with a span of each: ``load`` keeps ``perfbench.*`` and ``ds.*`` and drops
+    the rest. The fixture from the chip holds no ``ds.*`` span (it was recorded
+    around a bare program), so it reads the same under either set."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("perfbench.window"):
+        with jax.profiler.TraceAnnotation("ds.serve.decode.wait"):
+            with jax.profiler.TraceAnnotation("someone.elses.span"):
+                jnp.ones((8,)).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    names = {n for n, _, _ in xplane.load(str(tmp_path)).host_spans}
+    assert names == {"perfbench.window", "ds.serve.decode.wait"}
+    assert {n for n, _, _ in xplane.load(str(tmp_path), span_prefixes=("perfbench.",)).host_spans} == {"perfbench.window"}
+    assert xplane.load(DATA).host_spans == xplane.load(DATA, span_prefixes=("perfbench.",)).host_spans
 
 
 def test_window_falls_back_to_the_device_ops_extent():

@@ -1,10 +1,19 @@
 """A tiny copy of the benchmark for the CPU tests: the harness's own files
 (copied, not edited) with ``gpt2-tiny`` configurations and traffic that fits a
 second. ``make(tmp)`` writes a checkout-shaped directory and returns its
-manifest."""
+manifest.
+
+A real cell's stand-in is found from what the cell is: its configuration's
+``runner`` and its traffic's ``loop``. The two runners that are there have
+theirs below; a PR that brings a runner brings
+``tests/perfbench/stand_ins/<runner>.json`` (``{"config": {...}, "traffic":
+{"<loop>": {...}}}``) as a new file. A cell with no stand-in, or whose stand-in
+pair another cell already took, is left out of the copy and of every metric's
+``workloads`` list."""
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import shutil
@@ -54,43 +63,58 @@ BACKLOG = {"loop": "backlog", "block_requests": 8, "block_s": 1, "queue_depth": 
 TRAIN = {"loop": "train_steps"}
 
 
-def make(tmp: str):
+def stand_ins(repo: str = REPO) -> dict:
+    """runner -> {"config": (name, configuration), "traffic": {loop: (name, mix)}}"""
+    out = {
+        "serve": {"config": ("tiny-serve", serve_config()),
+                  "traffic": {"open": ("tiny-open", OPEN), "backlog": ("tiny-backlog", BACKLOG)}},
+        "train": {"config": ("tiny-train", train_config()), "traffic": {"train_steps": ("train-steady", TRAIN)}},
+    }
+    for path in sorted(glob.glob(os.path.join(repo, "tests", "perfbench", "stand_ins", "*.json"))):
+        runner = os.path.splitext(os.path.basename(path))[0]
+        with open(path) as f:
+            doc = json.load(f)
+        out[runner] = {"config": (f"tiny-{runner}", doc["config"]),
+                       "traffic": {loop: (f"tiny-{runner}-{loop}", mix) for loop, mix in doc["traffic"].items()}}
+    return out
+
+
+def make(tmp: str, repo: str = REPO):
+    """``repo`` is the checkout whose benchmark is copied: this one, or a copy
+    of it that a test has added a cell to."""
     from perfbench.manifest import Manifest
 
     root = os.path.join(str(tmp), "checkout")
-    shutil.copytree(os.path.join(REPO, "perfbench"), os.path.join(root, "perfbench"),
+    shutil.copytree(os.path.join(repo, "perfbench"), os.path.join(root, "perfbench"),
                     ignore=shutil.ignore_patterns("__pycache__", "configs", "traffic"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bm = json.load(f)
-    files = {
-        "perfbench/configs/tiny-serve.json": serve_config(),
-        "perfbench/configs/tiny-train.json": train_config(),
-        "perfbench/traffic/tiny-open.json": OPEN,
-        "perfbench/traffic/tiny-backlog.json": BACKLOG,
-        "perfbench/traffic/train-steady.json": TRAIN,
-    }
-    for rel, doc in files.items():
-        os.makedirs(os.path.dirname(os.path.join(root, rel)), exist_ok=True)
-        with open(os.path.join(root, rel), "w") as f:
-            json.dump(doc, f)
-    # the real cells' names, so that every metric's `workloads` list still holds
-    kinds = {"train-xl-l16-1chip": ("tiny-train", "train-steady"), "train-xl-dp4": ("tiny-train", "train-steady"),
-             "serve-xl-chat-open": ("tiny-serve", "tiny-open"), "serve-xl-doc-batch": ("tiny-serve", "tiny-backlog")}
-    bm["configs"] = [
-        {"name": "tiny-serve", "source": "tests", "file": "perfbench/configs/tiny-serve.json", "reduced": [], "why": "tests"},
-        {"name": "tiny-train", "source": "tests", "file": "perfbench/configs/tiny-train.json", "reduced": [], "why": "tests"},
-    ]
-    cells = []
+    real = Manifest(repo)
+    bm = real.doc   # this function's own copy: edited below and written to the tiny checkout
+    kinds = stand_ins(repo)
+    cells, pairs = [], set()
     for w in bm["workloads"]:
-        if w["name"] == "train-xl-dp4":
+        kind = kinds.get(real.config(w["config"])["runner"], {"traffic": {}})
+        mix = kind["traffic"].get(real.traffic(w["traffic"])["loop"])
+        if mix is None:
+            continue   # no stand-in for this runner under this loop
+        (c, cfg), (t, tr) = kind["config"], mix
+        if (c, t) in pairs:
             continue   # one cell per pair of configuration and traffic
-        c, t = kinds[w["name"]]
+        pairs.add((c, t))
+        for rel, doc in ((f"perfbench/configs/{c}.json", cfg), (f"perfbench/traffic/{t}.json", tr)):
+            os.makedirs(os.path.dirname(os.path.join(root, rel)), exist_ok=True)
+            with open(os.path.join(root, rel), "w") as f:
+                json.dump(doc, f)
+        # the real cell's name, so that every metric's `workloads` list still holds
         cells.append({"name": w["name"], "config": c, "traffic": t, "chips": 1, "why": "tests"})
+    bm["configs"] = [{"name": c, "source": "tests", "file": f"perfbench/configs/{c}.json", "reduced": [], "why": "tests"}
+                     for c in sorted({c for c, _ in pairs})]
     bm["workloads"] = cells
-    for m in bm["end_to_end"] + bm["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [w for w in m["workloads"] if w != "train-xl-dp4"]
-    bm["per_layer"] = [m for m in bm["per_layer"] if m.get("workloads", True)]
+    kept = {w["name"] for w in cells}
+    for group in ("end_to_end", "per_layer"):
+        for m in bm[group]:
+            if "workloads" in m:
+                m["workloads"] = [w for w in m["workloads"] if w in kept]
+        bm[group] = [m for m in bm[group] if m.get("workloads", True)]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return Manifest(root)
