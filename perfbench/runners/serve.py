@@ -184,6 +184,20 @@ class Runner:
                 self._submit(a, now)
             self._step()
         t1 = t_open + seconds
+        # A prompt whose prefill straddles an edge counts in proportion
+        # (arith.tokens_in_window), which takes its first-token stamp: the
+        # prefills the close caught in flight are stepped to their end, outside
+        # the window, with nothing new submitted (a chunk a step, so the
+        # longest prompt's chunks bound it). Without this the close credits
+        # such a prompt nothing, and runs that close just before and just
+        # after a first token read a whole prompt apart (0.65% of the window
+        # at 512 tokens; PERF.md, PR 28).
+        longest = int(self.sv["max_prompt_len"])
+        for _ in range(2 * -(-longest // int(self.sv.get("prefill_chunk_tokens") or longest)) + 2):
+            if not any(r.t_admit is not None and r.t_admit < t1 and r.t_first_token is None and not r.done
+                       for r, _, _ in self.live):
+                break
+            self._step()
         for r, _, _ in self.done + self.live:
             self.counted[id(r)] = r.done and r.t_finish is not None and t_open <= r.t_finish < t1
 

@@ -6,6 +6,7 @@ import json
 import os
 import subprocess
 import sys
+from types import SimpleNamespace
 
 import pytest
 
@@ -96,6 +97,92 @@ def test_backlog_keeps_the_slots_busy_and_counts_tokens_inside_only(results):
     everything = arith.tokens_in_window(ctx0.recs, t0 - 100, t1 + 100)
     assert 0 < inside < everything
     assert out0["metrics"]["serve_tok_s"]["value"] == pytest.approx(inside / (t1 - t0))
+
+
+class _FakeServer:
+    """A scheduler on a clock the test owns: ``slots`` requests at a time, each
+    prefills for ``prefill_steps`` steps and then emits a token a step; a step
+    takes ``step_s``. Stamps as the program puts them."""
+
+    def __init__(self, now, slots, prefill_steps, step_s):
+        self.now, self.n_slots, self.prefill_steps, self.step_s = now, slots, prefill_steps, step_s
+        self.queue, self.running = [], []
+
+    @property
+    def slots(self):
+        return [SimpleNamespace(request=r) for r in self.running]
+
+    def submit(self, ids, max_new_tokens, seed):
+        r = SimpleNamespace(prompt=ids, prompt_len=len(ids), tokens=[], t_emissions=[], t_submit=self.now[0], t_admit=None,
+                            t_first_token=None, t_finish=None, status="queued", done=False, want=max_new_tokens, left=0)
+        self.queue.append(r)
+        return r
+
+    def step(self):
+        while self.queue and len(self.running) < self.n_slots:
+            r = self.queue.pop(0)
+            r.t_admit, r.left = self.now[0], self.prefill_steps
+            self.running.append(r)
+        self.now[0] += self.step_s
+        for r in list(self.running):
+            if r.left > 1:
+                r.left -= 1
+                continue
+            r.left = 0
+            r.tokens.append(0)
+            r.t_emissions.append(self.now[0])
+            r.t_first_token = r.t_first_token or self.now[0]
+            if len(r.tokens) == r.want:
+                r.done, r.status, r.t_finish = True, "finished", self.now[0]
+                self.running.remove(r)
+
+
+def _fake_backlog_run(manifest, monkeypatch, step_s, seconds):
+    import contextlib
+
+    from perfbench import arith
+    from perfbench.context import Context
+    from perfbench.runners import serve
+
+    now = [100.0]
+    monkeypatch.setattr(serve, "clock", lambda: now[0])
+    cfg = dict(manifest.config("tiny-serve"))
+    cfg["serving"] = dict(cfg["serving"], max_slots=1)
+    tr = dict(manifest.traffic("tiny-backlog"), ramp={"seconds": 0.0, "aged": False}, queue_depth=1)
+    ctx = Context(cell={}, config=cfg, traffic=tr, chips=1, peak=None)
+    r = serve.Runner(ctx, 1, [], lambda name: contextlib.nullcontext(), lambda msg: None)
+    r.mcfg = serve.model_config(cfg)
+    r.srv = _FakeServer(now, slots=1, prefill_steps=5, step_s=step_s)   # 5 + 7 steps a request
+    r._backlog(seconds, type("T", (), {"tick": lambda self, rel, s: None})())
+    reqs = [q for q, _, _ in r.done + r.live]
+    recs = [arith.Rec(due=0, prompt_len=q.prompt_len, new_tokens=q.want, t_submit=q.t_submit, t_admit=q.t_admit,
+                      t_first_token=q.t_first_token, t_emissions=q.t_emissions) for q in reqs]
+    return ctx.window, recs, now[0]
+
+
+def test_a_prefill_the_close_catches_in_flight_is_counted_in_proportion(manifest, monkeypatch):
+    """Two runs 0.3% apart in speed: the third request's first token comes at
+    2.9 s in one and at 2.909 s in the other, and the window closes at 2.905 s
+    between them. The caught prefill is stepped to its end, outside the
+    window, and credited by the share inside; before PR 28 the slower run got
+    nothing for it and the two read a whole prompt apart."""
+    from perfbench import arith
+
+    (a0, a1), fast, _ = _fake_backlog_run(manifest, monkeypatch, 0.1, 2.905)
+    (b0, b1), slow, end = _fake_backlog_run(manifest, monkeypatch, 0.1003, 2.905)
+    assert not [x for x in fast if x.t_admit is not None and x.t_admit < a1 and (x.t_first_token is None or x.t_first_token >= a1)]
+    caught = [x for x in slow if x.t_admit is not None and x.t_admit < b1 and (x.t_first_token is None or x.t_first_token >= b1)]
+    assert len(caught) == 1 and caught[0].t_first_token is not None      # stepped to its first token, after the close
+    assert end < b1 + 2 * 0.1003                                           # the step that ends it, not a drain
+    share = (b1 - caught[0].t_admit) / (caught[0].t_first_token - caught[0].t_admit)
+    assert 0.98 < share < 1.0
+    got = arith.tokens_in_window(slow, b0, b1)
+    whole = [x for x in slow if x.t_first_token is not None and x.t_first_token < b1]
+    assert got == pytest.approx(sum(x.prompt_len + sum(1 for t in x.t_emissions if t < b1) for x in whole)
+                                + share * caught[0].prompt_len)
+    assert got == pytest.approx(arith.tokens_in_window(fast, a0, a1), rel=0.01)
+    caught[0].t_first_token = None       # as the run stood at the close before PR 28
+    assert arith.tokens_in_window(slow, b0, b1) < 0.85 * got
 
 
 def test_training_counts_whole_steps_and_checks_the_reference(results):
