@@ -40,10 +40,10 @@ import time
 # layers is 574M parameters, 10.3 GB of weights + gradients + Adam state at
 # 18 B/param, which leaves activations (micro batch 4, remat) room in 16 GB
 # at dp=1. The SERVED model is all 48 layers (3.1 GB of bf16 weights) beside a
-# 512-page pool (4.9 MB per 16-token page, 2.5 GB). Not 1024 pages: with 64-wide
-# heads the pool's device layout is not row-major, and the chunk-prefill
-# program re-lays both pools out around its page writes and kernel calls, an
-# HLO temp of 6x one pool (PERF.md, Bring-up) that leaves 16 GB at ~700 pages.
+# 512-page pool (4.9 MB per 16-token page, 2.5 GB of values; 5.0 GB on the
+# device, which keeps it row-major and so pads a 64-wide head to its 128
+# lanes: serving/kv_cache.py::pool_stored_shape). The benchmark's served
+# cells hold the same 512 pages.
 FULL_SIZES = {
     "model": "gpt2-xl",
     "train_layers": 16,

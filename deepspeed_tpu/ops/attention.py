@@ -132,14 +132,17 @@ def gather_pool_pages(k_pool, v_pool, block_tables, scales=None):
 
 def paged_cached_attention(
     q, k_pool, v_pool, block_tables, pos, impl: str = "auto",
-    sm_scale: Optional[float] = None, scales=None,
+    sm_scale: Optional[float] = None, scales=None, layer=None,
 ):
     """Single-token decode attention against a PAGED KV cache (the serving
     subsystem's layout): q [B,H,D], pools [P,KV,page,D] (KV == H or
     H % KV == 0), block_tables [B,n] i32 pool-page ids per slot, pos [B] i32
     per-slot highest valid index (inclusive) → [B,H,D]. ``scales``
     [P,KV,2] dequantizes int8 pools (ISSUE 12) — required iff the pool
-    dtype is int8.
+    dtype is int8. With a static ``layer`` the pools are the serving
+    engine's whole [L,P,KV,page,D] arrays: the kernel indexes the layer
+    itself (no ``pool[l]`` slice for XLA to materialise), the fallback
+    slices it.
 
     Dispatch mirrors :func:`cached_attention`: the Pallas paged kernel on TPU
     (the block-table gather IS the kernel's index maps — no dense copy, no
@@ -149,7 +152,7 @@ def paged_cached_attention(
     einsum of :func:`cached_attention` with a per-slot mask, so the two
     paths agree with the dense cache."""
     B, H, D = q.shape
-    P, KV, page, _ = k_pool.shape
+    KV, page = k_pool.shape[-3:-1]
     if H % KV != 0:
         raise ValueError(f"q heads {H} must divide by KV heads {KV}")
     if (scales is None) == (k_pool.dtype == jnp.int8):
@@ -169,10 +172,12 @@ def paged_cached_attention(
         ):
             return paged_decode_attention(
                 q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-                scales=scales,
+                scales=scales, layer=layer,
             )
     elif impl != "jnp":
         raise ValueError(f"unknown attention impl {impl}")
+    if layer is not None:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     # gather [B,n,KV,page,D] → logical [B,T,KV,D] per slot (pure data
     # movement; int8 pools dequantize here), then the same grouped math as
     # cached_attention's fallback
@@ -194,7 +199,7 @@ def paged_cached_attention(
 
 def paged_multitoken_cached_attention(
     q, k_pool, v_pool, block_tables, base, impl: str = "auto",
-    sm_scale: Optional[float] = None, scales=None,
+    sm_scale: Optional[float] = None, scales=None, layer=None,
 ):
     """T-token causal decode attention against a PAGED KV cache (ISSUE 10:
     the speculative verify step and chunked prefill): q [B,T,H,D], pools
@@ -202,6 +207,7 @@ def paged_multitoken_cached_attention(
     sits at absolute position ``base[b] + t`` and attends keys ``<= base[b]
     + t`` → [B,T,H,D]. The chunk's own K/V must already be scattered into
     the pool (update-then-attend, exactly like the single-token step).
+    ``layer`` as in :func:`paged_cached_attention`.
 
     Dispatch mirrors :func:`paged_cached_attention`: the multitoken Pallas
     kernel on TPU, and a pure-jnp fallback whose T == 1 slice is the exact
@@ -209,7 +215,7 @@ def paged_multitoken_cached_attention(
     softmax) so the verify step's first query agrees with the decode step
     bit for bit."""
     B, T, H, D = q.shape
-    P, KV, page, _ = k_pool.shape
+    KV, page = k_pool.shape[-3:-1]
     if H % KV != 0:
         raise ValueError(f"q heads {H} must divide by KV heads {KV}")
     if (scales is None) == (k_pool.dtype == jnp.int8):
@@ -228,10 +234,12 @@ def paged_multitoken_cached_attention(
         ):
             return paged_multitoken_attention(
                 q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-                scales=scales,
+                scales=scales, layer=layer,
             )
     elif impl != "jnp":
         raise ValueError(f"unknown attention impl {impl}")
+    if layer is not None:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     kd, vd = gather_pool_pages(k_pool, v_pool, block_tables, scales)
     kd = jnp.swapaxes(kd, 2, 3).reshape(B, -1, KV, D)
     vd = jnp.swapaxes(vd, 2, 3).reshape(B, -1, KV, D)

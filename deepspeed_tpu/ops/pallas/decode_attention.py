@@ -127,6 +127,11 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def _page_tile_bytes(page: int, D: int, itemsize: int) -> int:
+    """One head's ``[page, D]`` page as VMEM holds it: padded to its tile."""
+    return _round_up(page, max(1, 32 // itemsize)) * _round_up(D, 128) * itemsize
+
+
 def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
                         n_pages: Optional[int] = None):
     """(kv-heads, pages) one grid step of the paged decode kernel holds, from
@@ -137,8 +142,7 @@ def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
     ``KV`` whose single page fits. ``None`` when one head's page does not."""
     from .flash_attention import VMEM_RESIDENT_BYTES
 
-    sublane = max(1, 32 // itemsize)
-    tile = _round_up(page, sublane) * _round_up(D, 128) * itemsize
+    tile = _page_tile_bytes(page, D, itemsize)
     fit = VMEM_RESIDENT_BYTES // (4 * tile)  # (head, page) tiles: K, V x 2 buffers
     if fit < 1:
         return None
@@ -146,6 +150,29 @@ def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
         return max(h for h in range(1, fit + 1) if KV % h == 0), 1
     cap = min(fit // KV, max(1, S_BLOCK // page), n_pages or S_BLOCK)
     return KV, 1 << (cap.bit_length() - 1)
+
+
+def _pool_dims(pool, layer: Optional[int]):
+    """(KV, page) of a ``[P, KV, page, D]`` pool or, with a static ``layer``,
+    of a whole ``[L, P, KV, page, D]`` one."""
+    if pool.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            f"paged pool of rank {pool.ndim} with layer={layer}: a "
+            "[P, KV, page, D] pool takes no layer, a [L, P, KV, page, D] one "
+            "needs it"
+        )
+    return pool.shape[-3], pool.shape[-2]
+
+
+def _pool_block_spec(block, index_map, layer: Optional[int]):
+    """The BlockSpec of one pool page block. With ``layer`` the pool is the
+    whole ``[L, P, KV, page, D]`` array and the layer a squeezed leading block
+    index, so the kernel body sees the same ``[1, HB, page, D]`` ref."""
+    if layer is None:
+        return pl.BlockSpec(block, index_map)
+    return pl.BlockSpec(
+        (None, *block), lambda *a: (layer, *index_map(*a))
+    )
 
 
 def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
@@ -228,15 +255,21 @@ def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
 
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, D] current-step queries (one per serving slot)
-    k_pool: jnp.ndarray,  # [P, KV, page, D] shared page pool
-    v_pool: jnp.ndarray,  # [P, KV, page, D]
+    k_pool: jnp.ndarray,  # [P, KV, page, D] shared page pool, or [L, P, ...]
+    v_pool: jnp.ndarray,  # the same shape
     block_tables: jnp.ndarray,  # [B, n_pages] i32 pool-page ids per slot
     pos: jnp.ndarray,  # [B] i32: highest valid cache index per slot (inclusive)
     sm_scale: Optional[float] = None,
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
+    layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
 ) -> jnp.ndarray:
     """Single-token attention against a PAGED cache → [B, H, D].
+
+    With ``layer`` the pools are the serving engine's whole ``[L, P, KV, page,
+    D]`` arrays and the layer is one more (squeezed) block index: the caller
+    never slices ``pool[l]``, which XLA would materialise, a layer of padded
+    tiles per kernel call. ``scales`` stays that layer's ``[P, KV, 2]``.
 
     Each slot's logical cache is ``block_tables[b]``'s pages concatenated up
     to ``pos[b]``; the kernel walks them ``G`` pages at a time with all
@@ -251,7 +284,7 @@ def paged_decode_attention(
     per-key columns here (a few hundred KB beside the halved code bytes)
     and applied to the scores and the probabilities in VMEM."""
     B, H, D = q.shape
-    P, KV, page, _ = k_pool.shape
+    KV, page = _pool_dims(k_pool, layer)
     n_pages = block_tables.shape[1]
     if H % KV != 0:
         raise ValueError(f"q heads {H} must divide by KV heads {KV}")
@@ -288,7 +321,7 @@ def paged_decode_attention(
             b, hb = row(r)
             return walk[b, j * G + g], hb, 0, 0
 
-        return pl.BlockSpec((1, HB, page, D), index_map)
+        return _pool_block_spec((1, HB, page, D), index_map, layer)
 
     def qo_map(r, j, walk, pos):
         return (*row(r), 0, 0, 0)
@@ -394,23 +427,24 @@ def _paged_multitoken_kernel(bt_ref, base_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_multitoken_attention(
     q: jnp.ndarray,  # [B, T, H, D] T query tokens per slot
-    k_pool: jnp.ndarray,  # [P, KV, page, D] shared page pool
-    v_pool: jnp.ndarray,  # [P, KV, page, D]
+    k_pool: jnp.ndarray,  # [P, KV, page, D] shared page pool, or [L, P, ...]
+    v_pool: jnp.ndarray,  # the same shape
     block_tables: jnp.ndarray,  # [B, n_pages] i32 pool-page ids per slot
     base: jnp.ndarray,  # [B] i32: query t of slot b sits at position base[b]+t
     sm_scale: Optional[float] = None,
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
+    layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
 ) -> jnp.ndarray:
     """T-token causal attention against a PAGED cache → [B, T, H, D].
 
     Serves the speculative verify step (T = k+1 drafted tokens, base =
     per-slot cached length) and chunked prefill (T = chunk width, base =
     chunk start) — the chunk's own K/V must already be scattered into the
-    pool (update-then-attend, as in the single-token decode step). GQA as
-    in :func:`paged_decode_attention`."""
+    pool (update-then-attend, as in the single-token decode step). GQA and
+    ``layer`` as in :func:`paged_decode_attention`."""
     B, T, H, D = q.shape
-    P, KV, page, _ = k_pool.shape
+    KV, page = _pool_dims(k_pool, layer)
     n_pages = block_tables.shape[1]
     if H % KV != 0:
         raise ValueError(f"q heads {H} must divide by KV heads {KV}")
@@ -423,8 +457,9 @@ def paged_multitoken_attention(
         rep=rep, quantized=quantized,
     )
     q4 = jnp.swapaxes(q, 1, 2)  # [B, H, T, D]: trailing block == array dims
-    pool_spec = pl.BlockSpec(
-        (1, 1, page, D), lambda b, h, j, bt, base: (bt[b, j], h // rep, 0, 0)
+    pool_spec = _pool_block_spec(
+        (1, 1, page, D), lambda b, h, j, bt, base: (bt[b, j], h // rep, 0, 0),
+        layer,
     )
     in_specs = [
         pl.BlockSpec((1, 1, T, D), lambda b, h, j, bt, base: (b, h, 0, 0)),
@@ -462,7 +497,120 @@ def paged_multitoken_attention(
     return jnp.swapaxes(out, 1, 2)  # [B, T, H, D]
 
 
-def _paged_page_ok(page: int, D: int, itemsize: int) -> bool:
+def _token_write_kernel(pidx_ref, poff_ref, k_new_ref, v_new_ref, k_ref, v_ref,
+                        k_out_ref, v_out_ref):
+    """One slot's K page and V page (a block of kv-heads of them) with the
+    rows of the slot's new tokens replaced; the other rows pass through. The
+    step of token ``t`` brings the page ``pidx[b, t]`` and writes EVERY token
+    of the slot that lands on that page, so the steps of one page, which
+    follow one another, each leave the whole result: the pipeline fetches and
+    writes back a block only when its index changes."""
+    b, t = pl.program_id(0), pl.program_id(2)
+    page = k_out_ref.shape[-2]
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
+    here_page = pidx_ref[b, t]
+    k, v = k_ref[0], v_ref[0]
+    for u in range(k_new_ref.shape[1]):  # in order: a later token wins
+        here = row == jnp.where(pidx_ref[b, u] == here_page, poff_ref[b, u], -1)
+        k = jnp.where(here, k_new_ref[0, u], k)
+        v = jnp.where(here, v_new_ref[0, u], v)
+    k_out_ref[0] = k
+    v_out_ref[0] = v
+
+
+def paged_token_write_blocks(KV: int, page: int, D: int, itemsize: int = 2,
+                             T: int = 1) -> Optional[int]:
+    """kv-heads a grid step of :func:`paged_token_write` holds: the largest
+    divisor of ``KV`` whose K and V page, in and out and double-buffered,
+    with the ``T`` new rows of each (a tile a row), fit the VMEM budget.
+    ``None`` when a single head's do not."""
+    from .flash_attention import VMEM_RESIDENT_BYTES
+
+    per_head = (
+        8 * _page_tile_bytes(page, D, itemsize)
+        + 4 * T * _page_tile_bytes(1, D, itemsize)
+    )
+    fit = VMEM_RESIDENT_BYTES // per_head
+    return max((h for h in range(1, KV + 1) if KV % h == 0 and h <= fit),
+               default=None)
+
+
+def paged_token_write(
+    k_pool: jnp.ndarray,  # [L, P, KV, page, D], updated in place (donate it)
+    v_pool: jnp.ndarray,  # the same
+    layer: int,  # static
+    pidx: jnp.ndarray,  # [B] or [B, T] i32 page of each new token
+    poff: jnp.ndarray,  # the same shape: its offset in that page
+    k_vals: jnp.ndarray,  # [B, KV, D] or [B, T, KV, D] the new tokens' K
+    v_vals: jnp.ndarray,  # the same shape: their V
+    interpret: bool = False,
+):
+    """The decode step's one-token write into both pools (or the verify
+    step's ``T`` tokens a slot) as ONE device operation → ``(k_pool,
+    v_pool)``: a grid step brings a slot's current K and V page, replaces the
+    new tokens' rows and puts the pages back; the pools are aliased, so
+    nothing else moves. Same elements, same values as ``pool.at[layer,
+    pidx[b, t], :, poff[b, t]].set(vals[b, t])`` for every ``(b, t)`` in
+    order. A slot's tokens sit at consecutive positions, so the steps of one
+    page follow one another (the kernel counts on it); slots never share the
+    page they write but for the scratch page, which idle slots and drafts
+    past a slot's row share and nothing reads. (A scatter asks the TPU for
+    the page index minor-most and so re-lays the pool out; sixteen
+    ``dynamic_update_slice`` a layer and pool do the same in place in thirty
+    device operations, and a traced run has to write every one of them out.)"""
+    KV, page, D = k_pool.shape[2:]
+    if pidx.ndim == 1:
+        pidx, poff = pidx[:, None], poff[:, None]
+        k_vals, v_vals = k_vals[:, None], v_vals[:, None]
+    B, T = pidx.shape
+    HB = paged_token_write_blocks(KV, page, D, k_pool.dtype.itemsize, T)
+    if HB is None:
+        raise ValueError(
+            f"paged_token_write: a [{page}, {D}] page of {k_pool.dtype.name} "
+            "does not fit VMEM"
+        )
+    block = pl.BlockSpec(
+        (None, 1, HB, page, D),
+        lambda b, hb, t, pidx, poff: (layer, pidx[b, t], hb, 0, 0),
+    )
+    new = pl.BlockSpec(
+        (1, T, HB, 1, D), lambda b, hb, t, pidx, poff: (b, 0, hb, 0, 0)
+    )
+    pool_shape = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    return pl.pallas_call(
+        _token_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, KV // HB, T),
+            in_specs=[new, new, block, block],
+            out_specs=[block, block],
+        ),
+        out_shape=[pool_shape, pool_shape],
+        # the pools, after the two prefetched tables and the two new rows
+        input_output_aliases={4: 0, 5: 1},
+        # its own name in a trace: the roofline readers find the attention
+        # kernels by the name of the function that holds them (decode_fn)
+        name="kv_token_write",
+        interpret=interpret,
+    )(
+        jnp.asarray(pidx, jnp.int32), jnp.asarray(poff, jnp.int32),
+        k_vals.astype(k_pool.dtype).reshape(B, T, KV, 1, D),
+        v_vals.astype(v_pool.dtype).reshape(B, T, KV, 1, D),
+        k_pool, v_pool,
+    )
+
+
+def paged_token_write_ok(KV: int, page: int, D: int, itemsize: int = 2,
+                         T: int = 1) -> bool:
+    """Gate for :func:`paged_token_write`: the page rule, and a head block
+    :func:`paged_token_write_blocks` can place in VMEM."""
+    return (
+        paged_page_ok(page, D, itemsize)
+        and paged_token_write_blocks(KV, page, D, itemsize, T) is not None
+    )
+
+
+def paged_page_ok(page: int, D: int, itemsize: int) -> bool:
     """What both paged kernels ask of a page: TPU backend, lane-friendly head
     dim, sublane-aligned page length."""
     sublane = max(1, 32 // max(1, itemsize))
@@ -480,7 +628,7 @@ def paged_decode_attention_ok(
     block :func:`paged_decode_blocks` can place in VMEM (``KV`` is the pool's
     own head count: a tensor-parallel shard passes its ``KV / tp``)."""
     return (
-        _paged_page_ok(page, D, itemsize)
+        paged_page_ok(page, D, itemsize)
         and paged_decode_blocks(KV, page, D, itemsize) is not None
     )
 
@@ -494,7 +642,7 @@ def paged_multitoken_attention_ok(
     from .flash_attention import VMEM_RESIDENT_BYTES
 
     return (
-        _paged_page_ok(page, D, itemsize)
+        paged_page_ok(page, D, itemsize)
         and (2 * page * D * itemsize + T * D * (itemsize + 4)
              <= VMEM_RESIDENT_BYTES)
     )

@@ -17,6 +17,11 @@ ever reads. The decode kernel takes a page's ``[KV, page, D]`` run — all
 kv-heads, contiguous in this layout — with one DMA and walks a row only as
 far as the slot's own last page, so the padded tail costs it nothing; the
 multi-token kernel and the jnp fallbacks still gather it and mask.
+
+On a TPU the pool of a head narrower than 128 lanes is STORED with its page
+axis split (:func:`pool_stored_shape`), which keeps the device's
+default layout row-major; the programs see the ``[L, P, KV, page, D]``
+:func:`pool_view`, and the kernels take that whole view plus a layer index.
 """
 
 from __future__ import annotations
@@ -219,6 +224,76 @@ def pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size))
 
 
+PAGE_GROUP = 64  # the longest a stored page axis may be (see pool_stored_shape)
+
+
+class PoolLayoutError(RuntimeError):
+    """A KV pool is not, or a compiled program does not keep it, in the
+    layout the paged kernels read: raised at set-up, never inside a run."""
+
+
+def _page_axes(num_pages: int) -> tuple:
+    """``num_pages`` as a product of axes no longer than ``PAGE_GROUP``,
+    largest divisors last; a factor that has no such divisor stays whole."""
+    axes = []
+    while num_pages > PAGE_GROUP:
+        g = max(g for g in range(1, PAGE_GROUP + 1) if num_pages % g == 0)
+        if g == 1:
+            break
+        axes.append(g)
+        num_pages //= g
+    return (num_pages, *reversed(axes))
+
+
+def pool_stored_shape(n_layer: int, num_pages: int, n_kv_head: int,
+                      page_size: int, head_dim: int, dtype: Any) -> tuple:
+    """The shape the K and V pools are STORED with on the device:
+    ``[L, P, KV, page, D]``, or, where the paged kernels run (a TPU, a page
+    shape they take) on a head that does not fill the 128 lanes, the same
+    elements in the same order with the page axis split into axes of at
+    most ``PAGE_GROUP``, ``[L, P // g, g, KV, page, D]`` (512 pages:
+    ``[L, 8, 64, ...]``; 8192: ``[L, 2, 64, 64, ...]``). Every program sees
+    the 5-D :func:`pool_view`; only ``placement.ProgramSet`` handles the
+    stored shape.
+
+    Why. The TPU's default layout of an array whose minor dimension does not
+    fill the lanes puts an axis LONGER than that dimension in its place:
+    ``bf16[L, 512, KV, 16, 64]`` gets the PAGE index minor-most (it saves
+    padding 64 lanes to 128), where the paged kernels' page blocks and the
+    whole-page writes work row-major, and every program then re-lays a
+    layer, or both whole pools, out around each kernel call. With no axis
+    longer than the head the default layout is row-major, the 5-D view of it
+    is a bitcast, and nothing between two kernels changes it. (Compiled for
+    a described v5e, ``tests/unit/ops/test_mosaic_compile.py``: at D = 64 an
+    axis of 65 to 8192 is moved, whichever it is, L and KV included, and
+    none of 64 or less; a head of 128 or 256 is row-major at any length.)
+    That is the compiler's choice and not a contract, so
+    ``ProgramSet`` checks what it got and raises :class:`PoolLayoutError`
+    where a pool came out otherwise (a ``num_pages`` with a prime factor
+    over ``PAGE_GROUP``, more than 64 layers or kv-heads at D = 64). The
+    bytes are the padded tiles (twice the values at D = 64; a 128-wide head
+    is left alone and pads nothing). The layout is not pinned with
+    ``jax.experimental.layout`` instead, because this libtpu's executables
+    lose a custom result layout when they are loaded from the compilation
+    cache, so that every warm run fails; nor is the head stored 128 wide,
+    because every reader of a page, on the device and on the host, would
+    then carry the padding (PERF.md section 6, PR 29)."""
+    from ..ops.pallas.decode_attention import paged_page_ok
+
+    shape = (n_layer, num_pages, n_kv_head, page_size, head_dim)
+    if head_dim % 128 == 0 or not paged_page_ok(
+        page_size, head_dim, jnp.dtype(dtype).itemsize
+    ):
+        return shape
+    return (n_layer, *_page_axes(num_pages), *shape[2:])
+
+
+def pool_view(pool):
+    """The ``[L, P, KV, page, D]`` view every program works on, of a pool in
+    its :func:`pool_stored_shape` (a bitcast, or the pool itself)."""
+    return pool.reshape(pool.shape[0], -1, *pool.shape[-3:])
+
+
 def init_pools(
     n_layer: int,
     num_pages: int,
@@ -227,7 +302,8 @@ def init_pools(
     head_dim: int,
     dtype: Any = jnp.bfloat16,
 ):
-    """The shared K and V pools, ``[L, P, KV, page, D]`` zeros, plus the
+    """The shared K and V pools, zeros in :func:`pool_stored_shape`
+    (``[L, P, KV, page, D]`` but for a narrow head on a TPU), plus the
     per-page scales pool — ``(k_pool, v_pool, scales)``.
 
     Layout is kernel-native: per layer the pool is ``[P, KV, page, D]``, whose
@@ -242,7 +318,9 @@ def init_pools(
     for free: sharing a page shares its scale row, and a recomputed fork
     rewrites its own. Zero-initialized: a never-written page dequantizes to
     exact zeros. Full-precision pools return ``scales = None``."""
-    shape = (n_layer, num_pages, n_kv_head, page_size, head_dim)
+    shape = pool_stored_shape(
+        n_layer, num_pages, n_kv_head, page_size, head_dim, dtype
+    )
     scales = (
         jnp.zeros((n_layer, num_pages, n_kv_head, 2), jnp.float32)
         if jnp.dtype(dtype) == jnp.dtype(jnp.int8) else None

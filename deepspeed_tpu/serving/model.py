@@ -95,33 +95,61 @@ def _write_pool_pages(pool, scales, l, page_ids, chunks, sidx):
     return pool, scales, dequantize_kv_pages(codes, s)
 
 
-def _scatter_tokens(pool, l, pidx, poff, vals):
-    """``vals [..., KV, D]`` to (layer ``l``, page ``pidx[...]``, every kv
-    head, offset ``poff[...]``) of ``pool [L, P, KV, page, D]``.
+def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
+    """``k_vals`` / ``v_vals [..., KV, D]`` to (layer ``l``, page
+    ``pidx[...]``, every kv head, offset ``poff[...]``) of the ``[L, P, KV,
+    page, D]`` pools → ``(k_pool, v_pool)``; ``pidx`` / ``poff`` are ``[B]``
+    (the decode step) or ``[B, T]`` (the verify step).
 
-    The kv-head axis is INDEXED (an iota), not sliced, so the scatter's
-    update window is the minor dim D alone. With ``pool.at[l, pidx, :,
-    poff]`` the window is (KV, D), which straddles the page dim: XLA:TPU
-    then re-lays the WHOLE donated pool out around every step (measured on a
-    v5e at XL width: 2.6x the pool as HLO temp, out of HBM at 1024 pages).
-    Same elements, same values either way."""
-    kv = jnp.arange(pool.shape[2])
-    return pool.at[l, pidx[..., None], kv, poff[..., None]].set(
-        vals.astype(pool.dtype)
+    Where the paged kernels run it is one Pallas call for both pools
+    (``paged_token_write``: a slot's pages in, the new rows replaced, the
+    pages out, the pools aliased), which asks no layout of the pools, so
+    that they stay as the kernels read them between two of them. Elsewhere
+    it is a scatter whose kv-head axis is INDEXED (an iota), not sliced, so
+    that its update window is the minor dim D alone (with ``pool.at[l, pidx,
+    :, poff]`` the window (KV, D) straddles the page dim). On a TPU a scatter
+    wants the page index minor-most and XLA re-lays a layer or the whole
+    pool out around every kernel for it: ``ProgramSet.program_census``
+    refuses such a program. The indices are always in range (an idle slot or
+    an out-of-budget draft points at the scratch page); where two tokens
+    name the same element, which only happens on the scratch page, which one
+    stays is not defined. Same elements, same values either way."""
+    from ..ops.pallas.decode_attention import (
+        paged_token_write,
+        paged_token_write_ok,
+    )
+
+    KV, D = k_vals.shape[-2:]
+    T = 1 if pidx.ndim == 1 else pidx.shape[1]
+    if paged_token_write_ok(KV, k_pool.shape[3], D, k_pool.dtype.itemsize, T):
+        return paged_token_write(k_pool, v_pool, l, pidx, poff, k_vals, v_vals)
+    at = (l, pidx[..., None], jnp.arange(KV), poff[..., None])
+    return (
+        k_pool.at[at].set(k_vals.astype(k_pool.dtype)),
+        v_pool.at[at].set(v_vals.astype(v_pool.dtype)),
     )
 
 
-def _write_pool_token(pool, scales, l, pidx, poff, vals, sidx):
-    """One-token scatter: ``vals [B, KV, D]`` to (layer ``l``, page
-    ``pidx[b]``, offset ``poff[b]``). Offset 0 establishes the page's scale
-    from this token; any other offset codes against the frozen scale."""
+def _token_codes(scales, l, pidx, poff, vals, sidx):
+    """What a one-token write stores for ``vals [B, KV, D]`` → ``(codes,
+    scales)``: the values themselves, or, for an int8 pool, their codes
+    under the page's scale. Offset 0 establishes that scale from this token;
+    any other offset codes against the frozen scale."""
     if scales is None:
-        return _scatter_tokens(pool, l, pidx, poff, vals), None
+        return vals, None
     s_old = scales[l, pidx, :, sidx]                       # [B, KV]
     s = jnp.where((poff == 0)[:, None], kv_page_scale(vals), s_old)
-    pool = _scatter_tokens(pool, l, pidx, poff, quantize_kv_token(vals, s))
-    scales = scales.at[l, pidx, :, sidx].set(s)
-    return pool, scales
+    return quantize_kv_token(vals, s), scales.at[l, pidx, :, sidx].set(s)
+
+
+def _write_pool_tokens(k_pool, v_pool, scales, l, pidx, poff, k_vals, v_vals):
+    """One-token write: ``k_vals`` / ``v_vals [B, KV, D]`` to (layer ``l``,
+    page ``pidx[b]``, offset ``poff[b]``) of both pools, quantized at write
+    when they are int8 → ``(k_pool, v_pool, scales)``."""
+    k_vals, scales = _token_codes(scales, l, pidx, poff, k_vals, 0)
+    v_vals, scales = _token_codes(scales, l, pidx, poff, v_vals, 1)
+    k_pool, v_pool = _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals)
+    return k_pool, v_pool, scales
 
 
 def _proj(o, w, b, dtype, tp_axis=None):
@@ -268,9 +296,11 @@ def paged_prefill(
 # paged decode step (one token for every slot)
 # ---------------------------------------------------------------------------
 
-def _attend_decode_shaped(cfg, q, k_pool_l, v_pool_l, block_tables, pos,
+def _attend_decode_shaped(cfg, q, k_pool, v_pool, l, block_tables, pos,
                           out_dtype, scales_l=None):
-    """ONE query token per slot against the paged cache → [B, 1, E].
+    """ONE query token per slot against layer ``l`` of the paged cache →
+    [B, 1, E]. The kernel takes the whole pools and the layer as a block
+    index; only the ``jnp`` branch slices the layer out.
 
     The decode step's attention, factored so the speculative verify step
     can attend each of its T queries through EXACTLY this code — same
@@ -284,8 +314,8 @@ def _attend_decode_shaped(cfg, q, k_pool_l, v_pool_l, block_tables, pos,
         from ..ops.attention import paged_cached_attention
 
         o1 = paged_cached_attention(
-            q[:, 0], k_pool_l, v_pool_l, block_tables, pos,
-            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l,
+            q[:, 0], k_pool, v_pool, block_tables, pos,
+            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
         )
         return o1.reshape(B, 1, E).astype(out_dtype)
 
@@ -297,7 +327,7 @@ def _attend_decode_shaped(cfg, q, k_pool_l, v_pool_l, block_tables, pos,
     # own branch (probs cast to the CACHE dtype before the V einsum) — for
     # bf16 caches the two round differently, and serving must match whichever
     # path generate takes for the model's impl, bit for bit.
-    kd, vd = _gather_dense(k_pool_l, v_pool_l, block_tables, scales_l)
+    kd, vd = _gather_dense(k_pool[l], v_pool[l], block_tables, scales_l)
     kd, vd = kd.reshape(B, -1, H, D), vd.reshape(B, -1, H, D)
     Smax = kd.shape[1]
     scores = jnp.einsum(
@@ -330,11 +360,12 @@ def _attention_decode_paged(cfg, lp, h, k_pool, v_pool, block_tables,
     # [B,H,D] values to (l, pidx[b], :, poff[b], :) — advanced indices around
     # the head slice put the batch dim first, matching the value layout.
     # Inactive slots target the scratch page.
-    k_pool, scales = _write_pool_token(k_pool, scales, l, pidx, poff, k_c[:, 0], 0)
-    v_pool, scales = _write_pool_token(v_pool, scales, l, pidx, poff, v_c[:, 0], 1)
+    k_pool, v_pool, scales = _write_pool_tokens(
+        k_pool, v_pool, scales, l, pidx, poff, k_c[:, 0], v_c[:, 0]
+    )
 
     o = _attend_decode_shaped(
-        cfg, q, k_pool[l], v_pool[l], block_tables, pos, h.dtype,
+        cfg, q, k_pool, v_pool, l, block_tables, pos, h.dtype,
         scales[l] if scales is not None else None,
     )
     return (
@@ -425,10 +456,11 @@ def paged_decode_step(
 # ---------------------------------------------------------------------------
 
 
-def _attend_multitoken_paged(cfg, h, q, k_pool_l, v_pool_l,
+def _attend_multitoken_paged(cfg, h, q, k_pool, v_pool, l,
                              block_tables, base, scales_l=None):
     """Batched attention tail of the chunk-prefill program: q [B,T,H,D]
-    against the (already updated) paged cache, masked per query. The
+    against layer ``l`` of the (already updated) paged cache, masked per
+    query; the pools arrive whole, as in ``_attend_decode_shaped``. The
     caller applies the output projection. ``scales_l`` dequantizes an int8
     pool (ISSUE 12).
 
@@ -442,15 +474,15 @@ def _attend_multitoken_paged(cfg, h, q, k_pool_l, v_pool_l,
         from ..ops.attention import paged_multitoken_cached_attention
 
         o = paged_multitoken_cached_attention(
-            q, k_pool_l, v_pool_l, block_tables, base,
-            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l,
+            q, k_pool, v_pool, block_tables, base,
+            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
         )
         return o.reshape(B, T, H * D).astype(h.dtype)
 
     # jnp impl: dense gather + the exact einsum/cast structure of
     # _attention_decode_paged's jnp branch, extended to T query rows (see
     # that branch for why this is NOT deduplicated into the dispatcher)
-    kd, vd = _gather_dense(k_pool_l, v_pool_l, block_tables, scales_l)
+    kd, vd = _gather_dense(k_pool[l], v_pool[l], block_tables, scales_l)
     kd, vd = kd.reshape(B, -1, H, D), vd.reshape(B, -1, H, D)
     Smax = kd.shape[1]
     scores = jnp.einsum(
@@ -491,9 +523,8 @@ def _attention_verify_paged(cfg, lp, h, k_pool, v_pool, block_tables,
     k_c = k_.reshape(B, T, H, D).astype(pool_dt)
     v_c = v.reshape(B, T, H, D).astype(pool_dt)
     if scales is None:
-        # [B,T,H,D] values to (l, pidx[b,t], :, poff[b,t], :)
-        k_pool = _scatter_tokens(k_pool, l, pidx, poff, k_c)
-        v_pool = _scatter_tokens(v_pool, l, pidx, poff, v_c)
+        # [B,T,H,D] values to (l, pidx[b,t], :, poff[b,t], :), one write
+        k_pool, v_pool = _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_c, v_c)
     else:
         # quantized pools write the T tokens in sequence: a token landing at
         # a page's offset 0 establishes the page's scale, and the tokens
@@ -502,18 +533,15 @@ def _attention_verify_paged(cfg, lp, h, k_pool, v_pool, block_tables,
         # the pool state (codes AND scales) is bit-identical to spec-off
         # int8 decode
         for t in range(T):
-            k_pool, scales = _write_pool_token(
-                k_pool, scales, l, pidx[:, t], poff[:, t], k_c[:, t], 0
+            k_pool, v_pool, scales = _write_pool_tokens(
+                k_pool, v_pool, scales, l, pidx[:, t], poff[:, t],
+                k_c[:, t], v_c[:, t],
             )
-            v_pool, scales = _write_pool_token(
-                v_pool, scales, l, pidx[:, t], poff[:, t], v_c[:, t], 1
-            )
-    k_l, v_l = k_pool[l], v_pool[l]
     scales_l = scales[l] if scales is not None else None
     o = jnp.concatenate(
         [
             _attend_decode_shaped(
-                cfg, q[:, t:t + 1], k_l, v_l, block_tables,
+                cfg, q[:, t:t + 1], k_pool, v_pool, l, block_tables,
                 base + t, h.dtype, scales_l,
             )
             for t in range(T)
@@ -664,7 +692,7 @@ def paged_chunk_prefill(
             jnp.swapaxes(v_c[0].reshape(n_cp, page, H, D), 1, 2), 1,
         )
         o = _attend_multitoken_paged(
-            cfg, hn, q, k_pool[l], v_pool[l], block_tables, base,
+            cfg, hn, q, k_pool, v_pool, l, block_tables, base,
             scales[l] if scales is not None else None,
         )
         a = _proj(o, lp["attn"]["c_proj_w"], lp["attn"]["c_proj_b"],

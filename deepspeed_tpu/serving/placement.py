@@ -40,6 +40,8 @@ collective-order check passes by construction.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
@@ -55,7 +57,7 @@ from ..analysis.sharding_rules import (
 )
 from ..module_inject.tp_shard import tp_shard_serving_params
 from jax import shard_map
-from .kv_cache import PageAllocator, init_pools
+from .kv_cache import PageAllocator, PoolLayoutError, init_pools, pool_view
 
 PyTree = Any
 
@@ -84,6 +86,27 @@ GPT2_SERVING_RULES: List[Tuple[str, list]] = [
     ("ln_[12f]/(scale|bias)$", []),
     ("^w[tp]e$", []),
 ]
+
+
+# one instruction of an optimised HLO module: "= <dtype>[<dims>]{<layout>} <opcode>("
+_HLO_RESULT = re.compile(r"=\s+\w+\[([\d,]*)\](?:\{[^}]*\})?\s+([\w-]+)\(")
+_RELAYOUT_OPCODES = frozenset(("copy", "slice", "transpose", "dynamic-slice"))
+
+
+def pool_relayout_ops(hlo_text: str, layer_elems: int) -> int:
+    """How many instructions of an optimised HLO module (fused computations
+    included) copy, slice or transpose a whole number of pool layers:
+    results of ``layer_elems`` (= P * KV * page * D, per device) elements or
+    a multiple. 0 is what a program reads whose pool stays in one layout
+    from its entry to its kernels and back; each one is a layer or a pool of
+    HBM traffic per call that no kernel asked for."""
+    n = 0
+    for dims, opcode in _HLO_RESULT.findall(hlo_text):
+        if opcode not in _RELAYOUT_OPCODES:
+            continue
+        elems = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        n += elems >= layer_elems and elems % layer_elems == 0
+    return n
 
 
 def _path_of(keypath) -> str:
@@ -159,12 +182,14 @@ class Placement:
             )
         return dataclasses.replace(cfg, n_embd=E // self.tp, n_head=H // self.tp)
 
-    def pool_spec(self, ndim: int) -> PartitionSpec:
+    def pool_spec(self, ndim: int, kv_axis: int = 2) -> PartitionSpec:
         """KV pools / scales / packed handoff buffers all carry the KV-head
-        axis at dim 2 (``[L, P, KV, ...]``) — shard it, replicate the rest."""
+        axis at dim 2 (``[L, P, KV, ...]``) — shard it, replicate the rest.
+        (``kv_axis`` is for :class:`ProgramSet`, which alone knows the shape
+        its pools are stored in.)"""
         entries = [None] * ndim
         if self.tp > 1:
-            entries[2] = TP_AXIS
+            entries[kv_axis] = TP_AXIS
         return PartitionSpec(*entries)
 
     def rep_spec(self) -> PartitionSpec:
@@ -182,8 +207,10 @@ class Placement:
             return x
         return jax.device_put(x, self.device)
 
-    def put_pool(self, x):
-        return self.put(x, self.pool_spec(getattr(x, "ndim", len(x.shape))))
+    def put_pool(self, x, kv_axis: int = 2):
+        return self.put(
+            x, self.pool_spec(getattr(x, "ndim", len(x.shape)), kv_axis)
+        )
 
     def pull_pool(self, x):
         """Cross-placement transfer of a packed handoff buffer: ALWAYS
@@ -270,7 +297,14 @@ class ProgramSet:
     scales) sharded over the placement, the page allocator for that pool,
     and the compiled programs that consume them. Donated-pool rehoming
     (``take_pools``) lives here because the donated buffers belong to THIS
-    pool, whichever placement ran the program."""
+    pool, whichever placement ran the program.
+
+    The K and V pools may be STORED with their page axis split
+    (``kv_cache.pool_stored_shape``), and this class alone knows: a program
+    compiled through :meth:`aot` works on the ``[L, P, KV, page, D]`` views,
+    the host reads a page through :meth:`page_column`, and the verifiers get
+    the stored per-device dims from :meth:`local_pool_dims`. Payloads of
+    page columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always."""
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
                  page_size: int, cache_dtype, params: PyTree):
@@ -284,9 +318,11 @@ class ProgramSet:
             self.n_layer, self.num_pages, self.n_kv_head, self.page_size,
             self.head_dim, dtype=cache_dtype,
         )
-        self.k_pool = placement.put_pool(k)
-        self.v_pool = placement.put_pool(v)
+        self._kv_axis = k.ndim - 3  # [..., KV, page, D], however P is stored
+        self.k_pool = placement.put_pool(k, self._kv_axis)
+        self.v_pool = placement.put_pool(v, self._kv_axis)
         self.kv_scales = placement.put_pool(scales) if scales is not None else None
+        self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
         self.params = placement.shard_params(params)
         self.param_specs = (
@@ -303,6 +339,128 @@ class ProgramSet:
         if self.kv_scales is not None:
             return (self.k_pool, self.v_pool, self.kv_scales)
         return (self.k_pool, self.v_pool)
+
+    def _check_pool_layout(self) -> None:
+        """Where the paged kernels run, the pools must have come out
+        row-major on the device (``kv_cache.pool_stored_shape`` arranges it
+        by shape alone; the compiler has the last word)."""
+        from ..ops.pallas.decode_attention import paged_page_ok
+
+        if not paged_page_ok(
+            self.page_size, self.head_dim, self.k_pool.dtype.itemsize
+        ):
+            return
+        got = tuple(self.k_pool.format.layout.major_to_minor)
+        if got != tuple(range(self.k_pool.ndim)):
+            raise PoolLayoutError(
+                f"KV pool {self.k_pool.dtype.name}{list(self.k_pool.shape)} "
+                f"is laid out major-to-minor {got} on this device, not "
+                "row-major: every paged program would re-lay it out around "
+                "its kernels. An axis longer than the head moves: choose "
+                f"num_pages ({self.num_pages}) as a product of factors of "
+                "at most 64, and no more than 64 layers or kv-heads a "
+                "device at this head width"
+            )
+
+    def pool_specs(self) -> tuple:
+        """One ``PartitionSpec`` per :meth:`pool_args` operand."""
+        kv = self.placement.pool_spec(self.k_pool.ndim, self._kv_axis)
+        if self.kv_scales is None:
+            return (kv, kv)
+        return (kv, kv, self.placement.pool_spec(self.kv_scales.ndim))
+
+    def aot(self, fn, operands: Sequence, operand_specs: Sequence = (),
+            result_specs: Sequence = (), *, with_params: bool = False,
+            returns_pools: bool = True, donate: bool = True):
+        """AOT-compile ``fn([params,] k_pool, v_pool[, scales], *operands)``
+        for this set's placement, over this set's pools.
+
+        ``fn`` is written for ``[L, P, KV, page, D]`` pools and, with
+        ``returns_pools``, gives them back first (``k, v[, scales], *rest``).
+        The compiled program takes and returns the pools in the shape they
+        are stored in: the views both ways are bitcasts (the identity for a
+        pool stored 5-D). ``donate`` donates the pools. ``operand_specs`` and
+        ``result_specs`` are the specs of what follows the pools, used at
+        tp > 1 only."""
+        plc = self.placement
+        first = int(with_params)
+        pools = self.pool_args()
+
+        @functools.wraps(fn)  # jit(decode_fn): the name traces are read by
+        def program(*args):
+            stored = args[first].shape  # per device under shard_map
+            out = fn(
+                *args[:first], pool_view(args[first]),
+                pool_view(args[first + 1]), *args[first + 2:],
+            )
+            if not returns_pools:
+                return out
+            return (
+                out[0].reshape(stored), out[1].reshape(stored), *out[2:]
+            )
+
+        args = ((self.params,) if with_params else ()) + pools + tuple(operands)
+        dn = tuple(range(first, first + len(pools))) if donate else ()
+        if plc.mesh is None:
+            exe = plc.aot(program, args, (), (), dn)
+        else:
+            pool_specs = self.pool_specs()
+            exe = plc.aot(
+                program, args,
+                ((self.param_specs,) if with_params else ())
+                + pool_specs + tuple(operand_specs),
+                (pool_specs if returns_pools else ()) + tuple(result_specs),
+                dn,
+            )
+        # the program must take the pools as they lie and give them back so:
+        # a layout that differs would be refused at the first call (or, for
+        # a result, at the one after), inside a run
+        took = exe.input_formats[0][first:first + len(pools)]
+        gave = exe.output_formats[:len(pools)] if returns_pools else ()
+        for what, fmts in (("takes", took), ("returns", gave)):
+            for pool, fmt in zip(pools, fmts):
+                if fmt.layout != pool.format.layout:
+                    raise PoolLayoutError(
+                        f"{getattr(fn, '__name__', fn)} {what} a "
+                        f"{pool.dtype.name}{list(pool.shape)} pool as "
+                        f"{fmt.layout}, and the live pool is "
+                        f"{pool.format.layout}"
+                    )
+        return exe
+
+    def page_column(self, pid: int) -> tuple:
+        """Page ``pid`` of every layer, as device arrays ``(k, v, scales)``:
+        ``[L, KV, page, D]`` twice and ``[L, KV, 2]`` or ``None`` (the host
+        tier's demotion read; dispatched now, fetched by whoever waits)."""
+        at = (slice(None),) + tuple(int(i) for i in np.unravel_index(
+            int(pid), self.k_pool.shape[1:self._kv_axis]
+        ))
+        return (
+            self.k_pool[at], self.v_pool[at],
+            self.kv_scales[:, pid] if self.kv_scales is not None else None,
+        )
+
+    def program_census(self, name: str, exe) -> Tuple[int, int]:
+        """(``pool_relayout_ops``, HLO temp bytes) of a compiled program over
+        this set's pools; per device at tp>1. A program that hands the pools
+        to a Pallas kernel has to read 0: there the kernels take the pool
+        where it lies, and a copy or a slice of a layer means the pool was
+        re-laid out on the way (:class:`PoolLayoutError`). The ``jnp``
+        fallbacks slice their layer out and are only counted."""
+        layer = (
+            self.num_pages * self.local_kv_heads() * self.page_size
+            * self.head_dim
+        )
+        text = exe.as_text()
+        relayout = pool_relayout_ops(text, layer)
+        if relayout and "tpu_custom_call" in text:
+            raise PoolLayoutError(
+                f"{name}: {relayout} instruction(s) copy, slice or transpose "
+                f"a whole layer of the {list(self.k_pool.shape)} KV pool or "
+                "more around its kernels (placement.pool_relayout_ops)"
+            )
+        mem = exe.memory_analysis()
+        return relayout, int(getattr(mem, "temp_size_in_bytes", 0) or 0)
 
     def take_pools(self, out: tuple):
         """Rehome the donated pools from a program's output tuple and
@@ -326,22 +484,55 @@ class ProgramSet:
     def local_kv_heads(self) -> int:
         return self.n_kv_head // self.placement.tp
 
+    def _local_dims(self, shape, kv_axis: int = 2) -> str:
+        shape = list(shape)
+        shape[kv_axis] //= self.placement.tp
+        return ",".join(str(int(d)) for d in shape)
+
     def local_pool_dims(self) -> str:
-        return (
-            f"{self.n_layer},{self.num_pages},{self.local_kv_heads()},"
-            f"{self.page_size},{self.head_dim}"
+        """Per-device dims of a pool as the compiled programs take it: the
+        STORED shape (their entry parameters, the donation aliases)."""
+        return self._local_dims(self.k_pool.shape, self._kv_axis)
+
+    def kv_pool_dims(self) -> tuple:
+        """Every per-device dims string a pool-sized buffer of a compiled
+        program may carry: the stored shape and, where it differs, the
+        ``[L, P, KV, page, D]`` view the program body works on."""
+        view = self._local_dims(
+            (self.n_layer, self.num_pages, self.n_kv_head, self.page_size,
+             self.head_dim)
         )
+        stored = self.local_pool_dims()
+        return (stored,) if stored == view else (stored, view)
 
     def local_scales_dims(self) -> str:
         return f"{self.n_layer},{self.num_pages},{self.local_kv_heads()},2"
 
+    def packed_sds(self, n_pages: int) -> tuple:
+        """Global shapes of a page-column payload over ``n_pages`` pages, one
+        per :meth:`pool_args` operand: ``[L, n, KV, page, D]`` for K and V
+        (whatever shape the pools are stored in), ``[L, n, KV, 2]`` for the
+        scales."""
+        kv = jax.ShapeDtypeStruct(
+            (self.n_layer, int(n_pages), self.n_kv_head, self.page_size,
+             self.head_dim), self.k_pool.dtype,
+        )
+        if self.kv_scales is None:
+            return (kv, kv)
+        return (kv, kv, jax.ShapeDtypeStruct(
+            (self.n_layer, int(n_pages), self.n_kv_head, 2), jnp.float32
+        ))
+
+    def packed_specs(self) -> tuple:
+        """One ``PartitionSpec`` per :meth:`packed_sds` payload."""
+        return tuple(
+            self.placement.pool_spec(x.ndim) for x in self.packed_sds(1)
+        )
+
     def packed_dims(self, n_pages: int) -> str:
         """Per-device shape of the gather/scatter handoff payload over
         ``n_pages`` pages."""
-        return (
-            f"{self.n_layer},{int(n_pages)},{self.local_kv_heads()},"
-            f"{self.page_size},{self.head_dim}"
-        )
+        return self._local_dims(self.packed_sds(n_pages)[0].shape)
 
     def packed_scales_dims(self, n_pages: int) -> str:
         return f"{self.n_layer},{int(n_pages)},{self.local_kv_heads()},2"

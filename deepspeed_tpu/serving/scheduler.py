@@ -547,6 +547,20 @@ class ServingEngine:
             "serving_tenant_tokens_total", "generated tokens by tenant",
             labelnames=("tenant",),
         )
+        # -- is the pool met in the layout the paged programs work in ------
+        self._g_relayout = m.gauge(
+            "serving_pool_relayout_ops",
+            "copy / slice / transpose instructions of a compiled serving "
+            "program whose result is one or more whole layers of a KV pool "
+            "(0 = the pool keeps one layout from the program's entry to its "
+            "kernels and back)",
+            labelnames=("program",),
+        )
+        self._g_temp_bytes = m.gauge(
+            "serving_program_temp_bytes",
+            "HLO temp bytes of a compiled serving program (per device)",
+            labelnames=("program",),
+        )
         # -- ISSUE 14: TP sharding + disaggregation instruments ------------
         self._g_tp_coll = m.gauge(
             "serving_tp_collective_bytes",
@@ -626,6 +640,7 @@ class ServingEngine:
                 f" + {scales_bytes(mcfg.n_layer, int(config.num_pages), mcfg.n_head) / 1e6:.2f} MB scales"
                 if self.quantized else ""
             )
+            + f", [{self.decode_set.local_pool_dims()}] a device"
             + f") prefill_width={self.prefill_width} dtype={np.dtype(self.cache_dtype).name} "
             f"spec_k={self.spec_k if self.spec_enabled else 0} "
             f"prefix_cache={self.prefix_enabled} chunk={self.chunk_width} "
@@ -836,8 +851,9 @@ class ServingEngine:
     def _ensure_compiled(self) -> None:
         if self._prefill_exec is not None:
             return
-        with spans.phase("ds.init.programs", what="serving"):
+        with spans.phase("ds.init.programs", what="serving") as ph:
             self._compile_programs()
+            ph.set(**self._set_census_gauges())
 
     def _compile_programs(self) -> None:
         sc = self.config
@@ -845,7 +861,6 @@ class ServingEngine:
         quant = self.quantized
         S = jax.ShapeDtypeStruct
         i32, u32 = jnp.int32, jnp.uint32
-        donate = (1, 2, 3) if quant else (1, 2)
 
         # int8 pools (ISSUE 12) thread the scales pool as one more donated
         # operand through every program; the wrappers keep the operand order
@@ -898,23 +913,11 @@ class ServingEngine:
         # pools/params enter with their placement specs, host operands
         # replicate, and donation threads through the outer jit so XLA
         # aliases the per-device pool shards.
-        def compile_for(pset, fn, host_sds, donate_pools=True):
-            plc = pset.placement
-            pools = pset.pool_args()
-            args = (pset.params,) + pools + tuple(host_sds)
-            dn = donate if donate_pools else ()
-            if plc.mesh is None:
-                return plc.aot(fn, args, (), (), dn)
-            in_specs = (
-                (pset.param_specs,)
-                + tuple(plc.pool_spec(p.ndim) for p in pools)
-                + tuple(plc.rep_spec() for _ in host_sds)
+        def compile_for(pset, fn, host_sds):
+            rep = pset.placement.rep_spec()
+            return pset.aot(
+                fn, host_sds, (rep,) * len(host_sds), (rep,), with_params=True
             )
-            out_specs = (
-                tuple(plc.pool_spec(p.ndim) for p in pools)
-                + (plc.rep_spec(),)
-            )
-            return plc.aot(fn, args, in_specs, out_specs, dn)
 
         d_cfg = self.decode_placement.local_model_config(self.model_config)
         p_cfg = self.prefill_placement.local_model_config(self.model_config)
@@ -1017,38 +1020,20 @@ class ServingEngine:
 
         # gather: prefill pools are READ, not donated — the prompt pages
         # stay live for the prefix index until the host frees them
-        g_pools = pset.pool_args()
-        g_args = g_pools + (src_sds,)
-        if pp.mesh is None:
-            self._gather_exec = pp.aot(gather_fn, g_args, (), (), ())
-        else:
-            self._gather_exec = pp.aot(
-                gather_fn, g_args,
-                tuple(pp.pool_spec(p.ndim) for p in g_pools) + (pp.rep_spec(),),
-                tuple(pp.pool_spec(p.ndim) for p in g_pools), (),
-            )
+        self._gather_exec = pset.aot(
+            gather_fn, (src_sds,), (pp.rep_spec(),), pset.packed_specs(),
+            returns_pools=False, donate=False,
+        )
         info[f"serving_kv_gather{sfx}{pp.suffix()}"] = {
             "exe": self._gather_exec, "pset": pset, "kind": "gather",
         }
         self.executables.append(self._gather_exec)
 
-        # scatter: decode pools donated (args 0..n_pool-1 — no params slot)
-        d_pools = dset.pool_args()
-        packed_sds = tuple(
-            S((p.shape[0], n_hp) + tuple(p.shape[2:]), p.dtype)
-            for p in d_pools
+        # scatter: decode pools donated
+        self._scatter_exec = dset.aot(
+            scatter_fn, dset.packed_sds(n_hp) + (src_sds,),
+            dset.packed_specs() + (dp.rep_spec(),),
         )
-        s_args = d_pools + packed_sds + (src_sds,)
-        s_donate = tuple(range(len(d_pools)))
-        if dp.mesh is None:
-            self._scatter_exec = dp.aot(scatter_fn, s_args, (), (), s_donate)
-        else:
-            pool_specs = tuple(dp.pool_spec(p.ndim) for p in d_pools)
-            self._scatter_exec = dp.aot(
-                scatter_fn, s_args,
-                pool_specs + pool_specs + (dp.rep_spec(),),
-                pool_specs, s_donate,
-            )
         info[f"serving_kv_scatter{sfx}{dp.suffix()}"] = {
             "exe": self._scatter_exec, "pset": dset, "kind": "scatter",
         }
@@ -1076,26 +1061,32 @@ class ServingEngine:
 
         sfx = "_int8" if quant else ""
         pp, pset = self.prefill_placement, self.prefill_set
-        pools = pset.pool_args()
-        packed_sds = tuple(
-            S((p.shape[0], 1) + tuple(p.shape[2:]), p.dtype) for p in pools
+        self._restore_exec = pset.aot(
+            restore_fn, pset.packed_sds(1) + (S((1,), i32),),
+            pset.packed_specs() + (pp.rep_spec(),),
         )
-        args = pools + packed_sds + (S((1,), i32),)
-        dn = tuple(range(len(pools)))
-        if pp.mesh is None:
-            self._restore_exec = pp.aot(restore_fn, args, (), (), dn)
-        else:
-            pool_specs = tuple(pp.pool_spec(p.ndim) for p in pools)
-            self._restore_exec = pp.aot(
-                restore_fn, args,
-                pool_specs + pool_specs + (pp.rep_spec(),),
-                pool_specs, dn,
-            )
         info[f"serving_kv_restore{sfx}{pp.suffix()}"] = {
             "exe": self._restore_exec, "pset": pset, "kind": "restore",
         }
         self.executables.append(self._restore_exec)
         self.tiering.bind_restore_exec(self._restore_exec)
+
+    def _set_census_gauges(self) -> dict:
+        """Per compiled program: how many pool-layer-sized copies, slices
+        and transposes its optimised HLO holds, and its temp bytes, as
+        gauges and (returned) as the attrs of the ``ds.init.programs``
+        phase: ``relayout_ops`` / ``temp_bytes`` keyed ``<program>=<n>``."""
+        relayout, temp = {}, {}
+        for name, rec in self._program_info.items():
+            relayout[name], temp[name] = rec["pset"].program_census(
+                name, rec["exe"]
+            )
+            self._g_relayout.set(relayout[name], program=name)
+            self._g_temp_bytes.set(temp[name], program=name)
+        return {
+            "relayout_ops": " ".join(f"{k}={v}" for k, v in relayout.items()),
+            "temp_bytes": " ".join(f"{k}={v}" for k, v in temp.items()),
+        }
 
     def _set_collective_gauges(self) -> None:
         """Static per-invocation all-reduce payload of each TP program: the
@@ -2337,34 +2328,18 @@ class ServingEngine:
             return k_pool, v_pool
 
         dp, dset = self.decode_placement, self.decode_set
-        pools = dset.pool_args()
         ids_sds = S((W,), i32)
         # gather: decode pools READ, not donated — the source row stays
         # live until the peer's adoption is validated (crc), so a corrupt
         # payload never costs the conversation more than a requeue
-        g_args = pools + (ids_sds,)
-        if dp.mesh is None:
-            self._migrate_gather_exec = dp.aot(gather_fn, g_args, (), (), ())
-        else:
-            self._migrate_gather_exec = dp.aot(
-                gather_fn, g_args,
-                tuple(dp.pool_spec(p.ndim) for p in pools) + (dp.rep_spec(),),
-                tuple(dp.pool_spec(p.ndim) for p in pools), (),
-            )
-        packed_sds = tuple(
-            S((p.shape[0], W) + tuple(p.shape[2:]), p.dtype) for p in pools
+        self._migrate_gather_exec = dset.aot(
+            gather_fn, (ids_sds,), (dp.rep_spec(),), dset.packed_specs(),
+            returns_pools=False, donate=False,
         )
-        s_args = pools + packed_sds + (ids_sds,)
-        dn = tuple(range(len(pools)))
-        if dp.mesh is None:
-            self._migrate_scatter_exec = dp.aot(scatter_fn, s_args, (), (), dn)
-        else:
-            pool_specs = tuple(dp.pool_spec(p.ndim) for p in pools)
-            self._migrate_scatter_exec = dp.aot(
-                scatter_fn, s_args,
-                pool_specs + pool_specs + (dp.rep_spec(),),
-                pool_specs, dn,
-            )
+        self._migrate_scatter_exec = dset.aot(
+            scatter_fn, dset.packed_sds(W) + (ids_sds,),
+            dset.packed_specs() + (dp.rep_spec(),),
+        )
 
     def export_session(self, slot_i: int):
         """Serialize slot ``slot_i``'s live decode session for migration
@@ -2707,7 +2682,7 @@ class ServingEngine:
             self._memory_cfg = mcfg
             for name, rec in self._program_info.items():
                 pset, kind = rec["pset"], rec["kind"]
-                kv_dims = [pset.local_pool_dims()]
+                kv_dims = list(pset.kv_pool_dims())
                 scl = (pset.local_scales_dims(),) if self.quantized else ()
                 if kind in ("gather", "scatter"):
                     kv_dims.append(pset.packed_dims(self.prefill_pages))

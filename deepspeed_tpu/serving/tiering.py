@@ -411,10 +411,7 @@ class KVTieringEngine:
         # survive the sweep.
         self.drop_orphans(keep=key)
         # async read of the page column; device_get happens on the worker
-        k_dev = self.pset.k_pool[:, pid]
-        v_dev = self.pset.v_pool[:, pid]
-        s_dev = (self.pset.kv_scales[:, pid]
-                 if getattr(self.pset, "kv_scales", None) is not None else None)
+        k_dev, v_dev, s_dev = self.pset.page_column(pid)
         hid = self.store.reserve(key, pid)
         with self._lock:
             self._queue.append((hid, k_dev, v_dev, s_dev))

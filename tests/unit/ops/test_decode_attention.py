@@ -389,6 +389,97 @@ class TestQuantizedPagedAttention:
             )
 
 
+class TestLayerIndexedPool:
+    """ISSUE 29: both paged kernels take the serving engine's whole
+    ``[L, P, KV, page, D]`` pool and a static layer (one more squeezed block
+    index), so no caller slices ``pool[l]``. Same page blocks, same kernel
+    body: the result is bitwise the ``[P, KV, page, D]`` call's on that
+    layer, whatever the other layers hold."""
+
+    L, LAYER = 3, 1
+
+    def _pools(self, case, B=3, H=4, D=64, page=8, P=16, n=4, seed=0):
+        from deepspeed_tpu.ops.quantizer import quantize_kv_pages
+
+        KV = H // 2 if case == "gqa" else H
+        rs = np.random.RandomState(seed)
+        kf = rs.randn(self.L, P, KV, page, D).astype(np.float32)
+        vf = rs.randn(self.L, P, KV, page, D).astype(np.float32)
+        bt = jnp.asarray(
+            rs.choice(np.arange(1, P), (B * n,), replace=False).reshape(B, n),
+            jnp.int32,
+        )
+        scales = None
+        if case == "int8":
+            kq, ks = quantize_kv_pages(jnp.asarray(kf[self.LAYER]))
+            vq, vs = quantize_kv_pages(jnp.asarray(vf[self.LAYER]))
+            scales = jnp.stack([ks, vs], axis=-1)  # the layer's [P, KV, 2]
+            k5 = jnp.asarray(rs.randint(-127, 128, kf.shape), jnp.int8)
+            v5 = jnp.asarray(rs.randint(-127, 128, vf.shape), jnp.int8)
+            k5, v5 = k5.at[self.LAYER].set(kq), v5.at[self.LAYER].set(vq)
+        else:
+            dt = jnp.bfloat16 if case == "bf16" else jnp.float32
+            k5, v5 = jnp.asarray(kf, dt), jnp.asarray(vf, dt)
+        qdt = jnp.bfloat16 if case == "bf16" else jnp.float32
+        return rs, qdt, k5, v5, bt, scales
+
+    @pytest.mark.parametrize("case", ["bf16", "int8", "gqa"])
+    @pytest.mark.parametrize("kernel", ["decode", "multitoken"])
+    def test_whole_pool_with_layer_is_bitwise_the_layer_slice(self, kernel, case):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+            paged_multitoken_attention,
+        )
+
+        rs, qdt, k5, v5, bt, scales = self._pools(case)
+        at = jnp.asarray([0, 13, 27], jnp.int32)
+        if kernel == "decode":
+            fn, q = paged_decode_attention, rs.randn(3, 4, 64)
+        else:
+            fn, q = paged_multitoken_attention, rs.randn(3, 5, 4, 64)
+        q = jnp.asarray(q, qdt)
+        whole = fn(q, k5, v5, bt, at, interpret=True, scales=scales,
+                   layer=self.LAYER)
+        sliced = fn(q, k5[self.LAYER], v5[self.LAYER], bt, at, interpret=True,
+                    scales=scales)
+        assert whole.dtype == sliced.dtype and whole.shape == sliced.shape
+        assert bool(jnp.all(whole == sliced))
+        assert np.isfinite(np.asarray(whole, np.float32)).all()
+
+    @pytest.mark.parametrize("kernel", ["decode", "multitoken"])
+    def test_dispatcher_fallback_slices_the_layer(self, kernel):
+        from deepspeed_tpu.ops.attention import (
+            paged_cached_attention,
+            paged_multitoken_cached_attention,
+        )
+
+        rs, qdt, k5, v5, bt, _ = self._pools("gqa", seed=3)
+        at = jnp.asarray([2, 9, 30], jnp.int32)
+        if kernel == "decode":
+            fn, q = paged_cached_attention, rs.randn(3, 4, 64)
+        else:
+            fn, q = paged_multitoken_cached_attention, rs.randn(3, 2, 4, 64)
+        q = jnp.asarray(q, qdt)
+        whole = fn(q, k5, v5, bt, at, impl="jnp", layer=self.LAYER)
+        sliced = fn(q, k5[self.LAYER], v5[self.LAYER], bt, at, impl="jnp")
+        assert bool(jnp.all(whole == sliced))
+
+    @pytest.mark.parametrize("layer", [None, 0])
+    def test_pool_rank_and_layer_must_agree(self, layer):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+        )
+
+        rs, qdt, k5, v5, bt, _ = self._pools("f32")
+        q = jnp.asarray(rs.randn(3, 4, 64), qdt)
+        pools = (k5, v5) if layer is None else (k5[0], v5[0])
+        with pytest.raises(ValueError, match="layer"):
+            paged_decode_attention(
+                q, *pools, bt, jnp.zeros((3,), jnp.int32), interpret=True,
+                layer=layer,
+            )
+
+
 class TestPagedDecodeServedShape:
     """ISSUE 25: the paged decode kernel takes all kv-heads and a block of
     ``G`` pages to a grid step and stops at the slot's own last page. Cases

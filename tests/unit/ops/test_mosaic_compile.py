@@ -1,4 +1,4 @@
-"""Mosaic-compile the serving path's paged decode kernel at real widths for a
+"""Mosaic-compile the serving path's paged kernels and programs at real widths for a
 described (not attached) TPU v5e: what the chip's compiler would refuse is
 refused here, at no chip time. Interpret mode cannot show a misaligned slice
 or a kernel over its VMEM.
@@ -27,31 +27,239 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("name,B,H,KV,D,page,n,P,dtype", [
-    ("gpt2-xl", 8, 25, 25, 64, 16, 64, 512, jnp.bfloat16),
-    ("gqa-rep5", 8, 25, 5, 64, 16, 64, 512, jnp.bfloat16),
-    ("kv64-d128", 8, 64, 64, 128, 16, 64, 512, jnp.bfloat16),
-    ("xl-tp5-shard", 8, 5, 5, 64, 16, 64, 512, jnp.bfloat16),
-    ("xl-int8", 8, 25, 25, 64, 32, 32, 256, jnp.int8),
-    ("head-blocks", 2, 64, 64, 128, 128, 4, 16, jnp.float32),
-])
-def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, B, H, KV, D,
-                                              page, n, P, dtype):
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        paged_decode_attention,
-    )
+SHAPES = {
+    # name: (B, H, KV, D, page, n, P, pool dtype)
+    "gpt2-xl": (8, 25, 25, 64, 16, 64, 512, jnp.bfloat16),
+    "gqa-rep5": (8, 25, 5, 64, 16, 64, 512, jnp.bfloat16),
+    "kv64-d128": (8, 64, 64, 128, 16, 64, 512, jnp.bfloat16),
+    "xl-tp5-shard": (8, 5, 5, 64, 16, 64, 512, jnp.bfloat16),
+    "xl-int8": (8, 25, 25, 64, 32, 32, 256, jnp.int8),
+    "head-blocks": (2, 64, 64, 128, 128, 4, 16, jnp.float32),
+}
+def _default_format(one_chip, shape, dtype):
+    """The format the described device gives an array of this shape when
+    nothing asks otherwise."""
+    pool = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda p: p, donate_argnums=(0,)).lower(pool).compile()
+    return compiled.input_formats[0][0]
+
+
+LAYERS, LAYER = 3, 1  # the serving engine's [L, P, KV, page, D] pool and a layer of it
+
+
+def _kernel_args(one_chip, name, layered, T=None):
+    B, H, KV, D, page, n, P, dtype = SHAPES[name]
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    args = [sds((B, H, D), jnp.bfloat16), sds((P, KV, page, D), dtype),
-            sds((P, KV, page, D), dtype), sds((B, n), jnp.int32),
-            sds((B,), jnp.int32)]
+    pool = ((LAYERS,) if layered else ()) + (P, KV, page, D)
+    q = (B, H, D) if T is None else (B, T, H, D)
+    args = [sds(q, jnp.bfloat16), sds(pool, dtype), sds(pool, dtype),
+            sds((B, n), jnp.int32), sds((B,), jnp.int32)]
     if dtype == jnp.int8:
         args.append(sds((P, KV, 2), jnp.float32))
+    return args
+
+
+@pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, layered):
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention,
+    )
 
     def f(q, k, v, bt, pos, scales=None):
-        return paged_decode_attention(q, k, v, bt, pos, scales=scales)
+        return paged_decode_attention(
+            q, k, v, bt, pos, scales=scales, layer=LAYER if layered else None
+        )
 
+    compiled = jax.jit(f).lower(*_kernel_args(one_chip, name, layered)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
+@pytest.mark.parametrize("name", ["gpt2-xl", "kv64-d128", "xl-int8"])
+def test_paged_multitoken_kernel_compiles_for_v5e(one_chip, name, layered):
+    """The chunk-prefill width (128 query tokens) at the served shapes."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_multitoken_attention,
+    )
+
+    def f(q, k, v, bt, base, scales=None):
+        return paged_multitoken_attention(
+            q, k, v, bt, base, scales=scales, layer=LAYER if layered else None
+        )
+
+    args = _kernel_args(one_chip, name, layered, T=128)
     compiled = jax.jit(f).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [None, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_paged_token_write_compiles_for_v5e(one_chip, name, T):
+    """The decode step's one-token write and the verify step's T tokens a
+    slot, in place on both whole pools, at every shape the attention
+    kernels take (``head-blocks``: a block of the kv-heads a grid step)."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_token_write,
+        paged_token_write_blocks,
+    )
+
+    B, H, KV, D, page, n, P, dtype = SHAPES[name]
+    HB = paged_token_write_blocks(KV, page, D, jnp.dtype(dtype).itemsize, T or 1)
+    blocked = {("head-blocks", None): 4, ("head-blocks", 5): 4, ("kv64-d128", 5): 32}
+    assert HB == blocked.get((name, T), KV)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    at = (B,) if T is None else (B, T)
+    pool = sds((LAYERS, P, KV, page, D), dtype)
+    vals = sds(at + (KV, D), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda k, v, pidx, poff, kn, vn: paged_token_write(
+            k, v, LAYER, pidx, poff, kn, vn),
+        donate_argnums=(0, 1),
+    ).lower(pool, pool, sds(at, jnp.int32), sds(at, jnp.int32), vals, vals).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk", "prefill"])
+def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypatch):
+    """ISSUE 29's census: the paged programs at XL width, 512 pages
+    and 4 layers, the pools stored with the page axis split (``kv_cache.
+    pool_stored_shape``'s choice on a TPU; this process sees the CPU, so the
+    test hands it over), donated, and compiled as the scheduler compiles
+    them (``ProgramSet.aot``, over described pools): the device's default layout
+    of such a pool is row-major, no instruction copies, slices or
+    transposes a layer of a pool or more, and the temp stays under two
+    layers of K and V (the head's transposed ``wte`` is most of it; a
+    single re-laid pool would double it)."""
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    L, P, KV, page, D, B, W, C, Sp = 4, 512, 25, 16, 64, 8, 64, 128, 960
+    cfg = gpt2.GPT2Config(n_embd=KV * D, n_head=KV, n_layer=L,
+                          attn_impl="pallas", dtype=jnp.bfloat16)
+    # the pool is split, and the token write takes its kernel, where the
+    # backend is a TPU: say so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    shape = pool_stored_shape(L, P, KV, page, D, jnp.bfloat16)
+    assert shape == (L, 8, 64, KV, page, D)
+    # described as a live pool is: in the device's default format for its shape
+    pool = jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16)
+    )
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, k, v, bt, keys),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "verify": (  # three drafts a slot: one write and four attentions a layer
+            lambda p, k, v, tok, lens, bt: smodel.paged_verify_step(
+                cfg, p, tok, lens, k, v, bt),
+            (sds((B, 4), i32), sds((B,), i32), sds((B, W), i32)),
+        ),
+        "chunk": (
+            lambda p, k, v, ids, start, plen, pages, bt, key:
+                smodel.paged_chunk_prefill(
+                    cfg, p, ids, start, plen, k, v, pages, bt, key),
+            (sds((1, C), i32), sds((), i32), sds((), i32),
+             sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
+        "prefill": (
+            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(
+                cfg, p, ids, plen, k, v, pages, key),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32),
+             sds((2,), u32)),
+        ),
+    }[program]
+    # a ProgramSet over DESCRIBED pools (its own __init__ allocates them)
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params,
+        k_pool=pool, v_pool=pool, kv_scales=None, _kv_axis=pool.ndim - 3,
+        num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=L,
+    )
+    compiled = pset.aot(fn, host, with_params=True)
+    text = compiled.as_text()
+    assert pset.program_census(program, compiled)[0] == 0  # or it raises
+    assert "tpu_custom_call" in text or program == "prefill"
+    if program == "decode":  # one attention kernel and one token write a layer
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 2 * L
+        assert "dynamic-update-slice(" not in text
+    if program == "verify":
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 5 * L
+    took_in, _ = compiled.input_formats
+    for fmt in (took_in[1], took_in[2], *compiled.output_formats[:2]):
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3, 4, 5)
+    layer_kv_bytes = 2 * P * KV * page * 128 * 2  # 64 lanes pad to 128
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_kv_bytes
+
+
+def _default_layout(one_chip, shape, dtype):
+    return tuple(_default_format(one_chip, shape, dtype).layout.major_to_minor)
+
+
+@pytest.mark.parametrize("geometry", [
+    # (L, P, KV, page, D, dtype): the served cells, larger pools (ROADMAP R0),
+    # a tensor-parallel shard, int8 pages (R10), a pool of many small pages,
+    # and the 128-wide heads of queue R (OLMoE, Trinity-Mini), stored plainly
+    (48, 512, 25, 16, 64, jnp.bfloat16),
+    (48, 1024, 25, 16, 64, jnp.bfloat16),
+    (12, 4096, 12, 16, 64, jnp.bfloat16),
+    (12, 8192, 12, 16, 64, jnp.bfloat16),
+    (48, 1000, 5, 16, 64, jnp.bfloat16),
+    (48, 256, 25, 32, 64, jnp.int8),
+    (48, 512, 25, 32, 64, jnp.int8),
+    (16, 512, 16, 16, 128, jnp.bfloat16),
+    (16, 4096, 16, 16, 128, jnp.bfloat16),
+    (32, 2048, 4, 16, 128, jnp.bfloat16),
+    (27, 512, 16, 32, 128, jnp.int8),
+], ids=lambda g: "x".join(map(str, g[:5])) + "-" + jnp.dtype(g[5]).name)
+def test_stored_pool_shapes_are_row_major_by_default(one_chip, monkeypatch, geometry):
+    """``kv_cache.pool_stored_shape`` makes the pool row-major by its shape
+    alone: the device's default layout of what it returns keeps every axis in
+    its place, at the page counts and widths the cells and the roadmap's
+    next ones use."""
+    from deepspeed_tpu.serving.kv_cache import PAGE_GROUP, pool_stored_shape
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = pool_stored_shape(*geometry)
+    if geometry[4] % 128:
+        assert max(shape[1:-3]) <= PAGE_GROUP and len(shape) > 5
+    else:
+        assert len(shape) == 5
+    assert _default_layout(one_chip, shape, geometry[5]) == tuple(range(len(shape)))
+
+
+@pytest.mark.parametrize("shape, moved", [
+    # the page axis whole, as the parent stored it: pages minor-most
+    ((4, 512, 25, 16, 64), (0, 2, 3, 4, 1)),
+    # what pool_stored_shape cannot arrange, and ProgramSet refuses on the
+    # chip: a prime page count, an axis just over the head's width, more
+    # kv-heads or layers than the head is wide
+    ((4, 509, 25, 16, 64), (0, 2, 3, 4, 1)),
+    ((4, 65, 8, 25, 16, 64), (0, 2, 3, 4, 5, 1)),
+    ((4, 8, 64, 96, 16, 64), (0, 1, 2, 4, 5, 3)),
+    ((100, 8, 64, 5, 16, 64), (1, 2, 3, 4, 5, 0)),
+], ids=lambda x: "x".join(map(str, x)))
+def test_an_axis_longer_than_a_narrow_head_is_moved(one_chip, shape, moved):
+    """Why the pool is stored split, and where the split ends: with a
+    64-wide head the device's default layout puts an axis longer than the
+    head minor-most, whichever axis it is."""
+    assert _default_layout(one_chip, shape, jnp.bfloat16) == moved

@@ -348,6 +348,148 @@ class TestPagedAttentionHardware:
         )
 
 
+class TestServingPoolLayoutHardware:
+    """ISSUE 29: on the chip the K/V pools of a 64-wide head are stored with
+    the page axis split (``kv_cache.pool_stored_shape``), so their default
+    device layout is the row-major one the paged kernels and the page writes
+    work in, and every serving program takes and returns them so: no program
+    copies, slices or transposes a layer of a pool."""
+
+    @pytest.mark.parametrize("kv_dtype,page", [("bfloat16", 16), ("int8", 32)])
+    def test_programs_keep_the_pool_in_one_layout(self, kv_dtype, page):
+        from deepspeed_tpu.inference.engine import InferenceEngine
+        from deepspeed_tpu.models import gpt2
+
+        cfg = gpt2.GPT2Config(vocab_size=512, n_positions=256, n_embd=256,
+                              n_layer=3, n_head=4)
+        eng = InferenceEngine(
+            gpt2.make_module(cfg),
+            params=gpt2.init_params(cfg, jax.random.PRNGKey(0)),
+            dtype=jnp.bfloat16,
+        )
+        srv = eng.serve({
+            "max_slots": 4, "page_size": page, "num_pages": 256,
+            "max_prompt_len": 128, "max_new_tokens": 8,
+            "prefill_chunk_tokens": 64, "kv_cache_dtype": kv_dtype,
+        })
+        names = [name for name, _ in srv.executable_names()]
+        assert len(names) == 3
+        assert srv.k_pool.shape == (3, 4, 64, 4, page, 64)
+        row_major = tuple(range(6))
+        assert srv.k_pool.format.layout.major_to_minor == row_major
+        g = srv.metrics.get("serving_pool_relayout_ops")
+        assert {n: g.value(program=n) for n in names} == {n: 0 for n in names}
+        rs = np.random.RandomState(0)
+        reqs = [srv.submit(rs.randint(0, 512, (n,)).astype(np.int32),
+                           max_new_tokens=8, seed=i)
+                for i, n in enumerate((5, 40, 100, 17))]
+        srv.run()
+        assert all(len(r.tokens) == 8 for r in reqs)
+        # the donated pools came back in the layout they went in with
+        assert srv.k_pool.format.layout.major_to_minor == row_major
+        srv.check_no_leaks()
+
+    def test_host_tier_and_verify_read_the_split_pool(self):
+        """The readers outside the programs: the host tier demotes page
+        columns of the split pool and restores them (no miss, tokens as
+        without the tier), and ``verify()`` finds the pools donated and files
+        them under ``kv-pool``."""
+        from deepspeed_tpu.inference.engine import InferenceEngine
+        from deepspeed_tpu.models import gpt2
+
+        cfg = gpt2.GPT2Config(vocab_size=512, n_positions=256, n_embd=256,
+                              n_layer=3, n_head=4)
+        eng = InferenceEngine(
+            gpt2.make_module(cfg),
+            params=gpt2.init_params(cfg, jax.random.PRNGKey(0)),
+            dtype=jnp.bfloat16,
+        )
+        base = {
+            "max_slots": 4, "page_size": 16, "num_pages": 256,
+            "max_prompt_len": 128, "max_new_tokens": 8,
+            "prefill_chunk_tokens": 64, "prefix_cache": {"enabled": True},
+        }
+        rs = np.random.RandomState(0)
+        shared = rs.randint(0, 512, (64,)).astype(np.int32)
+        prompts = [np.concatenate([shared, rs.randint(0, 512, (n,)).astype(np.int32)])
+                   for n in (5, 40, 17)]
+
+        def rounds(srv):
+            out = []
+            for _ in range(2):
+                reqs = [srv.submit(p, max_new_tokens=8, seed=i)
+                        for i, p in enumerate(prompts)]
+                srv.run()
+                out += [list(r.tokens) for r in reqs]
+                if srv.tiering_enabled:
+                    srv.prefix_cache.evict(keep=0)
+                    srv.tiering.flush()
+            return out
+
+        want = rounds(eng.serve(base))
+        srv = eng.serve(dict(base, tiering={"enabled": True,
+                                            "host_budget_pages": 64}))
+        assert srv.k_pool.ndim == 6
+        assert rounds(srv) == want
+        t = srv.tiering
+        assert t.spills > 0 and t.restores > 0 and t.restore_misses == 0
+        # (the committed HBM pins are gpt2-tiny's: over budget here by design)
+        findings = srv.verify()
+        assert not [f for f in findings if f.rule == "donation-honored"], findings
+        for name, ana in srv._memory_analyses.items():
+            assert ana.by_category.get("kv-pool", 0) >= 2 * 3 * 256 * 4 * 16 * 64 * 2, name
+
+    @pytest.mark.parametrize("form", ["decode", "verify", "head_blocks"])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+    def test_token_write_kernel_matches_the_scatter(self, dtype, form, monkeypatch):
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        from deepspeed_tpu.ops.pallas import flash_attention
+
+        rs = np.random.RandomState(1)
+        L, P, KV, page, D, B = 3, 64, 25, 32, 64, 8
+        if form == "head_blocks":  # five kv-heads a grid step
+            monkeypatch.setattr(flash_attention, "VMEM_RESIDENT_BYTES", 1 << 19)
+            assert da.paged_token_write_blocks(KV, page, D, jnp.dtype(dtype).itemsize) == 5
+
+        def draw(shape):
+            if dtype == jnp.int8:
+                return jnp.asarray(rs.randint(-127, 128, shape), dtype)
+            return jnp.asarray(rs.randn(*shape), dtype)
+
+        kp, vp = draw((L, P, KV, page, D)), draw((L, P, KV, page, D))
+        pidx = np.asarray([5, 9, 0, 33, 0, 63, 1, 17], np.int32)
+        poff = np.asarray([0, 31, 0, 7, 0, 16, 15, 8], np.int32)
+        if form == "verify":
+            # three tokens a slot at consecutive positions: slot 1 crosses
+            # into its next page (10), slots 2 and 4 are idle on the scratch page
+            T = 3
+            nxt = {9: 10}
+            pidx = np.stack([
+                [p if o + t < page else nxt[p] for t in range(T)]
+                for p, o in zip(pidx, poff)]).astype(np.int32)
+            poff = np.stack([[(o + t) % page for t in range(T)] for o in poff]
+                            ).astype(np.int32)
+            pidx[[2, 4]], poff[[2, 4]] = 0, 0
+            kv, vv = draw((B, T, KV, D)), draw((B, T, KV, D))
+        else:
+            kv, vv = draw((B, KV, D)), draw((B, KV, D))
+        kv, vv = kv.at[4].set(kv[2]), vv.at[4].set(vv[2])  # the idle slots agree
+        if form == "verify":  # and so do their tokens: one element, one value
+            kv = kv.at[jnp.asarray([2, 4])].set(kv[2, :1])
+            vv = vv.at[jnp.asarray([2, 4])].set(vv[2, :1])
+        want = [np.asarray(kp).copy(), np.asarray(vp).copy()]
+        for at in np.ndindex(*pidx.shape):
+            want[0][1, int(pidx[at]), :, int(poff[at])] = np.asarray(kv[at])
+            want[1][1, int(pidx[at]), :, int(poff[at])] = np.asarray(vv[at])
+        got = jax.jit(
+            lambda k, v: da.paged_token_write(
+                k, v, 1, jnp.asarray(pidx), jnp.asarray(poff), kv, vv),
+            donate_argnums=(0, 1),
+        )(kp, vp)
+        np.testing.assert_array_equal(np.asarray(got[0]), want[0])
+        np.testing.assert_array_equal(np.asarray(got[1]), want[1])
+
+
 class TestRingFlashHardware:
     def test_ring_flash_compiles_on_chip(self):
         """Single-chip sp=1 ring: one diagonal step — compiles the flash

@@ -180,6 +180,326 @@ class TestTokenEquivalence:
         srv.check_no_leaks()
 
 
+class TestTokenWrite:
+    """ISSUE 29: where the paged kernels run, the one-token pool write is one
+    Pallas call for both pools, which asks no device layout of them, where
+    it was one scatter a pool (and still is elsewhere). Same elements, same
+    values: the pools it leaves are bitwise the scatter's, over random
+    tables, ragged lengths and idle slots on the scratch page, for the
+    decode step's targets and the verify step's. The kernel writes what it
+    is pointed at, so the indices are pinned in range as well."""
+
+    L, P, KV, PAGE, D, B, W = 2, 32, 2, 4, 8, 5, 4
+
+    @staticmethod
+    def _old_scatter(pool, l, pidx, poff, vals):
+        kv = jnp.arange(pool.shape[2])
+        return pool.at[l, pidx[..., None], kv, poff[..., None]].set(
+            vals.astype(pool.dtype)
+        )
+
+    def _state(self, seed, idle=(), lens=None):
+        rs = np.random.RandomState(seed)
+        bt = rs.choice(
+            np.arange(1, self.P), (self.B * self.W,), replace=False
+        ).reshape(self.B, self.W).astype(np.int32)
+        lens = np.asarray(
+            lens if lens is not None
+            else rs.randint(0, self.W * self.PAGE, (self.B,)), np.int32
+        )
+        for b in idle:  # as the scheduler leaves a free slot
+            bt[b], lens[b] = 0, 0
+        return rs, jnp.asarray(bt), jnp.asarray(lens)
+
+    def _vals(self, rs, shape, dtype, same_rows=()):
+        v = rs.randn(*shape, self.KV, self.D).astype(np.float32)
+        for b in same_rows[1:]:  # idle slots hold the same token and position
+            v[b] = v[same_rows[0]]
+        if dtype == jnp.int8:
+            return jnp.asarray(np.clip(v * 40, -127, 127), jnp.int8)
+        return jnp.asarray(v, dtype)
+
+    def _pool(self, rs, dtype):
+        shape = (self.L, self.P, self.KV, self.PAGE, self.D)
+        if dtype == jnp.int8:
+            return jnp.asarray(rs.randint(-127, 128, shape), jnp.int8)
+        return jnp.asarray(rs.randn(*shape), dtype)
+
+    def _decode_case(self, seed, idle, dtype):
+        """Pools, paged_decode_step's own write targets, and new K and V."""
+        rs, bt, lens = self._state(seed, idle)
+        pidx = jnp.take_along_axis(bt, (lens // self.PAGE)[:, None], axis=1)[:, 0]
+        poff = lens % self.PAGE
+        assert 0 <= int(pidx.min()) and int(pidx.max()) < self.P
+        assert all(int(pidx[b]) == 0 and int(poff[b]) == 0 for b in idle)
+        return (self._pool(rs, dtype), self._pool(rs, dtype), pidx, poff,
+                self._vals(rs, (self.B,), dtype, same_rows=idle),
+                self._vals(rs, (self.B,), dtype, same_rows=idle))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+    @pytest.mark.parametrize("idle", [(), (1, 3)])
+    @pytest.mark.parametrize("how", ["scatter", "pallas_call"])
+    def test_decode_write_is_bitwise_the_scatter(self, how, dtype, idle):
+        from deepspeed_tpu.ops.pallas.decode_attention import paged_token_write
+        from deepspeed_tpu.serving import model as smodel
+
+        kp, vp, pidx, poff, kv, vv = self._decode_case(11 + len(idle), idle, dtype)
+        for l in range(self.L):
+            if how == "pallas_call":  # the TPU's path, its body in the interpreter
+                k_new, v_new = paged_token_write(
+                    kp, vp, l, pidx, poff, kv, vv, interpret=True
+                )
+            else:
+                k_new, v_new = jax.jit(smodel._scatter_tokens, static_argnums=2)(
+                    kp, vp, l, pidx, poff, kv, vv
+                )
+            for new, pool, vals in ((k_new, kp, kv), (v_new, vp, vv)):
+                assert new.dtype == pool.dtype and new.shape == pool.shape
+                np.testing.assert_array_equal(
+                    np.asarray(new),
+                    np.asarray(self._old_scatter(pool, l, pidx, poff, vals)),
+                )
+                assert not np.array_equal(np.asarray(new), np.asarray(pool))
+
+    def _verify_case(self, case, T=3):
+        from deepspeed_tpu.serving import model as smodel
+
+        budget = self.W * self.PAGE
+        lens = (
+            [0, 3, 6, 9, 12] if case == "inside"  # 3, 6: the drafts cross a page
+            else [budget - 1, 3, budget - 2, 9, 12]  # drafts run past the row
+        )
+        rs, bt, lens = self._state(23, lens=lens)
+        return rs, lens, smodel._verify_write_targets(lens, bt, self.PAGE, T)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", ["inside", "past_the_row"])
+    @pytest.mark.parametrize("how", ["scatter", "pallas_call", "head_blocks"])
+    def test_verify_write_is_bitwise_the_scatter(self, how, case, dtype, monkeypatch):
+        """The verify step's ``[B, T]`` form, in one call: a slot's tokens
+        that share a page, that cross into the next one, and that run past
+        the row onto the scratch page (whose content nothing reads)."""
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        from deepspeed_tpu.ops.pallas import flash_attention
+        from deepspeed_tpu.serving import model as smodel
+
+        T = 3
+        rs, _, (pidx, poff) = self._verify_case(case, T)
+        kp, vp = self._pool(rs, dtype), self._pool(rs, dtype)
+        kv, vv = self._vals(rs, (self.B, T), dtype), self._vals(rs, (self.B, T), dtype)
+        if how == "head_blocks":  # VMEM for one kv-head a grid step
+            monkeypatch.setattr(flash_attention, "VMEM_RESIDENT_BYTES", 100_000)
+            assert da.paged_token_write_blocks(
+                self.KV, self.PAGE, self.D, kp.dtype.itemsize, T) == 1
+        if how == "scatter":
+            k_new, v_new = jax.jit(smodel._scatter_tokens, static_argnums=2)(
+                kp, vp, 1, pidx, poff, kv, vv)
+        else:
+            k_new, v_new = da.paged_token_write(
+                kp, vp, 1, pidx, poff, kv, vv, interpret=True)
+        for new, pool, vals in ((k_new, kp, kv), (v_new, vp, vv)):
+            want = np.asarray(self._old_scatter(pool, 1, pidx, poff, vals))
+            np.testing.assert_array_equal(np.asarray(new)[:, 1:], want[:, 1:])
+            if case == "inside":  # nothing landed on the scratch page
+                np.testing.assert_array_equal(np.asarray(new)[:, 0], want[:, 0])
+            assert not np.array_equal(np.asarray(new), np.asarray(pool))
+
+    @pytest.mark.parametrize("case", ["inside", "past_the_row"])
+    def test_verify_targets_stay_in_range(self, case):
+        T = 3
+        budget = self.W * self.PAGE
+        _, lens, (pidx, poff) = self._verify_case(case, T)
+        assert 0 <= int(pidx.min()) and int(pidx.max()) < self.P
+        assert 0 <= int(poff.min()) and int(poff.max()) < self.PAGE
+        past = np.asarray(lens)[:, None] + np.arange(T)[None, :] >= budget
+        assert past.any() == (case == "past_the_row")
+        # what runs past the slot's row lands on the scratch page, nowhere else
+        np.testing.assert_array_equal(np.asarray(pidx)[past], 0)
+        assert (np.asarray(pidx)[~past] > 0).all()
+
+    def test_the_write_takes_the_kernel_where_the_paged_kernels_run(self, monkeypatch):
+        """``_scatter_tokens`` asks ``paged_token_write_ok`` (a TPU, the page
+        rule, a K and a V page of a block of heads in VMEM): every shape the
+        attention kernels take."""
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        from deepspeed_tpu.serving import model as smodel
+
+        assert not da.paged_token_write_ok(25, 16, 64)  # this backend is no TPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert da.paged_token_write_ok(25, 16, 64)
+        assert da.paged_token_write_ok(64, 16, 128)
+        assert da.paged_token_write_ok(25, 32, 64, itemsize=1)
+        assert not da.paged_token_write_ok(25, 12, 64)       # the page rule
+        assert da.paged_token_write_ok(25, 16, 64, T=5)      # the verify step
+        assert da.paged_token_write_blocks(25, 16, 64) == 25
+        assert da.paged_token_write_blocks(64, 128, 128, 4) == 4  # 64 KB a page
+        assert da.paged_token_write_ok(64, 128, 128, 4)
+        assert not da.paged_token_write_ok(4, 1024, 128, 4)  # no head's pages fit
+        calls = []
+
+        def spy(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
+            calls.append((l, k_vals.shape))
+            return k_pool, v_pool
+
+        monkeypatch.setattr(da, "paged_token_write", spy)
+        pool = jnp.zeros((2, 8, 5, 16, 64), jnp.bfloat16)
+        i = jnp.zeros((3,), jnp.int32)
+        vals = jnp.zeros((3, 5, 64))
+        smodel._scatter_tokens(pool, pool, 1, i, i, vals, vals)
+        assert calls == [(1, (3, 5, 64))]
+
+    def test_int8_token_write_keeps_scale_discipline(self):
+        """``_write_pool_tokens``: offset 0 establishes the page's scale from
+        this token, any other offset codes against the frozen one; K's and
+        V's scales sit in their own columns."""
+        from deepspeed_tpu.ops.quantizer import kv_page_scale, quantize_kv_token
+        from deepspeed_tpu.serving import model as smodel
+
+        rs, bt, lens = self._state(5, lens=[0, 1, 4, 7, 8])
+        pidx = jnp.take_along_axis(bt, (lens // self.PAGE)[:, None], axis=1)[:, 0]
+        poff = lens % self.PAGE
+        kp, vp = self._pool(rs, jnp.int8), self._pool(rs, jnp.int8)
+        scales = jnp.asarray(
+            rs.rand(self.L, self.P, self.KV, 2) + 0.5, jnp.float32
+        )
+        kv = jnp.asarray(rs.randn(self.B, self.KV, self.D), jnp.float32)
+        vv = jnp.asarray(rs.randn(self.B, self.KV, self.D), jnp.float32)
+        k_new, v_new, new_scales = smodel._write_pool_tokens(
+            kp, vp, scales, 0, pidx, poff, kv, vv
+        )
+        fresh = np.asarray(poff) == 0
+        for col, (new, pool, vals) in enumerate(((k_new, kp, kv), (v_new, vp, vv))):
+            want_s = np.where(
+                fresh[:, None], np.asarray(kv_page_scale(vals)),
+                np.asarray(scales[0, pidx, :, col]),
+            )
+            np.testing.assert_array_equal(
+                np.asarray(new_scales[0, pidx, :, col]), want_s
+            )
+            codes = np.asarray(quantize_kv_token(vals, jnp.asarray(want_s)))
+            got = np.asarray(new)[0, np.asarray(pidx), :, np.asarray(poff)]
+            np.testing.assert_array_equal(got, codes)
+            # every other layer stays as it was
+            np.testing.assert_array_equal(np.asarray(new[1]), np.asarray(pool[1]))
+        np.testing.assert_array_equal(np.asarray(new_scales[1]), np.asarray(scales[1]))
+
+
+class TestSplitPagePoolEngine:
+    """The whole engine over pools stored with the page axis split in two, as
+    on a TPU with a narrow head (``kv_cache.pool_stored_shape``; forced here:
+    the choice reads the backend). Every program works on the
+    ``[L, P, KV, page, D]`` view: prefill, chunked prefill, decode, the
+    speculative verify step, the prefix cache's copy-on-write, int8 pages,
+    tensor-parallel shards, the disaggregated hand-off and the host tier's
+    demotion and restore give the token streams of the plainly stored pool."""
+
+    @staticmethod
+    def _split(n_layer, num_pages, n_kv_head, page_size, head_dim, dtype):
+        group = max(g for g in (1, 2, 3, 4) if num_pages % g == 0)
+        return (n_layer, num_pages // group, group, n_kv_head, page_size, head_dim)
+
+    @pytest.mark.parametrize("features", ["plain", "int8", "tp2_disaggregated"])
+    def test_verify_reads_the_stored_pool_as_it_reads_the_plain_one(
+        self, inference_engine, monkeypatch, features
+    ):
+        """``ServingEngine.verify()`` over the split pool: the donation rule
+        finds the pools' aliased entry parameters by their stored dims and
+        the memory engine files them under ``kv-pool``, so findings and
+        categories are the plain pool's."""
+        from deepspeed_tpu.serving import kv_cache
+
+        cfg = dict(SERVING_CFG, prefill_chunk_tokens=4)
+        if features == "int8":
+            cfg.update(kv_cache_dtype="int8")
+        elif features == "tp2_disaggregated":
+            cfg.update(placement={"tp": 2, "disaggregate": True})
+
+        def read(srv):
+            findings = sorted((f.rule, f.path) for f in srv.verify())
+            # what enters: the weights alone under params, the pools donated
+            # (kv-pool's peak also counts what the CPU copies of a view)
+            entry = {
+                name: (ana.by_category.get("params", 0), ana.args_bytes,
+                       ana.aliased_bytes)
+                for name, ana in srv._memory_analyses.items()
+            }
+            kv = {name: ana.by_category.get("kv-pool", 0)
+                  for name, ana in srv._memory_analyses.items()}
+            return findings, entry, kv
+
+        want = read(inference_engine.serve(cfg))
+        monkeypatch.setattr(kv_cache, "pool_stored_shape", self._split)
+        stored = inference_engine.serve(cfg)
+        assert stored.k_pool.ndim == 6
+        assert not [f for f in stored.verify() if f.rule == "donation-honored"]
+        got = read(stored)
+        assert got[:2] == want[:2]
+        assert all(got[2][name] >= n > 0 for name, n in want[2].items())
+
+    @pytest.mark.parametrize("features", [
+        "plain", "chunk_spec_prefix", "int8", "tp2_disaggregated", "tiering",
+    ])
+    def test_token_streams_match_the_plain_pool(
+        self, tiny_cfg, inference_engine, monkeypatch, features
+    ):
+        from deepspeed_tpu.serving import kv_cache
+
+        cfg = dict(SERVING_CFG)
+        if features == "chunk_spec_prefix":
+            cfg.update(prefill_chunk_tokens=4, prefix_cache={"enabled": True},
+                       speculative={"enabled": True, "k": 2})
+        elif features == "int8":
+            cfg.update(kv_cache_dtype="int8", prefill_chunk_tokens=4)
+        elif features == "tp2_disaggregated":
+            cfg.update(placement={"tp": 2, "disaggregate": True},
+                       prefill_chunk_tokens=4)
+        elif features == "tiering":
+            cfg.update(num_pages=24, prefix_cache={"enabled": True},
+                       tiering={"enabled": True, "host_budget_pages": 64})
+        rs = np.random.RandomState(5)
+        shared = rs.randint(0, tiny_cfg.vocab_size, (8,)).astype(np.int32)
+        prompts = [rs.randint(0, tiny_cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in (2, 5, 12, 7, 11, 3)]
+        prompts += [np.concatenate([shared, p[:3]]) for p in prompts[:2]] + [shared]
+        prompts += prompts[:3]  # again, after the index has turned over
+
+        def streams(srv):
+            out = []
+            for again in range(2 if features == "tiering" else 1):
+                reqs = [srv.submit(p, max_new_tokens=6, seed=i)
+                        for i, p in enumerate(prompts)]
+                srv.run()
+                out += [list(r.tokens) for r in reqs]
+                if features == "tiering":
+                    # every indexed page down to the host tier; the second
+                    # round then restores what it shares with the first
+                    srv.prefix_cache.evict(keep=0)
+                    srv.tiering.flush()
+            if srv.prefix_cache is not None:
+                srv.release_prefix_cache()
+            srv.check_no_leaks()
+            return out
+
+        plain = inference_engine.serve(cfg)
+        assert plain.k_pool.ndim == 5
+        want = streams(plain)
+
+        monkeypatch.setattr(kv_cache, "pool_stored_shape", self._split)
+        stored = inference_engine.serve(cfg)
+        assert stored.k_pool.ndim == 6 and stored.k_pool.shape[2] == 4
+        assert streams(stored) == want
+        if features == "tiering":  # the host tier worked, and alike
+            tiers = [srv.tiering for srv in (plain, stored)]
+            for t in tiers:
+                assert t.spills > 0 and t.restores > 0 and t.restore_misses == 0
+            assert len({(t.spills, t.restores) for t in tiers}) == 1
+        if not stored.disaggregated:  # there the hand-off's timing picks the pages
+            np.testing.assert_array_equal(
+                np.asarray(kv_cache.pool_view(stored.k_pool)),
+                np.asarray(plain.k_pool),
+            )
+
+
 class TestMidFlightAdmission:
     def test_queued_requests_fill_vacated_slots(self, tiny_cfg, inference_engine, shared_srv):
         """More requests than slots: finished sequences vacate mid-flight and
@@ -1002,6 +1322,105 @@ class TestServingStats:
         assert g.value(metric="ttft", q="p50") == st["ttft"]["p50_s"]
         prom = srv.metrics.to_prometheus()
         assert "serving_latency_quantile_seconds" in prom
+
+    def test_program_census_gauges_and_phase_attrs(self, inference_engine):
+        """ISSUE 29: ``_ensure_compiled`` leaves, per compiled program, the
+        count of pool-layer-sized copies / slices / transposes in its HLO
+        and its temp bytes, as two gauges and as attrs of the
+        ``ds.init.programs`` phase. (The count is only 0 where the kernels
+        run and the pool is stored for them: on a TPU, ``-m tpu``.)"""
+        from deepspeed_tpu.serving.placement import pool_relayout_ops
+        from deepspeed_tpu.telemetry import spans
+
+        srv = inference_engine.serve(dict(SERVING_CFG, prefill_chunk_tokens=4))
+        t0 = spans._clock()
+        names = [name for name, _ in srv.executable_names()]
+        assert len(names) == 3
+        ph = [r for r in spans.phases(since=t0) if r[0] == "ds.init.programs"]
+        assert len(ph) == 1
+        attrs = ph[0][3]
+        for key, gauge in (("relayout_ops", "serving_pool_relayout_ops"),
+                           ("temp_bytes", "serving_program_temp_bytes")):
+            got = dict(kv.split("=") for kv in attrs[key].split())
+            assert sorted(got) == sorted(names)
+            g = srv.metrics.get(gauge)
+            for name in names:
+                assert g.value(program=name) == int(got[name])
+        rec = srv._program_info["serving_decode"]
+        assert srv.decode_set.program_census("serving_decode", rec["exe"]) == (
+            srv.metrics.get("serving_pool_relayout_ops").value(
+                program="serving_decode"),
+            srv.metrics.get("serving_program_temp_bytes").value(
+                program="serving_decode"),
+        )
+        assert srv.k_pool.ndim == 5  # stored as it is viewed off the TPU
+        # the count itself, on HLO as the TPU compiler prints it
+        hlo = """
+  %copy.1 = bf16[4,512,25,16,64]{4,3,2,1,0:T(8,128)(2,1)} copy(%p)
+  %slice.7 = bf16[1,512,25,16,64]{1,4,3,2,0:T(8,128)(2,1)S(1)} slice(%p), slice={[0:1]}
+  %copy.2 = bf16[50257,1600]{1,0:T(8,128)(2,1)} copy(%wte)
+  %dus = bf16[4,512,25,16,64]{4,3,2,1,0} dynamic-update-slice(%p, %u, %i)
+  ROOT %transpose.3 = bf16[512,25,64,16]{3,2,1,0} transpose(%x), dimensions={0,1,3,2}
+  %copy.9 = bf16[25,16,64]{2,1,0} copy(%page)
+"""
+        assert pool_relayout_ops(hlo, 512 * 25 * 16 * 64) == 3
+
+    @pytest.mark.parametrize("case", ["relayout_around_a_kernel", "moved_pool",
+                                      "program_layout_differs"])
+    def test_a_pool_out_of_its_layout_fails_at_set_up(
+        self, inference_engine, monkeypatch, case
+    ):
+        """ISSUE 29: a pool that is not where the paged kernels read it, or a
+        program that does not keep it there, raises at set-up and is not
+        re-laid out silently in every call."""
+        from types import SimpleNamespace as NS
+
+        from deepspeed_tpu.ops.pallas import decode_attention
+        from deepspeed_tpu.serving.kv_cache import PoolLayoutError
+
+        srv = inference_engine.serve(SERVING_CFG)
+        pset = srv.decode_set
+        if case == "relayout_around_a_kernel":
+            srv._ensure_compiled()
+            exe = srv._program_info["serving_decode"]["exe"]
+            assert pset.program_census("serving_decode", exe)[0] > 0  # jnp: counted
+            with_kernel = NS(
+                as_text=lambda: exe.as_text() + '\ncustom_call_target="tpu_custom_call"',
+                memory_analysis=exe.memory_analysis,
+            )
+            with pytest.raises(PoolLayoutError, match="serving_decode: .* around its kernels"):
+                pset.program_census("serving_decode", with_kernel)
+        elif case == "moved_pool":
+            pset._check_pool_layout()  # off the TPU nothing is asked
+            monkeypatch.setattr(decode_attention, "paged_page_ok", lambda *a: True)
+            pset._check_pool_layout()  # row-major, as the CPU lays everything
+            moved = NS(layout=NS(major_to_minor=(0, 2, 3, 4, 1)))
+            pset.k_pool = NS(format=moved, dtype=pset.k_pool.dtype, ndim=5,
+                             shape=pset.k_pool.shape)
+            with pytest.raises(PoolLayoutError, match="num_pages"):
+                pset._check_pool_layout()
+        else:
+            real = pset.placement.aot
+
+            def aot(*a):
+                exe = real(*a)
+                took, kw = exe.input_formats
+                other = NS(layout="pages-minor")
+                return NS(input_formats=((took[0], other) + took[2:], kw),
+                          output_formats=exe.output_formats)
+
+            monkeypatch.setattr(pset.placement, "aot", aot)
+            with pytest.raises(PoolLayoutError, match="takes a float32"):
+                pset.aot(lambda k, v, i: (k, v, i), (jnp.zeros((), jnp.int32),))
+
+    @pytest.mark.parametrize("num_pages, axes", [
+        (24, (24,)), (64, (64,)), (65, (5, 13)), (256, (4, 64)), (512, (8, 64)),
+        (1000, (20, 50)), (8192, (2, 64, 64)), (509, (509,)), (508, (127, 4)),
+    ])
+    def test_page_axes_of_a_stored_pool(self, num_pages, axes):
+        from deepspeed_tpu.serving.kv_cache import _page_axes
+
+        assert _page_axes(num_pages) == axes and np.prod(axes) == num_pages
 
     def test_straggler_detection_with_fake_clock(self, inference_engine):
         """ISSUE 5 watchdog: a request resident in its slot far beyond the

@@ -405,7 +405,9 @@ class _FakePSet:
             n_layer, pages, kv, page, d
         ).astype(np.float32)
         self.v_pool = self.k_pool * 2
-        self.kv_scales = None
+
+    def page_column(self, pid):
+        return self.k_pool[:, pid], self.v_pool[:, pid], None
 
 
 class TestDemoteOrderingLockstep:
