@@ -41,8 +41,8 @@ Four engines over one findings/severity/suppression model:
 
 Front ends: the ``python -m deepspeed_tpu.tools.dslint`` CLI (with the
 committed-baseline CI gate, ``--engines a..g`` selection, and ``--sarif``
-export), the ``lint``/``dsan``/``dsmem``-marked tier-1 tests, and
-``bench.py``'s finding counters. Engine F has no file form — it runs where
+export) and the ``lint``/``dsan``/``dsmem``-marked tier-1 tests. Engine F
+has no file form — it runs where
 live param trees exist (``engine.verify_program()``, the dsmem tests). See
 ``docs/ANALYSIS.md`` for the rule catalog and the suppression / baseline
 workflow.

@@ -2,7 +2,7 @@
 
 Both analysis engines — the AST linter (``ast_rules``) and the HLO program
 verifier (``hlo_rules``) — report through one :class:`Finding` shape so the
-CLI, the baseline file, the pytest gate, and bench.py all consume a single
+CLI, the baseline file and the pytest gate all consume a single
 stream. A finding is identified across runs by its :meth:`Finding.fingerprint`
 — rule + file (or pseudo-path ``hlo://<program>``) + enclosing symbol + a
 hash of the offending line text — deliberately NOT the line number, so a

@@ -671,7 +671,7 @@ def xla_peak_bytes(compiled) -> Optional[int]:
     An executable deserialized from the persistent compilation cache
     reports ``alias_size_in_bytes=0`` even though its module header still
     carries the ``input_output_alias`` table — recompute the aliased bytes
-    from the text in that case, or a cached bench run would inflate the
+    from the text in that case, or a run served from the cache would inflate the
     reference by the whole donated state."""
     try:
         ma = compiled.memory_analysis()
@@ -771,7 +771,7 @@ def resolve_budget(mcfg, program: str,
 def headroom_pct(budget_bytes: int, peak_bytes: int) -> Optional[float]:
     """Budget headroom as a percentage (positive = under budget), None when
     no positive budget is set — the ONE definition every report shares
-    (engine/serving ``memory_report()``, bench, env_report)."""
+    (engine/serving ``memory_report()``)."""
     if not budget_bytes or budget_bytes <= 0:
         return None
     return round(100.0 * (budget_bytes - peak_bytes) / budget_bytes, 2)

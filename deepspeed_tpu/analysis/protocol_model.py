@@ -227,7 +227,7 @@ class ProtoReport:
 
 
 def default_model_configs() -> Dict[str, ProtoModelConfig]:
-    """The stock configurations the dslint gate / bench explore."""
+    """The stock configurations the dslint gate explores."""
     return {
         "shared": ProtoModelConfig(disaggregated=False),
         "disaggregated": ProtoModelConfig(disaggregated=True),

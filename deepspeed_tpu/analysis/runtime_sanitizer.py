@@ -26,9 +26,9 @@ access — and ONLY while a sanitizer is installed. When
 ``analysis.sanitizer`` is disabled, ``note_read``/``note_write`` are
 rebound to empty no-op functions and :class:`SanitizedLock` skips its
 recording branch, so the instrumented hot paths (``StepTracer.emit``, the
-checkpoint writer) pay nothing but the call itself (ISSUE 9 satellite —
-BENCH_pr8 measured 35.7% overhead on the instrumented emit micro-path with
-the recorder active; BENCH_pr9 re-measures both modes).
+checkpoint writer) pay nothing but the call itself (ISSUE 9 satellite: on
+the CPU the active recorder cost a third of the instrumented emit
+micro-path; not measured on the chip).
 """
 
 from __future__ import annotations

@@ -187,7 +187,7 @@ class CommsLogger:
     def _assumed_busbw_gbps() -> float:
         """Nominal interconnect bandwidth (GB/s) used to ESTIMATE
         latency/bandwidth for rows recorded at trace time but never measured
-        ("~"-prefixed columns): the peak table's per-link ICI figure for this
+        ("~"-prefixed columns): the peak table's ICI figure for this
         ``device_kind``; override with DS_COMM_ASSUMED_BUSBW_GBPS."""
         env = os.environ.get("DS_COMM_ASSUMED_BUSBW_GBPS")
         if env:
@@ -329,8 +329,7 @@ def record_from_compiled(compiled, reset: bool = False) -> dict:
     )
     # Track computation boundaries: a collective inside a while-loop body
     # (gas scan, decode loop) executes once PER ITERATION but prints once in
-    # HLO — the same scan-counted-once pitfall as cost_analysis (bench.py
-    # docstring). Those rows get axis "xla-loop" so the table says
+    # HLO — the same scan-counted-once pitfall as cost_analysis. Those rows get axis "xla-loop" so the table says
     # per-iteration, not per-step.
     cur_computation = ""
     comp_pat = re.compile(r"^\s*%?([\w.\-]+)\s*(?:\([^)]*\))?\s*(?:->[^{]*)?\{")

@@ -179,8 +179,8 @@ def records() -> Dict[Tuple[str, str], Dict[str, float]]:
 def records_by_axis() -> Dict[str, Dict[str, float]]:
     """Per-axis {logical_bytes, wire_bytes, ratio} aggregate of everything
     recorded so far. NOTE: like the CommsLogger wrappers, records accrue on
-    every trace — deliberately re-lowering the same program (bench's
-    device-only loop, ``Compiled``-based accounting) inflates the absolute
+    every trace — deliberately re-lowering the same program
+    (``Compiled``-based accounting) inflates the absolute
     byte totals, though the ratio survives. The engine's per-step numbers
     (``_compression_stats``) are therefore derived analytically from the
     bucket plan instead of from this registry."""

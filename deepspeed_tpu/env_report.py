@@ -1,7 +1,10 @@
 """``ds_report`` — environment and op-compatibility report.
 
 Analog of reference ``deepspeed/env_report.py`` (140 LoC): versions, device
-inventory, native-op build/compat table.
+inventory, native-op build/compat table, and which planes and config sections
+this build has. Everything printed is observed in the running process (and,
+for the two gate ledgers, found from the working directory); no record of an
+earlier run is read. Measured numbers live in ``PERF.md``.
 
     python -m deepspeed_tpu.env_report
 """
@@ -15,10 +18,7 @@ GREEN_OK = "\033[92m[OKAY]\033[0m"
 RED_NO = "\033[93m[NO]\033[0m"
 
 
-def main() -> int:
-    import jax
-
-    import deepspeed_tpu
+def _ops() -> None:
     from deepspeed_tpu.ops.op_builder import op_report
 
     print("-" * 60)
@@ -28,6 +28,13 @@ def main() -> int:
     for name, compat, built in op_report():
         print(f"{name:<20} {GREEN_OK if compat else RED_NO:<21} {GREEN_OK if built else RED_NO}")
     print("-" * 60)
+
+
+def _environment() -> None:
+    import jax
+
+    import deepspeed_tpu
+
     print("General environment:")
     print(f"deepspeed_tpu ....... {deepspeed_tpu.__version__}")
     print(f"python .............. {sys.version.split()[0]}")
@@ -61,7 +68,13 @@ def main() -> int:
     print(f"devices ............. {len(devs)} x {devs[0].device_kind if devs else '-'}")
     print(f"process count ....... {jax.process_count()}")
     print("-" * 60)
-    print("Telemetry / introspection:")
+
+
+def _telemetry() -> None:
+    import jax
+
+    print("Telemetry:")
+    devs = jax.devices()
     try:
         import jax.profiler  # noqa: F401
 
@@ -94,6 +107,9 @@ def main() -> int:
         "A.jsonl B.jsonl"
     )
     print("-" * 60)
+
+
+def _analysis() -> None:
     print("Static analysis (dslint):")
     try:
         from deepspeed_tpu.analysis import (
@@ -149,18 +165,17 @@ def main() -> int:
     except Exception as e:
         print(f"analysis ............ {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _memory() -> None:
     print("Memory (dsmem):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.analysis import (
             MEMORY_RULES,
             SHARDING_RULES,
             find_budget_file,
             load_budgets,
         )
-        from deepspeed_tpu.analysis.memory_rules import headroom_pct
 
         print(
             f"engine E/F rules .... {GREEN_OK} "
@@ -170,36 +185,12 @@ def main() -> int:
         budget_path = find_budget_file()
         if budget_path:
             budgets = load_budgets(budget_path)
-            # the bench artifact next to the ledger carries the measured
-            # per-program peaks (env_report stays cheap: no compiles here)
-            peaks, kv_bytes = {}, {}
-            bench_path = os.path.join(
-                os.path.dirname(os.path.abspath(budget_path)),
-                "BENCH_pr9.json",
-            )
-            if os.path.exists(bench_path):
-                try:
-                    with open(bench_path, encoding="utf-8") as fh:
-                        doc = json.load(fh)
-                    for prog, rec in (doc.get("programs") or {}).items():
-                        peaks[prog] = rec.get("peak_bytes_est")
-                        kv_bytes[prog] = rec.get("kv_pool_bytes", 0)
-                except Exception:
-                    pass
             print(f"budget ledger ....... {budget_path}: "
                   f"{len(budgets)} program(s)")
+            # the peaks these budgets gate come from a compile: ask the
+            # engine (memory_report()), not this report
             for prog in sorted(budgets):
-                b = budgets[prog]
-                peak = peaks.get(prog)
-                head = headroom_pct(b, peak) if peak else None
-                if peak and head is not None:
-                    extra = (f"peak {peak / 1e6:.2f} MB, "
-                             f"headroom {head:+.1f}%")
-                    if kv_bytes.get(prog):
-                        extra += f", kv pool {kv_bytes[prog] / 1e6:.2f} MB"
-                else:
-                    extra = "peak unmeasured — run bench.py"
-                print(f"  {prog:<18} budget {b / 1e6:.2f} MB ({extra})")
+                print(f"  {prog:<18} budget {budgets[prog] / 1e6:.2f} MB")
         else:
             print("budget ledger ....... none (hbm-over-budget gate off)")
         print(
@@ -209,6 +200,9 @@ def main() -> int:
     except Exception as e:
         print(f"dsmem ............... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _request_tracing() -> None:
     print("Request tracing (ISSUE 11):")
     try:
         from deepspeed_tpu.runtime.config import ServingConfig, TelemetryConfig
@@ -249,11 +243,11 @@ def main() -> int:
     except Exception as e:
         print(f"request tracing ..... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _placement() -> None:
     print("Serving placement (ISSUE 14):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.runtime.config import ServingConfig
         from deepspeed_tpu.serving.placement import (
             GPT2_SERVING_RULES,
@@ -273,32 +267,6 @@ def main() -> int:
             "one placement, decode/verify on another, KV handoff over "
             "the page machinery)"
         )
-        # per-device pool bytes come from the committed bench artifact —
-        # env_report stays cheap (no compiles, no pool allocation here)
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr14.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            for tp, rec in sorted((doc.get("tp_sweep") or {}).items()):
-                pools = ", ".join(
-                    f"{name}: {b / 1e6:.2f} MB/device"
-                    for name, b in (rec.get(
-                        "per_device_pool_bytes") or {}).items()
-                )
-                print(f"  {tp:<18} kv pool {pools}")
-            res = doc.get("resident_sessions_at_fixed_device_hbm") or {}
-            if res:
-                print(
-                    f"  resident sessions  "
-                    f"{res.get('sessions')} at fixed per-device HBM "
-                    f"(x{res.get('ratio')})"
-                )
-        else:
-            print("  pool bytes ......... unmeasured — run bench.py "
-                  "(BENCH_TP_SERVING_ONLY=1)")
         print(
             "program map ......... shared: all programs on one placement; "
             "disaggregated: serving_prefill/_chunk_prefill → 'prefill', "
@@ -313,11 +281,11 @@ def main() -> int:
     except Exception as e:
         print(f"serving placement ... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _protocol() -> None:
     print("Protocol (dsproto, ISSUE 15):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.analysis import (
             PROTOCOL_MODEL_RULES,
             PROTOCOL_RULES,
@@ -337,33 +305,6 @@ def main() -> int:
             f"retry_max={pcfg.retry_max} max_states={pcfg.max_states} "
             "(analysis.protocol)"
         )
-        # exploration stats come from the committed bench artifact —
-        # env_report stays cheap (no state-space walk here)
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr15.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            for mode, rec in sorted((doc.get("model") or {}).items()):
-                print(
-                    f"  {mode:<18} {rec.get('states')} states / "
-                    f"{rec.get('transitions')} transitions in "
-                    f"{rec.get('wall_s')}s, "
-                    f"{rec.get('violations', 0)} violation(s)"
-                )
-            replay = doc.get("replay_self_check")
-            if replay is not None:
-                print(
-                    f"  replay self-check  "
-                    f"{GREEN_OK if replay.get('ok') else RED_NO} "
-                    f"(mutations red: "
-                    f"{', '.join(replay.get('mutations_red', []))})"
-                )
-        else:
-            print("  exploration ........ unmeasured — run bench.py "
-                  "(BENCH_DSPROTO_ONLY=1)")
         print(
             "run checker ......... python -m deepspeed_tpu.tools.dslint "
             "deepspeed_tpu/serving/ --engines g (model counterexamples "
@@ -372,11 +313,11 @@ def main() -> int:
     except Exception as e:
         print(f"protocol ............ {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _kv_heat() -> None:
     print("KV heat (ISSUE 16):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.runtime.config import KVHeatConfig
         from deepspeed_tpu.telemetry.kv_heat import SCHEMA as HEAT_SCHEMA
 
@@ -392,37 +333,6 @@ def main() -> int:
             f"(cold-fraction gauges; segment_events={hcfg.segment_events}, "
             f"flush_interval={hcfg.flush_interval})"
         )
-        # headline curves come from the committed bench artifact —
-        # env_report stays cheap (no serving replay here)
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr16.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            ov = (doc.get("overhead") or {}).get("heat_overhead_pct")
-            if ov is not None:
-                print(f"  hook overhead ...... {ov}% of traced span "
-                      "(pin: <= 2%)")
-            for name, rec in sorted((doc.get("cold_fraction") or {}).items()):
-                end = rec.get("end") or {}
-                cf = ", ".join(
-                    f">{th}s: {100.0 * f:.0f}%" if f is not None else f">{th}s: -"
-                    for th, f in sorted(end.items(), key=lambda kv: float(kv[0]))
-                )
-                print(f"  {name:<18} {cf}")
-            pol = (doc.get("spill_policies") or {}).get("policies") or {}
-            if pol:
-                best = min(
-                    pol.items(),
-                    key=lambda kv: (kv[1].get("restore_stalls", 0),
-                                    kv[1].get("spills", 0), kv[0]),
-                )[0]
-                print(f"  spill what-if ...... fewest restore stalls: {best}")
-        else:
-            print("  curves ............. unmeasured — run bench.py "
-                  "(BENCH_KVHEAT_ONLY=1)")
         print(
             "report CLI .......... python -m deepspeed_tpu.tools.kv_heat "
             "kv_heat.jsonl [--heatmap] [--page N] [--what-if] "
@@ -431,11 +341,11 @@ def main() -> int:
     except Exception as e:
         print(f"kv heat ............. {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _kv_tiering() -> None:
     print("KV tiering (ISSUE 17):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.runtime.config import TieringConfig
         from deepspeed_tpu.serving.tiering import TIERING_POLICIES
 
@@ -451,50 +361,6 @@ def main() -> int:
             f"prefetch_depth={tcfg.prefetch_depth}, "
             f"crc={'on' if tcfg.crc else 'off'}"
         )
-        # tier sizes + spill/restore counters come from the committed bench
-        # artifact — env_report stays cheap (no serving replay here)
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr17.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            tiers = doc.get("tiers") or {}
-            if tiers:
-                print(
-                    f"  tier sizes ........ device {tiers.get('device_pages')}"
-                    f" pages / host {tiers.get('host_budget_pages')} pages "
-                    f"x {tiers.get('page_bytes')} B "
-                    f"(host buffer {(tiers.get('host_bytes') or 0) / 1e6:.2f}"
-                    " MB pinned)"
-                )
-            run = doc.get("tiering") or {}
-            cnt = doc.get("counters") or {}
-            if cnt:
-                print(
-                    f"  spill/restore ..... policy {run.get('policy')}: "
-                    f"{cnt.get('spills')} spills "
-                    f"({(cnt.get('spilled_bytes') or 0) / 1e6:.2f} MB) / "
-                    f"{cnt.get('restores')} restores, "
-                    f"{cnt.get('restore_misses', 0)} cold miss(es), "
-                    f"{cnt.get('host_evictions', 0)} host eviction(s)"
-                )
-            p99 = doc.get("restore_stall_p99_ms")
-            if p99 is not None:
-                print(f"  restore stall ..... p99 {p99} ms "
-                      "(queue-wait cause: kv_restore)")
-            res = doc.get("resident_sessions_at_fixed_hbm") or {}
-            if res:
-                print(
-                    f"  resident sessions  {res.get('tiered_sessions')} vs "
-                    f"{res.get('baseline_sessions')} untiered at fixed HBM "
-                    f"(x{res.get('ratio')}; PR-14 baseline "
-                    f"x{res.get('pr14_ratio')})"
-                )
-        else:
-            print("  tier metrics ...... unmeasured — run bench.py "
-                  "(BENCH_KVTIER_ONLY=1)")
         print(
             "cross-check ......... python -m deepspeed_tpu.tools.kv_heat "
             "kv_heat.jsonl --policy idle_lru (what-if simulator vs live "
@@ -503,11 +369,11 @@ def main() -> int:
     except Exception as e:
         print(f"kv tiering .......... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _fleet() -> None:
     print("Serving fleet (ISSUE 18):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.runtime.config import FleetConfig
 
         fcfg = FleetConfig()
@@ -522,49 +388,6 @@ def main() -> int:
             f"preempt_policy={fcfg.preempt_policy}, "
             f"admit_attainment_floor={fcfg.admit_attainment_floor}"
         )
-        # router/migration numbers come from the committed bench artifact —
-        # env_report stays cheap (no fleet replay here)
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr18.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            fl = doc.get("fleet") or {}
-            sg = doc.get("single") or {}
-            ratio = doc.get("fleet_goodput_over_single")
-            print(
-                f"  goodput ........... {doc.get('replicas')} replicas "
-                f"({doc.get('router_policy')}): "
-                f"{fl.get('goodput_tokens_per_sec')} tok/s vs single "
-                f"{sg.get('goodput_tokens_per_sec')} tok/s (x{ratio}) at "
-                f"{doc.get('offered_load_of_single_capacity')}x single "
-                "capacity"
-            )
-            att = fl.get("slo_attainment")
-            satt = sg.get("slo_attainment")
-            if att is not None and satt is not None:
-                print(
-                    f"  slo attainment .... fleet {100 * att:.1f}% vs "
-                    f"single {100 * satt:.1f}% (one scripted preemption "
-                    f"mid-run; {fl.get('replicas_alive_at_end')} replicas "
-                    "alive at end)"
-                )
-            mig = doc.get("migration") or {}
-            if mig:
-                p99 = mig.get("blackout_p99_s")
-                print(
-                    f"  migration ......... {mig.get('ok')} ok / "
-                    f"{mig.get('crc_failed')} crc-failed / "
-                    f"{mig.get('no_capacity')} no-capacity, "
-                    f"{(mig.get('bytes') or 0) / 1e3:.1f} kB moved, "
-                    f"blackout p99 "
-                    f"{'-' if p99 is None else f'{p99 * 1e3:.0f} ms'}"
-                )
-        else:
-            print("  fleet metrics ..... unmeasured — run bench.py "
-                  "(BENCH_FLEET_ONLY=1)")
         print(
             "trace grouping ...... python -m deepspeed_tpu.tools."
             "request_trace requests.jsonl --by replica"
@@ -572,11 +395,11 @@ def main() -> int:
     except Exception as e:
         print(f"serving fleet ....... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def _timeseries() -> None:
     print("Time series / SLO budget (ISSUE 20):")
     try:
-        import json
-        import os
-
         from deepspeed_tpu.runtime.config import (
             SLOAlertsConfig,
             TimeseriesConfig,
@@ -599,43 +422,28 @@ def main() -> int:
             f"{acfg.slow_burn_threshold}x, backpressure="
             f"{'on' if acfg.backpressure else 'off'}"
         )
-        bench_path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "BENCH_pr20.json",
-        )
-        if os.path.exists(bench_path):
-            with open(bench_path, encoding="utf-8") as fh:
-                doc = json.load(fh)
-            jd = doc.get("journal") or {}
-            ar = doc.get("alert_replay") or {}
-            print(
-                f"  snapshot hook ..... "
-                f"{doc.get('snapshot_hook_overhead_pct')}% step overhead "
-                f"(pin <= {doc.get('snapshot_hook_overhead_pct_pin')}%), "
-                f"{jd.get('bytes_per_record')} B/record, "
-                f"{(jd.get('bytes_per_hour_at_1hz') or 0) / 1e6:.2f} "
-                "MB/hour at 1 Hz"
-            )
-            print(
-                f"  alert replay ...... injected violation at 60s: fired "
-                f"t={ar.get('t_fired_s')}s (delay "
-                f"{ar.get('detection_delay_s')}s), resolved "
-                f"t={ar.get('t_resolved_s')}s after 120s recovery"
-            )
-        else:
-            print("  tsdb metrics ...... unmeasured — run bench.py "
-                  "(BENCH_TSDB_ONLY=1)")
         print(
             "dashboard ........... python -m deepspeed_tpu.tools."
             "fleet_dash metrics_tsdb.jsonl [--watch 5] [--diff OLD.jsonl]"
         )
-        print(
-            "bench trend ......... python -m deepspeed_tpu.tools."
-            "bench_trend --gate BENCH_pr20.json (pinned BENCH_index.json)"
-        )
     except Exception as e:
         print(f"time series ......... {RED_NO} ({type(e).__name__}: {e})")
     print("-" * 60)
+
+
+def main() -> int:
+    _ops()
+    _environment()
+    _telemetry()
+    _analysis()
+    _memory()
+    _request_tracing()
+    _placement()
+    _protocol()
+    _kv_heat()
+    _kv_tiering()
+    _fleet()
+    _timeseries()
     return 0
 
 

@@ -4,11 +4,9 @@ Capability analog of reference ``csrc/adam/multi_tensor_adam.cu:163`` +
 ``ops/adam/fused_adam.py:15`` (multi-tensor-apply fused CUDA Adam). Under XLA
 the optax update already fuses into the train step, so this kernel exists to
 answer SURVEY §2.7's own question — "Pallas fused optimizer kernel over flat
-param shards (or jax.jit fused update — **measure**)" — with a measurement:
-``benchmarks/fused_adam_bench.py`` times both at large param counts. The
-number has NOT yet been captured on hardware (no working TPU window since
-the harness landed — that file's RESULTS section tracks the status); optax
-stays the default optimizer until the kernel measures a material edge.
+param shards (or jax.jit fused update — **measure**)" — with a measurement.
+The two have NOT been measured on the chip; optax stays the default
+optimizer until the kernel measures a material edge.
 
 Design: the update is purely elementwise and HBM-bandwidth-bound (reads
 p,g,m,v + writes p,m,v = 28 B/param fp32). The kernel streams 2D tiles

@@ -890,7 +890,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # straight out of the fused [B,S,E] activations via lane-offset index maps
 # ((bh // H, i, bh % H) block coords), so the [B,S,H,D] <-> [B*H,S,D]
 # physical transposes around the 3D entry — XLA copies, ~30 ms each at the
-# r4 bench shape, 8+ per layer across fwd/recompute/bwd — never exist.
+# round-4 shape, 8+ per layer across fwd/recompute/bwd — never exist.
 # Kernel BODIES are shared with the 3D path; only the pallas_call block
 # maps differ. MHA resident shapes with the fused backward only (GQA dk/dv
 # would need cross-grid-step output accumulation over the group).

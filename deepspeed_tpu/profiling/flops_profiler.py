@@ -33,42 +33,6 @@ def _cost_analysis(fn: Callable, *args) -> Dict[str, float]:
     return dict(ca or {})
 
 
-def verify_against_hlo(fn: Callable, *args, tolerance: float = 0.05) -> Dict[str, Any]:
-    """Reconcile this profiler's flop source (XLA ``cost_analysis``) with the
-    telemetry HLO cost analyzer's independent instruction walk
-    (``telemetry/introspect.py``) on the same compiled program.
-
-    Two independent counters agreeing is the guard against both failure
-    modes: cost_analysis silently under-counting (scan bodies counted once,
-    Pallas calls invisible) and the text walk mis-parsing an opcode. Both
-    sides count a loop body once (the analyzer's loop multiplier is
-    deliberately not applied), so the comparison is apples-to-apples even
-    for scanned programs. Returns ``{xla_flops, hlo_flops, rel_err, agree,
-    categories}``; ``agree`` is ``rel_err <= tolerance`` (default 5%).
-    """
-    from ..telemetry import introspect as _intro
-
-    compiled = jax.jit(fn).lower(*args).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    xla_flops = float((ca or {}).get("flops", 0.0))
-    ana = _intro.analyze_compiled(compiled, loop_iterations=1)
-    hlo_flops = ana.total_flops
-    rel = (
-        abs(hlo_flops - xla_flops) / xla_flops if xla_flops > 0
-        else (0.0 if hlo_flops == 0 else float("inf"))
-    )
-    return {
-        "xla_flops": xla_flops,
-        "hlo_flops": hlo_flops,
-        "rel_err": rel,
-        "agree": rel <= tolerance,
-        "tolerance": tolerance,
-        "categories": {k: v.to_dict() for k, v in ana.categories.items()},
-    }
-
-
 def get_model_profile(
     fn: Callable,
     args: Tuple,

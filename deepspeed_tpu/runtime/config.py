@@ -383,23 +383,6 @@ class DataTypesConfig(DSConfigModel):
 
 
 @dataclass
-class IntrospectionConfig(DSConfigModel):
-    """telemetry.introspection section (ISSUE 5 tentpole): the HLO cost/MFU
-    analyzer (``telemetry/introspect.py``). On each sampled step of a
-    DISTINCT compiled program the engine walks the post-optimization HLO
-    into a per-category flops/bytes breakdown, computes step MFU against
-    the per-chip peak table (CPU fallback included) and a roofline
-    classification, and attaches the report to the StepTracer record +
-    registry gauges (``step_mfu``, ``flops_per_category``,
-    ``overlap_fraction``). ``peak_tflops`` overrides the table's flops
-    column (e.g. a derated fleet SKU). Costs one extra lower+compile per
-    distinct program — cheap with the persistent compilation cache."""
-
-    enabled: bool = True
-    peak_tflops: float = 0.0  # 0 = per-chip table lookup by device kind
-
-
-@dataclass
 class WatchdogConfig(DSConfigModel):
     """telemetry.watchdog section (ISSUE 5 tentpole): in-run anomaly
     detection (``telemetry/watchdog.py``). ``nan_check`` folds a
@@ -454,8 +437,8 @@ class RequestTraceConfig(DSConfigModel):
     schema-versioned JSONL record per terminal request through the
     StepTracer machinery — buffered appends, size-capped atomic rotation
     (``max_mb`` → ``<file>.1``), dsan-shimmed locking. All recording is
-    host-side list appends: no device syncs, always-on-cheap (bench pins
-    ≤ 2% on the offered-load sweep). ``path`` "" puts ``requests.jsonl``
+    host-side list appends: no device syncs (its cost on the chip is not
+    measured). ``path`` "" puts ``requests.jsonl``
     under ``telemetry.trace_path``. ``max_events_per_request`` bounds one
     request's event list (further events are counted dropped, never
     unbounded memory). Consumed by ``ServingEngine`` (the scheduler is the
@@ -495,15 +478,14 @@ class KVHeatConfig(DSConfigModel):
     — buffered appends, size-capped atomic rotation (``max_mb`` →
     ``<file>.1``), background JSON encode. All recording is host-side list
     appends off the engine's injectable clock: no device syncs, no
-    wall-clock fields (seeded replays are byte-deterministic), bench pins
-    hook overhead ≤ 2% of the traced serving span. ``path`` "" puts
+    wall-clock fields (seeded replays are byte-deterministic); the hooks'
+    cost on the chip is not measured. ``path`` "" puts
     ``kv_heat.jsonl`` under ``telemetry.trace_path``. ``segment_events``
     bounds one segment record's event count (the seal threshold).
     ``idle_thresholds_s`` are the cold-page-fraction gauge thresholds
     (ascending seconds). Consumed by ``ServingEngine`` (the scheduler
-    attaches ledgers per placement pool), ``tools/kv_heat.py`` (report /
-    timeline / heatmap / what-if spill CLI) and bench.py's
-    ``run_kv_heat_bench``."""
+    attaches ledgers per placement pool) and ``tools/kv_heat.py`` (report /
+    timeline / heatmap / what-if spill CLI)."""
 
     enabled: bool = False
     path: str = ""  # "" = <telemetry.trace_path>/kv_heat.jsonl
@@ -556,9 +538,8 @@ class TimeseriesConfig(DSConfigModel):
     window kept for live ``rate()`` / burn-rate evaluation (0 = auto: the
     largest SLO-alert window in play, min 1h). Consumed by
     ``ServingEngine`` (step-cadence snapshot hook + windowed goodput),
-    ``telemetry/slo_budget.py`` (error budget / burn-rate alerts),
-    ``tools/fleet_dash.py`` (capacity/trend dashboard) and bench.py's
-    ``run_tsdb_bench``."""
+    ``telemetry/slo_budget.py`` (error budget / burn-rate alerts) and
+    ``tools/fleet_dash.py`` (capacity/trend dashboard)."""
 
     enabled: bool = False
     path: str = ""  # "" = <telemetry.trace_path>/metrics_tsdb.jsonl
@@ -610,7 +591,6 @@ class TelemetryConfig(DSConfigModel):
     flush_interval: int = 20
     sample_every: int = 1
     trace_max_mb: int = 64  # 0 = unbounded
-    introspection: IntrospectionConfig = field(default_factory=IntrospectionConfig)
     watchdog: WatchdogConfig = field(default_factory=WatchdogConfig)
     # ISSUE 11: request-lifecycle tracing (serving) — see RequestTraceConfig
     request_trace: RequestTraceConfig = field(default_factory=RequestTraceConfig)
@@ -1155,7 +1135,7 @@ class SLOAlertsConfig(DSConfigModel):
     (the fast rule catches cliffs, the long window de-flaps it; the slow
     rule catches grinds): ``fast`` = 5m/1h at 14.4x, ``slow`` = 6h/3d at
     1.0x by default. Windows are *virtual-timebase* seconds off the
-    engine's injectable clock — tests and the bench compress them like the
+    engine's injectable clock — tests compress them like the
     PR-16 idle thresholds. Alerts run a ``pending → firing → resolved``
     state machine (``for_s`` is the dwell before pending promotes to
     firing), emit ``slo_alert`` journal events and

@@ -92,8 +92,7 @@ class DevicePrefetchLoader:
     free from torch DataLoader ``pin_memory`` + non-blocking copies. Under
     JAX, ``jax.device_put`` is async — dispatching the NEXT batch's transfer
     before blocking on the current step overlaps H2D with compute, removing
-    the per-step upload from the critical path (the blocked-vs-device gap
-    bench.py reports as host_overhead_ms).
+    the per-step upload from the critical path.
 
     ``put`` maps a host pytree to device arrays (typically
     ``engine.shard_batch``).

@@ -468,7 +468,7 @@ class DeepSpeedEngine:
         # the offload tier never holds optimizer state on device — initializing
         # Adam moments here just to discard them would OOM the chip for
         # exactly the models offload exists for (fp32 m+v alone exceed HBM on
-        # gpt2-xl; seen as a ResourceExhausted in the r4 offload bench).
+        # gpt2-xl; seen as a ResourceExhausted on the chip in round 4).
         # offload_enabled is decided HERE, once, and reused by the tier setup
         # below.
         self.offload_enabled = (
@@ -590,9 +590,7 @@ class DeepSpeedEngine:
     def _step_builder(self):
         """The (state, batch, rng) -> (state, metrics) step function for the
         standard device path: the compressed-collective variant when
-        ``comm_compression`` engages, the pjit path otherwise. bench.py's
-        device-only K-step loop compiles this too, so its numbers measure
-        the same program the engine runs."""
+        ``comm_compression`` engages, the pjit path otherwise."""
         return (
             self._make_compressed_train_step()
             if self._compress_grads
@@ -1420,7 +1418,7 @@ class DeepSpeedEngine:
         )
         # static shapes → the per-step collective mix is known here, exactly
         # (the basis for _compression_stats; trace-time registries would
-        # over-count when bench/telemetry re-lower the same program)
+        # over-count when telemetry re-lowers the same program)
         self._compression_plan = (plan, world, method, block)
 
         def scaled_loss(cp, micro, mrng):
@@ -1877,24 +1875,6 @@ class DeepSpeedEngine:
         }
         if comp:
             extra["comm_compression"] = comp
-        # HLO cost/MFU introspection (ISSUE 5): the program analysis is
-        # cached per compiled program; the MFU re-derives each sampled step
-        # from THIS step's measured duration
-        ana = self._introspection_analysis()
-        if ana is not None:
-            from ..telemetry import introspect as _intro
-
-            report = _intro.step_report(
-                ana,
-                duration_s=duration_s,
-                peak=_intro.chip_peak(
-                    peak_flops_override=float(
-                        getattr(tel.introspection, "peak_tflops", 0.0) or 0.0
-                    ) * 1e12
-                ),
-            )
-            extra["introspection"] = report
-            _intro.export_to_registry(tel.registry, report)
         tel.record_step(
             "train",
             step=self.global_steps,
@@ -1974,7 +1954,7 @@ class DeepSpeedEngine:
 
     def _lower_step_compiled(self):
         """Lower + compile the current jitted step for program-level analysis
-        (comms accounting, HLO introspection) without perturbing the
+        (comms accounting, the dslint verifiers) without perturbing the
         compressed layer's trace-time records."""
         from ..comm.compressed import suspend_records
 
@@ -1984,8 +1964,8 @@ class DeepSpeedEngine:
     def _compiled_step(self):
         """The analysis copy of the current step program, compiled at most
         ONCE per distinct program (jit cache size is the invalidation key).
-        Introspection (ISSUE 5), comms accounting, and the dslint program
-        verifier (ISSUE 6) all read this one executable."""
+        Comms accounting and the dslint program verifier (ISSUE 6) both read
+        this one executable."""
         key = self._jit_step_programs()
         cached = getattr(self, "_compiled_step_cache", None)
         if cached is not None and cached[0] == key:
@@ -2005,8 +1985,8 @@ class DeepSpeedEngine:
         program (``no-fp32-upcast``), no synchronous collectives when the
         latency-hiding scheduler flags are set (``collective-overlap``),
         and a bounded executable count (``static-shapes``). Returns the
-        findings list — empty means the program is clean. Reuses the
-        introspection path's one-compile cache; requires at least one
+        findings list — empty means the program is clean. Reuses
+        ``_compiled_step``'s one-compile cache; requires at least one
         ``train_batch()`` call and the standard jitted step."""
         acfg = self.config.analysis
         if not acfg.enabled:
@@ -2067,7 +2047,7 @@ class DeepSpeedEngine:
         findings.extend(dsa.verify_program_set({"train_step": txt}))
         # Engine E (ISSUE 9): static HBM liveness over the same text — the
         # peak-vs-budget gate plus donation/scratch/padding byte rules;
-        # the analysis is kept for memory_report() / bench / env_report
+        # the analysis is kept for memory_report()
         mcfg = getattr(acfg, "memory", None)
         if mcfg is not None and mcfg.enabled:
             from ..analysis import memory_rules as dsmem
@@ -2075,8 +2055,8 @@ class DeepSpeedEngine:
             ectx = dsmem.context_from_config(mcfg, "train_step")
             mem_findings, ana = dsmem.verify_memory_text(txt, ectx)
             findings.extend(mem_findings)
-            # keyed like _introspection_analysis: a retrace compiles a new
-            # program, whose profile must not be served from this cache
+            # keyed like _compiled_step: a retrace compiles a new program,
+            # whose profile must not be served from this cache
             self._memory_analysis = ana
             self._memory_analysis_key = self._jit_step_programs()
         # Engine F (ISSUE 9): the committed sharding-spec table (if any)
@@ -2126,47 +2106,13 @@ class DeepSpeedEngine:
         report["headroom_pct"] = dsmem.headroom_pct(budget, ana.peak_bytes)
         return report
 
-    def _introspection_analysis(self):
-        """Per-category HLO cost analysis of the current step program
-        (telemetry.introspection tentpole), cached per distinct program.
-        One lower+compile covers BOTH this and the comms accounting: the
-        compiled object is handed to ``_record_step_comms`` so the sampled
-        step pays a single re-lower. None on multi-program engine paths
-        (offload/onebit/infinity) and when introspection is disabled."""
-        tel = self.telemetry
-        icfg = tel.introspection if tel is not None else None
-        if icfg is None or not icfg.enabled:
-            return None
-        key = self._jit_step_programs()
-        cached = getattr(self, "_introspect_cache", None)
-        if cached is not None and cached[0] == key:
-            return cached[1]
-        ana = None
-        if hasattr(self._train_step, "lower") and self._step_arg_structs is not None:
-            try:
-                compiled = self._compiled_step()
-                from ..telemetry import introspect as _intro
-
-                ana = _intro.analyze_compiled(
-                    compiled,
-                    loop_iterations=self.gradient_accumulation_steps_value,
-                )
-                try:  # feed the comms accounting from the same compiled step
-                    self._record_step_comms(compiled=compiled)
-                except Exception:
-                    pass
-            except Exception:
-                ana = None
-        self._introspect_cache = (key, ana)
-        return ana
-
     def _compression_stats(self) -> Dict[str, Dict[str, float]]:
         """Per-axis {logical_bytes, wire_bytes, ratio} of ONE compressed
         train step, derived analytically from the bucket plan (shapes are
         static, so the per-step collective mix is exact). Not read from the
         trace-time registry in comm/compressed.py — that one grows on every
-        re-trace/lower of the same program (bench's device-only loop, the
-        comms-accounting ``.lower()``) and would over-count. Empty when
+        re-trace/lower of the same program (the comms-accounting
+        ``.lower()``) and would over-count. Empty when
         comm_compression never engaged."""
         if not getattr(self, "_compress_grads", False):
             return {}
@@ -2200,13 +2146,11 @@ class DeepSpeedEngine:
         except Exception:
             return 0
 
-    def _record_step_comms(self, compiled=None) -> Dict:
+    def _record_step_comms(self) -> Dict:
         """Merge the compiled train step's HLO collective mix into the comms
         logger ONCE per program (repeat calls would double-count; a retrace
         backs out the superseded program's rows and re-derives); returns the
-        current program's {(op, axis): {count, bytes}} mix. ``compiled``
-        lets a caller that already re-lowered the step (introspection) share
-        the executable instead of paying a second lower+compile."""
+        current program's {(op, axis): {count, bytes}} mix."""
         key = self._jit_step_programs()
         found = getattr(self, "_step_comms_found", None)
         if found is not None and getattr(self, "_step_comms_key", None) == key:
@@ -2225,8 +2169,7 @@ class DeepSpeedEngine:
         # records were already taken on the first (real) trace — appending
         # them again here would double the compressed rows in the logger
         # (suspend_records inside _lower_step_compiled)
-        if compiled is None:
-            compiled = self._compiled_step()
+        compiled = self._compiled_step()
         if found:
             # back out the superseded program's contribution before merging
             # the new one, keeping the shared logger's per-step semantics

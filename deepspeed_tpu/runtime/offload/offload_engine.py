@@ -12,7 +12,7 @@ swap_tensor package. Memory accounting that makes a 20B model fit one chip:
 The device step is a jitted (loss, grads) program; the optimizer update runs
 on TPU-VM host cores through the SIMD C++ kernels (``csrc/adam``).
 
-The step is a **subgroup pipeline** (VERDICT r1 item 4 — the reference
+The step is a **subgroup pipeline** (the reference
 overlaps swap of subgroup N±1 with step N, ``pipelined_optimizer_swapper.py``):
 
 1. every grad leaf starts its D2H copy up front (``copy_to_host_async``), so

@@ -5,9 +5,9 @@ the ``deepspeed/ops/{adam,lamb,adagrad}`` wrappers. The reference ships three
 flavors of Adam (torch, FusedAdam CUDA kernel, DeepSpeedCPUAdam SIMD); under
 XLA the optimizer update is fused into the train step by the compiler, so one
 optax definition covers the "fused" case. ``deepspeed_tpu/ops/fused_adam.py``
-is the Pallas multi-tensor kernel alternative; ``benchmarks/fused_adam_bench.py``
-measures both (SURVEY §2.7's required measurement) — optax stays the default
-unless the kernel wins on the target chip. The CPU (host-offload) variants
+is the Pallas multi-tensor kernel alternative (SURVEY §2.7 asks for the two
+to be measured; not measured on the chip) — optax stays the default unless
+the kernel wins on the target chip. The CPU (host-offload) variants
 live in ``deepspeed_tpu/runtime/offload/``.
 
 Accepted ``type`` strings keep DeepSpeed's names: Adam, AdamW, FusedAdam,

@@ -30,7 +30,7 @@ every program call paid O(pool bytes) of copy traffic even with donation
 (~170 ms/step at a 151 MB pool, linear in ``num_pages``). With a static
 python loop the pools are plain dataflow values updated by per-layer
 scatters into donated buffers: per-call cost scales with the pages
-actually touched, not the pool (38x on the bench config), which is the
+actually touched, not the pool (38x at gpt2-tiny on the CPU), which is the
 whole point of paging. n_layer is static and small, so the unroll's
 compile-time cost is bounded; the arithmetic per layer is unchanged, so
 token streams are unaffected (the equivalence tests pin this).

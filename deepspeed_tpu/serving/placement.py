@@ -538,8 +538,7 @@ class ProgramSet:
         return f"{self.n_layer},{int(n_pages)},{self.local_kv_heads()},2"
 
     def local_pool_bytes(self) -> int:
-        """Per-device K+V pool bytes (the quantity the resident-session
-        bench and env_report report per placement)."""
+        """Per-device K+V pool bytes of this placement."""
         itemsize = jnp.dtype(self.k_pool.dtype).itemsize
         return (
             2 * self.n_layer * self.num_pages * self.local_kv_heads()

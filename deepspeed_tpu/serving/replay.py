@@ -2,8 +2,8 @@
 
 The workload generator ROADMAP items 5 and 7 call for, landed as the
 observability plane's measurement rig: a **seeded** synthetic trace with the
-three production-shaped properties the steady Poisson sweep (bench PR-3)
-cannot express —
+three production-shaped properties a steady Poisson sweep cannot
+express —
 
 - **bursty / diurnal arrivals**: a base Poisson process whose rate is
   modulated by a sinusoid (the "diurnal" cycle, compressed to seconds) plus
@@ -30,8 +30,8 @@ through its injectable clock. Two modes:
   step — fully deterministic, wall-clock-free; same seed → identical
   per-request trace records (the determinism test's pin).
 - **realtime** (the engine's own ``time.monotonic``): arrivals are offset
-  from the replay start; this is the mode the bench uses to measure real
-  tracer overhead and goodput.
+  from the replay start; this is the mode that measures real tracer
+  overhead and goodput.
 
 Scoring happens from the emitted request-trace JSONL
 (:func:`deepspeed_tpu.telemetry.request_trace.score_requests`) — the
@@ -186,7 +186,7 @@ def replay(
     With a :class:`ReplayClock` installed on the engine, ``step_dt`` > 0
     advances virtual time per scheduler step (deterministic mode); idle
     gaps fast-forward to the next arrival instead of spinning. With a real
-    clock, pacing is wall-clock (the bench's overhead-measurement mode).
+    clock, pacing is wall-clock (the overhead-measurement mode).
     Returns ``{"requests", "steps", "duration_s"}`` — scoring belongs to
     :func:`~deepspeed_tpu.telemetry.request_trace.score_requests` over the
     emitted trace."""

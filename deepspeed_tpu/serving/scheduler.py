@@ -692,8 +692,8 @@ class ServingEngine:
     def attach_heat(self, tracer) -> None:
         """Attach a :class:`~deepspeed_tpu.telemetry.kv_heat.KVHeatTracer`:
         one ledger per placement pool, seeded from the allocator's CURRENT
-        refcount table (attaching mid-run — e.g. bench attaches after
-        warm-up — must reconcile from the first event), hooks installed on
+        refcount table (attaching mid-run — e.g. after warm-up — must
+        reconcile from the first event), hooks installed on
         the allocator(s) and the prefix index, derived gauges bound to this
         engine's registry. Idempotent for the same tracer."""
         if tracer is self._heat:

@@ -89,10 +89,7 @@ class Telemetry:
         )
         self.monitor_bridge: Optional[MonitorBridge] = None
         self._records_since_export = 0
-        # ISSUE 5: performance-introspection plane — the HLO cost/MFU
-        # analyzer config rides here (the engine drives the analysis; see
-        # introspect.py) and the anomaly watchdog is constructed iff enabled
-        self.introspection = getattr(config, "introspection", None)
+        # ISSUE 5: the anomaly watchdog is constructed iff enabled
         self.watchdog: Optional[AnomalyWatchdog] = watchdog_mod.from_config(
             getattr(config, "watchdog", None),
             registry=self.registry,

@@ -56,9 +56,8 @@ two columnar series:
 
 All timestamps come from the engine's injectable clock, and the records
 carry NO wall-clock field — a seeded replay under ``ReplayClock``
-(serving/replay.py) produces a byte-deterministic stream, which is what
-lets BENCH_pr16.json commit cold-fraction curves and the what-if spill
-comparison as stable artifacts.
+(serving/replay.py) produces a byte-deterministic stream, so cold-fraction
+curves and the what-if spill comparison are the same from run to run.
 
 Offline, :func:`load_heat_records` (same tolerance contract as the request
 trace: rolled ``.1`` generation first, one torn tail line forgiven) feeds
@@ -912,7 +911,7 @@ def cold_fraction_curve(
     bins: int = 10,
 ) -> List[Dict[str, Any]]:
     """The pool's cold-page fraction sampled at ``bins`` equal windows of
-    trace time — the BENCH_pr16 curve shape (cold fraction vs load)."""
+    trace time (cold fraction vs load)."""
     times = [
         float(ev[1]) for ev in iter_pool_events(records, pool)
     ]
@@ -1124,7 +1123,7 @@ def _simulate_policy(
 
 
 # ---------------------------------------------------------------------------
-# aggregate report (CLI + bench)
+# aggregate report (CLI)
 # ---------------------------------------------------------------------------
 
 

@@ -38,9 +38,9 @@ Records are schema-versioned (:data:`SCHEMA`) and emitted through the
 existing :class:`~deepspeed_tpu.telemetry.tracer.StepTracer` machinery, so
 they inherit buffered appends, the size-capped atomic rotation
 (``<file>.1``) and the dsan-instrumented locking (ISSUE 8). All recording
-is host-side list appends — no device syncs, no jnp dispatch — cheap enough
-to run always-on (the bench pins overhead ≤ 2% on the offered-load sweep;
-dslint Engine B stays clean over the instrumented hot functions).
+is host-side list appends — no device syncs, no jnp dispatch (its cost on
+the chip is not measured; dslint Engine B stays clean over the instrumented
+hot functions).
 
 Scoring (:func:`score_requests`) turns a set of records into per-tenant /
 per-SLO-class **goodput** (tokens from SLO-met requests per second of wall
@@ -422,8 +422,7 @@ def load_request_records(path: str) -> List[Dict[str, Any]]:
     One path = one logical stream: the writer APPENDS (StepTracer
     contract), so pointing a fresh run at a used path concatenates runs —
     in the main file and the rolled generation alike. Give each run a
-    fresh path (or clear the directory, as ``bench.py`` does) when runs
-    must score separately."""
+    fresh path (or clear the directory) when runs must score separately."""
     paths = [p for p in (path + ".1", path) if os.path.exists(p)]
     if not paths:
         raise RequestTraceError(f"{path}: no such trace file")
@@ -656,8 +655,8 @@ def score_requests(
         "goodput_tokens_per_sec": tot_good / wall,
         "throughput_tokens_per_sec": tot_tokens / wall,
     }
-    # run-level latency quantiles ride along so callers (CLI report/diff,
-    # bench) score the record set ONCE instead of re-walking every record
+    # run-level latency quantiles ride along so callers (CLI report/diff)
+    # score the record set ONCE instead of re-walking every record
     for metric, vals in (
         ("ttft", all_ttft), ("tpot", all_gaps), ("queue_wait", all_qwait),
     ):

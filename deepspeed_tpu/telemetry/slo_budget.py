@@ -14,7 +14,7 @@ budget in 5 hours. Two rules evaluate per SLO class:
 
 A rule's condition is ``burn(short) >= threshold AND burn(long) >=
 threshold``. Windows are **virtual-timebase seconds** read off the
-journal's clock — tests and the bench compress them exactly like the
+journal's clock — tests compress them exactly like the
 PR-16 idle thresholds, the state machine neither knows nor cares.
 
 Per (class, rule) the alert runs ``inactive → pending → firing →

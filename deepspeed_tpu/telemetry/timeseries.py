@@ -41,9 +41,8 @@ Record kinds::
 
 Consumers: ``ServingEngine`` (step-cadence ``maybe_snapshot`` hook +
 journal-backed windowed goodput), ``telemetry/slo_budget.py`` (error
-budget / burn-rate alerting over the in-memory mirror),
-``tools/fleet_dash.py`` (offline :func:`load_journal` + the query API)
-and bench.py's ``run_tsdb_bench`` (≤2% snapshot-hook overhead pin).
+budget / burn-rate alerting over the in-memory mirror) and
+``tools/fleet_dash.py`` (offline :func:`load_journal` + the query API).
 """
 
 from __future__ import annotations

@@ -126,7 +126,7 @@ class StepTracer:
     @staticmethod
     def _dsan_module():
         """The runtime sanitizer, when importable (deferred: the analysis
-        package reads telemetry.introspect, so a module-level import here
+        package reads telemetry.introspect's grammar, so a module-level import here
         would be circular)."""
         try:
             from ..analysis import runtime_sanitizer
@@ -156,7 +156,7 @@ class StepTracer:
 
     def force_next(self) -> None:
         """Make the next step emit a record regardless of ``sample_every``
-        (bench.py uses this: zero-overhead timed loop, one recorded step)."""
+        (a timed loop with sampling off, then one recorded step)."""
         self._force_next = True
 
     # -- emission ------------------------------------------------------

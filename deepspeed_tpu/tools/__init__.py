@@ -2,5 +2,5 @@
 
 - ``trace_diff`` — align two step-trace JSONL runs and report per-span /
   per-category deltas with a regression threshold and a non-zero exit code,
-  making bench regressions machine-checkable.
+  making a regression between two runs machine-checkable.
 """

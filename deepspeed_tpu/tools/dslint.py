@@ -31,7 +31,7 @@ old Engine B findings keep their baseline entries untouched.
 
 Engines A/D also run where live compiled programs exist:
 ``DeepSpeedEngine.verify_program()``, ``ServingEngine.verify()``, the
-``lint``/``dsan``-marked tier-1 tests, and bench.py.
+and the ``lint``/``dsan``-marked tier-1 tests.
 
 Exit codes: 0 clean (or baseline-known only), 1 new findings, 2 usage /
 unparseable file / corrupt baseline.
@@ -113,14 +113,14 @@ def collect(
     donate_patterns=None,
     engines=None,
 ) -> dict:
-    """Run the selected engines + baseline split; the dict the CLI/bench/
-    env report all consume. Raises SyntaxError / ValueError upward."""
+    """Run the selected engines + baseline split; the dict the CLI
+    consumes. Raises SyntaxError / ValueError upward."""
     findings, suppressed, files = lint_paths(
         paths, hot_patterns=hot_patterns, donate_patterns=donate_patterns,
         engines=engines,
     )
     # fingerprints embed the path: normalize relative to the baseline's
-    # directory so absolute-path callers (bench.py) and repo-root CLI runs
+    # directory so absolute-path callers and repo-root CLI runs
     # agree on what "the same finding" is
     anchor = os.path.realpath(
         os.path.dirname(os.path.abspath(baseline_path))

@@ -442,8 +442,8 @@ def _cold_gate(report: Dict[str, Any], pool: str, min_pct: float,
 
 
 def _overhead_gate(bench_path: str, max_pct: float) -> int:
-    """``--max-overhead-pct``: pin the recorded hook overhead (bench.py's
-    ``heat_overhead_pct`` in BENCH_pr16.json) under ``max_pct``."""
+    """``--max-overhead-pct``: pin the hook overhead a JSON record holds
+    (``overhead.heat_overhead_pct``) under ``max_pct``."""
     try:
         with open(bench_path, encoding="utf-8") as fh:
             bench = json.load(fh)
@@ -501,7 +501,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                    metavar="PCT", help="gate: exit 1 if --bench records "
                    "hook overhead above PCT%%")
     p.add_argument("--bench", default=None, metavar="BENCH_JSON",
-                   help="BENCH_pr16.json for --max-overhead-pct")
+                   help="JSON record holding overhead.heat_overhead_pct, "
+                   "for --max-overhead-pct")
     p.add_argument("--diff", default=None, metavar="B_JSONL",
                    help="compare against a second trace; regressions exit 1")
     p.add_argument("--threshold-pct", type=float, default=10.0,

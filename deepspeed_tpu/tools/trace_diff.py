@@ -9,8 +9,6 @@ compares per-run MEDIANS of:
 
 - end-to-end step latency (``dur_ms``),
 - every host span (``spans.children.*``),
-- per-category flops/bytes and MFU when the records carry an
-  ``introspection`` block (telemetry.introspection),
 - per-axis collective bytes (``comm_bytes.*``).
 
 A span/metric whose B-median exceeds its A-median by more than
@@ -42,8 +40,7 @@ def _check_schema(rec: Any, path: str, lineno: int) -> Dict[str, Any]:
             f"{path}:{lineno}: JSON line is {type(rec).__name__}, not an "
             "object — this is not a StepTracer trace"
         )
-    for key, want in (("spans", dict), ("comm_bytes", dict),
-                      ("introspection", dict)):
+    for key, want in (("spans", dict), ("comm_bytes", dict)):
         if key in rec and rec[key] is not None and not isinstance(rec[key], want):
             raise TraceFormatError(
                 f"{path}:{lineno}: field {key!r} is "
@@ -133,18 +130,7 @@ def _series(recs: List[Dict]) -> Dict[str, List[float]]:
             put(f"span:{name}_ms", ms)
         for axis, nbytes in (r.get("comm_bytes") or {}).items():
             put(f"comm_bytes:{axis}", nbytes)
-        intro = r.get("introspection") or {}
-        put("mfu", intro.get("mfu"))
-        put("overlap_fraction", intro.get("overlap_fraction"))
-        for cat, f in (intro.get("flops_per_category") or {}).items():
-            put(f"flops:{cat}", f)
-        for cat, nb in (intro.get("bytes_per_category") or {}).items():
-            put(f"bytes:{cat}", nb)
     return out
-
-
-# metrics where a DROP is the regression direction (higher is better)
-_HIGHER_BETTER = ("mfu", "overlap_fraction")
 
 
 def diff(
@@ -165,10 +151,8 @@ def diff(
             continue
         delta = mb - ma
         pct = (delta / abs(ma) * 100.0) if ma else (0.0 if not delta else float("inf"))
-        higher_better = name in _HIGHER_BETTER
-        worse = -pct if higher_better else pct
         is_time = name.endswith("_ms")
-        regressed = worse > threshold_pct and (not is_time or abs(delta) > min_ms)
+        regressed = pct > threshold_pct and (not is_time or abs(delta) > min_ms)
         row = {
             "metric": name,
             "a_median": ma,

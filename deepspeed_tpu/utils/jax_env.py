@@ -1,9 +1,8 @@
 """Process-level jax set-up shared by the entry points.
 
 One helper: :func:`setup_compile_cache`. Entry points that compile real
-programs (``chip_smoke.py``, ``bench.py``, ``benchmarks/*``) call it before
-their first compilation so every process of one checkout shares one
-persistent compilation cache. The directory is part of the cache key, so it
+programs (``chip_smoke.py``) call it before their first
+compilation so every process of one checkout shares one persistent compilation cache. The directory is part of the cache key, so it
 is a FIXED path — never a temp name, pid or timestamp, which would never hit.
 """
 

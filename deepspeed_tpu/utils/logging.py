@@ -56,7 +56,7 @@ def _process_index() -> int:
         # If the private probe ever disappears, assume backends are NOT
         # initialized: the env-rank fallback is always safe, while calling
         # jax.process_index() here would initialize the backend and break
-        # any later jax.distributed.initialize (ADVICE r4).
+        # any later jax.distributed.initialize.
         if not getattr(xla_bridge, "backends_are_initialized", lambda: False)():
             raise LookupError  # env fallback below
         return jax.process_index()

@@ -162,6 +162,35 @@ class TestNoFp32Upcast:
         ctx = H.RuleContext(program="t", expected_dtype="bf16")
         assert H.rule_no_fp32_upcast(_hlo(line), ctx) == []
 
+    @pytest.mark.xfail(
+        strict=True, raises=AssertionError,
+        reason="ROADMAP D14: introspect.operand_shapes reads typed shapes "
+        "inside the call parens, and jax 0.9.0 prints operands by name only "
+        "(`dot(%a.1, %b.1)`), so the rule sees no operand and cannot fire. "
+        "Repair the reader (operand shapes through parse_named_instruction's "
+        "symbol table), then remove this mark.",
+    )
+    def test_operand_shapes_on_the_installed_grammar(self):
+        """The rule on text the installed compiler prints, not on the
+        hand-written lines above: a bf16 program with one f32 dot."""
+        def f(x, w, v):
+            h = jnp.tanh(x @ w)
+            return h.astype(jnp.float32) @ v
+
+        x = jnp.ones((64, 128), jnp.bfloat16)
+        w = jnp.ones((128, 256), jnp.bfloat16)
+        v = jnp.ones((256, 32), jnp.float32)
+        txt = jax.jit(f).lower(x, w, v).compile().as_text()
+        f32_dots = [
+            line for line in txt.splitlines()
+            if H.parse_instruction(line)[0] == "dot" and "= f32[64,32]" in line
+        ]
+        if len(f32_dots) != 1:  # the program is not the one this test means
+            pytest.fail(f"expected one f32[64,32] dot in the compiled text: {f32_dots}")
+        ctx = H.RuleContext(program="t", expected_dtype="bf16")
+        fs = H.rule_no_fp32_upcast(txt, ctx)
+        assert "no-fp32-upcast" in rules_of(fs)
+
 
 class TestCollectiveOverlap:
     SYNC_AR = ("  %ar = f32[262144]{0} all-reduce(f32[262144]{0} %p0), "
@@ -945,25 +974,3 @@ def test_host_prng_key_matches_jax(seed):
 
     want = np.asarray(jax.random.PRNGKey(seed))
     assert np.array_equal(_host_prng_key(seed), want), seed
-
-
-# ---------------------------------------------------------------------------
-# bench hook satellite
-# ---------------------------------------------------------------------------
-
-def test_bench_dslint_artifact(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "_BENCH_DIR", str(tmp_path))
-    # point the scan at the real package from the temp artifact dir
-    os.symlink(
-        os.path.join(REPO_ROOT, "deepspeed_tpu"),
-        os.path.join(str(tmp_path), "deepspeed_tpu"),
-    )
-    pr6 = bench.run_dslint_bench()
-    assert pr6["schema"] == "bench_pr6_dslint_v1"
-    assert pr6["dslint_findings_total"] >= 0
-    assert pr6["dslint_new_findings"] == 0  # repo is gate-clean
-    assert os.path.exists(tmp_path / "BENCH_pr6.json")
-    on_disk = json.loads((tmp_path / "BENCH_pr6.json").read_text())
-    assert on_disk["dslint_findings_total"] == pr6["dslint_findings_total"]

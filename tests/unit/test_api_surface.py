@@ -142,3 +142,40 @@ class TestRevertTransformerLayer:
 
         with pytest.raises((ValueError, NotImplementedError)):
             deepspeed_tpu.revert_transformer_layer(Fake(), {})
+
+
+def test_package_reads_nothing_at_the_repository_root():
+    """The package measures nothing through files a script above it wrote:
+    no module under ``deepspeed_tpu/`` imports the benchmark (or the
+    pre-chip ``bench`` it replaced), and none names a ``BENCH_*.json``
+    record, in code, help text, docstring or any other string."""
+    import ast
+    import os
+    import re
+
+    pkg = os.path.dirname(os.path.abspath(deepspeed_tpu.__file__))
+    record = re.compile(r"BENCH_\w+\.json")
+    forbidden = {"bench", "perfbench"}
+    bad = []
+    for dirpath, _, names in os.walk(pkg):
+        for name in names:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path, encoding="utf-8") as fh:
+                tree = ast.parse(fh.read(), filename=path)
+            for node in ast.walk(tree):
+                mods = []
+                if isinstance(node, ast.Import):
+                    mods = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                    mods = [node.module or ""]
+                elif isinstance(node, ast.Constant) and isinstance(node.value, str):
+                    named = record.search(node.value)
+                    if named:
+                        bad.append(f"{path}:{node.lineno}: names {named.group()}")
+                bad.extend(
+                    f"{path}:{node.lineno}: imports {mod}"
+                    for mod in mods if mod.split(".")[0] in forbidden
+                )
+    assert not bad, "\n".join(bad)

@@ -4,8 +4,8 @@ rotation/torn-tail semantics, SeriesStore query API (counter-reset-tolerant
 seeded-replay byte-identity, the SLO error-budget burn-rate alert state
 machine (fires on an injected sustained violation, resolves after
 recovery), fleet backpressure flipping only on *firing* (never pending),
-windowed goodput under a fake clock, the ``fleet_dash`` / ``bench_trend``
-CLI 0/1/2 exit matrix, and the serving acceptance: the journal attached
+windowed goodput under a fake clock, the ``fleet_dash`` CLI 0/1/2 exit
+matrix, and the serving acceptance: the journal attached
 leaves the 16-request mixed suite's token streams bit-identical."""
 
 import json
@@ -28,7 +28,7 @@ from deepspeed_tpu.telemetry.timeseries import (
     TimeseriesError,
     load_journal,
 )
-from deepspeed_tpu.tools import bench_trend, fleet_dash
+from deepspeed_tpu.tools import fleet_dash
 
 warnings.filterwarnings("ignore")
 
@@ -706,102 +706,3 @@ class TestFleetDashCLI:
         assert dr["regressions"] == ["goodput_tokens_per_sec"]
         dr = fleet_dash.diff_reports(a, dict(a), threshold_pct=10.0)
         assert dr["regressions"] == []
-
-
-class TestBenchTrendCLI:
-    def _root(self, tmp_path):
-        root = tmp_path / "benches"
-        root.mkdir()
-        (root / "BENCH_pr2.json").write_text(json.dumps({
-            "schema": "x_v1", "tokens_per_sec_chip": 1000.0,
-            "step_latency_ms": 20.0,
-        }))
-        (root / "BENCH_pr3.json").write_text(json.dumps({
-            "schema": "y_v1",
-            "fleet": {"goodput_tokens_per_sec": 500.0},
-            "overhead_pct": 1.0,
-        }))
-        return str(root)
-
-    def test_update_gate_matrix(self, tmp_path, capsys):
-        root = self._root(tmp_path)
-        idx = os.path.join(root, "BENCH_index.json")
-        # gate before index exists: 2
-        assert bench_trend.main(
-            ["--root", root, "--gate", os.path.join(root, "BENCH_pr2.json")]
-        ) == 2
-        assert bench_trend.main(["--root", root, "--update"]) == 0
-        with open(idx) as fh:
-            index = json.load(fh)
-        assert index["schema"] == bench_trend.SCHEMA
-        assert index["order"] == ["BENCH_pr2.json", "BENCH_pr3.json"]
-        assert index["artifacts"]["BENCH_pr2.json"]["headlines"][
-            "tokens_per_sec_chip"]["value"] == 1000.0
-        # print + self-gate pass
-        assert bench_trend.main(["--root", root]) == 0
-        assert bench_trend.main(
-            ["--root", root, "--gate", os.path.join(root, "BENCH_pr2.json")]
-        ) == 0
-        # a regressed re-run fails the gate in the right direction
-        cand = tmp_path / "cand.json"
-        cand.write_text(json.dumps({
-            "schema": "x_v1", "tokens_per_sec_chip": 800.0,
-            "step_latency_ms": 20.0,
-        }))
-        assert bench_trend.main(
-            ["--root", root, "--gate", str(cand), "--name", "BENCH_pr2.json"]
-        ) == 1
-        # higher latency also regresses; faster tokens never does
-        cand.write_text(json.dumps({
-            "schema": "x_v1", "tokens_per_sec_chip": 1500.0,
-            "step_latency_ms": 40.0,
-        }))
-        assert bench_trend.main(
-            ["--root", root, "--gate", str(cand), "--name", "BENCH_pr2.json"]
-        ) == 1
-        # within threshold: clean
-        cand.write_text(json.dumps({
-            "schema": "x_v1", "tokens_per_sec_chip": 950.0,
-            "step_latency_ms": 21.0,
-        }))
-        assert bench_trend.main(
-            ["--root", root, "--gate", str(cand), "--name", "BENCH_pr2.json"]
-        ) == 0
-        # unknown artifact name: 2
-        assert bench_trend.main(
-            ["--root", root, "--gate", str(cand), "--name", "BENCH_nope.json"]
-        ) == 2
-        capsys.readouterr()
-
-    def test_update_is_deterministic(self, tmp_path, capsys):
-        root = self._root(tmp_path)
-        idx = os.path.join(root, "BENCH_index.json")
-        assert bench_trend.main(["--root", root, "--update"]) == 0
-        with open(idx, "rb") as fh:
-            first = fh.read()
-        assert bench_trend.main(["--root", root, "--update"]) == 0
-        with open(idx, "rb") as fh:
-            assert fh.read() == first
-        capsys.readouterr()
-
-    def test_committed_index_matches_artifacts(self, capsys):
-        """The repo-root BENCH_index.json is the trajectory regenerated
-        from the committed artifacts — never stale."""
-        import deepspeed_tpu
-
-        root = os.path.dirname(os.path.dirname(
-            os.path.abspath(deepspeed_tpu.__file__)
-        ))
-        idx_path = os.path.join(root, "BENCH_index.json")
-        assert os.path.exists(idx_path), "BENCH_index.json must be committed"
-        with open(idx_path) as fh:
-            committed = json.load(fh)
-        rebuilt = bench_trend.build_index(root)
-        assert committed == rebuilt
-        # every committed artifact self-gates clean against its own pin
-        for name in committed["order"]:
-            assert bench_trend.gate_candidate(
-                committed, name,
-                json.load(open(os.path.join(root, name))), 10.0,
-            ) == []
-        capsys.readouterr()
