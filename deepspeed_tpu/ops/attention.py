@@ -130,6 +130,14 @@ def gather_pool_pages(k_pool, v_pool, block_tables, scales=None):
     return kd, vd
 
 
+def _paged_kernel_taken(impl: str, ok) -> bool:
+    """The paged dispatchers' rule, and the gauge's: ``"pallas"`` forces the
+    kernel, ``"auto"`` asks its gate ``ok()``, ``"jnp"`` never takes it."""
+    if impl not in ("auto", "pallas", "jnp"):
+        raise ValueError(f"unknown attention impl {impl}")
+    return impl == "pallas" or (impl == "auto" and ok())
+
+
 def paged_cached_attention(
     q, k_pool, v_pool, block_tables, pos, impl: str = "auto",
     sm_scale: Optional[float] = None, scales=None, layer=None,
@@ -161,21 +169,18 @@ def paged_cached_attention(
             f"pool is int8 (pool dtype {k_pool.dtype}, scales "
             f"{'given' if scales is not None else 'missing'})"
         )
-    if impl in ("auto", "pallas"):
-        from .pallas.decode_attention import (
-            paged_decode_attention,
-            paged_decode_attention_ok,
-        )
+    from .pallas.decode_attention import (
+        paged_decode_attention,
+        paged_decode_attention_ok,
+    )
 
-        if impl == "pallas" or paged_decode_attention_ok(
-            KV, page, D, k_pool.dtype.itemsize
-        ):
-            return paged_decode_attention(
-                q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-                scales=scales, layer=layer,
-            )
-    elif impl != "jnp":
-        raise ValueError(f"unknown attention impl {impl}")
+    if _paged_kernel_taken(impl, lambda: paged_decode_attention_ok(
+        KV, page, D, k_pool.dtype.itemsize
+    )):
+        return paged_decode_attention(
+            q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
+            scales=scales, layer=layer,
+        )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
     # gather [B,n,KV,page,D] → logical [B,T,KV,D] per slot (pure data
@@ -223,21 +228,18 @@ def paged_multitoken_cached_attention(
             "paged_multitoken_cached_attention: scales must be given "
             f"exactly when the pool is int8 (pool dtype {k_pool.dtype})"
         )
-    if impl in ("auto", "pallas"):
-        from .pallas.decode_attention import (
-            paged_multitoken_attention,
-            paged_multitoken_attention_ok,
-        )
+    from .pallas.decode_attention import (
+        paged_multitoken_attention,
+        paged_multitoken_attention_ok,
+    )
 
-        if impl == "pallas" or paged_multitoken_attention_ok(
-            page, D, T, k_pool.dtype.itemsize
-        ):
-            return paged_multitoken_attention(
-                q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-                scales=scales, layer=layer,
-            )
-    elif impl != "jnp":
-        raise ValueError(f"unknown attention impl {impl}")
+    if _paged_kernel_taken(impl, lambda: paged_multitoken_attention_ok(
+        KV, page, D, T, k_pool.dtype.itemsize, H // KV
+    )):
+        return paged_multitoken_attention(
+            q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
+            scales=scales, layer=layer,
+        )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
     kd, vd = gather_pool_pages(k_pool, v_pool, block_tables, scales)
@@ -260,6 +262,35 @@ def paged_multitoken_cached_attention(
     )
     o = jnp.einsum("btgrs,bsgd->btgrd", probs, vd.astype(jnp.float32))
     return o.reshape(B, T, H, D).astype(q.dtype)
+
+
+def paged_attention_grid_steps(
+    impl: str, B: int, KV: int, page: int, D: int, itemsize: int,
+    n_pages: int, T: Optional[int] = None, rep: int = 1,
+) -> int:
+    """Grid steps of ONE call of the paged attention kernel that
+    :func:`paged_cached_attention` (``T`` None) or
+    :func:`paged_multitoken_cached_attention` dispatches these shapes to
+    under ``impl``: slots x head blocks x page blocks, by the dispatchers'
+    own rule and the kernels' own block rules (``rep`` query heads to a
+    kv-head widen a multi-token step's rows). 0 where the jnp fallback
+    runs."""
+    from .pallas import decode_attention as da
+
+    if T is None:
+        ok = lambda: da.paged_decode_attention_ok(KV, page, D, itemsize)
+        blocks = da.paged_decode_blocks(KV, page, D, itemsize, n_pages)
+    else:
+        ok = lambda: da.paged_multitoken_attention_ok(
+            KV, page, D, T, itemsize, rep
+        )
+        blocks = da.paged_multitoken_blocks(
+            KV, page, D, T, itemsize, n_pages, rep
+        )
+    if blocks is None or not _paged_kernel_taken(impl, ok):
+        return 0
+    HB, G = blocks
+    return B * (KV // HB) * -(-n_pages // G)
 
 
 def windowed_attention_ok(q) -> bool:

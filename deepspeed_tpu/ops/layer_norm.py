@@ -21,6 +21,22 @@ def layer_norm(x, scale, bias, eps):
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
+def layer_norm_inference(x, scale, bias, eps):
+    """:func:`layer_norm`'s arithmetic with the mean taken once, for programs
+    that are never differentiated (the serving programs). ``jnp.var`` takes
+    its own mean under a jit of its own, which XLA's CSE does not see through:
+    a norm of ``layer_norm`` is five small device operations behind the
+    matmul that feeds it, this one three, and a decode step is little but
+    small operations (17 a layer with it, 21 without). Same bits on the CPU
+    (``tests/unit/ops/test_layer_norm.py``); training keeps ``layer_norm``,
+    whose backward pass XLA fuses differently."""
+    xf = x.astype(jnp.float32)
+    c = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    v = jnp.mean(c * c, axis=-1, keepdims=True)
+    y = c * lax.rsqrt(v + eps)
+    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+
+
 def rms_norm(x, scale, eps):
     """RMSNorm (no mean subtraction, no bias) with fp32 statistics — the
     LLaMA-family normalization."""

@@ -152,6 +152,58 @@ def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
     return KV, 1 << (cap.bit_length() - 1)
 
 
+# What one grid step of the multi-token kernel may count in VMEM. The count
+# (:func:`paged_multitoken_blocks`) takes every temporary at its padded size
+# and all of them live at once, so it runs ahead of what Mosaic allocates:
+# steps counted at 16-18 MiB compile under the default 16 MiB scoped limit
+# (``tests/unit/ops/test_mosaic_compile.py``), and the call asks for no more.
+PAGED_MULTITOKEN_VMEM_BYTES = 18 * 1024 * 1024
+
+
+def paged_multitoken_blocks(KV: int, page: int, D: int, T: int,
+                            itemsize: int = 2, n_pages: Optional[int] = None,
+                            rep: int = 1):
+    """(kv-heads, pages) one grid step of the multi-token paged kernel holds,
+    from the shapes alone. It starts from :func:`paged_decode_blocks`' pair
+    and cuts the pages so that a head's ``[rep * T, G * page]`` score tile
+    keeps about 8 x ``S_BLOCK`` elements: the decode kernel's block for a
+    sublane tile of rows (the verify shape), 128 keys, one lane tile, from
+    32 rows on (a chunk computes, and masks, no further past its reach than
+    that). Then the step has to fit ``PAGED_MULTITOKEN_VMEM_BYTES``: beside
+    the K and V page buffers a head costs its query and output blocks
+    (double-buffered), the float32 (m, l, acc) scratch, the block's K and V
+    joined in the query's type and the float32 scores and probabilities.
+    Fewer pages first (down to the lane tile), then fewer heads a step (the
+    largest divisor of ``KV`` that fits). ``None`` when one head does not."""
+    decode = paged_decode_blocks(KV, page, D, itemsize, n_pages)
+    if decode is None:
+        return None
+    lanes, qsize = _round_up(D, 128), max(2, itemsize)  # int8 codes meet a bf16 q
+    rows = _round_up(rep * T, 8)
+
+    def step_bytes(HB, G):
+        keys = _round_up(G * page, 128)
+        return HB * (
+            4 * _round_up(rep * T, 32 // qsize) * lanes * qsize  # q, o x 2
+            + rows * (lanes + 2 * 128) * 4  # acc, m, l
+            + 4 * G * _page_tile_bytes(page, D, itemsize)  # K, V x 2
+            + 2 * G * page * lanes * qsize  # the block's K and V, joined
+            + rows * keys * (8 + qsize)  # s, p and p in V's type
+        )
+
+    HB, G = decode
+    floor = max(1, min(G, 128 // page))  # pages of one lane tile of keys
+    G = max(floor, min(G, S_BLOCK * 8 // rows // page))
+    G = 1 << (G.bit_length() - 1)
+    while G > floor and step_bytes(HB, G) > PAGED_MULTITOKEN_VMEM_BYTES:
+        G //= 2
+    return next(
+        ((hb, G) for hb in range(HB, 0, -1)
+         if HB % hb == 0 and step_bytes(hb, G) <= PAGED_MULTITOKEN_VMEM_BYTES),
+        None,
+    )
+
+
 def _pool_dims(pool, layer: Optional[int]):
     """(KV, page) of a ``[P, KV, page, D]`` pool or, with a static ``layer``,
     of a whole ``[L, P, KV, page, D]`` one."""
@@ -175,25 +227,38 @@ def _pool_block_spec(block, index_map, layer: Optional[int]):
     )
 
 
-def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
-                  nhb: int, quantized: bool = False):
-    """Online-softmax accumulation over one slot's pages, ``G`` pages and
-    ``HB`` kv-heads (all of them, unless a page of all does not fit VMEM) to
-    a grid step.
+def _paged_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
+                  nhb: int, T: int = 1, rep: int = 1, quantized: bool = False):
+    """Online-softmax accumulation over one slot's pages for ``T`` query
+    tokens of the slot (1: the decode step; more: chunked prefill and the
+    verify shape), ``G`` pages and ``HB`` kv-heads (all of them, unless a
+    step of all does not fit VMEM) to a grid step.
 
     Grid (B * nhb, ceil(n_pages / G)), sequential: row ``r`` is slot
     ``r // nhb``, head block ``r % nhb``; the (m, l, acc) scratch persists
     across the row's page blocks, reset at block 0 and emitted at the slot's
-    LAST OWN block ``pos // (G * page)``. Blocks past it skip their compute,
-    and the walked table (:func:`paged_decode_attention`) names for them the
-    pages the last own block named, so Pallas fetches nothing. ``rest`` holds
-    the block's ``G`` K pages and ``G`` V pages, ``[1, HB, page, D]`` each.
-    A page ref past the slot's last page holds one of the slot's earlier
-    pages; its scores are masked and its probabilities are exactly 0.
+    LAST OWN block. Query ``t`` of slot ``b`` sits at position ``at[b] + t``
+    and attends keys at positions ``<= at[b] + t``, so the last own block is
+    the one the tokens reach, ``(at + T - 1) // (G * page)``. Blocks past it
+    skip their compute, and the walked table (:func:`_walked_table`) names
+    for them the pages the last own block named, so Pallas fetches nothing.
+    ``rest`` holds the block's ``G`` K pages and ``G`` V pages, ``[1, HB,
+    page, D]`` each. A page ref past the slot's last page holds one of the
+    slot's earlier pages; its scores are masked and its probabilities are
+    exactly 0.
 
     The arithmetic is batched over heads: ``s[h, r, p]`` and ``acc[h, r, d]``
     are one ``dot_general`` each over ``[HB, G * page, D]``, q viewed as
-    ``[HB, rep, D]`` so a GQA group reads its single pool column.
+    ``[HB, rep * T, D]`` (row ``g * T + t`` is query head ``g`` of the group,
+    token ``t``) so a GQA group reads its single pool column.
+
+    The mask only where it can bite: with more than a sublane tile of query
+    rows, a block whose last key is ``<= at`` is visible to every query and
+    takes a branch that builds none; only the blocks that overlap ``at ..
+    at + T - 1`` do (the last one alone, where chunks start on block
+    multiples). With a tile of rows or fewer the scores are a few registers
+    and one masked branch, with the emit inside it, is cheaper than a second
+    copy of the body and a third branch a step.
 
     ``quantized`` (ISSUE 12): K/V pages are int8 codes (exact in the query's
     float type) and one more input carries the block's K and V scales per
@@ -205,9 +270,11 @@ def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
     o_ref, m_ref, l_ref, acc_ref = rest
     r = pl.program_id(0)
     j = pl.program_id(1)
-    pos = pos_ref[r if nhb == 1 else jax.lax.div(r, nhb)]
+    at = at_ref[r if nhb == 1 else jax.lax.div(r, nhb)]
     GP = G * k_refs[0].shape[2]
-    last_blk = jax.lax.div(pos, GP)
+    last_blk = jax.lax.div(at + (T - 1), GP)
+    if T > 1:  # a chunk may reach past the table; a decode position does not
+        last_blk = jnp.minimum(last_blk, pl.num_programs(1) - 1)
 
     @pl.when(j == 0)
     def _reset():
@@ -215,9 +282,13 @@ def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j <= last_blk)
-    def _update():
-        q = q_ref[0, 0]  # [HB, rep, D]
+    def emit():
+        o_ref[0, 0] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(o_ref.dtype)
+
+    def update(masked: bool, emits: bool = False):
+        q = q_ref[0, 0]  # [HB, rep * T, D]
         k = jnp.concatenate([r[0] for r in k_refs], axis=1)  # [HB, GP, D]
         v = jnp.concatenate([r[0] for r in v_refs], axis=1)
         if quantized:
@@ -227,30 +298,121 @@ def _paged_kernel(walk_ref, pos_ref, q_ref, *rest, sm_scale: float, G: int,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * sm_scale  # [HB, rep, GP]
+        ) * sm_scale  # [HB, rep * T, GP]
         if quantized:
             s = s * sc_ref[0, 0, 0, 0]
-        live = jax.lax.broadcasted_iota(jnp.int32, (1, 1, GP), 2) + j * GP <= pos
-        s = jnp.where(live, s, -1e30)
-        m_prev, l_prev = m_ref[...], l_ref[...]  # [HB, rep, 1]
+        if masked:
+            key = jax.lax.broadcasted_iota(jnp.int32, (1, 1, GP), 2) + j * GP
+            t = 0
+            if T > 1:
+                t = jax.lax.broadcasted_iota(jnp.int32, (1, rep * T, 1), 1)
+                for _ in range(1, rep):  # row g * T + t -> t, with no division
+                    t = jnp.where(t >= T, t - T, t)
+            s = jnp.where(key <= at + t, s, -1e30)
+        m_prev, l_prev = m_ref[...], l_ref[...]  # [HB, rep * T, 1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur)
         m_ref[...] = m_cur
         l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         if quantized:
-            # a padded table entry's scale row may hold anything: 0 * it too
-            p = p * jnp.where(live, sc_ref[0, 0, 1, 0], 0.0)
+            sv = sc_ref[0, 0, 1, 0]
+            if masked:
+                # a padded table entry's scale row may hold anything: 0 * it too
+                sv = jnp.where(key <= at + (T - 1), sv, 0.0)
+            p = p * sv
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )  # [HB, rep, D]
+        )  # [HB, rep * T, D]
+        if emits:
+            pl.when(j == last_blk)(emit)
 
-        @pl.when(j == last_blk)
-        def _emit():
-            o_ref[0, 0] = (
-                acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-            ).astype(o_ref.dtype)
+    own = j <= last_blk
+    if rep * T <= 8:
+        # the decode step's form: one branch a grid step, the emit inside it
+        pl.when(own)(functools.partial(update, True, emits=True))
+    else:
+        whole = (j + 1) * GP - 1 <= at  # every key visible to every query
+        pl.when(own & whole)(functools.partial(update, False))
+        pl.when(own & jnp.logical_not(whole))(functools.partial(update, True))
+        pl.when(j == last_blk)(emit)
+
+
+def _walked_table(block_tables, last, n_blk: int, G: int):
+    """The table as the grid walks it: entry (j, g) of a row is the page that
+    input g holds in block j. Past the slot's last own page ``last`` ([B, 1])
+    that is the page the input held a block ago (an unchanged index fetches
+    nothing) or, in the first block, the slot's first page. Computed once for
+    all layers (the same table and lengths: XLA folds the repeats), so an
+    index map is one SMEM read."""
+    e = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :]
+    e = jnp.minimum(e // G, last // G) * G + e % G
+    e = jnp.clip(jnp.where(e > last, e - G, e), 0, last)
+    return jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32), e, axis=1)
+
+
+def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
+                HB: int, G: int, scales, layer: Optional[int], interpret: bool):
+    """The ``pallas_call`` of :func:`_paged_kernel`, for both wrappers: grid
+    ``(B * nhb, n_blk)`` over ``q5`` ``[B, nhb, HB, R, D]`` (``R`` query rows
+    a kv-head: ``rep`` for the decode step, ``rep * T`` for T tokens), the
+    ``G`` K and ``G`` V page inputs under the walked table, an int8 pool's
+    scales per key column, and the (m, l, acc) scratch. ``at`` ([B], scalar
+    prefetched with the table) is what the kernel masks by, ``last`` the
+    slot's last own page and ``last_blk(at[b])`` its last own block."""
+    B, nhb, _, R, D = q5.shape
+    page = k_pool.shape[-2]
+    n_pages = block_tables.shape[1]
+    n_blk, GP = -(-n_pages // G), G * page
+    walk = _walked_table(block_tables, last[:, None], n_blk, G)
+
+    def row(r):  # grid row -> (slot, head block)
+        return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
+
+    def page_spec(g):
+        def index_map(r, j, walk, at):
+            b, hb = row(r)
+            return walk[b, j * G + g], hb, 0, 0
+
+        return _pool_block_spec((1, HB, page, D), index_map, layer)
+
+    def qo_map(r, j, walk, at):
+        return (*row(r), 0, 0, 0)
+
+    qo_spec = pl.BlockSpec((1, 1, HB, R, D), qo_map)
+    pages = [page_spec(g) for g in range(G)]
+    in_specs = [qo_spec] + pages + pages
+    operands = [q5] + [k_pool] * G + [v_pool] * G
+    if scales is not None:
+        # per key column of each page block: [B, n_blk, 2, nhb, HB, 1, GP];
+        # blocks past the slot's last keep its index, so nothing is fetched
+        st = jnp.asarray(scales, jnp.float32)[block_tables]  # [B, n, KV, 2]
+        st = jnp.pad(st, ((0, 0), (0, n_blk * G - n_pages), (0, 0), (0, 0)))
+        st = jnp.repeat(st, page, axis=1).reshape(B, n_blk, GP, nhb, HB, 2)
+        operands.append(st.transpose(0, 1, 5, 3, 4, 2)[..., None, :])
+
+        def scale_map(r, j, walk, at):
+            b, hb = row(r)
+            return b, jax.lax.min(j, last_blk(at[b])), 0, hb, 0, 0, 0
+
+        in_specs.append(pl.BlockSpec((1, 1, 2, 1, HB, 1, GP), scale_map))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # walked table + per-slot positions
+            grid=(B * nhb, n_blk),
+            in_specs=in_specs,
+            out_specs=qo_spec,
+            scratch_shapes=[
+                pltpu.VMEM((HB, R, 1), jnp.float32),  # running max
+                pltpu.VMEM((HB, R, 1), jnp.float32),  # running denominator
+                pltpu.VMEM((HB, R, D), jnp.float32),  # output accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+        interpret=interpret,
+    )(walk, at, *operands)
 
 
 def paged_decode_attention(
@@ -298,131 +460,18 @@ def paged_decode_attention(
             "does not fit the kernel's VMEM budget"
         )
     HB, G = blocks
-    nhb, n_blk, GP = KV // HB, -(-n_pages // G), G * page
-
-    # The table as the grid walks it: entry (j, g) of a row is the page that
-    # input g holds in block j. Past the slot's last page that is the page the
-    # input held a block ago (an unchanged index fetches nothing) or, in the
-    # first block, the slot's first page. Computed here once for all layers
-    # (the same table and lengths: XLA folds the repeats), so an index map
-    # is one SMEM read.
+    nhb = KV // HB
     pos = jnp.asarray(pos, jnp.int32)
-    last = (pos // page)[:, None]  # [B, 1] the slot's last own page
-    e = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :]
-    e = jnp.minimum(e // G, last // G) * G + e % G
-    e = jnp.clip(jnp.where(e > last, e - G, e), 0, last)
-    walk = jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32), e, axis=1)
-
-    def row(r):  # grid row -> (slot, head block)
-        return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
-
-    def page_spec(g):
-        def index_map(r, j, walk, pos):
-            b, hb = row(r)
-            return walk[b, j * G + g], hb, 0, 0
-
-        return _pool_block_spec((1, HB, page, D), index_map, layer)
-
-    def qo_map(r, j, walk, pos):
-        return (*row(r), 0, 0, 0)
-
-    qo_spec = pl.BlockSpec((1, 1, HB, rep, D), qo_map)
-    pages = [page_spec(g) for g in range(G)]
-    in_specs = [qo_spec] + pages + pages
-    operands = [q.reshape(B, nhb, HB, rep, D)] + [k_pool] * G + [v_pool] * G
-    if quantized:
-        # per key column of each page block: [B, n_blk, 2, nhb, HB, 1, GP];
-        # blocks past the slot's last keep its index, so nothing is fetched
-        st = jnp.asarray(scales, jnp.float32)[block_tables]  # [B, n, KV, 2]
-        st = jnp.pad(st, ((0, 0), (0, n_blk * G - n_pages), (0, 0), (0, 0)))
-        st = jnp.repeat(st, page, axis=1).reshape(B, n_blk, GP, nhb, HB, 2)
-        operands.append(st.transpose(0, 1, 5, 3, 4, 2)[..., None, :])
-
-        def scale_map(r, j, walk, pos):
-            b, hb = row(r)
-            return b, jax.lax.min(j, jax.lax.div(pos[b], GP)), 0, hb, 0, 0, 0
-
-        in_specs.append(pl.BlockSpec((1, 1, 2, 1, HB, 1, GP), scale_map))
     kernel = functools.partial(
-        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb,
+        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=1, rep=rep,
         quantized=quantized,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # walked table + per-slot positions
-            grid=(B * nhb, n_blk),
-            in_specs=in_specs,
-            out_specs=qo_spec,
-            scratch_shapes=[
-                pltpu.VMEM((HB, rep, 1), jnp.float32),  # running max
-                pltpu.VMEM((HB, rep, 1), jnp.float32),  # running denominator
-                pltpu.VMEM((HB, rep, D), jnp.float32),  # output accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, nhb, HB, rep, D), q.dtype),
-        interpret=interpret,
-    )(walk, pos, *operands)
+    out = _paged_call(
+        kernel, q.reshape(B, nhb, HB, rep, D), k_pool, v_pool, block_tables,
+        pos, pos // page, lambda pos_b: jax.lax.div(pos_b, G * page),
+        HB, G, scales, layer, interpret,
+    )
     return out.reshape(B, H, D)
-
-
-def _paged_multitoken_kernel(bt_ref, base_ref, q_ref, k_ref, v_ref, *rest,
-                             sm_scale: float, page: int, T: int,
-                             rep: int = 1, quantized: bool = False):
-    """Online-softmax over one slot's pages for T query tokens at once.
-
-    The verify-step / chunked-prefill analog of :func:`_paged_kernel`
-    (ISSUE 10): query t of slot b sits at absolute position
-    ``base[b] + t`` and may attend keys at positions ``<= base[b] + t`` —
-    the extra column dimension turns the scalar (m, l) softmax state into
-    [1, T] rows and the accumulator into [T, D], everything else is the
-    same sequential-grid accumulation. Pages wholly past ``base + T - 1``
-    skip their compute. ``quantized``: int8 K/V codes dequantize in VMEM
-    through the page's [1, KV, 2] scale row (ISSUE 12)."""
-    if quantized:
-        s_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        s_ref, (o_ref, m_ref, l_ref, acc_ref) = None, rest
-    b = pl.program_id(0)
-    g = pl.program_id(1) // rep
-    j = pl.program_id(2)
-    D = q_ref.shape[-1]
-
-    @pl.when(j == 0)
-    def _reset():
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    base = base_ref[b]
-
-    @pl.when(j * page <= base + T - 1)
-    def _update():
-        q = q_ref[...].reshape(T, D)
-        k = k_ref[0, 0]  # [page, D]
-        v = v_ref[0, 0]
-        if quantized:
-            k = k.astype(jnp.float32) * s_ref[0, g, 0]
-            v = v.astype(jnp.float32) * s_ref[0, g, 1]
-        s = jnp.dot(k, q.T, preferred_element_type=jnp.float32) * sm_scale  # [page,T]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (page, T), 0) + j * page
-        t_col = jax.lax.broadcasted_iota(jnp.int32, (page, T), 1)
-        s = jnp.where(idx <= base + t_col, s, -1e30)
-        m_prev, l_prev = m_ref[...], l_ref[...]           # [1, T]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        corr = jnp.exp(m_prev - m_cur)                    # [1, T]
-        p = jnp.exp(s - m_cur)                            # [page, T]
-        m_ref[...] = m_cur
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr.T + jnp.dot(
-            p.astype(v.dtype).T, v, preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[...].T, 1e-30)
-        ).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def paged_multitoken_attention(
@@ -438,11 +487,18 @@ def paged_multitoken_attention(
 ) -> jnp.ndarray:
     """T-token causal attention against a PAGED cache → [B, T, H, D].
 
-    Serves the speculative verify step (T = k+1 drafted tokens, base =
-    per-slot cached length) and chunked prefill (T = chunk width, base =
-    chunk start) — the chunk's own K/V must already be scattered into the
-    pool (update-then-attend, as in the single-token decode step). GQA and
-    ``layer`` as in :func:`paged_decode_attention`."""
+    Serves chunked prefill (T = chunk width, base = chunk start) and the
+    verify shape (T = k+1 drafted tokens, base = per-slot cached length) —
+    the chunk's own K/V must already be scattered into the pool
+    (update-then-attend, as in the single-token decode step). The plan is
+    :func:`paged_decode_attention`'s: all kv-heads and ``G`` pages to a grid
+    step (:func:`paged_multitoken_blocks` picks both from the shapes), the
+    walked table ending at the page the chunk reaches, so table entries past
+    ``(base[b] + T - 1) // page`` are never read; GQA, int8 ``scales`` and
+    ``layer`` as there. The queries go in head-major, ``[B, KV, rep * T,
+    D]``, which is what the head-batched ``dot_general`` takes: the two
+    ``[T, H] <-> [H, T]`` transposes around the call stay (0.4 MB each at
+    the served shape)."""
     B, T, H, D = q.shape
     KV, page = _pool_dims(k_pool, layer)
     n_pages = block_tables.shape[1]
@@ -450,51 +506,33 @@ def paged_multitoken_attention(
         raise ValueError(f"q heads {H} must divide by KV heads {KV}")
     rep = H // KV
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
-    quantized = scales is not None
-
+    blocks = paged_multitoken_blocks(
+        KV, page, D, T, k_pool.dtype.itemsize, n_pages, rep
+    )
+    if blocks is None:
+        raise ValueError(
+            f"paged_multitoken_attention: {rep * T} query rows against one "
+            f"[{page}, {D}] page of one head do not fit the kernel's VMEM "
+            "budget"
+        )
+    HB, G = blocks
+    nhb = KV // HB
+    base = jnp.asarray(base, jnp.int32)
     kernel = functools.partial(
-        _paged_multitoken_kernel, sm_scale=float(scale), page=page, T=T,
-        rep=rep, quantized=quantized,
+        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=T, rep=rep,
+        quantized=scales is not None,
     )
-    q4 = jnp.swapaxes(q, 1, 2)  # [B, H, T, D]: trailing block == array dims
-    pool_spec = _pool_block_spec(
-        (1, 1, page, D), lambda b, h, j, bt, base: (bt[b, j], h // rep, 0, 0),
-        layer,
+    q5 = q.reshape(B, T, nhb, HB, rep, D).transpose(0, 2, 3, 4, 1, 5)
+    n_blk = -(-n_pages // G)
+    out = _paged_call(
+        kernel, q5.reshape(B, nhb, HB, rep * T, D), k_pool, v_pool,
+        block_tables, base, jnp.minimum((base + (T - 1)) // page, n_pages - 1),
+        lambda base_b: jax.lax.min(
+            jax.lax.div(base_b + (T - 1), G * page), n_blk - 1),
+        HB, G, scales, layer, interpret,
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, T, D), lambda b, h, j, bt, base: (b, h, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [q4, k_pool, v_pool]
-    if quantized:
-        in_specs.append(pl.BlockSpec(
-            (1, KV, 2), lambda b, h, j, bt, base: (bt[b, j], 0, 0)
-        ))
-        operands.append(jnp.asarray(scales, jnp.float32))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # block table + per-slot base positions
-            grid=(B, H, n_pages),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, T, D), lambda b, h, j, bt, base: (b, h, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((1, T), jnp.float32),  # running max per query
-                pltpu.VMEM((1, T), jnp.float32),  # running denominator
-                pltpu.VMEM((T, D), jnp.float32),  # output accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
-        interpret=interpret,
-    )(
-        jnp.asarray(block_tables, jnp.int32),
-        jnp.asarray(base, jnp.int32),
-        *operands,
-    )
-    return jnp.swapaxes(out, 1, 2)  # [B, T, H, D]
+    out = out.reshape(B, nhb, HB, rep, T, D).transpose(0, 4, 1, 2, 3, 5)
+    return out.reshape(B, T, H, D)
 
 
 def _token_write_kernel(pidx_ref, poff_ref, k_new_ref, v_new_ref, k_ref, v_ref,
@@ -634,17 +672,15 @@ def paged_decode_attention_ok(
 
 
 def paged_multitoken_attention_ok(
-    page: int, D: int, T: int, itemsize: int = 2
+    KV: int, page: int, D: int, T: int, itemsize: int = 2, rep: int = 1
 ) -> bool:
-    """Gate for the multitoken paged kernel: the page rule plus one head's
-    K+V page and the [T, D] query/accumulator slabs staying VMEM-resident
-    (its per-program cost is pool/B/H independent)."""
-    from .flash_attention import VMEM_RESIDENT_BYTES
-
+    """Gate for the multi-token paged kernel: the page rule, and a block
+    :func:`paged_multitoken_blocks` can place in VMEM (``KV`` the pool's own
+    head count, ``rep`` query heads to each)."""
     return (
         paged_page_ok(page, D, itemsize)
-        and (2 * page * D * itemsize + T * D * (itemsize + 4)
-             <= VMEM_RESIDENT_BYTES)
+        and paged_multitoken_blocks(KV, page, D, T, itemsize, rep=rep)
+        is not None
     )
 
 

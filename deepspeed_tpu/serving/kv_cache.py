@@ -13,10 +13,11 @@ layouts (the page dim sits where Mosaic wants its sublane axis, see
 Page 0 is a permanently-reserved scratch page: inactive slots and the padded
 tail of block-table rows point at it, so every compiled gather/scatter index
 is valid without masking, and garbage writes land somewhere no active slot
-ever reads. The decode kernel takes a page's ``[KV, page, D]`` run — all
-kv-heads, contiguous in this layout — with one DMA and walks a row only as
-far as the slot's own last page, so the padded tail costs it nothing; the
-multi-token kernel and the jnp fallbacks still gather it and mask.
+ever reads. The paged kernels take a page's ``[KV, page, D]`` run — all
+kv-heads, contiguous in this layout — with one DMA and walk a row only as
+far as the slot's own last page (the decode step) or the page the chunk
+reaches (the multi-token kernel), so the padded tail costs them nothing;
+the jnp fallbacks still gather it and mask.
 
 On a TPU the pool of a head narrower than 128 lanes is STORED with its page
 axis split (:func:`pool_stored_shape`), which keeps the device's

@@ -46,7 +46,8 @@ import numpy as np
 from jax import lax
 
 from ..models import gpt2
-from ..models.gpt2 import GPT2Config, KVCache, _layer_norm, _mlp
+from ..models.gpt2 import GPT2Config, KVCache, _mlp
+from ..ops.layer_norm import layer_norm_inference as _layer_norm
 from ..ops.quantizer import (
     dequantize_kv_pages,
     kv_page_scale,

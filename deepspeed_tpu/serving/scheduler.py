@@ -561,6 +561,13 @@ class ServingEngine:
             "HLO temp bytes of a compiled serving program (per device)",
             labelnames=("program",),
         )
+        self._g_grid_steps = m.gauge(
+            "serving_paged_grid_steps",
+            "grid steps of one paged attention kernel call of a compiled "
+            "serving program: slots x head blocks x page blocks (0 = the "
+            "program calls no such kernel)",
+            labelnames=("program",),
+        )
         # -- ISSUE 14: TP sharding + disaggregation instruments ------------
         self._g_tp_coll = m.gauge(
             "serving_tp_collective_bytes",
@@ -1073,19 +1080,40 @@ class ServingEngine:
 
     def _set_census_gauges(self) -> dict:
         """Per compiled program: how many pool-layer-sized copies, slices
-        and transposes its optimised HLO holds, and its temp bytes, as
-        gauges and (returned) as the attrs of the ``ds.init.programs``
-        phase: ``relayout_ops`` / ``temp_bytes`` keyed ``<program>=<n>``."""
-        relayout, temp = {}, {}
+        and transposes its optimised HLO holds, its temp bytes, and the grid
+        steps of one call of its paged attention kernel, as gauges and
+        (returned) as the attrs of the ``ds.init.programs`` phase:
+        ``relayout_ops`` / ``temp_bytes`` / ``grid_steps`` keyed
+        ``<program>=<n>``."""
+        from ..ops.attention import paged_attention_grid_steps
+
+        # (slots, query tokens a slot) of a program's attention kernel call;
+        # the verify step attends as k+1 single-token calls
+        shapes = {
+            "decode": (self.max_slots, None),
+            "verify": (self.max_slots, None),
+            "chunk": (1, self.chunk_width),
+        }
+        relayout, temp, steps = {}, {}, {}
         for name, rec in self._program_info.items():
-            relayout[name], temp[name] = rec["pset"].program_census(
-                name, rec["exe"]
-            )
+            pset = rec["pset"]
+            relayout[name], temp[name] = pset.program_census(name, rec["exe"])
+            steps[name] = 0
+            if rec["kind"] in shapes:
+                B, T = shapes[rec["kind"]]
+                steps[name] = paged_attention_grid_steps(
+                    self.model_config.attn_impl, B, pset.local_kv_heads(),
+                    pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
+                    self.pages_per_slot, T,
+                    rep=self.model_config.n_head // pset.n_kv_head,
+                )
             self._g_relayout.set(relayout[name], program=name)
             self._g_temp_bytes.set(temp[name], program=name)
+            self._g_grid_steps.set(steps[name], program=name)
         return {
-            "relayout_ops": " ".join(f"{k}={v}" for k, v in relayout.items()),
-            "temp_bytes": " ".join(f"{k}={v}" for k, v in temp.items()),
+            key: " ".join(f"{k}={v}" for k, v in got.items())
+            for key, got in (("relayout_ops", relayout), ("temp_bytes", temp),
+                             ("grid_steps", steps))
         }
 
     def _set_collective_gauges(self) -> None:

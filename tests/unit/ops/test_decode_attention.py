@@ -268,7 +268,7 @@ class TestPagedMultitoken:
         )
 
         # CPU backend: gate is False regardless of shape
-        assert not paged_multitoken_attention_ok(16, 64, 5)
+        assert not paged_multitoken_attention_ok(25, 16, 64, 5)
 
 
 class TestQuantizedPagedAttention:
@@ -672,3 +672,232 @@ class TestPagedDecodeBlocks:
         )
 
         assert not paged_decode_attention_ok(25, 16, 64, 2)
+
+
+class TestPagedMultitokenServedShape:
+    """ISSUE 31: the multi-token paged kernel on the decode kernel's plan —
+    all kv-heads and ``G`` pages to a grid step, the walked table ending at
+    the page the chunk reaches, the mask built only in the blocks that
+    overlap the chunk. Cases at the benchmark's served shape (25 heads of
+    64, page 16, table 64 wide, chunks of 128) against the jnp fallback,
+    with every page a slot does not own set to NaN and the table entries
+    past the chunk's reach naming that page."""
+
+    def _pool(self, base, T, KV, D, page, n, dtype, seed):
+        """``TestPagedDecodeServedShape._pool`` for slots that own the pages
+        up to their chunk's last position."""
+        reach = [int(b) + T - 1 for b in base]
+        kp, vp, bt, _ = TestPagedDecodeServedShape()._pool(
+            reach, KV, D, page, n, dtype, seed
+        )
+        return kp, vp, bt, jnp.asarray(base, jnp.int32)
+
+    def _check(self, base, T=128, H=25, KV=25, D=64, page=16, n=64,
+               dtype=jnp.float32, tol=2e-5, seed=0, garbage=None):
+        from deepspeed_tpu.ops.attention import paged_multitoken_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_multitoken_attention,
+        )
+
+        kp, vp, bt, base = self._pool(base, T, KV, D, page, n, dtype, seed)
+        q = jnp.asarray(
+            np.random.RandomState(seed + 1).randn(len(base), T, H, D), dtype
+        )
+        table = bt
+        if garbage is not None:  # entries past the reach: ids of no page at all
+            reach = (np.asarray(base) + T - 1) // page
+            past = np.arange(n)[None, :] > reach[:, None]
+            table = jnp.where(jnp.asarray(past), garbage, bt)
+        out = paged_multitoken_attention(
+            q, kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan), table, base,
+            interpret=True,
+        )
+        ref = paged_multitoken_cached_attention(q, kp, vp, bt, base, impl="jnp")
+        assert out.shape == ref.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol,
+        )
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("base", [0, 128, 896])
+    def test_chunk_at_served_shape(self, base, dtype):
+        """One slot's chunk of 128 at its first, second and last start."""
+        self._check([base], dtype=jnp.dtype(dtype),
+                    tol=2e-5 if dtype == "float32" else 2e-2, seed=base)
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_verify_shape_off_the_block_edges(self, dtype):
+        """T = 5 queries a slot from positions that are no block multiple:
+        inside a block, across a page edge, across a block edge, at the end
+        of the table."""
+        self._check([3, 14, 125, 130, 1019], T=5, dtype=jnp.dtype(dtype),
+                    tol=2e-5 if dtype == "float32" else 2e-2, seed=5)
+
+    def test_several_slots_a_call(self):
+        """B > 1 with different reaches: what batching the prefilling slots
+        into one call will ask of the kernel."""
+        self._check([0, 640, 128, 896], seed=7)
+
+    @pytest.mark.parametrize("rep", [2, 5])
+    def test_gqa_groups_read_one_pool_column(self, rep):
+        self._check([0, 17, 250, 1000], T=16, H=5 * rep, KV=5, seed=rep)
+
+    def test_head_dim_128(self):
+        self._check([0, 100, 992], T=32, H=4, KV=4, D=128, seed=3)
+
+    def test_head_blocks_when_all_heads_do_not_fit(self):
+        """KV=64 heads of a [128, 128] f32 page: 16 heads to a step, four
+        head blocks a slot, one page at a time."""
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_multitoken_blocks,
+        )
+
+        assert paged_multitoken_blocks(64, 128, 128, 8, 4, 3) == (16, 1)
+        self._check([5, 300], T=8, H=64, KV=64, D=128, page=128, n=3, seed=4)
+
+    def test_table_entries_past_the_reach_are_never_read(self):
+        """Past ``(base + T - 1) // page`` the table may hold anything, ids
+        of no page included: the walked table stops at the chunk's reach."""
+        self._check([0, 130, 500], T=64, garbage=10 ** 6, seed=8)
+
+    @pytest.mark.parametrize("rep", [1, 2])
+    def test_int8_pool_with_scales(self, rep):
+        from deepspeed_tpu.ops.attention import paged_multitoken_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_multitoken_attention,
+        )
+        from deepspeed_tpu.ops.quantizer import quantize_kv_pages
+
+        KV, D, page, n, T = 5, 64, 32, 32, 64
+        kf, vf, bt, base = self._pool(
+            [0, 31, 64, 960, 200], T, KV, D, page, n, jnp.float32, 5
+        )
+        kq, ks = quantize_kv_pages(kf)
+        vq, vs = quantize_kv_pages(vf)
+        scales = jnp.stack([ks, vs], axis=-1)  # [P, KV, 2]
+        q = jnp.asarray(
+            np.random.RandomState(6).randn(5, T, KV * rep, D), jnp.float32
+        )
+        # the scratch page's codes cannot be NaN; its scales can
+        out = paged_multitoken_attention(
+            q, kq, vq, bt, base, interpret=True,
+            scales=scales.at[0].set(jnp.nan),
+        )
+        ref = paged_multitoken_cached_attention(
+            q, kq, vq, bt, base, impl="jnp", scales=scales
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
+        )
+
+    def test_one_token_is_the_decode_kernel(self):
+        """T = 1 is the decode step's attention: the same blocks, the same
+        walk, the same numbers as :func:`paged_decode_attention`."""
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+            paged_multitoken_attention,
+        )
+
+        kp, vp, bt, pos = self._pool(
+            [0, 15, 16, 127, 128, 700, 1023, 40], 1, 25, 64, 16, 64,
+            jnp.float32, 9,
+        )
+        q = jnp.asarray(np.random.RandomState(10).randn(8, 25, 64), jnp.float32)
+        one = paged_multitoken_attention(q[:, None], kp, vp, bt, pos,
+                                         interpret=True)[:, 0]
+        dec = paged_decode_attention(q, kp, vp, bt, pos, interpret=True)
+        np.testing.assert_allclose(np.asarray(one), np.asarray(dec),
+                                   atol=1e-6, rtol=1e-6)
+
+
+class TestPagedMultitokenBlocks:
+    """The multi-token block rule, the gate and the grid the gauge reports."""
+
+    @pytest.mark.parametrize("shape,want", [
+        # (KV, page, D, T, itemsize, n_pages[, rep])
+        # GPT-2-XL's chunk of 128, bf16: all heads, 128 keys a step
+        ((25, 16, 64, 128, 2, 64), (25, 8)),
+        # the verify shape: a sublane tile of rows takes the decode kernel's block
+        ((25, 16, 64, 5, 2, 64), (25, 8)),
+        ((5, 16, 64, 5, 2, 64), (5, 32)),
+        # a shard of five heads at T = 128: still 128 keys a step
+        ((5, 16, 64, 128, 2, 64), (5, 8)),
+        ((5, 16, 64, 128, 2, 64, 5), (5, 8)),
+        # 128-wide heads (OLMoE: 16 of them) and 64 of them: head blocks
+        ((16, 16, 128, 128, 2, 64), (16, 8)),
+        ((64, 16, 128, 128, 2, 64), (32, 4)),
+        # int8 pages of 32
+        ((25, 32, 64, 128, 1, 32), (25, 4)),
+        # a narrow table caps the block
+        ((25, 16, 64, 128, 2, 3), (25, 2)),
+        # a page of all heads does not fit; one head's page does not
+        ((64, 128, 128, 128, 4, 4), (16, 1)),
+        ((8, 2048, 256, 128, 4, 2), None),
+    ])
+    def test_blocks_from_shapes(self, shape, want):
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_blocks,
+            paged_multitoken_blocks,
+        )
+
+        got = paged_multitoken_blocks(*shape)
+        assert got == want
+        if got is not None:
+            KV, page, D, T, itemsize, n = shape[:6]
+            hb, g = got
+            assert KV % hb == 0 and g & (g - 1) == 0
+            dec = paged_decode_blocks(KV, page, D, itemsize, n)
+            assert hb <= dec[0] and g <= dec[1]
+
+    @pytest.mark.parametrize("args,want", [
+        ((25, 16, 64, 128, 2), True),       # XL, chunk of 128
+        ((25, 16, 64, 5, 2), True),         # the verify shape
+        ((16, 16, 128, 128, 2), True),      # OLMoE's heads
+        ((5, 16, 64, 128, 2, 5), True),     # GQA, five query heads a column
+        ((25, 32, 64, 128, 1), True),       # int8 pages of 32
+        ((25, 16, 64, 128, 1), False),      # int8 wants pages of 32
+        ((25, 16, 80, 128, 2), False),      # head dim off the lanes
+        ((8, 2048, 256, 128, 4), False),    # one head's page over the budget
+    ])
+    def test_gate_on_tpu(self, monkeypatch, args, want):
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert da.paged_multitoken_attention_ok(*args) is want
+
+    @pytest.mark.parametrize("impl,T,want", [
+        ("pallas", 128, 8),    # the chunk call: one slot, 8 page blocks (1 600 before)
+        ("pallas", None, 64),  # the decode step: 8 slots x 8 page blocks
+        ("auto", 128, 0),      # off the TPU the fallback runs
+        ("jnp", None, 0),
+    ])
+    def test_grid_steps_at_the_served_shape(self, impl, T, want):
+        from deepspeed_tpu.ops.attention import paged_attention_grid_steps
+
+        B = 8 if T is None else 1
+        assert paged_attention_grid_steps(impl, B, 25, 16, 64, 2, 64, T) == want
+
+    @pytest.mark.parametrize("rep,T,want", [
+        (1, 256, 8),    # 4 kv-heads a step, 8 page blocks
+        (8, 256, 16),   # 2 048 query rows a kv-head: 2 kv-heads a step
+        (8, None, 2),   # the decode step does not widen with rep: 32 pages a block
+    ])
+    def test_grid_steps_count_the_group(self, rep, T, want):
+        """``rep`` query heads share a kv-head's rows in a multi-token step,
+        so the block rule gives fewer heads a step and the gauge must read
+        what the kernel runs."""
+        from deepspeed_tpu.ops.attention import paged_attention_grid_steps
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        got = paged_attention_grid_steps("pallas", 1, 4, 16, 64, 2, 64, T, rep)
+        assert got == want
+        if T is not None:
+            HB, G = da.paged_multitoken_blocks(4, 16, 64, T, 2, 64, rep)
+            assert got == (4 // HB) * -(-64 // G)
+
+    def test_grid_steps_refuse_an_unknown_impl(self):
+        from deepspeed_tpu.ops.attention import paged_attention_grid_steps
+
+        with pytest.raises(ValueError, match="unknown attention impl"):
+            paged_attention_grid_steps("flash", 1, 25, 16, 64, 2, 64, 128)

@@ -79,21 +79,33 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, layered):
 
 
 @pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
-@pytest.mark.parametrize("name", ["gpt2-xl", "kv64-d128", "xl-int8"])
-def test_paged_multitoken_kernel_compiles_for_v5e(one_chip, name, layered):
-    """The chunk-prefill width (128 query tokens) at the served shapes."""
+@pytest.mark.parametrize("T", [128, 5], ids=["chunk128", "verify5"])
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_paged_multitoken_kernel_compiles_for_v5e(one_chip, name, T, layered):
+    """The chunk-prefill width (128 query tokens) and the verify shape at
+    every shape the decode kernel takes: all kv-heads and a block of pages a
+    grid step (ISSUE 31), head blocks where ``paged_multitoken_blocks`` says
+    so."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_multitoken_attention,
+        paged_multitoken_blocks,
     )
+
+    B, H, KV, D, page, n, P, dtype = SHAPES[name]
+    blocks = paged_multitoken_blocks(
+        KV, page, D, T, jnp.dtype(dtype).itemsize, n, H // KV
+    )
+    blocked = {("head-blocks", 128): 16, ("head-blocks", 5): 16, ("kv64-d128", 128): 32}
+    assert blocks is not None and blocks[0] == blocked.get((name, T), KV)
 
     def f(q, k, v, bt, base, scales=None):
         return paged_multitoken_attention(
             q, k, v, bt, base, scales=scales, layer=LAYER if layered else None
         )
 
-    args = _kernel_args(one_chip, name, layered, T=128)
+    args = _kernel_args(one_chip, name, layered, T=T)
     compiled = jax.jit(f).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 @pytest.mark.parametrize("T", [None, 5], ids=["decode", "verify5"])

@@ -347,6 +347,58 @@ class TestPagedAttentionHardware:
             np.asarray(run("jnp"), np.float32), atol=2e-2, rtol=2e-2,
         )
 
+    @pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
+    @pytest.mark.parametrize(
+        "int8,page,T", [(False, 16, 128), (False, 16, 5), (True, 32, 128),
+                        (True, 32, 5)],
+    )
+    def test_paged_multitoken_at_the_served_shape(self, int8, page, T, layered):
+        """ISSUE 31: the chunk call's kernel at XL's shape (a table of 1 024
+        positions, chunks at their first, second and last start and one off
+        the block edges), a whole-pool ``layer`` or the layer's own pool,
+        against the jnp fallback; 8 page blocks a slot where there were
+        25 x 64 steps."""
+        from deepspeed_tpu.ops.attention import (
+            paged_attention_grid_steps,
+            paged_multitoken_cached_attention,
+        )
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_multitoken_blocks,
+        )
+
+        n, base = 1024 // page, [0, 128, 896, 437]
+        B = len(base)
+        kp, vp, bt, scales = self._pool(B, n, page, seed=34, int8=int8)
+        if layered:  # the layer between two others that hold something else
+            kp = jnp.stack([kp[::-1], kp, jnp.zeros_like(kp)])
+            vp = jnp.stack([vp[::-1], vp, jnp.zeros_like(vp)])
+        rs = np.random.RandomState(35)
+        q = jnp.asarray(rs.randn(B, T, self.H, self.D), jnp.bfloat16)
+        base = jnp.asarray(base, jnp.int32)
+        # entries past each chunk's reach name a page of another slot
+        reach = (np.asarray(base) + T - 1) // page
+        past = np.arange(n)[None, :] > reach[:, None]
+        bt = jnp.where(jnp.asarray(past), bt[::-1], bt)
+        # on the chip the gate takes the kernel, and its grid is the block rule's
+        G = paged_multitoken_blocks(self.H, page, self.D, T, kp.dtype.itemsize, n)[1]
+        assert G * page == (256 if int8 and T == 5 else 128)
+        assert paged_attention_grid_steps(
+            "auto", B, self.H, page, self.D, kp.dtype.itemsize, n, T
+        ) == B * (n // G)
+
+        def run(impl):
+            return jax.jit(
+                lambda q, kp, vp, bt, base, sc: paged_multitoken_cached_attention(
+                    q, kp, vp, bt, base, impl=impl, scales=sc,
+                    layer=1 if layered else None,
+                )
+            )(q, kp, vp, bt, base, scales)
+
+        np.testing.assert_allclose(
+            np.asarray(run("pallas"), np.float32),
+            np.asarray(run("jnp"), np.float32), atol=2e-2, rtol=2e-2,
+        )
+
 
 class TestServingPoolLayoutHardware:
     """ISSUE 29: on the chip the K/V pools of a 64-wide head are stored with

@@ -1340,7 +1340,8 @@ class TestServingStats:
         assert len(ph) == 1
         attrs = ph[0][3]
         for key, gauge in (("relayout_ops", "serving_pool_relayout_ops"),
-                           ("temp_bytes", "serving_program_temp_bytes")):
+                           ("temp_bytes", "serving_program_temp_bytes"),
+                           ("grid_steps", "serving_paged_grid_steps")):
             got = dict(kv.split("=") for kv in attrs[key].split())
             assert sorted(got) == sorted(names)
             g = srv.metrics.get(gauge)

@@ -196,8 +196,10 @@ def test_serving_setup_is_recorded_as_phases_that_name_their_programs(served):
     progs = [p for p in phases if p[0] == "ds.init.programs"]
     assert len(progs) == 1 and progs[0][3]["what"] == "serving"
     # and the census of the compiled programs (ISSUE 29): <program>=<n> each
-    assert set(progs[0][3]) == {"what", "relayout_ops", "temp_bytes"}
-    assert progs[0][3]["relayout_ops"].count("=") == progs[0][3]["temp_bytes"].count("=") >= 2
+    # and, since ISSUE 31, the grid steps of one call of its paged attention kernel
+    assert set(progs[0][3]) == {"what", "relayout_ops", "temp_bytes", "grid_steps"}
+    counts = {progs[0][3][k].count("=") for k in ("relayout_ops", "temp_bytes", "grid_steps")}
+    assert len(counts) == 1 and counts.pop() >= 2
     inside = [p for p in phases if p[0].startswith("ds.jit.") and progs[0][1] <= p[1] and p[2] <= progs[0][2]]
     assert {p[0] for p in inside} == {"ds.jit.trace", "ds.jit.lower", "ds.jit.compile"}
     compiled = {p[3]["fun"] for p in inside if p[0] == "ds.jit.compile"}
