@@ -277,6 +277,12 @@ class InferenceEngine:
         with spans.phase("ds.init.params", what="inference"):
             # --- params: shard over tp, convert dtype (reference engine.py:464)
             init_rng = jax.random.PRNGKey(seed)
+            in_dtype = (model.extra or {}).get("init_in_dtype")
+            if params is None and in_dtype is not None and not self.quantized:
+                # the model makes its tree on the device in the serving dtype,
+                # a leaf at a time: a tree in its own dtype beside the cast
+                # one would not fit for a model that fills the chip
+                params = in_dtype(init_rng, dtype)
             if params is None:
                 if model.init is None:
                     raise ValueError(

@@ -113,6 +113,10 @@ class GPT2Config:
     def is_moe(self) -> bool:
         return self.moe_experts > 0
 
+    def serving_family(self):
+        """The pieces ``serving/model.py``'s paged programs are built from."""
+        return GPT2Family(self)
+
 
 # name → config, sizes per the GPT-2 paper / HF checkpoints
 PRESETS: Dict[str, Dict] = {
@@ -319,6 +323,66 @@ def _mlp(cfg: GPT2Config, lp, h, train: bool, rng=None, tp_axis=None):
     if tp_axis is not None:
         out = jax.lax.psum(out, tp_axis)
     return out + lp["c_proj_b"], jnp.float32(0.0)
+
+
+class GPT2Family:
+    """What ``serving/model.py`` asks of a model (see its ``Family`` notes):
+    learned positions, LayerNorm, one K and V head per query head, every
+    layer's cache paged under the block table, a tied head. Under the TP
+    ``shard_map`` the config is the per-rank one and ``tp_axis`` names the
+    mesh axis the row-parallel partial products are summed over."""
+
+    prefill_block = 0      # the whole-prompt program attends as one dense product
+    sparse_layers = ()     # no layer reports expert loads
+    experts_held = 0
+    experts_per_token = 0
+
+    def __init__(self, cfg: GPT2Config):
+        self.cfg = cfg
+        self.n_layer, self.n_head, self.n_kv_head = cfg.n_layer, cfg.n_head, cfg.n_head
+        self.head_dim, self.vocab_size, self.n_positions = cfg.head_dim, cfg.vocab_size, cfg.n_positions
+        self.attn_impl = cfg.attn_impl
+        self.windows = (0,) * cfg.n_layer
+
+    def embed(self, params, ids, positions):
+        te, pe = params["wte"][ids], params["wpe"][positions]
+        if ids.ndim == 1:  # the decode step: a token a slot
+            return te[:, None, :] + pe[:, None, :]
+        return te + (pe if pe.ndim == te.ndim else pe[None])  # one row's [S] positions
+
+    def layer(self, params, l: int):
+        # a static index: XLA folds the slices into their consumers
+        return jax.tree_util.tree_map(lambda x: x[l], params["blocks"])
+
+    def qkv(self, lp, h, positions, l: int):
+        from ..ops.layer_norm import layer_norm_inference
+
+        cfg = self.cfg
+        hn = layer_norm_inference(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.layer_norm_epsilon)
+        qkv = hn @ _deq(lp["attn"]["c_attn_w"], hn.dtype) + lp["attn"]["c_attn_b"]
+        return tuple(
+            t.reshape(*t.shape[:-1], cfg.n_head, cfg.head_dim) for t in jnp.split(qkv, 3, axis=-1)
+        )
+
+    def attn_out(self, lp, o, tp_axis=None):
+        # row-parallel under TP: the partial product is summed over the axis
+        # BEFORE the replicated bias is added once
+        out = o @ _deq(lp["attn"]["c_proj_w"], o.dtype)
+        if tp_axis is not None:
+            out = lax.psum(out, tp_axis)
+        return out + lp["attn"]["c_proj_b"]
+
+    def mlp(self, lp, h, l: int, valid=None, tp_axis=None):
+        from ..ops.layer_norm import layer_norm_inference
+
+        hn = layer_norm_inference(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], self.cfg.layer_norm_epsilon)
+        return _mlp(self.cfg, lp["mlp"], hn, False, None, tp_axis=tp_axis)[0], None
+
+    def logits(self, params, h):
+        from ..ops.layer_norm import layer_norm_inference
+
+        h = layer_norm_inference(h, params["ln_f"]["scale"], params["ln_f"]["bias"], self.cfg.layer_norm_epsilon)
+        return (h @ params["wte"].T)[..., : self.cfg.vocab_size]
 
 
 def _block(cfg: GPT2Config, layer_params, h, train: bool, rng=None):

@@ -140,7 +140,7 @@ def _paged_kernel_taken(impl: str, ok) -> bool:
 
 def paged_cached_attention(
     q, k_pool, v_pool, block_tables, pos, impl: str = "auto",
-    sm_scale: Optional[float] = None, scales=None, layer=None,
+    sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
 ):
     """Single-token decode attention against a PAGED KV cache (the serving
     subsystem's layout): q [B,H,D], pools [P,KV,page,D] (KV == H or
@@ -150,7 +150,8 @@ def paged_cached_attention(
     dtype is int8. With a static ``layer`` the pools are the serving
     engine's whole [L,P,KV,page,D] arrays: the kernel indexes the layer
     itself (no ``pool[l]`` slice for XLA to materialise), the fallback
-    slices it.
+    slices it. ``lo`` [B] i32 bounds the keys from below (a sliding window:
+    ``lo[b] <= key <= pos[b]``, both counted from the table's first key).
 
     Dispatch mirrors :func:`cached_attention`: the Pallas paged kernel on TPU
     (the block-table gather IS the kernel's index maps — no dense copy, no
@@ -179,7 +180,7 @@ def paged_cached_attention(
     )):
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-            scales=scales, layer=layer,
+            scales=scales, layer=layer, lo=lo,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -192,6 +193,8 @@ def paged_cached_attention(
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
     S = kd.shape[1]
     mask = jnp.arange(S)[None, None, :] <= pos[:, None, None]  # [B,1,S]
+    if lo is not None:
+        mask = mask & (jnp.arange(S)[None, None, :] >= lo[:, None, None])
     rep = H // KV
     qg = q.reshape(B, KV, rep, D)
     scores = jnp.einsum(
@@ -204,7 +207,7 @@ def paged_cached_attention(
 
 def paged_multitoken_cached_attention(
     q, k_pool, v_pool, block_tables, base, impl: str = "auto",
-    sm_scale: Optional[float] = None, scales=None, layer=None,
+    sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
 ):
     """T-token causal decode attention against a PAGED KV cache (ISSUE 10:
     the speculative verify step and chunked prefill): q [B,T,H,D], pools
@@ -212,7 +215,8 @@ def paged_multitoken_cached_attention(
     sits at absolute position ``base[b] + t`` and attends keys ``<= base[b]
     + t`` → [B,T,H,D]. The chunk's own K/V must already be scattered into
     the pool (update-then-attend, exactly like the single-token step).
-    ``layer`` as in :func:`paged_cached_attention`.
+    ``layer`` as in :func:`paged_cached_attention`; with ``lo`` [B] query t
+    attends only keys ``>= lo[b] + t``.
 
     Dispatch mirrors :func:`paged_cached_attention`: the multitoken Pallas
     kernel on TPU, and a pure-jnp fallback whose T == 1 slice is the exact
@@ -238,7 +242,7 @@ def paged_multitoken_cached_attention(
     )):
         return paged_multitoken_attention(
             q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-            scales=scales, layer=layer,
+            scales=scales, layer=layer, lo=lo,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -252,6 +256,11 @@ def paged_multitoken_cached_attention(
         jnp.arange(S)[None, None, :]
         <= base[:, None, None] + jnp.arange(T)[None, :, None]
     )
+    if lo is not None:
+        mask = mask & (
+            jnp.arange(S)[None, None, :]
+            >= lo[:, None, None] + jnp.arange(T)[None, :, None]
+        )
     rep = H // KV
     qg = q.reshape(B, T, KV, rep, D)
     scores = jnp.einsum(

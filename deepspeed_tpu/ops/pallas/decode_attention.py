@@ -227,8 +227,9 @@ def _pool_block_spec(block, index_map, layer: Optional[int]):
     )
 
 
-def _paged_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
-                  nhb: int, T: int = 1, rep: int = 1, quantized: bool = False):
+def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
+                  nhb: int, T: int = 1, rep: int = 1, quantized: bool = False,
+                  windowed: bool = False):
     """Online-softmax accumulation over one slot's pages for ``T`` query
     tokens of the slot (1: the decode step; more: chunked prefill and the
     verify shape), ``G`` pages and ``HB`` kv-heads (all of them, unless a
@@ -263,14 +264,25 @@ def _paged_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
     ``quantized`` (ISSUE 12): K/V pages are int8 codes (exact in the query's
     float type) and one more input carries the block's K and V scales per
     key column, ``[2, HB, 1, G * page]``; scores and probabilities are scaled
-    in VMEM, so the HBM read per page stays the halved code bytes."""
+    in VMEM, so the HBM read per page stays the halved code bytes.
+
+    ``windowed``: a third prefetched operand ``lo`` ([B]) bounds the keys from
+    below: query ``t`` attends keys ``lo[b] + t <= key <= at[b] + t`` (a
+    sliding window; the caller's table starts at the page that holds
+    ``lo[b]``, so the walk starts there and block 0 is an own block)."""
+    lo_ref = None
+    if windowed:
+        lo_ref, *rest = rest
+    q_ref, *rest = rest
     k_refs, v_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
     if quantized:
         sc_ref, *rest = rest
     o_ref, m_ref, l_ref, acc_ref = rest
     r = pl.program_id(0)
     j = pl.program_id(1)
-    at = at_ref[r if nhb == 1 else jax.lax.div(r, nhb)]
+    slot = r if nhb == 1 else jax.lax.div(r, nhb)
+    at = at_ref[slot]
+    lo = lo_ref[slot] if windowed else None
     GP = G * k_refs[0].shape[2]
     last_blk = jax.lax.div(at + (T - 1), GP)
     if T > 1:  # a chunk may reach past the table; a decode position does not
@@ -308,7 +320,10 @@ def _paged_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
                 t = jax.lax.broadcasted_iota(jnp.int32, (1, rep * T, 1), 1)
                 for _ in range(1, rep):  # row g * T + t -> t, with no division
                     t = jnp.where(t >= T, t - T, t)
-            s = jnp.where(key <= at + t, s, -1e30)
+            seen = key <= at + t
+            if windowed:
+                seen = seen & (key >= lo + t)
+            s = jnp.where(seen, s, -1e30)
         m_prev, l_prev = m_ref[...], l_ref[...]  # [HB, rep * T, 1]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
@@ -334,6 +349,8 @@ def _paged_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
         pl.when(own)(functools.partial(update, True, emits=True))
     else:
         whole = (j + 1) * GP - 1 <= at  # every key visible to every query
+        if windowed:
+            whole = whole & (j * GP >= lo + (T - 1))
         pl.when(own & whole)(functools.partial(update, False))
         pl.when(own & jnp.logical_not(whole))(functools.partial(update, True))
         pl.when(j == last_blk)(emit)
@@ -353,14 +370,16 @@ def _walked_table(block_tables, last, n_blk: int, G: int):
 
 
 def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
-                HB: int, G: int, scales, layer: Optional[int], interpret: bool):
+                HB: int, G: int, scales, layer: Optional[int], interpret: bool,
+                lo=None):
     """The ``pallas_call`` of :func:`_paged_kernel`, for both wrappers: grid
     ``(B * nhb, n_blk)`` over ``q5`` ``[B, nhb, HB, R, D]`` (``R`` query rows
     a kv-head: ``rep`` for the decode step, ``rep * T`` for T tokens), the
     ``G`` K and ``G`` V page inputs under the walked table, an int8 pool's
     scales per key column, and the (m, l, acc) scratch. ``at`` ([B], scalar
     prefetched with the table) is what the kernel masks by, ``last`` the
-    slot's last own page and ``last_blk(at[b])`` its last own block."""
+    slot's last own page and ``last_blk(at[b])`` its last own block. ``lo``
+    ([B], for a ``windowed`` kernel) is prefetched after ``at``."""
     B, nhb, _, R, D = q5.shape
     page = k_pool.shape[-2]
     n_pages = block_tables.shape[1]
@@ -371,13 +390,13 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
         return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
 
     def page_spec(g):
-        def index_map(r, j, walk, at):
+        def index_map(r, j, walk, at, *lo):
             b, hb = row(r)
             return walk[b, j * G + g], hb, 0, 0
 
         return _pool_block_spec((1, HB, page, D), index_map, layer)
 
-    def qo_map(r, j, walk, at):
+    def qo_map(r, j, walk, at, *lo):
         return (*row(r), 0, 0, 0)
 
     qo_spec = pl.BlockSpec((1, 1, HB, R, D), qo_map)
@@ -392,7 +411,7 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
         st = jnp.repeat(st, page, axis=1).reshape(B, n_blk, GP, nhb, HB, 2)
         operands.append(st.transpose(0, 1, 5, 3, 4, 2)[..., None, :])
 
-        def scale_map(r, j, walk, at):
+        def scale_map(r, j, walk, at, *lo):
             b, hb = row(r)
             return b, jax.lax.min(j, last_blk(at[b])), 0, hb, 0, 0, 0
 
@@ -400,7 +419,8 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # walked table + per-slot positions
+            # walked table + per-slot positions (+ a window's lower bounds)
+            num_scalar_prefetch=2 if lo is None else 3,
             grid=(B * nhb, n_blk),
             in_specs=in_specs,
             out_specs=qo_spec,
@@ -412,7 +432,7 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
         ),
         out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
         interpret=interpret,
-    )(walk, at, *operands)
+    )(walk, at, *(() if lo is None else (jnp.asarray(lo, jnp.int32),)), *operands)
 
 
 def paged_decode_attention(
@@ -425,8 +445,14 @@ def paged_decode_attention(
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
     layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
+    lo: Optional[jnp.ndarray] = None,  # [B] i32: lowest key index attended (a window)
 ) -> jnp.ndarray:
     """Single-token attention against a PAGED cache → [B, H, D].
+
+    ``lo`` bounds the keys from below (``lo[b] <= key <= pos[b]``): a sliding
+    window, whose caller hands a table that starts at the page holding
+    ``lo[b]`` with ``pos`` and ``lo`` counted from that page's first key, so
+    that the walk starts where the window does.
 
     With ``layer`` the pools are the serving engine's whole ``[L, P, KV, page,
     D]`` arrays and the layer is one more (squeezed) block index: the caller
@@ -464,12 +490,12 @@ def paged_decode_attention(
     pos = jnp.asarray(pos, jnp.int32)
     kernel = functools.partial(
         _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=1, rep=rep,
-        quantized=quantized,
+        quantized=quantized, windowed=lo is not None,
     )
     out = _paged_call(
         kernel, q.reshape(B, nhb, HB, rep, D), k_pool, v_pool, block_tables,
         pos, pos // page, lambda pos_b: jax.lax.div(pos_b, G * page),
-        HB, G, scales, layer, interpret,
+        HB, G, scales, layer, interpret, lo,
     )
     return out.reshape(B, H, D)
 
@@ -484,8 +510,10 @@ def paged_multitoken_attention(
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
     layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
+    lo: Optional[jnp.ndarray] = None,  # [B] i32: query t attends keys >= lo[b] + t
 ) -> jnp.ndarray:
     """T-token causal attention against a PAGED cache → [B, T, H, D].
+    ``lo`` as in :func:`paged_decode_attention`, moving with the query.
 
     Serves chunked prefill (T = chunk width, base = chunk start) and the
     verify shape (T = k+1 drafted tokens, base = per-slot cached length) —
@@ -520,7 +548,7 @@ def paged_multitoken_attention(
     base = jnp.asarray(base, jnp.int32)
     kernel = functools.partial(
         _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=T, rep=rep,
-        quantized=scales is not None,
+        quantized=scales is not None, windowed=lo is not None,
     )
     q5 = q.reshape(B, T, nhb, HB, rep, D).transpose(0, 2, 3, 4, 1, 5)
     n_blk = -(-n_pages // G)
@@ -529,7 +557,7 @@ def paged_multitoken_attention(
         block_tables, base, jnp.minimum((base + (T - 1)) // page, n_pages - 1),
         lambda base_b: jax.lax.min(
             jax.lax.div(base_b + (T - 1), G * page), n_blk - 1),
-        HB, G, scales, layer, interpret,
+        HB, G, scales, layer, interpret, lo,
     )
     out = out.reshape(B, nhb, HB, rep, T, D).transpose(0, 4, 1, 2, 3, 5)
     return out.reshape(B, T, H, D)

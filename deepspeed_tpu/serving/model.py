@@ -1,8 +1,11 @@
 """Static-shape serving programs over the paged KV pool.
 
-Three compiled-once programs built from the gpt2 family's own building
-blocks (``models/gpt2``) so serving is BIT-IDENTICAL to per-request
-``generate``:
+Compiled-once programs over a model FAMILY's own building blocks, so that
+for the gpt2 family serving is BIT-IDENTICAL to per-request ``generate``.
+The programs hold what every family shares (the pool writes, the paged
+attention, the sampling); the model's module gives the rest through
+``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
+``models/exaone_moe.ExaoneFamily``):
 
 - :func:`paged_prefill` — one request's prompt (right-padded to the static
   prefill width) through the model, K/V written page-granularly into the
@@ -38,7 +41,8 @@ token streams are unaffected (the equivalence tests pin this).
 
 from __future__ import annotations
 
-from typing import Any, Tuple
+import math
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -46,15 +50,13 @@ import numpy as np
 from jax import lax
 
 from ..models import gpt2
-from ..models.gpt2 import GPT2Config, KVCache, _mlp
-from ..ops.layer_norm import layer_norm_inference as _layer_norm
+from ..models.gpt2 import KVCache
 from ..ops.quantizer import (
     dequantize_kv_pages,
     kv_page_scale,
     quantize_kv_pages,
     quantize_kv_token,
 )
-from ..ops.quantizer import maybe_dequantize as _deq
 from ..ops.sampling import sample_logits
 
 PyTree = Any
@@ -153,19 +155,6 @@ def _write_pool_tokens(k_pool, v_pool, scales, l, pidx, poff, k_vals, v_vals):
     return k_pool, v_pool, scales
 
 
-def _proj(o, w, b, dtype, tp_axis=None):
-    """Output projection shared by every attention variant. Under the TP
-    ``shard_map`` (ISSUE 14) ``w`` is the row-parallel slice — the partial
-    product is psum-reduced over ``tp_axis`` BEFORE the replicated bias is
-    added once (adding per-rank biases would count ``b`` tp times). With
-    ``tp_axis=None`` this is the exact historical ``o @ w + b`` graph, so
-    the TP=1 program set stays byte-identical."""
-    out = o @ _deq(w, dtype)
-    if tp_axis is not None:
-        out = lax.psum(out, tp_axis)
-    return out + b
-
-
 def _gather_dense(k_pool_l, v_pool_l, block_tables, scales_l=None):
     """Gather each slot's pages into the dense ``[B, n, page, KV, D]`` view
     the jnp attention branches consume, dequantizing int8 pools through
@@ -180,53 +169,176 @@ def _gather_dense(k_pool_l, v_pool_l, block_tables, scales_l=None):
 
 
 # ---------------------------------------------------------------------------
+# what the programs ask of a model
+# ---------------------------------------------------------------------------
+
+class Family:
+    """The protocol ``cfg.serving_family()`` returns (documentation; the
+    families do not inherit from it). Shapes: ``h`` is the residual stream
+    ``[B, S, E]``; ``positions`` is ``[B, S]`` or, one row, ``[S]``
+    (``embed`` also takes ``[B]`` ids and positions, the decode step's,
+    and then gives ``[B, 1, E]``).
+
+    - ``n_layer, n_head, n_kv_head, head_dim, vocab_size, n_positions,
+      attn_impl``: geometry.
+    - ``windows``: per layer, how many keys a query reads, itself included
+      (a sliding window, whose K/V live in the slot's RING of the window
+      pools), or 0: every key before it (K/V paged under the block table).
+    - ``prefill_block``: 0, or the query rows the whole-prompt program
+      attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
+    - ``sparse_layers`` / ``experts_held``: the layers whose ``mlp`` reports
+      the tokens each held expert got, and how many experts that is.
+    - ``embed(params, ids, positions) -> h``
+    - ``layer(params, l) -> lp``
+    - ``qkv(lp, h, positions, l) -> q [B,S,H,D], k, v [B,S,KV,D]``: the
+      norm before attention, the projections and whatever the family does
+      to a head before it is cached (QK norm, rotary positions).
+    - ``attn_out(lp, o [B,S,H*D], tp_axis) -> [B,S,E]``
+    - ``mlp(lp, h, l, valid, tp_axis) -> ([B,S,E], counts [experts_held] |
+      None)``: the norm before it and the MLP or expert layer; ``valid``
+      broadcasts against ``[B, S]`` (the rows that are real tokens).
+    - ``logits(params, h [..., E]) -> [..., vocab]``: final norm and head.
+    """
+
+
+def _kv_homes(fam):
+    """Per layer ``(windowed, index)``: its index among the window pools'
+    layers or among the paged pools'."""
+    homes, n_win, n_paged = [], 0, 0
+    for w in fam.windows:
+        homes.append((bool(w), n_win if w else n_paged))
+        n_win, n_paged = n_win + bool(w), n_paged + (not w)
+    return homes
+
+
+def ring_page_ids(slot, pages, ring: int):
+    """Window-pool page of a slot's logical page ``pages``: slot ``b`` owns
+    pages ``1 + b * ring .. (b + 1) * ring`` and logical page ``j`` lives in
+    the ``j % ring``-th of them (page 0 is scratch, as in the paged pools)."""
+    return 1 + slot * ring + pages % ring
+
+
+def _window_view(slots, pos0, window: int, page: int, ring: int):
+    """What a window layer's attention reads for slots whose first query sits
+    at ``pos0 [B]``: → (``table [B, ring]`` the ring's pages in position
+    order from the page that holds the first key the window reaches, the
+    position ``off [B]`` of that page's first key, ``lo [B]`` the first key
+    query 0 reads, counted from ``off``; it may be negative, and query ``t``
+    reads from ``lo + t``)."""
+    lo = pos0 - (window - 1)
+    first = jnp.maximum(lo, 0) // page
+    table = ring_page_ids(
+        slots[:, None], first[:, None] + jnp.arange(ring)[None, :], ring
+    )
+    return table, first * page, lo - first * page
+
+
+def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts):
+    """The rest of layer ``l`` in every program: the attention output into
+    the residual stream, then the MLP or expert layer, whose held experts'
+    token counts (if it reports any) join ``counts``."""
+    h = h + fam.attn_out(lp, o, tp_axis)
+    m, c = fam.mlp(lp, h, l, valid, tp_axis)
+    if c is not None:
+        counts.append(c)
+    return h + m
+
+
+def _window_views(fam, slots, pos0, page: int, ring: int):
+    """:func:`_window_view` for each distinct window of the family's layers."""
+    return {
+        w: _window_view(slots, pos0, w, page, ring)
+        for w in sorted(set(fam.windows) - {0})
+    }
+
+
+def _result(k_pool, v_pool, scales, win, token, counts):
+    """A program's results in the order the scheduler takes them: the paged
+    pools, an int8 pool's scales, a window family's ring pools, the token(s)
+    and, for a family with expert layers, the tokens each held expert got
+    ``[sparse layers, experts_held]``."""
+    out = (k_pool, v_pool)
+    if scales is not None:
+        out += (scales,)
+    if win is not None:
+        out += tuple(win)
+    return out + (token,) + ((jnp.stack(counts),) if counts else ())
+
+
+# ---------------------------------------------------------------------------
 # paged prefill (one request into one slot's pages)
 # ---------------------------------------------------------------------------
 
-def _layer_params(params: PyTree, l: int) -> PyTree:
-    """Layer ``l``'s slice of the stacked block params (static index — XLA
-    folds the slices into their consumers)."""
-    return jax.tree_util.tree_map(lambda x: x[l], params["blocks"])
+def _page_chunks(x, page: int):
+    """``[1, S, KV, D]`` → ``[S // page, KV, page, D]`` rows of a pool."""
+    _, S, KV, D = x.shape
+    return jnp.swapaxes(x[0].reshape(S // page, page, KV, D), 1, 2)
 
 
-def _attention_prefill_paged(cfg, lp, h, k_pool, v_pool, page_ids, l,
-                             scales=None, tp_axis=None):
+def _attend_prompt_blocked(q, k, v, window: int, block: int):
+    """Causal attention of a whole prompt chunk, ``block`` query rows at a
+    time, so that the scores alive are ``[H, block, keys]``: all ``Sp`` keys
+    on a full layer, a band of ``block + window`` on a window layer. q ``[1,
+    Sp, H, D]``, k / v ``[1, Sp, KV, D]`` → ``[1, Sp, H * D]``."""
+    _, Sp, H, D = q.shape
+    KV = k.shape[2]
+    rep = H // KV
+    scale = 1.0 / np.sqrt(D)
+    pad = window if window else 0
+    span = block + pad if window else Sp
+    kp = jnp.pad(k[0], ((pad, 0), (0, 0), (0, 0)))
+    vp = jnp.pad(v[0], ((pad, 0), (0, 0), (0, 0)))
+
+    def rows(i):
+        qi = lax.dynamic_slice_in_dim(q[0], i * block, block, 0).reshape(block, KV, rep, D)
+        start = i * block if window else 0          # in the padded keys
+        ki = lax.dynamic_slice_in_dim(kp, start, span, 0)
+        vi = lax.dynamic_slice_in_dim(vp, start, span, 0)
+        s = jnp.einsum("sgrd,tgd->grst", qi, ki, preferred_element_type=jnp.float32) * scale
+        qpos = i * block + jnp.arange(block)[:, None]
+        kpos = start - pad + jnp.arange(span)[None, :]
+        seen = (kpos <= qpos) & (kpos >= 0)
+        if window:
+            seen = seen & (kpos > qpos - window)
+        p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(vi.dtype)
+        o = jnp.einsum("grst,tgd->sgrd", p, vi, preferred_element_type=jnp.float32)
+        return o.reshape(block, H * D).astype(q.dtype)
+
+    return lax.map(rows, jnp.arange(Sp // block)).reshape(1, Sp, H * D)
+
+
+def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
+                             scales=None):
     """Causal self-attention over the prompt chunk; K/V written to layer
     ``l``'s pages of the FULL pool (quantized at write when ``scales`` is
     given — the attention then reads the DEQUANTIZED chunk back, so the
     first sampled token is consistent with every later read of the same
-    pages).
+    pages). → ``(o [B, Sp, H * D], k_pool, v_pool, scales)``.
 
     The chunk starts at position 0 of a fresh slot, so "the cache" IS the
     chunk — the dense causal einsum here is exactly ``_attention_cached``'s
     prefill path with ``pos = 0`` and ``Smax = Sp``."""
-    B, Sp, E = h.shape
-    H, D = cfg.n_head, cfg.head_dim
+    B, Sp, H, D = q.shape
+    KV = k_c.shape[2]
     page = k_pool.shape[3]
-    qkv = h @ _deq(lp["c_attn_w"], h.dtype) + lp["c_attn_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, Sp, H, D)
-    pool_dt = h.dtype if scales is not None else k_pool.dtype
-    k_c = k_.reshape(B, Sp, H, D).astype(pool_dt)
-    v_c = v.reshape(B, Sp, H, D).astype(pool_dt)
 
-    # page-granular scatter: [Sp,H,D] → [n_pp, H, page, D] rows of the pool.
+    # page-granular scatter: [Sp,KV,D] → [n_pp, KV, page, D] rows of the pool.
     # Whole pages are overwritten — a slot's pages are fresh at admission and
     # padded/garbage positions are masked until the decode write claims them;
     # padded page_ids point at the scratch page.
-    n_pp = Sp // page
-    chunks = jnp.swapaxes(k_c[0].reshape(n_pp, page, H, D), 1, 2)
     k_pool, scales, k_att = _write_pool_pages(
-        k_pool, scales, l, page_ids, chunks, 0
+        k_pool, scales, l, page_ids, _page_chunks(k_c, page), 0
     )
-    chunks_v = jnp.swapaxes(v_c[0].reshape(n_pp, page, H, D), 1, 2)
     v_pool, scales, v_att = _write_pool_pages(
-        v_pool, scales, l, page_ids, chunks_v, 1
+        v_pool, scales, l, page_ids, _page_chunks(v_c, page), 1
     )
     if scales is not None:
-        # [n_pp, KV, page, D] dequantized → the [B, Sp, H, D] chunk view
-        k_c = jnp.swapaxes(k_att, 1, 2).reshape(B, Sp, H, D)
-        v_c = jnp.swapaxes(v_att, 1, 2).reshape(B, Sp, H, D)
+        # [n_pp, KV, page, D] dequantized → the [B, Sp, KV, D] chunk view
+        k_c = jnp.swapaxes(k_att, 1, 2).reshape(B, Sp, KV, D)
+        v_c = jnp.swapaxes(v_att, 1, 2).reshape(B, Sp, KV, D)
+    if fam.prefill_block:
+        o = _attend_prompt_blocked(q, k_c, v_c, 0, math.gcd(Sp, fam.prefill_block))
+        return o, k_pool, v_pool, scales
 
     scale = 1.0 / np.sqrt(D)
     scores = jnp.einsum(
@@ -240,15 +352,32 @@ def _attention_prefill_paged(cfg, lp, h, k_pool, v_pool, page_ids, l,
     o = jnp.einsum("bhst,bthd->bshd", probs, v_c)
     # H*D == E at TP=1; under the TP shard_map H is the per-rank head count
     # and the row-parallel projection restores the full embed dim
-    o = o.reshape(B, Sp, H * D).astype(h.dtype)
-    return (
-        _proj(o, lp["c_proj_w"], lp["c_proj_b"], h.dtype, tp_axis),
-        k_pool, v_pool, scales,
-    )
+    return o.reshape(B, Sp, H * D).astype(q.dtype), k_pool, v_pool, scales
+
+
+def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
+                              window: int, ring: int):
+    """A window layer of the whole-prompt program: a band of ``window`` keys
+    a query, and of the prompt's pages only the ring's worth that a later
+    query can still reach is written (ring page ``r`` takes the LAST logical
+    page ``<=`` the prompt's last that lives in it; a ring page no prompt
+    page lives in yet takes page 0's rows, which the decode writes replace
+    before anything reads them). → ``(o, win)``."""
+    k_win, v_win = win
+    Sp = q.shape[1]
+    page = k_win.shape[3]
+    n_last = (prompt_len - 1) // page
+    r = jnp.arange(ring)
+    src = jnp.clip(n_last - (n_last - r) % ring, 0, Sp // page - 1)
+    ids = ring_page_ids(slot, r, ring)
+    k_win = k_win.at[li, ids].set(_page_chunks(k_c, page)[src].astype(k_win.dtype))
+    v_win = v_win.at[li, ids].set(_page_chunks(v_c, page)[src].astype(v_win.dtype))
+    block = math.gcd(Sp, fam.prefill_block or Sp)
+    return _attend_prompt_blocked(q, k_c, v_c, window, block), (k_win, v_win)
 
 
 def paged_prefill(
-    cfg: GPT2Config,
+    cfg,                      # the model's config (``serving_family()``)
     params: PyTree,
     input_ids: jnp.ndarray,   # [1, Sp] right-padded to the static prefill width
     prompt_len: jnp.ndarray,  # traced i32: true prompt length
@@ -261,44 +390,48 @@ def paged_prefill(
     top_p: float = 1.0,
     scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
+    win: tuple = None,    # (k_win, v_win) [Lw, 1 + slots * ring, KV, page, D]
+    slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
+    ring: int = 0,        # static: pages of one slot's ring
 ):
     """→ (k_pool, v_pool, first_token [1]), with ``scales`` threaded between
-    the pools and the token when the pool is quantized (ISSUE 12)."""
+    the pools and the token when the pool is quantized (ISSUE 12), a window
+    family's ring pools after them and its expert counts last
+    (:func:`_result`)."""
+    fam = cfg.serving_family()
     B, Sp = input_ids.shape
-    eps = cfg.layer_norm_epsilon
     positions = jnp.arange(Sp)
-    h = params["wte"][input_ids] + params["wpe"][positions][None, :, :]
+    h = fam.embed(params, input_ids, positions)
+    valid = (positions < prompt_len) if fam.sparse_layers else None
+    counts = []
 
-    for l in range(cfg.n_layer):
-        lp = _layer_params(params, l)
-        a, k_pool, v_pool, scales = _attention_prefill_paged(
-            cfg, lp["attn"],
-            _layer_norm(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], eps),
-            k_pool, v_pool, page_ids, l, scales, tp_axis,
-        )
-        h = h + a
-        m, _aux = _mlp(
-            cfg, lp["mlp"],
-            _layer_norm(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], eps),
-            False, None, tp_axis=tp_axis,
-        )
-        h = h + m
+    for l, (windowed, li) in enumerate(_kv_homes(fam)):
+        lp = fam.layer(params, l)
+        q, k_, v = fam.qkv(lp, h, positions, l)
+        if windowed:
+            o, win = _attention_prefill_window(
+                fam, q, k_, v, win, li, slot, prompt_len, fam.windows[l], ring
+            )
+        else:
+            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            o, k_pool, v_pool, scales = _attention_prefill_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
+                page_ids, li, scales,
+            )
+        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
     h_last = jnp.take(h, prompt_len - 1, axis=1)  # [B, E] true last prompt pos
-    h_last = _layer_norm(h_last, params["ln_f"]["scale"], params["ln_f"]["bias"], eps)
-    logits = (h_last @ params["wte"].T)[..., : cfg.vocab_size]
+    logits = fam.logits(params, h_last)
     first = sample_logits(logits, rng, temperature, top_k, top_p)
-    if scales is not None:
-        return k_pool, v_pool, scales, first
-    return k_pool, v_pool, first
+    return _result(k_pool, v_pool, scales, win, first, counts)
 
 
 # ---------------------------------------------------------------------------
 # paged decode step (one token for every slot)
 # ---------------------------------------------------------------------------
 
-def _attend_decode_shaped(cfg, q, k_pool, v_pool, l, block_tables, pos,
-                          out_dtype, scales_l=None):
+def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
+                          out_dtype, scales_l=None, lo=None):
     """ONE query token per slot against layer ``l`` of the paged cache →
     [B, 1, E]. The kernel takes the whole pools and the layer as a block
     index; only the ``jnp`` branch slices the layer out.
@@ -307,16 +440,19 @@ def _attend_decode_shaped(cfg, q, k_pool, v_pool, l, block_tables, pos,
     can attend each of its T queries through EXACTLY this code — same
     shapes, same XLA reduction trees, same bits (ISSUE 10). ``scales_l``
     (= ``scales[l]``, [P, KV, 2]) dequantizes an int8 pool in the read
-    path (ISSUE 12)."""
+    path (ISSUE 12). ``lo`` (a window layer: the pools are the ring pools,
+    the table and ``pos`` the ring's view, :func:`_window_view`) bounds the
+    keys from below."""
     B, S, H, D = q.shape  # S == 1
     E = H * D
     scale = 1.0 / np.sqrt(D)
-    if cfg.attn_impl in ("auto", "pallas"):
+    if fam.attn_impl in ("auto", "pallas") or lo is not None:
         from ..ops.attention import paged_cached_attention
 
         o1 = paged_cached_attention(
             q[:, 0], k_pool, v_pool, block_tables, pos,
-            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
+            impl=fam.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
+            lo=lo,
         )
         return o1.reshape(B, 1, E).astype(out_dtype)
 
@@ -341,42 +477,69 @@ def _attend_decode_shaped(cfg, q, k_pool, v_pool, l, block_tables, pos,
     return o.reshape(B, S, E).astype(out_dtype)
 
 
-def _attention_decode_paged(cfg, lp, h, k_pool, v_pool, block_tables,
-                            pos, pidx, poff, l, scales=None, tp_axis=None):
+class _RingWrites:
+    """Where the decode and verify steps' tokens land in the window pools
+    and what each query reads there, from ``seq_lens`` alone: the ring is
+    statically the slot's. A slot that holds no decoding request (its table
+    row is scratch: idle, or mid-prefill with its real row on the slot)
+    writes to the scratch page, as it does in the paged pools; its ring may
+    hold a prefill in flight."""
+
+    def __init__(self, fam, seq_lens, block_tables, page: int, ring: int, T: int):
+        B = seq_lens.shape[0]
+        slots = jnp.arange(B, dtype=jnp.int32)
+        pos = seq_lens[:, None] + jnp.arange(T)[None, :]               # [B, T]
+        active = (block_tables[:, 0] != 0)[:, None]
+        self.pidx = jnp.where(active, ring_page_ids(slots[:, None], pos // page, ring), 0)
+        self.poff = pos % page
+        self.views = _window_views(fam, slots, seq_lens, page, ring)
+
+
+def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int):
+    """A window layer of the decode (T = 1) or verify step: the T tokens'
+    K/V into the slots' rings, then query ``t`` against the ring's view as a
+    decode-shaped call, the keys bounded below. → ``(o [B, T, H * D], win)``."""
+    k_win, v_win = win
+    T = q.shape[1]
+    pidx, poff = (rw.pidx[:, 0], rw.poff[:, 0]) if T == 1 else (rw.pidx, rw.poff)
+    k_new, v_new = (k_c[:, 0], v_c[:, 0]) if T == 1 else (k_c, v_c)
+    k_win, v_win = _scatter_tokens(
+        k_win, v_win, li, pidx, poff, k_new.astype(k_win.dtype), v_new.astype(v_win.dtype)
+    )
+    table, off, lo = rw.views[window]
+    o = [
+        _attend_decode_shaped(
+            fam, q[:, t:t + 1], k_win, v_win, li, table, base + t - off,
+            q.dtype, None, lo + t,
+        )
+        for t in range(T)
+    ]
+    return (o[0] if T == 1 else jnp.concatenate(o, axis=1)), (k_win, v_win)
+
+
+def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
+                            pos, pidx, poff, l, scales=None):
     """One-token attention per slot against its paged cache (layer ``l`` of
-    the FULL pool).
+    the FULL pool) → ``(o [B, 1, H * D], k_pool, v_pool, scales)``.
 
     ``pos[b]`` = tokens already cached for slot b (the new token's position);
     new K/V scatters to (page ``pidx[b]``, offset ``poff[b]``) before the
     gather, mirroring ``_attention_cached``'s update-then-attend order."""
-    B, S, E = h.shape  # S == 1
-    H, D = cfg.n_head, cfg.head_dim
-    qkv = h @ _deq(lp["c_attn_w"], h.dtype) + lp["c_attn_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, S, H, D)
-    pool_dt = h.dtype if scales is not None else k_pool.dtype
-    k_c = k_.reshape(B, S, H, D).astype(pool_dt)
-    v_c = v.reshape(B, S, H, D).astype(pool_dt)
-
-    # [B,H,D] values to (l, pidx[b], :, poff[b], :) — advanced indices around
+    # [B,KV,D] values to (l, pidx[b], :, poff[b], :) — advanced indices around
     # the head slice put the batch dim first, matching the value layout.
     # Inactive slots target the scratch page.
     k_pool, v_pool, scales = _write_pool_tokens(
         k_pool, v_pool, scales, l, pidx, poff, k_c[:, 0], v_c[:, 0]
     )
-
     o = _attend_decode_shaped(
-        cfg, q, k_pool, v_pool, l, block_tables, pos, h.dtype,
+        fam, q, k_pool, v_pool, l, block_tables, pos, q.dtype,
         scales[l] if scales is not None else None,
     )
-    return (
-        _proj(o, lp["c_proj_w"], lp["c_proj_b"], h.dtype, tp_axis),
-        k_pool, v_pool, scales,
-    )
+    return o, k_pool, v_pool, scales
 
 
 def paged_decode_step(
-    cfg: GPT2Config,
+    cfg,
     params: PyTree,
     tokens: jnp.ndarray,        # [B] i32 last emitted token per slot
     seq_lens: jnp.ndarray,      # [B] i32 tokens already cached per slot
@@ -389,38 +552,43 @@ def paged_decode_step(
     top_p: float = 1.0,
     scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
+    win: tuple = None,    # a window family's ring pools
+    ring: int = 0,
 ):
     """→ (k_pool, v_pool, next_tokens [B]); ``scales`` threaded through and
-    returned before the tokens when the pool is quantized."""
+    returned before the tokens when the pool is quantized, a window family's
+    ring pools and expert counts as in :func:`_result`."""
+    fam = cfg.serving_family()
     B = tokens.shape[0]
     page = k_pool.shape[3]
-    eps = cfg.layer_norm_epsilon
-    h = params["wte"][tokens][:, None, :] + params["wpe"][seq_lens][:, None, :]
+    # rows gathered by [B] indices, then the token axis: gathered by [B, 1]
+    # ones the position table is copied whole and re-laid out every step
+    h = fam.embed(params, tokens, seq_lens)
+    positions = seq_lens[:, None]
     pidx = jnp.take_along_axis(
         block_tables, (seq_lens // page)[:, None], axis=1
     )[:, 0]
     poff = seq_lens % page
+    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
+    valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
+    counts = []
 
-    for l in range(cfg.n_layer):
-        lp = _layer_params(params, l)
-        a, k_pool, v_pool, scales = _attention_decode_paged(
-            cfg, lp["attn"],
-            _layer_norm(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], eps),
-            k_pool, v_pool, block_tables, seq_lens, pidx, poff, l, scales,
-            tp_axis,
-        )
-        h = h + a
-        m, _aux = _mlp(
-            cfg, lp["mlp"],
-            _layer_norm(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], eps),
-            False, None, tp_axis=tp_axis,
-        )
-        h = h + m
+    for l, (windowed, li) in enumerate(_kv_homes(fam)):
+        lp = fam.layer(params, l)
+        q, k_, v = fam.qkv(lp, h, positions, l)
+        if windowed:
+            o, win = _attention_step_window(
+                fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
+            )
+        else:
+            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            o, k_pool, v_pool, scales = _attention_decode_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
+                block_tables, seq_lens, pidx, poff, li, scales,
+            )
+        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    h_last = _layer_norm(
-        h[:, -1], params["ln_f"]["scale"], params["ln_f"]["bias"], eps
-    )
-    logits = (h_last @ params["wte"].T)[..., : cfg.vocab_size]
+    logits = fam.logits(params, h[:, -1])
     if not temperature or temperature <= 0.0:
         nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1)
     else:
@@ -432,9 +600,7 @@ def paged_decode_step(
                 lg[None, :], kk, temperature, top_k, top_p
             )[0]
         )(logits, keys)
-    if scales is not None:
-        return k_pool, v_pool, scales, nxt
-    return k_pool, v_pool, nxt
+    return _result(k_pool, v_pool, scales, win, nxt, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -457,28 +623,28 @@ def paged_decode_step(
 # ---------------------------------------------------------------------------
 
 
-def _attend_multitoken_paged(cfg, h, q, k_pool, v_pool, l,
-                             block_tables, base, scales_l=None):
+def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
+                             scales_l=None, lo=None):
     """Batched attention tail of the chunk-prefill program: q [B,T,H,D]
     against layer ``l`` of the (already updated) paged cache, masked per
     query; the pools arrive whole, as in ``_attend_decode_shaped``. The
     caller applies the output projection. ``scales_l`` dequantizes an int8
-    pool (ISSUE 12).
+    pool (ISSUE 12); ``lo`` as in ``_attend_decode_shaped``.
 
     Dispatch mirrors ``_attention_decode_paged`` branch for branch; see the
     block comment above for why this form is token-identical but not
     bit-identical across chunking boundaries."""
-    B, T, E = h.shape
-    H, D = cfg.n_head, cfg.head_dim
+    B, T, H, D = q.shape
     scale = 1.0 / np.sqrt(D)
-    if cfg.attn_impl in ("auto", "pallas"):
+    if fam.attn_impl in ("auto", "pallas") or lo is not None:
         from ..ops.attention import paged_multitoken_cached_attention
 
         o = paged_multitoken_cached_attention(
             q, k_pool, v_pool, block_tables, base,
-            impl=cfg.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
+            impl=fam.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
+            lo=lo,
         )
-        return o.reshape(B, T, H * D).astype(h.dtype)
+        return o.reshape(B, T, H * D).astype(q.dtype)
 
     # jnp impl: dense gather + the exact einsum/cast structure of
     # _attention_decode_paged's jnp branch, extended to T query rows (see
@@ -497,34 +663,27 @@ def _attend_multitoken_paged(cfg, h, q, k_pool, v_pool, l,
     probs = jax.nn.softmax(scores, axis=-1).astype(vd.dtype)
     o = jnp.einsum("bhst,bthd->bshd", probs, vd)
     # H*D == E at TP=1; the per-rank head slice under the TP shard_map
-    return o.reshape(B, T, H * D).astype(h.dtype)
+    return o.reshape(B, T, H * D).astype(q.dtype)
 
 
-def _attention_verify_paged(cfg, lp, h, k_pool, v_pool, block_tables,
-                            base, pidx, poff, l, scales=None, tp_axis=None):
+def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
+                            base, pidx, poff, l, scales=None):
     """T-token attention per slot: scatter every token's K/V to layer ``l``
     at (``pidx[b,t]``, ``poff[b,t]``), then attend query t at position
     ``base + t`` through the block table. Out-of-budget positions arrive
     with ``pidx`` already routed to the scratch page (see
-    :func:`_verify_write_targets`).
+    :func:`_verify_write_targets`). → ``(o [B, T, H * D], pools, scales)``.
 
     The T attention calls are UNROLLED single-token ``_attend_decode_shaped``
     invocations — identical shapes to the decode step, hence identical bits;
     query t's mask (``idx <= base + t``) hides the already-scattered K/V of
     queries > t exactly as it hides any other stale cache content, so
     scatter-all-then-attend equals the sequential write-attend interleaving
-    bit for bit. The QKV matmul above and projection below stay batched over
-    T — the arithmetic-intensity win speculation exists for."""
-    B, T, E = h.shape
-    H, D = cfg.n_head, cfg.head_dim
-    qkv = h @ _deq(lp["c_attn_w"], h.dtype) + lp["c_attn_b"]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, T, H, D)
-    pool_dt = h.dtype if scales is not None else k_pool.dtype
-    k_c = k_.reshape(B, T, H, D).astype(pool_dt)
-    v_c = v.reshape(B, T, H, D).astype(pool_dt)
+    bit for bit. The QKV matmul before and the projection after stay batched
+    over T — the arithmetic-intensity win speculation exists for."""
+    T = q.shape[1]
     if scales is None:
-        # [B,T,H,D] values to (l, pidx[b,t], :, poff[b,t], :), one write
+        # [B,T,KV,D] values to (l, pidx[b,t], :, poff[b,t], :), one write
         k_pool, v_pool = _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_c, v_c)
     else:
         # quantized pools write the T tokens in sequence: a token landing at
@@ -542,17 +701,14 @@ def _attention_verify_paged(cfg, lp, h, k_pool, v_pool, block_tables,
     o = jnp.concatenate(
         [
             _attend_decode_shaped(
-                cfg, q[:, t:t + 1], k_pool, v_pool, l, block_tables,
-                base + t, h.dtype, scales_l,
+                fam, q[:, t:t + 1], k_pool, v_pool, l, block_tables,
+                base + t, q.dtype, scales_l,
             )
             for t in range(T)
         ],
         axis=1,
     )
-    return (
-        _proj(o, lp["c_proj_w"], lp["c_proj_b"], h.dtype, tp_axis),
-        k_pool, v_pool, scales,
-    )
+    return o, k_pool, v_pool, scales
 
 
 def _verify_write_targets(seq_lens, block_tables, page: int, T: int):
@@ -573,7 +729,7 @@ def _verify_write_targets(seq_lens, block_tables, page: int, T: int):
 
 
 def paged_verify_step(
-    cfg: GPT2Config,
+    cfg,
     params: PyTree,
     tokens: jnp.ndarray,        # [B, T] col 0 = last emitted, cols 1.. = drafts
     seq_lens: jnp.ndarray,      # [B] i32 tokens already cached per slot
@@ -582,10 +738,13 @@ def paged_verify_step(
     block_tables: jnp.ndarray,  # [B, W] i32
     scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
+    win: tuple = None,    # a window family's ring pools
+    ring: int = 0,
 ):
     """Self-speculative verify (ISSUE 10): score T = k+1 tokens per slot in
     one forward pass → (k_pool, v_pool, greedy [B, T]); ``scales`` threaded
-    and returned before ``greedy`` when the pool is quantized.
+    and returned before ``greedy`` when the pool is quantized, a window
+    family's ring pools and expert counts as in :func:`_result`.
 
     ``greedy[b, t]`` is the argmax next token after prefix ⊕ tokens[b, :t+1]
     — i.e. exactly what ``paged_decode_step`` would emit at that point. The
@@ -595,44 +754,45 @@ def paged_verify_step(
     yields. Rejected drafts leave K/V at positions past the accepted length;
     the next step's T-token scatter overwrites every such position before
     anything attends it (``new_base = base + accepted + 1 <= base + T``), so
-    rollback is by construction, not by copy."""
+    rollback is by construction, not by copy. (In a ring a rejected draft
+    lands on the page of a position ``ring`` pages back, which no query of
+    this step or a later one reaches.)"""
+    fam = cfg.serving_family()
     B, T = tokens.shape
     page = k_pool.shape[3]
-    eps = cfg.layer_norm_epsilon
     # clamp garbage positions (past the decode budget) into the embedding
     # table; their queries are never emitted and their writes go to scratch
     positions = jnp.minimum(
-        seq_lens[:, None] + jnp.arange(T)[None, :], cfg.n_positions - 1
+        seq_lens[:, None] + jnp.arange(T)[None, :], fam.n_positions - 1
     )
-    h = params["wte"][tokens] + params["wpe"][positions]
+    h = fam.embed(params, tokens, positions)
     pidx, poff = _verify_write_targets(seq_lens, block_tables, page, T)
+    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if win is not None else None
+    valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
+    counts = []
 
-    for l in range(cfg.n_layer):
-        lp = _layer_params(params, l)
-        a, k_pool, v_pool, scales = _attention_verify_paged(
-            cfg, lp["attn"],
-            _layer_norm(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], eps),
-            k_pool, v_pool, block_tables, seq_lens, pidx, poff, l, scales,
-            tp_axis,
-        )
-        h = h + a
-        m, _aux = _mlp(
-            cfg, lp["mlp"],
-            _layer_norm(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], eps),
-            False, None, tp_axis=tp_axis,
-        )
-        h = h + m
+    for l, (windowed, li) in enumerate(_kv_homes(fam)):
+        lp = fam.layer(params, l)
+        q, k_, v = fam.qkv(lp, h, positions, l)
+        if windowed:
+            o, win = _attention_step_window(
+                fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
+            )
+        else:
+            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            o, k_pool, v_pool, scales = _attention_verify_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
+                block_tables, seq_lens, pidx, poff, li, scales,
+            )
+        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    h = _layer_norm(h, params["ln_f"]["scale"], params["ln_f"]["bias"], eps)
-    logits = (h @ params["wte"].T)[..., : cfg.vocab_size]
+    logits = fam.logits(params, h)
     greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-    if scales is not None:
-        return k_pool, v_pool, scales, greedy
-    return k_pool, v_pool, greedy
+    return _result(k_pool, v_pool, scales, win, greedy, counts)
 
 
 def paged_chunk_prefill(
-    cfg: GPT2Config,
+    cfg,
     params: PyTree,
     input_ids: jnp.ndarray,   # [1, C] one chunk, right-padded past the prompt
     start: jnp.ndarray,       # traced i32: absolute position of input_ids[0, 0]
@@ -647,74 +807,77 @@ def paged_chunk_prefill(
     top_p: float = 1.0,
     scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
+    win: tuple = None,    # a window family's ring pools
+    slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
+    ring: int = 0,
 ):
     """One chunk of an incremental prefill (ISSUE 10) → (k_pool, v_pool,
     token [1]); ``scales`` threaded and returned before the token when the
     pool is quantized (the COW fork-by-recompute path rides this program —
     the fresh private page is REQUANTIZED here, its own scale written,
-    while the shared original's codes and scale row are never touched).
+    while the shared original's codes and scale row are never touched); a
+    window family's ring pools and expert counts as in :func:`_result`.
 
     Positions ``start .. start+C-1`` run through the model attending the
     slot's cached prefix (``< start`` — earlier chunks or shared prefix
     pages) plus causal intra-chunk, K/V written page-granularly to
     ``page_ids`` (page-aligned because C is a page multiple; pages the
-    chunk overruns are scratch-padded by the scheduler). The returned token
-    is sampled at the true last prompt position and is only meaningful on
-    the final chunk — earlier chunks' samples are discarded host-side.
-    Long prompts stop stalling decode: the scheduler interleaves one chunk
-    per step with the batched decode of other slots."""
+    chunk overruns are scratch-padded by the scheduler). A window layer
+    writes the chunk's pages into the slot's ring (whose ``ring`` pages hold
+    the window before the chunk, the chunk and a page of slack, so no page a
+    query of this chunk reads is overwritten) and reads the ring's view.
+    The returned token is sampled at the true last prompt position and is
+    only meaningful on the final chunk — earlier chunks' samples are
+    discarded host-side. Long prompts stop stalling decode: the scheduler
+    interleaves one chunk per step with the batched decode of other slots."""
+    fam = cfg.serving_family()
     B, C = input_ids.shape
     page = k_pool.shape[3]
     n_cp = C // page
-    eps = cfg.layer_norm_epsilon
-    positions = jnp.minimum(start + jnp.arange(C), cfg.n_positions - 1)
-    h = params["wte"][input_ids] + params["wpe"][positions][None, :, :]
+    positions = jnp.minimum(start + jnp.arange(C), fam.n_positions - 1)
+    h = fam.embed(params, input_ids, positions)
     base = jnp.reshape(start, (1,))
+    valid = (positions < prompt_len) if fam.sparse_layers else None
+    counts = []
+    if win is not None:
+        ring_ids = ring_page_ids(slot, start // page + jnp.arange(n_cp), ring)
+        views = _window_views(fam, jnp.reshape(slot, (1,)), base, page, ring)
 
-    for l in range(cfg.n_layer):
-        lp = _layer_params(params, l)
-        hn = _layer_norm(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], eps)
-        qkv = hn @ _deq(lp["attn"]["c_attn_w"], hn.dtype) + lp["attn"]["c_attn_b"]
-        q, k_, v = jnp.split(qkv, 3, axis=-1)
-        H, D = cfg.n_head, cfg.head_dim
-        q = q.reshape(B, C, H, D)
-        pool_dt = hn.dtype if scales is not None else k_pool.dtype
-        k_c = k_.reshape(B, C, H, D).astype(pool_dt)
-        v_c = v.reshape(B, C, H, D).astype(pool_dt)
-        # page-granular scatter, exactly paged_prefill's write (quantized at
-        # write when the pool is int8; the attention below reads the pool,
-        # so it sees the dequantized codes either way)
-        k_pool, scales, _ = _write_pool_pages(
-            k_pool, scales, l, page_ids,
-            jnp.swapaxes(k_c[0].reshape(n_cp, page, H, D), 1, 2), 0,
-        )
-        v_pool, scales, _ = _write_pool_pages(
-            v_pool, scales, l, page_ids,
-            jnp.swapaxes(v_c[0].reshape(n_cp, page, H, D), 1, 2), 1,
-        )
-        o = _attend_multitoken_paged(
-            cfg, hn, q, k_pool, v_pool, l, block_tables, base,
-            scales[l] if scales is not None else None,
-        )
-        a = _proj(o, lp["attn"]["c_proj_w"], lp["attn"]["c_proj_b"],
-                  hn.dtype, tp_axis)
-        h = h + a
-        m, _aux = _mlp(
-            cfg, lp["mlp"],
-            _layer_norm(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], eps),
-            False, None, tp_axis=tp_axis,
-        )
-        h = h + m
+    for l, (windowed, li) in enumerate(_kv_homes(fam)):
+        lp = fam.layer(params, l)
+        q, k_, v = fam.qkv(lp, h, positions, l)
+        if windowed:
+            k_win, v_win = win
+            k_win = k_win.at[li, ring_ids].set(_page_chunks(k_, page).astype(k_win.dtype))
+            v_win = v_win.at[li, ring_ids].set(_page_chunks(v, page).astype(v_win.dtype))
+            win = (k_win, v_win)
+            table, off, lo = views[fam.windows[l]]
+            o = _attend_multitoken_paged(
+                fam, q, k_win, v_win, li, table, base - off, None, lo
+            )
+        else:
+            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            # page-granular scatter, exactly paged_prefill's write (quantized
+            # at write when the pool is int8; the attention below reads the
+            # pool, so it sees the dequantized codes either way)
+            k_pool, scales, _ = _write_pool_pages(
+                k_pool, scales, li, page_ids, _page_chunks(k_.astype(pool_dt), page), 0,
+            )
+            v_pool, scales, _ = _write_pool_pages(
+                v_pool, scales, li, page_ids, _page_chunks(v.astype(pool_dt), page), 1,
+            )
+            o = _attend_multitoken_paged(
+                fam, q, k_pool, v_pool, li, block_tables, base,
+                scales[li] if scales is not None else None,
+            )
+        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
     # the true last prompt position, when it falls inside this chunk
     idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
     h_last = jnp.take(h, idx, axis=1)  # [B, E]
-    h_last = _layer_norm(h_last, params["ln_f"]["scale"], params["ln_f"]["bias"], eps)
-    logits = (h_last @ params["wte"].T)[..., : cfg.vocab_size]
+    logits = fam.logits(params, h_last)
     first = sample_logits(logits, rng, temperature, top_k, top_p)
-    if scales is not None:
-        return k_pool, v_pool, scales, first
-    return k_pool, v_pool, first
+    return _result(k_pool, v_pool, scales, win, first, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -722,7 +885,7 @@ def paged_chunk_prefill(
 # ---------------------------------------------------------------------------
 
 def generate_padded(
-    cfg: GPT2Config,
+    cfg: gpt2.GPT2Config,
     params: PyTree,
     input_ids: jnp.ndarray,   # [B, Sb] right-padded to the bucket length
     prompt_len: jnp.ndarray,  # traced i32: true prompt length

@@ -307,13 +307,17 @@ class ProgramSet:
     page columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always."""
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
-                 page_size: int, cache_dtype, params: PyTree):
+                 page_size: int, cache_dtype, params: PyTree,
+                 ring_slots: int = 0, ring_pages: int = 0):
         self.placement = placement
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.n_layer = int(mcfg.n_layer)
-        self.n_kv_head = int(mcfg.n_head)
-        self.head_dim = int(mcfg.head_dim)
+        fam = mcfg.serving_family()
+        # the paged pools hold the layers that read their whole context; a
+        # window layer's K/V live in the ring pools below
+        self.n_layer = sum(1 for w in fam.windows if not w)
+        self.n_kv_head = int(fam.n_kv_head)
+        self.head_dim = int(fam.head_dim)
         k, v, scales = init_pools(
             self.n_layer, self.num_pages, self.n_kv_head, self.page_size,
             self.head_dim, dtype=cache_dtype,
@@ -322,6 +326,20 @@ class ProgramSet:
         self.k_pool = placement.put_pool(k, self._kv_axis)
         self.v_pool = placement.put_pool(v, self._kv_axis)
         self.kv_scales = placement.put_pool(scales) if scales is not None else None
+        # window layers: ``ring_pages`` pages statically owned by each of
+        # ``ring_slots`` slots, after a scratch page; no allocator, and the
+        # bytes do not grow with context
+        self.n_window_layer = len(fam.windows) - self.n_layer
+        self.ring_pages = int(ring_pages) if self.n_window_layer else 0
+        self.window_pools = None
+        if self.n_window_layer:
+            kw, vw, _ = init_pools(
+                self.n_window_layer, 1 + int(ring_slots) * self.ring_pages,
+                self.n_kv_head, self.page_size, self.head_dim, dtype=cache_dtype,
+            )
+            self.window_pools = (
+                placement.put_pool(kw, kw.ndim - 3), placement.put_pool(vw, vw.ndim - 3)
+            )
         self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
         self.params = placement.shard_params(params)
@@ -335,10 +353,12 @@ class ProgramSet:
         return self.kv_scales is not None
 
     def pool_args(self) -> tuple:
-        """The donated pool operands, in program order."""
+        """The donated pool operands, in program order: K, V, an int8 pool's
+        scales, a window family's two ring pools."""
+        out = (self.k_pool, self.v_pool)
         if self.kv_scales is not None:
-            return (self.k_pool, self.v_pool, self.kv_scales)
-        return (self.k_pool, self.v_pool)
+            out += (self.kv_scales,)
+        return out + (self.window_pools or ())
 
     def _check_pool_layout(self) -> None:
         """Where the paged kernels run, the pools must have come out
@@ -365,9 +385,13 @@ class ProgramSet:
     def pool_specs(self) -> tuple:
         """One ``PartitionSpec`` per :meth:`pool_args` operand."""
         kv = self.placement.pool_spec(self.k_pool.ndim, self._kv_axis)
-        if self.kv_scales is None:
-            return (kv, kv)
-        return (kv, kv, self.placement.pool_spec(self.kv_scales.ndim))
+        out = (kv, kv)
+        if self.kv_scales is not None:
+            out += (self.placement.pool_spec(self.kv_scales.ndim),)
+        return out + tuple(
+            self.placement.pool_spec(w.ndim, w.ndim - 3)
+            for w in self.window_pools or ()
+        )
 
     def aot(self, fn, operands: Sequence, operand_specs: Sequence = (),
             result_specs: Sequence = (), *, with_params: bool = False,
@@ -385,19 +409,25 @@ class ProgramSet:
         plc = self.placement
         first = int(with_params)
         pools = self.pool_args()
+        # the K/V-shaped pools (all but the scales), which fn sees as views
+        kv_like = [first, first + 1] + (
+            [first + len(pools) - 2, first + len(pools) - 1]
+            if self.window_pools else []
+        )
 
         @functools.wraps(fn)  # jit(decode_fn): the name traces are read by
         def program(*args):
-            stored = args[first].shape  # per device under shard_map
-            out = fn(
-                *args[:first], pool_view(args[first]),
-                pool_view(args[first + 1]), *args[first + 2:],
-            )
+            args = list(args)
+            stored = {i: args[i].shape for i in kv_like}  # per device under shard_map
+            for i in kv_like:
+                args[i] = pool_view(args[i])
+            out = fn(*args)
             if not returns_pools:
                 return out
-            return (
-                out[0].reshape(stored), out[1].reshape(stored), *out[2:]
-            )
+            out = list(out)
+            for i in kv_like:
+                out[i - first] = out[i - first].reshape(stored[i])
+            return tuple(out)
 
         args = ((self.params,) if with_params else ()) + pools + tuple(operands)
         dn = tuple(range(first, first + len(pools))) if donate else ()
@@ -471,6 +501,9 @@ class ProgramSet:
         if self.kv_scales is not None:
             self.kv_scales = rest[0]
             rest = rest[1:]
+        if self.window_pools is not None:
+            self.window_pools = (rest[0], rest[1])
+            rest = rest[2:]
         return rest[0] if len(rest) == 1 else rest
 
     def set_pools(self, pools: tuple) -> None:
@@ -549,3 +582,8 @@ class ProgramSet:
         if self.kv_scales is None:
             return 0
         return self.n_layer * self.num_pages * self.local_kv_heads() * 2 * 4
+
+    def window_pool_bytes(self) -> int:
+        """K+V bytes of the ring pools: slots x ring pages (and the scratch
+        page) x page bytes x window layers, whatever the contexts' lengths."""
+        return sum(int(w.nbytes) for w in self.window_pools or ())

@@ -69,7 +69,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.gpt2 import GPT2Config
 from ..telemetry import spans
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
@@ -122,6 +121,15 @@ def _split_scales(rest: tuple, quantized: bool):
     return None, rest
 
 
+def _split_pools(rest: tuple, quantized: bool, windowed: bool):
+    """:func:`_split_scales`, then a window family's two ring pools:
+    → ``(scales | None, (k_win, v_win) | None, inputs)``."""
+    scales, rest = _split_scales(rest, quantized)
+    if windowed:
+        return scales, (rest[0], rest[1]), rest[2:]
+    return scales, None, rest
+
+
 @dataclass
 class _Slot:
     request: Optional[Request] = None
@@ -145,6 +153,9 @@ class _Slot:
     # the decode placement polls ``.is_ready()`` instead of blocking, so
     # decode batches never wait on another core-set's prefill compute
     pending_tok: Optional[Any] = None
+    # the expert layers' token counts of this prompt's chunk calls, device
+    # arrays until its last chunk's token is fetched
+    moe_counts: List[Any] = field(default_factory=list)
 
 
 class ServingEngine:
@@ -193,12 +204,43 @@ class ServingEngine:
         self._draining = False
         self._admissions = 0  # 1-based admission ordinal (stall injection)
         mcfg = engine.model_config
-        if not isinstance(mcfg, GPT2Config):
+        if not hasattr(mcfg, "serving_family"):
             raise ValueError(
-                "ServingEngine v1 serves the gpt2 family (GPT2Config models, "
-                f"including injected HF GPT-2); got {type(mcfg).__name__}"
+                "ServingEngine serves a model whose config gives the paged "
+                "programs its pieces (serving_family(): the gpt2 family, "
+                "including injected HF GPT-2, and exaone_moe); got "
+                f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
+        fam = self.family = mcfg.serving_family()
+        # a family with sliding-window layers keeps two kinds of KV state (the
+        # paged pools and a ring a slot); what moves, shares, shards or
+        # re-codes pages knows the first kind only (ROADMAP.md, queue R)
+        self.windowed = any(fam.windows)
+        if self.windowed:
+            plc_ = getattr(config, "placement", None)
+            for on, what in (
+                (getattr(getattr(config, "prefix_cache", None), "enabled", False),
+                 "serving.prefix_cache"),
+                (getattr(getattr(config, "tiering", None), "enabled", False),
+                 "serving.tiering"),
+                (bool(config.kv_cache_dtype)
+                 and jnp.dtype(config.kv_cache_dtype) == jnp.dtype(jnp.int8),
+                 "serving.kv_cache_dtype=int8"),
+                (plc_ is not None and max(
+                    int(getattr(plc_, k, 0) or 0)
+                    for k in ("tp", "decode_tp", "prefill_tp")) > 1,
+                 "serving.placement.tp > 1"),
+                (plc_ is not None and bool(getattr(plc_, "disaggregate", False)),
+                 "serving.placement.disaggregate"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} is not available for a model with "
+                        f"sliding-window layers ({type(mcfg).__name__}): a "
+                        "window layer's KV lives in a per-slot ring beside "
+                        "the paged pool, which this mechanism does not handle"
+                    )
 
         page = int(config.page_size)
         self.page_size = page
@@ -221,6 +263,32 @@ class ServingEngine:
             else engine.dtype
         )
         self.max_slots = int(config.max_slots)
+        pcfg = getattr(config, "prefix_cache", None)
+        self.prefix_enabled = bool(pcfg and pcfg.enabled)
+        cw = int(getattr(config, "prefill_chunk_tokens", 0) or 0)
+        self._chunk_cold = cw > 0  # chunk long COLD prompts too
+        if cw > 0:
+            self.chunk_width = pages_for(cw, page) * page
+        elif self.prefix_enabled:
+            # prefix-hit tails always run through the chunk program
+            self.chunk_width = page
+        else:
+            self.chunk_width = 0
+        if self.chunk_width > self.prefill_width:
+            self.chunk_width = self.prefill_width
+        # a window layer's ring: the window before a program's first query,
+        # the tokens one call writes (a chunk, a verify step's drafts, one
+        # token) and a page of slack for where in a page the window starts
+        spec_ = getattr(config, "speculative", None)
+        self.ring_pages = (
+            pages_for(
+                max(fam.windows) + max(
+                    self.chunk_width,
+                    int(spec_.k) + 1 if spec_ is not None and spec_.enabled else 1,
+                ), page,
+            ) + 1
+            if self.windowed else 0
+        )
 
         # -- ISSUE 14: placements + program sets ---------------------------
         # Every program compiles FOR a placement (mesh slice + spec table);
@@ -269,6 +337,7 @@ class ServingEngine:
         self.decode_set = ProgramSet(
             self.decode_placement, mcfg, int(config.num_pages), page,
             self.cache_dtype, engine.params,
+            ring_slots=self.max_slots, ring_pages=self.ring_pages,
         )
         if self.disaggregated:
             # the prefill pool only ever holds PROMPT pages (decode-side
@@ -333,8 +402,6 @@ class ServingEngine:
             raise ValueError(
                 "serving.speculative requires temperature == 0 (greedy)"
             )
-        pcfg = getattr(config, "prefix_cache", None)
-        self.prefix_enabled = bool(pcfg and pcfg.enabled)
         # the prefix index lives beside the pool prefill WRITES: under
         # disaggregation that is the prefill placement's pool — the chunk
         # program attends shared pages there, and decode-side pages are
@@ -359,8 +426,8 @@ class ServingEngine:
             budget = int(tcfg.host_budget_pages) or self.prefill_set.allocator.capacity
             store = HostPageStore(
                 budget,
-                n_layer=mcfg.n_layer,
-                n_kv_head=mcfg.n_head,  # GLOBAL layout: device_get unshards
+                n_layer=self.decode_set.n_layer,
+                n_kv_head=fam.n_kv_head,  # GLOBAL layout: device_get unshards
                 page_size=page,
                 head_dim=mcfg.head_dim,
                 dtype=self.cache_dtype,
@@ -381,18 +448,6 @@ class ServingEngine:
             self.tiering.device_resident = (
                 self.prefix_cache._entries.__contains__
             )
-        cw = int(getattr(config, "prefill_chunk_tokens", 0) or 0)
-        self._chunk_cold = cw > 0  # chunk long COLD prompts too
-        if cw > 0:
-            self.chunk_width = pages_for(cw, page) * page
-        elif self.prefix_enabled:
-            # prefix-hit tails always run through the chunk program
-            self.chunk_width = page
-        else:
-            self.chunk_width = 0
-        if self.chunk_width > self.prefill_width:
-            self.chunk_width = self.prefill_width
-
         # -- telemetry (PR-1 registry when the engine carries one) ---------
         self.metrics: MetricsRegistry = (
             engine.telemetry.registry if getattr(engine, "telemetry", None)
@@ -568,6 +623,34 @@ class ServingEngine:
             "program calls no such kernel)",
             labelnames=("program",),
         )
+        # -- two kinds of KV state, and the experts a chip's share holds ----
+        self._g_kv_bytes = m.gauge(
+            "serving_kv_bytes",
+            "K+V bytes held on the device by class: paged (the pools under "
+            "the block tables, the layers that read their whole context) and "
+            "window (the per-slot rings of the sliding-window layers, which "
+            "do not grow with context)",
+            labelnames=("class",),
+        )
+        self._g_ring_pages = m.gauge(
+            "serving_window_pages_per_slot",
+            "pages of one slot's ring in the window pools (0 = the model has "
+            "no sliding-window layer)",
+        )
+        self._g_experts_held = m.gauge(
+            "serving_moe_experts_held",
+            "routed experts of a layer held on this chip (0 = no expert layer)",
+        )
+        self._c_moe_held = m.counter(
+            "serving_moe_pairs_held_total",
+            "token-expert pairs whose expert is held here (computed), over "
+            "decode steps and chunk calls",
+        )
+        self._c_moe_routed = m.counter(
+            "serving_moe_pairs_routed_total",
+            "token-expert pairs routed (tokens x experts a token x expert "
+            "layers), over decode steps and chunk calls",
+        )
         # -- ISSUE 14: TP sharding + disaggregation instruments ------------
         self._g_tp_coll = m.gauge(
             "serving_tp_collective_bytes",
@@ -642,10 +725,15 @@ class ServingEngine:
         log_dist(
             f"ServingEngine: slots={self.max_slots} page={page} "
             f"pages={config.num_pages} (pool "
-            f"{pool_bytes(mcfg.n_layer, int(config.num_pages), mcfg.n_head, page, mcfg.head_dim, np.dtype(self.cache_dtype).itemsize) / 1e6:.1f} MB"
+            f"{self.decode_set.local_pool_bytes() * self.decode_placement.tp / 1e6:.1f} MB"
             + (
-                f" + {scales_bytes(mcfg.n_layer, int(config.num_pages), mcfg.n_head) / 1e6:.2f} MB scales"
+                f" + {scales_bytes(self.decode_set.n_layer, int(config.num_pages), fam.n_kv_head) / 1e6:.2f} MB scales"
                 if self.quantized else ""
+            )
+            + (
+                f" + {self.decode_set.window_pool_bytes() / 1e6:.1f} MB in "
+                f"{self.ring_pages}-page window rings"
+                if self.windowed else ""
             )
             + f", [{self.decode_set.local_pool_dims()}] a device"
             + f") prefill_width={self.prefill_width} dtype={np.dtype(self.cache_dtype).name} "
@@ -706,9 +794,9 @@ class ServingEngine:
         if tracer is self._heat:
             return
         tracer.bind_registry(self.metrics)
-        mc = self.model_config
+        ds = self.decode_set
         page_b = pool_bytes(
-            mc.n_layer, 1, mc.n_head, self.page_size, mc.head_dim,
+            ds.n_layer, 1, ds.n_kv_head, self.page_size, ds.head_dim,
             np.dtype(self.cache_dtype).itemsize,
         )
         now = self.clock()
@@ -876,38 +964,51 @@ class ServingEngine:
         # Each program is built FOR a placement (ISSUE 14): it traces with
         # that placement's LOCAL model config (n_embd/n_head divided by tp)
         # and psums its row-parallel partials over the tp axis.
+        # A window family's two ring pools follow as two more donated
+        # operands, and its prefill and chunk programs take the slot (whose
+        # ring they write) as their last host operand.
+        windowed, ring = self.windowed, self.ring_pages
+
         def make_fns(cfg, tp_axis):
             def prefill_fn(params, k_pool, v_pool, *rest):
-                scales, (ids, plen, page_ids, key) = _split_scales(rest, quant)
+                scales, win, (ids, plen, page_ids, key, *slot) = _split_pools(
+                    rest, quant, windowed
+                )
                 return smodel.paged_prefill(
                     cfg, params, ids, plen, k_pool, v_pool, page_ids, key,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis,
+                    tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
+                    ring=ring,
                 )
 
             def decode_fn(params, k_pool, v_pool, *rest):
-                scales, (tokens, seq_lens, bt, keys) = _split_scales(rest, quant)
+                scales, win, (tokens, seq_lens, bt, keys) = _split_pools(
+                    rest, quant, windowed
+                )
                 return smodel.paged_decode_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt, keys,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis,
+                    tp_axis=tp_axis, win=win, ring=ring,
                 )
 
             def verify_fn(params, k_pool, v_pool, *rest):
-                scales, (tokens, seq_lens, bt) = _split_scales(rest, quant)
+                scales, win, (tokens, seq_lens, bt) = _split_pools(
+                    rest, quant, windowed
+                )
                 return smodel.paged_verify_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt,
-                    scales=scales, tp_axis=tp_axis,
+                    scales=scales, tp_axis=tp_axis, win=win, ring=ring,
                 )
 
             def chunk_fn(params, k_pool, v_pool, *rest):
-                scales, (ids, start, plen, page_ids, bt_row, key) = _split_scales(
-                    rest, quant
+                scales, win, (ids, start, plen, page_ids, bt_row, key, *slot) = (
+                    _split_pools(rest, quant, windowed)
                 )
                 return smodel.paged_chunk_prefill(
                     cfg, params, ids, start, plen, k_pool, v_pool, page_ids,
                     bt_row, key, temperature=temp, top_k=tk, top_p=top_p,
-                    scales=scales, tp_axis=tp_axis,
+                    scales=scales, tp_axis=tp_axis, win=win,
+                    slot=slot[0] if slot else None, ring=ring,
                 )
 
             return prefill_fn, decode_fn, verify_fn, chunk_fn
@@ -920,10 +1021,16 @@ class ServingEngine:
         # pools/params enter with their placement specs, host operands
         # replicate, and donation threads through the outer jit so XLA
         # aliases the per-device pool shards.
+        # what a program returns after the pools: the token(s) and, for a
+        # family with expert layers, the tokens each held expert got
+        n_results = 2 if self.family.sparse_layers else 1
+        slot_sds = (S((), i32),) if windowed else ()
+
         def compile_for(pset, fn, host_sds):
             rep = pset.placement.rep_spec()
             return pset.aot(
-                fn, host_sds, (rep,) * len(host_sds), (rep,), with_params=True
+                fn, host_sds, (rep,) * len(host_sds), (rep,) * n_results,
+                with_params=True,
             )
 
         d_cfg = self.decode_placement.local_model_config(self.model_config)
@@ -938,7 +1045,7 @@ class ServingEngine:
 
         self._prefill_exec = compile_for(self.prefill_set, p_fns[0], (
             S((1, self.prefill_width), i32), S((), i32),
-            S((self.prefill_pages,), i32), S((2,), u32),
+            S((self.prefill_pages,), i32), S((2,), u32), *slot_sds,
         ))
         info[f"serving_prefill{sfx}{self.prefill_placement.suffix()}"] = {
             "exe": self._prefill_exec, "pset": self.prefill_set,
@@ -973,7 +1080,7 @@ class ServingEngine:
             self._chunk_exec = compile_for(self.prefill_set, p_fns[3], (
                 S((1, self.chunk_width), i32), S((), i32), S((), i32),
                 S((self.chunk_width // self.page_size,), i32),
-                S((1, self.pages_per_slot), i32), S((2,), u32),
+                S((1, self.pages_per_slot), i32), S((2,), u32), *slot_sds,
             ))
             info[f"serving_chunk_prefill{sfx}{self.prefill_placement.suffix()}"] = {
                 "exe": self._chunk_exec, "pset": self.prefill_set,
@@ -1110,10 +1217,38 @@ class ServingEngine:
             self._g_relayout.set(relayout[name], program=name)
             self._g_temp_bytes.set(temp[name], program=name)
             self._g_grid_steps.set(steps[name], program=name)
-        return {
+        ds = self.decode_set
+        kv_bytes = {
+            "paged": ds.local_pool_bytes() * ds.placement.tp,
+            "window": ds.window_pool_bytes(),
+        }
+        for cls, n in kv_bytes.items():
+            self._g_kv_bytes.set(n, **{"class": cls})
+        self._g_ring_pages.set(self.ring_pages)
+        self._g_experts_held.set(self.family.experts_held)
+        attrs = {
             key: " ".join(f"{k}={v}" for k, v in got.items())
             for key, got in (("relayout_ops", relayout), ("temp_bytes", temp),
-                             ("grid_steps", steps))
+                             ("grid_steps", steps), ("kv_bytes", kv_bytes))
+        }
+        attrs.update(window_pages_per_slot=self.ring_pages,
+                     moe_experts_held=self.family.experts_held)
+        return attrs
+
+    def _moe_attrs(self, counts: np.ndarray, n_tokens: int) -> dict:
+        """Span attributes from the expert layers' ``[calls x sparse layers,
+        experts_held]`` token counts of one decode step or of a prompt's
+        chunk calls, ``n_tokens`` real tokens in all; the registry's pair
+        counters move with them."""
+        fam = self.family
+        held = int(counts.sum())
+        routed = int(n_tokens) * fam.experts_per_token * len(fam.sparse_layers)
+        self._c_moe_held.inc(held)
+        self._c_moe_routed.inc(routed)
+        return {
+            "moe_pairs_held": held, "moe_pairs_routed": routed,
+            "moe_load_max": int(counts.max()),
+            "moe_experts_hit": int((counts > 0).sum()),
         }
 
     def _set_collective_gauges(self) -> None:
@@ -1372,11 +1507,22 @@ class ServingEngine:
         if pre:
             with spans.span("ds.serve.chunk", chunks=len(pre)) as sp:
                 n_tok = 0
+                moe = []   # (counts, tokens) of the prompts that finished here
                 for i in pre:
                     s = self.slots[i]
                     n_tok += min(self.chunk_width, s.request.prompt_len - s.prefill_pos)
-                    self._advance_chunk(i)
+                    got = self._advance_chunk(i)
+                    if got is not None:
+                        moe.append(got)
                 sp.set(tokens=n_tok)
+                if moe:
+                    # a prompt's chunk calls report with its last one, whose
+                    # token fetch is the one wait there is
+                    counts = np.concatenate([c for c, _ in moe])
+                    sp.set(
+                        moe_calls=len(counts) // len(self.family.sparse_layers),
+                        **self._moe_attrs(counts, sum(n for _, n in moe)),
+                    )
 
         # 2c. disaggregated handoff completion (ISSUE 14): a slot whose
         # prefill placement has sampled the first token moves its prompt KV
@@ -1418,6 +1564,14 @@ class ServingEngine:
                 # token this step writes) and the pages those contexts hold
                 lens = self.table.seq_lens[active]
                 attended = int(lens.sum()) + len(active)
+                if self.windowed:
+                    # what a layer reads, averaged over the layers: a window
+                    # layer reads its window of a context, not the context
+                    ws = self.family.windows
+                    attended = int(sum(
+                        np.minimum(lens + 1, w).sum() if w else attended
+                        for w in ws
+                    ) / len(ws))
                 sp.set(
                     attended=attended,
                     pages=int((lens // self.page_size).sum()) + len(active),
@@ -1454,7 +1608,14 @@ class ServingEngine:
             # read the sampled tokens to retire/advance slots
             with spans.span("ds.serve.decode.wait"):
                 out_np = jax.device_get(out)  # dslint: disable=host-sync-in-step
+            moe_np = None
+            if self.family.sparse_layers:
+                out_np, moe_np = out_np  # the expert loads rode the same fetch
             with spans.span("ds.serve.emit") as sp:
+                if moe_np is not None:
+                    sp.set(**self._moe_attrs(
+                        moe_np, len(active) * (self.spec_k + 1 if self.spec_enabled else 1)
+                    ))
                 n_emit = n_fin = 0
                 now = self.clock()
                 self._h_step.observe(now - t0)
@@ -1795,6 +1956,7 @@ class ServingEngine:
         slot.pages = pages
         slot.prefill_pages = prefill_pages
         slot.pending_tok = None
+        slot.moe_counts = []
         slot.pos = 0
         slot.step = 0
         slot.keys = None
@@ -1856,10 +2018,10 @@ class ServingEngine:
             # step phase 2c syncs it and completes the handoff
             page_ids = np.zeros((self.prefill_pages,), np.int32)
             page_ids[: len(prefill_pages)] = prefill_pages
-            first = pset.take_pools(self._prefill_exec(
+            first = self._token_of(pset.take_pools(self._prefill_exec(
                 pset.params, *pset.pool_args(),
                 ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
-            ))
+            )))
             self._c_prefills.inc()
             slot.pending_tok = first
             slot.prefilling = True
@@ -1875,10 +2037,11 @@ class ServingEngine:
 
         self.table.assign(slot_i, pages)
         page_ids = self.table.block_tables[slot_i, : self.prefill_pages]
-        first = pset.take_pools(self._prefill_exec(
+        first = self._token_of(pset.take_pools(self._prefill_exec(
             pset.params, *pset.pool_args(),
             ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
-        ))
+            *self._slot_operand(slot_i),
+        )))
         self._c_prefills.inc()
         # deliberate sync: TTFT is defined by the first token reaching the
         # host, and an at-admission EOS must retire the slot before decode
@@ -1892,10 +2055,24 @@ class ServingEngine:
             )
         self._start_decoding(slot_i, tok0)
 
-    def _advance_chunk(self, slot_i: int) -> None:
+    def _slot_operand(self, slot_i: int) -> tuple:
+        """The last host operand of a window family's prefill and chunk
+        programs: the slot, whose ring they write."""
+        return (np.asarray(slot_i, np.int32),) if self.windowed else ()
+
+    def _token_of(self, out):
+        """The sampled token of a prefill program's results (a family with
+        expert layers returns their loads after it; the whole-prompt program's
+        are not reported)."""
+        return out[0] if self.family.sparse_layers else out
+
+    def _advance_chunk(self, slot_i: int):
         """One chunk of a PREFILLING slot's prompt through the chunk
         program; on the final chunk the sampled token becomes the request's
-        first token and the slot joins the decode batch."""
+        first token and the slot joins the decode batch. → on that chunk, for
+        a family with expert layers, (the expert loads of the prompt's chunk
+        calls ``[calls x sparse layers, experts_held]``, the prompt's
+        tokens)."""
         slot = self.slots[slot_i]
         req = slot.request
         C = self.chunk_width
@@ -1915,7 +2092,11 @@ class ServingEngine:
             pset.params, *pset.pool_args(),
             ids, np.asarray(start, np.int32),
             np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
+            *self._slot_operand(slot_i),
         ))
+        if self.family.sparse_layers:
+            tok, counts = tok
+            slot.moe_counts.append(counts)  # on the device until the last chunk
         self._c_chunks.inc()
         slot.prefill_pos = start + C
         if self.tracer is not None:
@@ -1935,8 +2116,11 @@ class ServingEngine:
         # deliberate sync, as in _admit: the final chunk's sample is the
         # request's first token
         with spans.span("ds.serve.chunk.wait"):
-            tok0 = int(jax.device_get(tok)[0])  # dslint: disable=host-sync-in-step
-        self._start_decoding(slot_i, tok0)
+            tok_np, *counts = jax.device_get((tok, *slot.moe_counts))  # dslint: disable=host-sync-in-step
+        slot.moe_counts = []
+        self._start_decoding(slot_i, int(tok_np[0]))
+        if counts:
+            return np.concatenate(counts), req.prompt_len - req.prefix_shared_tokens
 
     def _complete_handoff(self, slot_i: int) -> None:
         """Finish a disaggregated prefill (ISSUE 14): read the pending first
@@ -2330,6 +2514,12 @@ class ServingEngine:
         so each side compiles exactly once per engine."""
         if self._migrate_gather_exec is not None:
             return
+        if self.windowed:
+            raise ValueError(
+                "session migration is not available for a model with "
+                "sliding-window layers: the transport moves a slot's paged "
+                "row, and its window rings would stay behind"
+            )
         self._ensure_compiled()
         S = jax.ShapeDtypeStruct
         i32 = jnp.int32
@@ -2802,10 +2992,9 @@ class ServingEngine:
             self.prefix_cache.host_metadata_bytes()
             if self.prefix_cache is not None else 0
         )
-        mcfg_m = self.model_config
         scl_bytes = (
-            scales_bytes(mcfg_m.n_layer, int(self.config.num_pages),
-                         mcfg_m.n_head)
+            scales_bytes(self.decode_set.n_layer, int(self.config.num_pages),
+                         self.decode_set.n_kv_head)
             if self.quantized else 0
         )
         # ISSUE 16 satellite: the full host-RSS metadata ledger (prefix
@@ -2929,15 +3118,16 @@ class ServingEngine:
         # ISSUE 12: the pool's storage dtype + its HBM split (codes vs
         # scales) — the ops surface for "how much cache does this engine
         # actually hold per byte"
-        mc = self.model_config
+        ds = self.decode_set
         out["kv_cache_dtype"] = np.dtype(self.cache_dtype).name
         out["kv_pool_bytes"] = pool_bytes(
-            mc.n_layer, int(self.config.num_pages), mc.n_head,
-            self.page_size, mc.head_dim,
+            ds.n_layer, int(self.config.num_pages), ds.n_kv_head,
+            self.page_size, ds.head_dim,
             np.dtype(self.cache_dtype).itemsize,
         )
+        out["kv_window_bytes"] = ds.window_pool_bytes()
         out["kv_scales_bytes"] = (
-            scales_bytes(mc.n_layer, int(self.config.num_pages), mc.n_head)
+            scales_bytes(ds.n_layer, int(self.config.num_pages), ds.n_kv_head)
             if self.quantized else 0
         )
         # ISSUE 14: where the programs run and what each device holds —

@@ -204,7 +204,8 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
         placement=Placement("v5e", [one_chip._device], 1), params=params,
-        k_pool=pool, v_pool=pool, kv_scales=None, _kv_axis=pool.ndim - 3,
+        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=None,
+        _kv_axis=pool.ndim - 3,
         num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=L,
     )
     compiled = pset.aot(fn, host, with_params=True)
@@ -221,6 +222,85 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
         assert fmt.layout.major_to_minor == (0, 1, 2, 3, 4, 5)
     layer_kv_bytes = 2 * P * KV * page * 128 * 2  # 64 lanes pad to 128
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_kv_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+def test_window_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
+    """The ``exaone_moe`` programs as the K-EXAONE cell serves them (64 slots,
+    64 query heads on 8 kv heads of 128, a paged full layer beside four
+    25-page window rings a slot, 16 held experts of 128, 7.4 GB of bf16
+    weights as shapes): the paged kernels with a lower bound on the walk and
+    the token write into the rings pass Mosaic, nothing re-lays a pool out,
+    the whole-prompt program's blocked attention keeps its temps under 1 GB
+    (``[64, 3072, 3072]`` float32 scores would be 2.4 GB a layer), and
+    arguments + temps fit the chip."""
+    import json
+
+    from deepspeed_tpu.models import exaone_moe
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    with open(os.path.join(root, "perfbench", "configs", "k-exaone-236b-ep8-serve-1chip.json")) as f:
+        c = json.load(f)
+    cfg = exaone_moe.ExaoneMoEConfig.from_dict(c)
+    sv = c["serving"]
+    B, page, P, Sp, C = (sv[k] for k in ("max_slots", "page_size", "num_pages", "max_prompt_len", "prefill_chunk_tokens"))
+    W = -(-(Sp + sv["max_new_tokens"]) // page)
+    ring = -(-(cfg.sliding_window + C) // page) + 1
+    KV, D = cfg.n_kv_head, cfg.head_dim
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: exaone_moe.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    assert 7.4e9 < 2 * sum(x.size for x in jax.tree.leaves(params)) < 7.45e9
+    # described as live pools are: in the device's default format for their shape
+    pool, wpool = (
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16))
+        for shape in ((1, P, KV, page, D), (4, 1 + B * ring, KV, page, D))
+    )
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, kw, vw, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, k, v, bt, keys, win=(kw, vw), ring=ring),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "chunk": (
+            lambda p, k, v, kw, vw, ids, start, plen, pages, bt, key, slot:
+                smodel.paged_chunk_prefill(
+                    cfg, p, ids, start, plen, k, v, pages, bt, key,
+                    win=(kw, vw), slot=slot, ring=ring),
+            (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
+             sds((1, W), i32), sds((2,), u32), sds((), i32)),
+        ),
+        "prefill": (
+            lambda p, k, v, kw, vw, ids, plen, pages, key, slot: smodel.paged_prefill(
+                cfg, p, ids, plen, k, v, pages, key, win=(kw, vw), slot=slot, ring=ring),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32),
+             sds((), i32)),
+        ),
+    }[program]
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params,
+        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=(wpool, wpool),
+        _kv_axis=2, num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=1,
+    )
+    compiled = pset.aot(fn, host, with_params=True)
+    text = compiled.as_text()
+    assert pset.program_census(program, compiled)[0] == 0  # or it raises
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    # an attention kernel a layer, and in the decode step a token write a layer
+    assert calls == {"decode": 10, "chunk": 5, "prefill": 0}[program]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9   # of the chip's 16
 
 
 def _default_layout(one_chip, shape, dtype):

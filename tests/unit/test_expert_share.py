@@ -1,0 +1,112 @@
+"""The expert layer that knows its share (``moe/expert_share.py``), at a small
+size in float32: the shares add up to the uncut layer, the bias selects and
+does not weigh, and no held pair is ever dropped."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.moe import expert_share as es
+
+E, F, N, K, T = 24, 12, 16, 4, 40
+SCALE = 2.5
+
+
+def _layer(seed=0, n=N, bias_std=0.5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    w = lambda k, s, std=0.3: jax.random.normal(k, s, jnp.float32) * std
+    return {
+        "router": w(ks[0], (E, N)), "bias": w(ks[1], (N,), bias_std),
+        "experts": {"w_gate": w(ks[2], (n, E, F)), "w_up": w(ks[3], (n, E, F)), "w_down": w(ks[4], (n, F, E))},
+        "shared": {"w_gate": w(ks[5], (E, F)), "w_up": w(ks[6], (E, F)), "w_down": w(ks[7], (F, E))},
+    }
+
+
+def _slice(lp, share):
+    """What chip ``share.index`` holds of the whole layer."""
+    lo, hi = share.index * share.n_held, (share.index + 1) * share.n_held
+    return dict(lp, experts={k: v[lo:hi] for k, v in lp["experts"].items()})
+
+
+def _plain(lp, u, use_bias_in_weights=False):
+    """The uncut layer, one token and one expert at a time."""
+    out = np.zeros((T, E), np.float64)
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(u, np.float64) @ np.asarray(lp["router"], np.float64))))
+    b = np.asarray(lp["bias"], np.float64)
+    ffn = lambda x, wg, wu, wd: ((lambda g: g / (1 + np.exp(-g)))(x @ wg) * (x @ wu)) @ wd
+    for t in range(T):
+        sel = np.argsort(-(s[t] + b))[:K]
+        base = s[t] + b if use_bias_in_weights else s[t]
+        for e in sel:
+            wt = SCALE * base[e] / base[sel].sum()
+            out[t] += wt * ffn(np.asarray(u[t], np.float64), *(np.asarray(lp["experts"][k][e], np.float64)
+                                                               for k in ("w_gate", "w_up", "w_down")))
+        out[t] += ffn(np.asarray(u[t], np.float64), *(np.asarray(lp["shared"][k], np.float64)
+                                                      for k in ("w_gate", "w_up", "w_down")))
+    return out
+
+
+@pytest.fixture(scope="module")
+def u():
+    return jax.random.normal(jax.random.PRNGKey(9), (T, E), jnp.float32)
+
+
+@pytest.mark.parametrize("chips", [1, 4, 8])
+def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_layer(u, chips):
+    lp = _layer()
+    shared = np.asarray(es.gated_ffn(u, *(lp["shared"][k] for k in ("w_gate", "w_up", "w_down"))))
+    total, pairs = np.zeros((T, E)), 0
+    for i in range(chips):
+        share = es.ExpertShare(N, chips, i)
+        y, counts = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE)
+        assert counts.shape == (N // chips,) and counts.dtype == jnp.int32
+        total += np.asarray(y) - shared          # this chip's routed part
+        pairs += int(counts.sum())
+    assert pairs == T * K                         # every pair is held by exactly one chip: none dropped, none twice
+    np.testing.assert_allclose(total + shared, _plain(lp, u), rtol=2e-5, atol=2e-6)
+
+
+def test_selection_uses_s_plus_b_and_weights_use_s(u):
+    """A bias as wide as the scores' spread changes who is selected; the
+    weights are formed from the scores alone. A layer that weighed with
+    s + b, or selected without b, is far from it."""
+    lp = _layer(bias_std=0.5)
+    share = es.ExpertShare(N, 1, 0)
+    y = np.asarray(es.expert_share_layer(lp, u, share, K, SCALE)[0])
+    np.testing.assert_allclose(y, _plain(lp, u), rtol=2e-5, atol=2e-6)
+    assert np.abs(y - _plain(lp, u, use_bias_in_weights=True)).max() > 1e-2
+    no_bias = np.asarray(es.expert_share_layer(dict(lp, bias=jnp.zeros(N)), u, share, K, SCALE)[0])
+    assert np.abs(y - no_bias).max() > 1e-2
+    idx, w = es.route(u, lp["router"], lp["bias"], K, SCALE)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), SCALE, rtol=1e-6)   # renormalised over the k, then scaled
+
+
+def test_no_held_pair_is_dropped_under_the_most_uneven_routing(u):
+    """Every token selects the same K experts, all of them held here: each
+    gets all T tokens (ten times an even share of 1.25 a held expert), and
+    the output is the whole layer's."""
+    lp = _layer()
+    hot = jnp.zeros(N).at[jnp.array([4, 5, 6, 7])].set(100.0)     # chip 1 of 4 holds experts 4..7
+    lp = dict(lp, bias=hot)
+    share = es.ExpertShare(N, 4, 1)
+    y, counts = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE)
+    assert counts.tolist() == [T] * 4
+    np.testing.assert_allclose(np.asarray(y), _plain(lp, u), rtol=2e-5, atol=2e-6)
+    # and the chips that hold none of them compute the shared expert alone
+    other = es.ExpertShare(N, 4, 2)
+    y2, counts2 = es.expert_share_layer(_slice(lp, other), u, other, K, SCALE)
+    assert counts2.tolist() == [0] * 4
+    np.testing.assert_allclose(np.asarray(y2), np.asarray(es.gated_ffn(u, *(lp["shared"][k] for k in ("w_gate", "w_up", "w_down")))),
+                               rtol=1e-6, atol=1e-7)
+
+
+def test_counts_leave_out_the_rows_that_are_no_tokens(u):
+    lp = _layer()
+    share = es.ExpertShare(N, 2, 0)
+    _, all_rows = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE)
+    valid = jnp.arange(T) < 10
+    y, some = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE, valid=valid)
+    _, first10 = es.expert_share_layer(_slice(lp, share), u[:10], share, K, SCALE)
+    assert some.tolist() == first10.tolist() and int(some.sum()) < int(all_rows.sum())
+    assert y.shape == (T, E)          # the rows are still computed: a mask on the count, not on the work
