@@ -302,6 +302,22 @@ def paged_attention_grid_steps(
     return B * (KV // HB) * -(-n_pages // G)
 
 
+def traced_flash_plan(reset: bool = False) -> dict:
+    """Under which plan the resident flash forward last traced in this
+    process runs: its q block ``bq``, its inner width ``bk``, and the block
+    pairs of ONE forward call it masks (``masked``: the causal edge crosses
+    them) and does not (``plain``), from the kernels' own block rule
+    (``flash_plan``). All 0 where only the jnp path was traced. ``reset``
+    forgets it first: whoever reports a program resets, traces, reads."""
+    import sys
+
+    fa = sys.modules.get(f"{__package__}.pallas.flash_attention")
+    plan = {} if fa is None else fa.traced_plan
+    if reset:
+        plan.clear()
+    return {key: plan.get(key, 0) for key in ("bq", "bk", "masked", "plain")}
+
+
 def windowed_attention_ok(q) -> bool:
     """Whether sliding-window causal attention will ride the Pallas kernels
     for this shape: the ordinary dispatch gate plus the resident-kernel

@@ -6,8 +6,14 @@ score softmax/dropout fused ops; ``softmax_kernels.cu``): one VMEM-resident
 online-softmax kernel instead of materializing the [S,S] score matrix in HBM.
 
 Layout: inputs [B, S, H, D]; internally processed as [B*H, S, D].
-Block sizes: BQ=BK=128 (MXU-tile aligned); D may be 64/128/256 (sub-128 head
-dims are lane-padded by Mosaic).
+D may be 64/128/256 (sub-128 head dims are lane-padded by Mosaic).
+
+Block sizes: the resident kernels take theirs from the shape
+(:func:`flash_plan`: the q block of a grid step and the width of one inner
+iteration over the partner blocks, as measured on the chip), and mask only
+the block pairs the causal edge (or a window's trailing edge) crosses: the
+pairs wholly inside the band run the same math with no mask. The KV-blocked
+grid variant keeps BQ=BK=128 and masks every live pair.
 
 Backward follows the standard flash recomputation: forward also emits the
 per-row logsumexp; dq and dk/dv are computed by two kernels that recompute
@@ -26,59 +32,28 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Q/K block sizes, MXU-tile aligned. Env-tunable (read once at import) so
-# the hardware sweep can A/B larger blocks — at D=64 the per-block dots run
-# with a half-width MXU contraction, and bigger blocks amortize more of the
-# grid/DMA overhead per dot — without a code change. All kernels require
-# S % BQ == 0 and S % BK == 0 (flash_ok / windowed_flash_ok enforce).
-def _block_env(name: str, default: int) -> int:
-    """Validated block-size override: must be a positive multiple of 128
-    (MXU lane width — anything else yields opaque Mosaic lowering errors,
-    and odd sizes silently flip flash_ok dispatch for S % B != 0 shapes)."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        v = int(raw)
-    except ValueError:
-        v = -1
-    if v <= 0 or v % 128:
-        import warnings
-
-        warnings.warn(
-            f"{name}={raw!r} ignored: flash block sizes must be positive "
-            f"multiples of 128 (using {default})"
-        )
-        return default
-    if v != default:
-        import warnings
-
-        warnings.warn(
-            f"{name}={v}: non-default flash block size changes dispatch "
-            f"eligibility (kernels require S % {v} == 0)"
-        )
-    return v
-
-
-BQ = _block_env("DS_FLASH_BQ", 128)
-BK = _block_env("DS_FLASH_BK", 128)
+# Block sizes of the KV-blocked grid variant, the granule every plan of
+# flash_plan is a multiple of, and what flash_ok asks S to divide by.
+BQ = 128
+BK = 128
 NUM_LANES = 128  # lse/delta carry a broadcast 128-lane trailing dim (Mosaic
                  # requires >=(8,128)-tileable blocks; same layout as the
                  # official jax TPU flash kernel)
 NEG_INF = -1e30
 
 
-def _causal_mask(s, q_block, k_block, window=None):
-    """Mask scores where key position > query position (shared by all
-    kernels). ``window`` (traced i32 scalar; 0 = global) additionally masks
-    keys older than ``window`` positions: kept iff row - window < col <= row
-    (GPT-Neo local attention / Mistral sliding window semantics)."""
+def _mask(s, keep):
+    """Scores with the pairs ``keep`` ([bq, bk] bool) drops set to NEG_INF;
+    ``keep`` None = a pair wholly inside the band, no mask."""
+    return s if keep is None else jnp.where(keep, s, NEG_INF)
+
+
+def _grid_keep(q_block, k_block):
+    """Causal keep of one 128 x 128 pair of the grid variant (key position
+    <= query position), which masks every live pair."""
     row = q_block * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
     col = k_block * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-    keep = row >= col
-    if window is not None:
-        keep = keep & ((window <= 0) | (col > row - window))
-    return jnp.where(keep, s, NEG_INF)
+    return row >= col
 
 
 # ---- shared per-block math (one copy for the resident AND grid kernels) ----
@@ -91,16 +66,15 @@ def _causal_mask(s, q_block, k_block, window=None):
 # reference; exact for any scale, where pre-scaling a bf16 q would round).
 # The second GEMM of each pass casts its f32 left operand (p / ds) down to
 # the storage dtype — the standard flash-kernel precision contract.
+# ``keep`` is the pair's mask (None: none needed), see _mask.
 
-def _online_softmax_step(q, k, v, carry, qi, ki, causal: bool, sm_scale, window=None):
+def _online_softmax_step(q, k, v, carry, keep, sm_scale):
     """One K/V block of the online-softmax forward.
     carry = (acc [BQ,D], m [BQ,1], l [BQ,1]) in f32."""
     acc, m_prev, l_prev = carry
-    s = jax.lax.dot_general(
+    s = _mask(jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if causal:
-        s = _causal_mask(s, qi, ki, window)
+    ) * sm_scale, keep)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
@@ -112,14 +86,12 @@ def _online_softmax_step(q, k, v, carry, qi, ki, causal: bool, sm_scale, window=
     return acc, m_new, l_new
 
 
-def _dq_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, window=None):
+def _dq_block(q, k, v, do, lse, delta, keep, sm_scale):
     """One K/V block's contribution to dq (unscaled: caller multiplies the
     accumulated dq by sm_scale once). lse/delta [BQ,1] f32."""
-    s = jax.lax.dot_general(
+    s = _mask(jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if causal:
-        s = _causal_mask(s, qi, ki, window)
+    ) * sm_scale, keep)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -131,14 +103,12 @@ def _dq_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, window=No
     )
 
 
-def _dkv_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, window=None):
+def _dkv_block(q, k, v, do, lse, delta, keep, sm_scale):
     """One Q block's contributions to (dk, dv); dk unscaled (caller applies
     sm_scale once at finalize)."""
-    s = jax.lax.dot_general(
+    s = _mask(jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if causal:
-        s = _causal_mask(s, qi, ki, window)
+    ) * sm_scale, keep)
     p = jnp.exp(s - lse)  # [BQ, BK] f32
     dv = jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -155,17 +125,15 @@ def _dkv_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, window=N
     return dk, dv
 
 
-def _joint_bwd_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, window=None):
+def _joint_bwd_block(q, k, v, do, lse, delta, keep, sm_scale):
     """One (q,k) block pair's contributions to (dq, dk, dv) from a SINGLE
     recompute of s/p/dp/ds — the fused-backward building block. The split
     dq/dkv kernels each recompute QK^T, exp, dp and ds for every pair; this
     shares them (7 MXU dots -> 5 per pair, softmax VPU work halved).
     dq/dk returned unscaled (caller applies sm_scale once)."""
-    s = jax.lax.dot_general(
+    s = _mask(jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
-    if causal:
-        s = _causal_mask(s, qi, ki, window)
+    ) * sm_scale, keep)
     p = jnp.exp(s - lse)  # [BQ, BK] f32
     dv = jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -185,34 +153,168 @@ def _joint_bwd_block(q, k, v, do, lse, delta, qi, ki, causal: bool, sm_scale, wi
     return dq, dk, dv
 
 
-def _causal_hi(qi, num_k_blocks):
-    """Number of k blocks a q block attends into (correct for any BQ/BK)."""
-    return jnp.minimum(pl.cdiv((qi + 1) * BQ, BK), num_k_blocks)
+# ---- which pairs a resident kernel walks, and which of them it masks -------
+#
+# A resident kernel takes a piece of one side (``rows`` rows of q from
+# ``row0``, or ``cols`` keys from ``col0``) and walks the other side in
+# blocks. THE bounds of that walk, for any sizes, in one place: the live
+# range [lo, hi) (blocks wholly outside the band are never visited) is cut
+# at a and b into the blocks the window's trailing edge crosses, the blocks
+# wholly inside the band, which need no mask, and the blocks the causal edge
+# crosses. A walk is its three ranges ``(start, stop, masked, trips)``;
+# ``trips`` is stop - start where it is known as the kernel is traced (the
+# causal edge of a piece crosses a fixed number of blocks), so that the range
+# becomes straight-line code, which the chip runs far faster than a loop of
+# a trip count it has to read (PERF.md section 6, PR 33). Where the piece's
+# position is a Python int (a grid step that takes the whole sequence) every
+# bound is. ``win``: None = no window operand (no window arithmetic is traced
+# at all); else the traced i32 scalar, 0 = global. Key j is visible to row i
+# iff i - window < j <= i.
+
+_NO_WINDOW = 1 << 30  # a window no sequence reaches (GRID_KERNEL_MAX_SEQ < it)
+_UNROLL_MAX = 8  # longest range written out as straight-line code
 
 
-def _causal_lo(ki):
-    """First q block that can attend to k block ki (correct for any BQ/BK)."""
-    return (ki * BK) // BQ
+def _static(*xs) -> bool:
+    return all(isinstance(x, int) for x in xs)
 
 
-def _window_lo(qi, window):
-    """First k block a windowed q block can see (window 0 = global). The
-    oldest visible key for q row i is i - window + 1; the block's oldest
-    row is qi*BQ."""
-    return jnp.where(
-        window > 0, jnp.maximum(0, (qi * BQ - window + 1) // BK), 0
-    )
+def _min(a, b):
+    return min(a, b) if _static(a, b) else jnp.minimum(a, b)
 
 
-def _window_hi_q(ki, num_q_blocks, window):
-    """One-past-last q block that can see k block ki under a window: the
-    newest key of the block (ki*BK + BK - 1) is visible to q rows up to
-    key + window - 1."""
-    return jnp.where(
-        window > 0,
-        jnp.minimum(num_q_blocks, (ki * BK + BK + window - 2) // BQ + 1),
-        num_q_blocks,
-    )
+def _edge_trips(piece: int, block: int, win):
+    """Blocks of ``block`` the causal edge of a ``piece`` crosses, where the
+    one divides the other and no window can clip the range."""
+    if win is not None:
+        return None
+    if piece % block == 0:
+        return piece // block
+    return 1 if block % piece == 0 else None
+
+
+def _k_walk(row0, rows: int, num_k_blocks: int, bk: int, win):
+    """The walk over k blocks of ``bk`` for q rows [row0, row0 + rows):
+    [lo, a) cross the window's trailing edge, [a, b) are unmasked, [b, hi)
+    cross the causal edge."""
+    # k blocks the piece attends into: up to its newest row
+    hi = _min(pl.cdiv(row0 + rows, bk), num_k_blocks)
+    # k blocks whose newest key the piece's OLDEST row already sees
+    b = _min((row0 + 1) // bk, hi)
+    lo = a = 0
+    if win is not None:
+        # first k block the oldest row can see: its oldest visible key is
+        # row0 - window + 1
+        lo = jnp.where(win > 0, jnp.maximum(0, (row0 - win + 1) // bk), 0)
+        # first k block whose oldest key the piece's NEWEST row still sees
+        a = jnp.where(win > 0, jnp.maximum(0, pl.cdiv(row0 + rows - win, bk)), 0)
+        # a window narrower than a block: a > b, and every live pair is masked
+        a = jnp.minimum(a, hi)
+        b = jnp.maximum(a, b)
+    return ((lo, a, True, None), (a, b, False, None),
+            (b, hi, True, _edge_trips(rows, bk, win)))
+
+
+def _q_walk(col0, cols: int, num_q_blocks: int, bq: int, win):
+    """The walk over q blocks of ``bq`` for keys [col0, col0 + cols):
+    [lo, a) cross the causal edge, [a, b) are unmasked, [b, hi) cross the
+    window's trailing edge."""
+    # first q block that can attend to the piece's oldest key
+    lo = col0 // bq
+    # first q block whose oldest row sees the piece's NEWEST key
+    a = _min(pl.cdiv(col0 + cols - 1, bq), num_q_blocks)
+    b = hi = num_q_blocks
+    if win is not None:
+        # one-past-last q block that can see the piece: its newest key
+        # (col0 + cols - 1) is visible to rows up to key + window - 1
+        hi = jnp.where(
+            win > 0,
+            jnp.minimum(num_q_blocks, (col0 + cols + win - 2) // bq + 1),
+            num_q_blocks,
+        )
+        # one-past-last q block whose NEWEST row still sees the piece's
+        # oldest key: (i+1)*bq - 1 - window < col0
+        b = jnp.where(win > 0, jnp.minimum((col0 + win) // bq, hi), hi)
+        a = jnp.minimum(a, hi)
+        b = jnp.maximum(a, b)
+    return ((lo, a, True, _edge_trips(cols, bq, win)), (a, b, False, None),
+            (b, hi, True, None))
+
+
+def _whole_walk(num_blocks: int):
+    """The walk of a kernel that is not causal: every block, none masked."""
+    return ((0, num_blocks, False, None),)
+
+
+def _walk(ranges, pair, carry):
+    """Run ``pair(j, carry, masked)`` over the ranges of a walk."""
+    for start, stop, masked, trips in ranges:
+        if _static(start, stop):
+            trips = max(stop - start, 0)
+        if trips is not None and trips <= _UNROLL_MAX:
+            for t in range(trips):
+                carry = pair(start + t, carry, masked)
+        else:
+            carry = jax.lax.fori_loop(
+                start, stop, functools.partial(pair, masked=masked), carry
+            )
+    return carry
+
+
+def _pair_diff(rows: int, cols: int):
+    """row - col inside a pair, made once a grid step for all its masks."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _edge_keep(diff, off, win):
+    """The mask of a pair an edge crosses. ``diff`` is _pair_diff, ``off``
+    the pair's first column less its first row, so row - col of the sequence
+    is diff - off: keep iff 0 <= diff - off < window. Selects what the one
+    mask of every pair selected before the walk was split."""
+    keep = diff >= off
+    if win is not None:
+        keep = keep & (diff < off + jnp.where(win > 0, win, _NO_WINDOW))
+    return keep
+
+
+def plan_pairs(S: int, bq: int, bk: int, causal: bool = True) -> tuple:
+    """(masked, plain) pairs one head's forward walks under a plan with no
+    window: pieces of min(bq, bk) rows against blocks of bk keys, told apart
+    by the positions themselves, not by the walk's bounds (the tests hold
+    the two against each other)."""
+    rows = min(bq, bk)
+    masked = plain = 0
+    for row0 in range(0, S, rows):
+        for col0 in range(0, S, bk):
+            if not causal or col0 + bk - 1 <= row0:
+                plain += 1
+            elif col0 <= row0 + rows - 1:
+                masked += 1
+    return masked, plain
+
+
+def _row_pieces(win_ref, q_ref, *, seq_len: int, bk: int, causal: bool, windowed: bool):
+    """A grid step over ``bq`` rows of q, as the forward, the dq and the
+    fused backward kernels take it: pieces of min(bq, bk) rows, each with
+    its slice of the step's blocks, its walk over the k blocks and
+    ``keep(j, masked)``, the mask of its pair with k block j (None where the
+    pair needs none). A step that takes the whole sequence knows every
+    bound as it is traced."""
+    bq = q_ref.shape[1]
+    rows = min(bq, bk)
+    base = 0 if bq == seq_len else pl.program_id(1) * bq
+    win = win_ref[0] if windowed else None  # i32 scalar; 0 = global
+    nk = seq_len // bk
+    diff = _pair_diff(rows, bk) if causal else None
+    for r in range(0, bq, rows):
+        row0 = base + r
+
+        def keep(j, masked, row0=row0):
+            return _edge_keep(diff, j * bk - row0, win) if masked else None
+
+        walk = _k_walk(row0, rows, nk, bk, win) if causal else _whole_walk(nk)
+        yield slice(r, r + rows), walk, keep
 
 
 # This kernel keeps the full per-(batch,head) K/V (fwd, dq) or Q/dO (dkv) block
@@ -225,27 +327,28 @@ VMEM_RESIDENT_BYTES = 4 * 1024 * 1024
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float, causal: bool, seq_len: int):
-    qi = pl.program_id(1)
-    win = win_ref[0]  # i32 scalar; 0 = global (pure causal)
-    q = q_ref[0]  # [BQ, D], storage dtype (bf16 dots ride the native MXU path)
+def _fwd_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale: float,
+                bk: int, **step):
+    """One grid step: ``bq`` rows of one head's q against its whole K/V,
+    in pieces (_row_pieces) that each walk the k blocks they see."""
+    for rows, walk, keep in _row_pieces(win_ref, q_ref, bk=bk, **step):
+        q = q_ref[0, rows, :]  # storage dtype (bf16 dots ride the native MXU path)
 
-    num_k_blocks = pl.cdiv(seq_len, BK)
-    hi = _causal_hi(qi, num_k_blocks) if causal else num_k_blocks
-    lo = _window_lo(qi, win) if causal else 0
+        def pair(j, carry, masked):
+            k = k_ref[0, pl.ds(j * bk, bk), :]  # [bk, D]
+            v = v_ref[0, pl.ds(j * bk, bk), :]
+            return _online_softmax_step(q, k, v, carry, keep(j, masked), sm_scale)
 
-    def body(j, carry):
-        k = k_ref[0, pl.ds(j * BK, BK), :]  # [BK, D]
-        v = v_ref[0, pl.ds(j * BK, BK), :]
-        return _online_softmax_step(q, k, v, carry, qi, j, causal, sm_scale, win)
-
-    acc0 = jnp.zeros((BQ, q_ref.shape[-1]), jnp.float32)
-    m0 = jnp.full((BQ, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((BQ, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = jax.lax.broadcast_in_dim((m + jnp.log(l))[:, 0], (BQ, NUM_LANES), (0,))
+        n = q.shape[0]
+        acc0 = jnp.zeros(q.shape, jnp.float32)
+        m0 = jnp.full((n, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((n, 1), jnp.float32)
+        acc, m, l = _walk(walk, pair, (acc0, m0, l0))
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = jax.lax.broadcast_in_dim(
+            (m + jnp.log(l))[:, 0], (n, NUM_LANES), (0,)
+        )
 
 
 def _win_arr(window) -> jnp.ndarray:
@@ -253,25 +356,30 @@ def _win_arr(window) -> jnp.ndarray:
     return jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 
 
-def _fwd_call(q, k, v, window, *, S, D, grid, head_idx, kv_idx, lse_idx,
-              o_shape, lse_shape, sm_scale, causal, interpret):
+def _fwd_call(q, k, v, window, *, S, D, BH, head_idx, kv_idx, lse_idx,
+              o_shape, lse_shape, sm_scale, causal, interpret, kv_rep=1):
     """ONE pallas_call site for the resident forward, shared by the 3D
     ([BH,S,D]) and S-major ([B,S,E]) layouts — they differ only in index
     maps and output shapes; the kernel body is identical."""
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal, seq_len=S)
+    bq, bk = flash_plan(S, D, q.dtype.itemsize, kv_rep, causal=causal)
+    _note_plan(S, BH, bq, bk, causal)
+    kernel = functools.partial(
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, seq_len=S, bk=bk,
+        windowed=window is not None,
+    )
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(BH, S // bq),
             in_specs=[
-                pl.BlockSpec((1, BQ, D), head_idx),
+                pl.BlockSpec((1, bq, D), head_idx),
                 pl.BlockSpec((1, S, D), kv_idx),
                 pl.BlockSpec((1, S, D), kv_idx),
             ],
             out_specs=[
-                pl.BlockSpec((1, BQ, D), head_idx),
-                pl.BlockSpec((1, BQ, NUM_LANES), lse_idx),
+                pl.BlockSpec((1, bq, D), head_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
             ],
         ),
         interpret=interpret,
@@ -293,13 +401,13 @@ def _fwd(q3, k3, v3, sm_scale: float, causal: bool, interpret: bool = False, kv_
     window (GPT-Neo alternating local/global layers under one lax.scan)."""
     BH, S, D = q3.shape
     return _fwd_call(
-        q3, k3, v3, window, S=S, D=D, grid=(BH, S // BQ),
+        q3, k3, v3, window, S=S, D=D, BH=BH,
         head_idx=lambda b, i, w: (b, i, 0),
         kv_idx=lambda b, i, w: (b // kv_rep, 0, 0),
         lse_idx=lambda b, i, w: (b, i, 0),
         o_shape=jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
         lse_shape=jax.ShapeDtypeStruct((BH, S, NUM_LANES), jnp.float32),
-        sm_scale=sm_scale, causal=causal, interpret=interpret,
+        sm_scale=sm_scale, causal=causal, interpret=interpret, kv_rep=kv_rep,
     )
 
 
@@ -307,54 +415,53 @@ def _fwd(q3, k3, v3, sm_scale: float, causal: bool, interpret: bool = False, kv_
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, sm_scale, causal, seq_len):
-    qi = pl.program_id(1)
-    win = win_ref[0]
-    q = q_ref[0]
-    do = do_ref[0]
-    # load full lanes, slice the VALUE: a width-1 lane slice in the ref
-    # indexer is a Mosaic hazard; the value slice is free (lanes broadcast)
-    lse = lse_ref[0][:, 0:1]  # [BQ, 1]
-    delta = delta_ref[0][:, 0:1]
+def _bwd_dq_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   *, sm_scale, bk, **step):
+    for rows, walk, keep in _row_pieces(win_ref, q_ref, bk=bk, **step):
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        # load full lanes, slice the VALUE: a width-1 lane slice in the ref
+        # indexer is a Mosaic hazard; the value slice is free (lanes broadcast)
+        lse = lse_ref[0, rows, :][:, 0:1]  # [rows, 1]
+        delta = delta_ref[0, rows, :][:, 0:1]
 
-    num_k_blocks = pl.cdiv(seq_len, BK)
-    hi = _causal_hi(qi, num_k_blocks) if causal else num_k_blocks
-    lo = _window_lo(qi, win) if causal else 0
+        def pair(j, dq, masked):
+            k = k_ref[0, pl.ds(j * bk, bk), :]
+            v = v_ref[0, pl.ds(j * bk, bk), :]
+            return dq + _dq_block(q, k, v, do, lse, delta, keep(j, masked), sm_scale)
 
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * BK, BK), :]
-        v = v_ref[0, pl.ds(j * BK, BK), :]
-        return dq + _dq_block(q, k, v, do, lse, delta, qi, j, causal, sm_scale, win)
-
-    dq = jax.lax.fori_loop(lo, hi, body, jnp.zeros((BQ, q_ref.shape[-1]), jnp.float32))
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+        dq = _walk(walk, pair, jnp.zeros(q.shape, jnp.float32))
+        dq_ref[0, rows, :] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, sm_scale, causal, seq_len):
-    ki = pl.program_id(1)
-    win = win_ref[0]
-    k = k_ref[0]  # [BK, D]
+def _bwd_dkv_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                    *, sm_scale, causal, seq_len, bq, windowed):
+    """One grid step: one block of ``bk`` keys against the head's whole
+    q/dO, walked in blocks of ``bq`` rows (the plan's piece)."""
+    bk, D = k_ref.shape[1:]
+    col0 = 0 if bk == seq_len else pl.program_id(1) * bk
+    win = win_ref[0] if windowed else None
+    k = k_ref[0]  # [bk, D]
     v = v_ref[0]
+    nq = seq_len // bq
+    diff = _pair_diff(bq, bk) if causal else None
 
-    num_q_blocks = pl.cdiv(seq_len, BQ)
-    lo = _causal_lo(ki) if causal else 0
-    hi = _window_hi_q(ki, num_q_blocks, win) if causal else num_q_blocks
-
-    def body(i, carry):
+    def pair(i, carry, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * BQ, BQ), :]
-        do = do_ref[0, pl.ds(i * BQ, BQ), :]
+        q = q_ref[0, pl.ds(i * bq, bq), :]
+        do = do_ref[0, pl.ds(i * bq, bq), :]
         # dynamic sublane slice at full lanes, then slice the value (the
         # combined dynamic-sublane + width-1-lane ref slice is a Mosaic hazard)
-        lse = lse_ref[0, pl.ds(i * BQ, BQ), :][:, 0:1]  # [BQ, 1]
-        delta = delta_ref[0, pl.ds(i * BQ, BQ), :][:, 0:1]
-        dkc, dvc = _dkv_block(q, k, v, do, lse, delta, i, ki, causal, sm_scale, win)
+        lse = lse_ref[0, pl.ds(i * bq, bq), :][:, 0:1]  # [bq, 1]
+        delta = delta_ref[0, pl.ds(i * bq, bq), :][:, 0:1]
+        keep = _edge_keep(diff, col0 - i * bq, win) if masked else None
+        dkc, dvc = _dkv_block(q, k, v, do, lse, delta, keep, sm_scale)
         return dk + dkc, dv + dvc
 
-    D = k_ref.shape[-1]
-    dk0 = jnp.zeros((BK, D), jnp.float32)
-    dv0 = jnp.zeros((BK, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, hi, body, (dk0, dv0))
+    dk0 = jnp.zeros((bk, D), jnp.float32)
+    dv0 = jnp.zeros((bk, D), jnp.float32)
+    walk = _q_walk(col0, bk, nq, bq, win) if causal else _whole_walk(nq)
+    dk, dv = _walk(walk, pair, (dk0, dv0))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -376,73 +483,70 @@ def _fused_bwd_ok(S: int, D: int, kv_rep: int = 1) -> bool:
 
 def _bwd_fused_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                      *, sm_scale, causal, seq_len, num_q_blocks):
+                      *, sm_scale, bk, **step):
     """dq + dk + dv in ONE pass over the (q,k) block pairs (resident shapes):
     dk/dv accumulate in whole-sequence VMEM f32 scratch across the
     sequential q-block grid dimension and are written once at the last q
     step. Each pair's s/p/dp/ds are computed once (_joint_bwd_block) instead
     of once per split kernel."""
     qi = pl.program_id(1)
-    win = win_ref[0]
 
     @pl.when(qi == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q = q_ref[0]
-    do = do_ref[0]
-    # load full lanes, slice the VALUE (width-1 lane ref slices are a
-    # Mosaic hazard — same pattern as the split kernels)
-    lse = lse_ref[0][:, 0:1]
-    delta = delta_ref[0][:, 0:1]
-    num_k_blocks = pl.cdiv(seq_len, BK)
-    hi = _causal_hi(qi, num_k_blocks) if causal else num_k_blocks
-    lo = _window_lo(qi, win) if causal else 0
+    for rows, walk, keep in _row_pieces(win_ref, q_ref, bk=bk, **step):
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        # load full lanes, slice the VALUE (width-1 lane ref slices are a
+        # Mosaic hazard — same pattern as the split kernels)
+        lse = lse_ref[0, rows, :][:, 0:1]
+        delta = delta_ref[0, rows, :][:, 0:1]
 
-    def body(j, dq):
-        k = k_ref[0, pl.ds(j * BK, BK), :]
-        v = v_ref[0, pl.ds(j * BK, BK), :]
-        dqc, dkc, dvc = _joint_bwd_block(
-            q, k, v, do, lse, delta, qi, j, causal, sm_scale, win
-        )
-        dk_acc[pl.ds(j * BK, BK), :] = dk_acc[pl.ds(j * BK, BK), :] + dkc
-        dv_acc[pl.ds(j * BK, BK), :] = dv_acc[pl.ds(j * BK, BK), :] + dvc
-        return dq + dqc
+        def pair(j, dq, masked):
+            k = k_ref[0, pl.ds(j * bk, bk), :]
+            v = v_ref[0, pl.ds(j * bk, bk), :]
+            dqc, dkc, dvc = _joint_bwd_block(
+                q, k, v, do, lse, delta, keep(j, masked), sm_scale
+            )
+            dk_acc[pl.ds(j * bk, bk), :] = dk_acc[pl.ds(j * bk, bk), :] + dkc
+            dv_acc[pl.ds(j * bk, bk), :] = dv_acc[pl.ds(j * bk, bk), :] + dvc
+            return dq + dqc
 
-    dq = jax.lax.fori_loop(lo, hi, body, jnp.zeros((BQ, q_ref.shape[-1]), jnp.float32))
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+        dq = _walk(walk, pair, jnp.zeros(q.shape, jnp.float32))
+        dq_ref[0, rows, :] = (dq * sm_scale).astype(dq_ref.dtype)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(qi == pl.num_programs(1) - 1)
     def _finalize():
         dk_ref[0] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_call(q, k, v, do, lse, delta, win, *, S, D, grid, head_idx,
+def _bwd_fused_call(q, k, v, do, lse, delta, window, *, S, D, BH, head_idx,
                     kv_idx, dkv_idx, lse_idx, dq_shape, dkv_shape,
-                    sm_scale, causal, interpret):
+                    sm_scale, causal, interpret, kv_rep=1):
     """ONE pallas_call site for the fused backward, shared by the 3D and
     S-major layouts (index maps + output shapes differ, body is shared)."""
-    nq = grid[1]
+    bq, bk = flash_plan(S, D, q.dtype.itemsize, kv_rep, backward=True, causal=causal)
     return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-            seq_len=S, num_q_blocks=nq,
+            seq_len=S, bk=bk, windowed=window is not None,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(BH, S // bq),
             in_specs=[
-                pl.BlockSpec((1, BQ, D), head_idx),
+                pl.BlockSpec((1, bq, D), head_idx),
                 pl.BlockSpec((1, S, D), kv_idx),
                 pl.BlockSpec((1, S, D), kv_idx),
-                pl.BlockSpec((1, BQ, D), head_idx),
-                pl.BlockSpec((1, BQ, NUM_LANES), lse_idx),
-                pl.BlockSpec((1, BQ, NUM_LANES), lse_idx),
+                pl.BlockSpec((1, bq, D), head_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
             ],
             out_specs=[
-                pl.BlockSpec((1, BQ, D), head_idx),
+                pl.BlockSpec((1, bq, D), head_idx),
                 pl.BlockSpec((1, S, D), dkv_idx),
                 pl.BlockSpec((1, S, D), dkv_idx),
             ],
@@ -453,13 +557,13 @@ def _bwd_fused_call(q, k, v, do, lse, delta, win, *, S, D, grid, head_idx,
         ),
         interpret=interpret,
         out_shape=[dq_shape, dkv_shape, dkv_shape],
-    )(win, q, k, v, do, lse, delta)
+    )(_win_arr(window), q, k, v, do, lse, delta)
 
 
-def _bwd_fused(q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, win):
+def _bwd_fused(q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, window):
     BH, S, D = q3.shape
     return _bwd_fused_call(
-        q3, k3, v3, do3, lse, delta, win, S=S, D=D, grid=(BH, S // BQ),
+        q3, k3, v3, do3, lse, delta, window, S=S, D=D, BH=BH,
         head_idx=lambda b, i, w: (b, i, 0),
         kv_idx=lambda b, i, w: (b // kv_rep, 0, 0),
         # dk/dv staged PER Q HEAD (b, not b//kv_rep): under GQA the group is
@@ -470,7 +574,7 @@ def _bwd_fused(q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep,
         dkv_shape=jax.ShapeDtypeStruct(
             (BH, S, D), jnp.float32 if kv_rep > 1 else q3.dtype
         ),
-        sm_scale=sm_scale, causal=causal, interpret=interpret,
+        sm_scale=sm_scale, causal=causal, interpret=interpret, kv_rep=kv_rep,
     )
 
 
@@ -494,50 +598,52 @@ def _bwd(q3, k3, v3, o3, lse, do3, sm_scale: float, causal: bool, interpret: boo
 
     full = lambda b, i, w: (b, 0, 0)
     kv_full = lambda b, i, w: (b // kv_rep, 0, 0)
-    win = _win_arr(window)
     if _fused_bwd_ok(S, D, kv_rep):
         dq, dk, dv = _bwd_fused(
-            q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, win
+            q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, window
         )
         if kv_rep > 1:
             dk = dk.reshape(BH // kv_rep, kv_rep, S, D).sum(axis=1).astype(k3.dtype)
             dv = dv.reshape(BH // kv_rep, kv_rep, S, D).sum(axis=1).astype(v3.dtype)
         return dq, dk, dv
+    bq, bk = flash_plan(S, D, q3.dtype.itemsize, kv_rep, backward=True, causal=causal)
+    win = _win_arr(window)
+    static = dict(sm_scale=sm_scale, causal=causal, seq_len=S, windowed=window is not None)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal, seq_len=S),
+        functools.partial(_bwd_dq_kernel, bk=bk, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH, S // BQ),
+            grid=(BH, S // bq),
             in_specs=[
-                pl.BlockSpec((1, BQ, D), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bq, D), lambda b, i, w: (b, i, 0)),
                 pl.BlockSpec((1, S, D), kv_full),
                 pl.BlockSpec((1, S, D), kv_full),
-                pl.BlockSpec((1, BQ, D), lambda b, i, w: (b, i, 0)),
-                pl.BlockSpec((1, BQ, NUM_LANES), lambda b, i, w: (b, i, 0)),
-                pl.BlockSpec((1, BQ, NUM_LANES), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bq, D), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bq, NUM_LANES), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bq, NUM_LANES), lambda b, i, w: (b, i, 0)),
             ],
-            out_specs=pl.BlockSpec((1, BQ, D), lambda b, i, w: (b, i, 0)),
+            out_specs=pl.BlockSpec((1, bq, D), lambda b, i, w: (b, i, 0)),
         ),
         interpret=interpret,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
     )(win, q3, k3, v3, do3, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, seq_len=S),
+        functools.partial(_bwd_dkv_kernel, bq=min(bq, bk), **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH, S // BK),
+            grid=(BH, S // bk),
             in_specs=[
                 pl.BlockSpec((1, S, D), full),
-                pl.BlockSpec((1, BK, D), lambda b, i, w: (b // kv_rep, i, 0)),
-                pl.BlockSpec((1, BK, D), lambda b, i, w: (b // kv_rep, i, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, i, w: (b // kv_rep, i, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, i, w: (b // kv_rep, i, 0)),
                 pl.BlockSpec((1, S, D), full),
                 pl.BlockSpec((1, S, NUM_LANES), full),
                 pl.BlockSpec((1, S, NUM_LANES), full),
             ],
             out_specs=[
-                pl.BlockSpec((1, BK, D), lambda b, i, w: (b, i, 0)),
-                pl.BlockSpec((1, BK, D), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, i, w: (b, i, 0)),
+                pl.BlockSpec((1, bk, D), lambda b, i, w: (b, i, 0)),
             ],
         ),
         interpret=interpret,
@@ -609,7 +715,8 @@ def _fwd_grid_kernel(
         k = k_ref[0]
         v = v_ref[0]
         carry = (acc_ref[...], m_ref[:, 0:1], l_ref[:, 0:1])
-        acc, m_new, l_new = _online_softmax_step(q, k, v, carry, qi, ki, causal, sm_scale)
+        keep = _grid_keep(qi, ki) if causal else None
+        acc, m_new, l_new = _online_softmax_step(q, k, v, carry, keep, sm_scale)
         acc_ref[...] = acc
         m_ref[...] = jax.lax.broadcast_in_dim(m_new[:, 0], m_ref.shape, (0,))
         l_ref[...] = jax.lax.broadcast_in_dim(l_new[:, 0], l_ref.shape, (0,))
@@ -683,7 +790,8 @@ def _bwd_dq_grid_kernel(
         do = do_ref[0]
         lse = lse_ref[0, :, 0:1]
         delta = delta_ref[0, :, 0:1]
-        dq_acc[...] = dq_acc[...] + _dq_block(q, k, v, do, lse, delta, qi, ki, causal, sm_scale)
+        keep = _grid_keep(qi, ki) if causal else None
+        dq_acc[...] = dq_acc[...] + _dq_block(q, k, v, do, lse, delta, keep, sm_scale)
 
     if causal:
         @pl.when(_causal_block_live(qi, ki))
@@ -716,7 +824,8 @@ def _bwd_dkv_grid_kernel(
         do = do_ref[0]
         lse = lse_ref[0, :, 0:1]
         delta = delta_ref[0, :, 0:1]
-        dkc, dvc = _dkv_block(q, k, v, do, lse, delta, qi, ki, causal, sm_scale)
+        keep = _grid_keep(qi, ki) if causal else None
+        dkc, dvc = _dkv_block(q, k, v, do, lse, delta, keep, sm_scale)
         dk_acc[...] = dk_acc[...] + dkc
         dv_acc[...] = dv_acc[...] + dvc
 
@@ -804,6 +913,75 @@ def resident_ok(S: int, D: int, itemsize: int) -> bool:
     fits the whole-K/V VMEM budget. Shared by the auto dispatchers and any
     telemetry that reports which variant served a shape."""
     return S * D * itemsize <= VMEM_RESIDENT_BYTES
+
+
+# What the census of the resident kernels found (v5e, bf16, D 64 and 128,
+# S 512 to 4096; PERF.md section 6, PR 33): a pair's time is loop and grid
+# overhead before it is arithmetic, so the widest inner iteration measured
+# (512 keys) wins at every shape, and a grid step that takes a head's whole
+# sequence, whose walk is then straight-line code, beats one of 512 rows by
+# a fifth to a quarter. That holds while the walk is at most
+# PLAN_WHOLE_PAIRS pairs: written out longer it outgrows the kernel's stack.
+PLAN_WIDTHS = (512, 256)
+PLAN_WHOLE_PAIRS = 10
+# The VMEM a v5e kernel is allowed (Mosaic's scoped limit).
+PLAN_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def plan_vmem_bytes(S: int, D: int, itemsize: int, kv_rep: int, bq: int,
+                    bk: int, backward: bool) -> int:
+    """VMEM a resident kernel needs under a plan: the [rows, bk] f32 tiles
+    alive in a pair (forward s and p; fused backward s, p, dp and ds), two
+    buffers each of K and V, of the step's q rows and of what comes and goes
+    with them, and in the backward the two [S, D] f32 accumulators and two
+    buffers each of the dk and dv they are written to. A reckoning from
+    shapes that errs high where it was held against the chip's compiler,
+    which has the last word: tests/unit/ops/test_mosaic_compile.py asks it."""
+    tile = min(bq, bk) * bk * 4
+    kv = 2 * S * D * itemsize
+    row = D * itemsize      # one row of q, o, dO or dq
+    stat = NUM_LANES * 4    # one row of lse or delta
+    if not backward:
+        return 2 * tile + 2 * (kv + bq * (2 * row + stat))
+    staged = 4 if kv_rep > 1 else itemsize
+    accumulators = 2 * S * max(D, NUM_LANES) * 4
+    return (4 * tile + accumulators
+            + 2 * (kv + bq * (3 * row + 2 * stat) + 2 * S * D * staged))
+
+
+def flash_plan(S: int, D: int, itemsize: int, kv_rep: int = 1,
+               backward: bool = False, causal: bool = True) -> tuple:
+    """THE block rule of the resident kernels: (q rows of a grid step,
+    width of one inner iteration over k) for a shape. The widest measured
+    width that divides S, under a grid step of the whole sequence where its
+    walk is short enough and fits, else of one piece; 128 x 128, what every
+    shape ran before, where the chip was not asked (other head dims, f32
+    operands, sequences under 512, the split backward)."""
+    if D not in (64, 128) or itemsize != 2 or S < 512:
+        return BQ, BK
+    if backward and not _fused_bwd_ok(S, D, kv_rep):
+        return BQ, BK  # the split dq / dkv kernels were not in the census
+    for bk in PLAN_WIDTHS:
+        if S % bk:
+            continue
+        fits = lambda bq: plan_vmem_bytes(
+            S, D, itemsize, kv_rep, bq, bk, backward) <= PLAN_VMEM_BYTES
+        if sum(plan_pairs(S, S, bk, causal)) <= PLAN_WHOLE_PAIRS and fits(S):
+            return S, bk
+        if fits(bk):
+            return bk, bk
+    return BQ, BK
+
+
+# The plan of the last resident forward traced in this process, for whoever
+# reports what a compiled step runs (runtime/engine.py copies it into the
+# ``ds.init.programs`` phase): empty while only the jnp path was traced.
+traced_plan: dict = {}
+
+
+def _note_plan(S: int, BH: int, bq: int, bk: int, causal: bool) -> None:
+    masked, plain = plan_pairs(S, bq, bk, causal)
+    traced_plan.update(bq=bq, bk=bk, masked=BH * masked, plain=BH * plain)
 
 
 def _fwd_auto(q3, k3, v3, sm_scale: float, causal: bool, interpret: bool = False, kv_rep: int = 1, window=None):
@@ -915,7 +1093,7 @@ def _fwd_bse(q2, k2, v2, H: int, sm_scale, causal, interpret, window):
     B, S, E = q2.shape
     D = E // H
     return _fwd_call(
-        q2, k2, v2, window, S=S, D=D, grid=(B * H, S // BQ),
+        q2, k2, v2, window, S=S, D=D, BH=B * H,
         head_idx=lambda bh, i, w: (bh // H, i, bh % H),
         kv_idx=lambda bh, i, w: (bh // H, 0, bh % H),
         lse_idx=lambda bh, i, w: (bh, i, 0),
@@ -934,8 +1112,7 @@ def _bwd_fused_bse(q2, k2, v2, o2, lse, do2, H: int, sm_scale, causal, interpret
     delta = jnp.sum(d4 * o4, axis=-1).transpose(0, 2, 1).reshape(BH, S)  # [B,S,H] transpose: E-free, cheap
     delta = jnp.broadcast_to(delta[..., None], (BH, S, NUM_LANES))
     return _bwd_fused_call(
-        q2, k2, v2, do2, lse, delta, _win_arr(window), S=S, D=D,
-        grid=(BH, S // BQ),
+        q2, k2, v2, do2, lse, delta, window, S=S, D=D, BH=BH,
         head_idx=lambda bh, i, w: (bh // H, i, bh % H),
         kv_idx=lambda bh, i, w: (bh // H, 0, bh % H),
         dkv_idx=lambda bh, i, w: (bh // H, 0, bh % H),

@@ -34,6 +34,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ..ops.attention import traced_flash_plan
 from ..parallel.topology import MeshSpec, mesh_axis_size
 from ..telemetry import compile_stats, spans
 from ..utils.logging import log_dist, logger
@@ -1720,8 +1721,12 @@ class DeepSpeedEngine:
             with self._mesh_scope(), (
                 spans.phase("ds.init.programs", what="train_step")
                 if first_call else contextlib.nullcontext()
-            ):
+            ) as programs_phase:
+                if first_call:
+                    traced_flash_plan(reset=True)
                 self.state, metrics = self._train_step(self.state, device_batch, step_rng)
+                if first_call:
+                    programs_phase.set(flash_plan=self._set_flash_plan_gauges())
             self.global_steps += 1
             # monotonic train_batch ordinal: the fault-injection index. NOT
             # global_steps — a rollback rewinds that, which would re-fire the
@@ -2136,6 +2141,34 @@ class DeepSpeedEngine:
                 "ratio": logical / wire if wire else 1.0,
             }
         }
+
+    def _set_flash_plan_gauges(self) -> str:
+        """Under which plan the step just traced runs its flash forward
+        (``ops.attention.traced_flash_plan``), as registry gauges and
+        (returned) as the ``flash_plan`` attr of the ``ds.init.programs``
+        phase: ``bq=<n> bk=<n> masked=<n> plain=<n>``, 0 where the jnp path
+        runs."""
+        plan = traced_flash_plan()
+        if self.telemetry is not None:
+            reg = self.telemetry.registry
+            pairs = reg.gauge(
+                "train_flash_block_pairs",
+                "block pairs of one flash forward call of the train step, "
+                "by whether the causal edge crosses them (masked) or not "
+                "(plain); 0 = the jnp path runs",
+                labelnames=("kind",),
+            )
+            block = reg.gauge(
+                "train_flash_block",
+                "the flash kernels' q block and the width of one inner "
+                "iteration over k, from flash_plan (0 = the jnp path runs)",
+                labelnames=("dim",),
+            )
+            pairs.set(plan["masked"], kind="masked")
+            pairs.set(plan["plain"], kind="plain")
+            block.set(plan["bq"], dim="q")
+            block.set(plan["bk"], dim="k")
+        return " ".join(f"{k}={v}" for k, v in plan.items())
 
     def _jit_step_programs(self) -> int:
         """Invalidation key for program-derived caches: the jitted step's

@@ -1,5 +1,5 @@
-"""Mosaic-compile the serving path's paged kernels and programs at real widths for a
-described (not attached) TPU v5e: what the chip's compiler would refuse is
+"""Mosaic-compile the serving path's paged kernels and programs, and the training
+step's flash kernels, at real widths for a described (not attached) TPU v5e: what the chip's compiler would refuse is
 refused here, at no chip time. Interpret mode cannot show a misaligned slice
 or a kernel over its VMEM.
 
@@ -355,3 +355,54 @@ def test_an_axis_longer_than_a_narrow_head_is_moved(one_chip, shape, moved):
     64-wide head the device's default layout puts an axis longer than the
     head minor-most, whichever axis it is."""
     assert _default_layout(one_chip, shape, jnp.bfloat16) == moved
+
+
+# -- the training step's flash kernels under the plan the rule picks (ISSUE 33) --
+
+FLASH_SHAPES = {
+    # name: (B*H, S, D, q heads to a kv head); bf16
+    "train-cells": (100, 1024, 64, 1),      # [4, 1024, 25, 64] a chip, both cells
+    "d128": (64, 1024, 128, 1),
+    "gqa-rep4": (64, 1024, 128, 4),
+    "gqa-rep8-d64": (96, 1024, 64, 8),
+    "s2048": (50, 2048, 64, 1),             # the longest whole-sequence grid step
+    "s2048-d128": (32, 2048, 128, 1),       # forward whole, backward a piece a step
+    "s4096-d128": (16, 4096, 128, 1),       # the backward's VMEM leaves 256 x 256
+    "s512": (200, 512, 64, 1),
+}
+
+
+@pytest.mark.parametrize("mode", ["causal", "window", "full"])
+@pytest.mark.parametrize("name", list(FLASH_SHAPES))
+def test_flash_kernels_compile_for_v5e_under_their_plan(one_chip, name, mode):
+    """The resident forward and the fused backward at the plan ``flash_plan``
+    gives the shape: interpret mode cannot show a tile over VMEM, a walk
+    written out past the kernel's stack, or a misaligned slice. ``window``
+    is a traced window operand (GPT-Neo's local layers), ``full`` the
+    non-causal kernels (bidirectional, Ulysses, the ring's off-diagonal)."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    BH, S, D, rep = FLASH_SHAPES[name]
+    causal = mode != "full"
+    assert fa.resident_ok(S, D, 2) and fa._fused_bwd_ok(S, D, rep)
+    plans = [fa.flash_plan(S, D, 2, rep, backward=b, causal=causal) for b in (False, True)]
+    assert all(S % b == 0 and b > 128 for plan in plans for b in plan), plans
+    if name == "train-cells" and causal:
+        assert plans == [(1024, 512)] * 2      # what the census found fastest there
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, kv = sds((BH, S, D)), sds((BH // rep, S, D))
+    win = [sds((1,), jnp.int32)] if mode == "window" else []
+
+    def fwd(q, k, v, *w):
+        return fa._fwd(q, k, v, 0.125, causal, False, rep, *w)
+
+    def bwd(q, k, v, o, lse, do, *w):
+        return fa._bwd(q, k, v, o, lse, do, 0.125, causal, False, rep, *w)
+
+    for f, args in ((fwd, [q, kv, kv]),
+                    (bwd, [q, kv, kv, q, sds((BH, S, fa.NUM_LANES), jnp.float32), q])):
+        compiled = jax.jit(f).lower(*args, *win).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
