@@ -232,12 +232,15 @@ class ExaoneFamily:
     """What ``serving/model.py`` asks of a model (see its ``Family`` notes)."""
 
     prefill_block = 256   # the whole-prompt program attends in query blocks of this many
+    kv_pools = 2          # a K and a V pool
+    grouped_from = 0      # every call of the expert layer keeps the masked form (moe/expert_share.py)
 
     def __init__(self, cfg: ExaoneMoEConfig):
         self.cfg = cfg
         self.n_layer, self.n_head, self.n_kv_head = cfg.n_layer, cfg.n_head, cfg.n_kv_head
         self.head_dim, self.vocab_size, self.n_positions = cfg.head_dim, cfg.vocab_size, cfg.n_positions
         self.attn_impl = cfg.attn_impl
+        self.v_width = cfg.head_dim
         self.windows = tuple(cfg.window(l) for l in range(cfg.n_layer))
         self.sparse_layers = tuple(l for l in range(cfg.n_layer) if cfg.is_sparse(l))
         self.experts_held = cfg.num_experts

@@ -333,6 +333,8 @@ class GPT2Family:
     mesh axis the row-parallel partial products are summed over."""
 
     prefill_block = 0      # the whole-prompt program attends as one dense product
+    kv_pools = 2           # a K and a V pool
+    grouped_from = 0       # (no expert layer the serving path knows)
     sparse_layers = ()     # no layer reports expert loads
     experts_held = 0
     experts_per_token = 0
@@ -343,6 +345,7 @@ class GPT2Family:
         self.head_dim, self.vocab_size, self.n_positions = cfg.head_dim, cfg.vocab_size, cfg.n_positions
         self.attn_impl = cfg.attn_impl
         self.windows = (0,) * cfg.n_layer
+        self.v_width = cfg.head_dim
 
     def embed(self, params, ids, positions):
         te, pe = params["wte"][ids], params["wpe"][positions]

@@ -13,13 +13,29 @@ have added is left out; nothing stands in for the other chips.
     w_e   = scale * s_e / sum_{sel} s           for e in sel
     y     = sum_{e in sel, e held} w_e FFN_e(u) + FFN_shared(u)
 
-No capacity: a held expert computes every token routed to it. The products
-run over all held experts at once with the weights of the unselected pairs
-zero (``[n_held, T, F]``): at the served shapes every held expert is hit by
-some token of the batch anyway (64 tokens x 8 of 128: an expert is missed
-with probability 0.016), so its weights are streamed either way, and a chunk
-of 256 tokens pays 16 x the products a grouped form would need, which the MXU
-has room for beside that stream (PERF.md, PR 32, has both forms measured).
+No capacity: a held expert computes every token routed to it. Two forms of
+the same sum. A family that takes the grouped form says from how many rows a
+call on (``expert_share_layer``'s ``grouped_from``, a static property of the
+family: ``GROUPED_MIN_ROWS`` for the top-4 family this was measured for; 0,
+the default, keeps every call of a family masked, as the top-8 family's
+accepted cell was measured); the switch is then by the call's static row
+count alone:
+
+- MASKED (:func:`held_experts`), for a decode step's rows and a short chunk:
+  the products run over all held experts at once with the weights of the
+  unselected pairs zero (``[n_held, T, F]``). At 64 tokens x 8 of 128 every
+  held expert is hit by some token of the batch anyway, so its weights are
+  streamed either way, and a chunk of 256 tokens pays 16 x the products a
+  grouped form would need, which the MXU has room for beside that stream
+  (PERF.md, PR 32, has both forms measured).
+- GROUPED (:func:`held_experts_grouped`), for calls of many rows: the
+  (token, expert) pairs whose expert is held, sorted by expert, multiplied
+  group by group (``lax.ragged_dot``: a held expert's weights meet only the
+  rows routed to it). At 1 024 rows x 4 of 128 with 16 held the masked form
+  multiplies 32 x the pairs there are (PERF.md, PR 34, has both measured).
+  The pair budget is the static ``T x top_k`` (every token may pick held
+  experts only); the pairs whose expert is absent sort behind the held
+  groups, belong to no group and carry weight 0. No pair is dropped.
 
 The sum of all the shares' routed parts and the shared part once is the uncut
 layer (``tests/unit/test_expert_share.py``).
@@ -89,16 +105,69 @@ def held_experts(u, wh, w_gate, w_up, w_down):
     return jnp.einsum("ntf,nfe->te", a.astype(u.dtype), w_down)
 
 
+GROUPED_MIN_ROWS = 512     # where the grouped form wins at top-4 of 128, 16 held (PERF.md, PR 34)
+GROUPED_BLOCK_ROWS = 4096  # the grouped form takes this many rows at a time (a whole-prompt program's temporaries)
+
+
+def grouped_rows(T: int, top_k: int, grouped_from: int) -> int:
+    """Pair rows a call of ``T`` token rows hands the grouped products
+    (padding included: the static budget), 0 where the masked form runs."""
+    return T * top_k if grouped_from and T >= grouped_from else 0
+
+
+def held_experts_grouped(u, idx, w, share: ExpertShare, w_gate, w_up, w_down):
+    """:func:`held_experts`' sum over the pairs themselves: ``idx`` / ``w [T,
+    k]`` as :func:`route` gives them. The ``T x k`` pairs are sorted by held
+    expert (absent experts' pairs last, outside every group), each group's
+    rows meet its expert's weights once, and a pair's product is weighted in
+    float32 before its one rounding, as in the masked form."""
+    T, k = idx.shape
+    n = share.n_held
+    local = idx - share.index * n
+    group = jnp.where((local >= 0) & (local < n), local, n).reshape(T * k)
+    order = jnp.argsort(group, stable=True)                        # pair rows, by group
+    sizes = jnp.sum(group[:, None] == jnp.arange(n)[None, :], axis=0, dtype=jnp.int32)
+    in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]       # rows past the groups: nothing
+    x = u[order // k]                                              # [T * k, E]
+    g = jax.lax.ragged_dot(x, w_gate, sizes)
+    v = jax.lax.ragged_dot(x, w_up, sizes)
+    a = jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32) * w.reshape(T * k)[order][:, None]
+    a = jnp.where(in_group, a, 0.0).astype(u.dtype)
+    y = jnp.where(in_group, jax.lax.ragged_dot(a, w_down, sizes), 0)
+    # back to the pairs' own order, then a token's k pairs summed in float32
+    y = y[jnp.argsort(order)].reshape(T, k, -1)
+    return jnp.sum(y.astype(jnp.float32), axis=1).astype(u.dtype)
+
+
+def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from):
+    """→ (the held experts' part for ``u [T, E]``, ``wh [T, n_held]``)."""
+    idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk)
+    wh = held_weights(idx, w, share)
+    ex = lp["experts"]
+    if grouped_rows(u.shape[0], top_k, grouped_from):
+        return held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
+    return held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
+
+
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
-                       norm_topk: bool = True, valid: Optional[jnp.ndarray] = None):
+                       norm_topk: bool = True, valid: Optional[jnp.ndarray] = None,
+                       grouped_from: int = 0):
     """``u [T, E]`` → (``y [T, E]``, ``counts [n_held]`` int32: the tokens
     each held expert got; with ``valid [T]`` only those rows count, e.g. the
     slots that hold a request). ``lp``: ``router [E, n_experts]``, ``bias
-    [n_experts]``, ``experts`` and ``shared`` with ``w_gate, w_up, w_down``."""
-    idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk)
-    wh = held_weights(idx, w, share)
-    ex, sh = lp["experts"], lp["shared"]
-    y = held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"])
+    [n_experts]``, ``experts`` and ``shared`` with ``w_gate, w_up, w_down``.
+    ``grouped_from``: calls of that many rows or more take the grouped form
+    (0: none does)."""
+    T = u.shape[0]
+    if grouped_from and T > GROUPED_BLOCK_ROWS and T % GROUPED_BLOCK_ROWS == 0:
+        y, wh = jax.lax.map(
+            lambda ub: _routed(ub, lp, share, top_k, scale, norm_topk, grouped_from),
+            u.reshape(-1, GROUPED_BLOCK_ROWS, u.shape[1]),
+        )
+        y, wh = y.reshape(T, -1), wh.reshape(T, -1)
+    else:
+        y, wh = _routed(u, lp, share, top_k, scale, norm_topk, grouped_from)
+    sh = lp["shared"]
     y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
     got = wh > 0.0  # sigmoid scores are positive: a selected pair's weight is
     if valid is not None:

@@ -415,3 +415,53 @@ def bidirectional_attention(
             )
         return bidirectional_attention_jnp(q, k, v, None, sm_scale)
     raise ValueError(f"unknown attention impl {impl}")
+
+
+def latent_paged_cached_attention(
+    q, pool, block_tables, base, v_width: int, impl: str = "auto",
+    sm_scale: float = 1.0, layer=None, name: Optional[str] = None,
+):
+    """Causal attention of ``T`` query tokens a slot against a LATENT paged
+    cache: q [B,T,H,W] (absorbed queries), pool [P,1,page,W] or, with a static
+    ``layer``, [L,P,1,page,W]: one row a token that every head reads, keys
+    the whole row, values its first ``v_width`` lanes; query t of slot b sits
+    at ``base[b] + t`` → [B,T,H,v_width]. ``T`` = 1 is the decode step. The
+    tokens' own rows must already be in the pool.
+
+    Dispatch as :func:`paged_cached_attention`: the latent Pallas kernel on a
+    TPU (a page read once for scores and values), else a jnp fallback that
+    gathers the slot's pages and runs the same masked softmax in float32."""
+    B, T, H, W = q.shape
+    page = pool.shape[-2]
+    from .pallas.latent_attention import latent_attention_ok, latent_paged_attention
+
+    if _paged_kernel_taken(impl, lambda: latent_attention_ok(page, W, pool.dtype.itemsize)):
+        return latent_paged_attention(
+            q, pool, block_tables, base, v_width, sm_scale, layer=layer, name=name
+        )
+    if layer is not None:
+        pool = pool[layer]
+    kd = pool[block_tables].reshape(B, -1, W).astype(jnp.float32)     # [B, S, W]
+    S = kd.shape[1]
+    seen = (
+        jnp.arange(S)[None, None, :]
+        <= base[:, None, None] + jnp.arange(T)[None, :, None]
+    )  # [B, T, S]
+    s = jnp.einsum("bthw,bsw->bths", q.astype(jnp.float32), kd) * sm_scale
+    p = jax.nn.softmax(jnp.where(seen[:, :, None, :], s, -1e30), axis=-1)
+    return jnp.einsum("bths,bsv->bthv", p, kd[..., :v_width]).astype(q.dtype)
+
+
+def latent_attention_grid_steps(
+    impl: str, B: int, H: int, page: int, W: int, itemsize: int, n_pages: int, T: int = 1,
+) -> int:
+    """Grid steps of ONE call of the latent attention kernel that
+    :func:`latent_paged_cached_attention` dispatches these shapes to under
+    ``impl``: slots x query blocks x page blocks, by the dispatcher's own rule
+    and the kernel's own block rule. 0 where the jnp fallback runs."""
+    from .pallas.latent_attention import latent_attention_ok, latent_blocks
+
+    if not _paged_kernel_taken(impl, lambda: latent_attention_ok(page, W, itemsize)):
+        return 0
+    TQ, G = latent_blocks(H, page, T, n_pages)
+    return B * (T // TQ) * -(-n_pages // G)

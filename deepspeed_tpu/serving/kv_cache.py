@@ -247,7 +247,8 @@ def _page_axes(num_pages: int) -> tuple:
 
 
 def pool_stored_shape(n_layer: int, num_pages: int, n_kv_head: int,
-                      page_size: int, head_dim: int, dtype: Any) -> tuple:
+                      page_size: int, head_dim: int, dtype: Any,
+                      latent: bool = False) -> tuple:
     """The shape the K and V pools are STORED with on the device:
     ``[L, P, KV, page, D]``, or, where the paged kernels run (a TPU, a page
     shape they take) on a head that does not fill the 128 lanes, the same
@@ -278,10 +279,32 @@ def pool_stored_shape(n_layer: int, num_pages: int, n_kv_head: int,
     lose a custom result layout when they are loaded from the compilation
     cache, so that every warm run fails; nor is the head stored 128 wide,
     because every reader of a page, on the device and on the host, would
-    then carry the padding (PERF.md section 6, PR 29)."""
+    then carry the padding (PERF.md section 6, PR 29).
+
+    A third case, a LATENT pool (``latent``: one row a token, ``head_dim``
+    wide, that is neither 64 nor whole lane tiles: 320). Its rows are kept
+    padded to whole 128-lane tiles, ``[L, P, 1, page, 384]``, where the latent
+    kernels run, and that IS the programs' view: they write the row with
+    zeros behind it and pad the query with zeros. Why not 320: compiled for
+    the described v5e, ``bf16[6, P, 1, page, 320]`` comes out with the page
+    INDEX minor-most at page 16 and the page's ROW axis minor-most at page
+    128 (either saves the padding), so no split of the page axis helps once
+    the page itself is a lane tile long; row-major at 320 the tiles would
+    pad every row to 384 lanes anyway, so the bytes are the same 768 a row,
+    and three whole lane tiles are what the kernels' products contract over.
+    A minor dimension of whole tiles is row-major at any length, so
+    :class:`PoolLayoutError` has only that to check. Off the TPU the row
+    stays ``head_dim`` wide."""
     from ..ops.pallas.decode_attention import paged_page_ok
 
     shape = (n_layer, num_pages, n_kv_head, page_size, head_dim)
+    if latent:
+        from ..ops.pallas.latent_attention import latent_attention_ok
+
+        lanes = -(-head_dim // 128) * 128
+        if latent_attention_ok(page_size, lanes, jnp.dtype(dtype).itemsize):
+            return (*shape[:4], lanes)
+        return shape
     if head_dim % 128 == 0 or not paged_page_ok(
         page_size, head_dim, jnp.dtype(dtype).itemsize
     ):
@@ -302,10 +325,13 @@ def init_pools(
     page_size: int,
     head_dim: int,
     dtype: Any = jnp.bfloat16,
+    pools: int = 2,
 ):
     """The shared K and V pools, zeros in :func:`pool_stored_shape`
     (``[L, P, KV, page, D]`` but for a narrow head on a TPU), plus the
-    per-page scales pool — ``(k_pool, v_pool, scales)``.
+    per-page scales pool — ``(k_pool, v_pool, scales)``. ``pools = 1``: a
+    latent family's ONE pool (``n_kv_head`` 1, ``head_dim`` the cached row)
+    and no V pool: ``(pool, None, None)``.
 
     Layout is kernel-native: per layer the pool is ``[P, KV, page, D]``, whose
     trailing ``(page, D)`` dims are exactly one Mosaic block — the paged
@@ -320,8 +346,11 @@ def init_pools(
     rewrites its own. Zero-initialized: a never-written page dequantizes to
     exact zeros. Full-precision pools return ``scales = None``."""
     shape = pool_stored_shape(
-        n_layer, num_pages, n_kv_head, page_size, head_dim, dtype
+        n_layer, num_pages, n_kv_head, page_size, head_dim, dtype,
+        **({"latent": True} if pools == 1 else {}),
     )
+    if pools == 1:
+        return jnp.zeros(shape, dtype), None, None
     scales = (
         jnp.zeros((n_layer, num_pages, n_kv_head, 2), jnp.float32)
         if jnp.dtype(dtype) == jnp.dtype(jnp.int8) else None
@@ -331,12 +360,13 @@ def init_pools(
 
 def pool_bytes(
     n_layer: int, num_pages: int, n_kv_head: int, page_size: int, head_dim: int,
-    itemsize: int = 2,
+    itemsize: int = 2, pools: int = 2,
 ) -> int:
     """HBM footprint of K+V pools (sizing aid for the ``serving`` config);
-    ``itemsize = 1`` for int8 pages. Scales are accounted separately
+    ``itemsize = 1`` for int8 pages, ``pools = 1`` for a latent pool (with
+    ``head_dim`` the row as it is stored). Scales are accounted separately
     (:func:`scales_bytes`) — they are metadata, not page payload."""
-    return 2 * n_layer * num_pages * n_kv_head * page_size * head_dim * itemsize
+    return pools * n_layer * num_pages * n_kv_head * page_size * head_dim * itemsize
 
 
 def scales_bytes(n_layer: int, num_pages: int, n_kv_head: int) -> int:

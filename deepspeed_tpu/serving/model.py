@@ -5,7 +5,7 @@ for the gpt2 family serving is BIT-IDENTICAL to per-request ``generate``.
 The programs hold what every family shares (the pool writes, the paged
 attention, the sampling); the model's module gives the rest through
 ``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
-``models/exaone_moe.ExaoneFamily``):
+``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``):
 
 - :func:`paged_prefill` — one request's prompt (right-padded to the static
   prefill width) through the model, K/V written page-granularly into the
@@ -181,13 +181,25 @@ class Family:
 
     - ``n_layer, n_head, n_kv_head, head_dim, vocab_size, n_positions,
       attn_impl``: geometry.
+    - ``kv_pools, v_width``: what a program needs to know of the cache. 2: a
+      K and a V pool of ``n_kv_head`` heads ``head_dim`` wide (``v_width`` is
+      ``head_dim``). 1: a LATENT family, ONE pool of one row a token
+      (``n_kv_head`` 1, ``head_dim`` the row's width) that every query head
+      reads, whose first ``v_width`` lanes are the values; the programs then
+      get ``v_pool = None``, ``qkv`` gives the absorbed query ``[B,S,H,
+      head_dim]``, the row ``[B,S,1,head_dim]`` and ``None``, ``attn_out``
+      takes ``[B,S,H*v_width]``, the family states its ``sm_scale``, and the
+      whole-prompt program attends per head through ``qkv_expanded(lp, h,
+      positions, l) -> q, k, v, row`` and ``attn_out_expanded``.
     - ``windows``: per layer, how many keys a query reads, itself included
       (a sliding window, whose K/V live in the slot's RING of the window
       pools), or 0: every key before it (K/V paged under the block table).
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers whose ``mlp`` reports
-      the tokens each held expert got, and how many experts that is.
+      the tokens each held expert got, and how many experts that is
+      (``grouped_from``: the rows a call from which the family's expert
+      layer takes the grouped form, 0 for never; ``moe/expert_share.py``).
     - ``embed(params, ids, positions) -> h``
     - ``layer(params, l) -> lp``
     - ``qkv(lp, h, positions, l) -> q [B,S,H,D], k, v [B,S,KV,D]``: the
@@ -233,11 +245,12 @@ def _window_view(slots, pos0, window: int, page: int, ring: int):
     return table, first * page, lo - first * page
 
 
-def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts):
+def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, attn_out=None):
     """The rest of layer ``l`` in every program: the attention output into
-    the residual stream, then the MLP or expert layer, whose held experts'
-    token counts (if it reports any) join ``counts``."""
-    h = h + fam.attn_out(lp, o, tp_axis)
+    the residual stream (through ``attn_out``: the family's, unless a latent
+    family attended per head), then the MLP or expert layer, whose held
+    experts' token counts (if it reports any) join ``counts``."""
+    h = h + (attn_out or fam.attn_out)(lp, o, tp_axis)
     m, c = fam.mlp(lp, h, l, valid, tp_axis)
     if c is not None:
         counts.append(c)
@@ -257,12 +270,59 @@ def _result(k_pool, v_pool, scales, win, token, counts):
     pools, an int8 pool's scales, a window family's ring pools, the token(s)
     and, for a family with expert layers, the tokens each held expert got
     ``[sparse layers, experts_held]``."""
-    out = (k_pool, v_pool)
+    out = (k_pool, v_pool)  # v_pool None: a latent family's (ProgramSet.aot drops it)
     if scales is not None:
         out += (scales,)
     if win is not None:
         out += tuple(win)
     return out + (token,) + ((jnp.stack(counts),) if counts else ())
+
+
+# ---------------------------------------------------------------------------
+# a latent family's one pool (``fam.kv_pools == 1``): the row a token that
+# every head reads. The pool's lanes may be more than the row's (whole lane
+# tiles where the kernels run, ``kv_cache.pool_stored_shape``): the row is
+# written with zeros behind it and the query padded with zeros.
+# ---------------------------------------------------------------------------
+
+def _pad_lanes(x, lanes: int):
+    """``x [..., w]`` with zeros up to ``lanes`` (itself when it has them)."""
+    w = x.shape[-1]
+    return x if w == lanes else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - w)])
+
+
+def _latent_write_pages(pool, l, page_ids, rows):
+    """Whole-page scatter of a prompt chunk's rows ``[1, S, 1, w]`` into
+    layer ``l``'s pages (the write of :func:`_write_pool_pages`, one pool)."""
+    rows = _pad_lanes(rows, pool.shape[-1]).astype(pool.dtype)
+    return pool.at[l, page_ids].set(_page_chunks(rows, pool.shape[3]))
+
+
+def _latent_write_tokens(pool, l, pidx, poff, rows):
+    """``rows [B, 1, w]`` (the decode step) or ``[B, T, 1, w]`` to (layer
+    ``l``, page ``pidx``, offset ``poff``) of the latent pool: one Pallas
+    call where the latent kernels run (``latent_token_write``), else the
+    scatter of :func:`_scatter_tokens`."""
+    from ..ops.pallas.latent_attention import latent_attention_ok, latent_token_write
+
+    rows = _pad_lanes(rows, pool.shape[-1])
+    if latent_attention_ok(pool.shape[3], pool.shape[4], pool.dtype.itemsize):
+        return latent_token_write(pool, l, pidx, poff, rows)
+    at = (l, pidx[..., None], jnp.arange(1), poff[..., None])
+    return pool.at[at].set(rows.astype(pool.dtype))
+
+
+def _attend_latent(fam, q, pool, l, block_tables, base, name):
+    """The absorbed queries ``q [B, T, H, w]`` against layer ``l`` of the
+    (already updated) latent pool → ``[B, T, H * v_width]``."""
+    from ..ops.attention import latent_paged_cached_attention
+
+    B, T, H, _ = q.shape
+    o = latent_paged_cached_attention(
+        _pad_lanes(q, pool.shape[-1]), pool, block_tables, base, fam.v_width,
+        impl=fam.attn_impl, sm_scale=fam.sm_scale, layer=l, name=name,
+    )
+    return o.reshape(B, T, H * fam.v_width).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -275,15 +335,16 @@ def _page_chunks(x, page: int):
     return jnp.swapaxes(x[0].reshape(S // page, page, KV, D), 1, 2)
 
 
-def _attend_prompt_blocked(q, k, v, window: int, block: int):
+def _attend_prompt_blocked(q, k, v, window: int, block: int, sm_scale=None):
     """Causal attention of a whole prompt chunk, ``block`` query rows at a
     time, so that the scores alive are ``[H, block, keys]``: all ``Sp`` keys
     on a full layer, a band of ``block + window`` on a window layer. q ``[1,
-    Sp, H, D]``, k / v ``[1, Sp, KV, D]`` → ``[1, Sp, H * D]``."""
+    Sp, H, D]``, k / v ``[1, Sp, KV, D]`` (v may be another width) → ``[1,
+    Sp, H * Dv]``."""
     _, Sp, H, D = q.shape
     KV = k.shape[2]
     rep = H // KV
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if sm_scale is None else sm_scale
     pad = window if window else 0
     span = block + pad if window else Sp
     kp = jnp.pad(k[0], ((pad, 0), (0, 0), (0, 0)))
@@ -302,9 +363,9 @@ def _attend_prompt_blocked(q, k, v, window: int, block: int):
             seen = seen & (kpos > qpos - window)
         p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1).astype(vi.dtype)
         o = jnp.einsum("grst,tgd->sgrd", p, vi, preferred_element_type=jnp.float32)
-        return o.reshape(block, H * D).astype(q.dtype)
+        return o.reshape(block, H * v.shape[-1]).astype(q.dtype)
 
-    return lax.map(rows, jnp.arange(Sp // block)).reshape(1, Sp, H * D)
+    return lax.map(rows, jnp.arange(Sp // block)).reshape(1, Sp, H * v.shape[-1])
 
 
 def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
@@ -407,6 +468,18 @@ def paged_prefill(
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
+        if fam.kv_pools == 1:
+            # a latent family: the row into the one pool, attention per head
+            # (expanded) in query blocks, its output straight into ``wo``
+            q, k_, v, row = fam.qkv_expanded(lp, h, positions, l)
+            k_pool = _latent_write_pages(k_pool, li, page_ids, row)
+            o = _attend_prompt_blocked(
+                q, k_, v, 0, math.gcd(Sp, fam.prefill_block), fam.sm_scale
+            )
+            h = _after_attention(
+                fam, lp, h, o, l, valid, tp_axis, counts, fam.attn_out_expanded
+            )
+            continue
         q, k_, v = fam.qkv(lp, h, positions, l)
         if windowed:
             o, win = _attention_prefill_window(
@@ -576,7 +649,10 @@ def paged_decode_step(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         q, k_, v = fam.qkv(lp, h, positions, l)
-        if windowed:
+        if fam.kv_pools == 1:
+            k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_[:, 0])
+            o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_decode")
+        elif windowed:
             o, win = _attention_step_window(
                 fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
             )
@@ -774,7 +850,12 @@ def paged_verify_step(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         q, k_, v = fam.qkv(lp, h, positions, l)
-        if windowed:
+        if fam.kv_pools == 1:
+            # one batched call: a latent family holds no bit-for-bit contract
+            # with a per-request generate for T single-token calls to keep
+            k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_)
+            o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_verify")
+        elif windowed:
             o, win = _attention_step_window(
                 fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
             )
@@ -846,7 +927,10 @@ def paged_chunk_prefill(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         q, k_, v = fam.qkv(lp, h, positions, l)
-        if windowed:
+        if fam.kv_pools == 1:
+            k_pool = _latent_write_pages(k_pool, li, page_ids, k_)
+            o = _attend_latent(fam, q, k_pool, li, block_tables, base, "mla_paged_chunk")
+        elif windowed:
             k_win, v_win = win
             k_win = k_win.at[li, ring_ids].set(_page_chunks(k_, page).astype(k_win.dtype))
             v_win = v_win.at[li, ring_ids].set(_page_chunks(v, page).astype(v_win.dtype))

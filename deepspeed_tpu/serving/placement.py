@@ -304,7 +304,14 @@ class ProgramSet:
     compiled through :meth:`aot` works on the ``[L, P, KV, page, D]`` views,
     the host reads a page through :meth:`page_column`, and the verifiers get
     the stored per-device dims from :meth:`local_pool_dims`. Payloads of
-    page columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always."""
+    page columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always.
+
+    A LATENT family (``fam.kv_pools == 1``) has ONE pool and no V pool:
+    ``k_pool`` is ``[L, P, 1, page, W]`` with ``W`` (``head_dim`` here) the
+    cached row as it is stored, ``v_pool`` is ``None``, and a program written
+    ``fn(params, k_pool, v_pool, ...)`` is handed ``None`` for the second."""
+
+    kv_pools = 2  # a K and a V pool; 1: a latent family's one pool
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
                  page_size: int, cache_dtype, params: PyTree,
@@ -317,14 +324,15 @@ class ProgramSet:
         # window layer's K/V live in the ring pools below
         self.n_layer = sum(1 for w in fam.windows if not w)
         self.n_kv_head = int(fam.n_kv_head)
-        self.head_dim = int(fam.head_dim)
+        self.kv_pools = int(fam.kv_pools)
         k, v, scales = init_pools(
             self.n_layer, self.num_pages, self.n_kv_head, self.page_size,
-            self.head_dim, dtype=cache_dtype,
+            int(fam.head_dim), dtype=cache_dtype, pools=self.kv_pools,
         )
+        self.head_dim = int(k.shape[-1])  # a latent row as it is stored
         self._kv_axis = k.ndim - 3  # [..., KV, page, D], however P is stored
         self.k_pool = placement.put_pool(k, self._kv_axis)
-        self.v_pool = placement.put_pool(v, self._kv_axis)
+        self.v_pool = placement.put_pool(v, self._kv_axis) if v is not None else None
         self.kv_scales = placement.put_pool(scales) if scales is not None else None
         # window layers: ``ring_pages`` pages statically owned by each of
         # ``ring_slots`` slots, after a scratch page; no allocator, and the
@@ -353,9 +361,10 @@ class ProgramSet:
         return self.kv_scales is not None
 
     def pool_args(self) -> tuple:
-        """The donated pool operands, in program order: K, V, an int8 pool's
-        scales, a window family's two ring pools."""
-        out = (self.k_pool, self.v_pool)
+        """The donated pool operands, in program order: K, V (a latent
+        family: its one pool), an int8 pool's scales, a window family's two
+        ring pools."""
+        out = (self.k_pool,) + ((self.v_pool,) if self.v_pool is not None else ())
         if self.kv_scales is not None:
             out += (self.kv_scales,)
         return out + (self.window_pools or ())
@@ -365,10 +374,10 @@ class ProgramSet:
         row-major on the device (``kv_cache.pool_stored_shape`` arranges it
         by shape alone; the compiler has the last word)."""
         from ..ops.pallas.decode_attention import paged_page_ok
+        from ..ops.pallas.latent_attention import latent_attention_ok
 
-        if not paged_page_ok(
-            self.page_size, self.head_dim, self.k_pool.dtype.itemsize
-        ):
+        ok = latent_attention_ok if self.kv_pools == 1 else paged_page_ok
+        if not ok(self.page_size, self.head_dim, self.k_pool.dtype.itemsize):
             return
         got = tuple(self.k_pool.format.layout.major_to_minor)
         if got != tuple(range(self.k_pool.ndim)):
@@ -385,7 +394,7 @@ class ProgramSet:
     def pool_specs(self) -> tuple:
         """One ``PartitionSpec`` per :meth:`pool_args` operand."""
         kv = self.placement.pool_spec(self.k_pool.ndim, self._kv_axis)
-        out = (kv, kv)
+        out = (kv,) * self.kv_pools
         if self.kv_scales is not None:
             out += (self.placement.pool_spec(self.kv_scales.ndim),)
         return out + tuple(
@@ -409,8 +418,9 @@ class ProgramSet:
         plc = self.placement
         first = int(with_params)
         pools = self.pool_args()
+        one_pool = self.kv_pools == 1
         # the K/V-shaped pools (all but the scales), which fn sees as views
-        kv_like = [first, first + 1] + (
+        kv_like = list(range(first, first + self.kv_pools)) + (
             [first + len(pools) - 2, first + len(pools) - 1]
             if self.window_pools else []
         )
@@ -421,10 +431,14 @@ class ProgramSet:
             stored = {i: args[i].shape for i in kv_like}  # per device under shard_map
             for i in kv_like:
                 args[i] = pool_view(args[i])
+            if one_pool:
+                args.insert(first + 1, None)  # fn's v_pool: there is none
             out = fn(*args)
             if not returns_pools:
                 return out
             out = list(out)
+            if one_pool:
+                del out[1]
             for i in kv_like:
                 out[i - first] = out[i - first].reshape(stored[i])
             return tuple(out)
@@ -466,7 +480,7 @@ class ProgramSet:
             int(pid), self.k_pool.shape[1:self._kv_axis]
         ))
         return (
-            self.k_pool[at], self.v_pool[at],
+            self.k_pool[at], self.v_pool[at] if self.v_pool is not None else None,
             self.kv_scales[:, pid] if self.kv_scales is not None else None,
         )
 
@@ -496,8 +510,10 @@ class ProgramSet:
         """Rehome the donated pools from a program's output tuple and
         return the rest (single element unwrapped, like the scheduler's
         original helper)."""
-        self.k_pool, self.v_pool = out[0], out[1]
-        rest = out[2:]
+        self.k_pool = out[0]
+        if self.kv_pools == 2:
+            self.v_pool = out[1]
+        rest = out[self.kv_pools:]
         if self.kv_scales is not None:
             self.kv_scales = rest[0]
             rest = rest[1:]
@@ -571,10 +587,11 @@ class ProgramSet:
         return f"{self.n_layer},{int(n_pages)},{self.local_kv_heads()},2"
 
     def local_pool_bytes(self) -> int:
-        """Per-device K+V pool bytes of this placement."""
+        """Per-device K+V pool bytes of this placement (a latent family: of
+        its one pool, rows as they are stored)."""
         itemsize = jnp.dtype(self.k_pool.dtype).itemsize
         return (
-            2 * self.n_layer * self.num_pages * self.local_kv_heads()
+            self.kv_pools * self.n_layer * self.num_pages * self.local_kv_heads()
             * self.page_size * self.head_dim * itemsize
         )
 

@@ -208,7 +208,7 @@ class ServingEngine:
             raise ValueError(
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
-                "including injected HF GPT-2, and exaone_moe); got "
+                "including injected HF GPT-2, exaone_moe and mistral4); got "
                 f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
@@ -217,7 +217,10 @@ class ServingEngine:
         # paged pools and a ring a slot); what moves, shares, shards or
         # re-codes pages knows the first kind only (ROADMAP.md, queue R)
         self.windowed = any(fam.windows)
-        if self.windowed:
+        # a latent family keeps ONE pool of one row a token that all heads
+        # share: the same mechanisms know K and V pools of per-head pages only
+        self.latent = fam.kv_pools == 1
+        if self.windowed or self.latent:
             plc_ = getattr(config, "placement", None)
             for on, what in (
                 (getattr(getattr(config, "prefix_cache", None), "enabled", False),
@@ -237,9 +240,16 @@ class ServingEngine:
                 if on:
                     raise ValueError(
                         f"{what} is not available for a model with "
-                        f"sliding-window layers ({type(mcfg).__name__}): a "
-                        "window layer's KV lives in a per-slot ring beside "
-                        "the paged pool, which this mechanism does not handle"
+                        + (
+                            f"sliding-window layers ({type(mcfg).__name__}): a "
+                            "window layer's KV lives in a per-slot ring beside "
+                            "the paged pool, which this mechanism does not handle"
+                            if self.windowed else
+                            f"a latent KV pool ({type(mcfg).__name__}): its "
+                            "cache is one pool of one row a token that every "
+                            "head reads, with no V pool and no head axis, which "
+                            "this mechanism does not handle"
+                        )
                     )
 
         page = int(config.page_size)
@@ -276,6 +286,12 @@ class ServingEngine:
             self.chunk_width = 0
         if self.chunk_width > self.prefill_width:
             self.chunk_width = self.prefill_width
+        # pair rows one chunk call hands the expert layers' grouped products
+        # (0: its rows take the masked form; moe/expert_share.py)
+        from ..moe.expert_share import grouped_rows
+        self._moe_rows_grouped = len(fam.sparse_layers) * grouped_rows(
+            self.chunk_width, fam.experts_per_token, fam.grouped_from
+        )
         # a window layer's ring: the window before a program's first query,
         # the tokens one call writes (a chunk, a verify step's drafts, one
         # token) and a page of slack for where in a page the window starts
@@ -627,10 +643,17 @@ class ServingEngine:
         self._g_kv_bytes = m.gauge(
             "serving_kv_bytes",
             "K+V bytes held on the device by class: paged (the pools under "
-            "the block tables, the layers that read their whole context) and "
-            "window (the per-slot rings of the sliding-window layers, which "
-            "do not grow with context)",
+            "the block tables, the layers that read their whole context), "
+            "latent (a latent family's one pool under the block tables, in "
+            "paged's place) and window (the per-slot rings of the "
+            "sliding-window layers, which do not grow with context)",
             labelnames=("class",),
+        )
+        self._g_kv_row_bytes = m.gauge(
+            "serving_kv_row_bytes",
+            "bytes one token costs one layer of the paged cache as it is "
+            "stored (K and V of every kv-head, or a latent family's one row "
+            "with its lane padding)",
         )
         self._g_ring_pages = m.gauge(
             "serving_window_pages_per_slot",
@@ -797,7 +820,7 @@ class ServingEngine:
         ds = self.decode_set
         page_b = pool_bytes(
             ds.n_layer, 1, ds.n_kv_head, self.page_size, ds.head_dim,
-            np.dtype(self.cache_dtype).itemsize,
+            np.dtype(self.cache_dtype).itemsize, pools=ds.kv_pools,
         )
         now = self.clock()
         alloc = self.decode_set.allocator
@@ -1192,7 +1215,10 @@ class ServingEngine:
         (returned) as the attrs of the ``ds.init.programs`` phase:
         ``relayout_ops`` / ``temp_bytes`` / ``grid_steps`` keyed
         ``<program>=<n>``."""
-        from ..ops.attention import paged_attention_grid_steps
+        from ..ops.attention import (
+            latent_attention_grid_steps,
+            paged_attention_grid_steps,
+        )
 
         # (slots, query tokens a slot) of a program's attention kernel call;
         # the verify step attends as k+1 single-token calls
@@ -1208,7 +1234,11 @@ class ServingEngine:
             steps[name] = 0
             if rec["kind"] in shapes:
                 B, T = shapes[rec["kind"]]
-                steps[name] = paged_attention_grid_steps(
+                steps[name] = latent_attention_grid_steps(
+                    self.model_config.attn_impl, B, self.family.n_head,
+                    pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
+                    self.pages_per_slot, T or 1,
+                ) if self.latent else paged_attention_grid_steps(
                     self.model_config.attn_impl, B, pset.local_kv_heads(),
                     pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
                     self.pages_per_slot, T,
@@ -1219,11 +1249,17 @@ class ServingEngine:
             self._g_grid_steps.set(steps[name], program=name)
         ds = self.decode_set
         kv_bytes = {
-            "paged": ds.local_pool_bytes() * ds.placement.tp,
+            "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
             "window": ds.window_pool_bytes(),
         }
         for cls, n in kv_bytes.items():
             self._g_kv_bytes.set(n, **{"class": cls})
+        # what one token costs one layer of the cache, as it is stored
+        row_bytes = (
+            ds.kv_pools * ds.n_kv_head * ds.head_dim
+            * jnp.dtype(ds.k_pool.dtype).itemsize
+        )
+        self._g_kv_row_bytes.set(row_bytes)
         self._g_ring_pages.set(self.ring_pages)
         self._g_experts_held.set(self.family.experts_held)
         attrs = {
@@ -1232,7 +1268,8 @@ class ServingEngine:
                              ("grid_steps", steps), ("kv_bytes", kv_bytes))
         }
         attrs.update(window_pages_per_slot=self.ring_pages,
-                     moe_experts_held=self.family.experts_held)
+                     moe_experts_held=self.family.experts_held,
+                     kv_row_bytes=row_bytes)
         return attrs
 
     def _moe_attrs(self, counts: np.ndarray, n_tokens: int) -> dict:
@@ -1506,15 +1543,21 @@ class ServingEngine:
         ]
         if pre:
             with spans.span("ds.serve.chunk", chunks=len(pre)) as sp:
-                n_tok = 0
+                n_tok = attended = 0
                 moe = []   # (counts, tokens) of the prompts that finished here
                 for i in pre:
                     s = self.slots[i]
-                    n_tok += min(self.chunk_width, s.request.prompt_len - s.prefill_pos)
+                    t = min(self.chunk_width, s.request.prompt_len - s.prefill_pos)
+                    n_tok += t
+                    # key rows the call's queries read: the context before the
+                    # chunk for each of them, and the causal triangle inside it
+                    attended += t * s.prefill_pos + t * (t + 1) // 2
                     got = self._advance_chunk(i)
                     if got is not None:
                         moe.append(got)
-                sp.set(tokens=n_tok)
+                sp.set(tokens=n_tok, attended=attended)
+                if self._moe_rows_grouped:
+                    sp.set(moe_rows_grouped=len(pre) * self._moe_rows_grouped)
                 if moe:
                     # a prompt's chunk calls report with its last one, whose
                     # token fetch is the one wait there is
@@ -2520,6 +2563,11 @@ class ServingEngine:
                 "sliding-window layers: the transport moves a slot's paged "
                 "row, and its window rings would stay behind"
             )
+        if self.latent:
+            raise ValueError(
+                "session migration is not available for a model with a latent "
+                "KV pool: the transport packs a K and a V pool's page columns"
+            )
         self._ensure_compiled()
         S = jax.ShapeDtypeStruct
         i32 = jnp.int32
@@ -3123,7 +3171,7 @@ class ServingEngine:
         out["kv_pool_bytes"] = pool_bytes(
             ds.n_layer, int(self.config.num_pages), ds.n_kv_head,
             self.page_size, ds.head_dim,
-            np.dtype(self.cache_dtype).itemsize,
+            np.dtype(self.cache_dtype).itemsize, pools=ds.kv_pools,
         )
         out["kv_window_bytes"] = ds.window_pool_bytes()
         out["kv_scales_bytes"] = (

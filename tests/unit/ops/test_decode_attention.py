@@ -901,3 +901,72 @@ class TestPagedMultitokenBlocks:
 
         with pytest.raises(ValueError, match="unknown attention impl"):
             paged_attention_grid_steps("flash", 1, 25, 16, 64, 2, 64, 128)
+
+
+# -- the latent (MLA) paged kernels against their jnp fallbacks (interpret mode) --
+
+class TestLatentPaged:
+    L, P, page, W, VW, H = 2, 40, 8, 128, 64, 4
+
+    def _pool(self, seed=0):
+        rng = np.random.default_rng(seed)
+        return jnp.asarray(rng.normal(size=(self.L, self.P, 1, self.page, self.W)), jnp.float32), rng
+
+    @pytest.mark.parametrize("B,T,n", [(3, 1, 6), (2, 8, 6), (1, 16, 9), (2, 64, 12)],
+                             ids=["decode", "verify-shape", "chunk", "chunk-two-query-blocks"])
+    def test_attention_kernel_equals_the_fallback(self, B, T, n, monkeypatch):
+        from jax.experimental.pallas import tpu as pltpu
+
+        from deepspeed_tpu.ops.attention import latent_paged_cached_attention
+        from deepspeed_tpu.ops.pallas import latent_attention as la
+
+        if T == 64:
+            monkeypatch.setattr(la, "CHUNK_ROWS", 128)     # 32 tokens x 4 heads a step: two query blocks
+            assert la.latent_blocks(self.H, self.page, T, n)[0] == 32
+        pool, rng = self._pool()
+        bt = jnp.asarray(rng.permutation(np.arange(1, self.P))[: B * n].reshape(B, n), jnp.int32)
+        base = jnp.asarray(rng.integers(0, n * self.page - T, B), jnp.int32)
+        q = jnp.asarray(rng.normal(size=(B, T, self.H, self.W)), jnp.float32)
+        want = latent_paged_cached_attention(q, pool, bt, base, self.VW, impl="jnp", sm_scale=0.2, layer=1)
+        with pltpu.force_tpu_interpret_mode():
+            got = la.latent_paged_attention(q, pool, bt, base, self.VW, 0.2, layer=1)
+        assert got.shape == (B, T, self.H, self.VW)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-6, rtol=1e-5)
+
+    def test_the_values_are_the_rows_leading_lanes_and_padding_lanes_change_nothing(self):
+        from deepspeed_tpu.ops.attention import latent_paged_cached_attention
+
+        pool, rng = self._pool(1)
+        bt = jnp.asarray(rng.permutation(np.arange(1, self.P))[:6].reshape(1, 6), jnp.int32)
+        base = jnp.asarray([20], jnp.int32)
+        q = jnp.asarray(rng.normal(size=(1, 4, self.H, self.W)), jnp.float32)
+        a = latent_paged_cached_attention(q, pool, bt, base, self.VW, impl="jnp", sm_scale=0.2, layer=0)
+        # zero lanes behind the row on both sides (the stored row's lane padding)
+        pad = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, 32)])
+        b = latent_paged_cached_attention(pad(q), pad(pool), bt, base, self.VW, impl="jnp", sm_scale=0.2, layer=0)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+        # a dense softmax over the slot's first base + t + 1 rows
+        rows = np.asarray(pool[0])[np.asarray(bt[0])].reshape(-1, self.W)
+        for t in range(4):
+            s = np.einsum("hw,sw->hs", np.asarray(q[0, t]), rows[: 21 + t]) * 0.2
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want = (p / p.sum(-1, keepdims=True)) @ rows[: 21 + t, : self.VW]
+            np.testing.assert_allclose(np.asarray(a[0, t]), want, atol=2e-5, rtol=1e-4)
+
+    @pytest.mark.parametrize("T", [1, 3])
+    def test_token_write_equals_the_scatter(self, T):
+        from jax.experimental.pallas import tpu as pltpu
+
+        from deepspeed_tpu.ops.pallas.latent_attention import latent_token_write
+
+        pool, rng = self._pool(2)
+        B = 3
+        pos = jnp.asarray(rng.integers(0, 5 * self.page - T, B)[:, None] + np.arange(T)[None], jnp.int32)
+        pages = jnp.asarray(rng.permutation(np.arange(1, self.P))[: B * 5].reshape(B, 5), jnp.int32)
+        pidx, poff = jnp.take_along_axis(pages, pos // self.page, axis=1), pos % self.page
+        rows = jnp.asarray(rng.normal(size=(B, T, 1, self.W)), jnp.float32)
+        want = pool.at[1, pidx, 0, poff].set(rows[:, :, 0])
+        args = (pidx[:, 0], poff[:, 0], rows[:, 0]) if T == 1 else (pidx, poff, rows)
+        with pltpu.force_tpu_interpret_mode():
+            got = latent_token_write(pool, 1, *args)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

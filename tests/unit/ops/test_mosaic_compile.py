@@ -406,3 +406,123 @@ def test_flash_kernels_compile_for_v5e_under_their_plan(one_chip, name, mode):
                     (bwd, [q, kv, kv, q, sds((BH, S, fa.NUM_LANES), jnp.float32), q])):
         compiled = jax.jit(f).lower(*args, *win).compile()
         assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+# -- the latent (MLA) family: kernels, pool layout and programs at the served size --
+
+def _ms4_config():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    with open(os.path.join(root, "perfbench", "configs", "mistral-small-4-119b-ep8-serve-1chip.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("B,T", [(48, 1), (1, 1024)], ids=["decode", "chunk"])
+def test_latent_kernels_compile_for_v5e_at_the_served_shapes(one_chip, B, T):
+    from deepspeed_tpu.ops.pallas.latent_attention import latent_paged_attention, latent_token_write
+
+    c = _ms4_config()
+    sv = c["serving"]
+    L, P, page, W = c["num_hidden_layers"], sv["num_pages"], sv["page_size"], 384
+    n = -(-(sv["max_prompt_len"] + sv["max_new_tokens"]) // page)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((L, P, 1, page, W), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, p, bt, base: latent_paged_attention(q, p, bt, base, 256, 0.195, layer=3, name="mla")
+    ).lower(sds((B, T, 32, W), jnp.bfloat16), pool, sds((B, n), jnp.int32), sds((B,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    if T == 1:
+        compiled = jax.jit(
+            lambda p, pidx, poff, rows: latent_token_write(p, 3, pidx, poff, rows), donate_argnums=(0,)
+        ).lower(pool, sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, 1, W), jnp.bfloat16)).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6      # the pool is written in place
+
+
+def test_a_latent_pool_is_row_major_only_with_whole_lane_tiles_a_row(one_chip, monkeypatch):
+    """What ``pool_stored_shape``'s third case rests on: at 320 lanes the
+    default layout moves the page index (page 16) or the page's row axis
+    (page 128) minor-most; at 384 it is row-major."""
+    from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+
+    assert _default_layout(one_chip, (6, 9313, 1, 128, 384), jnp.bfloat16) == (0, 1, 2, 3, 4)
+    assert _default_layout(one_chip, (6, 9313, 1, 128, 320), jnp.bfloat16) == (0, 1, 2, 4, 3)
+    assert _default_layout(one_chip, (6, 74497, 1, 16, 320), jnp.bfloat16) == (0, 2, 3, 4, 1)
+    assert pool_stored_shape(6, 9313, 1, 128, 320, jnp.bfloat16, latent=True) == (6, 9313, 1, 128, 320)  # off the TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pool_stored_shape(6, 9313, 1, 128, 320, jnp.bfloat16, latent=True) == (6, 9313, 1, 128, 384)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+def test_latent_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
+    """The ``mistral4`` programs as the long-document cell serves them (48
+    slots, 32 query heads on one 384-lane latent row a token, 128-token pages,
+    a 24 576-token whole-prompt width, 16 held experts of 128, 5.75 GB of bf16
+    weights as shapes): the latent kernels and the one-pool token write pass
+    Mosaic, nothing re-lays the pool out, the whole-prompt program's blocked
+    attention and blocked expert products keep its temps beside the pool, and
+    arguments + temps fit the chip."""
+    from deepspeed_tpu.models import mistral4
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    c = _ms4_config()
+    cfg = mistral4.Mistral4Config.from_dict(c)
+    sv = c["serving"]
+    B, page, P, Sp, C = (sv[k] for k in ("max_slots", "page_size", "num_pages", "max_prompt_len", "prefill_chunk_tokens"))
+    W = -(-(Sp + sv["max_new_tokens"]) // page)
+    L = cfg.n_layer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: mistral4.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    assert 5.7e9 < 2 * sum(x.size for x in jax.tree.leaves(params)) < 5.8e9
+    shape = (L, P, 1, page, 384)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16))
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "chunk": (
+            lambda p, k, v, ids, start, plen, pages, bt, key: smodel.paged_chunk_prefill(
+                cfg, p, ids, start, plen, k, v, pages, bt, key),
+            (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
+        "prefill": (
+            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
+        ),
+    }[program]
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
+        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=384, n_layer=L,
+    )
+    compiled = pset.aot(fn, host, with_params=True)
+    text = compiled.as_text()
+    assert pset.program_census(program, compiled)[0] == 0  # or it raises
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    # an attention kernel a layer, and in the decode step a token write a layer;
+    # the grouped expert products are the compiler's own ragged-dot calls
+    want = {"decode": 2 * L, "chunk": L, "prefill": 0}[program]
+    ragged = text.count("ragged-dot") > 0
+    assert ragged == (program != "decode")
+    if program == "decode":
+        assert calls == want
+    took_in, _ = compiled.input_formats
+    assert took_in[1].layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].layout.major_to_minor
+    mem = compiled.memory_analysis()
+    print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16

@@ -110,3 +110,56 @@ def test_counts_leave_out_the_rows_that_are_no_tokens(u):
     _, first10 = es.expert_share_layer(_slice(lp, share), u[:10], share, K, SCALE)
     assert some.tolist() == first10.tolist() and int(some.sum()) < int(all_rows.sum())
     assert y.shape == (T, E)          # the rows are still computed: a mask on the count, not on the work
+
+
+# -- the grouped form: the same sum over the sorted pairs -----------------------
+
+@pytest.mark.parametrize("chips,index", [(1, 0), (4, 1), (8, 7)])
+def test_the_grouped_product_equals_the_masked_one(u, chips, index):
+    lp = _layer()
+    share = es.ExpertShare(N, chips, index)
+    ex = _slice(lp, share)["experts"]
+    idx, w = es.route(u, lp["router"], lp["bias"], K, SCALE)
+    masked = es.held_experts(u, es.held_weights(idx, w, share), ex["w_gate"], ex["w_up"], ex["w_down"])
+    grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
+
+
+def test_the_grouped_product_drops_no_held_pair_under_the_most_uneven_routing(u):
+    """Every token selects the same K experts, all held here: the whole
+    static pair budget T x K is in the groups, none behind them. And with
+    none held, every pair sorts behind the groups and the routed part is 0."""
+    lp = _layer()
+    lp = dict(lp, bias=jnp.zeros(N).at[jnp.array([4, 5, 6, 7])].set(100.0))
+    idx, w = es.route(u, lp["router"], lp["bias"], K, SCALE)
+    for index, full in ((1, True), (2, False)):
+        share = es.ExpertShare(N, 4, index)
+        ex = _slice(lp, share)["experts"]
+        masked = es.held_experts(u, es.held_weights(idx, w, share), ex["w_gate"], ex["w_up"], ex["w_down"])
+        grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
+        np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
+        assert (float(jnp.abs(grouped).max()) > 0) == full
+
+
+def test_the_layer_switches_form_by_its_static_row_count(monkeypatch):
+    """Many rows take the grouped form (and a whole-prompt program's rows go
+    through in blocks), few the masked one; the layer's result and counts are
+    the same either way."""
+    lp = _layer()
+    share = es.ExpertShare(N, 4, 1)
+    big = jax.random.normal(jax.random.PRNGKey(3), (64, E), jnp.float32)
+    want_y, want_c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
+    n = es.GROUPED_MIN_ROWS
+    assert es.grouped_rows(64, K, n) == 0 and es.grouped_rows(n, K, n) == n * K and es.grouped_rows(8 * n, K, 0) == 0
+    calls = []
+    real = es.held_experts_grouped
+    monkeypatch.setattr(es, "held_experts_grouped", lambda *a: calls.append(a[0].shape) or real(*a))
+    es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
+    assert calls == []                 # a family that does not ask keeps every call masked
+    for block in (4096, 16):           # in one piece, then four blocks of 16 rows
+        monkeypatch.setattr(es, "GROUPED_BLOCK_ROWS", block)
+        calls.clear()
+        y, c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE, grouped_from=16)
+        assert calls == [(64 if block == 4096 else 16, E)]
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=2e-5, atol=2e-6)
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(want_c))

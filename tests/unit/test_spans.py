@@ -153,7 +153,7 @@ def test_leaves_tile_each_serve_step_and_carry_the_documented_attrs(served):
         ratios.append(sum(c[2] - c[1] for c in top) / (st[2] - st[1]))
         assert set(st[3]) == {"step", "queue", "active"}
     assert statistics.median(ratios) > 0.95
-    want = {"ds.serve.admit": {"admitted", "blocked"}, "ds.serve.chunk": {"chunks", "tokens"},
+    want = {"ds.serve.admit": {"admitted", "blocked"}, "ds.serve.chunk": {"chunks", "tokens", "attended"},
             "ds.serve.decode.dispatch": {"active", "attended", "pages"}, "ds.serve.decode.wait": set(),
             "ds.serve.emit": {"tokens", "finished"}, "ds.serve.housekeep": set(),
             "ds.serve.prefill.wait": set(), "ds.serve.chunk.wait": set()}
@@ -198,7 +198,7 @@ def test_serving_setup_is_recorded_as_phases_that_name_their_programs(served):
     # and the census of the compiled programs (ISSUE 29): <program>=<n> each
     # and, since ISSUE 31, the grid steps of one call of its paged attention kernel
     assert set(progs[0][3]) == {"what", "relayout_ops", "temp_bytes", "grid_steps",
-                                "kv_bytes", "window_pages_per_slot", "moe_experts_held"}
+                                "kv_bytes", "window_pages_per_slot", "moe_experts_held", "kv_row_bytes"}
     # GPT-2: every layer's KV is paged, no window ring, no expert layer
     assert progs[0][3]["kv_bytes"].endswith("window=0") and progs[0][3]["kv_bytes"].startswith("paged=")
     assert progs[0][3]["window_pages_per_slot"] == 0 and progs[0][3]["moe_experts_held"] == 0
