@@ -65,7 +65,8 @@ DEFAULT_HOT_PATTERNS = [
     # ISSUE 10: chunked prefill runs once per scheduler step while a slot
     # prefills, and _start_decoding is the per-admission transition _admit
     # used to carry — both stay under the hot-path lint
-    "ServingEngine._advance_chunk", "ServingEngine._start_decoding",
+    "ServingEngine._advance_chunks", "ServingEngine._launch_chunk",
+    "ServingEngine._launch_alone", "ServingEngine._start_decoding",
     "ServingEngine._draft", "ServingEngine._accept_tokens",
     "*.train_batch", "*._train_batch", "*._print_cadence", "*.eval_batch",
     "*._telemetry_step", "*._watchdog_step",

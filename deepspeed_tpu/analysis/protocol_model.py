@@ -744,10 +744,10 @@ class ProtocolMonitor:
         if self._undo_hook is not None:
             return
         srv = self.srv
-        orig = srv._advance_chunk
+        orig = srv._launch_chunk
         page = srv.page_size
 
-        def advance(slot_i):
+        def launch(slot_i, rows):
             slot = srv.slots[slot_i]
             req = slot.request
             if req is not None and slot.row is not None:
@@ -763,12 +763,12 @@ class ProtocolMonitor:
                         int(slot.row[0, pi]),
                         f"chunk prefill slot {slot_i}",
                     )
-            return orig(slot_i)
+            return orig(slot_i, rows)
 
-        srv._advance_chunk = advance
+        srv._launch_chunk = launch
 
         def undo():
-            srv._advance_chunk = orig
+            srv._launch_chunk = orig
 
         self._undo_hook = undo
 

@@ -141,6 +141,7 @@ def _paged_kernel_taken(impl: str, ok) -> bool:
 def paged_cached_attention(
     q, k_pool, v_pool, block_tables, pos, impl: str = "auto",
     sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
+    name: Optional[str] = None,
 ):
     """Single-token decode attention against a PAGED KV cache (the serving
     subsystem's layout): q [B,H,D], pools [P,KV,page,D] (KV == H or
@@ -152,6 +153,8 @@ def paged_cached_attention(
     itself (no ``pool[l]`` slice for XLA to materialise), the fallback
     slices it. ``lo`` [B] i32 bounds the keys from below (a sliding window:
     ``lo[b] <= key <= pos[b]``, both counted from the table's first key).
+    ``name`` is the kernel call's name in a trace (else that of the jitted
+    function that holds it).
 
     Dispatch mirrors :func:`cached_attention`: the Pallas paged kernel on TPU
     (the block-table gather IS the kernel's index maps — no dense copy, no
@@ -180,7 +183,7 @@ def paged_cached_attention(
     )):
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-            scales=scales, layer=layer, lo=lo,
+            scales=scales, layer=layer, lo=lo, name=name,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -208,6 +211,7 @@ def paged_cached_attention(
 def paged_multitoken_cached_attention(
     q, k_pool, v_pool, block_tables, base, impl: str = "auto",
     sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
+    name: Optional[str] = None,
 ):
     """T-token causal decode attention against a PAGED KV cache (ISSUE 10:
     the speculative verify step and chunked prefill): q [B,T,H,D], pools
@@ -242,7 +246,7 @@ def paged_multitoken_cached_attention(
     )):
         return paged_multitoken_attention(
             q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-            scales=scales, layer=layer, lo=lo,
+            scales=scales, layer=layer, lo=lo, name=name,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]

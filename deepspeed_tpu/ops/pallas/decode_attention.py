@@ -216,20 +216,29 @@ def _pool_dims(pool, layer: Optional[int]):
     return pool.shape[-3], pool.shape[-2]
 
 
-def _pool_block_spec(block, index_map, layer: Optional[int]):
+def _pool_block_spec(block, index_map, layer):
     """The BlockSpec of one pool page block. With ``layer`` the pool is the
     whole ``[L, P, KV, page, D]`` array and the layer a squeezed leading block
-    index, so the kernel body sees the same ``[1, HB, page, D]`` ref."""
+    index, so the kernel body sees the same ``[1, HB, page, D]`` ref. A
+    ``layer`` that is not a Python int is an operand: the call's LAST
+    scalar-prefetched one (``[1]`` i32), read here."""
     if layer is None:
         return pl.BlockSpec(block, index_map)
-    return pl.BlockSpec(
-        (None, *block), lambda *a: (layer, *index_map(*a))
-    )
+    if isinstance(layer, int):
+        return pl.BlockSpec((None, *block), lambda *a: (layer, *index_map(*a)))
+    return pl.BlockSpec((None, *block), lambda *a: (a[-1][0], *index_map(*a)))
+
+
+def _layer_operand(layer) -> tuple:
+    """The scalar-prefetch operand of a ``layer`` that is an operand."""
+    if layer is None or isinstance(layer, int):
+        return ()
+    return (jnp.asarray(layer, jnp.int32).reshape(1),)
 
 
 def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
                   nhb: int, T: int = 1, rep: int = 1, quantized: bool = False,
-                  windowed: bool = False):
+                  windowed: bool = False, layer_operand: bool = False):
     """Online-softmax accumulation over one slot's pages for ``T`` query
     tokens of the slot (1: the decode step; more: chunked prefill and the
     verify shape), ``G`` pages and ``HB`` kv-heads (all of them, unless a
@@ -269,10 +278,15 @@ def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
     ``windowed``: a third prefetched operand ``lo`` ([B]) bounds the keys from
     below: query ``t`` attends keys ``lo[b] + t <= key <= at[b] + t`` (a
     sliding window; the caller's table starts at the page that holds
-    ``lo[b]``, so the walk starts there and block 0 is an own block)."""
+    ``lo[b]``, so the walk starts there and block 0 is an own block).
+
+    ``layer_operand``: one more prefetched operand follows, the pool's layer,
+    which the pools' index maps read and the body does not."""
     lo_ref = None
     if windowed:
         lo_ref, *rest = rest
+    if layer_operand:
+        rest = rest[1:]
     q_ref, *rest = rest
     k_refs, v_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
     if quantized:
@@ -371,7 +385,7 @@ def _walked_table(block_tables, last, n_blk: int, G: int):
 
 def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
                 HB: int, G: int, scales, layer: Optional[int], interpret: bool,
-                lo=None):
+                lo=None, name: Optional[str] = None):
     """The ``pallas_call`` of :func:`_paged_kernel`, for both wrappers: grid
     ``(B * nhb, n_blk)`` over ``q5`` ``[B, nhb, HB, R, D]`` (``R`` query rows
     a kv-head: ``rep`` for the decode step, ``rep * T`` for T tokens), the
@@ -379,7 +393,10 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
     scales per key column, and the (m, l, acc) scratch. ``at`` ([B], scalar
     prefetched with the table) is what the kernel masks by, ``last`` the
     slot's last own page and ``last_blk(at[b])`` its last own block. ``lo``
-    ([B], for a ``windowed`` kernel) is prefetched after ``at``."""
+    ([B], for a ``windowed`` kernel) is prefetched after ``at``. ``name`` is
+    the call's name in a trace; without one it takes the name of the jitted
+    function that holds it (``decode_fn``, ``verify_fn``), which is what a
+    program that holds both kernels cannot leave to chance."""
     B, nhb, _, R, D = q5.shape
     page = k_pool.shape[-2]
     n_pages = block_tables.shape[1]
@@ -416,11 +433,16 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
             return b, jax.lax.min(j, last_blk(at[b])), 0, hb, 0, 0, 0
 
         in_specs.append(pl.BlockSpec((1, 1, 2, 1, HB, 1, GP), scale_map))
+    prefetched = (
+        walk, at, *(() if lo is None else (jnp.asarray(lo, jnp.int32),)),
+        *_layer_operand(layer),
+    )
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             # walked table + per-slot positions (+ a window's lower bounds)
-            num_scalar_prefetch=2 if lo is None else 3,
+            # (+ the layer, where it is an operand)
+            num_scalar_prefetch=len(prefetched),
             grid=(B * nhb, n_blk),
             in_specs=in_specs,
             out_specs=qo_spec,
@@ -432,7 +454,38 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
         ),
         out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
         interpret=interpret,
-    )(walk, at, *(() if lo is None else (jnp.asarray(lo, jnp.int32),)), *operands)
+        **({} if name is None else {"name": name}),
+    )(*prefetched, *operands)
+
+
+# A pool of this many layers or more has its kernels' calls, one a layer of a
+# program, share one traced and lowered kernel (:func:`_for_all_layers`). The
+# jitted twin costs a trace of its own, 0.25-0.5 s on the chip's host where a
+# layer's call traced and lowered in place costs about 0.1 s (PERF.md, PR 35:
+# 48 layers 11.2 -> 1.8 s off the chip; five layers of two kinds lost 2 s).
+SHARED_FROM_LAYERS = 8
+
+
+def _shares_kernel(pool, layer, name) -> bool:
+    """Whether this call goes through its jitted twin: a named call (XLA
+    names an unnamed kernel's custom call after the innermost jitted
+    function, so it would be lost to a trace's readers) with a static layer
+    into a pool deep enough for the twin to pay."""
+    return (
+        name is not None and isinstance(layer, int)
+        and pool.shape[0] >= SHARED_FROM_LAYERS
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("fn", "sm_scale", "interpret", "name"))
+def _for_all_layers(fn, q, k_pool, v_pool, block_tables, at, layer, scales, lo,
+                    *, sm_scale, interpret, name):
+    """``fn`` (one of the two wrappers below) with the layer an OPERAND, under
+    a jit of its own: a program's calls, one a layer, then share one traced
+    and one lowered kernel, which is most of what a layer costs a served
+    program's set-up (ROADMAP S9)."""
+    return fn(q, k_pool, v_pool, block_tables, at, sm_scale, interpret, scales,
+              layer, lo, name)
 
 
 def paged_decode_attention(
@@ -444,8 +497,9 @@ def paged_decode_attention(
     sm_scale: Optional[float] = None,
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
-    layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
+    layer=None,  # the layer of a [L, P, KV, page, D] pool: static, or a traced i32
     lo: Optional[jnp.ndarray] = None,  # [B] i32: lowest key index attended (a window)
+    name: Optional[str] = None,  # the call's name in a trace (:func:`_paged_call`)
 ) -> jnp.ndarray:
     """Single-token attention against a PAGED cache → [B, H, D].
 
@@ -470,7 +524,16 @@ def paged_decode_attention(
     heads. ``scales`` (ISSUE 12): int8 pools are served by the same kernel;
     the slots' per-page scale rows are gathered through the table into
     per-key columns here (a few hundred KB beside the halved code bytes)
-    and applied to the scores and the probabilities in VMEM."""
+    and applied to the scores and the probabilities in VMEM.
+
+    A call with a ``name`` and a static ``layer`` into a deep pool shares its
+    kernel with the program's other layers (:func:`_shares_kernel`)."""
+    if _shares_kernel(k_pool, layer, name):
+        return _for_all_layers(
+            paged_decode_attention, q, k_pool, v_pool, block_tables, pos,
+            jnp.int32(layer), scales, lo, sm_scale=sm_scale,
+            interpret=interpret, name=name,
+        )
     B, H, D = q.shape
     KV, page = _pool_dims(k_pool, layer)
     n_pages = block_tables.shape[1]
@@ -491,11 +554,12 @@ def paged_decode_attention(
     kernel = functools.partial(
         _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=1, rep=rep,
         quantized=quantized, windowed=lo is not None,
+        layer_operand=bool(_layer_operand(layer)),
     )
     out = _paged_call(
         kernel, q.reshape(B, nhb, HB, rep, D), k_pool, v_pool, block_tables,
         pos, pos // page, lambda pos_b: jax.lax.div(pos_b, G * page),
-        HB, G, scales, layer, interpret, lo,
+        HB, G, scales, layer, interpret, lo, name,
     )
     return out.reshape(B, H, D)
 
@@ -509,8 +573,9 @@ def paged_multitoken_attention(
     sm_scale: Optional[float] = None,
     interpret: bool = False,
     scales: Optional[jnp.ndarray] = None,  # [P, KV, 2] f32 for int8 pools
-    layer: Optional[int] = None,  # static: the layer of a [L, P, KV, page, D] pool
+    layer=None,  # the layer of a [L, P, KV, page, D] pool: static, or a traced i32
     lo: Optional[jnp.ndarray] = None,  # [B] i32: query t attends keys >= lo[b] + t
+    name: Optional[str] = None,  # the call's name in a trace (:func:`_paged_call`)
 ) -> jnp.ndarray:
     """T-token causal attention against a PAGED cache → [B, T, H, D].
     ``lo`` as in :func:`paged_decode_attention`, moving with the query.
@@ -526,7 +591,13 @@ def paged_multitoken_attention(
     ``layer`` as there. The queries go in head-major, ``[B, KV, rep * T,
     D]``, which is what the head-batched ``dot_general`` takes: the two
     ``[T, H] <-> [H, T]`` transposes around the call stay (0.4 MB each at
-    the served shape)."""
+    the served shape). ``name`` as in :func:`paged_decode_attention`."""
+    if _shares_kernel(k_pool, layer, name):
+        return _for_all_layers(
+            paged_multitoken_attention, q, k_pool, v_pool, block_tables, base,
+            jnp.int32(layer), scales, lo, sm_scale=sm_scale,
+            interpret=interpret, name=name,
+        )
     B, T, H, D = q.shape
     KV, page = _pool_dims(k_pool, layer)
     n_pages = block_tables.shape[1]
@@ -549,6 +620,7 @@ def paged_multitoken_attention(
     kernel = functools.partial(
         _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=T, rep=rep,
         quantized=scales is not None, windowed=lo is not None,
+        layer_operand=bool(_layer_operand(layer)),
     )
     q5 = q.reshape(B, T, nhb, HB, rep, D).transpose(0, 2, 3, 4, 1, 5)
     n_blk = -(-n_pages // G)
@@ -557,20 +629,21 @@ def paged_multitoken_attention(
         block_tables, base, jnp.minimum((base + (T - 1)) // page, n_pages - 1),
         lambda base_b: jax.lax.min(
             jax.lax.div(base_b + (T - 1), G * page), n_blk - 1),
-        HB, G, scales, layer, interpret, lo,
+        HB, G, scales, layer, interpret, lo, name,
     )
     out = out.reshape(B, nhb, HB, rep, T, D).transpose(0, 4, 1, 2, 3, 5)
     return out.reshape(B, T, H, D)
 
 
-def _token_write_kernel(pidx_ref, poff_ref, k_new_ref, v_new_ref, k_ref, v_ref,
-                        k_out_ref, v_out_ref):
+def _token_write_kernel(pidx_ref, poff_ref, *rest):
     """One slot's K page and V page (a block of kv-heads of them) with the
     rows of the slot's new tokens replaced; the other rows pass through. The
     step of token ``t`` brings the page ``pidx[b, t]`` and writes EVERY token
     of the slot that lands on that page, so the steps of one page, which
     follow one another, each leave the whole result: the pipeline fetches and
-    writes back a block only when its index changes."""
+    writes back a block only when its index changes. (Where the layer is an
+    operand, its prefetched ref comes third and only the index maps read it.)"""
+    k_new_ref, v_new_ref, k_ref, v_ref, k_out_ref, v_out_ref = rest[-6:]
     b, t = pl.program_id(0), pl.program_id(2)
     page = k_out_ref.shape[-2]
     row = jax.lax.broadcasted_iota(jnp.int32, (1, page, 1), 1)
@@ -604,12 +677,13 @@ def paged_token_write_blocks(KV: int, page: int, D: int, itemsize: int = 2,
 def paged_token_write(
     k_pool: jnp.ndarray,  # [L, P, KV, page, D], updated in place (donate it)
     v_pool: jnp.ndarray,  # the same
-    layer: int,  # static
+    layer,  # static, or (under the jitted twin) a traced i32
     pidx: jnp.ndarray,  # [B] or [B, T] i32 page of each new token
     poff: jnp.ndarray,  # the same shape: its offset in that page
     k_vals: jnp.ndarray,  # [B, KV, D] or [B, T, KV, D] the new tokens' K
     v_vals: jnp.ndarray,  # the same shape: their V
     interpret: bool = False,
+    shared: bool = False,  # a program's calls, one a layer, share one kernel
 ):
     """The decode step's one-token write into both pools (or the verify
     step's ``T`` tokens a slot) as ONE device operation → ``(k_pool,
@@ -623,7 +697,13 @@ def paged_token_write(
     past a slot's row share and nothing reads. (A scatter asks the TPU for
     the page index minor-most and so re-lays the pool out; sixteen
     ``dynamic_update_slice`` a layer and pool do the same in place in thirty
-    device operations, and a traced run has to write every one of them out.)"""
+    device operations, and a traced run has to write every one of them out.)
+    ``shared``: into a deep pool the call goes through a jitted twin with the
+    layer an operand (:func:`_shares_kernel`; the kernel has its name)."""
+    if shared and _shares_kernel(k_pool, layer, "kv_token_write"):
+        return _token_write_for_all_layers(
+            k_pool, v_pool, jnp.int32(layer), pidx, poff, k_vals, v_vals, interpret
+        )
     KV, page, D = k_pool.shape[2:]
     if pidx.ndim == 1:
         pidx, poff = pidx[:, None], poff[:, None]
@@ -635,35 +715,42 @@ def paged_token_write(
             f"paged_token_write: a [{page}, {D}] page of {k_pool.dtype.name} "
             "does not fit VMEM"
         )
-    block = pl.BlockSpec(
-        (None, 1, HB, page, D),
-        lambda b, hb, t, pidx, poff: (layer, pidx[b, t], hb, 0, 0),
+    block = _pool_block_spec(
+        (1, HB, page, D),
+        lambda b, hb, t, pidx, poff, *_: (pidx[b, t], hb, 0, 0), layer,
     )
     new = pl.BlockSpec(
-        (1, T, HB, 1, D), lambda b, hb, t, pidx, poff: (b, 0, hb, 0, 0)
+        (1, T, HB, 1, D), lambda b, hb, t, pidx, poff, *_: (b, 0, hb, 0, 0)
+    )
+    prefetched = (
+        jnp.asarray(pidx, jnp.int32), jnp.asarray(poff, jnp.int32),
+        *_layer_operand(layer),
     )
     pool_shape = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
     return pl.pallas_call(
         _token_write_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetched),
             grid=(B, KV // HB, T),
             in_specs=[new, new, block, block],
             out_specs=[block, block],
         ),
         out_shape=[pool_shape, pool_shape],
-        # the pools, after the two prefetched tables and the two new rows
-        input_output_aliases={4: 0, 5: 1},
+        # the pools, after the prefetched operands and the two new rows
+        input_output_aliases={len(prefetched) + 2: 0, len(prefetched) + 3: 1},
         # its own name in a trace: the roofline readers find the attention
         # kernels by the name of the function that holds them (decode_fn)
         name="kv_token_write",
         interpret=interpret,
     )(
-        jnp.asarray(pidx, jnp.int32), jnp.asarray(poff, jnp.int32),
+        *prefetched,
         k_vals.astype(k_pool.dtype).reshape(B, T, KV, 1, D),
         v_vals.astype(v_pool.dtype).reshape(B, T, KV, 1, D),
         k_pool, v_pool,
     )
+
+
+_token_write_for_all_layers = jax.jit(paged_token_write, static_argnums=(7,))
 
 
 def paged_token_write_ok(KV: int, page: int, D: int, itemsize: int = 2,

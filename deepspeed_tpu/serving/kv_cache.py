@@ -204,6 +204,11 @@ class SlotTable:
         self.tokens = np.zeros((max_slots,), np.int32)
         self.keys = np.zeros((max_slots, 2), np.uint32)
 
+    def rows(self) -> tuple:
+        """The decode rows' host operands, in program order (a table nobody
+        assigns to is a step's worth of idle rows)."""
+        return self.tokens, self.seq_lens, self.block_tables, self.keys
+
     def assign(self, slot: int, pages: List[int]) -> None:
         if len(pages) > self.pages_per_slot:
             raise ValueError(

@@ -15,6 +15,11 @@ attention, the sampling); the model's module gives the rest through
   (``ops.attention.paged_cached_attention``), sample per-slot with per-slot
   keys. All shapes are functions of the serving config only — finished
   sequences vacating slots and new prompts arriving never retrace.
+- :func:`paged_mixed_step` — the chunk program: one chunk of ONE slot's
+  incremental prefill and one token for every decoding slot, both sets of
+  rows through every weight once and parted for attention alone (a chunk
+  that finds no decode step to ride takes it with idle decode rows);
+  :func:`paged_verify_step` is the decode step's speculative twin.
 - :func:`generate_padded` — the bucket-padded analog of ``gpt2.generate``
   for the offline ``InferenceEngine.generate`` path: prompt length is a
   TRACED scalar, so every length in a bucket reuses one executable.
@@ -98,7 +103,7 @@ def _write_pool_pages(pool, scales, l, page_ids, chunks, sidx):
     return pool, scales, dequantize_kv_pages(codes, s)
 
 
-def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
+def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared=False):
     """``k_vals`` / ``v_vals [..., KV, D]`` to (layer ``l``, page
     ``pidx[...]``, every kv head, offset ``poff[...]``) of the ``[L, P, KV,
     page, D]`` pools → ``(k_pool, v_pool)``; ``pidx`` / ``poff`` are ``[B]``
@@ -116,7 +121,10 @@ def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
     refuses such a program. The indices are always in range (an idle slot or
     an out-of-budget draft points at the scratch page); where two tokens
     name the same element, which only happens on the scratch page, which one
-    stays is not defined. Same elements, same values either way."""
+    stays is not defined. Same elements, same values either way. ``shared``:
+    the kernel's calls of a program, one a layer, may share one traced and
+    lowered kernel (the layer an operand; the mixed step asks for it, the
+    pool's depth decides)."""
     from ..ops.pallas.decode_attention import (
         paged_token_write,
         paged_token_write_ok,
@@ -125,7 +133,9 @@ def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
     KV, D = k_vals.shape[-2:]
     T = 1 if pidx.ndim == 1 else pidx.shape[1]
     if paged_token_write_ok(KV, k_pool.shape[3], D, k_pool.dtype.itemsize, T):
-        return paged_token_write(k_pool, v_pool, l, pidx, poff, k_vals, v_vals)
+        return paged_token_write(
+            k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared=shared
+        )
     at = (l, pidx[..., None], jnp.arange(KV), poff[..., None])
     return (
         k_pool.at[at].set(k_vals.astype(k_pool.dtype)),
@@ -145,13 +155,16 @@ def _token_codes(scales, l, pidx, poff, vals, sidx):
     return quantize_kv_token(vals, s), scales.at[l, pidx, :, sidx].set(s)
 
 
-def _write_pool_tokens(k_pool, v_pool, scales, l, pidx, poff, k_vals, v_vals):
+def _write_pool_tokens(k_pool, v_pool, scales, l, pidx, poff, k_vals, v_vals,
+                       shared=False):
     """One-token write: ``k_vals`` / ``v_vals [B, KV, D]`` to (layer ``l``,
     page ``pidx[b]``, offset ``poff[b]``) of both pools, quantized at write
     when they are int8 → ``(k_pool, v_pool, scales)``."""
     k_vals, scales = _token_codes(scales, l, pidx, poff, k_vals, 0)
     v_vals, scales = _token_codes(scales, l, pidx, poff, v_vals, 1)
-    k_pool, v_pool = _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals)
+    k_pool, v_pool = _scatter_tokens(
+        k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared
+    )
     return k_pool, v_pool, scales
 
 
@@ -312,12 +325,25 @@ def _latent_write_tokens(pool, l, pidx, poff, rows):
     return pool.at[at].set(rows.astype(pool.dtype))
 
 
-def _attend_latent(fam, q, pool, l, block_tables, base, name):
+def _unless_idle(live, attend, shape, dtype):
+    """``attend()`` where ``live`` (a traced bool: a row of the call is real),
+    else zeros of its shape, which nothing reads: a call of the mixed step
+    that rode no decode step skips its decode rows' reads."""
+    return lax.cond(live, attend, lambda: jnp.zeros(shape, dtype))
+
+
+def _attend_latent(fam, q, pool, l, block_tables, base, name, live=None):
     """The absorbed queries ``q [B, T, H, w]`` against layer ``l`` of the
-    (already updated) latent pool → ``[B, T, H * v_width]``."""
+    (already updated) latent pool → ``[B, T, H * v_width]``; ``live`` as in
+    :func:`_attend_decode_shaped`."""
     from ..ops.attention import latent_paged_cached_attention
 
     B, T, H, _ = q.shape
+    if live is not None:
+        return _unless_idle(
+            live, lambda: _attend_latent(fam, q, pool, l, block_tables, base, name),
+            (B, T, H * fam.v_width), q.dtype,
+        )
     o = latent_paged_cached_attention(
         _pad_lanes(q, pool.shape[-1]), pool, block_tables, base, fam.v_width,
         impl=fam.attn_impl, sm_scale=fam.sm_scale, layer=l, name=name,
@@ -504,7 +530,8 @@ def paged_prefill(
 # ---------------------------------------------------------------------------
 
 def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
-                          out_dtype, scales_l=None, lo=None):
+                          out_dtype, scales_l=None, lo=None, name=None,
+                          live=None):
     """ONE query token per slot against layer ``l`` of the paged cache →
     [B, 1, E]. The kernel takes the whole pools and the layer as a block
     index; only the ``jnp`` branch slices the layer out.
@@ -515,9 +542,22 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
     (= ``scales[l]``, [P, KV, 2]) dequantizes an int8 pool in the read
     path (ISSUE 12). ``lo`` (a window layer: the pools are the ring pools,
     the table and ``pos`` the ring's view, :func:`_window_view`) bounds the
-    keys from below."""
+    keys from below. ``name``: the kernel call's name in a trace, for a
+    program that holds more kernels than this one; a named call into a deep
+    pool shares its traced and lowered kernel with the program's other
+    layers (``ops/pallas/decode_attention._shares_kernel``). ``live``:
+    :func:`_unless_idle`'s, where the caller has one."""
     B, S, H, D = q.shape  # S == 1
     E = H * D
+    if live is not None:
+        return _unless_idle(
+            live,
+            lambda: _attend_decode_shaped(
+                fam, q, k_pool, v_pool, l, block_tables, pos, out_dtype,
+                scales_l, lo, name,
+            ),
+            (B, S, E), out_dtype,
+        )
     scale = 1.0 / np.sqrt(D)
     if fam.attn_impl in ("auto", "pallas") or lo is not None:
         from ..ops.attention import paged_cached_attention
@@ -525,7 +565,7 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
         o1 = paged_cached_attention(
             q[:, 0], k_pool, v_pool, block_tables, pos,
             impl=fam.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
-            lo=lo,
+            lo=lo, name=name,
         )
         return o1.reshape(B, 1, E).astype(out_dtype)
 
@@ -568,7 +608,8 @@ class _RingWrites:
         self.views = _window_views(fam, slots, seq_lens, page, ring)
 
 
-def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int):
+def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int,
+                           name=None, live=None):
     """A window layer of the decode (T = 1) or verify step: the T tokens'
     K/V into the slots' rings, then query ``t`` against the ring's view as a
     decode-shaped call, the keys bounded below. → ``(o [B, T, H * D], win)``."""
@@ -577,13 +618,14 @@ def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int):
     pidx, poff = (rw.pidx[:, 0], rw.poff[:, 0]) if T == 1 else (rw.pidx, rw.poff)
     k_new, v_new = (k_c[:, 0], v_c[:, 0]) if T == 1 else (k_c, v_c)
     k_win, v_win = _scatter_tokens(
-        k_win, v_win, li, pidx, poff, k_new.astype(k_win.dtype), v_new.astype(v_win.dtype)
+        k_win, v_win, li, pidx, poff, k_new.astype(k_win.dtype), v_new.astype(v_win.dtype),
+        shared=name is not None,
     )
     table, off, lo = rw.views[window]
     o = [
         _attend_decode_shaped(
             fam, q[:, t:t + 1], k_win, v_win, li, table, base + t - off,
-            q.dtype, None, lo + t,
+            q.dtype, None, lo + t, name, live,
         )
         for t in range(T)
     ]
@@ -591,7 +633,8 @@ def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int):
 
 
 def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
-                            pos, pidx, poff, l, scales=None):
+                            pos, pidx, poff, l, scales=None, name=None,
+                            live=None):
     """One-token attention per slot against its paged cache (layer ``l`` of
     the FULL pool) → ``(o [B, 1, H * D], k_pool, v_pool, scales)``.
 
@@ -602,13 +645,26 @@ def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
     # the head slice put the batch dim first, matching the value layout.
     # Inactive slots target the scratch page.
     k_pool, v_pool, scales = _write_pool_tokens(
-        k_pool, v_pool, scales, l, pidx, poff, k_c[:, 0], v_c[:, 0]
+        k_pool, v_pool, scales, l, pidx, poff, k_c[:, 0], v_c[:, 0],
+        shared=name is not None,
     )
     o = _attend_decode_shaped(
         fam, q, k_pool, v_pool, l, block_tables, pos, q.dtype,
-        scales[l] if scales is not None else None,
+        scales[l] if scales is not None else None, name=name, live=live,
     )
     return o, k_pool, v_pool, scales
+
+
+def _sample_slots(logits, keys, temperature, top_k, top_p):
+    """One token a slot from ``logits [B, V]`` under per-slot ``keys [B, 2]``."""
+    if not temperature or temperature <= 0.0:
+        return jnp.argmax(logits.astype(jnp.float32), axis=-1)
+    # per-slot keys: each row samples exactly as its own B=1 generate (vmap of
+    # the PRNG is semantics-preserving, so slot b's draw equals the sequential
+    # request's draw with the same key)
+    return jax.vmap(
+        lambda lg, kk: sample_logits(lg[None, :], kk, temperature, top_k, top_p)[0]
+    )(logits, keys)
 
 
 def paged_decode_step(
@@ -664,18 +720,7 @@ def paged_decode_step(
             )
         h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    logits = fam.logits(params, h[:, -1])
-    if not temperature or temperature <= 0.0:
-        nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-    else:
-        # per-slot keys: each row samples exactly as its own B=1 generate
-        # (vmap of the PRNG is semantics-preserving, so slot b's draw equals
-        # the sequential request's draw with the same key)
-        nxt = jax.vmap(
-            lambda lg, kk: sample_logits(
-                lg[None, :], kk, temperature, top_k, top_p
-            )[0]
-        )(logits, keys)
+    nxt = _sample_slots(fam.logits(params, h[:, -1]), keys, temperature, top_k, top_p)
     return _result(k_pool, v_pool, scales, win, nxt, counts)
 
 
@@ -700,12 +745,12 @@ def paged_decode_step(
 
 
 def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
-                             scales_l=None, lo=None):
+                             scales_l=None, lo=None, name=None):
     """Batched attention tail of the chunk-prefill program: q [B,T,H,D]
     against layer ``l`` of the (already updated) paged cache, masked per
     query; the pools arrive whole, as in ``_attend_decode_shaped``. The
     caller applies the output projection. ``scales_l`` dequantizes an int8
-    pool (ISSUE 12); ``lo`` as in ``_attend_decode_shaped``.
+    pool (ISSUE 12); ``lo`` and ``name`` as in ``_attend_decode_shaped``.
 
     Dispatch mirrors ``_attention_decode_paged`` branch for branch; see the
     block comment above for why this form is token-identical but not
@@ -718,7 +763,7 @@ def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
         o = paged_multitoken_cached_attention(
             q, k_pool, v_pool, block_tables, base,
             impl=fam.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
-            lo=lo,
+            lo=lo, name=name,
         )
         return o.reshape(B, T, H * D).astype(q.dtype)
 
@@ -872,96 +917,182 @@ def paged_verify_step(
     return _result(k_pool, v_pool, scales, win, greedy, counts)
 
 
-def paged_chunk_prefill(
+# A call of the mixed step whose decode rows are ALL idle (a chunk that rode no
+# decode step) skips those rows' attention reads from this many slots on. On
+# the chip (PERF.md, PR 35) the one-token kernel walks idle rows at about half
+# a microsecond a grid step, slots x page blocks of them (33 us a layer at 8
+# slots, 240 at 64, 420 at 48 slots of 13 blocks), and a conditional costs
+# about 10 us a layer in every call that does carry rows: most calls.
+SKIP_IDLE_READS_FROM_SLOTS = 16
+
+
+def paged_mixed_step(
     cfg,
     params: PyTree,
-    input_ids: jnp.ndarray,   # [1, C] one chunk, right-padded past the prompt
-    start: jnp.ndarray,       # traced i32: absolute position of input_ids[0, 0]
-    prompt_len: jnp.ndarray,  # traced i32: the request's true prompt length
-    k_pool: jnp.ndarray,      # [L, P, KV, page, D]
+    tokens: jnp.ndarray,        # [B] i32 last emitted token per slot
+    seq_lens: jnp.ndarray,      # [B] i32 tokens already cached per slot
+    input_ids: jnp.ndarray,     # [1, C] one chunk, right-padded past the prompt
+    start: jnp.ndarray,         # traced i32: absolute position of input_ids[0, 0]
+    prompt_len: jnp.ndarray,    # traced i32: the request's true prompt length
+    k_pool: jnp.ndarray,        # [L, P, KV, page, D]
     v_pool: jnp.ndarray,
-    page_ids: jnp.ndarray,    # [C // page] i32: THIS chunk's slot pages
-    block_tables: jnp.ndarray,  # [1, W] i32: the slot's full table row
-    rng: jnp.ndarray,         # PRNGKey for the first sampled token
+    block_tables: jnp.ndarray,  # [B, W] i32: the decode rows' tables
+    page_ids: jnp.ndarray,      # [C // page] i32: THIS chunk's slot pages
+    chunk_row: jnp.ndarray,     # [1, W] i32: the prefilling slot's full table row
+    keys: jnp.ndarray,          # [B, 2] u32 per-slot sampling keys
+    rng: jnp.ndarray,           # PRNGKey for the prompt's first sampled token
     temperature: float = 0.0,
     top_k: int = 0,
     top_p: float = 1.0,
     scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
     win: tuple = None,    # a window family's ring pools
-    slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
+    slot: jnp.ndarray = None,  # traced i32: the prefilling slot, whose ring a window layer writes
     ring: int = 0,
 ):
-    """One chunk of an incremental prefill (ISSUE 10) → (k_pool, v_pool,
-    token [1]); ``scales`` threaded and returned before the token when the
-    pool is quantized (the COW fork-by-recompute path rides this program —
-    the fresh private page is REQUANTIZED here, its own scale written,
-    while the shared original's codes and scale row are never touched); a
-    window family's ring pools and expert counts as in :func:`_result`.
+    """One chunk of ONE slot's incremental prefill (ISSUE 10) and one token
+    for every decoding slot, through every weight ONCE → (k_pool, v_pool,
+    tokens [B + 1]: the slots' next tokens, then the chunk's), pools, scales,
+    rings and expert counts as in :func:`_result` (ONE count of the call's
+    real tokens a held expert: the chunk's and the active slots').
 
-    Positions ``start .. start+C-1`` run through the model attending the
-    slot's cached prefix (``< start`` — earlier chunks or shared prefix
-    pages) plus causal intra-chunk, K/V written page-granularly to
-    ``page_ids`` (page-aligned because C is a page multiple; pages the
-    chunk overruns are scratch-padded by the scheduler). A window layer
-    writes the chunk's pages into the slot's ring (whose ``ring`` pages hold
-    the window before the chunk, the chunk and a page of slack, so no page a
-    query of this chunk reads is overwritten) and reads the ring's view.
-    The returned token is sampled at the true last prompt position and is
-    only meaningful on the final chunk — earlier chunks' samples are
-    discarded host-side. Long prompts stop stalling decode: the scheduler
-    interleaves one chunk per step with the batched decode of other slots."""
+    The ``C`` chunk rows and the ``B`` decode rows are one ``[1, C + B, E]``
+    activation through the norms, ``fam.qkv``, the output projection, the MLP
+    or expert layer and the head: a step that carries both streams the weights
+    once, and both sets of rows are far below the rows at which the matmuls
+    stop being bound by that stream. They part for attention alone, each to
+    the code its own program runs: the decode rows to
+    :func:`paged_decode_step`'s write and one-token kernel under
+    ``block_tables``, the chunk's to the page-granular write and the
+    multi-token kernel under ``chunk_row``. The kernels carry the names they
+    have in those programs' traces (``decode_fn``, ``chunk_fn``; a latent
+    family's ``mla_paged_decode``, ``mla_paged_chunk``).
+
+    The chunk: positions ``start .. start+C-1`` attend the slot's cached
+    prefix (``< start``: earlier chunks or shared prefix pages) plus causal
+    intra-chunk, K/V written page-granularly to ``page_ids`` (page-aligned
+    because C is a page multiple; pages the chunk overruns are scratch-padded
+    by the scheduler; an int8 pool's fresh pages are quantized here, their
+    own scales written: the COW fork-by-recompute path rides this program). A
+    window layer writes the chunk's pages into the slot's ring (whose
+    ``ring`` pages hold the window before the chunk, the chunk and a page of
+    slack, so no page a query of this chunk reads is overwritten) and reads
+    the ring's view. Its token is sampled at the true last prompt position
+    and only meaningful on the final chunk. Chunking reorders prefill
+    arithmetic at the ulp level by nature (token identity is pinned in
+    tests).
+
+    The decode rows: as in :func:`paged_decode_step`. The prefilling slot's
+    own decode row is an idle one (its table row is scratch while it
+    prefills), so it writes to the scratch page of the pools and of the
+    rings (:class:`_RingWrites`) and its token is discarded. ``B`` may be 0
+    (:func:`paged_chunk_prefill`); a call whose rows are all idle is what a
+    chunk takes when it has no decode step to ride, and where the slots are
+    many it skips their reads (``SKIP_IDLE_READS_FROM_SLOTS``)."""
     fam = cfg.serving_family()
-    B, C = input_ids.shape
+    B, C = tokens.shape[0], input_ids.shape[1]
     page = k_pool.shape[3]
-    n_cp = C // page
-    positions = jnp.minimum(start + jnp.arange(C), fam.n_positions - 1)
-    h = fam.embed(params, input_ids, positions)
+    # chunk rows first: they start at row 0 and the decode rows at a page
+    # multiple, whole tiles both
+    c_pos = jnp.minimum(start + jnp.arange(C), fam.n_positions - 1)
+    positions = jnp.concatenate([c_pos, seq_lens])
+    h = fam.embed(params, jnp.concatenate([input_ids[0], tokens])[None], positions)
     base = jnp.reshape(start, (1,))
-    valid = (positions < prompt_len) if fam.sparse_layers else None
+    pidx = jnp.take_along_axis(block_tables, (seq_lens // page)[:, None], axis=1)[:, 0]
+    poff = seq_lens % page
+    real = block_tables[:, 0] != 0  # a slot that decodes holds a page; page 0 is scratch
+    valid = jnp.concatenate([c_pos < prompt_len, real]) if fam.sparse_layers else None
+    live = jnp.any(real) if B >= SKIP_IDLE_READS_FROM_SLOTS else None
     counts = []
+    rw = None
     if win is not None:
-        ring_ids = ring_page_ids(slot, start // page + jnp.arange(n_cp), ring)
+        ring_ids = ring_page_ids(slot, start // page + jnp.arange(C // page), ring)
         views = _window_views(fam, jnp.reshape(slot, (1,)), base, page, ring)
+        rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if B else None
+
+    def part(x):
+        """``[1, C + B, ...]`` → the chunk's ``[1, C, ...]`` and the decode
+        rows as their own program has them, ``[B, 1, ...]``."""
+        return x[:, :C], jnp.swapaxes(x[:, C:], 0, 1)
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         q, k_, v = fam.qkv(lp, h, positions, l)
+        (qc, qd), (kc, kd) = part(q), part(k_)
+        od = None
         if fam.kv_pools == 1:
-            k_pool = _latent_write_pages(k_pool, li, page_ids, k_)
-            o = _attend_latent(fam, q, k_pool, li, block_tables, base, "mla_paged_chunk")
+            k_pool = _latent_write_pages(k_pool, li, page_ids, kc)
+            if B:
+                k_pool = _latent_write_tokens(k_pool, li, pidx, poff, kd[:, 0])
+                od = _attend_latent(
+                    fam, qd, k_pool, li, block_tables, seq_lens, "mla_paged_decode", live
+                )
+            oc = _attend_latent(fam, qc, k_pool, li, chunk_row, base, "mla_paged_chunk")
         elif windowed:
+            vc, vd = part(v)
             k_win, v_win = win
-            k_win = k_win.at[li, ring_ids].set(_page_chunks(k_, page).astype(k_win.dtype))
-            v_win = v_win.at[li, ring_ids].set(_page_chunks(v, page).astype(v_win.dtype))
+            k_win = k_win.at[li, ring_ids].set(_page_chunks(kc, page).astype(k_win.dtype))
+            v_win = v_win.at[li, ring_ids].set(_page_chunks(vc, page).astype(v_win.dtype))
             win = (k_win, v_win)
+            if B:
+                od, win = _attention_step_window(
+                    fam, qd, kd, vd, win, li, seq_lens, rw, fam.windows[l], "decode_fn", live
+                )
             table, off, lo = views[fam.windows[l]]
-            o = _attend_multitoken_paged(
-                fam, q, k_win, v_win, li, table, base - off, None, lo
+            oc = _attend_multitoken_paged(
+                fam, qc, *win, li, table, base - off, None, lo, "chunk_fn"
             )
         else:
+            vc, vd = part(v)
             pool_dt = h.dtype if scales is not None else k_pool.dtype
             # page-granular scatter, exactly paged_prefill's write (quantized
             # at write when the pool is int8; the attention below reads the
             # pool, so it sees the dequantized codes either way)
             k_pool, scales, _ = _write_pool_pages(
-                k_pool, scales, li, page_ids, _page_chunks(k_.astype(pool_dt), page), 0,
+                k_pool, scales, li, page_ids, _page_chunks(kc.astype(pool_dt), page), 0,
             )
             v_pool, scales, _ = _write_pool_pages(
-                v_pool, scales, li, page_ids, _page_chunks(v.astype(pool_dt), page), 1,
+                v_pool, scales, li, page_ids, _page_chunks(vc.astype(pool_dt), page), 1,
             )
-            o = _attend_multitoken_paged(
-                fam, q, k_pool, v_pool, li, block_tables, base,
-                scales[li] if scales is not None else None,
+            if B:
+                od, k_pool, v_pool, scales = _attention_decode_paged(
+                    fam, qd, kd.astype(pool_dt), vd.astype(pool_dt), k_pool, v_pool,
+                    block_tables, seq_lens, pidx, poff, li, scales, "decode_fn", live,
+                )
+            oc = _attend_multitoken_paged(
+                fam, qc, k_pool, v_pool, li, chunk_row, base,
+                scales[li] if scales is not None else None, name="chunk_fn",
             )
+        o = oc if od is None else jnp.concatenate([oc, jnp.swapaxes(od, 0, 1)], axis=1)
         h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    # the true last prompt position, when it falls inside this chunk
+    # one pass of the head: the chunk's true last prompt position (when it
+    # falls inside this chunk) and the decode rows
     idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
-    h_last = jnp.take(h, idx, axis=1)  # [B, E]
-    logits = fam.logits(params, h_last)
-    first = sample_logits(logits, rng, temperature, top_k, top_p)
+    logits = fam.logits(params, jnp.concatenate([jnp.take(h[0], idx[None], axis=0), h[0, C:]]))
+    first = sample_logits(logits[:1], rng, temperature, top_k, top_p)
+    if B:
+        nxt = _sample_slots(logits[1:], keys, temperature, top_k, top_p)
+        first = jnp.concatenate([nxt, first.astype(nxt.dtype)])
     return _result(k_pool, v_pool, scales, win, first, counts)
+
+
+def paged_chunk_prefill(
+    cfg, params: PyTree, input_ids, start, prompt_len, k_pool, v_pool,
+    page_ids, block_tables, rng, **kw,
+):
+    """One chunk through the model with no decode row beside it → (k_pool,
+    v_pool, token [1], ...): :func:`paged_mixed_step` at ``B`` = 0, its
+    operands in the chunk's own order (``block_tables [1, W]`` is the
+    prefilling slot's row). No engine compiles this shape (a chunk that has
+    no decode step to ride takes the mixed program with idle rows); it is
+    what the tests hold the mixed step's chunk rows against."""
+    none = jnp.zeros((0,), jnp.int32)
+    return paged_mixed_step(
+        cfg, params, none, none, input_ids, start, prompt_len, k_pool, v_pool,
+        jnp.zeros((0, block_tables.shape[1]), jnp.int32), page_ids, block_tables,
+        jnp.zeros((0, 2), jnp.uint32), rng, **kw,
+    )
 
 
 # ---------------------------------------------------------------------------

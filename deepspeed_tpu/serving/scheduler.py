@@ -153,9 +153,11 @@ class _Slot:
     # the decode placement polls ``.is_ready()`` instead of blocking, so
     # decode batches never wait on another core-set's prefill compute
     pending_tok: Optional[Any] = None
-    # the expert layers' token counts of this prompt's chunk calls, device
-    # arrays until its last chunk's token is fetched
+    # the expert layers' token counts of this prompt's chunk calls that rode
+    # no decode step (device arrays until its last chunk's token is fetched)
+    # and the prompt tokens those calls advanced
     moe_counts: List[Any] = field(default_factory=list)
+    moe_tokens: int = 0
 
 
 class ServingEngine:
@@ -286,11 +288,12 @@ class ServingEngine:
             self.chunk_width = 0
         if self.chunk_width > self.prefill_width:
             self.chunk_width = self.prefill_width
-        # pair rows one chunk call hands the expert layers' grouped products
-        # (0: its rows take the masked form; moe/expert_share.py)
+        # pair rows one call of the chunk program (the chunk's rows and every
+        # slot's decode row) hands the expert layers' grouped products (0: its
+        # rows take the masked form; moe/expert_share.py)
         from ..moe.expert_share import grouped_rows
         self._moe_rows_grouped = len(fam.sparse_layers) * grouped_rows(
-            self.chunk_width, fam.experts_per_token, fam.grouped_from
+            self.chunk_width + self.max_slots, fam.experts_per_token, fam.grouped_from
         )
         # a window layer's ring: the window before a program's first query,
         # the tokens one call writes (a chunk, a verify step's drafts, one
@@ -389,6 +392,8 @@ class ServingEngine:
                 f"cannot hold one max-size prompt ({self.prefill_pages} pages)"
             )
         self.table = SlotTable(self.max_slots, self.pages_per_slot)
+        # the decode rows of a chunk call that rides no decode step: all idle
+        self._idle_table = SlotTable(self.max_slots, self.pages_per_slot)
         self.slots: List[_Slot] = [_Slot() for _ in range(self.max_slots)]
         self.queue: Deque[Request] = deque()
         self.completed: List[Request] = []
@@ -568,7 +573,16 @@ class ServingEngine:
             "shared pages forked copy-on-write at a full-prefix hit",
         )
         self._c_chunks = m.counter(
-            "serving_chunk_prefills_total", "chunk-prefill program invocations"
+            "serving_chunk_prefills_total",
+            "prompt chunks advanced (chunk program invocations: those that "
+            "carried a decode step's rows and those that did not)",
+        )
+        self._c_chunks_rode = m.counter(
+            "serving_chunks_rode_total",
+            "prompt chunks that rode a decode step's dispatch: one call of "
+            "the chunk program carried the chunk and the step's decode rows, "
+            "so the step streamed the weights once (over "
+            "serving_chunk_prefills_total: how often a chunk found a step)",
         )
         self._g_index_pages = m.gauge(
             "serving_prefix_index_pages", "pages held live by the prefix index"
@@ -634,9 +648,9 @@ class ServingEngine:
         )
         self._g_grid_steps = m.gauge(
             "serving_paged_grid_steps",
-            "grid steps of one paged attention kernel call of a compiled "
-            "serving program: slots x head blocks x page blocks (0 = the "
-            "program calls no such kernel)",
+            "grid steps of one call of each paged attention kernel of a "
+            "compiled serving program (the chunk program has two): slots x "
+            "head blocks x page blocks (0 = the program calls no such kernel)",
             labelnames=("program",),
         )
         # -- two kinds of KV state, and the experts a chip's share holds ----
@@ -990,6 +1004,9 @@ class ServingEngine:
         # A window family's two ring pools follow as two more donated
         # operands, and its prefill and chunk programs take the slot (whose
         # ring they write) as their last host operand.
+        # The chunk program is the MIXED step: one prefilling slot's chunk
+        # and every slot's decode row through the weights once. Its host
+        # operands are the decode step's four, then the chunk's.
         windowed, ring = self.windowed, self.ring_pages
 
         def make_fns(cfg, tp_axis):
@@ -1023,18 +1040,22 @@ class ServingEngine:
                     scales=scales, tp_axis=tp_axis, win=win, ring=ring,
                 )
 
-            def chunk_fn(params, k_pool, v_pool, *rest):
-                scales, win, (ids, start, plen, page_ids, bt_row, key, *slot) = (
-                    _split_pools(rest, quant, windowed)
-                )
-                return smodel.paged_chunk_prefill(
-                    cfg, params, ids, start, plen, k_pool, v_pool, page_ids,
-                    bt_row, key, temperature=temp, top_k=tk, top_p=top_p,
-                    scales=scales, tp_axis=tp_axis, win=win,
-                    slot=slot[0] if slot else None, ring=ring,
+            # named for what a trace's readers find it by: a chunk program
+            # and the program a decode dispatch launches
+            def chunk_decode_fn(params, k_pool, v_pool, *rest):
+                scales, win, (
+                    tokens, seq_lens, bt, keys,
+                    ids, start, plen, page_ids, bt_row, key, *slot,
+                ) = _split_pools(rest, quant, windowed)
+                return smodel.paged_mixed_step(
+                    cfg, params, tokens, seq_lens, ids, start, plen, k_pool,
+                    v_pool, bt, page_ids, bt_row, keys, key,
+                    temperature=temp, top_k=tk, top_p=top_p, scales=scales,
+                    tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
+                    ring=ring,
                 )
 
-            return prefill_fn, decode_fn, verify_fn, chunk_fn
+            return prefill_fn, decode_fn, verify_fn, chunk_decode_fn
 
         # AOT: lower + compile ONCE with the config-derived static shapes;
         # the compiled objects reject any other shape, enforcing the
@@ -1048,6 +1069,12 @@ class ServingEngine:
         # family with expert layers, the tokens each held expert got
         n_results = 2 if self.family.sparse_layers else 1
         slot_sds = (S((), i32),) if windowed else ()
+        # the decode rows' host operands: tokens, lengths, tables, keys
+        rows_sds = (
+            S((self.max_slots,), i32), S((self.max_slots,), i32),
+            S((self.max_slots, self.pages_per_slot), i32),
+            S((self.max_slots, 2), u32),
+        )
 
         def compile_for(pset, fn, host_sds):
             rep = pset.placement.rep_spec()
@@ -1089,18 +1116,14 @@ class ServingEngine:
             }
             self.executables.append(self._verify_exec)
         else:
-            self._decode_exec = compile_for(self.decode_set, d_fns[1], (
-                S((self.max_slots,), i32), S((self.max_slots,), i32),
-                S((self.max_slots, self.pages_per_slot), i32),
-                S((self.max_slots, 2), u32),
-            ))
+            self._decode_exec = compile_for(self.decode_set, d_fns[1], rows_sds)
             info[f"serving_decode{sfx}{self.decode_placement.suffix()}"] = {
                 "exe": self._decode_exec, "pset": self.decode_set,
                 "kind": "decode",
             }
             self.executables.append(self._decode_exec)
         if self.chunk_width > 0:
-            self._chunk_exec = compile_for(self.prefill_set, p_fns[3], (
+            self._chunk_exec = compile_for(self.prefill_set, p_fns[3], rows_sds + (
                 S((1, self.chunk_width), i32), S((), i32), S((), i32),
                 S((self.chunk_width // self.page_size,), i32),
                 S((1, self.pages_per_slot), i32), S((2,), u32), *slot_sds,
@@ -1211,7 +1234,7 @@ class ServingEngine:
     def _set_census_gauges(self) -> dict:
         """Per compiled program: how many pool-layer-sized copies, slices
         and transposes its optimised HLO holds, its temp bytes, and the grid
-        steps of one call of its paged attention kernel, as gauges and
+        steps of one call of each of its paged attention kernels, as gauges and
         (returned) as the attrs of the ``ds.init.programs`` phase:
         ``relayout_ops`` / ``temp_bytes`` / ``grid_steps`` keyed
         ``<program>=<n>``."""
@@ -1220,21 +1243,21 @@ class ServingEngine:
             paged_attention_grid_steps,
         )
 
-        # (slots, query tokens a slot) of a program's attention kernel call;
-        # the verify step attends as k+1 single-token calls
+        # (slots, query tokens a slot) of a program's attention kernel calls;
+        # the verify step attends as k+1 single-token calls, the chunk
+        # program calls both kernels a layer
+        rows = (self.max_slots, None)
         shapes = {
-            "decode": (self.max_slots, None),
-            "verify": (self.max_slots, None),
-            "chunk": (1, self.chunk_width),
+            "decode": (rows,), "verify": (rows,),
+            "chunk": ((1, self.chunk_width), rows),
         }
         relayout, temp, steps = {}, {}, {}
         for name, rec in self._program_info.items():
             pset = rec["pset"]
             relayout[name], temp[name] = pset.program_census(name, rec["exe"])
             steps[name] = 0
-            if rec["kind"] in shapes:
-                B, T = shapes[rec["kind"]]
-                steps[name] = latent_attention_grid_steps(
+            for B, T in shapes.get(rec["kind"], ()):
+                steps[name] += latent_attention_grid_steps(
                     self.model_config.attn_impl, B, self.family.n_head,
                     pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
                     self.pages_per_slot, T or 1,
@@ -1300,7 +1323,7 @@ class ServingEngine:
             "prefill": (1, self.prefill_width),
             "decode": (self.max_slots, 1),
             "verify": (self.max_slots, self.spec_k + 1),
-            "chunk": (1, self.chunk_width),
+            "chunk": (1, self.chunk_width + self.max_slots),
         }
         for name, rec in self._program_info.items():
             bs = widths.get(rec["kind"])
@@ -1537,35 +1560,26 @@ class ServingEngine:
         # decodes for its whole width. A slot whose first token is already
         # in flight (pending_tok) is past its last chunk — it waits on the
         # handoff phase below, not on more chunks.
+        # Where a decode step follows on the same placement, the FIRST
+        # prefilling slot's chunk RIDES its dispatch (phase 3: one call of
+        # the chunk program carries the chunk and the decode rows, so the
+        # step streams the weights once); every other chunk is a call of
+        # that program with no decode row: here where this phase waits for
+        # it (a prompt's last chunk), else under that dispatch, ahead of the
+        # call that carries the rider (`_advance_chunks` says why).
         pre = [
             i for i, s in enumerate(self.slots)
             if s.request is not None and s.prefilling and s.pending_tok is None
         ]
+        rider = None
         if pre:
-            with spans.span("ds.serve.chunk", chunks=len(pre)) as sp:
-                n_tok = attended = 0
-                moe = []   # (counts, tokens) of the prompts that finished here
-                for i in pre:
-                    s = self.slots[i]
-                    t = min(self.chunk_width, s.request.prompt_len - s.prefill_pos)
-                    n_tok += t
-                    # key rows the call's queries read: the context before the
-                    # chunk for each of them, and the causal triangle inside it
-                    attended += t * s.prefill_pos + t * (t + 1) // 2
-                    got = self._advance_chunk(i)
-                    if got is not None:
-                        moe.append(got)
-                sp.set(tokens=n_tok, attended=attended)
-                if self._moe_rows_grouped:
-                    sp.set(moe_rows_grouped=len(pre) * self._moe_rows_grouped)
-                if moe:
-                    # a prompt's chunk calls report with its last one, whose
-                    # token fetch is the one wait there is
-                    counts = np.concatenate([c for c, _ in moe])
-                    sp.set(
-                        moe_calls=len(counts) // len(self.family.sparse_layers),
-                        **self._moe_attrs(counts, sum(n for _, n in moe)),
-                    )
+            if not self.spec_enabled and not self.disaggregated and any(
+                s.request is not None and not s.prefilling for s in self.slots
+            ):
+                rider = pre[0]
+            chunk_sp, moe_done, unwaited = self._advance_chunks(
+                [i for i in pre if i != rider], rider
+            )
 
         # 2c. disaggregated handoff completion (ISSUE 14): a slot whose
         # prefill placement has sampled the first token moves its prompt KV
@@ -1640,24 +1654,38 @@ class ServingEngine:
                     ))
                     self._c_spec_steps.inc()
                     self._c_spec_drafted.inc(self.spec_k * len(active))
+                elif rider is not None:
+                    # the further prefilling slots' calls that nothing waits
+                    # for, then the chunk program with the step's own rows:
+                    # the slots' tokens come back with the chunk's, in one fetch
+                    for i in unwaited:
+                        self._launch_alone(i)
+                    rode = self._chunk_reach(rider)[0]
+                    out, last = self._launch_chunk(rider, self.table.rows())
+                    self._c_chunks_rode.inc()
                 else:
                     dset = self.decode_set
                     out = dset.take_pools(self._decode_exec(
-                        dset.params, *dset.pool_args(),
-                        self.table.tokens, self.table.seq_lens,
-                        self.table.block_tables, self.table.keys,
+                        dset.params, *dset.pool_args(), *self.table.rows(),
                     ))
+            # a rider whose chunk was its prompt's last: its token is in `out`
+            started = self.slots[rider] if rider is not None and last else None
             # the ONE deliberate sync of the slot loop: the scheduler must
-            # read the sampled tokens to retire/advance slots
+            # read the sampled tokens to retire/advance slots (with them, a
+            # prompt's earlier calls' expert loads where its last chunk rode)
             with spans.span("ds.serve.decode.wait"):
-                out_np = jax.device_get(out)  # dslint: disable=host-sync-in-step
+                out_np, *alone_np = jax.device_get(  # dslint: disable=host-sync-in-step
+                    (out, *(started.moe_counts if started else ()))
+                )
             moe_np = None
             if self.family.sparse_layers:
                 out_np, moe_np = out_np  # the expert loads rode the same fetch
             with spans.span("ds.serve.emit") as sp:
                 if moe_np is not None:
                     sp.set(**self._moe_attrs(
-                        moe_np, len(active) * (self.spec_k + 1 if self.spec_enabled else 1)
+                        moe_np,
+                        len(active) * (self.spec_k + 1 if self.spec_enabled else 1)
+                        + (rode if rider is not None else 0),
                     ))
                 n_emit = n_fin = 0
                 now = self.clock()
@@ -1740,6 +1768,16 @@ class ServingEngine:
                         self._fail_slot(i, "injected slot stall", now)
                     elif slot.keys is not None and slot.step < len(slot.keys):
                         self.table.keys[i] = slot.keys[slot.step]
+                if started is not None:
+                    # the chunk that rode was its prompt's last: the first
+                    # token is this step's, decoding starts with the next
+                    if alone_np:
+                        moe_done.append((np.concatenate(alone_np), started.moe_tokens))
+                    started.moe_counts, started.moe_tokens = [], 0
+                    self._start_decoding(rider, int(out_np[-1]))
+                if rider is not None and moe_done:
+                    # the step's chunk leaf is closed: its record takes this
+                    chunk_sp.set(**self._moe_report(moe_done))
                 sp.set(tokens=n_emit, finished=n_fin)
 
         with spans.span("ds.serve.housekeep"):
@@ -1999,7 +2037,7 @@ class ServingEngine:
         slot.pages = pages
         slot.prefill_pages = prefill_pages
         slot.pending_tok = None
-        slot.moe_counts = []
+        slot.moe_counts, slot.moe_tokens = [], 0
         slot.pos = 0
         slot.step = 0
         slot.keys = None
@@ -2109,13 +2147,13 @@ class ServingEngine:
         are not reported)."""
         return out[0] if self.family.sparse_layers else out
 
-    def _advance_chunk(self, slot_i: int):
-        """One chunk of a PREFILLING slot's prompt through the chunk
-        program; on the final chunk the sampled token becomes the request's
-        first token and the slot joins the decode batch. → on that chunk, for
-        a family with expert layers, (the expert loads of the prompt's chunk
-        calls ``[calls x sparse layers, experts_held]``, the prompt's
-        tokens)."""
+    def _launch_chunk(self, slot_i: int, rows: tuple):
+        """The next chunk of a PREFILLING slot's prompt through the chunk
+        program, beside the decode ``rows`` (a step's own: the chunk rides
+        its dispatch; the idle table's: a call with no decode row). → (the
+        call's results after the pools, on the device: the tokens ``[slots +
+        1]``, the chunk's last, and for a family with expert layers their
+        loads; whether that was the prompt's last chunk)."""
         slot = self.slots[slot_i]
         req = slot.request
         C = self.chunk_width
@@ -2131,39 +2169,117 @@ class ServingEngine:
         page_ids[: len(avail)] = avail
         key0 = _host_prng_key(req.seed)
         pset = self.prefill_set
-        tok = pset.take_pools(self._chunk_exec(
-            pset.params, *pset.pool_args(),
+        out = pset.take_pools(self._chunk_exec(
+            pset.params, *pset.pool_args(), *rows,
             ids, np.asarray(start, np.int32),
             np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
             *self._slot_operand(slot_i),
         ))
-        if self.family.sparse_layers:
-            tok, counts = tok
-            slot.moe_counts.append(counts)  # on the device until the last chunk
         self._c_chunks.inc()
         slot.prefill_pos = start + C
+        final = slot.prefill_pos >= req.prompt_len
+        if final:
+            self._c_prefills.inc()
         if self.tracer is not None:
             self.tracer.event(
                 req, "prefill_chunk", self.clock(), step=self._step_count,
-                slot=slot_i, start=start, width=C,
-                final=slot.prefill_pos >= req.prompt_len,
+                slot=slot_i, start=start, width=C, final=final,
             )
-        if slot.prefill_pos < req.prompt_len:
-            return  # more chunks; the decode batch advances meanwhile
-        self._c_prefills.inc()
-        if self.disaggregated:
-            # the final chunk's sample stays on device; step phase 2c syncs
-            # it and hands the prompt KV off to the decode placement
-            slot.pending_tok = tok
-            return
-        # deliberate sync, as in _admit: the final chunk's sample is the
-        # request's first token
-        with spans.span("ds.serve.chunk.wait"):
-            tok_np, *counts = jax.device_get((tok, *slot.moe_counts))  # dslint: disable=host-sync-in-step
-        slot.moe_counts = []
-        self._start_decoding(slot_i, int(tok_np[0]))
-        if counts:
-            return np.concatenate(counts), req.prompt_len - req.prefix_shared_tokens
+        return out, final
+
+    def _chunk_reach(self, slot_i: int) -> tuple:
+        """(prompt tokens the next chunk of a PREFILLING slot advances, key
+        rows their queries read: the context before the chunk for each of
+        them and the causal triangle inside it)."""
+        slot = self.slots[slot_i]
+        t = min(self.chunk_width, slot.request.prompt_len - slot.prefill_pos)
+        return t, t * slot.prefill_pos + t * (t + 1) // 2
+
+    def _moe_report(self, prompts: list) -> dict:
+        """``ds.serve.chunk``'s expert attributes for the prompts that
+        finished prefilling: (loads ``[calls x sparse layers, experts_held]``
+        of a prompt's chunk calls that rode no decode step, the tokens those
+        advanced) each. A call that rode is in its step's ``ds.serve.emit``."""
+        counts = np.concatenate([c for c, _ in prompts])
+        return dict(
+            moe_calls=len(counts) // len(self.family.sparse_layers),
+            **self._moe_attrs(counts, sum(n for _, n in prompts)),
+        )
+
+    def _launch_alone(self, slot_i: int):
+        """:meth:`_launch_chunk` with no decode row (the idle table's rows):
+        the call's expert loads wait on the slot, on the device, for the
+        prompt's last chunk. → (the call's tokens on the device, whether that
+        was the prompt's last chunk)."""
+        slot = self.slots[slot_i]
+        t = self._chunk_reach(slot_i)[0]
+        out, final = self._launch_chunk(slot_i, self._idle_table.rows())
+        if self.family.sparse_layers:
+            out, counts = out
+            slot.moe_counts.append(counts)
+            slot.moe_tokens += t
+        return out, final
+
+    def _advance_chunks(self, alone: list, rider: int = None):
+        """The ``ds.serve.chunk`` leaf of a step: one call of the chunk
+        program with no decode row for each slot of ``alone``; ``rider`` is
+        the slot whose chunk the step's decode dispatch will carry, if one
+        will (the leaf counts its tokens and key rows beside the others').
+        On a prompt's final chunk the sampled token becomes the request's
+        first token and the slot joins the decode batch. → (the span,
+        closed; (loads, tokens) of the prompts that finished here, for
+        :meth:`_moe_report`; the slots of ``alone`` whose call the dispatch
+        leaf launches). With a ``rider`` the caller reports the prompts on
+        the span once the step's fetch is in, which may bring one prompt
+        more (the rider's, where its last chunk rode): the ring's record
+        takes attributes until the step ends.
+
+        Which calls the dispatch leaf launches, ahead of the one that carries
+        the ``rider``: those nothing here waits for (not a prompt's last
+        chunk). A trace names every call of this program after the decode
+        programs, and its reader takes a program of that name that starts
+        shortly before a dispatch leaf's launch for that leaf's own and sets
+        the device's clock by it; launched under the leaf, none does. A call
+        this leaf waits for has ended before the dispatch opens."""
+        with spans.span(
+            "ds.serve.chunk", chunks=len(alone), rode=int(rider is not None)
+        ) as sp:
+            n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
+            moe = []   # (counts, tokens) of the prompts that finished here
+            unwaited = []
+            for i in alone:
+                slot = self.slots[i]
+                t, att = self._chunk_reach(i)
+                n_tok += t
+                attended += att
+                if rider is not None and slot.prefill_pos + t < slot.request.prompt_len:
+                    unwaited.append(i)
+                    continue
+                out, final = self._launch_alone(i)
+                if not final:
+                    continue  # more chunks; the decode batch advances meanwhile
+                if self.disaggregated:
+                    # the final chunk's sample stays on device; step phase 2c
+                    # syncs it and hands the prompt KV off to the decode
+                    # placement
+                    slot.pending_tok = out
+                    continue
+                # deliberate sync, as in _admit: the final chunk's sample is
+                # the request's first token
+                with spans.span("ds.serve.chunk.wait"):
+                    tok_np, *counts = jax.device_get((out, *slot.moe_counts))  # dslint: disable=host-sync-in-step
+                if counts:
+                    moe.append((np.concatenate(counts), slot.moe_tokens))
+                slot.moe_counts, slot.moe_tokens = [], 0
+                self._start_decoding(i, int(tok_np[-1]))
+            sp.set(tokens=n_tok, attended=attended)
+            if self._moe_rows_grouped:
+                sp.set(moe_rows_grouped=(len(alone) + (rider is not None)) * self._moe_rows_grouped)
+            if moe and rider is None:
+                # a prompt's chunk calls report with its last one, whose
+                # token fetch is the one wait there is
+                sp.set(**self._moe_report(moe))
+        return sp, moe, unwaited
 
     def _complete_handoff(self, slot_i: int) -> None:
         """Finish a disaggregated prefill (ISSUE 14): read the pending first
@@ -2181,8 +2297,9 @@ class ServingEngine:
         req = slot.request
         # phase 2c only calls here once the array is ready (or nothing is
         # decoding, so blocking costs no batch progress)
+        # a whole prefill's [1] or a chunk call's [slots + 1]: the prompt's last
         with spans.span("ds.serve.handoff.wait"):
-            tok0 = int(jax.device_get(slot.pending_tok)[0])  # dslint: disable=host-sync-in-step
+            tok0 = int(jax.device_get(slot.pending_tok)[-1])  # dslint: disable=host-sync-in-step
         slot.pending_tok = None
         if req.max_new_tokens == 1 or (
             req.eos_token_id is not None and tok0 == req.eos_token_id

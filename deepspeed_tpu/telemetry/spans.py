@@ -46,7 +46,9 @@ class Span:
         self._ann = None
 
     def set(self, **attrs: Any) -> None:
-        """Attributes that are only known at exit (tokens emitted, slots left)."""
+        """Attributes that are only known at exit (tokens emitted, slots left)
+        or, for the ring's record alone, shortly after it (what a later fetch
+        of the same step brought: the record holds this span's own dict)."""
         self.attrs.update(attrs)
         if self._ann is not None:
             self._ann.set_metadata(**attrs)
@@ -72,6 +74,7 @@ class Span:
         self.t1 = _clock()
         if self._ann is not None:
             self._ann.__exit__(*exc)
+            self._ann = None  # a later ``set`` reaches the record alone
         self._sink.append((self.name, self.t0, self.t1, self.attrs))
         return False
 

@@ -446,6 +446,53 @@ class TestLayerIndexedPool:
         assert bool(jnp.all(whole == sliced))
         assert np.isfinite(np.asarray(whole, np.float32)).all()
 
+    @pytest.mark.parametrize("case", ["bf16", "int8", "gqa"])
+    @pytest.mark.parametrize("kernel", ["decode", "multitoken"])
+    def test_a_named_call_takes_the_layer_as_an_operand_and_is_bitwise_the_same(self, kernel, case, monkeypatch):
+        """ISSUE 35: a call with a ``name`` into a deep pool goes through a
+        jitted twin whose layer is a traced scalar (the pools' index maps read
+        it from a prefetched operand), so that a program's layers share one
+        traced kernel. Same bits as the static layer, for each layer, a
+        window's lower bound included; the twin is traced once for all of
+        them, and a shallow pool's calls stay in place."""
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        rs, qdt, k5, v5, bt, scales = self._pools(case)
+        assert not da._shares_kernel(k5, 0, "decode_fn") and da.SHARED_FROM_LAYERS > self.L
+        monkeypatch.setattr(da, "SHARED_FROM_LAYERS", self.L)
+        assert da._shares_kernel(k5, 0, "decode_fn") and not da._shares_kernel(k5, 0, None)
+        at = jnp.asarray([0, 13, 27], jnp.int32)
+        lo = jnp.asarray([0, 5, 11], jnp.int32)
+        if kernel == "decode":
+            fn, q = da.paged_decode_attention, rs.randn(3, 4, 64)
+        else:
+            fn, q = da.paged_multitoken_attention, rs.randn(3, 5, 4, 64)
+        q = jnp.asarray(q, qdt)
+        before = da._for_all_layers._cache_size()
+        for layer in ((self.LAYER,) if case == "int8" else range(self.L)):
+            for bound in (None, lo):
+                plain = fn(q, k5, v5, bt, at, interpret=True, scales=scales, layer=layer, lo=bound)
+                named = fn(q, k5, v5, bt, at, interpret=True, scales=scales, layer=layer, lo=bound,
+                           name="decode_fn")
+                assert named.dtype == plain.dtype and bool(jnp.all(named == plain))
+        assert da._for_all_layers._cache_size() - before == 2     # with and without the bound, not one a layer
+
+    def test_the_shared_token_write_is_the_token_write(self, monkeypatch):
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        monkeypatch.setattr(da, "SHARED_FROM_LAYERS", self.L)
+        rs, qdt, k5, v5, bt, _ = self._pools("bf16", seed=5)
+        pidx = jnp.asarray([[3, 3], [7, 8], [0, 0]], jnp.int32)
+        poff = jnp.asarray([[2, 3], [7, 0], [0, 1]], jnp.int32)
+        kn = jnp.asarray(rs.randn(3, 2, k5.shape[2], 64), k5.dtype)
+        vn = jnp.asarray(rs.randn(3, 2, k5.shape[2], 64), k5.dtype)
+        for layer in range(self.L):
+            want = da.paged_token_write(k5, v5, layer, pidx, poff, kn, vn, interpret=True)
+            got = da.paged_token_write(k5, v5, layer, pidx, poff, kn, vn, interpret=True, shared=True)
+            for g, w in zip(got, want):
+                assert bool(jnp.all(g[:, 1:] == w[:, 1:]))         # page 0 is scratch: two rows name it
+        assert da._token_write_for_all_layers._cache_size() == 1
+
     @pytest.mark.parametrize("kernel", ["decode", "multitoken"])
     def test_dispatcher_fallback_slices_the_layer(self, kernel):
         from deepspeed_tpu.ops.attention import (

@@ -8,6 +8,7 @@ that runs this file loads the TPU library (see the on-chip-measurement
 guide). Keep such tests in this one file."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -138,7 +139,7 @@ def test_paged_token_write_compiles_for_v5e(one_chip, name, T):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
-@pytest.mark.parametrize("program", ["decode", "verify", "chunk", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "verify", "chunk", "mixed", "prefill"])
 def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypatch):
     """ISSUE 29's census: the paged programs at XL width, 512 pages
     and 4 layers, the pools stored with the page axis split (``kv_cache.
@@ -160,6 +161,11 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
     # the pool is split, and the token write takes its kernel, where the
     # backend is a TPU: say so
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if program == "mixed":
+        # as at the served depth of 48: the layers' calls of each kernel share
+        # one traced kernel whose layer is a prefetched operand
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        monkeypatch.setattr(da, "SHARED_FROM_LAYERS", L)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -193,6 +199,14 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
             (sds((1, C), i32), sds((), i32), sds((), i32),
              sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
+        "mixed": (  # what the engine compiles in the chunk program's place (ISSUE 35)
+            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key:
+                smodel.paged_mixed_step(
+                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
+             sds((1, C), i32), sds((), i32), sds((), i32),
+             sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
         "prefill": (
             lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(
                 cfg, p, ids, plen, k, v, pages, key),
@@ -217,6 +231,10 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
         assert "dynamic-update-slice(" not in text
     if program == "verify":
         assert text.count("custom_call_target=\"tpu_custom_call\"") == 5 * L
+    if program == "mixed":  # both attention kernels and the token write a layer, under their own names
+        assert text.count("custom_call_target=\"tpu_custom_call\"") == 3 * L
+        for kernel in ("decode_fn", "chunk_fn", "kv_token_write"):
+            assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
     for fmt in (took_in[1], took_in[2], *compiled.output_formats[:2]):
         assert fmt.layout.major_to_minor == (0, 1, 2, 3, 4, 5)
@@ -224,7 +242,7 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_kv_bytes
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed", "prefill"])
 def test_window_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
     """The ``exaone_moe`` programs as the K-EXAONE cell serves them (64 slots,
     64 query heads on 8 kv heads of 128, a paged full layer beside four
@@ -279,6 +297,15 @@ def test_window_family_programs_compile_at_the_served_size(one_chip, program, mo
             (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
              sds((1, W), i32), sds((2,), u32), sds((), i32)),
         ),
+        "mixed": (
+            lambda p, k, v, kw, vw, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
+                smodel.paged_mixed_step(
+                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key,
+                    win=(kw, vw), slot=slot, ring=ring),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
+             sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
+             sds((1, W), i32), sds((2,), u32), sds((), i32)),
+        ),
         "prefill": (
             lambda p, k, v, kw, vw, ids, plen, pages, key, slot: smodel.paged_prefill(
                 cfg, p, ids, plen, k, v, pages, key, win=(kw, vw), slot=slot, ring=ring),
@@ -297,7 +324,7 @@ def test_window_family_programs_compile_at_the_served_size(one_chip, program, mo
     assert pset.program_census(program, compiled)[0] == 0  # or it raises
     calls = text.count("custom_call_target=\"tpu_custom_call\"")
     # an attention kernel a layer, and in the decode step a token write a layer
-    assert calls == {"decode": 10, "chunk": 5, "prefill": 0}[program]
+    assert calls == {"decode": 10, "chunk": 5, "mixed": 15, "prefill": 0}[program]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9   # of the chip's 16
@@ -457,7 +484,7 @@ def test_a_latent_pool_is_row_major_only_with_whole_lane_tiles_a_row(one_chip, m
     assert pool_stored_shape(6, 9313, 1, 128, 320, jnp.bfloat16, latent=True) == (6, 9313, 1, 128, 384)
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed", "prefill"])
 def test_latent_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
     """The ``mistral4`` programs as the long-document cell serves them (48
     slots, 32 query heads on one 384-lane latent row a token, 128-token pages,
@@ -499,6 +526,12 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
                 cfg, p, ids, start, plen, k, v, pages, bt, key),
             (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
+        "mixed": (
+            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
+                cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
+             sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
         "prefill": (
             lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
@@ -516,11 +549,14 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     calls = text.count("custom_call_target=\"tpu_custom_call\"")
     # an attention kernel a layer, and in the decode step a token write a layer;
     # the grouped expert products are the compiler's own ragged-dot calls
-    want = {"decode": 2 * L, "chunk": L, "prefill": 0}[program]
+    want = {"decode": 2 * L, "chunk": L, "mixed": 3 * L, "prefill": 0}[program]
     ragged = text.count("ragged-dot") > 0
     assert ragged == (program != "decode")
     if program == "decode":
         assert calls == want
+    if program == "mixed":  # the kernels keep the names the readers find them by
+        for kernel in ("mla_paged_decode", "mla_paged_chunk", "kv_token_write"):
+            assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
     assert took_in[1].layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].layout.major_to_minor
     mem = compiled.memory_analysis()

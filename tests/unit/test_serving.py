@@ -337,8 +337,8 @@ class TestTokenWrite:
         assert not da.paged_token_write_ok(4, 1024, 128, 4)  # no head's pages fit
         calls = []
 
-        def spy(k_pool, v_pool, l, pidx, poff, k_vals, v_vals):
-            calls.append((l, k_vals.shape))
+        def spy(k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared=False):
+            calls.append((l, k_vals.shape) + ((shared,) if shared else ()))
             return k_pool, v_pool
 
         monkeypatch.setattr(da, "paged_token_write", spy)
@@ -347,6 +347,9 @@ class TestTokenWrite:
         vals = jnp.zeros((3, 5, 64))
         smodel._scatter_tokens(pool, pool, 1, i, i, vals, vals)
         assert calls == [(1, (3, 5, 64))]
+        # the mixed step asks for one kernel for all its layers (ISSUE 35)
+        smodel._scatter_tokens(pool, pool, 0, i, i, vals, vals, shared=True)
+        assert calls[1:] == [(0, (3, 5, 64), True)]
 
     def test_int8_token_write_keeps_scale_discipline(self):
         """``_write_pool_tokens``: offset 0 establishes the page's scale from

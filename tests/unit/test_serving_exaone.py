@@ -118,13 +118,21 @@ def test_spans_and_counters_report_the_expert_loads(engine, prompts):
     recs = [r for r in spans.snapshot(since=t0)]
     emits = [r[3] for r in recs if r[0] == "ds.serve.emit"]
     assert emits and all({"moe_pairs_held", "moe_pairs_routed", "moe_load_max", "moe_experts_hit"} <= set(a) for a in emits)
-    for a, d in zip(emits, [r[3] for r in recs if r[0] == "ds.serve.decode.dispatch"]):
-        assert a["moe_pairs_routed"] == d["active"] * 4 * 2              # tokens x top-4 x 2 sparse layers
+    disp = [r[3] for r in recs if r[0] == "ds.serve.decode.dispatch"]
+    for a, d in zip(emits, disp):
+        # tokens x top-4 x 2 sparse layers; a step that carried a chunk counts the chunk's tokens too
+        rode = a["moe_pairs_routed"] // (4 * 2) - d["active"]
+        assert a["moe_pairs_routed"] % (4 * 2) == 0 and 0 <= rode <= 8
         assert 0 <= a["moe_pairs_held"] <= a["moe_pairs_routed"] and a["moe_experts_hit"] <= 4 * 2
-        assert a["moe_load_max"] <= d["active"]
-    chunks = [r[3] for r in recs if r[0] == "ds.serve.chunk" and "moe_calls" in r[3]]
-    assert sum(c["moe_calls"] for c in chunks) == sum(-(-len(p) // 8) for p in prompts[:4] if len(p) > 8)
-    assert sum(c["moe_pairs_routed"] for c in chunks) == sum(len(p) for p in prompts[:4] if len(p) > 8) * 4 * 2
+        assert a["moe_load_max"] <= d["active"] + rode
+    every = [r[3] for r in recs if r[0] == "ds.serve.chunk"]
+    chunks = [c for c in every if "moe_calls" in c]
+    long = [len(p) for p in prompts[:4] if len(p) > 8]
+    # a prompt reports the calls that rode no decode step; one that rode is in its step's emit
+    assert sum(c["chunks"] + c["rode"] for c in every) == sum(-(-n // 8) for n in long)
+    assert sum(c["moe_calls"] for c in chunks) == sum(c["chunks"] for c in every) > 0
+    assert sum(c["rode"] for c in every) == srv.metrics.counter("serving_chunks_rode_total", "").value() > 0
+    assert sum(a["moe_pairs_routed"] for a in emits + chunks) == (sum(d["active"] for d in disp) + sum(long)) * 4 * 2
     held = srv.metrics.counter("serving_moe_pairs_held_total", "").value()
     routed = srv.metrics.counter("serving_moe_pairs_routed_total", "").value()
     assert held == sum(a["moe_pairs_held"] for a in emits + chunks)

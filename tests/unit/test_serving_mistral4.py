@@ -183,15 +183,21 @@ def test_spans_and_counters_count_latent_rows_and_expert_loads(engine, prompts):
     recs = [r for r in spans.snapshot(since=t0)]
     emits = [r[3] for r in recs if r[0] == "ds.serve.emit"]
     assert emits and all({"moe_pairs_held", "moe_pairs_routed", "moe_load_max", "moe_experts_hit"} <= set(a) for a in emits)
-    for a, d in zip(emits, [r[3] for r in recs if r[0] == "ds.serve.decode.dispatch"]):
-        assert a["moe_pairs_routed"] == d["active"] * 2 * 2              # tokens x top-2 x 2 layers
+    disp = [r[3] for r in recs if r[0] == "ds.serve.decode.dispatch"]
+    for a, d in zip(emits, disp):
+        # tokens x top-2 x 2 layers; a step that carried a chunk counts the chunk's tokens too
+        assert a["moe_pairs_routed"] % (2 * 2) == 0 and 0 <= a["moe_pairs_routed"] // (2 * 2) - d["active"] <= 8
         assert d["active"] <= d["attended"] and d["pages"] >= d["active"]
     chunks = [r[3] for r in recs if r[0] == "ds.serve.chunk"]
     long = [len(p) for p in prompts[:4] if len(p) > 8]
     assert sum(c["tokens"] for c in chunks) == sum(long)
     # a prompt of n tokens in chunks: every query reads the rows before it and itself, n (n + 1) / 2 in all
     assert sum(c["attended"] for c in chunks) == sum(n * (n + 1) // 2 for n in long)
-    assert sum(c["moe_calls"] for c in chunks if "moe_calls" in c) == sum(-(-n // 8) for n in long)
+    # a prompt reports the calls that rode no decode step; one that rode is in its step's emit
+    assert sum(c["chunks"] + c["rode"] for c in chunks) == sum(-(-n // 8) for n in long)
+    assert sum(c["moe_calls"] for c in chunks if "moe_calls" in c) == sum(c["chunks"] for c in chunks)
+    reports = [c for c in chunks if "moe_calls" in c]
+    assert sum(a["moe_pairs_routed"] for a in emits + reports) == (sum(d["active"] for d in disp) + sum(long)) * 2 * 2
     assert not any("moe_rows_grouped" in c for c in chunks)      # 8 rows a call: the masked form
     prog = [r[3] for r in spans.phases(since=t0) if r[0] == "ds.init.programs"][-1]
     assert "latent=" in prog["kv_bytes"] and prog["kv_row_bytes"] == 24 * 4 and prog["moe_experts_held"] == 4

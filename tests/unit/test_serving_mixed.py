@@ -1,0 +1,379 @@
+"""The mixed step (ISSUE 35): a prefilling slot's chunk and the decode rows of
+a step through the weights once, in the chunk program's place.
+
+Three families at their tiny sizes on the CPU, float32: ``gpt2`` (two pools,
+also int8), ``exaone_moe`` (a paged layer beside window rings, expert layers)
+and ``mistral4`` (one latent pool, expert layers). The program itself against
+the chunk call followed by the decode step on the same pools; the engine with
+chunks riding against every request served alone (where nothing decodes, so
+no chunk can ride); when a rider starts decoding and what stamps its first
+token; two slots prefilling in one step; the counts the benchmark's readers
+live on; and the paths that must not ride (speculation, disaggregation)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.inference.engine import InferenceEngine
+from deepspeed_tpu.models import exaone_moe, gpt2, mistral4
+from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.telemetry import spans
+
+from .test_serving_exaone import CFG as KX_CFG
+from .test_serving_mistral4 import CFG as MS4_CFG
+
+SERVING = dict(max_slots=3, page_size=4, num_pages=96, max_prompt_len=40, max_new_tokens=12,
+               prefill_chunk_tokens=8, temperature=0.0, kv_cache_dtype="float32")
+PROMPTS = (5, 19, 33, 40, 27, 9, 22)    # the whole-prompt program (<= one chunk) and 3-5 chunks
+FAMILIES = ("gpt2", "exaone_moe", "mistral4")
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """family -> (engine, vocabulary), each built once."""
+    made = {}
+
+    def get(family):
+        if family not in made:
+            if family == "gpt2":
+                cfg = gpt2.get_config("gpt2-tiny", attn_impl="jnp")
+                eng = InferenceEngine(gpt2.make_module(cfg), params=gpt2.init_params(cfg, jax.random.PRNGKey(0)),
+                                      dtype=jnp.float32)
+                made[family] = (eng, cfg.vocab_size)
+            else:
+                mod, cfg = (exaone_moe, exaone_moe.ExaoneMoEConfig.from_dict(KX_CFG)) if family == "exaone_moe" \
+                    else (mistral4, mistral4.Mistral4Config.from_dict(MS4_CFG))
+                made[family] = (deepspeed_tpu.init_inference(model=mod.make_module(cfg), dtype=jnp.float32, seed=3),
+                                cfg.vocab_size)
+        return made[family]
+
+    return get
+
+
+def _prompts(vocab, lens=PROMPTS, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+
+
+def _together(engine, prompts, **over):
+    srv = engine.serve(dict(SERVING, **over))
+    reqs = [srv.submit(p, max_new_tokens=12, seed=i) for i, p in enumerate(prompts)]
+    srv.run()
+    return srv, reqs
+
+
+def _alone(engine, prompts, **over):
+    """Each request through a server that holds nothing else: its chunks find
+    no decode step to ride, its decode steps carry no chunk."""
+    srv = engine.serve(dict(SERVING, **over))
+    reqs = []
+    for i, p in enumerate(prompts):
+        reqs.append(srv.submit(p, max_new_tokens=12, seed=i))
+        srv.run()
+    return srv, reqs
+
+
+def _count(srv, name):
+    return srv.metrics.counter(name, "").value()
+
+
+def _steps(recs):
+    """The ring's records, a list of {leaf name: attrs} a ``ds.serve.step``
+    (a step's leaves are recorded before the step itself)."""
+    out, cur = [], {}
+    for name, _, _, attrs in recs:
+        if name == "ds.serve.step":
+            out.append(cur)
+            cur = {}
+        else:
+            cur[name] = attrs
+    return out
+
+
+# -- the program ----------------------------------------------------------------
+
+def _pools(fam, rng, P, page, B, ring, int8):
+    """Pools whose every page holds something (a cached context is any values
+    at all): (k_pool, v_pool | None, scales | None, win | None)."""
+    n_paged = sum(1 for w in fam.windows if not w)
+
+    def pool(layers, pages):
+        shape = (layers, pages, fam.n_kv_head, page, fam.head_dim)
+        if int8:
+            return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        return jnp.asarray(rng.normal(size=shape) * 0.3, jnp.float32)
+
+    k = pool(n_paged, P)
+    v = pool(n_paged, P) if fam.kv_pools == 2 else None
+    scales = jnp.asarray(rng.uniform(0.002, 0.004, (n_paged, P, fam.n_kv_head, 2)), jnp.float32) if int8 else None
+    n_win = len(fam.windows) - n_paged
+    win = (pool(n_win, 1 + B * ring), pool(n_win, 1 + B * ring)) if n_win else None
+    return k, v, scales, win
+
+
+def _one_call(engines, family, int8):
+    """A step's operands: slot 2 prefills (two pages cached, the chunk at 8
+    of a 13-token prompt: 5 real rows), 0 and 3 decode, 1 is empty."""
+    engine, vocab = engines(family)
+    cfg, params = engine.model_config, engine.params
+    fam = cfg.serving_family()
+    rng = np.random.default_rng(5)
+    B, page, P, W, C = 4, 4, 40, 13, 8
+    ring = -(-(max(fam.windows) + C) // page) + 1 if any(fam.windows) else 0
+    k, v, scales, win = _pools(fam, rng, P, page, B, ring, int8)
+    row = np.zeros((1, W), np.int32)
+    row[0, :5] = [21, 22, 23, 24, 25]
+    bt = np.zeros((B, W), np.int32)
+    bt[0, :4], bt[3, :6] = [1, 2, 3, 4], [5, 6, 7, 8, 9, 10]
+    seq_lens = np.asarray([9, 0, 0, 20], np.int32)        # slot 3 writes at offset 0 of a page: an int8 scale is set
+    tokens = np.asarray([7, 0, 0, 11], np.int32)
+    keys = np.zeros((B, 2), np.uint32)
+    ids = rng.integers(0, vocab, (1, C)).astype(np.int32)
+    start, plen, slot = np.int32(8), np.int32(13), np.int32(2)
+    page_ids, key0 = np.asarray([23, 24], np.int32), np.asarray([0, 3], np.uint32)
+    kw = dict(scales=scales, win=win, ring=ring, slot=slot)
+    n_pools = 2 + int8 + 2 * (win is not None)            # _result's order: k, v, scales, rings, token, counts
+    chunk = (ids, start, plen, k, v)
+    return cfg, params, fam, (tokens, seq_lens), chunk, bt, (page_ids, row), (keys, key0), kw, n_pools
+
+
+@pytest.mark.parametrize("family,int8", [("gpt2", False), ("gpt2", True), ("exaone_moe", False), ("mistral4", False)])
+def test_one_mixed_call_is_the_chunk_call_then_the_decode_step(engines, family, int8):
+    """The same pools, rows and keys: tokens equal, every real page equal
+    (the scratch page takes idle rows' and padding's writes in any order)."""
+    cfg, params, fam, (tokens, seq_lens), (ids, start, plen, k, v), bt, (page_ids, row), (keys, key0), kw, n_pools = \
+        _one_call(engines, family, int8)
+    B, ring, win, slot = len(tokens), kw["ring"], kw["win"], kw.pop("slot")
+    after_chunk = smodel.paged_chunk_prefill(cfg, params, ids, start, plen, k, v, page_ids, row, key0, slot=slot, **kw)
+    k1, v1 = after_chunk[:2]
+    s1 = after_chunk[2] if int8 else None
+    w1 = tuple(after_chunk[n_pools - 2:n_pools]) if win is not None else None
+    tok_c = after_chunk[n_pools]
+    after_step = smodel.paged_decode_step(cfg, params, tokens, seq_lens, k1, v1, bt, keys, scales=s1, win=w1, ring=ring)
+    mixed = smodel.paged_mixed_step(cfg, params, tokens, seq_lens, ids, start, plen, k, v, bt, page_ids, row, keys,
+                                    key0, slot=slot, **kw)
+
+    toks = np.asarray(mixed[n_pools])
+    assert toks.shape == (B + 1,)
+    np.testing.assert_array_equal(toks[[0, 3]], np.asarray(after_step[n_pools])[[0, 3]])
+    np.testing.assert_array_equal(toks[-1:], np.asarray(tok_c))
+    for i in range(n_pools):
+        if mixed[i] is None:                               # a latent family has no V pool
+            assert after_step[i] is None
+            continue
+        got, want = np.asarray(mixed[i], np.float32), np.asarray(after_step[i], np.float32)
+        np.testing.assert_allclose(got[:, 1:], want[:, 1:], atol=1 if int8 and i < 2 else 1e-5)   # a code may round apart
+    if win is not None:
+        # the prefilling slot's own decode row is idle: its ring holds the chunk's pages and nothing of that row
+        mine = 1 + 2 * ring + np.arange(ring)
+        for got, chunk_only in zip(mixed[n_pools - 2:n_pools], w1):
+            np.testing.assert_allclose(np.asarray(got)[:, mine], np.asarray(chunk_only)[:, mine], atol=1e-5)
+    if fam.sparse_layers:
+        counts, c_chunk, c_step = (np.asarray(x[n_pools + 1]) for x in (mixed, after_chunk, after_step))
+        assert counts.shape == (len(fam.sparse_layers), fam.experts_held)
+        np.testing.assert_array_equal(counts, c_chunk + c_step)    # ONE count of the call's real tokens
+        assert counts.sum() <= (5 + 2) * fam.experts_per_token * len(fam.sparse_layers)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_call_with_no_real_decode_row_is_the_chunk_call_and_may_skip_the_rows_reads(engines, family, monkeypatch):
+    """What a chunk takes that rides nothing: the chunk's token and every real
+    page are the call's with no decode row at all, whether the idle rows'
+    attention is skipped (as it is where the slots are many) or read; and
+    with a real row the skip's conditional changes nothing."""
+    cfg, params, fam, rows, (ids, start, plen, k, v), bt, (page_ids, row), (keys, key0), kw, n_pools = \
+        _one_call(engines, family, False)
+
+    def call(rows, bt, skip):
+        monkeypatch.setattr(smodel, "SKIP_IDLE_READS_FROM_SLOTS", 1 if skip else 1 << 30)
+        return smodel.paged_mixed_step(cfg, params, *rows, ids, start, plen, k, v, bt, page_ids, row, keys, key0, **kw)
+
+    def same(a, b, toks):
+        np.testing.assert_array_equal(np.asarray(a[n_pools])[toks], np.asarray(b[n_pools])[toks])
+        for x, y in zip(a[:n_pools], b[:n_pools]):
+            if x is not None:
+                np.testing.assert_allclose(np.asarray(x)[:, 1:], np.asarray(y)[:, 1:], atol=1e-5)
+
+    alone = smodel.paged_chunk_prefill(cfg, params, ids, start, plen, k, v, page_ids, row, key0, **kw)
+    idle = (np.zeros_like(rows[0]), np.zeros_like(rows[1]))
+    for skip in (True, False):
+        got = call(idle, np.zeros_like(bt), skip)
+        same((*got[:n_pools], got[n_pools][-1:]), alone, slice(None))       # tokens [slots + 1]: the chunk's is last
+        if fam.sparse_layers:                                   # idle rows are no tokens of the call
+            np.testing.assert_array_equal(np.asarray(got[n_pools + 1]), np.asarray(alone[n_pools + 1]))
+    same(call(rows, bt, True), call(rows, bt, False), [0, 3, 4])
+
+
+# -- the engine -------------------------------------------------------------------
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_requests_served_with_chunks_riding_get_the_tokens_they_get_alone(engines, family):
+    engine, vocab = engines(family)
+    prompts = _prompts(vocab)
+    srv, mixed = _together(engine, prompts)
+    ref, alone = _alone(engine, prompts)
+    for a, b in zip(mixed, alone):
+        assert a.status == b.status == "finished" and list(a.tokens) == list(b.tokens)
+    n_chunks = sum(-(-len(p) // 8) for p in prompts if len(p) > 8)
+    assert _count(srv, "serving_chunk_prefills_total") == _count(ref, "serving_chunk_prefills_total") == n_chunks
+    assert _count(ref, "serving_chunks_rode_total") == 0                 # alone: nothing decodes beside a prefill
+    assert 0 < _count(srv, "serving_chunks_rode_total") <= n_chunks
+    assert len(srv.executables) == srv.expected_executables == 3        # the mixed program is the chunk program
+    for s in (srv, ref):
+        s.drain(0.0)
+        s.check_no_leaks()
+
+
+@pytest.mark.parametrize("over", [{"kv_cache_dtype": "int8"}, {"prefix_cache": {"enabled": True}},
+                                  {"kv_cache_dtype": "int8", "prefix_cache": {"enabled": True}}],
+                         ids=["int8", "prefix", "int8-prefix"])
+def test_int8_pools_and_prefix_tails_ride_too(engines, over):
+    """The gpt2 family's other pools: int8 codes and scales, and a prefix
+    cache, whose hits' tails always take the chunk program."""
+    engine, vocab = engines("gpt2")
+    rng = np.random.default_rng(2)
+    shared = rng.integers(0, vocab, 16).astype(np.int32)
+    prompts = [np.concatenate([shared, rng.integers(0, vocab, n).astype(np.int32)]) for n in (3, 9, 14, 6, 20)]
+    srv, mixed = _together(engine, prompts, **over)
+    ref, alone = _alone(engine, prompts, **over)
+    for a, b in zip(mixed, alone):
+        assert a.status == b.status == "finished" and list(a.tokens) == list(b.tokens)
+    assert _count(srv, "serving_chunks_rode_total") > 0 == _count(ref, "serving_chunks_rode_total")
+    if "prefix_cache" in over:
+        assert srv.stats()["prefix_hits_partial"] + srv.stats()["prefix_hits_full"] > 0
+    for s in (srv, ref):
+        s.drain(0.0)
+        s.release_prefix_cache()
+        s.check_no_leaks()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_rider_starts_decoding_the_step_after_its_last_chunk_and_that_step_stamps_its_first_token(engines, family):
+    engine, vocab = engines(family)
+    short, long = _prompts(vocab, (5, 20), seed=1)
+    ticks = iter(range(10_000))
+    from deepspeed_tpu.serving import ServingEngine
+    srv = ServingEngine(engine, dict(SERVING), clock=lambda: float(next(ticks)))
+    a = srv.submit(short, max_new_tokens=12, seed=0)
+    srv.step()                                     # whole prefill and the first decode step
+    assert len(a.tokens) == 2
+    b = srv.submit(long, max_new_tokens=12, seed=1)
+    for n_chunk in (1, 2, 3):                      # 20 tokens: three chunks, each beside a's decode step
+        t_before = float(next(ticks))
+        srv.step()
+        assert _count(srv, "serving_chunks_rode_total") == n_chunk == _count(srv, "serving_chunk_prefills_total")
+        assert len(a.tokens) == 2 + n_chunk         # no decode step was held back
+    # the last chunk's step: one token, no more (the parent decoded it in that same step)
+    assert len(b.tokens) == 1 and b.t_first_token is not None and b.t_first_token > t_before
+    assert b.t_emissions == [b.t_first_token] and a.t_emissions[-1] <= b.t_first_token
+    assert not any(s.prefilling for s in srv.slots if s.request is not None)
+    srv.step()
+    assert len(b.tokens) == 2 and len(a.tokens) == 6
+    srv.run()
+    _, alone = _alone(engine, [short, long])
+    assert [list(r.tokens) for r in (a, b)] == [list(r.tokens) for r in alone]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_of_two_slots_prefilling_in_a_step_one_rides_and_one_is_a_call_with_no_decode_row(engines, family):
+    engine, vocab = engines(family)
+    short, l1, l2 = _prompts(vocab, (5, 30, 26), seed=3)
+    t0 = spans._clock()
+    srv = engine.serve(dict(SERVING))
+    reqs = [srv.submit(short, max_new_tokens=12, seed=0)]
+    srv.step()
+    reqs += [srv.submit(p, max_new_tokens=12, seed=i + 1) for i, p in enumerate((l1, l2))]
+    launched, launch = [], srv._launch_chunk
+    srv._launch_chunk = lambda i, rows: launched.append((spans._clock(), rows[2].any())) or launch(i, rows)
+    srv.step()
+    srv._launch_chunk = launch
+    assert sum(1 for s in srv.slots if s.request is not None and s.prefilling) == 2
+    assert _count(srv, "serving_chunk_prefills_total") == 2 and _count(srv, "serving_chunks_rode_total") == 1
+    # both calls under the dispatch leaf, the one with no decode row first: nothing waits for it, and a trace's
+    # reader takes a program of this name launched shortly before a dispatch for that dispatch's own
+    (_, d0, d1, _), = [r for r in spans.snapshot(since=t0) if r[0] == "ds.serve.decode.dispatch"][-1:]
+    assert [rows for _, rows in launched] == [False, True] and all(d0 <= t <= d1 for t, _ in launched)
+    step = _steps(spans.snapshot(since=t0))[-1]
+    assert (step["ds.serve.chunk"]["chunks"], step["ds.serve.chunk"]["rode"]) == (1, 1)
+    assert step["ds.serve.chunk"]["tokens"] == 16 and step["ds.serve.decode.dispatch"]["active"] == 1
+    srv.run()
+    _, alone = _alone(engine, [short, l1, l2])
+    assert [list(r.tokens) for r in reqs] == [list(r.tokens) for r in alone]
+    assert _count(srv, "serving_chunk_prefills_total") == 4 + 4
+    srv.drain(0.0)
+    srv.check_no_leaks()
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
+    engine, vocab = engines(family)
+    fam = engine.model_config.serving_family()
+    prompts = _prompts(vocab)
+    t0 = spans._clock()
+    srv, reqs = _together(engine, prompts)
+    steps = _steps(spans.snapshot(since=t0))
+    chunks = [s["ds.serve.chunk"] for s in steps if "ds.serve.chunk" in s]
+    long = [len(p) for p in prompts if len(p) > 8]
+    # every chunk advanced is in tokens / attended, ridden or not; chunks counts the calls with no decode row
+    assert all(c["rode"] in (0, 1) for c in chunks)
+    assert sum(c["chunks"] + c["rode"] for c in chunks) == sum(-(-n // 8) for n in long)
+    assert sum(c["tokens"] for c in chunks) == sum(long)
+    assert sum(c["attended"] for c in chunks) == sum(n * (n + 1) // 2 for n in long)
+    assert sum(c["rode"] for c in chunks) == _count(srv, "serving_chunks_rode_total") > 0
+    assert sum(c["chunks"] + c["rode"] for c in chunks) == _count(srv, "serving_chunk_prefills_total")
+    n_sparse = len(fam.sparse_layers)
+    for s in steps:
+        d, c, e = (s.get(k) for k in ("ds.serve.decode.dispatch", "ds.serve.chunk", "ds.serve.emit"))
+        if c is not None and c["rode"]:
+            assert d is not None and d["active"] >= 1              # a chunk rides a decode dispatch only
+        if d is None:
+            continue
+        # the dispatch leaf counts the decode rows alone, whatever rode
+        assert e["tokens"] == d["active"] <= d["attended"] and d["pages"] >= d["active"]
+        if n_sparse:
+            rode = c["tokens"] if c is not None and c["rode"] and not c["chunks"] else None
+            routed = e["moe_pairs_routed"] // (fam.experts_per_token * n_sparse)
+            assert routed == d["active"] + rode if rode is not None else routed >= d["active"]
+            assert e["moe_experts_hit"] <= fam.experts_held * n_sparse      # the union over the call's rows
+            assert e["moe_load_max"] <= routed
+    assert sum(s["ds.serve.decode.dispatch"]["active"] for s in steps if "ds.serve.decode.dispatch" in s) \
+        == sum(len(r.tokens) - 1 for r in reqs)
+    if n_sparse:
+        reports = [c for c in chunks if "moe_calls" in c]
+        # no ridden call is reported under moe_calls: a prompt reports the calls that rode nothing
+        assert sum(c["moe_calls"] for c in reports) == sum(c["chunks"] for c in chunks)
+        emits = [s["ds.serve.emit"] for s in steps if "ds.serve.emit" in s]
+        per_token = fam.experts_per_token * n_sparse
+        assert sum(a["moe_pairs_routed"] for a in emits + reports) \
+            == (sum(len(r.tokens) - 1 for r in reqs) + sum(long)) * per_token
+        assert _count(srv, "serving_moe_pairs_held_total") == sum(a["moe_pairs_held"] for a in emits + reports)
+    else:
+        assert not any("moe_calls" in c for c in chunks)
+
+
+# -- what must not ride -------------------------------------------------------------
+
+@pytest.mark.parametrize("over,n_exe", [
+    ({"speculative": {"enabled": True, "k": 3, "ngram": 2}}, 3),
+    ({"placement": {"disaggregate": True}}, 5),
+    ({"placement": {"tp": 2}}, 3),
+], ids=["speculation", "disaggregated", "tp2"])
+def test_speculation_and_disaggregation_keep_the_chunk_alone_and_tp_rides(engines, over, n_exe):
+    """The verify step and a prefill placement of its own take the chunk
+    program with no decode row, as they took the chunk program; a
+    tensor-parallel placement is one placement and rides. Same tokens, same
+    number of executables."""
+    engine, vocab = engines("gpt2")
+    prompts = _prompts(vocab)
+    srv, got = _together(engine, prompts, **over)
+    _, alone = _alone(engine, prompts)
+    for a, b in zip(got, alone):
+        assert a.status == "finished" and list(a.tokens) == list(b.tokens)
+    assert len(srv.executables) == srv.expected_executables == n_exe
+    rode = _count(srv, "serving_chunks_rode_total")
+    assert rode > 0 if "tp" in over.get("placement", {}) else rode == 0
+    assert _count(srv, "serving_chunk_prefills_total") == sum(-(-len(p) // 8) for p in prompts if len(p) > 8)
+    srv.drain(0.0)
+    srv.check_no_leaks()

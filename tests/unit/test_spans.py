@@ -153,7 +153,7 @@ def test_leaves_tile_each_serve_step_and_carry_the_documented_attrs(served):
         ratios.append(sum(c[2] - c[1] for c in top) / (st[2] - st[1]))
         assert set(st[3]) == {"step", "queue", "active"}
     assert statistics.median(ratios) > 0.95
-    want = {"ds.serve.admit": {"admitted", "blocked"}, "ds.serve.chunk": {"chunks", "tokens", "attended"},
+    want = {"ds.serve.admit": {"admitted", "blocked"}, "ds.serve.chunk": {"chunks", "rode", "tokens", "attended"},
             "ds.serve.decode.dispatch": {"active", "attended", "pages"}, "ds.serve.decode.wait": set(),
             "ds.serve.emit": {"tokens", "finished"}, "ds.serve.housekeep": set(),
             "ds.serve.prefill.wait": set(), "ds.serve.chunk.wait": set()}
@@ -207,7 +207,7 @@ def test_serving_setup_is_recorded_as_phases_that_name_their_programs(served):
     inside = [p for p in phases if p[0].startswith("ds.jit.") and progs[0][1] <= p[1] and p[2] <= progs[0][2]]
     assert {p[0] for p in inside} == {"ds.jit.trace", "ds.jit.lower", "ds.jit.compile"}
     compiled = {p[3]["fun"] for p in inside if p[0] == "ds.jit.compile"}
-    for program in ("prefill_fn", "decode_fn", "chunk_fn"):   # jax calls them jit(prefill_fn), ...
+    for program in ("prefill_fn", "decode_fn", "chunk_decode_fn"):   # jax calls them jit(prefill_fn), ...
         assert any(program in f for f in compiled), (program, compiled)
 
 
