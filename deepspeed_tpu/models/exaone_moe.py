@@ -38,6 +38,7 @@ import numpy as np
 from ..moe.expert_share import ExpertShare, expert_share_layer, gated_ffn
 from ..ops.layer_norm import rms_norm
 from ..runtime.module import ModuleSpec
+from ..telemetry import parts
 
 PyTree = Any
 
@@ -259,7 +260,8 @@ class ExaoneFamily:
         takes positions: what goes into the cache is what attention reads."""
         cfg = self.cfg
         H, KV, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
+        with parts.part("norm"):
+            u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
         qkv = u @ lp["attn"]["wqkv"]
         q, k, v = jnp.split(qkv, [H * D, (H + KV) * D], axis=-1)
         q = rms_norm(q.reshape(*q.shape[:-1], H, D), lp["attn"]["q_norm"], cfg.rms_norm_eps)
@@ -276,7 +278,8 @@ class ExaoneFamily:
         """→ (the layer's MLP of the residual stream ``h [B, S, E]``, the
         tokens each held expert got ``[n_held]`` or ``None`` on a dense layer)."""
         cfg = self.cfg
-        u = rms_norm(h, lp["norm_2"], cfg.rms_norm_eps)
+        with parts.part("norm"):
+            u = rms_norm(h, lp["norm_2"], cfg.rms_norm_eps)
         if "mlp" in lp:
             m = lp["mlp"]
             return gated_ffn(u, m["w_gate"], m["w_up"], m["w_down"]), None

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec
 from ..ops.quantizer import maybe_dequantize as _deq
 from ..ops.layer_norm import layer_norm
 from ..runtime.module import ModuleSpec
+from ..telemetry import parts
 
 PyTree = Any
 
@@ -262,33 +263,36 @@ def _dropout(x, rate: float, rng, train: bool):
 def _attention(cfg: GPT2Config, lp, h, train: bool, rng=None):
     B, S, E = h.shape
     H, D = cfg.n_head, cfg.head_dim
-    qkv = h @ _deq(lp["c_attn_w"], h.dtype) + lp["c_attn_b"]  # [B,S,3E]
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
+    with parts.part("attn.qkv"):
+        qkv = h @ _deq(lp["c_attn_w"], h.dtype) + lp["c_attn_b"]  # [B,S,3E]
+        q, k_, v = jnp.split(qkv, 3, axis=-1)
 
     def heads(x):
         return x.reshape(B, S, H, D)
 
-    q, k_, v = heads(q), heads(k_), heads(v)
+    with parts.part("attn.core"):
+        q, k_, v = heads(q), heads(k_), heads(v)
 
-    if cfg.attn_impl in ("ring", "ring_flash", "ulysses"):
-        from ..parallel.sequence import sequence_parallel_attention
+        if cfg.attn_impl in ("ring", "ring_flash", "ulysses"):
+            from ..parallel.sequence import sequence_parallel_attention
 
-        assert cfg.mesh is not None, f"attn_impl={cfg.attn_impl} requires cfg.mesh"
-        o = sequence_parallel_attention(q, k_, v, cfg.mesh, impl=cfg.attn_impl)
-    elif cfg.attn_impl == "sparse":
-        from ..ops.sparse_attention import FixedSparsityConfig, sparse_attention
+            assert cfg.mesh is not None, f"attn_impl={cfg.attn_impl} requires cfg.mesh"
+            o = sequence_parallel_attention(q, k_, v, cfg.mesh, impl=cfg.attn_impl)
+        elif cfg.attn_impl == "sparse":
+            from ..ops.sparse_attention import FixedSparsityConfig, sparse_attention
 
-        sp = cfg.sparsity or FixedSparsityConfig(num_heads=H)
-        o = sparse_attention(q, k_, v, sp, causal=True)
-    else:
-        from ..ops.attention import causal_attention
+            sp = cfg.sparsity or FixedSparsityConfig(num_heads=H)
+            o = sparse_attention(q, k_, v, sp, causal=True)
+        else:
+            from ..ops.attention import causal_attention
 
-        o = causal_attention(q, k_, v, impl=cfg.attn_impl)  # [B,S,H,D]
-    # name the kernel output so remat policies can save it: a Pallas
-    # custom_vjp output is not a dot_general, so even dots_saveable would
-    # otherwise re-run the whole flash forward to rebuild c_proj's input
-    o = checkpoint_name(o.reshape(B, S, E), "attn_out")
-    out = o @ _deq(lp["c_proj_w"], o.dtype) + lp["c_proj_b"]
+            o = causal_attention(q, k_, v, impl=cfg.attn_impl)  # [B,S,H,D]
+        # name the kernel output so remat policies can save it: a Pallas
+        # custom_vjp output is not a dot_general, so even dots_saveable would
+        # otherwise re-run the whole flash forward to rebuild c_proj's input
+        o = checkpoint_name(o.reshape(B, S, E), "attn_out")
+    with parts.part("attn.out"):
+        out = o @ _deq(lp["c_proj_w"], o.dtype) + lp["c_proj_b"]
     return out
 
 
@@ -361,7 +365,8 @@ class GPT2Family:
         from ..ops.layer_norm import layer_norm_inference
 
         cfg = self.cfg
-        hn = layer_norm_inference(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.layer_norm_epsilon)
+        with parts.part("norm"):
+            hn = layer_norm_inference(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.layer_norm_epsilon)
         qkv = hn @ _deq(lp["attn"]["c_attn_w"], hn.dtype) + lp["attn"]["c_attn_b"]
         return tuple(
             t.reshape(*t.shape[:-1], cfg.n_head, cfg.head_dim) for t in jnp.split(qkv, 3, axis=-1)
@@ -378,7 +383,8 @@ class GPT2Family:
     def mlp(self, lp, h, l: int, valid=None, tp_axis=None):
         from ..ops.layer_norm import layer_norm_inference
 
-        hn = layer_norm_inference(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], self.cfg.layer_norm_epsilon)
+        with parts.part("norm"):
+            hn = layer_norm_inference(h, lp["ln_2"]["scale"], lp["ln_2"]["bias"], self.cfg.layer_norm_epsilon)
         return _mlp(self.cfg, lp["mlp"], hn, False, None, tp_axis=tp_axis)[0], None
 
     def logits(self, params, h):
@@ -394,10 +400,17 @@ def _block(cfg: GPT2Config, layer_params, h, train: bool, rng=None):
     if rng is not None:
         # distinct keys per stochastic op: attn dropout, MoE routing, mlp dropout
         r1, r2, r3 = jax.random.split(rng, 3)
-    a = _attention(cfg, layer_params["attn"], _layer_norm(h, layer_params["ln_1"]["scale"], layer_params["ln_1"]["bias"], eps), train, r1)
-    h = h + _dropout(a, cfg.dropout, r1, train)
-    m, aux = _mlp(cfg, layer_params["mlp"], _layer_norm(h, layer_params["ln_2"]["scale"], layer_params["ln_2"]["bias"], eps), train, r2)
-    return h + _dropout(m, cfg.dropout, r3, train), aux
+    # the residual adds go with the part whose output they take in
+    with parts.part("norm"):
+        hn = _layer_norm(h, layer_params["ln_1"]["scale"], layer_params["ln_1"]["bias"], eps)
+    a = _attention(cfg, layer_params["attn"], hn, train, r1)
+    with parts.part("attn.out"):
+        h = h + _dropout(a, cfg.dropout, r1, train)
+    with parts.part("norm"):
+        hn = _layer_norm(h, layer_params["ln_2"]["scale"], layer_params["ln_2"]["bias"], eps)
+    with parts.part("mlp"):
+        m, aux = _mlp(cfg, layer_params["mlp"], hn, train, r2)
+        return h + _dropout(m, cfg.dropout, r3, train), aux
 
 
 def _tag_boundary(cfg: GPT2Config, h):
@@ -490,7 +503,8 @@ def hidden_with_aux(
     materialize full logits. ``pld_theta`` (traced scalar) engages
     progressive layer drop during training."""
     B, S = input_ids.shape
-    h = params["wte"][input_ids] + params["wpe"][:S][None, :, :]
+    with parts.part("embed"):
+        h = params["wte"][input_ids] + params["wpe"][:S][None, :, :]
     # rng per layer when dropout or MoE stochastic routing needs it
     need_rng = rng is not None and (
         (train and cfg.dropout > 0.0)
@@ -499,7 +513,8 @@ def hidden_with_aux(
     use_pld = pld_theta is not None and train and rng is not None
     if need_rng or use_pld:
         if train and cfg.dropout > 0.0:
-            h = _dropout(h, cfg.dropout, jax.random.fold_in(rng, cfg.n_layer), train)
+            with parts.part("embed"):
+                h = _dropout(h, cfg.dropout, jax.random.fold_in(rng, cfg.n_layer), train)
         xs = {
             "lp": params["blocks"],
             "key": jax.random.split(jax.random.fold_in(rng, 0), cfg.n_layer),
@@ -534,7 +549,8 @@ def hidden_with_aux(
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg), prevent_cse=False)
     (h, aux_total), _ = lax.scan(body, (h, jnp.float32(0.0)), xs)
-    h = _layer_norm(h, params["ln_f"]["scale"], params["ln_f"]["bias"], cfg.layer_norm_epsilon)
+    with parts.part("head"):
+        h = _layer_norm(h, params["ln_f"]["scale"], params["ln_f"]["bias"], cfg.layer_norm_epsilon)
     return h, aux_total
 
 
@@ -590,9 +606,10 @@ def _head_token_loss(cfg: GPT2Config, wte, h, batch):
     the knob works everywhere). Math lives in models/lm_loss.py."""
     from .lm_loss import head_token_loss
 
-    return head_token_loss(
-        lambda x: x @ wte.T, h, batch, cfg.ce_chunk, logical_vocab=cfg.vocab_size
-    )
+    with parts.part("head"):
+        return head_token_loss(
+            lambda x: x @ wte.T, h, batch, cfg.ce_chunk, logical_vocab=cfg.vocab_size
+        )
 
 
 def pipeline_lm_loss(cfg: GPT2Config, params: PyTree, batch_micro, rng, train: bool, mesh):

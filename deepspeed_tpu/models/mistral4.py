@@ -45,6 +45,7 @@ import numpy as np
 from ..moe.expert_share import GROUPED_MIN_ROWS, ExpertShare, expert_share_layer
 from ..ops.layer_norm import rms_norm
 from ..runtime.module import ModuleSpec
+from ..telemetry import parts
 
 PyTree = Any
 
@@ -300,7 +301,8 @@ class Mistral4Family:
         type)."""
         cfg, a = self.cfg, lp["attn"]
         H, N = cfg.n_head, cfg.qk_nope_head_dim
-        u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
+        with parts.part("norm"):
+            u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
         q = rms_norm(u @ a["wq_a"], a["q_norm"], cfg.rms_norm_eps) @ a["wq_b"]
         q = q.reshape(*q.shape[:-1], H, cfg.qk_head_dim)
         kv = u @ a["wkv_a"]
@@ -343,8 +345,9 @@ class Mistral4Family:
         """``o [B, S, H * v_width]``, the absorbed attention's output (a mix
         of latents a head) → through ``w_uv`` then ``wo``."""
         H = self.cfg.n_head
-        o = o.reshape(*o.shape[:-1], H, self.v_width)
-        o = jnp.einsum("...hc,chv->...hv", o, lp["attn"]["w_uv"])
+        with parts.part("attn.core"):  # the values' half of the absorption belongs to the attention
+            o = o.reshape(*o.shape[:-1], H, self.v_width)
+            o = jnp.einsum("...hc,chv->...hv", o, lp["attn"]["w_uv"])
         return o.reshape(*o.shape[:-2], -1) @ lp["attn"]["wo"]
 
     def attn_out_expanded(self, lp, o, tp_axis=None):
@@ -354,7 +357,8 @@ class Mistral4Family:
         """→ (the layer's expert MLP of the residual stream ``h [B, S, E]``,
         the tokens each held expert got ``[n_held]``)."""
         cfg = self.cfg
-        u = rms_norm(h, lp["norm_2"], cfg.rms_norm_eps)
+        with parts.part("norm"):
+            u = rms_norm(h, lp["norm_2"], cfg.rms_norm_eps)
         B, S, E = u.shape
         y, counts = expert_share_layer(
             lp["moe"], u.reshape(B * S, E), cfg.share, cfg.num_experts_per_tok,

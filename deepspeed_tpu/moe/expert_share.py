@@ -48,6 +48,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import parts
+
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -123,30 +125,35 @@ def held_experts_grouped(u, idx, w, share: ExpertShare, w_gate, w_up, w_down):
     float32 before its one rounding, as in the masked form."""
     T, k = idx.shape
     n = share.n_held
-    local = idx - share.index * n
-    group = jnp.where((local >= 0) & (local < n), local, n).reshape(T * k)
-    order = jnp.argsort(group, stable=True)                        # pair rows, by group
-    sizes = jnp.sum(group[:, None] == jnp.arange(n)[None, :], axis=0, dtype=jnp.int32)
-    in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]       # rows past the groups: nothing
-    x = u[order // k]                                              # [T * k, E]
-    g = jax.lax.ragged_dot(x, w_gate, sizes)
-    v = jax.lax.ragged_dot(x, w_up, sizes)
-    a = jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32) * w.reshape(T * k)[order][:, None]
-    a = jnp.where(in_group, a, 0.0).astype(u.dtype)
-    y = jnp.where(in_group, jax.lax.ragged_dot(a, w_down, sizes), 0)
-    # back to the pairs' own order, then a token's k pairs summed in float32
-    y = y[jnp.argsort(order)].reshape(T, k, -1)
-    return jnp.sum(y.astype(jnp.float32), axis=1).astype(u.dtype)
+    with parts.part("moe.route"):   # the sort and the gather
+        local = idx - share.index * n
+        group = jnp.where((local >= 0) & (local < n), local, n).reshape(T * k)
+        order = jnp.argsort(group, stable=True)                        # pair rows, by group
+        sizes = jnp.sum(group[:, None] == jnp.arange(n)[None, :], axis=0, dtype=jnp.int32)
+        in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]       # rows past the groups: nothing
+        x = u[order // k]                                              # [T * k, E]
+    with parts.part("moe.experts"):
+        g = jax.lax.ragged_dot(x, w_gate, sizes)
+        v = jax.lax.ragged_dot(x, w_up, sizes)
+        a = jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32) * w.reshape(T * k)[order][:, None]
+        a = jnp.where(in_group, a, 0.0).astype(u.dtype)
+        y = jnp.where(in_group, jax.lax.ragged_dot(a, w_down, sizes), 0)
+    with parts.part("moe.route"):   # the scatter back and the combine
+        # back to the pairs' own order, then a token's k pairs summed in float32
+        y = y[jnp.argsort(order)].reshape(T, k, -1)
+        return jnp.sum(y.astype(jnp.float32), axis=1).astype(u.dtype)
 
 
 def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from):
     """→ (the held experts' part for ``u [T, E]``, ``wh [T, n_held]``)."""
-    idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk)
-    wh = held_weights(idx, w, share)
+    with parts.part("moe.route"):
+        idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk)
+        wh = held_weights(idx, w, share)
     ex = lp["experts"]
     if grouped_rows(u.shape[0], top_k, grouped_from):
         return held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
-    return held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
+    with parts.part("moe.experts"):
+        return held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
 
 
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
@@ -169,7 +176,8 @@ def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
         y, wh = _routed(u, lp, share, top_k, scale, norm_topk, grouped_from)
     sh = lp["shared"]
     y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
-    got = wh > 0.0  # sigmoid scores are positive: a selected pair's weight is
-    if valid is not None:
-        got = got & valid[:, None]
-    return y, jnp.sum(got, axis=0, dtype=jnp.int32)
+    with parts.part("moe.route"):   # the load count
+        got = wh > 0.0  # sigmoid scores are positive: a selected pair's weight is
+        if valid is not None:
+            got = got & valid[:, None]
+        return y, jnp.sum(got, axis=0, dtype=jnp.int32)

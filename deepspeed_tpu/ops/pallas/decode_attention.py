@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...telemetry import parts
+
 S_BLOCK = 512  # cache rows per online-softmax tile
 
 
@@ -437,25 +439,26 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
         walk, at, *(() if lo is None else (jnp.asarray(lo, jnp.int32),)),
         *_layer_operand(layer),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            # walked table + per-slot positions (+ a window's lower bounds)
-            # (+ the layer, where it is an operand)
-            num_scalar_prefetch=len(prefetched),
-            grid=(B * nhb, n_blk),
-            in_specs=in_specs,
-            out_specs=qo_spec,
-            scratch_shapes=[
-                pltpu.VMEM((HB, R, 1), jnp.float32),  # running max
-                pltpu.VMEM((HB, R, 1), jnp.float32),  # running denominator
-                pltpu.VMEM((HB, R, D), jnp.float32),  # output accumulator
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
-        interpret=interpret,
-        **({} if name is None else {"name": name}),
-    )(*prefetched, *operands)
+    with parts.unscoped():
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                # walked table + per-slot positions (+ a window's lower bounds)
+                # (+ the layer, where it is an operand)
+                num_scalar_prefetch=len(prefetched),
+                grid=(B * nhb, n_blk),
+                in_specs=in_specs,
+                out_specs=qo_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((HB, R, 1), jnp.float32),  # running max
+                    pltpu.VMEM((HB, R, 1), jnp.float32),  # running denominator
+                    pltpu.VMEM((HB, R, D), jnp.float32),  # output accumulator
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct(q5.shape, q5.dtype),
+            interpret=interpret,
+            **({} if name is None else {"name": name}),
+        )(*prefetched, *operands)
 
 
 # A pool of this many layers or more has its kernels' calls, one a layer of a

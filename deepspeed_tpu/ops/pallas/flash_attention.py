@@ -32,6 +32,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...telemetry import parts
+
 # Block sizes of the KV-blocked grid variant, the granule every plan of
 # flash_plan is a multiple of, and what flash_ok asks S to divide by.
 BQ = 128
@@ -1216,10 +1218,11 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
         # S-major path: head slices read via lane-offset index maps — the
         # reshapes below are free (contiguous), no physical transposes
         E = H * D
-        o2 = _flash_bse(
-            q.reshape(B, S, E), k.reshape(B, S, E), v.reshape(B, S, E),
-            win, H, float(scale), bool(causal), bool(interpret),
-        )
+        with parts.unscoped():
+            o2 = _flash_bse(
+                q.reshape(B, S, E), k.reshape(B, S, E), v.reshape(B, S, E),
+                win, H, float(scale), bool(causal), bool(interpret),
+            )
         return o2.reshape(B, S, H, D)
 
     def to3(x):
@@ -1228,6 +1231,9 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
 
     # batch-major flattening makes bh = (b*KV + g)*rep + r for q and
     # b*KV + g for k/v, so bh // rep recovers the kv row exactly
-    o3 = _flash(to3(q), to3(k), to3(v), win, float(scale),
-                bool(causal), bool(interpret), rep)
+    q3, k3, v3 = to3(q), to3(k), to3(v)
+    # the kernels keep the names XLA gives them (a trace's readers know them by those): the
+    # custom_vjp call carries its scope into the forward and the backward kernels alike
+    with parts.unscoped():
+        o3 = _flash(q3, k3, v3, win, float(scale), bool(causal), bool(interpret), rep)
     return o3.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, Iterable, Iterator, NamedTuple, Optional, Tuple
 
 import jax
@@ -36,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..ops.attention import traced_flash_plan
 from ..parallel.topology import MeshSpec, mesh_axis_size
-from ..telemetry import compile_stats, spans
+from ..telemetry import compile_stats, parts, spans
 from ..utils.logging import log_dist, logger
 from ..utils.pytree import path_str as _path_str
 from ..utils.timer import (
@@ -1220,7 +1221,8 @@ class DeepSpeedEngine:
                 * jnp.exp(-pld_gamma * state.global_step.astype(jnp.float32))
                 + pld_theta0
             ) if use_pld else None
-            cparams = _cast_params(state.params, compute_dtype)
+            with parts.part("optim"):   # the masters' cast to the compute type
+                cparams = _cast_params(state.params, compute_dtype)
 
             if pipeline_mode:
                 # pipeline path: all gas microbatches flow through the 1F1B/
@@ -1295,42 +1297,43 @@ class DeepSpeedEngine:
                     micro_step, (zero_grads, jnp.float32(0.0), 0), None, length=gas
                 )
 
-            # unscale + average over gas (reference: scale loss by 1/GAS, engine.py:1775)
-            inv = 1.0 / (scale * gas) if fp16 else 1.0 / gas
-            if pipeline_mode:
-                inv = inv * gas  # pipeline loss is already the mean over microbatches
-            grads = jax.tree.map(lambda g: (g.astype(jnp.float32) * inv), grads)
-            # pre-divide only happens in the micro_step accumulation loop, so
-            # the re-multiply must not run on the pipeline path
-            if predivide and predivide_factor != 1.0 and not pipeline_mode:
-                grads = jax.tree.map(lambda g: g * predivide_factor, grads)
+            with parts.part("optim"):
+                # unscale + average over gas (reference: scale loss by 1/GAS, engine.py:1775)
+                inv = 1.0 / (scale * gas) if fp16 else 1.0 / gas
+                if pipeline_mode:
+                    inv = inv * gas  # pipeline loss is already the mean over microbatches
+                grads = jax.tree.map(lambda g: (g.astype(jnp.float32) * inv), grads)
+                # pre-divide only happens in the micro_step accumulation loop, so
+                # the re-multiply must not run on the pipeline path
+                if predivide and predivide_factor != 1.0 and not pipeline_mode:
+                    grads = jax.tree.map(lambda g: g * predivide_factor, grads)
 
-            overflow = ls.has_inf_or_nan(grads) if fp16 else jnp.bool_(False)
-            grads = jax.tree.map(lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
+                overflow = ls.has_inf_or_nan(grads) if fp16 else jnp.bool_(False)
+                grads = jax.tree.map(lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
 
-            gnorm = global_norm(grads)
-            if clip > 0.0:
-                coef = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * coef, grads)
+                gnorm = global_norm(grads)
+                if clip > 0.0:
+                    coef = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grads = jax.tree.map(lambda g: g * coef, grads)
 
-            updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+                updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
 
-            # predicated skip-on-overflow (fp16/fused_optimizer.py step semantics)
-            new_params = _tree_select(~overflow, new_params, state.params)
-            new_opt_state = _tree_select(~overflow, new_opt_state, state.opt_state)
+                # predicated skip-on-overflow (fp16/fused_optimizer.py step semantics)
+                new_params = _tree_select(~overflow, new_params, state.params)
+                new_opt_state = _tree_select(~overflow, new_opt_state, state.opt_state)
 
-            new_scale_state = ls.update(
-                state.loss_scale, overflow, dynamic=dynamic,
-                scale_window=scale_window, min_scale=min_scale,
-            )
-            new_state = TrainState(
-                params=new_params,
-                opt_state=new_opt_state,
-                loss_scale=new_scale_state,
-                global_step=state.global_step + jnp.where(overflow, 0, 1),
-                skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
-            )
+                new_scale_state = ls.update(
+                    state.loss_scale, overflow, dynamic=dynamic,
+                    scale_window=scale_window, min_scale=min_scale,
+                )
+                new_state = TrainState(
+                    params=new_params,
+                    opt_state=new_opt_state,
+                    loss_scale=new_scale_state,
+                    global_step=state.global_step + jnp.where(overflow, 0, 1),
+                    skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
+                )
             metrics = {
                 "loss": loss_sum / gas,
                 "grad_norm": gnorm,
@@ -1727,6 +1730,7 @@ class DeepSpeedEngine:
                 self.state, metrics = self._train_step(self.state, device_batch, step_rng)
                 if first_call:
                     programs_phase.set(flash_plan=self._set_flash_plan_gauges())
+                    self._register_parts()
             self.global_steps += 1
             # monotonic train_batch ordinal: the fault-injection index. NOT
             # global_steps — a rollback rewinds that, which would re-fire the
@@ -1956,6 +1960,22 @@ class DeepSpeedEngine:
             "skipped"
         )
         return True
+
+    def _register_parts(self) -> None:
+        """The step program for ``telemetry.parts``, under the name a trace's
+        line of programs shows. A callable, called when a reader asks: nothing
+        is lowered, compiled or rendered here. (The engine is held weakly: the
+        registry must not keep its state on the device.)"""
+        name = getattr(self._train_step, "__name__", None)
+        if name is None or not hasattr(self._train_step, "lower"):
+            return   # offload / 1-bit / infinity: several programs a step
+        me = weakref.ref(self)
+
+        def text():
+            eng = me()
+            return None if eng is None else eng._compiled_step().as_text()
+
+        parts.register("jit_" + name, text)
 
     def _lower_step_compiled(self):
         """Lower + compile the current jitted step for program-level analysis

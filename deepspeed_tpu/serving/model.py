@@ -63,6 +63,7 @@ from ..ops.quantizer import (
     quantize_kv_token,
 )
 from ..ops.sampling import sample_logits
+from ..telemetry import parts
 
 PyTree = Any
 
@@ -87,6 +88,7 @@ PyTree = Any
 # ---------------------------------------------------------------------------
 
 
+@parts.scoped("kv.write")
 def _write_pool_pages(pool, scales, l, page_ids, chunks, sidx):
     """Whole-page scatter: ``chunks [n_pp, KV, page, D]`` (compute precision)
     into layer ``l``'s pages; quantize-at-write when the pool is int8.
@@ -103,6 +105,7 @@ def _write_pool_pages(pool, scales, l, page_ids, chunks, sidx):
     return pool, scales, dequantize_kv_pages(codes, s)
 
 
+@parts.scoped("kv.write")
 def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared=False):
     """``k_vals`` / ``v_vals [..., KV, D]`` to (layer ``l``, page
     ``pidx[...]``, every kv head, offset ``poff[...]``) of the ``[L, P, KV,
@@ -143,6 +146,7 @@ def _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared=False)
     )
 
 
+@parts.scoped("kv.write")
 def _token_codes(scales, l, pidx, poff, vals, sidx):
     """What a one-token write stores for ``vals [B, KV, D]`` → ``(codes,
     scales)``: the values themselves, or, for an int8 pool, their codes
@@ -263,11 +267,13 @@ def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, attn_out=None):
     the residual stream (through ``attn_out``: the family's, unless a latent
     family attended per head), then the MLP or expert layer, whose held
     experts' token counts (if it reports any) join ``counts``."""
-    h = h + (attn_out or fam.attn_out)(lp, o, tp_axis)
-    m, c = fam.mlp(lp, h, l, valid, tp_axis)
-    if c is not None:
-        counts.append(c)
-    return h + m
+    with parts.part("attn.out"):  # the residual adds go with the part whose output they take in
+        h = h + (attn_out or fam.attn_out)(lp, o, tp_axis)
+    with parts.part("mlp"):
+        m, c = fam.mlp(lp, h, l, valid, tp_axis)
+        if c is not None:
+            counts.append(c)
+        return h + m
 
 
 def _window_views(fam, slots, pos0, page: int, ring: int):
@@ -304,6 +310,7 @@ def _pad_lanes(x, lanes: int):
     return x if w == lanes else jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - w)])
 
 
+@parts.scoped("kv.write")
 def _latent_write_pages(pool, l, page_ids, rows):
     """Whole-page scatter of a prompt chunk's rows ``[1, S, 1, w]`` into
     layer ``l``'s pages (the write of :func:`_write_pool_pages`, one pool)."""
@@ -311,6 +318,7 @@ def _latent_write_pages(pool, l, page_ids, rows):
     return pool.at[l, page_ids].set(_page_chunks(rows, pool.shape[3]))
 
 
+@parts.scoped("kv.write")
 def _latent_write_tokens(pool, l, pidx, poff, rows):
     """``rows [B, 1, w]`` (the decode step) or ``[B, T, 1, w]`` to (layer
     ``l``, page ``pidx``, offset ``poff``) of the latent pool: one Pallas
@@ -332,6 +340,7 @@ def _unless_idle(live, attend, shape, dtype):
     return lax.cond(live, attend, lambda: jnp.zeros(shape, dtype))
 
 
+@parts.scoped("attn.core")
 def _attend_latent(fam, q, pool, l, block_tables, base, name, live=None):
     """The absorbed queries ``q [B, T, H, w]`` against layer ``l`` of the
     (already updated) latent pool → ``[B, T, H * v_width]``; ``live`` as in
@@ -361,6 +370,7 @@ def _page_chunks(x, page: int):
     return jnp.swapaxes(x[0].reshape(S // page, page, KV, D), 1, 2)
 
 
+@parts.scoped("attn.core")
 def _attend_prompt_blocked(q, k, v, window: int, block: int, sm_scale=None):
     """Causal attention of a whole prompt chunk, ``block`` query rows at a
     time, so that the scores alive are ``[H, block, keys]``: all ``Sp`` keys
@@ -428,18 +438,19 @@ def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
         return o, k_pool, v_pool, scales
 
     scale = 1.0 / np.sqrt(D)
-    scores = jnp.einsum(
-        "bshd,bthd->bhst", q.astype(jnp.float32), k_c.astype(jnp.float32)
-    ) * scale
-    j_idx = jnp.arange(Sp)
-    i_idx = jnp.arange(Sp)
-    mask = j_idx[None, :] <= i_idx[:, None]
-    scores = jnp.where(mask[None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v_c.dtype)
-    o = jnp.einsum("bhst,bthd->bshd", probs, v_c)
-    # H*D == E at TP=1; under the TP shard_map H is the per-rank head count
-    # and the row-parallel projection restores the full embed dim
-    return o.reshape(B, Sp, H * D).astype(q.dtype), k_pool, v_pool, scales
+    with parts.part("attn.core"):
+        scores = jnp.einsum(
+            "bshd,bthd->bhst", q.astype(jnp.float32), k_c.astype(jnp.float32)
+        ) * scale
+        j_idx = jnp.arange(Sp)
+        i_idx = jnp.arange(Sp)
+        mask = j_idx[None, :] <= i_idx[:, None]
+        scores = jnp.where(mask[None, None, :, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v_c.dtype)
+        o = jnp.einsum("bhst,bthd->bshd", probs, v_c)
+        # H*D == E at TP=1; under the TP shard_map H is the per-rank head count
+        # and the row-parallel projection restores the full embed dim
+        return o.reshape(B, Sp, H * D).astype(q.dtype), k_pool, v_pool, scales
 
 
 def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
@@ -457,8 +468,9 @@ def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
     r = jnp.arange(ring)
     src = jnp.clip(n_last - (n_last - r) % ring, 0, Sp // page - 1)
     ids = ring_page_ids(slot, r, ring)
-    k_win = k_win.at[li, ids].set(_page_chunks(k_c, page)[src].astype(k_win.dtype))
-    v_win = v_win.at[li, ids].set(_page_chunks(v_c, page)[src].astype(v_win.dtype))
+    with parts.part("kv.write"):
+        k_win = k_win.at[li, ids].set(_page_chunks(k_c, page)[src].astype(k_win.dtype))
+        v_win = v_win.at[li, ids].set(_page_chunks(v_c, page)[src].astype(v_win.dtype))
     block = math.gcd(Sp, fam.prefill_block or Sp)
     return _attend_prompt_blocked(q, k_c, v_c, window, block), (k_win, v_win)
 
@@ -488,7 +500,8 @@ def paged_prefill(
     fam = cfg.serving_family()
     B, Sp = input_ids.shape
     positions = jnp.arange(Sp)
-    h = fam.embed(params, input_ids, positions)
+    with parts.part("embed"):
+        h = fam.embed(params, input_ids, positions)
     valid = (positions < prompt_len) if fam.sparse_layers else None
     counts = []
 
@@ -497,7 +510,8 @@ def paged_prefill(
         if fam.kv_pools == 1:
             # a latent family: the row into the one pool, attention per head
             # (expanded) in query blocks, its output straight into ``wo``
-            q, k_, v, row = fam.qkv_expanded(lp, h, positions, l)
+            with parts.part("attn.qkv"):
+                q, k_, v, row = fam.qkv_expanded(lp, h, positions, l)
             k_pool = _latent_write_pages(k_pool, li, page_ids, row)
             o = _attend_prompt_blocked(
                 q, k_, v, 0, math.gcd(Sp, fam.prefill_block), fam.sm_scale
@@ -506,7 +520,8 @@ def paged_prefill(
                 fam, lp, h, o, l, valid, tp_axis, counts, fam.attn_out_expanded
             )
             continue
-        q, k_, v = fam.qkv(lp, h, positions, l)
+        with parts.part("attn.qkv"):
+            q, k_, v = fam.qkv(lp, h, positions, l)
         if windowed:
             o, win = _attention_prefill_window(
                 fam, q, k_, v, win, li, slot, prompt_len, fam.windows[l], ring
@@ -519,9 +534,11 @@ def paged_prefill(
             )
         h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    h_last = jnp.take(h, prompt_len - 1, axis=1)  # [B, E] true last prompt pos
-    logits = fam.logits(params, h_last)
-    first = sample_logits(logits, rng, temperature, top_k, top_p)
+    with parts.part("head"):
+        h_last = jnp.take(h, prompt_len - 1, axis=1)  # [B, E] true last prompt pos
+        logits = fam.logits(params, h_last)
+    with parts.part("sample"):
+        first = sample_logits(logits, rng, temperature, top_k, top_p)
     return _result(k_pool, v_pool, scales, win, first, counts)
 
 
@@ -529,6 +546,7 @@ def paged_prefill(
 # paged decode step (one token for every slot)
 # ---------------------------------------------------------------------------
 
+@parts.scoped("attn.core")
 def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
                           out_dtype, scales_l=None, lo=None, name=None,
                           live=None):
@@ -655,6 +673,7 @@ def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
     return o, k_pool, v_pool, scales
 
 
+@parts.scoped("sample")
 def _sample_slots(logits, keys, temperature, top_k, top_p):
     """One token a slot from ``logits [B, V]`` under per-slot ``keys [B, 2]``."""
     if not temperature or temperature <= 0.0:
@@ -692,7 +711,8 @@ def paged_decode_step(
     page = k_pool.shape[3]
     # rows gathered by [B] indices, then the token axis: gathered by [B, 1]
     # ones the position table is copied whole and re-laid out every step
-    h = fam.embed(params, tokens, seq_lens)
+    with parts.part("embed"):
+        h = fam.embed(params, tokens, seq_lens)
     positions = seq_lens[:, None]
     pidx = jnp.take_along_axis(
         block_tables, (seq_lens // page)[:, None], axis=1
@@ -704,7 +724,8 @@ def paged_decode_step(
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
-        q, k_, v = fam.qkv(lp, h, positions, l)
+        with parts.part("attn.qkv"):
+            q, k_, v = fam.qkv(lp, h, positions, l)
         if fam.kv_pools == 1:
             k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_[:, 0])
             o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_decode")
@@ -720,7 +741,9 @@ def paged_decode_step(
             )
         h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    nxt = _sample_slots(fam.logits(params, h[:, -1]), keys, temperature, top_k, top_p)
+    with parts.part("head"):
+        logits = fam.logits(params, h[:, -1])
+    nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
     return _result(k_pool, v_pool, scales, win, nxt, counts)
 
 
@@ -744,6 +767,7 @@ def paged_decode_step(
 # ---------------------------------------------------------------------------
 
 
+@parts.scoped("attn.core")
 def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
                              scales_l=None, lo=None, name=None):
     """Batched attention tail of the chunk-prefill program: q [B,T,H,D]
@@ -886,7 +910,8 @@ def paged_verify_step(
     positions = jnp.minimum(
         seq_lens[:, None] + jnp.arange(T)[None, :], fam.n_positions - 1
     )
-    h = fam.embed(params, tokens, positions)
+    with parts.part("embed"):
+        h = fam.embed(params, tokens, positions)
     pidx, poff = _verify_write_targets(seq_lens, block_tables, page, T)
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if win is not None else None
     valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
@@ -894,7 +919,8 @@ def paged_verify_step(
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
-        q, k_, v = fam.qkv(lp, h, positions, l)
+        with parts.part("attn.qkv"):
+            q, k_, v = fam.qkv(lp, h, positions, l)
         if fam.kv_pools == 1:
             # one batched call: a latent family holds no bit-for-bit contract
             # with a per-request generate for T single-token calls to keep
@@ -912,8 +938,10 @@ def paged_verify_step(
             )
         h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
 
-    logits = fam.logits(params, h)
-    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    with parts.part("head"):
+        logits = fam.logits(params, h)
+    with parts.part("sample"):
+        greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
     return _result(k_pool, v_pool, scales, win, greedy, counts)
 
 
@@ -996,7 +1024,8 @@ def paged_mixed_step(
     # multiple, whole tiles both
     c_pos = jnp.minimum(start + jnp.arange(C), fam.n_positions - 1)
     positions = jnp.concatenate([c_pos, seq_lens])
-    h = fam.embed(params, jnp.concatenate([input_ids[0], tokens])[None], positions)
+    with parts.part("embed"):
+        h = fam.embed(params, jnp.concatenate([input_ids[0], tokens])[None], positions)
     base = jnp.reshape(start, (1,))
     pidx = jnp.take_along_axis(block_tables, (seq_lens // page)[:, None], axis=1)[:, 0]
     poff = seq_lens % page
@@ -1017,7 +1046,8 @@ def paged_mixed_step(
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
-        q, k_, v = fam.qkv(lp, h, positions, l)
+        with parts.part("attn.qkv"):
+            q, k_, v = fam.qkv(lp, h, positions, l)
         (qc, qd), (kc, kd) = part(q), part(k_)
         od = None
         if fam.kv_pools == 1:
@@ -1031,8 +1061,9 @@ def paged_mixed_step(
         elif windowed:
             vc, vd = part(v)
             k_win, v_win = win
-            k_win = k_win.at[li, ring_ids].set(_page_chunks(kc, page).astype(k_win.dtype))
-            v_win = v_win.at[li, ring_ids].set(_page_chunks(vc, page).astype(v_win.dtype))
+            with parts.part("kv.write"):
+                k_win = k_win.at[li, ring_ids].set(_page_chunks(kc, page).astype(k_win.dtype))
+                v_win = v_win.at[li, ring_ids].set(_page_chunks(vc, page).astype(v_win.dtype))
             win = (k_win, v_win)
             if B:
                 od, win = _attention_step_window(
@@ -1069,11 +1100,13 @@ def paged_mixed_step(
     # one pass of the head: the chunk's true last prompt position (when it
     # falls inside this chunk) and the decode rows
     idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
-    logits = fam.logits(params, jnp.concatenate([jnp.take(h[0], idx[None], axis=0), h[0, C:]]))
-    first = sample_logits(logits[:1], rng, temperature, top_k, top_p)
-    if B:
-        nxt = _sample_slots(logits[1:], keys, temperature, top_k, top_p)
-        first = jnp.concatenate([nxt, first.astype(nxt.dtype)])
+    with parts.part("head"):
+        logits = fam.logits(params, jnp.concatenate([jnp.take(h[0], idx[None], axis=0), h[0, C:]]))
+    with parts.part("sample"):
+        first = sample_logits(logits[:1], rng, temperature, top_k, top_p)
+        if B:
+            nxt = _sample_slots(logits[1:], keys, temperature, top_k, top_p)
+            first = jnp.concatenate([nxt, first.astype(nxt.dtype)])
     return _result(k_pool, v_pool, scales, win, first, counts)
 
 

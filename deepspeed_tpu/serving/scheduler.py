@@ -61,6 +61,7 @@ token-equivalence test asserts for mixed-length streams.
 from __future__ import annotations
 
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, List, Optional
@@ -69,7 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..telemetry import spans
+from ..telemetry import parts, spans
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
 from ..utils.logging import log_dist
@@ -756,7 +757,7 @@ class ServingEngine:
         self._migrate_gather_exec = None
         self._migrate_scatter_exec = None
         self.executables: List[Any] = []
-        # program name -> {"exe", "pset", "kind"} (built by _ensure_compiled;
+        # program name -> {"exe", "pset", "kind"[, "fn"]} (built by _ensure_compiled;
         # verify() derives per-program local shapes and aliasing from it)
         self._program_info: dict = {}
         log_dist(
@@ -1099,7 +1100,7 @@ class ServingEngine:
         ))
         info[f"serving_prefill{sfx}{self.prefill_placement.suffix()}"] = {
             "exe": self._prefill_exec, "pset": self.prefill_set,
-            "kind": "prefill",
+            "kind": "prefill", "fn": p_fns[0].__name__,
         }
         self.executables = [self._prefill_exec]
         # the verify step REPLACES the decode step when speculation is on:
@@ -1112,14 +1113,14 @@ class ServingEngine:
             ))
             info[f"serving_verify{sfx}{self.decode_placement.suffix()}"] = {
                 "exe": self._verify_exec, "pset": self.decode_set,
-                "kind": "verify",
+                "kind": "verify", "fn": d_fns[2].__name__,
             }
             self.executables.append(self._verify_exec)
         else:
             self._decode_exec = compile_for(self.decode_set, d_fns[1], rows_sds)
             info[f"serving_decode{sfx}{self.decode_placement.suffix()}"] = {
                 "exe": self._decode_exec, "pset": self.decode_set,
-                "kind": "decode",
+                "kind": "decode", "fn": d_fns[1].__name__,
             }
             self.executables.append(self._decode_exec)
         if self.chunk_width > 0:
@@ -1130,7 +1131,7 @@ class ServingEngine:
             ))
             info[f"serving_chunk_prefill{sfx}{self.prefill_placement.suffix()}"] = {
                 "exe": self._chunk_exec, "pset": self.prefill_set,
-                "kind": "chunk",
+                "kind": "chunk", "fn": p_fns[3].__name__,
             }
             self.executables.append(self._chunk_exec)
 
@@ -1141,7 +1142,26 @@ class ServingEngine:
             self._compile_restore(info, quant, S, i32)
 
         self._program_info = info
+        self._register_parts()
         self._set_collective_gauges()
+
+    def _register_parts(self) -> None:
+        """Each compiled program for ``telemetry.parts``, under the name a
+        trace's line of programs shows (``jit_decode_fn``, ...). A callable,
+        called when a reader asks: no text is rendered here. (The engine is
+        held weakly: the registry must not keep its pools on the device.)"""
+        me = weakref.ref(self)
+
+        def text_of(key):
+            def text():
+                eng = me()
+                rec = None if eng is None else eng._program_info.get(key)
+                return None if rec is None else rec["exe"].as_text()
+            return text
+
+        for key, rec in self._program_info.items():
+            if "fn" in rec:   # the programs that run the model: the page movers hold no part
+                parts.register("jit_" + rec["fn"], text_of(key))
 
     def _compile_handoff(self, info: dict, quant: bool, S, i32) -> None:
         """The disaggregated KV handoff pair (ISSUE 14): ``gather`` packs a
@@ -1780,7 +1800,9 @@ class ServingEngine:
                     chunk_sp.set(**self._moe_report(moe_done))
                 sp.set(tokens=n_emit, finished=n_fin)
 
-        with spans.span("ds.serve.housekeep"):
+        with spans.span("ds.serve.housekeep") as hk:
+            # which of its four chores a long one ran (the span's attrs, set at exit)
+            scanned = pumped = 0
             # straggler detection (ISSUE 5 watchdog): a request resident in a
             # slot far beyond its expected decode budget (straggler_factor x
             # max_new_tokens x EMA step time) is flagged once — a wedged or
@@ -1794,6 +1816,7 @@ class ServingEngine:
                     req = slot.request
                     if req is None or req.t_first_token is None:
                         continue
+                    scanned += 1
                     budget = factor * max(1, req.max_new_tokens) * self._ema_step_s
                     elapsed = now - req.t_first_token
                     if elapsed > budget and self.watchdog.observe_straggler(
@@ -1812,11 +1835,12 @@ class ServingEngine:
             if self.prefix_cache is not None:
                 self._g_index_pages.set(len(self.prefix_cache))
             if self.tiering is not None:
-                self._tier_pump()
-            if self._step_count and self._step_count % 32 == 0:
+                pumped = self._tier_pump()
+            refresh = bool(self._step_count and self._step_count % 32 == 0)
+            if refresh:
                 self.stats()  # refresh the quantile gauges for textfile scrapes
-            if self._journal is not None:
-                self._journal.maybe_snapshot(self.clock())
+            journaled = self._journal is not None and self._journal.maybe_snapshot(self.clock())
+            hk.set(stats=int(refresh), journal=int(journaled), pump=pumped, stragglers=scanned)
         return n_active
 
     def _pages_needed(self, req: Request) -> int:
@@ -1884,22 +1908,25 @@ class ServingEngine:
                 )
         return False
 
-    def _tier_pump(self) -> None:
+    def _tier_pump(self) -> int:
         """Keep free-page headroom by demoting cold index leaves to host
         BEFORE admissions hit the relief valve: when the prefill pool's
         free list drops under 1/8 capacity, evict (= demote, the sink is
         wired) enough LRU leaves to climb back. The device-side snapshot
         is dispatched here; the blocking device→host copy runs on the
-        spill worker — the step path never waits on host DMA."""
+        spill worker — the step path never waits on host DMA. → the pages
+        it moved."""
         pc = self.prefix_cache
         if pc is None or not len(pc):
-            return
+            return 0
         palloc = self.prefill_set.allocator
         low = max(1, palloc.capacity // 8)
         if palloc.free_pages >= low:
-            return
+            return 0
+        held = len(pc)
         pc.evict(need_free=low)
         self._g_index_pages.set(len(pc))
+        return held - len(pc)
 
     def _draft(self, req: Request) -> np.ndarray:
         """Host-side prompt-lookup draft (ISSUE 10): the continuation of the

@@ -435,6 +435,96 @@ def test_flash_kernels_compile_for_v5e_under_their_plan(one_chip, name, mode):
         assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
+# -- telemetry.parts: a scope names no kernel, and every kernel has a part ------------------
+
+def _xl_train_step(one_chip):
+    """Two XL-wide layers of the training step's forward, backward and update
+    under full remat, the flash kernels forced: its optimised text."""
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.telemetry import parts
+
+    cfg = gpt2.GPT2Config(n_embd=1600, n_head=25, n_layer=2, n_positions=1024, attn_impl="pallas",
+                          dtype=jnp.bfloat16, remat=True)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, jnp.bfloat16),
+                          jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
+
+    def train_step(p, ids):
+        loss, grads = jax.value_and_grad(lambda p: gpt2.lm_loss(cfg, p, {"input_ids": ids}, None, True)[0])(p)
+        with parts.part("optim"):
+            return loss, jax.tree.map(lambda a, g: a - 0.01 * g.astype(a.dtype), p, grads)
+
+    return jax.jit(train_step).lower(params, sds((2, 1024), jnp.int32)).compile().as_text()
+
+
+def _xl_decode_step(one_chip):
+    """The decode step at XL width over plain 5-D pools (its kernels are the
+    ones the package gives no name): its optimised text."""
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.serving import model as smodel
+
+    L, P, KV, page, D, B, W = 2, 512, 25, 16, 64, 8, 64
+    cfg = gpt2.GPT2Config(n_embd=KV * D, n_head=KV, n_layer=L, attn_impl="pallas", dtype=jnp.bfloat16)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, jnp.bfloat16),
+                          jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
+    pool = sds((L, P, KV, page, D), jnp.bfloat16)
+
+    def decode_fn(p, k, v, tok, lens, bt, keys):
+        return smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys)
+
+    return jax.jit(decode_fn, donate_argnums=(1, 2)).lower(
+        params, pool, pool, sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, W), jnp.int32),
+        sds((B, 2), jnp.uint32)).compile().as_text()
+
+
+@pytest.mark.parametrize("program, kernels", [
+    ("train", {"closed_call": ("attn.core", "fwd"), "rematted_computation": ("attn.core", "recompute"),
+               "checkpoint": ("attn.core", "bwd")}),
+    ("decode", {"decode_fn": ("attn.core", "none"), "kv_token_write": ("kv.write", "none")}),
+])
+def test_scopes_rename_no_kernel_and_every_kernel_has_a_part(one_chip, monkeypatch, program, kernels):
+    """ISSUE 36. XLA names an unnamed kernel's custom call after the innermost
+    scope open around it, and the benchmark's patterns know the kernels by the
+    names they have: with the ``dspart.*`` scopes in the program the compiled
+    text is, metadata apart, the text without them, the kernels keep their
+    names, and the part table gives each its part and its pass (the flash
+    kernels' through the hole around their ``custom_vjp`` call)."""
+    import contextlib
+
+    from deepspeed_tpu.telemetry import parts
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")   # the token write takes its kernel
+    build = {"train": _xl_train_step, "decode": _xl_decode_step}[program]
+    scoped = build(one_chip)
+    with monkeypatch.context() as m:
+        m.setattr(parts, "part", lambda name: contextlib.nullcontext())
+        null = build(one_chip)
+
+    def strip(text):
+        # (a kernel's serialised body is not the same bytes from one lowering to the next)
+        text = re.sub(r'"body":"[^"]*"', "", text[text.index("\n\n", text.index("StackFrames")):])
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+    assert "dspart." in scoped and "dspart." not in null
+    assert strip(scoped) == strip(null)
+    table = parts.table_of(scoped)
+    found = {}
+    for name in re.findall(r"^\s*%([\w.\-]+) = .*custom-call\(.*tpu_custom_call", scoped, re.M):
+        e = table[name]
+        assert e.has_dot
+        found[re.sub(r"[.\d]+$", "", name)] = (e.part, e.phase)
+    assert found == kernels
+    dots = [e for e in table.values() if e.has_dot]
+    assert all(e.part for e in dots) and {e.part for e in dots} >= {"attn.qkv", "attn.out", "mlp", "head"}
+
+
 # -- the latent (MLA) family: kernels, pool layout and programs at the served size --
 
 def _ms4_config():

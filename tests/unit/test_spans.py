@@ -155,7 +155,8 @@ def test_leaves_tile_each_serve_step_and_carry_the_documented_attrs(served):
     assert statistics.median(ratios) > 0.95
     want = {"ds.serve.admit": {"admitted", "blocked"}, "ds.serve.chunk": {"chunks", "rode", "tokens", "attended"},
             "ds.serve.decode.dispatch": {"active", "attended", "pages"}, "ds.serve.decode.wait": set(),
-            "ds.serve.emit": {"tokens", "finished"}, "ds.serve.housekeep": set(),
+            "ds.serve.emit": {"tokens", "finished"},
+            "ds.serve.housekeep": {"stats", "journal", "pump", "stragglers"},
             "ds.serve.prefill.wait": set(), "ds.serve.chunk.wait": set()}
     seen = collections.defaultdict(set)
     for name, _, _, attrs in recs:
