@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec
 from ..ops.quantizer import maybe_dequantize as _deq
 from ..ops.layer_norm import layer_norm
 from ..runtime.module import ModuleSpec
+from ..runtime.zero.partitioning import on_batch_axis
 from ..telemetry import parts
 
 PyTree = Any
@@ -410,7 +411,13 @@ def _block(cfg: GPT2Config, layer_params, h, train: bool, rng=None):
         hn = _layer_norm(h, layer_params["ln_2"]["scale"], layer_params["ln_2"]["bias"], eps)
     with parts.part("mlp"):
         m, aux = _mlp(cfg, layer_params["mlp"], hn, train, r2)
-        return h + _dropout(m, cfg.dropout, r3, train), aux
+        # the residual stream a block hands on is STATED to be sharded over the batch:
+        # under ZeRO-3 the layer's collectives are then its weights' (a gather at each
+        # use, a reduce-scatter of each gradient), where the partitioner otherwise takes
+        # the feature-sharded weights' placement for the activations' and runs the layer
+        # tensor-parallel over dp. This one pin is enough: the layer's other activations
+        # follow it, forward and (a cotangent takes its primal's constraint) backward
+        return on_batch_axis(h + _dropout(m, cfg.dropout, r3, train)), aux
 
 
 def _tag_boundary(cfg: GPT2Config, h):

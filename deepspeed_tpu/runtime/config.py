@@ -97,6 +97,25 @@ class OffloadDeviceConfig(DSConfigModel):
 class ZeroConfig(DSConfigModel):
     """zero_optimization section (reference zero/config.py).
 
+    What a stage STATES and what it leaves to XLA
+    (``runtime/zero/partitioning.py``): a ``PartitionSpec`` for every
+    parameter, gradient and optimizer-state leaf (stage 1 shards the optimizer
+    state over ``dp``, stage 2 the gradients too, stage 3 the parameters too:
+    the largest free dimension, which for a projection is a FEATURE
+    dimension) AND, since ISSUE 40, the placement of the block's activations:
+    the residual stream a block hands on is pinned to the batch axis
+    (``partitioning.on_batch_axis`` in ``models/gpt2.py``), without which the
+    partitioner takes the feature-sharded weights' placement for the
+    activations' and runs every layer tensor-parallel over ``dp`` (the global
+    batch gathered onto every chip, all-to-alls back to the batch axis). With
+    both stated, the collectives of stage 3 are the weights': an all-gather
+    at each use and a reduce-scatter of each gradient (summed in the compute
+    dtype, bf16 under ``bf16.enabled``, then cast to the accumulation dtype).
+    XLA is left the SCHEDULE: which gathers run async under the previous
+    product, which gradients share a collective. The gauges
+    ``train_step_collectives`` / ``train_step_collective_bytes``
+    (docs/OBSERVABILITY.md) say what the compiled step holds.
+
     ``reduce_bucket_size`` IS consumed here: it caps the flat gradient
     buckets of the bucketed/compressed reduce paths (``comm_compression``
     section + ``comm/compressed.py``) — each bucket becomes an independent

@@ -1713,9 +1713,12 @@ class DeepSpeedEngine:
                 # new batch shape) so comm bytes re-derive from the CURRENT
                 # program — and only then, so steady-state sampled steps skip
                 # the tree_map
+                # (an uncommitted array, as a key split on the host is, goes where
+                # the program wants it: its one-device sharding is not an argument's)
                 self._step_arg_structs = jax.tree.map(
                     lambda x: jax.ShapeDtypeStruct(
-                        x.shape, x.dtype, sharding=getattr(x, "sharding", None)
+                        x.shape, x.dtype,
+                        sharding=getattr(x, "sharding", None) if getattr(x, "committed", True) else None,
                     ),
                     (self.state, device_batch, step_rng),
                 )
@@ -1729,7 +1732,10 @@ class DeepSpeedEngine:
                     traced_flash_plan(reset=True)
                 self.state, metrics = self._train_step(self.state, device_batch, step_rng)
                 if first_call:
-                    programs_phase.set(flash_plan=self._set_flash_plan_gauges())
+                    programs_phase.set(
+                        flash_plan=self._set_flash_plan_gauges(),
+                        collectives=self._set_collective_gauges(device_batch),
+                    )
                     self._register_parts()
             self.global_steps += 1
             # monotonic train_batch ordinal: the fault-injection index. NOT
@@ -2189,6 +2195,51 @@ class DeepSpeedEngine:
             block.set(plan["bq"], dim="q")
             block.set(plan["bk"], dim="k")
         return " ".join(f"{k}={v}" for k, v in plan.items())
+
+    def _set_collective_gauges(self, device_batch: PyTree) -> str:
+        """Which collectives the step just compiled runs once a layer (the
+        loop bodies of its text, ``introspect.loop_collectives``), by kind
+        and by what they carry: a result shaped like the GLOBAL batch's
+        activations, or anything else (a weight, a gradient, the small
+        leaves). As registry gauges and (returned) as the ``collectives``
+        attr of the ``ds.init.programs`` phase: ``<kind>=<n>w+<n>a ...``. An
+        ``a`` above zero under ``dp`` means the partitioner runs the layer
+        tensor-parallel over ``dp`` (``partitioning.on_batch_axis``). All
+        zero, and nothing read, on one ``dp`` rank and on the paths that run
+        several programs a step."""
+        from ..telemetry.introspect import COLLECTIVE_KINDS, loop_collectives
+
+        counts = {(k, o): 0 for k in COLLECTIVE_KINDS for o in ("weight", "activation")}
+        nbytes = dict.fromkeys(COLLECTIVE_KINDS, 0)
+        if self.dp_world_size > 1 and hasattr(self._train_step, "lower"):
+            # [gas, micro * dp, seq, ...]: the tokens of one micro-step over all ranks
+            tokens = int(np.prod(jax.tree.leaves(device_batch)[0].shape[1:3]))
+            # (the jit call above left trace, lowering and executable in jax's caches:
+            # the analysis copy costs no second compile)
+            for c in loop_collectives(self._compiled_step().as_text()):
+                counts[(c.kind, "activation" if c.carries(tokens) else "weight")] += 1
+                nbytes[c.kind] += c.nbytes
+        if self.telemetry is not None:
+            reg = self.telemetry.registry
+            n = reg.gauge(
+                "train_step_collectives",
+                "collectives in the loop bodies of the compiled train step "
+                "(once a layer), by kind and by whether the result is shaped "
+                "like the global batch's activations; 0 on one dp rank",
+                labelnames=("kind", "operand"),
+            )
+            b = reg.gauge(
+                "train_step_collective_bytes",
+                "result bytes on one device of those collectives, by kind",
+                labelnames=("kind",),
+            )
+            for (kind, operand), v in counts.items():
+                n.set(v, kind=kind, operand=operand)
+            for kind, v in nbytes.items():
+                b.set(v, kind=kind)
+        return " ".join(
+            f"{k}={counts[(k, 'weight')]}w+{counts[(k, 'activation')]}a" for k in COLLECTIVE_KINDS
+        )
 
     def _jit_step_programs(self) -> int:
         """Invalidation key for program-derived caches: the jitted step's

@@ -23,6 +23,7 @@ on the installed grammar it returns ``[]`` (ROADMAP D14).
 
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
@@ -297,7 +298,130 @@ def parse_named_instruction(line: str) -> Optional[NamedInstruction]:
     )
 
 
+OP_NAME = re.compile(r'op_name="([^"]*)"')   # an instruction's metadata names the primitive that made it
+
+
+def instructions_by_computation(hlo_text: str) -> Dict[str, List[NamedInstruction]]:
+    """Computation name → its parsed instructions, in the text's order. (The
+    backend's own config closes an instruction's line and is its longest
+    part: it is cut off before the line is parsed.)"""
+    out: Dict[str, List[NamedInstruction]] = {}
+    for comp, lines in split_computations(hlo_text).items():
+        for line in lines:
+            if " = " in line:
+                ni = parse_named_instruction(line.split(", backend_config=", 1)[0])
+                if ni is not None:
+                    out.setdefault(comp, []).append(ni)
+    return out
+
+
 def entry_computation(txt: str) -> Optional[str]:
     """Name of the ENTRY computation in ``txt`` (None if absent)."""
     m = re.search(r"^\s*ENTRY\s+%?([\w.\-]+)\s*\(", txt, re.M)
     return m.group(1) if m else None
+
+
+# ---------------------------------------------------------------------------
+# the collectives of a program's loops
+# ---------------------------------------------------------------------------
+
+COLLECTIVE_KINDS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
+_COLLECTIVE_OPS = {k.replace("_", "-"): k for k in COLLECTIVE_KINDS}
+_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+@dataclass(frozen=True)
+class LoopCollective:
+    """One collective of a loop body, as the optimised text has it."""
+
+    name: str            # the loop body's instruction: the collective, or the fusion that runs it
+    kind: str            # one of COLLECTIVE_KINDS
+    shapes: tuple        # result shapes, ((dtype, (dims...)), ...): an all-reduce of a tuple has several
+    nbytes: int          # bytes of the result on one device
+    op_name: str         # the metadata's op_name ("" where the compiler gave none)
+    overlapped: bool     # started and awaited by separate instructions: compute may run between them
+
+    def carries(self, tokens: int) -> bool:
+        """Whether a result is shaped like the activations of ``tokens``
+        tokens (the GLOBAL batch times the sequence): the dimensions before
+        the last multiply to ``tokens``
+        (``[16,1024,1600]`` and an all-to-all's ``[4,4,1024,1600]`` alike). A
+        weight with as many rows as the batch has tokens reads the same."""
+        return any(len(dims) >= 2 and math.prod(dims[:-1]) == tokens for _, dims in self.shapes)
+
+
+def loop_collectives(hlo_text: str) -> List[LoopCollective]:
+    """The all-gathers, reduce-scatters, all-reduces and all-to-alls that run
+    once an iteration of a ``while`` loop of one optimised HLO module (a scan
+    over layers, forward and backward; nested loops and conditionals
+    included), each counted ONCE however the backend spells it:
+
+    - a plain instruction (``all-gather``, ``all-to-all``, ...): nothing
+      overlaps it;
+    - an ``X-start`` / ``X-done`` pair: the start counts, with the larger
+      element of its (operand, result) tuple;
+    - the TPU's fused forms: a ``kCustom`` fusion named
+      ``async-collective-start`` counts for the collective its computation
+      holds, the ``async-collective-done`` that awaits it and the
+      ``async_collective_fusion`` computation of the work that runs meanwhile
+      repeat that collective and are passed over; a fusion that calls an
+      ``all-reduce-scatter`` computation is the reduce-scatter (an all-reduce
+      and each device's slice of it), with the fusion's result: the shard."""
+    comps = instructions_by_computation(hlo_text)
+
+    def called(ni: NamedInstruction) -> List[str]:
+        out = _CALLED.findall(ni.attrs)
+        for group in _BRANCHES.findall(ni.attrs):
+            out.extend(c.strip().lstrip("%") for c in group.split(","))
+        return out
+
+    def dims_of(shapes) -> tuple:
+        return tuple((dt, tuple(int(d) for d in dd.split(",") if d)) for dt, dd in shapes)
+
+    found: List[LoopCollective] = []
+    seen: set = set()
+
+    def collective(ni: NamedInstruction, holder: NamedInstruction, overlapped: bool) -> None:
+        kind = _COLLECTIVE_OPS.get(ni.op.removesuffix("-start"))
+        if kind is None:
+            return
+        shapes = [s for s in ni.result_shapes if s[0] in DTYPE_BYTES and s[1]]
+        if ni.op.endswith("-start") and len(shapes) > 1:   # (operand alias, result)
+            shapes = [max(shapes, key=lambda s: shape_bytes(*s))]
+        m = OP_NAME.search(ni.attrs) or OP_NAME.search(holder.attrs)
+        found.append(LoopCollective(
+            holder.name, kind, dims_of(shapes), sum(shape_bytes(*s) for s in shapes),
+            m.group(1) if m else "", overlapped or ni.op.endswith("-start"),
+        ))
+
+    def walk(comp: str) -> None:
+        if comp in seen:
+            return
+        seen.add(comp)
+        for ni in comps.get(comp, ()):
+            if ni.op != "fusion":
+                collective(ni, ni, False)
+                for c in called(ni):
+                    walk(c)
+                continue
+            inner = [c for c in called(ni) if c in comps]
+            if any(c.startswith("all-reduce-scatter") for c in inner):
+                m = OP_NAME.search(ni.attrs)
+                found.append(LoopCollective(
+                    ni.name, "reduce_scatter", dims_of(ni.result_shapes), ni.result_bytes,
+                    m.group(1) if m else "", False,
+                ))
+            elif ni.name.startswith("async-collective-start"):
+                for c in inner:
+                    for held in comps[c]:
+                        collective(held, ni, True)
+            # any other fusion computes (or awaits, or runs beside, a collective counted at its start)
+
+    for instrs in comps.values():
+        for ni in instrs:
+            if ni.op == "while":
+                body = re.search(r"body=%?([\w.\-]+)", ni.attrs)
+                if body:
+                    walk(body.group(1))
+    return found

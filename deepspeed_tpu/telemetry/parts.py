@@ -33,7 +33,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
-from .introspect import parse_named_instruction, split_computations
+from .introspect import OP_NAME as _OP_NAME, instructions_by_computation
 
 logger = logging.getLogger(__name__)
 
@@ -120,7 +120,6 @@ class Entry(NamedTuple):
 # -- the op_name -------------------------------------------------------------
 
 _PART_IN_NAME = re.compile(re.escape(PREFIX) + r"([a-z]+(?:\.[a-z]+)*)")
-_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _FRAME = re.compile(r"stack_frame_id=(\d+)")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
@@ -219,14 +218,8 @@ def table_of(hlo_text: str) -> Dict[str, Entry]:
     frames = _frames(hlo_text)
     instrs: Dict[str, _Instr] = {}
     by_comp: Dict[str, List[str]] = {}
-    for comp, lines in split_computations(hlo_text).items():
-        for line in lines:
-            if " = " not in line:
-                continue
-            # the backend's own config closes the line and is its longest part
-            ni = parse_named_instruction(line.split(", backend_config=", 1)[0])
-            if ni is None:
-                continue
+    for comp, parsed in instructions_by_computation(hlo_text).items():
+        for ni in parsed:
             m = _OP_NAME.search(ni.attrs)
             calls = _CALLS.search(ni.attrs) if ni.op == "fusion" else None
             frame = _FRAME.search(ni.attrs)

@@ -16,15 +16,20 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -652,3 +657,65 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     mem = compiled.memory_analysis()
     print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16
+
+
+# -- ZeRO-3 over dp on the four described chips: the collectives are the weights' (ISSUE 40) --
+
+@pytest.mark.parametrize("pinned", [True, False])
+def test_zero3_over_four_chips_gathers_weights_and_reduce_scatters_gradients(topo, monkeypatch, pinned):
+    """The training step's forward and backward for the described 2x2 under
+    ZeRO-3's parameter and gradient specs (4 layers x 512 wide, 16 x 256
+    tokens, bf16, full remat, the flash kernels in their ``shard_map``). With
+    the residual stream pinned to the batch axis the loop bodies hold no
+    all-to-all and no collective shaped like the global batch's activations:
+    weight all-gathers, ONE ``kCustom`` fusion that calls
+    ``%all-reduce-scatter`` over three weight gradients, and at most one
+    weight-shaped all-reduce (the one the combiner merged with the small
+    leaves' before the reduce-scatters were made: ``c_attn_w``'s at this
+    width). Without the pin (the parent's program) the partitioner runs the
+    layer tensor-parallel over dp: five all-to-alls and the global batch
+    gathered."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy
+    from deepspeed_tpu.telemetry.introspect import loop_collectives
+
+    if not pinned:
+        monkeypatch.setattr(gpt2, "on_batch_axis", lambda x, axis="dp": x)
+    L, E, H, B, S = 4, 512, 8, 16, 256
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    cfg = gpt2.GPT2Config(n_embd=E, n_head=H, n_layer=L, n_positions=S, attn_impl="pallas", dtype=jnp.bfloat16,
+                          remat=True)
+    mod = gpt2.make_module(cfg)
+    abstract = jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    policy = ZeroShardingPolicy(mesh, stage=3)
+    grad_specs = policy.grad_shardings(abstract, mod.logical_axes)
+    params = jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+                          abstract, policy.param_shardings(abstract, mod.logical_axes))
+    ids = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec("dp")))
+
+    def step(p, ids):
+        loss, grads = jax.value_and_grad(lambda p: mod.loss_fn(p, {"input_ids": ids}, None, True)[0])(p)
+        return loss, jax.lax.with_sharding_constraint(grads, grad_specs)
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(step).lower(params, ids).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3   # the flash kernels are there
+    found = loop_collectives(text)
+    kinds = {k: [c for c in found if c.kind == k] for k in ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")}
+    activations = [c for c in found if c.carries(B * S)]
+    if not pinned:
+        assert len(kinds["all_to_all"]) == 5
+        assert any(dims == (B, S, E) for c in activations if c.kind == "all_gather" for _, dims in c.shapes)
+        return
+    assert kinds["all_to_all"] == [] and activations == []
+    gathered = {dims for c in kinds["all_gather"] for _, dims in c.shapes}
+    assert gathered >= {(1, E, 3 * E), (1, E, E), (1, E, 4 * E), (1, 4 * E, E)}, gathered
+    # each chip's quarter of three weight gradients, in one fusion
+    (scatter,) = kinds["reduce_scatter"]
+    assert sorted(dims for _, dims in scatter.shapes) == [(E // 4, E), (E, E), (E, E)], scatter
+    assert '%all-reduce-scatter' in text
+    weight_shaped = [c for c in kinds["all_reduce"] if any(len(dims) >= 2 for _, dims in c.shapes)]
+    assert len(weight_shaped) <= 1, weight_shaped

@@ -280,9 +280,11 @@ def test_leaves_tile_train_batch_and_the_first_call_is_a_programs_phase():
     assert statistics.median(ratios) > 0.95
     assert [p[3] for p in phases if p[0] == "ds.init.params"] == [{"what": "train_state"}]
     progs = [p for p in phases if p[0] == "ds.init.programs"]
-    # on the CPU the jnp attention runs: no flash kernel, so no plan (ISSUE 33)
+    # on the CPU the jnp attention runs: no flash kernel, so no plan (ISSUE 33); on one dp
+    # rank the step's text is not read for its collectives: all zero (ISSUE 40)
     assert len(progs) == 1 and progs[0][3] == {
-        "what": "train_step", "flash_plan": "bq=0 bk=0 masked=0 plain=0"}
+        "what": "train_step", "flash_plan": "bq=0 bk=0 masked=0 plain=0",
+        "collectives": "all_gather=0w+0a reduce_scatter=0w+0a all_reduce=0w+0a all_to_all=0w+0a"}
     # the step's compilation happened inside it, and inside the first step's dispatch leaf
     first_dispatch = next(r for r in recs if r[0] == "ds.train.dispatch")
     assert first_dispatch[1] <= progs[0][1] and progs[0][2] <= first_dispatch[2]
