@@ -33,7 +33,6 @@ module assumes is listed in the configuration file that runs it
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from dataclasses import dataclass
 from typing import Any
@@ -46,6 +45,8 @@ from ..moe.expert_share import GROUPED_MIN_ROWS, ExpertShare, expert_share_layer
 from ..ops.layer_norm import rms_norm
 from ..runtime.module import ModuleSpec
 from ..telemetry import parts
+from . import mla
+from .mla import rotary  # noqa: F401  (the family's rotary: shared with models/longcat_flash.py)
 
 PyTree = Any
 
@@ -159,8 +160,7 @@ class Mistral4Config:
 def _leaf_shapes(cfg: Mistral4Config) -> PyTree:
     """The tree, with (shape, kind) leaves: kind ``w`` is drawn normal with
     ``initializer_range``, ``one`` is a norm's gain."""
-    E, H, F, n = cfg.hidden_size, cfg.num_attention_heads, cfg.moe_intermediate_size, cfg.n_routed_experts
-    R, C = cfg.q_lora_rank, cfg.kv_lora_rank
+    E, F, n = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_routed_experts
     n_pub = cfg.n_routed_experts_published
 
     def ffn(lead):
@@ -169,14 +169,7 @@ def _leaf_shapes(cfg: Mistral4Config) -> PyTree:
 
     layer = {
         "norm_1": ((E,), "one"), "norm_2": ((E,), "one"),
-        "attn": {
-            "wq_a": ((E, R), "w"), "q_norm": ((R,), "one"),
-            "wq_b": ((R, H * cfg.qk_head_dim), "w"),
-            "wkv_a": ((E, cfg.kv_width), "w"), "kv_norm": ((C,), "one"),
-            "w_uk": ((C, H, cfg.qk_nope_head_dim), "w"),
-            "w_uv": ((C, H, cfg.v_head_dim), "w"),
-            "wo": ((H * cfg.v_head_dim, E), "w"),
-        },
+        "attn": mla.attention_leaf_shapes(cfg),
         "moe": {
             "router": ((E, n_pub), "w"),
             # drawn like a weight, not zero: s + b and s then select differently
@@ -190,28 +183,10 @@ def _leaf_shapes(cfg: Mistral4Config) -> PyTree:
     }
 
 
-def _is_leaf(x):
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
-
-
 def init_params(cfg: Mistral4Config, rng, dtype=None) -> PyTree:
     """Every leaf made on the device in ``dtype`` by a program of its own
-    (one a distinct shape), so the set-up never holds more than the tree and
-    one leaf's temporaries."""
-    dtype = dtype or cfg.dtype
-    leaves, treedef = jax.tree_util.tree_flatten(_leaf_shapes(cfg), is_leaf=_is_leaf)
-    keys = jax.random.split(rng, len(leaves))
-    std = cfg.initializer_range
-
-    @functools.lru_cache(maxsize=None)
-    def drawn(shape):
-        return jax.jit(lambda k: (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype))
-
-    def make(key, spec):
-        shape, kind = spec
-        return jnp.ones(shape, dtype) if kind == "one" else drawn(shape)(key)
-
-    return jax.tree_util.tree_unflatten(treedef, [make(k, s) for k, s in zip(keys, leaves)])
+    (``mla.draw_tree``)."""
+    return mla.draw_tree(_leaf_shapes(cfg), rng, dtype or cfg.dtype, cfg.initializer_range)
 
 
 def logical_axes(cfg: Mistral4Config) -> PyTree:
@@ -222,12 +197,7 @@ def logical_axes(cfg: Mistral4Config) -> PyTree:
 
     layer = {
         "norm_1": (None,), "norm_2": (None,),
-        "attn": {
-            "wq_a": ("embed", None), "q_norm": (None,), "wq_b": (None, "mlp"),
-            "wkv_a": ("embed", None), "kv_norm": (None,),
-            "w_uk": (None, "heads", None), "w_uv": (None, "heads", None),
-            "wo": ("mlp", "embed"),
-        },
+        "attn": dict(mla.ATTENTION_AXES),
         "moe": {"router": ("embed", None), "bias": (None,),
                 "experts": ffn(("expert",)), "shared": ffn(())},
     }
@@ -255,19 +225,9 @@ def yarn_inv_freq(cfg: Mistral4Config) -> np.ndarray:
     return ((1.0 - r) * f + r * f / cfg.rope_factor).astype(np.float32)
 
 
-def rotary(x, positions, inv_freq):
-    """Interleaved rotary in float32: ``x [..., S, heads, D]`` at ``positions
-    [..., S]``; the pair is elements (2j, 2j + 1) → float32."""
-    ang = positions.astype(jnp.float32)[..., None, None] * inv_freq      # [..., S, 1, D/2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-    x1, x2 = xp[..., 0], xp[..., 1]
-    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).reshape(x.shape)
-
-
-class Mistral4Family:
+class Mistral4Family(mla.LatentAttention):
     """What ``serving/model.py`` asks of a model (see its ``Family`` notes): a
-    LATENT family. One pool, whose one "kv head" is the cached row: ``head_dim``
+    LATENT family (the attention half is ``models/mla.LatentAttention``). One pool, whose one "kv head" is the cached row: ``head_dim``
     is the row's width and ``v_width`` the leading lanes that are the values."""
 
     prefill_block = 128   # the whole-prompt program attends (expanded) in query blocks of this many
@@ -294,64 +254,14 @@ class Mistral4Family:
     def layer(self, params, l: int):
         return params["layers"][l]
 
-    def _projections(self, lp, h, positions):
-        """→ (``q_nope [.., H, nope]``, rotated and position-scaled pieces in
-        float32: ``q_rope [.., H, rope]``, the query scale ``a [.., 1, 1]``,
-        and the row to cache ``[c | rot(kr)] [.., 1, kv_width]`` in ``h``'s
-        type)."""
-        cfg, a = self.cfg, lp["attn"]
-        H, N = cfg.n_head, cfg.qk_nope_head_dim
-        with parts.part("norm"):
-            u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
-        q = rms_norm(u @ a["wq_a"], a["q_norm"], cfg.rms_norm_eps) @ a["wq_b"]
-        q = q.reshape(*q.shape[:-1], H, cfg.qk_head_dim)
-        kv = u @ a["wkv_a"]
-        c = rms_norm(kv[..., : cfg.kv_lora_rank], a["kv_norm"], cfg.rms_norm_eps)
-        kr = rotary(kv[..., None, cfg.kv_lora_rank:], positions, self.inv_freq)
-        row = jnp.concatenate([c[..., None, :], kr.astype(c.dtype)], axis=-1)
-        # the position-scaled query: 1 inside the original context, then steps
+    def query_scale(self, positions):
+        """The position-scaled query ``a_i [..., 1, 1]``: 1 inside the original
+        context, then steps."""
+        cfg = self.cfg
         scale = 1.0 + cfg.llama_4_scaling_beta * jnp.log1p(jnp.floor(
             positions.astype(jnp.float32) / cfg.original_max_position_embeddings
         ))
-        return q[..., :N], rotary(q[..., N:], positions, self.inv_freq), scale[..., None, None], row
-
-    def qkv(self, lp, h, positions, l: int):
-        """``h [B, S, E]`` → the ABSORBED query ``[B, S, H, kv_width]`` (``a_i
-        [q_nope w_uk^T | rot(q_rope)]``, accumulated in float32 through
-        ``w_uk`` and rounded once), the row to cache ``[B, S, 1, kv_width]``,
-        and no values: they are the row's first ``v_width`` lanes."""
-        q_nope, q_rope, scale, row = self._projections(lp, h, positions)
-        qa = jnp.einsum("...hn,chn->...hc", q_nope, lp["attn"]["w_uk"],
-                        preferred_element_type=jnp.float32)
-        q = jnp.concatenate([qa, q_rope], axis=-1) * scale
-        return q.astype(h.dtype), row, None
-
-    def qkv_expanded(self, lp, h, positions, l: int):
-        """The same attention per head: ``q [B, S, H, qk_head_dim]`` (scaled
-        by ``a_i``), ``k`` the same shape (``[c w_uk_h | rot(kr)]``), ``v [B,
-        S, H, v_head_dim]``, and the row to cache."""
-        H = self.cfg.n_head
-        q_nope, q_rope, scale, row = self._projections(lp, h, positions)
-        q = jnp.concatenate([q_nope.astype(jnp.float32), q_rope], axis=-1) * scale
-        c, kr = row[..., 0, : self.v_width], row[..., self.v_width:]
-        k_nope = jnp.einsum("...c,chn->...hn", c, lp["attn"]["w_uk"])
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(kr, (*kr.shape[:-2], H, kr.shape[-1]))], axis=-1
-        )
-        v = jnp.einsum("...c,chv->...hv", c, lp["attn"]["w_uv"])
-        return q.astype(h.dtype), k, v, row
-
-    def attn_out(self, lp, o, tp_axis=None):
-        """``o [B, S, H * v_width]``, the absorbed attention's output (a mix
-        of latents a head) → through ``w_uv`` then ``wo``."""
-        H = self.cfg.n_head
-        with parts.part("attn.core"):  # the values' half of the absorption belongs to the attention
-            o = o.reshape(*o.shape[:-1], H, self.v_width)
-            o = jnp.einsum("...hc,chv->...hv", o, lp["attn"]["w_uv"])
-        return o.reshape(*o.shape[:-2], -1) @ lp["attn"]["wo"]
-
-    def attn_out_expanded(self, lp, o, tp_axis=None):
-        return o @ lp["attn"]["wo"]
+        return scale[..., None, None]
 
     def mlp(self, lp, h, l: int, valid=None, tp_axis=None):
         """→ (the layer's expert MLP of the residual stream ``h [B, S, E]``,
@@ -373,30 +283,9 @@ class Mistral4Family:
 
 
 def forward(cfg: Mistral4Config, params: PyTree, input_ids, absorbed: bool = False) -> jnp.ndarray:
-    """Whole-sequence logits ``[B, S, vocab]`` with no cache: the family's
-    pieces under a dense masked softmax, expanded (per-head keys and values)
-    or ``absorbed`` (multi-query on the cached row), for small sizes; the
-    served path is ``serving/model.py``."""
-    fam = Mistral4Family(cfg)
-    B, S = input_ids.shape
-    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-    h = fam.embed(params, input_ids, pos)
-    seen = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    for l in range(cfg.n_layer):
-        lp = fam.layer(params, l)
-        if absorbed:
-            q, row, _ = fam.qkv(lp, h, pos, l)
-            k = jnp.broadcast_to(row, (B, S, cfg.n_head, row.shape[-1]))
-            v, out = k[..., : fam.v_width], fam.attn_out
-        else:
-            q, k, v, _ = fam.qkv_expanded(lp, h, pos, l)
-            out = fam.attn_out_expanded
-        s = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32), k.astype(jnp.float32))
-        p = jax.nn.softmax(jnp.where(seen, s * fam.sm_scale, -1e30), axis=-1)
-        o = jnp.einsum("bhst,bthd->bshd", p, v.astype(jnp.float32)).astype(h.dtype)
-        h = h + out(lp, o.reshape(B, S, -1))
-        h = h + fam.mlp(lp, h, l)[0]
-    return fam.logits(params, h)
+    """Whole-sequence logits ``[B, S, vocab]`` with no cache (``mla.forward``:
+    expanded, or ``absorbed``), for small sizes."""
+    return mla.forward(Mistral4Family(cfg), params, input_ids, absorbed)
 
 
 def make_module(cfg: Mistral4Config) -> ModuleSpec:

@@ -1,17 +1,29 @@
 """An expert layer that is told which experts it holds.
 
 Under expert parallelism a chip holds ``n_held`` of a layer's ``n_experts``
-routed experts and every chip holds the shared expert. The layer here is that
-chip's part, with no exchange: it routes every token over ALL the published
-experts (the router keeps its full width), forms the token's weights over the
-``top_k`` it selected, and computes what its own experts give for the tokens
-routed to them, plus the shared expert once. What the absent experts would
-have added is left out; nothing stands in for the other chips.
+routed experts and every chip holds what all tokens take alike: the shared
+expert, where the model has one, and the IDENTITY experts, where the router has
+such columns (zero-compute experts: ``n_zero`` columns behind the real ones,
+whose "expert" gives the token back). The layer here is that chip's part, with
+no exchange: it routes every token over ALL the published columns (the router
+keeps its full width), forms the token's weights over the ``top_k`` it
+selected, and computes what its own experts give for the tokens routed to
+them, plus the shared expert and the identity term once. What the absent
+experts would have added is left out; nothing stands in for the other chips.
 
-    s     = sigmoid(u W_r)                      float32, over all n_experts
+    s     = sigmoid(u W_r)  |  softmax(u W_r)   float32, over all n_experts + n_zero columns
     sel   = top_k(s + b)                        the bias only selects
-    w_e   = scale * s_e / sum_{sel} s           for e in sel
-    y     = sum_{e in sel, e held} w_e FFN_e(u) + FFN_shared(u)
+    w_e   = scale * s_e / sum_{sel} s           for e in sel   (``norm_topk``)
+          | scale * s_e                                        (not renormalised)
+    y     = sum_{e in sel, e < n_experts, e held} w_e FFN_e(u)
+          + FFN_shared(u)                       where ``lp`` has ``shared``
+          + (sum_{e in sel, e >= n_experts} w_e) u             the identity columns
+
+Two scorings, one :func:`route`: ``sigmoid`` renormalised over the picks with a
+shared expert (K-EXAONE, Mistral Small 4) and ``softmax`` over 512 + 256
+columns, not renormalised, no shared expert (LongCat-Flash). The counts the
+layer reports are the tokens each held expert got and, with identity columns,
+the pairs that chose one of those (the last entry).
 
 No capacity: a held expert computes every token routed to it. Two forms of
 the same sum. A family that takes the grouped form says from how many rows a
@@ -34,11 +46,24 @@ count alone:
   rows routed to it). At 1 024 rows x 4 of 128 with 16 held the masked form
   multiplies 32 x the pairs there are (PERF.md, PR 34, has both measured).
   The pair budget is the static ``T x top_k`` (every token may pick held
-  experts only); the pairs whose expert is absent sort behind the held
-  groups, belong to no group and carry weight 0. No pair is dropped.
+  experts only); the pairs whose expert is absent (or an identity column) sort
+  behind the held groups, belong to no group and carry weight 0. No pair is
+  dropped.
 
-The sum of all the shares' routed parts and the shared part once is the uncut
-layer (``tests/unit/test_expert_share.py``).
+A third routing, LongCat-Flash's 12 of 768 with 16 of 512 held (E 6144, F
+2048): one selected pair in 48 meets a held expert, so the grouped form's
+static budget is 48 times the pairs there are and the masked form multiplies
+every row by every held expert. On the chip (PERF.md, PR 41;
+``perfbench/tools/micro_longcat_flash.py``), masked / grouped, a layer: 2.43 /
+2.49 ms at 64 rows (22 held pairs of 768, 11 experts hit), 3.20 / 3.70 ms at
+320, 7.89 / 8.08 ms at 1 024 (283 of 12 288): ``ragged_dot`` pays for the
+rows outside every group, so the grouped form wins nowhere and
+``models/longcat_flash.LongcatFlashFamily.grouped_from`` is 0. Either form
+streams all 16 held experts; a product that reads only the experts hit is
+ROADMAP S12.
+
+The sum of all the shares' routed parts, with the shared part and the identity
+term once, is the uncut layer (``tests/unit/test_expert_share.py``).
 """
 
 from __future__ import annotations
@@ -55,10 +80,13 @@ _HI = jax.lax.Precision.HIGHEST
 
 class ExpertShare(NamedTuple):
     """Which experts of ``n_experts`` live here: chip ``index`` of ``chips``
-    holds experts ``index * n_held .. (index + 1) * n_held - 1``."""
+    holds experts ``index * n_held .. (index + 1) * n_held - 1``. ``n_zero``:
+    the router's identity columns ``n_experts .. n_experts + n_zero - 1``,
+    which no chip holds matrices for and every chip computes for its tokens."""
     n_experts: int
     chips: int = 1
     index: int = 0
+    n_zero: int = 0
 
     @property
     def n_held(self) -> int:
@@ -68,11 +96,17 @@ class ExpertShare(NamedTuple):
         return self.index * self.n_held + jnp.arange(self.n_held)
 
 
-def route(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True):
-    """``u [T, E]`` → (``idx [T, k]`` int32 over all experts, ``w [T, k]``
-    float32). Scores in float32 at full precision: the selection is discrete,
-    and a bf16 pass would flip near-ties that the float32 reference keeps."""
-    s = jax.nn.sigmoid(jnp.dot(
+SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": lambda x: jax.nn.softmax(x, axis=-1)}
+
+
+def route(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True,
+          scoring: str = "sigmoid"):
+    """``u [T, E]`` → (``idx [T, k]`` int32 over all the router's columns, ``w
+    [T, k]`` float32: ``scale`` times the picked scores, renormalised over the
+    picks with ``norm_topk``). Scores (``scoring``: :data:`SCORINGS`) in
+    float32 at full precision: the selection is discrete, and a bf16 pass
+    would flip near-ties that the float32 reference keeps."""
+    s = SCORINGS[scoring](jnp.dot(
         u.astype(jnp.float32), router_w.astype(jnp.float32), precision=_HI
     ))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
@@ -88,6 +122,19 @@ def held_weights(idx, w, share: ExpertShare):
     did not select that held expert."""
     hit = idx[:, :, None] == share.held_ids()[None, None, :]      # [T, k, n]
     return jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
+
+
+def held_hits(idx, share: ExpertShare):
+    """``[T, n_held]`` bool: the token selected that held expert. From the
+    selection itself, not from the weight: a softmax score may round to 0."""
+    return jnp.any(idx[:, :, None] == share.held_ids()[None, None, :], axis=1)
+
+
+def zero_weights(idx, w, share: ExpertShare):
+    """The identity columns' part of a token's weights: → (``[T]`` float32,
+    the sum of its picks' weights there; ``[T]`` int32, how many picks)."""
+    zero = idx >= share.n_experts
+    return jnp.sum(jnp.where(zero, w, 0.0), axis=1), jnp.sum(zero, axis=1, dtype=jnp.int32)
 
 
 def gated_ffn(u, w_gate, w_up, w_down):
@@ -144,40 +191,60 @@ def held_experts_grouped(u, idx, w, share: ExpertShare, w_gate, w_up, w_down):
         return jnp.sum(y.astype(jnp.float32), axis=1).astype(u.dtype)
 
 
-def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from):
-    """→ (the held experts' part for ``u [T, E]``, ``wh [T, n_held]``)."""
+def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from, scoring):
+    """→ (the held experts' part for ``u [T, E]`` with the identity term;
+    what says which pairs are held, ``[T, n_held]``: the weights ``wh`` under
+    sigmoid scores (positive: a selected pair's weight is), the selection
+    itself under softmax (:func:`held_hits`); ``zero [T]`` int32: a token's
+    picks among the identity columns, or None)."""
     with parts.part("moe.route"):
-        idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk)
+        idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk, scoring)
         wh = held_weights(idx, w, share)
+        held = wh if scoring == "sigmoid" else held_hits(idx, share)
     ex = lp["experts"]
     if grouped_rows(u.shape[0], top_k, grouped_from):
-        return held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
-    with parts.part("moe.experts"):
-        return held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"]), wh
+        y = held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
+    else:
+        with parts.part("moe.experts"):
+            y = held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"])
+    if not share.n_zero:
+        return y, held, None
+    with parts.part("moe.route"):   # the identity experts: the token itself, weighted in float32
+        wz, zero = zero_weights(idx, w, share)
+        y = (y.astype(jnp.float32) + wz[:, None] * u.astype(jnp.float32)).astype(u.dtype)
+    return y, held, zero
 
 
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
                        norm_topk: bool = True, valid: Optional[jnp.ndarray] = None,
-                       grouped_from: int = 0):
+                       grouped_from: int = 0, scoring: str = "sigmoid"):
     """``u [T, E]`` → (``y [T, E]``, ``counts [n_held]`` int32: the tokens
-    each held expert got; with ``valid [T]`` only those rows count, e.g. the
-    slots that hold a request). ``lp``: ``router [E, n_experts]``, ``bias
-    [n_experts]``, ``experts`` and ``shared`` with ``w_gate, w_up, w_down``.
-    ``grouped_from``: calls of that many rows or more take the grouped form
-    (0: none does)."""
+    each held expert got, and with ``share.n_zero`` one entry more, the pairs
+    that chose an identity column; with ``valid [T]`` only those rows count,
+    e.g. the slots that hold a request). ``lp``: ``router [E, n_experts +
+    n_zero]``, ``bias`` as wide, ``experts`` and, where the model has a shared
+    expert, ``shared``, each with ``w_gate, w_up, w_down``. ``grouped_from``:
+    calls of that many rows or more take the grouped form (0: none does)."""
     T = u.shape[0]
     if grouped_from and T > GROUPED_BLOCK_ROWS and T % GROUPED_BLOCK_ROWS == 0:
-        y, wh = jax.lax.map(
-            lambda ub: _routed(ub, lp, share, top_k, scale, norm_topk, grouped_from),
+        y, held, zero = jax.lax.map(
+            lambda ub: _routed(ub, lp, share, top_k, scale, norm_topk, grouped_from, scoring),
             u.reshape(-1, GROUPED_BLOCK_ROWS, u.shape[1]),
         )
-        y, wh = y.reshape(T, -1), wh.reshape(T, -1)
+        y, held = y.reshape(T, -1), held.reshape(T, -1)
+        zero = None if zero is None else zero.reshape(T)
     else:
-        y, wh = _routed(u, lp, share, top_k, scale, norm_topk, grouped_from)
-    sh = lp["shared"]
-    y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
+        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, grouped_from, scoring)
+    if "shared" in lp:
+        sh = lp["shared"]
+        y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
     with parts.part("moe.route"):   # the load count
-        got = wh > 0.0  # sigmoid scores are positive: a selected pair's weight is
+        got = held > 0.0 if scoring == "sigmoid" else held
         if valid is not None:
             got = got & valid[:, None]
-        return y, jnp.sum(got, axis=0, dtype=jnp.int32)
+        counts = jnp.sum(got, axis=0, dtype=jnp.int32)
+        if zero is None:
+            return y, counts
+        if valid is not None:
+            zero = jnp.where(valid, zero, 0)
+        return y, jnp.concatenate([counts, jnp.sum(zero, dtype=jnp.int32)[None]])

@@ -5,7 +5,8 @@ for the gpt2 family serving is BIT-IDENTICAL to per-request ``generate``.
 The programs hold what every family shares (the pool writes, the paged
 attention, the sampling); the model's module gives the rest through
 ``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
-``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``):
+``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``,
+``models/longcat_flash.LongcatFlashFamily``):
 
 - :func:`paged_prefill` — one request's prompt (right-padded to the static
   prefill width) through the model, K/V written page-granularly into the
@@ -197,7 +198,12 @@ class Family:
     and then gives ``[B, 1, E]``).
 
     - ``n_layer, n_head, n_kv_head, head_dim, vocab_size, n_positions,
-      attn_impl``: geometry.
+      attn_impl``: geometry. ``n_layer`` counts the CACHED SUB-BLOCKS: what
+      the programs loop over, the pool's layers and the kernels' layer index.
+      For a model whose layer is one attention and one MLP that is its depth;
+      a family whose layer holds two attentions (LongCat-Flash's double
+      layer) gives twice its depth, ``layer(params, l)`` the l-th sub-block's
+      weights, and ties the sub-blocks together through ``after_attention``.
     - ``kv_pools, v_width``: what a program needs to know of the cache. 2: a
       K and a V pool of ``n_kv_head`` heads ``head_dim`` wide (``v_width`` is
       ``head_dim``). 1: a LATENT family, ONE pool of one row a token
@@ -213,10 +219,13 @@ class Family:
       pools), or 0: every key before it (K/V paged under the block table).
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
-    - ``sparse_layers`` / ``experts_held``: the layers whose ``mlp`` reports
-      the tokens each held expert got, and how many experts that is
+    - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
+      report the tokens each held expert got, and how many experts that is
       (``grouped_from``: the rows a call from which the family's expert
       layer takes the grouped form, 0 for never; ``moe/expert_share.py``).
+      ``zero_experts``: the router's identity columns, 0 for none; where it
+      has some, a report is ``[experts_held + 1]``, the last entry the pairs
+      that chose one of them.
     - ``embed(params, ids, positions) -> h``
     - ``layer(params, l) -> lp``
     - ``qkv(lp, h, positions, l) -> q [B,S,H,D], k, v [B,S,KV,D]``: the
@@ -227,6 +236,16 @@ class Family:
       None)``: the norm before it and the MLP or expert layer; ``valid``
       broadcasts against ``[B, S]`` (the rows that are real tokens).
     - ``logits(params, h [..., E]) -> [..., vocab]``: final norm and head.
+    - ``after_attention(lp, h, o, l, valid, tp_axis, carry, attn_out) -> h,
+      carry, counts`` (optional): the family OWNS the combination. Without
+      it the rest of a sub-block is :func:`_after_attention`'s ``h + attn_out``
+      then ``h + mlp``. With it the family takes the attention's output ``o``
+      (through ``attn_out``: its own, or the one the program names, as the
+      whole-prompt program of a latent family does) and whatever it carried
+      from the sub-blocks before (``carry``: None at sub-block 0, any pytree
+      after), and gives the stream, what it carries on, and its report or
+      None. LongCat-Flash's shortcut: the expert layer reads sub-block 0's
+      post-attention norm and is added after sub-block 1's dense FFN.
     """
 
 
@@ -262,18 +281,27 @@ def _window_view(slots, pos0, window: int, page: int, ring: int):
     return table, first * page, lo - first * page
 
 
-def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, attn_out=None):
-    """The rest of layer ``l`` in every program: the attention output into
-    the residual stream (through ``attn_out``: the family's, unless a latent
-    family attended per head), then the MLP or expert layer, whose held
-    experts' token counts (if it reports any) join ``counts``."""
+def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry=None, attn_out=None):
+    """The rest of sub-block ``l`` in every program → ``(h, carry)``: the
+    attention output into the residual stream (through ``attn_out``: the
+    family's, unless a latent family attended per head), then the MLP or
+    expert layer, whose held experts' token counts (if it reports any) join
+    ``counts``. A family that owns the combination (``after_attention``, the
+    :class:`Family` notes) does all of that itself and may hand a ``carry``
+    to its next sub-block; the others carry nothing."""
+    own = getattr(fam, "after_attention", None)
+    if own is not None:
+        h, carry, c = own(lp, h, o, l, valid, tp_axis, carry, attn_out)
+        if c is not None:
+            counts.append(c)
+        return h, carry
     with parts.part("attn.out"):  # the residual adds go with the part whose output they take in
         h = h + (attn_out or fam.attn_out)(lp, o, tp_axis)
     with parts.part("mlp"):
         m, c = fam.mlp(lp, h, l, valid, tp_axis)
         if c is not None:
             counts.append(c)
-        return h + m
+        return h + m, None
 
 
 def _window_views(fam, slots, pos0, page: int, ring: int):
@@ -288,7 +316,8 @@ def _result(k_pool, v_pool, scales, win, token, counts):
     """A program's results in the order the scheduler takes them: the paged
     pools, an int8 pool's scales, a window family's ring pools, the token(s)
     and, for a family with expert layers, the tokens each held expert got
-    ``[sparse layers, experts_held]``."""
+    ``[sparse layers, experts_held]`` (one entry more a layer where the
+    router has identity columns: the pairs that chose one)."""
     out = (k_pool, v_pool)  # v_pool None: a latent family's (ProgramSet.aot drops it)
     if scales is not None:
         out += (scales,)
@@ -503,7 +532,7 @@ def paged_prefill(
     with parts.part("embed"):
         h = fam.embed(params, input_ids, positions)
     valid = (positions < prompt_len) if fam.sparse_layers else None
-    counts = []
+    counts, carry = [], None
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
@@ -516,8 +545,8 @@ def paged_prefill(
             o = _attend_prompt_blocked(
                 q, k_, v, 0, math.gcd(Sp, fam.prefill_block), fam.sm_scale
             )
-            h = _after_attention(
-                fam, lp, h, o, l, valid, tp_axis, counts, fam.attn_out_expanded
+            h, carry = _after_attention(
+                fam, lp, h, o, l, valid, tp_axis, counts, carry, fam.attn_out_expanded
             )
             continue
         with parts.part("attn.qkv"):
@@ -532,7 +561,7 @@ def paged_prefill(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
                 page_ids, li, scales,
             )
-        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
+        h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
     with parts.part("head"):
         h_last = jnp.take(h, prompt_len - 1, axis=1)  # [B, E] true last prompt pos
@@ -720,7 +749,7 @@ def paged_decode_step(
     poff = seq_lens % page
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
     valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
-    counts = []
+    counts, carry = [], None
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
@@ -739,7 +768,7 @@ def paged_decode_step(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
                 block_tables, seq_lens, pidx, poff, li, scales,
             )
-        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
+        h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
     with parts.part("head"):
         logits = fam.logits(params, h[:, -1])
@@ -915,7 +944,7 @@ def paged_verify_step(
     pidx, poff = _verify_write_targets(seq_lens, block_tables, page, T)
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if win is not None else None
     valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
-    counts = []
+    counts, carry = [], None
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
@@ -936,7 +965,7 @@ def paged_verify_step(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
                 block_tables, seq_lens, pidx, poff, li, scales,
             )
-        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
+        h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
     with parts.part("head"):
         logits = fam.logits(params, h)
@@ -1032,7 +1061,7 @@ def paged_mixed_step(
     real = block_tables[:, 0] != 0  # a slot that decodes holds a page; page 0 is scratch
     valid = jnp.concatenate([c_pos < prompt_len, real]) if fam.sparse_layers else None
     live = jnp.any(real) if B >= SKIP_IDLE_READS_FROM_SLOTS else None
-    counts = []
+    counts, carry = [], None
     rw = None
     if win is not None:
         ring_ids = ring_page_ids(slot, start // page + jnp.arange(C // page), ring)
@@ -1095,7 +1124,7 @@ def paged_mixed_step(
                 scales[li] if scales is not None else None, name="chunk_fn",
             )
         o = oc if od is None else jnp.concatenate([oc, jnp.swapaxes(od, 0, 1)], axis=1)
-        h = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts)
+        h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
     # one pass of the head: the chunk's true last prompt position (when it
     # falls inside this chunk) and the decode rows

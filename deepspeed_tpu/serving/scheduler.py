@@ -211,7 +211,8 @@ class ServingEngine:
             raise ValueError(
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
-                "including injected HF GPT-2, exaone_moe and mistral4); got "
+                "including injected HF GPT-2, exaone_moe, mistral4 and "
+                "longcat_flash); got "
                 f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
@@ -688,6 +689,11 @@ class ServingEngine:
             "serving_moe_pairs_routed_total",
             "token-expert pairs routed (tokens x experts a token x expert "
             "layers), over decode steps and chunk calls",
+        )
+        self._c_moe_zero = m.counter(
+            "serving_moe_pairs_zero_total",
+            "token-expert pairs that chose an identity (zero-compute) expert: "
+            "no matrices, the token itself, on whichever chip serves it",
         )
         # -- ISSUE 14: TP sharding + disaggregation instruments ------------
         self._g_tp_coll = m.gauge(
@@ -1319,8 +1325,15 @@ class ServingEngine:
         """Span attributes from the expert layers' ``[calls x sparse layers,
         experts_held]`` token counts of one decode step or of a prompt's
         chunk calls, ``n_tokens`` real tokens in all; the registry's pair
-        counters move with them."""
+        counters move with them. A family whose router has identity columns
+        reports one entry more a layer, the pairs that chose one: a third kind
+        beside the held and the routed."""
         fam = self.family
+        attrs = {}
+        if getattr(fam, "zero_experts", 0):
+            counts, zero = counts[:, :-1], int(counts[:, -1].sum())
+            self._c_moe_zero.inc(zero)
+            attrs["moe_pairs_zero"] = zero
         held = int(counts.sum())
         routed = int(n_tokens) * fam.experts_per_token * len(fam.sparse_layers)
         self._c_moe_held.inc(held)
@@ -1328,7 +1341,7 @@ class ServingEngine:
         return {
             "moe_pairs_held": held, "moe_pairs_routed": routed,
             "moe_load_max": int(counts.max()),
-            "moe_experts_hit": int((counts > 0).sum()),
+            "moe_experts_hit": int((counts > 0).sum()), **attrs,
         }
 
     def _set_collective_gauges(self) -> None:

@@ -51,6 +51,7 @@ PARTS = (
     "attn.out",     # the output projection
     "kv.write",     # token and page writes into pools and rings
     "mlp",          # a dense MLP and a shared expert
+    "mlp.dense",    # a double layer's two dense FFNs, beside its expert layer (models/longcat_flash.py)
     "moe.route",    # router logits, top-k, the sort, gathers, scatters and the combine
     "moe.experts",  # the held experts' products, masked or grouped
     "head",         # final norm, logits, the loss in training

@@ -659,6 +659,123 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16
 
 
+# -- the double-layer latent family (LongCat-Flash): the same kernels at 640 lanes, 512 values, 64 heads --
+
+def _lcf_config():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    with open(os.path.join(root, "perfbench", "configs", "longcat-flash-560b-ep32-serve-1chip.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 256), (64, 4)], ids=["decode", "chunk", "verify4"])
+def test_latent_kernels_compile_for_v5e_at_the_double_layer_familys_shapes(one_chip, B, T):
+    """64 heads on a 576-wide row stored in 640 lanes whose first 512 are the
+    values: the blocks come from the shapes (``latent_blocks``: 16 tokens x 64
+    heads a chunk step against 512 keys, 2 048 keys a decode step) and fit the
+    kernels' VMEM budget as they are."""
+    from deepspeed_tpu.ops.pallas.latent_attention import latent_blocks, latent_paged_attention, latent_token_write
+    from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+
+    c = _lcf_config()
+    sv = c["serving"]
+    L, P, page, W = 2 * c["num_layers"], sv["num_pages"], sv["page_size"], 640
+    n = -(-(sv["max_prompt_len"] + sv["max_new_tokens"]) // page)
+    assert latent_blocks(64, page, T, n) == {1: (1, 16), 256: (16, 4), 4: (4, 16)}[T]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((L, P, 1, page, W), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, p, bt, base: latent_paged_attention(q, p, bt, base, 512, 0.0722, layer=5, name="mla")
+    ).lower(sds((B, T, 64, W), jnp.bfloat16), pool, sds((B, n), jnp.int32), sds((B,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    if T != 256:
+        idx = (B,) if T == 1 else (B, T)
+        rows = (B, 1, W) if T == 1 else (B, T, 1, W)
+        compiled = jax.jit(
+            lambda p, pidx, poff, rows: latent_token_write(p, 5, pidx, poff, rows), donate_argnums=(0,)
+        ).lower(pool, sds(idx, jnp.int32), sds(idx, jnp.int32), sds(rows, jnp.bfloat16)).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e6      # the pool is written in place
+    assert pool_stored_shape(8, 1793, 1, 128, 576, jnp.bfloat16, latent=True) == (8, 1793, 1, 128, 576)  # off the TPU
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "prefill"])
+def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
+    """The ``longcat_flash`` programs as its cell serves them (64 slots, 8
+    cached sub-blocks of 64 query heads on one 640-lane row a token, 128-token
+    pages, a 3 072-token whole-prompt width, 16 held experts of 512 + 256
+    identity columns, 10.35 GB of bf16 weights as shapes): a latent kernel and
+    a token write a SUB-BLOCK, nothing re-lays the pool out, every call of the
+    expert layer masked, and arguments + temps fit the chip."""
+    from deepspeed_tpu.models import longcat_flash
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    c = _lcf_config()
+    cfg = longcat_flash.LongcatFlashConfig.from_dict(c)
+    sv = c["serving"]
+    B, page, P, Sp, C = (sv[k] for k in ("max_slots", "page_size", "num_pages", "max_prompt_len", "prefill_chunk_tokens"))
+    W = -(-(Sp + sv["max_new_tokens"]) // page)
+    L = cfg.n_layer
+    assert L == 8 and P == B * W + 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: longcat_flash.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    assert 10.3e9 < 2 * sum(x.size for x in jax.tree.leaves(params)) < 10.4e9
+    shape = pool_stored_shape(L, P, 1, page, cfg.kv_width, jnp.bfloat16, latent=True)
+    assert shape == (L, P, 1, page, 640)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16))
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "mixed": (
+            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
+                cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
+             sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
+        "prefill": (
+            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
+        ),
+    }[program]
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
+        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=640, n_layer=L,
+    )
+    compiled = pset.aot(fn, host, with_params=True)
+    text = compiled.as_text()
+    assert pset.program_census(program, compiled)[0] == 0  # or it raises
+    assert text.count("ragged-dot") == 0                    # the family keeps every call masked
+    if program != "prefill":  # the kernels keep the names the readers find them by, one a sub-block
+        names = {"decode": ("mla_paged_decode", "kv_token_write"),
+                 "mixed": ("mla_paged_decode", "mla_paged_chunk", "kv_token_write")}[program]
+        for kernel in names:
+            assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
+    took_in, _ = compiled.input_formats
+    assert took_in[1].layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].layout.major_to_minor
+    mem = compiled.memory_analysis()
+    print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
+    assert 12.6e9 < mem.argument_size_in_bytes < 12.8e9     # 10.35 GB of weights and 2.35 GB of pool
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16
+
+
 # -- ZeRO-3 over dp on the four described chips: the collectives are the weights' (ISSUE 40) --
 
 @pytest.mark.parametrize("pinned", [True, False])

@@ -163,3 +163,130 @@ def test_the_layer_switches_form_by_its_static_row_count(monkeypatch):
         assert calls == [(64 if block == 4096 else 16, E)]
         np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=2e-5, atol=2e-6)
         np.testing.assert_array_equal(np.asarray(c), np.asarray(want_c))
+
+
+# -- softmax scoring, identity columns, no shared expert (LongCat-Flash) ---------
+
+NZ = 8                      # identity columns behind the N real ones
+SCALE_Z, KZ = 6.0, 5
+
+
+def _layer_z(seed=1, bias_std=1.0 / (N + NZ)):
+    lp = _layer(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 50), 2)
+    lp = {k: v for k, v in lp.items() if k != "shared"}
+    lp["router"] = jax.random.normal(ks[0], (E, N + NZ), jnp.float32) * 0.3
+    lp["bias"] = jax.random.normal(ks[1], (N + NZ,), jnp.float32) * bias_std
+    return lp
+
+
+def _plain_z(lp, u, mode=""):
+    """The uncut layer, one token and one pick at a time: softmax over all
+    N + NZ columns, top-k of s + b, weights SCALE_Z * s unrenormalised, a real
+    expert's FFN or, for an identity column, the token itself."""
+    z = np.asarray(u, np.float64) @ np.asarray(lp["router"], np.float64)
+    s = np.exp(z - z.max(-1, keepdims=True))
+    s = s / s.sum(-1, keepdims=True)
+    if mode == "sigmoid":
+        s = 1.0 / (1.0 + np.exp(-z))
+    b = np.asarray(lp["bias"], np.float64)
+    ffn = lambda x, wg, wu, wd: ((lambda g: g / (1 + np.exp(-g)))(x @ wg) * (x @ wu)) @ wd
+    out, zero_pairs = np.zeros((T, E), np.float64), 0
+    for t in range(T):
+        sel = np.argsort(-(s[t] + (0 if mode == "no_bias" else b)))[:KZ]
+        for e in sel:
+            wt = SCALE_Z * s[t, e] / (s[t, sel].sum() if mode == "renorm" else 1.0)
+            x = np.asarray(u[t], np.float64)
+            if e >= N:
+                zero_pairs += 1
+                out[t] += wt * x if mode != "no_identity" else 0.0
+            else:
+                out[t] += wt * ffn(x, *(np.asarray(lp["experts"][k][e], np.float64) for k in ("w_gate", "w_up", "w_down")))
+    return out, zero_pairs
+
+
+def _layer_call(lp, u, share, **kw):
+    return es.expert_share_layer(lp, u, share, KZ, SCALE_Z, False, scoring="softmax", **kw)
+
+
+@pytest.mark.parametrize("chips", [1, 4, 8])
+def test_softmax_shares_routed_parts_and_the_identity_term_once_add_up_to_the_uncut_layer(u, chips):
+    lp = _layer_z()
+    want, zero_pairs = _plain_z(lp, u)
+    idx, w = es.route(u, lp["router"], lp["bias"], KZ, SCALE_Z, False, "softmax")
+    wz, nz = es.zero_weights(idx, w, es.ExpertShare(N, chips, 0, NZ))
+    identity = np.asarray(wz)[:, None] * np.asarray(u)          # what every chip computes alike
+    assert int(nz.sum()) == zero_pairs and 0 < zero_pairs < T * KZ
+    total, held = np.zeros((T, E)), 0
+    for i in range(chips):
+        share = es.ExpertShare(N, chips, i, NZ)
+        y, counts = _layer_call(_slice(lp, share), u, share)
+        assert counts.shape == (N // chips + 1,) and int(counts[-1]) == zero_pairs
+        total += np.asarray(y) - identity                          # this chip's routed part
+        held += int(counts[:-1].sum())
+    assert held + zero_pairs == T * KZ            # every pair is held by one chip or is an identity pair
+    np.testing.assert_allclose(total + identity, want, rtol=2e-5, atol=2e-6)
+
+
+def test_softmax_selection_uses_s_plus_b_and_weights_are_scale_times_s_unrenormalised(u):
+    lp = _layer_z(bias_std=0.05)
+    share = es.ExpertShare(N, 1, 0, NZ)
+    y = np.asarray(_layer_call(lp, u, share)[0])
+    np.testing.assert_allclose(y, _plain_z(lp, u)[0], rtol=2e-5, atol=2e-6)
+    for mode in ("no_bias", "renorm", "sigmoid", "no_identity"):    # each is another layer
+        assert np.abs(y - _plain_z(lp, u, mode)[0]).max() > 1e-2, mode
+    idx, w = es.route(u, lp["router"], lp["bias"], KZ, SCALE_Z, False, "softmax")
+    z = np.asarray(u) @ np.asarray(lp["router"])
+    s = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(w), SCALE_Z * np.take_along_axis(s, np.asarray(idx), -1), rtol=1e-5)
+    assert float(w.sum(-1).max()) < SCALE_Z       # not renormalised: the picks' scores do not sum to 1
+    # and the sigmoid scoring of the other two families is what it was
+    i2, w2 = es.route(u, lp["router"], lp["bias"], KZ, SCALE_Z)
+    np.testing.assert_allclose(np.asarray(w2.sum(-1)), SCALE_Z, rtol=1e-6)
+
+
+@pytest.mark.parametrize("grouped_from", [0, 8], ids=["masked", "grouped"])
+def test_all_picks_held_drops_none_and_all_picks_identity_multiplies_nothing(u, grouped_from, monkeypatch):
+    lp = _layer_z()
+    share = es.ExpertShare(N, 2, 0, NZ)               # holds experts 0..7
+    held_lp = _slice(lp, share)
+    # every token picks the same 5 held experts: each gets all T tokens, none dropped
+    hot = dict(held_lp, bias=jnp.zeros(N + NZ).at[jnp.arange(KZ)].set(100.0))
+    y, counts = _layer_call(hot, u, share, grouped_from=grouped_from)
+    assert counts.tolist() == [T] * KZ + [0] * (8 - KZ) + [0]
+    np.testing.assert_allclose(np.asarray(y), _plain_z(dict(lp, bias=hot["bias"]), u)[0], rtol=2e-5, atol=2e-6)
+    # every token picks 5 identity columns: T x 5 zero pairs, the held experts' weights all 0
+    cold = dict(held_lp, bias=jnp.zeros(N + NZ).at[N + jnp.arange(KZ)].set(100.0))
+    seen = []
+    real = es.held_experts
+    monkeypatch.setattr(es, "held_experts", lambda u_, wh, *a: seen.append(float(jnp.abs(wh).max())) or real(u_, wh, *a))
+    y, counts = _layer_call(cold, u, share, grouped_from=grouped_from)
+    assert counts.tolist() == [0] * 8 + [T * KZ] and (seen == [0.0] if not grouped_from else seen == [])
+    idx, w = es.route(u, cold["router"], cold["bias"], KZ, SCALE_Z, False, "softmax")
+    np.testing.assert_allclose(np.asarray(y), np.asarray(w.sum(-1))[:, None] * np.asarray(u), rtol=1e-6, atol=1e-7)
+    # the rows that are no tokens count in neither kind
+    _, some = _layer_call(cold, u, share, grouped_from=grouped_from, valid=jnp.arange(T) < 10)
+    assert some.tolist() == [0] * 8 + [10 * KZ]
+
+
+@pytest.mark.parametrize("chips,index", [(1, 0), (4, 1), (8, 7)])
+def test_softmax_grouped_equals_masked_with_identity_pairs_outside_every_group(u, chips, index):
+    lp = _layer_z()
+    share = es.ExpertShare(N, chips, index, NZ)
+    masked, cm = _layer_call(_slice(lp, share), u, share)
+    grouped, cg = _layer_call(_slice(lp, share), u, share, grouped_from=8)
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(cm), np.asarray(cg))
+
+
+def test_held_counts_come_from_the_selection_where_a_softmax_score_rounds_to_zero(u):
+    """A router so sharp that the 5th pick's softmax score underflows to 0: the
+    pair is still a held pair (its weight is 0, its count is 1)."""
+    lp = _layer_z()
+    lp = dict(lp, router=lp["router"] * 400.0, bias=jnp.zeros(N + NZ))
+    share = es.ExpertShare(N, 1, 0, NZ)
+    idx, w = es.route(u, lp["router"], lp["bias"], KZ, SCALE_Z, False, "softmax")
+    assert float(w.min()) == 0.0
+    _, counts = _layer_call(lp, u, share)
+    assert int(counts.sum()) == T * KZ
+    assert int((es.held_weights(idx, w, share) > 0).sum()) + int(counts[-1]) < T * KZ

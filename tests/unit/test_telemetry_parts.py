@@ -38,7 +38,7 @@ def test_a_scope_outside_the_vocabulary_is_refused():
         parts.part("attention")
     with parts.part("attn.core"):
         pass
-    assert len(set(parts.PARTS)) == len(parts.PARTS) == 12
+    assert len(set(parts.PARTS)) == len(parts.PARTS) == 13
 
 
 @pytest.mark.parametrize("op_name, part, phase", [
