@@ -234,7 +234,6 @@ class ExaoneFamily:
 
     prefill_block = 256   # the whole-prompt program attends in query blocks of this many
     kv_pools = 2          # a K and a V pool
-    grouped_from = 0      # every call of the expert layer keeps the masked form (moe/expert_share.py)
 
     def __init__(self, cfg: ExaoneMoEConfig):
         self.cfg = cfg

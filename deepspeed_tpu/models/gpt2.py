@@ -339,7 +339,6 @@ class GPT2Family:
 
     prefill_block = 0      # the whole-prompt program attends as one dense product
     kv_pools = 2           # a K and a V pool
-    grouped_from = 0       # (no expert layer the serving path knows)
     sparse_layers = ()     # no layer reports expert loads
     experts_held = 0
     experts_per_token = 0

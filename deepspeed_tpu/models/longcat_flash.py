@@ -203,13 +203,6 @@ class LongcatFlashFamily(mla.LatentAttention):
 
     prefill_block = 128   # the whole-prompt program attends (expanded) in query blocks of this many
     kv_pools = 1
-    # The expert layer keeps the MASKED form at every call of the served shapes
-    # (64 decode rows, 320 of a mixed call, 3 072 of a whole prompt): at 12 of
-    # 768 with 16 held the grouped form's static budget of T x 12 pair rows is
-    # 48 times the pairs there are, and on the chip it lost at 64, 320 and
-    # 1 024 rows: 2.43 / 2.49, 3.20 / 3.70, 7.89 / 8.08 ms a layer, masked /
-    # grouped (PERF.md, PR 41; perfbench/tools/micro_longcat_flash.py).
-    grouped_from = 0
 
     def __init__(self, cfg: LongcatFlashConfig):
         self.cfg = cfg
@@ -262,7 +255,7 @@ class LongcatFlashFamily(mla.LatentAttention):
             m, counts = expert_share_layer(
                 lp["moe"], u.reshape(B * S, E), cfg.share, cfg.moe_topk, cfg.routed_scaling_factor,
                 False, None if valid is None else jnp.broadcast_to(valid, (B, S)).reshape(B * S),
-                grouped_from=self.grouped_from, scoring="softmax",
+                scoring="softmax",
             )
             carry = m.reshape(B, S, E)
         f = lp["ffn"]

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.expert_share import GROUPED_MIN_ROWS, ExpertShare, expert_share_layer
+from ..moe.expert_share import ExpertShare, expert_share_layer
 from ..ops.layer_norm import rms_norm
 from ..runtime.module import ModuleSpec
 from ..telemetry import parts
@@ -232,7 +232,6 @@ class Mistral4Family(mla.LatentAttention):
 
     prefill_block = 128   # the whole-prompt program attends (expanded) in query blocks of this many
     kv_pools = 1
-    grouped_from = GROUPED_MIN_ROWS   # the expert layer's grouped form, from this many rows a call on
 
     def __init__(self, cfg: Mistral4Config):
         self.cfg = cfg
@@ -274,7 +273,6 @@ class Mistral4Family(mla.LatentAttention):
             lp["moe"], u.reshape(B * S, E), cfg.share, cfg.num_experts_per_tok,
             cfg.routed_scaling_factor, cfg.norm_topk_prob,
             None if valid is None else jnp.broadcast_to(valid, (B, S)).reshape(B * S),
-            grouped_from=self.grouped_from,
         )
         return y.reshape(B, S, E), counts
 

@@ -25,42 +25,31 @@ columns, not renormalised, no shared expert (LongCat-Flash). The counts the
 layer reports are the tokens each held expert got and, with identity columns,
 the pairs that chose one of those (the last entry).
 
-No capacity: a held expert computes every token routed to it. Two forms of
-the same sum. A family that takes the grouped form says from how many rows a
-call on (``expert_share_layer``'s ``grouped_from``, a static property of the
-family: ``GROUPED_MIN_ROWS`` for the top-4 family this was measured for; 0,
-the default, keeps every call of a family masked, as the top-8 family's
-accepted cell was measured); the switch is then by the call's static row
-count alone:
+No capacity: a held expert computes every token routed to it. A row that is
+no token (``valid``) picks nothing: it joins no expert's rows and no count.
+Two forms of the same sum, and ONE rule between them, over what the trace can
+see: :func:`kernel_runs`, a TPU and whole lane tiles in both widths. Every
+call of every family takes the same form.
 
-- MASKED (:func:`held_experts`), for a decode step's rows and a short chunk:
-  the products run over all held experts at once with the weights of the
-  unselected pairs zero (``[n_held, T, F]``). At 64 tokens x 8 of 128 every
-  held expert is hit by some token of the batch anyway, so its weights are
-  streamed either way, and a chunk of 256 tokens pays 16 x the products a
-  grouped form would need, which the MXU has room for beside that stream
-  (PERF.md, PR 32, has both forms measured).
-- GROUPED (:func:`held_experts_grouped`), for calls of many rows: the
-  (token, expert) pairs whose expert is held, sorted by expert, multiplied
-  group by group (``lax.ragged_dot``: a held expert's weights meet only the
-  rows routed to it). At 1 024 rows x 4 of 128 with 16 held the masked form
-  multiplies 32 x the pairs there are (PERF.md, PR 34, has both measured).
-  The pair budget is the static ``T x top_k`` (every token may pick held
-  experts only); the pairs whose expert is absent (or an identity column) sort
-  behind the held groups, belong to no group and carry weight 0. No pair is
-  dropped.
-
-A third routing, LongCat-Flash's 12 of 768 with 16 of 512 held (E 6144, F
-2048): one selected pair in 48 meets a held expert, so the grouped form's
-static budget is 48 times the pairs there are and the masked form multiplies
-every row by every held expert. On the chip (PERF.md, PR 41;
-``perfbench/tools/micro_longcat_flash.py``), masked / grouped, a layer: 2.43 /
-2.49 ms at 64 rows (22 held pairs of 768, 11 experts hit), 3.20 / 3.70 ms at
-320, 7.89 / 8.08 ms at 1 024 (283 of 12 288): ``ragged_dot`` pays for the
-rows outside every group, so the grouped form wins nowhere and
-``models/longcat_flash.LongcatFlashFamily.grouped_from`` is 0. Either form
-streams all 16 held experts; a product that reads only the experts hit is
-ROADMAP S12.
+- GROUPED (:func:`held_experts_grouped`), where the kernel runs: the (token,
+  expert) pairs whose expert is held, sorted by expert, each group padded to
+  whole row tiles, multiplied tile by tile in one Pallas kernel
+  (``ops/pallas/grouped_experts.py``: gate, up, silu and down over a row
+  tile, the tile's expert named by a scalar-prefetched map). A hit expert's
+  three matrices are streamed once over its own rows, an unhit expert's are
+  not read, and the grid ends with the last live tile. The pair budget is the
+  static ``T x top_k`` (every token may pick held experts only): the tile
+  arrays are sized for it and no pair is dropped; the row tile comes from the
+  pairs a held expert expects at the call's static shapes. What the held
+  experts' matrices cost a call is therefore the experts HIT
+  (:func:`experts_streamed`), at every row count the cells serve: 64 rows x 12
+  of 768 hit 10-11 of the 16 held, 64 rows x 8 of 128 all of them, and the
+  products of a 1 072-row call run beside the stream (PERF.md, PR 42, has the
+  kernel beside ``lax.ragged_dot``, megablox ``gmm`` and the masked einsums
+  at the three cells' shapes).
+- MASKED (:func:`held_experts`), the plain ``jax.numpy`` form for every other
+  backend and the tests' oracle: the products run over all held experts at
+  once with the weights of the unselected pairs zero (``[n_held, T, F]``).
 
 The sum of all the shares' routed parts, with the shared part and the identity
 term once, is the uncut layer (``tests/unit/test_expert_share.py``).
@@ -73,6 +62,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas import grouped_experts
 from ..telemetry import parts
 
 _HI = jax.lax.Precision.HIGHEST
@@ -154,55 +144,65 @@ def held_experts(u, wh, w_gate, w_up, w_down):
     return jnp.einsum("ntf,nfe->te", a.astype(u.dtype), w_down)
 
 
-GROUPED_MIN_ROWS = 512     # where the grouped form wins at top-4 of 128, 16 held (PERF.md, PR 34)
-GROUPED_BLOCK_ROWS = 4096  # the grouped form takes this many rows at a time (a whole-prompt program's temporaries)
+def block_rows(u) -> int:
+    """Rows a call of the kernel takes of a program's ``u [T, E]``: all of
+    them, or their largest divisor among the rows one call holds
+    (``grouped_experts.max_rows``: a whole-prompt program's rows go through
+    in that many at a time)."""
+    T, E = u.shape
+    most = max(1, grouped_experts.max_rows(E, u.dtype.itemsize))
+    return T if T <= most else max(b for b in range(1, most + 1) if T % b == 0)
 
 
-def grouped_rows(T: int, top_k: int, grouped_from: int) -> int:
-    """Pair rows a call of ``T`` token rows hands the grouped products
-    (padding included: the static budget), 0 where the masked form runs."""
-    return T * top_k if grouped_from and T >= grouped_from else 0
-
-
-def held_experts_grouped(u, idx, w, share: ExpertShare, w_gate, w_up, w_down):
-    """:func:`held_experts`' sum over the pairs themselves: ``idx`` / ``w [T,
-    k]`` as :func:`route` gives them. The ``T x k`` pairs are sorted by held
-    expert (absent experts' pairs last, outside every group), each group's
-    rows meet its expert's weights once, and a pair's product is weighted in
-    float32 before its one rounding, as in the masked form."""
+def held_experts_grouped(u, idx, w, share: ExpertShare, w_gate, w_up, w_down, interpret: bool = False):
+    """:func:`held_experts`' sum over the pairs themselves, as ONE kernel
+    (``ops/pallas/grouped_experts.py``): ``idx`` / ``w [T, k]`` as
+    :func:`route` gives them. The ``T x k`` pairs are sorted by held expert,
+    each group padded to whole row tiles; a tile's rows meet its expert's
+    three matrices once, a pair's product is weighted in float32 before its
+    one rounding, as in the masked form, and a token's pairs are summed in
+    float32 in the kernel. The pairs whose expert is absent (or an identity
+    column, or -1: a row that is no token) are in no tile."""
     T, k = idx.shape
-    n = share.n_held
-    with parts.part("moe.route"):   # the sort and the gather
+    n, P = share.n_held, T * k
+    tm = grouped_experts.row_tile(P, share.n_experts + share.n_zero)
+    with parts.part("moe.route"):   # the sort and the tile map
         local = idx - share.index * n
-        group = jnp.where((local >= 0) & (local < n), local, n).reshape(T * k)
-        order = jnp.argsort(group, stable=True)                        # pair rows, by group
-        sizes = jnp.sum(group[:, None] == jnp.arange(n)[None, :], axis=0, dtype=jnp.int32)
-        in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]       # rows past the groups: nothing
-        x = u[order // k]                                              # [T * k, E]
+        group = jnp.where((idx >= 0) & (local >= 0) & (local < n), local, n).reshape(P)
+        order, tile_expert, tile_rows, tile_first, n_live = grouped_experts.tile_plan(group, n, tm)
+        r = jnp.arange(tm)[None, :]
+        live = r < tile_rows[:, None]                                  # [tiles, tm]: the row holds a pair
+        pair = order[jnp.where(live, tile_first[:, None] + r, 0)]
+        row_token = jnp.where(live, pair // k, -1)
+        row_weight = jnp.where(live, w.reshape(P)[pair], 0.0)
     with parts.part("moe.experts"):
-        g = jax.lax.ragged_dot(x, w_gate, sizes)
-        v = jax.lax.ragged_dot(x, w_up, sizes)
-        a = jax.nn.silu(g.astype(jnp.float32)) * v.astype(jnp.float32) * w.reshape(T * k)[order][:, None]
-        a = jnp.where(in_group, a, 0.0).astype(u.dtype)
-        y = jnp.where(in_group, jax.lax.ragged_dot(a, w_down, sizes), 0)
-    with parts.part("moe.route"):   # the scatter back and the combine
-        # back to the pairs' own order, then a token's k pairs summed in float32
-        y = y[jnp.argsort(order)].reshape(T, k, -1)
-        return jnp.sum(y.astype(jnp.float32), axis=1).astype(u.dtype)
+        y = grouped_experts.grouped_expert_ffn(
+            u, tile_expert, tile_rows, row_token, n_live, row_weight, w_gate, w_up, w_down, tm, interpret
+        )
+        return jnp.where(n_live[0] > 0, y, 0.0).astype(u.dtype)   # no live tile: the kernel ran no step
 
 
-def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from, scoring):
+def kernel_runs(lp) -> bool:
+    """Whether this layer's held products take the kernel (a TPU, whole lane
+    tiles): the ONE form there. Elsewhere the masked form runs."""
+    return grouped_experts.grouped_experts_ok(*lp["experts"]["w_gate"].shape[1:])
+
+
+def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid):
     """→ (the held experts' part for ``u [T, E]`` with the identity term;
     what says which pairs are held, ``[T, n_held]``: the weights ``wh`` under
     sigmoid scores (positive: a selected pair's weight is), the selection
     itself under softmax (:func:`held_hits`); ``zero [T]`` int32: a token's
-    picks among the identity columns, or None)."""
+    picks among the identity columns, or None). A row that is no token
+    (``valid``) picks nothing: no expert is read or counted for it."""
     with parts.part("moe.route"):
         idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk, scoring)
+        if valid is not None:
+            idx = jnp.where(valid[:, None], idx, -1)
         wh = held_weights(idx, w, share)
         held = wh if scoring == "sigmoid" else held_hits(idx, share)
     ex = lp["experts"]
-    if grouped_rows(u.shape[0], top_k, grouped_from):
+    if kernel_runs(lp):
         y = held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
     else:
         with parts.part("moe.experts"):
@@ -215,36 +215,39 @@ def _routed(u, lp, share, top_k, scale, norm_topk, grouped_from, scoring):
     return y, held, zero
 
 
+def experts_streamed(counts, kernel: bool) -> int:
+    """Held experts whose matrices the products of the calls behind ``counts
+    [calls x layers, n_held]`` read: where the kernel runs, those some token
+    picked (each has a live tile, no other has); in the masked form, all."""
+    return int((counts > 0).sum()) if kernel else int(counts.size)
+
+
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
                        norm_topk: bool = True, valid: Optional[jnp.ndarray] = None,
-                       grouped_from: int = 0, scoring: str = "sigmoid"):
+                       scoring: str = "sigmoid"):
     """``u [T, E]`` → (``y [T, E]``, ``counts [n_held]`` int32: the tokens
     each held expert got, and with ``share.n_zero`` one entry more, the pairs
-    that chose an identity column; with ``valid [T]`` only those rows count,
-    e.g. the slots that hold a request). ``lp``: ``router [E, n_experts +
-    n_zero]``, ``bias`` as wide, ``experts`` and, where the model has a shared
-    expert, ``shared``, each with ``w_gate, w_up, w_down``. ``grouped_from``:
-    calls of that many rows or more take the grouped form (0: none does)."""
-    T = u.shape[0]
-    if grouped_from and T > GROUPED_BLOCK_ROWS and T % GROUPED_BLOCK_ROWS == 0:
+    that chose an identity column; with ``valid [T]`` only those rows pick
+    experts at all, e.g. the slots that hold a request: the others get the
+    shared expert alone). ``lp``: ``router [E, n_experts + n_zero]``, ``bias``
+    as wide, ``experts`` and, where the model has a shared expert, ``shared``,
+    each with ``w_gate, w_up, w_down``."""
+    T, rows = u.shape[0], block_rows(u)
+    if kernel_runs(lp) and rows < T:
+        blocks = lambda a: a.reshape(-1, rows, *a.shape[1:])
         y, held, zero = jax.lax.map(
-            lambda ub: _routed(ub, lp, share, top_k, scale, norm_topk, grouped_from, scoring),
-            u.reshape(-1, GROUPED_BLOCK_ROWS, u.shape[1]),
+            lambda b: _routed(b[0], lp, share, top_k, scale, norm_topk, scoring, b[1]),
+            (blocks(u), None if valid is None else blocks(valid)),
         )
         y, held = y.reshape(T, -1), held.reshape(T, -1)
         zero = None if zero is None else zero.reshape(T)
     else:
-        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, grouped_from, scoring)
+        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid)
     if "shared" in lp:
         sh = lp["shared"]
         y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
     with parts.part("moe.route"):   # the load count
-        got = held > 0.0 if scoring == "sigmoid" else held
-        if valid is not None:
-            got = got & valid[:, None]
-        counts = jnp.sum(got, axis=0, dtype=jnp.int32)
+        counts = jnp.sum(held > 0.0 if scoring == "sigmoid" else held, axis=0, dtype=jnp.int32)
         if zero is None:
             return y, counts
-        if valid is not None:
-            zero = jnp.where(valid, zero, 0)
         return y, jnp.concatenate([counts, jnp.sum(zero, dtype=jnp.int32)[None]])

@@ -221,8 +221,8 @@ class Family:
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
       report the tokens each held expert got, and how many experts that is
-      (``grouped_from``: the rows a call from which the family's expert
-      layer takes the grouped form, 0 for never; ``moe/expert_share.py``).
+      (every call's held products are one grouped kernel on a TPU, the
+      masked einsums elsewhere; ``moe/expert_share.py``).
       ``zero_experts``: the router's identity columns, 0 for none; where it
       has some, a report is ``[experts_held + 1]``, the last entry the pairs
       that chose one of them.

@@ -70,6 +70,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..moe.expert_share import experts_streamed
+from ..ops.pallas import grouped_experts
 from ..telemetry import parts, spans
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
@@ -290,13 +292,6 @@ class ServingEngine:
             self.chunk_width = 0
         if self.chunk_width > self.prefill_width:
             self.chunk_width = self.prefill_width
-        # pair rows one call of the chunk program (the chunk's rows and every
-        # slot's decode row) hands the expert layers' grouped products (0: its
-        # rows take the masked form; moe/expert_share.py)
-        from ..moe.expert_share import grouped_rows
-        self._moe_rows_grouped = len(fam.sparse_layers) * grouped_rows(
-            self.chunk_width + self.max_slots, fam.experts_per_token, fam.grouped_from
-        )
         # a window layer's ring: the window before a program's first query,
         # the tokens one call writes (a chunk, a verify step's drafts, one
         # token) and a page of slack for where in a page the window starts
@@ -690,6 +685,12 @@ class ServingEngine:
             "token-expert pairs routed (tokens x experts a token x expert "
             "layers), over decode steps and chunk calls",
         )
+        self._c_moe_streamed = m.counter(
+            "serving_moe_experts_streamed_total",
+            "held experts whose matrices an expert layer's products read: "
+            "those some token picked where the grouped kernel runs, every "
+            "held expert in the masked form; over decode steps and chunk calls",
+        )
         self._c_moe_zero = m.counter(
             "serving_moe_pairs_zero_total",
             "token-expert pairs that chose an identity (zero-compute) expert: "
@@ -753,6 +754,7 @@ class ServingEngine:
 
         self._prefill_exec = None
         self._decode_exec = None
+        self._moe_kernel = False  # whether the compiled programs hold the grouped expert kernel
         self._verify_exec = None
         self._chunk_exec = None
         self._gather_exec = None
@@ -1311,6 +1313,10 @@ class ServingEngine:
         self._g_kv_row_bytes.set(row_bytes)
         self._g_ring_pages.set(self.ring_pages)
         self._g_experts_held.set(self.family.experts_held)
+        # the expert layers' form, as compiled: a program names the kernel or not
+        self._moe_kernel = bool(self.family.sparse_layers) and (
+            grouped_experts.KERNEL_NAME in self._prefill_exec.as_text()
+        )
         attrs = {
             key: " ".join(f"{k}={v}" for k, v in got.items())
             for key, got in (("relayout_ops", relayout), ("temp_bytes", temp),
@@ -1336,12 +1342,15 @@ class ServingEngine:
             attrs["moe_pairs_zero"] = zero
         held = int(counts.sum())
         routed = int(n_tokens) * fam.experts_per_token * len(fam.sparse_layers)
+        streamed = experts_streamed(counts, self._moe_kernel)
         self._c_moe_held.inc(held)
         self._c_moe_routed.inc(routed)
+        self._c_moe_streamed.inc(streamed)
         return {
             "moe_pairs_held": held, "moe_pairs_routed": routed,
             "moe_load_max": int(counts.max()),
-            "moe_experts_hit": int((counts > 0).sum()), **attrs,
+            "moe_experts_hit": int((counts > 0).sum()),
+            "moe_experts_streamed": streamed, **attrs,
         }
 
     def _set_collective_gauges(self) -> None:
@@ -2313,8 +2322,6 @@ class ServingEngine:
                 slot.moe_counts, slot.moe_tokens = [], 0
                 self._start_decoding(i, int(tok_np[-1]))
             sp.set(tokens=n_tok, attended=attended)
-            if self._moe_rows_grouped:
-                sp.set(moe_rows_grouped=(len(alone) + (rider is not None)) * self._moe_rows_grouped)
             if moe and rider is None:
                 # a prompt's chunk calls report with its last one, whose
                 # token fetch is the one wait there is

@@ -50,6 +50,24 @@ def _default_format(one_chip, shape, dtype):
     return compiled.input_formats[0][0]
 
 
+def _expert_kernel_census(text, layers, E, F, held=16):
+    """The held experts' products of a compiled program: ONE grouped kernel an
+    expert layer under the name the trace's readers match, no ``ragged-dot``,
+    and no copy, slice or transpose whose result is one expert's matrix out of
+    the stack or the stack itself (the census the pool tests make, pointed at
+    the weights; a bare ``[E, F]`` is the shared expert's shape too, which is
+    not this kernel's): the kernel reads the three stacked matrices where
+    they lie."""
+    from deepspeed_tpu.ops.pallas.grouped_experts import KERNEL_NAME
+    from deepspeed_tpu.serving.placement import _HLO_RESULT, _RELAYOUT_OPCODES
+
+    assert re.match(r"moe_+experts_+w_(gate|up|down)", KERNEL_NAME)
+    assert len(re.findall(rf"^\s*%?{KERNEL_NAME}[.\d]* = .*custom-call\(", text, re.M)) == layers
+    assert "ragged-dot" not in text
+    matrices = {f"{lead}{a},{b}" for a, b in ((E, F), (F, E)) for lead in ("1,", f"{held},")}
+    assert [(d, op) for d, op in _HLO_RESULT.findall(text) if op in _RELAYOUT_OPCODES and d in matrices] == []
+
+
 LAYERS, LAYER = 3, 1  # the serving engine's [L, P, KV, page, D] pool and a layer of it
 
 
@@ -328,8 +346,11 @@ def test_window_family_programs_compile_at_the_served_size(one_chip, program, mo
     text = compiled.as_text()
     assert pset.program_census(program, compiled)[0] == 0  # or it raises
     calls = text.count("custom_call_target=\"tpu_custom_call\"")
-    # an attention kernel a layer, and in the decode step a token write a layer
-    assert calls == {"decode": 10, "chunk": 5, "mixed": 15, "prefill": 0}[program]
+    # an attention kernel a layer, in the decode step a token write a layer,
+    # and the grouped kernel an expert layer
+    sparse = len(exaone_moe.ExaoneFamily(cfg).sparse_layers)
+    assert calls == {"decode": 10, "chunk": 5, "mixed": 15, "prefill": 0}[program] + sparse
+    _expert_kernel_census(text, sparse, cfg.n_embd, c["moe_intermediate_size"])
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10e9   # of the chip's 16
@@ -585,9 +606,9 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     slots, 32 query heads on one 384-lane latent row a token, 128-token pages,
     a 24 576-token whole-prompt width, 16 held experts of 128, 5.75 GB of bf16
     weights as shapes): the latent kernels and the one-pool token write pass
-    Mosaic, nothing re-lays the pool out, the whole-prompt program's blocked
-    attention and blocked expert products keep its temps beside the pool, and
-    arguments + temps fit the chip."""
+    Mosaic, nothing re-lays the pool out or an expert matrix, the whole-prompt
+    program's blocked attention and blocked expert products keep its temps
+    beside the pool, and arguments + temps fit the chip."""
     from deepspeed_tpu.models import mistral4
     from deepspeed_tpu.serving import model as smodel
     from deepspeed_tpu.serving.placement import Placement, ProgramSet
@@ -642,13 +663,10 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     text = compiled.as_text()
     assert pset.program_census(program, compiled)[0] == 0  # or it raises
     calls = text.count("custom_call_target=\"tpu_custom_call\"")
-    # an attention kernel a layer, and in the decode step a token write a layer;
-    # the grouped expert products are the compiler's own ragged-dot calls
-    want = {"decode": 2 * L, "chunk": L, "mixed": 3 * L, "prefill": 0}[program]
-    ragged = text.count("ragged-dot") > 0
-    assert ragged == (program != "decode")
-    if program == "decode":
-        assert calls == want
+    # an attention kernel a layer, in the decode step a token write a layer,
+    # and the grouped kernel an expert layer (every layer here)
+    assert calls == {"decode": 3 * L, "chunk": 2 * L, "mixed": 4 * L, "prefill": L}[program]
+    _expert_kernel_census(text, L, c["hidden_size"], c["moe_intermediate_size"])
     if program == "mixed":  # the kernels keep the names the readers find them by
         for kernel in ("mla_paged_decode", "mla_paged_chunk", "kv_token_write"):
             assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
@@ -710,7 +728,7 @@ def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip
     pages, a 3 072-token whole-prompt width, 16 held experts of 512 + 256
     identity columns, 10.35 GB of bf16 weights as shapes): a latent kernel and
     a token write a SUB-BLOCK, nothing re-lays the pool out, every call of the
-    expert layer masked, and arguments + temps fit the chip."""
+    expert layer the grouped kernel, and arguments + temps fit the chip."""
     from deepspeed_tpu.models import longcat_flash
     from deepspeed_tpu.serving import model as smodel
     from deepspeed_tpu.serving.kv_cache import pool_stored_shape
@@ -762,7 +780,7 @@ def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip
     compiled = pset.aot(fn, host, with_params=True)
     text = compiled.as_text()
     assert pset.program_census(program, compiled)[0] == 0  # or it raises
-    assert text.count("ragged-dot") == 0                    # the family keeps every call masked
+    _expert_kernel_census(text, L // 2, c["hidden_size"], c["expert_ffn_hidden_size"])   # one a DOUBLE layer
     if program != "prefill":  # the kernels keep the names the readers find them by, one a sub-block
         names = {"decode": ("mla_paged_decode", "kv_token_write"),
                  "mixed": ("mla_paged_decode", "mla_paged_chunk", "kv_token_write")}[program]
@@ -774,6 +792,52 @@ def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip
     print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
     assert 12.6e9 < mem.argument_size_in_bytes < 12.8e9     # 10.35 GB of weights and 2.35 GB of pool
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16
+
+
+# -- the held experts' grouped kernel at the three MoE cells' served shapes (ISSUE 42) --
+
+EXPERT_SHAPES = {
+    # name: (E, F, top_k, n_experts, n_zero, rows)
+    "kx-decode": (6144, 2048, 8, 128, 0, 64),
+    "kx-mixed": (6144, 2048, 8, 128, 0, 320),
+    "lcf-decode": (6144, 2048, 12, 512, 256, 64),
+    "lcf-mixed": (6144, 2048, 12, 512, 256, 320),
+    "ms4-mixed": (4096, 2048, 4, 128, 0, 1072),
+    "lcf-whole-prompt-block": (6144, 2048, 12, 512, 256, 768),
+    "ms4-whole-prompt-block": (4096, 2048, 4, 128, 0, 1024),
+}
+
+
+@pytest.mark.parametrize("name", list(EXPERT_SHAPES))
+def test_grouped_expert_kernel_compiles_for_v5e_within_the_vmem_it_asks(one_chip, name):
+    """``held_experts_grouped`` (the sort, the tile map, the kernel, the
+    combine) with 16 held experts as shapes: Mosaic takes the kernel at its
+    own ``vmem_limit_bytes``, which stays under the core's 128 MiB with room,
+    the weight blocks are megabytes (the largest the call's rows leave room for), and the three stacked matrices go in as
+    they are: no copy, slice or transpose of an expert matrix."""
+    from deepspeed_tpu.moe import expert_share as es
+    from deepspeed_tpu.ops.pallas import grouped_experts as ge
+
+    E, F, k, n_experts, n_zero, T = EXPERT_SHAPES[name]
+    share = es.ExpertShare(n_experts, n_experts // 16, 0, n_zero)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda u, idx, w, wg, wu, wd: es.held_experts_grouped(u, idx, w, share, wg, wu, wd)
+    ).lower(
+        sds((T, E), jnp.bfloat16), sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+        sds((16, E, F), jnp.bfloat16), sds((16, E, F), jnp.bfloat16), sds((16, F, E), jnp.bfloat16),
+    ).compile()
+    _expert_kernel_census(compiled.as_text(), 1, E, F)
+    tm = ge.row_tile(T * k, n_experts + n_zero)
+    assert tm == {"kx-decode": 16, "kx-mixed": 64, "lcf-decode": 16, "lcf-mixed": 16, "ms4-mixed": 128,
+                  "lcf-whole-prompt-block": 32, "ms4-whole-prompt-block": 64}[name]
+    bf = ge.f_block(T, tm, E, F, 2)
+    assert bf == (512 if name == "lcf-whole-prompt-block" else 1024) and E * bf * 2 >= 6 << 20   # megabytes a block, not kilobytes
+    assert ge.vmem_bytes(T, tm, E, bf, 2) <= ge.VMEM_BYTES < 128 << 20
+    assert es.block_rows(jax.ShapeDtypeStruct((T, E), jnp.bfloat16)) == T
 
 
 # -- ZeRO-3 over dp on the four described chips: the collectives are the weights' (ISSUE 40) --

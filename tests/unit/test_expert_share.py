@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.moe import expert_share as es
+from deepspeed_tpu.ops.pallas import grouped_experts
 
 E, F, N, K, T = 24, 12, 16, 4, 40
 SCALE = 2.5
@@ -109,10 +110,35 @@ def test_counts_leave_out_the_rows_that_are_no_tokens(u):
     y, some = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE, valid=valid)
     _, first10 = es.expert_share_layer(_slice(lp, share), u[:10], share, K, SCALE)
     assert some.tolist() == first10.tolist() and int(some.sum()) < int(all_rows.sum())
-    assert y.shape == (T, E)          # the rows are still computed: a mask on the count, not on the work
+    assert y.shape == (T, E)
+    # a row that is no token picks nothing: it gets the shared expert alone
+    np.testing.assert_allclose(np.asarray(y[10:]), np.asarray(es.gated_ffn(u[10:], *(lp["shared"][k] for k in ("w_gate", "w_up", "w_down")))),
+                               rtol=1e-6, atol=1e-7)
 
 
-# -- the grouped form: the same sum over the sorted pairs -----------------------
+# -- the grouped form: the same sum over the sorted pairs, one kernel -----------
+
+@pytest.fixture
+def kernel(monkeypatch):
+    """The layer takes the kernel (interpreted: this is the CPU) → the tile
+    map of each call made outside a trace: (tile_expert, n_live)."""
+    calls = []
+    real = grouped_experts.grouped_expert_ffn
+
+    def ffn(u_, tile_expert, tile_rows, row_token, n_live, *rest):
+        if not isinstance(n_live, jax.core.Tracer):
+            calls.append((np.asarray(tile_expert), int(n_live[0])))
+        return real(u_, tile_expert, tile_rows, row_token, n_live, *rest[:-1], True)
+
+    monkeypatch.setattr(grouped_experts, "grouped_expert_ffn", ffn)
+    monkeypatch.setattr(es, "kernel_runs", lambda lp: True)
+    return calls
+
+
+def _streamed(call):
+    tile_expert, n_live = call
+    return len(set(tile_expert[:n_live].tolist()))
+
 
 @pytest.mark.parametrize("chips,index", [(1, 0), (4, 1), (8, 7)])
 def test_the_grouped_product_equals_the_masked_one(u, chips, index):
@@ -121,14 +147,16 @@ def test_the_grouped_product_equals_the_masked_one(u, chips, index):
     ex = _slice(lp, share)["experts"]
     idx, w = es.route(u, lp["router"], lp["bias"], K, SCALE)
     masked = es.held_experts(u, es.held_weights(idx, w, share), ex["w_gate"], ex["w_up"], ex["w_down"])
-    grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
+    grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"], interpret=True)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
 
 
 def test_the_grouped_product_drops_no_held_pair_under_the_most_uneven_routing(u):
     """Every token selects the same K experts, all held here: the whole
-    static pair budget T x K is in the groups, none behind them. And with
-    none held, every pair sorts behind the groups and the routed part is 0."""
+    static pair budget T x K is in the groups, none behind them. With none
+    held, no pair is in a tile and the routed part is 0. And with every pair
+    on ONE held expert (no router gives that; the product takes any pairs)
+    the whole budget is one group of many tiles."""
     lp = _layer()
     lp = dict(lp, bias=jnp.zeros(N).at[jnp.array([4, 5, 6, 7])].set(100.0))
     idx, w = es.route(u, lp["router"], lp["bias"], K, SCALE)
@@ -136,33 +164,116 @@ def test_the_grouped_product_drops_no_held_pair_under_the_most_uneven_routing(u)
         share = es.ExpertShare(N, 4, index)
         ex = _slice(lp, share)["experts"]
         masked = es.held_experts(u, es.held_weights(idx, w, share), ex["w_gate"], ex["w_up"], ex["w_down"])
-        grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"])
+        grouped = es.held_experts_grouped(u, idx, w, share, ex["w_gate"], ex["w_up"], ex["w_down"], interpret=True)
         np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
         assert (float(jnp.abs(grouped).max()) > 0) == full
+    share = es.ExpertShare(N, 4, 1)
+    ex = _slice(lp, share)["experts"]
+    one = jnp.full_like(idx, 6)
+    masked = es.held_experts(u, es.held_weights(one, w, share), ex["w_gate"], ex["w_up"], ex["w_down"])
+    grouped = es.held_experts_grouped(u, one, w, share, ex["w_gate"], ex["w_up"], ex["w_down"], interpret=True)
+    np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-5)
 
 
-def test_the_layer_switches_form_by_its_static_row_count(monkeypatch):
-    """Many rows take the grouped form (and a whole-prompt program's rows go
-    through in blocks), few the masked one; the layer's result and counts are
-    the same either way."""
+def test_the_layer_takes_one_form_and_a_whole_prompts_rows_go_through_in_blocks(kernel, monkeypatch):
+    """Where the kernel runs every call takes it, whatever its rows (no
+    family says from where on); a program of more rows than one call holds
+    goes through in equal blocks; result and counts are the masked form's."""
     lp = _layer()
     share = es.ExpertShare(N, 4, 1)
     big = jax.random.normal(jax.random.PRNGKey(3), (64, E), jnp.float32)
-    want_y, want_c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
-    n = es.GROUPED_MIN_ROWS
-    assert es.grouped_rows(64, K, n) == 0 and es.grouped_rows(n, K, n) == n * K and es.grouped_rows(8 * n, K, 0) == 0
     calls = []
     real = es.held_experts_grouped
     monkeypatch.setattr(es, "held_experts_grouped", lambda *a: calls.append(a[0].shape) or real(*a))
-    es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
-    assert calls == []                 # a family that does not ask keeps every call masked
-    for block in (4096, 16):           # in one piece, then four blocks of 16 rows
-        monkeypatch.setattr(es, "GROUPED_BLOCK_ROWS", block)
+    monkeypatch.setattr(es, "kernel_runs", lambda lp: False)
+    want_y, want_c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
+    assert calls == []                 # off the TPU: the masked form
+    monkeypatch.setattr(es, "kernel_runs", lambda lp: True)
+    served = lambda T, E_: es.block_rows(jax.ShapeDtypeStruct((T, E_), jnp.bfloat16))
+    assert served(48, 4096) == 48 and served(1072, 4096) == 1072 and served(320, 6144) == 320
+    assert served(24576, 4096) == 1024 and served(3072, 6144) == 768 and served(4096, 6144) == 512
+    row = E * (4 + 4)                  # a float32 row of u and of y
+    for block, rows in ((1536, 64), (16, 16), (24, 16)):     # in one piece, then four blocks of 16 rows (24 does not divide 64)
+        monkeypatch.setattr(grouped_experts, "ROWS_BYTES", block * row)
         calls.clear()
-        y, c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE, grouped_from=16)
-        assert calls == [(64 if block == 4096 else 16, E)]
+        y, c = es.expert_share_layer(_slice(lp, share), big, share, K, SCALE)
+        assert calls == [(rows, E)]
         np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=2e-5, atol=2e-6)
         np.testing.assert_array_equal(np.asarray(c), np.asarray(want_c))
+
+
+# the three served routings at their router widths (16 held), small inside
+ROUTINGS = {
+    "sigmoid-top8-of-128-shared": dict(n=128, nz=0, k=8, scale=2.5, norm=True, scoring="sigmoid", shared=True, chips=8),
+    "sigmoid-top4-of-128": dict(n=128, nz=0, k=4, scale=1.0, norm=True, scoring="sigmoid", shared=False, chips=8),
+    "softmax-top12-of-512+256": dict(n=512, nz=256, k=12, scale=6.0, norm=False, scoring="softmax", shared=False, chips=32),
+}
+EK, FK = 32, 16
+
+
+def _routing_layer(r, seed=2):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    w = lambda k, s, std=0.3: jax.random.normal(k, s, jnp.float32) * std
+    cols = r["n"] + r["nz"]
+    lp = {
+        "router": w(ks[0], (EK, cols)), "bias": w(ks[1], (cols,), 0.5 if r["scoring"] == "sigmoid" else 1.0 / cols),
+        "experts": {"w_gate": w(ks[2], (r["n"], EK, FK)), "w_up": w(ks[3], (r["n"], EK, FK)), "w_down": w(ks[4], (r["n"], FK, EK))},
+    }
+    if r["shared"]:
+        lp["shared"] = {"w_gate": w(ks[5], (EK, FK)), "w_up": w(ks[6], (EK, FK)), "w_down": w(ks[7], (FK, EK))}
+    return lp
+
+
+@pytest.mark.parametrize("case", ["experts-unhit", "no-pair-held", "every-pick-held", "rows-not-valid"])
+@pytest.mark.parametrize("rows", [64, 320, 1072])
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+def test_the_kernel_equals_the_masked_form_and_the_uncut_layer(kernel, monkeypatch, routing, rows, case):
+    """One chip's part through the kernel against the masked form of the same
+    share (the oracle) and against the UNCUT float32 layer: all experts on
+    one chip, the absent ones' ``w_down`` zero. The counts are the masked
+    form's, and the experts the kernel's tile map streams are the experts
+    counted (``experts_streamed``), where the masked form streams all 16."""
+    r = ROUTINGS[routing]
+    lp = _routing_layer(r)
+    share = es.ExpertShare(r["n"], r["chips"], 1, r["nz"])
+    held = np.asarray(share.held_ids())
+    ub = jax.random.normal(jax.random.PRNGKey(rows), (rows, EK), jnp.float32)
+    valid, bias = None, lp["bias"]
+    if case == "experts-unhit":            # half the held experts can never be picked
+        bias = bias.at[held[::2]].set(-100.0)
+    elif case == "no-pair-held":
+        bias = bias.at[held].set(-100.0)
+    elif case == "every-pick-held":        # every token's k picks are held: the whole T x k budget is in the groups
+        bias = bias.at[held[: r["k"]]].set(100.0)
+    else:
+        valid = jnp.arange(rows) % 5 != 0
+    lp = dict(lp, bias=bias)
+    call = lambda lp_, sh: es.expert_share_layer(lp_, ub, sh, r["k"], r["scale"], r["norm"], valid, scoring=r["scoring"])
+    y, counts = call(_slice(lp, share), share)
+    (te, n_live), = kernel
+    monkeypatch.setattr(es, "kernel_runs", lambda lp: False)
+    masked, cm = call(_slice(lp, share), share)
+    absent = jnp.ones(r["n"]).at[held].set(0.0)[:, None, None] > 0
+    uncut_lp = dict(lp, experts=dict(lp["experts"], w_down=jnp.where(absent, 0.0, lp["experts"]["w_down"])))
+    uncut, cu = call(uncut_lp, es.ExpertShare(r["n"], 1, 0, r["nz"]))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(masked), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(uncut), rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(cm))
+    loads = np.asarray(counts)[: share.n_held]
+    np.testing.assert_array_equal(loads, np.asarray(cu)[held])
+    assert es.experts_streamed(loads[None], True) == _streamed((te, n_live)) == int((loads > 0).sum())
+    assert es.experts_streamed(loads[None], False) == share.n_held
+    tm = grouped_experts.row_tile(rows * r["k"], r["n"] + r["nz"])
+    assert n_live == int(np.ceil(loads / tm).sum()) and len(te) == rows * r["k"] // tm + share.n_held
+    if case == "experts-unhit":
+        assert (loads[::2] == 0).all() and 0 < _streamed((te, n_live)) <= share.n_held // 2
+    elif case == "no-pair-held":
+        assert n_live == 0 and loads.sum() == 0
+    elif case == "every-pick-held":
+        assert loads.tolist() == [rows] * r["k"] + [0] * (share.n_held - r["k"])
+    else:
+        assert int(counts.sum()) <= int(valid.sum()) * r["k"]
+        np.testing.assert_array_equal(np.asarray(y)[~np.asarray(valid)], np.asarray(masked)[~np.asarray(valid)])
 
 
 # -- softmax scoring, identity columns, no shared expert (LongCat-Flash) ---------
@@ -245,14 +356,16 @@ def test_softmax_selection_uses_s_plus_b_and_weights_are_scale_times_s_unrenorma
     np.testing.assert_allclose(np.asarray(w2.sum(-1)), SCALE_Z, rtol=1e-6)
 
 
-@pytest.mark.parametrize("grouped_from", [0, 8], ids=["masked", "grouped"])
-def test_all_picks_held_drops_none_and_all_picks_identity_multiplies_nothing(u, grouped_from, monkeypatch):
+@pytest.mark.parametrize("grouped", [False, True], ids=["masked", "grouped"])
+def test_all_picks_held_drops_none_and_all_picks_identity_multiplies_nothing(u, grouped, monkeypatch, request):
+    if grouped:
+        request.getfixturevalue("kernel")
     lp = _layer_z()
     share = es.ExpertShare(N, 2, 0, NZ)               # holds experts 0..7
     held_lp = _slice(lp, share)
     # every token picks the same 5 held experts: each gets all T tokens, none dropped
     hot = dict(held_lp, bias=jnp.zeros(N + NZ).at[jnp.arange(KZ)].set(100.0))
-    y, counts = _layer_call(hot, u, share, grouped_from=grouped_from)
+    y, counts = _layer_call(hot, u, share)
     assert counts.tolist() == [T] * KZ + [0] * (8 - KZ) + [0]
     np.testing.assert_allclose(np.asarray(y), _plain_z(dict(lp, bias=hot["bias"]), u)[0], rtol=2e-5, atol=2e-6)
     # every token picks 5 identity columns: T x 5 zero pairs, the held experts' weights all 0
@@ -260,21 +373,22 @@ def test_all_picks_held_drops_none_and_all_picks_identity_multiplies_nothing(u, 
     seen = []
     real = es.held_experts
     monkeypatch.setattr(es, "held_experts", lambda u_, wh, *a: seen.append(float(jnp.abs(wh).max())) or real(u_, wh, *a))
-    y, counts = _layer_call(cold, u, share, grouped_from=grouped_from)
-    assert counts.tolist() == [0] * 8 + [T * KZ] and (seen == [0.0] if not grouped_from else seen == [])
+    y, counts = _layer_call(cold, u, share)
+    assert counts.tolist() == [0] * 8 + [T * KZ] and (seen == [0.0] if not grouped else seen == [])
     idx, w = es.route(u, cold["router"], cold["bias"], KZ, SCALE_Z, False, "softmax")
     np.testing.assert_allclose(np.asarray(y), np.asarray(w.sum(-1))[:, None] * np.asarray(u), rtol=1e-6, atol=1e-7)
     # the rows that are no tokens count in neither kind
-    _, some = _layer_call(cold, u, share, grouped_from=grouped_from, valid=jnp.arange(T) < 10)
+    _, some = _layer_call(cold, u, share, valid=jnp.arange(T) < 10)
     assert some.tolist() == [0] * 8 + [10 * KZ]
 
 
 @pytest.mark.parametrize("chips,index", [(1, 0), (4, 1), (8, 7)])
-def test_softmax_grouped_equals_masked_with_identity_pairs_outside_every_group(u, chips, index):
+def test_softmax_grouped_equals_masked_with_identity_pairs_outside_every_group(u, chips, index, request):
     lp = _layer_z()
     share = es.ExpertShare(N, chips, index, NZ)
     masked, cm = _layer_call(_slice(lp, share), u, share)
-    grouped, cg = _layer_call(_slice(lp, share), u, share, grouped_from=8)
+    request.getfixturevalue("kernel")
+    grouped, cg = _layer_call(_slice(lp, share), u, share)
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(masked), rtol=2e-5, atol=2e-6)
     np.testing.assert_array_equal(np.asarray(cm), np.asarray(cg))
 

@@ -263,7 +263,10 @@ def test_spans_and_counters_count_zero_held_and_routed_pairs(engine, prompts):
     c = srv.metrics.counter
     assert c("serving_moe_pairs_zero_total", "").value() == zero
     assert c("serving_moe_pairs_held_total", "").value() == held and c("serving_moe_pairs_routed_total", "").value() == routed
-    assert not any("moe_rows_grouped" in c_ for c_ in chunks)      # the masked form at every call
+    # off the TPU every call is masked: each expert layer of a step streams all 4 held experts, hit or not
+    assert all(a["moe_experts_streamed"] == 4 * L >= a["moe_experts_hit"] for a in emits)
+    assert all(a["moe_experts_streamed"] == 4 * L * a["moe_calls"] for a in reports)
+    assert c("serving_moe_experts_streamed_total", "").value() == sum(a["moe_experts_streamed"] for a in both)
     prog = [r[3] for r in spans.phases(since=t0) if r[0] == "ds.init.programs"][-1]
     assert "latent=" in prog["kv_bytes"] and prog["kv_row_bytes"] == 24 * 4 and prog["moe_experts_held"] == 4
 

@@ -198,7 +198,11 @@ def test_spans_and_counters_count_latent_rows_and_expert_loads(engine, prompts):
     assert sum(c["moe_calls"] for c in chunks if "moe_calls" in c) == sum(c["chunks"] for c in chunks)
     reports = [c for c in chunks if "moe_calls" in c]
     assert sum(a["moe_pairs_routed"] for a in emits + reports) == (sum(d["active"] for d in disp) + sum(long)) * 2 * 2
-    assert not any("moe_rows_grouped" in c for c in chunks)      # 8 rows a call: the masked form
+    # off the TPU every call is masked: each expert layer of a step streams all 4 held experts, hit or not
+    assert all(a["moe_experts_streamed"] == 4 * 2 >= a["moe_experts_hit"] for a in emits)
+    assert all(c["moe_experts_streamed"] == 4 * 2 * c["moe_calls"] for c in reports)
+    assert srv.metrics.counter("serving_moe_experts_streamed_total", "").value() == sum(
+        a["moe_experts_streamed"] for a in emits + reports)
     prog = [r[3] for r in spans.phases(since=t0) if r[0] == "ds.init.programs"][-1]
     assert "latent=" in prog["kv_bytes"] and prog["kv_row_bytes"] == 24 * 4 and prog["moe_experts_held"] == 4
 
