@@ -6,7 +6,14 @@ The programs hold what every family shares (the pool writes, the paged
 attention, the sampling); the model's module gives the rest through
 ``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
 ``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``,
-``models/longcat_flash.LongcatFlashFamily``):
+``models/longcat_flash.LongcatFlashFamily``,
+``models/phi4flash.Phi4FlashFamily``). A sub-block is of one of four KINDS
+(``fam.kinds``; without it every one is the first): an attention that writes
+its own K/V (``attn``), a state-space mixer over a per-slot recurrent state
+(``ssm``: the third kind of state beside pages and rings, :func:`_ssm_block`),
+and two that keep nothing: a cross-attention that reads the pages another
+sub-block wrote (``cross``) and a gated memory unit that reads another's scan
+output of the same token (``gmu``). The programs:
 
 - :func:`paged_prefill` — one request's prompt (right-padded to the static
   prefill width) through the model, K/V written page-granularly into the
@@ -217,6 +224,27 @@ class Family:
     - ``windows``: per layer, how many keys a query reads, itself included
       (a sliding window, whose K/V live in the slot's RING of the window
       pools), or 0: every key before it (K/V paged under the block table).
+    - ``sm_scale`` (optional for a two-pool family; a latent one states it):
+      the scores' scale where it is not ``1 / sqrt(head_dim)``. A family whose
+      cached head is a PAIR of published heads (differential attention:
+      ``[k1 | k2]`` under the zero-padded queries ``[q1 | 0]``, ``[0 | q2]``)
+      scales by the published head's width and combines the pairs in
+      ``attn_out``.
+    - ``kinds`` (optional): per sub-block ``"attn"`` (the default: everything
+      above), ``"ssm"``, ``"gmu"`` or ``"cross"``. With it the family gives
+      ``sources`` (``{l: the sub-block a "cross" or "gmu" sub-block reads}``:
+      the K/V pages of an ``"attn"`` one, which get no second write, or the
+      scan output ``s`` of an ``"ssm"`` one, which travels in ``carry`` as
+      ``{source: s}``), ``ssm_state`` (``(N, d_inner)``: a slot's scan state
+      an ``"ssm"`` sub-block, float32), ``ssm_conv`` (the convolution's taps:
+      ``ssm_conv - 1`` rows of ``d_inner`` are carried), ``ssm_impl``, the
+      pieces ``ssm_in(lp, h) -> xs, z``, ``ssm_consts(lp) -> A [N, d_inner], D,
+      w_conv, b_conv``, ``ssm_dt(lp, c) -> dt, B, C``, ``ssm_out(lp, s, z,
+      tp_axis)``, ``gmu(lp, h, m, tp_axis)``, ``q_cross(lp, h, positions, l) ->
+      q``, and ``stop_after``: ``None``, or the last sub-block a PROMPT row
+      runs. Behind it only rows whose logits are sampled go on (a prompt's
+      last row, the decode rows): the sub-blocks there write no state.
+      ``windows`` is 0 for a sub-block that is no ``"attn"``.
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
@@ -249,14 +277,41 @@ class Family:
     """
 
 
+def sub_block_kinds(fam) -> tuple:
+    """Each sub-block's kind: the family's ``kinds``, or all ``"attn"``."""
+    return getattr(fam, "kinds", None) or ("attn",) * len(fam.windows)
+
+
 def _kv_homes(fam):
-    """Per layer ``(windowed, index)``: its index among the window pools'
-    layers or among the paged pools'."""
-    homes, n_win, n_paged = [], 0, 0
-    for w in fam.windows:
-        homes.append((bool(w), n_win if w else n_paged))
-        n_win, n_paged = n_win + bool(w), n_paged + (not w)
+    """Per sub-block ``(windowed, index)``: an attention's index among the
+    window pools' layers or among the paged pools', a state-space mixer's
+    among the recurrent state's; a cross-attention has its source's home
+    (and writes nothing there), a gated memory unit none."""
+    homes, n_win, n_paged, n_ssm = [], 0, 0, 0
+    for l, (kind, w) in enumerate(zip(sub_block_kinds(fam), fam.windows)):
+        if kind == "attn":
+            homes.append((bool(w), n_win if w else n_paged))
+            n_win, n_paged = n_win + bool(w), n_paged + (not w)
+        elif kind == "ssm":
+            homes.append((False, n_ssm))
+            n_ssm += 1
+        else:
+            homes.append(homes[fam.sources[l]] if kind == "cross" else (False, -1))
     return homes
+
+
+def pool_layers(fam) -> tuple:
+    """``(paged, window, recurrent)``: how many layers each kind of per-slot
+    state has for this family."""
+    kinds = sub_block_kinds(fam)
+    attn = [w for k, w in zip(kinds, fam.windows) if k == "attn"]
+    return sum(1 for w in attn if not w), sum(1 for w in attn if w), kinds.count("ssm")
+
+
+def _sm_scale(fam, D: int):
+    """The scores' scale of a two-pool family: its own, or ``1 / sqrt(D)``."""
+    s = getattr(fam, "sm_scale", None)
+    return 1.0 / np.sqrt(D) if s is None else s
 
 
 def ring_page_ids(slot, pages, ring: int):
@@ -288,7 +343,8 @@ def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry=None, attn_
     expert layer, whose held experts' token counts (if it reports any) join
     ``counts``. A family that owns the combination (``after_attention``, the
     :class:`Family` notes) does all of that itself and may hand a ``carry``
-    to its next sub-block; the others carry nothing."""
+    to its next sub-block; for the others it passes through as it came (a
+    family of several ``kinds`` keeps its sources' scan outputs there)."""
     own = getattr(fam, "after_attention", None)
     if own is not None:
         h, carry, c = own(lp, h, o, l, valid, tp_axis, carry, attn_out)
@@ -301,7 +357,7 @@ def _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry=None, attn_
         m, c = fam.mlp(lp, h, l, valid, tp_axis)
         if c is not None:
             counts.append(c)
-        return h + m, None
+        return h + m, carry
 
 
 def _window_views(fam, slots, pos0, page: int, ring: int):
@@ -312,10 +368,10 @@ def _window_views(fam, slots, pos0, page: int, ring: int):
     }
 
 
-def _result(k_pool, v_pool, scales, win, token, counts):
+def _result(k_pool, v_pool, scales, win, token, counts, state=None):
     """A program's results in the order the scheduler takes them: the paged
-    pools, an int8 pool's scales, a window family's ring pools, the token(s)
-    and, for a family with expert layers, the tokens each held expert got
+    pools, an int8 pool's scales, a window family's ring pools, a recurrent
+    family's two state pools, the token(s) and, for a family with expert layers, the tokens each held expert got
     ``[sparse layers, experts_held]`` (one entry more a layer where the
     router has identity columns: the pairs that chose one)."""
     out = (k_pool, v_pool)  # v_pool None: a latent family's (ProgramSet.aot drops it)
@@ -323,6 +379,8 @@ def _result(k_pool, v_pool, scales, win, token, counts):
         out += (scales,)
     if win is not None:
         out += tuple(win)
+    if state is not None:
+        out += tuple(state)
     return out + (token,) + ((jnp.stack(counts),) if counts else ())
 
 
@@ -387,6 +445,104 @@ def _attend_latent(fam, q, pool, l, block_tables, base, name, live=None):
         impl=fam.attn_impl, sm_scale=fam.sm_scale, layer=l, name=name,
     )
     return o.reshape(B, T, H * fam.v_width).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# the sub-blocks that are no attention of their own (``fam.kinds``): a
+# state-space mixer over the recurrent state pools, a gated memory unit and a
+# cross-attention over what other sub-blocks made
+#
+# ``state = (ssm, conv)``: ``ssm [Ls, slots, N, d_inner]`` float32, the scan
+# state a slot and "ssm" sub-block (the channels on the lanes:
+# ``ops/pallas/selective_scan.py``), and ``conv [Ls, slots, K - 1, d_inner]``,
+# the convolution's last inputs. Both are donated through every program like
+# the ring pools. A request's first rows start from zeros whatever the slot
+# held; a row that is padding, or an idle slot's, moves neither: its ``dt`` is
+# 0 (``exp(0) h + 0``) and its convolution rows are not shifted.
+# ---------------------------------------------------------------------------
+
+def _passed(lp, a, tp_axis):
+    """``attn_out`` of a mixer whose output is already projected."""
+    return a
+
+
+def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
+    """The state-space mixer of one sub-block (``li``-th of the state pools)
+    over the rows of ``h`` (``[1, C + B, E]``, or the decode step's ``[B, 1,
+    E]``): the first ``C`` rows are ONE slot's chunk, ``chunk = (slot, start,
+    n_real)`` (rows from ``n_real`` on are padding; ``start`` 0: the request's
+    first rows), the others a row a slot, ``real [B]`` the slots that hold a
+    decoding request. Both sets of rows go through the projections once and
+    part for the convolution and the scan alone. → (the mixer's output, shaped
+    like ``h``; the scan's ``s [..., d_inner]`` before the gate, float32:
+    what a gated memory unit reads; the state)."""
+    from ..ops.pallas.selective_scan import conv_rows, scan_rows, scan_step
+
+    ssm, conv = state
+    xs, z = fam.ssm_in(lp, h)
+    rows = xs.reshape(-1, xs.shape[-1])
+    A, D, w_conv, b_conv = fam.ssm_consts(lp)
+    K = w_conv.shape[-1]
+    impl = getattr(fam, "ssm_impl", "auto")
+    with parts.part("ssm.scan"):
+        cs = []
+        if C:
+            slot, start, n_real = chunk
+            fresh = start == 0
+            prev = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[li, slot])
+            c, full = conv_rows(w_conv, b_conv, rows[:C], prev)
+            # the next call's: the K - 1 rows before row ``n_real``
+            conv = conv.at[li, slot].set(
+                lax.dynamic_slice_in_dim(full, n_real, K - 1, 0).astype(conv.dtype)
+            )
+            cs.append(c)
+        if real is not None:
+            prev = conv[li]
+            c, full = conv_rows(w_conv, b_conv, rows[C:, None], prev)
+            conv = conv.at[li].set(
+                jnp.where(real[:, None, None], full[:, 1:].astype(conv.dtype), prev)
+            )
+            cs.append(c[:, 0])
+        c = cs[0] if len(cs) == 1 else jnp.concatenate(cs)
+    dt, Bm, Cm = fam.ssm_dt(lp, c)
+    with parts.part("ssm.scan"):
+        x = c.astype(jnp.float32)
+        ss = []
+        if C:
+            keep = (jnp.arange(C) < n_real)[:, None]
+            s, h1 = scan_rows(
+                x[:C], jnp.where(keep, dt[:C], 0.0), Bm[:C], Cm[:C], A, D,
+                jnp.where(fresh, 0.0, ssm[li, slot]), impl=impl,
+            )
+            ssm = ssm.at[li, slot].set(h1)
+            ss.append(s)
+        if real is not None:
+            s, ssm = scan_step(
+                x[C:], jnp.where(real[:, None], dt[C:], 0.0), Bm[C:], Cm[C:],
+                A, D, ssm, li, impl=impl,
+            )
+            ss.append(s)
+        s = (ss[0] if len(ss) == 1 else jnp.concatenate(ss)).reshape(xs.shape)
+    return fam.ssm_out(lp, s, z), s, (ssm, conv)
+
+
+def _mixer_without_kv(fam, lp, h, l, li, positions, carry, state, tp_axis, rows, attend):
+    """The mixer of a sub-block that writes no K/V, by its kind → (its output
+    ``[..., E]``, projected: :func:`_after_attention` takes it with
+    :func:`_passed`; ``carry``; ``state``). ``rows``: :func:`_ssm_block`'s
+    ``(C, chunk, real)`` for this program's rows. ``attend(q, li)``: how this
+    program's rows read the pages of a cross layer's source."""
+    kind = fam.kinds[l]
+    if kind == "ssm":
+        a, s, state = _ssm_block(fam, lp, h, state, li, *rows)
+        return a, {**(carry or {}), l: s}, state     # what its gated memory units will read
+    if kind == "gmu":
+        return fam.gmu(lp, h, carry[fam.sources[l]], tp_axis), carry, state
+    with parts.part("attn.qkv"):
+        q = fam.q_cross(lp, h, positions, l)
+    o = attend(q, li)
+    with parts.part("attn.out"):
+        return fam.attn_out(lp, o, tp_axis), carry, state
 
 
 # ---------------------------------------------------------------------------
@@ -463,10 +619,12 @@ def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
         k_c = jnp.swapaxes(k_att, 1, 2).reshape(B, Sp, KV, D)
         v_c = jnp.swapaxes(v_att, 1, 2).reshape(B, Sp, KV, D)
     if fam.prefill_block:
-        o = _attend_prompt_blocked(q, k_c, v_c, 0, math.gcd(Sp, fam.prefill_block))
+        o = _attend_prompt_blocked(
+            q, k_c, v_c, 0, math.gcd(Sp, fam.prefill_block), getattr(fam, "sm_scale", None)
+        )
         return o, k_pool, v_pool, scales
 
-    scale = 1.0 / np.sqrt(D)
+    scale = _sm_scale(fam, D)
     with parts.part("attn.core"):
         scores = jnp.einsum(
             "bshd,bthd->bhst", q.astype(jnp.float32), k_c.astype(jnp.float32)
@@ -501,7 +659,9 @@ def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
         k_win = k_win.at[li, ids].set(_page_chunks(k_c, page)[src].astype(k_win.dtype))
         v_win = v_win.at[li, ids].set(_page_chunks(v_c, page)[src].astype(v_win.dtype))
     block = math.gcd(Sp, fam.prefill_block or Sp)
-    return _attend_prompt_blocked(q, k_c, v_c, window, block), (k_win, v_win)
+    return _attend_prompt_blocked(
+        q, k_c, v_c, window, block, getattr(fam, "sm_scale", None)
+    ), (k_win, v_win)
 
 
 def paged_prefill(
@@ -521,11 +681,14 @@ def paged_prefill(
     win: tuple = None,    # (k_win, v_win) [Lw, 1 + slots * ring, KV, page, D]
     slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
     ring: int = 0,        # static: pages of one slot's ring
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
 ):
     """→ (k_pool, v_pool, first_token [1]), with ``scales`` threaded between
     the pools and the token when the pool is quantized (ISSUE 12), a window
-    family's ring pools after them and its expert counts last
-    (:func:`_result`)."""
+    family's ring pools after them, a recurrent family's state pools and its
+    expert counts last (:func:`_result`). Where the family states
+    ``stop_after``, only the prompt's last row runs the sub-blocks behind
+    it."""
     fam = cfg.serving_family()
     B, Sp = input_ids.shape
     positions = jnp.arange(Sp)
@@ -533,9 +696,32 @@ def paged_prefill(
         h = fam.embed(params, input_ids, positions)
     valid = (positions < prompt_len) if fam.sparse_layers else None
     counts, carry = [], None
+    kinds, stop = sub_block_kinds(fam), getattr(fam, "stop_after", None)
+    kv_of = {}       # a cross layer's source: the prompt's K and V there
+    stopped = False  # the stream is the prompt's last row alone
+
+    def cross(q, li):
+        """A cross layer's rows against its source's K/V: the one row left
+        behind the stop reads the pages just written, a whole prompt the
+        source's K and V of this call."""
+        if stopped:
+            return _attend_decode_shaped(
+                fam, q, k_pool, v_pool, li, page_ids[None, :],
+                jnp.reshape(prompt_len - 1, (1,)), q.dtype,
+            )
+        return _attend_prompt_blocked(
+            q, *kv_of[li], 0, math.gcd(Sp, fam.prefill_block), fam.sm_scale
+        )
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
+        if kinds[l] != "attn":
+            a, carry, state = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, state, tp_axis,
+                (Sp, (slot, jnp.zeros((), jnp.int32), prompt_len), None), cross,
+            )
+            h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
+            continue
         if fam.kv_pools == 1:
             # a latent family: the row into the one pool, attention per head
             # (expanded) in query blocks, its output straight into ``wo``
@@ -561,14 +747,22 @@ def paged_prefill(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
                 page_ids, li, scales,
             )
+            if l in getattr(fam, "sources", {}).values():
+                kv_of[li] = (k_.astype(pool_dt), v.astype(pool_dt))   # by its paged home, as its readers find it
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
+        if l == stop:
+            # the sub-blocks behind write no state: only the row that is
+            # sampled goes on (with its row of what the sources handed on)
+            last = lambda x: lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, 1)  # noqa: E731
+            h, carry, positions, stopped = last(h), jax.tree.map(last, carry), prompt_len - 1 + jnp.arange(1), True
 
     with parts.part("head"):
-        h_last = jnp.take(h, prompt_len - 1, axis=1)  # [B, E] true last prompt pos
+        # [B, E] at the true last prompt position
+        h_last = h[:, 0] if stopped else jnp.take(h, prompt_len - 1, axis=1)
         logits = fam.logits(params, h_last)
     with parts.part("sample"):
         first = sample_logits(logits, rng, temperature, top_k, top_p)
-    return _result(k_pool, v_pool, scales, win, first, counts)
+    return _result(k_pool, v_pool, scales, win, first, counts, state)
 
 
 # ---------------------------------------------------------------------------
@@ -605,7 +799,7 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
             ),
             (B, S, E), out_dtype,
         )
-    scale = 1.0 / np.sqrt(D)
+    scale = _sm_scale(fam, D)
     if fam.attn_impl in ("auto", "pallas") or lo is not None:
         from ..ops.attention import paged_cached_attention
 
@@ -731,10 +925,12 @@ def paged_decode_step(
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
     win: tuple = None,    # a window family's ring pools
     ring: int = 0,
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
 ):
     """→ (k_pool, v_pool, next_tokens [B]); ``scales`` threaded through and
     returned before the tokens when the pool is quantized, a window family's
-    ring pools and expert counts as in :func:`_result`."""
+    ring pools, a recurrent family's state pools and expert counts as in
+    :func:`_result`."""
     fam = cfg.serving_family()
     B = tokens.shape[0]
     page = k_pool.shape[3]
@@ -750,9 +946,20 @@ def paged_decode_step(
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
     valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
     counts, carry = [], None
+    kinds = sub_block_kinds(fam)
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
+        if kinds[l] != "attn":
+            a, carry, state = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, state, tp_axis,
+                (0, None, block_tables[:, 0] != 0),
+                lambda q, li: _attend_decode_shaped(
+                    fam, q, k_pool, v_pool, li, block_tables, seq_lens, q.dtype
+                ),
+            )
+            h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
+            continue
         with parts.part("attn.qkv"):
             q, k_, v = fam.qkv(lp, h, positions, l)
         if fam.kv_pools == 1:
@@ -773,7 +980,7 @@ def paged_decode_step(
     with parts.part("head"):
         logits = fam.logits(params, h[:, -1])
     nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
-    return _result(k_pool, v_pool, scales, win, nxt, counts)
+    return _result(k_pool, v_pool, scales, win, nxt, counts, state)
 
 
 # ---------------------------------------------------------------------------
@@ -809,7 +1016,7 @@ def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
     block comment above for why this form is token-identical but not
     bit-identical across chunking boundaries."""
     B, T, H, D = q.shape
-    scale = 1.0 / np.sqrt(D)
+    scale = _sm_scale(fam, D)
     if fam.attn_impl in ("auto", "pallas") or lo is not None:
         from ..ops.attention import paged_multitoken_cached_attention
 
@@ -932,6 +1139,9 @@ def paged_verify_step(
     lands on the page of a position ``ring`` pages back, which no query of
     this step or a later one reaches.)"""
     fam = cfg.serving_family()
+    if set(sub_block_kinds(fam)) != {"attn"}:
+        # a rejected draft's rows cannot be taken back out of a recurrent state
+        raise NotImplementedError("the verify step serves families whose sub-blocks are all attentions")
     B, T = tokens.shape
     page = k_pool.shape[3]
     # clamp garbage positions (past the decode budget) into the embedding
@@ -1006,6 +1216,7 @@ def paged_mixed_step(
     win: tuple = None,    # a window family's ring pools
     slot: jnp.ndarray = None,  # traced i32: the prefilling slot, whose ring a window layer writes
     ring: int = 0,
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
 ):
     """One chunk of ONE slot's incremental prefill (ISSUE 10) and one token
     for every decoding slot, through every weight ONCE → (k_pool, v_pool,
@@ -1045,7 +1256,16 @@ def paged_mixed_step(
     rings (:class:`_RingWrites`) and its token is discarded. ``B`` may be 0
     (:func:`paged_chunk_prefill`); a call whose rows are all idle is what a
     chunk takes when it has no decode step to ride, and where the slots are
-    many it skips their reads (``SKIP_IDLE_READS_FROM_SLOTS``)."""
+    many it skips their reads (``SKIP_IDLE_READS_FROM_SLOTS``).
+
+    A family of several ``kinds``: a state-space sub-block takes the chunk's
+    rows from the slot's carried state (zeros at ``start`` 0) and the decode
+    rows each from its slot's (:func:`_ssm_block`). Behind ``fam.stop_after``
+    the chunk is ONE row, the one whose logits are sampled (the prompt's last
+    where it falls in this chunk): the sub-blocks there write nothing a later
+    call reads, so the other prompt rows have no business in them. A cross
+    layer then reads its source's pages for that row and the decode rows in
+    one decode-shaped call (the chunk's row under ``chunk_row``)."""
     fam = cfg.serving_family()
     B, C = tokens.shape[0], input_ids.shape[1]
     page = k_pool.shape[3]
@@ -1062,6 +1282,11 @@ def paged_mixed_step(
     valid = jnp.concatenate([c_pos < prompt_len, real]) if fam.sparse_layers else None
     live = jnp.any(real) if B >= SKIP_IDLE_READS_FROM_SLOTS else None
     counts, carry = [], None
+    kinds, stop = sub_block_kinds(fam), getattr(fam, "stop_after", None)
+    # the chunk's row that is sampled (its last real one, on a prompt's final chunk)
+    idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
+    n_real = jnp.clip(prompt_len - start, 0, C)
+    Cs, stopped = C, False   # chunk rows in the stream: C, or the sampled one behind ``stop_after``
     rw = None
     if win is not None:
         ring_ids = ring_page_ids(slot, start // page + jnp.arange(C // page), ring)
@@ -1071,10 +1296,40 @@ def paged_mixed_step(
     def part(x):
         """``[1, C + B, ...]`` → the chunk's ``[1, C, ...]`` and the decode
         rows as their own program has them, ``[B, 1, ...]``."""
-        return x[:, :C], jnp.swapaxes(x[:, C:], 0, 1)
+        return x[:, :Cs], jnp.swapaxes(x[:, Cs:], 0, 1)
+
+    def cross(q, li):
+        """A cross layer's rows against its source's pages, which this call
+        has already written."""
+        if stopped:
+            # the chunk's one row beside the decode rows, its table row
+            # beside theirs: one call of the one-token kernel
+            o = _attend_decode_shaped(
+                fam, jnp.swapaxes(q, 0, 1), k_pool, v_pool, li,
+                jnp.concatenate([chunk_row, block_tables]),
+                jnp.concatenate([base + idx, seq_lens]), q.dtype, name="decode_fn",
+                live=None if live is None else live | (prompt_len <= start + C),
+            )
+            return jnp.swapaxes(o, 0, 1)
+        qc, qd = part(q)
+        o = _attend_multitoken_paged(fam, qc, k_pool, v_pool, li, chunk_row, base, name="chunk_fn")
+        if not B:
+            return o
+        od = _attend_decode_shaped(
+            fam, qd, k_pool, v_pool, li, block_tables, seq_lens, q.dtype,
+            name="decode_fn", live=live,
+        )
+        return jnp.concatenate([o, jnp.swapaxes(od, 0, 1)], axis=1)
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
+        if kinds[l] != "attn":
+            a, carry, state = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, state, tp_axis,
+                (Cs, (slot, start, n_real), real if B else None), cross,
+            )
+            h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
+            continue
         with parts.part("attn.qkv"):
             q, k_, v = fam.qkv(lp, h, positions, l)
         (qc, qd), (kc, kd) = part(q), part(k_)
@@ -1125,18 +1380,28 @@ def paged_mixed_step(
             )
         o = oc if od is None else jnp.concatenate([oc, jnp.swapaxes(od, 0, 1)], axis=1)
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
+        if l == stop:
+            # of the chunk only the sampled row goes on, with its row of
+            # what the sources handed on
+            sampled = lambda x: jnp.concatenate(  # noqa: E731
+                [lax.dynamic_slice_in_dim(x, idx, 1, 1), x[:, C:]], axis=1
+            )
+            h, carry, positions = sampled(h), jax.tree.map(sampled, carry), sampled(positions[None])[0]
+            Cs, stopped = 1, True
 
     # one pass of the head: the chunk's true last prompt position (when it
     # falls inside this chunk) and the decode rows
-    idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
     with parts.part("head"):
-        logits = fam.logits(params, jnp.concatenate([jnp.take(h[0], idx[None], axis=0), h[0, C:]]))
+        logits = fam.logits(
+            params, h[0] if stopped
+            else jnp.concatenate([jnp.take(h[0], idx[None], axis=0), h[0, C:]])
+        )
     with parts.part("sample"):
         first = sample_logits(logits[:1], rng, temperature, top_k, top_p)
         if B:
             nxt = _sample_slots(logits[1:], keys, temperature, top_k, top_p)
             first = jnp.concatenate([nxt, first.astype(nxt.dtype)])
-    return _result(k_pool, v_pool, scales, win, first, counts)
+    return _result(k_pool, v_pool, scales, win, first, counts, state)
 
 
 def paged_chunk_prefill(

@@ -309,9 +309,18 @@ class ProgramSet:
     A LATENT family (``fam.kv_pools == 1``) has ONE pool and no V pool:
     ``k_pool`` is ``[L, P, 1, page, W]`` with ``W`` (``head_dim`` here) the
     cached row as it is stored, ``v_pool`` is ``None``, and a program written
-    ``fn(params, k_pool, v_pool, ...)`` is handed ``None`` for the second."""
+    ``fn(params, k_pool, v_pool, ...)`` is handed ``None`` for the second.
+
+    A family with state-space sub-blocks (``fam.kinds``) has a third kind of
+    per-slot state, fixed in size and recurrent: ``state_pools = (ssm, conv)``,
+    ``[Ls, slots, N, d_inner]`` float32 and ``[Ls, slots, K - 1, d_inner]`` in
+    the cache's type (``serving/model._ssm_block``), the last two of
+    :meth:`pool_args`, donated like the ring pools. No allocator: a slot's row
+    is the slot's, and the program that takes a request's first rows starts it
+    from zeros."""
 
     kv_pools = 2  # a K and a V pool; 1: a latent family's one pool
+    state_pools = None  # (ssm, conv) for a family with state-space sub-blocks
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
                  page_size: int, cache_dtype, params: PyTree,
@@ -319,10 +328,13 @@ class ProgramSet:
         self.placement = placement
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        from .model import pool_layers
+
         fam = mcfg.serving_family()
         # the paged pools hold the layers that read their whole context; a
-        # window layer's K/V live in the ring pools below
-        self.n_layer = sum(1 for w in fam.windows if not w)
+        # window layer's K/V live in the ring pools below, a state-space
+        # sub-block's recurrent state in the state pools
+        self.n_layer, self.n_window_layer, n_state = pool_layers(fam)
         self.n_kv_head = int(fam.n_kv_head)
         self.kv_pools = int(fam.kv_pools)
         k, v, scales = init_pools(
@@ -337,7 +349,6 @@ class ProgramSet:
         # window layers: ``ring_pages`` pages statically owned by each of
         # ``ring_slots`` slots, after a scratch page; no allocator, and the
         # bytes do not grow with context
-        self.n_window_layer = len(fam.windows) - self.n_layer
         self.ring_pages = int(ring_pages) if self.n_window_layer else 0
         self.window_pools = None
         if self.n_window_layer:
@@ -347,6 +358,12 @@ class ProgramSet:
             )
             self.window_pools = (
                 placement.put_pool(kw, kw.ndim - 3), placement.put_pool(vw, vw.ndim - 3)
+            )
+        if n_state:
+            (N, d_inner), K = fam.ssm_state, fam.ssm_conv
+            self.state_pools = (
+                placement.put(jnp.zeros((n_state, int(ring_slots), N, d_inner), jnp.float32)),
+                placement.put(jnp.zeros((n_state, int(ring_slots), K - 1, d_inner), self.k_pool.dtype)),
             )
         self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
@@ -363,11 +380,11 @@ class ProgramSet:
     def pool_args(self) -> tuple:
         """The donated pool operands, in program order: K, V (a latent
         family: its one pool), an int8 pool's scales, a window family's two
-        ring pools."""
+        ring pools, a recurrent family's two state pools."""
         out = (self.k_pool,) + ((self.v_pool,) if self.v_pool is not None else ())
         if self.kv_scales is not None:
             out += (self.kv_scales,)
-        return out + (self.window_pools or ())
+        return out + (self.window_pools or ()) + (self.state_pools or ())
 
     def _check_pool_layout(self) -> None:
         """Where the paged kernels run, the pools must have come out
@@ -400,7 +417,7 @@ class ProgramSet:
         return out + tuple(
             self.placement.pool_spec(w.ndim, w.ndim - 3)
             for w in self.window_pools or ()
-        )
+        ) + (self.placement.rep_spec(),) * len(self.state_pools or ())
 
     def aot(self, fn, operands: Sequence, operand_specs: Sequence = (),
             result_specs: Sequence = (), *, with_params: bool = False,
@@ -420,9 +437,9 @@ class ProgramSet:
         pools = self.pool_args()
         one_pool = self.kv_pools == 1
         # the K/V-shaped pools (all but the scales), which fn sees as views
+        n_kv = len(pools) - len(self.state_pools or ())
         kv_like = list(range(first, first + self.kv_pools)) + (
-            [first + len(pools) - 2, first + len(pools) - 1]
-            if self.window_pools else []
+            [first + n_kv - 2, first + n_kv - 1] if self.window_pools else []
         )
 
         @functools.wraps(fn)  # jit(decode_fn): the name traces are read by
@@ -520,6 +537,9 @@ class ProgramSet:
         if self.window_pools is not None:
             self.window_pools = (rest[0], rest[1])
             rest = rest[2:]
+        if self.state_pools is not None:
+            self.state_pools = (rest[0], rest[1])
+            rest = rest[2:]
         return rest[0] if len(rest) == 1 else rest
 
     def set_pools(self, pools: tuple) -> None:
@@ -604,3 +624,9 @@ class ProgramSet:
         """K+V bytes of the ring pools: slots x ring pages (and the scratch
         page) x page bytes x window layers, whatever the contexts' lengths."""
         return sum(int(w.nbytes) for w in self.window_pools or ())
+
+    def state_pool_bytes(self) -> int:
+        """Bytes of the recurrent state pools: slots x state-space sub-blocks
+        x (the scan state and the convolution's rows), whatever the contexts'
+        lengths."""
+        return sum(int(w.nbytes) for w in self.state_pools or ())

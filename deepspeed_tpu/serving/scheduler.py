@@ -124,13 +124,17 @@ def _split_scales(rest: tuple, quantized: bool):
     return None, rest
 
 
-def _split_pools(rest: tuple, quantized: bool, windowed: bool):
-    """:func:`_split_scales`, then a window family's two ring pools:
-    → ``(scales | None, (k_win, v_win) | None, inputs)``."""
+def _split_pools(rest: tuple, quantized: bool, windowed: bool, recurrent: bool = False):
+    """:func:`_split_scales`, then a window family's two ring pools and a
+    recurrent family's two state pools: → ``(scales | None, (k_win, v_win) |
+    None, (ssm, conv) | None, inputs)``."""
     scales, rest = _split_scales(rest, quantized)
+    win = state = None
     if windowed:
-        return scales, (rest[0], rest[1]), rest[2:]
-    return scales, None, rest
+        win, rest = (rest[0], rest[1]), rest[2:]
+    if recurrent:
+        state, rest = (rest[0], rest[1]), rest[2:]
+    return scales, win, state, rest
 
 
 @dataclass
@@ -213,8 +217,8 @@ class ServingEngine:
             raise ValueError(
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
-                "including injected HF GPT-2, exaone_moe, mistral4 and "
-                "longcat_flash); got "
+                "including injected HF GPT-2, exaone_moe, mistral4, "
+                "longcat_flash and phi4flash); got "
                 f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
@@ -226,8 +230,13 @@ class ServingEngine:
         # a latent family keeps ONE pool of one row a token that all heads
         # share: the same mechanisms know K and V pools of per-head pages only
         self.latent = fam.kv_pools == 1
-        if self.windowed or self.latent:
+        # a family with state-space sub-blocks keeps a third kind: a fixed-size
+        # recurrent state a slot, which is no page, cannot be cut at a prefix
+        # and cannot be rolled back behind a rejected draft without a snapshot
+        self.recurrent = "ssm" in smodel.sub_block_kinds(fam)
+        if self.windowed or self.latent or self.recurrent:
             plc_ = getattr(config, "placement", None)
+            spec_on = bool(getattr(getattr(config, "speculative", None), "enabled", False))
             for on, what in (
                 (getattr(getattr(config, "prefix_cache", None), "enabled", False),
                  "serving.prefix_cache"),
@@ -242,11 +251,17 @@ class ServingEngine:
                  "serving.placement.tp > 1"),
                 (plc_ is not None and bool(getattr(plc_, "disaggregate", False)),
                  "serving.placement.disaggregate"),
+                (self.recurrent and spec_on, "serving.speculative"),
             ):
                 if on:
                     raise ValueError(
                         f"{what} is not available for a model with "
                         + (
+                            f"recurrent state ({type(mcfg).__name__}): a "
+                            "state-space layer's scan state and convolution "
+                            "rows live in a per-slot pool beside the paged "
+                            "pool, which this mechanism does not handle"
+                            if self.recurrent else
                             f"sliding-window layers ({type(mcfg).__name__}): a "
                             "window layer's KV lives in a per-slot ring beside "
                             "the paged pool, which this mechanism does not handle"
@@ -573,6 +588,12 @@ class ServingEngine:
             "serving_chunk_prefills_total",
             "prompt chunks advanced (chunk program invocations: those that "
             "carried a decode step's rows and those that did not)",
+        )
+        self._c_rows_skipped = m.counter(
+            "serve_prefill_rows_skipped_total",
+            "prompt rows that left the stream behind the family's stop_after "
+            "sub-block (a family whose last sub-blocks write no state runs "
+            "them for the sampled row alone)",
         )
         self._c_chunks_rode = m.counter(
             "serving_chunks_rode_total",
@@ -1016,33 +1037,33 @@ class ServingEngine:
         # The chunk program is the MIXED step: one prefilling slot's chunk
         # and every slot's decode row through the weights once. Its host
         # operands are the decode step's four, then the chunk's.
-        windowed, ring = self.windowed, self.ring_pages
+        windowed, ring, recurrent = self.windowed, self.ring_pages, self.recurrent
 
         def make_fns(cfg, tp_axis):
             def prefill_fn(params, k_pool, v_pool, *rest):
-                scales, win, (ids, plen, page_ids, key, *slot) = _split_pools(
-                    rest, quant, windowed
+                scales, win, state, (ids, plen, page_ids, key, *slot) = _split_pools(
+                    rest, quant, windowed, recurrent
                 )
                 return smodel.paged_prefill(
                     cfg, params, ids, plen, k_pool, v_pool, page_ids, key,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
                     tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
-                    ring=ring,
+                    ring=ring, state=state,
                 )
 
             def decode_fn(params, k_pool, v_pool, *rest):
-                scales, win, (tokens, seq_lens, bt, keys) = _split_pools(
-                    rest, quant, windowed
+                scales, win, state, (tokens, seq_lens, bt, keys) = _split_pools(
+                    rest, quant, windowed, recurrent
                 )
                 return smodel.paged_decode_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt, keys,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis, win=win, ring=ring,
+                    tp_axis=tp_axis, win=win, ring=ring, state=state,
                 )
 
             def verify_fn(params, k_pool, v_pool, *rest):
-                scales, win, (tokens, seq_lens, bt) = _split_pools(
-                    rest, quant, windowed
+                scales, win, state, (tokens, seq_lens, bt) = _split_pools(
+                    rest, quant, windowed, recurrent
                 )
                 return smodel.paged_verify_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt,
@@ -1052,16 +1073,16 @@ class ServingEngine:
             # named for what a trace's readers find it by: a chunk program
             # and the program a decode dispatch launches
             def chunk_decode_fn(params, k_pool, v_pool, *rest):
-                scales, win, (
+                scales, win, state, (
                     tokens, seq_lens, bt, keys,
                     ids, start, plen, page_ids, bt_row, key, *slot,
-                ) = _split_pools(rest, quant, windowed)
+                ) = _split_pools(rest, quant, windowed, recurrent)
                 return smodel.paged_mixed_step(
                     cfg, params, tokens, seq_lens, ids, start, plen, k_pool,
                     v_pool, bt, page_ids, bt_row, keys, key,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
                     tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
-                    ring=ring,
+                    ring=ring, state=state,
                 )
 
             return prefill_fn, decode_fn, verify_fn, chunk_decode_fn
@@ -1302,6 +1323,7 @@ class ServingEngine:
         kv_bytes = {
             "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
             "window": ds.window_pool_bytes(),
+            **({"state": ds.state_pool_bytes()} if self.recurrent else {}),
         }
         for cls, n in kv_bytes.items():
             self._g_kv_bytes.set(n, **{"class": cls})
@@ -1666,10 +1688,13 @@ class ServingEngine:
                 if self.windowed:
                     # what a layer reads, averaged over the layers: a window
                     # layer reads its window of a context, not the context
+                    # (of a family of several kinds the sub-blocks that
+                    # attend: a cross layer reads its source's whole context,
+                    # a state-space mixer and a memory unit no key)
                     ws = self.family.windows
                     attended = int(sum(
-                        np.minimum(lens + 1, w).sum() if w else attended
-                        for w in ws
+                        (np.minimum(lens + 1, w).sum() if w else attended)
+                        for k, w in zip(smodel.sub_block_kinds(self.family), ws) if k in ("attn", "cross")
                     ) / len(ws))
                 sp.set(
                     attended=attended,
@@ -2244,6 +2269,11 @@ class ServingEngine:
         t = min(self.chunk_width, slot.request.prompt_len - slot.prefill_pos)
         return t, t * slot.prefill_pos + t * (t + 1) // 2
 
+    def _chunk_is_last(self, slot_i: int) -> bool:
+        """Whether the next chunk of a PREFILLING slot is its prompt's last."""
+        slot = self.slots[slot_i]
+        return slot.prefill_pos + self.chunk_width >= slot.request.prompt_len
+
     def _moe_report(self, prompts: list) -> dict:
         """``ds.serve.chunk``'s expert attributes for the prompts that
         finished prefilling: (loads ``[calls x sparse layers, experts_held]``
@@ -2294,6 +2324,7 @@ class ServingEngine:
             "ds.serve.chunk", chunks=len(alone), rode=int(rider is not None)
         ) as sp:
             n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
+            finals = int(rider is not None and self._chunk_is_last(rider))
             moe = []   # (counts, tokens) of the prompts that finished here
             unwaited = []
             for i in alone:
@@ -2301,6 +2332,7 @@ class ServingEngine:
                 t, att = self._chunk_reach(i)
                 n_tok += t
                 attended += att
+                finals += self._chunk_is_last(i)
                 if rider is not None and slot.prefill_pos + t < slot.request.prompt_len:
                     unwaited.append(i)
                     continue
@@ -2322,6 +2354,13 @@ class ServingEngine:
                 slot.moe_counts, slot.moe_tokens = [], 0
                 self._start_decoding(i, int(tok_np[-1]))
             sp.set(tokens=n_tok, attended=attended)
+            if getattr(self.family, "kinds", None):
+                # rows through the sub-blocks up to the family's stop_after,
+                # and through those behind it: a final chunk's sampled row
+                stops = getattr(self.family, "stop_after", None) is not None
+                sp.set(rows_self=n_tok, rows_cross=finals if stops else n_tok)
+                if stops:
+                    self._c_rows_skipped.inc(n_tok - finals)
             if moe and rider is None:
                 # a prompt's chunk calls report with its last one, whose
                 # token fetch is the one wait there is
@@ -2721,6 +2760,12 @@ class ServingEngine:
         so each side compiles exactly once per engine."""
         if self._migrate_gather_exec is not None:
             return
+        if self.recurrent:
+            raise ValueError(
+                "session migration is not available for a model with "
+                "recurrent state: the transport moves a slot's paged row, and "
+                "its scan state and convolution rows would stay behind"
+            )
         if self.windowed:
             raise ValueError(
                 "session migration is not available for a model with "

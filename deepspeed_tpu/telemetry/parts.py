@@ -54,6 +54,8 @@ PARTS = (
     "mlp.dense",    # a double layer's two dense FFNs, beside its expert layer (models/longcat_flash.py)
     "moe.route",    # router logits, top-k, the sort, gathers, scatters and the combine
     "moe.experts",  # the held experts' products, masked or grouped
+    "ssm.proj",     # a state-space mixer's and a gated memory unit's projections and gates
+    "ssm.scan",     # the short convolution and the recurrence, kernel or lax.scan
     "head",         # final norm, logits, the loss in training
     "sample",       # sampling
     "optim",        # gradient norm and clip, AdamW, casts of masters, loss scaling
@@ -105,6 +107,7 @@ def unscoped():
 KERNEL_FILES = {
     "ops/pallas/flash_attention.py": "attn.core",
     "ops/pallas/decode_attention.py": "attn.core",
+    "ops/pallas/selective_scan.py": "ssm.scan",
 }
 
 

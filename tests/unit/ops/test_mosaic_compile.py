@@ -900,3 +900,131 @@ def test_zero3_over_four_chips_gathers_weights_and_reduce_scatters_gradients(top
     assert '%all-reduce-scatter' in text
     weight_shaped = [c for c in kinds["all_reduce"] if any(len(dims) >= 2 for _, dims in c.shapes)]
     assert len(weight_shaped) <= 1, weight_shaped
+
+
+# -- the recurrent family (phi4flash): the scan kernels and the three programs at the served size (ISSUE 43) --
+
+@pytest.mark.parametrize("entry,rows", [("chunk", 256), ("chunk", 2048), ("chunk", 37), ("step", 64), ("step", 48)])
+def test_selective_scan_kernels_compile_for_v5e_at_the_served_widths(one_chip, entry, rows):
+    """``ops/pallas/selective_scan.py`` at Phi-4-mini-flash's widths (5 120
+    channels, a state of 16): a chunk of one slot's rows (the chunk program's
+    256, the whole-prompt program's 2 048, an odd count), and one row for each
+    of 64 or 48 slots against a layer of the whole state pool, aliased: no
+    copy of the pool is made."""
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+
+    d, N = 5120, 16
+
+    def S(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    rowwise = (S((rows, d)), S((rows, d)), S((rows, N)), S((rows, N)), S((N, d)), S((d,)))
+    if entry == "chunk":
+        compiled = jax.jit(lambda *a: ss.scan_rows(*a, impl="pallas")).lower(*rowwise, S((N, d))).compile()
+        assert len(re.findall(rf"^\s*(ROOT )?%?{ss.CHUNK_KERNEL}[.\d]* = .*custom-call\(", compiled.as_text(), re.M)) == 1
+        return
+    pool = S((9, rows, N, d))
+    compiled = jax.jit(lambda *a: ss.scan_step(*a, 3, impl="pallas"), donate_argnums=(6,)).lower(*rowwise, pool).compile()
+    assert len(re.findall(rf"^\s*(ROOT )?%?{ss.STEP_KERNEL}[.\d]* = .*custom-call\(", compiled.as_text(), re.M)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 9 * rows * N * d * 4 and mem.temp_size_in_bytes < 1e6   # the pool in place
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", pytest.param("prefill", marks=pytest.mark.slow)])
+def test_recurrent_family_programs_compile_at_the_served_size(one_chip, program, monkeypatch):
+    """The ``phi4flash`` programs as its cell serves them (64 slots; 32
+    sub-blocks of four kinds; 40 padded pair-heads on 10 kv pairs of 128
+    lanes; ONE paged layer of 3 073 pages, 8 rings of 7 pages a slot, 9 scan
+    states of [16, 5120] float32 a slot; a 2 048-token whole-prompt width; the
+    whole 200 064-row vocabulary; 7.70 GB of bf16 weights as shapes): the
+    paged kernels, token writes and scan kernels pass Mosaic, nothing re-lays
+    a pool out, a decode step reads keys in 16 sub-blocks and advances 9
+    states, and arguments + temps fit the chip."""
+    import json
+
+    from deepspeed_tpu.models import phi4flash
+    from deepspeed_tpu.ops.pallas import selective_scan as ss
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    with open(os.path.join(root, "perfbench", "configs", "phi-4-mini-flash-serve-1chip.json")) as f:
+        c = json.load(f)
+    cfg = phi4flash.Phi4FlashConfig.from_dict(c)
+    sv = c["serving"]
+    B, page, P, Sp, C = (sv[k] for k in ("max_slots", "page_size", "num_pages", "max_prompt_len", "prefill_chunk_tokens"))
+    W = -(-(Sp + sv["max_new_tokens"]) // page)
+    ring = -(-(cfg.sliding_window + C) // page) + 1
+    KV, D = cfg.n_kv_head, cfg.head_dim
+    assert (B, W, ring, KV, D, P) == (64, 48, 7, 10, 128, B * W + 1)
+    assert smodel.pool_layers(cfg.serving_family()) == (1, 8, 9)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: phi4flash.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    assert 7.69e9 < 2 * sum(x.size for x in jax.tree.leaves(params)) < 7.72e9      # 3.852B parameters
+    pool, wpool = (
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16))
+        for shape in ((1, P, KV, page, D), (8, 1 + B * ring, KV, page, D))
+    )
+    ssm, conv = (
+        jax.ShapeDtypeStruct(shape, dt, sharding=_default_format(one_chip, shape, dt))
+        for shape, dt in (((9, B, 16, 5120), jnp.float32), ((9, B, 3, 5120), jnp.bfloat16))
+    )
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, kw, vw, s, cv, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, k, v, bt, keys, win=(kw, vw), ring=ring, state=(s, cv)),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "mixed": (
+            lambda p, k, v, kw, vw, s, cv, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
+                smodel.paged_mixed_step(
+                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key,
+                    win=(kw, vw), slot=slot, ring=ring, state=(s, cv)),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
+             sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
+             sds((1, W), i32), sds((2,), u32), sds((), i32)),
+        ),
+        "prefill": (
+            lambda p, k, v, kw, vw, s, cv, ids, plen, pages, key, slot: smodel.paged_prefill(
+                cfg, p, ids, plen, k, v, pages, key, win=(kw, vw), slot=slot, ring=ring, state=(s, cv)),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32), sds((), i32)),
+        ),
+    }[program]
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params,
+        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=(wpool, wpool), state_pools=(ssm, conv),
+        _kv_axis=2, num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=1,
+    )
+    compiled = pset.aot(fn, host, with_params=True)
+    text = compiled.as_text()
+    assert pset.program_census(program, compiled)[0] == 0  # or it raises
+
+    def kernels(name):
+        return len(re.findall(rf"^\s*%?{name}[.\d]* = .*custom-call\(", text, re.M))
+
+    # the one-token kernel: 8 rings, the paged layer and its 7 cross readers (in the decode program it takes the
+    # enclosing function's name, the scheduler's ``decode_fn``; here a lambda's); a token write where K/V are made
+    want = {"decode": {"kv_token_write": 9, ss.STEP_KERNEL: 9},
+            # the chunk rows' multi-token kernel on the 9 attentions; behind the stop the chunk's sampled row
+            # rides the cross layers' one-token call
+            "mixed": {"decode_fn": 16, "kv_token_write": 9, "chunk_fn": 9, ss.STEP_KERNEL: 9, ss.CHUNK_KERNEL: 9},
+            # the whole prompt: blocked jnp attention, the last row's 7 cross reads through the one-token kernel
+            "prefill": {ss.CHUNK_KERNEL: 9}}[program]
+    got = {name: kernels(name) for name in want}
+    print(program, got, text.count('custom_call_target="tpu_custom_call"'))
+    assert got == want
+    assert text.count('custom_call_target="tpu_custom_call"') == {"decode": 16 + 9 + 9, "mixed": 16 + 9 + 9 + 9 + 9, "prefill": 9 + 7}[program]
+    mem = compiled.memory_analysis()
+    print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
+    # 7.70 GB of weights, 2.01 GB of paged pool, 2.35 GB of rings, 0.21 GB of recurrent state
+    assert 12.2e9 < mem.argument_size_in_bytes < 12.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9   # of the chip's 16

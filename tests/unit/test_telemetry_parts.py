@@ -38,7 +38,9 @@ def test_a_scope_outside_the_vocabulary_is_refused():
         parts.part("attention")
     with parts.part("attn.core"):
         pass
-    assert len(set(parts.PARTS)) == len(parts.PARTS) == 13
+    assert len(set(parts.PARTS)) == len(parts.PARTS) == 15      # 13 and, since PR 43, ssm.proj and ssm.scan
+    with parts.part("ssm.scan"), parts.part("ssm.proj"):
+        pass
 
 
 @pytest.mark.parametrize("op_name, part, phase", [
