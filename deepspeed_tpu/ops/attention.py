@@ -459,10 +459,12 @@ def latent_paged_cached_attention(
 def latent_attention_grid_steps(
     impl: str, B: int, H: int, page: int, W: int, itemsize: int, n_pages: int, T: int = 1,
 ) -> int:
-    """Grid steps of ONE call of the latent attention kernel that
-    :func:`latent_paged_cached_attention` dispatches these shapes to under
-    ``impl``: slots x query blocks x page blocks, by the dispatcher's own rule
-    and the kernel's own block rule. 0 where the jnp fallback runs."""
+    """The STATIC BOUND on the grid steps of ONE call of the latent attention
+    kernel that :func:`latent_paged_cached_attention` dispatches these shapes
+    to under ``impl``: slots x query blocks x page blocks, by the dispatcher's
+    own rule and the kernel's own block rule; what a call of full slots takes.
+    A call walks only the pairs it owns (``latent_attention.latent_walk_steps``
+    reckons them from its lengths). 0 where the jnp fallback runs."""
     from .pallas.latent_attention import latent_attention_ok, latent_blocks
 
     if not _paged_kernel_taken(impl, lambda: latent_attention_ok(page, W, itemsize)):

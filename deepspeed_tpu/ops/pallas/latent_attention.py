@@ -14,10 +14,11 @@ so a page is read ONCE for scores and values, where the per-head kernels of
 :func:`latent_paged_attention` serves the decode step (``T`` = 1: a slot's 32
 heads are the rows of one step, pages walked ``G`` at a time) and the chunk
 program (``T`` = the chunk: ``TQ`` tokens x ``H`` heads are the rows of a
-step, the grid walks query blocks and, inside, page blocks up to the one the
-query block reaches). :func:`latent_token_write` is the decode step's write
-into the one pool. The pool is ``[L, P, 1, page, W]`` (or ``[P, 1, page, W]``)
-with ``W`` a whole number of 128-lane tiles where the kernels run
+step). The grid walks the (slot, query block, page block) pairs the call owns
+and no other: a list of items whose length is a value of the call.
+:func:`latent_token_write` is the decode step's write into the one pool. The
+pool is ``[L, P, 1, page, W]`` (or ``[P, 1, page, W]``) with ``W`` a whole
+number of 128-lane tiles where the kernels run
 (``serving/kv_cache.pool_stored_shape`` says why): the lanes past the
 family's row are zeros and the query is zero there too.
 """
@@ -29,10 +30,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import _pool_block_spec, _pool_dims, _walked_table
+from .decode_attention import _pool_block_spec, _pool_dims
 
 DECODE_KEYS = 2048   # keys a grid step of the decode shape holds (32 rows: the page DMAs are the step)
 CHUNK_KEYS = 512     # ... and of the chunk shape
@@ -48,26 +50,47 @@ def latent_blocks(H: int, page: int, T: int, n_pages: int):
     many tokens as keep ``TQ * H`` rows within ``CHUNK_ROWS`` (a divisor of
     ``T``), and pages up to ``DECODE_KEYS`` keys where the rows are a quarter of
     that or fewer, ``CHUNK_KEYS`` beyond (a power of two, at most the table)."""
-    TQ = max(t for t in range(1, T + 1) if T % t == 0 and t * H <= max(H, CHUNK_ROWS))
+    TQ = max(t for t in range(1, min(T, max(1, CHUNK_ROWS // H)) + 1) if T % t == 0)
     keys = DECODE_KEYS if TQ * H <= CHUNK_ROWS // 4 else CHUNK_KEYS
     G = max(1, min(keys // page, n_pages))
     return TQ, 1 << (G.bit_length() - 1)
 
 
-def _latent_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
-                   TQ: int, H: int, v_width: int):
-    """Online softmax over one slot's pages for the ``TQ`` query tokens of
-    grid column ``i`` (rows ``t * H + h``). Grid ``(B, T // TQ, n_blk)``: the
-    (m, l, acc) scratch persists over a query block's page blocks, reset at
-    block 0 and emitted at the last block the query block reaches; later
-    blocks skip their compute and, the index map naming the same pages, fetch
-    nothing. A block every query of the step sees whole takes the branch that
-    builds no mask."""
+def latent_walk(base, T: int, TQ: int, GP: int, n_blk: int, xp=jnp):
+    """Page blocks each (slot, query block) of a call owns, ``[B, T // TQ]``:
+    block 0 up to the one that holds the own key of the query block's last
+    token (``base`` ``[B]``). The kernel's wrapper and, with ``xp=np``, the
+    scheduler's counters reckon by this one rule."""
+    at = base[:, None] + TQ * xp.arange(T // TQ)[None, :]
+    return xp.minimum((at + (TQ - 1)) // GP, n_blk - 1) + 1
+
+
+def latent_walk_steps(base, H: int, page: int, T: int, n_pages: int):
+    """(grid steps one kernel call takes, steps of the rectangle ``slots x
+    query blocks x page blocks`` that bounds them: what full slots take) for
+    slots whose queries start at ``base`` ``[B]``; host arithmetic."""
+    TQ, G = latent_blocks(H, page, T, n_pages)
+    n_blk = -(-n_pages // G)
+    own = latent_walk(np.asarray(base, np.int64), T, TQ, G * page, n_blk, xp=np)
+    return int(own.sum()), own.size * n_blk
+
+
+def _latent_kernel(_row_ref, blk_ref, at_ref, _page_ref, q_ref, *rest, sm_scale: float,
+                   G: int, TQ: int, H: int, v_width: int, n_blk: int):
+    """Online softmax over one slot's pages for the ``TQ`` query tokens of one
+    query block (rows ``t * H + h``). The grid is the call's ITEMS, one a
+    (slot, query block, page block) the call owns, a query block's page blocks
+    in a row: step ``s`` holds block ``blk[s]`` for the query block whose first
+    token sits at ``at[s]``. The (m, l, acc) scratch persists over a query
+    block's items, reset at its block 0 and emitted at the last block it
+    reaches; every step computes, so the next item's pages, and the next query
+    block's queries, arrive under a computed step. A block every query of the
+    step sees whole takes the branch that builds no mask."""
     k_refs, (o_ref, m_ref, l_ref, acc_ref) = rest[:G], rest[G:]
-    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    at = at_ref[b] + i * TQ                      # position of the step's first query
+    step = pl.program_id(0)
+    j, at = blk_ref[step], at_ref[step]          # the page block; position of the step's first query
     GP = G * k_refs[0].shape[1]
-    last_blk = jnp.minimum(jax.lax.div(at + (TQ - 1), GP), pl.num_programs(2) - 1)
+    last_blk = jnp.minimum(jax.lax.div(at + (TQ - 1), GP), n_blk - 1)
 
     @pl.when(j == 0)
     def _reset():
@@ -76,7 +99,7 @@ def _latent_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def update(masked: bool):
-        q = q_ref[0, 0]                                          # [TQ * H, W]
+        q = q_ref[0]                                             # [TQ * H, W]
         k = jnp.concatenate([r[0] for r in k_refs], axis=0)      # [GP, W]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -101,14 +124,45 @@ def _latent_kernel(walk_ref, at_ref, q_ref, *rest, sm_scale: float, G: int,
             p.astype(k.dtype), k[:, :v_width], preferred_element_type=jnp.float32
         )
 
-    own = j <= last_blk
     whole = (j + 1) * GP - 1 <= at
-    pl.when(own & whole)(functools.partial(update, False))
-    pl.when(own & jnp.logical_not(whole))(functools.partial(update, True))
+    pl.when(whole)(functools.partial(update, False))
+    pl.when(jnp.logical_not(whole))(functools.partial(update, True))
 
     @pl.when(j == last_blk)
     def _emit():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _walk_items(block_tables, base, T: int, TQ: int, G: int, page: int):
+    """The call's items in the order the grid walks them, padded to the
+    rectangle ``B * nq * n_blk`` (what a call of full slots owns): per item
+    the row ``b * nq + i`` of its (slot, query block), its page block, the
+    position of the query block's first token and the ``G`` pages the inputs
+    hold (past the last page the query block reaches, the page the input held
+    a block ago, so nothing is fetched; in block 0 the slot's first pages),
+    and ``[1]`` the count of real items. Compares and sums over ``[items,
+    rows]`` and ONE gather from the table, no search and no loop: the decode
+    shape's call sits in a conditional a layer, where nothing folds."""
+    B, n_pages = block_tables.shape
+    nq, n_blk = T // TQ, -(-n_pages // G)
+    own = latent_walk(base, T, TQ, G * page, n_blk).reshape(-1)             # [B * nq]
+    ends = jnp.cumsum(own)
+    starts = ends - own
+    r = jnp.arange(B * nq, dtype=jnp.int32)
+    at = jnp.repeat(base, nq) + (r % nq) * TQ
+    per_row = jnp.stack([                                                   # of an item's (slot, query block):
+        r, starts, at,                                                      # its row, its first item, its first query
+        jnp.minimum((at + (TQ - 1)) // page, n_pages - 1),                  # the last page it reaches
+        (r // nq) * n_pages,                                                # where its slot's table row starts
+    ])
+    s = jnp.arange(B * nq * n_blk, dtype=jnp.int32)
+    mine = (s[:, None] >= starts[None, :]) & (s[:, None] < ends[None, :])   # [items, rows]: one row an item
+    row, first, at, last, table = jnp.where(mine[None], per_row[:, None, :], 0).sum(-1).astype(jnp.int32)
+    blk = jnp.minimum(s - first, n_blk - 1)
+    e = blk[:, None] * G + jnp.arange(G, dtype=jnp.int32)[None, :]
+    e = jnp.clip(jnp.where(e > last[:, None], e - G, e), 0, last[:, None])
+    pages = block_tables.reshape(-1)[table[:, None] + e]
+    return row, blk, at, pages.reshape(-1), ends[-1:].astype(jnp.int32)
 
 
 def latent_paged_attention(
@@ -125,8 +179,10 @@ def latent_paged_attention(
     """``T``-token causal attention against a latent paged cache → ``[B, T,
     H, v_width]``; the tokens' own rows must already be in the pool
     (update-then-attend). ``T`` = 1 is the decode step (``base`` the slot's
-    cached length), more the chunk program. ``name`` is the kernel's name in a
-    trace (the roofline readers find it by that)."""
+    cached length: one query block a slot), more the chunk program. The grid
+    is as long as the call's own walk (:func:`_walk_items`): its bound is a
+    value of the call. ``name`` is the kernel's name in a trace (the roofline
+    readers find it by that)."""
     B, T, H, W = q.shape
     one, page = _pool_dims(pool, layer)
     if one != 1 or pool.shape[-1] != W:
@@ -136,30 +192,27 @@ def latent_paged_attention(
         )
     n_pages = block_tables.shape[1]
     TQ, G = latent_blocks(H, page, T, n_pages)
-    nq, n_blk, GP = T // TQ, -(-n_pages // G), G * page
-    base = jnp.asarray(base, jnp.int32)
-    last = jnp.minimum((base + (T - 1)) // page, n_pages - 1)
-    walk = _walked_table(block_tables, last[:, None], n_blk, G)
+    nq, n_blk = T // TQ, -(-n_pages // G)
+    row, blk, at, pages, n_items = _walk_items(
+        jnp.asarray(block_tables, jnp.int32), jnp.asarray(base, jnp.int32), T, TQ, G, page
+    )
 
     def page_spec(g):
-        def index_map(b, i, j, walk, at):
-            # the last block THIS query block reaches: past it nothing moves
-            jj = jax.lax.min(j, jax.lax.div(at[b] + (i + 1) * TQ - 1, GP))
-            return walk[b, jax.lax.min(jj, n_blk - 1) * G + g], 0, 0, 0
-
-        return _pool_block_spec((1, None, page, W), index_map, layer)
+        return _pool_block_spec(
+            (1, None, page, W), lambda s, row, blk, at, pages: (pages[s * G + g], 0, 0, 0), layer
+        )
 
     def qo_spec(width):
-        return pl.BlockSpec((1, 1, TQ * H, width), lambda b, i, j, walk, at: (b, i, 0, 0))
+        return pl.BlockSpec((1, TQ * H, width), lambda s, row, blk, at, pages: (row[s], 0, 0))
 
     kernel = functools.partial(
-        _latent_kernel, sm_scale=float(sm_scale), G=G, TQ=TQ, H=H, v_width=int(v_width)
+        _latent_kernel, sm_scale=float(sm_scale), G=G, TQ=TQ, H=H, v_width=int(v_width), n_blk=n_blk
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, nq, n_blk),
+            num_scalar_prefetch=4,
+            grid=(n_items[0],),
             in_specs=[qo_spec(W)] + [page_spec(g) for g in range(G)],
             out_specs=qo_spec(v_width),
             scratch_shapes=[
@@ -168,11 +221,11 @@ def latent_paged_attention(
                 pltpu.VMEM((TQ * H, v_width), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, nq, TQ * H, v_width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * nq, TQ * H, v_width), q.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=LATENT_VMEM_BYTES),
         name=name,
         interpret=interpret,
-    )(walk, base, q.reshape(B, nq, TQ * H, W), *([pool] * G))
+    )(row, blk, at, pages, q.reshape(B * nq, TQ * H, W), *([pool] * G))
     return out.reshape(B, T, H, v_width)
 
 

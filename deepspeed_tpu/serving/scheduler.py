@@ -72,6 +72,7 @@ import numpy as np
 
 from ..moe.expert_share import experts_streamed
 from ..ops.pallas import grouped_experts
+from ..ops.pallas.latent_attention import latent_walk_steps
 from ..telemetry import parts, spans
 from ..telemetry.registry import MetricsRegistry
 from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
@@ -520,6 +521,23 @@ class ServingEngine:
             "serving_attended_tokens_total",
             "context tokens attended, summed over active slots and decode steps",
         )
+        # a latent family's attention kernels walk the (slot, query block,
+        # page block) pairs a call owns: their quotient is how much of the
+        # old rectangular grid a call's lengths skip
+        self._c_walk_steps = m.counter(
+            "serving_latent_walk_steps_total",
+            "grid steps of the latent attention kernels' calls, one layer's: "
+            "the (slot, query block, page block) pairs the calls own, by the "
+            "kernel's name in a trace (0 where the jnp fallback runs)",
+            labelnames=("program",),
+        )
+        self._c_rect_steps = m.counter(
+            "serving_latent_rect_steps_total",
+            "what the same calls' rectangles take: slots x query blocks x the "
+            "table's page blocks (serving_paged_grid_steps' terms)",
+            labelnames=("program",),
+        )
+        self._latent_kernel = False   # set by _set_census_gauges: the programs call the latent kernels
         self._c_timeouts = m.counter(
             "serving_timeout_evictions_total",
             "requests evicted mid-flight by deadline",
@@ -1319,6 +1337,7 @@ class ServingEngine:
             self._g_relayout.set(relayout[name], program=name)
             self._g_temp_bytes.set(temp[name], program=name)
             self._g_grid_steps.set(steps[name], program=name)
+        self._latent_kernel = self.latent and any(steps.values())
         ds = self.decode_set
         kv_bytes = {
             "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
@@ -1702,6 +1721,10 @@ class ServingEngine:
                 )
                 self._c_slot_steps.inc(len(active))
                 self._c_attended.inc(attended)
+                self._count_latent_walk(
+                    "mla_paged_verify" if self.spec_enabled else "mla_paged_decode",
+                    self.table.seq_lens, self.spec_k + 1 if self.spec_enabled else 1,
+                )
                 drafts: dict = {}
                 # the AOT executable takes the numpy slot tables directly — a
                 # jnp.asarray wrapper here would dispatch four extra device ops
@@ -2269,6 +2292,19 @@ class ServingEngine:
         t = min(self.chunk_width, slot.request.prompt_len - slot.prefill_pos)
         return t, t * slot.prefill_pos + t * (t + 1) // 2
 
+    def _count_latent_walk(self, kernel: str, base, T: int = 1) -> None:
+        """One call of the latent attention kernel ``kernel`` (its name in a
+        trace) whose slots' ``T`` queries start at ``base``: the steps its
+        walk takes and the steps of its rectangle, one layer's (every cached
+        layer of the call walks the same)."""
+        if not self._latent_kernel:
+            return
+        walk, rect = latent_walk_steps(
+            base, self.family.n_head, self.page_size, T, self.pages_per_slot
+        )
+        self._c_walk_steps.inc(walk, program=kernel)
+        self._c_rect_steps.inc(rect, program=kernel)
+
     def _chunk_is_last(self, slot_i: int) -> bool:
         """Whether the next chunk of a PREFILLING slot is its prompt's last."""
         slot = self.slots[slot_i]
@@ -2325,6 +2361,10 @@ class ServingEngine:
         ) as sp:
             n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
             finals = int(rider is not None and self._chunk_is_last(rider))
+            for i in alone + ([rider] if rider is not None else []):
+                self._count_latent_walk(
+                    "mla_paged_chunk", [self.slots[i].prefill_pos], self.chunk_width
+                )
             moe = []   # (counts, tokens) of the prompts that finished here
             unwaited = []
             for i in alone:

@@ -959,20 +959,44 @@ class TestLatentPaged:
         rng = np.random.default_rng(seed)
         return jnp.asarray(rng.normal(size=(self.L, self.P, 1, self.page, self.W)), jnp.float32), rng
 
-    @pytest.mark.parametrize("B,T,n", [(3, 1, 6), (2, 8, 6), (1, 16, 9), (2, 64, 12)],
-                             ids=["decode", "verify-shape", "chunk", "chunk-two-query-blocks"])
-    def test_attention_kernel_equals_the_fallback(self, B, T, n, monkeypatch):
+    # (B, T, n, base or None for a draw, CHUNK_ROWS, keys a step or None for the module's)
+    @pytest.mark.parametrize("B,T,n,base,rows,keys", [
+        (3, 1, 6, None, None, None), (2, 8, 6, None, None, None), (1, 16, 9, None, None, None),
+        (2, 64, 12, None, 128, None),
+        # what the walk of owned (query block, page block) pairs can get wrong: 32 tokens x 4
+        # heads a step (two query blocks), 16 keys a step (two pages)
+        (1, 64, 12, [0], 128, 16),        # the diagonal alone: walks of 2 and 4 blocks
+        (1, 64, 12, [21], 128, 16),       # a context that ends inside a key block
+        (1, 64, 12, [32], 128, 16),       # the chunk's last token is the table's last row
+        (2, 64, 19, [0, 80], 128, 16),    # walks of 6 and 16 steps side by side
+        (3, 1, 6, [3, 40, 0], None, 16),  # decode: one own page, three blocks, one key
+    ], ids=["decode", "verify-shape", "chunk", "chunk-two-query-blocks", "chunk-base-0",
+            "chunk-base-inside-a-block", "chunk-ends-in-the-last-page", "two-slots-walks-differ",
+            "decode-one-own-page"])
+    def test_attention_kernel_equals_the_fallback(self, B, T, n, base, rows, keys, monkeypatch):
         from jax.experimental.pallas import tpu as pltpu
 
         from deepspeed_tpu.ops.attention import latent_paged_cached_attention
         from deepspeed_tpu.ops.pallas import latent_attention as la
 
-        if T == 64:
-            monkeypatch.setattr(la, "CHUNK_ROWS", 128)     # 32 tokens x 4 heads a step: two query blocks
+        if rows:
+            monkeypatch.setattr(la, "CHUNK_ROWS", rows)     # 32 tokens x 4 heads a step: two query blocks
             assert la.latent_blocks(self.H, self.page, T, n)[0] == 32
+        if keys:
+            monkeypatch.setattr(la, "CHUNK_KEYS", keys)
+            monkeypatch.setattr(la, "DECODE_KEYS", keys)
+            assert la.latent_blocks(self.H, self.page, T, n)[1] == keys // self.page
         pool, rng = self._pool()
         bt = jnp.asarray(rng.permutation(np.arange(1, self.P))[: B * n].reshape(B, n), jnp.int32)
-        base = jnp.asarray(rng.integers(0, n * self.page - T, B), jnp.int32)
+        base = jnp.asarray(rng.integers(0, n * self.page - T, B) if base is None else base, jnp.int32)
+        if keys:   # the walk's length, by hand: blocks 0 .. the one of each query block's last token
+            TQ = la.latent_blocks(self.H, self.page, T, n)[0]
+            want_steps = sum(
+                (int(b) + i * TQ + TQ - 1) // keys + 1 for b in np.asarray(base) for i in range(T // TQ)
+            )
+            assert la.latent_walk_steps(np.asarray(base), self.H, self.page, T, n) == (
+                want_steps, B * (T // TQ) * -(-n * self.page // keys)
+            )
         q = jnp.asarray(rng.normal(size=(B, T, self.H, self.W)), jnp.float32)
         want = latent_paged_cached_attention(q, pool, bt, base, self.VW, impl="jnp", sm_scale=0.2, layer=1)
         with pltpu.force_tpu_interpret_mode():

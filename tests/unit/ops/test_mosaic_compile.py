@@ -586,6 +586,40 @@ def test_latent_kernels_compile_for_v5e_at_the_served_shapes(one_chip, B, T):
         assert compiled.memory_analysis().temp_size_in_bytes < 1e6      # the pool is written in place
 
 
+@pytest.mark.parametrize("ctx,steps", [(6144, 432), (12288, 816), (23552, 1520)])
+def test_the_latent_chunk_kernels_grid_is_the_calls_own_walk(ctx, steps):
+    """At ``mistral4``'s chunk shape (1 024 queries in 32 query blocks, 512
+    keys a step, a table of 194 pages) the grid bound the wrapper hands the
+    kernel is the (query block, page block) pairs the call owns, ``ctx / 16 +
+    48``, where the rectangle was 32 x 49 = 1 568 steps at any context; the
+    host's rule for the counters reckons the same."""
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas import latent_attention as la
+
+    c = _ms4_config()
+    sv = c["serving"]
+    page, T, H = sv["page_size"], sv["prefill_chunk_tokens"], c["num_attention_heads"]
+    n = -(-(sv["max_prompt_len"] + sv["max_new_tokens"]) // page)
+    TQ, G = la.latent_blocks(H, page, T, n)
+    assert (TQ, G, n) == (32, 4, 194)
+    bt = jnp.arange(n, dtype=jnp.int32)[None]
+    row, blk, at, pages, n_items = la._walk_items(bt, jnp.asarray([ctx], jnp.int32), T, TQ, G, page)
+    assert int(n_items[0]) == steps == ctx // 16 + 48
+    assert la.latent_walk_steps([ctx], H, page, T, n) == (steps, 1568)
+    # the items: each query block's blocks in a row from 0, the pages the table's ...
+    row, blk, at = (np.asarray(x)[:steps] for x in (row, blk, at))
+    pages = np.asarray(pages)[: steps * G]
+    assert (np.diff(row) >= 0).all() and row[0] == 0 and row[-1] == T // TQ - 1
+    assert (blk[np.r_[True, np.diff(row) > 0]] == 0).all()
+    assert (blk[1:][np.diff(row) == 0] == blk[:-1][np.diff(row) == 0] + 1).all()
+    assert (at == ctx + row * TQ).all() and (blk == np.minimum(blk, (at + TQ - 1) // (G * page))).all()
+    # ... but past the last page the query block reaches the page an input held a block ago: no fetch
+    e, reach = blk[:, None] * G + np.arange(G), ((at + TQ - 1) // page)[:, None]
+    assert (pages.reshape(steps, G) == np.clip(np.where(e > reach, e - G, e), 0, reach)).all()
+    assert (e > reach).sum() == 48      # 1.5 pages a query block on average, of the chunk's own 8
+
+
 def test_a_latent_pool_is_row_major_only_with_whole_lane_tiles_a_row(one_chip, monkeypatch):
     """What ``pool_stored_shape``'s third case rests on: at 320 lanes the
     default layout moves the page index (page 16) or the page's row axis

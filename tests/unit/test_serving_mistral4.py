@@ -207,6 +207,42 @@ def test_spans_and_counters_count_latent_rows_and_expert_loads(engine, prompts):
     assert "latent=" in prog["kv_bytes"] and prog["kv_row_bytes"] == 24 * 4 and prog["moe_experts_held"] == 4
 
 
+def test_the_walk_counters_count_the_pairs_the_calls_own_beside_their_rectangles(engine, prompts, monkeypatch):
+    """``serving_latent_walk_steps_total`` against ``serving_latent_rect_steps_total``,
+    by the kernel's name: host arithmetic on the calls' lengths by the kernel's
+    own block rule. (Off the TPU the fallback attends; the test tells the
+    gauge's rule that the kernel is taken, which is all the counters ask.)"""
+    from deepspeed_tpu.ops import attention
+
+    real = attention.latent_attention_grid_steps
+    monkeypatch.setattr(attention, "latent_attention_grid_steps", lambda impl, *a, **k: real("pallas", *a, **k))
+    srv, reqs = _serve(engine, prompts[:4])
+    walk = lambda k: srv.metrics.counter("serving_latent_walk_steps_total", "", ("program",)).value(program=k)
+    rect = lambda k: srv.metrics.counter("serving_latent_rect_steps_total", "", ("program",)).value(program=k)
+    page, H, n = SERVING["page_size"], CFG["num_attention_heads"], srv.pages_per_slot
+    assert n == 13
+    # 4 heads on pages of 4 in a table of 13: both shapes hold 8 pages, 32 keys, a step; 2 blocks a table
+    one = lambda B, T: real("pallas", B, H, page, 128, 4, n, T)
+    assert (one(1, 8), one(3, 1)) == (2, 6)
+    # the chunk shape: a call of 8 queries from `start` owns blocks 0 .. (start + 7) // 32, at most both
+    long = [len(p) for p in prompts[:4] if len(p) > 8]
+    starts = [s for m_ in long for s in range(0, m_, 8)]
+    assert walk("mla_paged_chunk") == sum(min((s + 7) // 32, 1) + 1 for s in starts) == 9
+    assert rect("mla_paged_chunk") == len(starts) * one(1, 8) == 16
+    # the decode shape: a request of n prompt tokens decodes at lengths n .. n + 10 (its first token
+    # is its prefill's), each owning blocks 0 .. length // 32; an idle row owns its one masked step
+    steps = srv.metrics.counter("serving_decode_steps_total", "").value()
+    slot_steps = srv.metrics.counter("serving_decode_slot_steps_total", "").value()
+    own = sum(min(m_ // 32, 1) + 1 for p in prompts[:4] for m_ in range(len(p), len(p) + 11))
+    assert slot_steps == 4 * 11 and own == 55
+    assert walk("mla_paged_decode") == own + (3 * steps - slot_steps)
+    assert rect("mla_paged_decode") == steps * one(3, 1)
+    # ... and nothing is counted where the programs hold no latent kernel
+    monkeypatch.undo()
+    srv, _ = _serve(engine, prompts[:2])
+    assert srv.metrics.counter("serving_latent_rect_steps_total", "", ("program",)).value(program="mla_paged_decode") == 0
+
+
 def test_the_verify_step_emits_the_decode_steps_stream(engine, served, prompts):
     _, plain = served
     srv, spec = _serve(engine, prompts, speculative={"enabled": True, "k": 3, "ngram": 2})
