@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..ops.attention import traced_flash_plan
@@ -69,6 +70,15 @@ class TrainState(NamedTuple):
     # dp — each rank's shard is its rank-local quantization error, fed back
     # into the next step's reduction. () when compression is off.
     comm_error: PyTree = ()
+    # the masters in the compute dtype, as the forward reads them: written by
+    # the update's own fusion, so a step reads each master once (the cast at
+    # the step's start was a pass of its own over them). One array for each
+    # leaf of ``params`` that ``DeepSpeedEngine._carried`` lists, in its
+    # order. Follows ``params`` wherever the engine is handed a state
+    # (``DeepSpeedEngine.state``) and is never checkpointed (``_persistent``).
+    # () where nothing is carried: float32 compute, and the paths that run
+    # several programs a step.
+    compute_params: Tuple = ()
 
 
 def _tree_select(pred, a: PyTree, b: PyTree) -> PyTree:
@@ -85,12 +95,39 @@ def _cast_params(params: PyTree, dtype) -> PyTree:
     return jax.tree.map(cast, params)
 
 
+def _cast_leaves(params: PyTree, dtype, which: Tuple[int, ...]) -> Tuple:
+    """The leaves of ``params`` that ``which`` lists, cast as ``_cast_params`` casts."""
+    leaves = jax.tree.leaves(params)
+    return tuple(_cast_params(leaves[i], dtype) for i in which)
+
+
 def global_norm(tree: PyTree) -> jnp.ndarray:
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32))) for x in jax.tree.leaves(tree)]
     return jnp.sqrt(jnp.sum(jnp.stack(leaves))) if leaves else jnp.float32(0.0)
 
 
+def _persistent(state: TrainState) -> TrainState:
+    """A state, or its shardings, as a checkpoint or a snapshot holds it:
+    without the compute copy, which the masters give back."""
+    return state._replace(compute_params=())
+
+
 class DeepSpeedEngine:
+    _cast_masters = None   # the jitted cast where the step carries the compute copy (_carry_compute_copy)
+    _carried: Tuple[int, ...] = ()   # the leaves of the masters (jax.tree.leaves order) whose copy is carried
+
+    @property
+    def state(self) -> TrainState:
+        return self._state
+
+    @state.setter
+    def state(self, value: TrainState) -> None:
+        # whoever hands the engine a state (a checkpoint, a rollback, a test)
+        # hands it masters: the compute copy is made from them again
+        if self._cast_masters is not None:
+            value = value._replace(compute_params=self._cast_masters(value.params))
+        self._state = value
+
     def __init__(
         self,
         model: ModuleSpec,
@@ -581,13 +618,72 @@ class DeepSpeedEngine:
             )
             self._train_step = self._offload_dispatch
         else:
+            if not self._compress_grads and self.compute_dtype != jnp.float32:
+                self._carry_compute_copy()
             self._train_step = jax.jit(
                 self._step_builder(),
                 donate_argnums=donate,
                 out_shardings=(self.state_shardings, None),
+                compiler_options=self._step_compiler_options(),
             )
             self._train_step_folds_rng = True
         self._eval_step = jax.jit(self._make_eval_step())
+
+    def _step_compiler_options(self) -> Optional[Dict[str, str]]:
+        """The TPU compiler orders a module by whichever of three schedulers
+        (list, depth-first, post-order) estimates the lowest peak memory, and
+        here the estimates lie within 0.01 GiB of 9.8: what the optimizer holds
+        after the loops decides an order that the BACKWARD loop's body is then
+        written in too. Depth-first, the body reads a layer's incoming
+        gradient for the last time before the first norm's backward writes the
+        outgoing one, which then takes its place in fast memory; in list order
+        a weight-gradient product reads it later, so the outgoing gradient is
+        written to HBM and copied (0.39 -> 0.63 ms a layer at GPT-2-XL's
+        width, 4 ms of a 16-layer step; PERF.md section 6, PR 45). The step
+        asks for the order that the step's speed rests on, and is no longer
+        moved by what a later change leaves live after the loops. The flag is
+        libtpu's: nothing is passed where the mesh is not a TPU's."""
+        if self.mesh.devices.flat[0].platform != "tpu":
+            return None
+        return {"xla_memory_scheduler": "dfs"}
+
+    def _carry_compute_copy(self) -> None:
+        """From here on the state holds the compute-dtype copy
+        (``TrainState.compute_params``) of the masters' floating leaves that
+        the update writes where, and as, the forward reads them: the update's
+        own fusion then writes the copy, the step reads it where it would
+        cast, and no pass re-reads the masters. Two kinds of leaf are cast at
+        the step's start as before. A leaf that is replicated over moments
+        that are sharded (ZeRO 1 and 2 on a ``dp`` mesh) is gathered after
+        its update: a carried copy would be gathered beside it. A table the
+        device holds column-major (``[50257, 1600]`` on a TPU, which puts
+        last a dimension that fills its lanes) is read by the forward through
+        a re-laid copy, because a gather wants rows: its carried copy would be
+        re-laid every step and kept beside that one, the same traffic and
+        more memory, where the cast re-lays in the pass it makes anyway."""
+        import functools
+
+        masters = jax.tree.leaves(self.state.params)
+        placed = jax.tree.leaves(self.param_shardings)
+        turned = jax.tree.leaves(self._state_layouts(), is_leaf=lambda x: x is None)
+        moments: Dict[Tuple[int, ...], list] = {}
+        for x in jax.tree.leaves(self.state.opt_state):
+            moments.setdefault(tuple(x.shape), []).append(x.sharding)
+        self._carried = tuple(
+            i for i, (x, sh) in enumerate(zip(masters, placed))
+            if jnp.issubdtype(x.dtype, jnp.floating)
+            and all(sh.is_equivalent_to(m, x.ndim) for m in moments.get(tuple(x.shape), ()))
+            and not (x.ndim == 2 and turned[i] is not None)
+        )
+        if not self._carried:
+            return
+        shardings = tuple(placed[i] for i in self._carried)
+        self._cast_masters = jax.jit(
+            functools.partial(_cast_leaves, dtype=self.compute_dtype, which=self._carried),
+            out_shardings=shardings,
+        )
+        self.state_shardings = self.state_shardings._replace(compute_params=shardings)
+        self.state = self._state
 
     def _step_builder(self):
         """The (state, batch, rng) -> (state, metrics) step function for the
@@ -1108,6 +1204,17 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # step construction
     # ------------------------------------------------------------------
+    def _state_layouts(self) -> PyTree:
+        """For each leaf of the masters, the order of its dimensions as the
+        device holds it (``Layout(major_to_minor)``, tiling left to the
+        compiler) where that is not row-major, else None. Read from the arrays
+        the state holds: what the compiled step is handed and must hand back."""
+        def of(x):
+            order = tuple(x.format.layout.major_to_minor)
+            return None if order == tuple(range(x.ndim)) else Layout(major_to_minor=order)
+
+        return jax.tree.map(of, self.state.params)
+
     def _make_train_step(self):
         model = self.module
         tx = self.optimizer
@@ -1130,6 +1237,8 @@ class DeepSpeedEngine:
                 "mesh has a pp axis but the model provides no pipeline_loss_fn"
             )
         mesh = self.mesh
+        grad_layouts = self._state_layouts()
+        carried = self._carried
 
         # --- bucketed grad reduce (comm_compression.bucketing): accumulate
         # into size-capped flat buckets instead of per-leaf buffers, so the
@@ -1221,8 +1330,15 @@ class DeepSpeedEngine:
                 * jnp.exp(-pld_gamma * state.global_step.astype(jnp.float32))
                 + pld_theta0
             ) if use_pld else None
-            with parts.part("optim"):   # the masters' cast to the compute type
+            # the masters' cast to the compute type, but for the leaves whose
+            # copy the last update wrote
+            with parts.part("optim"):
                 cparams = _cast_params(state.params, compute_dtype)
+                if state.compute_params:
+                    leaves, treedef = jax.tree.flatten(cparams)
+                    for i, copy in zip(carried, state.compute_params):
+                        leaves[i] = copy
+                    cparams = treedef.unflatten(leaves)
 
             if pipeline_mode:
                 # pipeline path: all gas microbatches flow through the 1F1B/
@@ -1246,9 +1362,10 @@ class DeepSpeedEngine:
                 if bucketing:
                     grads = from_buckets(constrain_buckets(to_buckets(grads)))
                 else:
-                    grads = jax.lax.with_sharding_constraint(
-                        jax.tree.map(lambda g: g.astype(acc_dtype), grads), grad_shardings
-                    )
+                    # nothing is accumulated here, so the gradient stays in the type
+                    # the backward wrote it in: the norm and the update upcast it
+                    # inside their fusions (the same float32, bit for bit)
+                    grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
                 loss_sum = loss.astype(jnp.float32)
             elif bucketing:
 
@@ -1308,6 +1425,19 @@ class DeepSpeedEngine:
                 if predivide and predivide_factor != 1.0 and not pipeline_mode:
                     grads = jax.tree.map(lambda g: g * predivide_factor, grads)
 
+                # a leaf the device holds in another order than row-major (a TPU puts a
+                # dimension that fills its 128 lanes last: [50257, 1600] lies column-major)
+                # gets its gradient in that order: ONE copy of the gradient, where the
+                # compiler would re-lay masters and moments to the gradient's order and back
+                grads = jax.tree.map(
+                    lambda g, lay: g if lay is None else with_layout_constraint(g, lay),
+                    grads, grad_layouts, is_leaf=lambda x: x is None,
+                )
+
+                # without fp16 `overflow` is a constant and the compiler folds this away (no
+                # pass in the compiled step; tests/unit/test_optim_step.py counts); it stays
+                # in the trace because a CPU draws its fusions around it, and the last bit of
+                # the norm's sum moves with them
                 overflow = ls.has_inf_or_nan(grads) if fp16 else jnp.bool_(False)
                 grads = jax.tree.map(lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
 
@@ -1319,9 +1449,10 @@ class DeepSpeedEngine:
                 updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
                 new_params = optax.apply_updates(state.params, updates)
 
-                # predicated skip-on-overflow (fp16/fused_optimizer.py step semantics)
-                new_params = _tree_select(~overflow, new_params, state.params)
-                new_opt_state = _tree_select(~overflow, new_opt_state, state.opt_state)
+                if fp16:
+                    # predicated skip-on-overflow (fp16/fused_optimizer.py step semantics)
+                    new_params = _tree_select(~overflow, new_params, state.params)
+                    new_opt_state = _tree_select(~overflow, new_opt_state, state.opt_state)
 
                 new_scale_state = ls.update(
                     state.loss_scale, overflow, dynamic=dynamic,
@@ -1333,6 +1464,10 @@ class DeepSpeedEngine:
                     loss_scale=new_scale_state,
                     global_step=state.global_step + jnp.where(overflow, 0, 1),
                     skipped_steps=state.skipped_steps + jnp.where(overflow, 1, 0),
+                    # the next step's compute copy, out of the update's own fusion
+                    compute_params=_cast_leaves(
+                        new_params, compute_dtype, carried if state.compute_params else ()
+                    ),
                 )
             metrics = {
                 "loss": loss_sum / gas,
@@ -1730,11 +1865,13 @@ class DeepSpeedEngine:
             ) as programs_phase:
                 if first_call:
                     traced_flash_plan(reset=True)
-                self.state, metrics = self._train_step(self.state, device_batch, step_rng)
+                # (past the setter: the step's own output holds the compute copy it wrote)
+                self._state, metrics = self._train_step(self._state, device_batch, step_rng)
                 if first_call:
                     programs_phase.set(
                         flash_plan=self._set_flash_plan_gauges(),
                         collectives=self._set_collective_gauges(device_batch),
+                        optim=self._set_optim_gauges(),
                     )
                     self._register_parts()
             self.global_steps += 1
@@ -1801,7 +1938,7 @@ class DeepSpeedEngine:
                     # judged clean: refresh the last-known-good host snapshot
                     # (device→host copy only — tput_timer.stop already blocked
                     # on this step's outputs)
-                    self._rollback.snapshot(self.state, self.global_steps)
+                    self._rollback.snapshot(_persistent(self.state), self.global_steps)
             if sampled:
                 self._telemetry_step(
                     tel, metrics, sp_batch,
@@ -1951,7 +2088,7 @@ class DeepSpeedEngine:
             )
             return False
         host_state, steps = rb.restore()
-        self.state = jax.device_put(host_state, self.state_shardings)
+        self.state = jax.device_put(host_state, _persistent(self.state_shardings))
         self.global_steps = steps
         if isinstance(metrics, dict):
             metrics["rolled_back"] = True
@@ -2240,6 +2377,41 @@ class DeepSpeedEngine:
         return " ".join(
             f"{k}={counts[(k, 'weight')]}w+{counts[(k, 'activation')]}a" for k in COLLECTIVE_KINDS
         )
+
+    def _set_optim_gauges(self) -> str:
+        """What the optimizer of the step just compiled moves
+        (``parts.optim_traffic`` over its text): the bytes that the
+        instructions of part ``optim`` read and write on one device in one
+        step, and how many of them write a whole leaf of the masters. An
+        update that reads and writes each state leaf once gives each leaf one
+        such instruction and, for float32 state over a 16-bit gradient and
+        compute copy, 14 bytes read and 14 written a parameter, with the
+        norm's read of the gradient besides; a cast of the masters in a pass
+        of its own, a float32 copy of the gradient, a leaf re-laid for the
+        update and back each show as bytes and as passes. As registry gauges
+        and (returned) as the ``optim`` attr of the ``ds.init.programs``
+        phase: ``<GB read>r+<GB written>w/<passes>p``. All zero, and nothing
+        read, on the paths that run several programs a step."""
+        traffic = parts.OptimTraffic(0, 0, 0, ())
+        if hasattr(self._train_step, "lower"):
+            leaves = [x.sharding.shard_shape(x.shape) for x in jax.tree.leaves(self.state.params)]
+            traffic = parts.optim_traffic(self._compiled_step().as_text(), leaves)
+        if self.telemetry is not None:
+            reg = self.telemetry.registry
+            b = reg.gauge(
+                "train_step_optim_bytes",
+                "bytes that the instructions of part optim of the compiled "
+                "train step read and write on one device in one step",
+                labelnames=("direction",),
+            )
+            b.set(traffic.read, direction="read")
+            b.set(traffic.written, direction="written")
+            reg.gauge(
+                "train_step_optim_passes",
+                "instructions of part optim of the compiled train step whose "
+                "result is shaped like a whole leaf of the masters",
+            ).set(traffic.passes)
+        return f"{traffic.read / 1e9:.2f}r+{traffic.written / 1e9:.2f}w/{traffic.passes}p"
 
     def _jit_step_programs(self) -> int:
         """Invalidation key for program-derived caches: the jitted step's
@@ -2570,7 +2742,7 @@ class DeepSpeedEngine:
         tag = tag or f"global_step{self.get_global_step()}"
         self._checkpoint_tag_validation(tag)
         path = save_train_state(
-            save_dir, tag, self.state,
+            save_dir, tag, _persistent(self.state),
             client_state={**(client_state or {}), "global_steps": self.global_steps},
             save_latest=save_latest,
             async_save=self.config.checkpoint.async_save,
@@ -2688,7 +2860,7 @@ class DeepSpeedEngine:
         # the snapshot is the only step-path cost: the write happens on the
         # writer thread (resilience.async_checkpoint; blocking overrides)
         arrays = snapshot_to_host(
-            self.state, extra={"__rng__": np.asarray(self._rng)}
+            _persistent(self.state), extra={"__rng__": np.asarray(self._rng)}
         )
         client = {
             **(client_state or {}),
@@ -2723,7 +2895,7 @@ class DeepSpeedEngine:
         t_ckpt0 = time.perf_counter()
         registry = self.telemetry.registry if self.telemetry is not None else None
         state, client_state, tag_used, extras = load_resilient_state(
-            load_dir, tag, self.state, self.state_shardings,
+            load_dir, tag, _persistent(self.state), _persistent(self.state_shardings),
             load_optimizer_states=load_optimizer_states,
             registry=registry,
         )
@@ -2814,7 +2986,7 @@ class DeepSpeedEngine:
         t_ckpt0 = time.perf_counter()
         try:
             state, client_state = load_train_state(
-                load_dir, tag, self.state, self.state_shardings,
+                load_dir, tag, _persistent(self.state), _persistent(self.state_shardings),
                 load_optimizer_states=load_optimizer_states,
             )
         except Exception as first_err:
@@ -2853,8 +3025,8 @@ class DeepSpeedEngine:
         from ..checkpoint.engine import load_train_state
 
         if self.state.comm_error != ():
-            template = self.state._replace(comm_error=())
-            shardings = self.state_shardings._replace(comm_error=())
+            template = _persistent(self.state)._replace(comm_error=())
+            shardings = _persistent(self.state_shardings)._replace(comm_error=())
             keep = self.state.comm_error
             note = (
                 "checkpoint has no comm_error residuals (saved without "
@@ -2862,13 +3034,13 @@ class DeepSpeedEngine:
             )
         else:
             world = self.dp_world_size
-            template = self.state._replace(
+            template = _persistent(self.state)._replace(
                 comm_error=jax.tree.map(
                     lambda p: jax.ShapeDtypeStruct((world,) + tuple(p.shape), jnp.float32),
                     self.state.params,
                 )
             )
-            shardings = self.state_shardings._replace(
+            shardings = _persistent(self.state_shardings)._replace(
                 comm_error=self.policy.residual_shardings(self.state.params)
             )
             keep = ()

@@ -33,7 +33,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
-from .introspect import OP_NAME as _OP_NAME, instructions_by_computation
+from .introspect import DTYPE_BYTES, OP_NAME as _OP_NAME, instructions_by_computation, shape_bytes
 
 logger = logging.getLogger(__name__)
 
@@ -324,6 +324,62 @@ def table_of(hlo_text: str) -> Dict[str, Entry]:
         dot, found = inside(i.calls) if i.calls else (False, frozenset())
         table[name] = Entry(*known[name], i.own_dot or dot, tuple(sorted(found)), shown[name], i.source)
     return table
+
+
+# -- the optimizer's passes --------------------------------------------------
+
+class OptimTraffic(NamedTuple):
+    """What the instructions of part ``optim`` move, from one program's text."""
+    read: int                       # bytes of their operands
+    written: int                    # bytes of their results
+    passes: int                     # of them, those with a result shaped like a whole leaf of the masters
+    leaf_results: Tuple[Tuple[str, str, Tuple[int, ...]], ...]   # (instruction, dtype, dims) of each such result
+
+
+# no traffic of their own: views, what carries other computations, and the end of an async pair
+_NO_TRAFFIC = frozenset(("bitcast", "get-tuple-element", "tuple", "parameter", "constant", "after-all",
+                         "partition-id", "replica-id")) | _CARRIES
+
+
+def optim_traffic(hlo_text: str, leaf_shapes) -> OptimTraffic:
+    """The bytes that the instructions of part ``optim`` read and write in ONE
+    run of an optimised HLO module, and how many of them write a whole leaf:
+    a result of exactly a leaf's dimensions (``leaf_shapes``: the shapes of
+    the masters' leaves as ONE device holds them). Counted are the
+    instructions that run as operations of their own (not those inside a
+    fused computation); an operand counts whole, once an instruction, and an
+    asynchronous copy's bytes count though it is no pass (so a leaf copied
+    into fast memory ahead of the fusion that reads it counts twice). An
+    update that reads each master and moment once and writes it once gives
+    one such instruction a leaf; a cast of the masters, a gradient made
+    float32, a leaf re-laid for the update and back each add one. Shapes and
+    types only: the same on any backend, no trace needed."""
+    comps = instructions_by_computation(hlo_text)
+    by_name = {ni.name: ni for parsed in comps.values() for ni in parsed}
+    fused = {c for parsed in comps.values() for ni in parsed if ni.op == "fusion"
+             for c in _CALLS.findall(ni.attrs)}
+    leaves = {tuple(int(d) for d in shape) for shape in leaf_shapes}
+    table = table_of(hlo_text)
+    read = written = 0
+    leaf_results = []
+    for comp, parsed in comps.items():
+        if comp in fused:
+            continue
+        for ni in parsed:
+            if table[ni.name].part != "optim" or ni.op in _NO_TRAFFIC or ni.op.endswith("-done"):
+                continue
+            shapes = ni.result_shapes
+            prefetch = ni.op.endswith("-start")
+            if prefetch:   # (operand alias, result, context): the result alone is written
+                shapes = shapes[1:2]
+            read += sum(by_name[o].result_bytes for o in set(ni.operands) if o in by_name)
+            written += sum(shape_bytes(dt, dd) for dt, dd in shapes if dt in DTYPE_BYTES)
+            for dt, dd in () if prefetch else shapes:   # a copy into fast memory ahead of its reader is no pass
+                dims = tuple(int(d) for d in dd.split(",") if d)
+                if dims in leaves:
+                    leaf_results.append((ni.name, dt, dims))
+    passes = len({name for name, _, _ in leaf_results})
+    return OptimTraffic(read, written, passes, tuple(leaf_results))
 
 
 # -- the registry ------------------------------------------------------------

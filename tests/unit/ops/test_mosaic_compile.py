@@ -551,6 +551,32 @@ def test_scopes_rename_no_kernel_and_every_kernel_has_a_part(one_chip, monkeypat
     assert all(e.part for e in dots) and {e.part for e in dots} >= {"attn.qkv", "attn.out", "mlp", "head"}
 
 
+def test_the_v5e_compiler_takes_the_schedule_the_train_step_asks_for(topo, one_chip):
+    """ISSUE 45. The train step names the module scheduler whose order keeps
+    the backward loop's carried gradient in place (``DeepSpeedEngine.
+    _step_compiler_options``): the option is libtpu's own, so the compiler of
+    the described v5e has to know it under that name, and a CPU mesh passes
+    nothing."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+
+    def options(device):
+        mesh = SimpleNamespace(devices=np.array([device], dtype=object))
+        return DeepSpeedEngine._step_compiler_options(SimpleNamespace(mesh=mesh))
+
+    assert options(jax.devices("cpu")[0]) is None
+    asked = options(topo.devices[0])
+    assert asked == {"xla_memory_scheduler": "dfs"}
+    x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=one_chip)
+    lowered = jax.jit(lambda a: (a @ a).sum()).lower(x)
+    assert lowered.compile(compiler_options=asked).as_text()
+    with pytest.raises(Exception, match="xla_memory_scheduler_"):
+        lowered.compile(compiler_options={"xla_memory_scheduler_": "dfs"})
+
+
 # -- the latent (MLA) family: kernels, pool layout and programs at the served size --
 
 def _ms4_config():

@@ -6,6 +6,7 @@ import collections
 import glob
 import json
 import os
+import re
 import statistics
 
 import jax
@@ -281,8 +282,11 @@ def test_leaves_tile_train_batch_and_the_first_call_is_a_programs_phase():
     assert [p[3] for p in phases if p[0] == "ds.init.params"] == [{"what": "train_state"}]
     progs = [p for p in phases if p[0] == "ds.init.programs"]
     # on the CPU the jnp attention runs: no flash kernel, so no plan (ISSUE 33); on one dp
-    # rank the step's text is not read for its collectives: all zero (ISSUE 40)
-    assert len(progs) == 1 and progs[0][3] == {
+    # rank the step's text is not read for its collectives: all zero (ISSUE 40); what the optimizer's
+    # instructions read and write, and how many write a whole leaf, is read on any mesh (ISSUE 45)
+    attrs = dict(progs[0][3])
+    assert len(progs) == 1 and re.fullmatch(r"\d+\.\d\dr\+\d+\.\d\dw/[1-9]\d*p", attrs.pop("optim"))
+    assert attrs == {
         "what": "train_step", "flash_plan": "bq=0 bk=0 masked=0 plain=0",
         "collectives": "all_gather=0w+0a reduce_scatter=0w+0a all_reduce=0w+0a all_to_all=0w+0a"}
     # the step's compilation happened inside it, and inside the first step's dispatch leaf
