@@ -4,9 +4,8 @@ The reference DeepSpeed spends most of its scaling budget on gradient
 communication (ZeRO's reduce-scatter / allreduce over NCCL). EQuARX
 (arXiv:2506.17615) shows a quantized allreduce inside XLA recovers 1.4-2x
 collective throughput with negligible quality loss; this module is that idea
-as a first-class layer over ``jax.lax`` collectives, generalizing the 1-bit
-``runtime/comm/compressed.py`` precedent from sign-bits to block-scaled
-int8 / fp8 (e4m3):
+as a first-class layer over ``jax.lax`` collectives, block-scaled int8 / fp8
+(e4m3):
 
     quantize per block -> all_to_all low-precision -> dequantize+reduce
     -> requantize -> all_gather low-precision -> dequantize
@@ -19,7 +18,7 @@ fp32, recompresses, and broadcasts the result. Wire volume per collective is
 Error feedback: quantization error is *returned to the caller* so it can be
 carried into the next step (per-leaf residuals in ``TrainState.comm_error``)
 — compensated compression preserves convergence where plain rounding biases
-it (1-bit Adam lineage; same EF algebra, milder quantizer).
+it (the error-feedback algebra of 1-bit Adam under a milder quantizer).
 
 Bucketing: :func:`build_bucket_plan` packs gradient leaves into size-capped
 flat buckets (``zero_optimization.reduce_bucket_size``), each reduced by an

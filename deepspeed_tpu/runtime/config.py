@@ -247,16 +247,6 @@ class MonitorSubConfig(DSConfigModel):
 
 
 @dataclass
-class FlopsProfilerConfig(DSConfigModel):
-    enabled: bool = False
-    profile_step: int = 1
-    module_depth: int = -1
-    top_modules: int = 1
-    detailed: bool = True
-    output_file: Optional[str] = None
-
-
-@dataclass
 class AIOConfig(DSConfigModel):
     """aio section (reference swap_tensor/aio_config.py).
 
@@ -1456,7 +1446,6 @@ class DeepSpeedConfig(DSConfigModel):
     tensorboard: MonitorSubConfig = field(default_factory=MonitorSubConfig)
     wandb: MonitorSubConfig = field(default_factory=MonitorSubConfig)
     csv_monitor: MonitorSubConfig = field(default_factory=MonitorSubConfig)
-    flops_profiler: FlopsProfilerConfig = field(default_factory=FlopsProfilerConfig)
     aio: AIOConfig = field(default_factory=AIOConfig)
     checkpoint: CheckpointConfig = field(default_factory=CheckpointConfig)
     elasticity: ElasticityConfig = field(default_factory=ElasticityConfig)

@@ -194,33 +194,11 @@ class DeepSpeedEngine:
                 fallback_lr=base_lr,
             )
         self.lr_schedule = lr_schedule
-        # 1-bit family needs explicit collectives (shard_map path below);
-        # everything else is a plain optax transform under pjit
-        opt_name = (opt_cfg.type if opt_cfg else "Adam").lower()
-        from .fp16.onebit import ONEBIT_OPTIMIZER_NAMES
-
-        self.onebit = opt_name in ONEBIT_OPTIMIZER_NAMES
-        if self.onebit:
-            if config.fp16.enabled:
-                raise ValueError(
-                    "1-bit optimizers do not support fp16 dynamic loss scaling "
-                    "(reference restriction); use bf16"
-                )
-            if zcfg.stage > 0:
-                raise ValueError(
-                    "1-bit optimizers require ZeRO stage 0 (reference: 1-bit "
-                    "Adam is incompatible with ZeRO) — their state is a "
-                    "replicated flat buffer"
-                )
-            if self.tp_world_size > 1 or self.sp_world_size > 1 or mesh_axis_size(mesh, "pp") > 1:
-                raise ValueError("1-bit optimizers support a dp-only mesh")
-            self.optimizer = self._build_onebit_optimizer(opt_name, opt_cfg, lr_schedule)
-        else:
-            self.optimizer = build_optimizer(
-                opt_cfg.type if opt_cfg else "Adam",
-                opt_cfg.params if opt_cfg else {"lr": base_lr},
-                learning_rate=lr_schedule,
-            )
+        self.optimizer = build_optimizer(
+            opt_cfg.type if opt_cfg else "Adam",
+            opt_cfg.params if opt_cfg else {"lr": base_lr},
+            learning_rate=lr_schedule,
+        )
 
         # --- compressed grad collectives + bucketed reduce (comm_compression)
         cc = config.comm_compression
@@ -262,11 +240,6 @@ class DeepSpeedEngine:
                     + ("dp=1 on this mesh" if self.dp_world_size <= 1 else "'dp' is not in comm_compression.axes")
                 )
         if self._compress_grads:
-            if self.onebit:
-                raise ValueError(
-                    "comm_compression cannot combine with 1-bit optimizers — "
-                    "they carry their own compressed-allreduce backend"
-                )
             if config.fp16.enabled:
                 raise ValueError(
                     "comm_compression does not support fp16 dynamic loss "
@@ -281,8 +254,7 @@ class DeepSpeedEngine:
             ):
                 raise ValueError(
                     "comm_compression supports a dp-only mesh (the grad "
-                    "reduction runs under shard_map over dp, like the 1-bit "
-                    "optimizer path)"
+                    "reduction runs under shard_map over dp)"
                 )
             if zcfg.offload_param.device in ("cpu", "nvme") or zcfg.offload_optimizer.device in ("cpu", "nvme", "hybrid"):
                 raise ValueError(
@@ -293,9 +265,7 @@ class DeepSpeedEngine:
         compile_stats.listen()  # every compilation from here on is a named phase
         # --- ZeRO-Infinity parameter tier (offload_param; stage3.py:465 analog)
         offp = zcfg.offload_param
-        self.param_offload_enabled = (
-            offp.device in ("cpu", "nvme") and not self.onebit
-        )
+        self.param_offload_enabled = offp.device in ("cpu", "nvme")
         if self.param_offload_enabled:
             # params never materialize on device: blocks stream host/NVMe ->
             # HBM per layer (runtime/zero/infinity.py). Everything below that
@@ -367,13 +337,13 @@ class DeepSpeedEngine:
                     "snapshot)"
                 )
             if not self._train_step_folds_rng:
-                # host-driven paths (offload/onebit/infinity) keep state the
+                # host-driven paths (offload/infinity) keep state the
                 # snapshot can't see (host optimizer tiers) and split the
                 # RNG per call — a restored snapshot would be inconsistent
                 # and the replayed steps would draw different keys
                 raise ValueError(
                     "telemetry.watchdog.policy='rollback' supports the "
-                    "standard jitted train step only (not offload / 1-bit / "
+                    "standard jitted train step only (not offload / "
                     "infinity engines)"
                 )
             from ..resilience.recovery import RollbackManager
@@ -510,12 +480,8 @@ class DeepSpeedEngine:
         # gpt2-xl; seen as a ResourceExhausted on the chip in round 4).
         # offload_enabled is decided HERE, once, and reused by the tier setup
         # below.
-        self.offload_enabled = (
-            zcfg.offload_optimizer.device in ("cpu", "nvme") and not self.onebit
-        )
-        if self.onebit:
-            opt_state, self.opt_shardings = self._init_onebit_opt_state(params)
-        elif self.offload_enabled:
+        self.offload_enabled = zcfg.offload_optimizer.device in ("cpu", "nvme")
+        if self.offload_enabled:
             opt_state, self.opt_shardings = (), ()
         else:
             abstract_opt = jax.eval_shape(self.optimizer.init, abstract_params)
@@ -525,7 +491,7 @@ class DeepSpeedEngine:
         # --- error-feedback residuals of the compressed grad collectives:
         # one [dp, ...] fp32 buffer per param leaf, sharded over dp (each
         # rank's shard is its rank-local quantization error — replicating
-        # divergent buffers would be UB, see _init_onebit_opt_state). The
+        # divergent buffers would be UB). The
         # jitted sharded-out zeros create each shard on its own device.
         # error_feedback=false keeps comm_error=() — no grad-sized HBM
         # buffer is allocated or carried for a feature that is off.
@@ -598,10 +564,7 @@ class DeepSpeedEngine:
         # --- compiled steps
         donate = (0,) if config.tpu.donate_state else ()
         self._train_step_folds_rng = False
-        if self.onebit:
-            self._onebit_step_cache: Dict[Tuple, Callable] = {}
-            self._train_step = self._onebit_dispatch
-        elif self.offload_enabled:
+        if self.offload_enabled:
             self._grad_step = jax.jit(
                 self._make_grad_step(),
                 out_shardings=(None, self.grad_shardings, None, None, None),
@@ -705,14 +668,14 @@ class DeepSpeedEngine:
         # --- progressive layer drop (reference progressive_layer_drop.py)
         self.progressive_layer_drop = None
         if config.progressive_layer_drop.enabled and (
-            self.onebit or self.offload_enabled or self.param_offload_enabled
+            self.offload_enabled or self.param_offload_enabled
             or self._compress_grads
         ):
             # only _make_train_step threads theta into the model; failing loud
             # beats a schedule that decays while no layer ever drops
             raise ValueError(
                 "progressive_layer_drop is only supported on the standard "
-                "device training path (not 1-bit / offload / infinity engines)"
+                "device training path (not offload / infinity engines)"
             )
         if config.progressive_layer_drop.enabled:
             from .progressive_layer_drop import ProgressiveLayerDrop
@@ -780,221 +743,6 @@ class DeepSpeedEngine:
         from ..telemetry import device_hbm_stats
 
         return device_hbm_stats()
-
-    # ------------------------------------------------------------------
-    # 1-bit optimizer path (explicit compressed collectives via shard_map)
-    # ------------------------------------------------------------------
-    def _init_onebit_opt_state(self, params):
-        """Init 1-bit optimizer state with rank-local buffers stored per-rank.
-
-        Error-feedback buffers (and ZeroOneAdam's momentum between syncs)
-        legitimately differ across dp ranks. Claiming them replicated through
-        ``shard_map(out_specs=P())`` is undefined behaviour: any reshard,
-        donation, or checkpoint round-trip silently collapses all ranks to
-        device 0's values, corrupting the compensated compression. Instead
-        they get a leading [dp] axis sharded P('dp'): each rank's shard IS
-        its buffer, and checkpoints save/restore every rank's state.
-        """
-        replicated = NamedSharding(self.mesh, PartitionSpec())
-        dp_sharded = NamedSharding(self.mesh, PartitionSpec("dp"))
-        per_rank = set(self.optimizer.PER_RANK_STATE_FIELDS)
-        world = self.dp_world_size
-
-        base = jax.jit(self.optimizer.init)(params)
-        leaves, shardings = {}, {}
-        for f in base._fields:
-            leaf = getattr(base, f)
-            if f in per_rank:
-                # initial buffers are zeros; a jitted sharded-out zeros
-                # creates each [1, ...] shard on its own device — no
-                # [world, n] materialization on device 0 first
-                shape, dtype = (world,) + leaf.shape, leaf.dtype
-                leaves[f] = jax.jit(
-                    lambda shape=shape, dtype=dtype: jnp.zeros(shape, dtype),
-                    out_shardings=dp_sharded,
-                )()
-                shardings[f] = dp_sharded
-            else:
-                leaves[f] = jax.device_put(leaf, replicated)
-                shardings[f] = replicated
-        return type(base)(**leaves), type(base)(**shardings)
-
-    def _build_onebit_optimizer(self, name: str, opt_cfg, lr_schedule):
-        from .fp16.onebit import OnebitAdam, OnebitLamb, ZeroOneAdam
-
-        p = dict(opt_cfg.params or {})
-        common = dict(
-            lr=lr_schedule,
-            betas=tuple(p.get("betas", (0.9, 0.999))),
-            weight_decay=float(p.get("weight_decay", 0.0)),
-            axis_name="dp",
-            world=self.dp_world_size,
-        )
-        if name == "onebitadam":
-            return OnebitAdam(
-                eps=float(p.get("eps", 1e-8)),
-                freeze_step=int(p.get("freeze_step", 100)), **common,
-            )
-        if name == "onebitlamb":
-            return OnebitLamb(
-                eps=float(p.get("eps", 1e-6)),
-                freeze_step=int(p.get("freeze_step", 100)),
-                min_trust=float(p.get("min_coeff", 0.01)),
-                max_trust=float(p.get("max_coeff", 10.0)), **common,
-            )
-        return ZeroOneAdam(
-            eps=float(p.get("eps", 1e-8)),
-            var_freeze_step=int(p.get("var_freeze_step", 100)),
-            var_update_scaler=int(p.get("var_update_scaler", 16)),
-            local_step_scaler=int(p.get("local_step_scaler", 1000)),
-            local_step_clipper=int(p.get("local_step_clipper", 16)), **common,
-        )
-
-    def _onebit_dispatch(self, state: "TrainState", batch: PyTree, rng):
-        """Host-side stage policy → static flags → cached jitted variant.
-
-        Static flags keep the collectives out of traced lax.cond branches:
-        a ZeroOneAdam local step compiles to a program with zero cross-chip
-        traffic (the point of local steps)."""
-        from .fp16.onebit import ZeroOneAdam
-
-        step = self.global_steps
-        if isinstance(self.optimizer, ZeroOneAdam):
-            sync = self.optimizer.sync_step(step)
-            # Local steps make params rank-divergent (rank-local momentum,
-            # zero comm — the point of 0/1 Adam). Re-averaging params on the
-            # (exponentially rare) sync steps restores exact replication at
-            # every sync boundary; the host-side flag pays the dense
-            # allreduce only when a local step actually ran since the last
-            # resync. Between a local step and the next sync, params carry
-            # bounded per-rank drift and a checkpoint/eval reads device 0's
-            # copy — the same rank-0-saves semantics as the reference's
-            # per-process torch params.
-            resync = sync and getattr(self, "_zoadam_divergent", False)
-            flags = {
-                "sync": sync,
-                "update_var": self.optimizer.variance_update_step(step),
-                "resync_params": resync,
-            }
-            self._zoadam_divergent = not sync
-        else:
-            flags = {"compressed": step >= self.optimizer.freeze_step}
-        key = tuple(sorted(flags.items()))
-        fn = self._onebit_step_cache.get(key)
-        if fn is None:
-            fn = jax.jit(self._make_onebit_train_step(**flags))
-            self._onebit_step_cache[key] = fn
-        return fn(state, batch, rng)
-
-    def _make_onebit_train_step(self, **opt_flags):
-        from jax import shard_map
-
-        model = self.module
-        opt = self.optimizer
-        compute_dtype = self.compute_dtype
-        gas = self.gradient_accumulation_steps_value
-        mesh = self.mesh
-        world = self.dp_world_size
-
-        per_rank_fields = tuple(opt.PER_RANK_STATE_FIELDS)
-        resync_params = opt_flags.pop("resync_params", False)
-
-        def per_rank(params, opt_state, batch, rng):
-            rank = jax.lax.axis_index("dp")
-            # per-rank buffers arrive as [1, ...] blocks of the [dp, ...]
-            # global; the optimizer sees its rank's flat buffer
-            opt_state = opt_state._replace(
-                **{f: getattr(opt_state, f)[0] for f in per_rank_fields}
-            )
-
-            def scaled_loss(cp, micro, mrng):
-                loss, metrics = model.loss_fn(cp, micro, mrng, True)
-                return loss.astype(jnp.float32), metrics
-
-            grad_fn = jax.value_and_grad(scaled_loss, has_aux=True)
-            cparams = _cast_params(params, compute_dtype)  # hoisted out of scan
-
-            def micro_step(carry, i):
-                grads_acc, loss_acc = carry
-                micro = jax.tree.map(lambda x: x[i], batch)
-                mrng = jax.random.fold_in(jax.random.fold_in(rng, i), rank)
-                (loss, _), grads = grad_fn(cparams, micro, mrng)
-                grads_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), grads_acc, grads
-                )
-                return (grads_acc, loss_acc + loss), None
-
-            zero_grads = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (grads, loss_sum), _ = jax.lax.scan(
-                micro_step, (zero_grads, jnp.float32(0.0)), jnp.arange(gas)
-            )
-            grads = jax.tree.map(lambda g: g / gas, grads)  # LOCAL mean over gas
-
-            gnorm_local = global_norm(grads)
-            updates, new_opt_state = opt.update(grads, opt_state, params, **opt_flags)
-            new_opt_state = new_opt_state._replace(
-                **{f: getattr(new_opt_state, f)[None] for f in per_rank_fields}
-            )
-            new_params = optax.apply_updates(params, updates)
-            if resync_params:
-                new_params = jax.tree.map(
-                    lambda p: jax.lax.pmean(p, "dp"), new_params
-                )
-            loss_mean = jax.lax.pmean(loss_sum / gas, "dp")
-            gnorm = jax.lax.pmean(gnorm_local, "dp")
-            return new_params, new_opt_state, loss_mean, gnorm
-
-        replicated_spec = PartitionSpec()
-        batch_specs = None  # filled per call via tree mapping
-
-        def opt_state_specs(opt_state):
-            return type(opt_state)(**{
-                f: PartitionSpec("dp") if f in per_rank_fields else replicated_spec
-                for f in opt_state._fields
-            })
-
-        def train_step(state: TrainState, batch: PyTree, rng):
-            in_batch_specs = jax.tree.map(
-                lambda x: PartitionSpec(None, "dp", *([None] * (x.ndim - 2))), batch
-            )
-            mapped = shard_map(
-                per_rank,
-                mesh=mesh,
-                in_specs=(
-                    jax.tree.map(lambda _: replicated_spec, state.params),
-                    opt_state_specs(state.opt_state),
-                    in_batch_specs,
-                    replicated_spec,
-                ),
-                out_specs=(
-                    jax.tree.map(lambda _: replicated_spec, state.params),
-                    opt_state_specs(state.opt_state),
-                    replicated_spec,
-                    replicated_spec,
-                ),
-                check_vma=False,
-            )
-            new_params, new_opt_state, loss, gnorm = mapped(
-                state.params, state.opt_state, batch, rng
-            )
-            new_state = TrainState(
-                params=new_params,
-                opt_state=new_opt_state,
-                loss_scale=state.loss_scale,
-                global_step=state.global_step + 1,
-                skipped_steps=state.skipped_steps,
-            )
-            metrics = {
-                "loss": loss,
-                "grad_norm": gnorm,
-                "loss_scale": jnp.float32(1.0),
-                "overflow": jnp.bool_(False),
-                "lr": jnp.asarray(self.lr_schedule(state.global_step), jnp.float32),
-                "global_step": new_state.global_step,
-            }
-            return new_state, metrics
-
-        return train_step
 
     # ------------------------------------------------------------------
     # ZeRO-Offload path: jitted (loss, grads) + host optimizer step
@@ -1500,8 +1248,7 @@ class DeepSpeedEngine:
         """Train step with the gradient dp-reduction as explicit block-scaled
         int8/fp8 collectives (comm_compression tentpole; comm/compressed.py).
 
-        Generalizes the 1-bit shard_map precedent (_make_onebit_train_step):
-        the grad-accumulation scan runs per-rank under ``shard_map`` over dp
+        The grad-accumulation scan runs per-rank under ``shard_map`` over dp
         (params replicated, batch dp-sharded), then each size-capped flat
         bucket (``reduce_bucket_size``) is reduced by an INDEPENDENT
         quantize → all_to_all → fp32-reduce → requantize → all_gather
@@ -1829,13 +1576,13 @@ class DeepSpeedEngine:
             device_batch = self.shard_batch(batch)
         with spans.span("ds.train.dispatch") as sp_dispatch:
             # the standard jitted step folds global_step into the key in-graph;
-            # the host-driven paths (offload/onebit/infinity) still need a fresh
+            # the host-driven paths (offload/infinity) still need a fresh
             # key per call
             if self._train_step_folds_rng:
                 step_rng = self._rng
             else:
                 # dslint: disable=jnp-in-hot-loop — the host-driven paths
-                # (offload/onebit/infinity) consume a fresh key per call
+                # (offload/infinity) consume a fresh key per call
                 self._rng, step_rng = jax.random.split(self._rng)
             first_call = self._step_arg_structs is None
             if first_call or (
@@ -2111,7 +1858,7 @@ class DeepSpeedEngine:
         registry must not keep its state on the device.)"""
         name = getattr(self._train_step, "__name__", None)
         if name is None or not hasattr(self._train_step, "lower"):
-            return   # offload / 1-bit / infinity: several programs a step
+            return   # offload / infinity: several programs a step
         me = weakref.ref(self)
 
         def text():
@@ -2162,7 +1909,7 @@ class DeepSpeedEngine:
         if self._step_arg_structs is None or not hasattr(self._train_step, "lower"):
             raise ValueError(
                 "verify_program requires the standard jitted train step and "
-                "at least one train_batch() call (offload/onebit/infinity "
+                "at least one train_batch() call (offload/infinity "
                 "paths run multiple programs per step)"
             )
         from .. import analysis as dsa
@@ -2437,7 +2184,7 @@ class DeepSpeedEngine:
         if not hasattr(self._train_step, "lower"):
             raise ValueError(
                 "comms accounting supports the standard jitted train step only "
-                "(offload/onebit/infinity paths run multiple programs per step)"
+                "(offload/infinity paths run multiple programs per step)"
             )
         from ..comm import comm as dscomm
 
@@ -2469,7 +2216,7 @@ class DeepSpeedEngine:
         telemetry record. Axes are mesh names where recoverable, else the
         HLO buckets ``xla`` (sharding-inserted) / ``xla-loop`` (inside a
         scan/while body, per-iteration counts) — see record_from_compiled.
-        Empty on the multi-program paths (offload/onebit/infinity).
+        Empty on the multi-program paths (offload/infinity).
 
         Deriving the mix lowers + compiles the step program once per DISTINCT
         program (the jit cache size is the invalidation key, so a retrace

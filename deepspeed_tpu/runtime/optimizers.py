@@ -11,9 +11,7 @@ as it is wrapped, at 170; ``PERF.md`` section 6, PR 45) — optax stays. The
 CPU (host-offload) variants live in ``deepspeed_tpu/runtime/offload/``.
 
 Accepted ``type`` strings keep DeepSpeed's names: Adam, AdamW, FusedAdam,
-DeepSpeedCPUAdam, Lamb, FusedLamb, Adagrad, DeepSpeedCPUAdagrad, SGD,
-OneBitAdam, ZeroOneAdam, OneBitLamb (1-bit variants currently run their
-uncompressed stage; compressed-collective stage in ops/onebit).
+DeepSpeedCPUAdam, Lamb, FusedLamb, Adagrad, DeepSpeedCPUAdagrad, SGD.
 """
 
 from __future__ import annotations
@@ -25,9 +23,9 @@ import optax
 ADAM_OPTIMIZER = "adam"
 ADAMW_OPTIMIZER = "adamw"
 LAMB_OPTIMIZER = "lamb"
-ONEBIT_ADAM_OPTIMIZER = "onebitadam"
-ONEBIT_LAMB_OPTIMIZER = "onebitlamb"
-ZERO_ONE_ADAM_OPTIMIZER = "zerooneadam"
+# DeepSpeed's compressed-communication optimizers: refused by name, so that a
+# config that asks for one never trains uncompressed under plain Adam or LAMB
+_REMOVED_OPTIMIZERS = ("onebitadam", "onebitlamb", "zerooneadam")
 
 Schedule = Union[float, Callable]
 
@@ -46,13 +44,19 @@ def build_optimizer(
     """Build the optax transform for a DeepSpeed ``optimizer`` config section."""
     p = dict(params_cfg or {})
     name = (opt_type or "Adam").lower()
+    if name in _REMOVED_OPTIMIZERS:
+        raise ValueError(
+            f"optimizer type {opt_type!r}: the port of the 1-bit optimizers was "
+            "removed in PR 46; compress the gradient reduce under any optimizer "
+            "with the 'comm_compression' section (docs/COMM_COMPRESSION.md)"
+        )
     lr = learning_rate if learning_rate is not None else p.get("lr", 1e-3)
     betas = tuple(p.get("betas", (0.9, 0.999)))
     eps = float(p.get("eps", 1e-8))
     weight_decay = float(p.get("weight_decay", 0.0))
     adam_w_mode = bool(p.get("adam_w_mode", True))
 
-    if name in ("adam", "adamw", "fusedadam", "deepspeedcpuadam", "onebitadam", "zerooneadam"):
+    if name in ("adam", "adamw", "fusedadam", "deepspeedcpuadam"):
         if weight_decay and (adam_w_mode or name == "adamw"):
             return optax.adamw(
                 lr, b1=betas[0], b2=betas[1], eps=eps, weight_decay=weight_decay,
@@ -66,7 +70,7 @@ def build_optimizer(
             )
         return optax.adam(lr, b1=betas[0], b2=betas[1], eps=eps)
 
-    if name in ("lamb", "fusedlamb", "onebitlamb"):
+    if name in ("lamb", "fusedlamb"):
         return optax.lamb(lr, b1=betas[0], b2=betas[1], eps=eps, weight_decay=weight_decay)
 
     if name in ("adagrad", "deepspeedcpuadagrad"):

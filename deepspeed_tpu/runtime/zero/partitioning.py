@@ -290,8 +290,7 @@ class ZeroShardingPolicy:
         """Shardings for the error-feedback residuals
         (``TrainState.comm_error``): one ``[world, ...]``-leading buffer per
         param leaf, sharded over ``zero_axis`` so each rank's shard IS its
-        rank-local residual (same rationale as the 1-bit optimizer's
-        PER_RANK_STATE_FIELDS — claiming divergent buffers replicated is
+        rank-local residual (claiming divergent buffers replicated is
         undefined behaviour under reshard/donation)."""
         sh = NamedSharding(self.mesh, PartitionSpec(self.zero_axis))
         return jax.tree.map(lambda _: sh, abstract_params)

@@ -3,6 +3,10 @@ add_tuning_arguments, revert_transformer_layer (reference __init__.py:16-33
 export list)."""
 
 import argparse
+import ast
+import functools
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,38 +148,210 @@ class TestRevertTransformerLayer:
             deepspeed_tpu.revert_transformer_layer(Fake(), {})
 
 
+@pytest.mark.parametrize("opt_type", ["OneBitAdam", "OneBitLamb", "ZeroOneAdam"])
+def test_a_removed_optimizer_is_refused_by_name(opt_type):
+    """The 1-bit optimizers left in PR 46. Their names must not fall through
+    to plain Adam or LAMB (a config that asks for compression would train
+    without it): ``initialize`` refuses them before it builds any state."""
+    from deepspeed_tpu.runtime.module import ModuleSpec
+
+    built = []
+
+    def init(rng):
+        built.append(rng)
+        return {"w": jnp.zeros((4, 4))}
+
+    model = ModuleSpec(init=init, loss_fn=lambda params, batch, rng, train: (0.0, {}))
+    config = {
+        "train_micro_batch_size_per_gpu": 1,
+        "optimizer": {"type": opt_type, "params": {"lr": 1e-3}},
+        "mesh": {"dp": 8},
+    }
+    with pytest.raises(ValueError) as refused:
+        deepspeed_tpu.initialize(model=model, config=config)
+    said = str(refused.value)
+    assert opt_type in said and "removed in PR 46" in said
+    assert "comm_compression" in said and "docs/COMM_COMPRESSION.md" in said
+    assert not built
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+@functools.lru_cache(maxsize=None)
+def _trees():
+    """``{dotted name: (path, ast)}`` of every module of the package, of the
+    benchmark and of ``chip_smoke.py``."""
+    paths = [os.path.join(_REPO, "chip_smoke.py")]
+    for top in ("deepspeed_tpu", "perfbench"):
+        for dirpath, _, names in os.walk(os.path.join(_REPO, top)):
+            paths.extend(os.path.join(dirpath, n) for n in names if n.endswith(".py"))
+    trees = {}
+    for path in paths:
+        parts = os.path.relpath(path, _REPO)[:-3].split(os.sep)
+        if parts[-1] == "__init__":
+            parts.pop()
+        with open(path, encoding="utf-8") as fh:
+            trees[".".join(parts)] = (path, ast.parse(fh.read(), filename=path))
+    return trees
+
+
+@functools.lru_cache(maxsize=None)
+def _imports():
+    """``{module: the modules of ``_trees`` it imports}``, at module level or
+    inside a function. Importing ``a.b.c`` runs ``a`` and ``a.b`` too."""
+    trees = _trees()
+
+    def known(dotted):
+        parts = dotted.split(".")
+        return {".".join(parts[:i]) for i in range(1, len(parts) + 1)} & trees.keys()
+
+    graph = {}
+    for name, (path, tree) in trees.items():
+        package = name if path.endswith("__init__.py") else name.rpartition(".")[0]
+        found = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                for alias in node.names:
+                    found |= known(alias.name)
+            elif isinstance(node, ast.ImportFrom):
+                base = node.module or ""
+                if node.level:
+                    above = package.split(".")
+                    above = above[: len(above) - (node.level - 1)]
+                    base = ".".join(above + ([node.module] if node.module else []))
+                for alias in node.names:
+                    found |= known(base + "." + alias.name)
+        graph[name] = found | known(name) - {name}
+    return graph
+
+
+def _subpackage(module):
+    """``runtime`` of ``deepspeed_tpu.runtime.engine``; None of the root."""
+    parts = module.split(".")
+    return parts[1] if parts[0] == "deepspeed_tpu" and len(parts) > 1 else None
+
+
+@functools.lru_cache(maxsize=None)
+def _reached():
+    """Every module an entry point runs: the package's root, ``setup.py``'s
+    console scripts, the tools with a ``main``, ``chip_smoke.py``, and the
+    benchmark (``perfbench/run.py`` and the runners and readers that
+    ``perfbench/manifest.py`` loads by path)."""
+    trees, graph = _trees(), _imports()
+    with open(os.path.join(_REPO, "setup.py"), encoding="utf-8") as fh:
+        scripts = re.findall(r"=\s*(deepspeed_tpu[\w.]*):", fh.read())
+    assert len(scripts) == 5, scripts
+    todo = ["deepspeed_tpu", "chip_smoke", "perfbench.run", *scripts]
+    for name, (_, tree) in trees.items():
+        if name.startswith(("perfbench.runners.", "perfbench.metrics.readers.")):
+            todo.append(name)
+        elif name.startswith("deepspeed_tpu.tools.") and any(
+            isinstance(n, ast.FunctionDef) and n.name == "main" for n in tree.body
+        ):
+            todo.append(name)
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo.extend(graph[name])
+    return seen
+
+
+# modules no entry point reaches, each with the ROADMAP debt that names it:
+# tests import them, nothing that runs does
+UNREACHED = {
+    "deepspeed_tpu.compression": "D7",
+    "deepspeed_tpu.compression.basic_layer": "D7",
+    "deepspeed_tpu.compression.compress": "D7",
+    "deepspeed_tpu.runtime.quantize": "D7",
+    "deepspeed_tpu.runtime.zero.tiling": "D7",
+    "deepspeed_tpu.ops.fused_adam": "D7",
+}
+
+# bottom to top: a subpackage imports from those before it. ``env_report`` is
+# the one module at the package's root.
+LAYERS = (
+    "utils", "telemetry", "comm", "ops", "parallel", "moe", "compression",
+    "monitor", "checkpoint", "resilience", "elasticity", "models",
+    "module_inject", "runtime", "serving", "inference", "analysis",
+    "launcher", "tools", "env_report",
+)
+
+# today's imports against that order (ROADMAP D20): importer -> imported
+UPWARD = {
+    ("utils", "checkpoint"): "D20",
+    ("telemetry", "analysis"): "D20",
+    ("ops", "models"): "D20",
+    ("checkpoint", "resilience"): "D20",
+    ("resilience", "analysis"): "D20",
+    ("models", "runtime"): "D20",
+    ("runtime", "analysis"): "D20",
+    ("serving", "analysis"): "D20",
+}
+
+
+def _subpackages():
+    pkg = os.path.join(_REPO, "deepspeed_tpu")
+    return sorted(
+        d for d in os.listdir(pkg)
+        if os.path.isfile(os.path.join(pkg, d, "__init__.py"))
+    )
+
+
+@pytest.mark.parametrize("subpackage", _subpackages())
+def test_every_module_is_reached_from_an_entry_point_or_is_a_named_debt(subpackage):
+    """No port sits in the package unseen: a module that nothing an entry
+    point runs imports stands in ``UNREACHED`` under its ROADMAP label, and
+    leaves the table when something reaches it or when it goes."""
+    reached = _reached()
+    mine = {m for m in _trees() if _subpackage(m) == subpackage}
+    unreached = mine - reached
+    named = {m for m in UNREACHED if _subpackage(m) == subpackage}
+    assert unreached - named == set(), "reached by no entry point and not in UNREACHED"
+    assert named - unreached == set(), "in UNREACHED, but reached or gone"
+
+
+def test_no_new_upward_import():
+    """The subpackages have one declared order (``LAYERS``); the imports that
+    point up it are the debt ``UPWARD`` lists, no more and no fewer."""
+    assert sorted(LAYERS[:-1]) == _subpackages()
+    rank = {name: i for i, name in enumerate(LAYERS)}
+    upward = {}
+    for module, imported in _imports().items():
+        for other in imported:
+            a, b = _subpackage(module), _subpackage(other)
+            if a and b and rank[a] < rank[b]:
+                upward.setdefault((a, b), set()).add(f"{module} -> {other}")
+    new = {edge: sorted(upward[edge]) for edge in upward.keys() - UPWARD.keys()}
+    assert not new, f"new upward imports: {new}"
+    assert not UPWARD.keys() - upward.keys(), "repaired: take it out of UPWARD"
+
+
 def test_package_reads_nothing_at_the_repository_root():
     """The package measures nothing through files a script above it wrote:
     no module under ``deepspeed_tpu/`` imports the benchmark (or the
     pre-chip ``bench`` it replaced), and none names a ``BENCH_*.json``
     record, in code, help text, docstring or any other string."""
-    import ast
-    import os
-    import re
-
-    pkg = os.path.dirname(os.path.abspath(deepspeed_tpu.__file__))
     record = re.compile(r"BENCH_\w+\.json")
     forbidden = {"bench", "perfbench"}
     bad = []
-    for dirpath, _, names in os.walk(pkg):
-        for name in names:
-            if not name.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, name)
-            with open(path, encoding="utf-8") as fh:
-                tree = ast.parse(fh.read(), filename=path)
-            for node in ast.walk(tree):
-                mods = []
-                if isinstance(node, ast.Import):
-                    mods = [a.name for a in node.names]
-                elif isinstance(node, ast.ImportFrom) and node.level == 0:
-                    mods = [node.module or ""]
-                elif isinstance(node, ast.Constant) and isinstance(node.value, str):
-                    named = record.search(node.value)
-                    if named:
-                        bad.append(f"{path}:{node.lineno}: names {named.group()}")
-                bad.extend(
-                    f"{path}:{node.lineno}: imports {mod}"
-                    for mod in mods if mod.split(".")[0] in forbidden
-                )
+    for name, (path, tree) in _trees().items():
+        if not name.startswith("deepspeed_tpu"):
+            continue
+        for node in ast.walk(tree):
+            mods = []
+            if isinstance(node, ast.Import):
+                mods = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                mods = [node.module or ""]
+            elif isinstance(node, ast.Constant) and isinstance(node.value, str):
+                named = record.search(node.value)
+                if named:
+                    bad.append(f"{path}:{node.lineno}: names {named.group()}")
+            bad.extend(
+                f"{path}:{node.lineno}: imports {mod}"
+                for mod in mods if mod.split(".")[0] in forbidden
+            )
     assert not bad, "\n".join(bad)

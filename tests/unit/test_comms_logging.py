@@ -107,46 +107,3 @@ def test_measured_summary_has_latency(mesh_dp8):
     assert rec["time_ms"] is not None and rec["time_ms"] > 0
     text = dscomm.log_summary()
     assert "algbw" in text and "-" not in text.splitlines()[2].split()[-1]
-
-
-def test_onebit_wire_volume_reduction(mesh_dp8):
-    """Prove the ~31x wire-volume claim (VERDICT r2 weak #7): the compiled
-    compressed-allreduce program moves far fewer collective bytes than a
-    dense pmean of the same gradient, measured from the post-optimization
-    HLO (runtime/comm/compressed.py docstring claim)."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from deepspeed_tpu.runtime.comm.compressed import compressed_allreduce
-
-    world = 8
-    n = world * 4096  # 32k f32 grads
-    x = jnp.ones((n,), jnp.float32)
-    we = jnp.zeros((n,), jnp.float32)
-    se = jnp.zeros((n // world,), jnp.float32)
-
-    dense = jax.jit(
-        shard_map(
-            lambda v: jax.lax.pmean(v, "dp"),
-            mesh=mesh_dp8, in_specs=(P(),), out_specs=P(), check_vma=False,
-        )
-    ).lower(x).compile()
-
-    comp = jax.jit(
-        shard_map(
-            lambda v, w, s: compressed_allreduce(v, w, s, "dp", world)[0],
-            mesh=mesh_dp8, in_specs=(P(), P(), P()), out_specs=P(), check_vma=False,
-        )
-    ).lower(x, we, se).compile()
-
-    def coll_bytes(compiled):
-        found = dscomm.record_from_compiled(compiled)
-        dscomm.comms_logger.reset()
-        return sum(rec["bytes"] for rec in found.values())
-
-    b_dense = coll_bytes(dense)
-    b_comp = coll_bytes(comp)
-    assert b_dense > 0 and b_comp > 0
-    # signs are 1 bit vs 32 (+ per-chunk scales); require at least 8x less
-    # on the wire, expect ~30x
-    assert b_comp * 8 <= b_dense, (b_comp, b_dense)

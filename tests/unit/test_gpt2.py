@@ -121,8 +121,9 @@ class TestChunkedCE:
                 return gpt2.lm_loss(cfg, p, batch, None, True)[0]
             return f
 
-        l_full, g_full = jax.value_and_grad(loss(cfg_full))(params)
-        l_chunk, g_chunk = jax.value_and_grad(loss(cfg_chunk))(params)
+        # (one program a config: eager, the scan's pieces dispatch one op at a time)
+        l_full, g_full = jax.jit(jax.value_and_grad(loss(cfg_full)))(params)
+        l_chunk, g_chunk = jax.jit(jax.value_and_grad(loss(cfg_chunk)))(params)
         np.testing.assert_allclose(float(l_full), float(l_chunk), rtol=1e-6)
         for gf, gc in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gc), atol=1e-5, rtol=1e-4)
@@ -151,8 +152,9 @@ class TestChunkedCE:
             def loss(cfg, p):
                 return gpt2.lm_loss(cfg, p, batch, None, True)[0]
 
-            l_u, g_u = jax.value_and_grad(loss, argnums=1)(cfg_uc, params)
-            l_p, g_p = jax.value_and_grad(loss, argnums=1)(cfg_p, params_p)
+            grad = jax.jit(jax.value_and_grad(loss, argnums=1), static_argnums=0)
+            l_u, g_u = grad(cfg_uc, params)
+            l_p, g_p = grad(cfg_p, params_p)
             np.testing.assert_allclose(float(l_u), float(l_p), rtol=1e-6)
             np.testing.assert_allclose(
                 np.asarray(g_p["wte"])[:509], np.asarray(g_u["wte"]), atol=1e-6

@@ -46,12 +46,15 @@ class TestKVCacheDecode:
         rs = np.random.RandomState(1)
         ids = jnp.asarray(rs.randint(0, tiny_cfg.vocab_size, (2, 8)), jnp.int32)
         cache = gpt2.init_cache(tiny_cfg, 2, 16, dtype=jnp.float32)
-        _, cache = gpt2.forward_cached(tiny_cfg, tiny_params, ids, cache)
+        # (a program a shape: eagerly the layers' pieces dispatch one op at a time)
+        forward = jax.jit(gpt2.forward, static_argnums=0)
+        forward_cached = jax.jit(gpt2.forward_cached, static_argnums=0)
+        _, cache = forward_cached(tiny_cfg, tiny_params, ids, cache)
         for t in range(3):
             nxt = jnp.asarray(rs.randint(0, tiny_cfg.vocab_size, (2, 1)), jnp.int32)
-            dec, cache = gpt2.forward_cached(tiny_cfg, tiny_params, nxt, cache)
+            dec, cache = forward_cached(tiny_cfg, tiny_params, nxt, cache)
             ids = jnp.concatenate([ids, nxt], axis=1)
-            full = gpt2.forward(tiny_cfg, tiny_params, ids)[:, -1]
+            full = forward(tiny_cfg, tiny_params, ids)[:, -1]
             assert np.allclose(np.asarray(full), np.asarray(dec), atol=1e-4)
 
     def test_generate_greedy_matches_recompute(self, tiny_cfg, tiny_params):
@@ -59,8 +62,9 @@ class TestKVCacheDecode:
         ids = jnp.asarray(rs.randint(0, tiny_cfg.vocab_size, (2, 6)), jnp.int32)
         out = gpt2.generate(tiny_cfg, tiny_params, ids, max_new_tokens=5, cache_dtype=jnp.float32)
         ref = ids
+        forward = jax.jit(gpt2.forward, static_argnums=0)
         for _ in range(5):
-            lg = gpt2.forward(tiny_cfg, tiny_params, ref)[:, -1]
+            lg = forward(tiny_cfg, tiny_params, ref)[:, -1]
             ref = jnp.concatenate([ref, jnp.argmax(lg, -1)[:, None].astype(jnp.int32)], 1)
         assert np.array_equal(np.asarray(out), np.asarray(ref[:, 6:]))
 

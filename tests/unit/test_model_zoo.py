@@ -295,9 +295,10 @@ class TestDecoderChunkedCE:
         def loss(cfg_):
             return lambda p: decoder.lm_loss(cfg_, p, batch, None, True)[0]
 
-        l_full, g_full = jax.value_and_grad(loss(cfg))(params)
+        # (one program a config: eager, the scan's pieces dispatch one op at a time)
+        l_full, g_full = jax.jit(jax.value_and_grad(loss(cfg)))(params)
         cfg_c = replace(cfg, ce_chunk=16)  # 49 positions → pad path
-        l_chunk, g_chunk = jax.value_and_grad(loss(cfg_c))(params)
+        l_chunk, g_chunk = jax.jit(jax.value_and_grad(loss(cfg_c)))(params)
         np.testing.assert_allclose(float(l_full), float(l_chunk), rtol=1e-6)
         for gf, gc in zip(jax.tree.leaves(g_full), jax.tree.leaves(g_chunk)):
             np.testing.assert_allclose(np.asarray(gf), np.asarray(gc), atol=1e-5, rtol=1e-4)

@@ -90,7 +90,7 @@ def test_whole_forward_is_the_references(cfg, seed):
     params = _params(cfg, seed)
     ids = np.random.default_rng(seed).integers(0, 96, 48).astype(np.int32)
     want = np.asarray(reference.logits(params, jnp.asarray(ids), reference.Arch.from_config(CFG)))
-    got, _ = m.forward(cfg, params, jnp.asarray(ids[None]))
+    got = jax.jit(lambda p, i: m.forward(cfg, p, i)[0])(params, jnp.asarray(ids[None]))   # one program: eagerly, an op at a time
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=2e-4)
     assert np.abs(want).max() > 1.0
 

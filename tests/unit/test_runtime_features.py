@@ -1,8 +1,8 @@
-"""Tests for activation checkpointing, curriculum, PLD, eigenvalue, sparse tensor.
+"""Tests for activation checkpointing, curriculum, PLD, eigenvalue.
 
 Reference analogs: tests around activation_checkpointing (tests/unit/
 test_activation_checkpointing.py), curriculum (test_curriculum_learning.py),
-PLD (test_pld.py), sparse grads (test_sparse_grads.py).
+PLD (test_pld.py).
 """
 
 import jax
@@ -20,10 +20,6 @@ from deepspeed_tpu.runtime.activation_checkpointing import (
 from deepspeed_tpu.runtime.data_pipeline.curriculum_scheduler import CurriculumScheduler
 from deepspeed_tpu.runtime.eigenvalue import Eigenvalue
 from deepspeed_tpu.runtime.progressive_layer_drop import ProgressiveLayerDrop
-from deepspeed_tpu.runtime.sparse_tensor import (
-    SparseTensor,
-    embedding_grad_to_sparse,
-)
 
 
 class TestActivationCheckpointing:
@@ -218,55 +214,6 @@ class TestEigenvalue:
         )
         # Hessian of sum(tanh(w)^2) at 0 is 2*I → top eigenvalue 2
         assert float(ev) == pytest.approx(2.0, rel=1e-2)
-
-
-class TestSparseTensor:
-    def test_roundtrip(self):
-        dense = jnp.zeros((10, 4)).at[jnp.asarray([1, 7])].set(1.5)
-        sp = SparseTensor.from_dense_rows(dense, jnp.asarray([1, 7]))
-        assert np.allclose(sp.to_dense(), dense)
-        stored, full = sp.sparse_size()
-        assert stored < full
-
-    def test_embedding_grad_to_sparse(self):
-        vocab, dim = 50, 8
-        token_ids = jnp.asarray([[3, 3, 9], [12, 9, 3]])
-
-        def loss(emb):
-            return jnp.sum(emb[token_ids] ** 2)
-
-        emb = jnp.asarray(np.random.RandomState(0).randn(vocab, dim), jnp.float32)
-        grad = jax.grad(loss)(emb)
-        sp = embedding_grad_to_sparse(grad, token_ids)
-        assert np.allclose(sp.to_dense(), grad, atol=1e-6)
-        assert sp.indices.shape[0] == 3  # unique ids {3, 9, 12}
-
-    def test_sparse_allgather_apply(self, mesh_dp8):
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        from deepspeed_tpu.runtime.sparse_tensor import sparse_allgather_apply
-
-        vocab, dim = 16, 4
-        # per-shard: each dp rank contributes one row id + row grad
-        ids = jnp.arange(8, dtype=jnp.int32)  # rank r touches row r
-        vals = jnp.ones((8, dim), jnp.float32) * (1 + ids)[:, None]
-
-        def body(idx, v):
-            sp = SparseTensor(indices=idx, values=v, dense_shape=(vocab, dim))
-            return sparse_allgather_apply(sp, "dp")
-
-        out = shard_map(
-            body,
-            mesh=mesh_dp8,
-            in_specs=(P("dp"), P("dp")),
-            out_specs=P(),  # dense result replicated
-            check_vma=False,
-        )(ids, vals)
-        expect = np.zeros((vocab, dim), np.float32)
-        for r in range(8):
-            expect[r] += r + 1
-        assert np.allclose(out, expect)
 
 
 class TestPLDIntegration:

@@ -10,6 +10,8 @@ model's module) while the programs compute ABSORBED, the one latent pool of
 ``2 x num_layers`` layers, the spans and counters (held, routed and ZERO
 pairs), the share, and the refusals."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,37 +145,45 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
     table = jnp.arange(1, 1 + n_pg, dtype=jnp.int32)
     key = jnp.zeros((2,), jnp.uint32)
     seq = list(ids)
+    # (each program compiled once, as the engine calls it: eagerly its operations dispatch one at a time)
+    chunk = jax.jit(functools.partial(smodel.paged_chunk_prefill, mcfg))
     if chunked:
         for start in range(0, 19, 8):
             buf = np.zeros((1, 8), np.int32)
             seg = ids[start:start + 8]
             buf[0, : len(seg)] = seg
-            pool, _, tok, counts = smodel.paged_chunk_prefill(
-                mcfg, engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
+            pool, _, tok, counts = chunk(
+                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
                 table[start // page: start // page + 2], table[None], key)
             n_real = len(seg)
             assert counts.shape == (L, 4 + 1) and int(counts.sum()) <= n_real * K * L
     else:
         buf = np.zeros((1, 24), np.int32)
         buf[0, :19] = ids
-        pool, _, tok, counts = smodel.paged_prefill(
-            mcfg, engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
+        pool, _, tok, counts = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+            engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
         assert counts.shape == (L, 4 + 1)
     assert int(tok[0]) == int(np.argmax(_reference_last_logits(engine, seq, 19)))
     seq.append(int(tok[0]))
-    for _ in range(4):
-        n = len(seq)
-        h = fam.embed(engine.params, jnp.asarray([seq[-1]]), jnp.asarray([n - 1]))
-        pos = jnp.asarray([[n - 1]])
+
+    @jax.jit
+    def step(params, pool, token, n):     # the token at position n - 1 through the family's own pieces
+        h = fam.embed(params, token[None], n[None] - 1)
+        pos = n[None, None] - 1
         carry = None
         for l in range(fam.n_layer):
-            lp = fam.layer(engine.params, l)
+            lp = fam.layer(params, l)
             q, row, _ = fam.qkv(lp, h, pos, l)
             pool = pool.at[l, table[(n - 1) // page], 0, (n - 1) % page].set(row[0, 0, 0])
-            o = smodel._attend_latent(fam, q, pool, l, table[None], jnp.asarray([n - 1]), None)
+            o = smodel._attend_latent(fam, q, pool, l, table[None], n[None] - 1, None)
             h, carry, _ = fam.after_attention(lp, h, o, l, None, None, carry)
             assert (carry is None) == (l % 2 == 1)
-        got = np.asarray(fam.logits(engine.params, h[:, -1]))[0]
+        return pool, fam.logits(params, h[:, -1])
+
+    for _ in range(4):
+        n = len(seq)
+        pool, got = step(engine.params, pool, jnp.int32(seq[-1]), jnp.int32(n))
+        got = np.asarray(got)[0]
         np.testing.assert_allclose(got, _reference_last_logits(engine, seq, n), atol=5e-5, rtol=1e-4)
         seq.append(int(np.argmax(got)))
 
@@ -190,7 +200,8 @@ def test_step_programs_logits_are_the_references(engine, mcfg, prompts, program,
     key = jnp.zeros((2,), jnp.uint32)
     buf = np.zeros((1, 16), np.int32)
     buf[0, :13] = ids[:13]
-    pool, _, _, _ = smodel.paged_prefill(mcfg, engine.params, jnp.asarray(buf), jnp.int32(13), pool, None, table[:4], key)
+    pool, _, _, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+        engine.params, jnp.asarray(buf), jnp.int32(13), pool, None, table[:4], key)
     caught = []
     fam_cls = type(mcfg.serving_family())
     plain = fam_cls.logits

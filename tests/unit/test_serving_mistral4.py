@@ -8,6 +8,8 @@ and logits against the float32 reference's full forward in the EXPANDED form
 module) while the programs compute ABSORBED, the one latent pool, the spans
 and counters, the share, and the refusals."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,6 +129,8 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
     table = jnp.arange(1, 1 + n_pg, dtype=jnp.int32)
     key = jnp.zeros((2,), jnp.uint32)
     seq = list(ids)
+    # (each program compiled once, as the engine calls it: eagerly its operations dispatch one at a time)
+    chunk = jax.jit(functools.partial(smodel.paged_chunk_prefill, mcfg))
 
     def last_logits(n):      # the reference's logits at position n - 1 of the stream so far
         padded = np.zeros((32,), np.int32)
@@ -138,28 +142,34 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
             buf = np.zeros((1, 8), np.int32)
             seg = ids[start:start + 8]
             buf[0, : len(seg)] = seg
-            pool, _, tok, _ = smodel.paged_chunk_prefill(
-                mcfg, engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
+            pool, _, tok, _ = chunk(
+                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
                 table[start // page: start // page + 2], table[None], key)
     else:
         buf = np.zeros((1, 24), np.int32)
         buf[0, :19] = ids
-        pool, _, tok, _ = smodel.paged_prefill(
-            mcfg, engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
+        pool, _, tok, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+            engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
     assert int(tok[0]) == int(np.argmax(last_logits(19)))
     seq.append(int(tok[0]))
-    for _ in range(4):       # positions 19..22: past the scale's first step at 16
-        n = len(seq)
-        h = fam.embed(engine.params, jnp.asarray([seq[-1]]), jnp.asarray([n - 1]))
-        pos = jnp.asarray([[n - 1]])
+
+    @jax.jit
+    def step(params, pool, token, n):     # the token at position n - 1 through the family's own pieces
+        h = fam.embed(params, token[None], n[None] - 1)
+        pos = n[None, None] - 1
         for l in range(2):
-            lp = fam.layer(engine.params, l)
+            lp = fam.layer(params, l)
             q, row, _ = fam.qkv(lp, h, pos, l)
             pool = pool.at[l, table[(n - 1) // page], 0, (n - 1) % page].set(row[0, 0, 0])
-            o = smodel._attend_latent(fam, q, pool, l, table[None], jnp.asarray([n - 1]), None)
+            o = smodel._attend_latent(fam, q, pool, l, table[None], n[None] - 1, None)
             h = h + fam.attn_out(lp, o)
             h = h + fam.mlp(lp, h, l)[0]
-        got = np.asarray(fam.logits(engine.params, h[:, -1]))[0]
+        return pool, fam.logits(params, h[:, -1])
+
+    for _ in range(4):       # positions 19..22: past the scale's first step at 16
+        n = len(seq)
+        pool, got = step(engine.params, pool, jnp.int32(seq[-1]), jnp.int32(n))
+        got = np.asarray(got)[0]
         np.testing.assert_allclose(got, last_logits(n), atol=2e-5, rtol=1e-4)
         seq.append(int(np.argmax(got)))
 

@@ -10,6 +10,8 @@ no chunk can ride); when a rider starts decoding and what stamps its first
 token; two slots prefilling in one step; the counts the benchmark's readers
 live on; and the paths that must not ride (speculation, disaggregation)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,6 +115,13 @@ def _pools(fam, rng, P, page, B, ring, int8):
     return k, v, scales, win
 
 
+def _program(fn, cfg):
+    """``fn(cfg, ...)`` as the engine calls it, one compiled program (called
+    eagerly its operations dispatch one at a time). A new program every time:
+    what the trace reads of ``smodel`` is read again."""
+    return jax.jit(functools.partial(fn, cfg), static_argnames=("ring",))
+
+
 def _one_call(engines, family, int8):
     """A step's operands: slot 2 prefills (two pages cached, the chunk at 8
     of a 13-token prompt: 5 real rows), 0 and 3 decode, 1 is empty."""
@@ -146,14 +155,14 @@ def test_one_mixed_call_is_the_chunk_call_then_the_decode_step(engines, family, 
     cfg, params, fam, (tokens, seq_lens), (ids, start, plen, k, v), bt, (page_ids, row), (keys, key0), kw, n_pools = \
         _one_call(engines, family, int8)
     B, ring, win, slot = len(tokens), kw["ring"], kw["win"], kw.pop("slot")
-    after_chunk = smodel.paged_chunk_prefill(cfg, params, ids, start, plen, k, v, page_ids, row, key0, slot=slot, **kw)
+    after_chunk = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, k, v, page_ids, row, key0, slot=slot, **kw)
     k1, v1 = after_chunk[:2]
     s1 = after_chunk[2] if int8 else None
     w1 = tuple(after_chunk[n_pools - 2:n_pools]) if win is not None else None
     tok_c = after_chunk[n_pools]
-    after_step = smodel.paged_decode_step(cfg, params, tokens, seq_lens, k1, v1, bt, keys, scales=s1, win=w1, ring=ring)
-    mixed = smodel.paged_mixed_step(cfg, params, tokens, seq_lens, ids, start, plen, k, v, bt, page_ids, row, keys,
-                                    key0, slot=slot, **kw)
+    after_step = _program(smodel.paged_decode_step, cfg)(params, tokens, seq_lens, k1, v1, bt, keys, scales=s1, win=w1, ring=ring)
+    mixed = _program(smodel.paged_mixed_step, cfg)(params, tokens, seq_lens, ids, start, plen, k, v, bt, page_ids, row, keys,
+                                                   key0, slot=slot, **kw)
 
     toks = np.asarray(mixed[n_pools])
     assert toks.shape == (B + 1,)
@@ -188,7 +197,7 @@ def test_a_call_with_no_real_decode_row_is_the_chunk_call_and_may_skip_the_rows_
 
     def call(rows, bt, skip):
         monkeypatch.setattr(smodel, "SKIP_IDLE_READS_FROM_SLOTS", 1 if skip else 1 << 30)
-        return smodel.paged_mixed_step(cfg, params, *rows, ids, start, plen, k, v, bt, page_ids, row, keys, key0, **kw)
+        return _program(smodel.paged_mixed_step, cfg)(params, *rows, ids, start, plen, k, v, bt, page_ids, row, keys, key0, **kw)
 
     def same(a, b, toks):
         np.testing.assert_array_equal(np.asarray(a[n_pools])[toks], np.asarray(b[n_pools])[toks])
@@ -196,7 +205,7 @@ def test_a_call_with_no_real_decode_row_is_the_chunk_call_and_may_skip_the_rows_
             if x is not None:
                 np.testing.assert_allclose(np.asarray(x)[:, 1:], np.asarray(y)[:, 1:], atol=1e-5)
 
-    alone = smodel.paged_chunk_prefill(cfg, params, ids, start, plen, k, v, page_ids, row, key0, **kw)
+    alone = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, k, v, page_ids, row, key0, **kw)
     idle = (np.zeros_like(rows[0]), np.zeros_like(rows[1]))
     for skip in (True, False):
         got = call(idle, np.zeros_like(bt), skip)
