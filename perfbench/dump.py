@@ -21,7 +21,23 @@ def summary(ctx) -> dict:
         "requests": len(ctx.recs), "counted": sum(1 for r in ctx.recs if r.counted),
         "steps": len(ctx.steps), "gaps": len(gaps),
         "gap_hist_10ms": {f"{k:.2f}": v for k, v in sorted(hist.items())},
+        "moe": moe_counts(ctx),
     }
+
+
+def moe_counts(ctx) -> dict:
+    """What the expert layers did over the whole window, from the program's
+    ``ds.serve.emit`` counters (PERF.md section 3): the mean, a decode step, of
+    the held experts a token reached (summed over layers), of the pairs
+    computed here and of the fullest expert's tokens. The routing follows the
+    weights and the token ids, so it is the one part of a backlog cell's work
+    that ``--seed`` reaches. Empty where the program reports no such counter."""
+    from perfbench import program_spans
+
+    emits = [r[3] for r in program_spans.records_in(ctx.window) or ()
+             if r[0] == "ds.serve.emit" and r[3].get("moe_pairs_held")]
+    keys = ("moe_experts_hit", "moe_experts_streamed", "moe_pairs_held", "moe_load_max")
+    return {k: sum(a[k] for a in emits) / len(emits) for k in keys if emits and all(k in a for a in emits)}
 
 
 def write(out_dir: str, out: dict, ctx) -> None:

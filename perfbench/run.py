@@ -9,6 +9,10 @@ warms the shapes this cell's traffic uses, opens the window for ``--seconds``
 and prints one JSON object as the last line of stdout. With ``--trace 0`` the
 metrics are the cell's end-to-end metrics, with ``--trace 1`` its per-layer
 metrics, the last ``trace_s`` seconds of the window under the profiler.
+``setup_s`` runs from the moment the chip is reached to the window's opening:
+importing the program, its parameters, its programs (compiled, or loaded from
+the cache), the warm-up and the ramp; what the process took to reach the chip
+is ``notes.reach_chip_s``.
 
 Everything that belongs to one cell, configuration, traffic mix or metric is
 data found by name (perfbench/manifest.py). ``BENCH_RUN`` is not read.
@@ -132,12 +136,21 @@ def run_cell(manifest, cell_name: str, seed: int, seconds: float, trace: bool,
     traffic = manifest.traffic(cell["traffic"])
     devs, peak = check_device(int(cell["chips"]), require_tpu)
     used = devs[: int(cell["chips"])]
+    # Set-up is counted from here: the interpreter's start, ``import jax`` and
+    # the runtime's attach to the chip lie before it. They are the machine's
+    # (9.6 to 14.4 s, up to 3.7 s apart on one machine, where everything after
+    # them repeats to a second: PERF.md, PR 47), no PR can move work into them,
+    # and with them in it two sets of runs of one tree read ``setup_s`` 10.6%
+    # apart. The result line's ``notes.reach_chip_s`` keeps them.
+    t_chip = time.perf_counter()
+    log(f"{len(devs)} device(s) reached: set-up counts from here")
 
     from deepspeed_tpu.telemetry import compile_stats
     from deepspeed_tpu.telemetry.registry import MetricsRegistry
 
     reg = MetricsRegistry()
     compile_stats.install(reg)
+    log("program imported")
 
     def compiles():
         return reg.counter("jit_compiles_total").value()
@@ -148,9 +161,9 @@ def run_cell(manifest, cell_name: str, seed: int, seconds: float, trace: bool,
     c_warm = compiles()
     trace_dir = trace_dir or os.path.join(_ROOT, ".perfbench_trace", cell_name)
     tracer = TraceCtl(trace, float(cfg.get("trace_s", 5.0)), trace_dir)
-    log("window opens")
+    log("warmed up; the ramp, then the window")
     runner.measure(float(seconds), tracer)
-    setup_s = ctx.window[0] - _T_PROCESS
+    setup_s = ctx.window[0] - t_chip
     compiled_in_window = compiles() - c_warm
     log(f"window closed; {compiled_in_window} compilation(s) inside")
     if tracer.state == "done":
@@ -163,6 +176,7 @@ def run_cell(manifest, cell_name: str, seed: int, seconds: float, trace: bool,
     if compiled_in_window:
         correct = False
     notes["compilations_in_window"] = compiled_in_window
+    notes["reach_chip_s"] = t_chip - _T_PROCESS
 
     group = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -204,6 +218,7 @@ def main(argv=None) -> int:
     manifest = Manifest(_ROOT)
     manifest.cell(args.workload)  # an unknown cell fails before JAX is touched
     setup_jax_cache()
+    log("jax imported")
     try:
         out, ctx = run_cell(manifest, args.workload, args.seed, args.seconds, bool(args.trace))
     except Refused as e:

@@ -159,22 +159,43 @@ class Runner:
         ``ramp.aged`` the first fill asks slot k of n for (k+1)/n of its new
         tokens, so that the slots come free one after another, evenly spread in
         steps, as they are in a server that has run for hours, and not all in
-        the same step. Building the first contexts is set-up the traffic needs."""
+        the same step. Building the first contexts is set-up the traffic needs.
+
+        The ramp is a stretch of the SCHEDULE, not of the clock: with
+        ``ramp.requests`` the window opens at the step boundary after that
+        many requests of the cycle have been submitted, so every run's window
+        starts at the same request of the same order and holds the same work
+        as far as its speed takes it. Under ``ramp.seconds`` (kept for a mix
+        that states no count) a host that stood still for three seconds of the
+        ramp opened its window 180 steps earlier in the cycle, and where the
+        window lies in the cycle moves ``serve_tok_s`` by up to 1.5% at one
+        speed, because a prompt token is cheaper than a generated one
+        (PERF.md, PR 47)."""
         tr = self.ctx.traffic
         ramp = tr.get("ramp", {})
         ramp_s, aged = float(ramp.get("seconds", 0.0)), bool(ramp.get("aged", False))
+        ramp_requests = int(ramp.get("requests", 0))
         depth = int(tr.get("queue_depth", 2))
         slots = int(self.sv["max_slots"])
         cycle = tg.backlog_cycle(tr)
-        t_open = clock() + ramp_s
-        self.ctx.window = (t_open, t_open + seconds)
+        t_ramp, submitted, n_steps = clock(), 0, len(self.ctx.steps)
+        t_open = None if ramp_requests else t_ramp + ramp_s
         first_fill = slots if aged else 0
+        opened = False
         while True:
             now = clock()
-            rel = now - t_open
-            tracer.tick(rel, seconds)
-            if rel >= seconds:
-                break
+            if t_open is None and submitted >= ramp_requests:
+                t_open = now
+            if not opened and t_open is not None and now >= t_open:
+                opened = True
+                self.ctx.window = (t_open, t_open + seconds)
+                self.log(f"ramp {now - t_ramp:.1f}s: {submitted} requests submitted, "
+                         f"{len(self.ctx.steps) - n_steps} steps before the window")
+            if opened:
+                rel = now - t_open
+                tracer.tick(rel, seconds)
+                if rel >= seconds:
+                    break
             while len(self.live) < slots + depth:
                 a = next(cycle)
                 if first_fill > 0:
@@ -182,6 +203,7 @@ class Runner:
                     a = dataclasses.replace(a, new_tokens=max(1, -(-a.new_tokens * (k + 1) // slots)))
                     first_fill -= 1
                 self._submit(a, now)
+                submitted += 1
             self._step()
         t1 = t_open + seconds
         # A prompt whose prefill straddles an edge counts in proportion

@@ -37,7 +37,9 @@ Parameters of a mix::
      "arrival_seed": 0,         # open: the arrival offsets come from this, not from --seed
      "order_seed": 0,           # which pair arrives when comes from this, not from --seed
      "ramp_s": 8,               # open: load offered before the window opens (set-up)
-     "ramp": {"seconds": 16, "aged": true}}  # backlog: see runners/serve.py
+     "ramp": {"requests": 160, "aged": true}}  # backlog: the window opens once that many requests of
+                                # the cycle are submitted (or after "seconds" of the clock, for a mix
+                                # that states no count); see runners/serve.py
 
 Distributions: ``const`` (value), ``uniform`` (min, max), ``lognormal``
 (median, sigma, clipped to min..max). Quantile point i of n is the

@@ -62,7 +62,7 @@ def test_traced_run_reports_the_metrics_that_need_no_device(manifest, results):
     out, ctx = results[True]
     listed = {m["name"]: m for m in manifest.metrics_for(CELL, "per_layer")}
     host = {n for n, m in listed.items() if m["source"] != "device_trace"}
-    assert {"moe_load_max_over_mean.kx", "decode_slots_active.kx", "gen_tok_s.kx"} <= host <= set(out["metrics"])
+    assert {"moe_load_max_over_mean.kx", "decode_slots_active.backlog", "gen_tok_s.backlog"} <= host <= set(out["metrics"])
     assert not (set(out["metrics"]) - host)      # no device plane on the CPU: those readers found nothing
     assert out["metrics"]["moe_load_max_over_mean.kx"]["value"] >= 1.0
 

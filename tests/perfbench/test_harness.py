@@ -54,11 +54,13 @@ def test_last_line_has_the_contracts_keys(results, cell, trace):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_untraced_run_reports_the_cells_end_to_end_metrics(manifest, results, cell):
-    out, _ = results[cell, False]
+    out, ctx = results[cell, False]
     want = {m["name"] for m in manifest.metrics_for(cell, "end_to_end")}
     assert set(out["metrics"]) == want and "setup_s" in want
     assert all(v["value"] > 0 for v in out["metrics"].values())   # metrics that are never 0
     assert "breakdown" not in out
+    # set-up counts from the chip reached to the window's opening; what came before is in the notes
+    assert out["notes"]["reach_chip_s"] > 0 and out["metrics"]["setup_s"]["value"] < ctx.window[0] - run._T_PROCESS
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -88,7 +90,7 @@ def test_chat_window_counts_what_was_due_in_it(manifest, results):
 
 def test_backlog_keeps_the_slots_busy_and_counts_tokens_inside_only(results):
     out, ctx = results["serve-xl-doc-batch", True]
-    assert out["metrics"]["decode_occupancy.doc"]["value"] > 50
+    assert out["metrics"]["decode_slots_active.backlog"]["value"] > 50
     out0, ctx0 = results["serve-xl-doc-batch", False]
     from perfbench import arith
 
@@ -137,7 +139,7 @@ class _FakeServer:
                 self.running.remove(r)
 
 
-def _fake_backlog_run(manifest, monkeypatch, step_s, seconds):
+def _fake_backlog_run(manifest, monkeypatch, step_s, seconds, ramp=None, stall=None):
     import contextlib
 
     from perfbench import arith
@@ -148,11 +150,19 @@ def _fake_backlog_run(manifest, monkeypatch, step_s, seconds):
     monkeypatch.setattr(serve, "clock", lambda: now[0])
     cfg = dict(manifest.config("tiny-serve"))
     cfg["serving"] = dict(cfg["serving"], max_slots=1)
-    tr = dict(manifest.traffic("tiny-backlog"), ramp={"seconds": 0.0, "aged": False}, queue_depth=1)
+    tr = dict(manifest.traffic("tiny-backlog"), ramp=ramp or {"seconds": 0.0, "aged": False}, queue_depth=1)
     ctx = Context(cell={}, config=cfg, traffic=tr, chips=1, peak=None)
     r = serve.Runner(ctx, 1, [], lambda name: contextlib.nullcontext(), lambda msg: None)
     r.mcfg = serve.model_config(cfg)
     r.srv = _FakeServer(now, slots=1, prefill_steps=5, step_s=step_s)   # 5 + 7 steps a request
+    if stall:   # the host stands still once, for stall[1] seconds, during step number stall[0]
+        step, n = r.srv.step, [0]
+
+        def stalled():
+            n[0] += 1
+            now[0] += stall[1] if n[0] == stall[0] else 0.0
+            step()
+        r.srv.step = stalled
     r._backlog(seconds, type("T", (), {"tick": lambda self, rel, s: None})())
     reqs = [q for q, _, _ in r.done + r.live]
     recs = [arith.Rec(due=0, prompt_len=q.prompt_len, new_tokens=q.want, t_submit=q.t_submit, t_admit=q.t_admit,
@@ -183,6 +193,24 @@ def test_a_prefill_the_close_catches_in_flight_is_counted_in_proportion(manifest
     assert got == pytest.approx(arith.tokens_in_window(fast, a0, a1), rel=0.01)
     caught[0].t_first_token = None       # as the run stood at the close before PR 28
     assert arith.tokens_in_window(slow, b0, b1) < 0.85 * got
+
+
+@pytest.mark.parametrize("rule, same", [({"requests": 4, "aged": False}, True), ({"seconds": 4.0, "aged": False}, False)])
+def test_a_ramp_told_in_requests_opens_the_window_at_the_same_request_whatever_the_host_did(manifest, monkeypatch, rule, same):
+    """A host that stands still for 1.5 s of the ramp. Told in requests, the
+    ramp ends at the same step boundary of the schedule in both runs (after
+    the fourth submission), so both windows hold the same requests from the
+    same one on and read the same; told in seconds, the stalled run's window
+    opens earlier in the cycle and holds other work (PERF.md, PR 47)."""
+    from perfbench import arith
+
+    (a0, a1), calm, _ = _fake_backlog_run(manifest, monkeypatch, 0.1, 3.0, ramp=rule)
+    (b0, b1), held, _ = _fake_backlog_run(manifest, monkeypatch, 0.1, 3.0, ramp=rule, stall=(7, 1.5))
+    first = lambda recs, t0: min(i for i, x in enumerate(recs) if x.t_admit is not None and x.t_admit >= t0)
+    assert (first(calm, a0) == first(held, b0)) is same
+    if same:
+        assert b0 - a0 == pytest.approx(1.5)      # the stall is set-up's, whole
+        assert arith.tokens_in_window(held, b0, b1) == pytest.approx(arith.tokens_in_window(calm, a0, a1))
 
 
 def test_training_counts_whole_steps_and_checks_the_reference(results):

@@ -23,11 +23,14 @@ CELL = "serve-lcflash-gen-backlog"
 CONFIG = "longcat-flash-560b-ep32-serve-1chip"
 SEED = 2**31 + 141
 REPO = tiny.REPO
-LCF = ("decode_step_p50_s", "prefill_step_p50_s", "gen_tok_s", "decode_slots_active", "srv_step_host_p50_s",
-       "device_idle_share", "idle_outside_spans_share", "copy_layout_share", "part_unattributed_share",
-       "part_attn_share", "part_dense_ffn_share", "part_moe_route_share", "moe_layer_share",
-       "moe_weight_stream_roofline", "moe_load_max_over_mean", "moe_zero_pair_share", "mla_decode_roofline",
-       "mla_chunk_roofline", "mla_attention_share")
+# the cell's own entries (`.lcf`: a reader or arguments of this configuration), in the order PR 41 appended them
+LCF = ("part_dense_ffn_share", "moe_weight_stream_roofline", "moe_load_max_over_mean", "moe_zero_pair_share",
+       "mla_decode_roofline", "mla_chunk_roofline")
+# the readings it takes the way other backlog cells do: one entry each, the cell listed in its `workloads` (PR 47)
+SHARED = ("decode_step_p50_s", "gen_tok_s", "decode_slots_active", "srv_step_host_p50_s", "idle_outside_spans_share",
+          "copy_layout_share", "part_unattributed_share", "part_attn_share", "part_moe_route_share", "moe_layer_share",
+          "mla_attention_share", "moe_streamed_per_hit")
+MINE = {n + ".lcf" for n in LCF} | {n + ".backlog" for n in SHARED}
 
 
 @pytest.fixture(scope="module")
@@ -65,31 +68,36 @@ def test_the_benchmark_lists_the_cell_and_its_nineteen_metrics_last():
     m = Manifest(REPO)
     m.validate()
     d = m.doc
-    assert d["workloads"][-1]["name"] == CELL and d["configs"][-1]["name"] == CONFIG and d["workloads"][-1]["chips"] == 1
-    assert d["workloads"][-1]["traffic"] == "gen-backlog-s64"
+    # the cell, its configuration and its metrics by name: a later PR appends behind them. Nineteen entries of the
+    # cell's own until PR 47: six still are, eleven are one entry with the other backlog cells' and two were twins
+    cell = m.cell(CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "gen-backlog-s64", 1)
+    assert m.config_entry(CONFIG)["file"] == f"perfbench/configs/{CONFIG}.json"
     mine = [x for x in d["per_layer"] if x.get("workloads") == [CELL]]
-    first = d["per_layer"].index(mine[0])                 # found where they were appended: a later PR appends behind them
-    assert [x["name"] for x in mine] == [n + ".lcf" for n in LCF] == [x["name"] for x in d["per_layer"][first:first + 19]]
-    assert {x["moves"] for x in mine} == {"serve_tok_s"} and first == 102
+    assert [x["name"] for x in mine] == [n + ".lcf" for n in LCF]
+    listed = [x for x in m.metrics_for(CELL, "per_layer") if x["moves"] != "setup_s"]
+    assert {x["name"] for x in listed} >= MINE and {x["moves"] for x in listed} == {"serve_tok_s"}
     assert {x["name"] for x in m.metrics_for(CELL, "end_to_end")} == {"serve_tok_s", "setup_s"}
     shares = [x["name"] for x in d["per_layer"] if "roofline" in x["name"] and CELL in x.get("workloads", ())]
     assert shares == ["moe_weight_stream_roofline.lcf", "mla_decode_roofline.lcf", "mla_chunk_roofline.lcf"]
 
 
 def test_the_23_part_metrics_of_pr_36_are_where_they_were_and_each_cell_has_its_unattributed_share():
-    """What `test_program_parts.py::test_the_manifest_holds_the_23_metrics_and_validates`
-    means, without its `per_layer[-23:]`: that pins PR 36's entries as the LAST
-    of the list, so it fails on any PR that appends a per-layer metric (this
-    one; PERF.md section 7), and the file is not a `model_config` PR's to edit."""
-    from .test_program_parts import NEW
+    """PR 36's entries are found by their first name and lie where they were
+    appended (as `test_program_parts.py::test_the_manifest_holds_the_23_metrics_and_validates`
+    finds them since PR 47: no slice from the list's end, so an append breaks
+    neither), and this cell's part shares are the three it shares and one of
+    its own."""
+    from .test_program_parts import NEW, PR_36
 
     m = Manifest(REPO)
-    old = [e for e in m.doc["per_layer"] if e["name"].startswith(NEW) and not e["name"].endswith(".lcf")]
-    first = m.doc["per_layer"].index(old[0])
-    assert len(old) == 23 and first == 102 - 23 and m.doc["per_layer"][first:first + 23] == old     # nothing moved
+    names = [e["name"] for e in m.doc["per_layer"]]
+    first = names.index(PR_36[0])
+    assert names[first:first + len(PR_36)] == PR_36     # nothing moved (23 until PR 47 made one entry of a shared reading)
     new = [e for e in m.doc["per_layer"] if e["name"].startswith(NEW)]
-    assert [e["name"] for e in new[23:]] == ["part_unattributed_share.lcf", "part_attn_share.lcf", "part_dense_ffn_share.lcf",
-                                             "part_moe_route_share.lcf"]
+    # this cell's part shares: its dense FFNs are an entry of its own, the other three it shares
+    assert {e["name"] for e in new if CELL in e["workloads"]} == {
+        "part_unattributed_share.backlog", "part_attn_share.backlog", "part_dense_ffn_share.lcf", "part_moe_route_share.backlog"}
     assert all(e["source"] == "device_trace" and e["unit"] == "%" for e in new)
     assert {m.metric_spec(e["name"])["reader"] for e in new} == {"part_share"}
     for cell in m.doc["workloads"]:
@@ -117,10 +125,10 @@ def test_traced_run_reports_every_lcf_metric_that_needs_no_device(manifest, resu
     out, _ = results[True]
     listed = {m["name"]: m for m in manifest.metrics_for(CELL, "per_layer")}
     setup = {"setup_compile_s", "setup_trace_lower_s", "setup_params_s"}     # every cell's: they move setup_s
-    assert set(listed) == {n + ".lcf" for n in LCF} | setup
+    assert MINE | setup <= set(listed)         # `<=`: a later PR may list the cell in an entry more
     host = {n for n, m in listed.items() if m["source"] != "device_trace"}
-    assert host == {"gen_tok_s.lcf", "decode_slots_active.lcf", "srv_step_host_p50_s.lcf", "moe_load_max_over_mean.lcf",
-                    "moe_zero_pair_share.lcf"} | setup <= set(out["metrics"])
+    assert {"gen_tok_s.backlog", "decode_slots_active.backlog", "srv_step_host_p50_s.backlog", "moe_streamed_per_hit.backlog",
+            "moe_load_max_over_mean.lcf", "moe_zero_pair_share.lcf"} | setup <= host <= set(out["metrics"])
     assert not (set(out["metrics"]) - host)      # no device plane on the CPU: those readers found nothing
     assert out["metrics"]["moe_load_max_over_mean.lcf"]["value"] >= 1.0
     # 8 of the stand-in's 24 router columns are identity experts: about a third of the pairs at seeded weights

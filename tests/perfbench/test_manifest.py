@@ -134,17 +134,37 @@ def test_reduced_may_not_name_a_width(real, key):
         _broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
 
 
-def test_a_table_of_eight_cells_takes_two_on_four_chips_and_not_three(real):
-    def grow(d):
-        first = d["workloads"][0]
-        for i in range(8 - len(d["workloads"])):
-            d["workloads"].append(dict(first, name=f"extra-{i}", traffic=f"extra-mix-{i}"))
-            for m in d["end_to_end"] + d["per_layer"]:
-                if first["name"] in m.get("workloads", ()):
-                    m["workloads"].append(f"extra-{i}")
-        first.update(chips=4)
+def _resize(d, n):
+    """``n`` cells whatever the table holds: a cell past the ``n``-th leaves
+    (from every metric's list too; a metric left with no cell and a
+    configuration left with none go with it), a missing one is a copy of the
+    first under a name and a mix of its own."""
+    del d["workloads"][n:]
+    tiny.keep_cells(d, {w["name"] for w in d["workloads"]})
+    used = {w["config"] for w in d["workloads"]}
+    d["configs"] = [c for c in d["configs"] if c["name"] in used]
+    first = d["workloads"][0]
+    while len(d["workloads"]) < n:
+        i = len(d["workloads"])
+        d["workloads"].append(dict(first, name=f"extra-{i}", traffic=f"extra-mix-{i}"))
+        for m in d["end_to_end"] + d["per_layer"]:
+            if first["name"] in m.get("workloads", ()):
+                m["workloads"].append(f"extra-{i}")
 
-    eight = _broken(real, grow)
+
+@pytest.mark.parametrize("start", [3, None, 11])
+def test_a_table_of_eight_cells_takes_two_on_four_chips_and_not_three(real, start):
+    """The eight are built from the committed table as it is (None), from one
+    cut to three cells and from one grown to eleven: how many cells are
+    committed is not this test's to pin."""
+    def eight_with_two_on_four(d):
+        if start is not None:
+            _resize(d, start)
+        _resize(d, 8)
+        for i, w in enumerate(d["workloads"]):
+            w.update(chips=4 if i < 2 else 1)
+
+    eight = _broken(real, eight_with_two_on_four)
     assert len(eight.doc["workloads"]) == 8 and sum(w["chips"] == 4 for w in eight.doc["workloads"]) == 2
     eight.validate(check_files=False)
     with pytest.raises(ManifestError, match="four-chip"):

@@ -21,6 +21,13 @@ from . import tiny
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "small.xplane.pb")
 NEW = ("part_", "recompute_share.", "dense_matmul_share.")
+# PR 36's 23 entries, in the order they were appended; since PR 47 the readings several cells take the same way
+# are one entry each (`.kx` and `.ms4` of two, `.doc`, `.kx` and `.ms4` of the unattributed share: `.backlog`), so 19
+PR_36 = (["part_mlp_share.train", "part_attn_proj_share.train", "part_attn_core_share.train", "part_head_share.train",
+          "part_optim_share.train", "recompute_share.train", "dense_matmul_share.train"]
+         + [f"part_{p}_share.{c}" for p in ("weights", "head") for c in ("chat", "loaded", "doc")]
+         + ["part_moe_route_share.backlog", "part_attn_share.backlog"]
+         + [f"part_unattributed_share.{c}" for c in ("train", "chat", "loaded", "backlog")])
 
 
 def entry(part, phase="none", dot=False, inside=(), op_name="x", source=""):
@@ -155,8 +162,10 @@ def test_nothing_without_a_trace_a_busy_device_or_the_parts_module(monkeypatch):
 def test_the_manifest_holds_the_23_metrics_and_validates():
     m = Manifest(REPO)
     m.validate()
+    names = [e["name"] for e in m.doc["per_layer"]]
+    first = names.index(PR_36[0])       # found by name: a later PR appends behind them, or before
+    assert names[first:first + len(PR_36)] == PR_36                       # where they were appended, nothing moved
     new = [e for e in m.doc["per_layer"] if e["name"].startswith(NEW)]
-    assert len(new) == 23 and m.doc["per_layer"][-23:] == new             # appended, nothing moved
     assert all(e["source"] == "device_trace" and e["unit"] == "%" for e in new)
     assert {m.metric_spec(e["name"])["reader"] for e in new} == {"part_share"}
     for cell in m.doc["workloads"]:

@@ -79,6 +79,16 @@ def stand_ins(repo: str = REPO) -> dict:
     return out
 
 
+def keep_cells(bm: dict, kept: set) -> None:
+    """Every metric's ``workloads`` cut to the cells in ``kept``; a metric left
+    with no cell goes."""
+    for group in ("end_to_end", "per_layer"):
+        for m in bm[group]:
+            if "workloads" in m:
+                m["workloads"] = [w for w in m["workloads"] if w in kept]
+        bm[group] = [m for m in bm[group] if m.get("workloads", True)]
+
+
 def make(tmp: str, repo: str = REPO):
     """``repo`` is the checkout whose benchmark is copied: this one, or a copy
     of it that a test has added a cell to."""
@@ -109,12 +119,7 @@ def make(tmp: str, repo: str = REPO):
     bm["configs"] = [{"name": c, "source": "tests", "file": f"perfbench/configs/{c}.json", "reduced": [], "why": "tests"}
                      for c in sorted({c for c, _ in pairs})]
     bm["workloads"] = cells
-    kept = {w["name"] for w in cells}
-    for group in ("end_to_end", "per_layer"):
-        for m in bm[group]:
-            if "workloads" in m:
-                m["workloads"] = [w for w in m["workloads"] if w in kept]
-        bm[group] = [m for m in bm[group] if m.get("workloads", True)]
+    keep_cells(bm, {w["name"] for w in cells})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return Manifest(root)
