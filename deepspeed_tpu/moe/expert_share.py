@@ -21,7 +21,10 @@ experts would have added is left out; nothing stands in for the other chips.
 
 Two scorings, one :func:`route`: ``sigmoid`` renormalised over the picks with a
 shared expert (K-EXAONE, Mistral Small 4) and ``softmax`` over 512 + 256
-columns, not renormalised, no shared expert (LongCat-Flash). The counts the
+columns, not renormalised, no shared expert (LongCat-Flash); and where the
+router is no single matrix the family hands the scores' arguments in
+(``logits``: ZAYA's three-layer MLP over a state that comes down the depth,
+softmax, one pick, the probability itself the weight). The counts the
 layer reports are the tokens each held expert got and, with identity columns,
 the pairs that chose one of those (the last entry).
 
@@ -90,15 +93,18 @@ SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": lambda x: jax.nn.softmax(x, ax
 
 
 def route(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True,
-          scoring: str = "sigmoid"):
+          scoring: str = "sigmoid", logits=None):
     """``u [T, E]`` → (``idx [T, k]`` int32 over all the router's columns, ``w
     [T, k]`` float32: ``scale`` times the picked scores, renormalised over the
     picks with ``norm_topk``). Scores (``scoring``: :data:`SCORINGS`) in
     float32 at full precision: the selection is discrete, and a bf16 pass
-    would flip near-ties that the float32 reference keeps."""
-    s = SCORINGS[scoring](jnp.dot(
-        u.astype(jnp.float32), router_w.astype(jnp.float32), precision=_HI
-    ))
+    would flip near-ties that the float32 reference keeps. ``logits [T,
+    columns]``: the scores' arguments where the FAMILY computes them (a router
+    that is no single matrix: ZAYA's MLP over a state carried down the depth);
+    ``u`` and ``router_w`` are then not read."""
+    if logits is None:
+        logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32), precision=_HI)
+    s = SCORINGS[scoring](logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
@@ -188,7 +194,7 @@ def kernel_runs(lp) -> bool:
     return grouped_experts.grouped_experts_ok(*lp["experts"]["w_gate"].shape[1:])
 
 
-def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid):
+def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits=None):
     """→ (the held experts' part for ``u [T, E]`` with the identity term;
     what says which pairs are held, ``[T, n_held]``: the weights ``wh`` under
     sigmoid scores (positive: a selected pair's weight is), the selection
@@ -196,7 +202,7 @@ def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid):
     picks among the identity columns, or None). A row that is no token
     (``valid``) picks nothing: no expert is read or counted for it."""
     with parts.part("moe.route"):
-        idx, w = route(u, lp["router"], lp["bias"], top_k, scale, norm_topk, scoring)
+        idx, w = route(u, lp.get("router"), lp["bias"], top_k, scale, norm_topk, scoring, logits)
         if valid is not None:
             idx = jnp.where(valid[:, None], idx, -1)
         wh = held_weights(idx, w, share)
@@ -224,25 +230,27 @@ def experts_streamed(counts, kernel: bool) -> int:
 
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
                        norm_topk: bool = True, valid: Optional[jnp.ndarray] = None,
-                       scoring: str = "sigmoid"):
+                       scoring: str = "sigmoid", logits: Optional[jnp.ndarray] = None):
     """``u [T, E]`` → (``y [T, E]``, ``counts [n_held]`` int32: the tokens
     each held expert got, and with ``share.n_zero`` one entry more, the pairs
     that chose an identity column; with ``valid [T]`` only those rows pick
     experts at all, e.g. the slots that hold a request: the others get the
     shared expert alone). ``lp``: ``router [E, n_experts + n_zero]``, ``bias``
     as wide, ``experts`` and, where the model has a shared expert, ``shared``,
-    each with ``w_gate, w_up, w_down``."""
+    each with ``w_gate, w_up, w_down``. ``logits [T, columns]``: the scores'
+    arguments from the family, in the place of ``u @ lp["router"]``
+    (:func:`route`)."""
     T, rows = u.shape[0], block_rows(u)
     if kernel_runs(lp) and rows < T:
-        blocks = lambda a: a.reshape(-1, rows, *a.shape[1:])
+        blocks = lambda a: None if a is None else a.reshape(-1, rows, *a.shape[1:])
         y, held, zero = jax.lax.map(
-            lambda b: _routed(b[0], lp, share, top_k, scale, norm_topk, scoring, b[1]),
-            (blocks(u), None if valid is None else blocks(valid)),
+            lambda b: _routed(b[0], lp, share, top_k, scale, norm_topk, scoring, b[1], b[2]),
+            (blocks(u), blocks(valid), blocks(logits)),
         )
         y, held = y.reshape(T, -1), held.reshape(T, -1)
         zero = None if zero is None else zero.reshape(T)
     else:
-        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid)
+        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits)
     if "shared" in lp:
         sh = lp["shared"]
         y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])

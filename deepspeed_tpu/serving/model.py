@@ -7,7 +7,7 @@ attention, the sampling); the model's module gives the rest through
 ``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
 ``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``,
 ``models/longcat_flash.LongcatFlashFamily``,
-``models/phi4flash.Phi4FlashFamily``). A sub-block is of one of four KINDS
+``models/phi4flash.Phi4FlashFamily``, ``models/zaya.ZayaFamily``). A sub-block is of one of four KINDS
 (``fam.kinds``; without it every one is the first): an attention that writes
 its own K/V (``attn``), a state-space mixer over a per-slot recurrent state
 (``ssm``: the third kind of state beside pages and rings, :func:`_ssm_block`),
@@ -259,6 +259,23 @@ class Family:
     - ``qkv(lp, h, positions, l) -> q [B,S,H,D], k, v [B,S,KV,D]``: the
       norm before attention, the projections and whatever the family does
       to a head before it is cached (QK norm, rotary positions).
+    - ``carry_width`` (optional; 0 or absent: none): an ``"attn"`` sub-block
+      that CARRIES ROWS, the fourth kind of per-slot state (pages, rings,
+      scan state, and rows under a paged layer). Its q, k and v of a call's
+      first rows need rows of the call before (ZAYA's CCA: two kernel-2
+      convolutions over the latent and a value shifted by one token), so the
+      programs keep ``carry_width`` values a slot and ``"attn"`` sub-block in
+      one more donated pool (``state``'s last, ``[attn sub-blocks, slots,
+      carry_width]`` in the cache's type; :func:`_qkv_carried`) and the
+      family gives ``qkv`` in two pieces: ``attn_in(lp, h) -> p [B,S,Wp]``,
+      what a row needs of the weights alone (the norm, the projections), and
+      ``attn_mix(lp, p, prev [B, carry_width], positions, l) -> q, k, v, nxt
+      [B,S,carry_width]``: each batch row a sequence that ``prev`` precedes
+      (zeros: a request's start), ``nxt[:, t]`` what a sequence ending with
+      row ``t`` hands to its next call. K and V are the FINISHED heads: the
+      paged kernels read them as any grouped-query cache. Every program
+      branches on it at TRACE time: a family that states none traces what it
+      traced without.
     - ``attn_out(lp, o [B,S,H*D], tp_axis) -> [B,S,E]``
     - ``mlp(lp, h, l, valid, tp_axis) -> ([B,S,E], counts [experts_held] |
       None)``: the norm before it and the MLP or expert layer; ``valid``
@@ -371,7 +388,8 @@ def _window_views(fam, slots, pos0, page: int, ring: int):
 def _result(k_pool, v_pool, scales, win, token, counts, state=None):
     """A program's results in the order the scheduler takes them: the paged
     pools, an int8 pool's scales, a window family's ring pools, a recurrent
-    family's two state pools, the token(s) and, for a family with expert layers, the tokens each held expert got
+    family's two state pools and the rows a family's attentions carry (``state``, as it came), the token(s) and,
+    for a family with expert layers, the tokens each held expert got
     ``[sparse layers, experts_held]`` (one entry more a layer where the
     router has identity columns: the pairs that chose one)."""
     out = (k_pool, v_pool)  # v_pool None: a latent family's (ProgramSet.aot drops it)
@@ -546,6 +564,48 @@ def _mixer_without_kv(fam, lp, h, l, li, positions, carry, state, tp_axis, rows,
 
 
 # ---------------------------------------------------------------------------
+# an attention that carries rows (``fam.carry_width``): K and V paged like any
+# other, and ``carry_width`` values a slot and sub-block from call to call
+#
+# ``rows [La, slots, carry_width]`` (the cache's type) is the LAST of ``state``,
+# donated through every program like the scan state. The whole-prompt program
+# takes a slot's rows at the prompt's TRUE length, not the bucket's; the chunk
+# program hands them from chunk to chunk and starts a request from zeros
+# whatever the slot held; a decode row reads and shifts its slot's; an idle
+# slot's row, or padding, moves nothing.
+# ---------------------------------------------------------------------------
+
+def _qkv_carried(fam, lp, h, positions, l, state, C: int = 0, chunk=None, real=None):
+    """``fam.qkv`` for sub-block ``l`` of a family that carries rows (``state``'s
+    last pool, whose layers are the ``"attn"`` sub-blocks in order), over the
+    rows of ``h`` as :func:`_ssm_block` takes them: the first ``C`` ONE slot's
+    chunk, ``chunk = (slot, start, n_real)``, the others a row a slot, ``real
+    [B]`` the slots that decode. The projections see all rows once
+    (``attn_in``); the chunk and the decode rows part for ``attn_mix`` alone.
+    → ``(q, k, v, state)``, q, k and v laid out like ``h``."""
+    rows, ai = state[-1], sub_block_kinds(fam)[:l].count("attn")
+    with parts.part("attn.qkv"):
+        p = fam.attn_in(lp, h)
+    qkv = None
+    if C:
+        slot, start, n_real = chunk
+        prev = jnp.where(start == 0, jnp.zeros((), rows.dtype), rows[ai, slot])
+        *qkv, nxt = fam.attn_mix(lp, p[:, :C], prev[None], positions[..., :C], l)
+        # the next call's: what the chunk's last REAL row hands on
+        last = lax.dynamic_index_in_dim(nxt[0], jnp.maximum(n_real - 1, 0), 0, keepdims=False)
+        rows = rows.at[ai, slot].set(jnp.where(n_real > 0, last.astype(rows.dtype), prev))
+        p, positions = jnp.swapaxes(p[:, C:], 0, 1), positions[C:, None]
+    if real is not None:
+        prev = rows[ai]
+        *qkv_d, nxt = fam.attn_mix(lp, p, prev, positions, l)
+        rows = rows.at[ai].set(jnp.where(real[:, None], nxt[:, 0].astype(rows.dtype), prev))
+        qkv = qkv_d if qkv is None else [
+            jnp.concatenate([c, jnp.swapaxes(d, 0, 1)], axis=1) for c, d in zip(qkv, qkv_d)
+        ]
+    return (*qkv, state[:-1] + (rows,))
+
+
+# ---------------------------------------------------------------------------
 # paged prefill (one request into one slot's pages)
 # ---------------------------------------------------------------------------
 
@@ -681,7 +741,7 @@ def paged_prefill(
     win: tuple = None,    # (k_win, v_win) [Lw, 1 + slots * ring, KV, page, D]
     slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
     ring: int = 0,        # static: pages of one slot's ring
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
     """→ (k_pool, v_pool, first_token [1]), with ``scales`` threaded between
     the pools and the token when the pool is quantized (ISSUE 12), a window
@@ -697,6 +757,7 @@ def paged_prefill(
     valid = (positions < prompt_len) if fam.sparse_layers else None
     counts, carry = [], None
     kinds, stop = sub_block_kinds(fam), getattr(fam, "stop_after", None)
+    carried = getattr(fam, "carry_width", 0)   # an attention that carries rows: ``state``'s last pool
     kv_of = {}       # a cross layer's source: the prompt's K and V there
     stopped = False  # the stream is the prompt's last row alone
 
@@ -735,8 +796,13 @@ def paged_prefill(
                 fam, lp, h, o, l, valid, tp_axis, counts, carry, fam.attn_out_expanded
             )
             continue
-        with parts.part("attn.qkv"):
-            q, k_, v = fam.qkv(lp, h, positions, l)
+        if carried:
+            q, k_, v, state = _qkv_carried(
+                fam, lp, h, positions, l, state, Sp, (slot, jnp.zeros((), jnp.int32), prompt_len)
+            )
+        else:
+            with parts.part("attn.qkv"):
+                q, k_, v = fam.qkv(lp, h, positions, l)
         if windowed:
             o, win = _attention_prefill_window(
                 fam, q, k_, v, win, li, slot, prompt_len, fam.windows[l], ring
@@ -925,7 +991,7 @@ def paged_decode_step(
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
     win: tuple = None,    # a window family's ring pools
     ring: int = 0,
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
     """→ (k_pool, v_pool, next_tokens [B]); ``scales`` threaded through and
     returned before the tokens when the pool is quantized, a window family's
@@ -946,7 +1012,7 @@ def paged_decode_step(
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
     valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
     counts, carry = [], None
-    kinds = sub_block_kinds(fam)
+    kinds, carried = sub_block_kinds(fam), getattr(fam, "carry_width", 0)
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
@@ -960,8 +1026,11 @@ def paged_decode_step(
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
             continue
-        with parts.part("attn.qkv"):
-            q, k_, v = fam.qkv(lp, h, positions, l)
+        if carried:
+            q, k_, v, state = _qkv_carried(fam, lp, h, positions, l, state, real=block_tables[:, 0] != 0)
+        else:
+            with parts.part("attn.qkv"):
+                q, k_, v = fam.qkv(lp, h, positions, l)
         if fam.kv_pools == 1:
             k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_[:, 0])
             o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_decode")
@@ -1139,9 +1208,9 @@ def paged_verify_step(
     lands on the page of a position ``ring`` pages back, which no query of
     this step or a later one reaches.)"""
     fam = cfg.serving_family()
-    if set(sub_block_kinds(fam)) != {"attn"}:
-        # a rejected draft's rows cannot be taken back out of a recurrent state
-        raise NotImplementedError("the verify step serves families whose sub-blocks are all attentions")
+    if set(sub_block_kinds(fam)) != {"attn"} or getattr(fam, "carry_width", 0):
+        # a rejected draft's rows cannot be taken back out of a recurrent state, nor out of carried rows
+        raise NotImplementedError("the verify step serves families whose sub-blocks are all attentions that carry no rows")
     B, T = tokens.shape
     page = k_pool.shape[3]
     # clamp garbage positions (past the decode budget) into the embedding
@@ -1216,7 +1285,7 @@ def paged_mixed_step(
     win: tuple = None,    # a window family's ring pools
     slot: jnp.ndarray = None,  # traced i32: the prefilling slot, whose ring a window layer writes
     ring: int = 0,
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools
+    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
     """One chunk of ONE slot's incremental prefill (ISSUE 10) and one token
     for every decoding slot, through every weight ONCE → (k_pool, v_pool,
@@ -1265,7 +1334,10 @@ def paged_mixed_step(
     where it falls in this chunk): the sub-blocks there write nothing a later
     call reads, so the other prompt rows have no business in them. A cross
     layer then reads its source's pages for that row and the decode rows in
-    one decode-shaped call (the chunk's row under ``chunk_row``)."""
+    one decode-shaped call (the chunk's row under ``chunk_row``). An attention
+    that carries rows (``fam.carry_width``) takes the chunk's from the slot's
+    (zeros at ``start`` 0), leaves what the chunk's last real row hands on, and
+    shifts each decoding slot's by its one row (:func:`_qkv_carried`)."""
     fam = cfg.serving_family()
     B, C = tokens.shape[0], input_ids.shape[1]
     page = k_pool.shape[3]
@@ -1283,6 +1355,7 @@ def paged_mixed_step(
     live = jnp.any(real) if B >= SKIP_IDLE_READS_FROM_SLOTS else None
     counts, carry = [], None
     kinds, stop = sub_block_kinds(fam), getattr(fam, "stop_after", None)
+    carried = getattr(fam, "carry_width", 0)
     # the chunk's row that is sampled (its last real one, on a prompt's final chunk)
     idx = jnp.clip(prompt_len - 1 - start, 0, C - 1)
     n_real = jnp.clip(prompt_len - start, 0, C)
@@ -1330,8 +1403,13 @@ def paged_mixed_step(
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
             continue
-        with parts.part("attn.qkv"):
-            q, k_, v = fam.qkv(lp, h, positions, l)
+        if carried:
+            q, k_, v, state = _qkv_carried(
+                fam, lp, h, positions, l, state, C, (slot, start, n_real), real if B else None
+            )
+        else:
+            with parts.part("attn.qkv"):
+                q, k_, v = fam.qkv(lp, h, positions, l)
         (qc, qd), (kc, kd) = part(q), part(k_)
         od = None
         if fam.kv_pools == 1:

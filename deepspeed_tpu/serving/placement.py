@@ -317,10 +317,17 @@ class ProgramSet:
     the cache's type (``serving/model._ssm_block``), the last two of
     :meth:`pool_args`, donated like the ring pools. No allocator: a slot's row
     is the slot's, and the program that takes a request's first rows starts it
-    from zeros."""
+    from zeros.
+
+    A family whose attentions CARRY ROWS (``fam.carry_width``: q, k and v of a
+    call's first rows need rows of the call before, while K and V are paged as
+    ever) has a fourth kind: ``carry_width`` values a slot and ``"attn"``
+    sub-block in the cache's type, ``[La, slots, carry_width]``, the LAST of
+    ``state_pools`` (``serving/model._qkv_carried``), donated and started from
+    zeros in the same way."""
 
     kv_pools = 2  # a K and a V pool; 1: a latent family's one pool
-    state_pools = None  # (ssm, conv) for a family with state-space sub-blocks
+    state_pools = None  # (ssm, conv) for a family with state-space sub-blocks, then the carried rows' pool
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
                  page_size: int, cache_dtype, params: PyTree,
@@ -365,6 +372,14 @@ class ProgramSet:
                 placement.put(jnp.zeros((n_state, int(ring_slots), N, d_inner), jnp.float32)),
                 placement.put(jnp.zeros((n_state, int(ring_slots), K - 1, d_inner), self.k_pool.dtype)),
             )
+        self.carry_pool_bytes = 0
+        carry = int(getattr(fam, "carry_width", 0) or 0)
+        if carry:
+            rows = placement.put(jnp.zeros(
+                (self.n_layer + self.n_window_layer, int(ring_slots), carry), self.k_pool.dtype
+            ))
+            self.state_pools = (self.state_pools or ()) + (rows,)
+            self.carry_pool_bytes = int(rows.nbytes)
         self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
         self.params = placement.shard_params(params)
@@ -380,7 +395,8 @@ class ProgramSet:
     def pool_args(self) -> tuple:
         """The donated pool operands, in program order: K, V (a latent
         family: its one pool), an int8 pool's scales, a window family's two
-        ring pools, a recurrent family's two state pools."""
+        ring pools, a recurrent family's two state pools, the pool of the rows
+        a family's attentions carry."""
         out = (self.k_pool,) + ((self.v_pool,) if self.v_pool is not None else ())
         if self.kv_scales is not None:
             out += (self.kv_scales,)
@@ -538,8 +554,8 @@ class ProgramSet:
             self.window_pools = (rest[0], rest[1])
             rest = rest[2:]
         if self.state_pools is not None:
-            self.state_pools = (rest[0], rest[1])
-            rest = rest[2:]
+            n = len(self.state_pools)
+            self.state_pools, rest = tuple(rest[:n]), rest[n:]
         return rest[0] if len(rest) == 1 else rest
 
     def set_pools(self, pools: tuple) -> None:
@@ -628,5 +644,5 @@ class ProgramSet:
     def state_pool_bytes(self) -> int:
         """Bytes of the recurrent state pools: slots x state-space sub-blocks
         x (the scan state and the convolution's rows), whatever the contexts'
-        lengths."""
-        return sum(int(w.nbytes) for w in self.state_pools or ())
+        lengths (the rows an attention carries are ``carry_pool_bytes``)."""
+        return sum(int(w.nbytes) for w in self.state_pools or ()) - self.carry_pool_bytes

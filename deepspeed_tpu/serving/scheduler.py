@@ -125,16 +125,17 @@ def _split_scales(rest: tuple, quantized: bool):
     return None, rest
 
 
-def _split_pools(rest: tuple, quantized: bool, windowed: bool, recurrent: bool = False):
-    """:func:`_split_scales`, then a window family's two ring pools and a
-    recurrent family's two state pools: → ``(scales | None, (k_win, v_win) |
-    None, (ssm, conv) | None, inputs)``."""
+def _split_pools(rest: tuple, quantized: bool, windowed: bool, n_state: int = 0):
+    """:func:`_split_scales`, then a window family's two ring pools and the
+    ``n_state`` state pools (a recurrent family's two, and the one of rows a
+    family's attentions carry): → ``(scales | None, (k_win, v_win) | None,
+    state pools | None, inputs)``."""
     scales, rest = _split_scales(rest, quantized)
     win = state = None
     if windowed:
         win, rest = (rest[0], rest[1]), rest[2:]
-    if recurrent:
-        state, rest = (rest[0], rest[1]), rest[2:]
+    if n_state:
+        state, rest = tuple(rest[:n_state]), rest[n_state:]
     return scales, win, state, rest
 
 
@@ -219,7 +220,7 @@ class ServingEngine:
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
                 "including injected HF GPT-2, exaone_moe, mistral4, "
-                "longcat_flash and phi4flash); got "
+                "longcat_flash, phi4flash and zaya); got "
                 f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
@@ -235,7 +236,12 @@ class ServingEngine:
         # recurrent state a slot, which is no page, cannot be cut at a prefix
         # and cannot be rolled back behind a rejected draft without a snapshot
         self.recurrent = "ssm" in smodel.sub_block_kinds(fam)
-        if self.windowed or self.latent or self.recurrent:
+        # a family whose attentions carry rows keeps a fourth: under its paged
+        # K and V, the rows before a call's first (serving/model._qkv_carried).
+        # A cached prefix's pages without the rows at its end would serve a
+        # wrong first token silently
+        self.carried = bool(getattr(fam, "carry_width", 0))
+        if self.windowed or self.latent or self.recurrent or self.carried:
             plc_ = getattr(config, "placement", None)
             spec_on = bool(getattr(getattr(config, "speculative", None), "enabled", False))
             for on, what in (
@@ -252,7 +258,7 @@ class ServingEngine:
                  "serving.placement.tp > 1"),
                 (plc_ is not None and bool(getattr(plc_, "disaggregate", False)),
                  "serving.placement.disaggregate"),
-                (self.recurrent and spec_on, "serving.speculative"),
+                ((self.recurrent or self.carried) and spec_on, "serving.speculative"),
             ):
                 if on:
                     raise ValueError(
@@ -263,6 +269,13 @@ class ServingEngine:
                             "rows live in a per-slot pool beside the paged "
                             "pool, which this mechanism does not handle"
                             if self.recurrent else
+                            f"carried attention rows ({type(mcfg).__name__}): "
+                            "the queries, keys and values of a call's first "
+                            "rows need the rows before them, which live in a "
+                            "per-slot pool beside the paged pool; this "
+                            "mechanism does not handle it (pages without the "
+                            "rows at their end would serve a wrong token)"
+                            if self.carried else
                             f"sliding-window layers ({type(mcfg).__name__}): a "
                             "window layer's KV lives in a per-slot ring beside "
                             "the paged pool, which this mechanism does not handle"
@@ -710,6 +723,12 @@ class ServingEngine:
             "pages of one slot's ring in the window pools (0 = the model has "
             "no sliding-window layer)",
         )
+        self._g_carry_bytes = m.gauge(
+            "serving_attn_carry_bytes",
+            "bytes of the rows the attentions of a family carry from call to "
+            "call under their paged K and V (slots x attention sub-blocks x "
+            "carry_width; 0 = the model's attentions carry none)",
+        )
         self._g_experts_held = m.gauge(
             "serving_moe_experts_held",
             "routed experts of a layer held on this chip (0 = no expert layer)",
@@ -1055,12 +1074,13 @@ class ServingEngine:
         # The chunk program is the MIXED step: one prefilling slot's chunk
         # and every slot's decode row through the weights once. Its host
         # operands are the decode step's four, then the chunk's.
-        windowed, ring, recurrent = self.windowed, self.ring_pages, self.recurrent
+        windowed, ring = self.windowed, self.ring_pages
+        n_state = len(self.decode_set.state_pools or ())   # the state pools a program threads
 
         def make_fns(cfg, tp_axis):
             def prefill_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (ids, plen, page_ids, key, *slot) = _split_pools(
-                    rest, quant, windowed, recurrent
+                    rest, quant, windowed, n_state
                 )
                 return smodel.paged_prefill(
                     cfg, params, ids, plen, k_pool, v_pool, page_ids, key,
@@ -1071,7 +1091,7 @@ class ServingEngine:
 
             def decode_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (tokens, seq_lens, bt, keys) = _split_pools(
-                    rest, quant, windowed, recurrent
+                    rest, quant, windowed, n_state
                 )
                 return smodel.paged_decode_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt, keys,
@@ -1081,7 +1101,7 @@ class ServingEngine:
 
             def verify_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (tokens, seq_lens, bt) = _split_pools(
-                    rest, quant, windowed, recurrent
+                    rest, quant, windowed, n_state
                 )
                 return smodel.paged_verify_step(
                     cfg, params, tokens, seq_lens, k_pool, v_pool, bt,
@@ -1094,7 +1114,7 @@ class ServingEngine:
                 scales, win, state, (
                     tokens, seq_lens, bt, keys,
                     ids, start, plen, page_ids, bt_row, key, *slot,
-                ) = _split_pools(rest, quant, windowed, recurrent)
+                ) = _split_pools(rest, quant, windowed, n_state)
                 return smodel.paged_mixed_step(
                     cfg, params, tokens, seq_lens, ids, start, plen, k_pool,
                     v_pool, bt, page_ids, bt_row, keys, key,
@@ -1116,7 +1136,7 @@ class ServingEngine:
         # what a program returns after the pools: the token(s) and, for a
         # family with expert layers, the tokens each held expert got
         n_results = 2 if self.family.sparse_layers else 1
-        slot_sds = (S((), i32),) if windowed else ()
+        slot_sds = (S((), i32),) if self._slot_operand(0) else ()
         # the decode rows' host operands: tokens, lengths, tables, keys
         rows_sds = (
             S((self.max_slots,), i32), S((self.max_slots,), i32),
@@ -1343,6 +1363,7 @@ class ServingEngine:
             "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
             "window": ds.window_pool_bytes(),
             **({"state": ds.state_pool_bytes()} if self.recurrent else {}),
+            **({"carry": ds.carry_pool_bytes} if self.carried else {}),
         }
         for cls, n in kv_bytes.items():
             self._g_kv_bytes.set(n, **{"class": cls})
@@ -1353,6 +1374,7 @@ class ServingEngine:
         )
         self._g_kv_row_bytes.set(row_bytes)
         self._g_ring_pages.set(self.ring_pages)
+        self._g_carry_bytes.set(ds.carry_pool_bytes)
         self._g_experts_held.set(self.family.experts_held)
         # the expert layers' form, as compiled: a program names the kernel or not
         self._moe_kernel = bool(self.family.sparse_layers) and (
@@ -1366,6 +1388,8 @@ class ServingEngine:
         attrs.update(window_pages_per_slot=self.ring_pages,
                      moe_experts_held=self.family.experts_held,
                      kv_row_bytes=row_bytes)
+        if self.carried:
+            attrs["carry_rows"] = ds.carry_pool_bytes
         return attrs
 
     def _moe_attrs(self, counts: np.ndarray, n_tokens: int) -> dict:
@@ -2234,9 +2258,10 @@ class ServingEngine:
         self._start_decoding(slot_i, tok0)
 
     def _slot_operand(self, slot_i: int) -> tuple:
-        """The last host operand of a window family's prefill and chunk
-        programs: the slot, whose ring they write."""
-        return (np.asarray(slot_i, np.int32),) if self.windowed else ()
+        """The last host operand of the prefill and chunk programs of a
+        family that keeps per-slot state there: the slot, whose ring a window
+        layer writes and whose carried rows an attention reads and leaves."""
+        return (np.asarray(slot_i, np.int32),) if self.windowed or self.carried else ()
 
     def _token_of(self, out):
         """The sampled token of a prefill program's results (a family with
@@ -2805,6 +2830,13 @@ class ServingEngine:
                 "session migration is not available for a model with "
                 "recurrent state: the transport moves a slot's paged row, and "
                 "its scan state and convolution rows would stay behind"
+            )
+        if self.carried:
+            raise ValueError(
+                "session migration is not available for a model with carried "
+                "attention rows: the transport moves a slot's paged row, and "
+                "the rows its next token's queries, keys and values need "
+                "would stay behind"
             )
         if self.windowed:
             raise ValueError(

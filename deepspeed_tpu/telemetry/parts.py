@@ -47,6 +47,8 @@ PARTS = (
     "embed",        # token and position embeddings
     "norm",         # the residual stream's norms
     "attn.qkv",     # projections, biases, QK norm, rotary, the absorbed w_uk product
+    "attn.cca",     # what CCA adds between the projections and the kernel: two short convolutions over the latent,
+                    # the q-k mean, norm, temperature, rotary and the value shift (models/zaya.py)
     "attn.core",    # the attention kernel or its jnp form, and all between the projections and it
     "attn.out",     # the output projection
     "kv.write",     # token and page writes into pools and rings

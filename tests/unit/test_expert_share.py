@@ -404,3 +404,26 @@ def test_held_counts_come_from_the_selection_where_a_softmax_score_rounds_to_zer
     _, counts = _layer_call(lp, u, share)
     assert int(counts.sum()) == T * KZ
     assert int((es.held_weights(idx, w, share) > 0).sum()) + int(counts[-1]) < T * KZ
+
+
+@pytest.mark.parametrize("scoring,top_k,norm", [("sigmoid", K, True), ("softmax", 1, False)], ids=["sigmoid-top4", "softmax-top1"])
+def test_scores_handed_in_equal_the_linear_routes_where_they_are_u_times_router(u, scoring, top_k, norm):
+    """A family whose router is no single matrix (ZAYA's MLP over a state
+    carried down the depth) hands ``logits`` in; where they ARE ``u @
+    router``, picks, weights, output and counts are the linear route's, and
+    the router's matrix is then not read at all."""
+    lp = _layer()
+    share = es.ExpertShare(N)
+    logits = jnp.dot(u, lp["router"], precision=jax.lax.Precision.HIGHEST)
+    want_idx, want_w = es.route(u, lp["router"], lp["bias"], top_k, SCALE, norm, scoring)
+    got_idx, got_w = es.route(u, None, lp["bias"], top_k, SCALE, norm, scoring, logits=logits)
+    np.testing.assert_array_equal(np.asarray(got_idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(got_w), np.asarray(want_w))
+    valid = jnp.arange(T) % 5 != 0
+    want_y, want_c = es.expert_share_layer(lp, u, share, top_k, SCALE, norm, valid, scoring)
+    no_router = {k: v for k, v in lp.items() if k != "router"}
+    got_y, got_c = es.expert_share_layer(no_router, u, share, top_k, SCALE, norm, valid, scoring, logits=logits)
+    np.testing.assert_array_equal(np.asarray(got_y), np.asarray(want_y))
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
+    other = es.expert_share_layer(no_router, u, share, top_k, SCALE, norm, valid, scoring, logits=-logits)[0]
+    assert float(jnp.abs(other - want_y).max()) > 1e-3      # the scores handed in are what routes
