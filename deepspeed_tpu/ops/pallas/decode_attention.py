@@ -134,14 +134,24 @@ def _page_tile_bytes(page: int, D: int, itemsize: int) -> int:
     return _round_up(page, max(1, 32 // itemsize)) * _round_up(D, 128) * itemsize
 
 
+# The most pages one grid step of the paged decode kernel takes. Each is an
+# input of the call for K and one for V, with an index map of its own to
+# trace and lower: 16 pages a step where 4 cost ZAYA's two programs 1.05 s of
+# set-up on the chip's host (PERF.md, PR 50), about 20 ms a page input. 32 is
+# what the widest served call carries (K-EXAONE's 512 keys of 16-key pages).
+PAGE_INPUTS = 32
+
+
 def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
                         n_pages: Optional[int] = None):
     """(kv-heads, pages) one grid step of the paged decode kernel holds, from
     the shapes alone: K and V of the block, double-buffered, at their padded
     VMEM tile sizes, stay inside ``VMEM_RESIDENT_BYTES``. All heads of a page
     when they fit (then as many pages as fit, a power of two, at most
-    ``S_BLOCK`` keys and the table's width); else the largest divisor of
-    ``KV`` whose single page fits. ``None`` when one head's page does not."""
+    ``PAGE_INPUTS`` and the table's width: few heads of long pages take the
+    pages their bytes leave room for, not a count of keys); else the largest
+    divisor of ``KV`` whose single page fits. ``None`` when one head's page
+    does not."""
     from .flash_attention import VMEM_RESIDENT_BYTES
 
     tile = _page_tile_bytes(page, D, itemsize)
@@ -150,7 +160,7 @@ def paged_decode_blocks(KV: int, page: int, D: int, itemsize: int = 2,
         return None
     if fit < KV:
         return max(h for h in range(1, fit + 1) if KV % h == 0), 1
-    cap = min(fit // KV, max(1, S_BLOCK // page), n_pages or S_BLOCK)
+    cap = min(fit // KV, PAGE_INPUTS, n_pages or PAGE_INPUTS)
     return KV, 1 << (cap.bit_length() - 1)
 
 
@@ -168,13 +178,14 @@ def paged_multitoken_blocks(KV: int, page: int, D: int, T: int,
     """(kv-heads, pages) one grid step of the multi-token paged kernel holds,
     from the shapes alone. It starts from :func:`paged_decode_blocks`' pair
     and cuts the pages so that a head's ``[rep * T, G * page]`` score tile
-    keeps about 8 x ``S_BLOCK`` elements: the decode kernel's block for a
-    sublane tile of rows (the verify shape), 128 keys, one lane tile, from
-    32 rows on (a chunk computes, and masks, no further past its reach than
-    that). Then the step has to fit ``PAGED_MULTITOKEN_VMEM_BYTES``: beside
-    the K and V page buffers a head costs its query and output blocks
-    (double-buffered), the float32 (m, l, acc) scratch, the block's K and V
-    joined in the query's type and the float32 scores and probabilities.
+    keeps about 8 x ``S_BLOCK`` elements: ``S_BLOCK`` keys for a sublane
+    tile of rows (the verify shape: at 16-key pages the decode kernel's
+    block), 128 keys, one lane tile, from 32 rows on (a chunk computes, and
+    masks, no further past its reach than that). Then the step has to fit
+    ``PAGED_MULTITOKEN_VMEM_BYTES``: beside the K and V page buffers a head
+    costs its query and output blocks (double-buffered), the float32
+    (m, l, acc) scratch, the block's K and V joined in the query's type and
+    the float32 scores and probabilities.
     Fewer pages first (down to the lane tile), then fewer heads a step (the
     largest divisor of ``KV`` that fits). ``None`` when one head does not."""
     decode = paged_decode_blocks(KV, page, D, itemsize, n_pages)
@@ -253,7 +264,8 @@ def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
     and attends keys at positions ``<= at[b] + t``, so the last own block is
     the one the tokens reach, ``(at + T - 1) // (G * page)``. Blocks past it
     skip their compute, and the walked table (:func:`_walked_table`) names
-    for them the pages the last own block named, so Pallas fetches nothing.
+    for them the pages the last own block named, so Pallas fetches nothing,
+    or the next slot's first block (:func:`_first_blocks_ahead`).
     ``rest`` holds the block's ``G`` K pages and ``G`` V pages, ``[1, HB,
     page, D]`` each. A page ref past the slot's last page holds one of the
     slot's earlier pages; its scores are masked and its probabilities are
@@ -385,6 +397,21 @@ def _walked_table(block_tables, last, n_blk: int, G: int):
     return jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32), e, axis=1)
 
 
+def _first_blocks_ahead(walk, last, G: int):
+    """:func:`_walked_table`'s table with the blocks past a slot's last own
+    one naming the NEXT slot's first block (the last slot's keep what they
+    name). The pipeline fetches a step's pages during the step before, and
+    the step before a slot's first is the slot before's last SKIPPED one,
+    over in 0.15 us: the first block's DMAs were waited for in full, once a
+    slot and layer. Named here, they run during the slot before's last own
+    step. For a grid row that is a slot (one head block): a row's table is
+    its slot's."""
+    n_blk = walk.shape[1] // G
+    blk = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :] // G
+    first = jnp.concatenate([walk[1:, :G], walk[-1:, -G:]])  # [B, G]
+    return jnp.where(blk > last // G, jnp.tile(first, (1, n_blk)), walk)
+
+
 def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
                 HB: int, G: int, scales, layer: Optional[int], interpret: bool,
                 lo=None, name: Optional[str] = None):
@@ -404,6 +431,8 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
     n_pages = block_tables.shape[1]
     n_blk, GP = -(-n_pages // G), G * page
     walk = _walked_table(block_tables, last[:, None], n_blk, G)
+    if nhb == 1:
+        walk = _first_blocks_ahead(walk, last[:, None], G)
 
     def row(r):  # grid row -> (slot, head block)
         return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
@@ -522,9 +551,11 @@ def paged_decode_attention(
     shapes). Each of the ``G`` page inputs is the same pool under its own
     index map: the scalar-prefetched table names the page, one DMA brings
     its whole ``[KV, page, D]`` run, and the map stops at the slot's own
-    last page, so table entries past ``pos[b] // page`` are never read. GQA
-    (KV < H) reads the group's pool column once for its ``rep`` query
-    heads. ``scales`` (ISSUE 12): int8 pools are served by the same kernel;
+    last page, so table entries past ``pos[b] // page`` are never read; the
+    blocks a slot skips name the next slot's first block, which is so
+    fetched ahead (:func:`_first_blocks_ahead`). GQA (KV < H) reads the
+    group's pool column once for its ``rep`` query heads. ``scales``
+    (ISSUE 12): int8 pools are served by the same kernel;
     the slots' per-page scale rows are gathered through the table into
     per-key columns here (a few hundred KB beside the halved code bytes)
     and applied to the scores and the probabilities in VMEM.

@@ -629,6 +629,67 @@ class TestPagedDecodeServedShape:
         assert paged_decode_blocks(64, 128, 128, 4, 3) == (16, 1)
         self._check([5, 300], H=64, KV=64, D=128, page=128, n=3, seed=4)
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_few_kv_heads_of_long_pages_take_a_wide_block(self, dtype):
+        """ISSUE 50, ZAYA's served shape: 2 kv heads of 4 query heads x 128,
+        pages of 128 keys, a table of 48. A grid step holds 16 pages, 2 048
+        keys; lengths on and around its block edges, an empty and a full
+        slot, and slots of fewer pages than a block (their unused inputs of
+        block 0 name the slot's first page)."""
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_blocks,
+        )
+
+        dtype = jnp.dtype(dtype)
+        assert paged_decode_blocks(2, 128, 128, dtype.itemsize, 48) == (
+            (2, 16) if dtype.itemsize == 2 else (2, 8)
+        )
+        self._check([0, 127, 128, 2047, 2048, 2049, 4096, 6143], H=8, KV=2,
+                    D=128, page=128, n=48, dtype=dtype,
+                    tol=2e-5 if dtype.itemsize == 4 else 2e-2, seed=11)
+
+    @pytest.mark.parametrize("KV,rep,D,page,n,G", [
+        (2, 4, 128, 128, 48, 16),  # ZAYA's served shape
+        (25, 1, 64, 16, 64, 8),    # GPT-2-XL's
+        (8, 8, 128, 16, 224, 32),  # K-EXAONE's full layer
+    ], ids=["zaya", "xl", "kexaone"])
+    def test_a_slots_first_block_is_fetched_ahead(self, monkeypatch, KV, rep, D, page, n, G):
+        """The blocks a slot skips name the NEXT slot's first block (so that
+        it is fetched behind arithmetic): an own block's entries are
+        ``_walked_table``'s, the last slot's skipped blocks keep what they
+        named, and the numbers are to the bit those of the plain table, which
+        no step computes from."""
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        assert da.paged_decode_blocks(KV, page, D, 2, n) == (KV, G)
+        GP, n_blk = G * page, n // G
+        pos = [0, GP // 4 - 1, GP - 1, GP, n * page - 1, 2 * GP + 4, page + 2, GP - page]
+        kp, vp, bt, posj = self._pool(pos, KV, D, page, n, jnp.bfloat16, 12)
+        last = (posj // page)[:, None]
+        plain = np.asarray(da._walked_table(bt, last, n_blk, G))
+        ahead = np.asarray(da._first_blocks_ahead(jnp.asarray(plain), last, G))
+        for b, p in enumerate(pos):
+            own = p // GP + 1  # the slot's own blocks
+            np.testing.assert_array_equal(ahead[b, :own * G], plain[b, :own * G])
+            nxt = plain[b + 1, :G] if b + 1 < len(pos) else plain[b, -G:]
+            for blk in range(own, n_blk):
+                np.testing.assert_array_equal(ahead[b, blk * G:(blk + 1) * G], nxt)
+        assert (ahead != plain).any()
+
+        q = jnp.asarray(np.random.RandomState(13).randn(8, rep * KV, D), jnp.bfloat16)
+        kp, vp = self._poison(kp), self._poison(vp)
+        calls = []
+        real = da._first_blocks_ahead
+        monkeypatch.setattr(
+            da, "_first_blocks_ahead", lambda *a: calls.append(1) or real(*a)
+        )
+        got = da.paged_decode_attention(q, kp, vp, bt, posj, interpret=True)
+        monkeypatch.setattr(
+            da, "_first_blocks_ahead", lambda walk, *a: calls.append(0) or walk
+        )
+        want = da.paged_decode_attention(q, kp, vp, bt, posj, interpret=True)
+        assert calls == [1, 0] and bool(jnp.all(got == want))
+
     @pytest.mark.parametrize("rep", [1, 2])
     def test_int8_pool_with_scales(self, rep):
         from deepspeed_tpu.ops.attention import paged_cached_attention
@@ -664,10 +725,12 @@ class TestPagedDecodeBlocks:
     """The block chooser and the gate: parameters come from the shapes."""
 
     @pytest.mark.parametrize("shape,want", [
-        # GPT-2-XL's pool, bf16: 25 x 8 tiles of 4 KB, K and V, two buffers
+        # GPT-2-XL's pool, bf16 (the three GPT-2 cells' served shape): the
+        # VMEM fit, 25 x 8 tiles of 4 KB, K and V, two buffers
         ((25, 16, 64, 2, 64), (25, 8)),
         ((64, 16, 128, 2, 64), (64, 4)),
-        # a tensor-parallel shard of five heads: capped at S_BLOCK keys
+        # a tensor-parallel shard of five heads: capped at PAGE_INPUTS pages
+        # (at 16-key pages those are the 512 keys the cap once counted)
         ((5, 16, 64, 2, 64), (5, 32)),
         # a narrow table caps the block
         ((25, 16, 64, 2, 4), (25, 4)),
@@ -679,6 +742,18 @@ class TestPagedDecodeBlocks:
         ((25, 256, 128, 4, 4), (5, 1)),
         # one head's page does not fit
         ((8, 2048, 256, 4, 2), None),
+        # the other served shapes (ISSUE 50): each the pair it had, but ZAYA's.
+        # K-EXAONE's full layer (32 page inputs) and its rings (the table)
+        ((8, 16, 128, 2, 224), (8, 32)),
+        ((8, 16, 128, 2, 25), (8, 16)),
+        # Phi-4-mini-flash's paged layer and its rings: the fit, 3 pages of 10 heads
+        ((10, 128, 128, 2, 48), (10, 2)),
+        ((10, 128, 128, 2, 7), (10, 2)),
+        # ZAYA: 2 kv heads of 128-key pages take what fits, 16 pages (4 under
+        # the cap of 512 keys)
+        ((2, 128, 128, 2, 48), (2, 16)),
+        # PAGE_INPUTS alone: one kv head, int8 pages of 32, a wide table
+        ((1, 32, 64, 1, 256), (1, 32)),
     ])
     def test_blocks_from_shapes(self, shape, want):
         from deepspeed_tpu.ops.pallas.decode_attention import (
@@ -913,17 +988,22 @@ class TestPagedMultitokenBlocks:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert da.paged_multitoken_attention_ok(*args) is want
 
-    @pytest.mark.parametrize("impl,T,want", [
-        ("pallas", 128, 8),    # the chunk call: one slot, 8 page blocks (1 600 before)
-        ("pallas", None, 64),  # the decode step: 8 slots x 8 page blocks
-        ("auto", 128, 0),      # off the TPU the fallback runs
-        ("jnp", None, 0),
+    XL = (25, 16, 64, 2, 64)      # GPT-2-XL: KV, page, D, itemsize, table
+    ZAYA = (2, 128, 128, 2, 48)   # 4 query heads to each of its 2 kv heads
+
+    @pytest.mark.parametrize("impl,B,shape,T,rep,want", [
+        ("pallas", 1, XL, 128, 1, 8),    # the chunk call: one slot, 8 page blocks (1 600 before)
+        ("pallas", 8, XL, None, 1, 64),  # the decode step: 8 slots x 8 page blocks
+        ("auto", 1, XL, 128, 1, 0),      # off the TPU the fallback runs
+        ("jnp", 8, XL, None, 1, 0),
+        # ZAYA's decode step: 64 slots x 3 blocks of 16 pages (12 of 4, 768 a call, before ISSUE 50)
+        ("pallas", 64, ZAYA, None, 4, 192),
+        ("pallas", 1, ZAYA, 256, 4, 48),  # its chunk call keeps one 128-key page a step
     ])
-    def test_grid_steps_at_the_served_shape(self, impl, T, want):
+    def test_grid_steps_at_the_served_shape(self, impl, B, shape, T, rep, want):
         from deepspeed_tpu.ops.attention import paged_attention_grid_steps
 
-        B = 8 if T is None else 1
-        assert paged_attention_grid_steps(impl, B, 25, 16, 64, 2, 64, T) == want
+        assert paged_attention_grid_steps(impl, B, *shape, T, rep) == want
 
     @pytest.mark.parametrize("rep,T,want", [
         (1, 256, 8),    # 4 kv-heads a step, 8 page blocks

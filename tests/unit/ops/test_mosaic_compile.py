@@ -102,6 +102,36 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, layered):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_paged_decode_kernel_compiles_at_its_widest_step_for_v5e(one_chip):
+    """ISSUE 50: 2 kv heads of 128-key pages x 128 lanes (ZAYA's served
+    shape: 64 slots, 4 query heads a kv head, a table of 48, 14 layers) take
+    16 pages a grid step: 32 page inputs for K and 32 for V, 4 MB of page
+    buffers, the block's K and V joined and the scores beside them, under
+    the default scoped VMEM (the call asks for no more). As the decode
+    program calls it: named, the layer an operand of the shared kernel."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention,
+        paged_decode_blocks,
+    )
+
+    B, H, KV, D, page, n, L = 64, 8, 2, 128, 128, 48, 14
+    assert paged_decode_blocks(KV, page, D, 2, n) == (KV, 16)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((L, B * n + 1, KV, page, D), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, bt, pos: paged_decode_attention(
+            q, k, v, bt, pos, layer=L - 1, name="decode_fn"
+        )
+    ).lower(
+        sds((B, H, D), jnp.bfloat16), pool, pool, sds((B, n), jnp.int32),
+        sds((B,), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
 @pytest.mark.parametrize("T", [128, 5], ids=["chunk128", "verify5"])
 @pytest.mark.parametrize("name", list(SHAPES))
