@@ -128,8 +128,11 @@ class ZeroConfig(DSConfigModel):
     ``contiguous_gradients``, ``reduce_scatter``,
     ``allgather_partitions``, ``allgather_bucket_size``, ``overlap_comm``,
     ``stage3_max_live_parameters``, ``stage3_max_reuse_distance``,
-    ``stage3_prefetch_bucket_size`` (XLA latency-hiding scheduler decides
-    prefetch depth), ``round_robin_gradients``, ``zero_hpz_partition_size``.
+    ``stage3_prefetch_bucket_size`` (accepted and unread: under stage 3 over
+    a ``dp`` axis of more than one TPU the step asks libtpu's collective
+    pipeliner for each layer's weights ONE layer ahead, forward and backward,
+    ``DeepSpeedEngine._step_compiler_options``; the depth is not a setting),
+    ``round_robin_gradients``, ``zero_hpz_partition_size``.
     ``sub_group_size`` and the offload sub-configs ARE consumed by the
     host-tier engines (offload/infinity); ``stage3_param_persistence_threshold``
     by the Infinity block streamer; ``stage3_gather_16bit_weights_on_model_save``

@@ -258,12 +258,21 @@ class ZeroShardingPolicy:
         the wire savings. Stage <= 2 with a nontrivial axis qualifies."""
         return self.stage <= 2 and self.mesh.shape.get(self.zero_axis, 1) > 1
 
+    def gathers_params_in_step(self) -> bool:
+        """Whether the train step all-gathers its parameters at each use:
+        they are sharded over ``zero_axis`` (stage 3) and the axis has more
+        than one device. What :func:`on_batch_axis` asks of the ambient mesh,
+        asked of the policy's own; the engine's step then asks the compiler
+        for a layer's weights one layer ahead
+        (``DeepSpeedEngine._step_compiler_options``)."""
+        return self.stage >= 3 and self.mesh.shape.get(self.zero_axis, 1) > 1
+
     def supports_compressed_param_gather(self) -> bool:
         """The OTHER side of the compression story (ISSUE 12): at stage 3
         the dominant wire transfer is the param all-gather, and an explicit
         materialization (:func:`gather_full`) can run it block-quantized.
-        Stage 3 with a nontrivial axis qualifies."""
-        return self.stage >= 3 and self.mesh.shape.get(self.zero_axis, 1) > 1
+        Wherever the step gathers its parameters."""
+        return self.gathers_params_in_step()
 
     def param_gather_fn(self, comp_cfg=None) -> Callable[[PyTree], PyTree]:
         """→ callable(tree) materializing fully-replicated params: the

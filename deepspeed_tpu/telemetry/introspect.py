@@ -23,10 +23,11 @@ on the installed grammar it returns ``[]`` (ROADMAP D14).
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 # ---------------------------------------------------------------------------
 # per-chip peak table
@@ -301,10 +302,14 @@ def parse_named_instruction(line: str) -> Optional[NamedInstruction]:
 OP_NAME = re.compile(r'op_name="([^"]*)"')   # an instruction's metadata names the primitive that made it
 
 
+@functools.lru_cache(maxsize=1)
 def instructions_by_computation(hlo_text: str) -> Dict[str, List[NamedInstruction]]:
     """Computation name → its parsed instructions, in the text's order. (The
     backend's own config closes an instruction's line and is its longest
-    part: it is cut off before the line is parsed.)"""
+    part: it is cut off before the line is parsed.) The last text's answer is
+    kept, for the readers to share and not to change: a compiled train step's
+    census of collectives and of the optimizer's traffic each read its
+    megabyte of text during set-up, a tenth of a second a parse."""
     out: Dict[str, List[NamedInstruction]] = {}
     for comp, lines in split_computations(hlo_text).items():
         for line in lines:
@@ -328,6 +333,9 @@ def entry_computation(txt: str) -> Optional[str]:
 COLLECTIVE_KINDS = ("all_gather", "reduce_scatter", "all_reduce", "all_to_all")
 _COLLECTIVE_OPS = {k.replace("_", "-"): k for k in COLLECTIVE_KINDS}
 _CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+DOT_OPS = frozenset(("dot", "convolution", "ragged-dot"))   # what multiplies matrices; a dot is a convolution on a TPU
+MOSAIC = 'custom_call_target="tpu_custom_call"'           # a Pallas kernel, in a custom-call's attributes
+_MOVES = frozenset(("bitcast", "copy", "copy-start", "copy-done", "get-tuple-element", "tuple", "reshape"))
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 
 
@@ -341,6 +349,25 @@ class LoopCollective:
     nbytes: int          # bytes of the result on one device
     op_name: str         # the metadata's op_name ("" where the compiler gave none)
     overlapped: bool     # started and awaited by separate instructions: compute may run between them
+    between: int = 0     # instructions that hold a matmul or are a Mosaic kernel, from its start to its awaiting end
+    carried: bool = False   # nothing of this iteration reads the result: it leaves in the loop's state, for a later one
+
+    @property
+    def ahead(self) -> bool:
+        """Whether the schedule gives the collective compute to hide behind:
+        an async pair with two or more products between its two ends, or
+        with one where the result is ``carried``. ONE product between the
+        ends of a pair whose result this iteration reads is the product just
+        before the one that needs it, the scheduler's only choice, and on the
+        chip it did not cover a weight's gather (``c_fc_w``'s pairs waited
+        0.10 and 0.08 s of a traced 4.7 behind the attention's 1600 x 1600
+        output product); a pair asked for on behalf of the NEXT iteration is
+        put over whichever product the scheduler likes, and its one was long
+        enough every time (under 0.001 s each). No product between (a pair
+        over elementwise work alone) waited half its length; a synchronous
+        collective is waited for where it stands, whoever reads it (0.15 and
+        0.07 s). PERF.md section 6, PRs 40 and 51."""
+        return self.overlapped and self.between >= (1 if self.carried else 2)
 
     def carries(self, tokens: int) -> bool:
         """Whether a result is shaped like the activations of ``tokens``
@@ -367,8 +394,69 @@ def loop_collectives(hlo_text: str) -> List[LoopCollective]:
       ``async_collective_fusion`` computation of the work that runs meanwhile
       repeat that collective and are passed over; a fusion that calls an
       ``all-reduce-scatter`` computation is the reduce-scatter (an all-reduce
-      and each device's slice of it), with the fusion's result: the shard."""
+      and each device's slice of it), with the fusion's result: the shard.
+
+    Each also says where the schedule put it (the text of a compiled module is
+    in scheduled order): ``between`` counts the instructions of the loop body
+    that multiply matrices (a ``dot`` or ``convolution``, bare or inside a
+    fusion, or a Mosaic kernel) from an async pair's start to the end that
+    awaits it (a ``-done`` that takes the start; ``async-collective-done[.N]``
+    for ``async-collective-start[.N]``), and ``carried`` says that the result
+    reaches the body's root through moves alone (bitcasts, copies, tuples):
+    it was asked for on behalf of a later iteration. See
+    :attr:`LoopCollective.ahead`."""
     comps = instructions_by_computation(hlo_text)
+    multiplies_memo: Dict[str, bool] = {}
+
+    def multiplies(ni: NamedInstruction) -> bool:
+        if ni.op in DOT_OPS or (ni.op == "custom-call" and MOSAIC in ni.attrs):
+            return True
+        return ni.op == "fusion" and any(holds_dot(c) for c in _CALLED.findall(ni.attrs))
+
+    def holds_dot(comp: str) -> bool:
+        if comp not in multiplies_memo:
+            multiplies_memo[comp] = False
+            multiplies_memo[comp] = any(multiplies(ni) for ni in comps.get(comp, ()))
+        return multiplies_memo[comp]
+
+    readers_memo: Dict[str, Dict[str, List[NamedInstruction]]] = {}
+
+    def readers_in(comp: str) -> Dict[str, List[NamedInstruction]]:
+        """Instruction name → the instructions of ``comp`` that take it."""
+        if comp not in readers_memo:
+            readers = readers_memo[comp] = {}
+            for x in comps[comp]:
+                for o in set(x.operands):
+                    readers.setdefault(o, []).append(x)
+        return readers_memo[comp]
+
+    def placed(comp: str, start: int) -> Tuple[int, bool]:
+        """(``between``, ``carried``) of the collective that instruction ``start`` of ``comp`` starts."""
+        body = comps[comp]
+        ni = body[start]
+        end = start
+        if ni.op.endswith("-start"):
+            done = ni.op.removesuffix("start") + "done"
+            end = next((j for j in range(start + 1, len(body))
+                        if body[j].op == done and ni.name in body[j].operands), start)
+        elif ni.name.startswith("async-collective-start"):
+            done = ni.name.replace("start", "done", 1)
+            end = next((j for j in range(start + 1, len(body)) if body[j].name == done), start)
+        between = sum(multiplies(x) for x in body[start + 1:end])
+        readers = readers_in(comp)
+        seen, todo, carried = set(), [body[end].name], False
+        while todo:
+            for x in readers.get(todo.pop(), ()):
+                if x.name in seen:
+                    continue
+                seen.add(x.name)
+                if x.is_root:
+                    carried = True
+                elif x.op in _MOVES:
+                    todo.append(x.name)
+                else:
+                    return between, False
+        return between, carried
 
     def called(ni: NamedInstruction) -> List[str]:
         out = _CALLED.findall(ni.attrs)
@@ -382,7 +470,7 @@ def loop_collectives(hlo_text: str) -> List[LoopCollective]:
     found: List[LoopCollective] = []
     seen: set = set()
 
-    def collective(ni: NamedInstruction, holder: NamedInstruction, overlapped: bool) -> None:
+    def collective(ni: NamedInstruction, holder: NamedInstruction, overlapped: bool, comp: str, at: int) -> None:
         kind = _COLLECTIVE_OPS.get(ni.op.removesuffix("-start"))
         if kind is None:
             return
@@ -393,15 +481,16 @@ def loop_collectives(hlo_text: str) -> List[LoopCollective]:
         found.append(LoopCollective(
             holder.name, kind, dims_of(shapes), sum(shape_bytes(*s) for s in shapes),
             m.group(1) if m else "", overlapped or ni.op.endswith("-start"),
+            *placed(comp, at),
         ))
 
     def walk(comp: str) -> None:
         if comp in seen:
             return
         seen.add(comp)
-        for ni in comps.get(comp, ()):
+        for at, ni in enumerate(comps.get(comp, ())):
             if ni.op != "fusion":
-                collective(ni, ni, False)
+                collective(ni, ni, False, comp, at)
                 for c in called(ni):
                     walk(c)
                 continue
@@ -415,7 +504,7 @@ def loop_collectives(hlo_text: str) -> List[LoopCollective]:
             elif ni.name.startswith("async-collective-start"):
                 for c in inner:
                     for held in comps[c]:
-                        collective(held, ni, True)
+                        collective(held, ni, True, comp, at)
             # any other fusion computes (or awaits, or runs beside, a collective counted at its start)
 
     for instrs in comps.values():

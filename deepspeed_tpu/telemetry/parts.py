@@ -33,7 +33,10 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 
-from .introspect import DTYPE_BYTES, OP_NAME as _OP_NAME, instructions_by_computation, shape_bytes
+from .introspect import (
+    DOT_OPS as _DOT_OPS, DTYPE_BYTES, MOSAIC as _MOSAIC, OP_NAME as _OP_NAME, instructions_by_computation,
+    shape_bytes,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -129,9 +132,7 @@ _PART_IN_NAME = re.compile(re.escape(PREFIX) + r"([a-z]+(?:\.[a-z]+)*)")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
 _FRAME = re.compile(r"stack_frame_id=(\d+)")
 _MODULE = re.compile(r"^HloModule\s+([\w.\-]+)", re.M)
-_DOT_OPS = frozenset(("dot", "convolution", "ragged-dot"))
 _CARRIES = frozenset(("tuple", "while", "conditional", "call"))
-_MOSAIC = 'custom_call_target="tpu_custom_call"'
 
 
 def part_of(op_name: str) -> Optional[str]:

@@ -629,7 +629,7 @@ class TestFlashPlanGauges:
         for _ in range(2):      # the second call traces nothing and sets nothing
             engine.train_batch({"input_ids": ids})
         progs = [p for p in spans.phases(since=t0) if p[0] == "ds.init.programs"]
-        assert len(progs) == 1 and set(progs[0][3]) == {"what", "flash_plan", "collectives", "optim"}
+        assert len(progs) == 1 and set(progs[0][3]) == {"what", "flash_plan", "collectives", "gathers_ahead", "optim"}
         # two rows of 128 against two blocks of 128 a head: the diagonal pairs
         # are masked, the one below it is not; 2 x n_head heads a call
         heads = 2 * cfg.n_head

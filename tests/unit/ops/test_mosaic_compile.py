@@ -595,7 +595,8 @@ def test_the_v5e_compiler_takes_the_schedule_the_train_step_asks_for(topo, one_c
 
     def options(device):
         mesh = SimpleNamespace(devices=np.array([device], dtype=object))
-        return DeepSpeedEngine._step_compiler_options(SimpleNamespace(mesh=mesh))
+        policy = SimpleNamespace(gathers_params_in_step=lambda: False)   # one chip: no parameter is gathered
+        return DeepSpeedEngine._step_compiler_options(SimpleNamespace(mesh=mesh, policy=policy))
 
     assert options(jax.devices("cpu")[0]) is None
     asked = options(topo.devices[0])
@@ -990,6 +991,66 @@ def test_zero3_over_four_chips_gathers_weights_and_reduce_scatters_gradients(top
     assert '%all-reduce-scatter' in text
     weight_shaped = [c for c in kinds["all_reduce"] if any(len(dims) >= 2 for _, dims in c.shapes)]
     assert len(weight_shaped) <= 1, weight_shaped
+
+
+def test_zero3_over_four_chips_asks_for_a_layers_weights_one_layer_ahead(topo):
+    """ISSUE 51. The same step under the options ``DeepSpeedEngine.
+    _step_compiler_options`` adds where the policy says that the step gathers
+    its parameters: the described v5e's compiler knows all four by name, and
+    its collective pipeliner then hands EVERY weight gather of the two layer
+    loops on in the loop's state (``LoopCollective.carried``: iteration n
+    gathers iteration n+1's slices), where without them none is and each
+    stands in front of its own layer's products. The backward loop's
+    recompute and transpose share one gathered ``c_attn_w``: a gather fewer.
+    The layer in use and the layer being gathered are live together, with
+    their re-laid copies: 53 MB more here, 0.22 GB of 3.24 at GPT-2-XL's width
+    and depth (PERF.md section 6, PR 51)."""
+    from types import SimpleNamespace
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    from deepspeed_tpu.runtime.zero.partitioning import ZeroShardingPolicy
+    from deepspeed_tpu.telemetry.introspect import loop_collectives
+
+    L, E, H, B, S = 4, 512, 8, 16, 256
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    cfg = gpt2.GPT2Config(n_embd=E, n_head=H, n_layer=L, n_positions=S, attn_impl="pallas", dtype=jnp.bfloat16,
+                          remat=True)
+    mod = gpt2.make_module(cfg)
+    abstract = jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    policy = ZeroShardingPolicy(mesh, stage=3)
+    grad_specs = policy.grad_shardings(abstract, mod.logical_axes)
+    params = jax.tree.map(lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+                          abstract, policy.param_shardings(abstract, mod.logical_axes))
+    ids = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec("dp")))
+
+    def step(p, ids):
+        loss, grads = jax.value_and_grad(lambda p: mod.loss_fn(p, {"input_ids": ids}, None, True)[0])(p)
+        return loss, jax.lax.with_sharding_constraint(grads, grad_specs)
+
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(step).lower(params, ids)
+    asked = DeepSpeedEngine._step_compiler_options(SimpleNamespace(mesh=mesh, policy=policy))
+    assert len(asked) == 5 and asked["xla_memory_scheduler"] == "dfs"
+    below = DeepSpeedEngine._step_compiler_options(SimpleNamespace(mesh=mesh, policy=ZeroShardingPolicy(mesh, stage=2)))
+    assert below == {"xla_memory_scheduler": "dfs"}
+
+    def gathers(options):
+        compiled = lowered.compile(compiler_options=options)
+        found = [c for c in loop_collectives(compiled.as_text()) if c.kind == "all_gather"]
+        assert found and not any(c.carries(B * S) for c in found)
+        return found, compiled.memory_analysis().temp_size_in_bytes
+
+    parents, parents_temp = gathers(below)
+    ahead, temp = gathers(asked)
+    assert not any(c.carried for c in parents)
+    assert all(c.carried for c in ahead), [(c.name, c.shapes) for c in ahead if not c.carried]
+    assert len(ahead) == len(parents) - 1
+    assert sum(c.ahead for c in ahead) >= sum(c.ahead for c in parents)
+    assert temp - parents_temp < 64e6   # (reads 53 MB of 261)
 
 
 # -- the recurrent family (phi4flash): the scan kernels and the three programs at the served size (ISSUE 43) --

@@ -288,7 +288,8 @@ def test_leaves_tile_train_batch_and_the_first_call_is_a_programs_phase():
     assert len(progs) == 1 and re.fullmatch(r"\d+\.\d\dr\+\d+\.\d\dw/[1-9]\d*p", attrs.pop("optim"))
     assert attrs == {
         "what": "train_step", "flash_plan": "bq=0 bk=0 masked=0 plain=0",
-        "collectives": "all_gather=0w+0a reduce_scatter=0w+0a all_reduce=0w+0a all_to_all=0w+0a"}
+        "collectives": "all_gather=0w+0a reduce_scatter=0w+0a all_reduce=0w+0a all_to_all=0w+0a",
+        "gathers_ahead": "0/0"}   # (ISSUE 51: of no weight gather, none ahead)
     # the step's compilation happened inside it, and inside the first step's dispatch leaf
     first_dispatch = next(r for r in recs if r[0] == "ds.train.dispatch")
     assert first_dispatch[1] <= progs[0][1] and progs[0][2] <= first_dispatch[2]
