@@ -17,11 +17,13 @@ experts would have added is left out; nothing stands in for the other chips.
           | scale * s_e                                        (not renormalised)
     y     = sum_{e in sel, e < n_experts, e held} w_e FFN_e(u)
           + FFN_shared(u)                       where ``lp`` has ``shared``
+            (times sigmoid(u . w_sg)            where it has ``shared_gate``)
           + (sum_{e in sel, e >= n_experts} w_e) u             the identity columns
 
 Two scorings, one :func:`route`: ``sigmoid`` renormalised over the picks with a
 shared expert (K-EXAONE, Mistral Small 4) and ``softmax`` over 512 + 256
-columns, not renormalised, no shared expert (LongCat-Flash); and where the
+columns, not renormalised, no shared expert (LongCat-Flash), or over 512
+columns, top-10 renormalised, beside a GATED shared expert (Qwen3-Next); and where the
 router is no single matrix the family hands the scores' arguments in
 (``logits``: ZAYA's three-layer MLP over a state that comes down the depth,
 softmax, one pick, the probability itself the weight). The counts the
@@ -237,7 +239,8 @@ def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
     experts at all, e.g. the slots that hold a request: the others get the
     shared expert alone). ``lp``: ``router [E, n_experts + n_zero]``, ``bias``
     as wide, ``experts`` and, where the model has a shared expert, ``shared``,
-    each with ``w_gate, w_up, w_down``. ``logits [T, columns]``: the scores'
+    each with ``w_gate, w_up, w_down``, and where that expert has a scalar gate
+    a token, ``shared_gate [E, 1]`` (``sigmoid(u . w_sg)`` times its output). ``logits [T, columns]``: the scores'
     arguments from the family, in the place of ``u @ lp["router"]``
     (:func:`route`)."""
     T, rows = u.shape[0], block_rows(u)
@@ -253,7 +256,11 @@ def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
         y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits)
     if "shared" in lp:
         sh = lp["shared"]
-        y = y + gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
+        s = gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
+        if "shared_gate" in lp:   # a scalar gate a token on the shared expert: sigmoid(u . w_sg), float32
+            gate = jax.nn.sigmoid(jnp.matmul(u, lp["shared_gate"], preferred_element_type=jnp.float32))
+            s = (gate * s.astype(jnp.float32)).astype(s.dtype)
+        y = y + s
     with parts.part("moe.route"):   # the load count
         counts = jnp.sum(held > 0.0 if scoring == "sigmoid" else held, axis=0, dtype=jnp.int32)
         if zero is None:

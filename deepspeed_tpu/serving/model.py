@@ -7,11 +7,14 @@ attention, the sampling); the model's module gives the rest through
 ``cfg.serving_family()`` (:class:`Family` below: ``models/gpt2.GPT2Family``,
 ``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``,
 ``models/longcat_flash.LongcatFlashFamily``,
-``models/phi4flash.Phi4FlashFamily``, ``models/zaya.ZayaFamily``). A sub-block is of one of four KINDS
+``models/phi4flash.Phi4FlashFamily``, ``models/zaya.ZayaFamily``,
+``models/qwen3_next.Qwen3NextFamily``). A sub-block is of one of five KINDS
 (``fam.kinds``; without it every one is the first): an attention that writes
 its own K/V (``attn``), a state-space mixer over a per-slot recurrent state
 (``ssm``: the third kind of state beside pages and rings, :func:`_ssm_block`),
-and two that keep nothing: a cross-attention that reads the pages another
+a linear attention over a per-slot MATRIX state a head (``lin``: the same kind
+of state, another shape and recurrence, :func:`_lin_block`), and two that keep
+nothing: a cross-attention that reads the pages another
 sub-block wrote (``cross``) and a gated memory unit that reads another's scan
 output of the same token (``gmu``). The programs:
 
@@ -245,6 +248,18 @@ class Family:
       runs. Behind it only rows whose logits are sampled go on (a prompt's
       last row, the decode rows): the sub-blocks there write no state.
       ``windows`` is 0 for a sub-block that is no ``"attn"``.
+      A ``"lin"`` sub-block (a linear attention under the gated delta rule,
+      ``ops/pallas/gated_delta.py``; a family has ``"ssm"`` or ``"lin"``
+      sub-blocks, not both) asks for ``lin_state`` (``(Hv, dk, dv)``: a slot's
+      state a sub-block, float32, a matrix a value head), ``lin_conv`` (``(K,
+      channels)``: the convolution's taps and the channels it runs over, ``K -
+      1`` rows of which are carried), ``lin_impl``, and the pieces
+      ``lin_in(lp, h) -> m [..., channels], rest`` (the norm and the
+      projections; ``rest`` is whatever else of the row the later pieces
+      need), ``lin_taps(lp) -> w_conv [channels, K]`` (no bias),
+      ``lin_gates(lp, c, rest) -> q, k [..., Hk, dk], v [..., Hv, dv], g, beta
+      [..., Hv]`` in float32 (``q``, ``k`` as the rule reads them) and
+      ``lin_out(lp, o [..., Hv, dv], rest, tp_axis)``.
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
@@ -309,7 +324,7 @@ def _kv_homes(fam):
         if kind == "attn":
             homes.append((bool(w), n_win if w else n_paged))
             n_win, n_paged = n_win + bool(w), n_paged + (not w)
-        elif kind == "ssm":
+        elif kind in ("ssm", "lin"):
             homes.append((False, n_ssm))
             n_ssm += 1
         else:
@@ -322,7 +337,7 @@ def pool_layers(fam) -> tuple:
     state has for this family."""
     kinds = sub_block_kinds(fam)
     attn = [w for k, w in zip(kinds, fam.windows) if k == "attn"]
-    return sum(1 for w in attn if not w), sum(1 for w in attn if w), kinds.count("ssm")
+    return sum(1 for w in attn if not w), sum(1 for w in attn if w), kinds.count("ssm") + kinds.count("lin")
 
 
 def _sm_scale(fam, D: int):
@@ -470,7 +485,8 @@ def _attend_latent(fam, q, pool, l, block_tables, base, name, live=None):
 # state-space mixer over the recurrent state pools, a gated memory unit and a
 # cross-attention over what other sub-blocks made
 #
-# ``state = (ssm, conv)``: ``ssm [Ls, slots, N, d_inner]`` float32, the scan
+# ``state = (ssm, conv)`` (a family of linear attentions: ``(lin, conv)``, ``lin
+# [Ll, slots, Hv, dk, dv]`` float32, :func:`_lin_block`): ``ssm [Ls, slots, N, d_inner]`` float32, the scan
 # state a slot and "ssm" sub-block (the channels on the lanes:
 # ``ops/pallas/selective_scan.py``), and ``conv [Ls, slots, K - 1, d_inner]``,
 # the convolution's last inputs. Both are donated through every program like
@@ -484,6 +500,37 @@ def _passed(lp, a, tp_axis):
     return a
 
 
+def _conv_carried(w_conv, b_conv, rows, conv, li, C: int, chunk, real):
+    """The short causal convolution of a recurrent sub-block over ``rows [C +
+    B, d]`` (the chunk's ``C``, then a row a slot) behind the rows its pool
+    ``conv [Ls, slots, K - 1, d]`` carries → (``c [C + B, d]``, the pool).
+    ``chunk = (slot, fresh, n_real)``: the chunk's slot takes the ``K - 1``
+    rows before its row ``n_real`` (zeros in front where ``fresh``: the
+    request's first rows); a decoding slot's are shifted by its one row, an
+    idle one's stay."""
+    from ..ops.pallas.selective_scan import conv_rows
+
+    K = w_conv.shape[-1]
+    cs = []
+    if C:
+        slot, fresh, n_real = chunk
+        prev = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[li, slot])
+        c, full = conv_rows(w_conv, b_conv, rows[:C], prev)
+        # the next call's: the K - 1 rows before row ``n_real``
+        conv = conv.at[li, slot].set(
+            lax.dynamic_slice_in_dim(full, n_real, K - 1, 0).astype(conv.dtype)
+        )
+        cs.append(c)
+    if real is not None:
+        prev = conv[li]
+        c, full = conv_rows(w_conv, b_conv, rows[C:, None], prev)
+        conv = conv.at[li].set(
+            jnp.where(real[:, None, None], full[:, 1:].astype(conv.dtype), prev)
+        )
+        cs.append(c[:, 0])
+    return (cs[0] if len(cs) == 1 else jnp.concatenate(cs)), conv
+
+
 def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
     """The state-space mixer of one sub-block (``li``-th of the state pools)
     over the rows of ``h`` (``[1, C + B, E]``, or the decode step's ``[B, 1,
@@ -494,34 +541,18 @@ def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
     part for the convolution and the scan alone. → (the mixer's output, shaped
     like ``h``; the scan's ``s [..., d_inner]`` before the gate, float32:
     what a gated memory unit reads; the state)."""
-    from ..ops.pallas.selective_scan import conv_rows, scan_rows, scan_step
+    from ..ops.pallas.selective_scan import scan_rows, scan_step
 
     ssm, conv = state
     xs, z = fam.ssm_in(lp, h)
     rows = xs.reshape(-1, xs.shape[-1])
     A, D, w_conv, b_conv = fam.ssm_consts(lp)
-    K = w_conv.shape[-1]
     impl = getattr(fam, "ssm_impl", "auto")
     with parts.part("ssm.scan"):
-        cs = []
         if C:
             slot, start, n_real = chunk
             fresh = start == 0
-            prev = jnp.where(fresh, jnp.zeros((), conv.dtype), conv[li, slot])
-            c, full = conv_rows(w_conv, b_conv, rows[:C], prev)
-            # the next call's: the K - 1 rows before row ``n_real``
-            conv = conv.at[li, slot].set(
-                lax.dynamic_slice_in_dim(full, n_real, K - 1, 0).astype(conv.dtype)
-            )
-            cs.append(c)
-        if real is not None:
-            prev = conv[li]
-            c, full = conv_rows(w_conv, b_conv, rows[C:, None], prev)
-            conv = conv.at[li].set(
-                jnp.where(real[:, None, None], full[:, 1:].astype(conv.dtype), prev)
-            )
-            cs.append(c[:, 0])
-        c = cs[0] if len(cs) == 1 else jnp.concatenate(cs)
+        c, conv = _conv_carried(w_conv, b_conv, rows, conv, li, C, C and (slot, fresh, n_real), real)
     dt, Bm, Cm = fam.ssm_dt(lp, c)
     with parts.part("ssm.scan"):
         x = c.astype(jnp.float32)
@@ -544,6 +575,49 @@ def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
     return fam.ssm_out(lp, s, z), s, (ssm, conv)
 
 
+def _lin_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
+    """The linear attention of one sub-block (``li``-th of the state pools,
+    ``state = (lin [Ll, slots, Hv, dk, dv] float32, conv [Ll, slots, K - 1,
+    channels])``) over the rows of ``h`` as :func:`_ssm_block` takes them: the
+    first ``C`` ONE slot's chunk, ``chunk = (slot, start, n_real)``, the others
+    a row a slot, ``real [B]`` the slots that decode. Both sets of rows go
+    through the family's projections once and part for the convolution and the
+    gated delta rule alone (``ops/pallas/gated_delta.py``: the chunk's rows in
+    sub-chunks from the slot's carried state, zeros at ``start`` 0; a row a
+    LIVE slot against the pool in place). A row that is padding has ``g`` and
+    ``beta`` 0 and moves nothing. → (the mixer's output, shaped like ``h``;
+    the state)."""
+    from ..ops.pallas import gated_delta
+
+    lin, conv = state
+    m, rest = fam.lin_in(lp, h)
+    impl = getattr(fam, "lin_impl", "auto")
+    with parts.part("lin.scan"):
+        if C:
+            slot, start, n_real = chunk
+            fresh = start == 0
+        c, conv = _conv_carried(
+            fam.lin_taps(lp), jnp.zeros((), jnp.float32), m.reshape(-1, m.shape[-1]), conv, li,
+            C, C and (slot, fresh, n_real), real,
+        )
+    q, k, v, g, beta = fam.lin_gates(lp, c, jax.tree.map(lambda x: x.reshape(-1, x.shape[-1]), rest))
+    with parts.part("lin.scan"):
+        os = []
+        if C:
+            keep = (jnp.arange(C) < n_real)[:, None]
+            o, s1 = gated_delta.chunk_rows(
+                q[:C], k[:C], v[:C], jnp.where(keep, g[:C], 0.0), jnp.where(keep, beta[:C], 0.0),
+                jnp.where(fresh, 0.0, lin[li, slot]), impl=impl,
+            )
+            lin = lin.at[li, slot].set(s1)
+            os.append(o)
+        if real is not None:
+            o, lin = gated_delta.step(q[C:], k[C:], v[C:], g[C:], beta[C:], lin, li, real, impl=impl)
+            os.append(o)
+        o = os[0] if len(os) == 1 else jnp.concatenate(os)
+    return fam.lin_out(lp, o.reshape(*h.shape[:-1], *o.shape[1:]), rest), (lin, conv)
+
+
 def _mixer_without_kv(fam, lp, h, l, li, positions, carry, state, tp_axis, rows, attend):
     """The mixer of a sub-block that writes no K/V, by its kind → (its output
     ``[..., E]``, projected: :func:`_after_attention` takes it with
@@ -551,6 +625,9 @@ def _mixer_without_kv(fam, lp, h, l, li, positions, carry, state, tp_axis, rows,
     ``(C, chunk, real)`` for this program's rows. ``attend(q, li)``: how this
     program's rows read the pages of a cross layer's source."""
     kind = fam.kinds[l]
+    if kind == "lin":
+        a, state = _lin_block(fam, lp, h, state, li, *rows)
+        return a, carry, state
     if kind == "ssm":
         a, s, state = _ssm_block(fam, lp, h, state, li, *rows)
         return a, {**(carry or {}), l: s}, state     # what its gated memory units will read

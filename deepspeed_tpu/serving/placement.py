@@ -317,7 +317,10 @@ class ProgramSet:
     the cache's type (``serving/model._ssm_block``), the last two of
     :meth:`pool_args`, donated like the ring pools. No allocator: a slot's row
     is the slot's, and the program that takes a request's first rows starts it
-    from zeros.
+    from zeros. A family of linear attentions (``"lin"`` sub-blocks) has the
+    same two pools in its own shapes: ``(lin, conv)``, ``[Ll, slots, Hv, dk,
+    dv]`` float32 (a matrix a value head: ``fam.lin_state``) and ``[Ll, slots,
+    K - 1, channels]`` (``fam.lin_conv``); ``lin_state_bytes`` is the first's.
 
     A family whose attentions CARRY ROWS (``fam.carry_width``: q, k and v of a
     call's first rows need rows of the call before, while K and V are paged as
@@ -366,12 +369,17 @@ class ProgramSet:
             self.window_pools = (
                 placement.put_pool(kw, kw.ndim - 3), placement.put_pool(vw, vw.ndim - 3)
             )
+        self.lin_state_bytes = 0
         if n_state:
-            (N, d_inner), K = fam.ssm_state, fam.ssm_conv
+            # the recurrent state a slot and sub-block, and the channels its convolution carries
+            # rows of: a state-space mixer's [N, d_inner], or a linear attention's [Hv, dk, dv]
+            lin = getattr(fam, "lin_state", None)
+            shape, (K, channels) = (lin, fam.lin_conv) if lin else (fam.ssm_state, (fam.ssm_conv, fam.ssm_state[1]))
             self.state_pools = (
-                placement.put(jnp.zeros((n_state, int(ring_slots), N, d_inner), jnp.float32)),
-                placement.put(jnp.zeros((n_state, int(ring_slots), K - 1, d_inner), self.k_pool.dtype)),
+                placement.put(jnp.zeros((n_state, int(ring_slots), *shape), jnp.float32)),
+                placement.put(jnp.zeros((n_state, int(ring_slots), K - 1, channels), self.k_pool.dtype)),
             )
+            self.lin_state_bytes = int(self.state_pools[0].nbytes) if lin else 0
         self.carry_pool_bytes = 0
         carry = int(getattr(fam, "carry_width", 0) or 0)
         if carry:

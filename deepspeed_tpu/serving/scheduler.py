@@ -220,7 +220,7 @@ class ServingEngine:
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
                 "including injected HF GPT-2, exaone_moe, mistral4, "
-                "longcat_flash, phi4flash and zaya); got "
+                "longcat_flash, phi4flash, zaya and qwen3_next); got "
                 f"{type(mcfg).__name__}"
             )
         self.model_config = mcfg
@@ -235,7 +235,15 @@ class ServingEngine:
         # a family with state-space sub-blocks keeps a third kind: a fixed-size
         # recurrent state a slot, which is no page, cannot be cut at a prefix
         # and cannot be rolled back behind a rejected draft without a snapshot
-        self.recurrent = "ssm" in smodel.sub_block_kinds(fam)
+        # (a linear attention's is a matrix a value head: the same kind of state)
+        kinds_ = smodel.sub_block_kinds(fam)
+        self.recurrent = "ssm" in kinds_ or "lin" in kinds_
+        # what that state is, for the refusals below: its bytes a slot and sub-block differ by 6x
+        self._state_what = (
+            "a linear-attention layer's matrix state a value head "
+            f"({4 * int(np.prod(fam.lin_state)) / 1e6:.1f} MB a slot and layer)"
+            if "lin" in kinds_ else "a state-space layer's scan state"
+        )
         # a family whose attentions carry rows keeps a fourth: under its paged
         # K and V, the rows before a call's first (serving/model._qkv_carried).
         # A cached prefix's pages without the rows at its end would serve a
@@ -264,8 +272,8 @@ class ServingEngine:
                     raise ValueError(
                         f"{what} is not available for a model with "
                         + (
-                            f"recurrent state ({type(mcfg).__name__}): a "
-                            "state-space layer's scan state and convolution "
+                            f"recurrent state ({type(mcfg).__name__}): "
+                            f"{self._state_what} and convolution "
                             "rows live in a per-slot pool beside the paged "
                             "pool, which this mechanism does not handle"
                             if self.recurrent else
@@ -728,6 +736,13 @@ class ServingEngine:
             "bytes of the rows the attentions of a family carry from call to "
             "call under their paged K and V (slots x attention sub-blocks x "
             "carry_width; 0 = the model's attentions carry none)",
+        )
+        self._g_lin_state_bytes = m.gauge(
+            "serving_lin_state_bytes",
+            "bytes of the matrix states the linear attentions of a family keep "
+            "(slots x linear-attention sub-blocks x value heads x dk x dv, "
+            "float32; a decode step reads and writes every live slot's; 0 = "
+            "the model has no such sub-block)",
         )
         self._g_experts_held = m.gauge(
             "serving_moe_experts_held",
@@ -1375,6 +1390,7 @@ class ServingEngine:
         self._g_kv_row_bytes.set(row_bytes)
         self._g_ring_pages.set(self.ring_pages)
         self._g_carry_bytes.set(ds.carry_pool_bytes)
+        self._g_lin_state_bytes.set(ds.lin_state_bytes)
         self._g_experts_held.set(self.family.experts_held)
         # the expert layers' form, as compiled: a program names the kernel or not
         self._moe_kernel = bool(self.family.sparse_layers) and (
@@ -1390,6 +1406,8 @@ class ServingEngine:
                      kv_row_bytes=row_bytes)
         if self.carried:
             attrs["carry_rows"] = ds.carry_pool_bytes
+        if ds.lin_state_bytes:
+            attrs["lin_state_bytes"] = ds.lin_state_bytes
         return attrs
 
     def _moe_attrs(self, counts: np.ndarray, n_tokens: int) -> dict:
@@ -2260,8 +2278,9 @@ class ServingEngine:
     def _slot_operand(self, slot_i: int) -> tuple:
         """The last host operand of the prefill and chunk programs of a
         family that keeps per-slot state there: the slot, whose ring a window
-        layer writes and whose carried rows an attention reads and leaves."""
-        return (np.asarray(slot_i, np.int32),) if self.windowed or self.carried else ()
+        layer writes, whose carried rows an attention reads and leaves and
+        whose recurrent state a mixer starts from and leaves."""
+        return (np.asarray(slot_i, np.int32),) if self.windowed or self.carried or self.recurrent else ()
 
     def _token_of(self, out):
         """The sampled token of a prefill program's results (a family with
@@ -2829,7 +2848,7 @@ class ServingEngine:
             raise ValueError(
                 "session migration is not available for a model with "
                 "recurrent state: the transport moves a slot's paged row, and "
-                "its scan state and convolution rows would stay behind"
+                f"{self._state_what} and convolution rows would stay behind"
             )
         if self.carried:
             raise ValueError(

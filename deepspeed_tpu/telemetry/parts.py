@@ -61,6 +61,8 @@ PARTS = (
     "moe.experts",  # the held experts' products, masked or grouped
     "ssm.proj",     # a state-space mixer's and a gated memory unit's projections and gates
     "ssm.scan",     # the short convolution and the recurrence, kernel or lax.scan
+    "lin.proj",     # a linear attention's (Gated DeltaNet) projections, gates, output norm and gate
+    "lin.scan",     # its short convolution and the gated delta rule, kernel or lax form
     "head",         # final norm, logits, the loss in training
     "sample",       # sampling
     "optim",        # gradient norm and clip, AdamW, casts of masters, loss scaling
@@ -113,6 +115,7 @@ KERNEL_FILES = {
     "ops/pallas/flash_attention.py": "attn.core",
     "ops/pallas/decode_attention.py": "attn.core",
     "ops/pallas/selective_scan.py": "ssm.scan",
+    "ops/pallas/gated_delta.py": "lin.scan",
 }
 
 

@@ -1179,3 +1179,31 @@ def test_recurrent_family_programs_compile_at_the_served_size(one_chip, program,
     # 7.70 GB of weights, 2.01 GB of paged pool, 2.35 GB of rings, 0.21 GB of recurrent state
     assert 12.2e9 < mem.argument_size_in_bytes < 12.4e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9   # of the chip's 16
+
+
+# -- the gated delta rule (qwen3_next): the two kernels at the published heads (ISSUE 52) --
+
+@pytest.mark.parametrize("entry,rows", [("chunk", 256), ("chunk", 37), ("step", 128)])
+def test_gated_delta_kernels_compile_for_v5e_at_the_published_heads(one_chip, entry, rows):
+    """``ops/pallas/gated_delta.py`` at Qwen3-Next's heads (16 key heads and 32
+    value heads of 128 x 128): a chunk of one slot's rows (the chunk program's
+    256, an odd count), and one row for each of 128 slots against a layer of
+    the whole 2.4 GB state pool, aliased: no copy of the pool is made."""
+    from deepspeed_tpu.ops.pallas import gated_delta as gd
+
+    Hk, Hv, dk, dv = 16, 32, 128, 128
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rowwise = (S((rows, Hk, dk)), S((rows, Hk, dk)), S((rows, Hv, dv)), S((rows, Hv)), S((rows, Hv)))
+    if entry == "chunk":
+        compiled = jax.jit(lambda *a: gd.chunk_rows(*a, impl="pallas")).lower(*rowwise, S((Hv, dk, dv))).compile()
+        assert len(re.findall(rf"^\s*(ROOT )?%?{gd.CHUNK_KERNEL}[.\d]* = .*custom-call\(", compiled.as_text(), re.M)) == 1
+        return
+    pool = S((9, rows, Hv, dk, dv))
+    compiled = jax.jit(lambda *a: gd.step(*a[:6], 3, a[6], impl="pallas"), donate_argnums=(5,)).lower(
+        *rowwise, pool, S((rows,), jnp.bool_)).compile()
+    assert len(re.findall(rf"^\s*(ROOT )?%?{gd.STEP_KERNEL}[.\d]* = .*custom-call\(", compiled.as_text(), re.M)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 9 * rows * Hv * dk * dv * 4 and mem.temp_size_in_bytes < 16e6   # the pool in place

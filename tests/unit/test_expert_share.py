@@ -427,3 +427,47 @@ def test_scores_handed_in_equal_the_linear_routes_where_they_are_u_times_router(
     np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
     other = es.expert_share_layer(no_router, u, share, top_k, SCALE, norm, valid, scoring, logits=-logits)[0]
     assert float(jnp.abs(other - want_y).max()) > 1e-3      # the scores handed in are what routes
+
+
+# -- a gated shared expert (PR 52) -----------------------------------------------------------------
+
+# the four expert families' calls of the layer: (scoring, top_k, scale, renormalised), each with a shared expert here
+FAMILY_CALLS = {"k-exaone": ("sigmoid", K, SCALE, True), "mistral4": ("sigmoid", K, 1.0, True),
+                "longcat": ("softmax", K, SCALE, False), "zaya": ("softmax", 1, 1.0, False)}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_CALLS))
+def test_without_a_shared_gate_the_layer_is_bit_for_bit_what_it_was(u, family):
+    """``shared_gate`` absent: the routed part plus the shared expert's plain
+    output, the sum the layer made before the key existed; present, the
+    shared part alone is scaled by ``sigmoid(u . w_sg)`` a token."""
+    scoring, top_k, scale, norm = FAMILY_CALLS[family]
+    lp, share = _layer(), es.ExpertShare(N)
+    sh = lp["shared"]
+    y, counts = es.expert_share_layer(lp, u, share, top_k, scale, norm, scoring=scoring)
+    routed, c0 = es.expert_share_layer({k: v for k, v in lp.items() if k != "shared"}, u, share, top_k, scale, norm, scoring=scoring)
+    plain = es.gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(routed + plain))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(c0))
+    w_sg = jax.random.normal(jax.random.PRNGKey(11), (E, 1), jnp.float32)
+    gated, c1 = es.expert_share_layer(dict(lp, shared_gate=w_sg), u, share, top_k, scale, norm, scoring=scoring)
+    np.testing.assert_allclose(np.asarray(gated), np.asarray(routed + jax.nn.sigmoid(u @ w_sg) * plain), rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c0))
+    assert float(jnp.abs(gated - y).max()) > 1e-3
+
+
+def test_softmax_top_k_counts_are_hits_not_weights(u):
+    """Under softmax scoring with several renormalised picks (Qwen3-Next's
+    top-10 of 512) a held expert's count is the TOKENS that picked it, whatever
+    their weights: the eight shares' counts add up to ``T x k``."""
+    lp = dict(_layer(), bias=jnp.zeros((N,)))
+    idx, w = es.route(u, lp["router"], lp["bias"], K, 1.0, True, "softmax")
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+    total = 0
+    for i in range(4):
+        share = es.ExpertShare(N, 4, i)
+        _, counts = es.expert_share_layer(_slice(lp, share), u, share, K, 1.0, True, scoring="softmax")
+        want = np.asarray((idx[:, :, None] == share.held_ids()[None, None, :]).sum((0, 1)))
+        np.testing.assert_array_equal(np.asarray(counts), want)
+        total += int(counts.sum())
+    assert total == T * K
