@@ -67,6 +67,11 @@ DEFAULT_HOT_PATTERNS = [
     # used to carry — both stay under the hot-path lint
     "ServingEngine._advance_chunks", "ServingEngine._launch_chunk",
     "ServingEngine._launch_alone", "ServingEngine._start_decoding",
+    # ISSUE 54: _step's launches, its one fetch and the two sides of a
+    # prompt's end are methods of their own — all of them the step's path
+    "ServingEngine._launch", "ServingEngine._dispatch", "ServingEngine._resolve",
+    "ServingEngine.settle", "ServingEngine._rows_due", "ServingEngine._arm",
+    "ServingEngine._first_token",
     "ServingEngine._draft", "ServingEngine._accept_tokens",
     "*.train_batch", "*._train_batch", "*._print_cadence", "*.eval_batch",
     "*._telemetry_step", "*._watchdog_step",

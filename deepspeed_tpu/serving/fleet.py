@@ -482,6 +482,10 @@ class FleetRouter:
         pools freed of sessions, its prefix index intact but unreachable."""
         now = self.clock()
         srv = rep.srv
+        # the step the replica has in flight is computed: read it, so that the
+        # sessions that move hold every token made for them and a slot that
+        # the read finishes is not moved at all
+        srv.settle()
         n_q = len(srv.queue)
         log_dist(
             f"fleet: preempting {rep.rid} "

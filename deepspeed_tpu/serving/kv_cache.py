@@ -203,11 +203,33 @@ class SlotTable:
         self.seq_lens = np.zeros((max_slots,), np.int32)
         self.tokens = np.zeros((max_slots,), np.int32)
         self.keys = np.zeros((max_slots, 2), np.uint32)
+        # a call none of whose rows takes its token from the step before
+        self._no_prev = np.zeros((max_slots + 1,), np.int32)
+        self._no_src = np.full((max_slots,), -1, np.int32)
 
-    def rows(self) -> tuple:
-        """The decode rows' host operands, in program order (a table nobody
-        assigns to is a step's worth of idle rows)."""
-        return self.tokens, self.seq_lens, self.block_tables, self.keys
+    def rows(self, live=None, prev=None, src=None) -> tuple:
+        """The decode rows' host operands, in program order, as arrays of
+        this call's own: the table moves on at the launch (``seq_lens``, the
+        keys), while the program that took them may still be queued.
+
+        ``live``: the slots that get a row; every other row goes idle (the
+        scratch table, length 0), as a table nobody assigns to is a step's
+        worth of idle rows. ``prev`` and ``src``: where a row's token is
+        still on the device, the token output ``[slots + 1]`` of the step
+        before and the row's place in it (``-1``: ``tokens`` holds it)."""
+        tokens, seq_lens, bt, keys = (
+            a.copy() for a in (self.tokens, self.seq_lens, self.block_tables, self.keys)
+        )
+        if live is not None:
+            idle = np.ones((self.max_slots,), bool)
+            idle[live] = False
+            seq_lens[idle] = 0
+            bt[idle] = SCRATCH_PAGE
+        return (
+            tokens, seq_lens, bt, keys,
+            self._no_prev if prev is None else prev,
+            self._no_src if src is None else src,
+        )
 
     def assign(self, slot: int, pages: List[int]) -> None:
         if len(pages) > self.pages_per_slot:

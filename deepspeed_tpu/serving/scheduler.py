@@ -41,6 +41,18 @@ the token streams:
   so a long prompt no longer stalls co-resident decode slots for its whole
   width (TPOT invariance, tested).
 
+One step in flight (ISSUE 54): a call of :meth:`ServingEngine.step` launches
+step n+1 before it reads step n's tokens. The sampled token stays on the
+device as the next step's input (the programs' ``prev`` / ``src`` operands),
+the host's tables advance at the launch (their dispatched side: ``seq_lens``,
+the keys, ``_Slot.sent``) and what the host has read is kept apart (the
+emitted side: ``_Slot.pos`` / ``step``, ``req.tokens``), a stop by count gives
+a slot no further row and a stop the tokens decide is seen one step late, its
+row launched ahead dropped. The host's turn between two programs lies under
+the program that runs. A server whose next rows wait for its tokens
+(speculation, a disaggregated handoff) launches nothing ahead, through the
+same loop.
+
 Robustness: admission control (queue-depth + KV-page budget) rejects at the
 door; per-request deadlines evict mid-flight to a TRUNCATED response; an
 over-long ask is clamped at submit. A stuck or runaway request can therefore
@@ -117,6 +129,15 @@ def _host_prng_key(seed: int) -> np.ndarray:
     return np.asarray(jax.random.PRNGKey(seed))
 
 
+def _tokens_of(tokens, prev, src):
+    """The decode rows' tokens inside a step program: the host's ``tokens``,
+    and for a row whose last token the host has not read (``src >= 0``: the
+    step that sampled it is still in flight) that place of ``prev``, the
+    token output ``[slots + 1]`` of that step (a slot's own row, or the last
+    place where the prompt's final chunk rode it)."""
+    return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
+
+
 def _split_scales(rest: tuple, quantized: bool):
     """Program-wrapper operand split: ``rest`` is ``(scales, *inputs)``
     under int8 pools, plain ``inputs`` otherwise."""
@@ -143,8 +164,17 @@ def _split_pools(rest: tuple, quantized: bool, windowed: bool, n_state: int = 0)
 class _Slot:
     request: Optional[Request] = None
     pages: List[int] = field(default_factory=list)  # full row: shared + private
-    pos: int = 0    # tokens currently in this slot's cache
-    step: int = 0   # decode steps completed
+    pos: int = 0    # tokens currently in this slot's cache (the emitted side: what the host has read)
+    step: int = 0   # decode steps completed (read back: the emitted side)
+    # decode rows launched (the dispatched side: ``step`` of them are read
+    # back, one more while a step is in flight)
+    sent: int = 0
+    # the first token is the last place of the step in flight: the prompt's
+    # final chunk rode it, and the slot's rows are launched from there on
+    first_due: bool = False
+    # the slot was handed on with this residency's LAST row in flight (a stop
+    # by count is known at the launch): the step's read finishes the request
+    handed: bool = False
     keys: Optional[np.ndarray] = None  # [max_new-1, 2] u32 decode sampling keys
     # -- ISSUE 10: chunked prefill + prefix sharing --------------------
     # True while the prompt is still prefilling chunk-by-chunk; the main
@@ -167,6 +197,18 @@ class _Slot:
     # and the prompt tokens those calls advanced
     moe_counts: List[Any] = field(default_factory=list)
     moe_tokens: int = 0
+
+
+@dataclass
+class _Flight:
+    """A step that is launched and not read: what :meth:`ServingEngine._resolve`
+    emits from once the host has its tokens."""
+    out: Any        # the program's results after the pools, on the device: the tokens, the expert loads
+    rows: List[tuple]   # (slot index, the _Slot that held it at the launch) of the rows launched
+    t0: float       # the dispatch leaf's opening, on the engine's clock
+    drafts: dict = field(default_factory=dict)   # a verify step's, by slot
+    started: Optional[tuple] = None   # (slot index, _Slot) whose prompt's last chunk rode this step
+    rode: int = 0   # prompt tokens of the chunk that rode
 
 
 class ServingEngine:
@@ -798,7 +840,29 @@ class ServingEngine:
             else None
         )
         self._ema_step_s = 0.0  # EWMA decode-step latency (straggler budget)
+        self._t_read = float("-inf")  # when the last step was read (a step launched ahead is timed from there)
         self._step_count = 0
+        # -- one step in flight while the host reads the step before it ----
+        # the launched step the host has not read (``_resolve`` reads it)
+        self._flight: Optional[_Flight] = None
+        # whether the next step's rows are known without the tokens in flight:
+        # a verify step's accepted count moves ``seq_lens``, and a handoff
+        # between placements is polled between two steps
+        self._ahead_ok = not (self.spec_enabled or self.disaggregated)
+        self._chunk_sp = None   # this call's ds.serve.chunk leaf
+        # (loads, tokens) of the prompts that finished prefilling, until a chunk leaf reports them
+        self._moe_done: list = []
+        self._c_ahead = m.counter(
+            "serving_steps_ahead_total",
+            "decode dispatches launched with the step before still in flight "
+            "(over serving_decode_steps_total: how often the host's turn lay "
+            "under a program)",
+        )
+        self._c_dropped = m.counter(
+            "serving_rows_dropped_total",
+            "decode rows computed and dropped: launched ahead for a slot that "
+            "the step before ended (EOS, a stall, a deadline, a drain)",
+        )
 
         # -- ISSUE 16: page-lifetime / session-heat tracing ----------------
         # explicit tracer wins, else the engine's telemetry plane provides
@@ -1092,6 +1156,9 @@ class ServingEngine:
         windowed, ring = self.windowed, self.ring_pages
         n_state = len(self.decode_set.state_pools or ())   # the state pools a program threads
 
+        # where the tokens lie in a decode program's results: last, or before the expert loads
+        tok_at = -2 if self.family.sparse_layers else -1
+
         def make_fns(cfg, tp_axis):
             def prefill_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (ids, plen, page_ids, key, *slot) = _split_pools(
@@ -1105,14 +1172,19 @@ class ServingEngine:
                 )
 
             def decode_fn(params, k_pool, v_pool, *rest):
-                scales, win, state, (tokens, seq_lens, bt, keys) = _split_pools(
+                scales, win, state, (tokens, seq_lens, bt, keys, prev, src) = _split_pools(
                     rest, quant, windowed, n_state
                 )
-                return smodel.paged_decode_step(
-                    cfg, params, tokens, seq_lens, k_pool, v_pool, bt, keys,
+                out = list(smodel.paged_decode_step(
+                    cfg, params, _tokens_of(tokens, prev, src), seq_lens,
+                    k_pool, v_pool, bt, keys,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
                     tp_axis=tp_axis, win=win, ring=ring, state=state,
-                )
+                ))
+                # the chunk program's shape: either's tokens are the next
+                # call's ``prev``, whichever program that is
+                out[tok_at] = jnp.pad(out[tok_at], (0, 1))
+                return tuple(out)
 
             def verify_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (tokens, seq_lens, bt) = _split_pools(
@@ -1127,11 +1199,11 @@ class ServingEngine:
             # and the program a decode dispatch launches
             def chunk_decode_fn(params, k_pool, v_pool, *rest):
                 scales, win, state, (
-                    tokens, seq_lens, bt, keys,
+                    tokens, seq_lens, bt, keys, prev, src,
                     ids, start, plen, page_ids, bt_row, key, *slot,
                 ) = _split_pools(rest, quant, windowed, n_state)
                 return smodel.paged_mixed_step(
-                    cfg, params, tokens, seq_lens, ids, start, plen, k_pool,
+                    cfg, params, _tokens_of(tokens, prev, src), seq_lens, ids, start, plen, k_pool,
                     v_pool, bt, page_ids, bt_row, keys, key,
                     temperature=temp, top_k=tk, top_p=top_p, scales=scales,
                     tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
@@ -1152,11 +1224,14 @@ class ServingEngine:
         # family with expert layers, the tokens each held expert got
         n_results = 2 if self.family.sparse_layers else 1
         slot_sds = (S((), i32),) if self._slot_operand(0) else ()
-        # the decode rows' host operands: tokens, lengths, tables, keys
+        # the decode rows' operands: tokens, lengths, tables, keys from the
+        # host, then the token output of the step before (on the device while
+        # that step is in flight) and each row's place in it
         rows_sds = (
             S((self.max_slots,), i32), S((self.max_slots,), i32),
             S((self.max_slots, self.pages_per_slot), i32),
             S((self.max_slots, 2), u32),
+            S((self.max_slots + 1,), i32), S((self.max_slots,), i32),
         )
 
         def compile_for(pset, fn, host_sds):
@@ -1557,13 +1632,158 @@ class ServingEngine:
         names and attributes are listed in PERF.md section 3): no statement
         that can take more than a few microseconds is outside one, and every
         place that blocks on the device has a leaf whose name ends in
-        ``.wait``."""
+        ``.wait``.
+
+        One step program is in flight while the host reads the step before
+        it: a call LAUNCHES the next step (admission, chunks, the decode
+        dispatch, all from the dispatched side of the tables) and then reads
+        the one the call before launched, emits its tokens and keeps house
+        while the device runs. A server whose next rows depend on the tokens
+        it has not read (speculative verification: the accepted count moves
+        ``seq_lens``; a disaggregated handoff) launches nothing ahead and
+        reads its step in the call that launched it, through the same loop."""
+        nxt = self._launch()
+        if self._flight is None:
+            self._flight, nxt = nxt, None
+            if self._flight is not None and self._ahead_ok:
+                # nothing was in flight (an empty server's first rows): fill
+                # the pipe, so that this call too reads a step's tokens
+                rows = self._rows_due()
+                if rows:
+                    nxt = self._dispatch(rows)
+        flight, self._flight = self._flight, nxt
+        if flight is not None:
+            self._resolve(flight)
+        elif self._moe_done:
+            self._report_chunk_loads()
+
+        with spans.span("ds.serve.housekeep") as hk:
+            # which of its four chores a long one ran (the span's attrs, set at exit)
+            scanned = pumped = 0
+            # straggler detection (ISSUE 5 watchdog): a request resident in a
+            # slot far beyond its expected decode budget (straggler_factor x
+            # max_new_tokens x EMA step time) is flagged once — a wedged or
+            # pathologically slow request surfaces instead of silently holding
+            # a slot. Slots advance in lockstep, so residence time is the only
+            # per-request axis that can straggle.
+            if self.watchdog is not None and self._ema_step_s > 0.0:
+                factor = float(getattr(self.watchdog.config, "straggler_factor", 3.0))
+                now = self.clock()
+                for slot in self.slots:
+                    req = slot.request
+                    if req is None or req.t_first_token is None:
+                        continue
+                    scanned += 1
+                    budget = factor * max(1, req.max_new_tokens) * self._ema_step_s
+                    elapsed = now - req.t_first_token
+                    if elapsed > budget and self.watchdog.observe_straggler(
+                        self._step_count, req.id,
+                        f"slot residence {elapsed:.3f}s > {budget:.3f}s "
+                        f"({len(req.tokens)}/{req.max_new_tokens} tokens)",
+                    ):
+                        self._c_stragglers.inc()
+
+            n_active = sum(1 for s in self.slots if s.request is not None)
+            self._g_queue.set(len(self.queue))
+            self._g_util.set(n_active / self.max_slots)
+            self._g_pages.set(self.allocator.pages_in_use)
+            self._g_occ.set(self.allocator.pages_in_use / self.allocator.capacity)
+            self._g_pages_shared.set(self.allocator.pages_shared)
+            if self.prefix_cache is not None:
+                self._g_index_pages.set(len(self.prefix_cache))
+            if self.tiering is not None:
+                pumped = self._tier_pump()
+            refresh = bool(self._step_count and self._step_count % 32 == 0)
+            if refresh:
+                self.stats()  # refresh the quantile gauges for textfile scrapes
+            journaled = self._journal is not None and self._journal.maybe_snapshot(self.clock())
+            hk.set(stats=int(refresh), journal=int(journaled), pump=pumped, stragglers=scanned)
+        return n_active
+
+    def settle(self) -> None:
+        """Read the step in flight, if one is: its tokens are emitted and the
+        slots it finishes are freed, as the next :meth:`step` would have. What
+        ends or moves a slot from outside :meth:`step` calls this first."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._chunk_sp = None   # a call's leaf takes no attribute after the call
+            self._resolve(flight)
+
+    def _rows_due(self) -> List[int]:
+        """The slots the next decode launch has a row for: past their prompt,
+        and short of their count by what the host has read AND what is in
+        flight. A stop by count is known at the launch; a stop the tokens
+        decide is seen when they are read, a step late."""
+        return [
+            i for i, s in enumerate(self.slots)
+            if s.request is not None and not s.prefilling
+            and self._tokens_out(s) < s.request.max_new_tokens
+        ]
+
+    @staticmethod
+    def _tokens_out(slot: _Slot) -> int:
+        """The tokens of a slot's request that are sampled or will be by what
+        is launched: those the host has read and those still on the device
+        (the first, where its chunk rode the step in flight; a row's)."""
+        return len(slot.request.tokens) + slot.first_due + slot.sent - slot.step
+
+    def _hand_on_spent_slot(self, now: float) -> Optional[int]:
+        """Free a slot whose request has its LAST row in the step in flight (a
+        stop by count is known at the launch, so nothing more is launched for
+        it) for the queue's next request, a call before that step is read:
+        the slot sits out no step between two requests (without it the
+        document cell's slots read 89.9% active where the synchronous loop
+        read 91.4 and this one reads 91.1: PERF.md section 6, PR 54). The
+        pages go back now (what the next owner writes is launched behind
+        the row that still reads and writes them); the request keeps its
+        place in the step in flight and is finished where its last token is
+        read, later in this call. → the slot, or ``None`` where no slot is
+        spent."""
+        for i, held in (self._flight.rows if self._flight is not None else ()):
+            if self.slots[i] is not held or self._tokens_out(held) < held.request.max_new_tokens:
+                continue
+            held.handed = True
+            if self._heat_decode is not None:
+                # the row's touch, before the session ends: the page the
+                # row in flight writes and the prefix it attends, under the
+                # number its step gets when it is read (the next one read)
+                pos_after = held.pos + 1
+                self._heat_decode.touch_step(now, self._step_count + 1, [(
+                    i, int(self.table.block_tables[i, (pos_after - 1) // self.page_size]),
+                    pages_for(pos_after, self.page_size),
+                )])
+            self._vacate(i, now)
+            return i
+        return None
+
+    def _vacate(self, slot_i: int, now: float) -> None:
+        """The slot's side of every end of a residency: the heat session
+        closed, the pages back to their pools, the table's row cleared, a
+        fresh slot in its place (by which a row of the old residency still
+        in flight is known for dropped)."""
+        slot = self.slots[slot_i]
+        if self._heat_decode is not None:
+            self._heat_decode.session_end(now, slot_i)
+        self.allocator.free(slot.pages)
+        if slot.prefill_pages:
+            # ended mid-prefill (timeout / preempt / stall) before the handoff
+            # could free the prefill-side reservation
+            self.prefill_set.allocator.free(slot.prefill_pages)
+        self.table.clear(slot_i)
+        self.slots[slot_i] = _Slot()
+
+    def _launch(self) -> Optional[_Flight]:
+        """A call's launches: admission (whole prefills), the prefilling
+        slots' chunks and the decode dispatch, from what the host knows
+        without the tokens of the step in flight. → the step launched, if the
+        call had a decode row."""
         with spans.span("ds.serve.admit") as sp:
             admitted, blocked = 0, ""
             now = self.clock()
 
             # 1. timeout eviction — a request past its deadline degrades to a
             # truncated response; its slot and pages are reclaimed immediately
+            # (a row of its in the step in flight is computed and dropped)
             for i, slot in enumerate(self.slots):
                 if slot.request is None:
                     continue
@@ -1613,6 +1833,8 @@ class ServingEngine:
                 free = next(
                     (i for i, s in enumerate(self.slots) if s.request is None), None
                 )
+                if free is None:
+                    free = self._hand_on_spent_slot(now)
                 if free is None:
                     # all slots busy: the ready head of line waited this step
                     # on slot capacity (queue depth, in SLO terms). The ready
@@ -1678,33 +1900,29 @@ class ServingEngine:
                 self._admit(free, req)
                 admitted += 1
             sp.set(admitted=admitted, blocked=blocked)
+            pre = [
+                i for i, s in enumerate(self.slots)
+                if s.request is not None and s.prefilling and s.pending_tok is None
+            ]
 
         # 2b. chunked prefill (ISSUE 10): every PREFILLING slot advances ONE
         # chunk, then the decode batch below still runs — a long prompt pays
         # out its prefill across steps instead of stalling co-resident
         # decodes for its whole width. A slot whose first token is already
-        # in flight (pending_tok) is past its last chunk — it waits on the
-        # handoff phase below, not on more chunks.
+        # on the device (pending_tok) is past its last chunk — it waits on the
+        # step's fetch (or the handoff phase below), not on more chunks.
         # Where a decode step follows on the same placement, the FIRST
         # prefilling slot's chunk RIDES its dispatch (phase 3: one call of
         # the chunk program carries the chunk and the decode rows, so the
         # step streams the weights once); every other chunk is a call of
         # that program with no decode row: here where this phase waits for
-        # it (a prompt's last chunk), else under that dispatch, ahead of the
-        # call that carries the rider (`_advance_chunks` says why).
-        pre = [
-            i for i, s in enumerate(self.slots)
-            if s.request is not None and s.prefilling and s.pending_tok is None
-        ]
-        rider = None
+        # it (a prompt's last chunk, with no step in flight), else under that
+        # dispatch, ahead of the call that carries the rider
+        # (`_advance_chunks` says why).
+        rider, unwaited = None, []
+        self._chunk_sp = None
         if pre:
-            if not self.spec_enabled and not self.disaggregated and any(
-                s.request is not None and not s.prefilling for s in self.slots
-            ):
-                rider = pre[0]
-            chunk_sp, moe_done, unwaited = self._advance_chunks(
-                [i for i in pre if i != rider], rider
-            )
+            self._chunk_sp, rider, unwaited = self._advance_chunks(pre)
 
         # 2c. disaggregated handoff completion (ISSUE 14): a slot whose
         # prefill placement has sampled the first token moves its prompt KV
@@ -1734,226 +1952,268 @@ class ServingEngine:
                     sp.set(slots=done)
 
         # 3. one batched decode (or speculative verify) step for every slot
-        # that is past prefill
-        active = [
-            i for i, s in enumerate(self.slots)
-            if s.request is not None and not s.prefilling
-        ]
-        if active:
-            with spans.span("ds.serve.decode.dispatch", active=len(active)) as sp:
-                t0 = self.clock()
-                # tokens the queries attend (each slot's cached context and the
-                # token this step writes) and the pages those contexts hold
-                lens = self.table.seq_lens[active]
-                attended = int(lens.sum()) + len(active)
-                if self.windowed:
-                    # what a layer reads, averaged over the layers: a window
-                    # layer reads its window of a context, not the context
-                    # (of a family of several kinds the sub-blocks that
-                    # attend: a cross layer reads its source's whole context,
-                    # a state-space mixer and a memory unit no key)
-                    ws = self.family.windows
-                    attended = int(sum(
-                        (np.minimum(lens + 1, w).sum() if w else attended)
-                        for k, w in zip(smodel.sub_block_kinds(self.family), ws) if k in ("attn", "cross")
-                    ) / len(ws))
-                sp.set(
-                    attended=attended,
-                    pages=int((lens // self.page_size).sum()) + len(active),
-                )
-                self._c_slot_steps.inc(len(active))
-                self._c_attended.inc(attended)
-                self._count_latent_walk(
-                    "mla_paged_verify" if self.spec_enabled else "mla_paged_decode",
-                    self.table.seq_lens, self.spec_k + 1 if self.spec_enabled else 1,
-                )
-                drafts: dict = {}
-                # the AOT executable takes the numpy slot tables directly — a
-                # jnp.asarray wrapper here would dispatch four extra device ops
-                # per decode step (dslint jnp-in-hot-loop)
-                if self.spec_enabled:
-                    T = self.spec_k + 1
-                    vt = np.zeros((self.max_slots, T), np.int32)
-                    vt[:, 0] = self.table.tokens
-                    for i in active:
-                        d = self._draft(self.slots[i].request)
-                        drafts[i] = d
-                        vt[i, 1:] = d
-                    dset = self.decode_set
-                    out = dset.take_pools(self._verify_exec(
-                        dset.params, *dset.pool_args(),
-                        vt, self.table.seq_lens, self.table.block_tables,
-                    ))
-                    self._c_spec_steps.inc()
-                    self._c_spec_drafted.inc(self.spec_k * len(active))
-                elif rider is not None:
-                    # the further prefilling slots' calls that nothing waits
-                    # for, then the chunk program with the step's own rows:
-                    # the slots' tokens come back with the chunk's, in one fetch
-                    for i in unwaited:
-                        self._launch_alone(i)
-                    rode = self._chunk_reach(rider)[0]
-                    out, last = self._launch_chunk(rider, self.table.rows())
-                    self._c_chunks_rode.inc()
-                else:
-                    dset = self.decode_set
-                    out = dset.take_pools(self._decode_exec(
-                        dset.params, *dset.pool_args(), *self.table.rows(),
-                    ))
-            # a rider whose chunk was its prompt's last: its token is in `out`
-            started = self.slots[rider] if rider is not None and last else None
-            # the ONE deliberate sync of the slot loop: the scheduler must
-            # read the sampled tokens to retire/advance slots (with them, a
-            # prompt's earlier calls' expert loads where its last chunk rode)
-            with spans.span("ds.serve.decode.wait"):
-                out_np, *alone_np = jax.device_get(  # dslint: disable=host-sync-in-step
-                    (out, *(started.moe_counts if started else ()))
-                )
-            moe_np = None
-            if self.family.sparse_layers:
-                out_np, moe_np = out_np  # the expert loads rode the same fetch
-            with spans.span("ds.serve.emit") as sp:
-                if moe_np is not None:
-                    sp.set(**self._moe_attrs(
-                        moe_np,
-                        len(active) * (self.spec_k + 1 if self.spec_enabled else 1)
-                        + (rode if rider is not None else 0),
-                    ))
-                n_emit = n_fin = 0
-                now = self.clock()
-                self._h_step.observe(now - t0)
-                self._c_steps.inc()
-                self._step_count += 1
-                dt = now - t0
-                self._ema_step_s = (
-                    dt if self._ema_step_s == 0.0
-                    else 0.8 * self._ema_step_s + 0.2 * dt
-                )
-                # pass 1 — tokens + trace events for EVERY slot, batched into
-                # ONE tracer ingestion (one lock round-trip per step, not per
-                # slot), and ingested BEFORE any retirement below can fold a
-                # finishing request's buffer into its terminal record
-                emitted: list = []
-                ev_batch: list = []
-                heat_batch: list = []
-                heat = self._heat_decode  # ISSUE 16: decode-pool heat ledger
-                page = self.page_size
+        # that is past prefill and short of its count
+        rows = self._rows_due()
+        return self._dispatch(rows, rider, unwaited) if rows else None
+
+    def _dispatch(self, active: List[int], rider: Optional[int] = None,
+                  unwaited=()) -> _Flight:
+        """The ``ds.serve.decode.dispatch`` leaf: launch one step program with
+        a row for each slot of ``active`` (and ``rider``'s chunk, after the
+        ``unwaited`` slots' calls) and move the dispatched side of the tables
+        on. With a step in flight (``ahead`` 1) a row whose last token that
+        step samples takes it from the step's output on the device."""
+        before = self._flight
+        with spans.span(
+            "ds.serve.decode.dispatch", active=len(active), ahead=int(before is not None)
+        ) as sp:
+            t0 = self.clock()
+            # tokens the queries attend (each slot's cached context and the
+            # token this step writes) and the pages those contexts hold
+            lens = self.table.seq_lens[active]
+            attended = int(lens.sum()) + len(active)
+            if self.windowed:
+                # what a layer reads, averaged over the layers: a window
+                # layer reads its window of a context, not the context
+                # (of a family of several kinds the sub-blocks that
+                # attend: a cross layer reads its source's whole context,
+                # a state-space mixer and a memory unit no key)
+                ws = self.family.windows
+                attended = int(sum(
+                    (np.minimum(lens + 1, w).sum() if w else attended)
+                    for k, w in zip(smodel.sub_block_kinds(self.family), ws) if k in ("attn", "cross")
+                ) / len(ws))
+            sp.set(
+                attended=attended,
+                pages=int((lens // self.page_size).sum()) + len(active),
+            )
+            self._c_slot_steps.inc(len(active))
+            self._c_attended.inc(attended)
+            prev = src = None
+            if before is not None:
+                self._c_ahead.inc()
+                prev = self._token_of(before.out)
+                src = np.full((self.max_slots,), -1, np.int32)
                 for i in active:
-                    req = self.slots[i].request
-                    if self.spec_enabled:
-                        toks = self._accept_tokens(req, drafts[i], out_np[i])
-                    else:
-                        toks = [int(out_np[i])]
-                    req.tokens.extend(toks)
-                    n_emit += len(toks)
-                    if heat is not None:
-                        # the step's KV write landed in the page holding the last
-                        # emitted position; the attended set is the slot's
-                        # block-table prefix (leanest columnar shape — offline
-                        # expansion rides the session's S-event page list)
-                        pos_after = self.slots[i].pos + len(toks)
-                        heat_batch.append((
-                            i, int(self.table.block_tables[i, (pos_after - 1) // page]),
-                            pages_for(pos_after, page),
-                        ))
-                    # one emission timestamp per token: an accepted speculative
-                    # run lands at ONE instant — the streaming-client truth the
-                    # TPOT quantiles derive from (ISSUE 11)
-                    req.t_emissions.extend([now] * len(toks))
-                    if self.tracer is not None:
-                        ev_batch.append((req.id, {
-                            "e": "verify", "t": now, "step": self._step_count,
-                            "slot": i, "emitted": len(toks),
-                            "drafted": self.spec_k, "accepted": len(toks) - 1,
-                            "total": len(req.tokens),
-                        } if self.spec_enabled else (
-                            # plain decode: the lean columnar series (emitted
-                            # is always 1) — this line runs for every slot of
-                            # every step the engine ever takes
-                            now, self._step_count, i,
-                        )))
-                    emitted.append((i, toks))
-                if ev_batch:
-                    if self.spec_enabled:
-                        self.tracer.step_events(ev_batch)
-                    else:
-                        self.tracer.decode_events(ev_batch)
-                if heat_batch:
-                    heat.touch_step(now, self._step_count, heat_batch)
-                # pass 2 — advance/retire the slots
-                for i, toks in emitted:
-                    slot = self.slots[i]
-                    req = slot.request
-                    slot.pos += len(toks)
-                    slot.step += 1
-                    self.table.seq_lens[i] = slot.pos
-                    self.table.tokens[i] = toks[-1]
-                    if len(req.tokens) >= req.max_new_tokens or (
-                        req.eos_token_id is not None
-                        and toks[-1] == req.eos_token_id
-                    ):
-                        self._finish_slot(i, RequestStatus.FINISHED, "", now)
-                        n_fin += 1
-                    elif req.stall_after is not None and len(req.tokens) >= req.stall_after:
-                        # injected transient slot failure (ISSUE 7): evict and
-                        # route through the retry-with-backoff path
-                        self._fail_slot(i, "injected slot stall", now)
-                    elif slot.keys is not None and slot.step < len(slot.keys):
-                        self.table.keys[i] = slot.keys[slot.step]
-                if started is not None:
+                    s = self.slots[i]
+                    if s.first_due:
+                        src[i] = self.max_slots
+                    elif s.sent > s.step:
+                        src[i] = i
+            rows = self.table.rows(active, prev, src)
+            self._count_latent_walk(
+                "mla_paged_verify" if self.spec_enabled else "mla_paged_decode",
+                rows[1], self.spec_k + 1 if self.spec_enabled else 1,
+            )
+            flight = _Flight(None, [(i, self.slots[i]) for i in active], t0)
+            # the AOT executable takes the numpy slot tables directly — a
+            # jnp.asarray wrapper here would dispatch extra device ops
+            # per decode step (dslint jnp-in-hot-loop)
+            if self.spec_enabled:
+                T = self.spec_k + 1
+                vt = np.zeros((self.max_slots, T), np.int32)
+                vt[:, 0] = self.table.tokens
+                for i in active:
+                    d = self._draft(self.slots[i].request)
+                    flight.drafts[i] = d
+                    vt[i, 1:] = d
+                dset = self.decode_set
+                flight.out = dset.take_pools(self._verify_exec(
+                    dset.params, *dset.pool_args(), vt, rows[1], rows[2],
+                ))
+                self._c_spec_steps.inc()
+                self._c_spec_drafted.inc(self.spec_k * len(active))
+            elif rider is not None:
+                # the further prefilling slots' calls that nothing waits
+                # for, then the chunk program with the step's own rows:
+                # the slots' tokens come back with the chunk's, in one fetch
+                for i in unwaited:
+                    self._launch_alone(i)
+                flight.rode = self._chunk_reach(rider)[0]
+                flight.out, last = self._launch_chunk(rider, rows)
+                self._c_chunks_rode.inc()
+                if last:
                     # the chunk that rode was its prompt's last: the first
-                    # token is this step's, decoding starts with the next
-                    if alone_np:
-                        moe_done.append((np.concatenate(alone_np), started.moe_tokens))
-                    started.moe_counts, started.moe_tokens = [], 0
-                    self._start_decoding(rider, int(out_np[-1]))
-                if rider is not None and moe_done:
-                    # the step's chunk leaf is closed: its record takes this
-                    chunk_sp.set(**self._moe_report(moe_done))
-                sp.set(tokens=n_emit, finished=n_fin)
+                    # token is this step's last place, and the slot's rows
+                    # are launched from the next step on
+                    flight.started = (rider, self.slots[rider])
+                    self.slots[rider].first_due = True
+                    self._arm(rider)
+            else:
+                dset = self.decode_set
+                flight.out = dset.take_pools(self._decode_exec(
+                    dset.params, *dset.pool_args(), *rows,
+                ))
+            # the dispatched side moves on at the launch: the next step's
+            # lengths and keys are known without this one's tokens (a verify
+            # step's lengths move by what it accepts, when it is read)
+            for i in active:
+                s = self.slots[i]
+                s.sent += 1
+                if s.keys is not None and s.sent < len(s.keys):
+                    self.table.keys[i] = s.keys[s.sent]
+            if not self.spec_enabled:
+                self.table.seq_lens[active] += 1
+        return flight
 
-        with spans.span("ds.serve.housekeep") as hk:
-            # which of its four chores a long one ran (the span's attrs, set at exit)
-            scanned = pumped = 0
-            # straggler detection (ISSUE 5 watchdog): a request resident in a
-            # slot far beyond its expected decode budget (straggler_factor x
-            # max_new_tokens x EMA step time) is flagged once — a wedged or
-            # pathologically slow request surfaces instead of silently holding
-            # a slot. Slots advance in lockstep, so residence time is the only
-            # per-request axis that can straggle.
-            if self.watchdog is not None and self._ema_step_s > 0.0:
-                factor = float(getattr(self.watchdog.config, "straggler_factor", 3.0))
-                now = self.clock()
-                for slot in self.slots:
-                    req = slot.request
-                    if req is None or req.t_first_token is None:
-                        continue
-                    scanned += 1
-                    budget = factor * max(1, req.max_new_tokens) * self._ema_step_s
-                    elapsed = now - req.t_first_token
-                    if elapsed > budget and self.watchdog.observe_straggler(
-                        self._step_count, req.id,
-                        f"slot residence {elapsed:.3f}s > {budget:.3f}s "
-                        f"({len(req.tokens)}/{req.max_new_tokens} tokens)",
-                    ):
-                        self._c_stragglers.inc()
+    def _resolve(self, flight: _Flight) -> None:
+        """Read a launched step and emit it: the ONE place a step waits for
+        the device. The fetch is of the step program's own outputs, the tokens
+        with the expert loads that ride them, and with them the first tokens
+        that a whole prefill or a prompt's last chunk left on its slot while
+        this step was in flight (programs queued before the step launched
+        after it: the fetch waits for neither more nor less than the host
+        needs). A row whose slot was ended since the launch (a stop the tokens
+        decided, a stall, a deadline, a drain) is dropped and counted."""
+        # the ONE deliberate sync of the slot loop: the scheduler must
+        # read the sampled tokens to retire/advance slots (with them, a
+        # prompt's earlier calls' expert loads where its last chunk rode)
+        with spans.span("ds.serve.decode.wait"):
+            rider, started = flight.started or (None, None)
+            if started is not None and self.slots[rider] is not started:
+                started = None   # ended since: its first token is nobody's
+            late = [] if self.disaggregated else [
+                i for i, s in enumerate(self.slots)
+                if s.request is not None and s.pending_tok is not None
+            ]
+            out_np, alone_np, late_np = jax.device_get((  # dslint: disable=host-sync-in-step
+                flight.out, list(started.moe_counts) if started else [],
+                [(self.slots[i].pending_tok, list(self.slots[i].moe_counts)) for i in late],
+            ))
+        moe_np = None
+        if self.family.sparse_layers:
+            out_np, moe_np = out_np  # the expert loads rode the same fetch
+        active = flight.rows
+        with spans.span("ds.serve.emit") as sp:
+            if moe_np is not None:
+                sp.set(**self._moe_attrs(
+                    moe_np,
+                    len(active) * (self.spec_k + 1 if self.spec_enabled else 1)
+                    + flight.rode,
+                ))
+            n_emit = n_fin = dropped = 0
+            now = self.clock()
+            # one step's time: from its launch, or from the read of the step
+            # before where it was launched behind that (it ran behind it on
+            # the device too), to its read
+            dt = now - max(flight.t0, self._t_read)
+            self._t_read = now
+            self._h_step.observe(dt)
+            self._c_steps.inc()
+            self._step_count += 1
+            self._ema_step_s = (
+                dt if self._ema_step_s == 0.0
+                else 0.8 * self._ema_step_s + 0.2 * dt
+            )
+            # pass 1 — tokens + trace events for EVERY slot, batched into
+            # ONE tracer ingestion (one lock round-trip per step, not per
+            # slot), and ingested BEFORE any retirement below can fold a
+            # finishing request's buffer into its terminal record
+            emitted: list = []
+            ev_batch: list = []
+            heat_batch: list = []
+            heat = self._heat_decode  # ISSUE 16: decode-pool heat ledger
+            page = self.page_size
+            for i, held in active:
+                slot = held
+                if self.slots[i] is not held and not held.handed:
+                    dropped += 1   # the slot was ended since the launch
+                    continue
+                req = slot.request
+                if self.spec_enabled:
+                    toks = self._accept_tokens(req, flight.drafts[i], out_np[i])
+                else:
+                    toks = [int(out_np[i])]
+                req.tokens.extend(toks)
+                n_emit += len(toks)
+                if heat is not None and not slot.handed:
+                    # the step's KV write landed in the page holding the last
+                    # emitted position; the attended set is the slot's
+                    # block-table prefix (leanest columnar shape — offline
+                    # expansion rides the session's S-event page list)
+                    pos_after = slot.pos + len(toks)
+                    heat_batch.append((
+                        i, int(self.table.block_tables[i, (pos_after - 1) // page]),
+                        pages_for(pos_after, page),
+                    ))
+                # one emission timestamp per token: an accepted speculative
+                # run lands at ONE instant — the streaming-client truth the
+                # TPOT quantiles derive from (ISSUE 11)
+                req.t_emissions.extend([now] * len(toks))
+                if self.tracer is not None:
+                    ev_batch.append((req.id, {
+                        "e": "verify", "t": now, "step": self._step_count,
+                        "slot": i, "emitted": len(toks),
+                        "drafted": self.spec_k, "accepted": len(toks) - 1,
+                        "total": len(req.tokens),
+                    } if self.spec_enabled else (
+                        # plain decode: the lean columnar series (emitted
+                        # is always 1) — this line runs for every slot of
+                        # every step the engine ever takes
+                        now, self._step_count, i,
+                    )))
+                emitted.append((i, slot, toks))
+            if ev_batch:
+                if self.spec_enabled:
+                    self.tracer.step_events(ev_batch)
+                else:
+                    self.tracer.decode_events(ev_batch)
+            if heat_batch:
+                heat.touch_step(now, self._step_count, heat_batch)
+            # pass 2 — advance/retire the slots (the emitted side; the
+            # lengths and keys moved on when the row was launched)
+            for i, slot, toks in emitted:
+                req = slot.request
+                slot.pos += len(toks)
+                slot.step += 1
+                if slot.handed:
+                    # the slot is its next request's since this row's launch
+                    self._close_request(req, RequestStatus.FINISHED, "", now)
+                    self._req_terminal(req, now)
+                    self.completed.append(req)
+                    n_fin += 1
+                    continue
+                if self.spec_enabled:
+                    self.table.seq_lens[i] = slot.pos
+                self.table.tokens[i] = toks[-1]
+                if len(req.tokens) >= req.max_new_tokens or (
+                    req.eos_token_id is not None
+                    and toks[-1] == req.eos_token_id
+                ):
+                    self._finish_slot(i, RequestStatus.FINISHED, "", now)
+                    n_fin += 1
+                elif req.stall_after is not None and len(req.tokens) >= req.stall_after:
+                    # injected transient slot failure (ISSUE 7): evict and
+                    # route through the retry-with-backoff path
+                    self._fail_slot(i, "injected slot stall", now)
+            if started is not None:
+                # the chunk that rode was its prompt's last: the first
+                # token is this step's, decoding started with the next
+                if alone_np:
+                    self._moe_done.append((np.concatenate(alone_np), started.moe_tokens))
+                started.moe_counts, started.moe_tokens = [], 0
+                self._first_token(rider, int(out_np[-1]), self.clock())
+            for i, (tok_np, counts) in zip(late, late_np):
+                # a whole prefill's [1] or a chunk call's [slots + 1]: the prompt's last
+                slot = self.slots[i]
+                if counts:
+                    self._moe_done.append((np.concatenate(counts), slot.moe_tokens))
+                slot.moe_counts, slot.moe_tokens, slot.pending_tok = [], 0, None
+                self._start_decoding(i, int(tok_np[-1]))
+            if dropped:
+                self._c_dropped.inc(dropped)
+            if self._moe_done:
+                self._report_chunk_loads()
+            sp.set(tokens=n_emit, finished=n_fin)
 
-            n_active = sum(1 for s in self.slots if s.request is not None)
-            self._g_queue.set(len(self.queue))
-            self._g_util.set(n_active / self.max_slots)
-            self._g_pages.set(self.allocator.pages_in_use)
-            self._g_occ.set(self.allocator.pages_in_use / self.allocator.capacity)
-            self._g_pages_shared.set(self.allocator.pages_shared)
-            if self.prefix_cache is not None:
-                self._g_index_pages.set(len(self.prefix_cache))
-            if self.tiering is not None:
-                pumped = self._tier_pump()
-            refresh = bool(self._step_count and self._step_count % 32 == 0)
-            if refresh:
-                self.stats()  # refresh the quantile gauges for textfile scrapes
-            journaled = self._journal is not None and self._journal.maybe_snapshot(self.clock())
-            hk.set(stats=int(refresh), journal=int(journaled), pump=pumped, stragglers=scanned)
-        return n_active
+    def _report_chunk_loads(self) -> None:
+        """The prompts whose last chunk's token this call read report their
+        chunk calls' expert loads on the call's ``ds.serve.chunk`` leaf: it is
+        closed, and its record takes attributes until the step ends. (Read
+        outside a call that has one, they wait for the next.)"""
+        if self._chunk_sp is not None:
+            self._chunk_sp.set(**self._moe_report(self._moe_done))
+            self._moe_done = []
 
     def _pages_needed(self, req: Request) -> int:
         """Net new DECODE-pool pages an admission must allocate: the
@@ -2178,7 +2438,7 @@ class ServingEngine:
         slot.pending_tok = None
         slot.moe_counts, slot.moe_tokens = [], 0
         slot.pos = 0
-        slot.step = 0
+        slot.step = slot.sent = 0
         slot.keys = None
         slot.shared_pages = len(shared)
         slot.row = None
@@ -2255,25 +2515,38 @@ class ServingEngine:
                 )
             return
 
-        self.table.assign(slot_i, pages)
-        page_ids = self.table.block_tables[slot_i, : self.prefill_pages]
+        # the slot's real block table lives on the slot until it decodes: the
+        # main table's row stays scratch, so that a decode step launched while
+        # the first token is still on the device cannot write this slot's pages
+        slot.row = np.zeros((1, self.pages_per_slot), np.int32)
+        slot.row[0, : len(pages)] = pages
         first = self._token_of(pset.take_pools(self._prefill_exec(
             pset.params, *pset.pool_args(),
-            ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
+            ids, np.asarray(req.prompt_len, np.int32),
+            slot.row[0, : self.prefill_pages], key0,
             *self._slot_operand(slot_i),
         )))
         self._c_prefills.inc()
-        # deliberate sync: TTFT is defined by the first token reaching the
-        # host, and an at-admission EOS must retire the slot before decode
-        with spans.span("ds.serve.prefill.wait"):
-            tok0 = int(jax.device_get(first)[0])  # dslint: disable=host-sync-in-step
+        if self._flight is not None:
+            # a step is in flight: the token stays on the slot, as under
+            # disaggregation, and is read with that step's fetch (`_resolve`)
+            slot.pending_tok = first
+            slot.prefilling = True
+            slot.prefill_pos = req.prompt_len
+            req.status = RequestStatus.RUNNING
+        else:
+            # deliberate sync: TTFT is defined by the first token reaching the
+            # host, and an at-admission EOS must retire the slot before decode
+            with spans.span("ds.serve.prefill.wait"):
+                tok0 = int(jax.device_get(first)[0])  # dslint: disable=host-sync-in-step
         if self.tracer is not None:
             self.tracer.event(
                 req, "prefill", self.clock(), step=self._step_count,
                 slot=slot_i, width=self.prefill_width,
                 prompt_len=req.prompt_len,
             )
-        self._start_decoding(slot_i, tok0)
+        if slot.pending_tok is None:
+            self._start_decoding(slot_i, tok0)
 
     def _slot_operand(self, slot_i: int) -> tuple:
         """The last host operand of the prefill and chunk programs of a
@@ -2379,19 +2652,23 @@ class ServingEngine:
             slot.moe_tokens += t
         return out, final
 
-    def _advance_chunks(self, alone: list, rider: int = None):
-        """The ``ds.serve.chunk`` leaf of a step: one call of the chunk
-        program with no decode row for each slot of ``alone``; ``rider`` is
-        the slot whose chunk the step's decode dispatch will carry, if one
-        will (the leaf counts its tokens and key rows beside the others').
+    def _advance_chunks(self, pre: list):
+        """The ``ds.serve.chunk`` leaf of a step, for the prefilling slots
+        ``pre``: where the call has a decode dispatch to come on the same
+        placement, the first of them is the ``rider``, whose chunk that
+        dispatch will carry (the leaf counts its tokens and key rows beside
+        the others'); one call of the chunk program with no decode row for
+        each of the others.
         On a prompt's final chunk the sampled token becomes the request's
-        first token and the slot joins the decode batch. → (the span,
-        closed; (loads, tokens) of the prompts that finished here, for
-        :meth:`_moe_report`; the slots of ``alone`` whose call the dispatch
-        leaf launches). With a ``rider`` the caller reports the prompts on
-        the span once the step's fetch is in, which may bring one prompt
-        more (the rider's, where its last chunk rode): the ring's record
-        takes attributes until the step ends.
+        first token and the slot joins the decode batch: here, where nothing
+        is in flight and this leaf waits for it; else the token stays on the
+        slot (``pending_tok``) and the step's fetch reads it. → (the span,
+        closed; the rider; the slots whose call the dispatch leaf
+        launches). (loads, tokens) of the prompts that finished go to
+        ``_moe_done``, and :meth:`_step` reports them on the span once the
+        step's fetch is in, which may bring more (a prompt whose last chunk
+        rode, or was not waited for): the ring's record takes attributes
+        until the step ends.
 
         Which calls the dispatch leaf launches, ahead of the one that carries
         the ``rider``: those nothing here waits for (not a prompt's last
@@ -2400,16 +2677,16 @@ class ServingEngine:
         shortly before a dispatch leaf's launch for that leaf's own and sets
         the device's clock by it; launched under the leaf, none does. A call
         this leaf waits for has ended before the dispatch opens."""
-        with spans.span(
-            "ds.serve.chunk", chunks=len(alone), rode=int(rider is not None)
-        ) as sp:
+        with spans.span("ds.serve.chunk") as sp:
+            rider = pre[0] if self._ahead_ok and self._rows_due() else None
+            alone = pre[rider is not None:]
+            sp.set(chunks=len(alone), rode=int(rider is not None))
             n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
             finals = int(rider is not None and self._chunk_is_last(rider))
             for i in alone + ([rider] if rider is not None else []):
                 self._count_latent_walk(
                     "mla_paged_chunk", [self.slots[i].prefill_pos], self.chunk_width
                 )
-            moe = []   # (counts, tokens) of the prompts that finished here
             unwaited = []
             for i in alone:
                 slot = self.slots[i]
@@ -2423,10 +2700,11 @@ class ServingEngine:
                 out, final = self._launch_alone(i)
                 if not final:
                     continue  # more chunks; the decode batch advances meanwhile
-                if self.disaggregated:
-                    # the final chunk's sample stays on device; step phase 2c
+                if self.disaggregated or self._flight is not None:
+                    # the final chunk's sample stays on device: step phase 2c
                     # syncs it and hands the prompt KV off to the decode
-                    # placement
+                    # placement, or (a step in flight) that step's fetch
+                    # reads it (`_resolve`)
                     slot.pending_tok = out
                     continue
                 # deliberate sync, as in _admit: the final chunk's sample is
@@ -2434,7 +2712,7 @@ class ServingEngine:
                 with spans.span("ds.serve.chunk.wait"):
                     tok_np, *counts = jax.device_get((out, *slot.moe_counts))  # dslint: disable=host-sync-in-step
                 if counts:
-                    moe.append((np.concatenate(counts), slot.moe_tokens))
+                    self._moe_done.append((np.concatenate(counts), slot.moe_tokens))
                 slot.moe_counts, slot.moe_tokens = [], 0
                 self._start_decoding(i, int(tok_np[-1]))
             sp.set(tokens=n_tok, attended=attended)
@@ -2445,11 +2723,7 @@ class ServingEngine:
                 sp.set(rows_self=n_tok, rows_cross=finals if stops else n_tok)
                 if stops:
                     self._c_rows_skipped.inc(n_tok - finals)
-            if moe and rider is None:
-                # a prompt's chunk calls report with its last one, whose
-                # token fetch is the one wait there is
-                sp.set(**self._moe_report(moe))
-        return sp, moe, unwaited
+        return sp, rider, unwaited
 
     def _complete_handoff(self, slot_i: int) -> None:
         """Finish a disaggregated prefill (ISSUE 14): read the pending first
@@ -2521,13 +2795,20 @@ class ServingEngine:
         self._start_decoding(slot_i, tok0)
 
     def _start_decoding(self, slot_i: int, tok0: int) -> None:
-        """Shared post-prefill transition: install the real block table (if
-        the prefill ran chunked), record TTFT, register the prompt's full
-        pages in the prefix index, arm sampling keys, and handle an
-        immediate EOS / single-token ask."""
+        """Shared post-prefill transition, where the host has the first token
+        in hand: both sides of it, :meth:`_arm` and :meth:`_first_token`."""
+        now = self.clock()
+        self._arm(slot_i)
+        self._first_token(slot_i, tok0, now)
+
+    def _arm(self, slot_i: int) -> None:
+        """The dispatched side of a prompt's end: install the real block
+        table (if the prefill kept it on the slot), set the length and arm
+        the sampling keys. From here on the slot's decode rows can be
+        launched, with the first token in the host's table or still on the
+        device (``first_due``)."""
         slot = self.slots[slot_i]
         req = slot.request
-        now = self.clock()
         if self.disaggregated:
             # the slot decodes against its private decode-pool reservation;
             # whatever row the prefill used addressed the OTHER pool
@@ -2538,27 +2819,8 @@ class ServingEngine:
             self.table.block_tables[slot_i, :] = slot.row[0]
             slot.prefilling = False
             slot.row = None
-        req.status = RequestStatus.RUNNING
-        # TTFT = the first SAMPLED token reaching the host. Under chunked
-        # prefill that is the LAST chunk's sample (earlier chunks emit
-        # nothing a client could stream) — the ISSUE 11 pin.
-        req.t_first_token = now
-        self._h_ttft.observe(now - req.t_submit)
-        req.tokens.append(tok0)
-        req.t_emissions.append(now)
-        if self.tracer is not None:
-            self.tracer.event(
-                req, "first_token", now, step=self._step_count, slot=slot_i,
-                ttft_s=now - req.t_submit,
-            )
         slot.pos = req.prompt_len
         self.table.seq_lens[slot_i] = slot.pos
-        self.table.tokens[slot_i] = tok0
-        if self.prefix_cache is not None and not self.disaggregated:
-            # disaggregated: _complete_handoff already indexed the
-            # PREFILL-side pages — slot.pages here are decode-pool ids
-            self.prefix_cache.insert(req.prompt, slot.pages)
-            self._g_index_pages.set(len(self.prefix_cache))
         if self._sampling and req.max_new_tokens > 1:
             # the EXACT key sequence of gpt2.generate for this request:
             # step t consumes split(fold_in(PRNGKey(seed), 1), N-1)[t-1].
@@ -2573,6 +2835,33 @@ class ServingEngine:
             with spans.span("ds.serve.keys.wait"):
                 slot.keys = np.asarray(keys)  # dslint: disable=host-sync-in-step
             self.table.keys[slot_i] = slot.keys[0]
+
+    def _first_token(self, slot_i: int, tok0: int, now: float) -> None:
+        """The emitted side of a prompt's end, at ``now``, where the host got
+        the token: record TTFT, register the prompt's full pages in the
+        prefix index, and handle an immediate EOS / single-token ask."""
+        slot = self.slots[slot_i]
+        req = slot.request
+        slot.first_due = False
+        req.status = RequestStatus.RUNNING
+        # TTFT = the first SAMPLED token reaching the host. Under chunked
+        # prefill that is the LAST chunk's sample (earlier chunks emit
+        # nothing a client could stream) — the ISSUE 11 pin.
+        req.t_first_token = now
+        self._h_ttft.observe(now - req.t_submit)
+        req.tokens.append(tok0)
+        req.t_emissions.append(now)
+        if self.tracer is not None:
+            self.tracer.event(
+                req, "first_token", now, step=self._step_count, slot=slot_i,
+                ttft_s=now - req.t_submit,
+            )
+        self.table.tokens[slot_i] = tok0
+        if self.prefix_cache is not None and not self.disaggregated:
+            # disaggregated: _complete_handoff already indexed the
+            # PREFILL-side pages — slot.pages here are decode-pool ids
+            self.prefix_cache.insert(req.prompt, slot.pages)
+            self._g_index_pages.set(len(self.prefix_cache))
         if req.max_new_tokens == 1 or (
             req.eos_token_id is not None and tok0 == req.eos_token_id
         ):
@@ -2581,6 +2870,14 @@ class ServingEngine:
     def _finish_slot(self, slot_i: int, status: str, detail: str, now: float) -> None:
         slot = self.slots[slot_i]
         req = slot.request
+        self._close_request(req, status, detail, now)
+        self._vacate(slot_i, now)
+        self._req_terminal(req, now)
+        self.completed.append(req)
+
+    def _close_request(self, req: Request, status: str, detail: str, now: float) -> None:
+        """The request's side of a finish: its status, its stamp, the gaps
+        between its tokens and the counters."""
         stopped_on_eos = (
             req.eos_token_id is not None
             and bool(req.tokens)
@@ -2607,17 +2904,6 @@ class ServingEngine:
             self._h_tpot.observe(gap)
         self._c_requests.inc(status=status)
         self._c_tokens.inc(len(req.tokens))
-        if self._heat_decode is not None:
-            self._heat_decode.session_end(now, slot_i)
-        self.allocator.free(slot.pages)
-        if slot.prefill_pages:
-            # evicted mid-prefill (timeout / preempt) before the handoff
-            # could free the prefill-side reservation
-            self.prefill_set.allocator.free(slot.prefill_pages)
-        self.table.clear(slot_i)
-        self.slots[slot_i] = _Slot()
-        self._req_terminal(req, now)
-        self.completed.append(req)
 
     def _slo_verdict(self, req: Request) -> Optional[dict]:
         """The request's SLO outcome against its class targets, or None
@@ -2680,15 +2966,8 @@ class ServingEngine:
         immediately, then either re-enqueue the request with exponential
         backoff (``serving.retry_max`` budget — generation restarts from
         scratch, the evicted KV is gone) or finish it terminal FAILED."""
-        slot = self.slots[slot_i]
-        req = slot.request
-        if self._heat_decode is not None:
-            self._heat_decode.session_end(now, slot_i)
-        self.allocator.free(slot.pages)
-        if slot.prefill_pages:
-            self.prefill_set.allocator.free(slot.prefill_pages)
-        self.table.clear(slot_i)
-        self.slots[slot_i] = _Slot()
+        req = self.slots[slot_i].request
+        self._vacate(slot_i, now)
         self._retry_or_fail(req, why, now)
 
     def _retry_or_fail(self, req: Request, why: str, now: float) -> None:
@@ -2746,6 +3025,9 @@ class ServingEngine:
         Idempotent and terminal for this engine instance — ``submit`` after
         ``drain`` rejects with "engine draining"."""
         self._draining = True
+        # the step in flight is computed: read it, so that no token of it is
+        # lost and the slots it finishes count as finished
+        self.settle()
         start = self.clock()
         deadline = start + float(
             self.config.drain_deadline_s if deadline_s is None else deadline_s
@@ -2769,6 +3051,7 @@ class ServingEngine:
                 1 for x in before
                 if x not in {id(s.request) for s in self.slots if s.request is not None}
             )
+        self.settle()   # what the last step launched ahead
         now = self.clock()
         deadline_hit = False
         for i, s in enumerate(self.slots):
@@ -2828,6 +3111,7 @@ class ServingEngine:
                 f"(queue={len(self.queue)}, "
                 f"active={sum(1 for s in self.slots if s.request)})"
             )
+        self.settle()   # rows launched ahead for a slot that a late stop ended
         return self.completed[start:]
 
     # ------------------------------------------------------------------
@@ -2915,6 +3199,7 @@ class ServingEngine:
         the PR-7 crc-checked manifest, transfers, and the peer rebuilds the
         slot with :meth:`adopt_session`. The slot itself is untouched —
         pair with :meth:`release_slot` once the payload is written."""
+        self.settle()   # the session that moves holds every token computed for it
         slot = self.slots[slot_i]
         req = slot.request
         if req is None:
@@ -2969,20 +3254,14 @@ class ServingEngine:
         request handed back to the caller still RUNNING — it finishes on
         the peer replica. The source can never emit for this session again
         (its slot is gone), which is the concrete form of the model's
-        no-dual-emission invariant."""
+        no-dual-emission invariant. A row of the slot's in the step in
+        flight is computed and dropped when that step is read: the request
+        leaves with the tokens it has, and no token is emitted here after."""
         slot = self.slots[slot_i]
         req = slot.request
         if req is None:
             raise ValueError(f"slot {slot_i} is empty")
-        if now is None:
-            now = self.clock()
-        if self._heat_decode is not None:
-            self._heat_decode.session_end(now, slot_i)
-        self.allocator.free(slot.pages)
-        if slot.prefill_pages:
-            self.prefill_set.allocator.free(slot.prefill_pages)
-        self.table.clear(slot_i)
-        self.slots[slot_i] = _Slot()
+        self._vacate(slot_i, self.clock() if now is None else now)
         return req
 
     def adopt_session(self, state: dict, arrays: dict, request=None):
@@ -3063,7 +3342,9 @@ class ServingEngine:
         slot.request = req
         slot.pages = list(pages)
         slot.pos = int(state["pos"])
-        slot.step = int(state["step"])
+        # the source read its step in flight before the export: the
+        # dispatched side of the session stands where its emitted side does
+        slot.step = slot.sent = int(state["step"])
         slot.prefilling = False
         keys = arrays.get("keys")
         if keys is not None:
@@ -3505,6 +3786,10 @@ class ServingEngine:
             total, n = self._h_handoff.stats()
             out["kv_handoff_latency_mean_s"] = (total / n) if n else None
         out["chunk_prefills"] = int(self._c_chunks.value())
+        # steps launched with the step before in flight, and rows launched
+        # ahead for a slot that a late stop ended (computed and dropped)
+        out["steps_ahead"] = int(self._c_ahead.value())
+        out["rows_dropped"] = int(self._c_dropped.value())
         if self.prefix_cache is not None:
             pc = self.prefix_cache
             lookups = pc.hits_full + pc.hits_partial + pc.misses
@@ -3547,6 +3832,7 @@ class ServingEngine:
         index lives on the PREFILL allocator; the decode pool must drain
         completely — a page left there means a handoff leaked its
         reservation."""
+        self.settle()
         held = self.prefix_cache.held_pages if self.prefix_cache else None
         if self.disaggregated:
             self.prefill_set.allocator.check_no_leaks(allowed=held)

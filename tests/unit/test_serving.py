@@ -1443,6 +1443,9 @@ class TestServingStats:
         srv.step()  # admit + first decode (EMA step time learned)
         srv.step()
         assert srv.metrics.counter("serving_stragglers_total").value() == 0
+        # a step is in flight between two calls: read it before the clock
+        # jumps, or the jump is that step's latency and moves the budget
+        srv.settle()
         clock.t += 1000.0  # the request now looks wedged in its slot
         srv.step()
         assert srv.metrics.counter("serving_stragglers_total").value() == 1
@@ -1453,3 +1456,411 @@ class TestServingStats:
         assert srv.metrics.counter("serving_stragglers_total").value() == 1
         srv.run()
         srv.check_no_leaks()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 54: one step in flight while the host reads the step before it
+# ---------------------------------------------------------------------------
+
+AHEAD_FAMILIES = ("gpt2", "exaone_moe", "mistral4", "longcat_flash", "phi4flash", "zaya", "qwen3_next")
+AHEAD_SERVING = dict(max_slots=3, page_size=4, num_pages=96, max_prompt_len=40, max_new_tokens=12,
+                     prefill_chunk_tokens=8, temperature=0.0, retry_max=1, retry_backoff_s=0.0)
+
+
+@pytest.fixture(scope="module")
+def family_engines(inference_engine, tiny_cfg):
+    """family -> (engine, vocabulary) at the tiny configuration its own test
+    file serves, each built once a module."""
+    import deepspeed_tpu
+
+    made = {}
+
+    def get(family):
+        if family in made:
+            return made[family]
+        if family == "gpt2":
+            made[family] = (inference_engine, tiny_cfg.vocab_size)
+            return made[family]
+        import importlib
+
+        mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
+        if family == "zaya":
+            from .test_zaya import CFG, seeded
+
+            mcfg = mod.ZayaConfig.from_dict(CFG)
+            eng = deepspeed_tpu.init_inference(
+                model=mod.make_module(mcfg), dtype=jnp.float32, params=seeded(mcfg, 3))
+        else:
+            test = "exaone" if family == "exaone_moe" else family
+            CFG = importlib.import_module(f"tests.unit.test_serving_{test}").CFG
+            cls = next(v for k, v in vars(mod).items() if k.endswith("Config") and hasattr(v, "from_dict")
+                       and k.lower().startswith(family.replace("_", "")[:4]))
+            eng = deepspeed_tpu.init_inference(
+                model=mod.make_module(cls.from_dict(CFG)), dtype=jnp.float32, seed=3)
+        made[family] = (eng, int(CFG["vocab_size"]))
+        return made[family]
+
+    return get
+
+
+def _ahead_srv(engine, ahead=True, clock=None, **over):
+    """A server of the family; ``ahead=False``: the same loop held to depth 0,
+    which launches, reads and emits a step in one call: the parent's order."""
+    cfg = dict(AHEAD_SERVING, **over)
+    if engine.model_config.__class__.__name__ == "GPT2Config":
+        cfg.setdefault("kv_cache_dtype", "float32")
+    srv = engine.serve(cfg, **({"clock": clock} if clock is not None else {}))
+    if not ahead:
+        srv._ahead_ok = False
+    return srv
+
+
+def _ahead_prompts(vocab, lens, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, n).astype(np.int32) for n in lens]
+
+
+def _play(srv, plan, after=None):
+    """Submit ``plan``'s requests at their calls of ``step`` — ``(call,
+    prompt, submit's keywords)`` each — and step until nothing is left.
+    ``after(call)`` runs behind each call. → the requests in plan order."""
+    reqs, call = {}, 0
+    while True:
+        for k, (at, prompt, kw) in enumerate(plan):
+            if at == call:
+                reqs[k] = srv.submit(prompt, **kw)
+        if len(reqs) == len(plan) and not srv.queue and all(s.request is None for s in srv.slots):
+            break
+        srv.step()
+        if after is not None:
+            after(call)
+        call += 1
+        assert call < 400
+    srv.settle()
+    return [reqs[k] for k in range(len(plan))]
+
+
+def _same_service(got, want):
+    for a, b in zip(got, want):
+        assert list(a.tokens) == list(b.tokens)
+        assert a.status == b.status and len(a.t_emissions) == len(a.tokens)
+
+
+def _span_attrs(t0, name):
+    from deepspeed_tpu.telemetry import spans
+
+    return [r[3] for r in spans.snapshot(since=t0) if r[0] == name]
+
+
+def _span_clock():
+    from deepspeed_tpu.telemetry import spans
+
+    return spans._clock()
+
+
+class TestStepAhead:
+    @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    def test_staggered_requests_are_served_what_the_synchronous_order_serves(
+        self, family_engines, family, temperature
+    ):
+        """(a) Prompts that end in a whole prefill with nothing in flight (5),
+        in a last chunk that rides (20), in a last chunk alone (27: the second
+        slot prefilling) and in a whole prefill behind a step in flight (7),
+        staggered over the calls: tokens, status and stamps a token equal the
+        loop held to depth 0, and gpt2's equal ``generate``'s."""
+        engine, vocab = family_engines(family)
+        prompts = _ahead_prompts(vocab, (5, 20, 27, 7), seed=2)
+        plan = [(0, prompts[0], dict(max_new_tokens=9, seed=0)), (2, prompts[1], dict(max_new_tokens=12, seed=1)),
+                (2, prompts[2], dict(max_new_tokens=12, seed=2)), (4, prompts[3], dict(max_new_tokens=6, seed=3))]
+        t0 = _span_clock()
+        srv = _ahead_srv(engine, temperature=temperature)
+        got = _play(srv, plan)
+        waits = len(_span_attrs(t0, "ds.serve.prefill.wait")), len(_span_attrs(t0, "ds.serve.chunk.wait"))
+        launches = _span_attrs(t0, "ds.serve.decode.dispatch")
+        want = _play(_ahead_srv(engine, ahead=False, temperature=temperature), plan)
+        _same_service(got, want)
+        assert all(r.status == RequestStatus.FINISHED for r in got)
+        # only the empty server's first prefill waited where it was launched: the other first tokens were left
+        # on their slots (a whole prefill, a last chunk alone) or in the step their chunk rode
+        assert waits == (1, 0)
+        st = srv.stats()
+        assert st["steps_ahead"] == sum(d["ahead"] for d in launches) >= len(launches) - 2
+        assert st["rows_dropped"] == 0
+        assert sum(d["active"] for d in launches) == sum(len(r.tokens) - 1 for r in got)   # no row but a token's
+        srv.check_no_leaks()
+        if family == "gpt2" and temperature == 0.0:
+            for p, r in zip(prompts, got):
+                ref = np.asarray(engine.generate(p[None, :], max_new_tokens=len(r.tokens)))[0]
+                np.testing.assert_array_equal(r.output, ref)
+
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    def test_a_step_is_launched_before_the_step_before_it_is_read(self, family_engines, family, monkeypatch):
+        """(b) With the executables and ``jax.device_get`` instrumented: step
+        n+1's launch precedes the fetch of step n, what is fetched IS an
+        output of the step program (no slice of it, which would be a program
+        more, queued behind the step launched ahead), a call holds at most one
+        token fetch, and the program set is what it was."""
+        from deepspeed_tpu.serving import scheduler as sched
+
+        engine, vocab = family_engines(family)
+        srv = _ahead_srv(engine)
+        assert len(srv.executable_names()) == srv.expected_executables == 3
+        log = []
+
+        def spy(name):
+            exe = getattr(srv, name)
+
+            def call(*a):
+                out = exe(*a)
+                log.append(("launch", name, out))
+                return out
+
+            setattr(srv, name, call)
+
+        for name in ("_prefill_exec", "_decode_exec", "_chunk_exec"):
+            spy(name)
+        get = jax.device_get
+        monkeypatch.setattr(sched.jax, "device_get", lambda x: log.append(("fetch", x)) or get(x))
+        prompts = _ahead_prompts(vocab, (5, 20), seed=4)
+        srv.submit(prompts[0], max_new_tokens=12, seed=0)
+        calls = []
+        for call in range(14):
+            if call == 3:
+                srv.submit(prompts[1], max_new_tokens=8, seed=1)
+            mark = len(log)
+            srv.step()
+            calls.append(log[mark:])
+        steps = [e for e in log if e[0] == "launch" and e[1] != "_prefill_exec"]
+        step_at = {id(e): n for n, e in enumerate(steps)}
+        read = 0
+        for k, events in enumerate(calls):
+            kinds = [e[0] for e in events]
+            if k == 0:
+                # the empty server: its prefill waits where it is launched, then two steps go out and one is read
+                assert kinds == ["launch", "fetch", "launch", "launch", "fetch"]
+                events, kinds = events[2:], kinds[2:]
+            assert kinds.count("fetch") <= 1
+            if "fetch" not in kinds:
+                continue
+            at = kinds.index("fetch")
+            ahead = [step_at[id(e)] for e in events[:at] if id(e) in step_at]
+            # the fetch of step n follows the launch of step n+1 in the same call (but where n was the last)
+            assert ahead[-1:] == [read + 1] or read + 1 == len(steps)
+            assert kinds[at + 1:].count("launch") == 0
+            fetched = jax.tree_util.tree_leaves(events[at][1])
+            assert any(fetched[0] is leaf for leaf in jax.tree_util.tree_leaves(steps[read][2]))
+            read += 1
+        assert read >= 12
+        srv.run()
+        srv.check_no_leaks()
+        assert len(srv.executables) == srv.expected_executables == 3
+
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    def test_a_stop_by_count_launches_no_row_and_a_late_stop_drops_one(self, family_engines, family):
+        """(c) A stop by count is known at the launch: the slot gets no row in
+        the next one. An EOS in mid-request, an injected stall and a deadline
+        are seen a step late: ``req.tokens``, the status and the freed pages
+        are the synchronous order's, the row launched ahead is dropped and
+        counted."""
+        engine, vocab = family_engines(family)
+        clocks = FakeClock(), FakeClock()
+        srv = _ahead_srv(engine, clock=clocks[0])
+        ref = _ahead_srv(engine, ahead=False, clock=clocks[1])
+        p = _ahead_prompts(vocab, (5, 20, 6), seed=5)
+        # by count: a lone request of 5 tokens is 4 rows in all, and nothing is dropped
+        t0 = _span_clock()
+        (r,) = _play(srv, [(0, p[0], dict(max_new_tokens=5, seed=0))])
+        assert sum(d["active"] for d in _span_attrs(t0, "ds.serve.decode.dispatch")) == 4 == len(r.tokens) - 1
+        assert srv.stats()["rows_dropped"] == 0
+        (full,) = _play(ref, [(0, p[0], dict(max_new_tokens=12, seed=0))])
+        # EOS: the first token from the fourth on that the request has not sampled before
+        k = next(i for i in range(3, 12) if full.tokens[i] not in full.tokens[:i])
+        plan = [(0, p[0], dict(max_new_tokens=12, seed=0, eos_token_id=full.tokens[k])),
+                (1, p[1], dict(max_new_tokens=12, seed=1))]
+        got, want = _play(srv, plan), _play(ref, plan)
+        _same_service(got, want)
+        assert list(got[0].tokens) == list(full.tokens[:k + 1]) and got[0].status == RequestStatus.FINISHED
+        assert srv.stats()["rows_dropped"] == 1
+        srv.check_no_leaks()
+        # an injected stall in mid-decode: evicted, retried from scratch (into the slot it left, while the row
+        # launched ahead for its first residency is still in flight)
+        for s in (srv, ref):
+            s.fault_injector = _StallOnce()
+        got, want = (_play(s, [(0, p[2], dict(max_new_tokens=8, seed=2))]) for s in (srv, ref))
+        _same_service(got, want)
+        assert got[0].retries == 1 and len(got[0].tokens) == 8
+        assert srv.stats()["rows_dropped"] == 2
+        for s in (srv, ref):
+            s.fault_injector = None
+        # a deadline that passes in mid-decode, on a clock both servers read alike
+        def late(s, clock):
+            clock.t = 0.0
+            return _play(s, [(0, p[0], dict(max_new_tokens=12, seed=0, deadline_s=5.0)),
+                             (0, p[2], dict(max_new_tokens=12, seed=1))],
+                         after=lambda call: setattr(clock, "t", 10.0) if call == 3 else None)
+
+        got, want = late(srv, clocks[0]), late(ref, clocks[1])
+        _same_service(got, want)
+        assert got[0].status == RequestStatus.TRUNCATED and 0 < len(got[0].tokens) < 12
+        assert srv.stats()["rows_dropped"] == 3
+        for s in (srv, ref):
+            s.check_no_leaks()
+            assert s.allocator.pages_in_use == 0
+
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    def test_a_drain_and_a_release_with_a_step_in_flight_leak_no_page_and_lose_no_token(
+        self, family_engines, family
+    ):
+        """(d) ``release_slot``, ``check_no_leaks`` and ``drain(0.0)`` with a
+        step in flight: every request keeps a prefix of what it is served
+        whole, a stamp a token; nothing is emitted for a released slot after
+        its release; no page leaks."""
+        engine, vocab = family_engines(family)
+        prompts = _ahead_prompts(vocab, (5, 20, 6), seed=6)
+        plan = [(0, prompts[0], dict(max_new_tokens=12, seed=0)), (1, prompts[1], dict(max_new_tokens=12, seed=1)),
+                (1, prompts[2], dict(max_new_tokens=12, seed=2))]
+        whole = _play(_ahead_srv(engine, ahead=False), plan)
+        srv = _ahead_srv(engine)
+        reqs = [srv.submit(plan[0][1], **plan[0][2])]
+        srv.step()
+        reqs += [srv.submit(p, **kw) for _, p, kw in plan[1:]]
+        for _ in range(4):
+            srv.step()
+        assert srv._flight is not None and srv._flight.rows
+        i = next(i for i, s in enumerate(srv.slots) if s.request is reqs[0])
+        n_before = len(reqs[0].tokens)
+        assert srv.release_slot(i) is reqs[0] and reqs[0].status == RequestStatus.RUNNING
+        dropped = srv.stats()["rows_dropped"]
+        srv.step()                                # reads the step that held the released slot's row: dropped
+        assert len(reqs[0].tokens) == n_before and srv.stats()["rows_dropped"] == dropped + 1
+        srv.step()
+        assert srv._flight is not None
+        with pytest.raises(PageAllocatorError):
+            srv.check_no_leaks()                  # slots are live; it read the step in flight first all the same
+        assert srv._flight is None
+        srv.step()
+        assert srv._flight is not None
+        n_mid = [len(r.tokens) for r in reqs]
+        out = srv.drain(0.0)
+        assert srv._flight is None and out["preempted"] == 2
+        # the drain read the step in flight: one token more a live slot, none lost and none doubled
+        assert [len(r.tokens) for r in reqs[1:]] == [n + 1 for n in n_mid[1:]]
+        for r, w in zip(reqs, whole):
+            assert list(r.tokens) == list(w.tokens[:len(r.tokens)]) and len(r.t_emissions) == len(r.tokens)
+        assert [r.status for r in reqs[1:]] == [RequestStatus.PREEMPTED] * 2
+        srv.check_no_leaks()
+        assert srv.allocator.pages_in_use == 0
+
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    def test_a_slot_sits_out_no_step_between_two_requests(self, family_engines, family):
+        """A stop by count is known at the launch of a request's last row: its
+        slot is handed to the queue's next request a call before that row is
+        read, so a backlog takes the calls the synchronous order takes (and
+        one more, to read the last step)."""
+        engine, vocab = family_engines(family)
+        # every prompt in chunks, so that each but the first two (a server with nothing to ride) rides to its end
+        prompts = _ahead_prompts(vocab, (20, 13, 27, 20, 19, 30, 15, 18), seed=9)
+        plan = [(0, p, dict(max_new_tokens=5 + k % 3, seed=k)) for k, p in enumerate(prompts)]
+
+        def calls(srv):
+            n = [0]
+            reqs = _play(srv, plan, after=lambda call: n.__setitem__(0, call + 1))
+            return n[0], reqs
+
+        srv = _ahead_srv(engine, max_slots=2)
+        n_ahead, got = calls(srv)
+        n_sync, want = calls(_ahead_srv(engine, ahead=False, max_slots=2))
+        _same_service(got, want)
+        kept = _ahead_srv(engine, max_slots=2)
+        kept._hand_on_spent_slot = lambda now: None     # a spent slot kept until its last row is read
+        n_kept, same = calls(kept)
+        _same_service(same, want)
+        assert n_ahead <= n_sync + 1 < n_kept
+        assert srv.stats()["rows_dropped"] == 0 and all(r.status == RequestStatus.FINISHED for r in got)
+        srv.check_no_leaks()
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+    def test_a_session_moves_with_a_step_in_flight(self, inference_engine, tiny_cfg, temperature):
+        """(d) ``export_session`` / ``adopt_session``: the session that moves
+        holds every token computed for it, and the peer goes on where the
+        source stopped (the dispatched side of the adopted slot stands where
+        its emitted side does: the next rows take the token in flight and the
+        next keys), a step in flight on both sides and no row dropped."""
+        prompts = _ahead_prompts(tiny_cfg.vocab_size, (5, 9), seed=7)
+        kw = dict(prefill_chunk_tokens=0, temperature=temperature)
+        plan = [(0, p, dict(max_new_tokens=12, seed=k)) for k, p in enumerate(prompts)]
+        want = _play(_ahead_srv(inference_engine, ahead=False, **kw), plan)
+        src, dst = (_ahead_srv(inference_engine, **kw) for _ in range(2))
+        a = src.submit(prompts[0], max_new_tokens=12, seed=0)
+        b = dst.submit(prompts[1], max_new_tokens=12, seed=1)
+        for s in (src, dst):
+            for _ in range(3):
+                s.step()
+            assert s._flight is not None
+        n = len(a.tokens)
+        state, arrays = src.export_session(0)
+        assert src._flight is None and len(state["tokens"]) == n + 1 == len(a.tokens)
+        assert state["step"] >= 2
+        src.release_slot(0)
+        assert dst.adopt_session(state, arrays, request=a) is a and dst._flight is not None
+        t0 = _span_clock()
+        dst.run()
+        # every launch of the destination's but the last went out behind a step in flight
+        launches = _span_attrs(t0, "ds.serve.decode.dispatch")
+        assert sum(d["ahead"] for d in launches) >= len(launches) - 1
+        assert dst.stats()["rows_dropped"] == 0 == src.stats()["rows_dropped"]
+        for s in (src, dst):
+            s.check_no_leaks()
+        _same_service((a, b), want)
+        for p, r in zip(prompts, (a, b)):
+            assert r.status == RequestStatus.FINISHED and len(r.t_emissions) == 12
+            if temperature:
+                assert len(set(r.tokens[n:])) > 2     # a stale token fed or a key out of place would show
+            else:
+                ref = np.asarray(inference_engine.generate(p[None, :], max_new_tokens=12))[0]
+                np.testing.assert_array_equal(r.output, ref)
+
+    @pytest.mark.parametrize("over", [
+        {"speculative": {"enabled": True, "k": 3, "ngram": 2}},
+        {"placement": {"disaggregate": True}},
+    ], ids=["speculation", "disaggregated"])
+    def test_a_server_whose_rows_wait_for_its_tokens_launches_nothing_ahead(
+        self, inference_engine, tiny_cfg, over
+    ):
+        """(e) A verify step's accepted count moves ``seq_lens`` and a
+        handoff is polled between two steps: such a server reads every step
+        in the call that launched it (``ahead`` 0 on every dispatch) and
+        emits what ``generate`` does."""
+        prompts = _ahead_prompts(tiny_cfg.vocab_size, (5, 20, 27, 7), seed=8)
+        t0 = _span_clock()
+        srv = _ahead_srv(inference_engine, **over)
+        assert not srv._ahead_ok
+        reqs = []
+        for k, p in enumerate(prompts):
+            reqs.append(srv.submit(p, max_new_tokens=10, seed=k))
+            srv.step()
+            assert srv._flight is None
+        srv.run()
+        launches = _span_attrs(t0, "ds.serve.decode.dispatch")
+        assert launches and all(d["ahead"] == 0 for d in launches)
+        assert srv.stats()["steps_ahead"] == 0 == srv.stats()["rows_dropped"]
+        for p, r in zip(prompts, reqs):
+            assert r.status == RequestStatus.FINISHED
+            ref = np.asarray(inference_engine.generate(p[None, :], max_new_tokens=10))[0]
+            np.testing.assert_array_equal(r.output, ref)
+        srv.release_prefix_cache()
+        srv.check_no_leaks()
+
+
+class _StallOnce:
+    """A fault injector whose first ``serving_stall`` fires."""
+
+    def __init__(self):
+        self.fired = False
+
+    def fire(self, kind, ordinal):
+        if kind == "serving_stall" and not self.fired:
+            self.fired = True
+            return True
+        return False

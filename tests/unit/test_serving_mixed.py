@@ -270,16 +270,20 @@ def test_a_rider_starts_decoding_the_step_after_its_last_chunk_and_that_step_sta
     assert len(a.tokens) == 2
     b = srv.submit(long, max_new_tokens=12, seed=1)
     for n_chunk in (1, 2, 3):                      # 20 tokens: three chunks, each beside a's decode step
-        t_before = float(next(ticks))
         srv.step()
         assert _count(srv, "serving_chunks_rode_total") == n_chunk == _count(srv, "serving_chunk_prefills_total")
         assert len(a.tokens) == 2 + n_chunk         # no decode step was held back
-    # the last chunk's step: one token, no more (the parent decoded it in that same step)
+    # the last chunk rode the step that call LAUNCHED, which is in flight: b's first token is its last place, on
+    # the device, and b's rows are launched from the next step on all the same
+    assert len(b.tokens) == 0 and b.t_first_token is None
+    assert not any(s.prefilling for s in srv.slots if s.request is not None)
+    t_before = float(next(ticks))
+    srv.step()                                     # launches b's first decode row, then reads the step the chunk rode
     assert len(b.tokens) == 1 and b.t_first_token is not None and b.t_first_token > t_before
     assert b.t_emissions == [b.t_first_token] and a.t_emissions[-1] <= b.t_first_token
-    assert not any(s.prefilling for s in srv.slots if s.request is not None)
-    srv.step()
-    assert len(b.tokens) == 2 and len(a.tokens) == 6
+    assert len(a.tokens) == 6
+    srv.step()                                     # no step was lost: the row launched ahead is read here
+    assert len(b.tokens) == 2 and len(a.tokens) == 7
     srv.run()
     _, alone = _alone(engine, [short, long])
     assert [list(r.tokens) for r in (a, b)] == [list(r.tokens) for r in alone]
@@ -333,12 +337,23 @@ def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
     assert sum(c["rode"] for c in chunks) == _count(srv, "serving_chunks_rode_total") > 0
     assert sum(c["chunks"] + c["rode"] for c in chunks) == _count(srv, "serving_chunk_prefills_total")
     n_sparse = len(fam.sparse_layers)
-    for s in steps:
-        d, c, e = (s.get(k) for k in ("ds.serve.decode.dispatch", "ds.serve.chunk", "ds.serve.emit"))
-        if c is not None and c["rode"]:
-            assert d is not None and d["active"] >= 1              # a chunk rides a decode dispatch only
-        if d is None:
-            continue
+    # a call reads the step the call before launched (an empty server's first call launches two and reads the
+    # first): the n-th emit leaf is the n-th dispatch leaf's, and a chunk rides its own call's first dispatch
+    launches, emits, cur = [], [], None
+    for name, _, _, attrs in spans.snapshot(since=t0):
+        if name == "ds.serve.chunk":
+            cur = attrs
+        elif name == "ds.serve.decode.dispatch":
+            launches.append((attrs, cur))
+            cur = None
+        elif name == "ds.serve.emit":
+            emits.append(attrs)
+        elif name == "ds.serve.step":
+            assert cur is None or not cur["rode"]                  # a chunk rides a decode dispatch only
+            cur = None
+    assert len(launches) == len(emits) and sum(d["ahead"] for d, _ in launches) >= len(launches) - 2
+    for (d, c), e in zip(launches, emits):
+        assert d["active"] >= 1
         # the dispatch leaf counts the decode rows alone, whatever rode
         assert e["tokens"] == d["active"] <= d["attended"] and d["pages"] >= d["active"]
         if n_sparse:
@@ -347,8 +362,7 @@ def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
             assert routed == d["active"] + rode if rode is not None else routed >= d["active"]
             assert e["moe_experts_hit"] <= fam.experts_held * n_sparse      # the union over the call's rows
             assert e["moe_load_max"] <= routed
-    assert sum(s["ds.serve.decode.dispatch"]["active"] for s in steps if "ds.serve.decode.dispatch" in s) \
-        == sum(len(r.tokens) - 1 for r in reqs)
+    assert sum(d["active"] for d, _ in launches) == sum(len(r.tokens) - 1 for r in reqs)
     if n_sparse:
         reports = [c for c in chunks if "moe_calls" in c]
         # no ridden call is reported under moe_calls: a prompt reports the calls that rode nothing
