@@ -603,7 +603,7 @@ class FleetRouter:
             req.status = RequestStatus.QUEUED
             req.tokens = []
             req.t_emissions = []
-            req.t_first_token = None
+            req.t_first_token = req.first_launch = None
             req.t_admit = None
             req.t_requeue = now
             req.detail = why

@@ -87,6 +87,9 @@ class Request:
     # into a wait that never happened)
     t_requeue: Optional[float] = None
     t_first_token: Optional[float] = None
+    # the launch number of the program that sampled the first token (the
+    # ``launch`` of its ``ds.serve.*`` leaf: docs/OBSERVABILITY.md)
+    first_launch: Optional[int] = None
     t_finish: Optional[float] = None
     # one wall timestamp per emitted token (parallel to ``tokens``): a
     # speculative verify step emits its accepted run at ONE instant, so the

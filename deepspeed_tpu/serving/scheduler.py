@@ -192,6 +192,10 @@ class _Slot:
     # the decode placement polls ``.is_ready()`` instead of blocking, so
     # decode batches never wait on another core-set's prefill compute
     pending_tok: Optional[Any] = None
+    # the launch number of the program that samples this slot's first token
+    # (a whole prefill, the prompt's last chunk): what the leaf that hands
+    # the token out names in ``firsts``, and the request's ``first_launch``
+    first_launch: int = 0
     # the expert layers' token counts of this prompt's chunk calls that rode
     # no decode step (device arrays until its last chunk's token is fetched)
     # and the prompt tokens those calls advanced
@@ -206,6 +210,7 @@ class _Flight:
     out: Any        # the program's results after the pools, on the device: the tokens, the expert loads
     rows: List[tuple]   # (slot index, the _Slot that held it at the launch) of the rows launched
     t0: float       # the dispatch leaf's opening, on the engine's clock
+    launch: int = 0   # the step program's launch number (``flight`` on the leaves that read it)
     drafts: dict = field(default_factory=dict)   # a verify step's, by slot
     started: Optional[tuple] = None   # (slot index, _Slot) whose prompt's last chunk rode this step
     rode: int = 0   # prompt tokens of the chunk that rode
@@ -845,6 +850,9 @@ class ServingEngine:
         # -- one step in flight while the host reads the step before it ----
         # the launched step the host has not read (``_resolve`` reads it)
         self._flight: Optional[_Flight] = None
+        # calls of a compiled serving program so far: each takes the next
+        # number (:meth:`_launch_attrs`) and its leaf span carries it
+        self._launches = 0
         # whether the next step's rows are known without the tokens in flight:
         # a verify step's accepted count moves ``seq_lens``, and a handoff
         # between placements is polled between two steps
@@ -1709,6 +1717,22 @@ class ServingEngine:
             self._chunk_sp = None   # a call's leaf takes no attribute after the call
             self._resolve(flight)
 
+    def _launch_attrs(self, kind: str, rows: int, tokens: int) -> dict:
+        """The next launch number and what its program carries: the
+        attributes, from its entry, of the leaf span that makes ONE call of a
+        compiled serving program (``ds.serve.decode.dispatch`` where the step
+        program is the leaf's only call, else a ``ds.serve.launch`` of the
+        call's own nested in the leaf that is there). ``kind``: ``plain``
+        (the decode program), ``mixed`` (the chunk program with decode rows),
+        ``chunk`` (with none), ``prefill`` (the whole-prompt program),
+        ``verify``; ``rows``: decode rows carried; ``tokens``: prompt tokens
+        carried. A trace's reader joins the runtime's launch inside the leaf
+        to the number, and the leaves that read the program's outputs name it
+        (``flight``, ``firsts``, a synchronous wait's ``launch``):
+        docs/OBSERVABILITY.md."""
+        self._launches += 1
+        return {"launch": self._launches, "kind": kind, "rows": rows, "tokens": tokens}
+
     def _rows_due(self) -> List[int]:
         """The slots the next decode launch has a row for: past their prompt,
         and short of their count by what the host has read AND what is in
@@ -1964,8 +1988,12 @@ class ServingEngine:
         on. With a step in flight (``ahead`` 1) a row whose last token that
         step samples takes it from the step's output on the device."""
         before = self._flight
+        # the step program is this leaf's one call, and the leaf carries its
+        # number; a chunk that rides (and the calls ahead of it) has a leaf each
+        own = {} if rider is not None else self._launch_attrs(
+            "verify" if self.spec_enabled else "plain", len(active), 0)
         with spans.span(
-            "ds.serve.decode.dispatch", active=len(active), ahead=int(before is not None)
+            "ds.serve.decode.dispatch", active=len(active), ahead=int(before is not None), **own
         ) as sp:
             t0 = self.clock()
             # tokens the queries attend (each slot's cached context and the
@@ -2030,7 +2058,7 @@ class ServingEngine:
                 for i in unwaited:
                     self._launch_alone(i)
                 flight.rode = self._chunk_reach(rider)[0]
-                flight.out, last = self._launch_chunk(rider, rows)
+                flight.out, last = self._launch_chunk(rider, rows, len(active))
                 self._c_chunks_rode.inc()
                 if last:
                     # the chunk that rode was its prompt's last: the first
@@ -2044,6 +2072,7 @@ class ServingEngine:
                 flight.out = dset.take_pools(self._decode_exec(
                     dset.params, *dset.pool_args(), *rows,
                 ))
+            flight.launch = self._launches   # the step program is the leaf's last call
             # the dispatched side moves on at the launch: the next step's
             # lengths and keys are known without this one's tokens (a verify
             # step's lengths move by what it accepts, when it is read)
@@ -2068,7 +2097,7 @@ class ServingEngine:
         # the ONE deliberate sync of the slot loop: the scheduler must
         # read the sampled tokens to retire/advance slots (with them, a
         # prompt's earlier calls' expert loads where its last chunk rode)
-        with spans.span("ds.serve.decode.wait"):
+        with spans.span("ds.serve.decode.wait", flight=flight.launch):
             rider, started = flight.started or (None, None)
             if started is not None and self.slots[rider] is not started:
                 started = None   # ended since: its first token is nobody's
@@ -2084,7 +2113,14 @@ class ServingEngine:
         if self.family.sparse_layers:
             out_np, moe_np = out_np  # the expert loads rode the same fetch
         active = flight.rows
-        with spans.span("ds.serve.emit") as sp:
+        with spans.span("ds.serve.emit", flight=flight.launch) as sp:
+            if started is not None or late:
+                # the programs whose FIRST token this leaf hands out: the
+                # step's own where a prompt's last chunk rode it, and the
+                # prefill or last chunk that left its token on a slot
+                firsts = [flight.launch] if started is not None else []
+                firsts += [self.slots[i].first_launch for i in late]
+                sp.set(firsts=",".join(map(str, firsts)))
             if moe_np is not None:
                 sp.set(**self._moe_attrs(
                     moe_np,
@@ -2498,12 +2534,13 @@ class ServingEngine:
             # step phase 2c syncs it and completes the handoff
             page_ids = np.zeros((self.prefill_pages,), np.int32)
             page_ids[: len(prefill_pages)] = prefill_pages
-            first = self._token_of(pset.take_pools(self._prefill_exec(
-                pset.params, *pset.pool_args(),
-                ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
-            )))
+            with spans.span("ds.serve.launch", **self._launch_attrs("prefill", 0, req.prompt_len)):
+                first = self._token_of(pset.take_pools(self._prefill_exec(
+                    pset.params, *pset.pool_args(),
+                    ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
+                )))
             self._c_prefills.inc()
-            slot.pending_tok = first
+            slot.pending_tok, slot.first_launch = first, self._launches
             slot.prefilling = True
             slot.prefill_pos = req.prompt_len
             req.status = RequestStatus.RUNNING
@@ -2520,13 +2557,15 @@ class ServingEngine:
         # the first token is still on the device cannot write this slot's pages
         slot.row = np.zeros((1, self.pages_per_slot), np.int32)
         slot.row[0, : len(pages)] = pages
-        first = self._token_of(pset.take_pools(self._prefill_exec(
-            pset.params, *pset.pool_args(),
-            ids, np.asarray(req.prompt_len, np.int32),
-            slot.row[0, : self.prefill_pages], key0,
-            *self._slot_operand(slot_i),
-        )))
+        with spans.span("ds.serve.launch", **self._launch_attrs("prefill", 0, req.prompt_len)):
+            first = self._token_of(pset.take_pools(self._prefill_exec(
+                pset.params, *pset.pool_args(),
+                ids, np.asarray(req.prompt_len, np.int32),
+                slot.row[0, : self.prefill_pages], key0,
+                *self._slot_operand(slot_i),
+            )))
         self._c_prefills.inc()
+        slot.first_launch = self._launches
         if self._flight is not None:
             # a step is in flight: the token stays on the slot, as under
             # disaggregation, and is read with that step's fetch (`_resolve`)
@@ -2537,7 +2576,7 @@ class ServingEngine:
         else:
             # deliberate sync: TTFT is defined by the first token reaching the
             # host, and an at-admission EOS must retire the slot before decode
-            with spans.span("ds.serve.prefill.wait"):
+            with spans.span("ds.serve.prefill.wait", launch=slot.first_launch):
                 tok0 = int(jax.device_get(first)[0])  # dslint: disable=host-sync-in-step
         if self.tracer is not None:
             self.tracer.event(
@@ -2561,10 +2600,11 @@ class ServingEngine:
         are not reported)."""
         return out[0] if self.family.sparse_layers else out
 
-    def _launch_chunk(self, slot_i: int, rows: tuple):
+    def _launch_chunk(self, slot_i: int, rows: tuple, carried: int = 0):
         """The next chunk of a PREFILLING slot's prompt through the chunk
-        program, beside the decode ``rows`` (a step's own: the chunk rides
-        its dispatch; the idle table's: a call with no decode row). → (the
+        program, beside the decode ``rows`` (a step's own, ``carried`` of
+        them: the chunk rides its dispatch; the idle table's: a call with no
+        decode row), under a ``ds.serve.launch`` leaf of its own. → (the
         call's results after the pools, on the device: the tokens ``[slots +
         1]``, the chunk's last, and for a family with expert layers their
         loads; whether that was the prompt's last chunk)."""
@@ -2583,17 +2623,21 @@ class ServingEngine:
         page_ids[: len(avail)] = avail
         key0 = _host_prng_key(req.seed)
         pset = self.prefill_set
-        out = pset.take_pools(self._chunk_exec(
-            pset.params, *pset.pool_args(), *rows,
-            ids, np.asarray(start, np.int32),
-            np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
-            *self._slot_operand(slot_i),
-        ))
+        with spans.span("ds.serve.launch", **self._launch_attrs(
+            "mixed" if carried else "chunk", carried, len(seg)
+        )):
+            out = pset.take_pools(self._chunk_exec(
+                pset.params, *pset.pool_args(), *rows,
+                ids, np.asarray(start, np.int32),
+                np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
+                *self._slot_operand(slot_i),
+            ))
         self._c_chunks.inc()
         slot.prefill_pos = start + C
         final = slot.prefill_pos >= req.prompt_len
         if final:
             self._c_prefills.inc()
+            slot.first_launch = self._launches
         if self.tracer is not None:
             self.tracer.event(
                 req, "prefill_chunk", self.clock(), step=self._step_count,
@@ -2672,11 +2716,10 @@ class ServingEngine:
 
         Which calls the dispatch leaf launches, ahead of the one that carries
         the ``rider``: those nothing here waits for (not a prompt's last
-        chunk). A trace names every call of this program after the decode
-        programs, and its reader takes a program of that name that starts
-        shortly before a dispatch leaf's launch for that leaf's own and sets
-        the device's clock by it; launched under the leaf, none does. A call
-        this leaf waits for has ended before the dispatch opens."""
+        chunk). A call this leaf waits for has ended before the dispatch
+        opens. Every call, here or there, has a ``ds.serve.launch`` leaf of
+        its own with its launch number (:meth:`_launch_attrs`), so a trace's
+        reader knows each program by its number, wherever it was launched."""
         with spans.span("ds.serve.chunk") as sp:
             rider = pre[0] if self._ahead_ok and self._rows_due() else None
             alone = pre[rider is not None:]
@@ -2709,7 +2752,7 @@ class ServingEngine:
                     continue
                 # deliberate sync, as in _admit: the final chunk's sample is
                 # the request's first token
-                with spans.span("ds.serve.chunk.wait"):
+                with spans.span("ds.serve.chunk.wait", launch=slot.first_launch):
                     tok_np, *counts = jax.device_get((out, *slot.moe_counts))  # dslint: disable=host-sync-in-step
                 if counts:
                     self._moe_done.append((np.concatenate(counts), slot.moe_tokens))
@@ -2742,7 +2785,7 @@ class ServingEngine:
         # phase 2c only calls here once the array is ready (or nothing is
         # decoding, so blocking costs no batch progress)
         # a whole prefill's [1] or a chunk call's [slots + 1]: the prompt's last
-        with spans.span("ds.serve.handoff.wait"):
+        with spans.span("ds.serve.handoff.wait", launch=slot.first_launch):
             tok0 = int(jax.device_get(slot.pending_tok)[-1])  # dslint: disable=host-sync-in-step
         slot.pending_tok = None
         if req.max_new_tokens == 1 or (
@@ -2848,6 +2891,7 @@ class ServingEngine:
         # prefill that is the LAST chunk's sample (earlier chunks emit
         # nothing a client could stream) — the ISSUE 11 pin.
         req.t_first_token = now
+        req.first_launch = slot.first_launch
         self._h_ttft.observe(now - req.t_submit)
         req.tokens.append(tok0)
         req.t_emissions.append(now)
@@ -2986,7 +3030,7 @@ class ServingEngine:
             # re-measured from the re-admission)
             object.__setattr__(req, "_draft_state", None)
             req.status = RequestStatus.QUEUED
-            req.t_first_token = None
+            req.t_first_token = req.first_launch = None
             req.t_admit = None
             req.t_requeue = now
             req.t_emissions = []

@@ -299,15 +299,20 @@ def test_of_two_slots_prefilling_in_a_step_one_rides_and_one_is_a_call_with_no_d
     srv.step()
     reqs += [srv.submit(p, max_new_tokens=12, seed=i + 1) for i, p in enumerate((l1, l2))]
     launched, launch = [], srv._launch_chunk
-    srv._launch_chunk = lambda i, rows: launched.append((spans._clock(), rows[2].any())) or launch(i, rows)
+    srv._launch_chunk = lambda i, rows, carried=0: (launched.append((spans._clock(), rows[2].any(), carried))
+                                                    or launch(i, rows, carried))
     srv.step()
     srv._launch_chunk = launch
     assert sum(1 for s in srv.slots if s.request is not None and s.prefilling) == 2
     assert _count(srv, "serving_chunk_prefills_total") == 2 and _count(srv, "serving_chunks_rode_total") == 1
-    # both calls under the dispatch leaf, the one with no decode row first: nothing waits for it, and a trace's
-    # reader takes a program of this name launched shortly before a dispatch for that dispatch's own
+    # both calls under the dispatch leaf, the one with no decode row first (nothing waits for it), each under a
+    # ds.serve.launch leaf of its own that says what it carried: a trace's reader knows a call by its number
     (_, d0, d1, _), = [r for r in spans.snapshot(since=t0) if r[0] == "ds.serve.decode.dispatch"][-1:]
-    assert [rows for _, rows in launched] == [False, True] and all(d0 <= t <= d1 for t, _ in launched)
+    assert [(rows, carried) for _, rows, carried in launched] == [(False, 0), (True, 1)]
+    assert all(d0 <= t <= d1 for t, _, _ in launched)
+    leaves = [r for r in spans.snapshot(since=t0) if r[0] == "ds.serve.launch" and d0 <= r[1] and r[2] <= d1]
+    assert [(r[3]["kind"], r[3]["rows"], r[3]["tokens"]) for r in leaves] == [("chunk", 0, 8), ("mixed", 1, 8)]
+    assert leaves[1][3]["launch"] == leaves[0][3]["launch"] + 1 == srv._flight.launch
     step = _steps(spans.snapshot(since=t0))[-1]
     assert (step["ds.serve.chunk"]["chunks"], step["ds.serve.chunk"]["rode"]) == (1, 1)
     assert step["ds.serve.chunk"]["tokens"] == 16 and step["ds.serve.decode.dispatch"]["active"] == 1
