@@ -22,6 +22,7 @@ written for XLA:
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -118,6 +119,21 @@ class GPT2Config:
     def serving_family(self):
         """The pieces ``serving/model.py``'s paged programs are built from."""
         return GPT2Family(self)
+
+    def per_head_cache(self) -> "GPT2Config":
+        """This config with a serving family that caches a head a PUBLISHED
+        head whatever its width: what an int8 cache is served with, whose
+        pages carry one scale a cached head (a pair under one scale would
+        quantise the quieter head of the two coarser)."""
+        return _PerHeadCacheConfig(**{f.name: getattr(self, f.name) for f in dataclasses.fields(self)})
+
+
+@dataclass(frozen=True)
+class _PerHeadCacheConfig(GPT2Config):
+    """:meth:`GPT2Config.per_head_cache`'s: the same model, no head pairs."""
+
+    def serving_family(self):
+        return GPT2Family(self, pairs=False)
 
 
 # name → config, sizes per the GPT-2 paper / HF checkpoints
@@ -335,21 +351,48 @@ class GPT2Family:
     learned positions, LayerNorm, one K and V head per query head, every
     layer's cache paged under the block table, a tied head. Under the TP
     ``shard_map`` the config is the per-rank one and ``tp_axis`` names the
-    mesh axis the row-parallel partial products are summed over."""
+    mesh axis the row-parallel partial products are summed over.
+
+    Where two heads fill the 128 lanes (``2 * head_dim == 128``: every
+    published GPT-2) and the attention goes through the paged dispatcher, the
+    cache holds head PAIRS, as the ``Family`` notes describe for differential
+    attention: ``n_kv_head = ceil(n_head / 2)`` heads of 128 lanes, ``[k_2p |
+    k_2p+1]``, under ``n_head = 2 * n_kv_head`` zero-padded queries ``[q_2p |
+    0]``, ``[0 | q_2p+1]``, scaled by the PUBLISHED head's width. The zeros add
+    exactly 0 to every product, so the scores, the probabilities and the lanes
+    ``attn_out`` keeps (head 2p's first half, head 2p + 1's second) are the
+    published model's; every lane of a page is a value, where a 64-wide head
+    pads its tile to twice its bytes, and the paged kernels batch half the
+    heads. An ODD head count (XL's 25) gets one zero head behind the last, in
+    the activations only: the weights stay as published. Under TP the pairs
+    are made inside a rank's heads. Not paired: ``attn_impl="jnp"`` (the
+    programs' own dense branches, which mirror ``generate()``'s bits a head a
+    published head) and a config from :meth:`GPT2Config.per_head_cache` (an
+    int8 cache, whose pages carry one scale a cached head)."""
 
     prefill_block = 0      # the whole-prompt program attends as one dense product
     kv_pools = 2           # a K and a V pool
     sparse_layers = ()     # no layer reports expert loads
     experts_held = 0
     experts_per_token = 0
+    sm_scale = None        # 1 / sqrt(head_dim), but for pairs (below)
 
-    def __init__(self, cfg: GPT2Config):
+    def __init__(self, cfg: GPT2Config, pairs: bool = True):
         self.cfg = cfg
         self.n_layer, self.n_head, self.n_kv_head = cfg.n_layer, cfg.n_head, cfg.n_head
         self.head_dim, self.vocab_size, self.n_positions = cfg.head_dim, cfg.vocab_size, cfg.n_positions
         self.attn_impl = cfg.attn_impl
         self.windows = (0,) * cfg.n_layer
-        self.v_width = cfg.head_dim
+        self.pairs = pairs and 2 * cfg.head_dim == 128 and cfg.attn_impl in ("auto", "pallas")
+        if self.pairs:
+            self.n_kv_head = -(-cfg.n_head // 2)
+            self.n_head, self.head_dim = 2 * self.n_kv_head, 2 * cfg.head_dim
+            self.sm_scale = 1.0 / np.sqrt(cfg.head_dim)
+            # [pairs, 2, 128]: the lanes of pair p that are published head 2p + r's own
+            # (none for the padding head behind an odd count's last)
+            head = 2 * np.arange(self.n_kv_head)[:, None, None] + np.arange(2)[:, None]
+            self._own = (head < cfg.n_head) & ((np.arange(self.head_dim) >= cfg.head_dim) == (head % 2 == 1))
+        self.v_width = self.head_dim
 
     def embed(self, params, ids, positions):
         te, pe = params["wte"][ids], params["wpe"][positions]
@@ -368,11 +411,34 @@ class GPT2Family:
         with parts.part("norm"):
             hn = layer_norm_inference(h, lp["ln_1"]["scale"], lp["ln_1"]["bias"], cfg.layer_norm_epsilon)
         qkv = hn @ _deq(lp["attn"]["c_attn_w"], hn.dtype) + lp["attn"]["c_attn_b"]
-        return tuple(
-            t.reshape(*t.shape[:-1], cfg.n_head, cfg.head_dim) for t in jnp.split(qkv, 3, axis=-1)
+        if not self.pairs:
+            return tuple(
+                t.reshape(*t.shape[:-1], cfg.n_head, cfg.head_dim) for t in jnp.split(qkv, 3, axis=-1)
+            )
+        # in LANES, before any reshape: a pair is one lane tile of a window of
+        # the row. An odd count's last pair ends in a zero head: V's window runs
+        # into the row's padding, K's (which runs into V) is cut to its own
+        # lanes, Q's (which runs into K) by the select below
+        E, KV, W = cfg.n_embd, self.n_kv_head, self.head_dim
+        lanes, zero = qkv.ndim - 1, jnp.zeros((), qkv.dtype)
+        row = lax.pad(qkv, zero, [(0, 0, 0)] * lanes + [(0, KV * W - E, 0)])
+        q, k, v = (
+            lax.slice_in_dim(row, i * E, i * E + KV * W, axis=lanes).reshape(*row.shape[:-1], KV, W)
+            for i in range(3)
         )
+        if KV * W > E:
+            k = jnp.where(self._own.any(1), k, zero)
+        # head 2p is [q | 0], head 2p + 1 [0 | q]: a select over a constant of lanes
+        q = jnp.where(self._own, q[..., None, :], zero)
+        return q.reshape(*q.shape[:-3], 2 * KV, W), k, v
 
     def attn_out(self, lp, o, tp_axis=None):
+        if self.pairs:
+            # head 2p's product is its first 64 lanes, head 2p + 1's its last;
+            # the padding head goes
+            o = o.reshape(*o.shape[:-1], *self._own.shape)
+            o = jnp.where(self._own, o, jnp.zeros((), o.dtype)).sum(-2)
+            o = o.reshape(*o.shape[:-2], -1)[..., : self.cfg.n_embd]
         # row-parallel under TP: the partial product is summed over the axis
         # BEFORE the replicated bias is added once
         out = o @ _deq(lp["attn"]["c_proj_w"], o.dtype)

@@ -23,6 +23,13 @@ On a TPU the pool of a head narrower than 128 lanes is STORED with its page
 axis split (:func:`pool_stored_shape`), which keeps the device's
 default layout row-major; the programs see the ``[L, P, KV, page, D]``
 :func:`pool_view`, and the kernels take that whole view plus a layer index.
+Such a pool is padded tiles, twice its values at 64 lanes, and the kernels'
+DMAs move the padding (PERF.md, PR 56: the same keys as pairs of heads in 128
+lanes cost the one-token kernel 0.58 of the time). Since PR 56 no served
+configuration stores one: GPT-2's 64-wide heads reach this module as PAIRS,
+``ceil(H / 2)`` kv-heads of 128 lanes (``models/gpt2.GPT2Family``; the
+``Family`` notes in ``serving/model.py``), and only an int8 cache of such a
+model, which keeps a head and a scale a published head, is still split.
 """
 
 from __future__ import annotations

@@ -229,10 +229,23 @@ class Family:
       pools), or 0: every key before it (K/V paged under the block table).
     - ``sm_scale`` (optional for a two-pool family; a latent one states it):
       the scores' scale where it is not ``1 / sqrt(head_dim)``. A family whose
-      cached head is a PAIR of published heads (differential attention:
-      ``[k1 | k2]`` under the zero-padded queries ``[q1 | 0]``, ``[0 | q2]``)
-      scales by the published head's width and combines the pairs in
-      ``attn_out``.
+      cached head is a PAIR of published heads (``[k1 | k2]`` under the
+      zero-padded queries ``[q1 | 0]``, ``[0 | q2]``: ``n_head`` is then ``2 *
+      n_kv_head`` and the programs' ``rep`` 2) scales by the published head's
+      width and takes the pairs apart in ``attn_out``: Phi-4-mini-flash's
+      differential attention combines the two products; GPT-2, whose 64-wide
+      heads are cached two to the 128 lanes (``models/gpt2.GPT2Family``: the
+      rule is ``2 * head_dim == 128``, read from the config's shape), keeps
+      head 2p's first half and head 2p + 1's second. The zeros add exactly 0,
+      so the results are the published model's. An ODD head count gets one
+      zero head behind the last, in the activations only (the projections
+      stay as published and ``attn_out`` drops it again), and under TP a rank
+      pairs its own heads: the pools' kv-heads are ``tp x`` a rank's pairs
+      (``placement.ProgramSet`` builds its family from the per-rank config).
+      An int8 cache carries ONE scale a page and cached head, so it is served
+      a head a published head (``ServingEngine`` asks the config for
+      ``per_head_cache()`` where it has one): a pair under one scale would
+      quantise the quieter of its two heads coarser than it is today.
     - ``kinds`` (optional): per sub-block ``"attn"`` (the default: everything
       above), ``"ssm"``, ``"gmu"`` or ``"cross"``. With it the family gives
       ``sources`` (``{l: the sub-block a "cross" or "gmu" sub-block reads}``:
@@ -763,15 +776,17 @@ def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
 
     scale = _sm_scale(fam, D)
     with parts.part("attn.core"):
+        # a K head's group of ``H // KV`` query heads (1, or a pair family's 2)
         scores = jnp.einsum(
-            "bshd,bthd->bhst", q.astype(jnp.float32), k_c.astype(jnp.float32)
+            "bsgrd,btgd->bgrst", q.astype(jnp.float32).reshape(B, Sp, KV, H // KV, D),
+            k_c.astype(jnp.float32),
         ) * scale
         j_idx = jnp.arange(Sp)
         i_idx = jnp.arange(Sp)
         mask = j_idx[None, :] <= i_idx[:, None]
-        scores = jnp.where(mask[None, None, :, :], scores, -1e30)
+        scores = jnp.where(mask, scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(v_c.dtype)
-        o = jnp.einsum("bhst,bthd->bshd", probs, v_c)
+        o = jnp.einsum("bgrst,btgd->bsgrd", probs, v_c)
         # H*D == E at TP=1; under the TP shard_map H is the per-rank head count
         # and the row-parallel projection restores the full embed dim
         return o.reshape(B, Sp, H * D).astype(q.dtype), k_pool, v_pool, scales
@@ -1117,9 +1132,12 @@ def paged_decode_step(
             )
         else:
             pool_dt = h.dtype if scales is not None else k_pool.dtype
+            # named (the name the program's jit would give them anyway), so that
+            # a deep pool's layers share ONE traced kernel each: 48 traces of a
+            # kernel with 32 page inputs are 3 s of set-up (PERF.md, PR 56)
             o, k_pool, v_pool, scales = _attention_decode_paged(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
-                block_tables, seq_lens, pidx, poff, li, scales,
+                block_tables, seq_lens, pidx, poff, li, scales, name="decode_fn",
             )
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 

@@ -340,12 +340,14 @@ class ProgramSet:
         self.page_size = int(page_size)
         from .model import pool_layers
 
-        fam = mcfg.serving_family()
+        # the family of ONE rank (a family that caches pairs of heads makes
+        # them inside a rank's heads): the pools' kv-heads are tp x its own
+        fam = placement.local_model_config(mcfg).serving_family()
         # the paged pools hold the layers that read their whole context; a
         # window layer's K/V live in the ring pools below, a state-space
         # sub-block's recurrent state in the state pools
         self.n_layer, self.n_window_layer, n_state = pool_layers(fam)
-        self.n_kv_head = int(fam.n_kv_head)
+        self.n_kv_head = int(fam.n_kv_head) * placement.tp
         self.kv_pools = int(fam.kv_pools)
         k, v, scales = init_pools(
             self.n_layer, self.num_pages, self.n_kv_head, self.page_size,

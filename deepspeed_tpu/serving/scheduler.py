@@ -270,6 +270,11 @@ class ServingEngine:
                 "longcat_flash, phi4flash, zaya and qwen3_next); got "
                 f"{type(mcfg).__name__}"
             )
+        int8_pages = bool(config.kv_cache_dtype) and jnp.dtype(config.kv_cache_dtype) == jnp.dtype(jnp.int8)
+        if int8_pages and hasattr(mcfg, "per_head_cache"):
+            # an int8 page carries one scale a cached head: a family that would
+            # cache PAIRS of heads keeps a head a published head instead
+            mcfg = mcfg.per_head_cache()
         self.model_config = mcfg
         fam = self.family = mcfg.serving_family()
         # a family with sliding-window layers keeps two kinds of KV state (the
@@ -304,9 +309,7 @@ class ServingEngine:
                  "serving.prefix_cache"),
                 (getattr(getattr(config, "tiering", None), "enabled", False),
                  "serving.tiering"),
-                (bool(config.kv_cache_dtype)
-                 and jnp.dtype(config.kv_cache_dtype) == jnp.dtype(jnp.int8),
-                 "serving.kv_cache_dtype=int8"),
+                (int8_pages, "serving.kv_cache_dtype=int8"),
                 (plc_ is not None and max(
                     int(getattr(plc_, k, 0) or 0)
                     for k in ("tp", "decode_tp", "prefill_tp")) > 1,
@@ -529,9 +532,9 @@ class ServingEngine:
             store = HostPageStore(
                 budget,
                 n_layer=self.decode_set.n_layer,
-                n_kv_head=fam.n_kv_head,  # GLOBAL layout: device_get unshards
+                n_kv_head=self.prefill_set.n_kv_head,  # GLOBAL layout: device_get unshards
                 page_size=page,
-                head_dim=mcfg.head_dim,
+                head_dim=self.prefill_set.head_dim,  # the CACHED head's, as the family gives both
                 dtype=self.cache_dtype,
                 quantized=self.quantized,
                 crc=bool(tcfg.crc),
@@ -918,7 +921,7 @@ class ServingEngine:
             f"pages={config.num_pages} (pool "
             f"{self.decode_set.local_pool_bytes() * self.decode_placement.tp / 1e6:.1f} MB"
             + (
-                f" + {scales_bytes(self.decode_set.n_layer, int(config.num_pages), fam.n_kv_head) / 1e6:.2f} MB scales"
+                f" + {scales_bytes(self.decode_set.n_layer, int(config.num_pages), self.decode_set.n_kv_head) / 1e6:.2f} MB scales"
                 if self.quantized else ""
             )
             + (
@@ -1450,7 +1453,7 @@ class ServingEngine:
                     self.model_config.attn_impl, B, pset.local_kv_heads(),
                     pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
                     self.pages_per_slot, T,
-                    rep=self.model_config.n_head // pset.n_kv_head,
+                    rep=self.family.n_head // self.family.n_kv_head,
                 )
             self._g_relayout.set(relayout[name], program=name)
             self._g_temp_bytes.set(temp[name], program=name)

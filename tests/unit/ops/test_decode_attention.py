@@ -1032,6 +1032,84 @@ class TestPagedMultitokenBlocks:
 
 # -- the latent (MLA) paged kernels against their jnp fallbacks (interpret mode) --
 
+class TestPagedPairedPool:
+    """ISSUE 56: GPT-2's 64-wide heads cached as PAIRS. A pool of ``ceil(H /
+    2)`` heads of 128 lanes ``[k_2p | k_2p+1]`` under the zero-padded queries
+    ``[q_2p | 0]``, ``[0 | q_2p+1]`` (``rep`` 2, scaled by the published
+    head's width) gives, in the lanes each query head owns, what the per-head
+    pool gives: the one-token and the multi-token kernel, interpreted, against
+    the jnp fallback over the per-head pool holding the same keys. An odd
+    count's last pair ends in a zero head."""
+
+    D, PAGE = 64, 16
+
+    def _pools(self, pos, H, n, dtype, seed):
+        """Per-head pools ``[P, H, page, 64]``, the paired pools ``[P, ceil(H
+        / 2), page, 128]`` of the same keys, a table and ``pos``."""
+        kp, vp, bt, pos = TestPagedDecodeServedShape()._pool(pos, H, self.D, self.PAGE, n, dtype, seed)
+        return (kp, vp), (self._pair_pool(kp), self._pair_pool(vp)), bt, pos
+
+    def _pair_pool(self, pool):
+        P, H, page, D = pool.shape
+        pool = jnp.pad(pool, ((0, 0), (0, H % 2), (0, 0), (0, 0)))
+        return pool.reshape(P, -1, 2, page, D).transpose(0, 1, 3, 2, 4).reshape(P, -1, page, 2 * D)
+
+    def _pair_queries(self, q):
+        """``q [..., H, 64]`` → ``[..., 2 * ceil(H / 2), 128]``."""
+        H, D = q.shape[-2:]
+        q = jnp.pad(q, [(0, 0)] * (q.ndim - 2) + [(0, H % 2), (0, 0)])
+        q = q.reshape(*q.shape[:-2], -1, 2, 1, D) * jnp.eye(2, dtype=q.dtype)[:, :, None]
+        return q.reshape(*q.shape[:-4], -1, 2 * D)
+
+    def _own_lanes(self, o, H):
+        """``o [..., 2 * KV, 128]`` → ``[..., H, 64]``: each head's own half."""
+        D = self.D
+        o = o.reshape(*o.shape[:-2], -1, 2, 2, D)
+        o = jnp.stack([o[..., 0, 0, :], o[..., 1, 1, :]], axis=-2)
+        return o.reshape(*o.shape[:-3], -1, D)[..., :H, :]
+
+    @pytest.mark.parametrize("H,dtype", [(25, "float32"), (25, "bfloat16"), (4, "float32")],
+                             ids=["xl-odd", "xl-odd-bf16", "even"])
+    def test_one_token_kernel_over_pairs_is_the_per_head_result(self, H, dtype):
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
+            paged_decode_blocks,
+        )
+
+        dtype = jnp.dtype(dtype)
+        pos = [0, 15, 16, 255, 256, 735, 1023, 200]
+        (kp, vp), (kq, vq), bt, posj = self._pools(pos, H, 64, dtype, 21)
+        assert kq.shape[1:] == ((H + 1) // 2, 16, 128)
+        if dtype.itemsize == 2:  # the served plan: all 13 pairs and 16 pages a grid step
+            assert paged_decode_blocks(13, 16, 128, 2, 64) == (13, 16)
+        q = jnp.asarray(np.random.RandomState(22).randn(len(pos), H, self.D), dtype)
+        got = paged_decode_attention(
+            self._pair_queries(q), kq.at[0].set(jnp.nan), vq.at[0].set(jnp.nan), bt, posj,
+            sm_scale=1 / 8, interpret=True,
+        )
+        want = paged_cached_attention(q, kp, vp, bt, posj, impl="jnp")
+        tol = 2e-5 if dtype.itemsize == 4 else 2e-2
+        np.testing.assert_allclose(np.asarray(self._own_lanes(got, H), np.float32),
+                                   np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("H,T", [(25, 128), (25, 5), (4, 8)], ids=["xl-chunk128", "xl-verify5", "even-8"])
+    def test_multitoken_kernel_over_pairs_is_the_per_head_result(self, H, T):
+        from deepspeed_tpu.ops.attention import paged_multitoken_cached_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import paged_multitoken_attention
+
+        base = [0, 128, 896] if T == 128 else [3, 14, 125, 130]
+        (kp, vp), (kq, vq), bt, _ = self._pools([b + T - 1 for b in base], H, 64, jnp.float32, 23)
+        basej = jnp.asarray(base, jnp.int32)
+        q = jnp.asarray(np.random.RandomState(24).randn(len(base), T, H, self.D), jnp.float32)
+        got = paged_multitoken_attention(
+            self._pair_queries(q), kq.at[0].set(jnp.nan), vq.at[0].set(jnp.nan), bt, basej,
+            sm_scale=1 / 8, interpret=True,
+        )
+        want = paged_multitoken_cached_attention(q, kp, vp, bt, basej, impl="jnp")
+        np.testing.assert_allclose(np.asarray(self._own_lanes(got, H)), np.asarray(want), atol=3e-5, rtol=3e-5)
+
+
 class TestLatentPaged:
     L, P, page, W, VW, H = 2, 40, 8, 128, 64, 4
 

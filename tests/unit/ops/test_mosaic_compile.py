@@ -36,6 +36,8 @@ def one_chip(topo):
 SHAPES = {
     # name: (B, H, KV, D, page, n, P, pool dtype)
     "gpt2-xl": (8, 25, 25, 64, 16, 64, 512, jnp.bfloat16),
+    # XL as it is served since ISSUE 56: 13 PAIRS of 128 lanes under 26 padded queries
+    "gpt2-xl-pairs": (8, 26, 13, 128, 16, 64, 512, jnp.bfloat16),
     "gqa-rep5": (8, 25, 5, 64, 16, 64, 512, jnp.bfloat16),
     "kv64-d128": (8, 64, 64, 128, 16, 64, 512, jnp.bfloat16),
     "xl-tp5-shard": (8, 5, 5, 64, 16, 64, 512, jnp.bfloat16),
@@ -192,25 +194,34 @@ def test_paged_token_write_compiles_for_v5e(one_chip, name, T):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("layout", ["pairs", "per_head"])
 @pytest.mark.parametrize("program", ["decode", "verify", "chunk", "mixed", "prefill"])
-def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypatch):
+def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, layout, monkeypatch):
     """ISSUE 29's census: the paged programs at XL width, 512 pages
-    and 4 layers, the pools stored with the page axis split (``kv_cache.
-    pool_stored_shape``'s choice on a TPU; this process sees the CPU, so the
-    test hands it over), donated, and compiled as the scheduler compiles
-    them (``ProgramSet.aot``, over described pools): the device's default layout
-    of such a pool is row-major, no instruction copies, slices or
-    transposes a layer of a pool or more, and the temp stays under two
-    layers of K and V (the head's transposed ``wte`` is most of it; a
-    single re-laid pool would double it)."""
+    and 4 layers, the pools stored as ``kv_cache.pool_stored_shape`` chooses on
+    a TPU (this process sees the CPU, so the test hands it over), donated, and
+    compiled as the scheduler compiles them (``ProgramSet.aot``, over described
+    pools): the device's default layout of such a pool is row-major, no
+    instruction copies, slices or transposes a layer of a pool or more, and
+    the temp stays under the head's transposed ``wte`` and one layer of K and V
+    (a single re-laid pool is four layers of either). ``pairs``: what
+    ``GPT2Family`` serves 64-wide heads as (ISSUE 56), 13 cached heads of 128
+    lanes in the plain 5-D shape; ``per_head``: a head a published head
+    (``GPT2Config.per_head_cache``, what an int8 cache keeps), 25 heads of 64
+    with the page axis split."""
     from deepspeed_tpu.models import gpt2
     from deepspeed_tpu.serving import model as smodel
     from deepspeed_tpu.serving.kv_cache import pool_stored_shape
     from deepspeed_tpu.serving.placement import Placement, ProgramSet
 
-    L, P, KV, page, D, B, W, C, Sp = 4, 512, 25, 16, 64, 8, 64, 128, 960
-    cfg = gpt2.GPT2Config(n_embd=KV * D, n_head=KV, n_layer=L,
+    L, P, H, page, B, W, C, Sp = 4, 512, 25, 16, 8, 64, 128, 960
+    cfg = gpt2.GPT2Config(n_embd=H * 64, n_head=H, n_layer=L,
                           attn_impl="pallas", dtype=jnp.bfloat16)
+    if layout == "per_head":
+        cfg = cfg.per_head_cache()
+    fam = cfg.serving_family()
+    KV, D = fam.n_kv_head, fam.head_dim
+    assert (KV, D) == ((13, 128) if layout == "pairs" else (25, 64))
     # the pool is split, and the token write takes its kernel, where the
     # backend is a TPU: say so
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -228,7 +239,7 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
         jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))),
     )
     shape = pool_stored_shape(L, P, KV, page, D, jnp.bfloat16)
-    assert shape == (L, 8, 64, KV, page, D)
+    assert shape == ((L, P, KV, page, D) if layout == "pairs" else (L, 8, 64, KV, page, D))
     # described as a live pool is: in the device's default format for its shape
     pool = jax.ShapeDtypeStruct(
         shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16)
@@ -290,9 +301,10 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, monkeypat
             assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
     for fmt in (took_in[1], took_in[2], *compiled.output_formats[:2]):
-        assert fmt.layout.major_to_minor == (0, 1, 2, 3, 4, 5)
+        assert fmt.layout.major_to_minor == tuple(range(len(shape)))
     layer_kv_bytes = 2 * P * KV * page * 128 * 2  # 64 lanes pad to 128
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * layer_kv_bytes
+    head_bytes = cfg.padded_vocab_size * cfg.n_embd * 2  # the tied head's transposed ``wte``
+    assert compiled.memory_analysis().temp_size_in_bytes < head_bytes + layer_kv_bytes
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "mixed", "prefill"])
@@ -517,13 +529,14 @@ def _xl_train_step(one_chip):
 
 
 def _xl_decode_step(one_chip):
-    """The decode step at XL width over plain 5-D pools (its kernels are the
-    ones the package gives no name): its optimised text."""
+    """The decode step at XL width over plain 5-D pools (13 pairs of 128
+    lanes; its kernels are the ones the package gives no name): its optimised
+    text."""
     from deepspeed_tpu.models import gpt2
     from deepspeed_tpu.serving import model as smodel
 
-    L, P, KV, page, D, B, W = 2, 512, 25, 16, 64, 8, 64
-    cfg = gpt2.GPT2Config(n_embd=KV * D, n_head=KV, n_layer=L, attn_impl="pallas", dtype=jnp.bfloat16)
+    L, P, KV, page, D, B, W = 2, 512, 13, 16, 128, 8, 64
+    cfg = gpt2.GPT2Config(n_embd=1600, n_head=25, n_layer=L, attn_impl="pallas", dtype=jnp.bfloat16)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
