@@ -142,12 +142,7 @@ class Mistral4Config:
 
     @property
     def sm_scale(self) -> float:
-        """``m^2 / sqrt(qk_head_dim)``: yarn's attention factor, squared
-        because it scales queries and keys alike, on the usual scale."""
-        m = 1.0
-        if self.mscale_all_dim and self.rope_factor > 1.0:
-            m = 0.1 * self.mscale_all_dim * math.log(self.rope_factor) + 1.0
-        return m * m / math.sqrt(self.qk_head_dim)
+        return yarn_sm_scale(self)
 
     def serving_family(self):
         return Mistral4Family(self)
@@ -209,7 +204,16 @@ def logical_axes(cfg: Mistral4Config) -> PyTree:
 # the family's pieces
 # ---------------------------------------------------------------------------
 
-def yarn_inv_freq(cfg: Mistral4Config) -> np.ndarray:
+def yarn_sm_scale(cfg) -> float:
+    """``m^2 / sqrt(qk_head_dim)``: yarn's attention factor, squared because
+    it scales queries and keys alike, on the usual scale."""
+    m = 1.0
+    if cfg.mscale_all_dim and cfg.rope_factor > 1.0:
+        m = 0.1 * cfg.mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+    return m * m / math.sqrt(cfg.qk_head_dim)
+
+
+def yarn_inv_freq(cfg) -> np.ndarray:
     """The ``qk_rope_head_dim / 2`` rotary frequencies, yarn-scaled: below
     ``low`` a pair keeps its frequency, above ``high`` it is divided by
     ``rope_factor``, between them a linear ramp of the two."""

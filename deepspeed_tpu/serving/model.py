@@ -8,7 +8,7 @@ attention, the sampling); the model's module gives the rest through
 ``models/exaone_moe.ExaoneFamily``, ``models/mistral4.Mistral4Family``,
 ``models/longcat_flash.LongcatFlashFamily``,
 ``models/phi4flash.Phi4FlashFamily``, ``models/zaya.ZayaFamily``,
-``models/qwen3_next.Qwen3NextFamily``). A sub-block is of one of five KINDS
+``models/qwen3_next.Qwen3NextFamily``, ``models/xing4.Xing4Family``). A sub-block is of one of five KINDS
 (``fam.kinds``; without it every one is the first): an attention that writes
 its own K/V (``attn``), a state-space mixer over a per-slot recurrent state
 (``ssm``: the third kind of state beside pages and rings, :func:`_ssm_block`),
@@ -205,7 +205,14 @@ class Family:
     families do not inherit from it). Shapes: ``h`` is the residual stream
     ``[B, S, E]``; ``positions`` is ``[B, S]`` or, one row, ``[S]``
     (``embed`` also takes ``[B]`` ids and positions, the decode step's,
-    and then gives ``[B, 1, E]``).
+    and then gives ``[B, 1, E]``). The stream's WIDTH is the family's: the
+    programs pass ``h`` from ``embed`` through ``qkv`` and the rest of every
+    sub-block to ``logits`` and never read its last axis. A family whose
+    residual is several streams a token (Xing4.0's four, mHC) gives ``[B, S,
+    n E]``, the streams side by side on the lanes, reads it through its
+    pre-map in ``qkv``, owns the rest of the layer in ``after_attention`` and
+    sums the streams in ``logits``; it states ``stream_row_width`` (``n E``:
+    the gauge ``serving_hc_row_bytes``).
 
     - ``n_layer, n_head, n_kv_head, head_dim, vocab_size, n_positions,
       attn_impl``: geometry. ``n_layer`` counts the CACHED SUB-BLOCKS: what
@@ -283,7 +290,15 @@ class Family:
       has some, a report is ``[experts_held + 1]``, the last entry the pairs
       that chose one of them.
     - ``embed(params, ids, positions) -> h``
-    - ``layer(params, l) -> lp``
+    - ``layer(params, l) -> lp``: sub-block ``l``'s weights. Every program
+      calls it once a sub-block and hands the SAME ``lp`` to ``qkv`` (or
+      ``qkv_expanded``) and then to the rest of the sub-block, so a family
+      that makes ``lp`` anew in each call (a dict of this call's own) may
+      leave in it what its ``qkv`` computed and its ``after_attention``
+      needs: the HAND-OVER. Xing4.0's attention sub-block computes its three
+      maps from ``h`` in ``qkv`` and writes the attention's output back
+      through two of them in ``after_attention`` (``lp["handed"]``); the
+      programs know nothing of it.
     - ``qkv(lp, h, positions, l) -> q [B,S,H,D], k, v [B,S,KV,D]``: the
       norm before attention, the projections and whatever the family does
       to a head before it is cached (QK norm, rotary positions).

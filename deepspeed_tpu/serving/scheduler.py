@@ -794,6 +794,13 @@ class ServingEngine:
             "float32; a decode step reads and writes every live slot's; 0 = "
             "the model has no such sub-block)",
         )
+        self._g_hc_row_bytes = m.gauge(
+            "serving_hc_row_bytes",
+            "bytes of one token's row of a multi-stream residual as the "
+            "programs carry it (streams x hidden x the compute type's size: "
+            "what one pass of the mixing moves a row; 0 = the model's "
+            "residual is one stream)",
+        )
         self._g_experts_held = m.gauge(
             "serving_moe_experts_held",
             "routed experts of a layer held on this chip (0 = no expert layer)",
@@ -1478,6 +1485,8 @@ class ServingEngine:
         self._g_carry_bytes.set(ds.carry_pool_bytes)
         self._g_lin_state_bytes.set(ds.lin_state_bytes)
         self._g_experts_held.set(self.family.experts_held)
+        hc_row_bytes = getattr(self.family, "stream_row_width", 0) * np.dtype(self.engine.dtype).itemsize
+        self._g_hc_row_bytes.set(hc_row_bytes)
         # the expert layers' form, as compiled: a program names the kernel or not
         self._moe_kernel = bool(self.family.sparse_layers) and (
             grouped_experts.KERNEL_NAME in self._prefill_exec.as_text()
@@ -1494,6 +1503,8 @@ class ServingEngine:
             attrs["carry_rows"] = ds.carry_pool_bytes
         if ds.lin_state_bytes:
             attrs["lin_state_bytes"] = ds.lin_state_bytes
+        if hc_row_bytes:
+            attrs["hc_row_bytes"] = hc_row_bytes
         return attrs
 
     def _moe_attrs(self, counts: np.ndarray, n_tokens: int) -> dict:

@@ -63,6 +63,8 @@ PARTS = (
     "ssm.scan",     # the short convolution and the recurrence, kernel or lax.scan
     "lin.proj",     # a linear attention's (Gated DeltaNet) projections, gates, output norm and gate
     "lin.scan",     # its short convolution and the gated delta rule, kernel or lax form
+    "hc.mix",       # a multi-stream residual's mixing around a sub-block (mHC): the maps from the stream, the
+                    # pre-mixed row, the write back through H_res and H_post; kernel pair or jnp (models/xing4.py)
     "head",         # final norm, logits, the loss in training
     "sample",       # sampling
     "optim",        # gradient norm and clip, AdamW, casts of masters, loss scaling
@@ -116,6 +118,7 @@ KERNEL_FILES = {
     "ops/pallas/decode_attention.py": "attn.core",
     "ops/pallas/selective_scan.py": "ssm.scan",
     "ops/pallas/gated_delta.py": "lin.scan",
+    "ops/pallas/hyper_connection.py": "hc.mix",
 }
 
 

@@ -1220,3 +1220,87 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_published_heads(one_chip, en
     assert len(re.findall(rf"^\s*(ROOT )?%?{gd.STEP_KERNEL}[.\d]* = .*custom-call\(", compiled.as_text(), re.M)) == 1
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 9 * rows * Hv * dk * dv * 4 and mem.temp_size_in_bytes < 16e6   # the pool in place
+
+
+# -- a multi-stream residual (xing4_0): the mixing's kernel pair at the published widths (ISSUE 57) --
+
+def _x4_config():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+    with open(os.path.join(root, "perfbench", "configs", "xing4.0-29b-a4b-ep8-l20-serve-1chip.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("rows", [64, 320, 150], ids=["decode", "mixed", "a-partial-block"])
+def test_hyper_connection_kernels_compile_for_v5e_at_the_published_widths(one_chip, rows):
+    """``ops/pallas/hyper_connection.py`` at Xing4.0's four streams of 3 584:
+    the decode step's 64 rows, the mixed step's 320 and a count the grid has to
+    pad; ``hc_post`` writes the stream in place."""
+    from deepspeed_tpu.ops.pallas import hyper_connection as hc
+
+    n, E = 4, 3584
+    K = 2 * n + n * n
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pre = hc._pre.lower(S((rows, n * E)), S((K, n * E)), S((3,)), S((K,)), n=n, eps=1e-6, iters=20,
+                        clamp=(-30.0, 30.0), impl="pallas").compile()
+    assert len(re.findall(rf"^\s*(ROOT )?%?{hc.PRE_KERNEL}[.\d]* = .*custom-call\(", pre.as_text(), re.M)) == 1
+    post = jax.jit(lambda x, y, m: hc._post(x, y, m, n=n, impl="pallas"), donate_argnums=(0,)).lower(
+        S((rows, n * E)), S((rows, E)), S((rows, K), jnp.float32)).compile()
+    assert len(re.findall(rf"^\s*(ROOT )?%?{hc.POST_KERNEL}[.\d]* = .*custom-call\(", post.as_text(), re.M)) == 1
+    mem = post.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * n * E * 2 and mem.temp_size_in_bytes < 1e6       # the stream in place (rows padded to whole tiles)
+
+
+def test_multi_stream_family_decode_program_compiles_with_the_stream_kept_on_the_chip(one_chip, monkeypatch):
+    """The ``xing4_0`` decode program as the cell serves it but FOUR layers deep
+    (both dense layers and two expert layers; 64 slots, 32 heads on a 640-lane
+    latent row, 8 held experts of 64): two mixing kernels a sub-block under the
+    names the readers find them by, nothing re-lays the pool out, and the
+    compiler keeps the stream in the chip's fast memory between them (``S(1)``
+    on the kernels' results: what ``kernel_costs_xing4.hc_mix`` counts no HBM
+    byte of a row for)."""
+    from deepspeed_tpu.models import xing4
+    from deepspeed_tpu.ops.pallas import hyper_connection as hc
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet
+
+    c = dict(_x4_config(), num_hidden_layers=4)
+    cfg = xing4.Xing4Config.from_dict(c)
+    sv = c["serving"]
+    B, page, P = sv["max_slots"], sv["page_size"], sv["num_pages"]
+    W = -(-(sv["max_prompt_len"] + sv["max_new_tokens"]) // page)
+    L = cfg.n_layer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: sds(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: xing4.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    shape = (L, P, 1, page, 640)
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=_default_format(one_chip, shape, jnp.bfloat16))
+    i32, u32 = jnp.int32, jnp.uint32
+    pset = object.__new__(ProgramSet)
+    pset.__dict__.update(
+        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
+        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=640, n_layer=L,
+    )
+    compiled = pset.aot(
+        lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+        (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)), with_params=True,
+    )
+    text = compiled.as_text()
+    assert pset.program_census("decode", compiled)[0] == 0  # or it raises
+    for kernel in (hc.PRE_KERNEL, hc.POST_KERNEL):
+        lines = re.findall(rf"^\s*%?{kernel}[.\d]* = (.*?) custom-call\(", text, re.M)
+        assert len(lines) == 2 * L and all("S(1)" in x for x in lines), kernel
+    # an attention kernel and a token write a layer, four mixing kernels, the grouped kernel an expert layer
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 2 * L + 4 * L + (L - 2)
+    assert "dspart.hc.mix/hc_pre" in text and "dspart.hc.mix/hc_post" in text

@@ -38,7 +38,7 @@ def test_a_scope_outside_the_vocabulary_is_refused():
         parts.part("attention")
     with parts.part("attn.core"):
         pass
-    assert len(set(parts.PARTS)) == len(parts.PARTS) == 18      # 13, since PR 43 ssm.proj and ssm.scan, since PR 49 attn.cca, since PR 52 lin.proj and lin.scan
+    assert len(set(parts.PARTS)) == len(parts.PARTS) == 19      # 13, since PR 43 ssm.proj and ssm.scan, since PR 49 attn.cca, since PR 52 lin.proj and lin.scan, since PR 57 hc.mix
     with parts.part("ssm.scan"), parts.part("ssm.proj"):
         pass
 
