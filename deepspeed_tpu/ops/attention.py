@@ -138,10 +138,18 @@ def _paged_kernel_taken(impl: str, ok) -> bool:
     return impl == "pallas" or (impl == "auto" and ok())
 
 
+def _live_rows(o, live):
+    """``o [B, ...]`` with the rows of slots that are not ``live`` ([B] bool;
+    None: all are) zeros, as the paged kernels leave them."""
+    if live is None:
+        return o
+    return jnp.where(live.reshape((-1,) + (1,) * (o.ndim - 1)), o, 0)
+
+
 def paged_cached_attention(
     q, k_pool, v_pool, block_tables, pos, impl: str = "auto",
     sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
-    name: Optional[str] = None,
+    name: Optional[str] = None, live=None,
 ):
     """Single-token decode attention against a PAGED KV cache (the serving
     subsystem's layout): q [B,H,D], pools [P,KV,page,D] (KV == H or
@@ -154,7 +162,8 @@ def paged_cached_attention(
     slices it. ``lo`` [B] i32 bounds the keys from below (a sliding window:
     ``lo[b] <= key <= pos[b]``, both counted from the table's first key).
     ``name`` is the kernel call's name in a trace (else that of the jitted
-    function that holds it).
+    function that holds it). ``live`` [B] bool names the slots that hold a
+    request (None: all): an idle slot's row reads nothing and comes out zeros.
 
     Dispatch mirrors :func:`cached_attention`: the Pallas paged kernel on TPU
     (the block-table gather IS the kernel's index maps — no dense copy, no
@@ -183,7 +192,7 @@ def paged_cached_attention(
     )):
         return paged_decode_attention(
             q, k_pool, v_pool, block_tables, pos, sm_scale=sm_scale,
-            scales=scales, layer=layer, lo=lo, name=name,
+            scales=scales, layer=layer, lo=lo, name=name, live=live,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -205,13 +214,13 @@ def paged_cached_attention(
     ) * scale
     probs = jax.nn.softmax(jnp.where(mask[:, :, None], scores, -1e30), axis=-1)
     o = jnp.einsum("bgrs,bsgd->bgrd", probs, vd.astype(jnp.float32))
-    return o.reshape(B, H, D).astype(q.dtype)
+    return _live_rows(o.reshape(B, H, D).astype(q.dtype), live)
 
 
 def paged_multitoken_cached_attention(
     q, k_pool, v_pool, block_tables, base, impl: str = "auto",
     sm_scale: Optional[float] = None, scales=None, layer=None, lo=None,
-    name: Optional[str] = None,
+    name: Optional[str] = None, live=None,
 ):
     """T-token causal decode attention against a PAGED KV cache (ISSUE 10:
     the speculative verify step and chunked prefill): q [B,T,H,D], pools
@@ -219,8 +228,8 @@ def paged_multitoken_cached_attention(
     sits at absolute position ``base[b] + t`` and attends keys ``<= base[b]
     + t`` → [B,T,H,D]. The chunk's own K/V must already be scattered into
     the pool (update-then-attend, exactly like the single-token step).
-    ``layer`` as in :func:`paged_cached_attention`; with ``lo`` [B] query t
-    attends only keys ``>= lo[b] + t``.
+    ``layer`` and ``live`` as in :func:`paged_cached_attention`; with ``lo``
+    [B] query t attends only keys ``>= lo[b] + t``.
 
     Dispatch mirrors :func:`paged_cached_attention`: the multitoken Pallas
     kernel on TPU, and a pure-jnp fallback whose T == 1 slice is the exact
@@ -246,7 +255,7 @@ def paged_multitoken_cached_attention(
     )):
         return paged_multitoken_attention(
             q, k_pool, v_pool, block_tables, base, sm_scale=sm_scale,
-            scales=scales, layer=layer, lo=lo, name=name,
+            scales=scales, layer=layer, lo=lo, name=name, live=live,
         )
     if layer is not None:
         k_pool, v_pool = k_pool[layer], v_pool[layer]
@@ -274,20 +283,22 @@ def paged_multitoken_cached_attention(
         jnp.where(mask[:, :, None, None, :], scores, -1e30), axis=-1
     )
     o = jnp.einsum("btgrs,bsgd->btgrd", probs, vd.astype(jnp.float32))
-    return o.reshape(B, T, H, D).astype(q.dtype)
+    return _live_rows(o.reshape(B, T, H, D).astype(q.dtype), live)
 
 
 def paged_attention_grid_steps(
     impl: str, B: int, KV: int, page: int, D: int, itemsize: int,
     n_pages: int, T: Optional[int] = None, rep: int = 1,
 ) -> int:
-    """Grid steps of ONE call of the paged attention kernel that
-    :func:`paged_cached_attention` (``T`` None) or
+    """The STATIC BOUND on the grid steps of ONE call of the paged attention
+    kernel that :func:`paged_cached_attention` (``T`` None) or
     :func:`paged_multitoken_cached_attention` dispatches these shapes to
     under ``impl``: slots x head blocks x page blocks, by the dispatchers'
     own rule and the kernels' own block rules (``rep`` query heads to a
-    kv-head widen a multi-token step's rows). 0 where the jnp fallback
-    runs."""
+    kv-head widen a multi-token step's rows); what a call of full slots
+    takes. A call walks only the items it owns
+    (``decode_attention.paged_walk_steps`` reckons them from its lengths and
+    its live slots). 0 where the jnp fallback runs."""
     from .pallas import decode_attention as da
 
     if T is None:

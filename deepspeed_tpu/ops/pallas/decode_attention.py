@@ -249,23 +249,80 @@ def _layer_operand(layer) -> tuple:
     return (jnp.asarray(layer, jnp.int32).reshape(1),)
 
 
-def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
-                  nhb: int, T: int = 1, rep: int = 1, quantized: bool = False,
+def own_blocks(at, reach: int, GP: int, n_blk: int, xp=jnp):
+    """Page blocks of ``GP`` keys that queries starting at ``at`` and
+    ``reach`` tokens long own: block 0 up to the one that holds the last
+    token's own key, inside the table's ``n_blk``. The ONE walk rule of both
+    paged kernel families (``latent_attention.latent_walk`` states it a query
+    block); with ``xp=np`` the scheduler's counters reckon by it too."""
+    return xp.minimum((at + (reach - 1)) // GP, n_blk - 1) + 1
+
+
+def items_of_rows(starts, ends, per_row, n: int):
+    """``per_row`` ``[K, rows]`` spread over the ``n`` items of a walk in which
+    row ``r`` owns items ``starts[r] .. ends[r] - 1`` → ``[K, n]`` (zeros
+    past the last item). Compares and sums over ``[items, rows]``, no search
+    and no loop: a call may sit in a conditional a layer, where nothing folds."""
+    s = jnp.arange(n, dtype=jnp.int32)
+    mine = (s[:, None] >= starts[None, :]) & (s[:, None] < ends[None, :])   # [items, rows]: one row an item
+    return s, jnp.where(mine[None], per_row[:, None, :], 0).sum(-1).astype(jnp.int32)
+
+
+def item_pages(block_tables, table, blk, last, G: int):
+    """The ``G`` pages the inputs hold in each item: block ``blk`` of the
+    table row that starts at ``table`` (in the flattened table) and is owned
+    up to page ``last``. Past ``last``, the page the input held a block ago
+    (an unchanged index fetches nothing) or, in block 0, the row's first
+    pages. ONE gather from the table."""
+    e = blk[:, None] * G + jnp.arange(G, dtype=jnp.int32)[None, :]
+    e = jnp.clip(jnp.where(e > last[:, None], e - G, e), 0, last[:, None])
+    return block_tables.reshape(-1)[table[:, None] + e]
+
+
+def paged_walk_steps(at, live, KV: int, page: int, D: int, itemsize: int,
+                     n_pages: int, T: Optional[int] = None, rep: int = 1):
+    """(grid steps one call of the paged attention kernel takes, steps of the
+    rectangle ``slots x head blocks x page blocks`` that bounds them: what
+    full slots take) for slots whose queries start at ``at`` ``[B]``, the
+    ``live`` ones (``[B]`` bool; None: all) owning their blocks and the others
+    one item each; ``T`` None is the one-token kernel. Host arithmetic, by
+    the wrappers' own rules."""
+    import numpy as np
+
+    blocks = (
+        paged_decode_blocks(KV, page, D, itemsize, n_pages) if T is None
+        else paged_multitoken_blocks(KV, page, D, T, itemsize, n_pages, rep)
+    )
+    if blocks is None:  # a page no kernel takes
+        return 0, 0
+    HB, G = blocks
+    n_blk = -(-n_pages // G)
+    own = own_blocks(np.asarray(at, np.int64), T or 1, G * page, n_blk, xp=np)
+    if live is not None:
+        own = np.where(live, own, 1)
+    return int(own.sum()) * (KV // HB), own.size * (KV // HB) * n_blk
+
+
+def _paged_kernel(row_ref, home_ref, blk_ref, at_ref, pages_ref, *rest,
+                  sm_scale: float, G: int, n_blk: int, T: int = 1,
+                  rep: int = 1, quantized: bool = False,
                   windowed: bool = False, layer_operand: bool = False):
     """Online-softmax accumulation over one slot's pages for ``T`` query
     tokens of the slot (1: the decode step; more: chunked prefill and the
     verify shape), ``G`` pages and ``HB`` kv-heads (all of them, unless a
     step of all does not fit VMEM) to a grid step.
 
-    Grid (B * nhb, ceil(n_pages / G)), sequential: row ``r`` is slot
-    ``r // nhb``, head block ``r % nhb``; the (m, l, acc) scratch persists
-    across the row's page blocks, reset at block 0 and emitted at the slot's
-    LAST OWN block. Query ``t`` of slot ``b`` sits at position ``at[b] + t``
-    and attends keys at positions ``<= at[b] + t``, so the last own block is
-    the one the tokens reach, ``(at + T - 1) // (G * page)``. Blocks past it
-    skip their compute, and the walked table (:func:`_walked_table`) names
-    for them the pages the last own block named, so Pallas fetches nothing,
-    or the next slot's first block (:func:`_first_blocks_ahead`).
+    The grid is the call's ITEMS (:func:`_walk_items`), sequential: step
+    ``s`` holds page block ``blk[s]`` of grid row ``row[s]`` (slot ``row //
+    nhb``, head block ``row % nhb``), a row's blocks in a row. The (m, l, acc)
+    scratch persists across a row's items, reset at its block 0 and emitted
+    at its LAST OWN block. Query ``t`` of the row's slot sits at position
+    ``at[s] + t`` and attends keys at positions ``<= at[s] + t``, so the last
+    own block is the one the tokens reach, ``(at + T - 1) // (G * page)``:
+    every step computes, and the next item's pages arrive under it. An IDLE
+    row's one item has ``at[s]`` -1: its inputs name what the item before
+    held, so nothing is fetched, and the step writes the row's output zeros
+    (rows feed shared reductions in the families with expert layers).
     ``rest`` holds the block's ``G`` K pages and ``G`` V pages, ``[1, HB,
     page, D]`` each. A page ref past the slot's last page holds one of the
     slot's earlier pages; its scores are masked and its probabilities are
@@ -289,13 +346,14 @@ def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
     key column, ``[2, HB, 1, G * page]``; scores and probabilities are scaled
     in VMEM, so the HBM read per page stays the halved code bytes.
 
-    ``windowed``: a third prefetched operand ``lo`` ([B]) bounds the keys from
-    below: query ``t`` attends keys ``lo[b] + t <= key <= at[b] + t`` (a
-    sliding window; the caller's table starts at the page that holds
-    ``lo[b]``, so the walk starts there and block 0 is an own block).
+    ``windowed``: one more prefetched operand ``lo`` (an item's) bounds the
+    keys from below: query ``t`` attends keys ``lo + t <= key <= at + t`` (a
+    sliding window; the caller's table starts at the page that holds ``lo``,
+    so the walk starts there and block 0 is an own block).
 
     ``layer_operand``: one more prefetched operand follows, the pool's layer,
-    which the pools' index maps read and the body does not."""
+    which the pools' index maps read and the body does not. ``row``, ``home``
+    and ``pages`` are the index maps' too."""
     lo_ref = None
     if windowed:
         lo_ref, *rest = rest
@@ -306,21 +364,24 @@ def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
     if quantized:
         sc_ref, *rest = rest
     o_ref, m_ref, l_ref, acc_ref = rest
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-    slot = r if nhb == 1 else jax.lax.div(r, nhb)
-    at = at_ref[slot]
-    lo = lo_ref[slot] if windowed else None
+    step = pl.program_id(0)
+    j, at = blk_ref[step], at_ref[step]
+    lo = lo_ref[step] if windowed else None
+    live = at >= 0
     GP = G * k_refs[0].shape[2]
     last_blk = jax.lax.div(at + (T - 1), GP)
     if T > 1:  # a chunk may reach past the table; a decode position does not
-        last_blk = jnp.minimum(last_blk, pl.num_programs(1) - 1)
+        last_blk = jnp.minimum(last_blk, n_blk - 1)
 
-    @pl.when(j == 0)
+    @pl.when(live & (j == 0))
     def _reset():
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(jnp.logical_not(live))
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     def emit():
         o_ref[0, 0] = (
@@ -371,58 +432,71 @@ def _paged_kernel(walk_ref, at_ref, *rest, sm_scale: float, G: int,
         if emits:
             pl.when(j == last_blk)(emit)
 
-    own = j <= last_blk
     if rep * T <= 8:
         # the decode step's form: one branch a grid step, the emit inside it
-        pl.when(own)(functools.partial(update, True, emits=True))
+        pl.when(live)(functools.partial(update, True, emits=True))
     else:
         whole = (j + 1) * GP - 1 <= at  # every key visible to every query
         if windowed:
             whole = whole & (j * GP >= lo + (T - 1))
-        pl.when(own & whole)(functools.partial(update, False))
-        pl.when(own & jnp.logical_not(whole))(functools.partial(update, True))
-        pl.when(j == last_blk)(emit)
+        pl.when(live & whole)(functools.partial(update, False))
+        pl.when(live & jnp.logical_not(whole))(functools.partial(update, True))
+        pl.when(live & (j == last_blk))(emit)
 
 
-def _walked_table(block_tables, last, n_blk: int, G: int):
-    """The table as the grid walks it: entry (j, g) of a row is the page that
-    input g holds in block j. Past the slot's last own page ``last`` ([B, 1])
-    that is the page the input held a block ago (an unchanged index fetches
-    nothing) or, in the first block, the slot's first page. Computed once for
-    all layers (the same table and lengths: XLA folds the repeats), so an
-    index map is one SMEM read."""
-    e = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :]
-    e = jnp.minimum(e // G, last // G) * G + e % G
-    e = jnp.clip(jnp.where(e > last, e - G, e), 0, last)
-    return jnp.take_along_axis(jnp.asarray(block_tables, jnp.int32), e, axis=1)
+def _walk_items(block_tables, at, live, T: int, G: int, page: int, nhb: int):
+    """The call's items in the order the grid walks them, padded to the
+    rectangle ``B * nhb * n_blk`` (what a call of full slots owns): a LIVE
+    grid row (a slot's head block) owns the blocks :func:`own_blocks` counts
+    for its slot's ``at`` ``[B]``, an IDLE one (``live`` ``[B]`` false; None:
+    every slot is live) ONE item. → per item its grid ``row``; the row
+    ``home`` whose query and pages its inputs hold; the page block ``blk``;
+    ``at`` of its slot, -1 for an idle row's; the ``G`` ``pages`` (flat:
+    :func:`item_pages`); and ``[1]`` the count of real items. An idle row's
+    ``home`` is the nearest live row before it, or for leading idle rows the
+    first live one, and its item names that row's nearest block (its last
+    own, or block 0): the inputs hold already what the item names, or fetch it
+    ONCE for the live item too."""
+    B, n_pages = block_tables.shape
+    n_blk = -(-n_pages // G)
+    slot = jnp.arange(B, dtype=jnp.int32)
+    live = jnp.ones((B,), bool) if live is None else live
+    own = jnp.where(live, own_blocks(at, T, G * page, n_blk), 1)            # [B]
+    before = jax.lax.cummax(jnp.where(live, slot, -1))                      # a live slot's: itself
+    host = jnp.where(before >= 0, before, jnp.argmax(live)).astype(jnp.int32)
+    per_slot = jnp.stack([                                                  # of an item's slot:
+        slot, host, jnp.where(live, at, -1),
+        jnp.where(live | (before < 0), 0, own[host] - 1),                   # the block an idle row's item names
+        jnp.where(before >= 0, nhb - 1, 0),                                 # ... and the head block
+        jnp.minimum((at[host] + (T - 1)) // page, n_pages - 1),             # the last page its host reaches
+    ])
+    hb = jnp.tile(jnp.arange(nhb, dtype=jnp.int32), B)                      # rows: a slot's head blocks in a row
+    own = jnp.repeat(own, nhb)
+    ends = jnp.cumsum(own)
+    starts = ends - own
+    s, (b, host, at, blk, host_hb, last, hb, first) = items_of_rows(
+        starts, ends, jnp.concatenate([jnp.repeat(per_slot, nhb, axis=1), hb[None], starts[None]]),
+        B * nhb * n_blk,
+    )
+    row = b * nhb + hb
+    home = jnp.where(at >= 0, row, host * nhb + host_hb)
+    blk = jnp.minimum(blk + s - first, n_blk - 1)
+    pages = item_pages(block_tables, host * n_pages, blk, last, G)
+    return row, home, blk, at, pages.reshape(-1), ends[-1:].astype(jnp.int32)
 
 
-def _first_blocks_ahead(walk, last, G: int):
-    """:func:`_walked_table`'s table with the blocks past a slot's last own
-    one naming the NEXT slot's first block (the last slot's keep what they
-    name). The pipeline fetches a step's pages during the step before, and
-    the step before a slot's first is the slot before's last SKIPPED one,
-    over in 0.15 us: the first block's DMAs were waited for in full, once a
-    slot and layer. Named here, they run during the slot before's last own
-    step. For a grid row that is a slot (one head block): a row's table is
-    its slot's."""
-    n_blk = walk.shape[1] // G
-    blk = jnp.arange(n_blk * G, dtype=jnp.int32)[None, :] // G
-    first = jnp.concatenate([walk[1:, :G], walk[-1:, -G:]])  # [B, G]
-    return jnp.where(blk > last // G, jnp.tile(first, (1, n_blk)), walk)
-
-
-def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
+def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, live, T: int,
                 HB: int, G: int, scales, layer: Optional[int], interpret: bool,
                 lo=None, name: Optional[str] = None):
-    """The ``pallas_call`` of :func:`_paged_kernel`, for both wrappers: grid
-    ``(B * nhb, n_blk)`` over ``q5`` ``[B, nhb, HB, R, D]`` (``R`` query rows
-    a kv-head: ``rep`` for the decode step, ``rep * T`` for T tokens), the
-    ``G`` K and ``G`` V page inputs under the walked table, an int8 pool's
-    scales per key column, and the (m, l, acc) scratch. ``at`` ([B], scalar
-    prefetched with the table) is what the kernel masks by, ``last`` the
-    slot's last own page and ``last_blk(at[b])`` its last own block. ``lo``
-    ([B], for a ``windowed`` kernel) is prefetched after ``at``. ``name`` is
+    """The ``pallas_call`` of :func:`_paged_kernel`, for both wrappers: a grid
+    as long as the call's own walk (:func:`_walk_items`: its bound is a value
+    of the call) over ``q5`` ``[B, nhb, HB, R, D]`` (``R`` query rows a
+    kv-head: ``rep`` for the decode step, ``rep * T`` for T tokens), the
+    ``G`` K and ``G`` V page inputs under the items' pages, an int8 pool's
+    scales per key column, and the (m, l, acc) scratch. ``at`` ([B]) is what
+    the kernel masks by and what a slot's own blocks follow from, ``live``
+    ([B] bool, or None) the slots that hold a request. ``lo`` ([B], for a
+    ``windowed`` kernel) is prefetched an item, after the pages. ``name`` is
     the call's name in a trace; without one it takes the name of the jitted
     function that holds it (``decode_fn``, ``verify_fn``), which is what a
     program that holds both kernels cannot leave to chance."""
@@ -430,54 +504,53 @@ def _paged_call(kernel, q5, k_pool, v_pool, block_tables, at, last, last_blk,
     page = k_pool.shape[-2]
     n_pages = block_tables.shape[1]
     n_blk, GP = -(-n_pages // G), G * page
-    walk = _walked_table(block_tables, last[:, None], n_blk, G)
-    if nhb == 1:
-        walk = _first_blocks_ahead(walk, last[:, None], G)
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    row, home, blk, at_s, pages, n_items = _walk_items(
+        block_tables, at, live, T, G, page, nhb
+    )
 
-    def row(r):  # grid row -> (slot, head block)
+    def rows(r):  # grid row -> (slot, head block)
         return (r, 0) if nhb == 1 else (jax.lax.div(r, nhb), jax.lax.rem(r, nhb))
 
     def page_spec(g):
-        def index_map(r, j, walk, at, *lo):
-            b, hb = row(r)
-            return walk[b, j * G + g], hb, 0, 0
+        def index_map(s, row, home, blk, at, pages, *_):
+            return pages[s * G + g], rows(home[s])[1], 0, 0
 
         return _pool_block_spec((1, HB, page, D), index_map, layer)
 
-    def qo_map(r, j, walk, at, *lo):
-        return (*row(r), 0, 0, 0)
-
-    qo_spec = pl.BlockSpec((1, 1, HB, R, D), qo_map)
-    pages = [page_spec(g) for g in range(G)]
-    in_specs = [qo_spec] + pages + pages
+    block = (1, 1, HB, R, D)
+    q_spec = pl.BlockSpec(block, lambda s, row, home, *_: (*rows(home[s]), 0, 0, 0))
+    o_spec = pl.BlockSpec(block, lambda s, row, *_: (*rows(row[s]), 0, 0, 0))
+    page_specs = [page_spec(g) for g in range(G)]
+    in_specs = [q_spec] + page_specs + page_specs
     operands = [q5] + [k_pool] * G + [v_pool] * G
     if scales is not None:
         # per key column of each page block: [B, n_blk, 2, nhb, HB, 1, GP];
-        # blocks past the slot's last keep its index, so nothing is fetched
+        # an idle row's item keeps the index of the item before
         st = jnp.asarray(scales, jnp.float32)[block_tables]  # [B, n, KV, 2]
         st = jnp.pad(st, ((0, 0), (0, n_blk * G - n_pages), (0, 0), (0, 0)))
         st = jnp.repeat(st, page, axis=1).reshape(B, n_blk, GP, nhb, HB, 2)
         operands.append(st.transpose(0, 1, 5, 3, 4, 2)[..., None, :])
 
-        def scale_map(r, j, walk, at, *lo):
-            b, hb = row(r)
-            return b, jax.lax.min(j, last_blk(at[b])), 0, hb, 0, 0, 0
+        def scale_map(s, row, home, blk, *_):
+            b, hb = rows(home[s])
+            return b, blk[s], 0, hb, 0, 0, 0
 
         in_specs.append(pl.BlockSpec((1, 1, 2, 1, HB, 1, GP), scale_map))
-    prefetched = (
-        walk, at, *(() if lo is None else (jnp.asarray(lo, jnp.int32),)),
-        *_layer_operand(layer),
-    )
+    prefetched = (row, home, blk, at_s, pages)
+    if lo is not None:
+        prefetched += (jnp.asarray(lo, jnp.int32)[rows(row)[0]],)
+    prefetched += _layer_operand(layer)
     with parts.unscoped():
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                # walked table + per-slot positions (+ a window's lower bounds)
+                # the items' five vectors (+ their windows' lower bounds)
                 # (+ the layer, where it is an operand)
                 num_scalar_prefetch=len(prefetched),
-                grid=(B * nhb, n_blk),
+                grid=(n_items[0],),
                 in_specs=in_specs,
-                out_specs=qo_spec,
+                out_specs=o_spec,
                 scratch_shapes=[
                     pltpu.VMEM((HB, R, 1), jnp.float32),  # running max
                     pltpu.VMEM((HB, R, 1), jnp.float32),  # running denominator
@@ -511,13 +584,13 @@ def _shares_kernel(pool, layer, name) -> bool:
 
 @functools.partial(jax.jit, static_argnames=("fn", "sm_scale", "interpret", "name"))
 def _for_all_layers(fn, q, k_pool, v_pool, block_tables, at, layer, scales, lo,
-                    *, sm_scale, interpret, name):
+                    live, *, sm_scale, interpret, name):
     """``fn`` (one of the two wrappers below) with the layer an OPERAND, under
     a jit of its own: a program's calls, one a layer, then share one traced
     and one lowered kernel, which is most of what a layer costs a served
     program's set-up (ROADMAP S9)."""
     return fn(q, k_pool, v_pool, block_tables, at, sm_scale, interpret, scales,
-              layer, lo, name)
+              layer, lo, name, live)
 
 
 def paged_decode_attention(
@@ -532,6 +605,7 @@ def paged_decode_attention(
     layer=None,  # the layer of a [L, P, KV, page, D] pool: static, or a traced i32
     lo: Optional[jnp.ndarray] = None,  # [B] i32: lowest key index attended (a window)
     name: Optional[str] = None,  # the call's name in a trace (:func:`_paged_call`)
+    live: Optional[jnp.ndarray] = None,  # [B] bool: the slots that hold a request (None: all)
 ) -> jnp.ndarray:
     """Single-token attention against a PAGED cache → [B, H, D].
 
@@ -549,11 +623,12 @@ def paged_decode_attention(
     to ``pos[b]``; the kernel walks them ``G`` pages at a time with all
     kv-heads in one step (:func:`paged_decode_blocks` picks both from the
     shapes). Each of the ``G`` page inputs is the same pool under its own
-    index map: the scalar-prefetched table names the page, one DMA brings
-    its whole ``[KV, page, D]`` run, and the map stops at the slot's own
-    last page, so table entries past ``pos[b] // page`` are never read; the
-    blocks a slot skips name the next slot's first block, which is so
-    fetched ahead (:func:`_first_blocks_ahead`). GQA (KV < H) reads the
+    index map: the scalar-prefetched items name the page, one DMA brings
+    its whole ``[KV, page, D]`` run, and the walk stops at the slot's own
+    last block (:func:`_walk_items`), so table entries past ``pos[b] //
+    page`` are never read and the next slot's first block is fetched under
+    this slot's last. A slot that ``live`` says holds no request owns one
+    item that fetches nothing; its output rows are zeros. GQA (KV < H) reads the
     group's pool column once for its ``rep`` query heads. ``scales``
     (ISSUE 12): int8 pools are served by the same kernel;
     the slots' per-page scale rows are gathered through the table into
@@ -565,7 +640,7 @@ def paged_decode_attention(
     if _shares_kernel(k_pool, layer, name):
         return _for_all_layers(
             paged_decode_attention, q, k_pool, v_pool, block_tables, pos,
-            jnp.int32(layer), scales, lo, sm_scale=sm_scale,
+            jnp.int32(layer), scales, lo, live, sm_scale=sm_scale,
             interpret=interpret, name=name,
         )
     B, H, D = q.shape
@@ -586,14 +661,13 @@ def paged_decode_attention(
     nhb = KV // HB
     pos = jnp.asarray(pos, jnp.int32)
     kernel = functools.partial(
-        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=1, rep=rep,
-        quantized=quantized, windowed=lo is not None,
+        _paged_kernel, sm_scale=float(scale), G=G, n_blk=-(-n_pages // G), T=1,
+        rep=rep, quantized=quantized, windowed=lo is not None,
         layer_operand=bool(_layer_operand(layer)),
     )
     out = _paged_call(
         kernel, q.reshape(B, nhb, HB, rep, D), k_pool, v_pool, block_tables,
-        pos, pos // page, lambda pos_b: jax.lax.div(pos_b, G * page),
-        HB, G, scales, layer, interpret, lo, name,
+        pos, live, 1, HB, G, scales, layer, interpret, lo, name,
     )
     return out.reshape(B, H, D)
 
@@ -610,6 +684,7 @@ def paged_multitoken_attention(
     layer=None,  # the layer of a [L, P, KV, page, D] pool: static, or a traced i32
     lo: Optional[jnp.ndarray] = None,  # [B] i32: query t attends keys >= lo[b] + t
     name: Optional[str] = None,  # the call's name in a trace (:func:`_paged_call`)
+    live: Optional[jnp.ndarray] = None,  # [B] bool: the slots that hold a request (None: all)
 ) -> jnp.ndarray:
     """T-token causal attention against a PAGED cache → [B, T, H, D].
     ``lo`` as in :func:`paged_decode_attention`, moving with the query.
@@ -620,16 +695,16 @@ def paged_multitoken_attention(
     (update-then-attend, as in the single-token decode step). The plan is
     :func:`paged_decode_attention`'s: all kv-heads and ``G`` pages to a grid
     step (:func:`paged_multitoken_blocks` picks both from the shapes), the
-    walked table ending at the page the chunk reaches, so table entries past
-    ``(base[b] + T - 1) // page`` are never read; GQA, int8 ``scales`` and
-    ``layer`` as there. The queries go in head-major, ``[B, KV, rep * T,
+    walk ending at the page the chunk reaches, so table entries past
+    ``(base[b] + T - 1) // page`` are never read; GQA, int8 ``scales``,
+    ``layer`` and ``live`` as there. The queries go in head-major, ``[B, KV, rep * T,
     D]``, which is what the head-batched ``dot_general`` takes: the two
     ``[T, H] <-> [H, T]`` transposes around the call stay (0.4 MB each at
     the served shape). ``name`` as in :func:`paged_decode_attention`."""
     if _shares_kernel(k_pool, layer, name):
         return _for_all_layers(
             paged_multitoken_attention, q, k_pool, v_pool, block_tables, base,
-            jnp.int32(layer), scales, lo, sm_scale=sm_scale,
+            jnp.int32(layer), scales, lo, live, sm_scale=sm_scale,
             interpret=interpret, name=name,
         )
     B, T, H, D = q.shape
@@ -652,18 +727,14 @@ def paged_multitoken_attention(
     nhb = KV // HB
     base = jnp.asarray(base, jnp.int32)
     kernel = functools.partial(
-        _paged_kernel, sm_scale=float(scale), G=G, nhb=nhb, T=T, rep=rep,
-        quantized=scales is not None, windowed=lo is not None,
+        _paged_kernel, sm_scale=float(scale), G=G, n_blk=-(-n_pages // G), T=T,
+        rep=rep, quantized=scales is not None, windowed=lo is not None,
         layer_operand=bool(_layer_operand(layer)),
     )
     q5 = q.reshape(B, T, nhb, HB, rep, D).transpose(0, 2, 3, 4, 1, 5)
-    n_blk = -(-n_pages // G)
     out = _paged_call(
         kernel, q5.reshape(B, nhb, HB, rep * T, D), k_pool, v_pool,
-        block_tables, base, jnp.minimum((base + (T - 1)) // page, n_pages - 1),
-        lambda base_b: jax.lax.min(
-            jax.lax.div(base_b + (T - 1), G * page), n_blk - 1),
-        HB, G, scales, layer, interpret, lo, name,
+        block_tables, base, live, T, HB, G, scales, layer, interpret, lo, name,
     )
     out = out.reshape(B, nhb, HB, rep, T, D).transpose(0, 4, 1, 2, 3, 5)
     return out.reshape(B, T, H, D)

@@ -34,7 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .decode_attention import _pool_block_spec, _pool_dims
+from .decode_attention import _pool_block_spec, _pool_dims, item_pages, items_of_rows, own_blocks
 
 DECODE_KEYS = 2048   # keys a grid step of the decode shape holds (32 rows: the page DMAs are the step)
 CHUNK_KEYS = 512     # ... and of the chunk shape
@@ -58,11 +58,11 @@ def latent_blocks(H: int, page: int, T: int, n_pages: int):
 
 def latent_walk(base, T: int, TQ: int, GP: int, n_blk: int, xp=jnp):
     """Page blocks each (slot, query block) of a call owns, ``[B, T // TQ]``:
-    block 0 up to the one that holds the own key of the query block's last
-    token (``base`` ``[B]``). The kernel's wrapper and, with ``xp=np``, the
-    scheduler's counters reckon by this one rule."""
+    ``decode_attention.own_blocks``, the ONE walk rule of both paged kernel
+    families, for a query block's ``TQ`` tokens (``base`` ``[B]``). The wrapper
+    and, with ``xp=np``, the scheduler's counters reckon by it."""
     at = base[:, None] + TQ * xp.arange(T // TQ)[None, :]
-    return xp.minimum((at + (TQ - 1)) // GP, n_blk - 1) + 1
+    return own_blocks(at, TQ, GP, n_blk, xp)
 
 
 def latent_walk_steps(base, H: int, page: int, T: int, n_pages: int):
@@ -142,7 +142,11 @@ def _walk_items(block_tables, base, T: int, TQ: int, G: int, page: int):
     a block ago, so nothing is fetched; in block 0 the slot's first pages),
     and ``[1]`` the count of real items. Compares and sums over ``[items,
     rows]`` and ONE gather from the table, no search and no loop: the decode
-    shape's call sits in a conditional a layer, where nothing folds."""
+    shape's call sits in a conditional a layer, where nothing folds.
+    The two steps are ``decode_attention``'s, which the per-head kernels'
+    walk takes too (``items_of_rows``: a row's values spread over its items;
+    ``item_pages``: the pages an item's inputs hold): ONE walk for both kernel
+    families, whose rows differ (a query block here, a head block there)."""
     B, n_pages = block_tables.shape
     nq, n_blk = T // TQ, -(-n_pages // G)
     own = latent_walk(base, T, TQ, G * page, n_blk).reshape(-1)             # [B * nq]
@@ -155,13 +159,9 @@ def _walk_items(block_tables, base, T: int, TQ: int, G: int, page: int):
         jnp.minimum((at + (TQ - 1)) // page, n_pages - 1),                  # the last page it reaches
         (r // nq) * n_pages,                                                # where its slot's table row starts
     ])
-    s = jnp.arange(B * nq * n_blk, dtype=jnp.int32)
-    mine = (s[:, None] >= starts[None, :]) & (s[:, None] < ends[None, :])   # [items, rows]: one row an item
-    row, first, at, last, table = jnp.where(mine[None], per_row[:, None, :], 0).sum(-1).astype(jnp.int32)
+    s, (row, first, at, last, table) = items_of_rows(starts, ends, per_row, B * nq * n_blk)
     blk = jnp.minimum(s - first, n_blk - 1)
-    e = blk[:, None] * G + jnp.arange(G, dtype=jnp.int32)[None, :]
-    e = jnp.clip(jnp.where(e > last[:, None], e - G, e), 0, last[:, None])
-    pages = block_tables.reshape(-1)[table[:, None] + e]
+    pages = item_pages(block_tables, table, blk, last, G)
     return row, blk, at, pages.reshape(-1), ends[-1:].astype(jnp.int32)
 
 

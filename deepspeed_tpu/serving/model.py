@@ -945,7 +945,7 @@ def paged_prefill(
 @parts.scoped("attn.core")
 def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
                           out_dtype, scales_l=None, lo=None, name=None,
-                          live=None):
+                          live=None, real=None):
     """ONE query token per slot against layer ``l`` of the paged cache →
     [B, 1, E]. The kernel takes the whole pools and the layer as a block
     index; only the ``jnp`` branch slices the layer out.
@@ -960,7 +960,10 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
     program that holds more kernels than this one; a named call into a deep
     pool shares its traced and lowered kernel with the program's other
     layers (``ops/pallas/decode_attention._shares_kernel``). ``live``:
-    :func:`_unless_idle`'s, where the caller has one."""
+    :func:`_unless_idle`'s, where the caller has one. ``real`` ([B] bool):
+    the rows whose slot holds a decoding request, where the caller has
+    observed them; the kernel walks only those rows' pages and leaves the
+    others' output zeros."""
     B, S, H, D = q.shape  # S == 1
     E = H * D
     if live is not None:
@@ -968,7 +971,7 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
             live,
             lambda: _attend_decode_shaped(
                 fam, q, k_pool, v_pool, l, block_tables, pos, out_dtype,
-                scales_l, lo, name,
+                scales_l, lo, name, real=real,
             ),
             (B, S, E), out_dtype,
         )
@@ -979,7 +982,7 @@ def _attend_decode_shaped(fam, q, k_pool, v_pool, l, block_tables, pos,
         o1 = paged_cached_attention(
             q[:, 0], k_pool, v_pool, block_tables, pos,
             impl=fam.attn_impl, sm_scale=scale, scales=scales_l, layer=l,
-            lo=lo, name=name,
+            lo=lo, name=name, live=real,
         )
         return o1.reshape(B, 1, E).astype(out_dtype)
 
@@ -1016,8 +1019,8 @@ class _RingWrites:
         B = seq_lens.shape[0]
         slots = jnp.arange(B, dtype=jnp.int32)
         pos = seq_lens[:, None] + jnp.arange(T)[None, :]               # [B, T]
-        active = (block_tables[:, 0] != 0)[:, None]
-        self.pidx = jnp.where(active, ring_page_ids(slots[:, None], pos // page, ring), 0)
+        self.real = block_tables[:, 0] != 0
+        self.pidx = jnp.where(self.real[:, None], ring_page_ids(slots[:, None], pos // page, ring), 0)
         self.poff = pos % page
         self.views = _window_views(fam, slots, seq_lens, page, ring)
 
@@ -1039,7 +1042,7 @@ def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int,
     o = [
         _attend_decode_shaped(
             fam, q[:, t:t + 1], k_win, v_win, li, table, base + t - off,
-            q.dtype, None, lo + t, name, live,
+            q.dtype, None, lo + t, name, live, rw.real,
         )
         for t in range(T)
     ]
@@ -1048,7 +1051,7 @@ def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int,
 
 def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
                             pos, pidx, poff, l, scales=None, name=None,
-                            live=None):
+                            live=None, real=None):
     """One-token attention per slot against its paged cache (layer ``l`` of
     the FULL pool) → ``(o [B, 1, H * D], k_pool, v_pool, scales)``.
 
@@ -1065,6 +1068,7 @@ def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
     o = _attend_decode_shaped(
         fam, q, k_pool, v_pool, l, block_tables, pos, q.dtype,
         scales[l] if scales is not None else None, name=name, live=live,
+        real=real,
     )
     return o, k_pool, v_pool, scales
 
@@ -1117,7 +1121,8 @@ def paged_decode_step(
     )[:, 0]
     poff = seq_lens % page
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
-    valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
+    real = block_tables[:, 0] != 0  # a slot that decodes holds a page; page 0 is scratch
+    valid = real[:, None] if fam.sparse_layers else None
     counts, carry = [], None
     kinds, carried = sub_block_kinds(fam), getattr(fam, "carry_width", 0)
 
@@ -1126,15 +1131,16 @@ def paged_decode_step(
         if kinds[l] != "attn":
             a, carry, state = _mixer_without_kv(
                 fam, lp, h, l, li, positions, carry, state, tp_axis,
-                (0, None, block_tables[:, 0] != 0),
+                (0, None, real),
                 lambda q, li: _attend_decode_shaped(
-                    fam, q, k_pool, v_pool, li, block_tables, seq_lens, q.dtype
+                    fam, q, k_pool, v_pool, li, block_tables, seq_lens, q.dtype,
+                    real=real,
                 ),
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
             continue
         if carried:
-            q, k_, v, state = _qkv_carried(fam, lp, h, positions, l, state, real=block_tables[:, 0] != 0)
+            q, k_, v, state = _qkv_carried(fam, lp, h, positions, l, state, real=real)
         else:
             with parts.part("attn.qkv"):
                 q, k_, v = fam.qkv(lp, h, positions, l)
@@ -1153,6 +1159,7 @@ def paged_decode_step(
             o, k_pool, v_pool, scales = _attention_decode_paged(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
                 block_tables, seq_lens, pidx, poff, li, scales, name="decode_fn",
+                real=real,
             )
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
@@ -1227,7 +1234,7 @@ def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
 
 
 def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
-                            base, pidx, poff, l, scales=None):
+                            base, pidx, poff, l, scales=None, real=None):
     """T-token attention per slot: scatter every token's K/V to layer ``l``
     at (``pidx[b,t]``, ``poff[b,t]``), then attend query t at position
     ``base + t`` through the block table. Out-of-budget positions arrive
@@ -1262,7 +1269,7 @@ def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
         [
             _attend_decode_shaped(
                 fam, q[:, t:t + 1], k_pool, v_pool, l, block_tables,
-                base + t, q.dtype, scales_l,
+                base + t, q.dtype, scales_l, real=real,
             )
             for t in range(T)
         ],
@@ -1332,7 +1339,8 @@ def paged_verify_step(
         h = fam.embed(params, tokens, positions)
     pidx, poff = _verify_write_targets(seq_lens, block_tables, page, T)
     rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if win is not None else None
-    valid = (block_tables[:, 0] != 0)[:, None] if fam.sparse_layers else None
+    real = block_tables[:, 0] != 0
+    valid = real[:, None] if fam.sparse_layers else None
     counts, carry = [], None
 
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
@@ -1352,7 +1360,7 @@ def paged_verify_step(
             pool_dt = h.dtype if scales is not None else k_pool.dtype
             o, k_pool, v_pool, scales = _attention_verify_paged(
                 fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
-                block_tables, seq_lens, pidx, poff, li, scales,
+                block_tables, seq_lens, pidx, poff, li, scales, real,
             )
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
@@ -1492,6 +1500,7 @@ def paged_mixed_step(
                 jnp.concatenate([chunk_row, block_tables]),
                 jnp.concatenate([base + idx, seq_lens]), q.dtype, name="decode_fn",
                 live=None if live is None else live | (prompt_len <= start + C),
+                real=jnp.concatenate([jnp.ones((1,), bool), real]),
             )
             return jnp.swapaxes(o, 0, 1)
         qc, qd = part(q)
@@ -1500,7 +1509,7 @@ def paged_mixed_step(
             return o
         od = _attend_decode_shaped(
             fam, qd, k_pool, v_pool, li, block_tables, seq_lens, q.dtype,
-            name="decode_fn", live=live,
+            name="decode_fn", live=live, real=real,
         )
         return jnp.concatenate([o, jnp.swapaxes(od, 0, 1)], axis=1)
 
@@ -1561,6 +1570,7 @@ def paged_mixed_step(
                 od, k_pool, v_pool, scales = _attention_decode_paged(
                     fam, qd, kd.astype(pool_dt), vd.astype(pool_dt), k_pool, v_pool,
                     block_tables, seq_lens, pidx, poff, li, scales, "decode_fn", live,
+                    real,
                 )
             oc = _attend_multitoken_paged(
                 fam, qc, k_pool, v_pool, li, chunk_row, base,

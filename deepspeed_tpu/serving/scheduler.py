@@ -84,6 +84,7 @@ import numpy as np
 
 from ..moe.expert_share import experts_streamed
 from ..ops.pallas import grouped_experts
+from ..ops.pallas.decode_attention import paged_walk_steps
 from ..ops.pallas.latent_attention import latent_walk_steps
 from ..telemetry import parts, spans
 from ..telemetry.registry import MetricsRegistry
@@ -91,6 +92,7 @@ from ..telemetry.request_trace import LATENCY_BUCKETS, RequestTracer
 from ..utils.logging import log_dist
 from . import model as smodel
 from .kv_cache import (
+    SCRATCH_PAGE,
     PageAllocatorError,
     PrefixCache,
     SlotTable,
@@ -592,23 +594,24 @@ class ServingEngine:
             "serving_attended_tokens_total",
             "context tokens attended, summed over active slots and decode steps",
         )
-        # a latent family's attention kernels walk the (slot, query block,
-        # page block) pairs a call owns: their quotient is how much of the
-        # old rectangular grid a call's lengths skip
+        # the paged attention kernels of both families walk the items a call
+        # owns: a live slot's (query block, page block) pairs and one item an
+        # idle slot. Their quotient is how much of the rectangular grid a
+        # call's lengths and idle rows skip
         self._c_walk_steps = m.counter(
-            "serving_latent_walk_steps_total",
-            "grid steps of the latent attention kernels' calls, one layer's: "
-            "the (slot, query block, page block) pairs the calls own, by the "
-            "kernel's name in a trace (0 where the jnp fallback runs)",
+            "serving_attn_walk_steps_total",
+            "grid steps of the paged attention kernels' calls, one paged "
+            "layer's: the items the calls own, by the kernel's name in a "
+            "trace (0 where the jnp fallback runs)",
             labelnames=("program",),
         )
         self._c_rect_steps = m.counter(
-            "serving_latent_rect_steps_total",
-            "what the same calls' rectangles take: slots x query blocks x the "
-            "table's page blocks (serving_paged_grid_steps' terms)",
+            "serving_attn_rect_steps_total",
+            "what the same calls' rectangles take: slots x head or query "
+            "blocks x the table's page blocks (serving_paged_grid_steps' terms)",
             labelnames=("program",),
         )
-        self._latent_kernel = False   # set by _set_census_gauges: the programs call the latent kernels
+        self._attn_kernel = False   # set by _set_census_gauges: the programs call the paged attention kernels
         self._c_timeouts = m.counter(
             "serving_timeout_evictions_total",
             "requests evicted mid-flight by deadline",
@@ -755,9 +758,11 @@ class ServingEngine:
         )
         self._g_grid_steps = m.gauge(
             "serving_paged_grid_steps",
-            "grid steps of one call of each paged attention kernel of a "
-            "compiled serving program (the chunk program has two): slots x "
-            "head blocks x page blocks (0 = the program calls no such kernel)",
+            "the bound on the grid steps of one call of each paged attention "
+            "kernel of a compiled serving program (the chunk program has two): "
+            "slots x head blocks x page blocks, what a call of full slots "
+            "takes; a call walks the items it owns "
+            "(serving_attn_walk_steps_total; 0 = the program calls no such kernel)",
             labelnames=("program",),
         )
         # -- two kinds of KV state, and the experts a chip's share holds ----
@@ -1465,7 +1470,7 @@ class ServingEngine:
             self._g_relayout.set(relayout[name], program=name)
             self._g_temp_bytes.set(temp[name], program=name)
             self._g_grid_steps.set(steps[name], program=name)
-        self._latent_kernel = self.latent and any(steps.values())
+        self._attn_kernel = any(steps.values())
         ds = self.decode_set
         kv_bytes = {
             "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
@@ -2043,10 +2048,10 @@ class ServingEngine:
                     elif s.sent > s.step:
                         src[i] = i
             rows = self.table.rows(active, prev, src)
-            self._count_latent_walk(
-                "mla_paged_verify" if self.spec_enabled else "mla_paged_decode",
-                rows[1], self.spec_k + 1 if self.spec_enabled else 1,
+            walk, rect = self._count_walk(
+                rows[1], self.spec_k + 1 if self.spec_enabled else None, rows[2][:, 0] != SCRATCH_PAGE
             )
+            sp.set(walk_steps=walk, rect_steps=rect)
             flight = _Flight(None, [(i, self.slots[i]) for i in active], t0)
             # the AOT executable takes the numpy slot tables directly — a
             # jnp.asarray wrapper here would dispatch extra device ops
@@ -2667,18 +2672,39 @@ class ServingEngine:
         t = min(self.chunk_width, slot.request.prompt_len - slot.prefill_pos)
         return t, t * slot.prefill_pos + t * (t + 1) // 2
 
-    def _count_latent_walk(self, kernel: str, base, T: int = 1) -> None:
-        """One call of the latent attention kernel ``kernel`` (its name in a
-        trace) whose slots' ``T`` queries start at ``base``: the steps its
-        walk takes and the steps of its rectangle, one layer's (every cached
-        layer of the call walks the same)."""
-        if not self._latent_kernel:
-            return
-        walk, rect = latent_walk_steps(
-            base, self.family.n_head, self.page_size, T, self.pages_per_slot
-        )
-        self._c_walk_steps.inc(walk, program=kernel)
-        self._c_rect_steps.inc(rect, program=kernel)
+    def _count_walk(self, base, T: Optional[int] = None, live=None, chunk: bool = False) -> tuple:
+        """One call of a program's paged attention kernel whose slots' queries
+        start at ``base`` (``T`` of them a slot; None: the decode step's one;
+        ``chunk``: the chunk kernel's), the slots that are not ``live``
+        idle: → (the items the call owns, the steps of its rectangle), one
+        paged layer's (every such layer of the call walks the same; a window
+        layer's ring is not counted), by the rule the kernel's wrapper walks
+        by. Where the programs call the kernel, both are added to the
+        counters under its name in a trace."""
+        if self.latent:
+            kernel = "mla_paged_" + ("chunk" if chunk else "decode" if T is None else "verify")
+            walk, rect = latent_walk_steps(
+                base, self.family.n_head, self.page_size, T or 1, self.pages_per_slot
+            )
+        else:
+            kernel = "chunk_fn" if chunk else "decode_fn" if T is None else "verify_fn"
+            pset = self.decode_set
+            shape = (
+                pset.local_kv_heads(), pset.page_size, pset.head_dim,
+                pset.k_pool.dtype.itemsize, self.pages_per_slot,
+            )
+            if chunk:
+                rep = self.family.n_head // self.family.n_kv_head
+                walk, rect = paged_walk_steps(base, live, *shape, T, rep)
+            else:
+                # the verify step attends its T queries as T one-token calls
+                walk, rect = map(sum, zip(*(
+                    paged_walk_steps(np.asarray(base) + t, live, *shape) for t in range(T or 1)
+                )))
+        if self._attn_kernel:
+            self._c_walk_steps.inc(walk, program=kernel)
+            self._c_rect_steps.inc(rect, program=kernel)
+        return walk, rect
 
     def _chunk_is_last(self, slot_i: int) -> bool:
         """Whether the next chunk of a PREFILLING slot is its prompt's last."""
@@ -2741,9 +2767,7 @@ class ServingEngine:
             n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
             finals = int(rider is not None and self._chunk_is_last(rider))
             for i in alone + ([rider] if rider is not None else []):
-                self._count_latent_walk(
-                    "mla_paged_chunk", [self.slots[i].prefill_pos], self.chunk_width
-                )
+                self._count_walk([self.slots[i].prefill_pos], self.chunk_width, chunk=True)
             unwaited = []
             for i in alone:
                 slot = self.slots[i]

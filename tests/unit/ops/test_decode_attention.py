@@ -567,20 +567,25 @@ class TestPagedDecodeServedShape:
 
         kp, vp, bt, pos = self._pool(pos, KV, D, page, n, dtype, seed)
         # an idle slot sits on the scratch page with pos 0, as the scheduler
-        # leaves it; what it computes is never read
+        # leaves it: its row walks nothing (the pages it held are poisoned
+        # with the scratch page) and comes out zeros
+        live = np.array([b not in idle for b in range(len(pos))])
+        gone = np.unique(np.asarray(bt)[~live])
+        poison = lambda pool: self._poison(pool).at[gone].set(jnp.nan)  # noqa: E731
         for b in idle:
             bt = bt.at[b].set(0)
         rs = np.random.RandomState(seed + 1)
         q = jnp.asarray(rs.randn(len(pos), H, D), dtype)
         out = paged_decode_attention(
-            q, self._poison(kp), self._poison(vp), bt, pos, interpret=True
+            q, poison(kp), poison(vp), bt, pos, interpret=True,
+            live=jnp.asarray(live) if idle else None,
         )
         ref = paged_cached_attention(q, kp, vp, bt, pos, impl="jnp")
-        live = np.array([b not in idle for b in range(len(pos))])
         np.testing.assert_allclose(
             np.asarray(out, np.float32)[live], np.asarray(ref, np.float32)[live],
             atol=tol, rtol=tol,
         )
+        assert not np.asarray(out, np.float32)[~live].any()
 
     def _gp(self, itemsize=4, KV=25, D=64, page=16, n=64):
         from deepspeed_tpu.ops.pallas.decode_attention import (
@@ -648,48 +653,6 @@ class TestPagedDecodeServedShape:
                     D=128, page=128, n=48, dtype=dtype,
                     tol=2e-5 if dtype.itemsize == 4 else 2e-2, seed=11)
 
-    @pytest.mark.parametrize("KV,rep,D,page,n,G", [
-        (2, 4, 128, 128, 48, 16),  # ZAYA's served shape
-        (25, 1, 64, 16, 64, 8),    # GPT-2-XL's
-        (8, 8, 128, 16, 224, 32),  # K-EXAONE's full layer
-    ], ids=["zaya", "xl", "kexaone"])
-    def test_a_slots_first_block_is_fetched_ahead(self, monkeypatch, KV, rep, D, page, n, G):
-        """The blocks a slot skips name the NEXT slot's first block (so that
-        it is fetched behind arithmetic): an own block's entries are
-        ``_walked_table``'s, the last slot's skipped blocks keep what they
-        named, and the numbers are to the bit those of the plain table, which
-        no step computes from."""
-        from deepspeed_tpu.ops.pallas import decode_attention as da
-
-        assert da.paged_decode_blocks(KV, page, D, 2, n) == (KV, G)
-        GP, n_blk = G * page, n // G
-        pos = [0, GP // 4 - 1, GP - 1, GP, n * page - 1, 2 * GP + 4, page + 2, GP - page]
-        kp, vp, bt, posj = self._pool(pos, KV, D, page, n, jnp.bfloat16, 12)
-        last = (posj // page)[:, None]
-        plain = np.asarray(da._walked_table(bt, last, n_blk, G))
-        ahead = np.asarray(da._first_blocks_ahead(jnp.asarray(plain), last, G))
-        for b, p in enumerate(pos):
-            own = p // GP + 1  # the slot's own blocks
-            np.testing.assert_array_equal(ahead[b, :own * G], plain[b, :own * G])
-            nxt = plain[b + 1, :G] if b + 1 < len(pos) else plain[b, -G:]
-            for blk in range(own, n_blk):
-                np.testing.assert_array_equal(ahead[b, blk * G:(blk + 1) * G], nxt)
-        assert (ahead != plain).any()
-
-        q = jnp.asarray(np.random.RandomState(13).randn(8, rep * KV, D), jnp.bfloat16)
-        kp, vp = self._poison(kp), self._poison(vp)
-        calls = []
-        real = da._first_blocks_ahead
-        monkeypatch.setattr(
-            da, "_first_blocks_ahead", lambda *a: calls.append(1) or real(*a)
-        )
-        got = da.paged_decode_attention(q, kp, vp, bt, posj, interpret=True)
-        monkeypatch.setattr(
-            da, "_first_blocks_ahead", lambda walk, *a: calls.append(0) or walk
-        )
-        want = da.paged_decode_attention(q, kp, vp, bt, posj, interpret=True)
-        assert calls == [1, 0] and bool(jnp.all(got == want))
-
     @pytest.mark.parametrize("rep", [1, 2])
     def test_int8_pool_with_scales(self, rep):
         from deepspeed_tpu.ops.attention import paged_cached_attention
@@ -719,6 +682,172 @@ class TestPagedDecodeServedShape:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
         )
+
+
+class TestPagedWalk:
+    """ISSUE 58: the grid of the per-head paged kernels is the call's own
+    ITEMS: a live grid row (a slot's head block) owns page blocks 0 .. the one
+    its tokens reach, an idle one ONE item that names what the inputs hold
+    already and writes zeros."""
+
+    @staticmethod
+    def _by_hand(bt, pos, idle, G, page, nhb, T=1):
+        """(row, block or None, pages) of every item, written out."""
+        n_pages = bt.shape[1]
+        n_blk = -(-n_pages // G)
+        items = []
+        for b, p in enumerate(pos):
+            for hb in range(nhb):
+                if b in idle:
+                    items.append((b * nhb + hb, None, None))
+                    continue
+                last = min((p + T - 1) // page, n_pages - 1)
+                for j in range(min((p + T - 1) // (G * page), n_blk - 1) + 1):
+                    e = [j * G + g for g in range(G)]
+                    e = [min(max(x - G if x > last else x, 0), last) for x in e]
+                    items.append((b * nhb + hb, j, [int(bt[b, x]) for x in e]))
+        return items
+
+    @staticmethod
+    def _fetches(pages):
+        """Page DMAs a pipeline issues over the items: the first item's, then
+        an input's whenever what it names changes."""
+        pages = np.asarray(pages)
+        return pages.shape[1] + int((pages[1:] != pages[:-1]).sum())
+
+    @pytest.mark.parametrize("KV,D,page,n,itemsize,blocks", [
+        (2, 128, 128, 48, 2, (2, 16)),   # ZAYA's served shape
+        (25, 64, 16, 64, 2, (25, 8)),    # GPT-2-XL's, per head
+        (8, 128, 16, 224, 2, (8, 32)),   # K-EXAONE's full layer
+        (64, 128, 128, 3, 4, (16, 1)),   # four head blocks a slot
+    ], ids=["zaya", "xl", "kexaone", "head-blocks"])
+    def test_the_item_table_by_hand(self, KV, D, page, n, itemsize, blocks):
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        assert da.paged_decode_blocks(KV, page, D, itemsize, n) == blocks
+        HB, G = blocks
+        nhb, GP = KV // HB, G * page
+        pos = [5, GP // 4 - 1, GP - 1, 7, 9, min(2 * GP + 4, n * page - 1), n * page - 1, 3]
+        idle = (0, 3, 4, 7)  # leading, two between live rows, trailing
+        rs = np.random.RandomState(12)
+        bt = np.zeros((8, n), np.int32)
+        for b, p in enumerate(pos):
+            k = p // page + 1
+            bt[b, :k] = rs.randint(1, 10_000, k)
+        live = np.array([b not in idle for b in range(8)])
+        row, home, blk, at, pages, n_items = (
+            np.asarray(x) for x in da._walk_items(
+                jnp.asarray(bt), jnp.asarray(pos, jnp.int32), jnp.asarray(live), 1, G, page, nhb)
+        )
+        want = self._by_hand(bt, pos, idle, G, page, nhb)
+        own = sum(p // GP + 1 for b, p in enumerate(pos) if b not in idle)
+        # the live rows' own blocks and one item an idle row
+        assert n_items[0] == len(want) == nhb * (own + len(idle))
+        assert da.paged_walk_steps(pos, live, KV, page, D, itemsize, n) == (len(want), 8 * nhb * (n // G))
+        pages = pages.reshape(-1, G)[:len(want)]
+        first_live = next(w for w in want if w[1] is not None)
+        before = None
+        for s, (r, j, named) in enumerate(want):
+            assert row[s] == r
+            if j is None:
+                # what the live item before holds, or ahead of every live row the first one's
+                host = before or first_live
+                assert at[s] == -1 and home[s] == host[0] and blk[s] == host[1]
+                np.testing.assert_array_equal(pages[s], host[2])
+            else:
+                assert (at[s], home[s], blk[s]) == (pos[r // nhb], r, j)
+                np.testing.assert_array_equal(pages[s], named)
+                before = (r, j, named)
+        # an idle row's item fetches nothing: the DMAs are the live items' own
+        assert self._fetches(pages) == self._fetches([w[2] for w in want if w[1] is not None])
+        # with no liveness given every row walks its blocks, an empty slot block 0
+        every = da._walk_items(jnp.asarray(bt), jnp.asarray(pos, jnp.int32), None, 1, G, page, nhb)
+        assert int(every[-1][0]) == nhb * sum(p // GP + 1 for p in pos)
+
+    KV, D, PAGE, N = 2, 64, 8, 8
+
+    @pytest.fixture(scope="class")
+    def call(self):
+        """ONE trace of the kernel for every liveness below: 6 slots of 2
+        heads, pages of 8 in a table of 8 (4 pages, 32 keys, a grid step)."""
+        from deepspeed_tpu.ops.pallas.decode_attention import paged_decode_attention
+
+        return jax.jit(lambda q, kp, vp, bt, pos, live: paged_decode_attention(
+            q, kp, vp, bt, pos, interpret=True, live=live))
+
+    @pytest.mark.parametrize("idle", [(0, 1), (4, 5), (1, 2, 4), (0, 1, 2, 3, 4, 5), ()],
+                             ids=["leading", "trailing", "between", "all_idle", "all_live"])
+    def test_idle_rows_are_zeros_and_live_rows_the_same_bits(self, call, idle):
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+
+        pos = [40, 7, 33, 63, 0, 17]
+        shape = TestPagedDecodeServedShape()
+        kp, vp, bt, posj = shape._pool(pos, self.KV, self.D, self.PAGE, self.N, jnp.float32, 21)
+        q = jnp.asarray(np.random.RandomState(22).randn(6, self.KV, self.D), jnp.float32)
+        live = np.array([b not in idle for b in range(6)])
+        # what every row computes walked as a live one (the walk before ISSUE 58)
+        every = call(q, kp, vp, bt, posj, jnp.ones((6,), bool))
+        ref = paged_cached_attention(q, kp, vp, bt, posj, impl="jnp")
+        np.testing.assert_allclose(np.asarray(every), np.asarray(ref), atol=2e-5, rtol=2e-5)
+        # idle slots sit on the scratch page at length 0; the pages they held
+        # and the scratch page are poisoned
+        gone = np.unique(np.asarray(bt)[~live])
+        poison = lambda pool: pool.at[0].set(jnp.nan).at[gone].set(jnp.nan)  # noqa: E731
+        got = call(q, poison(kp), poison(vp), jnp.where(live[:, None], bt, 0),
+                   jnp.where(live, posj, 0), jnp.asarray(live))
+        assert bool(jnp.all(got[live] == every[live]))
+        assert not np.asarray(got)[~live].any()
+
+    def test_head_blocks_int8_scales_and_idle_rows(self, monkeypatch):
+        """Two head blocks a slot over an int8 pool (a VMEM budget of two
+        heads' pages): an idle row's item names the page and the scale block
+        of the item before, the last head block's, and multiplies neither."""
+        from deepspeed_tpu.ops.attention import paged_cached_attention
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        from deepspeed_tpu.ops.pallas import flash_attention as fa
+        from deepspeed_tpu.ops.quantizer import quantize_kv_pages
+
+        KV, D, page, n = 4, 128, 32, 4
+        monkeypatch.setattr(fa, "VMEM_RESIDENT_BYTES", 8 * da._page_tile_bytes(page, D, 1))
+        assert da.paged_decode_blocks(KV, page, D, 1, n) == (2, 1)
+        kf, vf, bt, pos = TestPagedDecodeServedShape()._pool([70, 0, 9, 127], KV, D, page, n, jnp.float32, 23)
+        (kq, ks), (vq, vs) = quantize_kv_pages(kf), quantize_kv_pages(vf)
+        scales = jnp.stack([ks, vs], axis=-1)
+        q = jnp.asarray(np.random.RandomState(24).randn(4, 2 * KV, D), jnp.float32)
+        live = jnp.asarray([True, False, True, True])
+        bt = jnp.where(live[:, None], bt, 0)
+        got = da.paged_decode_attention(
+            q, kq, vq, bt, pos, interpret=True, scales=scales.at[0].set(jnp.nan), live=live)
+        ref = paged_cached_attention(q, kq, vq, bt, pos, impl="jnp", scales=scales, live=live)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+        assert not np.asarray(got)[1].any() and not np.asarray(ref)[1].any()
+
+    @pytest.mark.parametrize("T", [1, 16], ids=["decode", "chunk16"])
+    def test_windowed_rows_and_idle_rows(self, T):
+        """Keys bounded from below (a window's ring view), one token or 16 a
+        slot (more than a sublane tile of rows: the three-branch form)."""
+        from deepspeed_tpu.ops import attention
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+
+        KV, D, page, n = 2, 64, 8, 8
+        kp, vp, bt, pos = TestPagedDecodeServedShape()._pool([45, 12, 30, 20], KV, D, page, n, jnp.float32, 25)
+        live = np.array([True, False, False, True])
+        lo = jnp.asarray([30, 0, 0, 5], jnp.int32)
+        rs = np.random.RandomState(26)
+        poison = lambda pool: pool.at[0].set(jnp.nan)  # noqa: E731
+        bt = jnp.where(live[:, None], bt, 0)
+        if T == 1:
+            q = jnp.asarray(rs.randn(4, KV, D), jnp.float32)
+            got = da.paged_decode_attention(
+                q, poison(kp), poison(vp), bt, pos, interpret=True, lo=lo, live=jnp.asarray(live))
+            ref = attention.paged_cached_attention(q, kp, vp, bt, pos, impl="jnp", lo=lo)
+        else:
+            q = jnp.asarray(rs.randn(4, T, KV, D), jnp.float32)
+            got = da.paged_multitoken_attention(
+                q, poison(kp), poison(vp), bt, pos - (T - 1), interpret=True, lo=lo, live=jnp.asarray(live))
+            ref = attention.paged_multitoken_cached_attention(q, kp, vp, bt, pos - (T - 1), impl="jnp", lo=lo)
+        np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live], atol=2e-5, rtol=2e-5)
+        assert not np.asarray(got)[~live].any()
 
 
 class TestPagedDecodeBlocks:

@@ -134,6 +134,43 @@ def test_paged_decode_kernel_compiles_at_its_widest_step_for_v5e(one_chip):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("B,H,KV,D,page,n,L,blocks,window", [
+    (8, 26, 13, 128, 16, 64, 48, (13, 16), False),     # GPT-2-XL's pairs
+    (64, 8, 2, 128, 128, 48, 14, (2, 16), False),      # ZAYA's
+    (64, 40, 10, 128, 128, 48, 1, (10, 2), False),     # Phi-4-mini-flash's pair heads, its one paged layer
+    (64, 64, 8, 128, 16, 224, 3, (8, 32), False),      # K-EXAONE's full layers: 32 page inputs for K, 32 for V
+    (64, 64, 8, 128, 16, 10, 9, (8, 8), True),         # ... and its window rings, the keys bounded from below
+], ids=["gpt2-xl-pairs", "zaya", "phi4flash-pairs", "kexaone-32-inputs", "kexaone-ring"])
+def test_the_paged_decode_kernels_walk_of_a_calls_own_items_compiles_for_v5e(one_chip, B, H, KV, D, page, n, L, blocks, window):
+    """ISSUE 58: the one-dimensional grid whose bound is a value of the call
+    (the items the live slots' lengths and the idle slots come to) under the
+    seven or eight scalar-prefetched vectors, as the served programs call it:
+    named, the slots' liveness beside the table, the layer of a deep pool an
+    operand of the shared kernel."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention,
+        paged_decode_blocks,
+    )
+
+    assert paged_decode_blocks(KV, page, D, 2, n) == blocks
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((L, B * n + 1, KV, page, D), jnp.bfloat16)
+    lowered = jax.jit(
+        lambda q, k, v, bt, pos, live, lo: paged_decode_attention(
+            q, k, v, bt, pos, layer=L - 1, name="decode_fn", live=live, lo=lo if window else None
+        )
+    ).lower(
+        sds((B, H, D), jnp.bfloat16), pool, pool, sds((B, n), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.bool_), sds((B,), jnp.int32),
+    )
+    # the grid's bound goes in as the call's first operand, a scalar, ahead of the prefetched vectors
+    assert "operand_layouts = [dense<> : tensor<0xindex>, dense<0> : tensor<1xindex>" in lowered.as_text()
+    assert lowered.compile().as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("layered", [False, True], ids=["pool4d", "pool5d"])
 @pytest.mark.parametrize("T", [128, 5], ids=["chunk128", "verify5"])
 @pytest.mark.parametrize("name", list(SHAPES))

@@ -218,7 +218,7 @@ def test_spans_and_counters_count_latent_rows_and_expert_loads(engine, prompts):
 
 
 def test_the_walk_counters_count_the_pairs_the_calls_own_beside_their_rectangles(engine, prompts, monkeypatch):
-    """``serving_latent_walk_steps_total`` against ``serving_latent_rect_steps_total``,
+    """``serving_attn_walk_steps_total`` against ``serving_attn_rect_steps_total``,
     by the kernel's name: host arithmetic on the calls' lengths by the kernel's
     own block rule. (Off the TPU the fallback attends; the test tells the
     gauge's rule that the kernel is taken, which is all the counters ask.)"""
@@ -227,8 +227,8 @@ def test_the_walk_counters_count_the_pairs_the_calls_own_beside_their_rectangles
     real = attention.latent_attention_grid_steps
     monkeypatch.setattr(attention, "latent_attention_grid_steps", lambda impl, *a, **k: real("pallas", *a, **k))
     srv, reqs = _serve(engine, prompts[:4])
-    walk = lambda k: srv.metrics.counter("serving_latent_walk_steps_total", "", ("program",)).value(program=k)
-    rect = lambda k: srv.metrics.counter("serving_latent_rect_steps_total", "", ("program",)).value(program=k)
+    walk = lambda k: srv.metrics.counter("serving_attn_walk_steps_total", "", ("program",)).value(program=k)
+    rect = lambda k: srv.metrics.counter("serving_attn_rect_steps_total", "", ("program",)).value(program=k)
     page, H, n = SERVING["page_size"], CFG["num_attention_heads"], srv.pages_per_slot
     assert n == 13
     # 4 heads on pages of 4 in a table of 13: both shapes hold 8 pages, 32 keys, a step; 2 blocks a table
@@ -250,7 +250,7 @@ def test_the_walk_counters_count_the_pairs_the_calls_own_beside_their_rectangles
     # ... and nothing is counted where the programs hold no latent kernel
     monkeypatch.undo()
     srv, _ = _serve(engine, prompts[:2])
-    assert srv.metrics.counter("serving_latent_rect_steps_total", "", ("program",)).value(program="mla_paged_decode") == 0
+    assert srv.metrics.counter("serving_attn_rect_steps_total", "", ("program",)).value(program="mla_paged_decode") == 0
 
 
 def test_the_verify_step_emits_the_decode_steps_stream(engine, served, prompts):

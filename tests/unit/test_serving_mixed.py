@@ -381,6 +381,47 @@ def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
         assert not any("moe_calls" in c for c in chunks)
 
 
+@pytest.mark.parametrize("family,kernels", [("gpt2", ("decode_fn", "chunk_fn")), ("exaone_moe", ("decode_fn", "chunk_fn")),
+                                            ("mistral4", ("mla_paged_decode", "mla_paged_chunk"))])
+def test_the_dispatch_leaf_and_the_counters_carry_the_attention_kernels_walk(engines, family, kernels, monkeypatch):
+    """ISSUE 58: the items a step's one-token kernel call owns beside its rectangle, on the
+    ``ds.serve.decode.dispatch`` leaf whichever implementation attends, and in the registry by the kernel's
+    name in a trace where the programs call the kernel (off the TPU the test tells the census so). Host
+    arithmetic by the wrapper's rule: a live slot owns the page blocks its length reaches, an idle one ONE item."""
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.decode_attention import paged_decode_blocks
+
+    engine, vocab = engines(family)
+    fam = engine.model_config.serving_family()
+    prompts = _prompts(vocab)[:4]
+    steps = "latent_attention_grid_steps" if fam.kv_pools == 1 else "paged_attention_grid_steps"
+    real = getattr(attention, steps)
+    monkeypatch.setattr(attention, steps, lambda impl, *a, **k: real("pallas", *a, **k))
+    t0 = spans._clock()
+    srv, reqs = _together(engine, prompts)
+    walk = lambda k: srv.metrics.counter("serving_attn_walk_steps_total", "", ("program",)).value(program=k)  # noqa: E731
+    rect = lambda k: srv.metrics.counter("serving_attn_rect_steps_total", "", ("program",)).value(program=k)  # noqa: E731
+    disp = [r[3] for r in spans.snapshot(since=t0) if r[0] == "ds.serve.decode.dispatch"]
+    # 13 pages of 4 a slot, 8 of them (32 keys) a grid step in every family here: 2 blocks a slot, 3 slots
+    assert srv.pages_per_slot == 13
+    if fam.kv_pools == 2:
+        assert paged_decode_blocks(fam.n_kv_head, 4, fam.head_dim, 4, 13) == (fam.n_kv_head, 8)
+    assert all(d["rect_steps"] == 3 * 2 for d in disp)
+    # a step of `active` rows at lengths that sum to `attended - active`: each owns 1 + length // 32, the rest one item
+    assert all(3 <= d["walk_steps"] <= 3 + d["active"] for d in disp)
+    lens = [range(len(p), len(p) + 11) for p in prompts]      # a request's first token is its prefill's
+    assert sum(d["walk_steps"] for d in disp) == sum(3 - d["active"] for d in disp) + sum(1 + n // 32 for r in lens for n in r)
+    assert walk(kernels[0]) == sum(d["walk_steps"] for d in disp) and rect(kernels[0]) == 6 * len(disp)
+    starts = [s for p in prompts if len(p) > 8 for s in range(0, len(p), 8)]
+    assert walk(kernels[1]) == sum(min((s + 7) // 32, 1) + 1 for s in starts) and rect(kernels[1]) == 2 * len(starts)
+    # nothing is counted where the programs hold no kernel; the leaf still says what the call owns
+    monkeypatch.undo()
+    t0 = spans._clock()
+    srv, _ = _together(engine, prompts[:2])
+    assert srv.metrics.counter("serving_attn_rect_steps_total", "", ("program",)).value(program=kernels[0]) == 0
+    assert all(d["rect_steps"] == 6 for d in (r[3] for r in spans.snapshot(since=t0) if r[0] == "ds.serve.decode.dispatch"))
+
+
 # -- what must not ride -------------------------------------------------------------
 
 @pytest.mark.parametrize("over,n_exe", [

@@ -186,7 +186,7 @@ def _attr_sets(recs, names):
 # that makes ONE call of a program carries LAUNCH, the leaves that read a step carry ``flight``, the emit that hands
 # out a first token left on the device ``firsts``, a synchronous wait for one ``launch``
 LAUNCH = {"launch", "kind", "rows", "tokens"}
-DISPATCH = {"active", "ahead", "attended", "pages"}
+DISPATCH = {"active", "ahead", "attended", "pages", "walk_steps", "rect_steps"}   # the attention kernel's walk (ISSUE 58)
 LEAF_ATTRS = {"ds.serve.admit": [{"admitted", "blocked"}], "ds.serve.chunk": [{"chunks", "rode", "tokens", "attended"}],
               "ds.serve.decode.dispatch": [DISPATCH | LAUNCH, DISPATCH],      # a plain step; a chunk rides
               "ds.serve.launch": [LAUNCH], "ds.serve.decode.wait": [{"flight"}],
