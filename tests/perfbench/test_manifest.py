@@ -1,9 +1,14 @@
 """BENCHMARK.json as committed is inside the contract's limits, the validator
 refuses what the contract refuses, and a cell, a configuration (of any
 architecture), a runner, a traffic mix and a metric with a new reader are
-added without editing a file that is there."""
+added without editing a file that is there. For the per-layer table that last
+sentence is tested by the tests themselves since PR 59: ``real`` is
+``conftest.py``'s ``table``, the committed benchmark and then the committed
+benchmark with a stand-in cell appended (``tiny.with_stand_in``: twelve entries
+of its own, a place in every shared list), and
+``test_the_stand_in_is_appended_and_edits_no_file_that_is_there`` holds the
+overlay itself to the rule."""
 
-import copy
 import json
 import os
 import shutil
@@ -18,8 +23,8 @@ REPO = tiny.REPO
 
 
 @pytest.fixture(scope="module")
-def real():
-    return Manifest(REPO)
+def real(table):
+    return table
 
 
 # the cells in the order they were proved on the chip (PRs 23 and 26); a later PR's come after them
@@ -74,13 +79,6 @@ def test_every_cell_reports_setup_another_end_to_end_metric_and_a_layer_metric(r
             assert m["moves"] in e2e
 
 
-def _broken(real, edit):
-    m = copy.copy(real)
-    m.doc = copy.deepcopy(real.doc)
-    edit(m.doc)
-    return m
-
-
 BREAKS = {
     "space_in_name": lambda d: d["workloads"][0].update(name="train xl"),
     "slash_in_name": lambda d: d["per_layer"][0].update(name="a/b"),
@@ -107,13 +105,15 @@ BREAKS = {
     "tab_in_why": lambda d: d["workloads"][0].update(why="a\tb"),
     "unknown_workload_on_metric": lambda d: d["per_layer"][0].update(workloads=["ghost"]),
     "chips_two": lambda d: d["workloads"][0].update(chips=2),
+    "129_per_layer_entries": lambda d: d["per_layer"].extend(dict(d["per_layer"][0], name=f"pad-{i}")
+                                                             for i in range(129 - len(d["per_layer"]))),
 }
 
 
 @pytest.mark.parametrize("case", sorted(BREAKS))
 def test_validator_refuses(real, case):
     with pytest.raises(ManifestError):
-        _broken(real, BREAKS[case]).validate(check_files=False)
+        tiny.broken(real, BREAKS[case]).validate(check_files=False)
 
 
 # `reduced` takes depth and counts of things, in any architecture's spelling, and no width
@@ -125,13 +125,13 @@ WIDTHS = ["hidden_size", "intermediate_size", "moe_intermediate_size", "head_dim
 
 @pytest.mark.parametrize("key", DEPTHS)
 def test_reduced_may_name_a_depth_or_a_count(real, key):
-    _broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
+    tiny.broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
 
 
 @pytest.mark.parametrize("key", WIDTHS)
 def test_reduced_may_not_name_a_width(real, key):
     with pytest.raises(ManifestError, match="a width"):
-        _broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
+        tiny.broken(real, lambda d: d["configs"][0].update(reduced=[key])).validate(check_files=False)
 
 
 def _resize(d, n):
@@ -164,11 +164,11 @@ def test_a_table_of_eight_cells_takes_two_on_four_chips_and_not_three(real, star
         for i, w in enumerate(d["workloads"]):
             w.update(chips=4 if i < 2 else 1)
 
-    eight = _broken(real, eight_with_two_on_four)
+    eight = tiny.broken(real, eight_with_two_on_four)
     assert len(eight.doc["workloads"]) == 8 and sum(w["chips"] == 4 for w in eight.doc["workloads"]) == 2
     eight.validate(check_files=False)
     with pytest.raises(ManifestError, match="four-chip"):
-        _broken(eight, BREAKS["two_four_chip_cells"]).validate(check_files=False)
+        tiny.broken(eight, BREAKS["two_four_chip_cells"]).validate(check_files=False)
 
 
 def _files(root):
@@ -190,8 +190,37 @@ def _is_grown(old, new):
     return old == new
 
 
+def test_the_stand_in_is_appended_and_edits_no_file_that_is_there(real):
+    """The overlay that every table-reading test runs against is itself what it says: the committed files byte for
+    byte, BENCHMARK.json with entries appended to its lists, new files beside them; one cell, twelve entries of its
+    own, and a place in every list that two or more backlog cells share."""
+    if real.root == REPO:
+        assert tiny.STAND_IN_CELL not in [w["name"] for w in real.doc["workloads"]]     # nothing of it is committed
+        return
+    tree = Manifest(REPO)
+    assert _is_grown(tree.doc, real.doc)
+    before = {rel: data for path in tree.doc["paths"] for rel, data in _files(os.path.join(REPO, path)).items()
+              if not rel.startswith("data" + os.sep)}
+    after = {rel: data for path in real.doc["paths"] for rel, data in _files(os.path.join(real.root, path)).items()}
+    assert before.items() <= after.items(), sorted(rel for rel in before if after.get(rel) != before[rel])
+    assert len(after) == len(before) + 1 + len(tiny.STAND_IN_OWN)       # its configuration file and a spec file an entry
+    assert [w["name"] for w in real.doc["workloads"]][len(tree.doc["workloads"]):] == [tiny.STAND_IN_CELL]
+    own = [e["name"] for e in real.doc["per_layer"] if e.get("workloads") == [tiny.STAND_IN_CELL]]
+    assert own == [row[0] for row in tiny.STAND_IN_OWN] and len(own) == 12
+    shared = tiny.shared_lists(tree.doc)
+    assert len(shared) >= 20
+    for e in shared:
+        now = next(x for x in real.doc["end_to_end"] + real.doc["per_layer"] if x["name"] == e["name"])
+        assert now["workloads"] == e["workloads"] + [tiny.STAND_IN_CELL], e["name"]
+    # the tiny copy builds from it too, and is the tree's: the stand-in's pair of stand-ins is taken (tiny.make), so
+    # the tests that RUN a tiny cell have nothing to run twice
+    small = tiny.make(os.path.join(real.root, os.pardir, "tiny"), repo=real.root)
+    small.validate()
+    assert small.doc == tiny.make(os.path.join(real.root, os.pardir, "tiny_tree")).doc
+
+
 @pytest.mark.parametrize("with_stand_in", [False, True])
-def test_a_new_architecture_goes_in_as_new_files_and_appended_entries(tmp_path, with_stand_in):
+def test_a_new_architecture_goes_in_as_new_files_and_appended_entries(real, tmp_path, with_stand_in):
     """What the next ``model_config`` PR does, on a copy of the *committed*
     benchmark: a configuration of another ``model_type`` with Hugging Face's
     keys and its depth cut, a runner of its own, a cell, one per-layer metric.
@@ -201,8 +230,8 @@ def test_a_new_architecture_goes_in_as_new_files_and_appended_entries(tmp_path, 
     differs."""
     root = str(tmp_path / "committed")
     for sub in ("perfbench", os.path.join("tests", "perfbench")):
-        shutil.copytree(os.path.join(REPO, sub), os.path.join(root, sub), ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+        shutil.copytree(os.path.join(real.root, sub), os.path.join(root, sub), ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(real.root, "BENCHMARK.json"), root)
     before = _files(root)
 
     def write(rel, text):

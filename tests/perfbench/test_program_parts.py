@@ -159,9 +159,8 @@ def test_nothing_without_a_trace_a_busy_device_or_the_parts_module(monkeypatch):
     assert pp.by_part(ctx) is None
 
 
-def test_the_manifest_holds_the_23_metrics_and_validates():
-    m = Manifest(REPO)
-    m.validate()
+def test_the_manifest_holds_the_23_metrics_and_validates(table):
+    m = table
     names = [e["name"] for e in m.doc["per_layer"]]
     first = names.index(PR_36[0])       # found by name: a later PR appends behind them, or before
     assert names[first:first + len(PR_36)] == PR_36                       # where they were appended, nothing moved
@@ -229,7 +228,11 @@ def test_a_tiny_cell_reports_every_new_metric_and_its_parts_partition_the_busy_t
     ctx.trace = SimpleNamespace(busy_s=busy)
     ctx.extra["program_parts.by_part"] = seconds
     mine = [m["name"] for m in manifest.metrics_for(cell, "per_layer") if m["name"].startswith(NEW)]
-    assert len(mine) == (8 if cell.startswith("train") else 3)
+    kept = {"train-xl-l16-1chip": {f"part_{p}_share.train" for p in ("mlp", "attn_proj", "attn_core", "head", "optim", "unattributed")}
+            | {"recompute_share.train", "dense_matmul_share.train"},
+            "serve-xl-chat-open": {f"part_{p}_share.chat" for p in ("weights", "head", "unattributed")},
+            "serve-xl-doc-batch": {"part_weights_share.doc", "part_head_share.doc", "part_unattributed_share.backlog"}}[cell]
+    assert kept <= set(mine)        # at least these, by name: a later PR may bring the cell a part share more
     got = {}
     for name in mine:
         spec = manifest.metric_spec(name)

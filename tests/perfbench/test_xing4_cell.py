@@ -26,9 +26,9 @@ REPO = tiny.REPO
 X4 = ("part_hc_share", "hc_mix_roofline")       # the cell's own entries, in the order PR 57 appended them
 MS4 = ("mla_decode_roofline", "mla_chunk_roofline", "moe_weight_stream_roofline", "moe_load_max_over_mean")
 SHARED = ("gen_tok_s", "copy_layout_share", "srv_step_host_p50_s", "decode_slots_active", "dispatched_ahead_share",
-          "idle_outside_spans_share", "decode_step_p50_s", "part_unattributed_share", "part_attn_share",
+          "idle_outside_spans_share", "part_unattributed_share", "part_attn_share",
           "part_moe_route_share", "moe_layer_share", "moe_streamed_per_hit", "mla_attention_share", "plain_step_p50_s",
-          "mixed_step_p50_s")
+          "mixed_step_p50_s", "chunk_step_p50_s", "paged_walk_share")
 JOINED = {n + ".backlog" for n in SHARED} | {n + ".ms4" for n in MS4}
 
 
@@ -52,11 +52,9 @@ def runner(manifest):
     return r
 
 
-def test_the_manifest_validates_with_the_cell_in_every_list_it_joined():
-    m = Manifest(REPO)
-    m.validate()
+def test_the_manifest_validates_with_the_cell_in_every_list_it_joined(table):
+    m = table
     d = m.doc
-    assert len(d["workloads"]) >= 12 and len(d["per_layer"]) >= 104
     cell = m.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "gen-backlog-s64", 1)
     assert "40 four-stream mixings" in cell["why"] and "20 of 40 layers" in cell["why"] and "8x its share" in cell["why"]
@@ -66,14 +64,7 @@ def test_the_manifest_validates_with_the_cell_in_every_list_it_joined():
     assert entry["source"] == "https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.json"
     assert sum(w["chips"] == 4 for w in d["workloads"]) <= max(1, len(d["workloads"]) // 4)
     by_name = {x["name"]: x for x in d["per_layer"]}
-    for name in JOINED | {n + ".x4" for n in X4}:
-        assert CELL in by_name[name]["workloads"], name
-    mine = [x["name"] for x in d["per_layer"] if x.get("workloads", [None])[0] == CELL]
-    assert mine[:2] == [n + ".x4" for n in X4]         # a later cell of the same reading is listed behind this one
-    listed = [x for x in m.metrics_for(CELL, "per_layer") if x["moves"] != "setup_s"]
-    assert JOINED | {n + ".x4" for n in X4} <= {x["name"] for x in listed} and {x["moves"] for x in listed} == {"serve_tok_s"}
-    assert {"serve_tok_s", "setup_s"} <= {x["name"] for x in m.metrics_for(CELL, "end_to_end")}
-    assert CELL in next(x["workloads"] for x in d["end_to_end"] if x["name"] == "serve_tok_s")
+    tiny.check_cell_keeps(m, CELL, [n + ".x4" for n in X4], JOINED)      # at least these, by name
     shares = [x for x in d["per_layer"] if "roofline" in x["name"] and CELL in x.get("workloads", ())]
     assert all(x["unit"] == "%" and x["source"] == "device_trace" for x in shares) and len(shares) >= 4
     assert m.metric_spec("part_hc_share.x4")["args"] == {"parts": ["hc.mix"], "of": "busy"}

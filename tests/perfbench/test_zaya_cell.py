@@ -23,9 +23,11 @@ CELL = "serve-zaya1-reason-backlog"
 CONFIG = "zaya1-8b-l14-serve-1chip"
 SEED = 2**31 + 149
 REPO = tiny.REPO
-# the cell's own entries (`.zaya`), in the order PR 49 appended them, and the `.backlog` entries it is listed in
+# the cell's own entries (`.zaya`), in the order PR 49 appended them, and the `.backlog` entries it is listed in: what
+# the cell must KEEP, found by name (a later PR may list it in an entry more; PR 59 listed it in five, and its steps are
+# timed by kind since: `plain_step_p50_s` 14.09 ms, where the blend had read 14.10, and `mixed_step_p50_s` 15.98)
 ZAYA = ("part_cca_share", "part_head_share", "moe_weight_stream_roofline", "paged_decode_roofline", "moe_load_max_over_mean")
-SHARED = ("decode_step_p50_s", "decode_slots_active", "idle_outside_spans_share", "copy_layout_share", "srv_step_host_p50_s",
+SHARED = ("plain_step_p50_s", "mixed_step_p50_s", "dispatched_ahead_share", "paged_walk_share", "decode_slots_active", "idle_outside_spans_share", "copy_layout_share", "srv_step_host_p50_s",
           "gen_tok_s", "part_unattributed_share", "part_attn_share", "part_moe_route_share", "moe_streamed_per_hit",
           "moe_layer_share")
 MINE = {n + ".zaya" for n in ZAYA} | {n + ".backlog" for n in SHARED}
@@ -65,27 +67,18 @@ def runner(manifest):
     return r
 
 
-def test_the_manifest_validates_with_the_cell_its_entries_last_and_the_lists_it_joined():
-    m = Manifest(REPO)
-    m.validate()
+def test_the_manifest_validates_with_the_cell_its_entries_last_and_the_lists_it_joined(table):
+    m = table
     d = m.doc
-    assert len(d["workloads"]) >= 10 and len(d["per_layer"]) <= 104
     cell = m.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "reason-backlog-s64", 1)
     assert "14 of 40 layers" in cell["why"]           # fewer layers than a deployment: the host's share is larger
     assert m.config_entry(CONFIG)["file"] == f"perfbench/configs/{CONFIG}.json"
     assert sum(w["chips"] == 4 for w in d["workloads"]) <= max(1, len(d["workloads"]) // 4)
-    mine = [x for x in d["per_layer"] if x.get("workloads", [None])[0] == CELL]     # a later cell of the same reading is listed behind it
-    assert [x["name"] for x in mine] == [n + ".zaya" for n in ZAYA] and len(mine) <= 7
-    listed = [x for x in m.metrics_for(CELL, "per_layer") if x["moves"] != "setup_s"]
-    assert {x["name"] for x in listed} == MINE and {x["moves"] for x in listed} == {"serve_tok_s"}
-    assert {x["name"] for x in m.metrics_for(CELL, "per_layer") if x["moves"] == "setup_s"} == \
-        {"setup_compile_s", "setup_trace_lower_s", "setup_params_s"}
-    assert {x["name"] for x in m.metrics_for(CELL, "end_to_end")} == {"serve_tok_s", "setup_s"}
-    assert CELL in next(x["workloads"] for x in d["end_to_end"] if x["name"] == "serve_tok_s")      # not "the last": the next cell joins behind
-    shares = [x for x in d["per_layer"] if "roofline" in x["name"] and CELL in x.get("workloads", ())]
-    assert [x["name"] for x in shares] == ["moe_weight_stream_roofline.zaya", "paged_decode_roofline.zaya"]
-    assert all(x["unit"] == "%" and x["source"] == "device_trace" for x in shares)
+    tiny.check_cell_keeps(m, CELL, [n + ".zaya" for n in ZAYA], MINE)
+    shares = {x["name"]: x for x in d["per_layer"] if "roofline" in x["name"] and CELL in x.get("workloads", ())}
+    assert {"moe_weight_stream_roofline.zaya", "paged_decode_roofline.zaya"} <= set(shares)       # at least these
+    assert all(x["unit"] == "%" and x["source"] == "device_trace" for x in shares.values())
     assert m.metric_spec("part_cca_share.zaya")["args"]["parts"] == ["attn.cca"]
     assert m.metric_spec("part_head_share.zaya")["args"]["parts"] == ["head"]       # the 1.07 GB head alone, not the sampler
     # the shared expert-kernel pattern finds this program's kernel by the name it has in every expert family
@@ -106,7 +99,7 @@ def test_traced_stand_in_run_is_correct_and_prints_every_metric_that_needs_no_de
     assert MINE <= set(listed)
     host = {n for n, m in listed.items() if m["source"] != "device_trace"}
     assert {"gen_tok_s.backlog", "decode_slots_active.backlog", "srv_step_host_p50_s.backlog", "moe_streamed_per_hit.backlog",
-            "moe_load_max_over_mean.zaya", "setup_compile_s", "setup_trace_lower_s", "setup_params_s"} == host
+            "dispatched_ahead_share.backlog", "paged_walk_share.backlog", "moe_load_max_over_mean.zaya"} | tiny.SETUP <= host
     assert set(out["metrics"]) == host       # no device plane on the CPU: the device readers found nothing, and said so
     assert out["metrics"]["moe_load_max_over_mean.zaya"]["value"] >= 1.0
     assert out["metrics"]["moe_streamed_per_hit.backlog"]["value"] >= 1.0          # the masked form streams every held expert

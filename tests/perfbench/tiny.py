@@ -9,10 +9,16 @@ theirs below; a PR that brings a runner brings
 ``tests/perfbench/stand_ins/<runner>.json`` (``{"config": {...}, "traffic":
 {"<loop>": {...}}}``) as a new file. A cell with no stand-in, or whose stand-in
 pair another cell already took, is left out of the copy and of every metric's
-``workloads`` list."""
+``workloads`` list.
+
+``with_stand_in(tmp)`` is the other copy: the committed benchmark at its real
+size with ONE MORE backlog cell appended, twelve per-layer entries of its own
+and a place in every shared list, as the next PR that adds a cell would leave
+it. ``conftest.py``'s ``table`` fixture hands the tests both."""
 
 from __future__ import annotations
 
+import copy
 import glob
 import json
 import os
@@ -123,3 +129,111 @@ def make(tmp: str, repo: str = REPO):
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bm, f)
     return Manifest(root)
+
+
+def broken(m, edit):
+    """A manifest whose tables are ``m``'s with ``edit`` applied to a copy: ``m`` itself (a fixture many tests share)
+    is left as it was."""
+    out = copy.copy(m)
+    out.doc = copy.deepcopy(m.doc)
+    edit(out.doc)
+    return out
+
+
+SETUP = {"setup_compile_s", "setup_trace_lower_s", "setup_params_s"}      # every cell's: they move setup_s
+
+
+def check_cell_keeps(m, cell: str, own, joined, moves: str = "serve_tok_s") -> None:
+    """What a cell's test holds of the committed table: what the cell must KEEP, found by name, never what it may
+    ever list. ``own`` are the entries the cell brought, still in the order they were appended and with the cell first
+    in each (a later cell of the same reading is listed behind it); ``joined`` are the shared entries it is listed in.
+    A later PR may list the cell in an entry more, or bring it another of its own."""
+    own, names = list(own), [e["name"] for e in m.doc["per_layer"]]
+    by_name = {e["name"]: e for e in m.doc["per_layer"]}
+    assert [n for n in names if n in set(own)] == own
+    for n in own:
+        assert by_name[n]["workloads"][0] == cell, n
+    listed = [e for e in m.metrics_for(cell, "per_layer") if e["moves"] != "setup_s"]
+    missing = (set(own) | set(joined)) - {e["name"] for e in listed}
+    assert not missing, f"{cell} is no longer listed in {sorted(missing)}"
+    assert {e["moves"] for e in listed} == {moves}
+    assert SETUP <= {e["name"] for e in m.metrics_for(cell, "per_layer") if e["moves"] == "setup_s"}
+    assert {moves, "setup_s"} <= {e["name"] for e in m.metrics_for(cell, "end_to_end")}
+    assert cell in next(e["workloads"] for e in m.doc["end_to_end"] if e["name"] == moves)
+
+
+# -- the stand-in cell: what the next PR that adds a cell does to the table ---------------------------------
+STAND_IN_CELL = "serve-standin-reason-backlog"
+STAND_IN_CONFIG = "standin-reason-serve-1chip"
+STAND_IN_COPIES = "serve-qwen3next-reason-backlog"     # its configuration file is a copy of this cell's, its mix this cell's
+# twelve entries of its own: (name, unit, better, source, the entry whose layer it takes, moves, reader, arguments).
+# Every reader is one that is there; no entry of the tree has these arguments.
+STAND_IN_OWN = [
+    ("part_embed_share.standin", "%", "lower", "device_trace", "part_attn_share.backlog", "serve_tok_s",
+     "part_share", {"parts": ["embed"], "of": "busy"}),
+    ("part_norm_share.standin", "%", "lower", "device_trace", "part_attn_share.backlog", "serve_tok_s",
+     "part_share", {"parts": ["norm"], "of": "busy"}),
+    ("srv_emit_p90_s.standin", "s", "lower", "program_span", "srv_step_host_p50_s.backlog", "serve_tok_s",
+     "span_quantile", {"name": "ds.serve.emit", "q": 0.9}),
+    ("srv_admit_p90_s.standin", "s", "lower", "program_span", "srv_step_host_p50_s.backlog", "serve_tok_s",
+     "span_quantile", {"name": "ds.serve.admit", "q": 0.9}),
+    ("prefill_launch_p50_s.standin", "s", "lower", "device_trace", "part_attn_share.backlog", "serve_tok_s",
+     "launch_time", {"kind": "prefill", "q": 0.5}),
+    ("token_hold_p90_s.standin", "s", "lower", "device_trace", "srv_step_host_p50_s.backlog", "serve_tok_s",
+     "launch_hold", {"of": "token", "q": 0.9}),
+    ("chunk_module_p90_s.standin", "s", "lower", "device_trace", "part_attn_share.backlog", "serve_tok_s",
+     "module_time", {"pattern": "chunk_decode", "q": 0.9}),
+    ("chunks_rode_share.standin", "%", "higher", "program_counter", "decode_slots_active.backlog", "serve_tok_s",
+     "span_attr_ratio", {"name": "ds.serve.chunk", "attr": "rode", "over": "chunks"}),
+    ("emit_finished_share.standin", "%", "lower", "program_counter", "decode_slots_active.backlog", "serve_tok_s",
+     "span_flag_share", {"name": "ds.serve.emit", "attr": "finished"}),
+    ("standin_kernel_share.standin", "%", "lower", "device_trace", "copy_layout_share.backlog", "serve_tok_s",
+     "op_share", {"pattern": "stand_in_kernel", "of": "busy"}),
+    ("queue_wait_p90_s.standin", "s", "lower", "host_clock", "gen_tok_s.backlog", "serve_tok_s",
+     "stamp_quantile", {"field": "queue_wait", "q": 0.9}),
+    ("setup_programs_s.standin", "s", "lower", "program_span", "setup_compile_s", "setup_s",
+     "phase_sum", {"names": ["ds.init.programs"]}),
+]
+
+
+def shared_lists(bm: dict) -> list:
+    """The entries a backlog cell joins at no entry of its own: ``serve_tok_s`` itself and every per-layer entry that
+    moves it and lists two cells or more (a list of one cell is that cell's own reading)."""
+    return [e for e in bm["end_to_end"] if e["name"] == "serve_tok_s"] + \
+        [e for e in bm["per_layer"] if e["moves"] == "serve_tok_s" and len(e.get("workloads", ())) >= 2]
+
+
+def with_stand_in(tmp: str, repo: str = REPO) -> str:
+    """A checkout-shaped copy of ``repo``'s benchmark (``BENCHMARK.json`` and its ``paths``, the recorded traces
+    apart) with the stand-in cell appended: files are written, lists grow, nothing that is there is edited. Returns
+    the copy's root."""
+    root = os.path.join(str(tmp), "with_stand_in")
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        bm = json.load(f)
+    for path in bm["paths"]:
+        shutil.copytree(os.path.join(repo, path), os.path.join(root, path),
+                        ignore=shutil.ignore_patterns("__pycache__", "data"))
+    bench = os.path.join(root, bm["paths"][0])
+
+    def write(rel, doc):
+        p = os.path.join(bench, rel)
+        assert not os.path.exists(p), f"{rel} is there: the stand-in may edit no file"
+        with open(p, "w") as f:
+            json.dump(doc, f)
+
+    copied = next(w for w in bm["workloads"] if w["name"] == STAND_IN_COPIES)
+    entry = next(c for c in bm["configs"] if c["name"] == copied["config"])
+    with open(os.path.join(repo, entry["file"])) as f:
+        write(f"configs/{STAND_IN_CONFIG}.json", json.load(f))
+    bm["configs"].append(dict(entry, name=STAND_IN_CONFIG, file=f"{bm['paths'][0]}/configs/{STAND_IN_CONFIG}.json"))
+    bm["workloads"].append(dict(copied, name=STAND_IN_CELL, config=STAND_IN_CONFIG, why="tests: the next cell"))
+    for e in shared_lists(bm):
+        e["workloads"].append(STAND_IN_CELL)
+    layer = {e["name"]: e["layer"] for e in bm["per_layer"]}
+    for name, unit, better, source, layer_of, moves, reader, args in STAND_IN_OWN:
+        write(f"metrics/{name}.json", {"reader": reader, "args": args})
+        bm["per_layer"].append({"name": name, "unit": unit, "better": better, "source": source, "layer": layer[layer_of],
+                                "moves": moves, "workloads": [STAND_IN_CELL]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+    return root
