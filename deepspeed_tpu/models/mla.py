@@ -1,7 +1,8 @@
 """Multi-head LATENT attention as a serving family's pieces: what
 ``models/mistral4.py`` and ``models/longcat_flash.py`` have in common.
 
-The queries come through a low-rank pair (``wq_a``, RMS norm, ``wq_b``), the
+The queries come through a low-rank pair (``wq_a``, RMS norm, ``wq_b``; or,
+where the config's ``q_lora_rank`` is None, through ONE matrix ``wq``), the
 keys and values of ALL heads from one latent a token, ``[c | kr] = u wkv_a``
 with ``c`` normed (``kv_lora_rank`` wide) and ``kr`` one rotary key every head
 shares (``qk_rope_head_dim`` wide). What is cached is the row ``[c | rot(kr)]``;
@@ -63,7 +64,10 @@ class LatentAttention:
         H, N = cfg.n_head, cfg.qk_nope_head_dim
         with parts.part("norm"):
             u = rms_norm(h, lp["norm_1"], cfg.rms_norm_eps)
-        q = rms_norm(u @ a["wq_a"], a["q_norm"], cfg.rms_norm_eps) @ a["wq_b"]
+        if "wq" in a:   # q_lora_rank None: the query is ONE matrix, no low-rank pair and no norm between
+            q = u @ a["wq"]
+        else:
+            q = rms_norm(u @ a["wq_a"], a["q_norm"], cfg.rms_norm_eps) @ a["wq_b"]
         q = q.reshape(*q.shape[:-1], H, cfg.qk_head_dim)
         kv = u @ a["wkv_a"]
         gain = a["kv_norm"]
@@ -118,9 +122,11 @@ def attention_leaf_shapes(cfg) -> dict:
     """``lp["attn"]``'s leaves as ``(shape, kind)`` (kind ``w``: drawn; ``one``:
     a norm's gain), from the config's published keys."""
     E, H, R, C = cfg.hidden_size, cfg.num_attention_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    query = {"wq": ((E, H * cfg.qk_head_dim), "w")} if R is None else {
+        "wq_a": ((E, R), "w"), "q_norm": ((R,), "one"), "wq_b": ((R, H * cfg.qk_head_dim), "w"),
+    }
     return {
-        "wq_a": ((E, R), "w"), "q_norm": ((R,), "one"),
-        "wq_b": ((R, H * cfg.qk_head_dim), "w"),
+        **query,
         "wkv_a": ((E, cfg.kv_width), "w"), "kv_norm": ((C,), "one"),
         "w_uk": ((C, H, cfg.qk_nope_head_dim), "w"),
         "w_uv": ((C, H, cfg.v_head_dim), "w"),
@@ -134,6 +140,15 @@ ATTENTION_AXES = {
     "w_uk": (None, "heads", None), "w_uv": (None, "heads", None),
     "wo": ("mlp", "embed"),
 }
+
+
+def attention_axes(cfg) -> dict:
+    """:data:`ATTENTION_AXES` for ``cfg``'s leaves (:func:`attention_leaf_shapes`):
+    where ``q_lora_rank`` is None the one query matrix in place of the pair."""
+    if cfg.q_lora_rank is not None:
+        return dict(ATTENTION_AXES)
+    rest = {k: v for k, v in ATTENTION_AXES.items() if k not in ("wq_a", "q_norm", "wq_b")}
+    return {"wq": ("embed", "mlp"), **rest}
 
 
 def is_leaf_spec(x) -> bool:

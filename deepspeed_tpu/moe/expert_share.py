@@ -13,6 +13,9 @@ experts would have added is left out; nothing stands in for the other chips.
 
     s     = sigmoid(u W_r)  |  softmax(u W_r)   float32, over all n_experts + n_zero columns
     sel   = top_k(s + b)                        the bias only selects
+            (with ``n_group`` > 1 over the columns of the ``topk_group`` groups a
+            token KEEPS: the columns are ``n_group`` equal runs, a group's score
+            the sum of its two largest ``s + b``; DeepSeek-V3's group limit)
     w_e   = scale * s_e / sum_{sel} s           for e in sel   (``norm_topk``)
           | scale * s_e                                        (not renormalised)
     y     = sum_{e in sel, e < n_experts, e held} w_e FFN_e(u)
@@ -28,7 +31,10 @@ router is no single matrix the family hands the scores' arguments in
 (``logits``: ZAYA's three-layer MLP over a state that comes down the depth,
 softmax, one pick, the probability itself the weight). The counts the
 layer reports are the tokens each held expert got and, with identity columns,
-the pairs that chose one of those (the last entry).
+the pairs that chose one of those (the last entry). Under a group limit
+(``n_group`` > 1) the last entry is the ROWS that kept a group this chip holds
+experts of: a chip that holds whole groups sees only those rows, and how many
+they are is the deployment's load (:func:`held_groups`).
 
 No capacity: a held expert computes every token routed to it. A row that is
 no token (``valid``) picks nothing: it joins no expert's rows and no count.
@@ -94,8 +100,36 @@ class ExpertShare(NamedTuple):
 SCORINGS = {"sigmoid": jax.nn.sigmoid, "softmax": lambda x: jax.nn.softmax(x, axis=-1)}
 
 
+def kept_groups(biased, n_group: int, topk_group: int):
+    """``biased [T, columns]`` (``s + b``, float32) → ``[T, n_group]`` bool: the
+    ``topk_group`` groups a token keeps. The columns are ``n_group`` equal
+    runs; a group's score is the sum of its two largest entries."""
+    T, N = biased.shape
+    best2, _ = jax.lax.top_k(biased.reshape(T, n_group, N // n_group), 2)
+    _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+    return jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+
+
+def route_kept(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True,
+               scoring: str = "sigmoid", logits=None, n_group: int = 1, topk_group: int = 1):
+    """:func:`route`, and with it the groups each token kept (``[T, n_group]``
+    bool; None where ``n_group`` is 1: no limit)."""
+    if logits is None:
+        logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32), precision=_HI)
+    s = SCORINGS[scoring](logits.astype(jnp.float32))
+    biased, kept = s + bias.astype(jnp.float32), None
+    if n_group > 1:
+        kept = kept_groups(biased, n_group, topk_group)
+        biased = jnp.where(jnp.repeat(kept, biased.shape[-1] // n_group, axis=-1), biased, -jnp.inf)
+    _, idx = jax.lax.top_k(biased, top_k)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return idx, picked * scale, kept
+
+
 def route(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True,
-          scoring: str = "sigmoid", logits=None):
+          scoring: str = "sigmoid", logits=None, n_group: int = 1, topk_group: int = 1):
     """``u [T, E]`` → (``idx [T, k]`` int32 over all the router's columns, ``w
     [T, k]`` float32: ``scale`` times the picked scores, renormalised over the
     picks with ``norm_topk``). Scores (``scoring``: :data:`SCORINGS`) in
@@ -103,15 +137,19 @@ def route(u, router_w, bias, top_k: int, scale: float, norm_topk: bool = True,
     would flip near-ties that the float32 reference keeps. ``logits [T,
     columns]``: the scores' arguments where the FAMILY computes them (a router
     that is no single matrix: ZAYA's MLP over a state carried down the depth);
-    ``u`` and ``router_w`` are then not read."""
-    if logits is None:
-        logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32), precision=_HI)
-    s = SCORINGS[scoring](logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
-    picked = jnp.take_along_axis(s, idx, axis=-1)
-    if norm_topk:
-        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return idx, picked * scale
+    ``u`` and ``router_w`` are then not read. ``n_group``, ``topk_group``: the
+    group limit (:func:`kept_groups`; 1, 1: none, and the trace is what it
+    was): the picks are the ``top_k`` of the kept groups' columns, both
+    selections on the same float32 scores."""
+    return route_kept(u, router_w, bias, top_k, scale, norm_topk, scoring, logits, n_group, topk_group)[:2]
+
+
+def held_groups(share: "ExpertShare", n_group: int):
+    """The groups (of ``n_group`` equal runs of the router's columns) this
+    share holds an expert of: one group's part, or some whole groups."""
+    size = share.n_experts // n_group
+    first, last = share.index * share.n_held, (share.index + 1) * share.n_held - 1
+    return list(range(first // size, last // size + 1))
 
 
 def held_weights(idx, w, share: ExpertShare):
@@ -196,17 +234,22 @@ def kernel_runs(lp) -> bool:
     return grouped_experts.grouped_experts_ok(*lp["experts"]["w_gate"].shape[1:])
 
 
-def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits=None):
+def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits=None, groups=(1, 1)):
     """→ (the held experts' part for ``u [T, E]`` with the identity term;
     what says which pairs are held, ``[T, n_held]``: the weights ``wh`` under
     sigmoid scores (positive: a selected pair's weight is), the selection
     itself under softmax (:func:`held_hits`); ``zero [T]`` int32: a token's
-    picks among the identity columns, or None). A row that is no token
-    (``valid``) picks nothing: no expert is read or counted for it."""
+    picks among the identity columns, or under a group limit (``groups``:
+    ``n_group, topk_group``) 1 where it kept a group held here, or None). A
+    row that is no token (``valid``) picks nothing: no expert is read or
+    counted for it."""
     with parts.part("moe.route"):
-        idx, w = route(u, lp.get("router"), lp["bias"], top_k, scale, norm_topk, scoring, logits)
+        idx, w, kept = route_kept(u, lp.get("router"), lp["bias"], top_k, scale, norm_topk, scoring, logits, *groups)
         if valid is not None:
             idx = jnp.where(valid[:, None], idx, -1)
+        if kept is not None:
+            here = jnp.any(kept[:, jnp.asarray(held_groups(share, groups[0]))], axis=1)
+            kept = (here if valid is None else here & valid).astype(jnp.int32)
         wh = held_weights(idx, w, share)
         held = wh if scoring == "sigmoid" else held_hits(idx, share)
     ex = lp["experts"]
@@ -216,7 +259,7 @@ def _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits=None):
         with parts.part("moe.experts"):
             y = held_experts(u, wh, ex["w_gate"], ex["w_up"], ex["w_down"])
     if not share.n_zero:
-        return y, held, None
+        return y, held, kept
     with parts.part("moe.route"):   # the identity experts: the token itself, weighted in float32
         wz, zero = zero_weights(idx, w, share)
         y = (y.astype(jnp.float32) + wz[:, None] * u.astype(jnp.float32)).astype(u.dtype)
@@ -232,10 +275,13 @@ def experts_streamed(counts, kernel: bool) -> int:
 
 def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
                        norm_topk: bool = True, valid: Optional[jnp.ndarray] = None,
-                       scoring: str = "sigmoid", logits: Optional[jnp.ndarray] = None):
+                       scoring: str = "sigmoid", logits: Optional[jnp.ndarray] = None,
+                       n_group: int = 1, topk_group: int = 1):
     """``u [T, E]`` → (``y [T, E]``, ``counts [n_held]`` int32: the tokens
     each held expert got, and with ``share.n_zero`` one entry more, the pairs
-    that chose an identity column; with ``valid [T]`` only those rows pick
+    that chose an identity column, or with ``n_group`` > 1 (:func:`route`'s
+    group limit; not both) the rows that kept a group held here; with
+    ``valid [T]`` only those rows pick
     experts at all, e.g. the slots that hold a request: the others get the
     shared expert alone). ``lp``: ``router [E, n_experts + n_zero]``, ``bias``
     as wide, ``experts`` and, where the model has a shared expert, ``shared``,
@@ -244,16 +290,19 @@ def expert_share_layer(lp, u, share: ExpertShare, top_k: int, scale: float,
     arguments from the family, in the place of ``u @ lp["router"]``
     (:func:`route`)."""
     T, rows = u.shape[0], block_rows(u)
+    groups = (n_group, topk_group)
+    if n_group > 1 and share.n_zero:
+        raise ValueError("a group limit over a router with identity columns is not built: the report has one last entry")
     if kernel_runs(lp) and rows < T:
         blocks = lambda a: None if a is None else a.reshape(-1, rows, *a.shape[1:])
         y, held, zero = jax.lax.map(
-            lambda b: _routed(b[0], lp, share, top_k, scale, norm_topk, scoring, b[1], b[2]),
+            lambda b: _routed(b[0], lp, share, top_k, scale, norm_topk, scoring, b[1], b[2], groups),
             (blocks(u), blocks(valid), blocks(logits)),
         )
         y, held = y.reshape(T, -1), held.reshape(T, -1)
         zero = None if zero is None else zero.reshape(T)
     else:
-        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits)
+        y, held, zero = _routed(u, lp, share, top_k, scale, norm_topk, scoring, valid, logits, groups)
     if "shared" in lp:
         sh = lp["shared"]
         s = gated_ffn(u, sh["w_gate"], sh["w_up"], sh["w_down"])

@@ -279,7 +279,14 @@ class Family:
       need), ``lin_taps(lp) -> w_conv [channels, K]`` (no bias),
       ``lin_gates(lp, c, rest) -> q, k [..., Hk, dk], v [..., Hv, dv], g, beta
       [..., Hv]`` in float32 (``q``, ``k`` as the rule reads them) and
-      ``lin_out(lp, o [..., Hv, dv], rest, tp_axis)``.
+      ``lin_out(lp, o [..., Hv, dv], rest, tp_axis)``. ``g``'s RANK is the
+      rule: ``[..., Hv]`` one decay a head (Gated DeltaNet), ``[..., Hv, dk]``
+      a decay a key channel (KDA); :func:`_lin_block` and the kernels' entries
+      branch on it at trace time, and a family of the second kind states
+      ``lin_g_min``, the lower bound of its decays (the chunk kernel's form
+      rests on it). ``"lin"`` sub-blocks beside ``kv_pools == 1`` are served
+      (Ling-3.0-flash: the state pools beside ONE latent pool; the pools'
+      layers count each kind alone, :func:`_kv_homes`).
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
@@ -288,7 +295,11 @@ class Family:
       masked einsums elsewhere; ``moe/expert_share.py``).
       ``zero_experts``: the router's identity columns, 0 for none; where it
       has some, a report is ``[experts_held + 1]``, the last entry the pairs
-      that chose one of them.
+      that chose one of them. ``expert_groups`` (optional): > 1 where the
+      router keeps some of that many groups a token; a report is then
+      ``[experts_held + 1]`` too, the last entry the ROWS that kept a group
+      this chip holds experts of (``group_rows`` on the leaves that carry
+      the loads).
     - ``embed(params, ids, positions) -> h``
     - ``layer(params, l) -> lp``: sub-block ``l``'s weights. Every program
       calls it once a sub-block and hands the SAME ``lp`` to ``qkv`` (or
@@ -634,8 +645,9 @@ def _lin_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
         if C:
             keep = (jnp.arange(C) < n_real)[:, None]
             o, s1 = gated_delta.chunk_rows(
-                q[:C], k[:C], v[:C], jnp.where(keep, g[:C], 0.0), jnp.where(keep, beta[:C], 0.0),
-                jnp.where(fresh, 0.0, lin[li, slot]), impl=impl,
+                q[:C], k[:C], v[:C], jnp.where(keep[..., None] if g.ndim == 3 else keep, g[:C], 0.0),   # g [..., Hv, dk]: a decay a channel
+                jnp.where(keep, beta[:C], 0.0),
+                jnp.where(fresh, 0.0, lin[li, slot]), impl=impl, g_min=getattr(fam, "lin_g_min", None),
             )
             lin = lin.at[li, slot].set(s1)
             os.append(o)

@@ -269,7 +269,7 @@ class ServingEngine:
                 "ServingEngine serves a model whose config gives the paged "
                 "programs its pieces (serving_family(): the gpt2 family, "
                 "including injected HF GPT-2, exaone_moe, mistral4, "
-                "longcat_flash, phi4flash, zaya and qwen3_next); got "
+                "longcat_flash, phi4flash, zaya, qwen3_next, xing4 and ling3); got "
                 f"{type(mcfg).__name__}"
             )
         int8_pages = bool(config.kv_cache_dtype) and jnp.dtype(config.kv_cache_dtype) == jnp.dtype(jnp.int8)
@@ -321,31 +321,36 @@ class ServingEngine:
                 ((self.recurrent or self.carried) and spec_on, "serving.speculative"),
             ):
                 if on:
-                    raise ValueError(
-                        f"{what} is not available for a model with "
-                        + (
-                            f"recurrent state ({type(mcfg).__name__}): "
-                            f"{self._state_what} and convolution "
-                            "rows live in a per-slot pool beside the paged "
-                            "pool, which this mechanism does not handle"
-                            if self.recurrent else
-                            f"carried attention rows ({type(mcfg).__name__}): "
-                            "the queries, keys and values of a call's first "
-                            "rows need the rows before them, which live in a "
-                            "per-slot pool beside the paged pool; this "
-                            "mechanism does not handle it (pages without the "
-                            "rows at their end would serve a wrong token)"
-                            if self.carried else
-                            f"sliding-window layers ({type(mcfg).__name__}): a "
-                            "window layer's KV lives in a per-slot ring beside "
-                            "the paged pool, which this mechanism does not handle"
-                            if self.windowed else
-                            f"a latent KV pool ({type(mcfg).__name__}): its "
-                            "cache is one pool of one row a token that every "
-                            "head reads, with no V pool and no head axis, which "
-                            "this mechanism does not handle"
-                        )
-                    )
+                    # every kind of state that stands in the way, by name: a
+                    # family may hold more than one (a recurrent state beside
+                    # a latent pool)
+                    name = type(mcfg).__name__
+                    why = [text for held, text in (
+                        (self.recurrent,
+                         f"recurrent state ({name}): "
+                         f"{self._state_what} and convolution "
+                         "rows live in a per-slot pool beside the paged "
+                         "pool, which this mechanism does not handle"),
+                        (self.carried,
+                         f"carried attention rows ({name}): "
+                         "the queries, keys and values of a call's first "
+                         "rows need the rows before them, which live in a "
+                         "per-slot pool beside the paged pool; this "
+                         "mechanism does not handle it (pages without the "
+                         "rows at their end would serve a wrong token)"),
+                        (self.windowed,
+                         f"sliding-window layers ({name}): a "
+                         "window layer's KV lives in a per-slot ring beside "
+                         "the paged pool, which this mechanism does not handle"),
+                        (self.latent,
+                         f"a latent KV pool ({name}): its "
+                         "cache is one pool of one row a token that every "
+                         "head reads, with no V pool and no head axis, which "
+                         "this mechanism does not handle"),
+                    ) if held]
+                    if what == "serving.speculative":   # only a state that cannot be rolled back refuses a draft
+                        why = why[: int(self.recurrent) + int(self.carried)]
+                    raise ValueError(f"{what} is not available for a model with " + "; and with ".join(why))
 
         page = int(config.page_size)
         self.page_size = page
@@ -830,6 +835,17 @@ class ServingEngine:
             "serving_moe_pairs_zero_total",
             "token-expert pairs that chose an identity (zero-compute) expert: "
             "no matrices, the token itself, on whichever chip serves it",
+        )
+        self._c_moe_group_rows = m.counter(
+            "serving_moe_group_rows_total",
+            "rows whose kept routing groups include one this chip holds "
+            "experts of, summed over the expert layers, over decode steps "
+            "and chunk calls (a router with a group limit; else 0)",
+        )
+        self._c_moe_rows = m.counter(
+            "serving_moe_rows_total",
+            "rows a group-limited router routed, summed over the expert "
+            "layers: what serving_moe_group_rows_total is a share of",
         )
         # -- ISSUE 14: TP sharding + disaggregation instruments ------------
         self._g_tp_coll = m.gauge(
@@ -1525,6 +1541,13 @@ class ServingEngine:
             counts, zero = counts[:, :-1], int(counts[:, -1].sum())
             self._c_moe_zero.inc(zero)
             attrs["moe_pairs_zero"] = zero
+        if getattr(fam, "expert_groups", 1) > 1:
+            # a group-limited router's last entry: the rows that kept a group held here
+            counts, kept = counts[:, :-1], int(counts[:, -1].sum())
+            rows = int(n_tokens) * len(fam.sparse_layers)
+            self._c_moe_group_rows.inc(kept)
+            self._c_moe_rows.inc(rows)
+            attrs.update(group_rows=kept, rows=rows)
         held = int(counts.sum())
         routed = int(n_tokens) * fam.experts_per_token * len(fam.sparse_layers)
         streamed = experts_streamed(counts, self._moe_kernel)
@@ -3210,30 +3233,22 @@ class ServingEngine:
         so each side compiles exactly once per engine."""
         if self._migrate_gather_exec is not None:
             return
-        if self.recurrent:
-            raise ValueError(
-                "session migration is not available for a model with "
-                "recurrent state: the transport moves a slot's paged row, and "
-                f"{self._state_what} and convolution rows would stay behind"
-            )
-        if self.carried:
-            raise ValueError(
-                "session migration is not available for a model with carried "
-                "attention rows: the transport moves a slot's paged row, and "
-                "the rows its next token's queries, keys and values need "
-                "would stay behind"
-            )
-        if self.windowed:
-            raise ValueError(
-                "session migration is not available for a model with "
-                "sliding-window layers: the transport moves a slot's paged "
-                "row, and its window rings would stay behind"
-            )
-        if self.latent:
-            raise ValueError(
-                "session migration is not available for a model with a latent "
-                "KV pool: the transport packs a K and a V pool's page columns"
-            )
+        why = [text for held, text in (
+            (self.recurrent,
+             "recurrent state: the transport moves a slot's paged row, and "
+             f"{self._state_what} and convolution rows would stay behind"),
+            (self.carried,
+             "carried attention rows: the transport moves a slot's paged row, and "
+             "the rows its next token's queries, keys and values need "
+             "would stay behind"),
+            (self.windowed,
+             "sliding-window layers: the transport moves a slot's paged "
+             "row, and its window rings would stay behind"),
+            (self.latent,
+             "a latent KV pool: the transport packs a K and a V pool's page columns"),
+        ) if held]
+        if why:
+            raise ValueError("session migration is not available for a model with " + "; and with ".join(why))
         self._ensure_compiled()
         S = jax.ShapeDtypeStruct
         i32 = jnp.int32
@@ -3872,6 +3887,7 @@ class ServingEngine:
         # ahead for a slot that a late stop ended (computed and dropped)
         out["steps_ahead"] = int(self._c_ahead.value())
         out["rows_dropped"] = int(self._c_dropped.value())
+        out["group_rows"] = int(self._c_moe_group_rows.value())
         if self.prefix_cache is not None:
             pc = self.prefix_cache
             lookups = pc.hits_full + pc.hits_partial + pc.misses

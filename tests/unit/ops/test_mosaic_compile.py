@@ -1259,6 +1259,37 @@ def test_gated_delta_kernels_compile_for_v5e_at_the_published_heads(one_chip, en
     assert mem.alias_size_in_bytes == 9 * rows * Hv * dk * dv * 4 and mem.temp_size_in_bytes < 16e6   # the pool in place
 
 
+# -- the delta rule with a decay a key channel (ling3, KDA): the two kernels at the published heads (ISSUE 60) --
+
+@pytest.mark.parametrize("entry,rows", [("chunk", 256), ("step", 128)])
+def test_kda_kernels_compile_for_v5e_at_the_published_heads(one_chip, entry, rows):
+    """``ops/pallas/gated_delta.py`` under a decay a KEY CHANNEL at Ling-3.0-flash's
+    heads (32 of 128 x 128, a key head a value head, ``g [rows, 32, 128]``): a
+    chunk of one slot's rows (sub-chunks of 64 in diagonal blocks of 16), and
+    one row for each of 128 slots against a layer of the whole 2.7 GB state
+    pool, aliased: no copy of the pool is made. Under their own names: the
+    scalar rule's kernels are in neither program."""
+    from deepspeed_tpu.ops.pallas import gated_delta as gd
+
+    H, dk, dv = 32, 128, 128
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    count = lambda name, text: len(re.findall(rf"^\s*(ROOT )?%?{name}[.\d]* = .*custom-call\(", text, re.M))  # noqa: E731
+    rowwise = (S((rows, H, dk)), S((rows, H, dk)), S((rows, H, dv)), S((rows, H, dk)), S((rows, H)))
+    if entry == "chunk":
+        text = jax.jit(lambda *a: gd.chunk_rows(*a, impl="pallas", g_min=-5.0)).lower(*rowwise, S((H, dk, dv))).compile().as_text()
+        assert count(gd.KDA_CHUNK_KERNEL, text) == 1 and count(gd.CHUNK_KERNEL, text) == 0
+        return
+    pool = S((10, rows, H, dk, dv))
+    compiled = jax.jit(lambda *a: gd.step(*a[:6], 3, a[6], impl="pallas"), donate_argnums=(5,)).lower(
+        *rowwise, pool, S((rows,), jnp.bool_)).compile()
+    assert count(gd.KDA_STEP_KERNEL, compiled.as_text()) == 1 and count(gd.STEP_KERNEL, compiled.as_text()) == 0
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 10 * rows * H * dk * dv * 4 and mem.temp_size_in_bytes < 16e6   # the pool in place
+
+
 # -- a multi-stream residual (xing4_0): the mixing's kernel pair at the published widths (ISSUE 57) --
 
 def _x4_config():

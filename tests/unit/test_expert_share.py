@@ -471,3 +471,77 @@ def test_softmax_top_k_counts_are_hits_not_weights(u):
         np.testing.assert_array_equal(np.asarray(counts), want)
         total += int(counts.sum())
     assert total == T * K
+
+
+# -- a group limit (DeepSeek-V3's; Ling-3.0-flash: top-8 of 512 in 4 of 8 groups) --------------
+
+NG, KG = 4, 2     # N = 16 columns: four groups of four, a token keeps two
+
+
+def _plain_grouped(lp, u):
+    """The uncut layer under the group limit, one token at a time: (the
+    output, the picks ``[T, K]``, the groups kept ``[T, NG]``)."""
+    out, picks, kept = np.zeros((T, E), np.float64), np.zeros((T, K), np.int64), np.zeros((T, NG), bool)
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(u, np.float64) @ np.asarray(lp["router"], np.float64))))
+    sb = s + np.asarray(lp["bias"], np.float64)
+    ffn = lambda x, wg, wu, wd: ((lambda g: g / (1 + np.exp(-g)))(x @ wg) * (x @ wu)) @ wd
+    size = N // NG
+    for t in range(T):
+        score = [np.sort(sb[t, j * size:(j + 1) * size])[-2:].sum() for j in range(NG)]
+        kept[t, np.argsort(score)[-KG:]] = True
+        masked = np.where(np.repeat(kept[t], size), sb[t], -np.inf)
+        picks[t] = np.argsort(-masked)[:K]
+        for e in picks[t]:
+            out[t] += SCALE * s[t, e] / s[t, picks[t]].sum() * ffn(
+                np.asarray(u[t], np.float64), *(np.asarray(lp["experts"][k][e], np.float64) for k in ("w_gate", "w_up", "w_down")))
+        out[t] += ffn(np.asarray(u[t], np.float64), *(np.asarray(lp["shared"][k], np.float64) for k in ("w_gate", "w_up", "w_down")))
+    return out, picks, kept
+
+
+def test_group_limited_route_is_the_plain_loop_and_one_group_is_todays_route_bit_for_bit(u):
+    lp = _layer()
+    _, picks, kept = _plain_grouped(lp, u)
+    idx, w, kept_ = es.route_kept(u, lp["router"], lp["bias"], K, SCALE, n_group=NG, topk_group=KG)
+    assert np.array_equal(np.sort(np.asarray(idx), axis=1), np.sort(picks, axis=1)) and np.array_equal(np.asarray(kept_), kept)
+    assert kept.sum(1).tolist() == [KG] * T and np.all(kept[np.arange(T)[:, None], picks // (N // NG)])   # every pick in a kept group
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), SCALE, rtol=1e-6)
+    free = es.route(u, lp["router"], lp["bias"], K, SCALE)
+    assert not np.array_equal(np.sort(np.asarray(free[0]), axis=1), np.sort(picks, axis=1))           # the limit changes who is picked
+    # n_group 1 is the route there was: the same values to the bit, and the same traced text
+    one = es.route(u, lp["router"], lp["bias"], K, SCALE, n_group=1, topk_group=1)
+    assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(free, one))
+    text = lambda **kw: jax.jit(lambda u: es.route(u, lp["router"], lp["bias"], K, SCALE, **kw)).lower(u).as_text()  # noqa: E731
+    assert text() == text(n_group=1, topk_group=1) != text(n_group=NG, topk_group=KG)
+    assert es.held_groups(es.ExpertShare(N, 4, 2), NG) == [2] and es.held_groups(es.ExpertShare(N, 2, 1), NG) == [2, 3]
+    assert es.held_groups(es.ExpertShare(N, 8, 5), NG) == [2]                                          # half a group
+
+
+@pytest.mark.parametrize("chips", [2, 4, 8])
+def test_the_group_limited_shares_add_up_to_the_uncut_layer_and_a_share_sees_only_rows_that_kept_its_group(u, chips):
+    """The guide's test that ties the share to the model: the ``chips`` shares'
+    routed parts, with the shared expert once, are the uncut group-limited
+    layer; a share's counts are of tokens that kept its group, and its last
+    entry is how many rows did."""
+    lp = _layer()
+    want, picks, kept = _plain_grouped(lp, u)
+    shared = np.asarray(es.gated_ffn(u, *(lp["shared"][k] for k in ("w_gate", "w_up", "w_down"))))
+    total, pairs = np.zeros((T, E)), 0
+    for i in range(chips):
+        share = es.ExpertShare(N, chips, i)
+        y, counts = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE, n_group=NG, topk_group=KG)
+        assert counts.shape == (N // chips + 1,)
+        mine = es.held_groups(share, NG)
+        rows = kept[:, mine].any(axis=1)
+        assert int(counts[-1]) == int(rows.sum())
+        part = np.asarray(y) - shared
+        assert np.abs(part[~rows]).max(initial=0.0) == 0.0           # a row that kept none of this share's groups brings nothing here
+        held = np.isin(picks, np.arange(i * share.n_held, (i + 1) * share.n_held))
+        assert counts[:-1].tolist() == [int((picks == e).any(1).sum()) for e in range(i * share.n_held, (i + 1) * share.n_held)]
+        assert not held[~rows].any()
+        total, pairs = total + part, pairs + int(counts[:-1].sum())
+    assert pairs == T * K
+    np.testing.assert_allclose(total + shared, want, rtol=2e-5, atol=2e-6)
+    # rows that are no tokens keep nothing
+    share = es.ExpertShare(N, 4, 1)
+    _, some = es.expert_share_layer(_slice(lp, share), u, share, K, SCALE, valid=jnp.arange(T) < 10, n_group=NG, topk_group=KG)
+    assert int(some[-1]) == int(kept[:10, 1].sum())
