@@ -376,6 +376,10 @@ class GPT2Family:
     experts_held = 0
     experts_per_token = 0
     sm_scale = None        # 1 / sqrt(head_dim), but for pairs (below)
+    # the leaves ``embed`` takes ROWS of: a placement lays them row-major, once (a v5e's own
+    # order for [50257, 1600] is vocabulary-minor, which every program re-laid to gather 8
+    # rows); the tied head contracts against the same bytes
+    row_gathered = ("wte", "wpe")
 
     def __init__(self, cfg: GPT2Config, pairs: bool = True):
         self.cfg = cfg

@@ -301,6 +301,13 @@ class Family:
       this chip holds experts of (``group_rows`` on the leaves that carry
       the loads).
     - ``embed(params, ids, positions) -> h``
+    - ``row_gathered`` (optional): the paths of the leaves ``embed`` gathers
+      rows of. ``Placement.shard_params`` lays each row-major on the device,
+      once, where the device's own order for its shape is another (a v5e's
+      for a table whose rows are not whole lane tiles: GPT-2 XL's 1 600-wide
+      ``wte`` and ``wpe``), and the programs take the leaf as it lies; else
+      every program copies the whole table to gather its few rows
+      (``serving_weight_relayout_bytes`` counts such copies, in any family).
     - ``layer(params, l) -> lp``: sub-block ``l``'s weights. Every program
       calls it once a sub-block and hands the SAME ``lp`` to ``qkv`` (or
       ``qkv_expanded``) and then to the rest of the sub-block, so a family

@@ -39,6 +39,7 @@ collective-order check passes by construction.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import re
@@ -47,6 +48,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..analysis.sharding_rules import (
@@ -89,8 +91,17 @@ GPT2_SERVING_RULES: List[Tuple[str, list]] = [
 
 
 # one instruction of an optimised HLO module: "= <dtype>[<dims>]{<layout>} <opcode>("
-_HLO_RESULT = re.compile(r"=\s+\w+\[([\d,]*)\](?:\{[^}]*\})?\s+([\w-]+)\(")
+_HLO_RESULT = re.compile(r"=\s+(\w+)\[([\d,]*)\](?:\{[^}]*\})?\s+([\w-]+)\(")
 _RELAYOUT_OPCODES = frozenset(("copy", "slice", "transpose", "dynamic-slice"))
+WEIGHT_LEAF_MIN_BYTES = 1 << 20  # a leaf the weights' census looks for: 1 MB or more a device
+
+
+def _relayout_results(hlo_text: str):
+    """``(opcode, dtype, dims)`` of every instruction of an optimised HLO module
+    (fused computations included) that copies, slices or transposes."""
+    for dtype, dims, opcode in _HLO_RESULT.findall(hlo_text):
+        if opcode in _RELAYOUT_OPCODES:
+            yield opcode, dtype, tuple(int(d) for d in dims.split(",") if d)
 
 
 def pool_relayout_ops(hlo_text: str, layer_elems: int) -> int:
@@ -101,12 +112,94 @@ def pool_relayout_ops(hlo_text: str, layer_elems: int) -> int:
     from its entry to its kernels and back; each one is a layer or a pool of
     HBM traffic per call that no kernel asked for."""
     n = 0
-    for dims, opcode in _HLO_RESULT.findall(hlo_text):
-        if opcode not in _RELAYOUT_OPCODES:
-            continue
-        elems = int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+    for _, _, dims in _relayout_results(hlo_text):
+        elems = int(np.prod(dims or (1,)))
         n += elems >= layer_elems and elems % layer_elems == 0
     return n
+
+
+def _hlo_dtype(dtype) -> str:
+    """A numpy dtype as HLO text names it: ``bf16``, ``f32``, ``s8``, ``u32``."""
+    dtype = np.dtype(dtype)
+    kind = {"f": "f", "i": "s", "u": "u"}.get(dtype.kind, "f")
+    return ("b" if dtype.name == "bfloat16" else "") + f"{kind}{8 * dtype.itemsize}"
+
+
+def weight_relayout(hlo_text: str, leaves) -> Tuple[int, int]:
+    """``(instructions, bytes)`` of an optimised HLO module that COPY or
+    TRANSPOSE a whole parameter leaf: a result in the type and with the dims,
+    in any order, of one of ``leaves`` (``(dtype, per-device dims)``). Each is
+    that leaf read and written again in every call, for an order its consumer
+    wants and the placed leaf has not (``Placement.shard_params`` lays a
+    row-gathered table out once, at load); 0 is a program that reads every
+    such leaf where it lies. Slices are not counted: one that gives a whole
+    leaf is no instruction, and what is left are pieces of a LARGER leaf that
+    happen to have another leaf's dims (a fused ``wqkv``'s thirds, one expert
+    of a stack beside a shared expert: found on the chip, PR 61)."""
+    whole = {(_hlo_dtype(dtype), tuple(sorted(dims))) for dtype, dims in leaves}
+    n = nbytes = 0
+    for opcode, dtype, dims in _relayout_results(hlo_text):
+        if opcode in ("copy", "transpose") and (dtype, tuple(sorted(dims))) in whole:
+            n += 1
+            nbytes += int(np.prod(dims)) * int(re.search(r"\d+", dtype).group()) // 8
+    return n, nbytes
+
+
+class WeightLayoutError(RuntimeError):
+    """A compiled program would take a weight in another order than the placed
+    leaf lies in: raised at set-up, never re-laid inside a run."""
+
+
+def row_major_format(x) -> Optional[Format]:
+    """The format that holds ``x`` on the devices it is on with its dimensions
+    in their own order from major to minor, or ``None`` where it lies so
+    already (every array of a CPU; a TPU's, where its rows are whole lane
+    tiles). What decides is the order the device gave the array, ``x.format``."""
+    fmt = getattr(x, "format", None)
+    order = tuple(range(x.ndim))
+    if fmt is None or fmt.layout is None or tuple(fmt.layout.major_to_minor) == order:
+        return None
+    return Format(Layout(major_to_minor=order), fmt.sharding)
+
+
+@contextlib.contextmanager
+def _compiled_in_this_process():
+    """No program compiled inside is read from, or written to, jax's
+    persistent compilation cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def lay_row_major(x):
+    """``x`` row-major on its devices: itself, or one copy, made once.
+
+    The copy's program (``jax.device_put`` to a format is a jitted identity)
+    is compiled here, never taken from the persistent cache: under libtpu
+    0.0.34 the results of a DESERIALIZED executable report the device's
+    default order in ``Array.format`` whatever order the program wrote them
+    in, every program lowered over such an array then asks for the default
+    order, and its first call fails on the buffer's size (found on the chip,
+    PR 61). The programs themselves, which TAKE the leaf in its order, come
+    from the cache as ever."""
+    fmt = row_major_format(x)
+    if fmt is None:
+        return x
+    with _compiled_in_this_process():
+        laid = jax.device_put(x, fmt)
+    if row_major_format(laid) is not None:
+        raise WeightLayoutError(
+            f"{x.dtype.name}{list(x.shape)} was put as {fmt.layout} and "
+            f"reports {laid.format.layout}"
+        )
+    return laid
 
 
 def _path_of(keypath) -> str:
@@ -242,19 +335,30 @@ class Placement:
             params,
         )
 
-    def shard_params(self, params: PyTree) -> PyTree:
+    def shard_params(self, params: PyTree, row_gathered: Sequence[str] = ()) -> PyTree:
         """QKV-permute (rank-major columns) + device_put the tree onto this
         placement. tp=1: placement pin only (no permute, no resharding on
-        the default device)."""
+        the default device). The leaves at the paths ``row_gathered`` (a
+        family's ``row_gathered``: the tables its ``embed`` takes rows of) lie
+        row-major from here on (:func:`lay_row_major`), so that no program
+        re-lays a table out to gather a few rows of it; a leaf whose own order
+        on the device is row-major already is the one that came in."""
         if self.tp == 1:
             if self.device is jax.devices()[0]:
-                return params
-            return jax.tree.map(lambda x: jax.device_put(x, self.device), params)
-        permuted = tp_shard_serving_params(params, self.tp)
-        specs = self.param_spec_tree(permuted)
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            permuted, specs,
+                placed = params
+            else:
+                placed = jax.tree.map(lambda x: jax.device_put(x, self.device), params)
+        else:
+            permuted = tp_shard_serving_params(params, self.tp)
+            specs = self.param_spec_tree(permuted)
+            placed = jax.tree.map(
+                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+                permuted, specs,
+            )
+        if not row_gathered:
+            return placed
+        return jax.tree_util.tree_map_with_path(
+            lambda kp, x: lay_row_major(x) if _path_of(kp) in row_gathered else x, placed
         )
 
     def verify_rules(self, params: PyTree, program: str = "serving_params",
@@ -392,7 +496,7 @@ class ProgramSet:
             self.carry_pool_bytes = int(rows.nbytes)
         self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
-        self.params = placement.shard_params(params)
+        self.params = placement.shard_params(params, getattr(fam, "row_gathered", ()))
         self.param_specs = (
             placement.param_spec_tree(self.params)
             if placement.mesh is not None else None
@@ -513,6 +617,21 @@ class ProgramSet:
                         f"{fmt.layout}, and the live pool is "
                         f"{pool.format.layout}"
                     )
+        if with_params:
+            # and every weight as the placed leaf lies (a row-gathered table
+            # row-major: ``Placement.shard_params``)
+            for (kp, x), fmt in zip(
+                jax.tree_util.tree_leaves_with_path(self.params),
+                jax.tree.leaves(exe.input_formats[0][0]),
+            ):
+                lies = getattr(getattr(x, "format", None), "layout", None)
+                # (a leaf the program does not read has no layout there)
+                if lies is not None and fmt.layout is not None and fmt.layout != lies:
+                    raise WeightLayoutError(
+                        f"{getattr(fn, '__name__', fn)} takes {_path_of(kp)} "
+                        f"{x.dtype.name}{list(x.shape)} as {fmt.layout}, and "
+                        f"the placed leaf is {lies}"
+                    )
         return exe
 
     def page_column(self, pid: int) -> tuple:
@@ -527,13 +646,17 @@ class ProgramSet:
             self.kv_scales[:, pid] if self.kv_scales is not None else None,
         )
 
-    def program_census(self, name: str, exe) -> Tuple[int, int]:
-        """(``pool_relayout_ops``, HLO temp bytes) of a compiled program over
-        this set's pools; per device at tp>1. A program that hands the pools
+    def program_census(self, name: str, exe) -> Tuple[int, int, int, int]:
+        """(``pool_relayout_ops``, HLO temp bytes, then :func:`weight_relayout`'s
+        instructions and bytes) of a compiled program over this set's pools
+        and weights; per device at tp>1. A program that hands the pools
         to a Pallas kernel has to read 0: there the kernels take the pool
         where it lies, and a copy or a slice of a layer means the pool was
         re-laid out on the way (:class:`PoolLayoutError`). The ``jnp``
-        fallbacks slice their layer out and are only counted."""
+        fallbacks slice their layer out and are only counted, and so are the
+        weights: a whole leaf of ``WEIGHT_LEAF_MIN_BYTES`` or more that a
+        program copies for another order is traffic of every call, and no
+        error."""
         layer = (
             self.num_pages * self.local_kv_heads() * self.page_size
             * self.head_dim
@@ -547,7 +670,19 @@ class ProgramSet:
                 "more around its kernels (placement.pool_relayout_ops)"
             )
         mem = exe.memory_analysis()
-        return relayout, int(getattr(mem, "temp_size_in_bytes", 0) or 0)
+        w_ops, w_bytes = weight_relayout(text, self._weight_leaves())
+        return relayout, int(getattr(mem, "temp_size_in_bytes", 0) or 0), w_ops, w_bytes
+
+    def _weight_leaves(self) -> set:
+        """``(dtype, per-device dims)`` of every leaf of the placed weights
+        that holds ``WEIGHT_LEAF_MIN_BYTES`` or more a device."""
+        leaves = set()
+        for x in jax.tree.leaves(self.params):
+            shard_shape = getattr(getattr(x, "sharding", None), "shard_shape", None)
+            shape = tuple(shard_shape(x.shape) if shard_shape else x.shape)
+            if int(np.prod(shape)) * x.dtype.itemsize >= WEIGHT_LEAF_MIN_BYTES:
+                leaves.add((x.dtype, shape))
+        return leaves
 
     def take_pools(self, out: tuple):
         """Rehome the donated pools from a program's output tuple and

@@ -470,6 +470,11 @@ class ServingEngine:
         else:
             self.prefill_placement = self.decode_placement
             self.prefill_set = self.decode_set
+            if decode_tp == 1 and self.decode_placement.device is jax.devices()[0]:
+                # the engine's own arrays, but for a leaf the placement laid
+                # out anew (``Placement.shard_params``): hand the tree back,
+                # or the table would lie on the device twice
+                engine.params = self.decode_set.params
         self.quantized = self.decode_set.quantized
         if self.pages_per_slot > self.decode_set.allocator.capacity:
             raise ValueError(
@@ -754,6 +759,14 @@ class ServingEngine:
             "program whose result is one or more whole layers of a KV pool "
             "(0 = the pool keeps one layout from the program's entry to its "
             "kernels and back)",
+            labelnames=("program",),
+        )
+        self._g_weight_relayout = m.gauge(
+            "serving_weight_relayout_bytes",
+            "bytes of the results of the copy / slice / transpose instructions "
+            "of a compiled serving program that are a whole parameter leaf of "
+            "1 MB or more (per device; 0 = the program reads every weight "
+            "where the placement laid it)",
             labelnames=("program",),
         )
         self._g_temp_bytes = m.gauge(
@@ -1449,9 +1462,11 @@ class ServingEngine:
 
     def _set_census_gauges(self) -> dict:
         """Per compiled program: how many pool-layer-sized copies, slices
-        and transposes its optimised HLO holds, its temp bytes, and the grid
-        steps of one call of each of its paged attention kernels, as gauges and
-        (returned) as the attrs of the ``ds.init.programs`` phase:
+        and transposes its optimised HLO holds, its temp bytes, the whole
+        weight leaves it copies for another order (``weight_relayout``, keyed
+        ``<program>=<instructions>/<bytes>``; the gauge holds the bytes), and
+        the grid steps of one call of each of its paged attention kernels, as
+        gauges and (returned) as the attrs of the ``ds.init.programs`` phase:
         ``relayout_ops`` / ``temp_bytes`` / ``grid_steps`` keyed
         ``<program>=<n>``."""
         from ..ops.attention import (
@@ -1467,10 +1482,12 @@ class ServingEngine:
             "decode": (rows,), "verify": (rows,),
             "chunk": ((1, self.chunk_width), rows),
         }
-        relayout, temp, steps = {}, {}, {}
+        relayout, temp, steps, w_relayout = {}, {}, {}, {}
         for name, rec in self._program_info.items():
             pset = rec["pset"]
-            relayout[name], temp[name] = pset.program_census(name, rec["exe"])
+            relayout[name], temp[name], w_ops, w_bytes = pset.program_census(name, rec["exe"])
+            w_relayout[name] = f"{w_ops}/{w_bytes}"
+            self._g_weight_relayout.set(w_bytes, program=name)
             steps[name] = 0
             for B, T in shapes.get(rec["kind"], ()):
                 steps[name] += latent_attention_grid_steps(
@@ -1514,8 +1531,8 @@ class ServingEngine:
         )
         attrs = {
             key: " ".join(f"{k}={v}" for k, v in got.items())
-            for key, got in (("relayout_ops", relayout), ("temp_bytes", temp),
-                             ("grid_steps", steps), ("kv_bytes", kv_bytes))
+            for key, got in (("relayout_ops", relayout), ("weight_relayout", w_relayout),
+                             ("temp_bytes", temp), ("grid_steps", steps), ("kv_bytes", kv_bytes))
         }
         attrs.update(window_pages_per_slot=self.ring_pages,
                      moe_experts_held=self.family.experts_held,

@@ -61,13 +61,13 @@ def _expert_kernel_census(text, layers, E, F, held=16):
     not this kernel's): the kernel reads the three stacked matrices where
     they lie."""
     from deepspeed_tpu.ops.pallas.grouped_experts import KERNEL_NAME
-    from deepspeed_tpu.serving.placement import _HLO_RESULT, _RELAYOUT_OPCODES
+    from deepspeed_tpu.serving.placement import _relayout_results
 
     assert re.match(r"moe_+experts_+w_(gate|up|down)", KERNEL_NAME)
     assert len(re.findall(rf"^\s*%?{KERNEL_NAME}[.\d]* = .*custom-call\(", text, re.M)) == layers
     assert "ragged-dot" not in text
-    matrices = {f"{lead}{a},{b}" for a, b in ((E, F), (F, E)) for lead in ("1,", f"{held},")}
-    assert [(d, op) for d, op in _HLO_RESULT.findall(text) if op in _RELAYOUT_OPCODES and d in matrices] == []
+    matrices = {(lead, a, b) for a, b in ((E, F), (F, E)) for lead in (1, held)}
+    assert [(op, d) for op, _, d in _relayout_results(text) if d in matrices] == []
 
 
 LAYERS, LAYER = 3, 1  # the serving engine's [L, P, KV, page, D] pool and a layer of it
@@ -342,6 +342,124 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, layout, m
     layer_kv_bytes = 2 * P * KV * page * 128 * 2  # 64 lanes pad to 128
     head_bytes = cfg.padded_vocab_size * cfg.n_embd * 2  # the tied head's transposed ``wte``
     assert compiled.memory_analysis().temp_size_in_bytes < head_bytes + layer_kv_bytes
+
+
+def _placed_format(one_chip, shape, dtype):
+    """The format ``placement.lay_row_major`` leaves an array of this shape in
+    on the described device: the device's own where that is row-major, else
+    what the one copy it makes comes out in (``jax.device_put`` to a format is
+    this identity program; nothing can be put on a described device)."""
+    from types import SimpleNamespace as NS
+
+    from deepspeed_tpu.serving.placement import row_major_format
+
+    own = _default_format(one_chip, shape, dtype)
+    want = row_major_format(NS(format=own, ndim=len(shape)))
+    if want is None:
+        return own
+    lies = jax.ShapeDtypeStruct(shape, dtype, sharding=own)
+    return jax.jit(lambda x: x, out_shardings=want).lower(lies).compile().output_formats
+
+
+@pytest.mark.parametrize("shape, own, placed", [
+    # GPT-2 XL's tables, as published and with the vocabulary padded to whole
+    # lane tiles: 1 600 is 12.5 tiles of 128 lanes, so the device lays the
+    # VOCABULARY minor, and a gather of rows has to re-lay the table
+    ((50257, 1600), (1, 0), (0, 1)),
+    ((50304, 1600), (1, 0), (0, 1)),
+    ((1024, 1600), (1, 0), (0, 1)),
+    # rows of whole lane tiles are row-major as they come: nothing is made
+    ((50257, 1664), (0, 1), (0, 1)),
+    ((50257, 2048), (0, 1), (0, 1)),
+], ids=lambda x: "x".join(map(str, x)))
+def test_a_table_whose_rows_are_no_whole_lane_tiles_is_vocabulary_minor_until_placed(one_chip, shape, own, placed):
+    """The fact ISSUE 61's rule rests on, and what the rule leaves: the
+    device's default order for a bf16 table, and the order of the leaf as
+    ``Placement.shard_params`` places a family's ``row_gathered`` leaves."""
+    assert _default_layout(one_chip, shape, jnp.bfloat16) == own
+    fmt = _placed_format(one_chip, shape, jnp.bfloat16)
+    assert tuple(fmt.layout.major_to_minor) == placed
+    if own == placed:
+        assert fmt == _default_format(one_chip, shape, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "mixed", "prefill"])
+def test_gpt2_programs_read_the_tables_where_the_placement_laid_them(one_chip, program, monkeypatch):
+    """ISSUE 61: the GPT-2 XL served programs (XL's widths, 2 layers), compiled
+    as the scheduler compiles them, twice: over the weights as the device
+    lays them by default (the parent's) and as ``Placement.shard_params``
+    places them (the family's ``row_gathered`` leaves row-major). The first
+    copies the whole ``wte`` (161 MB) and ``wpe`` to gather a few rows; the
+    second holds no copy, slice or transpose of either's size, takes the
+    tables as they lie, and needs at least 150 MB less of temporaries."""
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.serving import model as smodel
+    from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+    from deepspeed_tpu.serving.placement import Placement, ProgramSet, _relayout_results
+
+    L, P, H, page, B, W, C, Sp = 2, 512, 25, 16, 8, 64, 128, 960
+    cfg = gpt2.GPT2Config(n_embd=H * 64, n_head=H, n_layer=L, attn_impl="pallas", dtype=jnp.bfloat16)
+    fam = cfg.serving_family()
+    assert fam.row_gathered == ("wte", "wpe")
+    KV, D = fam.n_kv_head, fam.head_dim
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt, fmt=one_chip):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=fmt)
+
+    abstract = jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0)))
+    as_they_come = jax.tree.map(lambda x: sds(x.shape, jnp.bfloat16), abstract)
+    placed = dict(as_they_come, **{
+        name: sds(abstract[name].shape, jnp.bfloat16, _placed_format(one_chip, abstract[name].shape, jnp.bfloat16))
+        for name in fam.row_gathered
+    })
+    shape = pool_stored_shape(L, P, KV, page, D, jnp.bfloat16)
+    pool = sds(shape, jnp.bfloat16, _default_format(one_chip, shape, jnp.bfloat16))
+    i32, u32 = jnp.int32, jnp.uint32
+    fn, host = {
+        "decode": (
+            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
+        ),
+        "chunk": (
+            lambda p, k, v, ids, start, plen, pages, bt, key:
+                smodel.paged_chunk_prefill(cfg, p, ids, start, plen, k, v, pages, bt, key),
+            (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
+        "mixed": (
+            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key:
+                smodel.paged_mixed_step(cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32), sds((1, C), i32), sds((), i32),
+             sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
+        ),
+        "prefill": (
+            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
+        ),
+    }[program]
+    tables = {dims for name in fam.row_gathered for dims in (abstract[name].shape, abstract[name].shape[::-1])}
+    table_bytes = 2 * sum(abstract[name].size for name in fam.row_gathered)
+
+    def compiled_over(params):
+        pset = object.__new__(ProgramSet)
+        pset.__dict__.update(
+            placement=Placement("v5e", [one_chip._device], 1), params=params, k_pool=pool, v_pool=pool,
+            kv_scales=None, window_pools=None, _kv_axis=pool.ndim - 3, num_pages=P, page_size=page,
+            n_kv_head=KV, head_dim=D, n_layer=L,
+        )
+        exe = pset.aot(fn, host, with_params=True)   # or WeightLayoutError: the program takes a leaf in another order
+        sized = [d for _, _, d in _relayout_results(exe.as_text()) if d in tables]
+        return exe, sized, pset.program_census(program, exe)[2:], exe.memory_analysis().temp_size_in_bytes
+
+    _, sized, census, parents_temp = compiled_over(as_they_come)
+    assert sorted(sized) == [(1024, 1600), (50257, 1600)]
+    assert census == (2, table_bytes)
+    exe, sized, census, temp = compiled_over(placed)
+    assert sized == [] and census == (0, 0)
+    assert parents_temp - temp >= 150e6
+    for name in fam.row_gathered:
+        assert exe.input_formats[0][0][name] == placed[name].format
+        assert tuple(placed[name].format.layout.major_to_minor) == (0, 1)
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "mixed", "prefill"])

@@ -187,6 +187,26 @@ def test_the_cache_is_one_pool_of_one_latent_row_a_token(engine, served):
     assert srv.stats()["kv_window_bytes"] == 0
 
 
+def test_the_weights_census_counts_this_familys_programs_too(engine, served, monkeypatch):
+    """ISSUE 61: ``serving_weight_relayout_bytes`` has a value for each program
+    of a family that names no ``row_gathered`` leaves (the census only counts
+    there: no leaf is laid anew, nothing is refused); with the threshold at
+    this size's leaves it is what ``program_census`` reads of each program."""
+    from deepspeed_tpu.serving import placement
+
+    srv, _ = served
+    assert not hasattr(srv.family, "row_gathered")
+    assert all(a is b for a, b in zip(jax.tree.leaves(srv.decode_set.params), jax.tree.leaves(engine.params)))
+    gauge = srv.metrics.get("serving_weight_relayout_bytes")
+    names = [name for name, _ in srv.executable_names()]
+    assert names and all(gauge.value(program=name) == 0 for name in names)   # no leaf of a megabyte here
+    monkeypatch.setattr(placement, "WEIGHT_LEAF_MIN_BYTES", 1 << 10)
+    for name in names:
+        rec = srv._program_info[name]
+        ops, nbytes = rec["pset"].program_census(name, rec["exe"])[2:]
+        assert ops >= 0 and (nbytes > 0) == (ops > 0)
+
+
 def test_spans_and_counters_count_latent_rows_and_expert_loads(engine, prompts):
     t0 = spans.snapshot()[-1][2] if spans.snapshot() else 0.0
     srv, reqs = _serve(engine, prompts[:4])

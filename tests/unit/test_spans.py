@@ -269,13 +269,14 @@ def test_serving_setup_is_recorded_as_phases_that_name_their_programs(served):
     progs = [p for p in phases if p[0] == "ds.init.programs"]
     assert len(progs) == 1 and progs[0][3]["what"] == "serving"
     # and the census of the compiled programs (ISSUE 29): <program>=<n> each
-    # and, since ISSUE 31, the grid steps of one call of its paged attention kernel
-    assert set(progs[0][3]) == {"what", "relayout_ops", "temp_bytes", "grid_steps",
+    # and, since ISSUE 31, the grid steps of one call of its paged attention kernel,
+    # since ISSUE 61 the whole weight leaves it copies for another order
+    assert set(progs[0][3]) == {"what", "relayout_ops", "weight_relayout", "temp_bytes", "grid_steps",
                                 "kv_bytes", "window_pages_per_slot", "moe_experts_held", "kv_row_bytes"}
     # GPT-2: every layer's KV is paged, no window ring, no expert layer
     assert progs[0][3]["kv_bytes"].endswith("window=0") and progs[0][3]["kv_bytes"].startswith("paged=")
     assert progs[0][3]["window_pages_per_slot"] == 0 and progs[0][3]["moe_experts_held"] == 0
-    counts = {progs[0][3][k].count("=") for k in ("relayout_ops", "temp_bytes", "grid_steps")}
+    counts = {progs[0][3][k].count("=") for k in ("relayout_ops", "weight_relayout", "temp_bytes", "grid_steps")}
     assert len(counts) == 1 and counts.pop() >= 2
     inside = [p for p in phases if p[0].startswith("ds.jit.") and progs[0][1] <= p[1] and p[2] <= progs[0][2]]
     assert {p[0] for p in inside} == {"ds.jit.trace", "ds.jit.lower", "ds.jit.compile"}
