@@ -23,7 +23,6 @@ P = exp(S - lse) blockwise, using delta = rowsum(dO * O).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -358,37 +357,6 @@ def _win_arr(window) -> jnp.ndarray:
     return jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 
 
-def _fwd_call(q, k, v, window, *, S, D, BH, head_idx, kv_idx, lse_idx,
-              o_shape, lse_shape, sm_scale, causal, interpret, kv_rep=1):
-    """ONE pallas_call site for the resident forward, shared by the 3D
-    ([BH,S,D]) and S-major ([B,S,E]) layouts — they differ only in index
-    maps and output shapes; the kernel body is identical."""
-    bq, bk = flash_plan(S, D, q.dtype.itemsize, kv_rep, causal=causal)
-    _note_plan(S, BH, bq, bk, causal)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, seq_len=S, bk=bk,
-        windowed=window is not None,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(BH, S // bq),
-            in_specs=[
-                pl.BlockSpec((1, bq, D), head_idx),
-                pl.BlockSpec((1, S, D), kv_idx),
-                pl.BlockSpec((1, S, D), kv_idx),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, D), head_idx),
-                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
-            ],
-        ),
-        interpret=interpret,
-        out_shape=[o_shape, lse_shape],
-    )(_win_arr(window), q, k, v)
-
-
 def _fwd(q3, k3, v3, sm_scale: float, causal: bool, interpret: bool = False, kv_rep: int = 1, window=None):
     """q3: [BH, S, D], k3/v3: [BH // kv_rep, S, D] → (o [BH,S,D], lse).
 
@@ -402,15 +370,35 @@ def _fwd(q3, k3, v3, sm_scale: float, causal: bool, interpret: bool = False, kv_
     a scalar-prefetch operand so one compiled kernel serves every per-layer
     window (GPT-Neo alternating local/global layers under one lax.scan)."""
     BH, S, D = q3.shape
-    return _fwd_call(
-        q3, k3, v3, window, S=S, D=D, BH=BH,
-        head_idx=lambda b, i, w: (b, i, 0),
-        kv_idx=lambda b, i, w: (b // kv_rep, 0, 0),
-        lse_idx=lambda b, i, w: (b, i, 0),
-        o_shape=jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
-        lse_shape=jax.ShapeDtypeStruct((BH, S, NUM_LANES), jnp.float32),
-        sm_scale=sm_scale, causal=causal, interpret=interpret, kv_rep=kv_rep,
+    bq, bk = flash_plan(S, D, q3.dtype.itemsize, kv_rep, causal=causal)
+    _note_plan(S, BH, bq, bk, causal)
+    kernel = functools.partial(
+        _fwd_kernel, sm_scale=sm_scale, causal=causal, seq_len=S, bk=bk,
+        windowed=window is not None,
     )
+    head_idx = lambda b, i, w: (b, i, 0)  # noqa: E731
+    kv_idx = lambda b, i, w: (b // kv_rep, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, S // bq),
+            in_specs=[
+                pl.BlockSpec((1, bq, D), head_idx),
+                pl.BlockSpec((1, S, D), kv_idx),
+                pl.BlockSpec((1, S, D), kv_idx),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, D), head_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), head_idx),
+            ],
+        ),
+        interpret=interpret,
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
+            jax.ShapeDtypeStruct((BH, S, NUM_LANES), jnp.float32),
+        ],
+    )(_win_arr(window), q3, k3, v3)
 
 
 # ---------------------------------------------------------------------------
@@ -475,12 +463,11 @@ def _bwd_dkv_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk
 # next to the per-block operands; larger resident shapes fall back to the
 # split dq/dkv kernels.
 FUSED_BWD_BYTES = 8 * 1024 * 1024
-_FUSED_BWD_ENABLED = os.environ.get("DS_FLASH_FUSED_BWD", "1") != "0"
 
 
 def _fused_bwd_ok(S: int, D: int, kv_rep: int = 1) -> bool:
     per_elem = 20 if kv_rep > 1 else 16
-    return _FUSED_BWD_ENABLED and S * D * per_elem <= FUSED_BWD_BYTES
+    return S * D * per_elem <= FUSED_BWD_BYTES
 
 
 def _bwd_fused_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -525,12 +512,15 @@ def _bwd_fused_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_fused_call(q, k, v, do, lse, delta, window, *, S, D, BH, head_idx,
-                    kv_idx, dkv_idx, lse_idx, dq_shape, dkv_shape,
-                    sm_scale, causal, interpret, kv_rep=1):
-    """ONE pallas_call site for the fused backward, shared by the 3D and
-    S-major layouts (index maps + output shapes differ, body is shared)."""
-    bq, bk = flash_plan(S, D, q.dtype.itemsize, kv_rep, backward=True, causal=causal)
+def _bwd_fused(q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, window):
+    BH, S, D = q3.shape
+    bq, bk = flash_plan(S, D, q3.dtype.itemsize, kv_rep, backward=True, causal=causal)
+    head_idx = lambda b, i, w: (b, i, 0)  # noqa: E731
+    kv_idx = lambda b, i, w: (b // kv_rep, 0, 0)  # noqa: E731
+    # dk/dv staged PER Q HEAD (b, not b//kv_rep): under GQA the group is
+    # summed outside in f32 so the storage rounding happens exactly once
+    dkv_idx = lambda b, i, w: (b, 0, 0)  # noqa: E731
+    dkv_shape = jax.ShapeDtypeStruct((BH, S, D), jnp.float32 if kv_rep > 1 else q3.dtype)
     return pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
@@ -544,8 +534,8 @@ def _bwd_fused_call(q, k, v, do, lse, delta, window, *, S, D, BH, head_idx,
                 pl.BlockSpec((1, S, D), kv_idx),
                 pl.BlockSpec((1, S, D), kv_idx),
                 pl.BlockSpec((1, bq, D), head_idx),
-                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
-                pl.BlockSpec((1, bq, NUM_LANES), lse_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), head_idx),
+                pl.BlockSpec((1, bq, NUM_LANES), head_idx),
             ],
             out_specs=[
                 pl.BlockSpec((1, bq, D), head_idx),
@@ -558,26 +548,8 @@ def _bwd_fused_call(q, k, v, do, lse, delta, window, *, S, D, BH, head_idx,
             ],
         ),
         interpret=interpret,
-        out_shape=[dq_shape, dkv_shape, dkv_shape],
-    )(_win_arr(window), q, k, v, do, lse, delta)
-
-
-def _bwd_fused(q3, k3, v3, delta, lse, do3, sm_scale, causal, interpret, kv_rep, window):
-    BH, S, D = q3.shape
-    return _bwd_fused_call(
-        q3, k3, v3, do3, lse, delta, window, S=S, D=D, BH=BH,
-        head_idx=lambda b, i, w: (b, i, 0),
-        kv_idx=lambda b, i, w: (b // kv_rep, 0, 0),
-        # dk/dv staged PER Q HEAD (b, not b//kv_rep): under GQA the group is
-        # summed outside in f32 so the storage rounding happens exactly once
-        dkv_idx=lambda b, i, w: (b, 0, 0),
-        lse_idx=lambda b, i, w: (b, i, 0),
-        dq_shape=jax.ShapeDtypeStruct((BH, S, D), q3.dtype),
-        dkv_shape=jax.ShapeDtypeStruct(
-            (BH, S, D), jnp.float32 if kv_rep > 1 else q3.dtype
-        ),
-        sm_scale=sm_scale, causal=causal, interpret=interpret, kv_rep=kv_rep,
-    )
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), q3.dtype), dkv_shape, dkv_shape],
+    )(_win_arr(window), q3, k3, v3, do3, lse, delta)
 
 
 def _bwd(q3, k3, v3, o3, lse, do3, sm_scale: float, causal: bool, interpret: bool = False, kv_rep: int = 1, window=None):
@@ -1065,89 +1037,6 @@ def _flash_bwd_rule(sm_scale, causal, interpret, kv_rep, res, do3):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-# ---------------------------------------------------------------------------
-# S-major ([B, S, H*D]) entry: the kernels read each head's D-lane slice
-# straight out of the fused [B,S,E] activations via lane-offset index maps
-# ((bh // H, i, bh % H) block coords), so the [B,S,H,D] <-> [B*H,S,D]
-# physical transposes around the 3D entry — XLA copies, ~30 ms each at the
-# round-4 shape, 8+ per layer across fwd/recompute/bwd — never exist.
-# Kernel BODIES are shared with the 3D path; only the pallas_call block
-# maps differ. MHA resident shapes with the fused backward only (GQA dk/dv
-# would need cross-grid-step output accumulation over the group).
-# ---------------------------------------------------------------------------
-
-# OPT-IN (DS_FLASH_BSE=1), and D % 128 == 0 only: the D-lane blocks sit at
-# h*D lane offsets inside E, and a D=64 block is a sub-128-lane block that
-# the Pallas TPU lowering rejects (chip run, PR 21: "last two dimensions of
-# your block shape are divisible by 8 and 128"). D=128 compiled and matched
-# the 3D path on a v5e (TestBSEFlashHardware).
-_BSE_ENABLED = os.environ.get("DS_FLASH_BSE", "0") == "1"
-
-
-def _bse_ok(S: int, D: int, itemsize: int = 2) -> bool:
-    return (
-        _BSE_ENABLED and D % NUM_LANES == 0
-        and resident_ok(S, D, itemsize) and _fused_bwd_ok(S, D)
-    )
-
-
-def _fwd_bse(q2, k2, v2, H: int, sm_scale, causal, interpret, window):
-    B, S, E = q2.shape
-    D = E // H
-    return _fwd_call(
-        q2, k2, v2, window, S=S, D=D, BH=B * H,
-        head_idx=lambda bh, i, w: (bh // H, i, bh % H),
-        kv_idx=lambda bh, i, w: (bh // H, 0, bh % H),
-        lse_idx=lambda bh, i, w: (bh, i, 0),
-        o_shape=jax.ShapeDtypeStruct((B, S, E), q2.dtype),
-        lse_shape=jax.ShapeDtypeStruct((B * H, S, NUM_LANES), jnp.float32),
-        sm_scale=sm_scale, causal=causal, interpret=interpret,
-    )
-
-
-def _bwd_fused_bse(q2, k2, v2, o2, lse, do2, H: int, sm_scale, causal, interpret, window):
-    B, S, E = q2.shape
-    D = E // H
-    BH = B * H
-    d4 = do2.astype(jnp.float32).reshape(B, S, H, D)
-    o4 = o2.astype(jnp.float32).reshape(B, S, H, D)
-    delta = jnp.sum(d4 * o4, axis=-1).transpose(0, 2, 1).reshape(BH, S)  # [B,S,H] transpose: E-free, cheap
-    delta = jnp.broadcast_to(delta[..., None], (BH, S, NUM_LANES))
-    return _bwd_fused_call(
-        q2, k2, v2, do2, lse, delta, window, S=S, D=D, BH=BH,
-        head_idx=lambda bh, i, w: (bh // H, i, bh % H),
-        kv_idx=lambda bh, i, w: (bh // H, 0, bh % H),
-        dkv_idx=lambda bh, i, w: (bh // H, 0, bh % H),
-        lse_idx=lambda bh, i, w: (bh, i, 0),
-        dq_shape=jax.ShapeDtypeStruct((B, S, E), q2.dtype),
-        dkv_shape=jax.ShapeDtypeStruct((B, S, E), k2.dtype),
-        sm_scale=sm_scale, causal=causal, interpret=interpret,
-    )
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_bse(q2, k2, v2, window, H: int, sm_scale: float, causal: bool, interpret: bool):
-    o, _ = _fwd_bse(q2, k2, v2, H, sm_scale, causal, interpret, window)
-    return o
-
-
-def _flash_bse_fwd_rule(q2, k2, v2, window, H, sm_scale, causal, interpret):
-    o, lse = _fwd_bse(q2, k2, v2, H, sm_scale, causal, interpret, window)
-    return o, (q2, k2, v2, o, lse, window)
-
-
-def _flash_bse_bwd_rule(H, sm_scale, causal, interpret, res, do2):
-    q2, k2, v2, o2, lse, window = res
-    dq, dk, dv = _bwd_fused_bse(
-        q2, k2, v2, o2, lse, do2, H, sm_scale, causal, interpret, window
-    )
-    win_ct = None if window is None else np.zeros((1,), jax.dtypes.float0)
-    return dq, dk, dv, win_ct
-
-
-_flash_bse.defvjp(_flash_bse_fwd_rule, _flash_bse_bwd_rule)
-
-
 def validate_kv_heads(H: int, k, v) -> int:
     """THE kv-head rule (one copy; decode + dispatch share it): K/V head
     counts must match and divide the q head count. Returns rep = H // KV."""
@@ -1214,17 +1103,6 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
     scale = sm_scale if sm_scale is not None else 1.0 / (D**0.5)
 
     win = None if window is None else _win_arr(window)
-    if rep == 1 and _bse_ok(S, D, q.dtype.itemsize):
-        # S-major path: head slices read via lane-offset index maps — the
-        # reshapes below are free (contiguous), no physical transposes
-        E = H * D
-        with parts.unscoped():
-            o2 = _flash_bse(
-                q.reshape(B, S, E), k.reshape(B, S, E), v.reshape(B, S, E),
-                win, H, float(scale), bool(causal), bool(interpret),
-            )
-        return o2.reshape(B, S, H, D)
-
     def to3(x):
         nh = x.shape[2]
         return x.transpose(0, 2, 1, 3).reshape(B * nh, S, D)

@@ -22,7 +22,7 @@ the jnp fallbacks still gather it and mask.
 On a TPU the pool of a head narrower than 128 lanes is STORED with its page
 axis split (:func:`pool_stored_shape`), which keeps the device's
 default layout row-major; the programs see the ``[L, P, KV, page, D]``
-:func:`pool_view`, and the kernels take that whole view plus a layer index.
+view (:func:`viewed`), and the kernels take that whole view plus a layer index.
 Such a pool is padded tiles, twice its values at 64 lanes, and the kernels'
 DMAs move the padding (PERF.md, PR 56: the same keys as pairs of heads in 128
 lanes cost the one-token kernel 0.58 of the time). Since PR 56 no served
@@ -35,7 +35,7 @@ model, which keeps a head and a scale a published head, is still split.
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -289,8 +289,8 @@ def pool_stored_shape(n_layer: int, num_pages: int, n_kv_head: int,
     elements in the same order with the page axis split into axes of at
     most ``PAGE_GROUP``, ``[L, P // g, g, KV, page, D]`` (512 pages:
     ``[L, 8, 64, ...]``; 8192: ``[L, 2, 64, 64, ...]``). Every program sees
-    the 5-D :func:`pool_view`; only ``placement.ProgramSet`` handles the
-    stored shape.
+    the 5-D view (:func:`viewed`); only ``placement.ProgramSet.aot`` handles
+    the stored shape.
 
     Why. The TPU's default layout of an array whose minor dimension does not
     fill the lanes puts an axis LONGER than that dimension in its place:
@@ -346,10 +346,147 @@ def pool_stored_shape(n_layer: int, num_pages: int, n_kv_head: int,
     return (n_layer, *_page_axes(num_pages), *shape[2:])
 
 
-def pool_view(pool):
-    """The ``[L, P, KV, page, D]`` view every program works on, of a pool in
-    its :func:`pool_stored_shape` (a bitcast, or the pool itself)."""
-    return pool.reshape(pool.shape[0], -1, *pool.shape[-3:])
+class Cache(NamedTuple):
+    """The served cache of one placement, as ONE value (docs/SERVING.md, "The
+    cache"): ``placement.ProgramSet`` owns it, every served program takes it as
+    one donated argument and gives it back as its first result. A field a
+    family has no use for is ``None``, which a jitted program flattens into
+    nothing: the buffers a program takes and returns are the fields that are
+    there, in this order."""
+
+    k: Any                # the paged K pool [L, P, KV, page, D]; a latent family's ONE pool [L, P, 1, page, W]
+    v: Any = None         # the paged V pool; None: a latent family
+    scales: Any = None    # [L, P, KV, 2] float32, an int8 cache's scale a page and head (K, V)
+    win_k: Any = None     # a window layer's K ring [Lw, 1 + slots * ring, KV, page, D]
+    win_v: Any = None
+    rec: Any = None       # a recurrent sub-block's state a slot, float32: [Ls, slots, N, d_inner] or [Ll, slots, Hv, dk, dv]
+    conv: Any = None      # its convolution's last K - 1 input rows [Ls, slots, K - 1, channels]
+    carry: Any = None     # the rows an attention carries from call to call [La, slots, carry_width]
+
+    @property
+    def latent(self) -> bool:
+        return self.v is None
+
+    @property
+    def per_slot(self) -> bool:
+        """Whether a slot owns a part of it outright (a ring, a recurrent
+        state, carried rows): the prefill and chunk programs then take the
+        slot as their last host operand."""
+        return any(x is not None for x in (self.win_k, self.rec, self.carry))
+
+
+PAGED_FIELDS = ("k", "v", "win_k", "win_v")   # the pools of pages: [L, P, KV, page, D], the page axis maybe stored split
+
+
+def viewed(cache: Cache) -> Cache:
+    """``cache`` with every pool of pages as the ``[L, P, KV, page, D]`` view
+    the programs work on, from its :func:`pool_stored_shape` (a bitcast, or
+    the pool itself)."""
+    return cache._replace(**{
+        f: x.reshape(x.shape[0], -1, *x.shape[-3:])
+        for f in PAGED_FIELDS if (x := getattr(cache, f)) is not None
+    })
+
+
+def stored_as(cache: Cache, stored: Cache) -> Cache:
+    """:func:`viewed`'s inverse: ``cache`` with its pools of pages in the
+    shapes ``stored`` has them in."""
+    return cache._replace(**{
+        f: x.reshape(getattr(stored, f).shape)
+        for f in PAGED_FIELDS if (x := getattr(cache, f)) is not None
+    })
+
+
+# ---------------------------------------------------------------------------
+# the kinds of per-slot state a family may hold besides paged per-head pages
+# ---------------------------------------------------------------------------
+
+MECHANISMS = (
+    "serving.prefix_cache", "serving.tiering", "serving.kv_cache_dtype=int8",
+    "serving.placement.tp > 1", "serving.placement.disaggregate",
+    "serving.speculative", "session migration",
+)
+# What moves, shares, shards or re-codes pages knows K and V pools of per-head
+# pages only, so none of it handles another kind. A draft is another matter:
+# a rejected draft's rows are overwritten where they lie in pages and rings,
+# so only a state that cannot be rolled back refuses one.
+_NONE_HANDLES = frozenset(MECHANISMS)
+_ROLLED_BACK = _NONE_HANDLES - {"serving.speculative"}
+
+
+def _recurrent(fam) -> bool:
+    return bool({"ssm", "lin"} & set(getattr(fam, "kinds", None) or ()))
+
+
+def _state_what(fam) -> str:
+    """What a recurrent state is, for the refusals: its bytes a slot and
+    sub-block differ by 6x between the two."""
+    if "lin" in (getattr(fam, "kinds", None) or ()):
+        return ("a linear-attention layer's matrix state a value head "
+                f"({4 * int(np.prod(fam.lin_state)) / 1e6:.1f} MB a slot and layer)")
+    return "a state-space layer's scan state"
+
+
+class StateKind(NamedTuple):
+    holds: Callable[[Any], bool]   # whether a family holds it
+    unhandled: frozenset           # the mechanisms that do not handle it
+    admission: str                 # why, where the engine is built ({name}: the model's; {state}: _state_what)
+    migration: str                 # why, where a session would move
+
+
+# in the order the refusals name them
+STATE_KINDS = (
+    # a fixed-size recurrent state a slot (a state-space mixer's, a linear
+    # attention's matrix a value head): no page, cannot be cut at a prefix
+    StateKind(
+        _recurrent, _NONE_HANDLES,
+        "recurrent state ({name}): {state} and convolution rows live in a "
+        "per-slot pool beside the paged pool, which this mechanism does not handle",
+        "recurrent state: the transport moves a slot's paged row, and "
+        "{state} and convolution rows would stay behind",
+    ),
+    # under paged K and V, the rows before a call's first
+    # (serving/model._qkv_carried): a cached prefix's pages without the rows
+    # at its end would serve a wrong first token silently
+    StateKind(
+        lambda fam: bool(getattr(fam, "carry_width", 0)), _NONE_HANDLES,
+        "carried attention rows ({name}): the queries, keys and values of a "
+        "call's first rows need the rows before them, which live in a per-slot "
+        "pool beside the paged pool; this mechanism does not handle it (pages "
+        "without the rows at their end would serve a wrong token)",
+        "carried attention rows: the transport moves a slot's paged row, and "
+        "the rows its next token's queries, keys and values need would stay behind",
+    ),
+    StateKind(
+        lambda fam: any(fam.windows), _ROLLED_BACK,
+        "sliding-window layers ({name}): a window layer's KV lives in a "
+        "per-slot ring beside the paged pool, which this mechanism does not handle",
+        "sliding-window layers: the transport moves a slot's paged row, and "
+        "its window rings would stay behind",
+    ),
+    StateKind(
+        lambda fam: fam.kv_pools == 1, _ROLLED_BACK,
+        "a latent KV pool ({name}): its cache is one pool of one row a token "
+        "that every head reads, with no V pool and no head axis, which this "
+        "mechanism does not handle",
+        "a latent KV pool: the transport packs a K and a V pool's page columns",
+    ),
+)
+
+
+def refusals(fam, mechanism: str, name: str = "") -> List[str]:
+    """Why ``mechanism`` (one of :data:`MECHANISMS`) does not serve the family
+    ``fam``: a sentence a kind of state it holds that the mechanism does not
+    handle, every one by name (a family may hold more than one: a recurrent
+    state beside a latent pool); empty where it serves. ``name``: the model's,
+    as the admission sentences cite it."""
+    if mechanism not in MECHANISMS:
+        raise ValueError(f"unknown mechanism {mechanism!r}")
+    return [
+        (kind.migration if mechanism == "session migration" else kind.admission)
+        .format(name=name, state=_state_what(fam))
+        for kind in STATE_KINDS if kind.holds(fam) and mechanism in kind.unhandled
+    ]
 
 
 def init_pools(

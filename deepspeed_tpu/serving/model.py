@@ -170,17 +170,27 @@ def _token_codes(scales, l, pidx, poff, vals, sidx):
     return quantize_kv_token(vals, s), scales.at[l, pidx, :, sidx].set(s)
 
 
-def _write_pool_tokens(k_pool, v_pool, scales, l, pidx, poff, k_vals, v_vals,
-                       shared=False):
+def _write_pool_tokens(cache, l, pidx, poff, k_vals, v_vals, shared=False):
     """One-token write: ``k_vals`` / ``v_vals [B, KV, D]`` to (layer ``l``,
-    page ``pidx[b]``, offset ``poff[b]``) of both pools, quantized at write
-    when they are int8 → ``(k_pool, v_pool, scales)``."""
-    k_vals, scales = _token_codes(scales, l, pidx, poff, k_vals, 0)
+    page ``pidx[b]``, offset ``poff[b]``) of both paged pools, quantized at
+    write when they are int8 → the cache."""
+    k_vals, scales = _token_codes(cache.scales, l, pidx, poff, k_vals, 0)
     v_vals, scales = _token_codes(scales, l, pidx, poff, v_vals, 1)
     k_pool, v_pool = _scatter_tokens(
-        k_pool, v_pool, l, pidx, poff, k_vals, v_vals, shared
+        cache.k, cache.v, l, pidx, poff, k_vals, v_vals, shared
     )
-    return k_pool, v_pool, scales
+    return cache._replace(k=k_pool, v=v_pool, scales=scales)
+
+
+def _write_cache_pages(cache, l, page_ids, k_c, v_c, dtype=None):
+    """:func:`_write_pool_pages` for a chunk's K and V ``[1, S, KV, D]`` (cast
+    to ``dtype`` where one is given) into layer ``l`` of the paged pools →
+    ``(cache, k_att, v_att)``, what attention must read of either."""
+    page = cache.k.shape[3]
+    cast = (lambda x: x) if dtype is None else (lambda x: x.astype(dtype))
+    k_pool, scales, k_att = _write_pool_pages(cache.k, cache.scales, l, page_ids, _page_chunks(cast(k_c), page), 0)
+    v_pool, scales, v_att = _write_pool_pages(cache.v, scales, l, page_ids, _page_chunks(cast(v_c), page), 1)
+    return cache._replace(k=k_pool, v=v_pool, scales=scales), k_att, v_att
 
 
 def _gather_dense(k_pool_l, v_pool_l, block_tables, scales_l=None):
@@ -221,12 +231,13 @@ class Family:
       a family whose layer holds two attentions (LongCat-Flash's double
       layer) gives twice its depth, ``layer(params, l)`` the l-th sub-block's
       weights, and ties the sub-blocks together through ``after_attention``.
-    - ``kv_pools, v_width``: what a program needs to know of the cache. 2: a
+    - ``kv_pools, v_width``: what a program needs to know of the cache (the
+      fields of ``kv_cache.Cache``: docs/SERVING.md, "The cache"). 2: a
       K and a V pool of ``n_kv_head`` heads ``head_dim`` wide (``v_width`` is
       ``head_dim``). 1: a LATENT family, ONE pool of one row a token
       (``n_kv_head`` 1, ``head_dim`` the row's width) that every query head
-      reads, whose first ``v_width`` lanes are the values; the programs then
-      get ``v_pool = None``, ``qkv`` gives the absorbed query ``[B,S,H,
+      reads, whose first ``v_width`` lanes are the values; the cache then
+      has ``v = None``, ``qkv`` gives the absorbed query ``[B,S,H,
       head_dim]``, the row ``[B,S,1,head_dim]`` and ``None``, ``attn_out``
       takes ``[B,S,H*v_width]``, the family states its ``sm_scale``, and the
       whole-prompt program attends per head through ``qkv_expanded(lp, h,
@@ -285,8 +296,8 @@ class Family:
       branch on it at trace time, and a family of the second kind states
       ``lin_g_min``, the lower bound of its decays (the chunk kernel's form
       rests on it). ``"lin"`` sub-blocks beside ``kv_pools == 1`` are served
-      (Ling-3.0-flash: the state pools beside ONE latent pool; the pools'
-      layers count each kind alone, :func:`_kv_homes`).
+      (Ling-3.0-flash: ``cache.rec`` and ``cache.conv`` beside ONE latent
+      pool; the pools' layers count each kind alone, :func:`_kv_homes`).
     - ``prefill_block``: 0, or the query rows the whole-prompt program
       attends at a time (where ``[H, Sp, Sp]`` scores would not fit).
     - ``sparse_layers`` / ``experts_held``: the layers (sub-blocks) that
@@ -326,8 +337,8 @@ class Family:
       first rows need rows of the call before (ZAYA's CCA: two kernel-2
       convolutions over the latent and a value shifted by one token), so the
       programs keep ``carry_width`` values a slot and ``"attn"`` sub-block in
-      one more donated pool (``state``'s last, ``[attn sub-blocks, slots,
-      carry_width]`` in the cache's type; :func:`_qkv_carried`) and the
+      ``cache.carry`` (``[attn sub-blocks, slots, carry_width]`` in the
+      cache's type; :func:`_qkv_carried`) and the
       family gives ``qkv`` in two pieces: ``attn_in(lp, h) -> p [B,S,Wp]``,
       what a row needs of the weights alone (the norm, the projections), and
       ``attn_mix(lp, p, prev [B, carry_width], positions, l) -> q, k, v, nxt
@@ -446,21 +457,12 @@ def _window_views(fam, slots, pos0, page: int, ring: int):
     }
 
 
-def _result(k_pool, v_pool, scales, win, token, counts, state=None):
-    """A program's results in the order the scheduler takes them: the paged
-    pools, an int8 pool's scales, a window family's ring pools, a recurrent
-    family's two state pools and the rows a family's attentions carry (``state``, as it came), the token(s) and,
-    for a family with expert layers, the tokens each held expert got
-    ``[sparse layers, experts_held]`` (one entry more a layer where the
-    router has identity columns: the pairs that chose one)."""
-    out = (k_pool, v_pool)  # v_pool None: a latent family's (ProgramSet.aot drops it)
-    if scales is not None:
-        out += (scales,)
-    if win is not None:
-        out += tuple(win)
-    if state is not None:
-        out += tuple(state)
-    return out + (token,) + ((jnp.stack(counts),) if counts else ())
+def _result(cache, token, counts):
+    """A program's results: the cache, the token(s) and, for a family with
+    expert layers, the tokens each held expert got ``[sparse layers,
+    experts_held]`` (one entry more a layer where the router has identity
+    columns: the pairs that chose one)."""
+    return (cache, token) + ((jnp.stack(counts),) if counts else ())
 
 
 # ---------------------------------------------------------------------------
@@ -531,12 +533,10 @@ def _attend_latent(fam, q, pool, l, block_tables, base, name, live=None):
 # state-space mixer over the recurrent state pools, a gated memory unit and a
 # cross-attention over what other sub-blocks made
 #
-# ``state = (ssm, conv)`` (a family of linear attentions: ``(lin, conv)``, ``lin
-# [Ll, slots, Hv, dk, dv]`` float32, :func:`_lin_block`): ``ssm [Ls, slots, N, d_inner]`` float32, the scan
-# state a slot and "ssm" sub-block (the channels on the lanes:
-# ``ops/pallas/selective_scan.py``), and ``conv [Ls, slots, K - 1, d_inner]``,
-# the convolution's last inputs. Both are donated through every program like
-# the ring pools. A request's first rows start from zeros whatever the slot
+# ``cache.rec [Ls, slots, N, d_inner]`` float32 is the scan state a slot and
+# "ssm" sub-block (the channels on the lanes: ``ops/pallas/selective_scan.py``;
+# a family of linear attentions: ``[Ll, slots, Hv, dk, dv]``, :func:`_lin_block`)
+# and ``cache.conv [Ls, slots, K - 1, d_inner]`` the convolution's last inputs. A request's first rows start from zeros whatever the slot
 # held; a row that is padding, or an idle slot's, moves neither: its ``dt`` is
 # 0 (``exp(0) h + 0``) and its convolution rows are not shifted.
 # ---------------------------------------------------------------------------
@@ -577,8 +577,8 @@ def _conv_carried(w_conv, b_conv, rows, conv, li, C: int, chunk, real):
     return (cs[0] if len(cs) == 1 else jnp.concatenate(cs)), conv
 
 
-def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
-    """The state-space mixer of one sub-block (``li``-th of the state pools)
+def _ssm_block(fam, lp, h, cache, li, C: int = 0, chunk=None, real=None):
+    """The state-space mixer of one sub-block (``li``-th of ``cache.rec``)
     over the rows of ``h`` (``[1, C + B, E]``, or the decode step's ``[B, 1,
     E]``): the first ``C`` rows are ONE slot's chunk, ``chunk = (slot, start,
     n_real)`` (rows from ``n_real`` on are padding; ``start`` 0: the request's
@@ -586,10 +586,10 @@ def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
     decoding request. Both sets of rows go through the projections once and
     part for the convolution and the scan alone. → (the mixer's output, shaped
     like ``h``; the scan's ``s [..., d_inner]`` before the gate, float32:
-    what a gated memory unit reads; the state)."""
+    what a gated memory unit reads; the cache)."""
     from ..ops.pallas.selective_scan import scan_rows, scan_step
 
-    ssm, conv = state
+    ssm, conv = cache.rec, cache.conv
     xs, z = fam.ssm_in(lp, h)
     rows = xs.reshape(-1, xs.shape[-1])
     A, D, w_conv, b_conv = fam.ssm_consts(lp)
@@ -618,13 +618,13 @@ def _ssm_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
             )
             ss.append(s)
         s = (ss[0] if len(ss) == 1 else jnp.concatenate(ss)).reshape(xs.shape)
-    return fam.ssm_out(lp, s, z), s, (ssm, conv)
+    return fam.ssm_out(lp, s, z), s, cache._replace(rec=ssm, conv=conv)
 
 
-def _lin_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
-    """The linear attention of one sub-block (``li``-th of the state pools,
-    ``state = (lin [Ll, slots, Hv, dk, dv] float32, conv [Ll, slots, K - 1,
-    channels])``) over the rows of ``h`` as :func:`_ssm_block` takes them: the
+def _lin_block(fam, lp, h, cache, li, C: int = 0, chunk=None, real=None):
+    """The linear attention of one sub-block (``li``-th of ``cache.rec [Ll,
+    slots, Hv, dk, dv]`` float32 and ``cache.conv [Ll, slots, K - 1,
+    channels]``) over the rows of ``h`` as :func:`_ssm_block` takes them: the
     first ``C`` ONE slot's chunk, ``chunk = (slot, start, n_real)``, the others
     a row a slot, ``real [B]`` the slots that decode. Both sets of rows go
     through the family's projections once and part for the convolution and the
@@ -632,10 +632,10 @@ def _lin_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
     sub-chunks from the slot's carried state, zeros at ``start`` 0; a row a
     LIVE slot against the pool in place). A row that is padding has ``g`` and
     ``beta`` 0 and moves nothing. → (the mixer's output, shaped like ``h``;
-    the state)."""
+    the cache)."""
     from ..ops.pallas import gated_delta
 
-    lin, conv = state
+    lin, conv = cache.rec, cache.conv
     m, rest = fam.lin_in(lp, h)
     impl = getattr(fam, "lin_impl", "auto")
     with parts.part("lin.scan"):
@@ -662,52 +662,51 @@ def _lin_block(fam, lp, h, state, li, C: int = 0, chunk=None, real=None):
             o, lin = gated_delta.step(q[C:], k[C:], v[C:], g[C:], beta[C:], lin, li, real, impl=impl)
             os.append(o)
         o = os[0] if len(os) == 1 else jnp.concatenate(os)
-    return fam.lin_out(lp, o.reshape(*h.shape[:-1], *o.shape[1:]), rest), (lin, conv)
+    return fam.lin_out(lp, o.reshape(*h.shape[:-1], *o.shape[1:]), rest), cache._replace(rec=lin, conv=conv)
 
 
-def _mixer_without_kv(fam, lp, h, l, li, positions, carry, state, tp_axis, rows, attend):
+def _mixer_without_kv(fam, lp, h, l, li, positions, carry, cache, tp_axis, rows, attend):
     """The mixer of a sub-block that writes no K/V, by its kind → (its output
     ``[..., E]``, projected: :func:`_after_attention` takes it with
-    :func:`_passed`; ``carry``; ``state``). ``rows``: :func:`_ssm_block`'s
+    :func:`_passed`; ``carry``; the cache). ``rows``: :func:`_ssm_block`'s
     ``(C, chunk, real)`` for this program's rows. ``attend(q, li)``: how this
     program's rows read the pages of a cross layer's source."""
     kind = fam.kinds[l]
     if kind == "lin":
-        a, state = _lin_block(fam, lp, h, state, li, *rows)
-        return a, carry, state
+        a, cache = _lin_block(fam, lp, h, cache, li, *rows)
+        return a, carry, cache
     if kind == "ssm":
-        a, s, state = _ssm_block(fam, lp, h, state, li, *rows)
-        return a, {**(carry or {}), l: s}, state     # what its gated memory units will read
+        a, s, cache = _ssm_block(fam, lp, h, cache, li, *rows)
+        return a, {**(carry or {}), l: s}, cache     # what its gated memory units will read
     if kind == "gmu":
-        return fam.gmu(lp, h, carry[fam.sources[l]], tp_axis), carry, state
+        return fam.gmu(lp, h, carry[fam.sources[l]], tp_axis), carry, cache
     with parts.part("attn.qkv"):
         q = fam.q_cross(lp, h, positions, l)
     o = attend(q, li)
     with parts.part("attn.out"):
-        return fam.attn_out(lp, o, tp_axis), carry, state
+        return fam.attn_out(lp, o, tp_axis), carry, cache
 
 
 # ---------------------------------------------------------------------------
 # an attention that carries rows (``fam.carry_width``): K and V paged like any
 # other, and ``carry_width`` values a slot and sub-block from call to call
 #
-# ``rows [La, slots, carry_width]`` (the cache's type) is the LAST of ``state``,
-# donated through every program like the scan state. The whole-prompt program
+# ``cache.carry [La, slots, carry_width]`` (the cache's type): the whole-prompt program
 # takes a slot's rows at the prompt's TRUE length, not the bucket's; the chunk
 # program hands them from chunk to chunk and starts a request from zeros
 # whatever the slot held; a decode row reads and shifts its slot's; an idle
 # slot's row, or padding, moves nothing.
 # ---------------------------------------------------------------------------
 
-def _qkv_carried(fam, lp, h, positions, l, state, C: int = 0, chunk=None, real=None):
-    """``fam.qkv`` for sub-block ``l`` of a family that carries rows (``state``'s
-    last pool, whose layers are the ``"attn"`` sub-blocks in order), over the
+def _qkv_carried(fam, lp, h, positions, l, cache, C: int = 0, chunk=None, real=None):
+    """``fam.qkv`` for sub-block ``l`` of a family that carries rows
+    (``cache.carry``, whose layers are the ``"attn"`` sub-blocks in order), over the
     rows of ``h`` as :func:`_ssm_block` takes them: the first ``C`` ONE slot's
     chunk, ``chunk = (slot, start, n_real)``, the others a row a slot, ``real
     [B]`` the slots that decode. The projections see all rows once
     (``attn_in``); the chunk and the decode rows part for ``attn_mix`` alone.
-    → ``(q, k, v, state)``, q, k and v laid out like ``h``."""
-    rows, ai = state[-1], sub_block_kinds(fam)[:l].count("attn")
+    → ``(q, k, v, cache)``, q, k and v laid out like ``h``."""
+    rows, ai = cache.carry, sub_block_kinds(fam)[:l].count("attn")
     with parts.part("attn.qkv"):
         p = fam.attn_in(lp, h)
     qkv = None
@@ -726,7 +725,7 @@ def _qkv_carried(fam, lp, h, positions, l, state, C: int = 0, chunk=None, real=N
         qkv = qkv_d if qkv is None else [
             jnp.concatenate([c, jnp.swapaxes(d, 0, 1)], axis=1) for c, d in zip(qkv, qkv_d)
         ]
-    return (*qkv, state[:-1] + (rows,))
+    return (*qkv, cache._replace(carry=rows))
 
 
 # ---------------------------------------------------------------------------
@@ -773,32 +772,25 @@ def _attend_prompt_blocked(q, k, v, window: int, block: int, sm_scale=None):
     return lax.map(rows, jnp.arange(Sp // block)).reshape(1, Sp, H * v.shape[-1])
 
 
-def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
-                             scales=None):
+def _attention_prefill_paged(fam, q, k_c, v_c, cache, page_ids, l):
     """Causal self-attention over the prompt chunk; K/V written to layer
-    ``l``'s pages of the FULL pool (quantized at write when ``scales`` is
-    given — the attention then reads the DEQUANTIZED chunk back, so the
+    ``l``'s pages of the FULL pool (quantized at write when the cache has
+    ``scales`` — the attention then reads the DEQUANTIZED chunk back, so the
     first sampled token is consistent with every later read of the same
-    pages). → ``(o [B, Sp, H * D], k_pool, v_pool, scales)``.
+    pages). → ``(o [B, Sp, H * D], cache)``.
 
     The chunk starts at position 0 of a fresh slot, so "the cache" IS the
     chunk — the dense causal einsum here is exactly ``_attention_cached``'s
     prefill path with ``pos = 0`` and ``Smax = Sp``."""
     B, Sp, H, D = q.shape
     KV = k_c.shape[2]
-    page = k_pool.shape[3]
 
     # page-granular scatter: [Sp,KV,D] → [n_pp, KV, page, D] rows of the pool.
     # Whole pages are overwritten — a slot's pages are fresh at admission and
     # padded/garbage positions are masked until the decode write claims them;
     # padded page_ids point at the scratch page.
-    k_pool, scales, k_att = _write_pool_pages(
-        k_pool, scales, l, page_ids, _page_chunks(k_c, page), 0
-    )
-    v_pool, scales, v_att = _write_pool_pages(
-        v_pool, scales, l, page_ids, _page_chunks(v_c, page), 1
-    )
-    if scales is not None:
+    cache, k_att, v_att = _write_cache_pages(cache, l, page_ids, k_c, v_c)
+    if cache.scales is not None:
         # [n_pp, KV, page, D] dequantized → the [B, Sp, KV, D] chunk view
         k_c = jnp.swapaxes(k_att, 1, 2).reshape(B, Sp, KV, D)
         v_c = jnp.swapaxes(v_att, 1, 2).reshape(B, Sp, KV, D)
@@ -806,7 +798,7 @@ def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
         o = _attend_prompt_blocked(
             q, k_c, v_c, 0, math.gcd(Sp, fam.prefill_block), getattr(fam, "sm_scale", None)
         )
-        return o, k_pool, v_pool, scales
+        return o, cache
 
     scale = _sm_scale(fam, D)
     with parts.part("attn.core"):
@@ -823,18 +815,18 @@ def _attention_prefill_paged(fam, q, k_c, v_c, k_pool, v_pool, page_ids, l,
         o = jnp.einsum("bgrst,btgd->bsgrd", probs, v_c)
         # H*D == E at TP=1; under the TP shard_map H is the per-rank head count
         # and the row-parallel projection restores the full embed dim
-        return o.reshape(B, Sp, H * D).astype(q.dtype), k_pool, v_pool, scales
+        return o.reshape(B, Sp, H * D).astype(q.dtype), cache
 
 
-def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
+def _attention_prefill_window(fam, q, k_c, v_c, cache, li, slot, prompt_len,
                               window: int, ring: int):
     """A window layer of the whole-prompt program: a band of ``window`` keys
     a query, and of the prompt's pages only the ring's worth that a later
     query can still reach is written (ring page ``r`` takes the LAST logical
     page ``<=`` the prompt's last that lives in it; a ring page no prompt
     page lives in yet takes page 0's rows, which the decode writes replace
-    before anything reads them). → ``(o, win)``."""
-    k_win, v_win = win
+    before anything reads them). → ``(o, cache)``."""
+    k_win, v_win = cache.win_k, cache.win_v
     Sp = q.shape[1]
     page = k_win.shape[3]
     n_last = (prompt_len - 1) // page
@@ -847,7 +839,7 @@ def _attention_prefill_window(fam, q, k_c, v_c, win, li, slot, prompt_len,
     block = math.gcd(Sp, fam.prefill_block or Sp)
     return _attend_prompt_blocked(
         q, k_c, v_c, window, block, getattr(fam, "sm_scale", None)
-    ), (k_win, v_win)
+    ), cache._replace(win_k=k_win, win_v=v_win)
 
 
 def paged_prefill(
@@ -855,26 +847,19 @@ def paged_prefill(
     params: PyTree,
     input_ids: jnp.ndarray,   # [1, Sp] right-padded to the static prefill width
     prompt_len: jnp.ndarray,  # traced i32: true prompt length
-    k_pool: jnp.ndarray,      # [L, P, KV, page, D]
-    v_pool: jnp.ndarray,
+    cache,                    # ``kv_cache.Cache``, its pools of pages [L, P, KV, page, D]
     page_ids: jnp.ndarray,    # [Sp // page] i32 slot pages (scratch-padded)
     rng: jnp.ndarray,         # PRNGKey for the first sampled token
     temperature: float = 0.0,
     top_k: int = 0,
     top_p: float = 1.0,
-    scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
-    win: tuple = None,    # (k_win, v_win) [Lw, 1 + slots * ring, KV, page, D]
-    slot: jnp.ndarray = None,  # traced i32: the slot, whose ring a window layer writes
+    slot: jnp.ndarray = None,  # traced i32: the slot, whose ring, state and carried rows are written
     ring: int = 0,        # static: pages of one slot's ring
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
-    """→ (k_pool, v_pool, first_token [1]), with ``scales`` threaded between
-    the pools and the token when the pool is quantized (ISSUE 12), a window
-    family's ring pools after them, a recurrent family's state pools and its
-    expert counts last (:func:`_result`). Where the family states
-    ``stop_after``, only the prompt's last row runs the sub-blocks behind
-    it."""
+    """→ (cache, first_token [1]) and, for a family with expert layers, its
+    expert counts (:func:`_result`). Where the family states ``stop_after``,
+    only the prompt's last row runs the sub-blocks behind it."""
     fam = cfg.serving_family()
     B, Sp = input_ids.shape
     positions = jnp.arange(Sp)
@@ -883,7 +868,7 @@ def paged_prefill(
     valid = (positions < prompt_len) if fam.sparse_layers else None
     counts, carry = [], None
     kinds, stop = sub_block_kinds(fam), getattr(fam, "stop_after", None)
-    carried = getattr(fam, "carry_width", 0)   # an attention that carries rows: ``state``'s last pool
+    carried = getattr(fam, "carry_width", 0)   # an attention that carries rows: ``cache.carry``
     kv_of = {}       # a cross layer's source: the prompt's K and V there
     stopped = False  # the stream is the prompt's last row alone
 
@@ -893,7 +878,7 @@ def paged_prefill(
         source's K and V of this call."""
         if stopped:
             return _attend_decode_shaped(
-                fam, q, k_pool, v_pool, li, page_ids[None, :],
+                fam, q, cache.k, cache.v, li, page_ids[None, :],
                 jnp.reshape(prompt_len - 1, (1,)), q.dtype,
             )
         return _attend_prompt_blocked(
@@ -903,8 +888,8 @@ def paged_prefill(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         if kinds[l] != "attn":
-            a, carry, state = _mixer_without_kv(
-                fam, lp, h, l, li, positions, carry, state, tp_axis,
+            a, carry, cache = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, cache, tp_axis,
                 (Sp, (slot, jnp.zeros((), jnp.int32), prompt_len), None), cross,
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
@@ -914,7 +899,7 @@ def paged_prefill(
             # (expanded) in query blocks, its output straight into ``wo``
             with parts.part("attn.qkv"):
                 q, k_, v, row = fam.qkv_expanded(lp, h, positions, l)
-            k_pool = _latent_write_pages(k_pool, li, page_ids, row)
+            cache = cache._replace(k=_latent_write_pages(cache.k, li, page_ids, row))
             o = _attend_prompt_blocked(
                 q, k_, v, 0, math.gcd(Sp, fam.prefill_block), fam.sm_scale
             )
@@ -923,21 +908,20 @@ def paged_prefill(
             )
             continue
         if carried:
-            q, k_, v, state = _qkv_carried(
-                fam, lp, h, positions, l, state, Sp, (slot, jnp.zeros((), jnp.int32), prompt_len)
+            q, k_, v, cache = _qkv_carried(
+                fam, lp, h, positions, l, cache, Sp, (slot, jnp.zeros((), jnp.int32), prompt_len)
             )
         else:
             with parts.part("attn.qkv"):
                 q, k_, v = fam.qkv(lp, h, positions, l)
         if windowed:
-            o, win = _attention_prefill_window(
-                fam, q, k_, v, win, li, slot, prompt_len, fam.windows[l], ring
+            o, cache = _attention_prefill_window(
+                fam, q, k_, v, cache, li, slot, prompt_len, fam.windows[l], ring
             )
         else:
-            pool_dt = h.dtype if scales is not None else k_pool.dtype
-            o, k_pool, v_pool, scales = _attention_prefill_paged(
-                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
-                page_ids, li, scales,
+            pool_dt = h.dtype if cache.scales is not None else cache.k.dtype
+            o, cache = _attention_prefill_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), cache, page_ids, li
             )
             if l in getattr(fam, "sources", {}).values():
                 kv_of[li] = (k_.astype(pool_dt), v.astype(pool_dt))   # by its paged home, as its readers find it
@@ -954,7 +938,7 @@ def paged_prefill(
         logits = fam.logits(params, h_last)
     with parts.part("sample"):
         first = sample_logits(logits, rng, temperature, top_k, top_p)
-    return _result(k_pool, v_pool, scales, win, first, counts, state)
+    return _result(cache, first, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -1044,12 +1028,12 @@ class _RingWrites:
         self.views = _window_views(fam, slots, seq_lens, page, ring)
 
 
-def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int,
+def _attention_step_window(fam, q, k_c, v_c, cache, li, base, rw, window: int,
                            name=None, live=None):
     """A window layer of the decode (T = 1) or verify step: the T tokens'
     K/V into the slots' rings, then query ``t`` against the ring's view as a
-    decode-shaped call, the keys bounded below. → ``(o [B, T, H * D], win)``."""
-    k_win, v_win = win
+    decode-shaped call, the keys bounded below. → ``(o [B, T, H * D], cache)``."""
+    k_win, v_win = cache.win_k, cache.win_v
     T = q.shape[1]
     pidx, poff = (rw.pidx[:, 0], rw.poff[:, 0]) if T == 1 else (rw.pidx, rw.poff)
     k_new, v_new = (k_c[:, 0], v_c[:, 0]) if T == 1 else (k_c, v_c)
@@ -1065,14 +1049,13 @@ def _attention_step_window(fam, q, k_c, v_c, win, li, base, rw, window: int,
         )
         for t in range(T)
     ]
-    return (o[0] if T == 1 else jnp.concatenate(o, axis=1)), (k_win, v_win)
+    return (o[0] if T == 1 else jnp.concatenate(o, axis=1)), cache._replace(win_k=k_win, win_v=v_win)
 
 
-def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
-                            pos, pidx, poff, l, scales=None, name=None,
-                            live=None, real=None):
+def _attention_decode_paged(fam, q, k_c, v_c, cache, block_tables,
+                            pos, pidx, poff, l, name=None, live=None, real=None):
     """One-token attention per slot against its paged cache (layer ``l`` of
-    the FULL pool) → ``(o [B, 1, H * D], k_pool, v_pool, scales)``.
+    the FULL pool) → ``(o [B, 1, H * D], cache)``.
 
     ``pos[b]`` = tokens already cached for slot b (the new token's position);
     new K/V scatters to (page ``pidx[b]``, offset ``poff[b]``) before the
@@ -1080,16 +1063,15 @@ def _attention_decode_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
     # [B,KV,D] values to (l, pidx[b], :, poff[b], :) — advanced indices around
     # the head slice put the batch dim first, matching the value layout.
     # Inactive slots target the scratch page.
-    k_pool, v_pool, scales = _write_pool_tokens(
-        k_pool, v_pool, scales, l, pidx, poff, k_c[:, 0], v_c[:, 0],
-        shared=name is not None,
+    cache = _write_pool_tokens(
+        cache, l, pidx, poff, k_c[:, 0], v_c[:, 0], shared=name is not None
     )
     o = _attend_decode_shaped(
-        fam, q, k_pool, v_pool, l, block_tables, pos, q.dtype,
-        scales[l] if scales is not None else None, name=name, live=live,
-        real=real,
+        fam, q, cache.k, cache.v, l, block_tables, pos, q.dtype,
+        cache.scales[l] if cache.scales is not None else None, name=name,
+        live=live, real=real,
     )
-    return o, k_pool, v_pool, scales
+    return o, cache
 
 
 @parts.scoped("sample")
@@ -1110,26 +1092,19 @@ def paged_decode_step(
     params: PyTree,
     tokens: jnp.ndarray,        # [B] i32 last emitted token per slot
     seq_lens: jnp.ndarray,      # [B] i32 tokens already cached per slot
-    k_pool: jnp.ndarray,        # [L, P, KV, page, D]
-    v_pool: jnp.ndarray,
+    cache,                      # ``kv_cache.Cache``, its pools of pages [L, P, KV, page, D]
     block_tables: jnp.ndarray,  # [B, n_pages] i32
     keys: jnp.ndarray,          # [B, 2] u32 per-slot sampling keys
     temperature: float = 0.0,
     top_k: int = 0,
     top_p: float = 1.0,
-    scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
-    win: tuple = None,    # a window family's ring pools
     ring: int = 0,
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
-    """→ (k_pool, v_pool, next_tokens [B]); ``scales`` threaded through and
-    returned before the tokens when the pool is quantized, a window family's
-    ring pools, a recurrent family's state pools and expert counts as in
-    :func:`_result`."""
+    """→ (cache, next_tokens [B]) and expert counts as in :func:`_result`."""
     fam = cfg.serving_family()
     B = tokens.shape[0]
-    page = k_pool.shape[3]
+    page = cache.k.shape[3]
     # rows gathered by [B] indices, then the token axis: gathered by [B, 1]
     # ones the position table is copied whole and re-laid out every step
     with parts.part("embed"):
@@ -1139,7 +1114,7 @@ def paged_decode_step(
         block_tables, (seq_lens // page)[:, None], axis=1
     )[:, 0]
     poff = seq_lens % page
-    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if win is not None else None
+    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if cache.win_k is not None else None
     real = block_tables[:, 0] != 0  # a slot that decodes holds a page; page 0 is scratch
     valid = real[:, None] if fam.sparse_layers else None
     counts, carry = [], None
@@ -1148,44 +1123,43 @@ def paged_decode_step(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         if kinds[l] != "attn":
-            a, carry, state = _mixer_without_kv(
-                fam, lp, h, l, li, positions, carry, state, tp_axis,
+            a, carry, cache = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, cache, tp_axis,
                 (0, None, real),
                 lambda q, li: _attend_decode_shaped(
-                    fam, q, k_pool, v_pool, li, block_tables, seq_lens, q.dtype,
+                    fam, q, cache.k, cache.v, li, block_tables, seq_lens, q.dtype,
                     real=real,
                 ),
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
             continue
         if carried:
-            q, k_, v, state = _qkv_carried(fam, lp, h, positions, l, state, real=real)
+            q, k_, v, cache = _qkv_carried(fam, lp, h, positions, l, cache, real=real)
         else:
             with parts.part("attn.qkv"):
                 q, k_, v = fam.qkv(lp, h, positions, l)
         if fam.kv_pools == 1:
-            k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_[:, 0])
-            o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_decode")
+            cache = cache._replace(k=_latent_write_tokens(cache.k, li, pidx, poff, k_[:, 0]))
+            o = _attend_latent(fam, q, cache.k, li, block_tables, seq_lens, "mla_paged_decode")
         elif windowed:
-            o, win = _attention_step_window(
-                fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
+            o, cache = _attention_step_window(
+                fam, q, k_, v, cache, li, seq_lens, rw, fam.windows[l]
             )
         else:
-            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            pool_dt = h.dtype if cache.scales is not None else cache.k.dtype
             # named (the name the program's jit would give them anyway), so that
             # a deep pool's layers share ONE traced kernel each: 48 traces of a
             # kernel with 32 page inputs are 3 s of set-up (PERF.md, PR 56)
-            o, k_pool, v_pool, scales = _attention_decode_paged(
-                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
-                block_tables, seq_lens, pidx, poff, li, scales, name="decode_fn",
-                real=real,
+            o, cache = _attention_decode_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), cache,
+                block_tables, seq_lens, pidx, poff, li, name="decode_fn", real=real,
             )
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
     with parts.part("head"):
         logits = fam.logits(params, h[:, -1])
     nxt = _sample_slots(logits, keys, temperature, top_k, top_p)
-    return _result(k_pool, v_pool, scales, win, nxt, counts, state)
+    return _result(cache, nxt, counts)
 
 
 # ---------------------------------------------------------------------------
@@ -1252,13 +1226,13 @@ def _attend_multitoken_paged(fam, q, k_pool, v_pool, l, block_tables, base,
     return o.reshape(B, T, H * D).astype(q.dtype)
 
 
-def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
-                            base, pidx, poff, l, scales=None, real=None):
+def _attention_verify_paged(fam, q, k_c, v_c, cache, block_tables,
+                            base, pidx, poff, l, real=None):
     """T-token attention per slot: scatter every token's K/V to layer ``l``
     at (``pidx[b,t]``, ``poff[b,t]``), then attend query t at position
     ``base + t`` through the block table. Out-of-budget positions arrive
     with ``pidx`` already routed to the scratch page (see
-    :func:`_verify_write_targets`). → ``(o [B, T, H * D], pools, scales)``.
+    :func:`_verify_write_targets`). → ``(o [B, T, H * D], cache)``.
 
     The T attention calls are UNROLLED single-token ``_attend_decode_shaped``
     invocations — identical shapes to the decode step, hence identical bits;
@@ -1268,9 +1242,10 @@ def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
     bit for bit. The QKV matmul before and the projection after stay batched
     over T — the arithmetic-intensity win speculation exists for."""
     T = q.shape[1]
-    if scales is None:
+    if cache.scales is None:
         # [B,T,KV,D] values to (l, pidx[b,t], :, poff[b,t], :), one write
-        k_pool, v_pool = _scatter_tokens(k_pool, v_pool, l, pidx, poff, k_c, v_c)
+        k_pool, v_pool = _scatter_tokens(cache.k, cache.v, l, pidx, poff, k_c, v_c)
+        cache = cache._replace(k=k_pool, v=v_pool)
     else:
         # quantized pools write the T tokens in sequence: a token landing at
         # a page's offset 0 establishes the page's scale, and the tokens
@@ -1279,22 +1254,21 @@ def _attention_verify_paged(fam, q, k_c, v_c, k_pool, v_pool, block_tables,
         # the pool state (codes AND scales) is bit-identical to spec-off
         # int8 decode
         for t in range(T):
-            k_pool, v_pool, scales = _write_pool_tokens(
-                k_pool, v_pool, scales, l, pidx[:, t], poff[:, t],
-                k_c[:, t], v_c[:, t],
+            cache = _write_pool_tokens(
+                cache, l, pidx[:, t], poff[:, t], k_c[:, t], v_c[:, t]
             )
-    scales_l = scales[l] if scales is not None else None
+    scales_l = cache.scales[l] if cache.scales is not None else None
     o = jnp.concatenate(
         [
             _attend_decode_shaped(
-                fam, q[:, t:t + 1], k_pool, v_pool, l, block_tables,
+                fam, q[:, t:t + 1], cache.k, cache.v, l, block_tables,
                 base + t, q.dtype, scales_l, real=real,
             )
             for t in range(T)
         ],
         axis=1,
     )
-    return o, k_pool, v_pool, scales
+    return o, cache
 
 
 def _verify_write_targets(seq_lens, block_tables, page: int, T: int):
@@ -1319,18 +1293,14 @@ def paged_verify_step(
     params: PyTree,
     tokens: jnp.ndarray,        # [B, T] col 0 = last emitted, cols 1.. = drafts
     seq_lens: jnp.ndarray,      # [B] i32 tokens already cached per slot
-    k_pool: jnp.ndarray,        # [L, P, KV, page, D]
-    v_pool: jnp.ndarray,
+    cache,                      # ``kv_cache.Cache``, its pools of pages [L, P, KV, page, D]
     block_tables: jnp.ndarray,  # [B, W] i32
-    scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
-    win: tuple = None,    # a window family's ring pools
     ring: int = 0,
 ):
     """Self-speculative verify (ISSUE 10): score T = k+1 tokens per slot in
-    one forward pass → (k_pool, v_pool, greedy [B, T]); ``scales`` threaded
-    and returned before ``greedy`` when the pool is quantized, a window
-    family's ring pools and expert counts as in :func:`_result`.
+    one forward pass → (cache, greedy [B, T]) and expert counts as in
+    :func:`_result`.
 
     ``greedy[b, t]`` is the argmax next token after prefix ⊕ tokens[b, :t+1]
     — i.e. exactly what ``paged_decode_step`` would emit at that point. The
@@ -1348,7 +1318,7 @@ def paged_verify_step(
         # a rejected draft's rows cannot be taken back out of a recurrent state, nor out of carried rows
         raise NotImplementedError("the verify step serves families whose sub-blocks are all attentions that carry no rows")
     B, T = tokens.shape
-    page = k_pool.shape[3]
+    page = cache.k.shape[3]
     # clamp garbage positions (past the decode budget) into the embedding
     # table; their queries are never emitted and their writes go to scratch
     positions = jnp.minimum(
@@ -1357,7 +1327,7 @@ def paged_verify_step(
     with parts.part("embed"):
         h = fam.embed(params, tokens, positions)
     pidx, poff = _verify_write_targets(seq_lens, block_tables, page, T)
-    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if win is not None else None
+    rw = _RingWrites(fam, seq_lens, block_tables, page, ring, T) if cache.win_k is not None else None
     real = block_tables[:, 0] != 0
     valid = real[:, None] if fam.sparse_layers else None
     counts, carry = [], None
@@ -1369,17 +1339,17 @@ def paged_verify_step(
         if fam.kv_pools == 1:
             # one batched call: a latent family holds no bit-for-bit contract
             # with a per-request generate for T single-token calls to keep
-            k_pool = _latent_write_tokens(k_pool, li, pidx, poff, k_)
-            o = _attend_latent(fam, q, k_pool, li, block_tables, seq_lens, "mla_paged_verify")
+            cache = cache._replace(k=_latent_write_tokens(cache.k, li, pidx, poff, k_))
+            o = _attend_latent(fam, q, cache.k, li, block_tables, seq_lens, "mla_paged_verify")
         elif windowed:
-            o, win = _attention_step_window(
-                fam, q, k_, v, win, li, seq_lens, rw, fam.windows[l]
+            o, cache = _attention_step_window(
+                fam, q, k_, v, cache, li, seq_lens, rw, fam.windows[l]
             )
         else:
-            pool_dt = h.dtype if scales is not None else k_pool.dtype
-            o, k_pool, v_pool, scales = _attention_verify_paged(
-                fam, q, k_.astype(pool_dt), v.astype(pool_dt), k_pool, v_pool,
-                block_tables, seq_lens, pidx, poff, li, scales, real,
+            pool_dt = h.dtype if cache.scales is not None else cache.k.dtype
+            o, cache = _attention_verify_paged(
+                fam, q, k_.astype(pool_dt), v.astype(pool_dt), cache,
+                block_tables, seq_lens, pidx, poff, li, real,
             )
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
 
@@ -1387,7 +1357,7 @@ def paged_verify_step(
         logits = fam.logits(params, h)
     with parts.part("sample"):
         greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-    return _result(k_pool, v_pool, scales, win, greedy, counts)
+    return _result(cache, greedy, counts)
 
 
 # A call of the mixed step whose decode rows are ALL idle (a chunk that rode no
@@ -1407,8 +1377,7 @@ def paged_mixed_step(
     input_ids: jnp.ndarray,     # [1, C] one chunk, right-padded past the prompt
     start: jnp.ndarray,         # traced i32: absolute position of input_ids[0, 0]
     prompt_len: jnp.ndarray,    # traced i32: the request's true prompt length
-    k_pool: jnp.ndarray,        # [L, P, KV, page, D]
-    v_pool: jnp.ndarray,
+    cache,                      # ``kv_cache.Cache``, its pools of pages [L, P, KV, page, D]
     block_tables: jnp.ndarray,  # [B, W] i32: the decode rows' tables
     page_ids: jnp.ndarray,      # [C // page] i32: THIS chunk's slot pages
     chunk_row: jnp.ndarray,     # [1, W] i32: the prefilling slot's full table row
@@ -1417,18 +1386,15 @@ def paged_mixed_step(
     temperature: float = 0.0,
     top_k: int = 0,
     top_p: float = 1.0,
-    scales: jnp.ndarray = None,  # [L, P, KV, 2] when the pool is int8
     tp_axis: str = None,  # named mesh axis under the TP shard_map (ISSUE 14)
-    win: tuple = None,    # a window family's ring pools
-    slot: jnp.ndarray = None,  # traced i32: the prefilling slot, whose ring a window layer writes
+    slot: jnp.ndarray = None,  # traced i32: the prefilling slot, whose ring, state and carried rows are written
     ring: int = 0,
-    state: tuple = None,  # a recurrent family's (ssm, conv) state pools, then the rows an attention carries
 ):
     """One chunk of ONE slot's incremental prefill (ISSUE 10) and one token
-    for every decoding slot, through every weight ONCE → (k_pool, v_pool,
-    tokens [B + 1]: the slots' next tokens, then the chunk's), pools, scales,
-    rings and expert counts as in :func:`_result` (ONE count of the call's
-    real tokens a held expert: the chunk's and the active slots').
+    for every decoding slot, through every weight ONCE → (cache, tokens [B +
+    1]: the slots' next tokens, then the chunk's) and expert counts as in
+    :func:`_result` (ONE count of the call's real tokens a held expert: the
+    chunk's and the active slots').
 
     The ``C`` chunk rows and the ``B`` decode rows are one ``[1, C + B, E]``
     activation through the norms, ``fam.qkv``, the output projection, the MLP
@@ -1477,7 +1443,7 @@ def paged_mixed_step(
     shifts each decoding slot's by its one row (:func:`_qkv_carried`)."""
     fam = cfg.serving_family()
     B, C = tokens.shape[0], input_ids.shape[1]
-    page = k_pool.shape[3]
+    page = cache.k.shape[3]
     # chunk rows first: they start at row 0 and the decode rows at a page
     # multiple, whole tiles both
     c_pos = jnp.minimum(start + jnp.arange(C), fam.n_positions - 1)
@@ -1498,7 +1464,7 @@ def paged_mixed_step(
     n_real = jnp.clip(prompt_len - start, 0, C)
     Cs, stopped = C, False   # chunk rows in the stream: C, or the sampled one behind ``stop_after``
     rw = None
-    if win is not None:
+    if cache.win_k is not None:
         ring_ids = ring_page_ids(slot, start // page + jnp.arange(C // page), ring)
         views = _window_views(fam, jnp.reshape(slot, (1,)), base, page, ring)
         rw = _RingWrites(fam, seq_lens, block_tables, page, ring, 1) if B else None
@@ -1515,7 +1481,7 @@ def paged_mixed_step(
             # the chunk's one row beside the decode rows, its table row
             # beside theirs: one call of the one-token kernel
             o = _attend_decode_shaped(
-                fam, jnp.swapaxes(q, 0, 1), k_pool, v_pool, li,
+                fam, jnp.swapaxes(q, 0, 1), cache.k, cache.v, li,
                 jnp.concatenate([chunk_row, block_tables]),
                 jnp.concatenate([base + idx, seq_lens]), q.dtype, name="decode_fn",
                 live=None if live is None else live | (prompt_len <= start + C),
@@ -1523,11 +1489,11 @@ def paged_mixed_step(
             )
             return jnp.swapaxes(o, 0, 1)
         qc, qd = part(q)
-        o = _attend_multitoken_paged(fam, qc, k_pool, v_pool, li, chunk_row, base, name="chunk_fn")
+        o = _attend_multitoken_paged(fam, qc, cache.k, cache.v, li, chunk_row, base, name="chunk_fn")
         if not B:
             return o
         od = _attend_decode_shaped(
-            fam, qd, k_pool, v_pool, li, block_tables, seq_lens, q.dtype,
+            fam, qd, cache.k, cache.v, li, block_tables, seq_lens, q.dtype,
             name="decode_fn", live=live, real=real,
         )
         return jnp.concatenate([o, jnp.swapaxes(od, 0, 1)], axis=1)
@@ -1535,15 +1501,15 @@ def paged_mixed_step(
     for l, (windowed, li) in enumerate(_kv_homes(fam)):
         lp = fam.layer(params, l)
         if kinds[l] != "attn":
-            a, carry, state = _mixer_without_kv(
-                fam, lp, h, l, li, positions, carry, state, tp_axis,
+            a, carry, cache = _mixer_without_kv(
+                fam, lp, h, l, li, positions, carry, cache, tp_axis,
                 (Cs, (slot, start, n_real), real if B else None), cross,
             )
             h, carry = _after_attention(fam, lp, h, a, l, valid, tp_axis, counts, carry, _passed)
             continue
         if carried:
-            q, k_, v, state = _qkv_carried(
-                fam, lp, h, positions, l, state, C, (slot, start, n_real), real if B else None
+            q, k_, v, cache = _qkv_carried(
+                fam, lp, h, positions, l, cache, C, (slot, start, n_real), real if B else None
             )
         else:
             with parts.part("attn.qkv"):
@@ -1551,49 +1517,43 @@ def paged_mixed_step(
         (qc, qd), (kc, kd) = part(q), part(k_)
         od = None
         if fam.kv_pools == 1:
-            k_pool = _latent_write_pages(k_pool, li, page_ids, kc)
+            cache = cache._replace(k=_latent_write_pages(cache.k, li, page_ids, kc))
             if B:
-                k_pool = _latent_write_tokens(k_pool, li, pidx, poff, kd[:, 0])
+                cache = cache._replace(k=_latent_write_tokens(cache.k, li, pidx, poff, kd[:, 0]))
                 od = _attend_latent(
-                    fam, qd, k_pool, li, block_tables, seq_lens, "mla_paged_decode", live
+                    fam, qd, cache.k, li, block_tables, seq_lens, "mla_paged_decode", live
                 )
-            oc = _attend_latent(fam, qc, k_pool, li, chunk_row, base, "mla_paged_chunk")
+            oc = _attend_latent(fam, qc, cache.k, li, chunk_row, base, "mla_paged_chunk")
         elif windowed:
             vc, vd = part(v)
-            k_win, v_win = win
+            k_win, v_win = cache.win_k, cache.win_v
             with parts.part("kv.write"):
                 k_win = k_win.at[li, ring_ids].set(_page_chunks(kc, page).astype(k_win.dtype))
                 v_win = v_win.at[li, ring_ids].set(_page_chunks(vc, page).astype(v_win.dtype))
-            win = (k_win, v_win)
+            cache = cache._replace(win_k=k_win, win_v=v_win)
             if B:
-                od, win = _attention_step_window(
-                    fam, qd, kd, vd, win, li, seq_lens, rw, fam.windows[l], "decode_fn", live
+                od, cache = _attention_step_window(
+                    fam, qd, kd, vd, cache, li, seq_lens, rw, fam.windows[l], "decode_fn", live
                 )
             table, off, lo = views[fam.windows[l]]
             oc = _attend_multitoken_paged(
-                fam, qc, *win, li, table, base - off, None, lo, "chunk_fn"
+                fam, qc, cache.win_k, cache.win_v, li, table, base - off, None, lo, "chunk_fn"
             )
         else:
             vc, vd = part(v)
-            pool_dt = h.dtype if scales is not None else k_pool.dtype
+            pool_dt = h.dtype if cache.scales is not None else cache.k.dtype
             # page-granular scatter, exactly paged_prefill's write (quantized
             # at write when the pool is int8; the attention below reads the
             # pool, so it sees the dequantized codes either way)
-            k_pool, scales, _ = _write_pool_pages(
-                k_pool, scales, li, page_ids, _page_chunks(kc.astype(pool_dt), page), 0,
-            )
-            v_pool, scales, _ = _write_pool_pages(
-                v_pool, scales, li, page_ids, _page_chunks(vc.astype(pool_dt), page), 1,
-            )
+            cache, _, _ = _write_cache_pages(cache, li, page_ids, kc, vc, pool_dt)
             if B:
-                od, k_pool, v_pool, scales = _attention_decode_paged(
-                    fam, qd, kd.astype(pool_dt), vd.astype(pool_dt), k_pool, v_pool,
-                    block_tables, seq_lens, pidx, poff, li, scales, "decode_fn", live,
-                    real,
+                od, cache = _attention_decode_paged(
+                    fam, qd, kd.astype(pool_dt), vd.astype(pool_dt), cache,
+                    block_tables, seq_lens, pidx, poff, li, "decode_fn", live, real,
                 )
             oc = _attend_multitoken_paged(
-                fam, qc, k_pool, v_pool, li, chunk_row, base,
-                scales[li] if scales is not None else None, name="chunk_fn",
+                fam, qc, cache.k, cache.v, li, chunk_row, base,
+                cache.scales[li] if cache.scales is not None else None, name="chunk_fn",
             )
         o = oc if od is None else jnp.concatenate([oc, jnp.swapaxes(od, 0, 1)], axis=1)
         h, carry = _after_attention(fam, lp, h, o, l, valid, tp_axis, counts, carry)
@@ -1618,22 +1578,22 @@ def paged_mixed_step(
         if B:
             nxt = _sample_slots(logits[1:], keys, temperature, top_k, top_p)
             first = jnp.concatenate([nxt, first.astype(nxt.dtype)])
-    return _result(k_pool, v_pool, scales, win, first, counts, state)
+    return _result(cache, first, counts)
 
 
 def paged_chunk_prefill(
-    cfg, params: PyTree, input_ids, start, prompt_len, k_pool, v_pool,
+    cfg, params: PyTree, input_ids, start, prompt_len, cache,
     page_ids, block_tables, rng, **kw,
 ):
-    """One chunk through the model with no decode row beside it → (k_pool,
-    v_pool, token [1], ...): :func:`paged_mixed_step` at ``B`` = 0, its
+    """One chunk through the model with no decode row beside it → (cache,
+    token [1], ...): :func:`paged_mixed_step` at ``B`` = 0, its
     operands in the chunk's own order (``block_tables [1, W]`` is the
     prefilling slot's row). No engine compiles this shape (a chunk that has
     no decode step to ride takes the mixed program with idle rows); it is
     what the tests hold the mixed step's chunk rows against."""
     none = jnp.zeros((0,), jnp.int32)
     return paged_mixed_step(
-        cfg, params, none, none, input_ids, start, prompt_len, k_pool, v_pool,
+        cfg, params, none, none, input_ids, start, prompt_len, cache,
         jnp.zeros((0, block_tables.shape[1]), jnp.int32), page_ids, block_tables,
         jnp.zeros((0, 2), jnp.uint32), rng, **kw,
     )

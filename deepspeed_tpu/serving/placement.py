@@ -59,7 +59,7 @@ from ..analysis.sharding_rules import (
 )
 from ..module_inject.tp_shard import tp_shard_serving_params
 from jax import shard_map
-from .kv_cache import PageAllocator, PoolLayoutError, init_pools, pool_view
+from .kv_cache import PAGED_FIELDS, Cache, PageAllocator, PoolLayoutError, init_pools, stored_as, viewed
 
 PyTree = Any
 
@@ -300,11 +300,6 @@ class Placement:
             return x
         return jax.device_put(x, self.device)
 
-    def put_pool(self, x, kv_axis: int = 2):
-        return self.put(
-            x, self.pool_spec(getattr(x, "ndim", len(x.shape)), kv_axis)
-        )
-
     def pull_pool(self, x):
         """Cross-placement transfer of a packed handoff buffer: ALWAYS
         ``device_put`` (unlike :meth:`put`, which leaves default-device
@@ -397,44 +392,23 @@ class Placement:
 
 
 class ProgramSet:
-    """One placement's working set: placed params, paged K/V pools (+ int8
-    scales) sharded over the placement, the page allocator for that pool,
-    and the compiled programs that consume them. Donated-pool rehoming
-    (``take_pools``) lives here because the donated buffers belong to THIS
-    pool, whichever placement ran the program.
+    """One placement's working set: placed params, the served cache
+    (``kv_cache.Cache``, ONE value: the paged K/V pools, an int8 cache's
+    scales, a window family's rings, a recurrent family's state, the rows an
+    attention carries; docs/SERVING.md, "The cache", says what each field is)
+    sharded over the placement, the page allocator for the paged pools, and
+    the compiled programs that consume them. The cache a program gives back
+    is rehomed here (:meth:`call`) because the donated buffers belong to THIS
+    set, whichever placement ran the program. No allocator for the per-slot
+    fields: a slot's ring or row is the slot's, and the program that takes a
+    request's first rows starts it from zeros.
 
-    The K and V pools may be STORED with their page axis split
-    (``kv_cache.pool_stored_shape``), and this class alone knows: a program
-    compiled through :meth:`aot` works on the ``[L, P, KV, page, D]`` views,
-    the host reads a page through :meth:`page_column`, and the verifiers get
-    the stored per-device dims from :meth:`local_pool_dims`. Payloads of
-    page columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always.
-
-    A LATENT family (``fam.kv_pools == 1``) has ONE pool and no V pool:
-    ``k_pool`` is ``[L, P, 1, page, W]`` with ``W`` (``head_dim`` here) the
-    cached row as it is stored, ``v_pool`` is ``None``, and a program written
-    ``fn(params, k_pool, v_pool, ...)`` is handed ``None`` for the second.
-
-    A family with state-space sub-blocks (``fam.kinds``) has a third kind of
-    per-slot state, fixed in size and recurrent: ``state_pools = (ssm, conv)``,
-    ``[Ls, slots, N, d_inner]`` float32 and ``[Ls, slots, K - 1, d_inner]`` in
-    the cache's type (``serving/model._ssm_block``), the last two of
-    :meth:`pool_args`, donated like the ring pools. No allocator: a slot's row
-    is the slot's, and the program that takes a request's first rows starts it
-    from zeros. A family of linear attentions (``"lin"`` sub-blocks) has the
-    same two pools in its own shapes: ``(lin, conv)``, ``[Ll, slots, Hv, dk,
-    dv]`` float32 (a matrix a value head: ``fam.lin_state``) and ``[Ll, slots,
-    K - 1, channels]`` (``fam.lin_conv``); ``lin_state_bytes`` is the first's.
-
-    A family whose attentions CARRY ROWS (``fam.carry_width``: q, k and v of a
-    call's first rows need rows of the call before, while K and V are paged as
-    ever) has a fourth kind: ``carry_width`` values a slot and ``"attn"``
-    sub-block in the cache's type, ``[La, slots, carry_width]``, the LAST of
-    ``state_pools`` (``serving/model._qkv_carried``), donated and started from
-    zeros in the same way."""
-
-    kv_pools = 2  # a K and a V pool; 1: a latent family's one pool
-    state_pools = None  # (ssm, conv) for a family with state-space sub-blocks, then the carried rows' pool
+    The pools of pages may be STORED with their page axis split
+    (``kv_cache.pool_stored_shape``), and :meth:`aot` alone knows: a program
+    compiled through it works on the ``[L, P, KV, page, D]`` views, the host
+    reads a page through :meth:`page_column`, and the verifiers get the
+    stored per-device dims from :meth:`local_pool_dims`. Payloads of page
+    columns (:meth:`packed_sds`) are ``[L, n, KV, page, D]`` always."""
 
     def __init__(self, placement: Placement, mcfg, num_pages: int,
                  page_size: int, cache_dtype, params: PyTree,
@@ -448,52 +422,46 @@ class ProgramSet:
         # them inside a rank's heads): the pools' kv-heads are tp x its own
         fam = placement.local_model_config(mcfg).serving_family()
         # the paged pools hold the layers that read their whole context; a
-        # window layer's K/V live in the ring pools below, a state-space
-        # sub-block's recurrent state in the state pools
+        # window layer's K/V live in the rings, a recurrent sub-block's state
+        # in ``rec`` and ``conv``
         self.n_layer, self.n_window_layer, n_state = pool_layers(fam)
         self.n_kv_head = int(fam.n_kv_head) * placement.tp
-        self.kv_pools = int(fam.kv_pools)
+        self.kv_pools = int(fam.kv_pools)   # 2: a K and a V pool; 1: a latent family's one pool
         k, v, scales = init_pools(
             self.n_layer, self.num_pages, self.n_kv_head, self.page_size,
             int(fam.head_dim), dtype=cache_dtype, pools=self.kv_pools,
         )
         self.head_dim = int(k.shape[-1])  # a latent row as it is stored
         self._kv_axis = k.ndim - 3  # [..., KV, page, D], however P is stored
-        self.k_pool = placement.put_pool(k, self._kv_axis)
-        self.v_pool = placement.put_pool(v, self._kv_axis) if v is not None else None
-        self.kv_scales = placement.put_pool(scales) if scales is not None else None
         # window layers: ``ring_pages`` pages statically owned by each of
-        # ``ring_slots`` slots, after a scratch page; no allocator, and the
-        # bytes do not grow with context
+        # ``ring_slots`` slots, after a scratch page; the bytes do not grow
+        # with context
+        slots = int(ring_slots)
         self.ring_pages = int(ring_pages) if self.n_window_layer else 0
-        self.window_pools = None
+        win_k = win_v = rec = conv = carry = None
         if self.n_window_layer:
-            kw, vw, _ = init_pools(
-                self.n_window_layer, 1 + int(ring_slots) * self.ring_pages,
+            win_k, win_v, _ = init_pools(
+                self.n_window_layer, 1 + slots * self.ring_pages,
                 self.n_kv_head, self.page_size, self.head_dim, dtype=cache_dtype,
             )
-            self.window_pools = (
-                placement.put_pool(kw, kw.ndim - 3), placement.put_pool(vw, vw.ndim - 3)
-            )
-        self.lin_state_bytes = 0
+        # the recurrent state a slot and sub-block, and the channels its convolution carries
+        # rows of: a state-space mixer's [N, d_inner], or a linear attention's [Hv, dk, dv]
+        self.lin_state = bool(n_state and getattr(fam, "lin_state", None))
         if n_state:
-            # the recurrent state a slot and sub-block, and the channels its convolution carries
-            # rows of: a state-space mixer's [N, d_inner], or a linear attention's [Hv, dk, dv]
-            lin = getattr(fam, "lin_state", None)
-            shape, (K, channels) = (lin, fam.lin_conv) if lin else (fam.ssm_state, (fam.ssm_conv, fam.ssm_state[1]))
-            self.state_pools = (
-                placement.put(jnp.zeros((n_state, int(ring_slots), *shape), jnp.float32)),
-                placement.put(jnp.zeros((n_state, int(ring_slots), K - 1, channels), self.k_pool.dtype)),
+            shape, (K, channels) = (
+                (fam.lin_state, fam.lin_conv) if self.lin_state
+                else (fam.ssm_state, (fam.ssm_conv, fam.ssm_state[1]))
             )
-            self.lin_state_bytes = int(self.state_pools[0].nbytes) if lin else 0
-        self.carry_pool_bytes = 0
-        carry = int(getattr(fam, "carry_width", 0) or 0)
-        if carry:
-            rows = placement.put(jnp.zeros(
-                (self.n_layer + self.n_window_layer, int(ring_slots), carry), self.k_pool.dtype
-            ))
-            self.state_pools = (self.state_pools or ()) + (rows,)
-            self.carry_pool_bytes = int(rows.nbytes)
+            rec = jnp.zeros((n_state, slots, *shape), jnp.float32)
+            conv = jnp.zeros((n_state, slots, K - 1, channels), k.dtype)
+        width = int(getattr(fam, "carry_width", 0) or 0)
+        if width:   # a row a slot and "attn" sub-block (``serving/model._qkv_carried``)
+            carry = jnp.zeros((self.n_layer + self.n_window_layer, slots, width), k.dtype)
+        cache = Cache(k, v, scales, win_k, win_v, rec, conv, carry)
+        self.cache = Cache(*(
+            x if x is None else placement.put(x, spec)
+            for x, spec in zip(cache, self._cache_specs(cache))
+        ))
         self._check_pool_layout()
         self.allocator = PageAllocator(self.num_pages)
         self.params = placement.shard_params(params, getattr(fam, "row_gathered", ()))
@@ -504,17 +472,19 @@ class ProgramSet:
 
     @property
     def quantized(self) -> bool:
-        return self.kv_scales is not None
+        return self.cache.scales is not None
 
-    def pool_args(self) -> tuple:
-        """The donated pool operands, in program order: K, V (a latent
-        family: its one pool), an int8 pool's scales, a window family's two
-        ring pools, a recurrent family's two state pools, the pool of the rows
-        a family's attentions carry."""
-        out = (self.k_pool,) + ((self.v_pool,) if self.v_pool is not None else ())
-        if self.kv_scales is not None:
-            out += (self.kv_scales,)
-        return out + (self.window_pools or ()) + (self.state_pools or ())
+    def _cache_specs(self, cache: Cache) -> Cache:
+        """One ``PartitionSpec`` a field that is there: the pools of pages
+        and the scales shard their kv-head axis, a slot's own state is
+        replicated."""
+        plc = self.placement
+        return Cache(*(
+            None if x is None
+            else plc.pool_spec(x.ndim, x.ndim - 3) if f in PAGED_FIELDS
+            else plc.pool_spec(x.ndim) if f == "scales" else plc.rep_spec()
+            for f, x in zip(Cache._fields, cache)
+        ))
 
     def _check_pool_layout(self) -> None:
         """Where the paged kernels run, the pools must have come out
@@ -523,13 +493,14 @@ class ProgramSet:
         from ..ops.pallas.decode_attention import paged_page_ok
         from ..ops.pallas.latent_attention import latent_attention_ok
 
-        ok = latent_attention_ok if self.kv_pools == 1 else paged_page_ok
-        if not ok(self.page_size, self.head_dim, self.k_pool.dtype.itemsize):
+        pool = self.cache.k
+        ok = latent_attention_ok if self.cache.latent else paged_page_ok
+        if not ok(self.page_size, self.head_dim, pool.dtype.itemsize):
             return
-        got = tuple(self.k_pool.format.layout.major_to_minor)
-        if got != tuple(range(self.k_pool.ndim)):
+        got = tuple(pool.format.layout.major_to_minor)
+        if got != tuple(range(pool.ndim)):
             raise PoolLayoutError(
-                f"KV pool {self.k_pool.dtype.name}{list(self.k_pool.shape)} "
+                f"KV pool {pool.dtype.name}{list(pool.shape)} "
                 f"is laid out major-to-minor {got} on this device, not "
                 "row-major: every paged program would re-lay it out around "
                 "its kernels. An axis longer than the head moves: choose "
@@ -538,78 +509,50 @@ class ProgramSet:
                 "device at this head width"
             )
 
-    def pool_specs(self) -> tuple:
-        """One ``PartitionSpec`` per :meth:`pool_args` operand."""
-        kv = self.placement.pool_spec(self.k_pool.ndim, self._kv_axis)
-        out = (kv,) * self.kv_pools
-        if self.kv_scales is not None:
-            out += (self.placement.pool_spec(self.kv_scales.ndim),)
-        return out + tuple(
-            self.placement.pool_spec(w.ndim, w.ndim - 3)
-            for w in self.window_pools or ()
-        ) + (self.placement.rep_spec(),) * len(self.state_pools or ())
-
     def aot(self, fn, operands: Sequence, operand_specs: Sequence = (),
             result_specs: Sequence = (), *, with_params: bool = False,
-            returns_pools: bool = True, donate: bool = True):
-        """AOT-compile ``fn([params,] k_pool, v_pool[, scales], *operands)``
-        for this set's placement, over this set's pools.
+            returns_cache: bool = True, donate: bool = True):
+        """AOT-compile ``fn([params,] cache, *operands)`` for this set's
+        placement, over this set's cache.
 
         ``fn`` is written for ``[L, P, KV, page, D]`` pools and, with
-        ``returns_pools``, gives them back first (``k, v[, scales], *rest``).
+        ``returns_cache``, gives the cache back first (``cache, *rest``).
         The compiled program takes and returns the pools in the shape they
         are stored in: the views both ways are bitcasts (the identity for a
-        pool stored 5-D). ``donate`` donates the pools. ``operand_specs`` and
-        ``result_specs`` are the specs of what follows the pools, used at
-        tp > 1 only."""
+        pool stored 5-D). ``donate`` donates the cache, every field of it.
+        ``operand_specs`` and ``result_specs`` are the specs of what follows
+        the cache, used at tp > 1 only."""
         plc = self.placement
-        first = int(with_params)
-        pools = self.pool_args()
-        one_pool = self.kv_pools == 1
-        # the K/V-shaped pools (all but the scales), which fn sees as views
-        n_kv = len(pools) - len(self.state_pools or ())
-        kv_like = list(range(first, first + self.kv_pools)) + (
-            [first + n_kv - 2, first + n_kv - 1] if self.window_pools else []
-        )
+        at = int(with_params)   # the cache's place among the arguments
 
         @functools.wraps(fn)  # jit(decode_fn): the name traces are read by
         def program(*args):
-            args = list(args)
-            stored = {i: args[i].shape for i in kv_like}  # per device under shard_map
-            for i in kv_like:
-                args[i] = pool_view(args[i])
-            if one_pool:
-                args.insert(first + 1, None)  # fn's v_pool: there is none
-            out = fn(*args)
-            if not returns_pools:
+            stored = args[at]  # per device under shard_map
+            out = fn(*args[:at], viewed(stored), *args[at + 1:])
+            if not returns_cache:
                 return out
-            out = list(out)
-            if one_pool:
-                del out[1]
-            for i in kv_like:
-                out[i - first] = out[i - first].reshape(stored[i])
-            return tuple(out)
+            return (stored_as(out[0], stored), *out[1:])
 
-        args = ((self.params,) if with_params else ()) + pools + tuple(operands)
-        dn = tuple(range(first, first + len(pools))) if donate else ()
+        args = ((self.params,) if with_params else ()) + (self.cache,) + tuple(operands)
+        dn = (at,) if donate else ()
         if plc.mesh is None:
             exe = plc.aot(program, args, (), (), dn)
         else:
-            pool_specs = self.pool_specs()
+            specs = self._cache_specs(self.cache)
             exe = plc.aot(
                 program, args,
                 ((self.param_specs,) if with_params else ())
-                + pool_specs + tuple(operand_specs),
-                (pool_specs if returns_pools else ()) + tuple(result_specs),
+                + (specs,) + tuple(operand_specs),
+                ((specs,) if returns_cache else ()) + tuple(result_specs),
                 dn,
             )
-        # the program must take the pools as they lie and give them back so:
+        # the program must take the cache as it lies and give it back so:
         # a layout that differs would be refused at the first call (or, for
         # a result, at the one after), inside a run
-        took = exe.input_formats[0][first:first + len(pools)]
-        gave = exe.output_formats[:len(pools)] if returns_pools else ()
+        took = exe.input_formats[0][at]
+        gave = exe.output_formats[0] if returns_cache else ()
         for what, fmts in (("takes", took), ("returns", gave)):
-            for pool, fmt in zip(pools, fmts):
+            for pool, fmt in zip(jax.tree.leaves(self.cache), jax.tree.leaves(fmts)):
                 if fmt.layout != pool.format.layout:
                     raise PoolLayoutError(
                         f"{getattr(fn, '__name__', fn)} {what} a "
@@ -634,16 +577,25 @@ class ProgramSet:
                     )
         return exe
 
+    def call(self, exe, *host):
+        """One call of a program compiled ``with_params`` over this set's
+        weights and cache: the cache it gives back is the set's from here on
+        (the one it took was donated). → the rest of its results, a single
+        one unwrapped."""
+        self.cache, *rest = exe(self.params, self.cache, *host)
+        return rest[0] if len(rest) == 1 else tuple(rest)
+
     def page_column(self, pid: int) -> tuple:
         """Page ``pid`` of every layer, as device arrays ``(k, v, scales)``:
         ``[L, KV, page, D]`` twice and ``[L, KV, 2]`` or ``None`` (the host
         tier's demotion read; dispatched now, fetched by whoever waits)."""
+        k, v, scales = self.cache[:3]
         at = (slice(None),) + tuple(int(i) for i in np.unravel_index(
-            int(pid), self.k_pool.shape[1:self._kv_axis]
+            int(pid), k.shape[1:self._kv_axis]
         ))
         return (
-            self.k_pool[at], self.v_pool[at] if self.v_pool is not None else None,
-            self.kv_scales[:, pid] if self.kv_scales is not None else None,
+            k[at], v[at] if v is not None else None,
+            scales[:, pid] if scales is not None else None,
         )
 
     def program_census(self, name: str, exe) -> Tuple[int, int, int, int]:
@@ -666,7 +618,7 @@ class ProgramSet:
         if relayout and "tpu_custom_call" in text:
             raise PoolLayoutError(
                 f"{name}: {relayout} instruction(s) copy, slice or transpose "
-                f"a whole layer of the {list(self.k_pool.shape)} KV pool or "
+                f"a whole layer of the {list(self.cache.k.shape)} KV pool or "
                 "more around its kernels (placement.pool_relayout_ops)"
             )
         mem = exe.memory_analysis()
@@ -684,31 +636,6 @@ class ProgramSet:
                 leaves.add((x.dtype, shape))
         return leaves
 
-    def take_pools(self, out: tuple):
-        """Rehome the donated pools from a program's output tuple and
-        return the rest (single element unwrapped, like the scheduler's
-        original helper)."""
-        self.k_pool = out[0]
-        if self.kv_pools == 2:
-            self.v_pool = out[1]
-        rest = out[self.kv_pools:]
-        if self.kv_scales is not None:
-            self.kv_scales = rest[0]
-            rest = rest[1:]
-        if self.window_pools is not None:
-            self.window_pools = (rest[0], rest[1])
-            rest = rest[2:]
-        if self.state_pools is not None:
-            n = len(self.state_pools)
-            self.state_pools, rest = tuple(rest[:n]), rest[n:]
-        return rest[0] if len(rest) == 1 else rest
-
-    def set_pools(self, pools: tuple) -> None:
-        """Install a full replacement pool tuple (scatter-handoff output)."""
-        self.k_pool, self.v_pool = pools[0], pools[1]
-        if self.kv_scales is not None:
-            self.kv_scales = pools[2]
-
     # -- geometry for Engines A/E (per-DEVICE shapes at tp>1) ------------
 
     def local_kv_heads(self) -> int:
@@ -722,7 +649,7 @@ class ProgramSet:
     def local_pool_dims(self) -> str:
         """Per-device dims of a pool as the compiled programs take it: the
         STORED shape (their entry parameters, the donation aliases)."""
-        return self._local_dims(self.k_pool.shape, self._kv_axis)
+        return self._local_dims(self.cache.k.shape, self._kv_axis)
 
     def kv_pool_dims(self) -> tuple:
         """Every per-device dims string a pool-sized buffer of a compiled
@@ -739,15 +666,14 @@ class ProgramSet:
         return f"{self.n_layer},{self.num_pages},{self.local_kv_heads()},2"
 
     def packed_sds(self, n_pages: int) -> tuple:
-        """Global shapes of a page-column payload over ``n_pages`` pages, one
-        per :meth:`pool_args` operand: ``[L, n, KV, page, D]`` for K and V
-        (whatever shape the pools are stored in), ``[L, n, KV, 2]`` for the
-        scales."""
+        """Global shapes of a page-column payload over ``n_pages`` pages:
+        ``[L, n, KV, page, D]`` for K and V (whatever shape the pools are
+        stored in), then ``[L, n, KV, 2]`` for an int8 cache's scales."""
         kv = jax.ShapeDtypeStruct(
             (self.n_layer, int(n_pages), self.n_kv_head, self.page_size,
-             self.head_dim), self.k_pool.dtype,
+             self.head_dim), self.cache.k.dtype,
         )
-        if self.kv_scales is None:
+        if not self.quantized:
             return (kv, kv)
         return (kv, kv, jax.ShapeDtypeStruct(
             (self.n_layer, int(n_pages), self.n_kv_head, 2), jnp.float32
@@ -767,27 +693,21 @@ class ProgramSet:
     def packed_scales_dims(self, n_pages: int) -> str:
         return f"{self.n_layer},{int(n_pages)},{self.local_kv_heads()},2"
 
-    def local_pool_bytes(self) -> int:
-        """Per-device K+V pool bytes of this placement (a latent family: of
-        its one pool, rows as they are stored)."""
-        itemsize = jnp.dtype(self.k_pool.dtype).itemsize
-        return (
-            self.kv_pools * self.n_layer * self.num_pages * self.local_kv_heads()
-            * self.page_size * self.head_dim * itemsize
-        )
+    def cache_bytes(self) -> dict:
+        """The cache's bytes by class, over all devices (a device holds ``1 /
+        tp`` of ``pages`` and ``scales``): ``pages`` (K and V, or a latent
+        family's one pool, rows as they are stored), ``scales``, ``window``
+        (the rings), ``state`` (the recurrent state and its convolution's
+        rows, whatever the contexts' lengths), ``lin_state`` (of those, a
+        linear attention's matrix a value head; 0 for a state-space scan's)
+        and ``carry``."""
+        c = self.cache
 
-    def local_scales_bytes(self) -> int:
-        if self.kv_scales is None:
-            return 0
-        return self.n_layer * self.num_pages * self.local_kv_heads() * 2 * 4
+        def of(*xs):
+            return sum(int(x.nbytes) for x in xs if x is not None)
 
-    def window_pool_bytes(self) -> int:
-        """K+V bytes of the ring pools: slots x ring pages (and the scratch
-        page) x page bytes x window layers, whatever the contexts' lengths."""
-        return sum(int(w.nbytes) for w in self.window_pools or ())
-
-    def state_pool_bytes(self) -> int:
-        """Bytes of the recurrent state pools: slots x state-space sub-blocks
-        x (the scan state and the convolution's rows), whatever the contexts'
-        lengths (the rows an attention carries are ``carry_pool_bytes``)."""
-        return sum(int(w.nbytes) for w in self.state_pools or ()) - self.carry_pool_bytes
+        return {
+            "pages": of(c.k, c.v), "scales": of(c.scales), "window": of(c.win_k, c.win_v),
+            "state": of(c.rec, c.conv), "lin_state": of(c.rec) if self.lin_state else 0,
+            "carry": of(c.carry),
+        }

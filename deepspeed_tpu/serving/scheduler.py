@@ -98,6 +98,7 @@ from .kv_cache import (
     SlotTable,
     pages_for,
     pool_bytes,
+    refusals,
     scales_bytes,
 )
 from .placement import Placement, ProgramSet
@@ -140,26 +141,26 @@ def _tokens_of(tokens, prev, src):
     return jnp.where(src >= 0, prev[jnp.maximum(src, 0)], tokens)
 
 
-def _split_scales(rest: tuple, quantized: bool):
-    """Program-wrapper operand split: ``rest`` is ``(scales, *inputs)``
-    under int8 pools, plain ``inputs`` otherwise."""
-    if quantized:
-        return rest[0], rest[1:]
-    return None, rest
+def gather_fn(cache, src):
+    """Page columns ``src`` of the paged pools, packed ``[L, n, KV, page, D]``
+    a pool (an int8 cache's scales ride along): what crosses placements,
+    engines or tiers."""
+    return tuple(x[:, src] for x in (cache.k, cache.v, cache.scales) if x is not None)
 
 
-def _split_pools(rest: tuple, quantized: bool, windowed: bool, n_state: int = 0):
-    """:func:`_split_scales`, then a window family's two ring pools and the
-    ``n_state`` state pools (a recurrent family's two, and the one of rows a
-    family's attentions carry): → ``(scales | None, (k_win, v_win) | None,
-    state pools | None, inputs)``."""
-    scales, rest = _split_scales(rest, quantized)
-    win = state = None
-    if windowed:
-        win, rest = (rest[0], rest[1]), rest[2:]
-    if n_state:
-        state, rest = tuple(rest[:n_state]), rest[n_state:]
-    return scales, win, state, rest
+def scatter_fn(cache, *packed):
+    """``packed = (*payloads, dst)``: :func:`gather_fn`'s payloads into page
+    columns ``dst`` of the paged pools → ``(cache,)``."""
+    *packed, dst = packed
+    fields = [f for f in ("k", "v", "scales") if getattr(cache, f) is not None]
+    return (cache._replace(**{
+        f: getattr(cache, f).at[:, dst].set(x) for f, x in zip(fields, packed)
+    }),)
+
+
+def restore_fn(cache, *packed):
+    """:func:`scatter_fn` under the name the host tier's program has in a trace."""
+    return scatter_fn(cache, *packed)
 
 
 @dataclass
@@ -279,78 +280,27 @@ class ServingEngine:
             mcfg = mcfg.per_head_cache()
         self.model_config = mcfg
         fam = self.family = mcfg.serving_family()
-        # a family with sliding-window layers keeps two kinds of KV state (the
-        # paged pools and a ring a slot); what moves, shares, shards or
-        # re-codes pages knows the first kind only (ROADMAP.md, queue R)
-        self.windowed = any(fam.windows)
-        # a latent family keeps ONE pool of one row a token that all heads
-        # share: the same mechanisms know K and V pools of per-head pages only
-        self.latent = fam.kv_pools == 1
-        # a family with state-space sub-blocks keeps a third kind: a fixed-size
-        # recurrent state a slot, which is no page, cannot be cut at a prefix
-        # and cannot be rolled back behind a rejected draft without a snapshot
-        # (a linear attention's is a matrix a value head: the same kind of state)
-        kinds_ = smodel.sub_block_kinds(fam)
-        self.recurrent = "ssm" in kinds_ or "lin" in kinds_
-        # what that state is, for the refusals below: its bytes a slot and sub-block differ by 6x
-        self._state_what = (
-            "a linear-attention layer's matrix state a value head "
-            f"({4 * int(np.prod(fam.lin_state)) / 1e6:.1f} MB a slot and layer)"
-            if "lin" in kinds_ else "a state-space layer's scan state"
-        )
-        # a family whose attentions carry rows keeps a fourth: under its paged
-        # K and V, the rows before a call's first (serving/model._qkv_carried).
-        # A cached prefix's pages without the rows at its end would serve a
-        # wrong first token silently
-        self.carried = bool(getattr(fam, "carry_width", 0))
-        if self.windowed or self.latent or self.recurrent or self.carried:
-            plc_ = getattr(config, "placement", None)
-            spec_on = bool(getattr(getattr(config, "speculative", None), "enabled", False))
-            for on, what in (
-                (getattr(getattr(config, "prefix_cache", None), "enabled", False),
-                 "serving.prefix_cache"),
-                (getattr(getattr(config, "tiering", None), "enabled", False),
-                 "serving.tiering"),
-                (int8_pages, "serving.kv_cache_dtype=int8"),
-                (plc_ is not None and max(
-                    int(getattr(plc_, k, 0) or 0)
-                    for k in ("tp", "decode_tp", "prefill_tp")) > 1,
-                 "serving.placement.tp > 1"),
-                (plc_ is not None and bool(getattr(plc_, "disaggregate", False)),
-                 "serving.placement.disaggregate"),
-                ((self.recurrent or self.carried) and spec_on, "serving.speculative"),
-            ):
-                if on:
-                    # every kind of state that stands in the way, by name: a
-                    # family may hold more than one (a recurrent state beside
-                    # a latent pool)
-                    name = type(mcfg).__name__
-                    why = [text for held, text in (
-                        (self.recurrent,
-                         f"recurrent state ({name}): "
-                         f"{self._state_what} and convolution "
-                         "rows live in a per-slot pool beside the paged "
-                         "pool, which this mechanism does not handle"),
-                        (self.carried,
-                         f"carried attention rows ({name}): "
-                         "the queries, keys and values of a call's first "
-                         "rows need the rows before them, which live in a "
-                         "per-slot pool beside the paged pool; this "
-                         "mechanism does not handle it (pages without the "
-                         "rows at their end would serve a wrong token)"),
-                        (self.windowed,
-                         f"sliding-window layers ({name}): a "
-                         "window layer's KV lives in a per-slot ring beside "
-                         "the paged pool, which this mechanism does not handle"),
-                        (self.latent,
-                         f"a latent KV pool ({name}): its "
-                         "cache is one pool of one row a token that every "
-                         "head reads, with no V pool and no head axis, which "
-                         "this mechanism does not handle"),
-                    ) if held]
-                    if what == "serving.speculative":   # only a state that cannot be rolled back refuses a draft
-                        why = why[: int(self.recurrent) + int(self.carried)]
-                    raise ValueError(f"{what} is not available for a model with " + "; and with ".join(why))
+        # what a family holds besides paged per-head pages (window rings, a
+        # latent pool, recurrent state, carried rows: ``kv_cache.STATE_KINDS``)
+        # the mechanisms that move, share, shard or re-code pages do not handle
+        plc_ = getattr(config, "placement", None)
+        for on, what in (
+            (getattr(getattr(config, "prefix_cache", None), "enabled", False),
+             "serving.prefix_cache"),
+            (getattr(getattr(config, "tiering", None), "enabled", False),
+             "serving.tiering"),
+            (int8_pages, "serving.kv_cache_dtype=int8"),
+            (plc_ is not None and max(
+                int(getattr(plc_, k, 0) or 0)
+                for k in ("tp", "decode_tp", "prefill_tp")) > 1,
+             "serving.placement.tp > 1"),
+            (plc_ is not None and bool(getattr(plc_, "disaggregate", False)),
+             "serving.placement.disaggregate"),
+            (bool(getattr(getattr(config, "speculative", None), "enabled", False)),
+             "serving.speculative"),
+        ):
+            if on:
+                self._refuse_unhandled(what)
 
         page = int(config.page_size)
         self.page_size = page
@@ -397,7 +347,7 @@ class ServingEngine:
                     int(spec_.k) + 1 if spec_ is not None and spec_.enabled else 1,
                 ), page,
             ) + 1
-            if self.windowed else 0
+            if any(fam.windows) else 0
         )
 
         # -- ISSUE 14: placements + program sets ---------------------------
@@ -960,15 +910,15 @@ class ServingEngine:
         log_dist(
             f"ServingEngine: slots={self.max_slots} page={page} "
             f"pages={config.num_pages} (pool "
-            f"{self.decode_set.local_pool_bytes() * self.decode_placement.tp / 1e6:.1f} MB"
+            f"{self.decode_set.cache_bytes()['pages'] / 1e6:.1f} MB"
             + (
                 f" + {scales_bytes(self.decode_set.n_layer, int(config.num_pages), self.decode_set.n_kv_head) / 1e6:.2f} MB scales"
                 if self.quantized else ""
             )
             + (
-                f" + {self.decode_set.window_pool_bytes() / 1e6:.1f} MB in "
+                f" + {self.decode_set.cache_bytes()['window'] / 1e6:.1f} MB in "
                 f"{self.ring_pages}-page window rings"
-                if self.windowed else ""
+                if self.ring_pages else ""
             )
             + f", [{self.decode_set.local_pool_dims()}] a device"
             + f") prefill_width={self.prefill_width} dtype={np.dtype(self.cache_dtype).name} "
@@ -987,19 +937,26 @@ class ServingEngine:
     # main pool; pre-ISSUE-14 callers and tests read these directly) -------
     @property
     def k_pool(self):
-        return self.decode_set.k_pool
+        return self.decode_set.cache.k
 
     @property
     def v_pool(self):
-        return self.decode_set.v_pool
+        return self.decode_set.cache.v
 
     @property
     def kv_scales(self):
-        return self.decode_set.kv_scales
+        return self.decode_set.cache.scales
 
     @property
     def allocator(self):
         return self.decode_set.allocator
+
+    def _refuse_unhandled(self, mechanism: str) -> None:
+        """Raise where ``mechanism`` does not handle a kind of per-slot state
+        this model's family holds (``kv_cache.refusals``)."""
+        why = refusals(self.family, mechanism, type(self.model_config).__name__)
+        if why:
+            raise ValueError(f"{mechanism} is not available for a model with " + "; and with ".join(why))
 
     @property
     def expected_executables(self) -> int:
@@ -1188,78 +1145,53 @@ class ServingEngine:
     def _compile_programs(self) -> None:
         sc = self.config
         temp, tk, top_p = float(sc.temperature), int(sc.top_k), float(sc.top_p)
-        quant = self.quantized
         S = jax.ShapeDtypeStruct
         i32, u32 = jnp.int32, jnp.uint32
 
-        # int8 pools (ISSUE 12) thread the scales pool as one more donated
-        # operand through every program; the wrappers keep the operand order
-        # (params, k_pool, v_pool[, scales], ...static tables...) so the
-        # step loop below stays mode-agnostic apart from the scales slot.
+        # Every program is ``fn(params, cache, *host operands)`` and gives
+        # the cache back first (``kv_cache.Cache``: one donated value, which
+        # kinds of state it holds is the family's affair and the programs').
         # Each program is built FOR a placement (ISSUE 14): it traces with
         # that placement's LOCAL model config (n_embd/n_head divided by tp)
         # and psums its row-parallel partials over the tp axis.
-        # A window family's two ring pools follow as two more donated
-        # operands, and its prefill and chunk programs take the slot (whose
-        # ring they write) as their last host operand.
+        # The prefill and chunk programs of a family that keeps state a slot
+        # take the slot as their last host operand (:meth:`_slot_operand`).
         # The chunk program is the MIXED step: one prefilling slot's chunk
         # and every slot's decode row through the weights once. Its host
-        # operands are the decode step's four, then the chunk's.
-        windowed, ring = self.windowed, self.ring_pages
-        n_state = len(self.decode_set.state_pools or ())   # the state pools a program threads
-
-        # where the tokens lie in a decode program's results: last, or before the expert loads
-        tok_at = -2 if self.family.sparse_layers else -1
+        # operands are the decode step's six, then the chunk's.
+        ring = self.ring_pages
 
         def make_fns(cfg, tp_axis):
-            def prefill_fn(params, k_pool, v_pool, *rest):
-                scales, win, state, (ids, plen, page_ids, key, *slot) = _split_pools(
-                    rest, quant, windowed, n_state
-                )
+            def prefill_fn(params, cache, ids, plen, page_ids, key, slot=None):
                 return smodel.paged_prefill(
-                    cfg, params, ids, plen, k_pool, v_pool, page_ids, key,
-                    temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
-                    ring=ring, state=state,
+                    cfg, params, ids, plen, cache, page_ids, key,
+                    temperature=temp, top_k=tk, top_p=top_p,
+                    tp_axis=tp_axis, slot=slot, ring=ring,
                 )
 
-            def decode_fn(params, k_pool, v_pool, *rest):
-                scales, win, state, (tokens, seq_lens, bt, keys, prev, src) = _split_pools(
-                    rest, quant, windowed, n_state
+            def decode_fn(params, cache, tokens, seq_lens, bt, keys, prev, src):
+                cache, nxt, *counts = smodel.paged_decode_step(
+                    cfg, params, _tokens_of(tokens, prev, src), seq_lens, cache, bt, keys,
+                    temperature=temp, top_k=tk, top_p=top_p, tp_axis=tp_axis, ring=ring,
                 )
-                out = list(smodel.paged_decode_step(
-                    cfg, params, _tokens_of(tokens, prev, src), seq_lens,
-                    k_pool, v_pool, bt, keys,
-                    temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis, win=win, ring=ring, state=state,
-                ))
                 # the chunk program's shape: either's tokens are the next
                 # call's ``prev``, whichever program that is
-                out[tok_at] = jnp.pad(out[tok_at], (0, 1))
-                return tuple(out)
+                return (cache, jnp.pad(nxt, (0, 1)), *counts)
 
-            def verify_fn(params, k_pool, v_pool, *rest):
-                scales, win, state, (tokens, seq_lens, bt) = _split_pools(
-                    rest, quant, windowed, n_state
-                )
+            def verify_fn(params, cache, tokens, seq_lens, bt):
                 return smodel.paged_verify_step(
-                    cfg, params, tokens, seq_lens, k_pool, v_pool, bt,
-                    scales=scales, tp_axis=tp_axis, win=win, ring=ring,
+                    cfg, params, tokens, seq_lens, cache, bt, tp_axis=tp_axis, ring=ring,
                 )
 
             # named for what a trace's readers find it by: a chunk program
             # and the program a decode dispatch launches
-            def chunk_decode_fn(params, k_pool, v_pool, *rest):
-                scales, win, state, (
-                    tokens, seq_lens, bt, keys, prev, src,
-                    ids, start, plen, page_ids, bt_row, key, *slot,
-                ) = _split_pools(rest, quant, windowed, n_state)
+            def chunk_decode_fn(params, cache, tokens, seq_lens, bt, keys, prev, src,
+                                ids, start, plen, page_ids, bt_row, key, slot=None):
                 return smodel.paged_mixed_step(
-                    cfg, params, _tokens_of(tokens, prev, src), seq_lens, ids, start, plen, k_pool,
-                    v_pool, bt, page_ids, bt_row, keys, key,
-                    temperature=temp, top_k=tk, top_p=top_p, scales=scales,
-                    tp_axis=tp_axis, win=win, slot=slot[0] if slot else None,
-                    ring=ring, state=state,
+                    cfg, params, _tokens_of(tokens, prev, src), seq_lens, ids, start, plen,
+                    cache, bt, page_ids, bt_row, keys, key,
+                    temperature=temp, top_k=tk, top_p=top_p,
+                    tp_axis=tp_axis, slot=slot, ring=ring,
                 )
 
             return prefill_fn, decode_fn, verify_fn, chunk_decode_fn
@@ -1300,7 +1232,7 @@ class ServingEngine:
             p_fns if self.prefill_placement is self.decode_placement
             else make_fns(d_cfg, self.decode_placement.tp_axis)
         )
-        sfx = "_int8" if quant else ""
+        sfx = "_int8" if self.quantized else ""
         info: dict = {}
 
         self._prefill_exec = compile_for(self.prefill_set, p_fns[0], (
@@ -1345,10 +1277,10 @@ class ServingEngine:
             self.executables.append(self._chunk_exec)
 
         if self.disaggregated:
-            self._compile_handoff(info, quant, S, i32)
+            self._compile_handoff(info, sfx, S((self.prefill_pages,), i32))
 
         if self.tiering_enabled:
-            self._compile_restore(info, quant, S, i32)
+            self._compile_restore(info, sfx, S((1,), i32))
 
         self._program_info = info
         self._register_parts()
@@ -1372,55 +1304,32 @@ class ServingEngine:
             if "fn" in rec:   # the programs that run the model: the page movers hold no part
                 parts.register("jit_" + rec["fn"], text_of(key))
 
-    def _compile_handoff(self, info: dict, quant: bool, S, i32) -> None:
+    def _compile_handoff(self, info: dict, sfx: str, src_sds) -> None:
         """The disaggregated KV handoff pair (ISSUE 14): ``gather`` packs a
         finished prompt's pages out of the prefill pool ([L, n, KV, page, D]
         per pool, scales ride along under int8); the packed buffers cross
         placements via ``jax.device_put``; ``scatter`` writes them into the
-        decode pool's pages (pools donated — the decode cache never exists
+        decode pool's pages (the cache donated — the decode cache never exists
         twice). Page-id lists are scratch-padded to the static
         ``prefill_pages`` width, so the pair compiles once; duplicate pad
         indices all target scratch page 0, which no active slot reads."""
-        n_hp = self.prefill_pages
-
-        def gather_fn(k_pool, v_pool, *rest):
-            scales, (src,) = _split_scales(rest, quant)
-            out = (k_pool[:, src], v_pool[:, src])
-            if scales is not None:
-                out = out + (scales[:, src],)
-            return out
-
-        def scatter_fn(k_pool, v_pool, *rest):
-            scales, packed = _split_scales(rest, quant)
-            if quant:
-                pk, pv, ps, dst = packed
-            else:
-                pk, pv, dst = packed
-            k_pool = k_pool.at[:, dst].set(pk)
-            v_pool = v_pool.at[:, dst].set(pv)
-            if quant:
-                return k_pool, v_pool, scales.at[:, dst].set(ps)
-            return k_pool, v_pool
-
-        sfx = "_int8" if quant else ""
         pp, dp = self.prefill_placement, self.decode_placement
         pset, dset = self.prefill_set, self.decode_set
-        src_sds = S((n_hp,), i32)
 
-        # gather: prefill pools are READ, not donated — the prompt pages
+        # gather: the prefill cache is READ, not donated — the prompt pages
         # stay live for the prefix index until the host frees them
         self._gather_exec = pset.aot(
             gather_fn, (src_sds,), (pp.rep_spec(),), pset.packed_specs(),
-            returns_pools=False, donate=False,
+            returns_cache=False, donate=False,
         )
         info[f"serving_kv_gather{sfx}{pp.suffix()}"] = {
             "exe": self._gather_exec, "pset": pset, "kind": "gather",
         }
         self.executables.append(self._gather_exec)
 
-        # scatter: decode pools donated
+        # scatter: the decode cache donated
         self._scatter_exec = dset.aot(
-            scatter_fn, dset.packed_sds(n_hp) + (src_sds,),
+            scatter_fn, dset.packed_sds(self.prefill_pages) + (src_sds,),
             dset.packed_specs() + (dp.rep_spec(),),
         )
         info[f"serving_kv_scatter{sfx}{dp.suffix()}"] = {
@@ -1428,30 +1337,17 @@ class ServingEngine:
         }
         self.executables.append(self._scatter_exec)
 
-    def _compile_restore(self, info: dict, quant: bool, S, i32) -> None:
+    def _compile_restore(self, info: dict, sfx: str, dst_sds) -> None:
         """The host-tier restore program (ISSUE 17): a width-1 scatter into
         the PREFILL placement's pool (where the prefix index lives) —
-        ``(pools..., packed_k, packed_v[, packed_s], dst) -> pools`` with
-        the pools donated, so a restore rewrites exactly one page column in
+        ``(cache, packed_k, packed_v[, packed_s], dst) -> cache`` with
+        the cache donated, so a restore rewrites exactly one page column in
         place. The packed operands arrive as host numpy straight out of the
         :class:`HostPageStore` buffers (the ``device_put`` leg of the
         async_swapper pattern rides the program's own operand transfer)."""
-        def restore_fn(k_pool, v_pool, *rest):
-            scales, packed = _split_scales(rest, quant)
-            if quant:
-                pk, pv, ps, dst = packed
-            else:
-                pk, pv, dst = packed
-            k_pool = k_pool.at[:, dst].set(pk)
-            v_pool = v_pool.at[:, dst].set(pv)
-            if quant:
-                return k_pool, v_pool, scales.at[:, dst].set(ps)
-            return k_pool, v_pool
-
-        sfx = "_int8" if quant else ""
         pp, pset = self.prefill_placement, self.prefill_set
         self._restore_exec = pset.aot(
-            restore_fn, pset.packed_sds(1) + (S((1,), i32),),
+            restore_fn, pset.packed_sds(1) + (dst_sds,),
             pset.packed_specs() + (pp.rep_spec(),),
         )
         info[f"serving_kv_restore{sfx}{pp.suffix()}"] = {
@@ -1492,11 +1388,11 @@ class ServingEngine:
             for B, T in shapes.get(rec["kind"], ()):
                 steps[name] += latent_attention_grid_steps(
                     self.model_config.attn_impl, B, self.family.n_head,
-                    pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
+                    pset.page_size, pset.head_dim, pset.cache.k.dtype.itemsize,
                     self.pages_per_slot, T or 1,
-                ) if self.latent else paged_attention_grid_steps(
+                ) if pset.cache.latent else paged_attention_grid_steps(
                     self.model_config.attn_impl, B, pset.local_kv_heads(),
-                    pset.page_size, pset.head_dim, pset.k_pool.dtype.itemsize,
+                    pset.page_size, pset.head_dim, pset.cache.k.dtype.itemsize,
                     self.pages_per_slot, T,
                     rep=self.family.n_head // self.family.n_kv_head,
                 )
@@ -1505,23 +1401,24 @@ class ServingEngine:
             self._g_grid_steps.set(steps[name], program=name)
         self._attn_kernel = any(steps.values())
         ds = self.decode_set
+        by = ds.cache_bytes()
         kv_bytes = {
-            "latent" if self.latent else "paged": ds.local_pool_bytes() * ds.placement.tp,
-            "window": ds.window_pool_bytes(),
-            **({"state": ds.state_pool_bytes()} if self.recurrent else {}),
-            **({"carry": ds.carry_pool_bytes} if self.carried else {}),
+            "latent" if ds.cache.latent else "paged": by["pages"],
+            "window": by["window"],
+            **({"state": by["state"]} if ds.cache.rec is not None else {}),
+            **({"carry": by["carry"]} if ds.cache.carry is not None else {}),
         }
         for cls, n in kv_bytes.items():
             self._g_kv_bytes.set(n, **{"class": cls})
         # what one token costs one layer of the cache, as it is stored
         row_bytes = (
             ds.kv_pools * ds.n_kv_head * ds.head_dim
-            * jnp.dtype(ds.k_pool.dtype).itemsize
+            * jnp.dtype(ds.cache.k.dtype).itemsize
         )
         self._g_kv_row_bytes.set(row_bytes)
         self._g_ring_pages.set(self.ring_pages)
-        self._g_carry_bytes.set(ds.carry_pool_bytes)
-        self._g_lin_state_bytes.set(ds.lin_state_bytes)
+        self._g_carry_bytes.set(by["carry"])
+        self._g_lin_state_bytes.set(by["lin_state"])
         self._g_experts_held.set(self.family.experts_held)
         hc_row_bytes = getattr(self.family, "stream_row_width", 0) * np.dtype(self.engine.dtype).itemsize
         self._g_hc_row_bytes.set(hc_row_bytes)
@@ -1537,10 +1434,10 @@ class ServingEngine:
         attrs.update(window_pages_per_slot=self.ring_pages,
                      moe_experts_held=self.family.experts_held,
                      kv_row_bytes=row_bytes)
-        if self.carried:
-            attrs["carry_rows"] = ds.carry_pool_bytes
-        if ds.lin_state_bytes:
-            attrs["lin_state_bytes"] = ds.lin_state_bytes
+        if ds.cache.carry is not None:
+            attrs["carry_rows"] = by["carry"]
+        if by["lin_state"]:
+            attrs["lin_state_bytes"] = by["lin_state"]
         if hc_row_bytes:
             attrs["hc_row_bytes"] = hc_row_bytes
         return attrs
@@ -2059,7 +1956,7 @@ class ServingEngine:
             # token this step writes) and the pages those contexts hold
             lens = self.table.seq_lens[active]
             attended = int(lens.sum()) + len(active)
-            if self.windowed:
+            if self.ring_pages:
                 # what a layer reads, averaged over the layers: a window
                 # layer reads its window of a context, not the context
                 # (of a family of several kinds the sub-blocks that
@@ -2104,10 +2001,7 @@ class ServingEngine:
                     d = self._draft(self.slots[i].request)
                     flight.drafts[i] = d
                     vt[i, 1:] = d
-                dset = self.decode_set
-                flight.out = dset.take_pools(self._verify_exec(
-                    dset.params, *dset.pool_args(), vt, rows[1], rows[2],
-                ))
+                flight.out = self.decode_set.call(self._verify_exec, vt, rows[1], rows[2])
                 self._c_spec_steps.inc()
                 self._c_spec_drafted.inc(self.spec_k * len(active))
             elif rider is not None:
@@ -2127,10 +2021,7 @@ class ServingEngine:
                     self.slots[rider].first_due = True
                     self._arm(rider)
             else:
-                dset = self.decode_set
-                flight.out = dset.take_pools(self._decode_exec(
-                    dset.params, *dset.pool_args(), *rows,
-                ))
+                flight.out = self.decode_set.call(self._decode_exec, *rows)
             flight.launch = self._launches   # the step program is the leaf's last call
             # the dispatched side moves on at the launch: the next step's
             # lengths and keys are known without this one's tokens (a verify
@@ -2594,10 +2485,9 @@ class ServingEngine:
             page_ids = np.zeros((self.prefill_pages,), np.int32)
             page_ids[: len(prefill_pages)] = prefill_pages
             with spans.span("ds.serve.launch", **self._launch_attrs("prefill", 0, req.prompt_len)):
-                first = self._token_of(pset.take_pools(self._prefill_exec(
-                    pset.params, *pset.pool_args(),
-                    ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
-                )))
+                first = self._token_of(pset.call(
+                    self._prefill_exec, ids, np.asarray(req.prompt_len, np.int32), page_ids, key0,
+                ))
             self._c_prefills.inc()
             slot.pending_tok, slot.first_launch = first, self._launches
             slot.prefilling = True
@@ -2617,12 +2507,11 @@ class ServingEngine:
         slot.row = np.zeros((1, self.pages_per_slot), np.int32)
         slot.row[0, : len(pages)] = pages
         with spans.span("ds.serve.launch", **self._launch_attrs("prefill", 0, req.prompt_len)):
-            first = self._token_of(pset.take_pools(self._prefill_exec(
-                pset.params, *pset.pool_args(),
-                ids, np.asarray(req.prompt_len, np.int32),
+            first = self._token_of(pset.call(
+                self._prefill_exec, ids, np.asarray(req.prompt_len, np.int32),
                 slot.row[0, : self.prefill_pages], key0,
                 *self._slot_operand(slot_i),
-            )))
+            ))
         self._c_prefills.inc()
         slot.first_launch = self._launches
         if self._flight is not None:
@@ -2651,7 +2540,7 @@ class ServingEngine:
         family that keeps per-slot state there: the slot, whose ring a window
         layer writes, whose carried rows an attention reads and leaves and
         whose recurrent state a mixer starts from and leaves."""
-        return (np.asarray(slot_i, np.int32),) if self.windowed or self.carried or self.recurrent else ()
+        return (np.asarray(slot_i, np.int32),) if self.decode_set.cache.per_slot else ()
 
     def _token_of(self, out):
         """The sampled token of a prefill program's results (a family with
@@ -2685,12 +2574,11 @@ class ServingEngine:
         with spans.span("ds.serve.launch", **self._launch_attrs(
             "mixed" if carried else "chunk", carried, len(seg)
         )):
-            out = pset.take_pools(self._chunk_exec(
-                pset.params, *pset.pool_args(), *rows,
-                ids, np.asarray(start, np.int32),
+            out = pset.call(
+                self._chunk_exec, *rows, ids, np.asarray(start, np.int32),
                 np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
                 *self._slot_operand(slot_i),
-            ))
+            )
         self._c_chunks.inc()
         slot.prefill_pos = start + C
         final = slot.prefill_pos >= req.prompt_len
@@ -2721,7 +2609,7 @@ class ServingEngine:
         layer's ring is not counted), by the rule the kernel's wrapper walks
         by. Where the programs call the kernel, both are added to the
         counters under its name in a trace."""
-        if self.latent:
+        if self.decode_set.cache.latent:
             kernel = "mla_paged_" + ("chunk" if chunk else "decode" if T is None else "verify")
             walk, rect = latent_walk_steps(
                 base, self.family.n_head, self.page_size, T or 1, self.pages_per_slot
@@ -2731,7 +2619,7 @@ class ServingEngine:
             pset = self.decode_set
             shape = (
                 pset.local_kv_heads(), pset.page_size, pset.head_dim,
-                pset.k_pool.dtype.itemsize, self.pages_per_slot,
+                pset.cache.k.dtype.itemsize, self.pages_per_slot,
             )
             if chunk:
                 rep = self.family.n_head // self.family.n_kv_head
@@ -2888,10 +2776,9 @@ class ServingEngine:
         dst = np.zeros((self.prefill_pages,), np.int32)
         dst[:n] = slot.pages[:n]
         pset, dset = self.prefill_set, self.decode_set
-        packed = self._gather_exec(*pset.pool_args(), src)
+        packed = self._gather_exec(pset.cache, src)
         moved = tuple(dset.placement.pull_pool(x) for x in packed)
-        out = self._scatter_exec(*dset.pool_args(), *moved, dst)
-        dset.set_pools(out)
+        out = (dset.cache,) = self._scatter_exec(dset.cache, *moved, dst)
         # sync for latency truth: the handoff gauge must cover the actual
         # copy, not its async dispatch
         with spans.span("ds.serve.handoff.wait"):
@@ -3250,55 +3137,17 @@ class ServingEngine:
         so each side compiles exactly once per engine."""
         if self._migrate_gather_exec is not None:
             return
-        why = [text for held, text in (
-            (self.recurrent,
-             "recurrent state: the transport moves a slot's paged row, and "
-             f"{self._state_what} and convolution rows would stay behind"),
-            (self.carried,
-             "carried attention rows: the transport moves a slot's paged row, and "
-             "the rows its next token's queries, keys and values need "
-             "would stay behind"),
-            (self.windowed,
-             "sliding-window layers: the transport moves a slot's paged "
-             "row, and its window rings would stay behind"),
-            (self.latent,
-             "a latent KV pool: the transport packs a K and a V pool's page columns"),
-        ) if held]
-        if why:
-            raise ValueError("session migration is not available for a model with " + "; and with ".join(why))
+        self._refuse_unhandled("session migration")
         self._ensure_compiled()
-        S = jax.ShapeDtypeStruct
-        i32 = jnp.int32
-        quant = self.quantized
-        W = self.pages_per_slot
-
-        def gather_fn(k_pool, v_pool, *rest):
-            scales, (src,) = _split_scales(rest, quant)
-            out = (k_pool[:, src], v_pool[:, src])
-            if scales is not None:
-                out = out + (scales[:, src],)
-            return out
-
-        def scatter_fn(k_pool, v_pool, *rest):
-            scales, packed = _split_scales(rest, quant)
-            if quant:
-                pk, pv, ps, dst = packed
-            else:
-                pk, pv, dst = packed
-            k_pool = k_pool.at[:, dst].set(pk)
-            v_pool = v_pool.at[:, dst].set(pv)
-            if quant:
-                return k_pool, v_pool, scales.at[:, dst].set(ps)
-            return k_pool, v_pool
-
         dp, dset = self.decode_placement, self.decode_set
-        ids_sds = S((W,), i32)
+        W = self.pages_per_slot
+        ids_sds = jax.ShapeDtypeStruct((W,), jnp.int32)
         # gather: decode pools READ, not donated — the source row stays
         # live until the peer's adoption is validated (crc), so a corrupt
         # payload never costs the conversation more than a requeue
         self._migrate_gather_exec = dset.aot(
             gather_fn, (ids_sds,), (dp.rep_spec(),), dset.packed_specs(),
-            returns_pools=False, donate=False,
+            returns_cache=False, donate=False,
         )
         self._migrate_scatter_exec = dset.aot(
             scatter_fn, dset.packed_sds(W) + (ids_sds,),
@@ -3328,7 +3177,7 @@ class ServingEngine:
         ids = np.zeros((self.pages_per_slot,), np.int32)
         ids[:n] = np.asarray(slot.pages, np.int32)
         dset = self.decode_set
-        packed = self._migrate_gather_exec(*dset.pool_args(), ids)
+        packed = self._migrate_gather_exec(dset.cache, ids)
         packed_np = [np.asarray(x) for x in jax.device_get(packed)]  # dslint: disable=host-sync-in-step
         arrays = {"k_pages": packed_np[0], "v_pages": packed_np[1]}
         if self.quantized:
@@ -3450,8 +3299,7 @@ class ServingEngine:
         args = [arrays["k_pages"], arrays["v_pages"]]
         if self.quantized:
             args.append(arrays["kv_scales"])
-        out = self._migrate_scatter_exec(*dset.pool_args(), *args, dst)
-        dset.set_pools(out)
+        (dset.cache,) = self._migrate_scatter_exec(dset.cache, *args, dst)
         slot = self.slots[slot_i]
         slot.request = req
         slot.pages = list(pages)
@@ -3868,7 +3716,7 @@ class ServingEngine:
             self.page_size, ds.head_dim,
             np.dtype(self.cache_dtype).itemsize, pools=ds.kv_pools,
         )
-        out["kv_window_bytes"] = ds.window_pool_bytes()
+        out["kv_window_bytes"] = ds.cache_bytes()["window"]
         out["kv_scales_bytes"] = (
             scales_bytes(ds.n_layer, int(self.config.num_pages), ds.n_kv_head)
             if self.quantized else 0
@@ -3877,6 +3725,7 @@ class ServingEngine:
         # per-device pool bytes drop 1/tp, the whole point of the axis
         psets = {self.decode_set.placement.name: self.decode_set}
         psets[self.prefill_set.placement.name] = self.prefill_set
+        per_device = {name: {k: n // ps.placement.tp for k, n in ps.cache_bytes().items()} for name, ps in psets.items()}
         out["placement"] = {
             "tp": self.tp,
             "disaggregated": self.disaggregated,
@@ -3888,8 +3737,8 @@ class ServingEngine:
                     ],
                     "num_pages": ps.num_pages,
                     "pages_in_use": ps.allocator.pages_in_use,
-                    "per_device_pool_bytes": ps.local_pool_bytes(),
-                    "per_device_scales_bytes": ps.local_scales_bytes(),
+                    "per_device_pool_bytes": per_device[name]["pages"],
+                    "per_device_scales_bytes": per_device[name]["scales"],
                 }
                 for name, ps in psets.items()
             },

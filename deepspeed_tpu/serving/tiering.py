@@ -486,12 +486,8 @@ class KVTieringEngine:
         pk = np.ascontiguousarray(k)[:, None]
         pv = np.ascontiguousarray(v)[:, None]
         dst = np.array([pid], np.int32)
-        args = list(self.pset.pool_args()) + [pk, pv]
-        if s is not None:
-            args.append(np.ascontiguousarray(s)[:, None])
-        args.append(dst)
-        out = self._restore_exec(*args)
-        self.pset.set_pools(out)
+        packed = [pk, pv] + ([np.ascontiguousarray(s)[:, None]] if s is not None else [])
+        (self.pset.cache,) = self._restore_exec(self.pset.cache, *packed, dst)
         hid = self.store.drop(key)  # exactly-one-tier: host copy retires
         self.restores += 1
         self.restored_bytes += self.store.page_bytes

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from deepspeed_tpu.serving.kv_cache import Cache
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -284,33 +286,33 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, layout, m
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(
-                cfg, p, tok, lens, k, v, bt, keys),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, c, bt, keys),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "verify": (  # three drafts a slot: one write and four attentions a layer
-            lambda p, k, v, tok, lens, bt: smodel.paged_verify_step(
-                cfg, p, tok, lens, k, v, bt),
+            lambda p, c, tok, lens, bt: smodel.paged_verify_step(
+                cfg, p, tok, lens, c, bt),
             (sds((B, 4), i32), sds((B,), i32), sds((B, W), i32)),
         ),
         "chunk": (
-            lambda p, k, v, ids, start, plen, pages, bt, key:
+            lambda p, c, ids, start, plen, pages, bt, key:
                 smodel.paged_chunk_prefill(
-                    cfg, p, ids, start, plen, k, v, pages, bt, key),
+                    cfg, p, ids, start, plen, c, pages, bt, key),
             (sds((1, C), i32), sds((), i32), sds((), i32),
              sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "mixed": (  # what the engine compiles in the chunk program's place (ISSUE 35)
-            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key:
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key:
                 smodel.paged_mixed_step(
-                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+                    cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
              sds((1, C), i32), sds((), i32), sds((), i32),
              sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "prefill": (
-            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(
-                cfg, p, ids, plen, k, v, pages, key),
+            lambda p, c, ids, plen, pages, key: smodel.paged_prefill(
+                cfg, p, ids, plen, c, pages, key),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32),
              sds((2,), u32)),
         ),
@@ -319,7 +321,7 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, layout, m
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
         placement=Placement("v5e", [one_chip._device], 1), params=params,
-        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=None,
+        cache=Cache(pool, pool),
         _kv_axis=pool.ndim - 3,
         num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=L,
     )
@@ -337,7 +339,7 @@ def test_paged_programs_keep_the_pool_in_one_layout(one_chip, program, layout, m
         for kernel in ("decode_fn", "chunk_fn", "kv_token_write"):
             assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
-    for fmt in (took_in[1], took_in[2], *compiled.output_formats[:2]):
+    for fmt in (took_in[1].k, took_in[1].v, *compiled.output_formats[0][:2]):
         assert fmt.layout.major_to_minor == tuple(range(len(shape)))
     layer_kv_bytes = 2 * P * KV * page * 128 * 2  # 64 lanes pad to 128
     head_bytes = cfg.padded_vocab_size * cfg.n_embd * 2  # the tied head's transposed ``wte``
@@ -418,22 +420,22 @@ def test_gpt2_programs_read_the_tables_where_the_placement_laid_them(one_chip, p
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, c, bt, keys),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "chunk": (
-            lambda p, k, v, ids, start, plen, pages, bt, key:
-                smodel.paged_chunk_prefill(cfg, p, ids, start, plen, k, v, pages, bt, key),
+            lambda p, c, ids, start, plen, pages, bt, key:
+                smodel.paged_chunk_prefill(cfg, p, ids, start, plen, c, pages, bt, key),
             (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "mixed": (
-            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key:
-                smodel.paged_mixed_step(cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key:
+                smodel.paged_mixed_step(cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32), sds((1, C), i32), sds((), i32),
              sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "prefill": (
-            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            lambda p, c, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, c, pages, key),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
         ),
     }[program]
@@ -443,8 +445,8 @@ def test_gpt2_programs_read_the_tables_where_the_placement_laid_them(one_chip, p
     def compiled_over(params):
         pset = object.__new__(ProgramSet)
         pset.__dict__.update(
-            placement=Placement("v5e", [one_chip._device], 1), params=params, k_pool=pool, v_pool=pool,
-            kv_scales=None, window_pools=None, _kv_axis=pool.ndim - 3, num_pages=P, page_size=page,
+            placement=Placement("v5e", [one_chip._device], 1), params=params, cache=Cache(pool, pool),
+            _kv_axis=pool.ndim - 3, num_pages=P, page_size=page,
             n_kv_head=KV, head_dim=D, n_layer=L,
         )
         exe = pset.aot(fn, host, with_params=True)   # or WeightLayoutError: the program takes a leaf in another order
@@ -505,30 +507,30 @@ def test_window_family_programs_compile_at_the_served_size(one_chip, program, mo
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, kw, vw, tok, lens, bt, keys: smodel.paged_decode_step(
-                cfg, p, tok, lens, k, v, bt, keys, win=(kw, vw), ring=ring),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, c, bt, keys, ring=ring),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "chunk": (
-            lambda p, k, v, kw, vw, ids, start, plen, pages, bt, key, slot:
+            lambda p, c, ids, start, plen, pages, bt, key, slot:
                 smodel.paged_chunk_prefill(
-                    cfg, p, ids, start, plen, k, v, pages, bt, key,
-                    win=(kw, vw), slot=slot, ring=ring),
+                    cfg, p, ids, start, plen, c, pages, bt, key,
+                    slot=slot, ring=ring),
             (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
              sds((1, W), i32), sds((2,), u32), sds((), i32)),
         ),
         "mixed": (
-            lambda p, k, v, kw, vw, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
                 smodel.paged_mixed_step(
-                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key,
-                    win=(kw, vw), slot=slot, ring=ring),
+                    cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key,
+                    slot=slot, ring=ring),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
              sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
              sds((1, W), i32), sds((2,), u32), sds((), i32)),
         ),
         "prefill": (
-            lambda p, k, v, kw, vw, ids, plen, pages, key, slot: smodel.paged_prefill(
-                cfg, p, ids, plen, k, v, pages, key, win=(kw, vw), slot=slot, ring=ring),
+            lambda p, c, ids, plen, pages, key, slot: smodel.paged_prefill(
+                cfg, p, ids, plen, c, pages, key, slot=slot, ring=ring),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32),
              sds((), i32)),
         ),
@@ -536,7 +538,7 @@ def test_window_family_programs_compile_at_the_served_size(one_chip, program, mo
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
         placement=Placement("v5e", [one_chip._device], 1), params=params,
-        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=(wpool, wpool),
+        cache=Cache(pool, pool, None, wpool, wpool),
         _kv_axis=2, num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=1,
     )
     compiled = pset.aot(fn, host, with_params=True)
@@ -700,11 +702,11 @@ def _xl_decode_step(one_chip):
                           jax.eval_shape(lambda: gpt2.init_params(cfg, jax.random.PRNGKey(0))))
     pool = sds((L, P, KV, page, D), jnp.bfloat16)
 
-    def decode_fn(p, k, v, tok, lens, bt, keys):
-        return smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys)
+    def decode_fn(p, c, tok, lens, bt, keys):
+        return smodel.paged_decode_step(cfg, p, tok, lens, c, bt, keys)
 
-    return jax.jit(decode_fn, donate_argnums=(1, 2)).lower(
-        params, pool, pool, sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, W), jnp.int32),
+    return jax.jit(decode_fn, donate_argnums=(1,)).lower(
+        params, Cache(pool, pool), sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, W), jnp.int32),
         sds((B, 2), jnp.uint32)).compile().as_text()
 
 
@@ -893,29 +895,28 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, c, bt, keys),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "chunk": (
-            lambda p, k, v, ids, start, plen, pages, bt, key: smodel.paged_chunk_prefill(
-                cfg, p, ids, start, plen, k, v, pages, bt, key),
+            lambda p, c, ids, start, plen, pages, bt, key: smodel.paged_chunk_prefill(
+                cfg, p, ids, start, plen, c, pages, bt, key),
             (sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "mixed": (
-            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
-                cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
+                cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
              sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "prefill": (
-            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            lambda p, c, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, c, pages, key),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
         ),
     }[program]
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
-        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
-        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        placement=Placement("v5e", [one_chip._device], 1), params=params, cache=Cache(pool),
         _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=384, n_layer=L,
     )
     compiled = pset.aot(fn, host, with_params=True)
@@ -930,7 +931,7 @@ def test_latent_family_programs_compile_at_the_served_size(one_chip, program, mo
         for kernel in ("mla_paged_decode", "mla_paged_chunk", "kv_token_write"):
             assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
-    assert took_in[1].layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].layout.major_to_minor
+    assert took_in[1].k.layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].k.layout.major_to_minor
     mem = compiled.memory_analysis()
     print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.2e9   # of the chip's 16
@@ -1016,24 +1017,23 @@ def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, c, bt, keys),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "mixed": (
-            lambda p, k, v, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
-                cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key),
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key: smodel.paged_mixed_step(
+                cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
              sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32), sds((1, W), i32), sds((2,), u32)),
         ),
         "prefill": (
-            lambda p, k, v, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, k, v, pages, key),
+            lambda p, c, ids, plen, pages, key: smodel.paged_prefill(cfg, p, ids, plen, c, pages, key),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32)),
         ),
     }[program]
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
-        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
-        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        placement=Placement("v5e", [one_chip._device], 1), params=params, cache=Cache(pool),
         _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=640, n_layer=L,
     )
     compiled = pset.aot(fn, host, with_params=True)
@@ -1046,7 +1046,7 @@ def test_double_layer_latent_family_programs_compile_at_the_served_size(one_chip
         for kernel in names:
             assert len(re.findall(rf"^\s*%?{kernel}[.\d]* = .*custom-call\(", text, re.M)) == L, kernel
     took_in, _ = compiled.input_formats
-    assert took_in[1].layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].layout.major_to_minor
+    assert took_in[1].k.layout.major_to_minor == (0, 1, 2, 3, 4) == compiled.output_formats[0].k.layout.major_to_minor
     mem = compiled.memory_analysis()
     print(program, "argument", mem.argument_size_in_bytes, "temp", mem.temp_size_in_bytes)
     assert 12.6e9 < mem.argument_size_in_bytes < 12.8e9     # 10.35 GB of weights and 2.35 GB of pool
@@ -1298,29 +1298,29 @@ def test_recurrent_family_programs_compile_at_the_served_size(one_chip, program,
     i32, u32 = jnp.int32, jnp.uint32
     fn, host = {
         "decode": (
-            lambda p, k, v, kw, vw, s, cv, tok, lens, bt, keys: smodel.paged_decode_step(
-                cfg, p, tok, lens, k, v, bt, keys, win=(kw, vw), ring=ring, state=(s, cv)),
+            lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(
+                cfg, p, tok, lens, c, bt, keys, ring=ring),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)),
         ),
         "mixed": (
-            lambda p, k, v, kw, vw, s, cv, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
+            lambda p, c, tok, lens, bt, keys, ids, start, plen, pages, row, key, slot:
                 smodel.paged_mixed_step(
-                    cfg, p, tok, lens, ids, start, plen, k, v, bt, pages, row, keys, key,
-                    win=(kw, vw), slot=slot, ring=ring, state=(s, cv)),
+                    cfg, p, tok, lens, ids, start, plen, c, bt, pages, row, keys, key,
+                    slot=slot, ring=ring),
             (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32),
              sds((1, C), i32), sds((), i32), sds((), i32), sds((C // page,), i32),
              sds((1, W), i32), sds((2,), u32), sds((), i32)),
         ),
         "prefill": (
-            lambda p, k, v, kw, vw, s, cv, ids, plen, pages, key, slot: smodel.paged_prefill(
-                cfg, p, ids, plen, k, v, pages, key, win=(kw, vw), slot=slot, ring=ring, state=(s, cv)),
+            lambda p, c, ids, plen, pages, key, slot: smodel.paged_prefill(
+                cfg, p, ids, plen, c, pages, key, slot=slot, ring=ring),
             (sds((1, Sp), i32), sds((), i32), sds((Sp // page,), i32), sds((2,), u32), sds((), i32)),
         ),
     }[program]
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
         placement=Placement("v5e", [one_chip._device], 1), params=params,
-        k_pool=pool, v_pool=pool, kv_scales=None, window_pools=(wpool, wpool), state_pools=(ssm, conv),
+        cache=Cache(pool, pool, None, wpool, wpool, ssm, conv),
         _kv_axis=2, num_pages=P, page_size=page, n_kv_head=KV, head_dim=D, n_layer=1,
     )
     compiled = pset.aot(fn, host, with_params=True)
@@ -1474,12 +1474,11 @@ def test_multi_stream_family_decode_program_compiles_with_the_stream_kept_on_the
     i32, u32 = jnp.int32, jnp.uint32
     pset = object.__new__(ProgramSet)
     pset.__dict__.update(
-        placement=Placement("v5e", [one_chip._device], 1), params=params, kv_pools=1,
-        k_pool=pool, v_pool=None, kv_scales=None, window_pools=None,
+        placement=Placement("v5e", [one_chip._device], 1), params=params, cache=Cache(pool),
         _kv_axis=2, num_pages=P, page_size=page, n_kv_head=1, head_dim=640, n_layer=L,
     )
     compiled = pset.aot(
-        lambda p, k, v, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, k, v, bt, keys),
+        lambda p, c, tok, lens, bt, keys: smodel.paged_decode_step(cfg, p, tok, lens, c, bt, keys),
         (sds((B,), i32), sds((B,), i32), sds((B, W), i32), sds((B, 2), u32)), with_params=True,
     )
     text = compiled.as_text()

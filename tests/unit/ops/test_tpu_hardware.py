@@ -100,11 +100,13 @@ class TestFlashAttentionHardware:
             return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
         g_fused = grads()
-        fa._FUSED_BWD_ENABLED = False
+        budget = fa.FUSED_BWD_BYTES
+        fa.FUSED_BWD_BYTES = 0  # no shape fits: the shape rule selects the two-pass backward
         try:
+            assert not fa._fused_bwd_ok(512, 64)
             g_split = grads()
         finally:
-            fa._FUSED_BWD_ENABLED = True
+            fa.FUSED_BWD_BYTES = budget
         for a, b in zip(g_fused, g_split):
             np.testing.assert_allclose(
                 np.asarray(a, np.float32), np.asarray(b, np.float32),
@@ -122,55 +124,6 @@ class TestFlashAttentionHardware:
             np.asarray(o, np.float32), np.asarray(o_ref, np.float32),
             atol=2e-2, rtol=2e-2,
         )
-
-
-class TestBSEFlashHardware:
-    """S-major flash entry (lane-offset head blocks over [B,S,E]), opt-in.
-    D=128 blocks sit at 128-lane origins and compile; a D=64 block is a
-    sub-128-lane block that the Pallas TPU lowering rejects, so the gate
-    refuses it (chip run, PR 21)."""
-
-    def test_gate_refuses_sub_lane_head_dim(self):
-        from deepspeed_tpu.ops.pallas import flash_attention as fa
-
-        prev = fa._BSE_ENABLED
-        fa._BSE_ENABLED = True
-        try:
-            assert not fa._bse_ok(512, 64)
-            assert fa._bse_ok(512, 128)
-        finally:
-            fa._BSE_ENABLED = prev
-
-    def test_bse_fwd_bwd_matches_3d_on_chip(self):
-        from deepspeed_tpu.ops.pallas import flash_attention as fa
-
-        D, H = 128, 2
-        q, k, v = _qkv(1, 512, H, D, seed=11)
-
-        def grads():
-            loss = lambda q, k, v: jnp.sum(
-                fa.flash_attention(q, k, v).astype(jnp.float32) ** 2
-            )
-            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
-
-        prev = fa._BSE_ENABLED
-        fa._BSE_ENABLED = True
-        try:
-            assert fa._bse_ok(512, D)
-            l_bse, g_bse = grads()
-        finally:
-            fa._BSE_ENABLED = prev
-        fa._BSE_ENABLED = False
-        try:
-            l_3d, g_3d = grads()
-        finally:
-            fa._BSE_ENABLED = prev
-        np.testing.assert_allclose(float(l_bse), float(l_3d), rtol=1e-3)
-        for a, b in zip(g_bse, g_3d):
-            np.testing.assert_allclose(
-                np.asarray(a, np.float32), np.asarray(b, np.float32),
-                atol=1e-2, rtol=1e-2,
-            )
 
 
 class TestBlockSparseHardware:

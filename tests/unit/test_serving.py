@@ -356,6 +356,7 @@ class TestTokenWrite:
         this token, any other offset codes against the frozen one; K's and
         V's scales sit in their own columns."""
         from deepspeed_tpu.ops.quantizer import kv_page_scale, quantize_kv_token
+        from deepspeed_tpu.serving.kv_cache import Cache
         from deepspeed_tpu.serving import model as smodel
 
         rs, bt, lens = self._state(5, lens=[0, 1, 4, 7, 8])
@@ -368,8 +369,8 @@ class TestTokenWrite:
         kv = jnp.asarray(rs.randn(self.B, self.KV, self.D), jnp.float32)
         vv = jnp.asarray(rs.randn(self.B, self.KV, self.D), jnp.float32)
         k_new, v_new, new_scales = smodel._write_pool_tokens(
-            kp, vp, scales, 0, pidx, poff, kv, vv
-        )
+            Cache(kp, vp, scales), 0, pidx, poff, kv, vv
+        )[:3]
         fresh = np.asarray(poff) == 0
         for col, (new, pool, vals) in enumerate(((k_new, kp, kv), (v_new, vp, vv))):
             want_s = np.where(
@@ -498,7 +499,7 @@ class TestSplitPagePoolEngine:
             assert len({(t.spills, t.restores) for t in tiers}) == 1
         if not stored.disaggregated:  # there the hand-off's timing picks the pages
             np.testing.assert_array_equal(
-                np.asarray(kv_cache.pool_view(stored.k_pool)),
+                np.asarray(kv_cache.viewed(stored.decode_set.cache).k),
                 np.asarray(plain.k_pool),
             )
 
@@ -1407,8 +1408,8 @@ class TestServingStats:
             monkeypatch.setattr(decode_attention, "paged_page_ok", lambda *a: True)
             pset._check_pool_layout()  # row-major, as the CPU lays everything
             moved = NS(layout=NS(major_to_minor=(0, 2, 3, 4, 1)))
-            pset.k_pool = NS(format=moved, dtype=pset.k_pool.dtype, ndim=5,
-                             shape=pset.k_pool.shape)
+            pset.cache = pset.cache._replace(k=NS(
+                format=moved, dtype=pset.cache.k.dtype, ndim=5, shape=pset.cache.k.shape))
             with pytest.raises(PoolLayoutError, match="num_pages"):
                 pset._check_pool_layout()
         else:
@@ -1418,12 +1419,12 @@ class TestServingStats:
                 exe = real(*a)
                 took, kw = exe.input_formats
                 other = NS(layout="pages-minor")
-                return NS(input_formats=((took[0], other) + took[2:], kw),
+                return NS(input_formats=((took[0]._replace(k=other),) + took[1:], kw),
                           output_formats=exe.output_formats)
 
             monkeypatch.setattr(pset.placement, "aot", aot)
             with pytest.raises(PoolLayoutError, match="takes a float32"):
-                pset.aot(lambda k, v, i: (k, v, i), (jnp.zeros((), jnp.int32),))
+                pset.aot(lambda cache, i: (cache, i), (jnp.zeros((), jnp.int32),))
 
     @pytest.mark.parametrize("num_pages, axes", [
         (24, (24,)), (64, (64,)), (65, (5, 13)), (256, (4, 64)), (512, (8, 64)),

@@ -100,15 +100,15 @@ def test_window_state_does_not_grow_with_context_and_the_paged_pool_holds_full_l
     srv, _ = served
     ds = srv.decode_set
     page_bytes = 2 * 2 * 4 * 8 * 4          # K and V x kv heads x page x head_dim x float32
-    assert ds.k_pool.shape == (1, 64, 2, 4, 8) and ds.n_layer == 1            # the one full layer
-    assert ds.window_pools[0].shape == (2, 1 + 3 * 5, 2, 4, 8)                # two sliding layers, 3 slots x 5 pages + scratch
+    assert ds.cache.k.shape == (1, 64, 2, 4, 8) and ds.n_layer == 1            # the one full layer
+    assert ds.cache.win_k.shape == (2, 1 + 3 * 5, 2, 4, 8)                # two sliding layers, 3 slots x 5 pages + scratch
     g = srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",))
     assert g.value(**{"class": "window"}) == (3 * 5 + 1) * page_bytes * 2 == srv.stats()["kv_window_bytes"]
     assert g.value(**{"class": "paged"}) == 64 * page_bytes * 1
     assert srv.metrics.gauge("serving_window_pages_per_slot", "").value() == 5
     assert srv.metrics.gauge("serving_moe_experts_held", "").value() == 4
     longer = engine.serve(dict(SERVING, max_prompt_len=80, num_pages=128))
-    assert longer.decode_set.window_pool_bytes() == ds.window_pool_bytes()
+    assert longer.decode_set.cache_bytes()["window"] == ds.cache_bytes()["window"]
     assert longer.pages_per_slot > srv.pages_per_slot
 
 

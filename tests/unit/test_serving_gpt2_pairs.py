@@ -25,7 +25,7 @@ from deepspeed_tpu.ops.pallas.decode_attention import (
     paged_multitoken_blocks,
 )
 from deepspeed_tpu.serving import model as smodel
-from deepspeed_tpu.serving.kv_cache import pool_stored_shape
+from deepspeed_tpu.serving.kv_cache import Cache, pool_stored_shape
 
 pytestmark = pytest.mark.serving
 
@@ -85,7 +85,7 @@ def test_pair_attention_is_the_published_attention(n_head, path):
     assert q.shape == (1, S, fam.n_head, 128) and k.shape == v.shape == (1, S, fam.n_kv_head, 128)
     pools = [jnp.zeros((1, 1 + S // page, fam.n_kv_head, page, 128)) for _ in range(2)]
     page_ids = jnp.arange(1, 1 + S // page)
-    o, k_pool, v_pool, _ = smodel._attention_prefill_paged(fam, q, k, v, *pools, page_ids, 0)
+    o, (k_pool, v_pool, *_) = smodel._attention_prefill_paged(fam, q, k, v, Cache(*pools), page_ids, 0)
     if path == "paged":
         o = paged_multitoken_cached_attention(
             q, k_pool, v_pool, page_ids[None], jnp.zeros(1, jnp.int32), sm_scale=fam.sm_scale, layer=0,
@@ -142,7 +142,7 @@ def test_served_tokens_are_generates_odd_heads(engines, path):
                                "prefill_chunk_tokens": 8},
     }[path]
     srv = _serve_against_generate(engine, cfg, _prompts(cfg.vocab_size), **over)
-    assert srv.family.pairs and srv.decode_set.k_pool.shape[-3:] == (3, 4, 128)
+    assert srv.family.pairs and srv.decode_set.cache.k.shape[-3:] == (3, 4, 128)
     if path == "chunked_mixed":
         assert srv.metrics.counter("serving_chunks_rode_total", "").value() > 0
 
@@ -155,7 +155,7 @@ def test_served_tokens_are_generates_at_tp2(engines):
     srv = _serve_against_generate(engine, cfg, _prompts(cfg.vocab_size, PROMPTS[:5]), placement={"tp": 2},
                                   prefill_chunk_tokens=8)
     assert srv.decode_set.n_kv_head == 4 and srv.decode_set.local_kv_heads() == 2
-    assert srv.decode_set.k_pool.shape[-3:] == (4, 4, 128)
+    assert srv.decode_set.cache.k.shape[-3:] == (4, 4, 128)
 
 
 def test_int8_cache_keeps_a_scale_a_published_head(engines):
@@ -165,7 +165,7 @@ def test_int8_cache_keeps_a_scale_a_published_head(engines):
     engine, cfg = engines(5)
     srv = engine.serve(dict(SERVING, kv_cache_dtype="int8"))
     assert not srv.family.pairs
-    assert srv.decode_set.k_pool.shape[-3:] == (5, 4, 64) and srv.decode_set.kv_scales.shape[2] == 5
+    assert srv.decode_set.cache.k.shape[-3:] == (5, 4, 64) and srv.decode_set.cache.scales.shape[2] == 5
     prompts = _prompts(cfg.vocab_size, PROMPTS[:4])
     reqs = [srv.submit(p, max_new_tokens=6, seed=i) for i, p in enumerate(prompts)]
     srv.run()

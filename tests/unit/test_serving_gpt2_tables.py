@@ -319,8 +319,8 @@ class TestWeightCensus:
 
         monkeypatch.setattr(pset.placement, "aot", aot)
         with pytest.raises(plc.WeightLayoutError, match="takes wte float32.* as vocabulary-minor"):
-            pset.aot(lambda p, k, v, i: (k, v, i + p["wte"][0, 0].astype(jnp.int32)),
+            pset.aot(lambda p, cache, i: (cache, i + p["wte"][0, 0].astype(jnp.int32)),
                      (jnp.zeros((), jnp.int32),), with_params=True)
         monkeypatch.setattr(pset.placement, "aot", real)
         # a leaf the program does not read has no layout in it, and is not asked for one
-        pset.aot(lambda p, k, v, i: (k, v, i), (jnp.zeros((), jnp.int32),), with_params=True)
+        pset.aot(lambda p, cache, i: (cache, i), (jnp.zeros((), jnp.int32),), with_params=True)

@@ -17,6 +17,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import ling3 as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import STATE_KINDS
 from deepspeed_tpu.telemetry import parts, spans
 from perfbench import reference_ling3 as reference
 
@@ -91,22 +92,22 @@ def test_forward_absorbed_and_served_streams_are_the_references_with_both_kinds_
         other = jax.jit(jax.vmap(lambda i: reference.logits(engine.params, i, arch, skip)))(ids)
         assert float(jnp.abs(other - ref).max()) > 0.5, skip
     srv, reqs = served
-    assert srv.recurrent and srv.latent and not srv.windowed and not srv.carried
+    assert [k.holds(srv.family) for k in STATE_KINDS] == [True, False, False, True]   # recurrent AND latent; no carried rows, no rings
     for r, p in zip(reqs, prompts):      # 9 requests through 3 slots: every slot is used again, from zeros
         assert r.status == "finished" and len(r.tokens) == 12
         assert float(_gaps(engine.params, p, r.tokens, arch).max()) <= GAP_TOL, len(p)
     # -- the pools, the gauges, the phase: a latent pool of the 2 attention layers, no V pool, the states of the 4 KDA layers
     ds, fam = srv.decode_set, srv.family
-    assert smodel.pool_layers(fam) == (2, 0, 4) and ds.n_layer == 2 and fam.kv_pools == 1 and ds.v_pool is None
-    assert ds.k_pool.shape == (2, 64, 1, 4, 40) and ds.window_pools is None
-    lin, conv = ds.state_pools
+    assert smodel.pool_layers(fam) == (2, 0, 4) and ds.n_layer == 2 and fam.kv_pools == 1 and ds.cache.latent
+    assert ds.cache.k.shape == (2, 64, 1, 4, 40) and ds.cache.win_k is None
+    lin, conv, by = ds.cache.rec, ds.cache.conv, ds.cache_bytes()
     assert lin.shape == (4, 3, 4, 16, 16) and lin.dtype == jnp.float32 and conv.shape == (4, 3, 3, 3 * 64)
-    assert ds.lin_state_bytes == 4 * 3 * 4 * 16 * 16 * 4
-    assert srv.metrics.gauge("serving_lin_state_bytes", "").value() == ds.lin_state_bytes
+    assert by["lin_state"] == 4 * 3 * 4 * 16 * 16 * 4
+    assert srv.metrics.gauge("serving_lin_state_bytes", "").value() == by["lin_state"]
     kv = srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",))
-    assert kv.value(**{"class": "state"}) == ds.state_pool_bytes() and kv.value(**{"class": "latent"}) == 2 * 64 * 4 * 40 * 4
+    assert kv.value(**{"class": "state"}) == by["state"] and kv.value(**{"class": "latent"}) == 2 * 64 * 4 * 40 * 4
     phase = [p for p in spans.phases() if p[0] == "ds.init.programs"][-1]
-    assert phase[3]["lin_state_bytes"] == ds.lin_state_bytes and "state=" in phase[3]["kv_bytes"] and "latent=" in phase[3]["kv_bytes"]
+    assert phase[3]["lin_state_bytes"] == by["lin_state"] and "state=" in phase[3]["kv_bytes"] and "latent=" in phase[3]["kv_bytes"]
     assert smodel._kv_homes(fam) == [(False, 0), (False, 1), (False, 0), (False, 2), (False, 3), (False, 1)]
     assert fam.kinds == ("lin", "lin", "attn") * 2 and fam.sparse_layers == (1, 2, 3, 4, 5) and fam.lin_g_min == -5
     for name in ("jit_decode_fn", "jit_chunk_decode_fn", "jit_prefill_fn"):

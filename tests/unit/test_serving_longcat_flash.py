@@ -20,6 +20,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import longcat_flash as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import spans
 from perfbench import reference_longcat_flash as reference
 
@@ -152,16 +153,16 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
             buf = np.zeros((1, 8), np.int32)
             seg = ids[start:start + 8]
             buf[0, : len(seg)] = seg
-            pool, _, tok, counts = chunk(
-                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
+            (pool, *_), tok, counts = chunk(
+                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), Cache(pool),
                 table[start // page: start // page + 2], table[None], key)
             n_real = len(seg)
             assert counts.shape == (L, 4 + 1) and int(counts.sum()) <= n_real * K * L
     else:
         buf = np.zeros((1, 24), np.int32)
         buf[0, :19] = ids
-        pool, _, tok, counts = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
-            engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
+        (pool, *_), tok, counts = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+            engine.params, jnp.asarray(buf), jnp.int32(19), Cache(pool), table[:6], key)
         assert counts.shape == (L, 4 + 1)
     assert int(tok[0]) == int(np.argmax(_reference_last_logits(engine, seq, 19)))
     seq.append(int(tok[0]))
@@ -200,8 +201,8 @@ def test_step_programs_logits_are_the_references(engine, mcfg, prompts, program,
     key = jnp.zeros((2,), jnp.uint32)
     buf = np.zeros((1, 16), np.int32)
     buf[0, :13] = ids[:13]
-    pool, _, _, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
-        engine.params, jnp.asarray(buf), jnp.int32(13), pool, None, table[:4], key)
+    cache, _, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+        engine.params, jnp.asarray(buf), jnp.int32(13), Cache(pool), table[:4], key)
     caught = []
     fam_cls = type(mcfg.serving_family())
     plain = fam_cls.logits
@@ -211,11 +212,11 @@ def test_step_programs_logits_are_the_references(engine, mcfg, prompts, program,
     keys = jnp.zeros((2, 2), jnp.uint32)
     want = lambda n: _reference_last_logits(engine, list(ids), n)
     if program == "decode":
-        out = smodel.paged_decode_step(mcfg, engine.params, jnp.asarray([ids[13], 0]), seq_lens, pool, None, tables, keys)
+        out = smodel.paged_decode_step(mcfg, engine.params, jnp.asarray([ids[13], 0]), seq_lens, cache, tables, keys)
         np.testing.assert_allclose(np.asarray(caught[0])[0], want(14), atol=5e-5, rtol=1e-4)
     elif program == "verify":
         toks = jnp.asarray([ids[13:16], [0, 0, 0]], jnp.int32)
-        out = smodel.paged_verify_step(mcfg, engine.params, toks, seq_lens, pool, None, tables)
+        out = smodel.paged_verify_step(mcfg, engine.params, toks, seq_lens, cache, tables)
         for t in range(3):
             np.testing.assert_allclose(np.asarray(caught[0])[0, t], want(14 + t), atol=5e-5, rtol=1e-4)
     else:   # one call: slot 0 decodes token 13, a second request's first chunk of 8 rides
@@ -223,7 +224,7 @@ def test_step_programs_logits_are_the_references(engine, mcfg, prompts, program,
         row2 = jnp.arange(9, 17, dtype=jnp.int32)
         out = smodel.paged_mixed_step(
             mcfg, engine.params, jnp.asarray([ids[13], 0]), seq_lens, jnp.asarray(other)[None], jnp.int32(0),
-            jnp.int32(8), pool, None, tables, row2[:2], row2[None, :8], keys, key)
+            jnp.int32(8), cache, tables, row2[:2], row2[None, :8], keys, key)
         lg = np.asarray(caught[0])      # [1 + B, V]: the chunk's last prompt position, then the slots
         np.testing.assert_allclose(lg[1], want(14), atol=5e-5, rtol=1e-4)
         np.testing.assert_allclose(lg[0], _reference_last_logits(engine, list(other), 8), atol=5e-5, rtol=1e-4)
@@ -236,8 +237,8 @@ def test_step_programs_logits_are_the_references(engine, mcfg, prompts, program,
 def test_the_cache_is_one_latent_pool_of_two_layers_a_double_layer(engine, served):
     srv, _ = served
     ds = srv.decode_set
-    assert ds.k_pool.shape == (2 * 2, 64, 1, 4, 16 + 8) and ds.v_pool is None and ds.kv_pools == 1
-    assert len(ds.pool_args()) == 1
+    assert ds.cache.k.shape == (2 * 2, 64, 1, 4, 16 + 8) and ds.cache.latent and ds.kv_pools == 1
+    assert len(jax.tree.leaves(ds.cache)) == 1
     g = srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",))
     row_bytes = (16 + 8) * 4
     assert g.value(**{"class": "latent"}) == 64 * 4 * 4 * row_bytes == srv.stats()["kv_pool_bytes"]

@@ -18,6 +18,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import mistral4 as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import spans
 from perfbench import reference_mistral4 as reference
 
@@ -142,14 +143,14 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
             buf = np.zeros((1, 8), np.int32)
             seg = ids[start:start + 8]
             buf[0, : len(seg)] = seg
-            pool, _, tok, _ = chunk(
-                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
+            (pool, *_), tok, _ = chunk(
+                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), Cache(pool),
                 table[start // page: start // page + 2], table[None], key)
     else:
         buf = np.zeros((1, 24), np.int32)
         buf[0, :19] = ids
-        pool, _, tok, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
-            engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
+        (pool, *_), tok, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+            engine.params, jnp.asarray(buf), jnp.int32(19), Cache(pool), table[:6], key)
     assert int(tok[0]) == int(np.argmax(last_logits(19)))
     seq.append(int(tok[0]))
 
@@ -177,8 +178,8 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
 def test_the_cache_is_one_pool_of_one_latent_row_a_token(engine, served):
     srv, _ = served
     ds = srv.decode_set
-    assert ds.k_pool.shape == (2, 64, 1, 4, 16 + 8) and ds.v_pool is None and ds.kv_pools == 1
-    assert len(ds.pool_args()) == 1
+    assert ds.cache.k.shape == (2, 64, 1, 4, 16 + 8) and ds.cache.latent and ds.kv_pools == 1
+    assert len(jax.tree.leaves(ds.cache)) == 1
     g = srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",))
     row_bytes = (16 + 8) * 4
     assert g.value(**{"class": "latent"}) == 64 * 4 * 2 * row_bytes == srv.stats()["kv_pool_bytes"]

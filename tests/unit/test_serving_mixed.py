@@ -21,6 +21,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.models import exaone_moe, gpt2, mistral4
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import spans
 
 from .test_serving_exaone import CFG as KX_CFG
@@ -97,8 +98,8 @@ def _steps(recs):
 # -- the program ----------------------------------------------------------------
 
 def _pools(fam, rng, P, page, B, ring, int8):
-    """Pools whose every page holds something (a cached context is any values
-    at all): (k_pool, v_pool | None, scales | None, win | None)."""
+    """A cache whose every page holds something (a cached context is any
+    values at all): K, V | None, scales | None, the rings | None."""
     n_paged = sum(1 for w in fam.windows if not w)
 
     def pool(layers, pages):
@@ -111,8 +112,8 @@ def _pools(fam, rng, P, page, B, ring, int8):
     v = pool(n_paged, P) if fam.kv_pools == 2 else None
     scales = jnp.asarray(rng.uniform(0.002, 0.004, (n_paged, P, fam.n_kv_head, 2)), jnp.float32) if int8 else None
     n_win = len(fam.windows) - n_paged
-    win = (pool(n_win, 1 + B * ring), pool(n_win, 1 + B * ring)) if n_win else None
-    return k, v, scales, win
+    win = (pool(n_win, 1 + B * ring), pool(n_win, 1 + B * ring)) if n_win else (None, None)
+    return Cache(k, v, scales, *win)
 
 
 def _program(fn, cfg):
@@ -131,7 +132,7 @@ def _one_call(engines, family, int8):
     rng = np.random.default_rng(5)
     B, page, P, W, C = 4, 4, 40, 13, 8
     ring = -(-(max(fam.windows) + C) // page) + 1 if any(fam.windows) else 0
-    k, v, scales, win = _pools(fam, rng, P, page, B, ring, int8)
+    cache = _pools(fam, rng, P, page, B, ring, int8)
     row = np.zeros((1, W), np.int32)
     row[0, :5] = [21, 22, 23, 24, 25]
     bt = np.zeros((B, W), np.int32)
@@ -142,45 +143,40 @@ def _one_call(engines, family, int8):
     ids = rng.integers(0, vocab, (1, C)).astype(np.int32)
     start, plen, slot = np.int32(8), np.int32(13), np.int32(2)
     page_ids, key0 = np.asarray([23, 24], np.int32), np.asarray([0, 3], np.uint32)
-    kw = dict(scales=scales, win=win, ring=ring, slot=slot)
-    n_pools = 2 + int8 + 2 * (win is not None)            # _result's order: k, v, scales, rings, token, counts
-    chunk = (ids, start, plen, k, v)
-    return cfg, params, fam, (tokens, seq_lens), chunk, bt, (page_ids, row), (keys, key0), kw, n_pools
+    kw = dict(ring=ring, slot=slot)
+    chunk = (ids, start, plen, cache)
+    return cfg, params, fam, (tokens, seq_lens), chunk, bt, (page_ids, row), (keys, key0), kw
 
 
 @pytest.mark.parametrize("family,int8", [("gpt2", False), ("gpt2", True), ("exaone_moe", False), ("mistral4", False)])
 def test_one_mixed_call_is_the_chunk_call_then_the_decode_step(engines, family, int8):
     """The same pools, rows and keys: tokens equal, every real page equal
     (the scratch page takes idle rows' and padding's writes in any order)."""
-    cfg, params, fam, (tokens, seq_lens), (ids, start, plen, k, v), bt, (page_ids, row), (keys, key0), kw, n_pools = \
+    cfg, params, fam, (tokens, seq_lens), (ids, start, plen, cache), bt, (page_ids, row), (keys, key0), kw = \
         _one_call(engines, family, int8)
-    B, ring, win, slot = len(tokens), kw["ring"], kw["win"], kw.pop("slot")
-    after_chunk = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, k, v, page_ids, row, key0, slot=slot, **kw)
-    k1, v1 = after_chunk[:2]
-    s1 = after_chunk[2] if int8 else None
-    w1 = tuple(after_chunk[n_pools - 2:n_pools]) if win is not None else None
-    tok_c = after_chunk[n_pools]
-    after_step = _program(smodel.paged_decode_step, cfg)(params, tokens, seq_lens, k1, v1, bt, keys, scales=s1, win=w1, ring=ring)
-    mixed = _program(smodel.paged_mixed_step, cfg)(params, tokens, seq_lens, ids, start, plen, k, v, bt, page_ids, row, keys,
+    B, ring, slot = len(tokens), kw["ring"], kw.pop("slot")
+    after_chunk = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, cache, page_ids, row, key0, slot=slot, **kw)
+    c1, tok_c = after_chunk[:2]
+    after_step = _program(smodel.paged_decode_step, cfg)(params, tokens, seq_lens, c1, bt, keys, ring=ring)
+    mixed = _program(smodel.paged_mixed_step, cfg)(params, tokens, seq_lens, ids, start, plen, cache, bt, page_ids, row, keys,
                                                    key0, slot=slot, **kw)
 
-    toks = np.asarray(mixed[n_pools])
+    toks = np.asarray(mixed[1])
     assert toks.shape == (B + 1,)
-    np.testing.assert_array_equal(toks[[0, 3]], np.asarray(after_step[n_pools])[[0, 3]])
+    np.testing.assert_array_equal(toks[[0, 3]], np.asarray(after_step[1])[[0, 3]])
     np.testing.assert_array_equal(toks[-1:], np.asarray(tok_c))
-    for i in range(n_pools):
-        if mixed[i] is None:                               # a latent family has no V pool
-            assert after_step[i] is None
-            continue
-        got, want = np.asarray(mixed[i], np.float32), np.asarray(after_step[i], np.float32)
-        np.testing.assert_allclose(got[:, 1:], want[:, 1:], atol=1 if int8 and i < 2 else 1e-5)   # a code may round apart
-    if win is not None:
+    assert jax.tree.structure(mixed[0]) == jax.tree.structure(after_step[0]) == jax.tree.structure(cache)   # a latent family has no V pool
+    for f, got, want in zip(Cache._fields, mixed[0], after_step[0]):
+        if got is not None:
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            np.testing.assert_allclose(got[:, 1:], want[:, 1:], atol=1 if int8 and f in "kv" else 1e-5)   # a code may round apart
+    if cache.win_k is not None:
         # the prefilling slot's own decode row is idle: its ring holds the chunk's pages and nothing of that row
         mine = 1 + 2 * ring + np.arange(ring)
-        for got, chunk_only in zip(mixed[n_pools - 2:n_pools], w1):
+        for got, chunk_only in zip(mixed[0][3:5], c1[3:5]):
             np.testing.assert_allclose(np.asarray(got)[:, mine], np.asarray(chunk_only)[:, mine], atol=1e-5)
     if fam.sparse_layers:
-        counts, c_chunk, c_step = (np.asarray(x[n_pools + 1]) for x in (mixed, after_chunk, after_step))
+        counts, c_chunk, c_step = (np.asarray(x[2]) for x in (mixed, after_chunk, after_step))
         assert counts.shape == (len(fam.sparse_layers), fam.experts_held)
         np.testing.assert_array_equal(counts, c_chunk + c_step)    # ONE count of the call's real tokens
         assert counts.sum() <= (5 + 2) * fam.experts_per_token * len(fam.sparse_layers)
@@ -192,26 +188,25 @@ def test_a_call_with_no_real_decode_row_is_the_chunk_call_and_may_skip_the_rows_
     page are the call's with no decode row at all, whether the idle rows'
     attention is skipped (as it is where the slots are many) or read; and
     with a real row the skip's conditional changes nothing."""
-    cfg, params, fam, rows, (ids, start, plen, k, v), bt, (page_ids, row), (keys, key0), kw, n_pools = \
+    cfg, params, fam, rows, (ids, start, plen, cache), bt, (page_ids, row), (keys, key0), kw = \
         _one_call(engines, family, False)
 
     def call(rows, bt, skip):
         monkeypatch.setattr(smodel, "SKIP_IDLE_READS_FROM_SLOTS", 1 if skip else 1 << 30)
-        return _program(smodel.paged_mixed_step, cfg)(params, *rows, ids, start, plen, k, v, bt, page_ids, row, keys, key0, **kw)
+        return _program(smodel.paged_mixed_step, cfg)(params, *rows, ids, start, plen, cache, bt, page_ids, row, keys, key0, **kw)
 
     def same(a, b, toks):
-        np.testing.assert_array_equal(np.asarray(a[n_pools])[toks], np.asarray(b[n_pools])[toks])
-        for x, y in zip(a[:n_pools], b[:n_pools]):
-            if x is not None:
-                np.testing.assert_allclose(np.asarray(x)[:, 1:], np.asarray(y)[:, 1:], atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(a[1])[toks], np.asarray(b[1])[toks])
+        for x, y in zip(jax.tree.leaves(a[0]), jax.tree.leaves(b[0])):
+            np.testing.assert_allclose(np.asarray(x)[:, 1:], np.asarray(y)[:, 1:], atol=1e-5)
 
-    alone = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, k, v, page_ids, row, key0, **kw)
+    alone = _program(smodel.paged_chunk_prefill, cfg)(params, ids, start, plen, cache, page_ids, row, key0, **kw)
     idle = (np.zeros_like(rows[0]), np.zeros_like(rows[1]))
     for skip in (True, False):
         got = call(idle, np.zeros_like(bt), skip)
-        same((*got[:n_pools], got[n_pools][-1:]), alone, slice(None))       # tokens [slots + 1]: the chunk's is last
+        same((got[0], got[1][-1:]), alone, slice(None))       # tokens [slots + 1]: the chunk's is last
         if fam.sparse_layers:                                   # idle rows are no tokens of the call
-            np.testing.assert_array_equal(np.asarray(got[n_pools + 1]), np.asarray(alone[n_pools + 1]))
+            np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(alone[2]))
     same(call(rows, bt, True), call(rows, bt, False), [0, 3, 4])
 
 

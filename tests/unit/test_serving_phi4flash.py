@@ -17,6 +17,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import phi4flash as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import spans
 from perfbench import reference_phi4flash as reference
 
@@ -72,7 +73,7 @@ def _gaps(params, prompt, tokens):
 
 def test_served_streams_are_the_references_across_chunks_ring_wraps_and_slot_reuse(engine, served, prompts):
     srv, reqs = served
-    assert srv.ring_pages == 5 and 5 * 4 < max(PROMPTS) and srv.recurrent and srv.windowed and not srv.latent
+    assert srv.ring_pages == 5 and 5 * 4 < max(PROMPTS) and srv.decode_set.cache.rec is not None and srv.decode_set.cache.win_k is not None and not srv.decode_set.cache.latent
     for r, p in zip(reqs, prompts):      # 7 requests through 3 slots: every slot is used again
         assert r.status == "finished" and len(r.tokens) == 12
         assert float(_gaps(engine.params, p, r.tokens).max()) <= GAP_TOL, len(p)
@@ -107,14 +108,14 @@ def test_three_kinds_of_state_are_sized_by_what_each_holds(engine, served):
     srv, _ = served
     ds = srv.decode_set
     assert (ds.n_layer, ds.n_window_layer) == (1, 2) and smodel.pool_layers(srv.family) == (1, 2, 3)
-    assert ds.k_pool.shape == (1, 64, 1, 4, 16)                        # ONE paged layer of head PAIRS, every lane real
-    assert ds.window_pools[0].shape == (2, 1 + 3 * 5, 1, 4, 16)
-    ssm, conv = ds.state_pools
+    assert ds.cache.k.shape == (1, 64, 1, 4, 16)                        # ONE paged layer of head PAIRS, every lane real
+    assert ds.cache.win_k.shape == (2, 1 + 3 * 5, 1, 4, 16)
+    ssm, conv, by = ds.cache.rec, ds.cache.conv, ds.cache_bytes()
     assert ssm.shape == (3, 3, 16, 64) and ssm.dtype == jnp.float32 and conv.shape == (3, 3, 3, 64)
-    assert len(ds.pool_args()) == 6 and ds.state_pool_bytes() == 3 * 3 * (16 * 64 * 4 + 3 * 64 * 4)
+    assert len(jax.tree.leaves(ds.cache)) == 6 and by["state"] == 3 * 3 * (16 * 64 * 4 + 3 * 64 * 4) and by["lin_state"] == 0
     g = srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",))
-    assert g.value(**{"class": "state"}) == ds.state_pool_bytes()
-    assert g.value(**{"class": "paged"}) == 2 * 64 * 4 * 16 * 4 and g.value(**{"class": "window"}) == ds.window_pool_bytes()
+    assert g.value(**{"class": "state"}) == by["state"]
+    assert g.value(**{"class": "paged"}) == 2 * 64 * 4 * 16 * 4 and g.value(**{"class": "window"}) == by["window"]
     phase = [p for p in spans.phases() if p[0] == "ds.init.programs"][-1]
     assert "state=" in phase[3]["kv_bytes"] and "paged=" in phase[3]["kv_bytes"] and "window=" in phase[3]["kv_bytes"]
     assert smodel._kv_homes(srv.family) == [(False, 0), (True, 0), (False, 1), (True, 1), (False, 2), (False, 0),
@@ -122,7 +123,7 @@ def test_three_kinds_of_state_are_sized_by_what_each_holds(engine, served):
 
 
 def _pools(fam, slots, ring, dirty=None):
-    """The hand-driven programs' pools; ``dirty``: the recurrent state filled
+    """The hand-driven programs' cache; ``dirty``: the recurrent state filled
     with another request's leavings."""
     n_paged, n_win, n_ssm = smodel.pool_layers(fam)
     KV, D = fam.n_kv_head, fam.head_dim
@@ -134,7 +135,7 @@ def _pools(fam, slots, ring, dirty=None):
         state = tuple(jnp.zeros(s, jnp.float32) for s in shapes)
     else:
         state = tuple(jnp.asarray(np.random.default_rng(dirty).normal(size=s), jnp.float32) for s in shapes)
-    return kv, kv, (win, win), state
+    return Cache(kv, kv, None, win, win, *state)
 
 
 @functools.lru_cache(maxsize=None)
@@ -151,11 +152,11 @@ def _program(name, cfg, ring):
 
 def _prefill(cfg, params, prompt, slot, chunk, pages, dirty=None, slots=3):
     """A prompt into ``slot``'s state in chunks of ``chunk`` tokens (0: the
-    whole-prompt program) → (logits of the sampled row, the pools, the ring)."""
+    whole-prompt program) → (logits of the sampled row, the cache, the ring)."""
     fam = cfg.serving_family()
     n = len(prompt)
     ring = -(-(8 + (chunk or 1)) // PAGE) + 1
-    k, v, win, state = _pools(fam, slots, ring, dirty)
+    cache = _pools(fam, slots, ring, dirty)
     row = np.zeros((1, W), np.int32)
     row[0, : len(pages)] = pages
     key = jnp.zeros((2,), jnp.uint32)
@@ -163,10 +164,10 @@ def _prefill(cfg, params, prompt, slot, chunk, pages, dirty=None, slots=3):
         Sp = -(-n // PAGE) * PAGE
         ids = np.zeros((1, Sp), np.int32)
         ids[0, :n] = prompt
-        k, v, kw, vw, ssm, conv, lg = _program("paged_prefill", cfg, ring)(
-            params, jnp.asarray(ids), jnp.int32(n), k, v, jnp.asarray(row[0, : Sp // PAGE]), key,
-            win=win, slot=jnp.int32(slot), state=state)
-        return lg, (k, v, (kw, vw), (ssm, conv)), ring
+        cache, lg = _program("paged_prefill", cfg, ring)(
+            params, jnp.asarray(ids), jnp.int32(n), cache, jnp.asarray(row[0, : Sp // PAGE]), key,
+            slot=jnp.int32(slot))
+        return lg, cache, ring
     for start in range(0, n, chunk):
         ids = np.zeros((1, chunk), np.int32)
         seg = prompt[start: start + chunk]
@@ -175,11 +176,10 @@ def _prefill(cfg, params, prompt, slot, chunk, pages, dirty=None, slots=3):
         page_ids = np.zeros((chunk // PAGE,), np.int32)
         avail = row[0, p0: p0 + chunk // PAGE]
         page_ids[: len(avail)] = avail
-        k, v, kw, vw, ssm, conv, lg = _program("paged_chunk_prefill", cfg, ring)(
-            params, jnp.asarray(ids), jnp.int32(start), jnp.int32(n), k, v, jnp.asarray(page_ids),
-            jnp.asarray(row), key, win=win, slot=jnp.int32(slot), state=state)
-        win, state = (kw, vw), (ssm, conv)
-    return lg, (k, v, win, state), ring
+        cache, lg = _program("paged_chunk_prefill", cfg, ring)(
+            params, jnp.asarray(ids), jnp.int32(start), jnp.int32(n), cache, jnp.asarray(page_ids),
+            jnp.asarray(row), key, slot=jnp.int32(slot))
+    return lg, cache, ring
 
 
 def _logits(lg, *a, **kw):
@@ -202,19 +202,20 @@ def test_a_prompt_in_1_2_and_5_chunks_leaves_the_same_state_pages_and_first_logi
     prompt = np.random.default_rng(1).integers(0, 96, 19).astype(np.int32)
     pages = [7, 3, 9, 12, 5]
     assert calls == (1 if not chunk else -(-19 // chunk))
-    want_lg, (wk, wv, _, (wssm, wconv)), _ = _prefill(mcfg, engine.params, prompt, 1, 0, pages)
+    want_lg, w, _ = _prefill(mcfg, engine.params, prompt, 1, 0, pages)
     want = np.asarray(reference.logits(engine.params, jnp.asarray(np.pad(prompt, (0, 13))), reference.Arch.from_config(CFG)))[18]
     flat = lambda pool: np.asarray(pool[0, np.asarray(pages)]).transpose(0, 2, 1, 3).reshape(-1, 16)[:19]  # noqa: E731
     for dirty in (None, 7):
-        lg, (k, v, _, (ssm, conv)), _ = _prefill(mcfg, engine.params, prompt, 1, chunk, pages, dirty)
+        lg, c, _ = _prefill(mcfg, engine.params, prompt, 1, chunk, pages, dirty)
+        ssm, conv = c.rec, c.conv
         np.testing.assert_allclose(lg, want_lg, atol=2e-5)
-        np.testing.assert_allclose(ssm[:, 1], wssm[:, 1], rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(conv[:, 1], wconv[:, 1], rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(flat(k), flat(wk), atol=1e-5)
-        np.testing.assert_allclose(flat(v), flat(wv), atol=1e-5)
+        np.testing.assert_allclose(ssm[:, 1], w.rec[:, 1], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(conv[:, 1], w.conv[:, 1], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(flat(c.k), flat(w.k), atol=1e-5)
+        np.testing.assert_allclose(flat(c.v), flat(w.v), atol=1e-5)
         np.testing.assert_allclose(np.asarray(lg)[0], want, atol=2e-4)
     # and the other slots' leavings are where they were
-    _, _, _, (d_ssm, d_conv) = _pools(mcfg.serving_family(), 3, 5, 7)
+    d_ssm, d_conv = _pools(mcfg.serving_family(), 3, 5, 7)[5:7]
     for other in (0, 2):
         assert np.array_equal(ssm[:, other], d_ssm[:, other]) and np.array_equal(conv[:, other], d_conv[:, other])
 
@@ -231,17 +232,17 @@ def _decode_operands(slot_pages, lens, tokens):
 def test_idle_slots_and_padding_rows_leave_the_recurrent_state_bitwise_unchanged(mcfg, engine, logits_out):
     prompt = np.random.default_rng(2).integers(0, 96, 11).astype(np.int32)
     pages = [7, 3, 9, 12]
-    _, (k, v, win, state), ring = _prefill(mcfg, engine.params, prompt, 1, 8, pages, dirty=5)
-    before = tuple(np.asarray(s) for s in state)
+    _, cache, ring = _prefill(mcfg, engine.params, prompt, 1, 8, pages, dirty=5)
+    before = tuple(np.asarray(s) for s in (cache.rec, cache.conv))
     tok, lens, bt, keys = _decode_operands({1: pages}, [0, 11, 0], [0, 17, 0])      # slots 0 and 2 idle
-    out = _program("paged_decode_step", mcfg, ring)(engine.params, tok, lens, k, v, bt, keys, win=win, state=state)
-    ssm, conv = out[4], out[5]
+    out = _program("paged_decode_step", mcfg, ring)(engine.params, tok, lens, cache, bt, keys)
+    ssm, conv = out[0].rec, out[0].conv
     for idle in (0, 2):
         assert np.array_equal(ssm[:, idle], before[0][:, idle]) and np.array_equal(conv[:, idle], before[1][:, idle])
     assert not np.array_equal(ssm[:, 1], before[0][:, 1]) and np.array_equal(np.asarray(conv[:, 1, :2]), before[1][:, 1, 1:])
     # a chunk of 8 with 3 real rows: the 5 rows of padding behind them move nothing
-    _, (_, _, _, (s11, c11)), _ = _prefill(mcfg, engine.params, prompt, 1, 8, pages)
-    _, (_, _, _, (s_whole, c_whole)), _ = _prefill(mcfg, engine.params, prompt, 1, 0, pages)
+    s11, c11 = _prefill(mcfg, engine.params, prompt, 1, 8, pages)[1][5:7]
+    s_whole, c_whole = _prefill(mcfg, engine.params, prompt, 1, 0, pages)[1][5:7]
     np.testing.assert_allclose(s11[:, 1], s_whole[:, 1], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(c11[:, 1], c_whole[:, 1], rtol=1e-5, atol=1e-5)
 
@@ -249,25 +250,27 @@ def test_idle_slots_and_padding_rows_leave_the_recurrent_state_bitwise_unchanged
 def test_a_chunk_in_the_mixed_step_leaves_the_other_slots_decode_rows_as_the_decode_step_has_them(mcfg, engine, logits_out):
     prompt = np.random.default_rng(3).integers(0, 96, 14).astype(np.int32)
     pages = [7, 3, 9, 12, 5]
-    _, (k, v, win, state), ring = _prefill(mcfg, engine.params, prompt, 1, 8, pages, dirty=6)
+    _, cache, ring = _prefill(mcfg, engine.params, prompt, 1, 8, pages, dirty=6)
+    state = (cache.rec, cache.conv)
     tok, lens, bt, keys = _decode_operands({1: pages}, [0, 14, 0], [0, 23, 0])
-    want = _program("paged_decode_step", mcfg, ring)(engine.params, tok, lens, k, v, bt, keys, win=win, state=state)
+    want = _program("paged_decode_step", mcfg, ring)(engine.params, tok, lens, cache, bt, keys)
     # the same step carrying the first chunk of another prompt into slot 2
     other = np.random.default_rng(4).integers(0, 96, 8).astype(np.int32)
     row = np.zeros((1, W), np.int32)
     row[0, :3] = [20, 21, 22]
     got = _program("paged_mixed_step", mcfg, ring)(
-        engine.params, tok, lens, jnp.asarray(other[None]), jnp.int32(0), jnp.int32(13), k, v, bt,
+        engine.params, tok, lens, jnp.asarray(other[None]), jnp.int32(0), jnp.int32(13), cache, bt,
         jnp.asarray(row[0, :2]), jnp.asarray(row), keys, jnp.zeros((2,), jnp.uint32),
-        win=win, slot=jnp.int32(2), state=state)
-    np.testing.assert_allclose(got[6][1], want[6][1], atol=2e-5)                 # slot 1's logits
-    np.testing.assert_allclose(got[4][:, 1], want[4][:, 1], rtol=1e-5, atol=1e-5)            # its scan state
-    np.testing.assert_allclose(got[5][:, 1], want[5][:, 1], rtol=1e-5, atol=1e-5)
-    assert np.array_equal(got[4][:, 0], state[0][:, 0]) and np.array_equal(got[5][:, 0], state[1][:, 0])   # idle slot 0
-    assert not np.array_equal(got[4][:, 2], state[0][:, 2])                       # the chunk's slot took its rows
-    _, (_, _, _, (s2, c2)), _ = _prefill(mcfg, engine.params, other, 2, 8, [20, 21, 22])
-    np.testing.assert_allclose(got[4][:, 2], s2[:, 2], rtol=1e-5, atol=1e-5)                 # ... from zeros, not from its leavings
-    np.testing.assert_allclose(got[5][:, 2], c2[:, 2], rtol=1e-5, atol=1e-5)
+        slot=jnp.int32(2))
+    np.testing.assert_allclose(got[1][1], want[1][1], atol=2e-5)                 # slot 1's logits
+    got, want = got[0], want[0]          # the caches
+    np.testing.assert_allclose(got.rec[:, 1], want.rec[:, 1], rtol=1e-5, atol=1e-5)            # its scan state
+    np.testing.assert_allclose(got.conv[:, 1], want.conv[:, 1], rtol=1e-5, atol=1e-5)
+    assert np.array_equal(got.rec[:, 0], state[0][:, 0]) and np.array_equal(got.conv[:, 0], state[1][:, 0])   # idle slot 0
+    assert not np.array_equal(got.rec[:, 2], state[0][:, 2])                       # the chunk's slot took its rows
+    s2, c2 = _prefill(mcfg, engine.params, other, 2, 8, [20, 21, 22])[1][5:7]
+    np.testing.assert_allclose(got.rec[:, 2], s2[:, 2], rtol=1e-5, atol=1e-5)                 # ... from zeros, not from its leavings
+    np.testing.assert_allclose(got.conv[:, 2], c2[:, 2], rtol=1e-5, atol=1e-5)
 
 
 # -- (d) a slot another request just left ----------------------------------------------
@@ -290,11 +293,11 @@ def test_with_and_without_the_stop_the_sampled_rows_logits_agree(engine, logits_
     pages = [7, 3, 9, 12, 5, 6]
     stops, runs_all = (m.Phi4FlashConfig.from_dict(CFG, prefill_stops=s) for s in (True, False))
     assert stops.serving_family().stop_after == 5 and runs_all.serving_family().stop_after is None
-    a, (ka, _, _, (sa, _)), _ = _prefill(stops, engine.params, prompt, 0, chunk, pages)
-    b, (kb, _, _, (sb, _)), _ = _prefill(runs_all, engine.params, prompt, 0, chunk, pages)
+    a, ca, _ = _prefill(stops, engine.params, prompt, 0, chunk, pages)
+    b, cb, _ = _prefill(runs_all, engine.params, prompt, 0, chunk, pages)
     np.testing.assert_allclose(a, b, atol=2e-5)
-    np.testing.assert_allclose(sa, sb, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(ka, kb, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ca.rec, cb.rec, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(ca.k, cb.k, rtol=1e-5, atol=1e-5)
 
 
 def test_the_stop_takes_the_matrix_work_of_the_cross_decoder_off_the_prompt_rows(engine):
@@ -302,12 +305,12 @@ def test_the_stop_takes_the_matrix_work_of_the_cross_decoder_off_the_prompt_rows
     products behind layer 5 run on 1 + B rows, without it on C + B."""
     def flops(stops):
         cfg = m.Phi4FlashConfig.from_dict(CFG, prefill_stops=stops)
-        k, v, win, state = _pools(cfg.serving_family(), 3, 5)
+        cache = _pools(cfg.serving_family(), 3, 5)
         tok, lens, bt, keys = _decode_operands({}, [0, 0, 0], [0, 0, 0])
         fn = lambda p: smodel.paged_mixed_step(  # noqa: E731
-            cfg, p, tok, lens, jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(8), k, v, bt,
+            cfg, p, tok, lens, jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(8), cache, bt,
             jnp.zeros((2,), jnp.int32), jnp.zeros((1, W), jnp.int32), keys, jnp.zeros((2,), jnp.uint32),
-            win=win, slot=jnp.int32(0), ring=5, state=state)
+            slot=jnp.int32(0), ring=5)
         return jax.jit(fn).lower(engine.params).compile().cost_analysis()["flops"]
 
     with_stop, without = flops(True), flops(False)

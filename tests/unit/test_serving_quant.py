@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models import gpt2
-from deepspeed_tpu.serving.kv_cache import init_pools, pool_bytes, scales_bytes
+from deepspeed_tpu.serving.kv_cache import Cache, init_pools, pool_bytes, scales_bytes
 from deepspeed_tpu.serving.request import RequestStatus
 
 warnings.filterwarnings("ignore")
@@ -143,12 +143,12 @@ class TestInt8Parity:
         page_ids = np.arange(1, 1 + Sp // page).astype(np.int32)
         plen = jnp.asarray(Sp, jnp.int32)
         key = jax.random.PRNGKey(0)
-        kq2, vq2, sc2, tok_q = smodel.paged_prefill(
-            cfg, params, jnp.asarray(ids), plen, kq, vq,
-            jnp.asarray(page_ids), key, scales=sc,
+        (kq2, vq2, sc2, *_), tok_q = smodel.paged_prefill(
+            cfg, params, jnp.asarray(ids), plen, Cache(kq, vq, sc),
+            jnp.asarray(page_ids), key,
         )
-        kf2, vf2, tok_f = smodel.paged_prefill(
-            cfg, params, jnp.asarray(ids), plen, kf, vf,
+        (kf2, vf2, *_), tok_f = smodel.paged_prefill(
+            cfg, params, jnp.asarray(ids), plen, Cache(kf, vf),
             jnp.asarray(page_ids), key,
         )
         assert int(tok_q[0]) == int(tok_f[0])

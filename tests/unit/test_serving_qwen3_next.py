@@ -17,6 +17,7 @@ import deepspeed_tpu
 from deepspeed_tpu.models import qwen3_next as m
 from deepspeed_tpu.moe import expert_share as es
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import STATE_KINDS, Cache
 from deepspeed_tpu.telemetry import parts, spans
 from perfbench import reference_qwen3_next as reference
 
@@ -92,21 +93,21 @@ def test_forward_and_served_streams_are_the_references_with_state_pools_parts_an
     ref = jax.jit(jax.vmap(lambda i: reference.logits(engine.params, i, arch)))(ids)
     assert lg.shape == (2, 21, 96) and float(jnp.std(ref)) > 0.5 and float(jnp.abs(lg - ref).max()) <= 2e-4
     srv, reqs = served
-    assert srv.recurrent and not srv.windowed and not srv.latent and not srv.carried
+    assert [k.holds(srv.family) for k in STATE_KINDS] == [True, False, False, False]   # recurrent; no carried rows, rings or latent pool
     for r, p in zip(reqs, prompts):      # 9 requests through 3 slots: every slot is used again, from zeros
         assert r.status == "finished" and len(r.tokens) == 12
         assert float(_gaps(engine.params, p, r.tokens, arch).max()) <= GAP_TOL, len(p)
     # -- the pools, the gauge, the phase
     ds = srv.decode_set
     assert smodel.pool_layers(srv.family) == (2, 0, 6) and ds.n_layer == 2
-    assert ds.k_pool.shape == (2, 64, 2, 4, 16) and ds.window_pools is None
-    lin, conv = ds.state_pools
+    assert ds.cache.k.shape == (2, 64, 2, 4, 16) and ds.cache.win_k is None
+    lin, conv, by = ds.cache.rec, ds.cache.conv, ds.cache_bytes()
     assert lin.shape == (6, 3, 4, 16, 16) and lin.dtype == jnp.float32 and conv.shape == (6, 3, 3, 2 * 32 + 64)
-    assert len(ds.pool_args()) == 4 and ds.lin_state_bytes == 6 * 3 * 4 * 16 * 16 * 4 and ds.carry_pool_bytes == 0
-    assert srv.metrics.gauge("serving_lin_state_bytes", "").value() == ds.lin_state_bytes
-    assert srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",)).value(**{"class": "state"}) == ds.state_pool_bytes()
+    assert len(jax.tree.leaves(ds.cache)) == 4 and by["lin_state"] == 6 * 3 * 4 * 16 * 16 * 4 and by["carry"] == 0
+    assert srv.metrics.gauge("serving_lin_state_bytes", "").value() == by["lin_state"]
+    assert srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",)).value(**{"class": "state"}) == by["state"]
     phase = [p for p in spans.phases() if p[0] == "ds.init.programs"][-1]
-    assert phase[3]["lin_state_bytes"] == ds.lin_state_bytes and "state=" in phase[3]["kv_bytes"]
+    assert phase[3]["lin_state_bytes"] == by["lin_state"] and "state=" in phase[3]["kv_bytes"]
     assert smodel._kv_homes(srv.family) == [(False, 0), (False, 1), (False, 2), (False, 0),
                                             (False, 3), (False, 4), (False, 5), (False, 1)]
     # -- every product of the served programs has a part, and the rule has its own
@@ -197,12 +198,12 @@ def test_the_delta_kernels_interpreted_inside_the_block_give_what_the_lax_forms_
     for impl in ("jnp", "interpret"):
         fam = m.Qwen3NextConfig.from_dict(wide, lin_impl=impl).serving_family()
         rng = np.random.default_rng(7)
-        state = (jnp.asarray(rng.normal(size=(3, 3, *fam.lin_state)), jnp.float32),
-                 jnp.asarray(rng.normal(size=(3, 3, 3, fam.lin_conv[1])), jnp.float32))
+        state = Cache(None, rec=jnp.asarray(rng.normal(size=(3, 3, *fam.lin_state)), jnp.float32),
+                      conv=jnp.asarray(rng.normal(size=(3, 3, 3, fam.lin_conv[1])), jnp.float32))
         h = jnp.asarray(rng.normal(size=(1, 8 + 3, 64)), jnp.float32)
         block = jax.jit(lambda h, state: smodel._lin_block(
             fam, lp, h, state, 1, 8, (jnp.int32(2), jnp.int32(16), jnp.int32(5)), jnp.array([True, False, True])))
         outs.append(block(h, state))
-    (a0, (lin0, conv0)), (a1, (lin1, conv1)) = outs
+    (a0, (*_, lin0, conv0, _)), (a1, (*_, lin1, conv1, _)) = outs
     assert float(jnp.abs(a0 - a1).max()) <= 1e-5 and float(jnp.abs(lin0 - lin1).max()) <= 1e-5
     assert bool((conv0 == conv1).all()) and float(jnp.abs(a0).max()) > 1e-3

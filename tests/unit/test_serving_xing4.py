@@ -18,6 +18,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import xing4 as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import parts, spans
 from perfbench import reference_xing4 as reference
 
@@ -180,14 +181,14 @@ def test_paged_programs_logits_match_the_references_full_forward(engine, mcfg, p
             buf = np.zeros((1, 8), np.int32)
             seg = ids[start:start + 8]
             buf[0, : len(seg)] = seg
-            pool, _, tok, _ = chunk(
-                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), pool, None,
+            (pool, *_), tok, _ = chunk(
+                engine.params, jnp.asarray(buf), jnp.int32(start), jnp.int32(19), Cache(pool),
                 table[start // page: start // page + 2], table[None], key)
     else:
         buf = np.zeros((1, 24), np.int32)
         buf[0, :19] = ids
-        pool, _, tok, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
-            engine.params, jnp.asarray(buf), jnp.int32(19), pool, None, table[:6], key)
+        (pool, *_), tok, _ = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
+            engine.params, jnp.asarray(buf), jnp.int32(19), Cache(pool), table[:6], key)
     assert int(tok[0]) == int(np.argmax(last_logits(19)))
     seq.append(int(tok[0]))
 
@@ -236,7 +237,7 @@ def test_the_kernel_pair_serves_inside_the_programs_interpreted(prompts):
 def test_the_cache_is_one_pool_and_the_gauges_say_what_a_row_of_the_stream_is(engine, served):
     srv, _ = served
     ds = srv.decode_set
-    assert ds.k_pool.shape == (3, 64, 1, 4, 16 + 8) and ds.v_pool is None and ds.kv_pools == 1
+    assert ds.cache.k.shape == (3, 64, 1, 4, 16 + 8) and ds.cache.latent and ds.kv_pools == 1
     assert srv.metrics.gauge("serving_hc_row_bytes", "").value() == 4 * 64 * 4
     assert srv.metrics.gauge("serving_moe_experts_held", "").value() == 4
     prog = [r[3] for r in spans.phases() if r[0] == "ds.init.programs" and "hc_row_bytes" in r[3]][-1]
@@ -256,7 +257,7 @@ def test_spans_count_the_expert_layers_alone_and_the_mixing_has_a_part(engine, m
     assert all(a["moe_experts_streamed"] == 4 * 2 >= a["moe_experts_hit"] for a in emits)
     # the decode program's lowered text: the mixing's operations are under the part hc.mix
     text = jax.jit(functools.partial(smodel.paged_decode_step, mcfg)).lower(
-        engine.params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32), jnp.zeros((3, 64, 1, 4, 24), jnp.float32), None,
+        engine.params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32), Cache(jnp.zeros((3, 64, 1, 4, 24), jnp.float32)),
         jnp.zeros((3, 13), jnp.int32), jnp.zeros((3, 2), jnp.uint32)).as_text(debug_info=True)
     assert parts.PREFIX + "hc.mix" in text
     # ONE traced and lowered function each for the program's six sub-blocks' two calls
@@ -274,7 +275,7 @@ def test_another_latent_familys_decode_program_lowers_as_it_did_before_this_fami
     cfg = mistral4.Mistral4Config.from_dict(MS4)
     params = jax.eval_shape(lambda: mistral4.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
     text = jax.jit(functools.partial(smodel.paged_decode_step, cfg)).lower(
-        params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32), jnp.zeros((2, 64, 1, 4, 24), jnp.float32), None,
+        params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32), Cache(jnp.zeros((2, 64, 1, 4, 24), jnp.float32)),
         jnp.zeros((3, 13), jnp.int32), jnp.zeros((3, 2), jnp.uint32)).as_text(debug_info=True)
     assert parts.PREFIX + "hc.mix" not in text and "@_pre(" not in text and "@_post(" not in text
 

@@ -12,6 +12,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models import zaya as m
 from deepspeed_tpu.serving import model as smodel
+from deepspeed_tpu.serving.kv_cache import Cache
 from deepspeed_tpu.telemetry import spans
 from perfbench import reference_zaya as reference
 
@@ -84,12 +85,13 @@ def test_every_served_position_is_the_references_full_forward_and_the_mix_ran_ev
     # the carried rows are a pool of their own, and the gauge and the phase say so
     ds, fam = srv.decode_set, mcfg.serving_family()
     assert fam.carry_width == 2 * 96 + 16
-    assert len(ds.state_pools) == 1 and ds.state_pools[0].shape == (2, 3, fam.carry_width)
-    assert ds.carry_pool_bytes == 2 * 3 * fam.carry_width * 4 and ds.state_pool_bytes() == 0
-    assert srv.metrics.gauge("serving_attn_carry_bytes", "").value() == ds.carry_pool_bytes
-    assert srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",)).value(**{"class": "carry"}) == ds.carry_pool_bytes
+    carry_bytes = ds.cache_bytes()["carry"]
+    assert ds.cache.rec is None and ds.cache.carry.shape == (2, 3, fam.carry_width)
+    assert carry_bytes == 2 * 3 * fam.carry_width * 4 and ds.cache_bytes()["state"] == 0
+    assert srv.metrics.gauge("serving_attn_carry_bytes", "").value() == carry_bytes
+    assert srv.metrics.gauge("serving_kv_bytes", "", labelnames=("class",)).value(**{"class": "carry"}) == carry_bytes
     attrs = [p[3] for p in spans.phases() if p[0] == "ds.init.programs" and p[3].get("what") == "serving"][-1]
-    assert attrs["carry_rows"] == ds.carry_pool_bytes and f"carry={ds.carry_pool_bytes}" in attrs["kv_bytes"]
+    assert attrs["carry_rows"] == carry_bytes and f"carry={carry_bytes}" in attrs["kv_bytes"]
     assert srv.metrics.gauge("serving_moe_experts_held", "").value() == 4
     # the expert layers report their loads: one pick a token, 2 layers, all held; the masked form off the TPU
     emits = [r[3] for r in recs if r[0] == "ds.serve.emit"]
@@ -114,8 +116,8 @@ def test_idle_slots_keep_their_rows_bitwise_and_a_request_starts_from_zeros_what
 
     fam = mcfg.serving_family()
     page, W, slots = 4, 10, 3
-    pools = lambda: (jnp.zeros((2, 32, 2, page, 16), jnp.float32),) * 2  # noqa: E731
-    dirty = (jnp.full((2, slots, fam.carry_width), 0.37, jnp.float32),)
+    pools = lambda carry: Cache(*(jnp.zeros((2, 32, 2, page, 16), jnp.float32),) * 2, carry=carry)  # noqa: E731
+    dirty = jnp.full((2, slots, fam.carry_width), 0.37, jnp.float32)
     prompt = np.random.default_rng(7).integers(0, CFG["vocab_size"], 11).astype(np.int32)
     pages = [7, 3, 9, 12]
     row = np.zeros((1, W), np.int32)
@@ -123,34 +125,33 @@ def test_idle_slots_keep_their_rows_bitwise_and_a_request_starts_from_zeros_what
     chunk = jax.jit(functools.partial(smodel.paged_chunk_prefill, mcfg))
     key = jnp.zeros((2,), jnp.uint32)
 
-    def prefill(state):
-        k, v = pools()
+    def prefill(carry):
+        cache = pools(carry)
         for start in (0, 8):
             ids = np.zeros((1, 8), np.int32)
             seg = prompt[start: start + 8]
             ids[0, : len(seg)] = seg
-            k, v, rows, _, _ = chunk(params, jnp.asarray(ids), jnp.int32(start), jnp.int32(11), k, v,
-                                     jnp.asarray(row[0, start // page: start // page + 2]), jnp.asarray(row), key,
-                                     slot=jnp.int32(1), state=state)
-            state = (rows,)
-        return k, v, rows
+            cache, _, _ = chunk(params, jnp.asarray(ids), jnp.int32(start), jnp.int32(11), cache,
+                                jnp.asarray(row[0, start // page: start // page + 2]), jnp.asarray(row), key,
+                                slot=jnp.int32(1))
+        return cache
 
-    k, v, rows = prefill(dirty)
-    _, _, clean = prefill((jnp.zeros_like(dirty[0]),))
+    cache = prefill(dirty)
+    rows, clean = cache.carry, prefill(jnp.zeros_like(dirty)).carry
     np.testing.assert_array_equal(np.asarray(rows[:, 1]), np.asarray(clean[:, 1]))           # from zeros, not from 0.37
     assert np.all(np.asarray(rows[:, 0]) == np.float32(0.37)) and np.all(np.asarray(rows[:, 2]) == np.float32(0.37))
     # the whole-prompt program leaves the same rows at the prompt's TRUE length (11 of a 12-wide bucket)
     ids = np.zeros((1, 12), np.int32)
     ids[0, :11] = prompt
     out = jax.jit(functools.partial(smodel.paged_prefill, mcfg))(
-        params, jnp.asarray(ids), jnp.int32(11), *pools(), jnp.asarray(row[0, :3]), key, slot=jnp.int32(1), state=dirty)
-    np.testing.assert_allclose(np.asarray(out[2][:, 1]), np.asarray(rows[:, 1]), atol=1e-6)
+        params, jnp.asarray(ids), jnp.int32(11), pools(dirty), jnp.asarray(row[0, :3]), key, slot=jnp.int32(1))
+    np.testing.assert_allclose(np.asarray(out[0].carry[:, 1]), np.asarray(rows[:, 1]), atol=1e-6)
     # a decode step: slot 1 decodes, slots 0 and 2 are idle
     bt = np.zeros((slots, W), np.int32)
     bt[1, : len(pages)] = pages
     got = jax.jit(functools.partial(smodel.paged_decode_step, mcfg))(
-        params, jnp.asarray([0, 17, 0], jnp.int32), jnp.asarray([0, 11, 0], jnp.int32), k, v, jnp.asarray(bt),
-        jnp.zeros((slots, 2), jnp.uint32), state=(rows,))[2]
+        params, jnp.asarray([0, 17, 0], jnp.int32), jnp.asarray([0, 11, 0], jnp.int32), cache, jnp.asarray(bt),
+        jnp.zeros((slots, 2), jnp.uint32))[0].carry
     assert np.array_equal(np.asarray(got[:, 0]), np.asarray(rows[:, 0])) and np.array_equal(np.asarray(got[:, 2]), np.asarray(rows[:, 2]))
     C = mcfg.latent
     np.testing.assert_array_equal(np.asarray(got[:, 1, C: 2 * C]), np.asarray(rows[:, 1, :C]))     # z_{t-1} moved to z_{t-2}'s place
@@ -187,4 +188,4 @@ def test_mechanisms_that_do_not_know_the_carried_rows_are_refused_by_name(mcfg, 
 def test_the_verify_step_refuses_a_family_that_carries_rows(mcfg):
     with pytest.raises(NotImplementedError, match="carry no rows"):
         smodel.paged_verify_step(mcfg, None, jnp.zeros((3, 2), jnp.int32), jnp.zeros((3,), jnp.int32),
-                                 None, None, jnp.zeros((3, 4), jnp.int32))
+                                 None, jnp.zeros((3, 4), jnp.int32))
