@@ -56,9 +56,10 @@ FULL_SIZES = {
         "num_pages": 512,
         "max_prompt_len": 512,
         "max_new_tokens": 64,
-        # prompts longer than this prefill through the chunk program, whose
-        # attention is the multitoken paged kernel (the whole-prompt program
-        # attends densely in jnp and holds no kernel)
+        # every prompt prefills through the chunk program, whose attention
+        # is the multitoken paged kernel: one no longer than this in ONE
+        # call, a longer one in several (a server that chunks builds no
+        # whole-prompt program)
         "prefill_chunk_tokens": 128,
     },
     "requests": 12,
@@ -313,8 +314,8 @@ def serve_phase(sizes, on_tpu, counters):
         len(srv.executables), srv.expected_executables
     )
     if on_tpu:
-        # decode and the chunk program that prefills long prompts must hold
-        # the paged kernels; the whole-prompt program has none to hold
+        # the whole program set of a server that chunks: decode and the
+        # chunk program that prefills every prompt must hold the paged kernels
         for name in ("serving_decode", "serving_chunk_prefill"):
             assert "tpu_custom_call" in programs[name].as_text(), (
                 f"{name} compiled without the Mosaic paged kernel"
@@ -332,7 +333,7 @@ def serve_phase(sizes, on_tpu, counters):
             assert len(r.tokens) == n_new, (len(r.tokens), n_new)
             assert all(0 <= t < cfg.vocab_size for t in r.tokens)
 
-    # warm-up: one request per prefill program (whole-prompt, chunked)
+    # warm-up: a prompt of one chunk and one of several
     warm = [
         srv.submit(prompt(sizes["min_prompt"]), max_new_tokens=n_new, seed=1),
         srv.submit(prompt(sv["max_prompt_len"]), max_new_tokens=n_new, seed=2),

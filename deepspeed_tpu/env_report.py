@@ -269,9 +269,11 @@ def _placement() -> None:
         )
         print(
             "program map ......... shared: all programs on one placement; "
-            "disaggregated: serving_prefill/_chunk_prefill → 'prefill', "
-            "serving_decode/_verify → 'decode', serving_kv_gather/"
-            "_scatter bridge the two"
+            "disaggregated: serving_chunk_prefill (and serving_prefill, "
+            "which only an engine with prefill_chunk_tokens 0 builds: one "
+            "that chunks prefills every prompt with the chunk program) → "
+            "'prefill', serving_decode/_verify → 'decode', "
+            "serving_kv_gather/_scatter bridge the two"
         )
         print(
             "verify .............. ServingEngine.verify() runs Engine F "

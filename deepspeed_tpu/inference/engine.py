@@ -352,8 +352,9 @@ class InferenceEngine:
               heat_tracer=None, journal=None):
         """Continuous-batching server over this engine (serving/scheduler.py):
         a paged KV pool + slot-based decode loop over a fixed set of AOT
-        executables (prefill + decode, plus speculative verify / chunked
-        prefill when the config enables them; prefix-cache KV reuse rides
+        executables (prefill + decode, speculative verify in the decode
+        step's place and chunked prefill in the whole-prompt prefill's when
+        the config enables them; prefix-cache KV reuse rides
         the same programs).
         ``serving_config`` (dict or :class:`~deepspeed_tpu.runtime.config.ServingConfig`)
         overrides the ``serving`` section passed to ``init_inference``."""

@@ -1345,11 +1345,14 @@ class ServingConfig(DSConfigModel):
     speculative: SpeculativeConfig = field(default_factory=SpeculativeConfig)
     # shared-prefix KV reuse over the page pool (+1 chunk-prefill executable)
     prefix_cache: PrefixCacheConfig = field(default_factory=PrefixCacheConfig)
-    # > 0: long prompts prefill in page-rounded chunks of this many tokens,
-    # one chunk per scheduler step, interleaved with decode — a long prompt
-    # stops stalling co-resident decode slots (TPOT invariance). 0 keeps the
-    # whole-prompt prefill; prefix-cache tails always use the chunk program
-    # (width = this value when set, else one page).
+    # > 0: EVERY cold prompt prefills in page-rounded chunks of this many
+    # tokens, one chunk per scheduler step, interleaved with decode — a long
+    # prompt stops stalling co-resident decode slots (TPOT invariance), a
+    # prompt no longer than a chunk takes ONE call, and the whole-prompt
+    # program is not built (the chunk program takes its place in the
+    # program set). 0 keeps the whole-prompt prefill; prefix-cache tails
+    # always use the chunk program (width = this value when set, else one
+    # page).
     prefill_chunk_tokens: int = 0
     # --- ISSUE 11: per-tenant SLO classes + goodput accounting -------------
     slo: SLOConfig = field(default_factory=SLOConfig)

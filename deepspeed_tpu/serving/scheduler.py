@@ -6,7 +6,9 @@ static batch per ``generate`` call, a fixed array of ``max_slots`` decode
 slots advances through ONE compiled decode-shaped program per step, while
 finished sequences vacate their slot mid-flight and queued requests are
 admitted into free slots via prefill-insertions (ONE compiled prefill
-program). A fixed, config-derived set of executables exists for the
+program: the chunk program where ``serving.prefill_chunk_tokens`` is set,
+else the whole-prompt program; a prompt's length selects nothing). A fixed,
+config-derived set of executables exists for the
 lifetime of the engine — ``ServingEngine.executables``, exact-checked by
 ``verify()`` — because every input shape is a function of the ``serving``
 config alone:
@@ -14,7 +16,8 @@ config alone:
 - tokens/seq_lens/keys: ``[max_slots]`` — inactive slots ride along pointed
   at the scratch page (their compute is garbage nobody reads; all ops are
   row-independent, so active slots are unaffected).
-- prompts: right-padded to the static prefill width, true length traced.
+- prompts: right-padded to the static prefill (or chunk) width, true length
+  traced.
 - the KV cache: a paged pool + per-slot block tables (serving/kv_cache.py),
   so sequence length never appears in any array shape.
 
@@ -36,10 +39,17 @@ the token streams:
   and prefill only the tail through the chunk program. A full-prefix hit
   copy-on-write-forks the last page (recomputed privately — the shared
   original is never written) and collapses TTFT to roughly one chunk step.
-- **Chunked prefill** (``serving.prefill_chunk_tokens``): long prompts
-  prefill in fixed-width page-aligned chunks, ONE chunk per scheduler step,
+- **Chunked prefill** (``serving.prefill_chunk_tokens``): EVERY prompt
+  prefills in fixed-width page-aligned chunks, ONE chunk per scheduler step,
   so a long prompt no longer stalls co-resident decode slots for its whole
-  width (TPOT invariance, tested).
+  width (TPOT invariance, tested), and a prompt that fits one chunk takes
+  one call of the chunk program, its first chunk and its last (``whole=1``
+  on the call's ``ds.serve.launch`` leaf). Such an engine builds TWO model
+  programs, the decode step and the chunk program: the whole-prompt program
+  (``max_prompt_len`` rows wide) is neither compiled nor called. It stays
+  the cold prompts' program where ``prefill_chunk_tokens`` is 0, with or
+  without a prefix cache (whose one-page chunks carry the tails of hits
+  only).
 
 One step in flight (ISSUE 54): a call of :meth:`ServingEngine.step` launches
 step n+1 before it reads step n's tokens. The sampled token stays on the
@@ -326,7 +336,11 @@ class ServingEngine:
         pcfg = getattr(config, "prefix_cache", None)
         self.prefix_enabled = bool(pcfg and pcfg.enabled)
         cw = int(getattr(config, "prefill_chunk_tokens", 0) or 0)
-        self._chunk_cold = cw > 0  # chunk long COLD prompts too
+        # the ONE fact that says which program prefills a cold prompt, for
+        # admission and for the program set alike: the chunk program (every
+        # cold prompt, whatever its length; no whole-prompt program is built)
+        # or, with no ``prefill_chunk_tokens``, the whole-prompt program
+        self._chunk_cold = cw > 0
         if cw > 0:
             self.chunk_width = pages_for(cw, page) * page
         elif self.prefix_enabled:
@@ -960,15 +974,17 @@ class ServingEngine:
 
     @property
     def expected_executables(self) -> int:
-        """The static-shapes contract (Engine A ``exact`` budget): one
-        prefill program, ONE decode-shaped program (the speculative verify
-        step REPLACES the plain decode step when enabled — never both), the
-        chunk-prefill program when chunking or the prefix cache needs it,
-        and — under disaggregated placements (ISSUE 14) — the KV-handoff
-        gather + scatter pair; the host tier (ISSUE 17) adds the width-1
-        ``serving_kv_restore`` scatter."""
+        """The static-shapes contract (Engine A ``exact`` budget): ONE
+        decode-shaped program (the speculative verify step REPLACES the plain
+        decode step when enabled — never both), the whole-prompt prefill
+        program unless the engine chunks its cold prompts
+        (``serving.prefill_chunk_tokens``: the chunk program then prefills
+        every prompt), the chunk-prefill program when chunking or the prefix
+        cache needs it, and — under disaggregated placements (ISSUE 14) — the
+        KV-handoff gather + scatter pair; the host tier (ISSUE 17) adds the
+        width-1 ``serving_kv_restore`` scatter."""
         return (
-            2 + (1 if self.chunk_width > 0 else 0)
+            1 + (0 if self._chunk_cold else 1) + (1 if self.chunk_width > 0 else 0)
             + (2 if self.disaggregated else 0)
             + (1 if self.tiering_enabled else 0)
         )
@@ -1136,7 +1152,7 @@ class ServingEngine:
     # compilation: a fixed feature-derived program set, ahead-of-time
     # ------------------------------------------------------------------
     def _ensure_compiled(self) -> None:
-        if self._prefill_exec is not None:
+        if self._program_info:
             return
         with spans.phase("ds.init.programs", what="serving") as ph:
             self._compile_programs()
@@ -1235,15 +1251,18 @@ class ServingEngine:
         sfx = "_int8" if self.quantized else ""
         info: dict = {}
 
-        self._prefill_exec = compile_for(self.prefill_set, p_fns[0], (
-            S((1, self.prefill_width), i32), S((), i32),
-            S((self.prefill_pages,), i32), S((2,), u32), *slot_sds,
-        ))
-        info[f"serving_prefill{sfx}{self.prefill_placement.suffix()}"] = {
-            "exe": self._prefill_exec, "pset": self.prefill_set,
-            "kind": "prefill", "fn": p_fns[0].__name__,
-        }
-        self.executables = [self._prefill_exec]
+        self.executables = []
+        if not self._chunk_cold:
+            # the whole-prompt program, where cold prompts have no other
+            self._prefill_exec = compile_for(self.prefill_set, p_fns[0], (
+                S((1, self.prefill_width), i32), S((), i32),
+                S((self.prefill_pages,), i32), S((2,), u32), *slot_sds,
+            ))
+            info[f"serving_prefill{sfx}{self.prefill_placement.suffix()}"] = {
+                "exe": self._prefill_exec, "pset": self.prefill_set,
+                "kind": "prefill", "fn": p_fns[0].__name__,
+            }
+            self.executables.append(self._prefill_exec)
         # the verify step REPLACES the decode step when speculation is on:
         # exactly one decode-shaped program ever advances the batch
         if self.spec_enabled:
@@ -1422,9 +1441,12 @@ class ServingEngine:
         self._g_experts_held.set(self.family.experts_held)
         hc_row_bytes = getattr(self.family, "stream_row_width", 0) * np.dtype(self.engine.dtype).itemsize
         self._g_hc_row_bytes.set(hc_row_bytes)
-        # the expert layers' form, as compiled: a program names the kernel or not
+        # the expert layers' form, as compiled: a program names the kernel or
+        # not (read off the step program, which every engine has: the form
+        # hangs on the experts' shapes, which are every program's)
+        step_exec = self._verify_exec if self.spec_enabled else self._decode_exec
         self._moe_kernel = bool(self.family.sparse_layers) and (
-            grouped_experts.KERNEL_NAME in self._prefill_exec.as_text()
+            grouped_experts.KERNEL_NAME in step_exec.as_text()
         )
         attrs = {
             key: " ".join(f"{k}={v}" for k, v in got.items())
@@ -1680,9 +1702,11 @@ class ServingEngine:
         program is the leaf's only call, else a ``ds.serve.launch`` of the
         call's own nested in the leaf that is there). ``kind``: ``plain``
         (the decode program), ``mixed`` (the chunk program with decode rows),
-        ``chunk`` (with none), ``prefill`` (the whole-prompt program),
-        ``verify``; ``rows``: decode rows carried; ``tokens``: prompt tokens
-        carried. A trace's reader joins the runtime's launch inside the leaf
+        ``chunk`` (with none), ``prefill`` (the whole-prompt program, which
+        only an engine that chunks no cold prompt has), ``verify``; ``rows``:
+        decode rows carried; ``tokens``: prompt tokens carried (a chunk call
+        that carries a whole prompt, first chunk and last in one, adds
+        ``whole=1``). A trace's reader joins the runtime's launch inside the leaf
         to the number, and the leaves that read the program's outputs name it
         (``flight``, ``firsts``, a synchronous wait's ``launch``):
         docs/OBSERVABILITY.md."""
@@ -1753,10 +1777,10 @@ class ServingEngine:
         self.slots[slot_i] = _Slot()
 
     def _launch(self) -> Optional[_Flight]:
-        """A call's launches: admission (whole prefills), the prefilling
-        slots' chunks and the decode dispatch, from what the host knows
-        without the tokens of the step in flight. → the step launched, if the
-        call had a decode row."""
+        """A call's launches: admission (and, in an engine that chunks no
+        cold prompt, its whole prefills), the prefilling slots' chunks and the
+        decode dispatch, from what the host knows without the tokens of the
+        step in flight. → the step launched, if the call had a decode row."""
         with spans.span("ds.serve.admit") as sp:
             admitted, blocked = 0, ""
             now = self.clock()
@@ -2451,11 +2475,12 @@ class ServingEngine:
                 retries=req.retries,
             )
 
-        use_chunks = self.chunk_width > 0 and (
-            shared_tokens > 0
-            or (self._chunk_cold and req.prompt_len > self.chunk_width)
-        )
-        if use_chunks:
+        # which program prefills: the chunk program every prompt of an engine
+        # that chunks cold prompts (one no longer than a chunk as ONE chunk,
+        # its first and its last) and the tail behind a prefix hit; the
+        # whole-prompt program the cold prompts of an engine that does not.
+        # A prompt's length selects nothing.
+        if self.chunk_width > 0 and (shared_tokens > 0 or self._chunk_cold):
             # chunked tail prefill: the real block table lives on the slot;
             # the main table row stays scratch so the batched decode's
             # rides-along write for this slot cannot touch real (possibly
@@ -2571,9 +2596,10 @@ class ServingEngine:
         page_ids[: len(avail)] = avail
         key0 = _host_prng_key(req.seed)
         pset = self.prefill_set
-        with spans.span("ds.serve.launch", **self._launch_attrs(
-            "mixed" if carried else "chunk", carried, len(seg)
-        )):
+        attrs = self._launch_attrs("mixed" if carried else "chunk", carried, len(seg))
+        if self._chunk_is_whole(slot_i):
+            attrs["whole"] = 1   # the prompt's first chunk and its last: the one call of its prefill
+        with spans.span("ds.serve.launch", **attrs):
             out = pset.call(
                 self._chunk_exec, *rows, ids, np.asarray(start, np.int32),
                 np.asarray(req.prompt_len, np.int32), page_ids, slot.row, key0,
@@ -2639,6 +2665,12 @@ class ServingEngine:
         slot = self.slots[slot_i]
         return slot.prefill_pos + self.chunk_width >= slot.request.prompt_len
 
+    def _chunk_is_whole(self, slot_i: int) -> bool:
+        """Whether the next chunk of a PREFILLING slot is its whole prompt:
+        its first chunk and its last, the one call of a prompt no longer than
+        a chunk."""
+        return self.slots[slot_i].prefill_pos == 0 and self._chunk_is_last(slot_i)
+
     def _moe_report(self, prompts: list) -> dict:
         """``ds.serve.chunk``'s expert attributes for the prompts that
         finished prefilling: (loads ``[calls x sparse layers, experts_held]``
@@ -2670,7 +2702,8 @@ class ServingEngine:
         placement, the first of them is the ``rider``, whose chunk that
         dispatch will carry (the leaf counts its tokens and key rows beside
         the others'); one call of the chunk program with no decode row for
-        each of the others.
+        each of the others. ``whole``: how many of those chunks are a whole
+        prompt (one no longer than a chunk: its one call).
         On a prompt's final chunk the sampled token becomes the request's
         first token and the slot joins the decode batch: here, where nothing
         is in flight and this leaf waits for it; else the token stays on the
@@ -2694,8 +2727,10 @@ class ServingEngine:
             sp.set(chunks=len(alone), rode=int(rider is not None))
             n_tok, attended = self._chunk_reach(rider) if rider is not None else (0, 0)
             finals = int(rider is not None and self._chunk_is_last(rider))
+            whole = 0
             for i in alone + ([rider] if rider is not None else []):
                 self._count_walk([self.slots[i].prefill_pos], self.chunk_width, chunk=True)
+                whole += self._chunk_is_whole(i)
             unwaited = []
             for i in alone:
                 slot = self.slots[i]
@@ -2724,7 +2759,7 @@ class ServingEngine:
                     self._moe_done.append((np.concatenate(counts), slot.moe_tokens))
                 slot.moe_counts, slot.moe_tokens = [], 0
                 self._start_decoding(i, int(tok_np[-1]))
-            sp.set(tokens=n_tok, attended=attended)
+            sp.set(tokens=n_tok, attended=attended, whole=whole)
             if getattr(self.family, "kinds", None):
                 # rows through the sub-blocks up to the family's stop_after,
                 # and through those behind it: a final chunk's sampled row

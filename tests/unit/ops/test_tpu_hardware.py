@@ -377,8 +377,9 @@ class TestServingPoolLayoutHardware:
             "max_prompt_len": 128, "max_new_tokens": 8,
             "prefill_chunk_tokens": 64, "kv_cache_dtype": kv_dtype,
         })
+        sfx = "_int8" if kv_dtype == "int8" else ""
         names = [name for name, _ in srv.executable_names()]
-        assert len(names) == 3
+        assert names == ["serving_decode" + sfx, "serving_chunk_prefill" + sfx]    # a server that chunks: no whole-prompt program
         assert srv.k_pool.shape == (3, 4, 64, 4, page, 64)
         row_major = tuple(range(6))
         assert srv.k_pool.format.layout.major_to_minor == row_major

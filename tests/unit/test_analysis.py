@@ -819,12 +819,12 @@ class TestProgramGate:
             "prefix_cache": {"enabled": True},
             "prefill_chunk_tokens": 4,
         })
-        assert srv.expected_executables == 3
+        # the verify step and the chunk program: an engine that chunks its
+        # cold prompts builds no whole-prompt program (ISSUE 63)
+        assert srv.expected_executables == 2
         assert srv.verify() == []
         names = [n for n, _ in srv.executable_names()]
-        assert names == [
-            "serving_prefill", "serving_verify", "serving_chunk_prefill"
-        ]
+        assert names == ["serving_verify", "serving_chunk_prefill"]
         # the verify program's pools are donated-and-aliased like decode's
         pool_dims = ",".join(str(d) for d in srv.k_pool.shape)
         for _, exe in srv.executable_names():

@@ -914,9 +914,10 @@ class TestSpeculativeDecode:
             reqs.append((prompt, n, srv.submit(prompt, max_new_tokens=n, seed=i)))
         done = srv.run()
         assert len(done) == 16
-        # prefill + verify + chunk-prefill: the verify step REPLACES decode
-        assert len(srv.executables) == 3
-        assert srv.expected_executables == 3
+        # verify + chunk-prefill: the verify step REPLACES decode, and an
+        # engine that chunks its cold prompts builds no whole-prompt program
+        assert len(srv.executables) == 2
+        assert srv.expected_executables == 2
         for prompt, n, req in reqs:
             assert req.status == RequestStatus.FINISHED
             assert len(req.tokens) == n
@@ -1228,8 +1229,10 @@ class TestChunkedPrefill:
                 inference_engine.generate(prompt[None, :], max_new_tokens=6)
             )[0]
             np.testing.assert_array_equal(req.output, ref)
-        # 12 and 9 chunked (3 chunks each), 3 took the whole-prefill path
-        assert srv.metrics.counter("serving_chunk_prefills_total").value() == 6
+        # 12 and 9 in 3 chunks each, 3 as ONE chunk: no prompt's length selects a program
+        assert srv.metrics.counter("serving_chunk_prefills_total").value() == 7
+        assert srv.metrics.counter("serving_prefills_total").value() == 3
+        assert srv._prefill_exec is None
         srv.check_no_leaks()
 
     def test_chunked_prefill_does_not_stall_decode(
@@ -1339,7 +1342,7 @@ class TestServingStats:
         srv = inference_engine.serve(dict(SERVING_CFG, prefill_chunk_tokens=4))
         t0 = spans._clock()
         names = [name for name, _ in srv.executable_names()]
-        assert len(names) == 3
+        assert names == ["serving_decode", "serving_chunk_prefill"]
         ph = [r for r in spans.phases(since=t0) if r[0] == "ds.init.programs"]
         assert len(ph) == 1
         attrs = ph[0][3]
@@ -1569,30 +1572,40 @@ def _span_clock():
 
 class TestStepAhead:
     @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
-    @pytest.mark.parametrize("family", AHEAD_FAMILIES)
+    @pytest.mark.parametrize("family", AHEAD_FAMILIES + ("gpt2-whole",))
     def test_staggered_requests_are_served_what_the_synchronous_order_serves(
         self, family_engines, family, temperature
     ):
-        """(a) Prompts that end in a whole prefill with nothing in flight (5),
-        in a last chunk that rides (20), in a last chunk alone (27: the second
-        slot prefilling) and in a whole prefill behind a step in flight (7),
-        staggered over the calls: tokens, status and stamps a token equal the
-        loop held to depth 0, and gpt2's equal ``generate``'s."""
+        """(a) Prompts that end in ONE chunk, their first and last, with
+        nothing in flight (5), in a last chunk that rides (20), in a last
+        chunk alone (27: the second slot prefilling) and in one chunk behind a
+        step in flight (7), staggered over the calls: tokens, status and
+        stamps a token equal the loop held to depth 0, and gpt2's equal
+        ``generate``'s. ``gpt2-whole``: an engine that chunks no cold prompt,
+        so each of the four is a whole prefill (ISSUE 63: only such an engine
+        has the whole-prompt program), the first with nothing in flight, the
+        others behind a step."""
+        over = {"temperature": temperature}
+        if family == "gpt2-whole":
+            family, over["prefill_chunk_tokens"] = "gpt2", 0
         engine, vocab = family_engines(family)
         prompts = _ahead_prompts(vocab, (5, 20, 27, 7), seed=2)
         plan = [(0, prompts[0], dict(max_new_tokens=9, seed=0)), (2, prompts[1], dict(max_new_tokens=12, seed=1)),
                 (2, prompts[2], dict(max_new_tokens=12, seed=2)), (4, prompts[3], dict(max_new_tokens=6, seed=3))]
         t0 = _span_clock()
-        srv = _ahead_srv(engine, temperature=temperature)
+        srv = _ahead_srv(engine, **over)
         got = _play(srv, plan)
         waits = len(_span_attrs(t0, "ds.serve.prefill.wait")), len(_span_attrs(t0, "ds.serve.chunk.wait"))
         launches = _span_attrs(t0, "ds.serve.decode.dispatch")
-        want = _play(_ahead_srv(engine, ahead=False, temperature=temperature), plan)
+        want = _play(_ahead_srv(engine, ahead=False, **over), plan)
         _same_service(got, want)
         assert all(r.status == RequestStatus.FINISHED for r in got)
-        # only the empty server's first prefill waited where it was launched: the other first tokens were left
-        # on their slots (a whole prefill, a last chunk alone) or in the step their chunk rode
-        assert waits == (1, 0)
+        # only the empty server's first prompt (one chunk; a whole prefill where nothing chunks) waited where it
+        # was launched: the other first tokens were left on their slots (a last chunk alone, a whole prefill) or
+        # in the step their chunk rode
+        assert waits == ((0, 1) if srv._chunk_cold else (1, 0))
+        kinds = {a["kind"] for a in _span_attrs(t0, "ds.serve.launch")}
+        assert kinds == ({"chunk", "mixed"} if srv._chunk_cold else {"prefill"})
         st = srv.stats()
         assert st["steps_ahead"] == sum(d["ahead"] for d in launches) >= len(launches) - 2
         assert st["rows_dropped"] == 0
@@ -1614,7 +1627,7 @@ class TestStepAhead:
 
         engine, vocab = family_engines(family)
         srv = _ahead_srv(engine)
-        assert len(srv.executable_names()) == srv.expected_executables == 3
+        assert len(srv.executable_names()) == srv.expected_executables == 2     # the step program and the chunk program
         log = []
 
         def spy(name):
@@ -1622,12 +1635,14 @@ class TestStepAhead:
 
             def call(*a):
                 out = exe(*a)
-                log.append(("launch", name, out))
+                # a step: the decode program, or the chunk program with a decode row (params, cache, tokens,
+                # lengths, block tables, ...: a call that rides nothing takes the idle table's)
+                log.append(("launch", name == "_decode_exec" or bool(np.asarray(a[4]).any()), out))
                 return out
 
             setattr(srv, name, call)
 
-        for name in ("_prefill_exec", "_decode_exec", "_chunk_exec"):
+        for name in ("_decode_exec", "_chunk_exec"):
             spy(name)
         get = jax.device_get
         monkeypatch.setattr(sched.jax, "device_get", lambda x: log.append(("fetch", x)) or get(x))
@@ -1640,14 +1655,15 @@ class TestStepAhead:
             mark = len(log)
             srv.step()
             calls.append(log[mark:])
-        steps = [e for e in log if e[0] == "launch" and e[1] != "_prefill_exec"]
+        steps = [e for e in log if e[0] == "launch" and e[1]]
         step_at = {id(e): n for n, e in enumerate(steps)}
         read = 0
         for k, events in enumerate(calls):
             kinds = [e[0] for e in events]
             if k == 0:
-                # the empty server: its prefill waits where it is launched, then two steps go out and one is read
-                assert kinds == ["launch", "fetch", "launch", "launch", "fetch"]
+                # the empty server: its prompt's one chunk waits where it is launched, then two steps go out
+                # and one is read
+                assert kinds == ["launch", "fetch", "launch", "launch", "fetch"] and not events[0][1]
                 events, kinds = events[2:], kinds[2:]
             assert kinds.count("fetch") <= 1
             if "fetch" not in kinds:
@@ -1663,7 +1679,7 @@ class TestStepAhead:
         assert read >= 12
         srv.run()
         srv.check_no_leaks()
-        assert len(srv.executables) == srv.expected_executables == 3
+        assert len(srv.executables) == srv.expected_executables == 2
 
     @pytest.mark.parametrize("family", AHEAD_FAMILIES)
     def test_a_stop_by_count_launches_no_row_and_a_late_stop_drops_one(self, family_engines, family):

@@ -255,23 +255,25 @@ class TestWeightCensus:
         from deepspeed_tpu.telemetry import spans
 
         cfg, _, moved = engines
-        srv = moved.serve(dict(SERVING, prefill_chunk_tokens=4))
-        t0 = spans._clock()
-        names = [name for name, _ in srv.executable_names()]
-        assert len(names) == 3
-        big = srv.decode_set._weight_leaves()
-        assert ((jnp.dtype("float32"), (cfg.vocab_size, cfg.n_embd)) in big) == (cfg.n_embd == 192)
-        attrs = [r for r in spans.phases(since=t0) if r[0] == "ds.init.programs"][0][3]
-        logged = dict(kv.split("=") for kv in attrs["weight_relayout"].split())
-        gauge = srv.metrics.get("serving_weight_relayout_bytes")
-        for name in names:
-            rec = srv._program_info[name]
-            ops, nbytes = rec["pset"].program_census(name, rec["exe"])[2:]
-            assert gauge.value(program=name) == nbytes and logged[name] == f"{ops}/{nbytes}"
-            if not big:
-                assert (ops, nbytes) == (0, 0)
-            else:   # whole tables or nothing: a multiple of the one leaf's bytes
-                assert nbytes == ops * 4 * cfg.vocab_size * cfg.n_embd
+        # the program set of an engine that chunks its cold prompts, and of one that does not (ISSUE 63)
+        for chunk, programs in ((4, ["serving_decode", "serving_chunk_prefill"]), (0, ["serving_prefill", "serving_decode"])):
+            srv = moved.serve(dict(SERVING, prefill_chunk_tokens=chunk))
+            t0 = spans._clock()
+            names = [name for name, _ in srv.executable_names()]
+            assert names == programs
+            big = srv.decode_set._weight_leaves()
+            assert ((jnp.dtype("float32"), (cfg.vocab_size, cfg.n_embd)) in big) == (cfg.n_embd == 192)
+            attrs = [r for r in spans.phases(since=t0) if r[0] == "ds.init.programs"][0][3]
+            logged = dict(kv.split("=") for kv in attrs["weight_relayout"].split())
+            gauge = srv.metrics.get("serving_weight_relayout_bytes")
+            for name in names:
+                rec = srv._program_info[name]
+                ops, nbytes = rec["pset"].program_census(name, rec["exe"])[2:]
+                assert gauge.value(program=name) == nbytes and logged[name] == f"{ops}/{nbytes}"
+                if not big:
+                    assert (ops, nbytes) == (0, 0)
+                else:   # whole tables or nothing: a multiple of the one leaf's bytes
+                    assert nbytes == ops * 4 * cfg.vocab_size * cfg.n_embd
 
     def test_the_count_on_hlo_as_the_tpu_compiler_prints_it(self):
         """Whole leaves copied or transposed, in the leaf's own type. Not a

@@ -22,13 +22,15 @@ pytestmark = pytest.mark.serving
 LAUNCHERS = ("ds.serve.launch", "ds.serve.decode.dispatch")
 SYNC_WAITS = ("ds.serve.prefill.wait", "ds.serve.chunk.wait", "ds.serve.handoff.wait")
 # how a server is made and driven: a step ahead, the same loop held to depth 0 (nothing in flight at a read),
-# a step ahead whose every step is read by settle() outside step(), and the two servers that launch nothing ahead
+# a step ahead whose every step is read by settle() outside step(), the two servers that launch nothing ahead, and
+# a server a step ahead that chunks no cold prompt: the only one with the whole-prompt program (ISSUE 63)
 PATHS = {
     "ahead": {},
     "sync": {"ahead": False},
     "settle": {},
     "speculation": {"speculative": {"enabled": True, "k": 3, "ngram": 2}},
     "disaggregated": {"placement": {"disaggregate": True}},
+    "whole": {"prefill_chunk_tokens": 0},
 }
 
 
@@ -50,11 +52,12 @@ def _spied(srv):
 
 
 def _serve(engine, vocab, path):
-    """Four prompts that end in a whole prefill with nothing in flight (5), a
-    last chunk that rides (20), a last chunk alone in the same call (19: the
-    second slot prefilling) and a whole prefill behind a step in flight (7),
-    staggered over the calls. → (server, requests, the program calls, the
-    ``ds.serve.*`` records)."""
+    """Four prompts that end in ONE chunk, their first and last, with nothing
+    in flight (5), a last chunk that rides (20), a last chunk alone in the
+    same call (19: the second slot prefilling) and one chunk that rides (7),
+    staggered over the calls; on the ``whole`` path four whole prefills, the
+    first with nothing in flight and three behind a step. → (server, requests,
+    the program calls, the ``ds.serve.*`` records)."""
     prompts = _ahead_prompts(vocab, (5, 20, 19, 7), seed=2)
     plan = [(0, prompts[0], dict(max_new_tokens=9, seed=0)), (2, prompts[1], dict(max_new_tokens=12, seed=1)),
             (2, prompts[2], dict(max_new_tokens=12, seed=2)), (4, prompts[3], dict(max_new_tokens=6, seed=3))]
@@ -109,7 +112,7 @@ def test_a_steps_launch_wait_and_emit_leaves_carry_the_same_number(inference_eng
              if r[0] == "ds.serve.decode.wait" or r[3].get("kind") in ("plain", "mixed", "verify")]
     nxt = dict(zip(steps, steps[1:]))
     behind = [k for k, (what, n) in enumerate(order) if what == "R" and ("L", nxt.get(n)) in order[:k]]
-    if path == "ahead":
+    if path in ("ahead", "whole"):
         # the step after it was launched before a step was read, but for the last of a burst
         assert len(behind) >= len(steps) - 3
         assert sum(r[3]["ahead"] for r in recs if r[0] == "ds.serve.decode.dispatch") == srv.stats()["steps_ahead"]
@@ -135,18 +138,25 @@ def test_each_first_token_is_named_once_and_the_request_keeps_the_number(inferen
         # the program that sampled the first token carried the prompt's end: a whole prefill, or its last chunk
         assert a["kind"] in ("prefill", "mixed", "chunk")
         assert a["tokens"] == (r.prompt_len if a["kind"] == "prefill" else (r.prompt_len - 1) % 8 + 1)
+    whole = [n for n, a in leaves.items() if a.get("whole")]
+    # a chunk call says ``whole`` where it carried a prompt no longer than a chunk: its first chunk and its last
+    assert sorted(whole) == sorted(r.first_launch for r in reqs if r.prompt_len <= 8 and path != "whole")
     if path == "ahead":
-        # the empty server's first prefill waited where it was launched; the three others were left on the device:
-        # one in the step its last chunk rode (the flight's own number), two on their slots (a last chunk launched
-        # alone and a whole prefill, each behind a step in flight)
+        # the empty server's first prompt's one chunk waited where it was launched; the three others were left on
+        # the device: two in the step their last chunk rode (the flight's own number: a prompt of three chunks and
+        # one of ONE), one on its slot (a last chunk launched alone behind a step in flight)
         assert len(waited) == 1 and len(firsts) == 3
         own = [r[3]["flight"] for r in recs if r[0] == "ds.serve.emit" and "firsts" in r[3]
                and str(r[3]["flight"]) in str(r[3]["firsts"]).split(",")]
-        assert len(own) == 1 and leaves[own[0]]["kind"] == "mixed"
-        assert sorted(leaves[n]["kind"] for n in firsts) == ["chunk", "mixed", "prefill"]
+        assert len(own) == 2 and all(leaves[n]["kind"] == "mixed" for n in own)
+        assert sorted(leaves[n]["kind"] for n in firsts) == ["chunk", "mixed", "mixed"]
+    if path == "whole":
+        # the first whole prefill waited where it was launched, each of the others left its token on its slot
+        assert len(waited) == 1 and len(firsts) == 3 and {leaves[n]["kind"] for n in firsts + waited} == {"prefill"}
+        assert {r[0] for r in recs if r[0] in SYNC_WAITS and "launch" in r[3]} == {"ds.serve.prefill.wait"}
     if path in ("sync", "speculation", "disaggregated"):
         assert not firsts and len(waited) == len(reqs)      # nothing in flight: every first token is waited for
-        want = {"disaggregated": {"ds.serve.handoff.wait"}}.get(path, {"ds.serve.prefill.wait", "ds.serve.chunk.wait"})
+        want = {"disaggregated": {"ds.serve.handoff.wait"}}.get(path, {"ds.serve.chunk.wait"})
         assert {r[0] for r in recs if r[0] in SYNC_WAITS and "launch" in r[3]} == want
 
 
@@ -184,7 +194,7 @@ def test_a_retried_request_names_the_program_of_its_second_residency(inference_e
     t0 = _span_clock()
     (r,) = _play(srv, [(0, p, dict(max_new_tokens=8, seed=2))])
     assert r.retries == 1 and len(r.tokens) == 8
-    prefills = [a["launch"] for a in _launchers(_records(t0)) if a["kind"] == "prefill"]
+    prefills = [a["launch"] for a in _launchers(_records(t0)) if a.get("whole")]    # each residency's one chunk
     assert len(prefills) == 2 and r.first_launch == prefills[1]
 
 

@@ -31,7 +31,7 @@ CFG = dict(
 )
 SERVING = dict(max_slots=3, page_size=4, num_pages=64, max_prompt_len=40, max_new_tokens=12,
                prefill_chunk_tokens=8, temperature=0.0)
-# whole-prompt program (<= one chunk: 5, 8) and 2-5 chunks whose LAST has one row (9, 17, 33) or two (10), or is whole (40)
+# ONE chunk (<= a chunk: 5, 8; first and last in one call) and 2-5 chunks whose LAST has one row (9, 17, 33) or two (10), or is whole (40)
 PROMPTS = (5, 8, 9, 10, 17, 19, 33, 40, 27)
 GAP_TOL = 1e-4                          # float32 both ways, summed in another order
 
@@ -77,7 +77,7 @@ def _gaps(params, prompt, tokens, arch):
 def test_forward_absorbed_and_served_streams_are_the_references_with_both_kinds_of_pool_and_slot_reuse(mcfg, engine, served, prompts, arch):
     """One engine and one server, built once: the model's own ``forward``,
     expanded and absorbed, is the reference's logits; the served streams are
-    the reference's across the whole-prompt program and prefill in chunks
+    the reference's across prefill in one chunk and in several
     (last chunks of one and two rows), cached decode and slot reuse; the state
     pools stand beside ONE latent pool; the gauges, the phase's attrs, the
     parts, the group limit's rows; migration is refused with both kinds named."""
@@ -110,6 +110,8 @@ def test_forward_absorbed_and_served_streams_are_the_references_with_both_kinds_
     assert phase[3]["lin_state_bytes"] == by["lin_state"] and "state=" in phase[3]["kv_bytes"] and "latent=" in phase[3]["kv_bytes"]
     assert smodel._kv_homes(fam) == [(False, 0), (False, 1), (False, 0), (False, 2), (False, 3), (False, 1)]
     assert fam.kinds == ("lin", "lin", "attn") * 2 and fam.sparse_layers == (1, 2, 3, 4, 5) and fam.lin_g_min == -5
+    whole = engine.serve(dict(SERVING, prefill_chunk_tokens=0))      # the whole-prompt program: only where nothing chunks
+    whole._ensure_compiled()
     for name in ("jit_decode_fn", "jit_chunk_decode_fn", "jit_prefill_fn"):
         table = parts.tables()[name]
         assert {"lin.proj", "lin.scan", "attn.core", "moe.route", "moe.experts"} <= {e.part for e in table.values()}, name

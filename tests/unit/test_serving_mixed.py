@@ -29,7 +29,7 @@ from .test_serving_mistral4 import CFG as MS4_CFG
 
 SERVING = dict(max_slots=3, page_size=4, num_pages=96, max_prompt_len=40, max_new_tokens=12,
                prefill_chunk_tokens=8, temperature=0.0, kv_cache_dtype="float32")
-PROMPTS = (5, 19, 33, 40, 27, 9, 22)    # the whole-prompt program (<= one chunk) and 3-5 chunks
+PROMPTS = (5, 19, 33, 40, 27, 9, 22)    # ONE chunk (<= a chunk: its first and last) and 2-5 chunks
 FAMILIES = ("gpt2", "exaone_moe", "mistral4")
 
 
@@ -220,11 +220,12 @@ def test_requests_served_with_chunks_riding_get_the_tokens_they_get_alone(engine
     ref, alone = _alone(engine, prompts)
     for a, b in zip(mixed, alone):
         assert a.status == b.status == "finished" and list(a.tokens) == list(b.tokens)
-    n_chunks = sum(-(-len(p) // 8) for p in prompts if len(p) > 8)
+    n_chunks = sum(-(-len(p) // 8) for p in prompts)                      # a prompt of 5 is one chunk, as one of 8 is
     assert _count(srv, "serving_chunk_prefills_total") == _count(ref, "serving_chunk_prefills_total") == n_chunks
     assert _count(ref, "serving_chunks_rode_total") == 0                 # alone: nothing decodes beside a prefill
     assert 0 < _count(srv, "serving_chunks_rode_total") <= n_chunks
-    assert len(srv.executables) == srv.expected_executables == 3        # the mixed program is the chunk program
+    # the mixed program is the chunk program, and an engine that chunks has no whole-prompt program (ISSUE 63)
+    assert len(srv.executables) == srv.expected_executables == 2
     for s in (srv, ref):
         s.drain(0.0)
         s.check_no_leaks()
@@ -261,12 +262,12 @@ def test_a_rider_starts_decoding_the_step_after_its_last_chunk_and_that_step_sta
     from deepspeed_tpu.serving import ServingEngine
     srv = ServingEngine(engine, dict(SERVING), clock=lambda: float(next(ticks)))
     a = srv.submit(short, max_new_tokens=12, seed=0)
-    srv.step()                                     # whole prefill and the first decode step
-    assert len(a.tokens) == 2
+    srv.step()                                     # a's one chunk, alone and waited for, and the first decode step
+    assert len(a.tokens) == 2 and _count(srv, "serving_chunk_prefills_total") == 1
     b = srv.submit(long, max_new_tokens=12, seed=1)
     for n_chunk in (1, 2, 3):                      # 20 tokens: three chunks, each beside a's decode step
         srv.step()
-        assert _count(srv, "serving_chunks_rode_total") == n_chunk == _count(srv, "serving_chunk_prefills_total")
+        assert _count(srv, "serving_chunks_rode_total") == n_chunk == _count(srv, "serving_chunk_prefills_total") - 1
         assert len(a.tokens) == 2 + n_chunk         # no decode step was held back
     # the last chunk rode the step that call LAUNCHED, which is in flight: b's first token is its last place, on
     # the device, and b's rows are launched from the next step on all the same
@@ -299,7 +300,7 @@ def test_of_two_slots_prefilling_in_a_step_one_rides_and_one_is_a_call_with_no_d
     srv.step()
     srv._launch_chunk = launch
     assert sum(1 for s in srv.slots if s.request is not None and s.prefilling) == 2
-    assert _count(srv, "serving_chunk_prefills_total") == 2 and _count(srv, "serving_chunks_rode_total") == 1
+    assert _count(srv, "serving_chunk_prefills_total") == 1 + 2 and _count(srv, "serving_chunks_rode_total") == 1
     # both calls under the dispatch leaf, the one with no decode row first (nothing waits for it), each under a
     # ds.serve.launch leaf of its own that says what it carried: a trace's reader knows a call by its number
     (_, d0, d1, _), = [r for r in spans.snapshot(since=t0) if r[0] == "ds.serve.decode.dispatch"][-1:]
@@ -314,7 +315,7 @@ def test_of_two_slots_prefilling_in_a_step_one_rides_and_one_is_a_call_with_no_d
     srv.run()
     _, alone = _alone(engine, [short, l1, l2])
     assert [list(r.tokens) for r in reqs] == [list(r.tokens) for r in alone]
-    assert _count(srv, "serving_chunk_prefills_total") == 4 + 4
+    assert _count(srv, "serving_chunk_prefills_total") == 1 + 4 + 4
     srv.drain(0.0)
     srv.check_no_leaks()
 
@@ -328,7 +329,7 @@ def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
     srv, reqs = _together(engine, prompts)
     steps = _steps(spans.snapshot(since=t0))
     chunks = [s["ds.serve.chunk"] for s in steps if "ds.serve.chunk" in s]
-    long = [len(p) for p in prompts if len(p) > 8]
+    long = [len(p) for p in prompts]           # every prompt goes in chunks, one of 5 tokens in one
     # every chunk advanced is in tokens / attended, ridden or not; chunks counts the calls with no decode row
     assert all(c["rode"] in (0, 1) for c in chunks)
     assert sum(c["chunks"] + c["rode"] for c in chunks) == sum(-(-n // 8) for n in long)
@@ -336,6 +337,7 @@ def test_the_spans_carry_the_counts_the_readers_live_on(engines, family):
     assert sum(c["attended"] for c in chunks) == sum(n * (n + 1) // 2 for n in long)
     assert sum(c["rode"] for c in chunks) == _count(srv, "serving_chunks_rode_total") > 0
     assert sum(c["chunks"] + c["rode"] for c in chunks) == _count(srv, "serving_chunk_prefills_total")
+    assert sum(c["whole"] for c in chunks) == sum(n <= 8 for n in long) == 1     # the prompts that took ONE call
     n_sparse = len(fam.sparse_layers)
     # a call reads the step the call before launched (an empty server's first call launches two and reads the
     # first): the n-th emit leaf is the n-th dispatch leaf's, and a chunk rides its own call's first dispatch
@@ -407,7 +409,7 @@ def test_the_dispatch_leaf_and_the_counters_carry_the_attention_kernels_walk(eng
     lens = [range(len(p), len(p) + 11) for p in prompts]      # a request's first token is its prefill's
     assert sum(d["walk_steps"] for d in disp) == sum(3 - d["active"] for d in disp) + sum(1 + n // 32 for r in lens for n in r)
     assert walk(kernels[0]) == sum(d["walk_steps"] for d in disp) and rect(kernels[0]) == 6 * len(disp)
-    starts = [s for p in prompts if len(p) > 8 for s in range(0, len(p), 8)]
+    starts = [s for p in prompts for s in range(0, len(p), 8)]
     assert walk(kernels[1]) == sum(min((s + 7) // 32, 1) + 1 for s in starts) and rect(kernels[1]) == 2 * len(starts)
     # nothing is counted where the programs hold no kernel; the leaf still says what the call owns
     monkeypatch.undo()
@@ -420,15 +422,16 @@ def test_the_dispatch_leaf_and_the_counters_carry_the_attention_kernels_walk(eng
 # -- what must not ride -------------------------------------------------------------
 
 @pytest.mark.parametrize("over,n_exe", [
-    ({"speculative": {"enabled": True, "k": 3, "ngram": 2}}, 3),
-    ({"placement": {"disaggregate": True}}, 5),
-    ({"placement": {"tp": 2}}, 3),
+    ({"speculative": {"enabled": True, "k": 3, "ngram": 2}}, 2),
+    ({"placement": {"disaggregate": True}}, 4),
+    ({"placement": {"tp": 2}}, 2),
 ], ids=["speculation", "disaggregated", "tp2"])
 def test_speculation_and_disaggregation_keep_the_chunk_alone_and_tp_rides(engines, over, n_exe):
     """The verify step and a prefill placement of its own take the chunk
     program with no decode row, as they took the chunk program; a
-    tensor-parallel placement is one placement and rides. Same tokens, same
-    number of executables."""
+    tensor-parallel placement is one placement and rides. Same tokens, and
+    no whole-prompt program in any of them: the verify (or decode) step, the
+    chunk program and, disaggregated, the handoff's pair."""
     engine, vocab = engines("gpt2")
     prompts = _prompts(vocab)
     srv, got = _together(engine, prompts, **over)
@@ -438,6 +441,7 @@ def test_speculation_and_disaggregation_keep_the_chunk_alone_and_tp_rides(engine
     assert len(srv.executables) == srv.expected_executables == n_exe
     rode = _count(srv, "serving_chunks_rode_total")
     assert rode > 0 if "tp" in over.get("placement", {}) else rode == 0
-    assert _count(srv, "serving_chunk_prefills_total") == sum(-(-len(p) // 8) for p in prompts if len(p) > 8)
+    assert _count(srv, "serving_chunk_prefills_total") == sum(-(-len(p) // 8) for p in prompts)
+    assert not any("prefill" in name for name, _ in srv.executable_names() if "chunk" not in name)
     srv.drain(0.0)
     srv.check_no_leaks()

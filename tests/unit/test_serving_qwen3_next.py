@@ -31,7 +31,7 @@ CFG = dict(
 )
 SERVING = dict(max_slots=3, page_size=4, num_pages=64, max_prompt_len=40, max_new_tokens=12,
                prefill_chunk_tokens=8, temperature=0.0)
-# whole-prompt program (<= one chunk: 5, 8) and 2-5 chunks whose LAST has one row (9, 17, 33) or two (10), or is whole (40);
+# ONE chunk (<= a chunk: 5, 8; first and last in one call) and 2-5 chunks whose LAST has one row (9, 17, 33) or two (10), or is whole (40);
 # a chunk of 8 rows is no multiple of the rule's sub-chunk of 64
 PROMPTS = (5, 8, 9, 10, 17, 19, 33, 40, 27)
 GAP_TOL = 1e-4                          # float32 both ways, summed in another order
@@ -84,8 +84,8 @@ def _gaps(params, prompt, tokens, arch):
 def test_forward_and_served_streams_are_the_references_with_state_pools_parts_and_slot_reuse(mcfg, engine, served, prompts, arch):
     """One engine and one server, built once (the workers of a run share no
     fixture): the model's own ``forward`` is the reference's logits; the
-    served streams are the reference's across whole-prompt and chunked
-    prefill, last chunks of one and two rows and slot reuse; the state pools
+    served streams are the reference's across prefill in one chunk and in
+    several, last chunks of one and two rows and slot reuse; the state pools
     stand beside the paged pools; the gauge, the phase's attr and the parts;
     a slot used again serves the same tokens; migration is refused by name."""
     ids = jnp.asarray(np.random.default_rng(1).integers(0, 96, (2, 21)).astype(np.int32))
@@ -111,6 +111,8 @@ def test_forward_and_served_streams_are_the_references_with_state_pools_parts_an
     assert smodel._kv_homes(srv.family) == [(False, 0), (False, 1), (False, 2), (False, 0),
                                             (False, 3), (False, 4), (False, 5), (False, 1)]
     # -- every product of the served programs has a part, and the rule has its own
+    whole = engine.serve(dict(SERVING, prefill_chunk_tokens=0))      # the whole-prompt program: only where nothing chunks
+    whole._ensure_compiled()
     for name in ("jit_decode_fn", "jit_chunk_decode_fn", "jit_prefill_fn"):
         table = parts.tables()[name]
         got = {e.part for e in table.values()}

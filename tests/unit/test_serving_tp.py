@@ -192,9 +192,11 @@ class TestDisaggregatedPlacements:
     def test_disaggregated_verify_clean_and_handoff_programs(
         self, inference_engine
     ):
-        """TP=2 disaggregated compiles the full program set (prefill +
-        verify-or-decode + chunk + gather + scatter), verifies clean
-        through Engines A/D/E/F, and names programs per placement."""
+        """TP=2 disaggregated compiles the full program set of an engine
+        that chunks its cold prompts (verify-or-decode + chunk + gather +
+        scatter; no whole-prompt program: the chunk program runs on the
+        prefill placement and leaves its token for the handoff), verifies
+        clean through Engines A/D/E/F, and names programs per placement."""
         srv = inference_engine.serve(dict(
             BASE, **ALL_FEATURES,
             placement={"tp": 2, "disaggregate": True},
@@ -202,11 +204,11 @@ class TestDisaggregatedPlacements:
         assert srv.verify() == []
         names = [n for n, _ in srv.executable_names()]
         assert names == [
-            "serving_prefill_tp2", "serving_verify_tp2",
+            "serving_verify_tp2",
             "serving_chunk_prefill_tp2", "serving_kv_gather_tp2",
             "serving_kv_scatter_tp2",
         ]
-        assert len(srv.executables) == srv.expected_executables == 5
+        assert len(srv.executables) == srv.expected_executables == 4
 
     def test_handoff_trace_span(self, tiny_cfg, inference_engine, tmp_path):
         """The kv_handoff span lands in the PR-11 request trace with pages,
@@ -270,7 +272,7 @@ class TestShardingAnalysisPlane:
         assert "unmatched-param-rule" in kinds
         assert "spec-rank-mismatch" in kinds
         assert "replicated-large-leaf" in kinds
-        assert srv._prefill_exec is None  # pre-compile: nothing traced
+        assert not srv.executables and not srv._program_info  # pre-compile: nothing traced
 
     def test_committed_table_verifies_clean_pre_compile(self, inference_engine):
         """The committed GPT2_SERVING_RULES pass Engine F for the real tree

@@ -120,8 +120,8 @@ def _top_level(children):
 
 
 def _serve(ahead=True):
-    """A short run through a tiny server: mixed prompts, chunked and whole
-    prefill, more requests than slots. ``ahead=False`` holds the loop to
+    """A short run through a tiny server: mixed prompts, in one chunk and in
+    several, more requests than slots. ``ahead=False`` holds the loop to
     depth 0: a call reads the step it launched, nothing is ever in flight
     between two calls. Returns (records, phases, requests, engine)."""
     from deepspeed_tpu.inference.engine import InferenceEngine
@@ -187,12 +187,16 @@ def _attr_sets(recs, names):
 # out a first token left on the device ``firsts``, a synchronous wait for one ``launch``
 LAUNCH = {"launch", "kind", "rows", "tokens"}
 DISPATCH = {"active", "ahead", "attended", "pages", "walk_steps", "rect_steps"}   # the attention kernel's walk (ISSUE 58)
-LEAF_ATTRS = {"ds.serve.admit": [{"admitted", "blocked"}], "ds.serve.chunk": [{"chunks", "rode", "tokens", "attended"}],
+# (``whole``, ISSUE 63: a chunk call that carries a whole prompt, and how many of a step's chunks do. A server that
+# chunks has no whole-prompt program, so no ``ds.serve.prefill.wait``: tests/unit/test_serving_launches.py holds that
+# leaf to its ``launch`` on a server that does not chunk)
+LEAF_ATTRS = {"ds.serve.admit": [{"admitted", "blocked"}],
+              "ds.serve.chunk": [{"chunks", "rode", "tokens", "attended", "whole"}],
               "ds.serve.decode.dispatch": [DISPATCH | LAUNCH, DISPATCH],      # a plain step; a chunk rides
-              "ds.serve.launch": [LAUNCH], "ds.serve.decode.wait": [{"flight"}],
+              "ds.serve.launch": [LAUNCH, LAUNCH | {"whole"}], "ds.serve.decode.wait": [{"flight"}],
               "ds.serve.emit": [{"tokens", "finished", "flight"}, {"tokens", "finished", "flight", "firsts"}],
               "ds.serve.housekeep": [{"stats", "journal", "pump", "stragglers"}],
-              "ds.serve.prefill.wait": [{"launch"}], "ds.serve.chunk.wait": [{"launch"}]}
+              "ds.serve.chunk.wait": [{"launch"}]}
 
 
 def _hold_to_leaf_attrs(recs, want):
@@ -213,9 +217,11 @@ def test_leaves_tile_each_serve_step_and_carry_the_documented_attrs(served):
     # a statement of any weight outside a leaf, so its time outside them is held in seconds: 26-63 us a call with
     # six such processes on this host, 33-69 us with the loop held to depth 0 (the spans' own entries and exits).
     assert outside < 100e-6
-    # no ds.serve.chunk.wait here: with a step in flight a last chunk's token stays on its slot for that step's fetch
-    want = {k: v for k, v in LEAF_ATTRS.items() if k != "ds.serve.chunk.wait"}
-    seen = _hold_to_leaf_attrs(recs, want)
+    # two ds.serve.chunk.wait, for the one-chunk prompts (5 and 7 tokens) the empty server's first call admits:
+    # with a step in flight a last chunk's token stays on its slot for that step's fetch
+    seen = _hold_to_leaf_attrs(recs, LEAF_ATTRS)
+    assert sum(r[0] == "ds.serve.chunk.wait" for r in recs) == 2
+    assert not any(r[0] == "ds.serve.prefill.wait" or r[3].get("kind") == "prefill" for r in recs)
     # chunks rode steps, and first tokens were left on the device for a step's fetch
     assert len(seen["ds.serve.decode.dispatch"]) == 2 == len(seen["ds.serve.emit"])
     ahead = [r[3]["ahead"] for r in recs if r[0] == "ds.serve.decode.dispatch"]
@@ -225,9 +231,12 @@ def test_leaves_tile_each_serve_step_and_carry_the_documented_attrs(served):
     assert sum(a["admitted"] for a in admits) == len(reqs)
     assert {a["blocked"] for a in admits} == {"", "no_free_slot"}
     assert sum(r[3]["finished"] for r in recs if r[0] == "ds.serve.emit") == len(reqs)
-    # prompts of 18, 20 and 24 tokens went through the 8-token chunk program
+    # every prompt went through the 8-token chunk program, those of 4 to 7 tokens in ONE call that says so
     chunks = [r[3] for r in recs if r[0] == "ds.serve.chunk"]
-    assert sum(c["tokens"] for c in chunks) == 18 + 20 + 24
+    assert sum(c["tokens"] for c in chunks) == sum(r.prompt_len for r in reqs) == 84
+    launches = [r[3] for r in recs if r[0] == "ds.serve.launch"]
+    assert sum(c["whole"] for c in chunks) == 4 == sum(a.get("whole", 0) for a in launches)
+    assert sorted(a["tokens"] for a in launches if a.get("whole")) == [4, 5, 6, 7]
 
 
 def test_a_loop_with_nothing_in_flight_tiles_its_steps_and_waits_where_it_launches(served_in_turn):
@@ -281,8 +290,9 @@ def test_serving_setup_is_recorded_as_phases_that_name_their_programs(served):
     inside = [p for p in phases if p[0].startswith("ds.jit.") and progs[0][1] <= p[1] and p[2] <= progs[0][2]]
     assert {p[0] for p in inside} == {"ds.jit.trace", "ds.jit.lower", "ds.jit.compile"}
     compiled = {p[3]["fun"] for p in inside if p[0] == "ds.jit.compile"}
-    for program in ("prefill_fn", "decode_fn", "chunk_decode_fn"):   # jax calls them jit(prefill_fn), ...
+    for program in ("decode_fn", "chunk_decode_fn"):   # jax calls them jit(decode_fn), ...
         assert any(program in f for f in compiled), (program, compiled)
+    assert not any("prefill_fn" in f for f in compiled)    # a server that chunks builds no whole-prompt program
 
 
 # -- compile_stats ------------------------------------------------------------

@@ -288,8 +288,12 @@ def _served(family):
                   "moe.experts", "head", "sample"}),
 ])
 def test_a_served_familys_programs_every_dot_has_a_part(registry, family, expected):
-    srv = _served(family).serve(dict(SERVING))
+    engine = _served(family)
+    srv = engine.serve(dict(SERVING))
     srv._ensure_compiled()
+    assert set(parts.registered()) == {"jit_decode_fn", "jit_chunk_decode_fn"}    # a server that chunks: no whole-prompt program
+    whole = engine.serve(dict(SERVING, prefill_chunk_tokens=0))                  # (the registry holds a server weakly)
+    whole._ensure_compiled()
     assert set(parts.registered()) == {"jit_prefill_fn", "jit_decode_fn", "jit_chunk_decode_fn"}
     assert not parts._built
     tables = parts.tables()

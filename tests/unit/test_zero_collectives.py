@@ -223,7 +223,7 @@ def test_a_serving_decode_program_is_the_unpinned_builds(mechanism, monkeypatch)
     else:   # ISSUE 51: the served programs are not the engine's step, and ask the compiler for nothing
         _without_the_prefetch(monkeypatch)
     null = texts()
-    assert set(pinned) == {"jit_prefill_fn", "jit_decode_fn", "jit_chunk_decode_fn"}
+    assert set(pinned) == {"jit_decode_fn", "jit_chunk_decode_fn"}     # a server that chunks builds no whole-prompt program
     for name, text in pinned.items():
         assert _strip(text) == _strip(null[name]), name
         assert "sharding_constraint" not in text
